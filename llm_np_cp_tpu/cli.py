@@ -168,8 +168,8 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "dropping is always safe)")
     p.add_argument("--decode-attn", choices=["xla", "pallas"], default="xla",
                    help="attention kernel for the GATHERED decode step "
-                   "(pallas is gated: it silently downgrades off-TPU); "
-                   "ignored under --attn-impl paged")
+                   "(pallas fails at start-up if Mosaic refuses the "
+                   "kernel); ignored under --attn-impl paged")
     p.add_argument("--mixed-step", choices=["auto", "on", "off"],
                    default="auto",
                    help="unified ragged prefill+decode tick: ONE device "
@@ -587,6 +587,29 @@ def _resolve_serve_mesh(args, prog: str):
     return plan, [devices[i * per:(i + 1) * per] for i in range(replicas)]
 
 
+def _require_decode_kernel(args) -> None:
+    """``--decode-attn pallas`` names the cache-slab decode kernel (its
+    int8 variant under ``--cache-dtype int8``)."""
+    _require_kernel("--decode-attn pallas", (
+        "decode_attention_int8" if args.cache_dtype == "int8"
+        else "decode_attention"))
+
+
+def _require_kernel(flag: str, kernel: str) -> None:
+    """An EXPLICIT kernel flag must fail loudly when Mosaic refuses the
+    kernel — the library's gates downgrade to XLA with a log line, which
+    is what the ``auto`` modes are for, not what a user who named the
+    kernel asked for."""
+    from llm_np_cp_tpu.ops.pallas.support import kernel_error
+
+    err = kernel_error(kernel)
+    if err is not None:
+        raise SystemExit(
+            f"{flag}: the {kernel} kernel does not compile on this "
+            f"backend ({err}); drop the flag to use the XLA path"
+        )
+
+
 def _chaos_injector(args):
     """Resolve --chaos-spec (or LLMTPU_CHAOS_SPEC) into a FaultInjector —
     or None, the zero-overhead default.  Called BEFORE the model load so
@@ -633,7 +656,10 @@ def _build_serve_engine(args, params, config, *, prog: str,
     cache_dtype = {
         "bf16": jnp.bfloat16, "f32": jnp.float32, "int8": jnp.int8,
     }[args.cache_dtype]
-    gather_impl = "flash_decode" if args.decode_attn == "pallas" else "xla"
+    gather_impl = "xla"
+    if args.decode_attn == "pallas":
+        _require_decode_kernel(args)
+        gather_impl = "flash_decode"
     if args.attn_impl in ("paged", "auto"):
         from llm_np_cp_tpu.ops.pallas.support import (
             kernel_error,
@@ -936,7 +962,7 @@ def _run_serve_bench(argv: list[str], default_model: str) -> str:
         )
     plan, dev_slices = _resolve_serve_mesh(args, "serve-bench")
     injector = _chaos_injector(args)
-    _tok, params, config = _load(args)
+    _tok, params, config = _load(args, on_host=plan is not None)
     engine, num_blocks = _build_serve_engine(
         args, params, config, prog="serve-bench", fault_injector=injector,
         mesh_plan=plan, mesh_devices=dev_slices[0],
@@ -1085,7 +1111,7 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
         print(f"[serve] journal ACTIVE: {args.journal} "
               f"(epoch {journals[0].epoch}, sync={args.journal_sync}, "
               f"{sum(replays)} unterminated to replay)")
-    tok, params, config = _load(args)
+    tok, params, config = _load(args, on_host=plan is not None)
     engine, num_blocks = _build_serve_engine(
         args, params, config, prog="serve", tokenizer=tok,
         max_queue=args.max_queue or None, fault_injector=injector,
@@ -1102,6 +1128,9 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
         )[0]
         for i in range(1, args.replicas)
     ]
+    # each engine holds its own placed copy; under a mesh/replica
+    # placement the loaded tree is a host-side duplicate
+    del params
     runner = None
     if args.replicas > 1:
         from llm_np_cp_tpu.serve import ReplicaRunner
@@ -1156,7 +1185,7 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
         if body.get("model"):
             ns.model = str(body["model"])
         print(f"[serve] admin upgrade: loading checkpoint {ns.model}")
-        _, new_params, new_config = _load(ns)
+        _, new_params, new_config = _load(ns, on_host=plan is not None)
         if new_config != config:
             raise ValueError(
                 f"upgrade checkpoint {ns.model} has a different model "
@@ -1196,6 +1225,9 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
 def run(argv: list[str] | None = None, default_model: str = "meta-llama/Llama-3.2-1B") -> str:
     if argv is None:
         argv = sys.argv[1:]
+    from llm_np_cp_tpu.utils.runtime import configure_compile_cache
+
+    configure_compile_cache()
     if argv and argv[0] == "serve-bench":
         return _run_serve_bench(argv[1:], default_model)
     if argv and argv[0] == "serve":
@@ -1280,13 +1312,18 @@ def _draft_kwargs(kind: str, params: Any, config: Any) -> dict[str, Any]:
     return {}
 
 
-def _load(args) -> tuple[Any, Any, Any]:
+def _load(args, *, on_host: bool = False) -> tuple[Any, Any, Any]:
+    """(tokenizer, params, config).  ``on_host`` keeps the params as
+    host buffers for a caller that places them itself (serve replicas /
+    meshes: each engine device_puts its own copy or shards straight from
+    host memory, so nothing lands on — or lingers on — the default
+    device)."""
     import jax.numpy as jnp
 
     from llm_np_cp_tpu.utils.loading import load_model
 
     dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
-    return load_model(args.model, dtype=dtype)
+    return load_model(args.model, dtype=dtype, on_host=on_host)
 
 
 def _run_numpy(args) -> str:
@@ -1413,6 +1450,11 @@ def _run_tpu(args) -> str:
             "drop those flags or drop --speculative"
         )
     attn_impl = args.attn_impl or ("flash" if args.flash_prefill else "xla")
+    if attn_impl == "flash":
+        _require_kernel("--flash-prefill / --attn-impl flash",
+                        "flash_attention")
+    if args.decode_attn == "pallas":
+        _require_decode_kernel(args)
     if attn_impl == "ring" and (mesh is None or seq <= 1):
         raise SystemExit(
             "--attn-impl ring needs a sequence-parallel mesh: pass "

@@ -32,7 +32,7 @@ def softmax(
 
     Leading axes are flattened to rows; ``block_rows`` rows are processed
     per grid step (the whole axis must fit in VMEM — true for vocab-sized
-    axes: 8 rows × 128256 f32 ≈ 4 MB).
+    axes: 8 rows × 256000 f32 ≈ 8 MB per block).
 
     interpret=None auto-selects: compiled on TPU, interpreter elsewhere.
     """
@@ -49,9 +49,16 @@ def softmax(
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
 
+    # in + out blocks are double-buffered and the kernel holds f32
+    # temporaries of the same size: at vocab-sized axes that exceeds
+    # Mosaic's default 16 MiB scoped-VMEM limit (refused at compile), so
+    # the limit is stated from the block size, inside v5e's 128 MiB
+    block_bytes = block_rows * axis * 4
+    vmem_limit = min(8 * block_bytes + (4 << 20), 100 << 20)
     out = pl.pallas_call(
         _softmax_kernel,
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         grid=(x2.shape[0] // block_rows,),
         in_specs=[
             pl.BlockSpec(

@@ -16,6 +16,7 @@ process if the toolchain is broken; gating is the TPU-native upgrade.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
 
@@ -45,9 +46,358 @@ def disable_kernel(kernel: str, reason: str) -> None:
     )
 
 
+# Every kernel name the gates know.  The probes, bench.py's ``kernels``
+# child and chip_smoke.py's on-chip matrix all iterate THIS tuple.
+KERNELS = (
+    "softmax",
+    "flash_attention",
+    "decode_attention", "decode_attention_int8",
+    "paged_decode_attention", "paged_decode_attention_int8",
+    "ragged_paged_attention", "ragged_paged_attention_int8",
+    "sample_epilogue", "sample_epilogue_int8",
+)
+
+# Pool block sizes the serve path uses (cli --block-size default 64,
+# bench serve cells 128).
+SERVE_BLOCK_SIZES = (64, 128)
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelShape:
+    """The widths a kernel case is built at: head layout for the
+    attention kernels, hidden/vocab/head layout for the epilogue."""
+
+    name: str
+    heads: int
+    kv_heads: int
+    head_dim: int
+    hidden: int
+    vocab: int
+    attn_softcap: float | None = None
+    final_softcap: float | None = None
+    window: int | None = None
+    unit_offset: bool = False
+    # lm-head layout the epilogue streams: the tied [V, H] embedding
+    # table (every supported family) or an untied [H, V] head
+    tied: bool = True
+
+    @classmethod
+    def of(cls, name: str, config) -> "KernelShape":
+        return cls(
+            name=name, heads=config.num_attention_heads,
+            kv_heads=config.num_key_value_heads, head_dim=config.head_dim,
+            hidden=config.hidden_size, vocab=config.vocab_size,
+            attn_softcap=config.attn_logit_softcapping,
+            final_softcap=config.final_logit_softcapping,
+            window=config.sliding_window,
+            unit_offset=config.rms_norm_unit_offset,
+            tied=config.tie_word_embeddings,
+        )
+
+
+# The startup probe compiles at the head layout of the smallest real
+# model the serve path targets (Qwen2.5-1.5B: 12 q / 2 kv heads, so the
+# group is 6 — NOT a multiple of the 8-row sublane tile — and d = 128),
+# with a small hidden size and a multi-tile vocab with a ragged tail
+# (300 = 2*128 + 44) so it stays a sub-second compile.  "Probe passed"
+# then means the layout classes of real widths lowered through Mosaic.
+# Every supported family ties its head (the [V, H] stream); the second
+# probe shape covers the untied [H, V] stream with Gemma's softcap +
+# unit-offset norm, which only the epilogue kernels distinguish.
+PROBE_SHAPE = KernelShape(
+    "probe", heads=12, kv_heads=2, head_dim=128, hidden=256, vocab=300,
+)
+PROBE_SHAPES = (
+    PROBE_SHAPE,
+    dataclasses.replace(PROBE_SHAPE, name="probe/untied", tied=False,
+                        final_softcap=30.0, unit_offset=True),
+)
+
+
+def family_shapes() -> tuple[KernelShape, ...]:
+    """The three families the parity suite covers, at published widths."""
+    from llm_np_cp_tpu.config import GEMMA_2_2B, LLAMA_3_2_1B, QWEN_2_5_1_5B
+
+    return (
+        KernelShape.of("qwen2.5-1.5b", QWEN_2_5_1_5B),
+        KernelShape.of("llama-3.2-1b", LLAMA_3_2_1B),
+        KernelShape.of("gemma-2-2b", GEMMA_2_2B),
+    )
+
+
+def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
+                *, interpret: bool = False):
+    """``kernel`` at ``shape`` → ``(make_args, run, reference)``.
+
+    ``make_args()`` builds the seeded operands (jittable, so
+    ``jax.eval_shape`` yields their avals for a deviceless compile);
+    ``run(*args)`` is the Pallas kernel lowered THROUGH MOSAIC
+    (``interpret=False`` unless a CPU test asks otherwise);
+    ``reference(*args)`` computes the same result with the kernel's XLA
+    twin.  Attention kernels and softmax return the output array; the
+    epilogue returns, per row, the XLA logit of the token each
+    implementation chose (equal logits = same argmax up to rounding;
+    comparing token ids would flip on ties with random weights).
+    ``block_size`` is the pool block length for the paged kernels and is
+    ignored by the others."""
+    import jax.random as jr
+
+    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
+    from llm_np_cp_tpu.ops.attention import causal_mask, gqa_attention
+
+    int8 = kernel.endswith("_int8")
+    base = kernel.removesuffix("_int8")
+    h, kh, d = shape.heads, shape.kv_heads, shape.head_dim
+    scale = float(d) ** -0.5
+    softcap = shape.attn_softcap
+    bf16 = jnp.bfloat16
+
+    def normals(*shapes, dtype=bf16):
+        keys = jr.split(jr.PRNGKey(0), len(shapes))
+        return tuple(jr.normal(k, s, dtype) for k, s in zip(keys, shapes))
+
+    def kv_operands(kv):
+        """kv → the kernel's (k, v[, k_scale, v_scale]) operand tuple."""
+        if not int8:
+            return (kv, kv)
+        q8, sc = quantize_kv(kv)
+        return (q8, q8, sc, sc)
+
+    def kv_kwargs(ops):
+        return dict(k_scale=ops[2], v_scale=ops[3]) if int8 else {}
+
+    def kv_float(ops):
+        return dequantize_kv(ops[0], ops[2], bf16) if int8 else ops[0]
+
+    if base == "softmax":
+        from llm_np_cp_tpu.ops.pallas.softmax import softmax
+
+        return (
+            lambda: normals((16, shape.vocab), dtype=jnp.float32),
+            lambda x: softmax(x, interpret=interpret),
+            lambda x: jax.nn.softmax(x, axis=-1),
+        )
+
+    if base == "flash_attention":
+        from llm_np_cp_tpu.ops.pallas.flash_attention import flash_attention
+
+        s = 1024
+        pos = jnp.arange(s, dtype=jnp.int32)
+        return (
+            lambda: normals((1, s, h, d), (1, s, kh, d)),
+            lambda q, kv: flash_attention(
+                q, kv, kv, scale=scale, logit_softcap=softcap,
+                window=shape.window, interpret=interpret),
+            lambda q, kv: gqa_attention(
+                q, kv, kv, causal_mask(pos[None], pos, window=shape.window),
+                scale=scale, logit_softcap=softcap),
+        )
+
+    if base == "decode_attention":
+        from llm_np_cp_tpu.ops.pallas.decode_attention import decode_attention
+
+        # ragged visibility: each row sees [pad, length) of a 1024-slot
+        # cache, so both block-skip bounds are exercised
+        b, s = 4, 1024
+
+        def make_args():
+            q, kv = normals((b, 1, h, d), (b, s, kh, d))
+            pos = jnp.arange(s, dtype=jnp.int32)[None, :]
+            lengths = jnp.asarray([[1024], [700], [300], [40]], jnp.int32)
+            pads = jnp.asarray([[0], [130], [5], [33]], jnp.int32)
+            return (q, (pos >= pads) & (pos < lengths), *kv_operands(kv))
+
+        return (
+            make_args,
+            lambda q, mask, *ops: decode_attention(
+                q, ops[0], ops[1], mask, scale=scale, logit_softcap=softcap,
+                interpret=interpret, **kv_kwargs(ops)),
+            lambda q, mask, *ops: gqa_attention(
+                q, kv_float(ops), kv_float(ops), mask[:, None, :],
+                scale=scale, logit_softcap=softcap),
+        )
+
+    bs = block_size
+    nbp, mb = 24, 4
+    if base == "paged_decode_attention":
+        from llm_np_cp_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention,
+        )
+
+        # block tables permute the pool; row 1's pad spans a whole block
+        # (start = 1) so the leading-block-skip path compiles too
+        b = 4
+
+        def make_args():
+            q, pages = normals((b, 1, h, d), (nbp, bs, kh, d))
+            tables = jnp.asarray(
+                [[2, 1, 7, 9], [3, 0, 5, 11], [4, 6, 8, 10],
+                 [12, 13, 14, 15]], jnp.int32)
+            lengths = jnp.asarray(
+                [4 * bs, 2 * bs - 1, bs // 2 + 3, 3 * bs + 1], jnp.int32)
+            pads = jnp.asarray([0, bs + 3, 5, 0], jnp.int32)
+            return (q, tables, lengths, pads, *kv_operands(pages))
+
+        def reference(q, tables, lengths, pads, *ops):
+            view = kv_float(ops)[tables].reshape(b, mb * bs, kh, d)
+            pos = jnp.arange(mb * bs, dtype=jnp.int32)[None, :]
+            mask = (pos >= pads[:, None]) & (pos < lengths[:, None])
+            return gqa_attention(q, view, view, mask[:, None, :],
+                                 scale=scale, logit_softcap=softcap)
+
+        return (
+            make_args,
+            lambda q, tables, lengths, pads, *ops: paged_decode_attention(
+                q, ops[0], ops[1], tables, lengths, pads, scale=scale,
+                logit_softcap=softcap, interpret=interpret,
+                **kv_kwargs(ops)),
+            reference,
+        )
+
+    if base == "ragged_paged_attention":
+        from llm_np_cp_tpu.ops.pallas.decode_attention import (
+            RAGGED_Q_TILE,
+            ragged_paged_attention,
+            ragged_paged_attention_xla,
+        )
+
+        # a representative mixed tick: row 0 prefills a 2-tile chunk
+        # (ragged tail), row 1 decodes one token deep into its 4th
+        # block behind a whole-block pad, row 2 prefills its 2nd chunk
+        # across a block boundary, then a dead padding tile
+        qt = RAGGED_Q_TILE
+        n_tiles = 6
+        window = jnp.int32(shape.window or (1 << 30))
+
+        def make_args():
+            q, pages = normals((n_tiles * qt, h, d), (nbp, bs, kh, d))
+            tables = jnp.asarray(
+                [[2, 1, 4, 9], [3, 5, 0, 11], [6, 7, 8, 10]], jnp.int32)
+            tile_row = jnp.asarray([0, 0, 1, 2, 2, 0], jnp.int32)
+            tile_qpos0 = jnp.asarray(
+                [5, 5 + qt, 3 * bs + 7, bs - 4, bs - 4 + qt, 0], jnp.int32)
+            tile_qlen = jnp.asarray([qt, qt - 3, 1, qt, qt, 0], jnp.int32)
+            pads = jnp.asarray([5, bs + 2, 0], jnp.int32)
+            return (q, tables, tile_row, tile_qpos0, tile_qlen, pads,
+                    *kv_operands(pages))
+
+        def live_lanes(tile_qlen):
+            lane = jnp.arange(n_tiles * qt, dtype=jnp.int32) % qt
+            return lane, lane < jnp.repeat(tile_qlen, qt)
+
+        # dead lanes are unspecified in both implementations: zero them
+        def run(q, tables, tile_row, tile_qpos0, tile_qlen, pads, *ops):
+            out = ragged_paged_attention(
+                q, ops[0], ops[1], tables, tile_row, tile_qpos0, tile_qlen,
+                pads, window, scale=scale, logit_softcap=softcap,
+                interpret=interpret, **kv_kwargs(ops))
+            return jnp.where(live_lanes(tile_qlen)[1][:, None, None], out, 0)
+
+        def reference(q, tables, tile_row, tile_qpos0, tile_qlen, pads,
+                      *ops):
+            lane, live = live_lanes(tile_qlen)
+            out = ragged_paged_attention_xla(
+                q, ops[0], ops[1], tables, jnp.repeat(tile_row, qt),
+                jnp.repeat(tile_qpos0, qt) + lane, live, pads, window,
+                scale=scale, logit_softcap=softcap, **kv_kwargs(ops))
+            return jnp.where(live[:, None, None], out, 0)
+
+        return make_args, run, reference
+
+    if base == "sample_epilogue":
+        from llm_np_cp_tpu.ops.norms import rms_norm
+        from llm_np_cp_tpu.ops.pallas.sample_epilogue import sample_epilogue
+        from llm_np_cp_tpu.quant import quantize_array
+
+        # 5 rows exercise the sublane pad
+        n, hid, v = 5, shape.hidden, shape.vocab
+        tied, cap, offset = shape.tied, shape.final_softcap, shape.unit_offset
+
+        def make_args():
+            x, gamma, w = normals(
+                (n, hid), (hid,), (v, hid) if tied else (hid, v))
+            w = w * jnp.asarray(0.02, bf16)
+            if not int8:
+                return (x, gamma, w)
+            qw = quantize_array(w, axis=-1 if tied else -2)
+            return (x, gamma, qw["q"], qw["s"].reshape(1, -1))
+
+        def logits(x, gamma, w, w_scale=None):
+            xn = rms_norm(x, gamma, eps=1e-6, unit_offset=offset)
+            lg = jnp.einsum(
+                "nh,vh->nv" if tied else "nh,hv->nv", xn, w.astype(bf16),
+                preferred_element_type=jnp.float32)
+            if w_scale is not None:
+                lg = lg * w_scale
+            if cap is not None:
+                lg = jnp.tanh(lg / cap) * cap
+            return lg
+
+        def run(x, gamma, w, w_scale=None):
+            tok = sample_epilogue(
+                x, gamma, w, w_scale=w_scale, tied=tied, eps=1e-6,
+                logit_softcap=cap, unit_offset=offset, interpret=interpret)
+            return logits(x, gamma, w, w_scale)[jnp.arange(n), tok]
+
+        return (make_args, run,
+                lambda *args: jnp.max(logits(*args), axis=-1))
+
+    raise ValueError(f"unknown kernel {kernel!r}")
+
+
+# |kernel - XLA twin| a correct kernel stays inside at these seeded
+# inputs: bf16 operands, f32 accumulation, outputs of magnitude <= ~1
+# (the AMLA rescale and the XLA softmax round p to bf16 at different
+# octaves, which is the dominant term).  A kernel that lowers and then
+# computes garbage on hardware is off by O(1).
+KERNEL_TOLERANCE = 5e-2
+
+
+def kernel_cases(shapes=None):
+    """Every ``(kernel, shape, block_size)`` the on-chip matrix covers:
+    all of ``KERNELS`` at the probe shapes and the three family shapes,
+    the paged kernels at both serve block sizes."""
+    shapes = shapes if shapes is not None else (*PROBE_SHAPES,
+                                                *family_shapes())
+    for shape in shapes:
+        for kernel in KERNELS:
+            if not shape.tied and not kernel.startswith("sample_epilogue"):
+                continue  # only the epilogue distinguishes head layouts
+            paged = kernel.startswith(("paged_", "ragged_"))
+            for bs in SERVE_BLOCK_SIZES if paged else (None,):
+                yield kernel, shape, bs
+
+
+def kernel_matrix(shapes=None, *, interpret: bool = False) -> list[dict]:
+    """Compile+run every ``kernel_cases`` entry through Mosaic and
+    compare it against its XLA twin.  One verdict dict per case:
+    ``{"kernel", "shape", "block_size", "ok", "max_err" | "error"}``.
+    Needs a TPU backend (``interpret=False`` cannot lower elsewhere);
+    CPU tests pass ``interpret=True`` to keep the cases themselves
+    honest."""
+    out = []
+    for kernel, shape, bs in kernel_cases(shapes):
+        rec = {"kernel": kernel, "shape": shape.name, "block_size": bs}
+        try:
+            make_args, run, reference = kernel_case(
+                kernel, shape, bs or SERVE_BLOCK_SIZES[0],
+                interpret=interpret)
+            args = make_args()
+            got = np.asarray(jax.jit(run)(*args), np.float32)
+            want = np.asarray(jax.jit(reference)(*args), np.float32)
+            rec["max_err"] = float(np.max(np.abs(got - want)))
+            rec["ok"] = bool(np.isfinite(got).all()
+                             and rec["max_err"] <= KERNEL_TOLERANCE)
+        except Exception as e:  # noqa: BLE001 — the verdict IS the error
+            rec["ok"] = False
+            rec["error"] = f"{type(e).__name__}: {e}"
+        out.append(rec)
+    return out
+
+
 @functools.lru_cache(maxsize=None)
 def _probe(kernel: str, backend: str) -> str | None:
-    """Compile+run `kernel` at tiny shapes on `backend`.
+    """Compile+run `kernel` at ``PROBE_SHAPES`` on `backend`.
 
     Returns None on success, else the error string.  Cached per process;
     off-TPU backends return None without compiling (the kernels run the
@@ -57,141 +407,12 @@ def _probe(kernel: str, backend: str) -> str | None:
         return "forced failure (test hook)"
     if backend != "tpu":
         return None
-    rng = np.random.default_rng(0)
     try:
-        if kernel == "softmax":
-            from llm_np_cp_tpu.ops.pallas.softmax import softmax
-
-            x = jnp.asarray(rng.standard_normal((8, 256)), jnp.float32)
-            np.asarray(softmax(x, interpret=False))
-        elif kernel == "flash_attention":
-            from llm_np_cp_tpu.ops.pallas.flash_attention import flash_attention
-
-            q = jnp.asarray(rng.standard_normal((1, 128, 2, 64)), jnp.bfloat16)
-            k = jnp.asarray(rng.standard_normal((1, 128, 1, 64)), jnp.bfloat16)
-            np.asarray(flash_attention(q, k, k, scale=0.125, interpret=False))
-        elif kernel in ("decode_attention", "decode_attention_int8"):
-            from llm_np_cp_tpu.ops.pallas.decode_attention import decode_attention
-
-            # GQA shape representative of real models: kh>1 exercises the
-            # kernel's static kv-head unroll, and g=4 puts the scratch row
-            # slices at non-8-aligned sublane offsets (ki*g = 0, 4) — the
-            # layout class only a hardware compile validates
-            b, s, khd = 1, 128, 64
-            q = jnp.asarray(rng.standard_normal((b, 1, 8, khd)), jnp.bfloat16)
-            kv = jnp.asarray(rng.standard_normal((b, s, 2, khd)), jnp.bfloat16)
-            mask = jnp.ones((b, s), bool)
-            if kernel.endswith("int8"):
-                from llm_np_cp_tpu.cache import quantize_kv
-
-                kq, ks = quantize_kv(kv)
-                np.asarray(decode_attention(
-                    q, kq, kq, mask, k_scale=ks, v_scale=ks, scale=0.125,
-                    block_s=64, interpret=False,
-                ))
-            else:
-                np.asarray(decode_attention(
-                    q, kv, kv, mask, scale=0.125, block_s=64, interpret=False,
-                ))
-        elif kernel in ("paged_decode_attention", "paged_decode_attention_int8"):
-            from llm_np_cp_tpu.ops.pallas.decode_attention import (
-                paged_decode_attention,
-            )
-
-            # serving-pool shapes: 4 blocks of 32 slots, 2-row batch with
-            # block tables permuting the pool — the scalar-prefetch index
-            # map is the layout class only a hardware compile validates;
-            # row 1's pad spans a whole block (start = 1) so the
-            # leading-block-skip path compiles too
-            b, nbp, bs, khd = 2, 4, 32, 64
-            q = jnp.asarray(rng.standard_normal((b, 1, 8, khd)), jnp.bfloat16)
-            pages = jnp.asarray(
-                rng.standard_normal((nbp, bs, 2, khd)), jnp.bfloat16
-            )
-            tables = jnp.asarray([[2, 1], [3, 0]], jnp.int32)
-            lengths = jnp.asarray([40, 63], jnp.int32)
-            pads = jnp.asarray([0, 35], jnp.int32)
-            kwargs = {}
-            if kernel.endswith("int8"):
-                from llm_np_cp_tpu.cache import quantize_kv
-
-                pages, scales = quantize_kv(pages)
-                kwargs = dict(k_scale=scales, v_scale=scales)
-            np.asarray(paged_decode_attention(
-                q, pages, pages, tables, lengths, pads, scale=0.125,
-                interpret=False, **kwargs,
-            ))
-        elif kernel in ("sample_epilogue", "sample_epilogue_int8"):
-            from llm_np_cp_tpu.ops.pallas.sample_epilogue import (
-                sample_epilogue,
-            )
-
-            # both head layouts at a multi-tile vocab with a ragged tail
-            # (300 = 2*128 + 44): the streamed lm-head BlockSpecs + the
-            # argmax/scratch layout class only a hardware compile
-            # validates.  5 rows exercise the sublane pad too.
-            n, h, v = 5, 64, 300
-            x = jnp.asarray(rng.standard_normal((n, h)), jnp.bfloat16)
-            gamma = jnp.asarray(rng.standard_normal((h,)), jnp.bfloat16)
-            tied_w = jnp.asarray(rng.standard_normal((v, h)), jnp.bfloat16)
-            untied_w = jnp.asarray(
-                rng.standard_normal((h, v)), jnp.bfloat16
-            )
-            kwargs = {}
-            if kernel.endswith("int8"):
-                from llm_np_cp_tpu.quant import quantize_array
-
-                qt = quantize_array(tied_w, axis=-1)
-                qu = quantize_array(untied_w, axis=-2)
-                tied_w, untied_w = qt["q"], qu["q"]
-                tied_kwargs = dict(w_scale=qt["s"].reshape(1, -1))
-                untied_kwargs = dict(w_scale=qu["s"].reshape(1, -1))
-            else:
-                tied_kwargs = untied_kwargs = {}
-            np.asarray(sample_epilogue(
-                x, gamma, tied_w, tied=True, eps=1e-6, block_v=128,
-                interpret=False, **tied_kwargs,
-            ))
-            np.asarray(sample_epilogue(
-                x, gamma, untied_w, tied=False, eps=1e-6,
-                logit_softcap=30.0, unit_offset=True, block_v=128,
-                interpret=False, **untied_kwargs,
-            ))
-        elif kernel in ("ragged_paged_attention", "ragged_paged_attention_int8"):
-            from llm_np_cp_tpu.ops.pallas.decode_attention import (
-                RAGGED_Q_TILE,
-                ragged_paged_attention,
-            )
-
-            # a representative mixed tick: one 2-tile prefill segment
-            # (ragged tail), one decode tile, one dead padding tile —
-            # the tile-metadata scalar-prefetch + q-tile layout class
-            # only a hardware compile validates
-            nbp, bs, khd = 6, 32, 64
-            qt = RAGGED_Q_TILE
-            t = 4 * qt
-            q = jnp.asarray(rng.standard_normal((t, 8, khd)), jnp.bfloat16)
-            pages = jnp.asarray(
-                rng.standard_normal((nbp, bs, 2, khd)), jnp.bfloat16
-            )
-            tables = jnp.asarray([[2, 1, 4], [3, 5, 0]], jnp.int32)
-            tile_row = jnp.asarray([0, 0, 1, 0], jnp.int32)
-            tile_qpos0 = jnp.asarray([5, 13, 40, 0], jnp.int32)
-            tile_qlen = jnp.asarray([8, 4, 1, 0], jnp.int32)
-            pads = jnp.asarray([5, 33], jnp.int32)
-            kwargs = {}
-            if kernel.endswith("int8"):
-                from llm_np_cp_tpu.cache import quantize_kv
-
-                pages, scales = quantize_kv(pages)
-                kwargs = dict(k_scale=scales, v_scale=scales)
-            np.asarray(ragged_paged_attention(
-                q, pages, pages, tables, tile_row, tile_qpos0, tile_qlen,
-                pads, jnp.int32(1 << 30), scale=0.125, interpret=False,
-                **kwargs,
-            ))
-        else:
-            raise ValueError(f"unknown kernel {kernel!r}")
+        for shape in PROBE_SHAPES:
+            if shape.tied or kernel.startswith("sample_epilogue"):
+                make_args, run, _ = kernel_case(kernel, shape,
+                                                SERVE_BLOCK_SIZES[0])
+                np.asarray(run(*make_args()))
     except Exception as e:  # noqa: BLE001 — any compile/runtime error gates
         return f"{type(e).__name__}: {e}"
     return None

@@ -212,11 +212,14 @@ def param_specs(config: ModelConfig, plan: MeshPlan) -> dict[str, Any]:
         "down_proj": P(pp, m, None),
     }
     if config.attention_bias:
-        # biases follow their projection's output sharding; o_bias is added
-        # after the row-parallel psum, so it stays replicated on "model"
+        # biases follow their projection's output sharding
         layers["q_bias"] = P(pp, m)
         layers["k_bias"] = P(pp, kv)
         layers["v_bias"] = P(pp, kv)
+    if config.o_proj_bias:
+        # the same independent gate as param_shapes (Qwen-2 biases Q/K/V
+        # but not o_proj); added after the row-parallel psum, so it stays
+        # replicated on "model"
         layers["o_bias"] = P(pp, None)
     if config.mlp_bias:
         layers["gate_bias"] = P(pp, m)

@@ -33,9 +33,11 @@ slabs update in place.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from llm_np_cp_tpu.config import ModelConfig
 
@@ -174,22 +176,39 @@ class BlockPool:
             config.head_dim,
         )
         quantized = self.dtype == jnp.int8
-        self.pages = PagedKV(
-            k=jnp.zeros(shape, dtype=dtype),
-            v=jnp.zeros(shape, dtype=dtype),
-            k_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
-            v_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
-        )
         # mesh-sharded mode: a PagedKV of NamedShardings (kv-head axis on
         # "model", see parallel/sharding.paged_kv_specs) commits the slabs
         # onto the mesh; the FREE LIST stays global — allocation is a
         # host-side decision and every shard holds the same block ids,
-        # only a head-slice of each block's K/V
+        # only a head-slice of each block's K/V.
         self.shardings = shardings
-        if shardings is not None:
-            import jax
+        where = shardings if shardings is not None else PagedKV(
+            None, None, None, None)
 
-            self.pages = jax.tree.map(jax.device_put, self.pages, shardings)
+        def zeros(shp: tuple, dt: Any, sharding: Any) -> jnp.ndarray:
+            # Born ON its devices: each addressable shard is put from one
+            # lazily-zeroed host buffer straight onto the device that
+            # owns it.  What NOT to do, both measured on the four-chip
+            # host (PR 21): zeros-then-device_put and
+            # jnp.zeros(device=...) materialize a shard on the DEFAULT
+            # device and copy it out (+304 MiB peak on chip 0 with four
+            # replicas); a jitted zeros with a 2-device out_shardings
+            # halted the second TP replica's cores ("Invalid logical z:
+            # enhanced-barrier-parent-phase-1") at its first sync.
+            if sharding is None:
+                return jnp.zeros(shp, dt)
+            host = np.zeros(sharding.shard_shape(shp), dt)
+            return jax.make_array_from_callback(shp, sharding,
+                                                lambda _index: host)
+
+        self.pages = PagedKV(
+            k=zeros(shape, dtype, where.k),
+            v=zeros(shape, dtype, where.v),
+            k_scale=(zeros(shape[:-1], jnp.float32, where.k_scale)
+                     if quantized else None),
+            v_scale=(zeros(shape[:-1], jnp.float32, where.v_scale)
+                     if quantized else None),
+        )
 
     # -- accounting (delegates; the scheduler talks to these) ----------
     @property

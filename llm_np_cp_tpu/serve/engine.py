@@ -816,8 +816,8 @@ class ServeEngine:
         (+ int8 scale pages), while tables / lengths / pads / window
         metadata arrive replicated — GQA's kv-major head order makes the
         local group math identical to the global one.  Softmax is
-        per-head, so no cross-shard collective is needed; check_rep is
-        off because the kernel's gathers defeat rep inference.
+        per-head, so no cross-shard collective is needed; check_vma is
+        off because the kernel's gathers defeat replication inference.
 
         Off-mesh (or kv-replicated) the callable runs as-is.  Calling
         convention: ``wrapped(q, k_pages, v_pages, [k_scale, v_scale,]
@@ -831,7 +831,6 @@ class ServeEngine:
                 return fn(q, kp, vp, *meta, k_scale=None, v_scale=None)
         if self.mesh is None or not self._kv_sharded:
             return call
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from llm_np_cp_tpu.parallel.sharding import MODEL_AXIS
@@ -845,8 +844,8 @@ class ServeEngine:
         rep = P()
         in_specs = (qs, kvs, kvs) + ((ss, ss) if quantized else ())
         in_specs += (rep,) * n_meta
-        return shard_map(call, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=qs, check_rep=False)
+        return jax.shard_map(call, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=qs, check_vma=False)
 
     # ------------------------------------------------------------------
     def _prefill_width(self, req: Request) -> int:
@@ -1535,9 +1534,11 @@ class ServeEngine:
                             (vp.at[blk, off].set(vq[:, 0]),
                              vsp.at[blk, off].set(vs[:, 0])),
                         )
+                    # explicit cast: f32 activations into a bf16 pool
+                    # is the intended rounding, not an implicit promotion
                     return (
-                        kp.at[blk, off].set(k[:, 0]),
-                        vp.at[blk, off].set(v[:, 0]),
+                        kp.at[blk, off].set(k[:, 0].astype(kp.dtype)),
+                        vp.at[blk, off].set(v[:, 0].astype(vp.dtype)),
                     )
 
                 def attn_fn(q, k_att, v_att, sliding_l):
@@ -1696,9 +1697,11 @@ class ServeEngine:
                             (vp.at[tok_blk, tok_off].set(vq[0]),
                              vsp.at[tok_blk, tok_off].set(vs[0])),
                         )
+                    # explicit cast: f32 activations into a bf16 pool
+                    # is the intended rounding, not an implicit promotion
                     return (
-                        kp.at[tok_blk, tok_off].set(k[0]),
-                        vp.at[tok_blk, tok_off].set(v[0]),
+                        kp.at[tok_blk, tok_off].set(k[0].astype(kp.dtype)),
+                        vp.at[tok_blk, tok_off].set(v[0].astype(vp.dtype)),
                     )
 
                 def attn_fn(q, k_att, v_att, sliding_l):

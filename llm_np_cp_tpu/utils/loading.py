@@ -31,6 +31,7 @@ same semantics as the reference's key rewrite, zero extra memory.
 from __future__ import annotations
 
 import json
+import logging
 import re
 import time
 from pathlib import Path
@@ -44,6 +45,8 @@ from safetensors import safe_open
 from llm_np_cp_tpu.config import ModelConfig
 from llm_np_cp_tpu.models import gemma2, llama, qwen2
 from llm_np_cp_tpu.models.transformer import param_shapes
+
+log = logging.getLogger("llm_np_cp_tpu")
 
 _LAYER_RE = re.compile(r"^model\.layers\.(\d+)\.(.+)$")
 
@@ -88,6 +91,8 @@ def _read_shard(
             f, native = _open_shard(path, use_native)
             with f:
                 consume(f, native)
+            log.info("%s: read through the %s reader", path.name,
+                     "native C++" if native else "python safetensors")
             return
         except _PERMANENT_OS_ERRORS:
             raise  # the OS message already names the path
@@ -140,13 +145,15 @@ def _open_shard(path: Path, use_native: bool):
     does threaded transpose/cast (llm_np_cp_tpu/native); the safetensors
     python reader is the fallback."""
     if use_native:
-        try:
-            from llm_np_cp_tpu.native import NativeSafetensorsFile, is_available
+        from llm_np_cp_tpu.native import NativeSafetensorsFile, is_available
 
-            if is_available():
+        if is_available():
+            try:
                 return NativeSafetensorsFile(path), True
-        except Exception:
-            pass
+            except (OSError, ValueError) as e:
+                log.warning("%s: native reader refused the shard (%s: %s); "
+                            "using the python safetensors reader",
+                            path.name, type(e).__name__, e)
     return safe_open(path, framework="np"), False
 
 
@@ -157,6 +164,7 @@ def load_params(
     dtype=None,
     shardings: Any = None,
     use_native: bool = True,
+    on_host: bool = False,
 ) -> tuple[dict[str, Any], ModelConfig]:
     """Load an HF checkpoint directory into the model's param pytree.
 
@@ -164,6 +172,11 @@ def load_params(
     shardings: optional pytree of jax.sharding.Sharding matching the param
         tree; each buffer is device_put onto it as soon as it is filled.
     use_native: route tensor bytes through the C++ reader when built.
+    on_host: return the stacked numpy buffers without touching a device —
+        for callers that place the params themselves on devices that are
+        not the default one (N serve replicas, each on its own chip: a
+        load onto the default device would pile every copy's source onto
+        chip 0 and keep it there).
     Returns (params, config).
     """
     import jax.numpy as jnp
@@ -245,6 +258,8 @@ def load_params(
     _check_complete(host, filled, config)
 
     def place(path_: tuple, buf: np.ndarray):
+        if on_host:
+            return buf
         if shardings is not None:
             shard = _tree_get(shardings, path_)
             if shard is not None:
@@ -297,6 +312,7 @@ def load_model(
     dtype=None,
     shardings: Any = None,
     tokenizer: bool = True,
+    on_host: bool = False,
 ):
     """(tokenizer, params, config) from a local dir or an HF repo id.
 
@@ -315,5 +331,6 @@ def load_model(
         from transformers import AutoTokenizer
 
         tok = AutoTokenizer.from_pretrained(str(path))
-    params, config = load_params(path, dtype=dtype, shardings=shardings)
+    params, config = load_params(path, dtype=dtype, shardings=shardings,
+                                 on_host=on_host)
     return tok, params, config

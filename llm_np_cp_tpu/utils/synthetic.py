@@ -1,0 +1,233 @@
+"""Seeded synthetic checkpoints in the HF directory layout.
+
+What a run with no network loads instead of a download: ``config.json``,
+safetensors shards with an index, and a tokenizer — everything
+``utils.loading.load_model`` needs to start — generated from a seed, so
+the REAL load → place-on-device path runs at a model's published size
+(``chip_smoke.py``; the loader tests use the same writer at toy sizes).
+
+Key names are the inverse of the loader's own key maps
+(``models/<family>.py``), so a tensor the loader would not find cannot
+be written here.
+"""
+
+from __future__ import annotations
+
+import json
+from concurrent.futures import ThreadPoolExecutor
+from pathlib import Path
+from typing import Any, Mapping
+
+import ml_dtypes
+import numpy as np
+from safetensors.numpy import save_file
+
+from llm_np_cp_tpu.config import ModelConfig
+from llm_np_cp_tpu.models.transformer import param_shapes
+from llm_np_cp_tpu.utils.loading import _key_maps
+
+# elements drawn per RNG call — the unit of parallelism, so one large
+# tensor (a 233M-element embedding table) still uses every worker
+_CHUNK = 1 << 22
+
+
+def _write_json(path: Path, obj: Any) -> None:
+    with open(path, "w") as f:
+        json.dump(obj, f)
+
+
+def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
+    """The ``config.json`` mapping ``ModelConfig.from_hf_dict`` reads back
+    into an equal config."""
+    d: dict[str, Any] = {
+        "model_type": config.model_type,
+        "vocab_size": config.vocab_size,
+        "hidden_size": config.hidden_size,
+        "intermediate_size": config.intermediate_size,
+        "num_hidden_layers": config.num_hidden_layers,
+        "num_attention_heads": config.num_attention_heads,
+        "num_key_value_heads": config.num_key_value_heads,
+        "head_dim": config.head_dim,
+        "max_position_embeddings": config.max_position_embeddings,
+        "rope_theta": config.rope_theta,
+        "rms_norm_eps": config.rms_norm_eps,
+        "hidden_act": config.hidden_act,
+        "tie_word_embeddings": config.tie_word_embeddings,
+        # what a published checkpoint carries; AutoTokenizer reads it to
+        # decide the directory is not a pre-fix Mistral tokenizer
+        "transformers_version": "4.40.1",
+    }
+    if config.model_type != "qwen2":  # qwen2 implies its bias pattern
+        d["attention_bias"] = config.attention_bias
+    if config.mlp_bias:
+        d["mlp_bias"] = True
+    if config.rope_scaling_type == "llama3":
+        d["rope_scaling"] = {
+            "rope_type": "llama3",
+            "factor": config.rope_scaling_factor,
+            "low_freq_factor": config.rope_scaling_low_freq_factor,
+            "high_freq_factor": config.rope_scaling_high_freq_factor,
+            "original_max_position_embeddings":
+                config.rope_scaling_original_max_position,
+        }
+    if config.model_type == "gemma2":
+        d.update(
+            final_logit_softcapping=config.final_logit_softcapping,
+            attn_logit_softcapping=config.attn_logit_softcapping,
+            sliding_window=config.sliding_window,
+            query_pre_attn_scalar=config.query_pre_attn_scalar,
+            hidden_activation=config.hidden_act,
+        )
+    return d
+
+
+def hf_tensor_shapes(config: ModelConfig) -> dict[str, tuple[int, ...]]:
+    """HF tensor name → shape AS STORED (Linear weights ``[out, in]``),
+    for every tensor the loader will ask for."""
+    layer_map, top_map = _key_maps(config)
+    shapes = param_shapes(config)
+
+    def stored(shape: tuple[int, ...], transpose: bool) -> tuple[int, ...]:
+        return shape[::-1] if transpose else shape
+
+    out: dict[str, tuple[int, ...]] = {}
+    for hf_key, (name, transpose) in top_map.items():
+        if name in shapes and not isinstance(shapes[name], dict):
+            out[hf_key] = stored(shapes[name], transpose)
+    for suffix, (name, transpose) in layer_map.items():
+        if name in shapes["layers"]:
+            per_layer = shapes["layers"][name][1:]
+            for i in range(config.num_hidden_layers):
+                out[f"model.layers.{i}.{suffix}"] = stored(per_layer, transpose)
+    return out
+
+
+def hf_state_dict(params: Mapping[str, Any],
+                  config: ModelConfig) -> dict[str, np.ndarray]:
+    """A stacked host param pytree → HF-named tensors as stored
+    (Linear weights back to ``[out, in]``): the loader's inverse."""
+    layer_map, top_map = _key_maps(config)
+    out: dict[str, np.ndarray] = {}
+    for hf_key, (name, transpose) in top_map.items():
+        if name in params:
+            t = params[name]
+            out[hf_key] = np.ascontiguousarray(t.T if transpose else t)
+    for suffix, (name, transpose) in layer_map.items():
+        if name in params["layers"]:
+            for i, t in enumerate(params["layers"][name]):
+                out[f"model.layers.{i}.{suffix}"] = np.ascontiguousarray(
+                    t.T if transpose else t)
+    return out
+
+
+def _fill_chunk(flat: np.ndarray, start: int, seed: int, index: int) -> None:
+    """N(0, 0.02²) into ``flat[start:start + _CHUNK]`` from a generator
+    keyed on (seed, tensor index, chunk start) — the values do not
+    depend on which worker ran the chunk."""
+    part = np.empty(min(_CHUNK, flat.size - start), np.float32)
+    np.random.default_rng([seed, index, start]).standard_normal(
+        out=part, dtype=np.float32)
+    part *= 0.02
+    if flat.dtype == ml_dtypes.bfloat16:
+        # round-to-nearest-even on the bit pattern: ml_dtypes' own cast
+        # holds the GIL, which serializes the workers on a 1.5B-element
+        # checkpoint (measured: 43 s → the RNG's own cost)
+        bits = part.view(np.uint32)
+        bits += 0x7FFF + ((bits >> 16) & 1)
+        flat.view(np.uint16)[start:start + part.size] = bits >> 16
+    else:
+        flat[start:start + part.size] = part
+
+
+def write_hf_checkpoint(
+    out_dir: str | Path, config: ModelConfig,
+    tensors: Mapping[str, np.ndarray], *, shards: int = 2,
+    extra_config: Mapping[str, Any] | None = None,
+) -> None:
+    """``config.json`` + ``tensors`` split over ``shards`` safetensors
+    files with an index (``shards=0``: config only)."""
+    out_dir = Path(out_dir)
+    keys = sorted(tensors)
+    if shards > 0:
+        per = -(-len(keys) // shards)
+        weight_map = {}
+        for si in range(shards):
+            chunk = keys[si * per:(si + 1) * per]
+            if not chunk:
+                continue
+            fn = f"model-{si:05d}-of-{shards:05d}.safetensors"
+            save_file({k: tensors[k] for k in chunk}, str(out_dir / fn))
+            weight_map.update({k: fn for k in chunk})
+        _write_json(out_dir / "model.safetensors.index.json",
+                    {"weight_map": weight_map})
+    _write_json(out_dir / "config.json",
+                {**hf_config_dict(config), **(extra_config or {})})
+
+
+def write_random_checkpoint(
+    out_dir: str | Path, config: ModelConfig, *, seed: int = 0,
+    dtype: Any = ml_dtypes.bfloat16, layers_per_shard: int = 4,
+    workers: int = 8,
+) -> int:
+    """A full random checkpoint for ``config``, streamed shard by shard
+    (peak host memory: one shard).  Returns the bytes written."""
+    out_dir = Path(out_dir)
+    out_dir.mkdir(parents=True, exist_ok=True)
+    np_dtype = np.dtype(dtype)
+    shapes = hf_tensor_shapes(config)
+    index = {name: i for i, name in enumerate(sorted(shapes))}
+
+    def shard_of(name: str) -> int:
+        if not name.startswith("model.layers."):
+            return 0
+        return 1 + int(name.split(".")[2]) // layers_per_shard
+
+    groups: dict[int, list[str]] = {}
+    for name in shapes:
+        groups.setdefault(shard_of(name), []).append(name)
+    weight_map: dict[str, str] = {}
+    total = 0
+    gamma = 0.0 if config.rms_norm_unit_offset else 1.0
+    with ThreadPoolExecutor(workers) as pool:
+        for si, names in sorted(groups.items()):
+            # same distribution as models.transformer.init_params: norm
+            # gammas at identity, everything else N(0, 0.02²)
+            arrays = {
+                n: (np.full(shapes[n], gamma, np_dtype)
+                    if n.endswith("norm.weight")
+                    else np.empty(shapes[n], np_dtype))
+                for n in names
+            }
+            chunks = [
+                (a.reshape(-1), start, seed, index[n])
+                for n, a in arrays.items() if not n.endswith("norm.weight")
+                for start in range(0, a.size, _CHUNK)
+            ]
+            list(pool.map(lambda c: _fill_chunk(*c), chunks))
+            fn = f"model-{si:05d}-of-{len(groups):05d}.safetensors"
+            save_file(arrays, str(out_dir / fn))
+            weight_map.update({n: fn for n in names})
+            total += sum(a.nbytes for a in arrays.values())
+    _write_json(out_dir / "model.safetensors.index.json",
+                {"metadata": {"total_size": total}, "weight_map": weight_map})
+    _write_json(out_dir / "config.json", hf_config_dict(config))
+    return total
+
+
+def write_id_tokenizer(out_dir: str | Path, vocab_size: int) -> None:
+    """A tokenizer ``AutoTokenizer.from_pretrained`` loads offline: one
+    word ``t<i>`` per token id, whitespace-split.  Decoding any id the
+    model can emit yields text, which is all the serve path asks of it
+    when prompts arrive as token-id lists."""
+    from tokenizers import Tokenizer, models, pre_tokenizers
+
+    out_dir = Path(out_dir)
+    out_dir.mkdir(parents=True, exist_ok=True)
+    vocab = {f"t{i}": i for i in range(vocab_size)}
+    tok = Tokenizer(models.WordLevel(vocab, unk_token="t0"))
+    tok.pre_tokenizer = pre_tokenizers.WhitespaceSplit()
+    tok.save(str(out_dir / "tokenizer.json"))
+    _write_json(out_dir / "tokenizer_config.json",
+                {"tokenizer_class": "PreTrainedTokenizerFast",
+                 "unk_token": "t0", "clean_up_tokenization_spaces": False})
+

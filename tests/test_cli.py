@@ -26,7 +26,7 @@ def fake_load(monkeypatch):
     cfg = tiny_config("llama")
     params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
 
-    def _load(args):
+    def _load(args, on_host=False):
         return FakeTokenizer(), params, cfg
 
     monkeypatch.setattr(cli, "_load", _load)
@@ -593,6 +593,20 @@ def test_cli_serve_bench_rejects_paged_when_probe_fails(fake_load, monkeypatch):
                 "--max-tokens=2", "--slots=2", "--block-size=8",
                 "--attn-impl=paged",
             ])
+        # the other explicit kernel flags name a kernel too: no silent
+        # downgrade to XLA behind a log line
+        with pytest.raises(SystemExit, match="--decode-attn pallas"):
+            cli.run([
+                "serve-bench", "--requests=2", "--rate=50", "--prompt-len=8",
+                "--max-tokens=2", "--slots=2", "--block-size=8",
+                "--decode-attn=pallas",
+            ])
+        with pytest.raises(SystemExit, match="--decode-attn pallas"):
+            cli.run(["--backend=tpu", "--max-tokens=2", "--dtype=f32",
+                     "--no-stream", "--decode-attn=pallas"])
+        with pytest.raises(SystemExit, match="--flash-prefill"):
+            cli.run(["--backend=tpu", "--max-tokens=2", "--dtype=f32",
+                     "--no-stream", "--flash-prefill"])
         out = cli.run([
             "serve-bench", "--requests=2", "--rate=50", "--prompt-len=8",
             "--max-tokens=2", "--slots=2", "--block-size=8",

@@ -11,91 +11,18 @@ from safetensors.numpy import save_file
 
 from llm_np_cp_tpu.config import tiny_config
 from llm_np_cp_tpu.models.transformer import forward, init_params
+from llm_np_cp_tpu.utils import synthetic
 from llm_np_cp_tpu.utils.loading import load_params, shard_files
 
 
 def hf_tensors(params_np, model_type):
     """Convert a stacked param pytree into HF-named [out,in] tensors."""
-    out = {
-        "model.embed_tokens.weight": params_np["embed_tokens"],
-        "model.norm.weight": params_np["final_norm"],
-    }
-    lnames = {
-        "ln_attn_in": "input_layernorm.weight",
-        "q_proj": "self_attn.q_proj.weight",
-        "k_proj": "self_attn.k_proj.weight",
-        "v_proj": "self_attn.v_proj.weight",
-        "o_proj": "self_attn.o_proj.weight",
-        "gate_proj": "mlp.gate_proj.weight",
-        "up_proj": "mlp.up_proj.weight",
-        "down_proj": "mlp.down_proj.weight",
-    }
-    if model_type == "gemma2":
-        lnames.update(
-            ln_attn_out="post_attention_layernorm.weight",
-            ln_mlp_in="pre_feedforward_layernorm.weight",
-            ln_mlp_out="post_feedforward_layernorm.weight",
-        )
-    else:
-        lnames["ln_mlp_in"] = "post_attention_layernorm.weight"
-    for bname in (
-        "q_bias", "k_bias", "v_bias", "o_bias",
-        "gate_bias", "up_bias", "down_bias",
-    ):
-        if bname in params_np["layers"]:
-            mod = "self_attn" if bname[0] in "qkvo" else "mlp"
-            lnames[bname] = f"{mod}.{bname.replace('_bias', '_proj')}.bias"
-    n_layers = params_np["layers"]["q_proj"].shape[0]
-    for name, hf_suffix in lnames.items():
-        stacked = params_np["layers"][name]
-        for i in range(n_layers):
-            t = stacked[i]
-            if t.ndim == 2:  # projections stored (in, out) → HF stores (out, in)
-                t = t.T
-            out[f"model.layers.{i}.{hf_suffix}"] = np.ascontiguousarray(t)
-    return out
+    return synthetic.hf_state_dict(params_np, tiny_config(model_type))
 
 
 def write_checkpoint(tmp_path, cfg, tensors, shards=2, extra_cfg=None):
-    keys = sorted(tensors)
-    if shards > 0:
-        per = (len(keys) + shards - 1) // shards
-        weight_map = {}
-        for si in range(shards):
-            chunk = keys[si * per : (si + 1) * per]
-            if not chunk:
-                continue
-            fn = f"model-{si:05d}-of-{shards:05d}.safetensors"
-            save_file({k: tensors[k] for k in chunk}, str(tmp_path / fn))
-            weight_map.update({k: fn for k in chunk})
-        with open(tmp_path / "model.safetensors.index.json", "w") as f:
-            json.dump({"weight_map": weight_map}, f)
-    hf_cfg = {
-        "model_type": cfg.model_type,
-        "vocab_size": cfg.vocab_size,
-        "hidden_size": cfg.hidden_size,
-        "intermediate_size": cfg.intermediate_size,
-        "num_hidden_layers": cfg.num_hidden_layers,
-        "num_attention_heads": cfg.num_attention_heads,
-        "num_key_value_heads": cfg.num_key_value_heads,
-        "head_dim": cfg.head_dim,
-        "max_position_embeddings": cfg.max_position_embeddings,
-        "rope_theta": cfg.rope_theta,
-        "rms_norm_eps": cfg.rms_norm_eps,
-        "hidden_act": cfg.hidden_act,
-        "tie_word_embeddings": cfg.tie_word_embeddings,
-    }
-    if cfg.model_type == "gemma2":
-        hf_cfg.update(
-            final_logit_softcapping=cfg.final_logit_softcapping,
-            attn_logit_softcapping=cfg.attn_logit_softcapping,
-            sliding_window=cfg.sliding_window,
-            query_pre_attn_scalar=cfg.query_pre_attn_scalar,
-            hidden_activation=cfg.hidden_act,
-        )
-    hf_cfg.update(extra_cfg or {})
-    with open(tmp_path / "config.json", "w") as f:
-        json.dump(hf_cfg, f)
+    synthetic.write_hf_checkpoint(tmp_path, cfg, tensors, shards=shards,
+                                  extra_config=extra_cfg)
 
 
 @pytest.mark.parametrize("model_type", ["llama", "gemma2"])
@@ -240,3 +167,30 @@ def test_persistent_shard_read_error_fails_actionably(tmp_path, monkeypatch):
     finally:
         faults.install(None)
     assert ".safetensors" in str(ei.value)
+
+
+def test_hf_config_dict_roundtrips_every_preset():
+    """synthetic.hf_config_dict is what chip_smoke.py writes as
+    config.json: the loader must read back an EQUAL config, or the
+    smoke would quietly serve a different model than the one it names."""
+    from llm_np_cp_tpu.config import PRESETS, ModelConfig
+
+    for cfg in [*PRESETS.values(), tiny_config("qwen2"),
+                tiny_config("gemma2"), tiny_config("llama")]:
+        assert ModelConfig.from_hf_dict(synthetic.hf_config_dict(cfg)) == cfg
+
+
+def test_random_checkpoint_is_seeded_and_loadable(tmp_path):
+    cfg = tiny_config("qwen2")
+    for d, seed in (("a", 0), ("b", 0), ("c", 1)):
+        synthetic.write_random_checkpoint(tmp_path / d, cfg, seed=seed,
+                                          dtype=np.float32, workers=3)
+    a, cfg_a = load_params(tmp_path / "a", dtype=jnp.float32, on_host=True)
+    b, _ = load_params(tmp_path / "b", dtype=jnp.float32, on_host=True)
+    c, _ = load_params(tmp_path / "c", dtype=jnp.float32, on_host=True)
+    assert cfg_a == cfg
+    assert isinstance(a["embed_tokens"], np.ndarray)  # on_host: no device
+    jax.tree.map(np.testing.assert_array_equal, a, b)
+    assert not np.array_equal(a["embed_tokens"], c["embed_tokens"])
+    assert np.all(a["final_norm"] == 1.0)
+    assert 0.015 < float(a["embed_tokens"].std()) < 0.025

@@ -115,6 +115,33 @@ def test_block_pool_int8_pages_carry_scales():
     assert pool.pages.v_scale.shape == pool.pages.v.shape[:-1]
 
 
+def test_block_pool_slabs_are_born_on_their_sharding():
+    """N replicas each pin a pool to THEIR device: the slabs must be
+    born there.  zeros-then-device_put — and ``jnp.zeros(device=...)``,
+    which broadcasts one shard on the default device and copies it out —
+    stage every replica's pool through device 0 (measured on the
+    four-chip host: +304 MiB peak on chip 0).  The device-to-device
+    transfer guard turns any such staging into an error."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from llm_np_cp_tpu.parallel.sharding import (
+        MeshPlan, make_mesh, paged_kv_specs, to_shardings,
+    )
+
+    cfg = tiny_config("llama")
+    far = jax.devices()[5]
+    plan = MeshPlan()
+    mesh = make_mesh(plan, [far])
+    shardings = to_shardings(mesh, paged_kv_specs(cfg, plan, quantized=True))
+    with jax.transfer_guard_device_to_device("disallow"):
+        pool = BlockPool(cfg, num_blocks=4, block_size=8, dtype=jnp.int8,
+                         shardings=shardings)
+    for slab in pool.pages:
+        assert slab.devices() == {far}
+        assert slab.sharding == NamedSharding(mesh, P())
+
+
 def test_block_pool_rejects_bad_geometry():
     cfg = tiny_config("llama")
     with pytest.raises(ValueError):

@@ -14,6 +14,7 @@ the parity traffic doubles as the jit-stability evidence.
 import sys
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -209,9 +210,9 @@ def _iter_eqns(jaxpr):
 
 
 def _iter_param_eqns(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jax.extend.core.ClosedJaxpr):
         yield from _iter_eqns(v.jaxpr)
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jax.extend.core.Jaxpr):
         yield from _iter_eqns(v)
     elif isinstance(v, (tuple, list)):
         for x in v:

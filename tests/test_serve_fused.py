@@ -25,6 +25,7 @@ recorded live-TPU debt).
 import sys
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -475,9 +476,9 @@ def _iter_eqns(jaxpr, *, skip_pallas):
 
 
 def _iter_param_eqns(v, *, skip_pallas):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jax.extend.core.ClosedJaxpr):
         yield from _iter_eqns(v.jaxpr, skip_pallas=skip_pallas)
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jax.extend.core.Jaxpr):
         yield from _iter_eqns(v, skip_pallas=skip_pallas)
     elif isinstance(v, (tuple, list)):
         for x in v:

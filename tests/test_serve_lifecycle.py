@@ -674,7 +674,12 @@ def test_auto_action_host_sync_shed_and_revert(tiny):
     trace instants."""
     cfg, params = tiny
     tracer = TraceRecorder()
-    injector = FaultInjector("host_sync@8:14=0.02")
+    # the injected regression must stand out from THIS host's tick: the
+    # sentinel flags mean + 3 * max(dev, 10% of mean), and a CPU running
+    # the Pallas interpreter spends tens of ms in host_sync per tick — a
+    # 20 ms sleep (the value this test shipped with) is inside that band
+    # on a slow box and the action never engages
+    injector = FaultInjector("host_sync@8:14=0.2")
     engine = _engine(
         cfg, params, fault_injector=injector, tracer=tracer,
         sentinel=TickSentinel(threshold=3.0, warmup_ticks=4),

@@ -10,8 +10,7 @@ placement) and the slabs actually partitioned (pinned by inspecting
 the committed shardings, not trusted from the spec).
 
 Unlike tests/test_sharding.py these tests do NOT need ``jax.set_mesh``
-— the serve path commits every operand explicitly, which is what keeps
-it runnable on older jax.
+— the serve path commits every operand explicitly.
 """
 
 import sys

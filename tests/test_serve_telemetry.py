@@ -348,7 +348,11 @@ def test_tick_args_and_summarize_roofline_fixture(tiny, tmp_path):
     assert ticks, "no tick carried roofline args"
     for ev in ticks:
         a = ev["args"]
-        assert a["roofline_gbps"] > 0 and a["roofline_util"] > 0
+        # >= 0, not > 0: the args are rounded (6 places for util), and
+        # a toy model's bytes over a tick that paid a CPU compile
+        # (seconds) round to exactly 0.0 — the aggregate below is where
+        # "the bill is positive" is pinned
+        assert a["roofline_gbps"] >= 0 and a["roofline_util"] >= 0
         assert a["kv_read_bytes"] >= 0 and a["weight_bytes"] > 0
         assert a["device_time_s"] > 0
 

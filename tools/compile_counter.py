@@ -31,8 +31,9 @@ from __future__ import annotations
 import contextlib
 from typing import Iterator
 
-# Event keys that indicate an XLA computation was compiled.  jax renamed
-# these across versions; match loosely on purpose.
+# Event keys that indicate an XLA computation was compiled: every
+# compile request records ``/jax/compilation_cache/compile_requests_use_cache``
+# (persistent-cache hit or not); match loosely on purpose.
 _COMPILE_MARKERS = ("compile", "lowering")
 
 
@@ -52,20 +53,13 @@ class CompileCounter:
 
     @contextlib.contextmanager
     def watch(self) -> Iterator["CompileCounter"]:
-        from jax._src import monitoring
+        from jax import monitoring
 
         monitoring.register_event_listener(self._listener)
         try:
             yield self
         finally:
-            # jax's monitoring registry has no public remove in older
-            # versions; fall back to leaving a dead listener if needed
-            try:
-                monitoring._unregister_event_listener_by_callback(  # type: ignore[attr-defined]
-                    self._listener
-                )
-            except Exception:
-                pass
+            monitoring.unregister_event_listener(self._listener)
 
 
 def assert_serve_compiles_bounded(
@@ -205,22 +199,14 @@ def _self_check() -> None:
     import sys
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    # the mesh section below needs virtual devices; the flag must land
-    # before the CPU backend initializes (conftest discipline — jax may
-    # already be imported, but no computation has run yet)
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    ).strip()
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    # a lint self-check must never take the chip; the mesh section
+    # below needs virtual devices (set before the backend initializes)
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass  # older jax reads the XLA_FLAGS knob set above
+    jax.config.update("jax_num_cpu_devices", 8)
     from llm_np_cp_tpu.config import tiny_config
     from llm_np_cp_tpu.models.transformer import init_params
     from llm_np_cp_tpu.ops.sampling import Sampler

@@ -8,8 +8,8 @@ ask ``ops/pallas/support.py`` first (``gate_attn_impl`` /
 back to.  This rule checks, statically, that serve code cannot reach a
 kernel any other way:
 
-1. The GATED KERNEL SET is parsed out of ``support.py``'s ``_probe``
-   dispatch — the lint can never drift from what the probes cover.
+1. The GATED KERNEL SET is parsed out of ``support.py``'s ``KERNELS``
+   tuple — the lint can never drift from what the probes cover.
 2. A gate-taint analysis over each serve module marks every name/
    attribute derived from a gate-function result (``decode_attn_impl =
    gate_attn_impl(...)``, ``self.mixed`` assigned under ``if
@@ -49,31 +49,22 @@ _FALLBACK_MARK = "_xla"
 
 @functools.lru_cache(maxsize=1)
 def gated_kernels() -> frozenset[str]:
-    """Kernel callables gated by support.py probes, derived from the
-    ``_probe`` dispatch so rule and probes cannot drift."""
+    """Kernel callables gated by support.py probes, derived from its
+    ``KERNELS`` tuple — the one list the probes, the bench ``kernels``
+    child and the on-chip matrix iterate — so rule and probes cannot
+    drift."""
     tree = ast.parse((REPO_ROOT / SUPPORT_PATH).read_text())
-    probe = next(
-        (n for n in ast.walk(tree)
-         if isinstance(n, ast.FunctionDef) and n.name == "_probe"),
-        None,
-    )
     names: set[str] = set()
-    if probe is not None:
-        for node in ast.walk(probe):
-            if not isinstance(node, ast.Compare):
-                continue
-            if not (isinstance(node.left, ast.Name)
-                    and node.left.id == "kernel"):
-                continue
-            for comp in node.comparators:
-                consts = (
-                    comp.elts if isinstance(comp, (ast.Tuple, ast.List))
-                    else [comp]
-                )
-                for c in consts:
-                    if isinstance(c, ast.Constant) \
-                            and isinstance(c.value, str):
-                        names.add(c.value)
+    for node in tree.body:
+        if not (isinstance(node, ast.Assign)
+                and any(isinstance(t, ast.Name) and t.id == "KERNELS"
+                        for t in node.targets)
+                and isinstance(node.value, (ast.Tuple, ast.List))):
+            continue
+        names.update(
+            c.value for c in node.value.elts
+            if isinstance(c, ast.Constant) and isinstance(c.value, str)
+        )
     # int8 probe variants share one callable with the base kernel
     return frozenset(
         n[: -len("_int8")] if n.endswith("_int8") else n for n in names
