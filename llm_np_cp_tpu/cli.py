@@ -639,13 +639,15 @@ def _build_serve_engine(args, params, config, *, prog: str,
                         fault_injector=None, mesh_plan=None,
                         mesh_devices=None, shared_tracer=None,
                         journal=None, shared_request_log=None,
-                        shared_host_tier=None, quiet=False):
+                        shared_host_tier=None, quiet=False,
+                        early_spans=None):
     """The shared engine build for both serve subcommands: validate the
     pool flags, resolve --attn-impl against the Mosaic probe (an EXPLICIT
     paged request must fail with an actionable message when the kernel
     does not compile — not a Pallas traceback at first dispatch, and not
     a silent downgrade, which is what auto is for), size the pool, build.
     """
+    import jax
     import jax.numpy as jnp
 
     from llm_np_cp_tpu.ops.sampling import Sampler
@@ -653,6 +655,10 @@ def _build_serve_engine(args, params, config, *, prog: str,
     from llm_np_cp_tpu.serve.engine import pool_geometry
 
     _validate_pool_flags(args)  # re-checked for non-CLI callers
+    # set-up phases that run before the recorder exists: (name, start,
+    # end, args) on time.perf_counter, the recorder's clock — appended
+    # as cat "setup" spans once it does (TraceRecorder.us_at)
+    early_spans = list(early_spans or ())
     cache_dtype = {
         "bf16": jnp.bfloat16, "f32": jnp.float32, "int8": jnp.int8,
     }[args.cache_dtype]
@@ -667,7 +673,10 @@ def _build_serve_engine(args, params, config, *, prog: str,
         )
 
         paged_kernel = paged_kernel_name(args.cache_dtype == "int8")
+        t_probe = time.perf_counter()
         err = kernel_error(paged_kernel)
+        early_spans.append(("probe.paged_attn", t_probe, time.perf_counter(),
+                            {"ok": err is None}))
         if err is None:
             decode_attn_impl = "paged"
         elif args.attn_impl == "auto":
@@ -704,6 +713,14 @@ def _build_serve_engine(args, params, config, *, prog: str,
             # timestamps / span feed — keep its memory bounded
             ring = 100_000
         tracer = TraceRecorder(ring=ring)
+        # every backend compile from here on is a cat "compile" span: a
+        # recompile inside a measured window shows in the trace itself
+        tracer.watch_compiles()
+        # ...and is keyed in the persistent cache WITH its metadata, so
+        # the text of a warm step names this source's scopes and not
+        # those of whichever build filled the cache (device_op_map)
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True)
         implied = (jax_profile or sentinel_on or otlp_endpoint) \
             and not (args.trace_out or args.trace_ring)
         print(f"[{prog}] tracing ACTIVE (ring={ring or 'unbounded'}"
@@ -711,6 +728,10 @@ def _build_serve_engine(args, params, config, *, prog: str,
               + (", implied by --jax-profile/--tick-sentinel/"
                  "--otlp-endpoint" if implied else "")
               + ")")
+    if tracer is not None:
+        for name, t_a, t_b, span_args in early_spans:
+            tracer.complete(name, tracer.us_at(t_a), tracer.us_at(t_b),
+                            cat="setup", args=span_args)
     if otlp_endpoint and tracer is not None and tracer.otel is None:
         # one exporter per PROCESS, shared by every replica through the
         # shared recorder (replica engines arrive with shared_tracer
@@ -962,10 +983,13 @@ def _run_serve_bench(argv: list[str], default_model: str) -> str:
         )
     plan, dev_slices = _resolve_serve_mesh(args, "serve-bench")
     injector = _chaos_injector(args)
+    t_load = time.perf_counter()
     _tok, params, config = _load(args, on_host=plan is not None)
     engine, num_blocks = _build_serve_engine(
         args, params, config, prog="serve-bench", fault_injector=injector,
         mesh_plan=plan, mesh_devices=dev_slices[0],
+        early_spans=[("load_place", t_load, time.perf_counter(),
+                      {"on_host": plan is not None})],
     )
     replica_set = None
     if args.replicas > 1:
@@ -1111,11 +1135,14 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
         print(f"[serve] journal ACTIVE: {args.journal} "
               f"(epoch {journals[0].epoch}, sync={args.journal_sync}, "
               f"{sum(replays)} unterminated to replay)")
+    t_load = time.perf_counter()
     tok, params, config = _load(args, on_host=plan is not None)
     engine, num_blocks = _build_serve_engine(
         args, params, config, prog="serve", tokenizer=tok,
         max_queue=args.max_queue or None, fault_injector=injector,
         mesh_plan=plan, mesh_devices=dev_slices[0], journal=journals[0],
+        early_spans=[("load_place", t_load, time.perf_counter(),
+                      {"on_host": plan is not None})],
     )
     engines = [engine] + [
         _build_serve_engine(
@@ -1172,6 +1199,9 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
     print(banner)
 
     def on_started(server) -> None:
+        if tracer is not None:
+            tracer.complete("listen", tracer.us_at(t_listen), cat="setup",
+                            args={"port": server.port})
         print(f"[serve] listening on http://{server.host}:{server.port} "
               f"(POST /v1/completions, GET /healthz, GET /metrics)")
 
@@ -1194,6 +1224,7 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
             )
         return new_params
 
+    t_listen = time.perf_counter()
     with _jax_profile_ctx(args):
         serve_forever(
             engine,

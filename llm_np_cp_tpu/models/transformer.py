@@ -49,6 +49,22 @@ from llm_np_cp_tpu.quant import quant_einsum
 
 Params = dict[str, Any]
 
+# The parts of a step, as ``jax.named_scope`` names.  A scope is metadata
+# on the operations traced under it (the compiled program is the same
+# with or without it); serve/opmap.py reads the names back out of the
+# compiled module so a device profile can be cut by them.  The layer's
+# five are entered in ``run_decoder_layer`` (every caller shares them);
+# ``embed`` and ``tail`` belong to the step around the layer loop.
+SCOPE_EMBED = "embed"
+SCOPE_QKV = "qkv"            # input norm, q/k/v projections, RoPE
+SCOPE_KV_WRITE = "kv_write"  # the cache / pool write
+SCOPE_ATTN = "attn"
+SCOPE_O_PROJ = "o_proj"      # output projection, post-norm, residual
+SCOPE_MLP = "mlp"            # input norm, MLP, post-norm, residual
+SCOPE_TAIL = "tail"          # final norm, head, sampling
+STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
+               SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL)
+
 
 # ----------------------------------------------------------------------
 # Parameter pytree
@@ -351,114 +367,121 @@ def run_decoder_layer(
             else mask_global
         )
     b, s = x.shape[:2]
-    h = rms_norm(
-        x, w["ln_attn_in"], eps=config.rms_norm_eps,
-        unit_offset=config.rms_norm_unit_offset,
-    )
+
     def _proj_b(x, wname):
         y = _project(x, w[wname])
         bias = w.get(wname.replace("_proj", "_bias"))
         return y + bias.astype(y.dtype) if bias is not None else y
 
-    q = _proj_b(h, "q_proj").reshape(b, s, config.num_attention_heads, config.head_dim)
-    k = _proj_b(h, "k_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
-    v = _proj_b(h, "v_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope(SCOPE_QKV):
+        h = rms_norm(
+            x, w["ln_attn_in"], eps=config.rms_norm_eps,
+            unit_offset=config.rms_norm_unit_offset,
+        )
+        q = _proj_b(h, "q_proj").reshape(b, s, config.num_attention_heads, config.head_dim)
+        k = _proj_b(h, "k_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
+        v = _proj_b(h, "v_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if kv_update is not None:
-        k_att, v_att = kv_update(k, v)
-    else:
-        k_att, v_att = k, v
+    with jax.named_scope(SCOPE_KV_WRITE):
+        if kv_update is not None:
+            k_att, v_att = kv_update(k, v)
+        else:
+            k_att, v_att = k, v
 
     attn_weights = None
-    if attn_fn is not None:
-        attn = attn_fn(q, k_att, v_att, sliding)
-    elif attn_impl in ("flash", "ring"):
-        if attn_impl == "flash":
-            from llm_np_cp_tpu.ops.pallas.flash_attention import flash_attention as _impl_fn
-        else:
-            from llm_np_cp_tpu.parallel.ring_attention import ring_attention_ctx as _impl_fn
+    with jax.named_scope(SCOPE_ATTN):
+        if attn_fn is not None:
+            attn = attn_fn(q, k_att, v_att, sliding)
+        elif attn_impl in ("flash", "ring"):
+            if attn_impl == "flash":
+                from llm_np_cp_tpu.ops.pallas.flash_attention import flash_attention as _impl_fn
+            else:
+                from llm_np_cp_tpu.parallel.ring_attention import ring_attention_ctx as _impl_fn
 
-        def _fresh_attn(window):
-            return _impl_fn(
-                q, k, v,  # current K/V: self-attention over 0..S-1
+            def _fresh_attn(window):
+                return _impl_fn(
+                    q, k, v,  # current K/V: self-attention over 0..S-1
+                    scale=config.attn_scale,
+                    logit_softcap=config.attn_logit_softcapping,
+                    window=window,
+                )
+
+            if config.sliding_window is not None:
+                attn = lax.cond(
+                    sliding,
+                    lambda: _fresh_attn(config.sliding_window),
+                    lambda: _fresh_attn(None),
+                )
+            else:
+                attn = _fresh_attn(None)
+        elif attn_impl == "flash_decode" and s == 1:
+            # Fused single-token attention over the cache slab; consumes the
+            # same mask as the XLA path (validity ∧ window ∧ ragged pads), so
+            # every decode feature works unchanged.  Prefill/chunked calls
+            # (s > 1) under this impl fall through to the XLA path below.
+            # An int8 cache arrives as (values, scales) tuples: the kernel
+            # streams 1-byte slabs and dequantizes in VMEM.
+            from llm_np_cp_tpu.ops.pallas.decode_attention import decode_attention
+
+            if isinstance(k_att, tuple):
+                (k_vals, k_sc), (v_vals, v_sc) = k_att, v_att
+            else:
+                k_vals, k_sc, v_vals, v_sc = k_att, None, v_att, None
+            attn = decode_attention(
+                q, k_vals, v_vals,
+                jnp.broadcast_to(mask, (b, 1, k_vals.shape[1]))[:, 0],
+                k_scale=k_sc, v_scale=v_sc,
                 scale=config.attn_scale,
                 logit_softcap=config.attn_logit_softcapping,
-                window=window,
-            )
-
-        if config.sliding_window is not None:
-            attn = lax.cond(
-                sliding,
-                lambda: _fresh_attn(config.sliding_window),
-                lambda: _fresh_attn(None),
             )
         else:
-            attn = _fresh_attn(None)
-    elif attn_impl == "flash_decode" and s == 1:
-        # Fused single-token attention over the cache slab; consumes the
-        # same mask as the XLA path (validity ∧ window ∧ ragged pads), so
-        # every decode feature works unchanged.  Prefill/chunked calls
-        # (s > 1) under this impl fall through to the XLA path below.
-        # An int8 cache arrives as (values, scales) tuples: the kernel
-        # streams 1-byte slabs and dequantizes in VMEM.
-        from llm_np_cp_tpu.ops.pallas.decode_attention import decode_attention
+            attn = gqa_attention(
+                q, k_att, v_att, mask,
+                scale=config.attn_scale,
+                logit_softcap=config.attn_logit_softcapping,
+                return_weights=output_attentions,
+            )
+            if output_attentions:
+                attn, attn_weights = attn
 
-        if isinstance(k_att, tuple):
-            (k_vals, k_sc), (v_vals, v_sc) = k_att, v_att
+    with jax.named_scope(SCOPE_O_PROJ):
+        attn = _project(attn.reshape(b, s, -1), w["o_proj"])
+        if "o_bias" in w:
+            attn = attn + w["o_bias"].astype(attn.dtype)
+        if config.sandwich_norms:
+            attn = rms_norm(
+                attn, w["ln_attn_out"], eps=config.rms_norm_eps,
+                unit_offset=config.rms_norm_unit_offset,
+            )
+        x = x + attn
+
+    with jax.named_scope(SCOPE_MLP):
+        h = rms_norm(
+            x, w["ln_mlp_in"], eps=config.rms_norm_eps,
+            unit_offset=config.rms_norm_unit_offset,
+        )
+        moe_aux = jnp.zeros((), jnp.float32)
+        if config.is_moe:
+            from llm_np_cp_tpu.ops.moe import moe_mlp
+
+            mlp, moe_aux = moe_mlp(
+                h, w["router"], w["gate_proj"], w["up_proj"], w["down_proj"],
+                act=act, top_k=config.num_experts_per_tok,
+                capacity_factor=config.moe_capacity_factor,
+                group_size=config.moe_group_size,
+            )
         else:
-            k_vals, k_sc, v_vals, v_sc = k_att, None, v_att, None
-        attn = decode_attention(
-            q, k_vals, v_vals,
-            jnp.broadcast_to(mask, (b, 1, k_vals.shape[1]))[:, 0],
-            k_scale=k_sc, v_scale=v_sc,
-            scale=config.attn_scale,
-            logit_softcap=config.attn_logit_softcapping,
-        )
-    else:
-        attn = gqa_attention(
-            q, k_att, v_att, mask,
-            scale=config.attn_scale,
-            logit_softcap=config.attn_logit_softcapping,
-            return_weights=output_attentions,
-        )
-        if output_attentions:
-            attn, attn_weights = attn
-    attn = _project(attn.reshape(b, s, -1), w["o_proj"])
-    if "o_bias" in w:
-        attn = attn + w["o_bias"].astype(attn.dtype)
-    if config.sandwich_norms:
-        attn = rms_norm(
-            attn, w["ln_attn_out"], eps=config.rms_norm_eps,
-            unit_offset=config.rms_norm_unit_offset,
-        )
-    x = x + attn
-
-    h = rms_norm(
-        x, w["ln_mlp_in"], eps=config.rms_norm_eps,
-        unit_offset=config.rms_norm_unit_offset,
-    )
-    moe_aux = jnp.zeros((), jnp.float32)
-    if config.is_moe:
-        from llm_np_cp_tpu.ops.moe import moe_mlp
-
-        mlp, moe_aux = moe_mlp(
-            h, w["router"], w["gate_proj"], w["up_proj"], w["down_proj"],
-            act=act, top_k=config.num_experts_per_tok,
-            capacity_factor=config.moe_capacity_factor,
-            group_size=config.moe_group_size,
-        )
-    else:
-        gate = act(_proj_b(h, "gate_proj"))
-        up = _proj_b(h, "up_proj")
-        mlp = _proj_b(gate * up, "down_proj")
-    if config.sandwich_norms:
-        mlp = rms_norm(
-            mlp, w["ln_mlp_out"], eps=config.rms_norm_eps,
-            unit_offset=config.rms_norm_unit_offset,
-        )
-    x = x + mlp
+            gate = act(_proj_b(h, "gate_proj"))
+            up = _proj_b(h, "up_proj")
+            mlp = _proj_b(gate * up, "down_proj")
+        if config.sandwich_norms:
+            mlp = rms_norm(
+                mlp, w["ln_mlp_out"], eps=config.rms_norm_eps,
+                unit_offset=config.rms_norm_unit_offset,
+            )
+        x = x + mlp
     return x, (k_att, v_att), attn_weights, moe_aux
 
 

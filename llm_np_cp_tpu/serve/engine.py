@@ -107,6 +107,8 @@ from llm_np_cp_tpu.cache import KVCache, quantize_kv
 from llm_np_cp_tpu.config import ModelConfig
 from llm_np_cp_tpu.generate import IncrementalDetok, make_ragged_prefill_step
 from llm_np_cp_tpu.models.transformer import (
+    SCOPE_EMBED,
+    SCOPE_TAIL,
     embed_inputs,
     final_logits,
     forward,
@@ -345,10 +347,18 @@ class ServeEngine:
             ragged_kernel_name,
         )
 
+        # set-up as spans (cat "setup"), with a tracer: the build whole,
+        # and as its children what can take time in it — kernel probes
+        # (a compile each, the first time a process asks), placing the
+        # weights on a mesh, allocating the pool
+        t_build = tracer.now_us() if tracer is not None else -1.0
         int8_cache = jnp.dtype(cache_dtype) == jnp.int8
         decode_attn_impl = gate_attn_impl(
             decode_attn_impl, int8_cache=int8_cache
         )
+        if tracer is not None:
+            tracer.complete("probe.decode_attn", t_build, cat="setup",
+                            args={"impl": decode_attn_impl})
         # -- mesh-sharded mode (ROADMAP item 1): params tensor-parallel
         # over "model" via param_specs, pool slabs kv-head-partitioned
         # via paged_kv_specs, block tables / per-tick operands committed
@@ -393,7 +403,10 @@ class ServeEngine:
                     )
             mesh_plan.validate(config)
             self.mesh = make_mesh(mesh_plan, mesh_devices)
+            t_place = tracer.now_us() if tracer is not None else -1.0
             params = shard_params(params, config, mesh_plan, self.mesh)
+            if tracer is not None:
+                tracer.complete("place_params", t_place, cat="setup")
             self._rep_sharding = NamedSharding(self.mesh, P())
             self._kv_sharded = kv_heads_shardable(config, mesh_plan)
             self._pool_shardings = to_shardings(
@@ -432,7 +445,11 @@ class ServeEngine:
         if mixed_step == "off":
             self.mixed = False
         else:
+            t_probe = tracer.now_us() if tracer is not None else -1.0
             err = kernel_error(ragged_kernel_name(int8_cache))
+            if tracer is not None:
+                tracer.complete("probe.ragged_attn", t_probe, cat="setup",
+                                args={"ok": err is None})
             if err is None:
                 self.mixed, self.ragged_attn_impl = True, "pallas"
             elif mixed_step == "on":
@@ -493,6 +510,9 @@ class ServeEngine:
         # hook is a single is-None check, same discipline as faults
         # (pinned by tools/compile_counter.assert_tracing_hooks_guarded)
         self.tracer = tracer
+        # the unified tick's open ``serve.<phase>`` profiler annotation
+        # (_phase_mark); only ever set while a tracer is attached
+        self._phase_ann: Any = None
         # durable request journal (serve/journal.py): admissions,
         # per-tick delivery watermarks, and terminals go to an fsync'd
         # file a restarted PROCESS replays through recover(); same
@@ -560,11 +580,18 @@ class ServeEngine:
             math.lcm(self.block_size, self.prefill_chunk) // self.block_size
         )
 
+        t_pool = tracer.now_us() if tracer is not None else -1.0
         self.pool = BlockPool(
             config, num_blocks, block_size, dtype=cache_dtype,
             enable_prefix_cache=enable_prefix_cache,
             shardings=self._pool_shardings,
         )
+        if tracer is not None:
+            jax.block_until_ready(self.pool.pages)
+            tracer.complete("pool_alloc", t_pool, cat="setup", args={
+                "blocks": num_blocks, "bytes": int(sum(
+                    a.nbytes for a in self.pool.pages if a is not None)),
+            })
         self.scheduler = Scheduler(
             self.pool,
             max_slots=max_slots,
@@ -658,9 +685,14 @@ class ServeEngine:
                            "full lm head; a TP-aware epilogue is open "
                            "work)")
             else:
+                t_probe = tracer.now_us() if tracer is not None else -1.0
                 epi_err = epilogue_gate_error(
                     params, config, self.sampler.kind
                 )
+                if tracer is not None:
+                    tracer.complete(
+                        "probe.sample_epilogue", t_probe, cat="setup",
+                        args={"ok": epi_err is None})
             if epi_err is None:
                 self.epilogue_impl = "fused"
             elif sample_epilogue == "on":
@@ -718,6 +750,11 @@ class ServeEngine:
         # tick loops bump it at their single packed host_sync transfer
         # and the tick trace args carry the per-tick count
         self.n_host_fetches = 0
+        if tracer is not None:
+            tracer.complete("engine_build", t_build, cat="setup", args={
+                "tick": "unified" if self.mixed else "split",
+                "buckets": len(self.mixed_buckets),
+            })
 
     def _make_buckets(self, budget: int, max_slots: int) -> tuple[int, ...]:
         """Packed-width buckets for the mixed step: a doubling ladder of
@@ -1669,10 +1706,11 @@ class ServeEngine:
             seeds: jnp.ndarray,       # [R] uint32
             verify_len: jnp.ndarray,  # [R] int32 live sample slots per row
         ):
-            x = embed_inputs(params, tokens[None, :], config)  # [1, T, H]
-            cos, sin = rope_cos_sin(
-                positions[None, :], config, dtype=jnp.float32
-            )
+            with jax.named_scope(SCOPE_EMBED):
+                x = embed_inputs(params, tokens[None, :], config)  # [1, T, H]
+                cos, sin = rope_cos_sin(
+                    positions[None, :], config, dtype=jnp.float32
+                )
             act = ACT2FN[config.hidden_act]
             is_sliding = jnp.array(
                 [config.layer_is_sliding(i) for i in range(num_layers)],
@@ -1755,50 +1793,51 @@ class ServeEngine:
             # Keys derive from (seed, content position) per slot, so a
             # verify sample at position p is BIT-IDENTICAL to the plain
             # decode draw at p — the accept walk's whole parity story.
-            xr = x[0][last_idx]  # [R, W, H]
-            r_rows, w_cols = xr.shape[0], xr.shape[1]
-            if use_epilogue:
-                # fused tail: norm → lm_head → greedy sample streamed
-                # over vocab tiles — the [R, W, V] logits never exist
-                # (pinned by a jaxpr-inspection test).  Greedy ignores
-                # the RNG keys, so the draw is bit-identical to the
-                # oracle branch below.
-                from llm_np_cp_tpu.models.transformer import (
-                    sample_epilogue_tail,
-                )
+            with jax.named_scope(SCOPE_TAIL):
+                xr = x[0][last_idx]  # [R, W, H]
+                r_rows, w_cols = xr.shape[0], xr.shape[1]
+                if use_epilogue:
+                    # fused tail: norm → lm_head → greedy sample streamed
+                    # over vocab tiles — the [R, W, V] logits never exist
+                    # (pinned by a jaxpr-inspection test).  Greedy ignores
+                    # the RNG keys, so the draw is bit-identical to the
+                    # oracle branch below.
+                    from llm_np_cp_tpu.models.transformer import (
+                        sample_epilogue_tail,
+                    )
 
-                nxt = sample_epilogue_tail(
-                    params, xr.reshape(r_rows * w_cols, -1), config
-                ).reshape(r_rows, w_cols)
-            else:
-                logits = final_logits(params, xr, config)  # [R, W, V]
-                keys = jax.vmap(
-                    lambda s, ps: jax.vmap(
-                        lambda t: jax.random.fold_in(
-                            jax.random.PRNGKey(s), t
-                        )
-                    )(ps)
-                )(seeds, sample_pos)
-                nxt = jax.vmap(
-                    jax.vmap(lambda k, lg: sampler(k, lg[None])[0])
-                )(keys, logits)
-            # in-graph accept walk + stop detection, so host_sync is ONE
-            # packed transfer: a verify slice's draft tokens ARE the
-            # packed input tokens at columns 1..k', so the longest
-            # matching prefix is computable without a host round-trip
-            drafts = tokens[last_idx[:, 1:]]  # [R, W-1]
-            jpos = jnp.arange(
-                max(w_cols - 1, 0), dtype=jnp.int32
-            )[None, :]
-            live = jpos < (verify_len[:, None] - 1)
-            lead = jnp.cumprod(
-                ((drafts == nxt[:, :-1]) & live).astype(jnp.int32),
-                axis=1,
-            )
-            accept = jnp.sum(lead, axis=1, dtype=jnp.int32)
-            packed = _pack_sync(
-                nxt, _stop_hits(nxt, stop_tokens), accept
-            )
+                    nxt = sample_epilogue_tail(
+                        params, xr.reshape(r_rows * w_cols, -1), config
+                    ).reshape(r_rows, w_cols)
+                else:
+                    logits = final_logits(params, xr, config)  # [R, W, V]
+                    keys = jax.vmap(
+                        lambda s, ps: jax.vmap(
+                            lambda t: jax.random.fold_in(
+                                jax.random.PRNGKey(s), t
+                            )
+                        )(ps)
+                    )(seeds, sample_pos)
+                    nxt = jax.vmap(
+                        jax.vmap(lambda k, lg: sampler(k, lg[None])[0])
+                    )(keys, logits)
+                # in-graph accept walk + stop detection, so host_sync is ONE
+                # packed transfer: a verify slice's draft tokens ARE the
+                # packed input tokens at columns 1..k', so the longest
+                # matching prefix is computable without a host round-trip
+                drafts = tokens[last_idx[:, 1:]]  # [R, W-1]
+                jpos = jnp.arange(
+                    max(w_cols - 1, 0), dtype=jnp.int32
+                )[None, :]
+                live = jpos < (verify_len[:, None] - 1)
+                lead = jnp.cumprod(
+                    ((drafts == nxt[:, :-1]) & live).astype(jnp.int32),
+                    axis=1,
+                )
+                accept = jnp.sum(lead, axis=1, dtype=jnp.int32)
+                packed = _pack_sync(
+                    nxt, _stop_hits(nxt, stop_tokens), accept
+                )
             return packed, new_pages
 
         return mixed_step
@@ -2291,6 +2330,24 @@ class ServeEngine:
             })
         return outliers
 
+    def _phase_mark(self, name: str | None) -> float:
+        """The boundary between two phases of the unified tick, with a
+        tracer attached (every caller holds the ``tracer is not None``
+        guard): close the tick thread's open ``serve.<phase>`` profiler
+        annotation, stamp the recorder's clock, open ``name`` (None:
+        nothing — the dispatch brings its own annotation, the tick's
+        end opens none).  A tick that died mid-phase leaves its
+        annotation to the next mark."""
+        ann = self._phase_ann
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            self._phase_ann = None
+        now = self.tracer.now_us() if self.tracer is not None else -1.0
+        if name is not None:
+            self._phase_ann = ann = jax.profiler.TraceAnnotation(name)
+            ann.__enter__()
+        return now
+
     def _tick_budget(self) -> int:
         """This tick's token budget: the configured budget, capped by
         the ActionPolicy's shed-prefill verdict (decode rows are never
@@ -2781,7 +2838,8 @@ class ServeEngine:
         decode_rows: list[Request],
         prefill_segs: list[tuple[Request, int]],
     ) -> tuple:
-        """Build the mixed step's packed operands from the planner's
+        """Build the mixed step's packed operands (host arrays; the
+        tick places them with ``_put``) from the planner's
         verdict.  Each row's token segment lands at consecutive,
         q-tile-aligned packed positions (dead alignment lanes point at
         the scratch block and are masked); the packed width is the
@@ -2864,11 +2922,11 @@ class ServeEngine:
                     last_idx[slot, j] = cur + first + j
                     sample_pos[slot, j] = start_slot + first + j - r.pad
             cur += n_tiles * qb
-        return tuple(self._put(a) for a in (
+        return (
             tokens, positions, tok_blk, tok_off, tok_row, tok_slot,
             tok_live, tile_row, tile_qpos0, tile_qlen, tables, pads,
             last_idx, sample_pos, seeds, verify_len,
-        ))
+        )
 
     def _finish_mixed_prefill(self, req: Request, tok: int) -> None:
         """A row's prefill reached its target this tick: register its
@@ -2961,16 +3019,22 @@ class ServeEngine:
         block growth, token-budget planning, then ONE mixed ragged
         dispatch covering every planned prefill chunk slice, plain
         decode row, and speculative verify slice.  Phase slices
-        (``admission`` / ``draft`` / ``grow`` / ``plan`` /
-        ``mixed_dispatch`` / ``host_sync`` / ``deliver``,
-        serve/tracing.MIXED_TICK_PHASES) keep the
-        consecutive-timestamps sum-to-tick invariant; the tick args
-        additionally carry the prefill/decode token split — and, on
-        spec-enabled engines, the draft/accept token split — so
-        tools/summarize_trace.py can report mixed-step utilization.
+        (``admission`` / ``draft`` / ``grow`` / ``plan`` / ``pack`` /
+        ``h2d`` / ``mixed_dispatch`` / ``host_sync`` / ``deliver`` /
+        ``account``, serve/tracing.MIXED_TICK_PHASES) keep the
+        consecutive-timestamps sum-to-tick invariant, and each runs
+        under a ``serve.<phase>`` profiler annotation (``_phase_mark``)
+        so a device profile holds the host's phases on its own clock;
+        the tick args additionally carry the prefill/decode token
+        split, the dispatch's live context and packed width, the
+        tick thread's own CPU time — and, on spec-enabled engines, the
+        draft/accept token split — so tools/summarize_trace.py and the
+        benchmark's readers can say where a tick went.
         ``self.tracer`` is re-read at every hook for the same
         zombie-mute reason as the split tick."""
-        t0 = self.tracer.now_us() if self.tracer is not None else -1.0
+        t0 = (self._phase_mark("serve.admission")
+              if self.tracer is not None else -1.0)
+        cpu0 = time.thread_time_ns() if self.tracer is not None else 0
         fetches0 = self.n_host_fetches
         if self.host_tier is not None:
             self._tier_spill_bytes = 0
@@ -2995,17 +3059,20 @@ class ServeEngine:
                         preemptions=req.n_preemptions,
                     ))
         self._apply_tier_restores(admitted)
-        t1 = self.tracer.now_us() if self.tracer is not None else -1.0
+        t1 = (self._phase_mark("serve.draft")
+              if self.tracer is not None else -1.0)
 
         self._draft_tick()
-        td = self.tracer.now_us() if self.tracer is not None else -1.0
+        td = (self._phase_mark("serve.grow")
+              if self.tracer is not None else -1.0)
 
         for req in self.scheduler.ensure_decode_blocks():
             if self.tracer is not None:
                 self.tracer.request_instant(req.req_id, "evicted-requeued")
                 self.tracer.request_phase(req.req_id, "queued")
             self._emit_event(req, "evicted-requeued")
-        t2 = self.tracer.now_us() if self.tracer is not None else -1.0
+        t2 = (self._phase_mark("serve.plan")
+              if self.tracer is not None else -1.0)
 
         decode_rows, prefill_segs = self.scheduler.plan_tick(
             self._tick_budget(), self.prefill_chunk,
@@ -3015,9 +3082,12 @@ class ServeEngine:
                 else None
             ),
         )
-        t3 = self.tracer.now_us() if self.tracer is not None else -1.0
+        t3 = (self._phase_mark("serve.pack")
+              if self.tracer is not None else -1.0)
 
-        t4 = t5 = t3
+        tp = th = t4 = t5 = t3
+        cpu4 = cpu5 = 0
+        ctx_tokens = packed_width = h2d_count = h2d_bytes = 0
         n_prefill_tok = sum(n for _, n in prefill_segs)
         n_decode_tok = len(decode_rows)
         # drafts actually packed (post-trim) / accepted by the verifier
@@ -3033,14 +3103,34 @@ class ServeEngine:
                 cost = self.telemetry.mixed_tick_cost(
                     self, decode_rows, prefill_segs
                 )
-            args = self._pack_mixed(decode_rows, prefill_segs)
+            host_args = self._pack_mixed(decode_rows, prefill_segs)
+            if self.tracer is not None:
+                # what this dispatch attends: every row's live content
+                # after its tokens land (left pad excluded), read HERE —
+                # the deliver walks below advance and release the rows
+                ctx_tokens = sum(
+                    r.cache_len - r.pad + r.draft_len for r in decode_rows
+                ) + sum(r.prefill_done + n for r, n in prefill_segs)
+                packed_width = int(host_args[0].size)
+                h2d_count = len(host_args)
+                h2d_bytes = sum(a.nbytes for a in host_args)
+            tp = (self._phase_mark("serve.h2d")
+                  if self.tracer is not None else -1.0)
+            args = tuple(self._put(a) for a in host_args)
+            # closes serve.h2d and opens nothing: the dispatch's own
+            # annotation below is the one the harness aligns clocks on,
+            # and it wraps the jitted call alone
+            th = (self._phase_mark(None)
+                  if self.tracer is not None else -1.0)
             td0 = self.clock()
             with (jax.profiler.TraceAnnotation("serve.mixed_dispatch")
                   if self.tracer is not None else _NULL_CTX):
                 out, self.pool.pages = self._dispatch_mixed(
                     args, bool(prefill_segs)
                 )
-            t4 = self.tracer.now_us() if self.tracer is not None else -1.0
+            t4 = (self._phase_mark("serve.host_sync")
+                  if self.tracer is not None else -1.0)
+            cpu4 = time.thread_time_ns() if self.tracer is not None else 0
             if self.faults is not None:
                 # injected host_sync regression (the split tick's twin
                 # site): a real stall in the host_sync phase window
@@ -3056,7 +3146,9 @@ class ServeEngine:
             self.n_host_fetches += 1
             nxt_host = out_host[:, : self._spec_w]
             accept_host = out_host[:, self._spec_w + 2]
-            t5 = self.tracer.now_us() if self.tracer is not None else -1.0
+            cpu5 = time.thread_time_ns() if self.tracer is not None else 0
+            t5 = (self._phase_mark("serve.deliver")
+                  if self.tracer is not None else -1.0)
             if cost is not None and self.telemetry is not None:
                 # attribution lands BEFORE the deliver walks so a
                 # finishing request's canonical log line carries its
@@ -3123,6 +3215,8 @@ class ServeEngine:
                 r.draft_len = 0
                 self._spec_feedback(r, drafted, acc)
 
+        t6 = (self._phase_mark("serve.account")
+              if self.tracer is not None else -1.0)
         if self.journal is not None:
             # same per-tick watermark batching as the split tick; a
             # verify round's rows carry every ACCEPTED token this tick
@@ -3151,19 +3245,28 @@ class ServeEngine:
         )
         outliers: list[dict] = []
         if self.tracer is not None and t0 >= 0.0:
-            t6 = self.tracer.now_us()
+            t7 = self._phase_mark(None)
             targs = {
                 "active_slots": active,
                 "queue_depth": self.scheduler.queue_depth,
                 "admitted": len(admitted),
                 "prefill_tokens": n_prefill_tok,
                 "decode_tokens": n_decode_tok,
+                # the dispatch as the device sees it: the live context
+                # its rows attend (summed over rows), the bucket
+                "context_tokens": ctx_tokens,
+                "packed_width": packed_width,
                 # the tick-tail observables: host_sync wall (µs) and the
                 # number of device→host transfers this tick — the
                 # one-fetch contract says the latter is exactly 1 on
                 # dispatching ticks (bench + tests pin it)
                 "host_sync_us": round(max(t5 - t4, 0.0), 1),
                 "host_fetches": self.n_host_fetches - fetches0,
+                # the tick thread's own CPU time outside host_sync:
+                # tick - host_sync - this = time it neither computed
+                # nor waited for the device (the GIL, a blocking put)
+                "thread_cpu_us": round(
+                    (time.thread_time_ns() - cpu0 - (cpu5 - cpu4)) / 1e3, 1),
             }
             if self.spec_k:
                 # the draft/verify split for summarize_trace and the
@@ -3182,8 +3285,11 @@ class ServeEngine:
             self.tracer.tick(t0, (
                 ("admission", t0, t1), ("draft", t1, td),
                 ("grow", td, t2), ("plan", t2, t3),
-                ("mixed_dispatch", t3, t4),
+                ("pack", t3, tp),
+                ("h2d", tp, th, {"count": h2d_count, "bytes": h2d_bytes}),
+                ("mixed_dispatch", th, t4),
                 ("host_sync", t4, t5), ("deliver", t5, t6),
+                ("account", t6, t7),
             ), args=targs)
             if self.sentinel is not None:
                 # same literal tuple as the tick() call above (R2's
@@ -3194,8 +3300,10 @@ class ServeEngine:
                 outliers = self._sentinel_observe((
                     ("admission", t0, t1), ("draft", t1, td),
                     ("grow", td, t2), ("plan", t2, t3),
-                    ("mixed_dispatch", t3, t4),
+                    ("pack", t3, tp), ("h2d", tp, th),
+                    ("mixed_dispatch", th, t4),
                     ("host_sync", t4, t5), ("deliver", t5, t6),
+                    ("account", t6, t7),
                 ) + (
                     (("roofline_deficit", 0.0, tel["deficit_us"]),)
                     if tel is not None else ()
@@ -3291,14 +3399,14 @@ class ServeEngine:
         return int(mixed_tick_kv_read(self, decode_rows, prefill_segs,
                                       per_request=False)[0])
 
-    def _warm_mixed_bucket(self, t_w: int) -> None:
-        """Compile one packed-width bucket with an all-dead batch: every
-        lane points at the scratch block and is fully masked, so the
-        only effect is the compile (and a garbage write to scratch)."""
+    def _dead_mixed_operands(self, t_w: int) -> tuple:
+        """The mixed step's operands for an all-dead batch of packed
+        width ``t_w`` (host arrays): every lane points at the scratch
+        block and is fully masked."""
         qb = self._q_tile
         b = self.scheduler.max_slots
         mb = self.max_blocks_per_seq
-        zeros = (
+        return (
             np.zeros(t_w, np.int32), np.zeros(t_w, np.int32),
             np.zeros(t_w, np.int32), np.zeros(t_w, np.int32),
             np.zeros(t_w, np.int32), np.zeros(t_w, np.int32),
@@ -3310,11 +3418,47 @@ class ServeEngine:
             np.zeros((b, self._spec_w), np.int32),
             np.zeros(b, np.uint32), np.zeros(b, np.int32),
         )
+
+    def _warm_mixed_bucket(self, t_w: int) -> None:
+        """Compile one packed-width bucket with an all-dead batch, so the
+        only effect is the compile (and a garbage write to scratch)."""
         out, self.pool.pages = self._mixed_step(
             self.params, self.pool.pages,
-            *(self._put(a) for a in zeros),
+            *(self._put(a) for a in self._dead_mixed_operands(t_w)),
         )
         np.asarray(out)  # block until the compile lands
+
+    def device_op_map(self) -> dict:
+        """What the operations of the unified step are, over its compiled
+        buckets: a profile's name for an operation (``%copy.89
+        bf16[28,1026,64,2,128]``) → [named scope, "pool" | "slab" | ""]
+        (serve/opmap.py), read from the compiled modules' text.  Set-up
+        work, done once after warm-up and only for a tracer: lowering a
+        signature the warm step has run hands back the executable the
+        tick runs, so nothing compiles (0.2 s for eight buckets on a v5e).
+
+        The scopes are metadata, and the persistent compile cache keys a
+        program WITHOUT its metadata unless told otherwise: a warm
+        executable's text may say what another build's source said.
+        Whoever attaches the recorder therefore sets
+        ``jax_compilation_cache_include_metadata_in_key`` before warm-up
+        (cli ``_build_serve_engine``); without a persistent cache the
+        text is this process's own compile either way."""
+        from llm_np_cp_tpu.models.transformer import STEP_SCOPES
+        from llm_np_cp_tpu.serve import opmap
+
+        pool = opmap.pool_shapes(
+            (a.dtype.name, a.sharding.shard_shape(a.shape))
+            for a in self.pool.pages if a is not None
+        )
+        return opmap.merge(
+            opmap.op_map_from_hlo(
+                self._mixed_step.lower(
+                    self.params, self.pool.pages,
+                    *(self._put(a) for a in self._dead_mixed_operands(t_w)),
+                ).compile().as_text(), STEP_SCOPES, pool)
+            for t_w in self.mixed_buckets
+        )
 
     def _dispatch_decode(self, *args: jnp.ndarray) -> tuple:
         """One decode dispatch with runtime kernel degradation: if the
@@ -3444,8 +3588,9 @@ class ServeEngine:
         # metrics reset — the fresh ServeMetrics gets it back
         slo_tracker = getattr(self.metrics, "slo", None)
         self.metrics.slo = None
+        t_warm = tracer.now_us() if tracer is not None else -1.0
         try:
-            self._warmup_body(prompt_lens, max_new_tokens)
+            self._warmup_body(prompt_lens, max_new_tokens, tracer)
         finally:
             self.faults = faults
             self.tracer = tracer
@@ -3455,14 +3600,27 @@ class ServeEngine:
             self.host_tier = host_tier
             self.tenants = tenants
             self.metrics.slo = slo_tracker
+        if tracer is not None:
+            # set-up as spans: the warm-up whole (its buckets are its
+            # children), then — outside it, the cost is tracing's own —
+            # the device-side op map, once per recorder
+            tracer.complete("warmup", t_warm, cat="setup")
+            if self.mixed and tracer.get_other("op_map") is None:
+                t_map = tracer.now_us()
+                tracer.set_other("op_map", self.device_op_map())
+                tracer.complete("op_map", t_map, cat="setup", args={
+                    "buckets": len(self.mixed_buckets)})
 
-    def _warmup_body(self, prompt_lens: list[int],
-                     max_new_tokens: int) -> None:
+    def _warmup_body(self, prompt_lens: list[int], max_new_tokens: int,
+                     tracer: TraceRecorder | None = None) -> None:
         # two decode tokens compile the decode/sample/column-scatter
         # programs; the workload's full budget only matters for b_max
+        t_req = tracer.now_us() if tracer is not None else -1.0
         self.submit(np.ones(min(prompt_lens), np.int32),
                     min(2, max_new_tokens))
         self.run_until_complete()
+        if tracer is not None:
+            tracer.complete("warmup.request", t_req, cat="setup")
         if self._restore_block is not None:
             # the host tier's one landing program: warm it against the
             # scratch block (garbage there is harmless by construction)
@@ -3485,7 +3643,17 @@ class ServeEngine:
             # rest directly so mid-traffic composition churn can never
             # trigger a compile stall
             for t_w in self.mixed_buckets:
+                t_b = tracer.now_us() if tracer is not None else -1.0
+                missed = tracer.compile_misses if tracer is not None else 0
                 self._warm_mixed_bucket(t_w)
+                if tracer is not None:
+                    # compiled: a backend compile ran (the dummy request
+                    # above, or the persistent cache, had not covered it)
+                    tracer.complete(
+                        "warmup.bucket", t_b, cat="setup", args={
+                            "width": t_w,
+                            "compiled": tracer.compile_misses > missed,
+                        })
             if self.pool.prefix_cache is not None:
                 self.pool.prefix_cache.clear()
             self.scheduler.finished.clear()

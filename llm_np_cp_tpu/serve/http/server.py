@@ -53,6 +53,7 @@ import asyncio
 import contextlib
 import itertools
 import json
+import os
 import queue as queue_mod
 import signal
 import sys
@@ -476,6 +477,11 @@ class EngineRunner:
         if ent is None:
             return
         loop, aq = ent
+        tr = getattr(self.engine, "tracer", None)
+        if tr is not None and item[0] == "token":
+            # the emit end of the emit-to-write lag (the write end is
+            # stamped by the stream handler); the item keeps its shape
+            tr.stamp_emit(rid)
         try:
             loop.call_soon_threadsafe(aq.put_nowait, item)
         except RuntimeError:
@@ -1735,6 +1741,7 @@ class HttpServer:
                 reader, writer, payload, rid, loop, aq)
         finally:
             if tracer is not None:
+                tracer.stream_end(rid)
                 tracer.async_end(rid, "http")
 
     async def _completions_inner(self, reader, writer, payload, rid,
@@ -1856,6 +1863,7 @@ class HttpServer:
                     await monitor
         finally:
             if tracer is not None:
+                tracer.stream_end(rid)
                 tracer.async_end(rid, "http")
 
     @staticmethod
@@ -1929,6 +1937,9 @@ class HttpServer:
                 return
             try:
                 writer.write(frame)
+                tracer = self.tracer
+                if tracer is not None and ev[0] == "token":
+                    tracer.frame_written(rid)
                 await writer.drain()
             except (ConnectionResetError, BrokenPipeError, OSError):
                 self.runner.abort(rid)
@@ -2025,8 +2036,10 @@ async def run_server(
     )
     await server.start(host, port)
     if port_file:
-        with open(port_file, "w") as f:
+        # appears whole: a poller that sees the file can read it at once
+        with open(port_file + ".tmp", "w") as f:
             f.write(f"{server.host} {server.port}\n")
+        os.replace(port_file + ".tmp", port_file)
     if exit_after_s is not None:
         asyncio.get_running_loop().call_later(
             exit_after_s, server.begin_drain)

@@ -15,16 +15,32 @@ dump at ui.perfetto.dev or chrome://tracing):
   ``evicted-requeued`` preemptions and ``recovery-replay`` resubmits
   after a supervised restart.  The HTTP layer brackets the whole thing
   with an ``http`` span starting at socket accept, so queue wait is
-  visibly split from network/parse time.
+  visibly split from network/parse time, and follows the tokens down
+  to the socket: a ``first_write`` instant when the first SSE frame of
+  the request has been written, and, where it closes ``http``, a
+  ``stream_end`` instant with the emit-to-write lag of its frames.
 - **per-tick phase spans** — complete events (``ph`` X) on the engine
   tick thread: ``admission`` / ``prefill`` / ``grow`` /
   ``decode_dispatch`` / ``host_sync`` / ``deliver`` slices nested under
   one ``tick`` event.  The phases are measured at consecutive
   timestamps, so they sum to the tick span by construction — the
   invariant tests pin.
-- the dispatch phases also run under ``jax.profiler.TraceAnnotation``
-  named scopes, so this host timeline lines up against a device profile
-  captured with ``--jax-profile DIR`` (the live-TPU tuning workflow).
+- the phases of the unified tick also run under
+  ``jax.profiler.TraceAnnotation("serve.<phase>")``, so this host
+  timeline lines up against a device profile captured with
+  ``--jax-profile DIR`` (the live-TPU tuning workflow).
+- **set-up spans** (``cat: "setup"``): load + place, engine build with
+  pool allocation and kernel probes as children, one per warm-up
+  bucket, listen.  Phases that ran before the recorder existed are
+  stamped with the recorder's clock and appended afterwards
+  (``us_at``).
+- **compile spans** (``cat: "compile"``, ``watch_compiles``): every
+  backend compile the process sees while the recorder is attached,
+  with whether the persistent cache served it; the export names the
+  tick phase or set-up span each fell in.
+- ``otherData`` of the dump carries what is not an event: the
+  device-side **op map** (serve/opmap.py) from a profile's name for an
+  operation of the step to its named scope.
 
 ZERO-OVERHEAD WHEN OFF (the ``FaultInjector`` discipline): nothing
 constructs a recorder unless tracing is requested (``--trace-out`` /
@@ -48,6 +64,8 @@ import os
 import re
 import threading
 import time
+import weakref
+from bisect import bisect_left, bisect_right
 from collections import deque
 from typing import Any, Callable
 
@@ -74,10 +92,19 @@ TICK_PHASES = (
 # decode_tokens budget split — plus spec_draft_tokens/
 # spec_accept_tokens on spec-enabled engines — for
 # tools/summarize_trace.py's utilization line.
+# The host's share of the dispatch is cut where the work changes kind:
+# ``pack`` builds the numpy operands, ``h2d`` places them (its slice
+# carries the count and bytes of the transfers), ``mixed_dispatch`` is
+# the jitted call alone (what the ``serve.mixed_dispatch`` annotation
+# wraps), ``deliver`` the emit / accept walks with their callbacks,
+# ``account`` the journal watermark and the metrics of the tick.
 MIXED_TICK_PHASES = (
-    "admission", "draft", "grow", "plan", "mixed_dispatch", "host_sync",
-    "deliver",
+    "admission", "draft", "grow", "plan", "pack", "h2d", "mixed_dispatch",
+    "host_sync", "deliver", "account",
 )
+# jax.monitoring duration events the compile watcher reads
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 # ----------------------------------------------------------------------
 # W3C trace context (the `traceparent` header): the ONE request identity
@@ -124,6 +151,19 @@ def make_traceparent(trace_id: str, span_id: str | None = None) -> str:
     return f"00-{trace_id}-{span_id or gen_span_id()}-01"
 
 
+# Recorders that asked for compile spans.  jax.monitoring has no public
+# way to drop ONE listener, so the module registers one forwarding
+# listener the first time and recorders come and go from this set.
+_compile_watchers: "weakref.WeakSet[TraceRecorder]" = weakref.WeakSet()
+_compile_listener_installed = False
+
+
+def _on_duration_event(event: str, duration_secs: float, **_kw: Any) -> None:
+    if event == _BACKEND_COMPILE_EVENT or event == _CACHE_RETRIEVAL_EVENT:
+        for rec in list(_compile_watchers):
+            rec._on_compile_event(event, duration_secs)
+
+
 class TraceRecorder:
     def __init__(
         self,
@@ -158,11 +198,72 @@ class TraceRecorder:
         # async_begin/async_end)
         self._req_phase: dict[int, str] = {}
         self._named_threads: set[int] = set()
+        # what the dump carries beside events (``otherData``): the op
+        # map, written once after warm-up
+        self._other: dict[str, Any] = {}
+        # backend compiles seen / of those, served by the persistent
+        # cache (watch_compiles); warm-up reads the difference around a
+        # bucket to say whether a compile ran
+        self.n_compiles = 0
+        self.n_cache_hits = 0
+        self._compile_tl = threading.local()
+        # rid → [emit stamps not yet written, frames, lag sum, lag max]
+        self._streams: dict[int, list] = {}
 
     # -- clock ---------------------------------------------------------
     def now_us(self) -> float:
         """Microseconds since recorder construction (the trace epoch)."""
         return (self.clock() - self._t0) * 1e6
+
+    def us_at(self, clock_value: float) -> float:
+        """A reading of the recorder's ``clock`` taken elsewhere (before
+        the recorder existed, too: the result is then negative) on the
+        trace's time axis."""
+        return (clock_value - self._t0) * 1e6
+
+    # -- what is not an event ------------------------------------------
+    def set_other(self, key: str, value: Any) -> None:
+        with self._lock:
+            self._other[key] = value
+
+    def get_other(self, key: str) -> Any:
+        with self._lock:
+            return self._other.get(key)
+
+    @property
+    def compile_misses(self) -> int:
+        """Backend compiles the persistent cache did not serve."""
+        with self._lock:
+            return self.n_compiles - self.n_cache_hits
+
+    # -- compile spans -------------------------------------------------
+    def watch_compiles(self) -> None:
+        """From now on every backend compile of this process becomes a
+        ``cat: "compile"`` span on the compiling thread's track."""
+        global _compile_listener_installed
+        import jax.monitoring
+
+        _compile_watchers.add(self)
+        if not _compile_listener_installed:
+            _compile_listener_installed = True
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration_event)
+
+    def _on_compile_event(self, event: str, duration_secs: float) -> None:
+        # the cache's retrieval time is reported INSIDE the compile it
+        # served, on the same thread, just before the compile's own event
+        tl = self._compile_tl
+        if event == _CACHE_RETRIEVAL_EVENT:
+            tl.hit = True
+            return
+        hit = getattr(tl, "hit", False)
+        tl.hit = False
+        end = self.now_us()
+        with self._lock:
+            self.n_compiles += 1
+            self.n_cache_hits += hit
+        self.complete("backend_compile", end - duration_secs * 1e6, end,
+                      cat="compile", args={"cache_hit": hit})
 
     # -- low-level event append (callers hold no lock) -----------------
     def _ensure_thread_named(self, tid: int) -> None:
@@ -222,14 +323,15 @@ class TraceRecorder:
 
     def tick(
         self, start_us: float,
-        phases: tuple[tuple[str, float, float], ...],
+        phases: tuple[tuple, ...],
         *, args: dict | None = None,
     ) -> None:
         """One tick: the wrapper ``tick`` slice plus its phase slices,
         appended atomically (a ``/debug/trace`` read never sees a tick
         missing half its phases).  Phases are ``(name, t0_us, t1_us)``
         measured at consecutive timestamps, so their durations sum to
-        the tick span by construction."""
+        the tick span by construction; a fourth element is the slice's
+        own ``args``."""
         end_us = self.now_us()
         tid = threading.get_ident()
         events = [{
@@ -237,10 +339,11 @@ class TraceRecorder:
             "dur": max(end_us - start_us, 0.0), "pid": self._pid,
             "tid": tid, **({"args": args} if args else {}),
         }]
-        for name, p0, p1 in phases:
+        for name, p0, p1, *pargs in phases:
             events.append({
                 "name": name, "cat": "phase", "ph": "X", "ts": p0,
                 "dur": max(p1 - p0, 0.0), "pid": self._pid, "tid": tid,
+                **({"args": pargs[0]} if pargs else {}),
             })
         with self._lock:
             self._ensure_thread_named(tid)
@@ -280,12 +383,13 @@ class TraceRecorder:
         self.async_begin(rid, phase, ts_us=now, args=args)
 
     def request_instant(self, rid: int, name: str, *,
+                        ts_us: float | None = None,
                         args: dict | None = None) -> None:
         """Async instant (``ph: n``) on the request's track —
         annotations like ``evicted-requeued`` / ``recovery-replay``."""
         ev: dict[str, Any] = {
             "name": name, "cat": "request", "ph": "n", "id": rid,
-            "ts": self.now_us(),
+            "ts": self.now_us() if ts_us is None else ts_us,
         }
         if args:
             ev["args"] = args
@@ -309,6 +413,49 @@ class TraceRecorder:
             "ts": now, "args": merged,
         })
 
+    # -- the request track down to the socket --------------------------
+    # The engine's ``decode`` span begins where a token is EMITTED on the
+    # tick thread; the client sees it when the event loop has written
+    # its SSE frame.  The HTTP layer stamps both ends here (the item it
+    # hands across threads keeps its shape): ``stamp_emit`` on the tick
+    # thread, ``frame_written`` on the loop after ``writer.write``,
+    # ``stream_end`` where it closes the ``http`` span.
+    # No lock per frame: the tick thread only appends to a stream's
+    # deque (and creates the entry), the loop thread only pops from it
+    # and owns the counters; both are single operations under the GIL.
+    def stamp_emit(self, rid: int) -> None:
+        st = self._streams.get(rid)
+        if st is None:
+            st = self._streams.setdefault(rid, [deque(), 0, 0.0, 0.0])
+        st[0].append(self.now_us())
+
+    def frame_written(self, rid: int) -> None:
+        """One token frame of ``rid`` is written: its emit-to-write lag
+        joins the stream's stats; the first one stamps ``first_write``."""
+        st = self._streams.get(rid)
+        if st is None or not st[0]:
+            return  # emitted before the tracer was attached
+        now = self.now_us()
+        lag = now - st[0].popleft()
+        st[1] += 1
+        st[2] += lag
+        st[3] = max(st[3], lag)
+        if st[1] == 1:
+            self.request_instant(rid, "first_write", ts_us=now,
+                                 args={"lag_us": round(lag, 1)})
+
+    def stream_end(self, rid: int) -> None:
+        """The HTTP layer is done with ``rid``: a ``stream_end`` instant
+        with the count, mean and max of emit-to-write lag over its token
+        frames (nothing for a response that streamed none)."""
+        st = self._streams.pop(rid, None)
+        if st is None or not st[1]:
+            return
+        self.request_instant(rid, "stream_end", args={
+            "frames": st[1], "lag_mean_us": round(st[2] / st[1], 1),
+            "lag_max_us": round(st[3], 1),
+        })
+
     # -- export --------------------------------------------------------
     def __len__(self) -> int:
         with self._lock:
@@ -320,12 +467,16 @@ class TraceRecorder:
             return list(self._events)
 
     def to_dict(self) -> dict:
+        with self._lock:
+            events = list(self._events)
+            other = dict(self._other)
         return {
-            "traceEvents": self.events(),
+            "traceEvents": _name_compile_sites(events),
             "displayTimeUnit": "ms",
             "otherData": {
                 "dropped_events": self.dropped,
                 "wall_epoch": self.wall_epoch,
+                **other,
             },
         }
 
@@ -335,3 +486,38 @@ class TraceRecorder:
         with open(path, "w") as f:
             json.dump(payload, f)
         return len(payload["traceEvents"])
+
+
+def _name_compile_sites(events: list[dict]) -> list[dict]:
+    """Give every compile span ``args.within``: the shortest tick phase
+    or set-up span of its own thread that holds its midpoint (None when
+    it fell between them).  Done at export: a tick's phases are appended
+    when the tick ends, after the compile it contained.  One pass over
+    the events, each host span looked up among its thread's compiles."""
+    mids: dict[int, list[tuple[float, int]]] = {}  # tid → [(midpoint, i)]
+    for i, ev in enumerate(events):
+        if ev.get("cat") == "compile":
+            mids.setdefault(ev["tid"], []).append(
+                (ev["ts"] + ev["dur"] / 2, i))
+    if not mids:
+        return events
+    for of_thread in mids.values():
+        of_thread.sort()
+    within: dict[int, dict] = {}  # compile's index → its shortest holder
+    for ev in events:
+        if ev.get("ph") != "X" or ev.get("cat") not in ("phase", "setup"):
+            continue
+        of_thread = mids.get(ev["tid"], ())
+        lo = bisect_left(of_thread, (ev["ts"], -1))
+        hi = bisect_right(of_thread, (ev["ts"] + ev["dur"], len(events)))
+        for _, i in of_thread[lo:hi]:
+            if i not in within or ev["dur"] < within[i]["dur"]:
+                within[i] = ev
+    out = list(events)
+    for of_thread in mids.values():
+        for _, i in of_thread:
+            ev, holder = events[i], within.get(i)
+            out[i] = {**ev, "args": {
+                **ev.get("args", {}),
+                "within": holder["name"] if holder else None}}
+    return out

@@ -1,51 +1,19 @@
-"""Profiling & tracing (SURVEY §5 tracing row).
+"""The device profile (SURVEY §5 tracing row).
 
-The reference's entire profiling subsystem is a wall-clock ``timing``
-decorator whose every application is commented out (llama3.2_model.py:12-26,
-``#timing`` at :87, :179, :314).  Here the same decorator exists but is
-*switchable* (env ``LLMTPU_TIMING=1`` or ``enable_timing()``), understands
-async dispatch (blocks on results before stopping the clock — naive
-wall-clock around a JAX call measures dispatch, not compute), and the real
-tool is ``trace()``: a ``jax.profiler`` context that dumps a TensorBoard/
-Perfetto trace of the XLA timeline.
+``trace()`` is a ``jax.profiler`` context that dumps a TensorBoard /
+Perfetto trace of the XLA timeline (``--jax-profile DIR`` on the serve
+subcommands).  The host side of the serving stack is traced by
+``serve/tracing.TraceRecorder``; its ``serve.*`` annotations and the
+step's named scopes (``models/transformer.STEP_SCOPES``) are what line
+the two up.
 """
 
 from __future__ import annotations
 
 import contextlib
-import functools
-import os
-import time
-from typing import Any, Callable, Iterator
+from typing import Iterator
 
 import jax
-
-_TIMING_ENABLED = os.environ.get("LLMTPU_TIMING", "") not in ("", "0")
-
-
-def enable_timing(on: bool = True) -> None:
-    global _TIMING_ENABLED
-    _TIMING_ENABLED = on
-
-
-def timing(fn: Callable) -> Callable:
-    """Per-call wall-clock printer (the reference's decorator, made real)."""
-
-    @functools.wraps(fn)
-    def wrapper(*args: Any, **kwargs: Any):
-        if not _TIMING_ENABLED:
-            return fn(*args, **kwargs)
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        try:
-            jax.block_until_ready(out)
-        except Exception:
-            pass  # non-array outputs
-        dt = time.perf_counter() - t0
-        print(f"[timing] {fn.__qualname__}: {dt * 1e3:.2f} ms")
-        return out
-
-    return wrapper
 
 
 @contextlib.contextmanager
@@ -57,21 +25,3 @@ def trace(log_dir: str = "/tmp/llmtpu_trace") -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-class Stopwatch:
-    """Tiny helper for step metrics: TTFT, per-phase durations, rates."""
-
-    def __init__(self) -> None:
-        self.marks: dict[str, float] = {}
-        self._t0 = time.perf_counter()
-
-    def mark(self, name: str, result: Any = None) -> float:
-        if result is not None:
-            jax.block_until_ready(result)
-        t = time.perf_counter() - self._t0
-        self.marks[name] = t
-        return t
-
-    def span(self, a: str, b: str) -> float:
-        return self.marks[b] - self.marks[a]

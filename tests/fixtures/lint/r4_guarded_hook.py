@@ -29,6 +29,25 @@ class Engine:
     def finish_unguarded_tenants(self, req):
         self.tenants.on_terminal(req)  # BITE tenants ledger unguarded
 
+    def step_unguarded_phase_mark(self):
+        t0 = self._phase_mark("serve.admission")  # BITE tracing-only call, no guard
+        cpu = time.thread_time_ns()  # BITE tick-thread CPU clock when off
+        with jax.profiler.TraceAnnotation("serve.x"):  # BITE scope when off
+            pass
+        t1 = (self.tracer.now_us() if self.tracer is not None
+              else self._phase_mark(None))  # BITE the untaken arm
+        return t0, cpu, t1
+
+    def step_guarded_phase_mark(self):
+        t0 = (self._phase_mark("serve.admission")
+              if self.tracer is not None else -1.0)  # guarded: NOT a finding
+        if self.tracer is not None and t0 >= 0.0:
+            cpu = time.thread_time_ns()  # guarded: NOT a finding
+        with (jax.profiler.TraceAnnotation("serve.x")
+              if self.tracer is not None else None):  # guarded
+            pass
+        return t0, cpu
+
     def step_guarded(self):
         if self.tracer is not None:
             self.tracer.instant("tick")  # guarded: NOT a finding

@@ -1,4 +1,4 @@
-"""Orbax checkpoint save/resume + profiling utilities (SURVEY §5 rows)."""
+"""Orbax checkpoint save/resume (SURVEY §5 rows)."""
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +9,6 @@ from llm_np_cp_tpu.models.transformer import init_params
 from llm_np_cp_tpu.parallel.sharding import MeshPlan, make_mesh, shard_params
 from llm_np_cp_tpu.train import default_optimizer, make_train_step
 from llm_np_cp_tpu.utils.checkpoint import restore_checkpoint, save_checkpoint
-from llm_np_cp_tpu.utils.profiling import Stopwatch, enable_timing, timing
 
 
 def test_checkpoint_roundtrip(tmp_path):
@@ -69,29 +68,6 @@ def test_checkpoint_restore_onto_mesh(tmp_path):
     np.testing.assert_array_equal(
         np.asarray(leaf), np.asarray(params["layers"]["q_proj"])
     )
-
-
-def test_timing_decorator(capsys):
-    @timing
-    def f(x):
-        return x + 1
-
-    enable_timing(False)
-    f(jnp.ones(4))
-    assert "[timing]" not in capsys.readouterr().out
-    enable_timing(True)
-    try:
-        f(jnp.ones(4))
-        assert "[timing] " in capsys.readouterr().out
-    finally:
-        enable_timing(False)
-
-
-def test_stopwatch():
-    sw = Stopwatch()
-    sw.mark("a")
-    sw.mark("b", jnp.arange(8) * 2)
-    assert sw.span("a", "b") >= 0
 
 
 def test_quantized_params_checkpoint_roundtrip(tmp_path):

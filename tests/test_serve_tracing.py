@@ -14,6 +14,7 @@ fixture.
 import json
 import re
 import sys
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -572,7 +573,16 @@ def test_traced_chaos_poisson_covers_recovery(tiny):
     # compile outside the measured window (and outside the chaos
     # schedule — warmup suspends both injector and tracer)
     engine.warmup([12], max_new_tokens=5)
-    assert len(tracer) == 0, "warmup must not pollute the timeline"
+    # ...so the dummy request leaves no tick and no request track: all
+    # there is are the set-up spans of the build and the warm-up of a
+    # split-tick engine, once each (no compile watcher is attached here)
+    assert not engine.mixed
+    assert Counter(
+        (ev["cat"], ev["name"]) for ev in tracer.events() if ev["ph"] != "M"
+    ) == Counter(("setup", name) for name in (
+        "probe.decode_attn", "pool_alloc", "probe.sample_epilogue",
+        "engine_build", "warmup.request", "warmup",
+    )), "warmup must not pollute the timeline"
     rng = np.random.default_rng(11)
     reqs = [
         (rng.integers(1, cfg.vocab_size,

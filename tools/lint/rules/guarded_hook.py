@@ -23,7 +23,15 @@ tools/compile_counter.py) the original ``assert_tracing_hooks_guarded``
 AST check: it now covers the FaultInjector AND the tracer across every
 serve hot-path module, not just two files.
 
-Second check, engine-only: the supervisor mutes a zombie engine by
+Second check: what exists ONLY for the tracer — the unified tick's
+phase marks (``self._phase_mark``, which switches the ``serve.<phase>``
+profiler annotation), ``jax.profiler.TraceAnnotation`` scopes, and the
+tick thread's CPU clock (``time.thread_time_ns``) — must sit in the
+taken branch of a ``tracer is not None`` test (an ``if`` body or the
+true arm of a conditional expression), so tracing-off pays no
+timestamp, allocation or call for them.
+
+Third check, engine-only: the supervisor mutes a zombie engine by
 REPLACING ``self.metrics`` / clearing ``self.tracer`` — so engine tick
 code must re-read those attributes at every hook and never cache them
 in a local for the tick (a cached binding would keep a superseded hung
@@ -127,6 +135,65 @@ def scan_hook_guards(
     return problems
 
 
+# callee chains that exist only for the tracer (second check)
+TRACING_ONLY_CALLS = (
+    ("self", "_phase_mark"),
+    ("time", "thread_time_ns"),
+    ("jax", "profiler", "TraceAnnotation"),
+)
+# the helper's own body: every caller holds the guard
+_TRACING_ONLY_EXEMPT = {"_phase_mark"}
+
+
+def _is_tracer_test(test: ast.AST) -> bool:
+    """``<...>.tracer is not None`` / ``tracer is not None``, alone or
+    as a conjunct of an ``and``."""
+    if isinstance(test, ast.BoolOp) and isinstance(test.op, ast.And):
+        return any(_is_tracer_test(v) for v in test.values)
+    if not (isinstance(test, ast.Compare) and len(test.ops) == 1
+            and isinstance(test.ops[0], ast.IsNot)
+            and isinstance(test.comparators[0], ast.Constant)
+            and test.comparators[0].value is None):
+        return False
+    left = test.left
+    return (isinstance(left, ast.Attribute) and left.attr == "tracer") or (
+        isinstance(left, ast.Name) and left.id in ("tracer", "tr"))
+
+
+def scan_tracing_only_calls(tree: ast.AST, rel: str) -> list[tuple[int, str]]:
+    """→ ``[(lineno, message)]`` for tracing-only calls outside the
+    taken branch of a tracer-is-not-None test."""
+    problems: list[tuple[int, str]] = []
+
+    def visit(node: ast.AST, guarded: bool, fn_name: str) -> None:
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            guarded, fn_name = False, node.name
+        if isinstance(node, ast.Call) and not guarded \
+                and fn_name not in _TRACING_ONLY_EXEMPT:
+            chain = attr_chain(node.func)
+            if chain in TRACING_ONLY_CALLS:
+                problems.append((node.lineno, (
+                    f"{rel}:{node.lineno}: {'.'.join(chain)}() in "
+                    f"{fn_name}() outside a 'tracer is not None' branch "
+                    "— tracing-only work must cost nothing when off"
+                )))
+        if isinstance(node, (ast.If, ast.IfExp)) \
+                and _is_tracer_test(node.test):
+            taken = node.body if isinstance(node.body, list) else [node.body]
+            other = (node.orelse if isinstance(node.orelse, list)
+                     else [node.orelse])
+            for child in taken:
+                visit(child, True, fn_name)
+            for child in [node.test, *other]:
+                visit(child, guarded, fn_name)
+            return
+        for child in ast.iter_child_nodes(node):
+            visit(child, guarded, fn_name)
+
+    visit(tree, False, "<module>")
+    return problems
+
+
 def scan_hook_guard_files(
     files: tuple[str, ...], hooks: tuple[str, ...] = ("tracer",),
 ) -> list[str]:
@@ -140,6 +207,9 @@ def scan_hook_guard_files(
             path = REPO_ROOT / rel
         tree = ast.parse(path.read_text())
         out.extend(msg for _, msg in scan_hook_guards(tree, str(rel), hooks))
+        if "tracer" in hooks:
+            out.extend(
+                msg for _, msg in scan_tracing_only_calls(tree, str(rel)))
     return out
 
 
@@ -152,7 +222,8 @@ class _Rule:
         out = [
             Finding(rule=self.id, path=sf.rel, line=line,
                     message=msg.split(": ", 1)[1])
-            for line, msg in scan_hook_guards(sf.tree, sf.rel)
+            for line, msg in (scan_hook_guards(sf.tree, sf.rel)
+                              + scan_tracing_only_calls(sf.tree, sf.rel))
         ]
         if sf.rel.endswith("serve/engine.py"):
             self._check_no_cache(sf, out)

@@ -18,8 +18,21 @@ from ``GET /debug/trace``) and prints:
 - **kv_tier** (when the trace was recorded with ``--kv-tier host``) —
   spilled/restored bytes and restore-latency percentiles from the
   host-tier tick args;
+- **tick account** (unified tick) — the cut host phases in tick order
+  (pack / h2d / mixed_dispatch / deliver / account) with the transfers'
+  count and bytes, the tick thread's own CPU time and what is left over
+  (neither CPU nor the device wait), the live context per dispatch;
+- **set-up** — the ``cat: "setup"`` spans (load + place, engine build
+  and its probes, every warm-up bucket, the op map, listen) and the
+  backend compiles the recorder saw, by where they fell (one under
+  traffic is named with its tick phase);
+- **device scopes** (``--profile FILE.xplane.pb``) — device time by the
+  step's named scopes, from the op map the dump carries
+  (``otherData.op_map``: operation → scope, pool-shaped or not);
 - **per-request lifecycle table** — queued / prefill / decode (and, when
-  the HTTP layer traced it, the accept→response bracket) per request,
+  the HTTP layer traced it, the accept→response bracket, the first
+  frame's emit-to-write lag, the stream's mean and largest lag and its
+  number of frames) per request,
   with eviction/recovery counts and the finish reason;
 - **tenants** (when ``--request-log PATH`` points at the canonical
   request log for the same run) — per-tenant request / token / cost
@@ -42,6 +55,7 @@ from ``GET /debug/trace``) and prints:
 Usage::
 
     python tools/summarize_trace.py TRACE.json [--top K]
+    python tools/summarize_trace.py TRACE.json --profile RUN.xplane.pb
     python tools/summarize_trace.py TRACE.json --request-log REQS.jsonl
     python tools/summarize_trace.py A.json B.json [--merge OUT.json]
 """
@@ -59,6 +73,13 @@ from typing import Any
 # so this tool stays stdlib-only (no jax import just to print a table);
 # pinned equal to the recorder's vocabulary by tests/test_serve_tracing.
 LIFECYCLE_COLUMNS = ("queued", "prefill", "decode", "http")
+# serve.tracing.MIXED_TICK_PHASES, the same way (the tick-account line
+# prints them in tick order)
+MIXED_TICK_PHASES = (
+    "admission", "draft", "grow", "plan", "pack", "h2d", "mixed_dispatch",
+    "host_sync", "deliver", "account",
+)
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def load_trace(path: str) -> list[dict]:
@@ -410,6 +431,140 @@ def format_tenants(records: list[dict]) -> str:
     return "\n".join(lines)
 
 
+def tick_account(events: list[dict]) -> dict[str, Any] | None:
+    """Where the host's share of a unified tick goes, over DISPATCHING
+    ticks: mean of every cut phase (tick order), the ``h2d`` slice's
+    transfer count and bytes, and from the tick args the tick thread's
+    own CPU time, the leftover (tick - host_sync - CPU: neither
+    computing nor waiting for the device), the live context and the
+    packed-width buckets the dispatches used.  None
+    for a trace without the cut phases (split tick, older dumps)."""
+    ticks = [e for e in events if e.get("ph") == "X"
+             and e.get("cat") == "tick" and "packed_width" in
+             (e.get("args") or {}) and e["args"]["packed_width"]]
+    if not ticks:
+        return None
+    spans = sorted((e["ts"], e["ts"] + e["dur"]) for e in ticks)
+    out: dict[str, Any] = {"ticks": len(ticks)}
+    sums: dict[str, float] = defaultdict(float)
+    puts = put_bytes = 0
+    i = 0
+    for ev in sorted((e for e in events if e.get("ph") == "X"
+                      and e.get("cat") == "phase"), key=lambda e: e["ts"]):
+        while i < len(spans) and spans[i][1] < ev["ts"]:
+            i += 1
+        if i == len(spans) or ev["ts"] < spans[i][0]:
+            continue  # a phase of a tick that dispatched nothing
+        sums[ev["name"]] += ev["dur"]
+        if ev["name"] == "h2d":
+            puts += (ev.get("args") or {}).get("count", 0)
+            put_bytes += (ev.get("args") or {}).get("bytes", 0)
+    n = len(ticks)
+    for name in MIXED_TICK_PHASES:
+        out[f"{name}_us"] = sums.get(name, 0.0) / n
+    out["h2d_count"] = puts / n
+    out["h2d_bytes"] = put_bytes / n
+    out["tick_us"] = sum(e["dur"] for e in ticks) / n
+    out["context_tokens"] = sum(
+        e["args"].get("context_tokens", 0) for e in ticks) / n
+    widths: dict[int, int] = defaultdict(int)
+    for e in ticks:
+        widths[e["args"]["packed_width"]] += 1
+    out["packed_widths"] = dict(sorted(widths.items()))
+    cpu = [e["args"]["thread_cpu_us"] for e in ticks
+           if "thread_cpu_us" in e["args"]]
+    if cpu:
+        out["thread_cpu_us"] = sum(cpu) / len(cpu)
+        out["host_wait_us"] = sum(
+            e["dur"] - e["args"].get("host_sync_us", 0.0)
+            - e["args"]["thread_cpu_us"]
+            for e in ticks if "thread_cpu_us" in e["args"]) / len(cpu)
+    return out
+
+
+def setup_spans(events: list[dict]) -> list[dict]:
+    """The ``cat: "setup"`` spans in start order, each with the number
+    of backend compiles that fell in it (its children's included) and
+    how many of those the persistent cache did not serve."""
+    spans = sorted((e for e in events if e.get("ph") == "X"
+                    and e.get("cat") == "setup"), key=lambda e: e["ts"])
+    compiles = [e for e in events if e.get("cat") == "compile"]
+    out = []
+    for ev in spans:
+        inside = [c for c in compiles if c["tid"] == ev["tid"]
+                  and ev["ts"] <= c["ts"] + c["dur"] / 2 <= ev["ts"] + ev["dur"]]
+        out.append({
+            "name": ev["name"], "dur_us": ev["dur"],
+            "args": ev.get("args") or {}, "compiles": len(inside),
+            "compile_misses": sum(
+                1 for c in inside
+                if not (c.get("args") or {}).get("cache_hit")),
+        })
+    return out
+
+
+def stray_compiles(events: list[dict]) -> dict[str, int]:
+    """Backend compiles that fell OUTSIDE set-up, counted by where the
+    recorder says they fell (``args.within``: a tick phase, or "between
+    spans"): a recompile under traffic, which warm-up exists to prevent."""
+    setup = {e["name"] for e in events if e.get("cat") == "setup"}
+    out: dict[str, int] = defaultdict(int)
+    for ev in events:
+        if ev.get("cat") == "compile":
+            within = (ev.get("args") or {}).get("within")
+            if within not in setup:
+                out[within or "between spans"] += 1
+    return dict(out)
+
+
+def _load_by_path(name: str, rel: str):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(REPO, rel))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def device_scopes(op_map: dict, profile: str) -> dict[str, Any] | None:
+    """Device time of a ``jax.profiler`` window by the step's named
+    scopes: every operation's own time goes to the scope the dump's op
+    map gives it under the profile's own name for it (``pool``: the
+    result is the KV pool or one layer's slab of it, whatever the scope;
+    ``-``: the map has no scope for it; ``?``: not in the map, or two
+    buckets of the step disagree).
+    Reads the profile with the benchmark's own reader (needs jax)."""
+    devtrace = _load_by_path("devtrace", "benchmark/devtrace.py")
+    reduced = devtrace.reduce(devtrace.read_xplane(profile))
+    if not reduced or not reduced["busy_s"]:
+        return None
+    by: dict[str, float] = defaultdict(float)
+    for name, seconds in reduced["ops_s"].items():
+        known = op_map.get(name.rsplit(" ", 1)[0])
+        if known is None:
+            by["?"] += seconds
+        else:
+            scope, kind = known
+            by["pool" if kind and scope != "attn" else scope or "-"] += seconds
+    return {"busy_s": reduced["busy_s"], "window_s": reduced["window_s"],
+            "ticks": reduced["ticks"], "scopes": dict(by)}
+
+
+def format_device_scopes(dev: dict[str, Any]) -> str:
+    lines = [
+        "== device scopes ==",
+        f"{dev['busy_s'] * 1e3:.1f} ms busy of {dev['window_s'] * 1e3:.1f} ms"
+        f" ({dev['ticks']} ticks)",
+        f"{'scope':<10} {'ms':>10} {'share':>7}",
+    ]
+    for scope, seconds in sorted(dev["scopes"].items(),
+                                 key=lambda kv: -kv[1]):
+        lines.append(f"{scope:<10} {seconds * 1e3:>10.2f} "
+                     f"{seconds / dev['busy_s']:>7.1%}")
+    return "\n".join(lines)
+
+
 def slowest_ticks(events: list[dict], k: int) -> list[dict]:
     ticks = [e for e in events
              if e.get("ph") == "X" and e.get("cat") == "tick"]
@@ -422,7 +577,8 @@ def request_table(events: list[dict]) -> dict[Any, dict]:
     events."""
     table: dict[Any, dict] = defaultdict(lambda: {
         "phases_us": defaultdict(float), "evictions": 0, "recoveries": 0,
-        "finish": None,
+        "finish": None, "first_write_lag_us": None, "write_lag_us": None,
+        "write_lag_max_us": None, "frames": None,
     })
     open_spans: dict[tuple, float] = {}
     for ev in events:
@@ -442,6 +598,15 @@ def request_table(events: list[dict]) -> dict[Any, dict]:
                 table[rid]["evictions"] += 1
             elif name == "recovery-replay":
                 table[rid]["recoveries"] += 1
+            elif name == "first_write":
+                # the first frame's emit (tick thread) → write (loop)
+                table[rid]["first_write_lag_us"] = (
+                    ev.get("args") or {}).get("lag_us")
+            elif name == "stream_end":
+                end = ev.get("args") or {}
+                table[rid]["write_lag_us"] = end.get("lag_mean_us")
+                table[rid]["write_lag_max_us"] = end.get("lag_max_us")
+                table[rid]["frames"] = end.get("frames")
     return dict(table)
 
 
@@ -487,6 +652,35 @@ def format_summary(events: list[dict], top: int = 5) -> str:
                    if "host_fetches_max" in util else "")
                 + ")"
             )
+    acct = tick_account(events)
+    if acct is not None:
+        lines.append(
+            f"== tick account ({acct['ticks']:.0f} dispatching ticks, "
+            f"mean us) ==\n"
+            + "  ".join(f"{name} {acct[name + '_us']:.0f}"
+                        for name in MIXED_TICK_PHASES)
+            + f"\ntick {acct['tick_us']:.0f}us; h2d "
+            f"{acct['h2d_count']:.0f} transfers, "
+            f"{acct['h2d_bytes']:.0f} bytes; context "
+            f"{acct['context_tokens']:.0f} tokens/dispatch; packed width "
+            + " ".join(f"{w}x{n}" for w, n in acct["packed_widths"].items())
+            + (f"; tick thread CPU {acct['thread_cpu_us']:.0f}us, "
+               f"neither CPU nor device wait {acct['host_wait_us']:.0f}us"
+               if "thread_cpu_us" in acct else "")
+        )
+    setup = setup_spans(events)
+    if setup:
+        lines.append("== set-up ==")
+        for sp in setup:
+            extra = " ".join(f"{k}={v}" for k, v in sp["args"].items())
+            lines.append(
+                f"  {sp['name']:<22} {sp['dur_us'] / 1e3:>10.1f} ms  "
+                f"compiles {sp['compiles']} ({sp['compile_misses']} not "
+                f"from the cache)" + (f"  {extra}" if extra else ""))
+        stray = stray_compiles(events)
+        lines.append("  compiles outside set-up: " + (", ".join(
+            f"{n} in {where}" for where, n in sorted(stray.items()))
+            or "none"))
     roof = roofline(events)
     if roof is not None:
         lines.append("== roofline ==")
@@ -528,6 +722,7 @@ def format_summary(events: list[dict], top: int = 5) -> str:
     lines.append(
         f"{'rid':>5} "
         + " ".join(f"{c + '_ms':>10}" for c in LIFECYCLE_COLUMNS)
+        + f" {'fw_lag_ms':>9} {'wr_lag_ms':>9} {'wr_max_ms':>9} {'frames':>6}"
         + f" {'evict':>5} {'recov':>5} finish"
     )
     for rid in sorted(table, key=str):
@@ -540,6 +735,12 @@ def format_summary(events: list[dict], top: int = 5) -> str:
         lines.append(
             f"{rid!s:>5} "
             + " ".join(f"{ms(c):>10}" for c in LIFECYCLE_COLUMNS)
+            + "".join(
+                f" {rec[k] / 1e3:>9.2f}" if rec[k] is not None
+                else f" {'-':>9}"
+                for k in ("first_write_lag_us", "write_lag_us",
+                          "write_lag_max_us"))
+            + f" {'-' if rec['frames'] is None else rec['frames']:>6}"
             + f" {rec['evictions']:>5} {rec['recoveries']:>5} "
             f"{rec['finish'] or '-'}"
         )
@@ -561,6 +762,10 @@ def main(argv: list[str] | None = None) -> str:
     p.add_argument("--merge", default=None, metavar="OUT",
                    help="write the merged/rebased trace JSON to OUT "
                    "(implied merge mode; open at ui.perfetto.dev)")
+    p.add_argument("--profile", default=None, metavar="XPLANE",
+                   help="a jax.profiler .xplane.pb of the same run: adds "
+                   "device time by the step's named scopes, from the op "
+                   "map in the dump (needs jax to read the profile)")
     p.add_argument("--request-log", default=None, metavar="PATH",
                    help="canonical request log (--request-log JSONL) "
                    "for the same run: adds the per-tenant request/"
@@ -576,6 +781,15 @@ def main(argv: list[str] | None = None) -> str:
                     f"events to {args.merge}")
     else:
         out = format_summary(load_trace(args.trace[0]), top=args.top)
+    if args.profile is not None:
+        with open(args.trace[0]) as f:
+            data = json.load(f)
+        op_map = (data.get("otherData", {}) if isinstance(data, dict)
+                  else {}).get("op_map")
+        dev = device_scopes(op_map, args.profile) if op_map else None
+        out += "\n" + (format_device_scopes(dev) if dev else
+                       "== device scopes ==\nnothing to read: the dump "
+                       "has no op map or the profile no device operation")
     if args.request_log is not None:
         out += "\n" + format_tenants(load_request_log(args.request_log))
     print(out)
