@@ -914,7 +914,9 @@ def _build_serve_engine(args, params, config, *, prog: str,
         print(f"[{prog}] unified tick ACTIVE: one mixed dispatch/tick, "
               f"budget {engine.tick_token_budget} tokens "
               f"(ragged attention: {engine.ragged_attn_impl}, "
-              f"epilogue={'fused' if engine.epilogue_impl == 'fused' else 'xla'})")
+              f"epilogue={'fused' if engine.epilogue_impl == 'fused' else 'xla'}), "
+              + ("pool written in place" if engine.pool_carried else
+                 "pool moved by layer slabs (not row-major on this device)"))
     elif getattr(args, "mixed_step", "off") == "auto":
         print(f"[{prog}] --mixed-step auto: ragged kernel unavailable; "
               "using the phase-split tick "

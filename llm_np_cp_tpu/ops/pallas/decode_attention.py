@@ -642,8 +642,10 @@ def ragged_paged_attention(
     q [T, H, D] — the packed token axis: each row's segment occupies
     consecutive, ``RAGGED_Q_TILE``-aligned positions (the serve engine's
     packer guarantees this; dead lanes between segments are masked via
-    ``tile_qlen``).  k_pages/v_pages [NB, BS, K, D] — ONE layer's pool
-    slab.  tables [R, MB] int32 block ids per engine row.  Per TILE
+    ``tile_qlen``).  k_pages/v_pages [NB, BS, K, D] — a pool of pages:
+    one layer's slab, or (the unified tick) the whole pool flat over
+    layer and block with the layer's offset already in ``tables``.
+    tables [R, MB] int32 page ids per engine row.  Per TILE
     (T / RAGGED_Q_TILE entries): ``tile_row`` — the owning engine row,
     ``tile_qpos0`` — the cache slot of the tile's first token,
     ``tile_qlen`` — live tokens in the tile (0 = dead padding tile).

@@ -66,11 +66,13 @@ each on its own mesh slice.
 Unified tick (``mixed_step="on"/"auto"``): the phase-split pipeline
 above collapses into ONE jit-stable ``mixed_step`` dispatch per tick —
 a packed ragged batch of prefill chunk slices and decode rows runs
-through a single layer scan that threads the pool slabs, scatters every
-token's K/V straight into its pool block (NO temp prefill cache, NO
-``gather_prefix`` copy program — shared prefix blocks are attended
-in place through the block table), and attends via
-``ragged_paged_attention`` (probe-gated; XLA gather fallback).  The
+through a single layer scan that CARRIES the pool (flat over layer and
+block) and scatters every token's K/V into it in place (NO temp prefill
+cache, NO ``gather_prefix`` copy program — shared prefix blocks are
+attended in place through the block table; no slab sliced out of the
+pool, no copy of the pool back), and attends via
+``ragged_paged_attention`` over the carried pool, the layer's offset
+added to the block tables (probe-gated; XLA gather fallback).  The
 scheduler's token-budget planner (``Scheduler.plan_tick``) co-schedules
 chunked prefill with decode under ``tick_token_budget`` tokens per tick
 — decode rows first, so a long prefill can no longer stall the decoding
@@ -211,6 +213,23 @@ def _roofline_targs(tel: dict) -> dict:
         "kv_write_bytes": int(tel["kv_write_bytes"]),
         "weight_bytes": int(tel["weight_bytes"]),
     }
+
+
+def _pool_is_row_major(pages: PagedKV) -> bool:
+    """Whether the device keeps every array of the pool in the order the
+    paged-attention kernels read it: dimensions major to minor as the
+    shape lists them.  A TPU lays an array out by its own rule, and the
+    rule permutes the dimensions of a ``[.., BS, K, D]`` array whose
+    minor two would waste most of a tile: ``D`` under 128, one bf16 or up
+    to two int8 kv heads on the chip, and every ``[.., BS, K]`` scale
+    page (compiled for a described v5e: PERF.md §4).  A kernel cannot
+    read such a pool where it lies, so the unified step then keeps one
+    layer's slab, not the pool, as the unit it relays out."""
+    return all(
+        getattr(a.format.layout, "major_to_minor", None)
+        == tuple(range(a.ndim))
+        for a in pages if a is not None
+    )
 
 
 def worst_case_slots(prompt_len: int, max_new_tokens: int, chunk: int) -> int:
@@ -1650,7 +1669,15 @@ class ServeEngine:
         batch of prefill chunk slices (q_len up to ``prefill_chunk``)
         and decode rows (q_len 1) through the layer scan, scattering
         every token's K/V straight into its pool block and attending
-        through the block tables — no temp prefill cache, no
+        through the block tables.  The pool is the scan's carry,
+        reshaped once to ``[L*NB, ...]`` (a bitcast) and written in
+        place: layer ``l`` writes and attends pages ``l * NB + block``,
+        and the donated buffer comes back as the result with nothing
+        pool- or slab-sized copied (5 / 3.3 / 2.9 ms a tick of the
+        1.5B cell went on that, PERF.md §6 PR 25).  The one exception
+        is a pool the device does not keep row-major
+        (``_pool_is_row_major``), which goes through as ``xs`` / ``ys``
+        slabs.  No temp prefill cache, no
         ``gather_prefix`` copy (shared prefix blocks are read in place),
         no separate sample dispatch (logits are gathered at each row's
         last packed token and sampled in-graph with the SAME
@@ -1675,6 +1702,7 @@ class ServeEngine:
         stop_tokens = self.stop_tokens
         big_win = jnp.int32(1 << 30)
         constrain_pages = self._constrain_pages
+        carry_pool = self.pool_carried = _pool_is_row_major(self.pool.pages)
         attn_call = self._shard_attn(
             partial(
                 ragged_paged_attention if use_kernel
@@ -1717,29 +1745,45 @@ class ServeEngine:
                 dtype=jnp.bool_,
             )
 
-            def layer_step(x: jnp.ndarray, xs: tuple) -> tuple:
-                if quantized:
-                    w, kp, vp, ksp, vsp, sliding = xs
+            # The pool is the scan's CARRY, flat over (layer, block): a
+            # leading-dims reshape is a bitcast of the donated buffer, the
+            # scatter below updates it in place, and a layer's blocks are
+            # the ids ``layer * NB + block`` (pinned by
+            # tests/test_kernel_lowering.py).  A pool the device does
+            # not keep row-major would be relaid out WHOLE every tick
+            # that way: its layers get their slabs as ``xs`` / ``ys``.
+            nb = pages.k.shape[1]
+            pools = tuple(a for a in pages if a is not None)
+            layers = jnp.arange(num_layers, dtype=jnp.int32)
+
+            def layer_step(carry: Any, xs: tuple) -> tuple:
+                w, sliding, layer, *slabs = xs
+                if carry_pool:
+                    x, kp, vp, *scale_pages = carry
+                    base = layer * nb
                 else:
-                    w, kp, vp, sliding = xs
+                    x, (kp, vp, *scale_pages), base = carry, slabs, 0
+                blk = base + tok_blk
 
                 def kv_update(k, v):  # fresh projections [1, T, K, D]
-                    # dead lanes all write (scratch block 0, slot 0) —
-                    # duplicate scatter indices there are harmless
+                    # dead lanes all write (this layer's scratch block 0,
+                    # slot 0) — duplicate scatter indices there are
+                    # harmless
                     if quantized:
+                        ksp, vsp = scale_pages
                         kq, ks = quantize_kv(k)
                         vq, vs = quantize_kv(v)
                         return (
-                            (kp.at[tok_blk, tok_off].set(kq[0]),
-                             ksp.at[tok_blk, tok_off].set(ks[0])),
-                            (vp.at[tok_blk, tok_off].set(vq[0]),
-                             vsp.at[tok_blk, tok_off].set(vs[0])),
+                            (kp.at[blk, tok_off].set(kq[0]),
+                             ksp.at[blk, tok_off].set(ks[0])),
+                            (vp.at[blk, tok_off].set(vq[0]),
+                             vsp.at[blk, tok_off].set(vs[0])),
                         )
                     # explicit cast: f32 activations into a bf16 pool
                     # is the intended rounding, not an implicit promotion
                     return (
-                        kp.at[tok_blk, tok_off].set(k[0].astype(kp.dtype)),
-                        vp.at[tok_blk, tok_off].set(v[0].astype(vp.dtype)),
+                        kp.at[blk, tok_off].set(k[0].astype(kp.dtype)),
+                        vp.at[blk, tok_off].set(v[0].astype(vp.dtype)),
                     )
 
                 def attn_fn(q, k_att, v_att, sliding_l):
@@ -1753,14 +1797,17 @@ class ServeEngine:
                         if win is not None else big_win
                     )
                     scales = (ksp2, vsp2) if quantized else ()
+                    # the attention callables take "a pool of pages and
+                    # block ids": this layer's ids in the pool it is given
+                    layer_tables = tables + base
                     if use_kernel:
                         out = attn_call(
-                            q[0], kp2, vp2, *scales, tables, tile_row,
+                            q[0], kp2, vp2, *scales, layer_tables, tile_row,
                             tile_qpos0, tile_qlen, pads, win_eff,
                         )
                     else:
                         out = attn_call(
-                            q[0], kp2, vp2, *scales, tables, tok_row,
+                            q[0], kp2, vp2, *scales, layer_tables, tok_row,
                             tok_slot, tok_live, pads, win_eff,
                         )
                     return out[None]
@@ -1771,19 +1818,22 @@ class ServeEngine:
                 )
                 if quantized:
                     (kp2, ksp2), (vp2, vsp2) = kv_att
-                    return x, (kp2, vp2, ksp2, vsp2)
-                return x, kv_att
+                    kv_att = (kp2, vp2, ksp2, vsp2)
+                return ((x, *kv_att), None) if carry_pool else (x, kv_att)
 
-            xs: tuple = (params["layers"], pages.k, pages.v)
-            if quantized:
-                xs += (pages.k_scale, pages.v_scale)
-            xs += (is_sliding,)
-            x, ys = lax.scan(layer_step, x, xs, unroll=scan_unroll(config))
-            new_pages = PagedKV(
-                k=ys[0], v=ys[1],
-                k_scale=ys[2] if quantized else None,
-                v_scale=ys[3] if quantized else None,
-            )
+            xs = (params["layers"], is_sliding, layers)
+            if carry_pool:
+                (x, *flat), _ = lax.scan(
+                    layer_step,
+                    (x, *(a.reshape((num_layers * nb,) + a.shape[2:])
+                          for a in pools)),
+                    xs, unroll=scan_unroll(config))
+                new_pages = PagedKV(*(
+                    a.reshape(p.shape) for a, p in zip(flat, pools)))
+            else:
+                x, ys = lax.scan(layer_step, x, xs + pools,
+                                 unroll=scan_unroll(config))
+                new_pages = PagedKV(*ys)
             new_pages = constrain_pages(new_pages)
             # sampling ONLY at each row's sample slots — [R, W] packed
             # indices: column 0 is the plain sample (decode rows and

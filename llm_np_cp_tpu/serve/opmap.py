@@ -13,7 +13,9 @@ text of the compiled module carries, for every instruction, the
 operations the compiler adds around the layer loop to move the KV pool
 (layout copies, the loop's dynamic-slice / dynamic-update-slice of a
 layer's slab) carry no scope; they are told by their result, which has
-the shape of the pool or of one layer's slab of it.
+the shape of the pool (as the step's arguments have it, or flat over
+layer and block as the unified step's scan carries it) or of one
+layer's slab of it.
 
 The map goes into ``otherData["op_map"]`` of the trace dump once, after
 warm-up (``ServeEngine.device_op_map``), merged over the step's buckets
@@ -62,13 +64,19 @@ def hlo_shape(dtype_name: str, shape: Iterable[int]) -> str:
 
 def pool_shapes(arrays: Iterable[tuple[str, tuple[int, ...]]]) -> dict:
     """``{hlo shape: "pool" | "slab"}`` for the pool's arrays, each given
-    as (dtype name, per-device shape): the whole array, and one layer's
-    slab of it with and without the leading 1."""
+    as (dtype name, per-device shape): the whole array as the program's
+    arguments have it (``[L, NB, ...]``) and as the unified step's layer
+    loop carries it (flat over layer and block, ``[L*NB, ...]``), and one
+    layer's slab of it with and without the leading 1."""
+    arrays = [(name, tuple(shape)) for name, shape in arrays]
     out: dict[str, str] = {}
     for dtype_name, shape in arrays:
         out[hlo_shape(dtype_name, shape)] = "pool"
+        out[hlo_shape(dtype_name, (shape[0] * shape[1],) + shape[2:])] = "pool"
+    # after every "pool": a one-layer pool's slab IS its flat shape
+    for dtype_name, shape in arrays:
         out.setdefault(hlo_shape(dtype_name, shape[1:]), "slab")
-        out.setdefault(hlo_shape(dtype_name, (1,) + tuple(shape[1:])), "slab")
+        out.setdefault(hlo_shape(dtype_name, (1,) + shape[1:]), "slab")
     return out
 
 

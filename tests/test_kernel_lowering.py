@@ -20,14 +20,21 @@ against their XLA twins.
 """
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax import export
 
+from llm_np_cp_tpu.config import tiny_config
+from llm_np_cp_tpu.models import init_params
+from llm_np_cp_tpu.models.transformer import SCOPE_KV_WRITE, STEP_SCOPES
 from llm_np_cp_tpu.ops.pallas.decode_attention import decode_attention
 from llm_np_cp_tpu.ops.pallas.flash_attention import flash_attention
+from llm_np_cp_tpu.serve import ServeEngine, opmap
+from llm_np_cp_tpu.serve.block_pool import PagedKV
 
 
 def _export_tpu(fn, *args):
@@ -184,3 +191,160 @@ def test_every_kernel_case_compiles_for_v5e(v5e_sharding, case):
     """The whole on-chip matrix (every kernel in support.KERNELS at the
     probe and the three family shapes, both serve block sizes)."""
     _compile_case(v5e_sharding, *case)
+
+
+# ----------------------------------------------------------------------
+# the unified tick must not move the KV pool (whole step, for a v5e)
+# ----------------------------------------------------------------------
+#
+# ``_make_mixed_step`` carries the pool through the layer scan (flat over
+# layer and block) and scatters this tick's K/V into it in place.  The
+# form it replaced handed the pool to ``lax.scan`` as ``xs`` / ``ys``:
+# each layer's slab was sliced out, scattered into, stacked back into a
+# NEW pool-sized array and that copied onto the donated buffer — 41 / 36 /
+# 58 % of the step's device time in the benchmark's three cells (PERF.md
+# §6, PR 25), and a second pool held as a temporary.
+#
+# Whether the compiler updates in place is decided by the TPU compiler,
+# not by the jaxpr, so this compiles the step for a DESCRIBED v5e (libtpu
+# is installed; no chip — the way benchmark/tick_memory.py sizes a cell,
+# in THIS file because one process of a test run may load libtpu, so one
+# file describes the topology) and reads the result: its
+# temporaries, and every operation whose result is shaped like the pool
+# or one layer's slab of it, by serve/opmap.py's own parse.
+
+# Qwen2.5-shaped heads (12 q over 2 kv heads of 128), three layers, and a
+# pool whose one-layer slab (8 MiB of bf16 K) dwarfs every activation of
+# the step: a temporary that size can only be the pool
+SLOTS, BLOCKS, BLOCK, CHUNK = 4, 256, 64, 64
+
+
+def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS):
+    cfg = tiny_config(
+        "qwen2", num_hidden_layers=3, hidden_size=1536,
+        intermediate_size=1024, num_attention_heads=12,
+        num_key_value_heads=2, head_dim=128, vocab_size=2048,
+    )
+    abstract = jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
+    engine = ServeEngine(
+        abstract, cfg, max_slots=SLOTS, num_blocks=blocks, block_size=BLOCK,
+        max_seq_len=BLOCK * 8, prefill_chunk=CHUNK, cache_dtype=cache_dtype,
+        mixed_step="on",
+    )
+    assert engine.mixed and engine.ragged_attn_impl == "pallas"
+
+    def aval(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    avals = [jax.tree.map(aval, abstract),
+             jax.tree.map(aval, engine.pool.pages)]
+    avals += [aval(a) for a in
+              engine._dead_mixed_operands(engine.mixed_buckets[-1])]
+    # the kernels pick interpret mode from the backend they see: show
+    # them the one they are being compiled for
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        compiled = engine._mixed_step.lower(*avals).compile()
+    finally:
+        jax.default_backend = real
+    return engine, compiled
+
+
+def _pool_ops(engine, compiled):
+    """serve/opmap.py's parse of the compiled text: every operation that
+    runs, as [scope, result shape, "pool" | "slab" | ""]."""
+    pool = opmap.pool_shapes(
+        (a.dtype.name, a.shape) for a in engine.pool.pages if a is not None)
+    return opmap.op_map_from_hlo(compiled.as_text(), STEP_SCOPES, pool)
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_compiled_tick_writes_the_pool_in_place(v5e_sharding, cache_dtype):
+    engine, compiled = _compile_widest_bucket(v5e_sharding, cache_dtype)
+    pages = engine.pool.pages
+    k_slab_bytes = int(np.prod(pages.k.shape[1:])) * pages.k.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < k_slab_bytes, (
+        f"the tick holds {temp} B of temporaries, one layer's K slab is "
+        f"{k_slab_bytes} B: a copy of (part of) the pool is back")
+
+    ops = _pool_ops(engine, compiled)
+    assert {scope for scope, _, _ in ops.values()} >= set(STEP_SCOPES)
+    slabs = {n: v for n, v in ops.items() if v[2] == "slab"}
+    assert not slabs, f"a layer's slab is sliced out or rebuilt: {slabs}"
+    moved = {n: v for n, v in ops.items()
+             if v[2] == "pool" and v[0] != SCOPE_KV_WRITE}
+    assert not moved, f"the pool moves outside the K/V write: {moved}"
+    # what is left is the write itself: one scatter for K and one for V,
+    # in the layer loop's body, on the carried (flat) pool
+    writes = [v for v in ops.values() if v[2] == "pool"]
+    flat = opmap.hlo_shape(
+        pages.k.dtype.name,
+        (pages.k.shape[0] * pages.k.shape[1],) + pages.k.shape[2:])
+    assert [v[1] for v in writes] == [flat, flat]
+
+
+def test_int8_pool_on_a_v5e_is_why_the_slab_form_stays(
+        v5e_sharding, monkeypatch):
+    """A v5e keeps ``s8[.., 64, 2, 128]`` pages and their ``f32[.., 64,
+    2]`` scale pages with the dimensions permuted (two int8 kv heads
+    would fill half a tile), which the ragged kernel cannot read.  The
+    engine sees that on the pool it allocated (``_pool_is_row_major``)
+    and keeps the per-layer slab form there.  This pool lives on the CPU,
+    so the choice is forced each way and the two programs compared as the
+    v5e compiler sees them: carried, the whole pool is relaid out in and
+    out (entry-computation copies, a padded pool of temporaries — 14 GiB
+    at ``chat-open``'s pool size against the slab form's 3, PERF.md §4).
+    The day this fails the slab form has lost its reason: delete it."""
+    from llm_np_cp_tpu.serve import engine as engine_mod
+
+    temps = {}
+    for carried in (True, False):
+        monkeypatch.setattr(
+            engine_mod, "_pool_is_row_major", lambda pages, c=carried: c)
+        engine, compiled = _compile_widest_bucket(
+            v5e_sharding, jnp.int8, blocks=512)
+        temps[carried] = compiled.memory_analysis().temp_size_in_bytes
+        kinds = {v[2] for v in _pool_ops(engine, compiled).values()}
+        assert ("slab" in kinds) == (not carried)
+    pool_bytes = sum(a.nbytes for a in engine.pool.pages)
+    assert temps[False] < pool_bytes < temps[True], temps
+
+
+class _Laid:
+    """An array as ``_pool_is_row_major`` looks at it."""
+
+    def __init__(self, *major_to_minor):
+        self.ndim = len(major_to_minor)
+        self.format = types.SimpleNamespace(layout=types.SimpleNamespace(
+            major_to_minor=major_to_minor))
+
+
+@pytest.mark.parametrize("pages,carried", [
+    # what a v5e reports (compiled for a described one): bf16 pages of
+    # two kv heads x 128 lie as their shape says; int8 pages and every
+    # scale page, and pages of head_dim 64, do not
+    (PagedKV(_Laid(0, 1, 2, 3, 4), _Laid(0, 1, 2, 3, 4)), True),
+    (PagedKV(_Laid(0, 1, 3, 2, 4), _Laid(0, 1, 3, 2, 4),
+             _Laid(0, 2, 3, 1), _Laid(0, 2, 3, 1)), False),
+    (PagedKV(_Laid(0, 1, 2, 3, 4), _Laid(0, 1, 2, 3, 4),
+             _Laid(0, 2, 3, 1), _Laid(0, 2, 3, 1)), False),
+    (PagedKV(_Laid(0, 2, 3, 4, 1), _Laid(0, 2, 3, 4, 1)), False),
+], ids=["bf16-2x128", "int8-2x128", "int8-4x128", "bf16-8x64"])
+def test_the_step_carries_the_pool_where_the_device_keeps_it_row_major(
+        pages, carried):
+    from llm_np_cp_tpu.serve.engine import _pool_is_row_major
+
+    assert _pool_is_row_major(pages) is carried
+
+
+def test_a_pool_on_the_cpu_is_row_major():
+    from llm_np_cp_tpu.serve.block_pool import BlockPool
+    from llm_np_cp_tpu.serve.engine import _pool_is_row_major
+
+    for dtype in (jnp.float32, jnp.int8):
+        pool = BlockPool(tiny_config("llama"), 8, 8, dtype=dtype)
+        assert _pool_is_row_major(pool.pages)

@@ -121,6 +121,97 @@ def test_mixed_int8_pool_parity(tiny):
     _assert_offline_parity(mixed, cfg, params, jnp.int8)
 
 
+def _force_pool_form(monkeypatch, carried):
+    """Build engines as if the device reported the pool row-major
+    (carried through the layer scan) or not (per-layer slabs)."""
+    from llm_np_cp_tpu.serve import engine as engine_mod
+
+    monkeypatch.setattr(
+        engine_mod, "_pool_is_row_major", lambda pages: carried)
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.int8],
+                         ids=["f32", "int8"])
+@pytest.mark.parametrize("carried", [True, False], ids=["carried", "slabs"])
+def test_mixed_tick_writes_each_layer_at_its_own_blocks_only(
+        monkeypatch, carried, cache_dtype):
+    """Two layers' writes must not meet: after one tick the pool differs
+    from the pool before it ONLY at ``[l, tok_blk, tok_off]``, in every
+    layer ``l`` — a wrong ``layer * NB`` offset into the flat carried
+    pool that token parity on a tiny model could miss through block 0."""
+    from llm_np_cp_tpu.serve.block_pool import PagedKV
+
+    _force_pool_form(monkeypatch, carried)
+    cfg = tiny_config("llama", num_hidden_layers=2)
+    params = init_params(jax.random.PRNGKey(3), cfg, dtype=jnp.float32)
+    engine = _engine(cfg, params, mixed="on", max_slots=3, num_blocks=12,
+                     cache_dtype=cache_dtype)
+    rng = np.random.default_rng(5)
+
+    def noise(a):  # nothing a tick would write there
+        if a.dtype == jnp.int8:
+            return rng.integers(-100, 100, a.shape)
+        return rng.normal(size=a.shape) + 7.0
+
+    engine.pool.pages = PagedKV(*(
+        None if a is None else jnp.asarray(noise(a), dtype=a.dtype)
+        for a in engine.pool.pages))
+    lanes = []
+    step = engine._mixed_step
+
+    def spy(params_, pages, *ops):
+        lanes.append(tuple(np.asarray(o) for o in (ops[2], ops[3], ops[6])))
+        return step(params_, pages, *ops)
+
+    engine._mixed_step = spy
+    for j, n in enumerate((13, 5)):
+        engine.submit(rng.integers(1, cfg.vocab_size, size=n), 4, seed=j)
+    n_blocks = engine.pool.pages.num_blocks
+    for _ in range(4):  # prefill chunks, then decode rows beside them
+        before = [None if a is None else np.asarray(a)
+                  for a in engine.pool.pages]
+        engine.step()
+        tok_blk, tok_off, tok_live = lanes.pop()
+        assert not lanes and tok_live.any()
+        # dead lanes write (block 0, slot 0) of every layer
+        may = np.zeros((n_blocks, engine.block_size), bool)
+        may[tok_blk, tok_off] = True
+        must = np.zeros_like(may)
+        must[tok_blk[tok_live], tok_off[tok_live]] = True
+        assert tok_blk[tok_live].min() > 0  # block 0 is the scratch block
+        for old, new in zip(before, engine.pool.pages):
+            if old is None:
+                continue
+            changed = (np.asarray(new) != old).reshape(
+                old.shape[:3] + (-1,)).any(axis=-1)  # [L, NB, BS]
+            for layer in range(cfg.num_hidden_layers):
+                assert not (changed[layer] & ~may).any(), layer
+                assert (changed[layer] | ~must).all(), layer
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.int8],
+                         ids=["f32", "int8"])
+def test_mixed_slab_form_parity(tiny, monkeypatch, cache_dtype):
+    """Where the device does not keep the pool row-major the step hands
+    each layer its slab instead of carrying the pool: same tokens."""
+    cfg, params = tiny
+    rng = np.random.default_rng(17)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (12, 3, 19)]
+
+    def run(carried):
+        _force_pool_form(monkeypatch, carried)
+        engine = _engine(cfg, params, mixed="on", max_slots=3,
+                         cache_dtype=cache_dtype)
+        for j, p in enumerate(prompts):
+            engine.submit(p, 6, seed=j)
+        engine.run_until_complete()
+        return engine
+
+    slabs = run(False)
+    assert _tokens(slabs) == _tokens(run(True))
+    _assert_offline_parity(slabs, cfg, params, cache_dtype)
+
+
 def test_mixed_gemma2_sliding_window_parity():
     """Gemma-2's alternating sliding layers reach the ragged kernel as a
     traced per-layer window bound — long decodes crossing the window and

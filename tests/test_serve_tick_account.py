@@ -229,14 +229,19 @@ def test_op_map_names_every_scope_and_marks_the_pool(tiny, cache_dtype):
     known = [v for v in op_map.values() if v is not None]
     assert all(len(v) == 2 for v in known)
     assert {scope for scope, _ in known} == set(STEP_SCOPES) | {""}
-    assert {kind for _, kind in known} == {"pool", "slab", ""}
+    # the layer loop carries the pool and writes it in place: nothing the
+    # step computes has the shape of one layer's slab
+    assert {kind for _, kind in known} == {"pool", ""}
     k = engine.pool.pages.k
     whole = opmap.hlo_shape(k.dtype.name, k.shape)
-    slab = opmap.hlo_shape(k.dtype.name, k.shape[1:])
+    flat = opmap.hlo_shape(
+        k.dtype.name, (k.shape[0] * k.shape[1],) + k.shape[2:])
+    writes = [key for key, val in op_map.items()
+              if val is not None and val[0] == "kv_write" and val[1]]
+    assert writes and all(key.split(" ", 1)[1] != whole for key in writes)
     for key, val in op_map.items():
-        shape = key.split(" ", 1)[1]
-        if val is not None and shape in (whole, slab):
-            assert val[1] == ("pool" if shape == whole else "slab")
+        if val is not None and key.split(" ", 1)[1] in (whole, flat):
+            assert val[1] == "pool"
     # the map is read from the warm step itself: no second jit of it,
     # no executable more in the step's cache
     assert engine._mixed_step._cache_size() == warm == len(engine.mixed_buckets)
@@ -281,6 +286,16 @@ ENTRY %main.10 (pages: bf16[3,6,2,8]) -> f32[4] {
 }
 """
 POOL = opmap.pool_shapes([("bfloat16", (3, 6, 2, 8))])
+
+
+def test_pool_shapes_know_the_flat_pool():
+    """The unified step carries the pool flat over (layer, block); a
+    one-layer pool's flat shape is its slab's, and counts as the pool."""
+    assert POOL == {"bf16[3,6,2,8]": "pool", "bf16[18,2,8]": "pool",
+                    "bf16[6,2,8]": "slab", "bf16[1,6,2,8]": "slab"}
+    one = opmap.pool_shapes(iter([("int8", (1, 6, 2, 8)), ("float32", (1, 6, 2))]))
+    assert one["s8[6,2,8]"] == one["f32[6,2]"] == "pool"
+    assert one["s8[1,6,2,8]"] == "pool"
 PARSED = opmap.op_map_from_hlo(HLO, STEP_SCOPES, POOL)
 
 
