@@ -95,10 +95,11 @@ static [slots, K+1] extension of the step, so zero-recompiles survives.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import math
 import time
 from functools import partial
-from typing import Any, Callable
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -198,6 +199,73 @@ def _pack_sync(
         [samples, stop_mask[:, None], advance[:, None],
          accept[:, None]], axis=1,
     )
+
+
+def mixed_operand_layout(
+    t_w: int, q_tile: int, max_slots: int, max_blocks: int, spec_w: int,
+) -> tuple[dict[str, tuple[int, tuple[int, ...]]], int]:
+    """The unified step's ONE host-built operand, stated once: an int32
+    vector whose sections are the packed batch of width ``t_w`` —
+    ``{section: (offset, shape)}`` and the vector's length.  ``[T]``
+    token-level sections, ``[T/q_tile]`` tile metadata for the ragged
+    kernel, ``[max_slots, ..]`` row-level sections.  ``_pack_mixed``
+    writes through it, the jitted step slices by it
+    (``split_mixed_operands``), so host and device cannot drift.  Two
+    sections are not int32 values: ``tok_live`` is a bool written 0 / 1,
+    ``seeds`` the bits of a uint32.  The length grows with ``t_w``, so a
+    bucket is still one aval and one compile."""
+    nt = t_w // q_tile
+    shapes = {
+        "tokens": (t_w,),      # packed input ids
+        "positions": (t_w,),   # content positions (RoPE)
+        "tok_blk": (t_w,),     # pool block per token
+        "tok_off": (t_w,),     # in-block slot per token
+        "tok_row": (t_w,),     # owning engine row
+        "tok_slot": (t_w,),    # cache slot per token
+        "tok_live": (t_w,),    # 0 = packing lane
+        "tile_row": (nt,), "tile_qpos0": (nt,), "tile_qlen": (nt,),
+        "tables": (max_slots, max_blocks),  # scratch-0 padded
+        "pads": (max_slots,),
+        "last_idx": (max_slots, spec_w),    # packed sample indices
+        "sample_pos": (max_slots, spec_w),  # content position of each
+        "seeds": (max_slots,),
+        "verify_len": (max_slots,),         # live sample slots per row
+    }
+    layout, size = {}, 0
+    for name, shape in shapes.items():
+        layout[name] = (size, shape)
+        size += math.prod(shape)
+    return layout, size
+
+
+def split_mixed_operands(ops: Any, layout: dict) -> dict[str, Any]:
+    """The sections of a packed operand by name.  Of a host array they
+    are writable views (``seeds`` seen as the uint32 it carries); of a
+    traced one static slices, with ``tok_live`` back as a bool and
+    ``seeds`` as uint32 — the values the 16 separate operands had."""
+    sec = {
+        name: ops[off:off + math.prod(shape)].reshape(shape)
+        for name, (off, shape) in layout.items()
+    }
+    if isinstance(ops, np.ndarray):
+        sec["seeds"] = sec["seeds"].view(np.uint32)
+    else:
+        sec["seeds"] = lax.bitcast_convert_type(sec["seeds"], jnp.uint32)
+        sec["tok_live"] = sec["tok_live"] != 0
+    return sec
+
+
+def mixed_operand_width(size: int, q_tile: int, *geometry: int) -> int:
+    """The packed width whose operand is ``size`` words long: what the
+    jitted step, told nothing but its operand's aval, lays it out by."""
+    size0 = mixed_operand_layout(0, q_tile, *geometry)[1]
+    per_tile = mixed_operand_layout(q_tile, q_tile, *geometry)[1] - size0
+    t_w = (size - size0) // per_tile * q_tile
+    if mixed_operand_layout(t_w, q_tile, *geometry)[1] != size:
+        raise ValueError(
+            f"{size} words are no packed operand of q_tile {q_tile}, "
+            f"(max_slots, max_blocks, spec_w) {geometry}")
+    return t_w
 
 
 def _roofline_targs(tel: dict) -> dict:
@@ -740,6 +808,12 @@ class ServeEngine:
             # the rest are discarded host-side, so the shape is static
             # whatever each tick's draft widths turn out to be
             self._spec_w = self.spec_k + 1
+            # what lays the step's packed operand out beside its width
+            # (mixed_operand_layout)
+            self._mixed_geometry = (
+                self._q_tile, max_slots, self.max_blocks_per_seq,
+                self._spec_w,
+            )
             # spec engines get verify headroom in the default budget:
             # drafts only ever spend budget prefill left over, so
             # without the extra room a busy admission window would trim
@@ -1684,10 +1758,11 @@ class ServeEngine:
         (seed, content position) key derivation as both split-path
         samplers, so tokens are impl- and preemption-invariant).
 
-        Shapes are static per packed-width bucket: [T] token-level
-        operands, [T/q_tile] tile metadata for the ragged kernel,
-        [max_slots] row-level operands.  One compile per bucket, zero
-        per tick (tools/compile_counter lint)."""
+        The host hands the step ONE operand, an int32 vector whose
+        static slices are the packed batch (``mixed_operand_layout``):
+        one transfer a tick, and its length is the bucket's.  One
+        compile per bucket, zero per tick (tools/compile_counter
+        lint)."""
         from llm_np_cp_tpu.ops.pallas.decode_attention import (
             ragged_paged_attention,
             ragged_paged_attention_xla,
@@ -1703,6 +1778,7 @@ class ServeEngine:
         big_win = jnp.int32(1 << 30)
         constrain_pages = self._constrain_pages
         carry_pool = self.pool_carried = _pool_is_row_major(self.pool.pages)
+        geometry = self._mixed_geometry
         attn_call = self._shard_attn(
             partial(
                 ragged_paged_attention if use_kernel
@@ -1717,27 +1793,22 @@ class ServeEngine:
         def mixed_step(
             params: Params,
             pages: PagedKV,
-            tokens: jnp.ndarray,      # [T] int32 packed input ids
-            positions: jnp.ndarray,   # [T] int32 content positions (RoPE)
-            tok_blk: jnp.ndarray,     # [T] int32 pool block per token
-            tok_off: jnp.ndarray,     # [T] int32 in-block slot per token
-            tok_row: jnp.ndarray,     # [T] int32 owning engine row
-            tok_slot: jnp.ndarray,    # [T] int32 cache slot per token
-            tok_live: jnp.ndarray,    # [T] bool (False = packing lane)
-            tile_row: jnp.ndarray,    # [T/QB] int32
-            tile_qpos0: jnp.ndarray,  # [T/QB] int32
-            tile_qlen: jnp.ndarray,   # [T/QB] int32
-            tables: jnp.ndarray,      # [R, MB] int32 (scratch-0 padded)
-            pads: jnp.ndarray,        # [R] int32
-            last_idx: jnp.ndarray,    # [R, W] int32 packed sample indices
-            sample_pos: jnp.ndarray,  # [R, W] int32 content pos of each
-            seeds: jnp.ndarray,       # [R] uint32
-            verify_len: jnp.ndarray,  # [R] int32 live sample slots per row
+            ops: jnp.ndarray,  # the packed operand (mixed_operand_layout)
         ):
             with jax.named_scope(SCOPE_EMBED):
+                o = split_mixed_operands(ops, mixed_operand_layout(
+                    mixed_operand_width(ops.shape[0], *geometry), *geometry
+                )[0])
+                tokens, tables, pads = o["tokens"], o["tables"], o["pads"]
+                tok_blk, tok_off = o["tok_blk"], o["tok_off"]
+                tok_row, tok_slot = o["tok_row"], o["tok_slot"]
+                tok_live, seeds = o["tok_live"], o["seeds"]
+                tile_row, tile_qpos0 = o["tile_row"], o["tile_qpos0"]
+                tile_qlen, verify_len = o["tile_qlen"], o["verify_len"]
+                last_idx, sample_pos = o["last_idx"], o["sample_pos"]
                 x = embed_inputs(params, tokens[None, :], config)  # [1, T, H]
                 cos, sin = rope_cos_sin(
-                    positions[None, :], config, dtype=jnp.float32
+                    o["positions"][None, :], config, dtype=jnp.float32
                 )
             act = ACT2FN[config.hidden_act]
             is_sliding = jnp.array(
@@ -2887,96 +2958,127 @@ class ServeEngine:
         self,
         decode_rows: list[Request],
         prefill_segs: list[tuple[Request, int]],
-    ) -> tuple:
-        """Build the mixed step's packed operands (host arrays; the
-        tick places them with ``_put``) from the planner's
-        verdict.  Each row's token segment lands at consecutive,
-        q-tile-aligned packed positions (dead alignment lanes point at
-        the scratch block and are masked); the packed width is the
-        smallest bucket covering the aligned total, so the dispatch
-        reuses a warm compile whatever the prefill:decode mix."""
+    ) -> tuple[np.ndarray, int, int]:
+        """Build the mixed step's packed operand from the planner's
+        verdict: ONE host int32 array (``mixed_operand_layout``; the
+        tick places it with one ``_put``), its packed width, and how
+        many rows the array path filled.  Each row's token segment
+        lands at consecutive, q-tile-aligned packed positions, decode
+        rows first (dead alignment lanes point at the scratch block and
+        are masked); the packed width is the smallest bucket covering
+        the aligned total, so the dispatch reuses a warm compile
+        whatever the prefill:decode mix.
+
+        A decode row with no drafts is one token in one tile: all of
+        them are written together (``_fill_decode_rows``).  Speculating
+        rows and prefill chunks, a few a tick, go segment by segment
+        (``_fill_segment``)."""
         qb = self._q_tile
-        b = self.scheduler.max_slots
-        mb = self.max_blocks_per_seq
-        bs = self.block_size
-        w_v = self._spec_w
-        # segment = (request, tokens, first cache slot, n_verify):
-        # n_verify sample slots cover the segment's LAST n_verify tokens
-        # — a plain decode row or completing prefill samples 1 (its last
-        # token), a speculating row samples its whole verify slice
-        # (input + drafts), a mid-prefill chunk samples 0
-        segs: list[tuple[Request, np.ndarray, int, int]] = []
-        for r in decode_rows:
+        sizes = [1 + r.draft_len for r in decode_rows]
+        sizes.extend(n for _, n in prefill_segs)
+        starts = list(itertools.accumulate(
+            (_ceil_to(n, qb) for n in sizes), initial=0))
+        t_w = self._pick_bucket(max(starts[-1], qb))
+        layout, size = self._mixed_layout(t_w)
+        ops = np.zeros(size, np.int32)
+        sec = split_mixed_operands(ops, layout)
+        plain = [(r, cur) for r, cur in zip(decode_rows, starts)
+                 if not r.draft_len]
+        if plain:
+            self._fill_decode_rows(sec, *zip(*plain))
+        for r, cur in zip(decode_rows, starts):
+            if not r.draft_len:
+                continue
             toks = [r.generated[-1]]
-            if r.draft_len:
-                draft = r.extra["spec_draft"]
-                toks.extend(int(t) for t in draft[: r.draft_len])
-            segs.append((
-                r, np.asarray(toks, np.int32),
-                r.cache_len - 1, len(toks),
-            ))
-        for r, n in prefill_segs:
+            toks.extend(int(t) for t in r.extra["spec_draft"][: r.draft_len])
+            self._fill_segment(sec, r, np.asarray(toks, np.int32),
+                               r.cache_len - 1, len(toks), cur)
+        for (r, n), cur in zip(prefill_segs, starts[len(decode_rows):]):
             content = r.extra["prefill_content"]
-            toks = np.asarray(
-                content[r.prefill_done:r.prefill_done + n], np.int32
-            )
-            segs.append((
-                r, toks, r.pad + r.prefill_done,
-                1 if r.prefill_done + n >= r.prefill_target else 0,
-            ))
-        aligned = sum(_ceil_to(t.size, qb) for _, t, _, _ in segs)
-        t_w = self._pick_bucket(max(aligned, qb))
-        nt = t_w // qb
-        tokens = np.zeros(t_w, np.int32)
-        positions = np.zeros(t_w, np.int32)
-        tok_blk = np.zeros(t_w, np.int32)
-        tok_off = np.zeros(t_w, np.int32)
-        tok_row = np.zeros(t_w, np.int32)
-        tok_slot = np.zeros(t_w, np.int32)
-        tok_live = np.zeros(t_w, bool)
-        tile_row = np.zeros(nt, np.int32)
-        tile_qpos0 = np.zeros(nt, np.int32)
-        tile_qlen = np.zeros(nt, np.int32)
-        tables = np.zeros((b, mb), np.int32)
-        pads = np.zeros(b, np.int32)
-        last_idx = np.zeros((b, w_v), np.int32)
-        sample_pos = np.zeros((b, w_v), np.int32)
-        seeds = np.zeros(b, np.uint32)
-        verify_len = np.zeros(b, np.int32)
-        cur = 0
-        for r, toks, start_slot, n_verify in segs:
-            n = toks.size
-            slot = r.slot
-            tables[slot, :len(r.block_ids)] = r.block_ids
-            pads[slot] = r.pad
-            seeds[slot] = np.uint32(r.seed)
-            sl = start_slot + np.arange(n, dtype=np.int32)
-            tokens[cur:cur + n] = toks
-            positions[cur:cur + n] = sl - r.pad
-            blocks = np.asarray(r.block_ids, np.int32)
-            tok_blk[cur:cur + n] = blocks[sl // bs]
-            tok_off[cur:cur + n] = sl % bs
-            tok_row[cur:cur + n] = slot
-            tok_slot[cur:cur + n] = sl
-            tok_live[cur:cur + n] = True
-            n_tiles = -(-n // qb)
-            ti0 = cur // qb
-            for k in range(n_tiles):
-                tile_row[ti0 + k] = slot
-                tile_qpos0[ti0 + k] = start_slot + k * qb
-                tile_qlen[ti0 + k] = min(qb, n - k * qb)
-            if n_verify:
-                first = n - n_verify  # verify slots = the last n_verify
-                verify_len[slot] = n_verify
-                for j in range(n_verify):
-                    last_idx[slot, j] = cur + first + j
-                    sample_pos[slot, j] = start_slot + first + j - r.pad
-            cur += n_tiles * qb
-        return (
-            tokens, positions, tok_blk, tok_off, tok_row, tok_slot,
-            tok_live, tile_row, tile_qpos0, tile_qlen, tables, pads,
-            last_idx, sample_pos, seeds, verify_len,
-        )
+            self._fill_segment(
+                sec, r,
+                np.asarray(content[r.prefill_done:r.prefill_done + n],
+                           np.int32),
+                r.pad + r.prefill_done,
+                1 if r.prefill_done + n >= r.prefill_target else 0, cur)
+        return ops, t_w, len(plain)
+
+    def _fill_decode_rows(self, sec: dict[str, np.ndarray],
+                          rows: Sequence[Request],
+                          lanes: Sequence[int]) -> None:
+        """Plain decode rows — one token, one tile, one sample slot each
+        — written into the operand's sections by whole-array
+        assignments: what ``_fill_segment`` writes for each, without a
+        numpy call per row and field.  The block table is the one write
+        left per row."""
+        bs = self.block_size
+        tables = sec["tables"]
+        slot, tok, sl, pad, seed, blk = [], [], [], [], [], []
+        for r in rows:
+            ids = r.block_ids
+            last = r.cache_len - 1
+            tables[r.slot, :len(ids)] = ids
+            slot.append(r.slot)
+            tok.append(r.generated[-1])
+            sl.append(last)
+            pad.append(r.pad)
+            seed.append(r.seed)
+            blk.append(ids[last // bs])
+        slot = np.asarray(slot, np.intp)
+        sl = np.asarray(sl, np.int32)
+        pos = sl - np.asarray(pad, np.int32)
+        lane = np.asarray(lanes, np.intp)
+        tile = lane // self._q_tile
+        sec["tokens"][lane] = tok
+        sec["positions"][lane] = pos
+        sec["tok_blk"][lane] = blk
+        sec["tok_off"][lane] = sl % bs
+        sec["tok_row"][lane] = slot
+        sec["tok_slot"][lane] = sl
+        sec["tok_live"][lane] = 1
+        sec["tile_row"][tile] = slot
+        sec["tile_qpos0"][tile] = sl
+        sec["tile_qlen"][tile] = 1
+        sec["pads"][slot] = pad
+        sec["seeds"][slot] = np.asarray(seed, np.uint32)
+        sec["verify_len"][slot] = 1
+        sec["last_idx"][slot, 0] = lane
+        sec["sample_pos"][slot, 0] = pos
+
+    def _fill_segment(self, sec: dict[str, np.ndarray], r: Request,
+                      toks: np.ndarray, start_slot: int, n_verify: int,
+                      cur: int) -> None:
+        """One row's token segment at packed position ``cur`` (a q-tile
+        multiple): its tokens occupy cache slots ``start_slot..`` and
+        its LAST ``n_verify`` tokens are sampled — a speculating row
+        samples its whole verify slice (input + drafts), a completing
+        prefill 1 (its last token), a mid-prefill chunk 0."""
+        qb, bs = self._q_tile, self.block_size
+        n = toks.size
+        slot = r.slot
+        blocks = np.asarray(r.block_ids, np.int32)
+        sec["tables"][slot, :blocks.size] = blocks
+        sec["pads"][slot] = r.pad
+        sec["seeds"][slot] = np.uint32(r.seed)
+        sl = start_slot + np.arange(n, dtype=np.int32)
+        sec["tokens"][cur:cur + n] = toks
+        sec["positions"][cur:cur + n] = sl - r.pad
+        sec["tok_blk"][cur:cur + n] = blocks[sl // bs]
+        sec["tok_off"][cur:cur + n] = sl % bs
+        sec["tok_row"][cur:cur + n] = slot
+        sec["tok_slot"][cur:cur + n] = sl
+        sec["tok_live"][cur:cur + n] = 1
+        q0 = np.arange(0, n, qb)  # each tile's first token
+        tiles = slice(cur // qb, cur // qb + q0.size)
+        sec["tile_row"][tiles] = slot
+        sec["tile_qpos0"][tiles] = start_slot + q0
+        sec["tile_qlen"][tiles] = np.minimum(qb, n - q0)
+        if n_verify:
+            first = n - n_verify  # verify slots = the last n_verify
+            sec["verify_len"][slot] = n_verify
+            sec["last_idx"][slot, :n_verify] = (
+                cur + first + np.arange(n_verify))
+            sec["sample_pos"][slot, :n_verify] = sl[first:] - r.pad
 
     def _finish_mixed_prefill(self, req: Request, tok: int) -> None:
         """A row's prefill reached its target this tick: register its
@@ -3137,7 +3239,7 @@ class ServeEngine:
 
         tp = th = t4 = t5 = t3
         cpu4 = cpu5 = 0
-        ctx_tokens = packed_width = h2d_count = h2d_bytes = 0
+        ctx_tokens = packed_width = array_rows = h2d_count = h2d_bytes = 0
         n_prefill_tok = sum(n for _, n in prefill_segs)
         n_decode_tok = len(decode_rows)
         # drafts actually packed (post-trim) / accepted by the verifier
@@ -3153,7 +3255,8 @@ class ServeEngine:
                 cost = self.telemetry.mixed_tick_cost(
                     self, decode_rows, prefill_segs
                 )
-            host_args = self._pack_mixed(decode_rows, prefill_segs)
+            host_ops, packed_width, array_rows = self._pack_mixed(
+                decode_rows, prefill_segs)
             if self.tracer is not None:
                 # what this dispatch attends: every row's live content
                 # after its tokens land (left pad excluded), read HERE —
@@ -3161,12 +3264,11 @@ class ServeEngine:
                 ctx_tokens = sum(
                     r.cache_len - r.pad + r.draft_len for r in decode_rows
                 ) + sum(r.prefill_done + n for r, n in prefill_segs)
-                packed_width = int(host_args[0].size)
-                h2d_count = len(host_args)
-                h2d_bytes = sum(a.nbytes for a in host_args)
+                h2d_count = 1
+                h2d_bytes = host_ops.nbytes
             tp = (self._phase_mark("serve.h2d")
                   if self.tracer is not None else -1.0)
-            args = tuple(self._put(a) for a in host_args)
+            ops = self._put(host_ops)
             # closes serve.h2d and opens nothing: the dispatch's own
             # annotation below is the one the harness aligns clocks on,
             # and it wraps the jitted call alone
@@ -3176,7 +3278,7 @@ class ServeEngine:
             with (jax.profiler.TraceAnnotation("serve.mixed_dispatch")
                   if self.tracer is not None else _NULL_CTX):
                 out, self.pool.pages = self._dispatch_mixed(
-                    args, bool(prefill_segs)
+                    ops, bool(prefill_segs)
                 )
             t4 = (self._phase_mark("serve.host_sync")
                   if self.tracer is not None else -1.0)
@@ -3306,6 +3408,9 @@ class ServeEngine:
                 # its rows attend (summed over rows), the bucket
                 "context_tokens": ctx_tokens,
                 "packed_width": packed_width,
+                # rows _pack_mixed wrote by whole-array assignments
+                # (plain decode rows): how much of pack went the fast way
+                "pack_array_rows": array_rows,
                 # the tick-tail observables: host_sync wall (µs) and the
                 # number of device→host transfers this tick — the
                 # one-fetch contract says the latter is exactly 1 on
@@ -3361,7 +3466,7 @@ class ServeEngine:
         self._actions_tick(outliers)
         return self.scheduler.has_work
 
-    def _dispatch_mixed(self, args: tuple, has_prefill: bool) -> tuple:
+    def _dispatch_mixed(self, ops: jnp.ndarray, has_prefill: bool) -> tuple:
         """One mixed dispatch with the split path's runtime-degradation
         contract: a ragged-kernel dispatch fault permanently falls back
         to the XLA ragged attention for the process and retries the same
@@ -3382,7 +3487,7 @@ class ServeEngine:
                 raise FaultInjected("decode")
         self.n_dispatches += 1
         try:
-            return self._mixed_step(self.params, self.pool.pages, *args)
+            return self._mixed_step(self.params, self.pool.pages, ops)
         except Exception as e:  # noqa: BLE001 — any dispatch fault gates
             if not self._degrade_mixed(f"{type(e).__name__}: {e}"):
                 raise
@@ -3392,7 +3497,7 @@ class ServeEngine:
             # chaos retry never sees consumed pages; a real post-donation
             # fault raises on the deleted buffers here and the supervisor
             # restart (which rebuilds the pool) takes over
-            return self._mixed_step(self.params, self.pool.pages, *args)
+            return self._mixed_step(self.params, self.pool.pages, ops)
 
     def _degrade_mixed(self, reason: str) -> bool:
         """Pallas → XLA fallback for the unified tick, process-wide
@@ -3449,32 +3554,23 @@ class ServeEngine:
         return int(mixed_tick_kv_read(self, decode_rows, prefill_segs,
                                       per_request=False)[0])
 
-    def _dead_mixed_operands(self, t_w: int) -> tuple:
-        """The mixed step's operands for an all-dead batch of packed
-        width ``t_w`` (host arrays): every lane points at the scratch
+    def _mixed_layout(self, t_w: int) -> tuple[dict, int]:
+        """``mixed_operand_layout`` of this engine's step at packed
+        width ``t_w``."""
+        return mixed_operand_layout(t_w, *self._mixed_geometry)
+
+    def _dead_mixed_operands(self, t_w: int) -> np.ndarray:
+        """The mixed step's operand for an all-dead batch of packed
+        width ``t_w`` (a host array): every lane points at the scratch
         block and is fully masked."""
-        qb = self._q_tile
-        b = self.scheduler.max_slots
-        mb = self.max_blocks_per_seq
-        return (
-            np.zeros(t_w, np.int32), np.zeros(t_w, np.int32),
-            np.zeros(t_w, np.int32), np.zeros(t_w, np.int32),
-            np.zeros(t_w, np.int32), np.zeros(t_w, np.int32),
-            np.zeros(t_w, bool),
-            np.zeros(t_w // qb, np.int32), np.zeros(t_w // qb, np.int32),
-            np.zeros(t_w // qb, np.int32),
-            np.zeros((b, mb), np.int32), np.zeros(b, np.int32),
-            np.zeros((b, self._spec_w), np.int32),
-            np.zeros((b, self._spec_w), np.int32),
-            np.zeros(b, np.uint32), np.zeros(b, np.int32),
-        )
+        return np.zeros(self._mixed_layout(t_w)[1], np.int32)
 
     def _warm_mixed_bucket(self, t_w: int) -> None:
         """Compile one packed-width bucket with an all-dead batch, so the
         only effect is the compile (and a garbage write to scratch)."""
         out, self.pool.pages = self._mixed_step(
             self.params, self.pool.pages,
-            *(self._put(a) for a in self._dead_mixed_operands(t_w)),
+            self._put(self._dead_mixed_operands(t_w)),
         )
         np.asarray(out)  # block until the compile lands
 
@@ -3505,7 +3601,7 @@ class ServeEngine:
             opmap.op_map_from_hlo(
                 self._mixed_step.lower(
                     self.params, self.pool.pages,
-                    *(self._put(a) for a in self._dead_mixed_operands(t_w)),
+                    self._put(self._dead_mixed_operands(t_w)),
                 ).compile().as_text(), STEP_SCOPES, pool)
             for t_w in self.mixed_buckets
         )

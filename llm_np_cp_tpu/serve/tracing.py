@@ -93,8 +93,8 @@ TICK_PHASES = (
 # spec_accept_tokens on spec-enabled engines — for
 # tools/summarize_trace.py's utilization line.
 # The host's share of the dispatch is cut where the work changes kind:
-# ``pack`` builds the numpy operands, ``h2d`` places them (its slice
-# carries the count and bytes of the transfers), ``mixed_dispatch`` is
+# ``pack`` builds the one numpy operand, ``h2d`` places it (its slice
+# carries the count and bytes of the transfer), ``mixed_dispatch`` is
 # the jitted call alone (what the ``serve.mixed_dispatch`` annotation
 # wraps), ``deliver`` the emit / accept walks with their callbacks,
 # ``account`` the journal watermark and the metrics of the tick.

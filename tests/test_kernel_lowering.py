@@ -20,6 +20,7 @@ against their XLA twins.
 """
 
 import functools
+import re
 import types
 
 import jax
@@ -239,8 +240,7 @@ def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS):
 
     avals = [jax.tree.map(aval, abstract),
              jax.tree.map(aval, engine.pool.pages)]
-    avals += [aval(a) for a in
-              engine._dead_mixed_operands(engine.mixed_buckets[-1])]
+    avals.append(aval(engine._dead_mixed_operands(engine.mixed_buckets[-1])))
     # the kernels pick interpret mode from the backend they see: show
     # them the one they are being compiled for
     real = jax.default_backend
@@ -270,6 +270,14 @@ def test_compiled_tick_writes_the_pool_in_place(v5e_sharding, cache_dtype):
     assert temp < k_slab_bytes, (
         f"the tick holds {temp} B of temporaries, one layer's K slab is "
         f"{k_slab_bytes} B: a copy of (part of) the pool is back")
+
+    # besides params and pool the program takes ONE operand: the packed
+    # int32 vector of the widest bucket (one transfer a tick)
+    text = compiled.as_text()
+    ints = re.findall(r"= ((?:[su]\d+|pred)\[[\d,]*\])\S* parameter\(",
+                      text[text.index("\nENTRY"):])
+    assert ints == [opmap.hlo_shape("int32", (
+        engine._mixed_layout(engine.mixed_buckets[-1])[1],))]
 
     ops = _pool_ops(engine, compiled)
     assert {scope for scope, _, _ in ops.values()} >= set(STEP_SCOPES)
@@ -308,8 +316,12 @@ def test_int8_pool_on_a_v5e_is_why_the_slab_form_stays(
         engine, compiled = _compile_widest_bucket(
             v5e_sharding, jnp.int8, blocks=512)
         temps[carried] = compiled.memory_analysis().temp_size_in_bytes
-        kinds = {v[2] for v in _pool_ops(engine, compiled).values()}
-        assert ("slab" in kinds) == (not carried)
+        # which form this is: what the K/V write writes into (how the
+        # compiler cuts the carried form's whole-pool relayout is its own
+        # business: in three slab-sized slices, with one packed operand)
+        written = {v[2] for v in _pool_ops(engine, compiled).values()
+                   if v[0] == SCOPE_KV_WRITE and v[2]}
+        assert written == {"pool" if carried else "slab"}
     pool_bytes = sum(a.nbytes for a in engine.pool.pages)
     assert temps[False] < pool_bytes < temps[True], temps
 

@@ -486,24 +486,9 @@ def _iter_param_eqns(v, *, skip_pallas):
 
 
 def _mixed_step_shapes(engine, t_w, *, skip_pallas):
-    qb = engine._q_tile
-    b = engine.scheduler.max_slots
-    mb = engine.max_blocks_per_seq
-    w = engine._spec_w
-    args = (
-        jnp.zeros(t_w, jnp.int32), jnp.zeros(t_w, jnp.int32),
-        jnp.zeros(t_w, jnp.int32), jnp.zeros(t_w, jnp.int32),
-        jnp.zeros(t_w, jnp.int32), jnp.zeros(t_w, jnp.int32),
-        jnp.zeros(t_w, bool),
-        jnp.zeros(t_w // qb, jnp.int32), jnp.zeros(t_w // qb, jnp.int32),
-        jnp.zeros(t_w // qb, jnp.int32),
-        jnp.zeros((b, mb), jnp.int32), jnp.zeros(b, jnp.int32),
-        jnp.zeros((b, w), jnp.int32), jnp.zeros((b, w), jnp.int32),
-        jnp.zeros(b, jnp.uint32), jnp.zeros(b, jnp.int32),
-    )
-    jaxpr = jax.make_jaxpr(lambda *a: engine._mixed_step(
-        engine.params, engine.pool.pages, *a
-    ))(*args)
+    jaxpr = jax.make_jaxpr(lambda ops: engine._mixed_step(
+        engine.params, engine.pool.pages, ops
+    ))(jnp.asarray(engine._dead_mixed_operands(t_w)))
     return {
         tuple(v.aval.shape)
         for eqn in _iter_eqns(jaxpr.jaxpr, skip_pallas=skip_pallas)

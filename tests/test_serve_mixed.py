@@ -28,6 +28,11 @@ from llm_np_cp_tpu.generate import Generator
 from llm_np_cp_tpu.models.transformer import init_params
 from llm_np_cp_tpu.ops.sampling import Sampler
 from llm_np_cp_tpu.serve import ServeEngine, poisson_trace
+from llm_np_cp_tpu.serve.engine import (
+    mixed_operand_layout,
+    mixed_operand_width,
+    split_mixed_operands,
+)
 from tools.compile_counter import (
     CompileCounter,
     assert_serve_compiles_bounded,
@@ -49,6 +54,12 @@ def _engine(cfg, params, mixed="auto", **kw):
     kw.setdefault("cache_dtype", jnp.float32)
     return ServeEngine(params, cfg, sampler=Sampler(kind="greedy"),
                        mixed_step=mixed, **kw)
+
+
+def _sections(engine, ops):
+    """The sections of a packed operand of any of the engine's widths."""
+    t_w = mixed_operand_width(ops.shape[0], *engine._mixed_geometry)
+    return split_mixed_operands(ops, engine._mixed_layout(t_w)[0])
 
 
 def _tokens(engine):
@@ -159,9 +170,10 @@ def test_mixed_tick_writes_each_layer_at_its_own_blocks_only(
     lanes = []
     step = engine._mixed_step
 
-    def spy(params_, pages, *ops):
-        lanes.append(tuple(np.asarray(o) for o in (ops[2], ops[3], ops[6])))
-        return step(params_, pages, *ops)
+    def spy(params_, pages, ops):
+        sec = _sections(engine, np.asarray(ops))
+        lanes.append((sec["tok_blk"], sec["tok_off"], sec["tok_live"] != 0))
+        return step(params_, pages, ops)
 
     engine._mixed_step = spy
     for j, n in enumerate((13, 5)):
@@ -494,3 +506,207 @@ def test_mixed_rejects_bad_config(tiny):
         _engine(cfg, params, mixed="yes")
     with pytest.raises(ValueError, match="tick_token_budget"):
         _engine(cfg, params, mixed="on", max_slots=4, tick_token_budget=3)
+
+
+# ----------------------------------------------------------------------
+# the step's ONE operand (mixed_operand_layout) and the packer's two ways
+# of filling it
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("spec_w", [1, 4], ids=["w1", "w4"])
+@pytest.mark.parametrize("t_w", [8, 64, 200])
+def test_packed_operand_round_trips(t_w, spec_w):
+    """What the host writes through the layout's views is what the
+    jitted step's slices read, section by section, in the shapes and
+    dtypes the 16 separate operands had — ``tok_live`` a bool, ``seeds``
+    a uint32 above 2**31 included — and the vector's length names the
+    bucket."""
+    geometry = (8, 5, 6, spec_w)  # q_tile, slots, blocks a row, W
+    layout, size = mixed_operand_layout(t_w, *geometry)
+    assert mixed_operand_width(size, *geometry) == t_w
+    with pytest.raises(ValueError, match="no packed operand"):
+        mixed_operand_width(size + 1, *geometry)
+    sizes = [mixed_operand_layout(w, *geometry)[1] for w in (8, 16, 64, 200)]
+    assert sizes == sorted(set(sizes))  # one aval a bucket
+
+    shapes = {"tokens": (t_w,), "tok_live": (t_w,), "tile_qlen": (t_w // 8,),
+              "tables": (5, 6), "pads": (5,), "last_idx": (5, spec_w),
+              "sample_pos": (5, spec_w), "seeds": (5,), "verify_len": (5,)}
+    assert len(layout) == 16
+    assert {k: layout[k][1] for k in shapes} == shapes
+
+    rng = np.random.default_rng(t_w * 10 + spec_w)
+    ops = np.zeros(size, np.int32)
+    host = split_mixed_operands(ops, layout)
+    want = {}
+    for name, view in host.items():
+        assert view.base is not None and view.shape == layout[name][1]
+        if name == "seeds":
+            assert view.dtype == np.uint32
+            want[name] = rng.integers(2**31, 2**32, view.shape, np.uint32)
+            want[name][0] = 2**32 - 1
+        elif name == "tok_live":
+            want[name] = rng.integers(0, 2, view.shape, np.int32)
+        else:
+            want[name] = rng.integers(-2**31, 2**31, view.shape, np.int32)
+        view[...] = want[name]
+    # the sections tile the vector: no word unwritten, none written twice
+    assert sum(v.size for v in host.values()) == size
+    for name, view in host.items():
+        np.testing.assert_array_equal(view, want[name], err_msg=name)
+
+    dev = jax.jit(lambda o: split_mixed_operands(o, layout))(jnp.asarray(ops))
+    assert dev["tok_live"].dtype == jnp.bool_
+    assert dev["seeds"].dtype == jnp.uint32
+    for name, got in dev.items():
+        if name not in ("tok_live", "seeds"):
+            assert got.dtype == jnp.int32, name
+        np.testing.assert_array_equal(
+            np.asarray(got),
+            want[name] != 0 if name == "tok_live" else want[name],
+            err_msg=name)
+
+
+def _parent_pack(engine, decode_rows, prefill_segs):
+    """The 16 operands as the packer built them before they became one
+    vector, row by row and field by field: the reference both of
+    ``_pack_mixed``'s paths are held to, element for element."""
+    qb, bs = engine._q_tile, engine.block_size
+    b, mb, w_v = (engine.scheduler.max_slots, engine.max_blocks_per_seq,
+                  engine._spec_w)
+    segs = []
+    for r in decode_rows:
+        toks = [r.generated[-1]]
+        if r.draft_len:
+            toks.extend(int(t) for t in r.extra["spec_draft"][: r.draft_len])
+        segs.append((r, np.asarray(toks, np.int32), r.cache_len - 1,
+                     len(toks)))
+    for r, n in prefill_segs:
+        content = r.extra["prefill_content"]
+        segs.append((
+            r, np.asarray(content[r.prefill_done:r.prefill_done + n],
+                          np.int32),
+            r.pad + r.prefill_done,
+            1 if r.prefill_done + n >= r.prefill_target else 0))
+    aligned = sum(-(-t.size // qb) * qb for _, t, _, _ in segs)
+    t_w = engine._pick_bucket(max(aligned, qb))
+    o = {k: np.zeros(t_w, np.int32) for k in (
+        "tokens", "positions", "tok_blk", "tok_off", "tok_row", "tok_slot",
+        "tok_live")}
+    o.update({k: np.zeros(t_w // qb, np.int32)
+              for k in ("tile_row", "tile_qpos0", "tile_qlen")})
+    o.update(tables=np.zeros((b, mb), np.int32), pads=np.zeros(b, np.int32),
+             last_idx=np.zeros((b, w_v), np.int32),
+             sample_pos=np.zeros((b, w_v), np.int32),
+             seeds=np.zeros(b, np.uint32), verify_len=np.zeros(b, np.int32))
+    cur = 0
+    for r, toks, start_slot, n_verify in segs:
+        n, slot = toks.size, r.slot
+        o["tables"][slot, :len(r.block_ids)] = r.block_ids
+        o["pads"][slot] = r.pad
+        o["seeds"][slot] = np.uint32(r.seed)
+        sl = start_slot + np.arange(n, dtype=np.int32)
+        o["tokens"][cur:cur + n] = toks
+        o["positions"][cur:cur + n] = sl - r.pad
+        o["tok_blk"][cur:cur + n] = np.asarray(r.block_ids, np.int32)[sl // bs]
+        o["tok_off"][cur:cur + n] = sl % bs
+        o["tok_row"][cur:cur + n] = slot
+        o["tok_slot"][cur:cur + n] = sl
+        o["tok_live"][cur:cur + n] = 1
+        n_tiles = -(-n // qb)
+        for k in range(n_tiles):
+            o["tile_row"][cur // qb + k] = slot
+            o["tile_qpos0"][cur // qb + k] = start_slot + k * qb
+            o["tile_qlen"][cur // qb + k] = min(qb, n - k * qb)
+        for j in range(n_verify):
+            o["verify_len"][slot] = n_verify
+            o["last_idx"][slot, j] = cur + n - n_verify + j
+            o["sample_pos"][slot, j] = start_slot + n - n_verify + j - r.pad
+        cur += n_tiles * qb
+    return o
+
+
+# what a tick must have held for the case to have been packed at all:
+# (plain decode rows, speculating rows, prefill segments, longest prefill
+# segment, decode rows' slots out of order) of one tick, at least
+PACK_CASES = {
+    "decode-only": dict(lens=(5, 3, 7), new=6),
+    "decode+prefill-chunk": dict(lens=(5, 6, 4, 7, 3), new=5, stagger=True),
+    "multi-tile-prefill": dict(lens=(4, 29, 21), new=6, stagger=True,
+                               prefill_chunk=16),
+    "spec-rows": dict(lens=(12, 9, 5), new=10, spec_k=3),
+    "slots-reused-out-of-order": dict(
+        lens=(4, 6, 3, 5, 4, 7, 3, 6), new=(2, 9, 4, 7, 3, 5, 8, 2),
+        max_slots=3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACK_CASES))
+def test_array_path_packs_what_the_segment_path_packs(tiny, case):
+    """Every verdict the planner hands the packer in a run is packed
+    three ways — as the tick does it (plain decode rows by whole-array
+    writes), with every row through the per-segment code, and by the
+    parent's packer — into the identical vector."""
+    cfg, params = tiny
+    kw = dict(PACK_CASES[case])
+    lens, new, stagger = kw.pop("lens"), kw.pop("new"), kw.pop("stagger", 0)
+    engine = _engine(cfg, params, mixed="on", **kw)
+    pack, fill_rows = engine._pack_mixed, engine._fill_decode_rows
+    seen = []
+
+    def by_segment(sec, rows, lanes):
+        for r, cur in zip(rows, lanes):
+            engine._fill_segment(
+                sec, r, np.asarray([r.generated[-1]], np.int32),
+                r.cache_len - 1, 1, cur)
+
+    def checking_pack(decode_rows, prefill_segs):
+        ops, t_w, n_array = pack(decode_rows, prefill_segs)
+        engine._fill_decode_rows = by_segment
+        try:
+            slow, slow_w, _ = pack(decode_rows, prefill_segs)
+        finally:
+            engine._fill_decode_rows = fill_rows
+        assert slow_w == t_w
+        np.testing.assert_array_equal(ops, slow)
+        sec = _sections(engine, ops)
+        parent = _parent_pack(engine, decode_rows, prefill_segs)
+        assert parent["tokens"].size == t_w and set(parent) == set(sec)
+        for name, ref in parent.items():
+            assert sec[name].dtype == ref.dtype, name
+            np.testing.assert_array_equal(sec[name], ref, err_msg=name)
+        plain = [r for r in decode_rows if not r.draft_len]
+        assert n_array == len(plain)
+        slots = [r.slot for r in plain]
+        seen.append(dict(
+            plain=len(plain), spec=len(decode_rows) - len(plain),
+            prefill=len(prefill_segs),
+            longest=max([n for _, n in prefill_segs], default=0),
+            unordered=slots != sorted(slots)))
+        return ops, t_w, n_array
+
+    engine._pack_mixed = checking_pack
+    rng = np.random.default_rng(41)
+    news = new if isinstance(new, tuple) else (new,) * len(lens)
+    for j, (n, m) in enumerate(zip(lens, news)):
+        if kw.get("spec_k"):  # a tiled prompt: drafts get proposed
+            prompt = np.resize(rng.integers(1, cfg.vocab_size, size=3), n)
+        else:
+            prompt = rng.integers(1, cfg.vocab_size, size=n)
+        # seeds on both sides of 2**31: the section carries uint32 bits
+        engine.submit(prompt, m, seed=(2**32 - 1 - j) if j % 2 else j,
+                      speculative=bool(kw.get("spec_k")))
+        if stagger:
+            engine.step()  # later prompts arrive beside decoding rows
+    engine.run_until_complete()
+    assert len(engine.scheduler.finished) == len(lens)
+
+    qb = engine._q_tile
+    want = {
+        "decode-only": lambda t: t["plain"] >= 2 and not t["prefill"],
+        "decode+prefill-chunk": lambda t: t["plain"] and t["prefill"],
+        "multi-tile-prefill": lambda t: t["longest"] > qb and t["plain"],
+        "spec-rows": lambda t: t["spec"] and t["plain"],
+        "slots-reused-out-of-order": lambda t: t["unordered"],
+    }[case]
+    assert any(want(t) for t in seen), seen

@@ -88,10 +88,10 @@ def traced(request, tiny):
             + sum(r.prefill_done + n for r, n in prefill_segs),
             width=min(b for b in engine.mixed_buckets if b >= aligned),
         ))
-        host = pack(decode_rows, prefill_segs)
-        hand[-1]["bytes"] = sum(a.nbytes for a in host)
-        hand[-1]["count"] = len(host)
-        return host
+        packed = pack(decode_rows, prefill_segs)
+        hand[-1]["bytes"] = packed[0].nbytes
+        hand[-1]["array_rows"] = sum(not r.draft_len for r in decode_rows)
+        return packed
 
     engine._pack_mixed = counting_pack
     lens, new = WORKLOADS[request.param]
@@ -131,8 +131,9 @@ def test_tick_args_equal_a_hand_count_of_the_planned_rows(traced):
         assert args["packed_width"] == want["width"]
         assert args["thread_cpu_us"] >= 0.0
         h2d = next(p for p in phases if p["name"] == "h2d")
-        assert h2d["args"] == {"count": want["count"], "bytes": want["bytes"]}
-        assert want["count"] == 16
+        assert args["pack_array_rows"] == want["array_rows"]
+        # ONE transfer a tick: the packed operand
+        assert h2d["args"] == {"count": 1, "bytes": want["bytes"]}
 
 
 def test_idle_tick_has_empty_dispatch_phases(tiny):
@@ -353,9 +354,9 @@ def test_scopes_change_no_program(tiny):
 
     def lowered():
         engine = _engine(cfg, params)
-        ops = [engine._put(a) for a in engine._dead_mixed_operands(8)]
         text = engine._make_mixed_step().lower(
-            engine.params, engine.pool.pages, *ops).as_text()
+            engine.params, engine.pool.pages,
+            engine._put(engine._dead_mixed_operands(8))).as_text()
         return re.sub(r"loc\(.*?\)$|^#loc.*$", "", text, flags=re.M)
 
     with_scopes = lowered()
@@ -574,7 +575,7 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
     events = tracer.events()
     acct = tick_account(events)
     assert acct["ticks"] == len(hand)
-    assert acct["h2d_count"] == 16
+    assert acct["h2d_count"] == 1
     assert acct["context_tokens"] == pytest.approx(
         sum(h["context"] for h in hand) / len(hand))
     parts = sum(acct[p + "_us"] for p in MIXED_TICK_PHASES)
@@ -582,8 +583,13 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
     assert acct["host_wait_us"] <= acct["tick_us"]
     assert sum(acct["packed_widths"].values()) == len(hand)
     assert set(acct["packed_widths"]) == {h["width"] for h in hand}
+    assert acct["pack_array_rows"] == pytest.approx(
+        sum(h["array_rows"] for h in hand) / len(hand))
     out = format_summary(events)
     assert "== tick account" in out and "pack " in out
+    assert (f"h2d 1 transfers, {acct['h2d_bytes']:.0f} bytes; pack wrote "
+            f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
+            "arrays") in out
     assert "packed width " + " ".join(
         f"{w}x{n}" for w, n in acct["packed_widths"].items()) in out
     assert tick_account([]) is None

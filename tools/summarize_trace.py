@@ -434,7 +434,8 @@ def format_tenants(records: list[dict]) -> str:
 def tick_account(events: list[dict]) -> dict[str, Any] | None:
     """Where the host's share of a unified tick goes, over DISPATCHING
     ticks: mean of every cut phase (tick order), the ``h2d`` slice's
-    transfer count and bytes, and from the tick args the tick thread's
+    transfer count and bytes, and from the tick args the rows
+    ``_pack_mixed`` filled by whole-array writes, the tick thread's
     own CPU time, the leftover (tick - host_sync - CPU: neither
     computing nor waiting for the device), the live context and the
     packed-width buckets the dispatches used.  None
@@ -467,6 +468,9 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
     out["tick_us"] = sum(e["dur"] for e in ticks) / n
     out["context_tokens"] = sum(
         e["args"].get("context_tokens", 0) for e in ticks) / n
+    out["pack_array_rows"] = sum(
+        e["args"].get("pack_array_rows", 0) for e in ticks) / n
+    out["rows"] = sum(e["args"].get("active_slots", 0) for e in ticks) / n
     widths: dict[int, int] = defaultdict(int)
     for e in ticks:
         widths[e["args"]["packed_width"]] += 1
@@ -661,7 +665,9 @@ def format_summary(events: list[dict], top: int = 5) -> str:
                         for name in MIXED_TICK_PHASES)
             + f"\ntick {acct['tick_us']:.0f}us; h2d "
             f"{acct['h2d_count']:.0f} transfers, "
-            f"{acct['h2d_bytes']:.0f} bytes; context "
+            f"{acct['h2d_bytes']:.0f} bytes; pack wrote "
+            f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
+            f"arrays; context "
             f"{acct['context_tokens']:.0f} tokens/dispatch; packed width "
             + " ".join(f"{w}x{n}" for w, n in acct["packed_widths"].items())
             + (f"; tick thread CPU {acct['thread_cpu_us']:.0f}us, "
