@@ -187,7 +187,7 @@ SERVE_HTTP_CONFIGS = {
 # Chaos leg (llm_np_cp_tpu/serve/faults.py + the EngineRunner
 # supervisor): the SAME Poisson trace replayed twice over HTTP — clean,
 # then under a seeded fault schedule (a tick-thread crash mid-flight and
-# a paged-kernel dispatch fault, plus transient 429s on the smoke) with
+# a step dispatch fault, plus transient 429s on the smoke) with
 # supervised restarts on.  The observables are what an outage costs:
 # recovery latency, p99 TTFT degradation vs the clean leg, and
 # token-identical recovery (the teacher-forced replay contract).  The
@@ -983,6 +983,9 @@ def run_serve_config(name: str) -> dict:
             max_seq_len=max_seq_len,
             prefill_chunk=chunk,
             cache_dtype=jnp.int8 if cache_dtype == "int8" else jnp.bfloat16,
+            # the race is between the phase-split tick's decode paths;
+            # the served tick (the constructor's default) has neither
+            mixed_step="off",
             decode_attn_impl=decode_attn_impl,
             enable_prefix_cache=spec.get("prefix_cache", False),
         )
@@ -1938,8 +1941,9 @@ def _run_http_trace_leg(
             "recovery_latency_s": [
                 round(v, 4) for v in runner.recovery_latency_s
             ],
-            "decode_impl_final": runner.engine.decode_attn_impl,
+            "decode_impl_final": runner.engine.ragged_attn_impl,
             "compile_counts": runner.engine.compile_counts(),
+            "buckets": list(runner.engine.mixed_buckets),
         }
         server.begin_drain()
         await server.serve_until_shutdown()
@@ -2159,13 +2163,14 @@ def run_serve_http_config(name: str) -> dict:
         "request_log_lines": len(log_lines),
         "request_log_parity": request_log_parity,
         "compile_counts": engine.compile_counts(),
+        "buckets": list(engine.mixed_buckets),
     }
 
 
 def run_serve_chaos_config(name: str) -> dict:
     """Supervised recovery under fault injection: the SAME Poisson trace
     through the HTTP server twice — a clean leg, then a chaos leg with a
-    seeded fault schedule (tick-thread crash + paged dispatch fault) and
+    seeded fault schedule (tick-thread crash + step dispatch fault) and
     ``max_restarts=3`` supervision.  Reports recovery latency, restart
     count, p99 TTFT degradation vs clean, and token parity (recovered
     streams must be token-identical — the teacher-forced replay
@@ -2174,10 +2179,6 @@ def run_serve_chaos_config(name: str) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from llm_np_cp_tpu.ops.pallas.support import (
-        kernel_error,
-        paged_kernel_name,
-    )
     from llm_np_cp_tpu.ops.sampling import Sampler
     from llm_np_cp_tpu.serve import FaultInjector, ServeEngine, poisson_trace
     from llm_np_cp_tpu.serve.engine import pool_geometry
@@ -2193,11 +2194,10 @@ def run_serve_chaos_config(name: str) -> dict:
         spec["prompt_len"], spec["max_tokens"], spec["slots"], bs,
         prefill_chunk=chunk,
     )
-    # paged when the probe passes: the chaos 'decode' fault then
-    # exercises the runtime gather fallback; on gather it exercises a
-    # second supervised restart instead — both are recovery paths
-    impl = "paged" if kernel_error(paged_kernel_name(False)) is None \
-        else "xla"
+    # the default engine, i.e. the served tick: the chaos 'decode' fault
+    # exercises the runtime Pallas -> XLA degradation where a kernel is
+    # live, a second supervised restart where none is — both are
+    # recovery paths
     rng = np.random.default_rng(13)
     trace = poisson_trace(
         rng, spec["requests"], rate_rps=spec["rate"],
@@ -2218,7 +2218,6 @@ def run_serve_chaos_config(name: str) -> dict:
             max_seq_len=max_seq_len,
             prefill_chunk=chunk,
             cache_dtype=jnp.bfloat16,
-            decode_attn_impl=impl,
             fault_injector=injector,
         )
         engine.warmup([int(t["prompt"].size) for t in trace],
@@ -2263,7 +2262,6 @@ def run_serve_chaos_config(name: str) -> dict:
         "slots": spec["slots"],
         "pool_blocks": num_blocks,
         "block_size": bs,
-        "attn_impl": impl,
         "chaos_spec": spec["chaos"],
         # every request completed despite the schedule, token-identically
         "token_parity_chaos_vs_clean": parity,
@@ -2281,9 +2279,11 @@ def run_serve_chaos_config(name: str) -> dict:
         "ttft_s_p99_chaos": round(x99, 4),
         "chaos_ttft_p99_degradation_s": round(x99 - c99, 4),
         "decode_impl_final": chaos_stats["decode_impl_final"],
-        # restart must not recompile: decode stays at its one program
+        # restart must not recompile: the step stays within one program
+        # a packed-width bucket (``buckets``), degraded twin included
         "compile_counts": chaos_stats["compile_counts"],
         "compile_counts_clean": clean_stats["compile_counts"],
+        "buckets": chaos_stats["buckets"],
     }
 
 

@@ -63,10 +63,10 @@ the mesh.  The engine is TP-only by design; data parallelism is N
 engine replicas behind a prefix-affinity router (serve/replica.py),
 each on its own mesh slice.
 
-Unified tick (``mixed_step="on"/"auto"``): the phase-split pipeline
-above collapses into ONE jit-stable ``mixed_step`` dispatch per tick —
-a packed ragged batch of prefill chunk slices and decode rows runs
-through a single layer scan that CARRIES the pool (flat over layer and
+Unified tick (``mixed_step="auto"``, the default, or ``"on"``; the tick
+``cli serve`` serves): the phase-split pipeline above collapses into
+ONE jit-stable ``mixed_step`` dispatch per tick — a packed ragged
+batch of prefill chunk slices and decode rows runs through a single layer scan that CARRIES the pool (flat over layer and
 block) and scatters every token's K/V into it in place (NO temp prefill
 cache, NO ``gather_prefix`` copy program — shared prefix blocks are
 attended in place through the block table; no slab sliced out of the
@@ -368,7 +368,7 @@ class ServeEngine:
         clock: Callable[[], float] = time.perf_counter,
         fault_injector: FaultInjector | None = None,
         tracer: TraceRecorder | None = None,
-        mixed_step: str = "off",
+        mixed_step: str = "auto",
         sample_epilogue: str = "auto",
         tick_token_budget: int | None = None,
         mesh_plan: Any = None,
@@ -522,12 +522,12 @@ class ServeEngine:
                 # the partitionable gather path is the honest impl
                 decode_attn_impl = "xla"
         self.decode_attn_impl = decode_attn_impl  # post-gate (tests/CLI)
-        # -- unified-tick gate: "on" forces the unified tick (XLA ragged
-        # fallback if Mosaic rejects the kernel), "auto" takes it only
-        # when the ragged kernel probe passes (conservative: a broken
-        # Mosaic toolchain keeps the battle-tested phase-split path),
-        # "off" is the phase-split engine
-        self.mixed_step_mode = mixed_step
+        # -- unified-tick gate: "auto" — the default here and of the CLI,
+        # so an engine built with no ``mixed_step`` is the engine that
+        # is served — takes the unified tick when the ragged kernel
+        # probe passes (conservative: a broken Mosaic toolchain keeps
+        # the phase-split path), "on" forces it (XLA ragged fallback if
+        # Mosaic rejects the kernel), "off" is the phase-split engine
         self.ragged_attn_impl: str | None = None
         if mixed_step == "off":
             self.mixed = False
@@ -2299,7 +2299,11 @@ class ServeEngine:
             clock=self.clock,
             fault_injector=self.faults,
             tracer=self.tracer,
-            mixed_step=self.mixed_step_mode,
+            # the tick this engine RESOLVED to, not the mode it was asked
+            # for: after a runtime degradation (disable_kernel) "auto"
+            # would re-probe, fail, and rebuild a unified engine as the
+            # phase-split one — five cold programs in mid-traffic
+            mixed_step="on" if self.mixed else "off",
             sample_epilogue=self.sample_epilogue_mode,
             tick_token_budget=self.tick_token_budget or None,
             mesh_plan=self.mesh_plan,

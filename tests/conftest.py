@@ -44,6 +44,12 @@ def _fresh_kernel_ledger():
     from llm_np_cp_tpu.ops.pallas import support
 
     support._RUNTIME_DISABLED.clear()
+    # ...and so is a probe's verdict (``lru_cache``).  A test that forces
+    # probes to fail (``support._FORCE_FAIL``) and builds one more engine
+    # before ``monkeypatch`` puts the flag back leaves "forced failure"
+    # cached for every later test of its worker; this fixture is torn
+    # down after ``monkeypatch``, and off the chip a probe costs nothing
+    support._probe.cache_clear()
 
 
 @pytest.fixture

@@ -679,3 +679,20 @@ def test_cli_serve_http_stdlib_client_smoke(fake_load, tmp_path, capsys):
     assert not th.is_alive(), "serve did not drain on --exit-after-s"
     printed = capsys.readouterr().out
     assert "listening on http://" in printed
+
+
+@pytest.mark.parametrize("parser", ["build_http_serve_parser",
+                                    "build_serve_parser"])
+def test_engine_and_cli_agree_on_the_default_tick(parser):
+    """One default, not two: an engine built with no ``mixed_step`` (every
+    test helper) is the engine ``cli serve`` builds with no
+    ``--mixed-step`` — the unified tick where its kernel compiles.  With
+    the constructor at "off" the serve suite guarded a tick no user
+    reached (ISSUE 29)."""
+    import inspect
+
+    from llm_np_cp_tpu.serve import ServeEngine
+
+    args = getattr(cli, parser)("some/model").parse_args([])
+    default = inspect.signature(ServeEngine).parameters["mixed_step"].default
+    assert default == args.mixed_step == "auto"

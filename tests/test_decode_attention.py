@@ -387,3 +387,242 @@ def test_paged_leading_block_skip_parity():
     got = paged_decode_attention(q, pages_k, pages_v, tables, lengths, pads,
                                  scale=d**-0.5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# Ragged variant: the kernel the served tick runs (ServeEngine.mixed_step).
+# The same cases as the paged kernel above, on ``ragged_paged_attention``
+# against BOTH its XLA twin (the probe-failure fallback the engine degrades
+# to) and an independent per-token ``gqa_attention`` reference, then a
+# matrix of row mixes the packer produces: decode rows, prefill chunks and
+# speculative verify slices in one packed batch, dead lanes included.
+# Contract (kernel docstring): token i of a tile sits at cache slot
+# ``qpos0 + i`` of its row and sees slots [max(pad, slot - window + 1), slot].
+# ---------------------------------------------------------------------------
+
+_NO_WINDOW = 1 << 30
+
+
+def _pack_segments(segs, dead_tiles=0):
+    """``[(row, qpos0, n_tokens)]`` → the kernel's per-TILE metadata, the
+    XLA twin's per-TOKEN metadata and the packed width, laid out the way
+    the engine's packer does: each segment on its own q tiles, the tail
+    of its last tile dead, ``dead_tiles`` whole dead tiles at the end
+    (the bucket's padding)."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import RAGGED_Q_TILE as qt
+
+    tile_row, tile_qpos0, tile_qlen = [], [], []
+    tok_row, tok_slot, tok_live = [], [], []
+    for row, qpos0, n in segs:
+        for i in range(0, n, qt):
+            live = min(qt, n - i)
+            tile_row.append(row)
+            tile_qpos0.append(qpos0 + i)
+            tile_qlen.append(live)
+            for j in range(qt):
+                tok_row.append(row)
+                tok_slot.append(qpos0 + i + j if j < live else 0)
+                tok_live.append(j < live)
+    for _ in range(dead_tiles):
+        tile_row.append(0), tile_qpos0.append(0), tile_qlen.append(0)
+        tok_row += [0] * qt
+        tok_slot += [0] * qt
+        tok_live += [False] * qt
+    i32 = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
+    return ((i32(tile_row), i32(tile_qpos0), i32(tile_qlen)),
+            (i32(tok_row), i32(tok_slot), jnp.asarray(tok_live)),
+            len(tok_row))
+
+
+def _ragged_reference(q, pages_k, pages_v, tables, tok, pads, window, *,
+                      scale, logit_softcap=None):
+    """One ``gqa_attention`` call a live token over its row's blocks
+    gathered contiguous; dead lanes stay zero."""
+    tok_row, tok_slot, tok_live = (np.asarray(a) for a in tok)
+    bs, kh, d = pages_k.shape[1:]
+    out = np.zeros(q.shape, np.float32)
+    for t in np.flatnonzero(tok_live):
+        row, slot = int(tok_row[t]), int(tok_slot[t])
+        gk = pages_k[tables[row]].reshape(1, -1, kh, d)
+        gv = pages_v[tables[row]].reshape(1, -1, kh, d)
+        pos = jnp.arange(gk.shape[1])
+        lo = max(int(pads[row]), slot - window + 1)
+        mask = ((pos >= lo) & (pos <= slot))[None, None, :]
+        out[t] = np.asarray(gqa_attention(
+            q[t][None, None], gk, gv, mask, scale=scale,
+            logit_softcap=logit_softcap))[0, 0]
+    return out
+
+
+def _check_ragged(q, pages_k, pages_v, tables, segs, pads, *, scale,
+                  window=_NO_WINDOW, logit_softcap=None, dead_tiles=0,
+                  scales=None, float_pages=None):
+    """Kernel == XLA twin == reference on the live lanes; the kernel's
+    dead lanes are exactly zero (what the step scatters to scratch and
+    the host discards must never be NaN or another row's output)."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        ragged_paged_attention,
+        ragged_paged_attention_xla,
+    )
+
+    tile, tok, width = _pack_segments(segs, dead_tiles)
+    assert q.shape[0] == width
+    kw = dict(scale=scale, logit_softcap=logit_softcap)
+    if scales is not None:
+        kw.update(k_scale=scales[0], v_scale=scales[1])
+    win = jnp.asarray(window, jnp.int32)
+    got = np.asarray(ragged_paged_attention(
+        q, pages_k, pages_v, tables, *tile, pads, win, **kw))
+    twin = np.asarray(ragged_paged_attention_xla(
+        q, pages_k, pages_v, tables, *tok, pads, win, **kw))
+    fk, fv = float_pages if float_pages is not None else (pages_k, pages_v)
+    want = _ragged_reference(q, fk, fv, tables, tok, pads, window,
+                             scale=scale, logit_softcap=logit_softcap)
+    live = np.asarray(tok[2])
+    assert live.any() and (dead_tiles == 0 or not live.all())
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    np.testing.assert_allclose(twin[live], want[live], atol=2e-5)
+    assert np.all(got[~live] == 0.0)
+    return got
+
+
+def _decode_segs(lengths):
+    """One decode row a sequence: its one token sits at the last slot."""
+    return [(r, int(n) - 1, 1) for r, n in enumerate(lengths)]
+
+
+def _packed_q(rng, segs, h, d, dead_tiles=0):
+    width = _pack_segments(segs, dead_tiles)[2]
+    return _rand(rng, (width, h, d))
+
+
+@pytest.mark.parametrize("h,kh", [(4, 4), (8, 2), (4, 1)])
+def test_ragged_matches_gathered_contiguous(h, kh):
+    rng = np.random.default_rng(h * 7 + kh)
+    d, nbp, bs = 16, 8, 16
+    pages_k = _rand(rng, (nbp, bs, kh, d))
+    pages_v = _rand(rng, (nbp, bs, kh, d))
+    # permuted tables with scratch-0 padding past each row's allocation
+    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [7, 6, 5, 4]], jnp.int32)
+    segs = _decode_segs([40, 17, 64])  # mid-block, 1-past, full
+    pads = jnp.asarray([3, 0, 10], jnp.int32)
+    _check_ragged(_packed_q(rng, segs, h, d), pages_k, pages_v, tables,
+                  segs, pads, scale=d**-0.5)
+
+
+def test_ragged_softcap_parity():
+    rng = np.random.default_rng(0)
+    h, kh, d, nbp, bs = 4, 2, 8, 6, 8
+    pages_k = _rand(rng, (nbp, bs, kh, d)) * 3
+    pages_v = _rand(rng, (nbp, bs, kh, d))
+    tables = jnp.asarray([[5, 1, 2], [3, 4, 0]], jnp.int32)
+    segs = _decode_segs([24, 9])
+    pads = jnp.asarray([2, 0], jnp.int32)
+    _check_ragged(_packed_q(rng, segs, h, d) * 3, pages_k, pages_v, tables,
+                  segs, pads, scale=0.5, logit_softcap=20.0)
+
+
+def test_ragged_int8_pool_matches_dequantized_gather():
+    """int8 pool blocks + scale pages through the ragged kernel must
+    match the gathered-dequantized oracle in f32 (``--cache-dtype int8``
+    serves through this path)."""
+    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
+
+    rng = np.random.default_rng(21)
+    h, kh, d, nbp, bs = 8, 2, 16, 8, 16
+    kq, ks = quantize_kv(_rand(rng, (nbp, bs, kh, d)))
+    vq, vs = quantize_kv(_rand(rng, (nbp, bs, kh, d)))
+    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [7, 6, 5, 4]], jnp.int32)
+    segs = _decode_segs([40, 17, 64])
+    pads = jnp.asarray([3, 0, 10], jnp.int32)
+    _check_ragged(
+        _packed_q(rng, segs, h, d), kq, vq, tables, segs, pads,
+        scale=d**-0.5, scales=(ks, vs),
+        float_pages=(dequantize_kv(kq, ks, jnp.float32),
+                     dequantize_kv(vq, vs, jnp.float32)),
+    )
+
+
+def test_ragged_int8_requires_both_scales():
+    """int8 pages without scale pages (or scales with float pages) must
+    refuse rather than misread quantized blocks as floats."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        RAGGED_Q_TILE,
+        ragged_paged_attention,
+    )
+
+    q = jnp.zeros((RAGGED_Q_TILE, 4, 8))
+    pages = jnp.zeros((2, 8, 2, 8), jnp.int8)
+    scales = jnp.zeros((2, 8, 2), jnp.float32)
+    one = jnp.zeros((1,), jnp.int32)
+    args = (jnp.zeros((1, 1), jnp.int32), one, one + 3, one + 1, one,
+            jnp.asarray(_NO_WINDOW, jnp.int32))
+    with pytest.raises(ValueError, match="k_scale"):
+        ragged_paged_attention(q, pages, pages, *args, scale=0.35)
+    with pytest.raises(ValueError, match="k_scale"):
+        ragged_paged_attention(
+            q, pages, pages, *args, k_scale=scales, scale=0.35
+        )
+    with pytest.raises(ValueError, match="k_scale"):
+        ragged_paged_attention(
+            q, pages.astype(jnp.float32), pages.astype(jnp.float32), *args,
+            k_scale=scales, v_scale=scales, scale=0.35,
+        )
+
+
+def test_ragged_leading_block_skip_parity():
+    """Rows whose left pads span WHOLE blocks (start = pads // BS > 0):
+    the kernel's grid clamp and the scalar-prefetch index map both begin
+    at the first visible block — and the cells' geometry (prefill_chunk =
+    2 * block_size) routinely produces pads >= BS."""
+    rng = np.random.default_rng(42)
+    h, kh, d, nbp, bs = 8, 2, 16, 10, 8
+    pages_k = _rand(rng, (nbp, bs, kh, d))
+    pages_v = _rand(rng, (nbp, bs, kh, d))
+    tables = jnp.asarray(
+        [[1, 2, 3, 4], [5, 6, 7, 0], [9, 8, 7, 6]], jnp.int32
+    )
+    # start blocks 1, 2, 3: mid-block pad, exact-boundary pad, and a row
+    # whose single visible block is its LAST
+    segs = _decode_segs([30, 24, 32])
+    pads = jnp.asarray([9, 16, 25], jnp.int32)
+    _check_ragged(_packed_q(rng, segs, h, d), pages_k, pages_v, tables,
+                  segs, pads, scale=d**-0.5)
+
+
+# row mixes of one packed batch, as (row, first slot, tokens): what a
+# steady decode tick, an admission tick and a speculating tick pack
+_RAGGED_MIXES = {
+    "decode-rows": ([(0, 39, 1), (1, 16, 1), (2, 63, 1)], 0),
+    # one 19-token chunk mid-prompt: three tiles, the last 3 of 8 live
+    "prefill-chunk": ([(1, 5, 19)], 1),
+    # decode rows + a prefill chunk + a verify slice (its own token and
+    # 3 drafts) + two dead tiles of bucket padding
+    "decode+prefill+verify4": (
+        [(0, 39, 1), (3, 8, 13), (2, 50, 4), (1, 16, 1)], 2),
+}
+
+
+@pytest.mark.parametrize("h,kh", [(4, 4), (8, 2), (4, 1)])
+@pytest.mark.parametrize("mix", list(_RAGGED_MIXES))
+def test_ragged_row_mixes(mix, h, kh):
+    """Every token of a multi-token segment attends causally INSIDE its
+    own freshly written segment (the step scatters the whole packed
+    batch before attending), under a sliding window narrower than the
+    context, and lanes no segment owns come back zero."""
+    segs, dead_tiles = _RAGGED_MIXES[mix]
+    rng = np.random.default_rng(len(mix) * 31 + h + kh)
+    d, nbp, bs = 16, 12, 16
+    pages_k = _rand(rng, (nbp, bs, kh, d))
+    pages_v = _rand(rng, (nbp, bs, kh, d))
+    tables = jnp.asarray(
+        [[1, 2, 3, 0], [4, 5, 0, 0], [7, 6, 5, 4], [8, 9, 10, 11]], jnp.int32)
+    pads = jnp.asarray([3, 0, 10, 6], jnp.int32)
+    q = _packed_q(rng, segs, h, d, dead_tiles)
+    full = _check_ragged(q, pages_k, pages_v, tables, segs, pads,
+                         scale=d**-0.5, dead_tiles=dead_tiles)
+    windowed = _check_ragged(q, pages_k, pages_v, tables, segs, pads,
+                             scale=d**-0.5, window=12,
+                             dead_tiles=dead_tiles)
+    # the window bit: contexts here are longer than 12 slots
+    assert not np.allclose(full, windowed, atol=1e-3)

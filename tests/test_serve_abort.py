@@ -76,15 +76,17 @@ def test_abort_queued_request_frees_nothing_and_fires_event(tiny):
 
 
 def test_abort_mid_prefill_returns_all_blocks(tiny):
-    """Abort immediately after admission+prefill (before any decode
-    tick): the freshly scattered prefill blocks all come back."""
+    """Abort right after the admission tick — the prompt's first chunk
+    is in the pool, the rest is not, no token has been emitted: the
+    freshly written prefill blocks all come back."""
     cfg, params = tiny
     engine = _engine(cfg, params)
     rng = np.random.default_rng(1)
     req = engine.submit(rng.integers(1, cfg.vocab_size, size=14), 10)
-    engine.step()  # admits + prefills (+ the same tick's decode)
+    engine.step()  # admits + writes the first prefill chunk
     assert req.state is RequestState.RUNNING
-    assert 1 <= len(req.generated) <= 2  # prefill emitted the first token
+    assert 0 < req.prefill_done < req.prefill_target  # genuinely mid-prefill
+    assert not req.generated
     assert engine.pool.stats()["request_held"] > 0
     assert engine.abort(req.req_id)
     assert engine.pool.stats()["request_held"] == 0
@@ -148,12 +150,16 @@ def test_abort_decrefs_shared_prefix_without_corrupting_sharers(tiny):
     assert stats["cache_only"] == stats["allocated"]
 
 
-def test_abort_churn_never_recompiles_decode(tiny):
+@pytest.mark.parametrize(
+    "tick_kw", [{}, {"mixed_step": "off"}], ids=["unified", "split"])
+def test_abort_churn_never_recompiles_decode(tiny, tick_kw):
     """The compile-counter lint over an abort-churn trace: interleaved
     submits and aborts across queued/running states stay within the
-    static-shape bounds — decode compiles exactly once."""
+    static-shape bounds — the step compiles at most once a packed-width
+    bucket (the default engine), decode exactly once (the split tick)."""
     cfg, params = tiny
-    engine = _engine(cfg, params)
+    engine = _engine(cfg, params, **tick_kw)
+    assert engine.mixed == (not tick_kw)
     rng = np.random.default_rng(4)
     lens = (5, 9, 13)
     for round_ in range(4):
@@ -170,7 +176,12 @@ def test_abort_churn_never_recompiles_decode(tiny):
     }
     assert_serve_compiles_bounded(engine,
                                   distinct_prefill_shapes=len(shapes))
-    assert engine.compile_counts()["decode_step"] == 1
+    counts = engine.compile_counts()
+    if engine.mixed:
+        assert set(counts) == {"mixed_step"}
+        assert counts["mixed_step"] <= len(engine.mixed_buckets)
+    else:
+        assert counts["decode_step"] == 1
     assert engine.pool.stats()["request_held"] == 0
 
 

@@ -138,7 +138,7 @@ def test_decode_fault_degrades_paged_to_gather_token_identical(tiny):
     cfg, params = tiny
     inj = FaultInjector("decode@2")
     engine = _engine(cfg, params, decode_attn_impl="paged",
-                     fault_injector=inj)
+                     mixed_step="off", fault_injector=inj)
     assert engine.decode_attn_impl == "paged"
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (6, 11)]
@@ -155,7 +155,7 @@ def test_decode_fault_degrades_paged_to_gather_token_identical(tiny):
     assert support.kernel_error("paged_decode_attention") is not None
     assert support.gate_attn_impl("paged") == "xla"
     assert _engine(cfg, params, decode_attn_impl="paged",
-                   ).decode_attn_impl == "xla"
+                   mixed_step="off").decode_attn_impl == "xla"
 
 
 def test_decode_fault_on_gather_impl_propagates(tiny):
@@ -165,12 +165,30 @@ def test_decode_fault_on_gather_impl_propagates(tiny):
     the epilogue degrades to the XLA tail and the tick retries — so
     the floor is pinned with ``sample_epilogue="off"``."""
     cfg, params = tiny
-    engine = _engine(cfg, params, sample_epilogue="off",
+    engine = _engine(cfg, params, sample_epilogue="off", mixed_step="off",
                      fault_injector=FaultInjector("decode@1"))
     assert engine.epilogue_impl == "xla"
     engine.submit(np.asarray([3, 5, 7], np.int32), 4)
     with pytest.raises(FaultInjected):
         engine.run_until_complete()
+
+
+def test_decode_fault_on_the_xla_tick_propagates(tiny, monkeypatch):
+    """The served tick's floor: a process whose Mosaic probes fail
+    resolves to the unified tick over the XLA ragged attention and the
+    XLA tail (``mixed_step="on"``), and there a dispatch fault has
+    nothing left to degrade to — it surfaces for the supervisor."""
+    monkeypatch.setattr(support, "_FORCE_FAIL", True)
+    support._probe.cache_clear()  # conftest clears it again afterwards
+    cfg, params = tiny
+    engine = _engine(cfg, params, mixed_step="on",
+                     fault_injector=FaultInjector("decode@1"))
+    assert engine.mixed
+    assert (engine.ragged_attn_impl, engine.epilogue_impl) == ("xla", "xla")
+    engine.submit(np.asarray([3, 5, 7], np.int32), 4)
+    with pytest.raises(FaultInjected):
+        engine.run_until_complete()
+    assert engine.decode_degraded is None
 
 
 def test_decode_fault_degrades_fused_epilogue_then_propagates(tiny):
@@ -344,8 +362,8 @@ def test_restart_budget_exhaustion_goes_terminal(tiny):
 @pytest.mark.http
 def test_chaos_e2e_16_streams_crash_kernel_fault_and_429s(tiny):
     """16 concurrent HTTP streams under the seeded schedule the issue
-    names: one tick-thread crash mid-decode, one paged dispatch fault
-    (runtime gather fallback), three transient 429s (clients retry with
+    names: one tick-thread crash mid-decode, one step dispatch fault
+    (runtime Pallas → XLA fallback), three transient 429s (clients retry with
     backoff).  Every request completes; recovered requests are
     token-identical to a fault-free offline ``generate_ragged``;
     /healthz transitions ok→degraded→ok; restarts_total and
@@ -353,8 +371,8 @@ def test_chaos_e2e_16_streams_crash_kernel_fault_and_429s(tiny):
     cfg, params = tiny
     inj = FaultInjector("tick_crash@14;decode@6;http_429@2:3=0")
     engine = _engine(cfg, params, max_slots=4, num_blocks=64,
-                     decode_attn_impl="paged", fault_injector=inj)
-    assert engine.decode_attn_impl == "paged"
+                     fault_injector=inj)
+    assert engine.mixed and engine.ragged_attn_impl == "pallas"
     # compile outside the watchdog's clock (slow-host flake guard); the
     # chaos tick/decode hit counters only start with real traffic
     engine.warmup([19], max_new_tokens=12)
@@ -417,8 +435,9 @@ def test_chaos_e2e_16_streams_crash_kernel_fault_and_429s(tiny):
     assert inj.injected["decode"] == 1
     assert inj.injected["http_429"] == 3
     assert sum(r["retries"] for r in results) >= 3  # the 429s were retried
-    # runtime degradation stuck: the live engine ended on the gather impl
-    assert srv.runner.engine.decode_attn_impl == "xla"
+    # runtime degradation stuck: the live engine ended on the XLA twins
+    assert srv.runner.engine.ragged_attn_impl == "xla"
+    assert srv.runner.engine.epilogue_impl == "xla"
     # /healthz walked ok→degraded→ok
     assert {"ok", "degraded"} <= health_states
     # supervision observables in the Prometheus scrape

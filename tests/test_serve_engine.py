@@ -52,17 +52,21 @@ def _assert_parity(engine: ServeEngine, cfg, params, cache_dtype) -> None:
         )
 
 
-def test_trace_parity_32_requests_and_bounded_compiles(tiny):
+@pytest.mark.parametrize(
+    "tick_kw", [{}, {"mixed_step": "off"}], ids=["unified", "split"])
+def test_trace_parity_32_requests_and_bounded_compiles(tiny, tick_kw):
     """The acceptance criterion: a 32-request Poisson trace through the
     engine produces per-request greedy tokens identical to offline
-    ``generate_ragged``, and the jitted steps compile once per distinct
-    phase shape — never per tick."""
+    ``generate_ragged``, and the jitted steps compile once per packed
+    width bucket (the default engine: the tick that is served) or once
+    per distinct phase shape (the phase-split tick) — never per tick."""
     cfg, params = tiny
     engine = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"),
         max_slots=4, num_blocks=48, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32,
+        cache_dtype=jnp.float32, **tick_kw,
     )
+    assert engine.mixed == (not tick_kw)
     rng = np.random.default_rng(0)
     trace = poisson_trace(
         rng, 32, rate_rps=40.0, prompt_len_range=(3, 14),
@@ -83,6 +87,11 @@ def test_trace_parity_32_requests_and_bounded_compiles(tiny):
     assert engine.scheduler.n_preemptions == 0
     assert_serve_compiles_bounded(engine, distinct_prefill_shapes=len(shapes))
     counts = engine.compile_counts()
+    if engine.mixed:
+        assert set(counts) == {"mixed_step"}
+        assert 1 <= counts["mixed_step"] <= len(engine.mixed_buckets)
+        assert snap["ticks"] > counts["mixed_step"]
+        return
     assert counts["decode_step"] == 1
     assert snap["ticks"] > counts["decode_step"] + counts["prefill_step"]
 
@@ -247,7 +256,7 @@ def test_paged_trace_parity_32_requests_and_bounded_compiles(tiny):
     engine = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"),
         max_slots=4, num_blocks=48, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, decode_attn_impl="paged",
+        cache_dtype=jnp.float32, decode_attn_impl="paged", mixed_step="off",
     )
     assert engine.decode_attn_impl == "paged"
     rng = np.random.default_rng(0)
@@ -272,7 +281,7 @@ def test_paged_int8_pool_parity(tiny):
     engine = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"),
         max_slots=3, num_blocks=16, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.int8, decode_attn_impl="paged",
+        cache_dtype=jnp.int8, decode_attn_impl="paged", mixed_step="off",
     )
     assert engine.decode_attn_impl == "paged"
     rng = np.random.default_rng(11)
@@ -296,7 +305,7 @@ def test_paged_gemma2_sliding_window_parity():
         engine = ServeEngine(
             params, cfg, sampler=Sampler(kind="greedy"),
             max_slots=2, num_blocks=32, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, decode_attn_impl=impl,
+            cache_dtype=jnp.float32, decode_attn_impl=impl, mixed_step="off",
         )
         rng = np.random.default_rng(5)
         # long decodes so visible length crosses the window bound and
@@ -319,7 +328,7 @@ def test_paged_decode_step_has_no_materialized_gather(tiny):
         return ServeEngine(
             params, cfg, sampler=Sampler(kind="greedy"),
             max_slots=4, num_blocks=16, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, decode_attn_impl=impl,
+            cache_dtype=jnp.float32, decode_attn_impl=impl, mixed_step="off",
         )
 
     l = cfg.num_hidden_layers
@@ -359,7 +368,7 @@ def test_paged_falls_back_to_xla_when_probe_fails(tiny, monkeypatch):
         engine = ServeEngine(
             params, cfg, max_slots=2, num_blocks=16, block_size=8,
             max_seq_len=64, cache_dtype=jnp.float32,
-            decode_attn_impl="paged",
+            decode_attn_impl="paged", mixed_step="off",
         )
         assert engine.decode_attn_impl == "xla"
     finally:
@@ -396,7 +405,7 @@ def test_prefix_sharing_parity_and_fewer_prefill_dispatches(tiny, impl):
         engine = ServeEngine(
             params, cfg, sampler=Sampler(kind="greedy"),
             max_slots=4, num_blocks=48, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, decode_attn_impl=impl,
+            cache_dtype=jnp.float32, decode_attn_impl=impl, mixed_step="off",
             enable_prefix_cache=prefix,
         )
         calls = _count_prefill_calls(engine)

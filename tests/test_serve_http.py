@@ -399,35 +399,52 @@ def test_deadline_expiry_during_drain_aborts_and_drain_completes(tiny):
     """A per-request deadline that expires WHILE a SIGTERM drain is in
     progress must still be swept: the stream finishes ``aborted``, its
     blocks decref, and the drain completes promptly instead of waiting
-    out the full --drain-timeout on a request that will never finish."""
+    out the full --drain-timeout on a request that will never finish.
+
+    No wall-clock race decides the order of events: the drain begins
+    once the CLIENT has read its first token frame, and the deadline —
+    far away on the real clock — expires when the test then moves the
+    engine's clock past it."""
     cfg, params = tiny
-    engine = _engine(cfg, params)
+    skew = [0.0]
+    engine = _engine(cfg, params,
+                     clock=lambda: time.perf_counter() + skew[0])
 
     async def main():
         srv = HttpServer(engine, model_id="tiny", drain_timeout=30.0)
         await srv.start("127.0.0.1", 0)
         loop = asyncio.get_running_loop()
-        # a budget far larger than the deadline allows: without the sweep
-        # this stream would pin the drain until drain_timeout
-        task = asyncio.create_task(astream_completion(
+        # a budget the deadline will cut short: without the sweep this
+        # stream would pin the drain until it ran out its 40 tokens
+        st, headers, reader, writer = await _raw_post(
             srv.host, srv.port,
             {"prompt": [7] * 9, "max_tokens": 40, "stream": True,
-             "timeout_s": 0.6},
-        ))
-        # drain begins while the stream is mid-decode, before its deadline
-        deadline = time.time() + 20
-        while not engine.metrics.snapshot()["total_generated_tokens"] \
-                and time.time() < deadline:
-            await asyncio.sleep(0.01)
+             "timeout_s": 600.0})
+        assert st == 200
+        chunks = []
+
+        async def next_chunk():
+            while True:
+                line = await asyncio.wait_for(reader.readline(), timeout=60)
+                assert line, "stream closed before [DONE]"
+                if line.startswith(b"data: "):
+                    return line[6:].strip()
+
+        chunks.append(json.loads(await next_chunk()))  # the first token
         t_drain = loop.time()
         srv.begin_drain()
-        res = await asyncio.wait_for(task, timeout=30)
+        skew[0] = 3600.0  # ... and now the deadline is in the past
+        while (data := await next_chunk()) != b"[DONE]":
+            chunks.append(json.loads(data))
+        writer.close()
         await asyncio.wait_for(srv.serve_until_shutdown(), timeout=30)
         drain_s = loop.time() - t_drain
-        assert res["finish_reason"] == "aborted"
-        assert 0 < len(res["token_ids"]) < 40
-        # the sweep, not the drain timeout, ended it: well under the 30s
-        # drain window (deadline 0.6s + terminal-event flush)
+        finish = [c["choices"][0].get("finish_reason") for c in chunks]
+        assert finish[-1] == "aborted" and not any(finish[:-1])
+        n_tokens = sum(c["choices"][0].get("token_id") is not None
+                       for c in chunks)
+        assert 0 < n_tokens < 40
+        # the sweep, not the drain timeout, ended it
         assert drain_s < 15.0, f"drain stalled for {drain_s:.1f}s"
 
     asyncio.run(asyncio.wait_for(main(), timeout=120))

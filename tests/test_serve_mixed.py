@@ -500,6 +500,148 @@ def test_mixed_runtime_degradation_to_xla_fallback(tiny):
     assert _tokens(engine) == _tokens(ref)
 
 
+def test_degraded_engine_rebuilds_as_the_tick_it_is(tiny):
+    """``cli serve`` builds its engine with ``mixed_step="auto"``.  After
+    a kernel fault degraded it (the probe now reports the kernel
+    unavailable, process-wide) a supervisor's ``clone_fresh`` must come
+    back as the unified tick over the XLA twins with the degraded step
+    shared — not re-resolve ``auto`` into the phase-split engine, whose
+    five programs would compile cold in mid-traffic."""
+    from llm_np_cp_tpu.serve import FaultInjector
+    import llm_np_cp_tpu.ops.pallas.support as support
+
+    cfg, params = tiny
+    engine = _engine(cfg, params, mixed="auto",
+                     fault_injector=FaultInjector("decode@2"))
+    assert engine.mixed and engine.ragged_attn_impl == "pallas"
+    try:
+        live = engine.submit(np.arange(1, 12, dtype=np.int32), 8, seed=3)
+        for _ in range(4):
+            engine.step()
+        assert engine.ragged_attn_impl == "xla" and live.generated
+        rebuilt = engine.clone_fresh()
+        assert rebuilt.mixed
+        assert (rebuilt.ragged_attn_impl, rebuilt.epilogue_impl) \
+            == ("xla", "xla")
+        assert rebuilt._mixed_step is engine._mixed_step
+        assert set(rebuilt.compile_counts()) == {"mixed_step"}
+        rebuilt.faults = None
+        rebuilt.recover(live.prompt, live.max_new_tokens,
+                        request_id=live.req_id, seed=live.seed,
+                        generated=list(live.generated))
+        rebuilt.run_until_complete()
+        # a grandchild keeps the tick too
+        assert rebuilt.clone_fresh().mixed
+    finally:
+        support._RUNTIME_DISABLED.clear()
+    ref = _engine(cfg, params, mixed="off")
+    ref.submit(live.prompt, 8, seed=3)
+    ref.run_until_complete()
+    assert _tokens(rebuilt) == {live.req_id: _tokens(ref)[0]}
+
+
+def _iter_eqns(jaxpr, *, into_pallas=False):
+    """Every eqn of ``jaxpr`` and of its sub-jaxprs (pjit / scan / ...),
+    the body of a Pallas kernel left out unless asked for."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call" and not into_pallas:
+            continue
+        stack = list(eqn.params.values())
+        while stack:
+            v = stack.pop()
+            if isinstance(v, jax.extend.core.ClosedJaxpr):
+                yield from _iter_eqns(v.jaxpr, into_pallas=into_pallas)
+            elif isinstance(v, jax.extend.core.Jaxpr):
+                yield from _iter_eqns(v, into_pallas=into_pallas)
+            elif isinstance(v, (tuple, list)):
+                stack.extend(v)
+
+
+def test_mixed_step_has_no_materialized_gather(tiny, monkeypatch):
+    """Structural zero-gather assertion on the served program: the
+    gathered cache view the XLA ragged attention builds — every row's
+    blocks contiguous, ``[rows, S_max, K, D]``, and one copy a packed
+    token, ``[T, S_max, K, D]`` — is in the fallback step's jaxpr
+    (detector sanity) and in NO eqn of the Pallas step's outside the
+    kernel, at any packed width."""
+    import llm_np_cp_tpu.ops.pallas.support as support
+
+    cfg, params = tiny
+    kh, d = cfg.num_key_value_heads, cfg.head_dim
+
+    def shapes(engine, t_w):
+        jaxpr = jax.make_jaxpr(lambda ops: engine._mixed_step(
+            engine.params, engine.pool.pages, ops
+        ))(jnp.asarray(engine._dead_mixed_operands(t_w)))
+        return {tuple(v.aval.shape) for eqn in _iter_eqns(jaxpr.jaxpr)
+                for v in eqn.outvars if hasattr(v.aval, "shape")}
+
+    pallas = _engine(cfg, params)
+    assert pallas.ragged_attn_impl == "pallas"
+    monkeypatch.setattr(support, "_FORCE_FAIL", True)
+    support._probe.cache_clear()  # conftest clears it again afterwards
+    xla = _engine(cfg, params, mixed="on")
+    assert xla.ragged_attn_impl == "xla"
+    rows, s_max = pallas.scheduler.max_slots, pallas.max_seq_len
+    for t_w in pallas.mixed_buckets:
+        gathered = {(rows, s_max, kh, d), (t_w, s_max, kh, d)}
+        assert gathered <= shapes(xla, t_w), (
+            "control failed: the XLA ragged attention no longer "
+            "materializes the gathered view — update the shapes here"
+        )
+        hit = gathered & shapes(pallas, t_w)
+        assert not hit, (
+            f"the Pallas step materialized a gathered cache view {hit} "
+            f"at packed width {t_w} — the zero-gather contract is broken"
+        )
+
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("mesh", [None, 2], ids=["one-device", "model=2"])
+@pytest.mark.parametrize("prefix", [False, True], ids=["noprefix", "prefix"])
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.int8],
+                         ids=["f32", "int8"])
+def test_default_engine_names_only_mixed_step(tiny, cache_dtype, prefix, mesh):
+    """Whatever the pool's dtype, with or without the prefix cache, on
+    one device or tensor-parallel: the default engine is the unified
+    tick, its one program is ``mixed_step`` (plus the host tier's two
+    where a tier is attached), warm-up compiles it once a bucket and
+    traffic afterwards — prefix hits included — compiles nothing."""
+    from llm_np_cp_tpu.parallel.sharding import MeshPlan
+    from llm_np_cp_tpu.serve.host_tier import HostTier
+
+    cfg, params = tiny
+    tier = HostTier(8 << 20) if prefix else None
+    engine = ServeEngine(
+        params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
+        num_blocks=32, block_size=8, max_seq_len=64,
+        cache_dtype=cache_dtype, enable_prefix_cache=prefix,
+        host_tier=tier,
+        mesh_plan=MeshPlan(model=mesh) if mesh else None,
+    )
+    assert engine.mixed
+    want = {"mixed_step"} | ({"restore_block", "slice_block"} if prefix
+                             else set())
+    assert set(engine.compile_counts()) == want
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (19, 6)]
+    engine.warmup([19, 6], max_new_tokens=4)
+    warm = engine.compile_counts()
+    assert warm["mixed_step"] == len(engine.mixed_buckets)
+    with CompileCounter().watch() as counter:
+        for _ in range(2):
+            for j, p in enumerate(prompts):
+                engine.submit(p, 4, seed=j)
+            engine.run_until_complete()
+    assert counter.count == 0, counter.events
+    assert engine.compile_counts() == warm
+    assert_serve_compiles_bounded(engine, distinct_prefill_shapes=0)
+    assert (engine.metrics.prefix_blocks_hit > 0) == prefix
+    if tier is not None:
+        tier.close()
+
+
 def test_mixed_rejects_bad_config(tiny):
     cfg, params = tiny
     with pytest.raises(ValueError, match="mixed_step"):

@@ -523,6 +523,34 @@ def test_spec_auto_fallback_serves_plain(tiny, monkeypatch):
         support._probe.cache_clear()
 
 
+def test_spec_on_the_xla_tick_keeps_speculating(tiny, monkeypatch):
+    """mixed_step='on' with the ragged probe failing: the unified tick
+    runs over the XLA ragged attention and speculation STAYS on — the
+    verifier is the tick's, not the kernel's — with the tokens plain
+    decode emits."""
+    import llm_np_cp_tpu.ops.pallas.support as support
+
+    cfg, params = tiny
+    rng = np.random.default_rng(17)
+    prompts = _tiled_prompts(rng, cfg.vocab_size, (9, 12), pattern=3)
+
+    def run(**kw):
+        eng = _engine(cfg, params, **kw)
+        for j, p in enumerate(prompts):
+            eng.submit(p, 10, seed=j, speculative=bool(kw.get("spec_k")))
+        eng.run_until_complete()
+        return eng
+
+    plain = run(spec_k=0)
+    monkeypatch.setattr(support, "_FORCE_FAIL", True)
+    support._probe.cache_clear()  # conftest clears it again afterwards
+    spec = run(spec_k=4, mixed_step="on")
+    assert spec.mixed and spec.ragged_attn_impl == "xla"
+    assert spec.spec_k == 4
+    assert spec.metrics.snapshot()["spec_accepted_tokens"] > 0
+    assert _tokens(spec) == _tokens(plain)
+
+
 @pytest.mark.http
 def test_spec_over_http_opt_in_parity(tiny):
     """The /v1/completions `"speculative": true` opt-in round-trips to

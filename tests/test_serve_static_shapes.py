@@ -22,11 +22,14 @@ from llm_np_cp_tpu.serve import ServeEngine
 from tools.compile_counter import CompileCounter, assert_serve_compiles_bounded
 
 
-def _engine(cfg, params):
+def _engine(cfg, params, **kw):
+    """The default engine — the tick ``cli serve`` serves; the tests of
+    the phase-split tick's own programs ask for it (``mixed_step="off"``)."""
+    kw.setdefault("num_blocks", 24)
     return ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"),
-        max_slots=2, num_blocks=24, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32,
+        max_slots=2, block_size=8, max_seq_len=64,
+        cache_dtype=jnp.float32, **kw,
     )
 
 
@@ -66,12 +69,9 @@ def test_paged_prefix_steady_state_ticks_compile_nothing():
     once."""
     cfg = tiny_config("llama")
     params = init_params(jax.random.PRNGKey(2), cfg, dtype=jnp.float32)
-    engine = ServeEngine(
-        params, cfg, sampler=Sampler(kind="greedy"),
-        max_slots=2, num_blocks=32, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, decode_attn_impl="paged",
-        enable_prefix_cache=True,
-    )
+    engine = _engine(cfg, params, num_blocks=32, decode_attn_impl="paged",
+                     mixed_step="off", enable_prefix_cache=True)
+    assert not engine.mixed and engine.decode_attn_impl == "paged"
     # warm: both block-count buckets, then a repeat so the prefix-hit
     # path (gather_prefix per shared depth) compiles too
     _drive(engine, cfg, lens=(4, 12), seed0=0)
@@ -89,14 +89,56 @@ def test_paged_prefix_steady_state_ticks_compile_nothing():
     assert engine.metrics.prefix_blocks_hit > 0
 
 
-def test_compile_counts_bounded_by_phase_shapes():
-    """The per-program contract: decode/sample/prefill compile once (the
-    temp prefill cache has a fixed capacity), scatter at most once per
-    distinct prefill block count, regardless of how many requests or
-    ticks ran."""
+def test_prefix_steady_state_ticks_compile_nothing():
+    """The served tick with prefix sharing: after ``warmup`` (one compile
+    a packed-width bucket) repeated traffic — prompt-length buckets,
+    prefix hits, refcount churn — triggers ZERO backend compiles and
+    ``mixed_step`` stays the only program."""
+    cfg = tiny_config("llama")
+    params = init_params(jax.random.PRNGKey(2), cfg, dtype=jnp.float32)
+    engine = _engine(cfg, params, num_blocks=32, enable_prefix_cache=True)
+    assert engine.mixed
+    engine.warmup([4, 12], max_new_tokens=5)
+    warm_counts = dict(engine.compile_counts())
+    assert warm_counts == {"mixed_step": len(engine.mixed_buckets)}
+
+    counter = CompileCounter()
+    with counter.watch():
+        for _ in range(3):  # rounds 2+ hit the prefix cache
+            _drive(engine, cfg, lens=(4, 12, 4, 12, 4), seed0=0)
+    assert counter.count == 0, (
+        f"prefix steady-state serving compiled: {counter.events}"
+    )
+    assert engine.compile_counts() == warm_counts
+    assert engine.metrics.prefix_blocks_hit > 0
+
+
+def test_compile_counts_bounded_by_buckets():
+    """The per-program contract of the served tick: ONE program,
+    ``mixed_step``, compiled at most once a packed-width bucket however
+    many requests, prompt lengths or ticks ran — and with no warm-up,
+    only the buckets the traffic actually packed."""
     cfg = tiny_config("llama")
     params = init_params(jax.random.PRNGKey(1), cfg, dtype=jnp.float32)
     engine = _engine(cfg, params)
+    assert engine.mixed
+    _drive(engine, cfg, lens=(3, 5, 9, 14, 2, 11, 8, 16), seed0=0)
+    assert engine.scheduler.n_preemptions == 0
+    assert_serve_compiles_bounded(engine, distinct_prefill_shapes=0)
+    counts = engine.compile_counts()
+    assert set(counts) == {"mixed_step"}
+    assert 1 <= counts["mixed_step"] <= len(engine.mixed_buckets)
+    assert engine.metrics.n_ticks > counts["mixed_step"]
+
+
+def test_compile_counts_bounded_by_phase_shapes():
+    """The phase-split tick's per-program contract: decode/sample/prefill
+    compile once (the temp prefill cache has a fixed capacity), scatter
+    at most once per distinct prefill block count, regardless of how
+    many requests or ticks ran."""
+    cfg = tiny_config("llama")
+    params = init_params(jax.random.PRNGKey(1), cfg, dtype=jnp.float32)
+    engine = _engine(cfg, params, mixed_step="off")
     lens = (3, 5, 9, 14, 2, 11, 8, 16)
     _drive(engine, cfg, lens=lens, seed0=0)
     chunk = engine.prefill_chunk

@@ -26,7 +26,7 @@ from llm_np_cp_tpu.config import tiny_config
 from llm_np_cp_tpu.models.transformer import init_params
 from llm_np_cp_tpu.ops.sampling import Sampler
 from llm_np_cp_tpu.serve import ServeEngine, TraceRecorder, poisson_trace
-from llm_np_cp_tpu.serve.tracing import TICK_PHASES
+from llm_np_cp_tpu.serve.tracing import MIXED_TICK_PHASES, TICK_PHASES
 from tools.compile_counter import (
     CompileCounter,
     assert_tracing_hooks_guarded,
@@ -61,13 +61,22 @@ def _engine(cfg, params, **kw):
     return ServeEngine(params, cfg, sampler=Sampler(kind="greedy"), **kw)
 
 
-@pytest.fixture(scope="module")
-def traced_run(tiny):
-    """One traced 8-request Poisson replay shared by the schema /
+def _tick_phases(engine):
+    """The phase slices of the tick this engine runs."""
+    return MIXED_TICK_PHASES if engine.mixed else TICK_PHASES
+
+
+# the default engine (the unified tick, what ``cli serve`` serves), and
+# the phase-split tick, which has to be asked for
+@pytest.fixture(scope="module", params=[{}, {"mixed_step": "off"}],
+                ids=["unified", "split"])
+def traced_run(tiny, request):
+    """One traced 8-request Poisson replay a tick, shared by the schema /
     coverage / summarize / histogram tests (each reads, none mutates)."""
     cfg, params = tiny
     tracer = TraceRecorder()
-    engine = _engine(cfg, params, tracer=tracer)
+    engine = _engine(cfg, params, tracer=tracer, **request.param)
+    assert engine.mixed == (not request.param)
     rng = np.random.default_rng(0)
     trace = poisson_trace(rng, 8, rate_rps=50.0, prompt_len_range=(3, 10),
                           max_new_tokens=5, vocab_size=cfg.vocab_size)
@@ -127,7 +136,8 @@ def test_tick_phase_spans_cover_tick_time(traced_run):
     only the final event-emission tail is outside them).  Asserted on
     ticks above a jitter floor — a 50µs idle tick can be half timer
     noise."""
-    _, _, events = traced_run
+    engine, _, events = traced_run
+    tick_phases = _tick_phases(engine)
     checked = 0
     i = 0
     while i < len(events):
@@ -136,9 +146,9 @@ def test_tick_phase_spans_cover_tick_time(traced_run):
         if ev.get("cat") != "tick" or ev.get("ph") != "X":
             continue
         # the recorder appends a tick's phase slices atomically after it
-        phases = events[i:i + len(TICK_PHASES)]
-        i += len(TICK_PHASES)
-        assert [p["name"] for p in phases] == list(TICK_PHASES)
+        phases = events[i:i + len(tick_phases)]
+        i += len(tick_phases)
+        assert [p["name"] for p in phases] == list(tick_phases)
         for p in phases:
             assert p["ts"] >= ev["ts"] - 1e-6
             assert p["ts"] + p["dur"] <= ev["ts"] + ev["dur"] + 1e-6
@@ -346,17 +356,18 @@ def test_summarize_vocabulary_matches_recorder():
 
 
 def test_summarize_trace_tool(traced_run, tmp_path):
-    _, tracer, events = traced_run
+    engine, tracer, events = traced_run
     path = tmp_path / "fixture_trace.json"
     tracer.dump(str(path))
     loaded = load_trace(str(path))
     assert len(loaded) == len(events)
 
     totals = phase_totals(loaded)
-    for phase in TICK_PHASES:
+    for phase in _tick_phases(engine):
         assert phase in totals, f"missing phase {phase}"
         assert totals[phase]["count"] > 0
-    assert "prefill_chunk" in totals
+    # a prefill chunk is a dispatch of its own on the split tick only
+    assert ("prefill_chunk" in totals) == (not engine.mixed)
 
     stats = tick_stats(loaded)
     assert stats["ticks"] > 0
@@ -370,7 +381,7 @@ def test_summarize_trace_tool(traced_run, tmp_path):
     assert len(table) == 8
     out = format_summary(loaded, top=3)
     assert "tick phases" in out and "requests" in out
-    assert "decode_dispatch" in out
+    assert ("mixed_dispatch" if engine.mixed else "decode_dispatch") in out
     assert "length" in out  # finish reasons rendered
     # bare-list form loads too (both are valid Chrome trace JSON)
     bare = tmp_path / "bare.json"
@@ -574,14 +585,16 @@ def test_traced_chaos_poisson_covers_recovery(tiny):
     # schedule — warmup suspends both injector and tracer)
     engine.warmup([12], max_new_tokens=5)
     # ...so the dummy request leaves no tick and no request track: all
-    # there is are the set-up spans of the build and the warm-up of a
-    # split-tick engine, once each (no compile watcher is attached here)
-    assert not engine.mixed
+    # there is are the set-up spans of the build and the warm-up of the
+    # default engine — the unified tick: one bucket span a packed width,
+    # the op map once (no compile watcher is attached here)
+    assert engine.mixed
     assert Counter(
         (ev["cat"], ev["name"]) for ev in tracer.events() if ev["ph"] != "M"
     ) == Counter(("setup", name) for name in (
-        "probe.decode_attn", "pool_alloc", "probe.sample_epilogue",
-        "engine_build", "warmup.request", "warmup",
+        "probe.decode_attn", "probe.ragged_attn", "pool_alloc",
+        "probe.sample_epilogue", "engine_build", "warmup.request",
+        *["warmup.bucket"] * len(engine.mixed_buckets), "warmup", "op_map",
     )), "warmup must not pollute the timeline"
     rng = np.random.default_rng(11)
     reqs = [

@@ -214,9 +214,12 @@ def _self_check() -> None:
 
     cfg = tiny_config("llama")
     params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    # the phase-split tick's contract first (it has to be asked for: an
+    # engine built with no ``mixed_step`` is the unified tick, below)
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=16, block_size=8, max_seq_len=64, cache_dtype=jnp.float32,
+        mixed_step="off",
     )
     rng = np.random.default_rng(0)
     for n in (5, 9, 5, 13):
@@ -233,7 +236,8 @@ def _self_check() -> None:
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=32, block_size=8, max_seq_len=64, cache_dtype=jnp.float32,
-        decode_attn_impl="paged", enable_prefix_cache=True,
+        decode_attn_impl="paged", mixed_step="off",
+        enable_prefix_cache=True,
     )
     prompts = [rng.integers(1, 200, size=n) for n in (5, 9, 13, 17)]
     for _ in range(3):  # repeats after round 1 hit the prefix cache
@@ -576,7 +580,8 @@ def _self_check() -> None:
         eng = ServeEngine(
             mesh_params, mesh_cfg, sampler=Sampler(kind="greedy"),
             max_slots=2, num_blocks=32, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, mesh_plan=MeshPlan(model=2),
+            cache_dtype=jnp.float32, mixed_step="off",
+            mesh_plan=MeshPlan(model=2),
         )
         for p in mesh_prompts:
             eng.submit(p, 6)
