@@ -76,9 +76,19 @@ added to the block tables (probe-gated; XLA gather fallback).  The
 scheduler's token-budget planner (``Scheduler.plan_tick``) co-schedules
 chunked prefill with decode under ``tick_token_budget`` tokens per tick
 — decode rows first, so a long prefill can no longer stall the decoding
-batch (the PR-5 trace finding).  The packed width is bucketed
-(``mixed_buckets``), so the program compiles once per bucket and NEVER
-per tick, whatever the prefill:decode row mix (compile-counter lint).
+batch (the PR-5 trace finding).  The step's token axis is DENSE (one
+lane a token, ``dense_width``); the 8-lane query tiles the ragged kernel
+wants (``packed_width`` lanes) exist only inside attention, between two
+row gathers.  A program is the pair, and the set is small and fixed
+(``mixed_buckets``: today's doubling ladder of tile widths, each with
+ONE dense width — its capacity under the token budget — plus one
+program for the steady decode tick, ``max_slots`` one-tile rows at the
+width of their tokens), so the step compiles once per program and NEVER
+per tick, whatever the prefill:decode row mix (compile-counter lint),
+and warm-up pays for one program more than the ladder has rungs.
+``/metrics`` counts the dense lanes dispatched
+(``mixed_dense_lanes_total``) beside the tokens in them
+(``mixed_tokens_total``).
 
 Speculative serving (``spec_k=K``, unified tick only): per-request
 HOST-SIDE prompt-lookup draft streams (serve/spec.py) propose up to K
@@ -202,31 +212,44 @@ def _pack_sync(
 
 
 def mixed_operand_layout(
-    t_w: int, q_tile: int, max_slots: int, max_blocks: int, spec_w: int,
+    t_w: int, d_w: int, q_tile: int, max_slots: int, max_blocks: int,
+    spec_w: int,
 ) -> tuple[dict[str, tuple[int, tuple[int, ...]]], int]:
     """The unified step's ONE host-built operand, stated once: an int32
-    vector whose sections are the packed batch of width ``t_w`` —
-    ``{section: (offset, shape)}`` and the vector's length.  ``[T]``
-    token-level sections, ``[T/q_tile]`` tile metadata for the ragged
-    kernel, ``[max_slots, ..]`` row-level sections.  ``_pack_mixed``
-    writes through it, the jitted step slices by it
+    vector whose sections are the packed batch — ``{section: (offset,
+    shape)}`` and the vector's length.  The batch has TWO widths, and
+    the pair is the step's program.  ``d_w`` is the step's token axis,
+    DENSE: one lane a token, segments consecutive with no alignment;
+    every ``[D]`` token-level section, the embedding, qkv, the K/V
+    write, o_proj, the MLP and the tail's ``last_idx`` live on it.
+    ``t_w`` is the width INSIDE attention, where the ragged kernel wants
+    every segment aligned to ``q_tile`` so that a query tile belongs to
+    one row: ``[T/q_tile]`` tile metadata, and the two index maps that
+    tie the axes — ``lane_tok [T]``, the dense token each tile lane
+    reads (a dead lane reads token 0 and stays masked by ``tile_qlen``),
+    and ``tok_lane [D]``, the tile lane each token's result comes back
+    from.  ``[max_slots, ..]`` row-level sections follow.
+    ``_pack_mixed`` writes through it, the jitted step slices by it
     (``split_mixed_operands``), so host and device cannot drift.  Two
     sections are not int32 values: ``tok_live`` is a bool written 0 / 1,
-    ``seeds`` the bits of a uint32.  The length grows with ``t_w``, so a
-    bucket is still one aval and one compile."""
+    ``seeds`` the bits of a uint32.  The length grows with both widths:
+    a program is one aval and one compile, and an engine's programs have
+    lengths of their own (``mixed_operand_program`` inverts it)."""
     nt = t_w // q_tile
     shapes = {
-        "tokens": (t_w,),      # packed input ids
-        "positions": (t_w,),   # content positions (RoPE)
-        "tok_blk": (t_w,),     # pool block per token
-        "tok_off": (t_w,),     # in-block slot per token
-        "tok_row": (t_w,),     # owning engine row
-        "tok_slot": (t_w,),    # cache slot per token
-        "tok_live": (t_w,),    # 0 = packing lane
+        "tokens": (d_w,),      # packed input ids
+        "positions": (d_w,),   # content positions (RoPE)
+        "tok_blk": (d_w,),     # pool block per token
+        "tok_off": (d_w,),     # in-block slot per token
+        "tok_row": (d_w,),     # owning engine row
+        "tok_slot": (d_w,),    # cache slot per token
+        "tok_live": (d_w,),    # 0 = padding of the dense axis
+        "tok_lane": (d_w,),    # each token's lane of the tiled axis
+        "lane_tok": (t_w,),    # each tile lane's token
         "tile_row": (nt,), "tile_qpos0": (nt,), "tile_qlen": (nt,),
         "tables": (max_slots, max_blocks),  # scratch-0 padded
         "pads": (max_slots,),
-        "last_idx": (max_slots, spec_w),    # packed sample indices
+        "last_idx": (max_slots, spec_w),    # dense sample indices
         "sample_pos": (max_slots, spec_w),  # content position of each
         "seeds": (max_slots,),
         "verify_len": (max_slots,),         # live sample slots per row
@@ -255,17 +278,22 @@ def split_mixed_operands(ops: Any, layout: dict) -> dict[str, Any]:
     return sec
 
 
-def mixed_operand_width(size: int, q_tile: int, *geometry: int) -> int:
-    """The packed width whose operand is ``size`` words long: what the
-    jitted step, told nothing but its operand's aval, lays it out by."""
-    size0 = mixed_operand_layout(0, q_tile, *geometry)[1]
-    per_tile = mixed_operand_layout(q_tile, q_tile, *geometry)[1] - size0
-    t_w = (size - size0) // per_tile * q_tile
-    if mixed_operand_layout(t_w, q_tile, *geometry)[1] != size:
+def mixed_operand_program(
+    size: int, programs: Sequence[tuple[int, int]], *geometry: int,
+) -> tuple[int, int]:
+    """The ``(t_w, d_w)`` among a step's ``programs`` whose operand is
+    ``size`` words long: what the jitted step, told nothing but its
+    operand's aval, lays it out by.  Two widths do not follow from one
+    length in general, so the engine gives its programs lengths of
+    their own when it chooses them (``_make_buckets``)."""
+    found = [p for p in programs
+             if mixed_operand_layout(*p, *geometry)[1] == size]
+    if len(found) != 1:
         raise ValueError(
-            f"{size} words are no packed operand of q_tile {q_tile}, "
-            f"(max_slots, max_blocks, spec_w) {geometry}")
-    return t_w
+            f"{size} words are the packed operand of {found or 'none'} "
+            f"among the step's programs {tuple(programs)} (q_tile, "
+            f"max_slots, max_blocks, spec_w = {geometry})")
+    return found[0]
 
 
 def _roofline_targs(tel: dict) -> dict:
@@ -808,8 +836,8 @@ class ServeEngine:
             # the rest are discarded host-side, so the shape is static
             # whatever each tick's draft widths turn out to be
             self._spec_w = self.spec_k + 1
-            # what lays the step's packed operand out beside its width
-            # (mixed_operand_layout)
+            # what lays the step's packed operand out beside its two
+            # widths (mixed_operand_layout)
             self._mixed_geometry = (
                 self._q_tile, max_slots, self.max_blocks_per_seq,
                 self._spec_w,
@@ -829,10 +857,14 @@ class ServeEngine:
                 )
             self.tick_token_budget = budget
             self.mixed_buckets = self._make_buckets(budget, max_slots)
+            # stated once a program: the packer looks its layout up
+            self._mixed_layouts = {
+                p: mixed_operand_layout(*p, *self._mixed_geometry)
+                for p in self.mixed_buckets}
             self._mixed_step = self._make_mixed_step()
         else:
             self.tick_token_budget = 0
-            self.mixed_buckets: tuple[int, ...] = ()
+            self.mixed_buckets: tuple[tuple[int, int], ...] = ()
             # -- jitted programs (fixed set; tick loop never adds more)
             self._prefill_step = make_ragged_prefill_step(config)
             self._decode_step = self._make_decode_step(decode_attn_impl)
@@ -849,30 +881,68 @@ class ServeEngine:
                 "buckets": len(self.mixed_buckets),
             })
 
-    def _make_buckets(self, budget: int, max_slots: int) -> tuple[int, ...]:
-        """Packed-width buckets for the mixed step: a doubling ladder of
-        q-tile multiples capped by the worst aligned total (every planned
-        token plus per-row tile padding).  The mixed step compiles once
-        per bucket actually used — never per tick, never per
-        prefill:decode composition (compile-counter lint)."""
+    def _make_buckets(
+        self, budget: int, max_slots: int,
+    ) -> tuple[tuple[int, int], ...]:
+        """The mixed step's programs, ``(t_w, d_w)`` pairs in the order
+        ``_pick_bucket`` searches: the width inside attention (tile
+        lanes) and the width of the step's dense token axis.  Warm-up
+        compiles exactly these, so the set is kept SMALL: a warm program
+        still costs 0.7-1 s of every start (PERF.md §6, PR 30 / 31).
+
+        The tile ladder is a doubling ladder of q-tile multiples capped
+        by the worst aligned total (every planned token plus per-row
+        tile padding): the ragged kernel's time follows its grid, so a
+        tick gets the narrowest rung that holds its tiles.  Each rung
+        has ONE dense width, its capacity — a tick holds no more tokens
+        than its lanes, nor than the budget — because a finer dense
+        ladder buys nothing: under about 240 rows a bf16 matmul on a v5e
+        streams the same weights whatever its height.
+
+        The one exception is the steady decode tick, ``max_slots`` rows
+        of one tile each: at its rung's capacity (512 of 64 slots' 8-lane
+        tiles) qkv, o_proj, the MLP and the K/V scatter ran eight lanes
+        a token, compute-bound on lanes that hold nothing.  That rung
+        gets a second program as wide as the rows' tokens,
+        ``max_slots * (1 + spec_k)``."""
         qb = self._q_tile
         # each of up to max_slots segments wastes < qb lanes to alignment
         a_max = _ceil_to(budget + max_slots * (qb - 1), qb)
-        buckets = []
+        ladder = []
         t = qb
         while t < a_max:
-            buckets.append(t)
+            ladder.append(t)
             t *= 2
-        buckets.append(a_max)
-        return tuple(sorted(set(buckets)))
+        ladder.append(a_max)
+        cap = _ceil_to(budget, qb)
+        programs = [(t, min(t, cap)) for t in ladder]
+        t_rows = next(t for t in ladder if t >= max_slots * qb)
+        d_rows = _ceil_to(max_slots * self._spec_w, qb)
+        # the operand's length is all the jitted step knows of its
+        # program (mixed_operand_program): keep the lengths apart
 
-    def _pick_bucket(self, n: int) -> int:
-        for t in self.mixed_buckets:
-            if t >= n:
-                return t
+        def length(program: tuple[int, int]) -> int:
+            return mixed_operand_layout(*program, *self._mixed_geometry)[1]
+
+        taken = {length(p) for p in programs}
+        while d_rows < min(t_rows, cap) and length((t_rows, d_rows)) in taken:
+            d_rows += qb
+        if d_rows < min(t_rows, cap):
+            programs.append((t_rows, d_rows))
+        return tuple(sorted(set(programs)))
+
+    def _pick_bucket(self, n_lanes: int, n_tokens: int) -> tuple[int, int]:
+        """The program for a tick of ``n_tokens`` tokens whose segments
+        align to ``n_lanes`` tile lanes: the first, in ``(t_w, d_w)``
+        order, that holds both — so the narrowest attention rung that
+        holds the tiles, always, and on it the narrowest dense axis."""
+        for t_w, d_w in self.mixed_buckets:
+            if t_w >= n_lanes and d_w >= n_tokens:
+                return t_w, d_w
         raise AssertionError(
-            f"planner produced {n} aligned tokens > largest bucket "
-            f"{self.mixed_buckets[-1]} — budget accounting is broken"
+            f"planner produced {n_tokens} tokens aligned to {n_lanes} "
+            f"lanes > the largest program {self.mixed_buckets[-1]} — "
+            "budget accounting is broken"
         )
 
     # ------------------------------------------------------------------
@@ -1758,11 +1828,21 @@ class ServeEngine:
         (seed, content position) key derivation as both split-path
         samplers, so tokens are impl- and preemption-invariant).
 
+        The step's token axis is DENSE: one lane a token, ``D`` wide
+        from the embedding through qkv, the K/V scatter, o_proj and the
+        MLP to the tail.  The ragged kernel wants a row's tokens in
+        query tiles of their own, so the tile-aligned axis (``T`` lanes:
+        a decode row is one token and seven dead lanes) exists only
+        inside ``attn_fn``: a row gather spreads ``q`` over it, the
+        kernel runs, a row gather brings each token's result back.  Run
+        at ``T``, the matmuls of a full decode batch were compute-bound
+        on dead lanes (PERF.md §6, PR 30 / 31).
+
         The host hands the step ONE operand, an int32 vector whose
         static slices are the packed batch (``mixed_operand_layout``):
-        one transfer a tick, and its length is the bucket's.  One
-        compile per bucket, zero per tick (tools/compile_counter
-        lint)."""
+        one transfer a tick, and its length names the program
+        ``(T, D)``.  One compile per program, zero per tick
+        (tools/compile_counter lint)."""
         from llm_np_cp_tpu.ops.pallas.decode_attention import (
             ragged_paged_attention,
             ragged_paged_attention_xla,
@@ -1778,7 +1858,7 @@ class ServeEngine:
         big_win = jnp.int32(1 << 30)
         constrain_pages = self._constrain_pages
         carry_pool = self.pool_carried = _pool_is_row_major(self.pool.pages)
-        geometry = self._mixed_geometry
+        geometry, programs = self._mixed_geometry, self.mixed_buckets
         attn_call = self._shard_attn(
             partial(
                 ragged_paged_attention if use_kernel
@@ -1797,8 +1877,8 @@ class ServeEngine:
         ):
             with jax.named_scope(SCOPE_EMBED):
                 o = split_mixed_operands(ops, mixed_operand_layout(
-                    mixed_operand_width(ops.shape[0], *geometry), *geometry
-                )[0])
+                    *mixed_operand_program(ops.shape[0], programs, *geometry),
+                    *geometry)[0])
                 tokens, tables, pads = o["tokens"], o["tables"], o["pads"]
                 tok_blk, tok_off = o["tok_blk"], o["tok_off"]
                 tok_row, tok_slot = o["tok_row"], o["tok_slot"]
@@ -1806,7 +1886,8 @@ class ServeEngine:
                 tile_row, tile_qpos0 = o["tile_row"], o["tile_qpos0"]
                 tile_qlen, verify_len = o["tile_qlen"], o["verify_len"]
                 last_idx, sample_pos = o["last_idx"], o["sample_pos"]
-                x = embed_inputs(params, tokens[None, :], config)  # [1, T, H]
+                lane_tok, tok_lane = o["lane_tok"], o["tok_lane"]
+                x = embed_inputs(params, tokens[None, :], config)  # [1, D, H]
                 cos, sin = rope_cos_sin(
                     o["positions"][None, :], config, dtype=jnp.float32
                 )
@@ -1836,10 +1917,10 @@ class ServeEngine:
                     x, (kp, vp, *scale_pages), base = carry, slabs, 0
                 blk = base + tok_blk
 
-                def kv_update(k, v):  # fresh projections [1, T, K, D]
-                    # dead lanes all write (this layer's scratch block 0,
-                    # slot 0) — duplicate scatter indices there are
-                    # harmless
+                def kv_update(k, v):  # fresh projections [1, D, K, Dh]
+                    # the few lanes that pad the dense axis all write
+                    # (this layer's scratch block 0, slot 0) — duplicate
+                    # scatter indices there are harmless
                     if quantized:
                         ksp, vsp = scale_pages
                         kq, ks = quantize_kv(k)
@@ -1872,10 +1953,13 @@ class ServeEngine:
                     # block ids": this layer's ids in the pool it is given
                     layer_tables = tables + base
                     if use_kernel:
+                        # the one place the tile-aligned axis exists:
+                        # spread the tokens over their tiles, attend,
+                        # bring each token's row back
                         out = attn_call(
-                            q[0], kp2, vp2, *scales, layer_tables, tile_row,
-                            tile_qpos0, tile_qlen, pads, win_eff,
-                        )
+                            q[0][lane_tok], kp2, vp2, *scales, layer_tables,
+                            tile_row, tile_qpos0, tile_qlen, pads, win_eff,
+                        )[tok_lane]
                     else:
                         out = attn_call(
                             q[0], kp2, vp2, *scales, layer_tables, tok_row,
@@ -1906,11 +1990,11 @@ class ServeEngine:
                                  unroll=scan_unroll(config))
                 new_pages = PagedKV(*ys)
             new_pages = constrain_pages(new_pages)
-            # sampling ONLY at each row's sample slots — [R, W] packed
-            # indices: column 0 is the plain sample (decode rows and
-            # completing prefill segments), columns 1..k' are a
+            # sampling ONLY at each row's sample slots — [R, W] indices
+            # into the dense axis: column 0 is the plain sample (decode
+            # rows and completing prefill segments), columns 1..k' are a
             # speculating row's verify positions; unused slots point at
-            # packed index 0 and their draw is discarded host-side.
+            # token 0 and their draw is discarded host-side.
             # Keys derive from (seed, content position) per slot, so a
             # verify sample at position p is BIT-IDENTICAL to the plain
             # decode draw at p — the accept walk's whole parity story.
@@ -2962,16 +3046,22 @@ class ServeEngine:
         self,
         decode_rows: list[Request],
         prefill_segs: list[tuple[Request, int]],
-    ) -> tuple[np.ndarray, int, int]:
+    ) -> tuple[np.ndarray, tuple[int, int], int]:
         """Build the mixed step's packed operand from the planner's
         verdict: ONE host int32 array (``mixed_operand_layout``; the
-        tick places it with one ``_put``), its packed width, and how
-        many rows the array path filled.  Each row's token segment
-        lands at consecutive, q-tile-aligned packed positions, decode
-        rows first (dead alignment lanes point at the scratch block and
-        are masked); the packed width is the smallest bucket covering
-        the aligned total, so the dispatch reuses a warm compile
-        whatever the prefill:decode mix.
+        tick places it with one ``_put``), its program ``(t_w, d_w)``,
+        and how many rows the array path filled.  The batch is laid out
+        on TWO axes.  On the dense one — the step's token axis — each
+        row's token segment lands at consecutive lanes with no
+        alignment, decode rows first, and the only dead lanes are the
+        tail up to ``d_w`` (they point at the scratch block).  On the
+        tiled one — the ragged kernel's — every segment starts at a
+        q-tile multiple, so a tile belongs to one row; ``lane_tok`` /
+        ``tok_lane`` tie the two (dead tile lanes read token 0 and are
+        masked by ``tile_qlen``).  The program is the first that holds
+        the aligned total and the token total (``_pick_bucket``), so
+        the dispatch reuses a warm compile whatever the prefill:decode
+        mix.
 
         A decode row with no drafts is one token in one tile: all of
         them are written together (``_fill_decode_rows``).  Speculating
@@ -2980,41 +3070,47 @@ class ServeEngine:
         qb = self._q_tile
         sizes = [1 + r.draft_len for r in decode_rows]
         sizes.extend(n for _, n in prefill_segs)
-        starts = list(itertools.accumulate(
+        dense = list(itertools.accumulate(sizes, initial=0))
+        tiled = list(itertools.accumulate(
             (_ceil_to(n, qb) for n in sizes), initial=0))
-        t_w = self._pick_bucket(max(starts[-1], qb))
-        layout, size = self._mixed_layout(t_w)
+        program = self._pick_bucket(tiled[-1], dense[-1])
+        layout, size = self._mixed_layouts[program]
         ops = np.zeros(size, np.int32)
         sec = split_mixed_operands(ops, layout)
-        plain = [(r, cur) for r, cur in zip(decode_rows, starts)
+        plain = [(r, cur, lane)
+                 for r, cur, lane in zip(decode_rows, dense, tiled)
                  if not r.draft_len]
         if plain:
             self._fill_decode_rows(sec, *zip(*plain))
-        for r, cur in zip(decode_rows, starts):
+        for r, cur, lane in zip(decode_rows, dense, tiled):
             if not r.draft_len:
                 continue
             toks = [r.generated[-1]]
             toks.extend(int(t) for t in r.extra["spec_draft"][: r.draft_len])
             self._fill_segment(sec, r, np.asarray(toks, np.int32),
-                               r.cache_len - 1, len(toks), cur)
-        for (r, n), cur in zip(prefill_segs, starts[len(decode_rows):]):
+                               r.cache_len - 1, len(toks), cur, lane)
+        n_dec = len(decode_rows)
+        for (r, n), cur, lane in zip(
+                prefill_segs, dense[n_dec:], tiled[n_dec:]):
             content = r.extra["prefill_content"]
             self._fill_segment(
                 sec, r,
                 np.asarray(content[r.prefill_done:r.prefill_done + n],
                            np.int32),
                 r.pad + r.prefill_done,
-                1 if r.prefill_done + n >= r.prefill_target else 0, cur)
-        return ops, t_w, len(plain)
+                1 if r.prefill_done + n >= r.prefill_target else 0,
+                cur, lane)
+        return ops, program, len(plain)
 
     def _fill_decode_rows(self, sec: dict[str, np.ndarray],
                           rows: Sequence[Request],
+                          curs: Sequence[int],
                           lanes: Sequence[int]) -> None:
-        """Plain decode rows — one token, one tile, one sample slot each
-        — written into the operand's sections by whole-array
-        assignments: what ``_fill_segment`` writes for each, without a
-        numpy call per row and field.  The block table is the one write
-        left per row."""
+        """Plain decode rows — one token at dense index ``curs[i]``, one
+        tile at lane ``lanes[i]``, one sample slot each — written into
+        the operand's sections by whole-array assignments: what
+        ``_fill_segment`` writes for each, without a numpy call per row
+        and field.  The block table is the one write left per row."""
         bs = self.block_size
         tables = sec["tables"]
         slot, tok, sl, pad, seed, blk = [], [], [], [], [], []
@@ -3031,32 +3127,36 @@ class ServeEngine:
         slot = np.asarray(slot, np.intp)
         sl = np.asarray(sl, np.int32)
         pos = sl - np.asarray(pad, np.int32)
+        cur = np.asarray(curs, np.intp)
         lane = np.asarray(lanes, np.intp)
         tile = lane // self._q_tile
-        sec["tokens"][lane] = tok
-        sec["positions"][lane] = pos
-        sec["tok_blk"][lane] = blk
-        sec["tok_off"][lane] = sl % bs
-        sec["tok_row"][lane] = slot
-        sec["tok_slot"][lane] = sl
-        sec["tok_live"][lane] = 1
+        sec["tokens"][cur] = tok
+        sec["positions"][cur] = pos
+        sec["tok_blk"][cur] = blk
+        sec["tok_off"][cur] = sl % bs
+        sec["tok_row"][cur] = slot
+        sec["tok_slot"][cur] = sl
+        sec["tok_live"][cur] = 1
+        sec["tok_lane"][cur] = lane
+        sec["lane_tok"][lane] = cur
         sec["tile_row"][tile] = slot
         sec["tile_qpos0"][tile] = sl
         sec["tile_qlen"][tile] = 1
         sec["pads"][slot] = pad
         sec["seeds"][slot] = np.asarray(seed, np.uint32)
         sec["verify_len"][slot] = 1
-        sec["last_idx"][slot, 0] = lane
+        sec["last_idx"][slot, 0] = cur
         sec["sample_pos"][slot, 0] = pos
 
     def _fill_segment(self, sec: dict[str, np.ndarray], r: Request,
                       toks: np.ndarray, start_slot: int, n_verify: int,
-                      cur: int) -> None:
-        """One row's token segment at packed position ``cur`` (a q-tile
-        multiple): its tokens occupy cache slots ``start_slot..`` and
-        its LAST ``n_verify`` tokens are sampled — a speculating row
-        samples its whole verify slice (input + drafts), a completing
-        prefill 1 (its last token), a mid-prefill chunk 0."""
+                      cur: int, lane: int) -> None:
+        """One row's token segment at dense index ``cur`` and tile lane
+        ``lane`` (a q-tile multiple): its tokens occupy cache slots
+        ``start_slot..`` and its LAST ``n_verify`` tokens are sampled —
+        a speculating row samples its whole verify slice (input +
+        drafts), a completing prefill 1 (its last token), a mid-prefill
+        chunk 0."""
         qb, bs = self._q_tile, self.block_size
         n = toks.size
         slot = r.slot
@@ -3064,7 +3164,8 @@ class ServeEngine:
         sec["tables"][slot, :blocks.size] = blocks
         sec["pads"][slot] = r.pad
         sec["seeds"][slot] = np.uint32(r.seed)
-        sl = start_slot + np.arange(n, dtype=np.int32)
+        idx = np.arange(n, dtype=np.int32)
+        sl = start_slot + idx
         sec["tokens"][cur:cur + n] = toks
         sec["positions"][cur:cur + n] = sl - r.pad
         sec["tok_blk"][cur:cur + n] = blocks[sl // bs]
@@ -3072,8 +3173,10 @@ class ServeEngine:
         sec["tok_row"][cur:cur + n] = slot
         sec["tok_slot"][cur:cur + n] = sl
         sec["tok_live"][cur:cur + n] = 1
+        sec["tok_lane"][cur:cur + n] = lane + idx
+        sec["lane_tok"][lane:lane + n] = cur + idx
         q0 = np.arange(0, n, qb)  # each tile's first token
-        tiles = slice(cur // qb, cur // qb + q0.size)
+        tiles = slice(lane // qb, lane // qb + q0.size)
         sec["tile_row"][tiles] = slot
         sec["tile_qpos0"][tiles] = start_slot + q0
         sec["tile_qlen"][tiles] = np.minimum(qb, n - q0)
@@ -3243,7 +3346,8 @@ class ServeEngine:
 
         tp = th = t4 = t5 = t3
         cpu4 = cpu5 = 0
-        ctx_tokens = packed_width = array_rows = h2d_count = h2d_bytes = 0
+        ctx_tokens = array_rows = h2d_count = h2d_bytes = 0
+        packed_width = dense_width = 0
         n_prefill_tok = sum(n for _, n in prefill_segs)
         n_decode_tok = len(decode_rows)
         # drafts actually packed (post-trim) / accepted by the verifier
@@ -3259,8 +3363,8 @@ class ServeEngine:
                 cost = self.telemetry.mixed_tick_cost(
                     self, decode_rows, prefill_segs
                 )
-            host_ops, packed_width, array_rows = self._pack_mixed(
-                decode_rows, prefill_segs)
+            host_ops, (packed_width, dense_width), array_rows = (
+                self._pack_mixed(decode_rows, prefill_segs))
             if self.tracer is not None:
                 # what this dispatch attends: every row's live content
                 # after its tokens land (left pad excluded), read HERE —
@@ -3398,6 +3502,7 @@ class ServeEngine:
             ),
             prefill_tokens=n_prefill_tok,
             decode_tokens=n_decode_tok,
+            dense_lanes=dense_width,
         )
         outliers: list[dict] = []
         if self.tracer is not None and t0 >= 0.0:
@@ -3409,9 +3514,12 @@ class ServeEngine:
                 "prefill_tokens": n_prefill_tok,
                 "decode_tokens": n_decode_tok,
                 # the dispatch as the device sees it: the live context
-                # its rows attend (summed over rows), the bucket
+                # its rows attend (summed over rows), and the program —
+                # the width inside attention (tile lanes) and the width
+                # of the step's dense token axis
                 "context_tokens": ctx_tokens,
                 "packed_width": packed_width,
+                "dense_width": dense_width,
                 # rows _pack_mixed wrote by whole-array assignments
                 # (plain decode rows): how much of pack went the fast way
                 "pack_array_rows": array_rows,
@@ -3558,23 +3666,18 @@ class ServeEngine:
         return int(mixed_tick_kv_read(self, decode_rows, prefill_segs,
                                       per_request=False)[0])
 
-    def _mixed_layout(self, t_w: int) -> tuple[dict, int]:
-        """``mixed_operand_layout`` of this engine's step at packed
-        width ``t_w``."""
-        return mixed_operand_layout(t_w, *self._mixed_geometry)
-
-    def _dead_mixed_operands(self, t_w: int) -> np.ndarray:
-        """The mixed step's operand for an all-dead batch of packed
-        width ``t_w`` (a host array): every lane points at the scratch
+    def _dead_mixed_operands(self, t_w: int, d_w: int) -> np.ndarray:
+        """The mixed step's operand for an all-dead batch of the program
+        ``(t_w, d_w)`` (a host array): every lane points at the scratch
         block and is fully masked."""
-        return np.zeros(self._mixed_layout(t_w)[1], np.int32)
+        return np.zeros(self._mixed_layouts[t_w, d_w][1], np.int32)
 
-    def _warm_mixed_bucket(self, t_w: int) -> None:
-        """Compile one packed-width bucket with an all-dead batch, so the
-        only effect is the compile (and a garbage write to scratch)."""
+    def _warm_mixed_bucket(self, t_w: int, d_w: int) -> None:
+        """Compile one program with an all-dead batch, so the only effect
+        is the compile (and a garbage write to scratch)."""
         out, self.pool.pages = self._mixed_step(
             self.params, self.pool.pages,
-            self._put(self._dead_mixed_operands(t_w)),
+            self._put(self._dead_mixed_operands(t_w, d_w)),
         )
         np.asarray(out)  # block until the compile lands
 
@@ -3605,9 +3708,9 @@ class ServeEngine:
             opmap.op_map_from_hlo(
                 self._mixed_step.lower(
                     self.params, self.pool.pages,
-                    self._put(self._dead_mixed_operands(t_w)),
+                    self._put(self._dead_mixed_operands(*program)),
                 ).compile().as_text(), STEP_SCOPES, pool)
-            for t_w in self.mixed_buckets
+            for program in self.mixed_buckets
         )
 
     def _dispatch_decode(self, *args: jnp.ndarray) -> tuple:
@@ -3788,20 +3891,20 @@ class ServeEngine:
             # ...and the spill-path slicer (same traced-index contract)
             self._slice_block(self.pool.pages, self._put(np.int32(0)))
         if self.mixed:
-            # one compile per packed-width bucket — the dummy request
-            # covered whichever buckets its own ticks picked; warm the
-            # rest directly so mid-traffic composition churn can never
-            # trigger a compile stall
-            for t_w in self.mixed_buckets:
+            # one compile per program — the dummy request covered
+            # whichever its own ticks picked; warm the rest directly so
+            # mid-traffic composition churn can never trigger a compile
+            # stall
+            for t_w, d_w in self.mixed_buckets:
                 t_b = tracer.now_us() if tracer is not None else -1.0
                 missed = tracer.compile_misses if tracer is not None else 0
-                self._warm_mixed_bucket(t_w)
+                self._warm_mixed_bucket(t_w, d_w)
                 if tracer is not None:
                     # compiled: a backend compile ran (the dummy request
                     # above, or the persistent cache, had not covered it)
                     tracer.complete(
                         "warmup.bucket", t_b, cat="setup", args={
-                            "width": t_w,
+                            "width": t_w, "dense": d_w,
                             "compiled": tracer.compile_misses > missed,
                         })
             if self.pool.prefix_cache is not None:

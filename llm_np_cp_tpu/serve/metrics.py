@@ -175,6 +175,10 @@ class ServeMetrics:
         # budget was actually spent — exact counters, never trimmed
         self.mixed_prefill_tokens = 0
         self.mixed_decode_tokens = 0
+        # ...and the lanes of the step's dense token axis it was
+        # dispatched at, summed over dispatches: tokens / lanes is the
+        # share of the matmuls' rows that hold a token
+        self.mixed_dense_lanes = 0
         # speculative draft-then-verify accounting (exact counters +
         # a real accept-length histogram over SPEC_ACCEPT_BUCKETS —
         # one observation per verify round, value = accepted drafts)
@@ -232,10 +236,12 @@ class ServeMetrics:
         self, *, queue_depth: int, occupancy: float, active_slots: int,
         preemptions_total: int, kv_bytes: int = 0,
         prefill_tokens: int = 0, decode_tokens: int = 0,
+        dense_lanes: int = 0,
     ) -> None:
         with self._lock:
             self.mixed_prefill_tokens += prefill_tokens
             self.mixed_decode_tokens += decode_tokens
+            self.mixed_dense_lanes += dense_lanes
             self.n_ticks += 1
             self.t_last = self.clock()
             self.queue_depth.append(queue_depth)
@@ -448,6 +454,7 @@ class ServeMetrics:
                 out["tier_breakeven_ratio"] = self.tier_breakeven or 0.0
             out["mixed_prefill_tokens"] = self.mixed_prefill_tokens
             out["mixed_decode_tokens"] = self.mixed_decode_tokens
+            out["mixed_dense_lanes"] = self.mixed_dense_lanes
             if self.spec_rounds:
                 # reported only once a verify round ran (like the SLO
                 # block): a fabricated 0-acceptance series on a
@@ -626,6 +633,11 @@ class ServeMetrics:
              "Unified-tick token budget spent, split by work kind",
              [('{kind="prefill"}', s["mixed_prefill_tokens"]),
               ('{kind="decode"}', s["mixed_decode_tokens"])])
+        emit("mixed_dense_lanes_total", "counter",
+             "Lanes of the unified step's dense token axis, summed over "
+             "dispatches (mixed_tokens_total / this = the share of "
+             "lanes that hold a token)",
+             [("", s["mixed_dense_lanes"])])
         # -- speculative decoding (only once a verify round ran — a
         # constant-zero series on a plain engine would read as a broken
         # speculation deployment on a fleet dashboard)

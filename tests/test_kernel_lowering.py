@@ -220,7 +220,10 @@ def test_every_kernel_case_compiles_for_v5e(v5e_sharding, case):
 SLOTS, BLOCKS, BLOCK, CHUNK = 4, 256, 64, 64
 
 
-def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS):
+def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS, slots=SLOTS,
+                           program=None):
+    """The unified step of a small Qwen2.5-shaped engine, compiled for a
+    described v5e as ``program`` (its widest by default)."""
     cfg = tiny_config(
         "qwen2", num_hidden_layers=3, hidden_size=1536,
         intermediate_size=1024, num_attention_heads=12,
@@ -229,7 +232,7 @@ def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS):
     abstract = jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
     engine = ServeEngine(
-        abstract, cfg, max_slots=SLOTS, num_blocks=blocks, block_size=BLOCK,
+        abstract, cfg, max_slots=slots, num_blocks=blocks, block_size=BLOCK,
         max_seq_len=BLOCK * 8, prefill_chunk=CHUNK, cache_dtype=cache_dtype,
         mixed_step="on",
     )
@@ -240,7 +243,9 @@ def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS):
 
     avals = [jax.tree.map(aval, abstract),
              jax.tree.map(aval, engine.pool.pages)]
-    avals.append(aval(engine._dead_mixed_operands(engine.mixed_buckets[-1])))
+    program = program or engine.mixed_buckets[-1]
+    assert program in engine.mixed_buckets
+    avals.append(aval(engine._dead_mixed_operands(*program)))
     # the kernels pick interpret mode from the backend they see: show
     # them the one they are being compiled for
     real = jax.default_backend
@@ -272,12 +277,12 @@ def test_compiled_tick_writes_the_pool_in_place(v5e_sharding, cache_dtype):
         f"{k_slab_bytes} B: a copy of (part of) the pool is back")
 
     # besides params and pool the program takes ONE operand: the packed
-    # int32 vector of the widest bucket (one transfer a tick)
+    # int32 vector of the widest program (one transfer a tick)
     text = compiled.as_text()
     ints = re.findall(r"= ((?:[su]\d+|pred)\[[\d,]*\])\S* parameter\(",
                       text[text.index("\nENTRY"):])
     assert ints == [opmap.hlo_shape("int32", (
-        engine._mixed_layout(engine.mixed_buckets[-1])[1],))]
+        engine._mixed_layouts[engine.mixed_buckets[-1]][1],))]
 
     ops = _pool_ops(engine, compiled)
     assert {scope for scope, _, _ in ops.values()} >= set(STEP_SCOPES)
@@ -293,6 +298,135 @@ def test_compiled_tick_writes_the_pool_in_place(v5e_sharding, cache_dtype):
         pages.k.dtype.name,
         (pages.k.shape[0] * pages.k.shape[1],) + pages.k.shape[2:])
     assert [v[1] for v in writes] == [flat, flat]
+
+
+_FLOATS = re.compile(r"\b(?:bf16|f32)\[([\d,]*)\]")
+
+
+def _ragged_kernel_call(hlo_text):
+    """The ragged kernel as a compiled module calls it: (result shape,
+    operand layout constraints, the Mosaic module's text with its debug
+    locations dropped — they name the Python frames of the caller)."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    line, = (ln for ln in hlo_text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "ragged_paged_attention" in ln.split("=", 1)[0])
+    result = line.split("=", 1)[1].split("custom-call(", 1)[0].strip()
+    operands = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                         line).group(1)
+    cfg = line[line.index("backend_config=") + len("backend_config="):]
+    cfg = json.loads(cfg[:cfg.rindex("}") + 1])
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True  # ("stable_mosaic", serialized)
+    with ctx:
+        module = ir.Module.parse(
+            base64.b64decode(cfg["custom_call_config"]["body"]))
+        return result, operands, module.operation.get_asm(
+            enable_debug_info=False)
+
+
+def test_decode_program_is_dense_outside_attention(v5e_sharding):
+    """The steady decode tick of 64 rows, as a v5e runs it — the program
+    ``(512 tile lanes, 64 tokens)``.  The step's token axis is 64 wide:
+    the MLP's matmuls are ``[64, ffn]``, the K/V scatter writes 64 rows,
+    and the 512 lanes of the rows' query tiles exist only under scope
+    ``attn``, between the two row gathers (at 512 the matmuls were
+    compute-bound on lanes that hold nothing: PERF.md §6, PR 30 / 31).
+    The pool is still the donated buffer written in place.  And the
+    ragged kernel is the parent's call at the same 64 tiles, to the
+    byte: what this program changed is around it."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        ragged_paged_attention,
+    )
+
+    t_w, d_w, slots = 512, 64, 64
+    engine, compiled = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, blocks=600, slots=slots,
+        program=(t_w, d_w))
+    assert (t_w, d_w) == min(p for p in engine.mixed_buckets if p[0] == t_w)
+    cfg, pages = engine.config, engine.pool.pages
+    text = compiled.as_text()
+    ops = _pool_ops(engine, compiled)
+
+    def floats(shape):
+        return [tuple(int(x) for x in dims.split(",") if x)
+                for dims in _FLOATS.findall(shape)]
+
+    by_scope = {}
+    for name, (scope, shape, _) in ops.items():
+        if not scope and name.startswith("slice-"):
+            # the fused tail streams the tied head in vocabulary tiles of
+            # 512 rows: a weight slice, not a token axis
+            assert floats(shape)[-1] == (512, cfg.hidden_size)
+            continue
+        by_scope.setdefault(scope, []).extend(
+            (name, dims) for dims in floats(shape))
+    # the tiled width: under ``attn`` (the gathered q, the kernel, its
+    # result) and nowhere else
+    assert any(t_w in dims for _, dims in by_scope["attn"])
+    tiled_outside = {
+        scope: [(n, dims) for n, dims in arrays if t_w in dims]
+        for scope, arrays in by_scope.items() if scope != "attn"}
+    assert not any(tiled_outside.values()), tiled_outside
+    # the matmuls: every array as wide as the MLP's or a projection's
+    # output has the dense width beside it
+    f, h = cfg.intermediate_size, cfg.hidden_size
+    for scope, feature in (("mlp", f), ("mlp", h), ("o_proj", h),
+                           ("qkv", h)):
+        wide = [dims for _, dims in by_scope[scope]
+                if feature in dims and len(dims) > 1]  # (not a norm's scale)
+        assert wide, (scope, feature)
+        assert all(set(dims) - {1} == {d_w, feature} for dims in wide), (
+            scope, wide)
+    # the scatter: ``max_slots`` rows of fresh K (and V) a layer
+    kh, hd = cfg.num_key_value_heads, cfg.head_dim
+    scatters = [ln for ln in text.splitlines()
+                if " scatter(" in ln and "kv_write" in ln]
+    assert len(scatters) == 2, scatters
+    for ln in scatters:  # scatter(pool, indices, updates): by definition
+        shapes = [re.search(rf"{re.escape(arg)} = (\w+\[[\d,]*\])", text)
+                  .group(1) for arg in re.findall(r"%[\w.-]+", ln.split(
+                      " scatter(", 1)[1].split(")", 1)[0])]
+        assert shapes[1:] == [
+            opmap.hlo_shape("int32", (slots, 2)),  # (block, slot) a row
+            opmap.hlo_shape(pages.k.dtype.name, (slots, kh, hd))], shapes
+    # ...into the pool, which is still the result: nothing pool- or
+    # slab-sized beside it, nothing pool-shaped outside the write
+    k_slab_bytes = int(np.prod(pages.k.shape[1:])) * pages.k.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < min(k_slab_bytes, 1 << 20)
+    assert mem.alias_size_in_bytes >= sum(
+        a.nbytes for a in pages if a is not None)
+    assert {v[2] for v in ops.values() if v[0] != SCOPE_KV_WRITE} == {""}
+
+    # the parent's call: q tile-aligned all the way, ``[512, H, D]``
+    # straight into the kernel, on the same pool and tables
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+
+    flat = (pages.k.shape[0] * pages.k.shape[1],) + pages.k.shape[2:]
+    i32, nt = jnp.int32, t_w // engine._q_tile
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        parent = jax.jit(functools.partial(
+            ragged_paged_attention, scale=cfg.attn_scale,
+            logit_softcap=cfg.attn_logit_softcapping,
+        )).lower(
+            aval((t_w, cfg.num_attention_heads, hd), pages.k.dtype),
+            aval(flat, pages.k.dtype), aval(flat, pages.k.dtype),
+            aval((slots, engine.max_blocks_per_seq), i32),
+            aval((nt,), i32), aval((nt,), i32), aval((nt,), i32),
+            aval((slots,), i32), aval((), i32),
+        ).compile()
+    finally:
+        jax.default_backend = real
+    assert _ragged_kernel_call(text) == _ragged_kernel_call(parent.as_text())
 
 
 def test_int8_pool_on_a_v5e_is_why_the_slab_form_stays(

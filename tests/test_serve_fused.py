@@ -485,10 +485,10 @@ def _iter_param_eqns(v, *, skip_pallas):
             yield from _iter_param_eqns(x, skip_pallas=skip_pallas)
 
 
-def _mixed_step_shapes(engine, t_w, *, skip_pallas):
+def _mixed_step_shapes(engine, program, *, skip_pallas):
     jaxpr = jax.make_jaxpr(lambda ops: engine._mixed_step(
         engine.params, engine.pool.pages, ops
-    ))(jnp.asarray(engine._dead_mixed_operands(t_w)))
+    ))(jnp.asarray(engine._dead_mixed_operands(*program)))
     return {
         tuple(v.aval.shape)
         for eqn in _iter_eqns(jaxpr.jaxpr, skip_pallas=skip_pallas)
@@ -507,8 +507,8 @@ def test_fused_mixed_step_never_materializes_logits(tiny):
     v = cfg.vocab_size
 
     def logits_shapes(engine):
-        t_w = engine.mixed_buckets[0]
-        shapes = _mixed_step_shapes(engine, t_w, skip_pallas=True)
+        shapes = _mixed_step_shapes(
+            engine, engine.mixed_buckets[0], skip_pallas=True)
         return {s for s in shapes
                 if len(s) >= 2 and s[-1] == v and s[-2] != v}
 

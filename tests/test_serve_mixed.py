@@ -30,7 +30,7 @@ from llm_np_cp_tpu.ops.sampling import Sampler
 from llm_np_cp_tpu.serve import ServeEngine, poisson_trace
 from llm_np_cp_tpu.serve.engine import (
     mixed_operand_layout,
-    mixed_operand_width,
+    mixed_operand_program,
     split_mixed_operands,
 )
 from tools.compile_counter import (
@@ -57,9 +57,10 @@ def _engine(cfg, params, mixed="auto", **kw):
 
 
 def _sections(engine, ops):
-    """The sections of a packed operand of any of the engine's widths."""
-    t_w = mixed_operand_width(ops.shape[0], *engine._mixed_geometry)
-    return split_mixed_operands(ops, engine._mixed_layout(t_w)[0])
+    """The sections of a packed operand of any of the engine's programs."""
+    program = mixed_operand_program(
+        ops.shape[0], engine.mixed_buckets, *engine._mixed_geometry)
+    return split_mixed_operands(ops, engine._mixed_layouts[program][0])
 
 
 def _tokens(engine):
@@ -562,18 +563,19 @@ def test_mixed_step_has_no_materialized_gather(tiny, monkeypatch):
     """Structural zero-gather assertion on the served program: the
     gathered cache view the XLA ragged attention builds — every row's
     blocks contiguous, ``[rows, S_max, K, D]``, and one copy a packed
-    token, ``[T, S_max, K, D]`` — is in the fallback step's jaxpr
-    (detector sanity) and in NO eqn of the Pallas step's outside the
-    kernel, at any packed width."""
+    token, ``[D, S_max, K, Dh]`` on the dense axis the XLA twin is
+    handed — is in the fallback step's jaxpr (detector sanity) and in NO
+    eqn of the Pallas step's outside the kernel, on either of its axes,
+    in any of its programs."""
     import llm_np_cp_tpu.ops.pallas.support as support
 
     cfg, params = tiny
     kh, d = cfg.num_key_value_heads, cfg.head_dim
 
-    def shapes(engine, t_w):
+    def shapes(engine, program):
         jaxpr = jax.make_jaxpr(lambda ops: engine._mixed_step(
             engine.params, engine.pool.pages, ops
-        ))(jnp.asarray(engine._dead_mixed_operands(t_w)))
+        ))(jnp.asarray(engine._dead_mixed_operands(*program)))
         return {tuple(v.aval.shape) for eqn in _iter_eqns(jaxpr.jaxpr)
                 for v in eqn.outvars if hasattr(v.aval, "shape")}
 
@@ -584,16 +586,17 @@ def test_mixed_step_has_no_materialized_gather(tiny, monkeypatch):
     xla = _engine(cfg, params, mixed="on")
     assert xla.ragged_attn_impl == "xla"
     rows, s_max = pallas.scheduler.max_slots, pallas.max_seq_len
-    for t_w in pallas.mixed_buckets:
-        gathered = {(rows, s_max, kh, d), (t_w, s_max, kh, d)}
-        assert gathered <= shapes(xla, t_w), (
+    assert xla.mixed_buckets == pallas.mixed_buckets
+    for t_w, d_w in pallas.mixed_buckets:
+        gathered = {(rows, s_max, kh, d), (d_w, s_max, kh, d)}
+        assert gathered <= shapes(xla, (t_w, d_w)), (
             "control failed: the XLA ragged attention no longer "
             "materializes the gathered view — update the shapes here"
         )
-        hit = gathered & shapes(pallas, t_w)
+        hit = (gathered | {(t_w, s_max, kh, d)}) & shapes(pallas, (t_w, d_w))
         assert not hit, (
             f"the Pallas step materialized a gathered cache view {hit} "
-            f"at packed width {t_w} — the zero-gather contract is broken"
+            f"in program {t_w}x{d_w} — the zero-gather contract is broken"
         )
 
 
@@ -656,26 +659,40 @@ def test_mixed_rejects_bad_config(tiny):
 # ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("spec_w", [1, 4], ids=["w1", "w4"])
-@pytest.mark.parametrize("t_w", [8, 64, 200])
-def test_packed_operand_round_trips(t_w, spec_w):
+@pytest.mark.parametrize("program", [(8, 8), (64, 16), (200, 72)],
+                         ids=["8x8", "64x16", "200x72"])
+def test_packed_operand_round_trips(program, spec_w):
     """What the host writes through the layout's views is what the
     jitted step's slices read, section by section, in the shapes and
     dtypes the 16 separate operands had — ``tok_live`` a bool, ``seeds``
-    a uint32 above 2**31 included — and the vector's length names the
-    bucket."""
+    a uint32 above 2**31 included — with the token-level sections on the
+    dense width, the tile metadata on the tiled one and an index map
+    each way between them; and the vector's length names the program
+    among a step's programs."""
+    t_w, d_w = program
     geometry = (8, 5, 6, spec_w)  # q_tile, slots, blocks a row, W
-    layout, size = mixed_operand_layout(t_w, *geometry)
-    assert mixed_operand_width(size, *geometry) == t_w
-    with pytest.raises(ValueError, match="no packed operand"):
-        mixed_operand_width(size + 1, *geometry)
-    sizes = [mixed_operand_layout(w, *geometry)[1] for w in (8, 16, 64, 200)]
-    assert sizes == sorted(set(sizes))  # one aval a bucket
+    layout, size = mixed_operand_layout(t_w, d_w, *geometry)
+    programs = [(8, 8), (16, 16), (64, 16), (64, 64), (200, 72)]
+    assert mixed_operand_program(size, programs, *geometry) == program
+    with pytest.raises(ValueError, match="none among the step's programs"):
+        mixed_operand_program(size + 1, programs, *geometry)
+    with pytest.raises(ValueError, match="among the step's programs"):
+        mixed_operand_program(size, programs + [program], *geometry)
+    sizes = [mixed_operand_layout(*p, *geometry)[1] for p in programs]
+    assert sizes == sorted(set(sizes))  # one aval a program
 
-    shapes = {"tokens": (t_w,), "tok_live": (t_w,), "tile_qlen": (t_w // 8,),
+    shapes = {"tokens": (d_w,), "tok_live": (d_w,), "tok_lane": (d_w,),
+              "lane_tok": (t_w,), "tile_qlen": (t_w // 8,),
               "tables": (5, 6), "pads": (5,), "last_idx": (5, spec_w),
               "sample_pos": (5, spec_w), "seeds": (5,), "verify_len": (5,)}
-    assert len(layout) == 16
+    assert len(layout) == 18
     assert {k: layout[k][1] for k in shapes} == shapes
+    # every token-level section is dense, every tile-level one tiled
+    assert {layout[k][1] for k in (
+        "tokens", "positions", "tok_blk", "tok_off", "tok_row", "tok_slot",
+        "tok_live", "tok_lane")} == {(d_w,)}
+    assert {layout[k][1] for k in (
+        "tile_row", "tile_qpos0", "tile_qlen")} == {(t_w // 8,)}
 
     rng = np.random.default_rng(t_w * 10 + spec_w)
     ops = np.zeros(size, np.int32)
@@ -710,9 +727,13 @@ def test_packed_operand_round_trips(t_w, spec_w):
 
 
 def _parent_pack(engine, decode_rows, prefill_segs):
-    """The 16 operands as the packer built them before they became one
-    vector, row by row and field by field: the reference both of
-    ``_pack_mixed``'s paths are held to, element for element."""
+    """The 16 operands as the packer built them while the step's whole
+    token axis was tile-aligned (every section ``t_w`` lanes wide, a
+    token at its tile lane), row by row and field by field, at the rung
+    of the tile ladder that packer picked: the reference both of
+    ``_pack_mixed``'s paths are held to — the dense operand must be
+    that tick, token for token, seen through its two index maps
+    (``_assert_packs_the_parents_tick``)."""
     qb, bs = engine._q_tile, engine.block_size
     b, mb, w_v = (engine.scheduler.max_slots, engine.max_blocks_per_seq,
                   engine._spec_w)
@@ -731,7 +752,7 @@ def _parent_pack(engine, decode_rows, prefill_segs):
             r.pad + r.prefill_done,
             1 if r.prefill_done + n >= r.prefill_target else 0))
     aligned = sum(-(-t.size // qb) * qb for _, t, _, _ in segs)
-    t_w = engine._pick_bucket(max(aligned, qb))
+    t_w = min(t for t, _ in engine.mixed_buckets if t >= max(aligned, qb))
     o = {k: np.zeros(t_w, np.int32) for k in (
         "tokens", "positions", "tok_blk", "tok_off", "tok_row", "tok_slot",
         "tok_live")}
@@ -768,6 +789,50 @@ def _parent_pack(engine, decode_rows, prefill_segs):
     return o
 
 
+TOKEN_SECTIONS = ("tokens", "positions", "tok_blk", "tok_off", "tok_row",
+                  "tok_slot", "tok_live")
+
+
+def _assert_packs_the_parents_tick(engine, sec, program, parent, n_tok):
+    """The dense operand ``sec`` of ``program`` is the parent's
+    tile-aligned tick ``parent``: the same attention rung, the same tile
+    metadata and row sections word for word, every live token in one
+    dense lane (the first ``n_tok``, consecutive) carrying what its tile
+    lane carried, the two index maps inverse on live lanes, dead tile
+    lanes masked and reading token 0, and the sample slots naming the
+    dense lanes of the tokens the parent's named by tile lane."""
+    qb = engine._q_tile
+    t_w, d_w = program
+    assert program in engine.mixed_buckets
+    assert parent["tokens"].size == t_w, "a wider attention rung than today's"
+    assert set(sec) == set(parent) | {"tok_lane", "lane_tok"}
+    for name in ("tile_row", "tile_qpos0", "tile_qlen", "tables", "pads",
+                 "seeds", "verify_len", "sample_pos"):
+        assert sec[name].dtype == parent[name].dtype, name
+        np.testing.assert_array_equal(sec[name], parent[name], err_msg=name)
+    live = sec["tok_live"].astype(bool)
+    assert live.shape == (d_w,) and n_tok <= d_w
+    assert live[:n_tok].all() and not live[n_tok:].any()
+    lane_of, tok_of = sec["tok_lane"], sec["lane_tok"]
+    for name in TOKEN_SECTIONS:
+        np.testing.assert_array_equal(
+            sec[name][:n_tok], parent[name][lane_of[:n_tok]], err_msg=name)
+        assert not sec[name][n_tok:].any(), name  # padding: scratch, dead
+    lanes = np.arange(t_w)
+    tile_live = parent["tok_live"].astype(bool)
+    assert tile_live.sum() == n_tok
+    np.testing.assert_array_equal(
+        tile_live, lanes % qb < sec["tile_qlen"][lanes // qb])
+    np.testing.assert_array_equal(tok_of[lane_of[:n_tok]], np.arange(n_tok))
+    np.testing.assert_array_equal(lane_of[tok_of[tile_live]], lanes[tile_live])
+    assert not tok_of[~tile_live].any() and not lane_of[n_tok:].any()
+    n_v = sec["verify_len"]
+    cols = np.arange(sec["last_idx"].shape[1])[None, :] < n_v[:, None]
+    np.testing.assert_array_equal(
+        lane_of[sec["last_idx"]][cols], parent["last_idx"][cols])
+    assert not sec["last_idx"][~cols].any()
+
+
 # what a tick must have held for the case to have been packed at all:
 # (plain decode rows, speculating rows, prefill segments, longest prefill
 # segment, decode rows' slots out of order) of one tick, at least
@@ -787,8 +852,9 @@ PACK_CASES = {
 def test_array_path_packs_what_the_segment_path_packs(tiny, case):
     """Every verdict the planner hands the packer in a run is packed
     three ways — as the tick does it (plain decode rows by whole-array
-    writes), with every row through the per-segment code, and by the
-    parent's packer — into the identical vector."""
+    writes), with every row through the per-segment code (the identical
+    vector), and by the parent's tile-aligned packer: the same tick, on
+    the same attention rung, token for token."""
     cfg, params = tiny
     kw = dict(PACK_CASES[case])
     lens, new, stagger = kw.pop("lens"), kw.pop("new"), kw.pop("stagger", 0)
@@ -796,27 +862,26 @@ def test_array_path_packs_what_the_segment_path_packs(tiny, case):
     pack, fill_rows = engine._pack_mixed, engine._fill_decode_rows
     seen = []
 
-    def by_segment(sec, rows, lanes):
-        for r, cur in zip(rows, lanes):
+    def by_segment(sec, rows, curs, lanes):
+        for r, cur, lane in zip(rows, curs, lanes):
             engine._fill_segment(
                 sec, r, np.asarray([r.generated[-1]], np.int32),
-                r.cache_len - 1, 1, cur)
+                r.cache_len - 1, 1, cur, lane)
 
     def checking_pack(decode_rows, prefill_segs):
-        ops, t_w, n_array = pack(decode_rows, prefill_segs)
+        ops, program, n_array = pack(decode_rows, prefill_segs)
         engine._fill_decode_rows = by_segment
         try:
-            slow, slow_w, _ = pack(decode_rows, prefill_segs)
+            slow, slow_program, _ = pack(decode_rows, prefill_segs)
         finally:
             engine._fill_decode_rows = fill_rows
-        assert slow_w == t_w
+        assert slow_program == program
         np.testing.assert_array_equal(ops, slow)
-        sec = _sections(engine, ops)
-        parent = _parent_pack(engine, decode_rows, prefill_segs)
-        assert parent["tokens"].size == t_w and set(parent) == set(sec)
-        for name, ref in parent.items():
-            assert sec[name].dtype == ref.dtype, name
-            np.testing.assert_array_equal(sec[name], ref, err_msg=name)
+        _assert_packs_the_parents_tick(
+            engine, _sections(engine, ops), program,
+            _parent_pack(engine, decode_rows, prefill_segs),
+            sum(1 + r.draft_len for r in decode_rows)
+            + sum(n for _, n in prefill_segs))
         plain = [r for r in decode_rows if not r.draft_len]
         assert n_array == len(plain)
         slots = [r.slot for r in plain]
@@ -825,7 +890,7 @@ def test_array_path_packs_what_the_segment_path_packs(tiny, case):
             prefill=len(prefill_segs),
             longest=max([n for _, n in prefill_segs], default=0),
             unordered=slots != sorted(slots)))
-        return ops, t_w, n_array
+        return ops, program, n_array
 
     engine._pack_mixed = checking_pack
     rng = np.random.default_rng(41)
@@ -852,3 +917,206 @@ def test_array_path_packs_what_the_segment_path_packs(tiny, case):
         "slots-reused-out-of-order": lambda t: t["unordered"],
     }[case]
     assert any(want(t) for t in seen), seen
+
+
+# ----------------------------------------------------------------------
+# the dense token axis: the same tokens as the tile-wide step, and a
+# program set warm-up can afford
+# ----------------------------------------------------------------------
+
+def _run_as_the_parent_did(engine):
+    """Turn ``engine`` into the step it replaced: ONE width — every
+    program's dense axis is its tile axis, a token at its tile lane —
+    packed by the parent's packer (``_parent_pack``) with identity index
+    maps, so qkv, the K/V scatter, o_proj and the MLP run ``t_w`` lanes
+    wide as they did.  Same engine, same weights, same sampler: the
+    streams it serves are today's."""
+    ladder = sorted({t for t, _ in engine.mixed_buckets})
+    engine.mixed_buckets = tuple((t, t) for t in ladder)
+    engine._mixed_layouts = {
+        p: mixed_operand_layout(*p, *engine._mixed_geometry)
+        for p in engine.mixed_buckets}
+    engine._mixed_step = engine._make_mixed_step()
+
+    def pack(decode_rows, prefill_segs):
+        parent = _parent_pack(engine, decode_rows, prefill_segs)
+        t_w = parent["tokens"].size
+        layout, size = engine._mixed_layouts[t_w, t_w]
+        ops = np.zeros(size, np.int32)
+        sec = split_mixed_operands(ops, layout)
+        for name, section in parent.items():
+            sec[name][...] = section
+        sec["tok_lane"][...] = sec["lane_tok"][...] = np.arange(t_w)
+        return ops, (t_w, t_w), sum(not r.draft_len for r in decode_rows)
+
+    engine._pack_mixed = pack
+    return engine
+
+
+# (model family, engine keywords, prompt lengths, new tokens, staggered
+# submits, what some tick of the run must have held)
+DENSE_CASES = {
+    "decode-only": (  # four one-tile rows: 32 lanes, a dense axis of 8
+        "llama", dict(), (5, 3, 7, 4), 6, False,
+        lambda t: t["decode"] == 4 and t["program"] == (32, 8)),
+    "chunk-only": (
+        "llama", dict(), (21, 13), 3, False,
+        lambda t: not t["decode"] and len(t["prefill"]) == 2),
+    "decode+two-chunks": (
+        "llama", dict(prefill_chunk=16, tick_token_budget=40), (4, 30, 27),
+        8, "late", lambda t: t["decode"] and len(t["prefill"]) == 2
+        and t["program"][1] < t["program"][0]),
+    "speculating-row": (
+        "llama", dict(spec_k=3), (12, 9, 5), 10, False,
+        lambda t: t["spec"] and t["program"][1] < t["program"][0]),
+    "int8-pool": (
+        "llama", dict(cache_dtype=jnp.int8), (5, 19, 4, 11), 6, True,
+        lambda t: t["decode"] and t["prefill"]
+        and t["program"][1] < t["program"][0]),
+    "sliding-window": (
+        "gemma2", dict(), (9, 13, 6, 5), 24, True,
+        lambda t: t["decode"] == 4 and t["program"] == (32, 8)),
+}
+
+
+def _serve(engine, cfg, lens, new, stagger, spec, seen=None):
+    """Serve one fixed workload; ``seen`` collects what each tick held."""
+    if seen is not None:
+        pack = engine._pack_mixed
+
+        def recording_pack(decode_rows, prefill_segs):
+            packed = pack(decode_rows, prefill_segs)
+            seen.append(dict(
+                decode=len(decode_rows), program=packed[1],
+                spec=sum(bool(r.draft_len) for r in decode_rows),
+                prefill=[n for _, n in prefill_segs]))
+            return packed
+
+        engine._pack_mixed = recording_pack
+    rng = np.random.default_rng(43)
+    for j, n in enumerate(lens):
+        if spec:  # a tiled prompt: drafts get proposed
+            prompt = np.resize(rng.integers(1, cfg.vocab_size, size=3), n)
+        else:
+            prompt = rng.integers(1, cfg.vocab_size, size=n)
+        engine.submit(prompt, new, seed=j, speculative=spec)
+        if stagger is True or (stagger == "late" and j == 0):
+            engine.step()  # later prompts arrive beside decoding rows
+    engine.run_until_complete()
+    assert len(engine.scheduler.finished) == len(lens)
+    return _tokens(engine)
+
+
+@pytest.mark.parametrize("case", sorted(DENSE_CASES))
+def test_dense_step_serves_the_tile_wide_steps_tokens(case):
+    """A dense row goes through the same arithmetic as its tile lane
+    did: the streams of the dense step are, token for token, those of
+    the step it replaced (every section and every matmul ``t_w`` lanes
+    wide, the parent's packing) — for decode rows alone, a lone chunk,
+    decode rows beside two chunks, a verify slice, an int8 pool with its
+    scale pages, and Gemma-2's sliding-window layers across the window
+    and several block boundaries."""
+    family, kw, lens, new, stagger, want = DENSE_CASES[case]
+    cfg = tiny_config(family)
+    assert (cfg.sliding_window is not None) == (family == "gemma2")
+    params = init_params(jax.random.PRNGKey(2), cfg, dtype=jnp.float32)
+    spec = bool(kw.get("spec_k"))
+    seen = []
+    dense = _serve(_engine(cfg, params, mixed="on", **kw), cfg, lens, new,
+                   stagger, spec, seen)
+    assert any(want(t) for t in seen), seen
+    tile_wide = _serve(
+        _run_as_the_parent_did(_engine(cfg, params, mixed="on", **kw)),
+        cfg, lens, new, stagger, spec)
+    assert dense == tile_wide
+    if family == "gemma2":  # long enough to cross the window
+        assert max(lens) + new > cfg.sliding_window
+
+
+def _parent_ladder(engine):
+    """The packed-width buckets the parent's ``_make_buckets`` built for
+    this engine's geometry — ONE width a program, a doubling ladder of
+    q-tile multiples capped by the worst aligned total — and the rung
+    its ``_pick_bucket`` gave a tick."""
+    qb, budget = engine._q_tile, engine.tick_token_budget
+    a_max = -(-(budget + engine.scheduler.max_slots * (qb - 1)) // qb) * qb
+    ladder, t = [], qb
+    while t < a_max:
+        ladder.append(t)
+        t *= 2
+    return sorted({*ladder, a_max})
+
+
+# the benchmark cells' geometry (64 slots, chunks of 128: budget 320),
+# a small engine, and 128 slots — each with and without verify lanes
+GEOMETRIES = {
+    "cells-64-slots": dict(max_slots=64, num_blocks=64 * 6 + 8, block_size=64,
+                           max_seq_len=384, prefill_chunk=128),
+    "4-slots": dict(max_slots=4),
+    "128-slots": dict(max_slots=128, num_blocks=128 * 2 + 8, block_size=64,
+                      max_seq_len=128, prefill_chunk=128),
+    "4-slots-spec3": dict(max_slots=4, spec_k=3),
+    "64-slots-spec7": dict(max_slots=64, num_blocks=64 * 2 + 8, block_size=64,
+                           max_seq_len=128, prefill_chunk=128, spec_k=7),
+    # 8 * (216 - 128) tokens = 11 * (128 - 64) tiles: the decode program
+    # (1024, 128) would be as long as (512, 216)
+    "128-slots-same-length": dict(
+        max_slots=128, num_blocks=128 * 2 + 8, block_size=64, max_seq_len=128,
+        prefill_chunk=44, tick_token_budget=216),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_every_tick_has_a_program_on_todays_attention_rung(tiny, geometry):
+    """Over every tick the planner can emit — any number of tile lanes up
+    to the worst aligned total, with any token count those lanes and the
+    budget allow (a superset of every rows-and-segments mix) — the
+    program found holds the tick, its attention rung is EXACTLY the one
+    the parent's packer picked (never wider: the kernel's time follows
+    its grid), the operand's length names exactly one program, and the
+    set has at most ONE program more than the parent's ladder has rungs:
+    the steady decode tick's, ``max_slots`` one-tile rows at the width
+    of their tokens."""
+    cfg, params = tiny
+    engine = _engine(cfg, params, mixed="on", **GEOMETRIES[geometry])
+    qb, budget = engine._q_tile, engine.tick_token_budget
+    slots, spec_w = engine.scheduler.max_slots, engine._spec_w
+    ladder = _parent_ladder(engine)
+    programs = engine.mixed_buckets
+    assert list(programs) == sorted(set(programs))
+    assert sorted({t for t, _ in programs}) == ladder  # today's rungs
+    assert len(programs) <= len(ladder) + 1
+    sizes = {p: engine._mixed_layouts[p][1] for p in programs}
+    assert len(set(sizes.values())) == len(programs)
+    for p, size in sizes.items():
+        assert mixed_operand_program(
+            size, programs, *engine._mixed_geometry) == p
+        assert engine._dead_mixed_operands(*p).shape == (size,)
+    for n_lanes in range(qb, ladder[-1] + 1, qb):
+        rung = min(t for t in ladder if t >= n_lanes)
+        # at least a token a tile, at most a lane's worth or the budget
+        for n_tokens in range(n_lanes // qb, min(n_lanes, budget) + 1):
+            t_w, d_w = engine._pick_bucket(n_lanes, n_tokens)
+            assert t_w == rung and d_w >= n_tokens, (n_lanes, n_tokens)
+            assert (t_w, d_w) == min(
+                p for p in programs if p[0] == rung and p[1] >= n_tokens)
+    with pytest.raises(AssertionError, match="budget accounting"):
+        engine._pick_bucket(ladder[-1] + qb, 1)
+    # the steady decode tick: every slot one tile, one token (or one
+    # verify slice that fits a tile) — as wide as its tokens, no wider
+    full = slots * min(spec_w, qb)
+    t_w, d_w = engine._pick_bucket(slots * qb, full)
+    bumped = geometry == "128-slots-same-length"  # one q tile wider
+    assert d_w == -(-full // qb) * qb + (qb if bumped else 0) <= t_w
+    if geometry == "cells-64-slots":
+        assert programs == (
+            (8, 8), (16, 16), (32, 32), (64, 64), (128, 128), (256, 256),
+            (512, 64), (512, 320), (768, 320))
+        assert (t_w, d_w) == (512, 64)
+        # 64 rows and a chunk or two: 320 rows of matmul, not 768
+        assert engine._pick_bucket(512 + 128, 64 + 128) == (768, 320)
+        # a chunk-heavy tick on the 64-tile rung keeps that rung
+        assert engine._pick_bucket(40 * qb + 128, 40 + 128) == (512, 320)
+    if geometry == "64-slots-spec7":
+        # the rows' tokens fill their tiles: no second program
+        assert len(programs) == len(ladder)

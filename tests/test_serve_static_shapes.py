@@ -13,6 +13,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
 from llm_np_cp_tpu.config import tiny_config
@@ -152,3 +153,93 @@ def test_compile_counts_bounded_by_phase_shapes():
     assert counts["decode_step"] == 1
     assert counts["sample_first"] == 1
     assert counts["prefill_step"] == 1
+
+
+def _parent_rungs(engine):
+    """How many programs the parent warmed at this geometry: one a rung
+    of its packed-width ladder (q-tile multiples doubling up to the
+    worst aligned total)."""
+    qb = engine._q_tile
+    worst = engine.tick_token_budget + engine.scheduler.max_slots * (qb - 1)
+    n, t = 1, qb
+    while t < worst:
+        n, t = n + 1, t * 2
+    return n
+
+
+# the benchmark cells' geometry (budget 320: nine programs where the
+# parent warmed eight), 128 slots, and a small engine every program of
+# which a tick can reach
+PROGRAM_GEOMETRIES = {
+    "cells-64-slots": (dict(max_slots=64, num_blocks=64 * 6 + 8, block_size=64,
+                            max_seq_len=384, prefill_chunk=128), 9),
+    "128-slots": (dict(max_slots=128, num_blocks=128 * 2 + 8, block_size=64,
+                       max_seq_len=128, prefill_chunk=128), 10),
+    "4-slots": (dict(max_slots=4, num_blocks=48, block_size=8, max_seq_len=64,
+                     prefill_chunk=16), 5),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(PROGRAM_GEOMETRIES))
+def test_warmup_pays_for_one_program_more_than_the_parent(geometry):
+    """The program set is part of the design (a warm program costs
+    0.7-1 s of every start: PERF.md §6, PR 30 / 31): at most ONE more
+    than the parent's ladder has rungs, warm-up compiles exactly that
+    many, and a replay that visits every program compiles nothing."""
+    kw, n_programs = PROGRAM_GEOMETRIES[geometry]
+    cfg = tiny_config("llama")
+    params = init_params(jax.random.PRNGKey(3), cfg, dtype=jnp.float32)
+    engine = ServeEngine(params, cfg, sampler=Sampler(kind="greedy"),
+                         cache_dtype=jnp.float32, **kw)
+    assert engine.mixed
+    assert len(engine.mixed_buckets) == n_programs == _parent_rungs(engine) + 1
+    if geometry == "128-slots":
+        return  # the count is the claim; the small engines replay it
+    engine.warmup([4, 12], max_new_tokens=3)
+    warm = dict(engine.compile_counts())
+    assert warm == {"mixed_step": n_programs}
+    assert engine._mixed_step._cache_size() == n_programs
+
+    pack, picked = engine._pack_mixed, set()
+
+    def recording_pack(decode_rows, prefill_segs):
+        packed = pack(decode_rows, prefill_segs)
+        picked.add(packed[1])
+        return packed
+
+    engine._pack_mixed = recording_pack
+    slots, chunk = kw["max_slots"], kw["prefill_chunk"]
+    rng = np.random.default_rng(17)
+
+    def submit(n, new, seed):
+        engine.submit(rng.integers(1, cfg.vocab_size, size=n), new, seed=seed)
+
+    counter = CompileCounter()
+    with counter.watch():
+        # every slot prefills at once, decodes as one full batch (the
+        # steady decode tick) and drains row by row down the ladder
+        for i in range(slots):
+            submit(4, 3 + i % 12, i)
+        engine.run_until_complete()
+        # most of a batch decoding when a prompt of a chunk and more
+        # arrives: more tiles than the slots' rung holds
+        for i in range(slots - max(slots // 8, 1)):
+            submit(4, 8, 100 + i)
+        engine.step()
+        engine.step()
+        submit(chunk + 2, 2, 99)
+        engine.run_until_complete()
+        # ...and a mix of arrivals beside decoding rows
+        for i in range(2 * min(slots, 8)):
+            submit(int(rng.integers(1, chunk + 8)),
+                   int(rng.integers(2, 9)), 200 + i)
+            for _ in range(int(rng.integers(0, 3))):
+                engine.step()
+        engine.run_until_complete()
+        submit(3, 3, 999)  # ...and one request alone
+        engine.run_until_complete()
+    assert counter.count == 0, f"a tick compiled: {counter.events}"
+    assert engine.compile_counts() == warm
+    assert picked == set(engine.mixed_buckets), (
+        sorted(set(engine.mixed_buckets) - picked))
+    assert_serve_compiles_bounded(engine, distinct_prefill_shapes=0)

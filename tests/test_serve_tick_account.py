@@ -80,13 +80,18 @@ def traced(request, tiny):
         sizes = [1 + r.draft_len for r in decode_rows] + \
                 [n for _, n in prefill_segs]
         aligned = sum(-(-n // engine._q_tile) * engine._q_tile for n in sizes)
+        # the program: the first, by (packed, dense) width, that holds
+        # the aligned lanes and the tokens
+        width, dense = min(
+            p for p in engine.mixed_buckets
+            if p[0] >= aligned and p[1] >= sum(sizes))
         hand.append(dict(
-            rows=len(sizes),
+            rows=len(sizes), tokens=sum(sizes),
             # a decode row attends its prompt and everything generated
             # so far; a prefill row what it has prefilled with this chunk
             context=sum(r.prompt.size + len(r.generated) for r in decode_rows)
             + sum(r.prefill_done + n for r, n in prefill_segs),
-            width=min(b for b in engine.mixed_buckets if b >= aligned),
+            width=width, dense=dense,
         ))
         packed = pack(decode_rows, prefill_segs)
         hand[-1]["bytes"] = packed[0].nbytes
@@ -120,7 +125,15 @@ def test_cut_phases_are_consecutive_and_sum_to_the_tick(traced):
 
 
 def test_tick_args_equal_a_hand_count_of_the_planned_rows(traced):
-    _, tracer, hand = traced
+    engine, tracer, hand = traced
+    # the counter anyone can scrape: dense lanes dispatched, beside the
+    # tokens in them
+    snap = engine.metrics.snapshot()
+    assert snap["mixed_dense_lanes"] == sum(h["dense"] for h in hand)
+    assert (snap["mixed_prefill_tokens"] + snap["mixed_decode_tokens"]
+            == sum(h["tokens"] for h in hand) <= snap["mixed_dense_lanes"])
+    assert (f"llm_serve_mixed_dense_lanes_total {snap['mixed_dense_lanes']}"
+            in engine.metrics.prometheus().splitlines())
     ticks = [(t, p) for t, p in _ticks_with_phases(tracer.events())
              if t["args"]["packed_width"]]
     assert len(ticks) == len(hand) > 0
@@ -129,6 +142,7 @@ def test_tick_args_equal_a_hand_count_of_the_planned_rows(traced):
         assert args["active_slots"] == want["rows"]
         assert args["context_tokens"] == want["context"]
         assert args["packed_width"] == want["width"]
+        assert args["dense_width"] == want["dense"]
         assert args["thread_cpu_us"] >= 0.0
         h2d = next(p for p in phases if p["name"] == "h2d")
         assert args["pack_array_rows"] == want["array_rows"]
@@ -146,6 +160,7 @@ def test_idle_tick_has_empty_dispatch_phases(tiny):
     for name in ("pack", "h2d", "mixed_dispatch", "host_sync"):
         assert by[name]["dur"] == 0.0
     assert tick["args"]["active_slots"] == tick["args"]["packed_width"] == 0
+    assert tick["args"]["dense_width"] == 0
     assert by["h2d"]["args"] == {"count": 0, "bytes": 0}
 
 
@@ -356,7 +371,7 @@ def test_scopes_change_no_program(tiny):
         engine = _engine(cfg, params)
         text = engine._make_mixed_step().lower(
             engine.params, engine.pool.pages,
-            engine._put(engine._dead_mixed_operands(8))).as_text()
+            engine._put(engine._dead_mixed_operands(8, 8))).as_text()
         return re.sub(r"loc\(.*?\)$|^#loc.*$", "", text, flags=re.M)
 
     with_scopes = lowered()
@@ -402,8 +417,9 @@ def test_setup_spans_cover_build_and_each_warmup_bucket(tiny):
     assert by["pool_alloc"][0]["args"]["bytes"] == sum(
         a.nbytes for a in engine.pool.pages if a is not None)
     warm, = by["warmup"]
-    assert [ev["args"]["width"] for ev in by["warmup.bucket"]] == list(
-        engine.mixed_buckets)
+    assert [(ev["args"]["width"], ev["args"]["dense"])
+            for ev in by["warmup.bucket"]] == list(engine.mixed_buckets)
+    assert build["args"]["buckets"] == len(engine.mixed_buckets)
     assert all(inside(ev, warm) for ev in by["warmup.bucket"] + by["warmup.request"])
     assert not inside(by["op_map"][0], warm)  # tracing's own cost, apart
     # every compile the process made meanwhile is a span that names where
@@ -418,6 +434,11 @@ def test_setup_spans_cover_build_and_each_warmup_bucket(tiny):
     rows = setup_spans(events)
     assert [r["name"] for r in rows] == [
         ev["name"] for ev in sorted(setup, key=lambda e: e["ts"])]
+    # the warm-up's programs, each with its seconds, on one line
+    line = next(ln for ln in format_summary(events).splitlines()
+                if ln.lstrip().startswith("warm-up:"))
+    assert f"warm-up: {len(engine.mixed_buckets)} programs" in line
+    assert all(f"{t_w}x{d_w}" in line for t_w, d_w in engine.mixed_buckets)
 
 
 def test_compile_span_names_the_set_up_span_it_fell_in():
@@ -583,6 +604,12 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
     assert acct["host_wait_us"] <= acct["tick_us"]
     assert sum(acct["packed_widths"].values()) == len(hand)
     assert set(acct["packed_widths"]) == {h["width"] for h in hand}
+    assert sum(acct["programs"].values()) == len(hand)
+    assert set(acct["programs"]) == {
+        f"{h['width']}x{h['dense']}" for h in hand}
+    assert acct["dense_occupancy"] == pytest.approx(
+        sum(h["tokens"] for h in hand) / sum(h["dense"] for h in hand))
+    assert 0.0 < acct["dense_occupancy"] <= 1.0
     assert acct["pack_array_rows"] == pytest.approx(
         sum(h["array_rows"] for h in hand) / len(hand))
     out = format_summary(events)
@@ -591,7 +618,9 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
             "arrays") in out
     assert "packed width " + " ".join(
-        f"{w}x{n}" for w, n in acct["packed_widths"].items()) in out
+        f"{w}x{n}" for w, n in acct["packed_widths"].items()
+    ) + "; programs (packed x dense width: ticks) " + " ".join(
+        f"{p}:{n}" for p, n in acct["programs"].items()) in out
     assert tick_account([]) is None
 
 

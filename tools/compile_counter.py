@@ -82,7 +82,8 @@ def assert_serve_compiles_bounded(
 
     Unified-tick engines (``engine.mixed``) have ONE program under a
     stricter contract: ``mixed_step`` compiles at most once per
-    packed-width bucket (``engine.mixed_buckets``) regardless of the
+    program (``engine.mixed_buckets``: ``(packed, dense)`` width pairs,
+    one more than the tile ladder has rungs) regardless of the
     prefill:decode row composition, and NONE of the phase-split
     programs exist — in particular the deleted ``gather_prefix`` copy
     must not reappear (its job, copying shared prefix K/V into the temp
@@ -111,8 +112,8 @@ def assert_serve_compiles_bounded(
         if counts.get("mixed_step", 0) > len(engine.mixed_buckets):
             problems.append(
                 f"mixed_step compiled {counts['mixed_step']}x for "
-                f"{len(engine.mixed_buckets)} packed-width buckets "
-                "(must be <= one per bucket, never per tick or per "
+                f"{len(engine.mixed_buckets)} (packed, dense) width "
+                "programs (must be <= one per program, never per tick or per "
                 "prefill:decode composition)"
             )
         if any(v < 0 for v in counts.values()):

@@ -21,9 +21,13 @@ from ``GET /debug/trace``) and prints:
 - **tick account** (unified tick) — the cut host phases in tick order
   (pack / h2d / mixed_dispatch / deliver / account) with the transfers'
   count and bytes, the tick thread's own CPU time and what is left over
-  (neither CPU nor the device wait), the live context per dispatch;
+  (neither CPU nor the device wait), the live context per dispatch,
+  and the ticks by packed width (tile lanes inside attention) and by
+  program (``packed x dense`` width) with the share of dense lanes
+  that held a token;
 - **set-up** — the ``cat: "setup"`` spans (load + place, engine build
-  and its probes, every warm-up bucket, the op map, listen) and the
+  and its probes, every warm-up program with its seconds and whether it
+  compiled, the op map, listen) and the
   backend compiles the recorder saw, by where they fell (one under
   traffic is named with its tick phase);
 - **device scopes** (``--profile FILE.xplane.pb``) — device time by the
@@ -437,8 +441,10 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
     transfer count and bytes, and from the tick args the rows
     ``_pack_mixed`` filled by whole-array writes, the tick thread's
     own CPU time, the leftover (tick - host_sync - CPU: neither
-    computing nor waiting for the device), the live context and the
-    packed-width buckets the dispatches used.  None
+    computing nor waiting for the device), the live context, the
+    packed widths (tile lanes inside attention) the dispatches used
+    and — where the ticks say it — their programs, ``packed x dense``
+    width, with the share of dense lanes that held a token.  None
     for a trace without the cut phases (split tick, older dumps)."""
     ticks = [e for e in events if e.get("ph") == "X"
              and e.get("cat") == "tick" and "packed_width" in
@@ -475,6 +481,17 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
     for e in ticks:
         widths[e["args"]["packed_width"]] += 1
     out["packed_widths"] = dict(sorted(widths.items()))
+    dense = [e["args"] for e in ticks if e["args"].get("dense_width")]
+    if dense:
+        programs: dict[tuple[int, int], int] = defaultdict(int)
+        for a in dense:
+            programs[a["packed_width"], a["dense_width"]] += 1
+        out["programs"] = {
+            f"{t}x{d}": n for (t, d), n in sorted(programs.items())}
+        out["dense_occupancy"] = sum(
+            a.get("prefill_tokens", 0) + a.get("decode_tokens", 0)
+            + a.get("spec_draft_tokens", 0) for a in dense
+        ) / sum(a["dense_width"] for a in dense)
     cpu = [e["args"]["thread_cpu_us"] for e in ticks
            if "thread_cpu_us" in e["args"]]
     if cpu:
@@ -670,6 +687,10 @@ def format_summary(events: list[dict], top: int = 5) -> str:
             f"arrays; context "
             f"{acct['context_tokens']:.0f} tokens/dispatch; packed width "
             + " ".join(f"{w}x{n}" for w, n in acct["packed_widths"].items())
+            + ("; programs (packed x dense width: ticks) "
+               + " ".join(f"{p}:{n}" for p, n in acct["programs"].items())
+               + f", {acct['dense_occupancy']:.0%} of dense lanes held a "
+               "token" if "programs" in acct else "")
             + (f"; tick thread CPU {acct['thread_cpu_us']:.0f}us, "
                f"neither CPU nor device wait {acct['host_wait_us']:.0f}us"
                if "thread_cpu_us" in acct else "")
@@ -683,6 +704,16 @@ def format_summary(events: list[dict], top: int = 5) -> str:
                 f"  {sp['name']:<22} {sp['dur_us'] / 1e3:>10.1f} ms  "
                 f"compiles {sp['compiles']} ({sp['compile_misses']} not "
                 f"from the cache)" + (f"  {extra}" if extra else ""))
+        warm = [sp for sp in setup if sp["name"] == "warmup.bucket"]
+        if warm:
+            lines.append(
+                f"  warm-up: {len(warm)} programs, "
+                f"{sum(sp['dur_us'] for sp in warm) / 1e6:.2f} s (packed"
+                " x dense width, s; * = compiled): " + " ".join(
+                    f"{sp['args'].get('width')}x"
+                    f"{sp['args'].get('dense', sp['args'].get('width'))}"
+                    f"{'*' if sp['args'].get('compiled') else ''} "
+                    f"{sp['dur_us'] / 1e6:.2f}" for sp in warm))
         stray = stray_compiles(events)
         lines.append("  compiles outside set-up: " + (", ".join(
             f"{n} in {where}" for where, n in sorted(stray.items()))
