@@ -54,6 +54,11 @@ class KVCache(NamedTuple):
     # decode (scales are D=1/64..1/128 of the slab).
     k_scale: jnp.ndarray | None = None  # [L, B, S_max, K] f32
     v_scale: jnp.ndarray | None = None
+    # short-convolution state of a configuration with conv layers
+    # (config.conv_layers): the last ``conv_L_cache - 1`` gated inputs of
+    # every row, most recent last.  ``L`` above then counts the attention
+    # layers only (config.attn_layers): a conv layer has no K/V.
+    conv: jnp.ndarray | None = None  # [n_conv, B, conv_L_cache - 1, H]
 
     @classmethod
     def init(
@@ -76,14 +81,20 @@ class KVCache(NamedTuple):
         it is given so tests can build odd-capacity caches on purpose.
         """
         shape = (
-            config.num_hidden_layers,
+            len(config.attn_layers),
             batch_size,
             max_seq_len,
             config.num_key_value_heads,
             config.head_dim,
         )
         quantized = dtype == jnp.int8
+        n_conv = len(config.conv_layers)
         return cls(
+            conv=jnp.zeros(
+                (n_conv, batch_size, config.conv_L_cache - 1,
+                 config.hidden_size),
+                jnp.bfloat16 if quantized else dtype,
+            ) if n_conv else None,
             k=jnp.zeros(shape, dtype=dtype),
             v=jnp.zeros(shape, dtype=dtype),
             valid=jnp.zeros((batch_size, max_seq_len), dtype=jnp.bool_),

@@ -241,6 +241,12 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
     p.add_argument("--sampler", choices=["greedy", "min_p", "top_k", "top_p",
                                          "cdf"], default="greedy")
     p.add_argument("--dtype", choices=["bf16", "f32"], default="bf16")
+    p.add_argument("--arch", default=None, metavar="MODEL_TYPE",
+                   help="the operator's statement of what the loaded "
+                   "checkpoint must be: start-up fails unless its "
+                   "config's model_type is MODEL_TYPE (llama, qwen2, "
+                   "gemma2, lfm2_moe, ...) — so that a deployment never "
+                   "serves another architecture under a model's name")
     p.add_argument("--chaos-spec", default=None, metavar="SPEC",
                    help="fault-injection schedule (serve/faults.py): "
                    "events 'site@N[:COUNT][=ARG]' (deterministic) or "
@@ -655,6 +661,12 @@ def _build_serve_engine(args, params, config, *, prog: str,
     from llm_np_cp_tpu.serve.engine import pool_geometry
 
     _validate_pool_flags(args)  # re-checked for non-CLI callers
+    arch = getattr(args, "arch", None)
+    if arch and config.model_type != arch:
+        raise SystemExit(
+            f"--arch {arch}: the loaded checkpoint's config says "
+            f"model_type {config.model_type!r}; refusing to serve it "
+            "under another architecture's name")
     # set-up phases that run before the recorder exists: (name, start,
     # end, args) on time.perf_counter, the recorder's clock — appended
     # as cat "setup" spans once it does (TraceRecorder.us_at)

@@ -100,6 +100,34 @@ class ModelConfig:
     moe_group_size: int = 1024  # GShard token-group length (keeps dispatch linear in T)
     router_aux_loss_coef: float = 0.02
 
+    # --- A layer stack that is not one kind of layer (LFM2-MoE).  Each
+    # layer declares its OPERATOR (``layer_types[l]``: "full_attention" or
+    # "conv", a gated short convolution that carries ``conv_L_cache - 1``
+    # values a channel between steps instead of K/V) and its FEED-FORWARD
+    # (dense SwiGLU for the first ``num_dense_layers``, dropless routed
+    # experts after them).  ``layer_types is None`` is the homogeneous
+    # stack every other family has: one stacked pytree, one scanned body.
+    layer_types: tuple[str, ...] | None = None
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    qk_norm: bool = False  # RMSNorm over head_dim on q and k before RoPE
+    # dropless sigmoid-routed experts (ops/moe.moe_dropless): scores are
+    # sigmoid(gate) in float32, the top k are chosen by score + a
+    # per-expert selection bias, weighted by the scores WITHOUT the bias
+    num_experts: int | None = None
+    num_dense_layers: int = 0
+    moe_intermediate_size: int | None = None
+    use_expert_bias: bool = False
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # Seeded random weights only (models.init_params; no forward reads
+    # it), like a checkpoint's ``initializer_range``: how much of a random
+    # expert is its own — expert e's matrices are ``(shared + a * own_e) /
+    # sqrt(1 + a^2)``.  None: every expert an independent draw.  A
+    # configuration's file sets it (key ``init_expert_specific``) and
+    # says why; the program has no value of its own.
+    init_expert_specific: float | None = None
+
     def __post_init__(self) -> None:
         # Note: hidden_size need not equal heads*head_dim (Gemma-2-2B:
         # 2304 hidden, 8 heads of 256), so no divisibility constraint there.
@@ -107,6 +135,12 @@ class ModelConfig:
             raise ValueError(
                 f"num_attention_heads {self.num_attention_heads} not divisible "
                 f"by num_key_value_heads {self.num_key_value_heads}"
+            )
+        if (self.layer_types is not None
+                and len(self.layer_types) != self.num_hidden_layers):
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"num_hidden_layers is {self.num_hidden_layers}"
             )
 
     # ------------------------------------------------------------------
@@ -139,6 +173,62 @@ class ModelConfig:
     def layer_is_sliding(self, layer_idx: int) -> bool:
         return self.sliding_window is not None and layer_idx % 2 == 0
 
+    # -- the per-layer declaration (what a layer IS, not a schedule) ----
+    @property
+    def is_hybrid(self) -> bool:
+        """The stack has more than one kind of layer: params are groups
+        of like layers (``layer_groups``), not one stacked pytree."""
+        return self.layer_types is not None
+
+    def layer_op(self, layer_idx: int) -> str:
+        """``"attn"`` or ``"conv"``: the operator of layer ``layer_idx``."""
+        if self.layer_types is None:
+            return "attn"
+        return "conv" if self.layer_types[layer_idx] == "conv" else "attn"
+
+    def layer_ff(self, layer_idx: int) -> str:
+        """``"dense"`` or ``"experts"``: its feed-forward."""
+        if self.num_experts is None or layer_idx < self.num_dense_layers:
+            return "dense"
+        return "experts"
+
+    @property
+    def attn_layers(self) -> tuple[int, ...]:
+        """Layers that hold K/V, in order: the only ones a cache or a
+        pool has pages for (page ``i`` belongs to ``attn_layers[i]``)."""
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.layer_op(i) == "attn")
+
+    @property
+    def conv_layers(self) -> tuple[int, ...]:
+        """Layers that carry a short-convolution state, in order."""
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.layer_op(i) == "conv")
+
+    @property
+    def expert_layers(self) -> tuple[int, ...]:
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.layer_ff(i) == "experts")
+
+    def layer_groups(self) -> tuple[tuple[str, str, int, int], ...]:
+        """The stack as runs of like layers, ``(op, ff, first, count)``:
+        each run is one stacked pytree and one scanned body.  An expert
+        layer is always a run of its own: the grouped matmul wants each
+        expert tensor as a whole buffer, and a scan would copy a layer's
+        2 x 235 MB out of the stack every step (compiled for a v5e: 6 GB
+        of copies a tick for LFM2's nine stacked expert layers).  LFM2's
+        16 layers are one run of two conv + dense blocks, then 14 runs of
+        one."""
+        groups: list[tuple[str, str, int, int]] = []
+        for i in range(self.num_hidden_layers):
+            kind = (self.layer_op(i), self.layer_ff(i))
+            if groups and groups[-1][:2] == kind and kind[1] != "experts":
+                op, ff, first, count = groups[-1]
+                groups[-1] = (op, ff, first, count + 1)
+            else:
+                groups.append((*kind, i, 1))
+        return tuple(groups)
+
     # ------------------------------------------------------------------
     @classmethod
     def from_hf_dict(cls, d: Mapping[str, Any]) -> "ModelConfig":
@@ -149,6 +239,13 @@ class ModelConfig:
         attn_logit_softcapping).
         """
         model_type = d.get("model_type", "llama")
+        if model_type not in KNOWN_MODEL_TYPES:
+            # an architecture this package has no equations for must not
+            # be answered as a llama of the same widths
+            raise ValueError(
+                f"unknown model_type {model_type!r}: this package runs "
+                f"{', '.join(sorted(KNOWN_MODEL_TYPES))}"
+            )
         num_heads = d["num_attention_heads"]
         head_dim = d.get("head_dim") or d["hidden_size"] // num_heads
         kwargs: dict[str, Any] = dict(
@@ -162,7 +259,7 @@ class ModelConfig:
             head_dim=head_dim,
             max_position_embeddings=d.get("max_position_embeddings", 8192),
             rope_theta=d.get("rope_theta", 10000.0),
-            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+            rms_norm_eps=d.get("rms_norm_eps", d.get("norm_eps", 1e-6)),
             hidden_act=d.get("hidden_act", d.get("hidden_activation", "silu")),
             tie_word_embeddings=d.get("tie_word_embeddings", True),
             attention_bias=d.get("attention_bias", False),
@@ -195,6 +292,35 @@ class ModelConfig:
                 sliding_window=d.get("sliding_window"),
                 query_pre_attn_scalar=d.get("query_pre_attn_scalar"),
                 hidden_act=d.get("hidden_activation", d.get("hidden_act", "gelu_pytorch_tanh")),
+            )
+        if model_type == "lfm2_moe":
+            # LFM2-MoE: gated short convolutions between GQA layers (q/k
+            # RMSNorm before RoPE, no biases), two leading dense SwiGLU
+            # blocks, then dropless sigmoid-routed experts.  The family
+            # ties the head (the published config has no key for it).
+            layer_types = tuple(d["layer_types"])
+            if len(layer_types) != d["num_hidden_layers"]:
+                raise ValueError(
+                    f"layer_types names {len(layer_types)} layers, "
+                    f"num_hidden_layers is {d['num_hidden_layers']}"
+                )
+            bad = set(layer_types) - {"conv", "full_attention"}
+            if bad:
+                raise ValueError(f"unknown layer_types {sorted(bad)}")
+            kwargs.update(
+                layer_types=layer_types,
+                conv_L_cache=d.get("conv_L_cache", 3),
+                conv_bias=d.get("conv_bias", False),
+                qk_norm=True,
+                num_experts=d["num_experts"],
+                num_experts_per_tok=d["num_experts_per_tok"],
+                num_dense_layers=d.get("num_dense_layers", 0),
+                moe_intermediate_size=d["moe_intermediate_size"],
+                use_expert_bias=d.get("use_expert_bias", False),
+                norm_topk_prob=d.get("norm_topk_prob", True),
+                routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+                tie_word_embeddings=d.get("tie_word_embeddings", True),
+                init_expert_specific=d.get("init_expert_specific"),
             )
         if model_type == "qwen2":
             # Qwen-2/2.5: llama architecture with Q/K/V projection biases
@@ -349,6 +475,12 @@ QWEN_2_5_1_5B = dataclasses.replace(
     head_dim=128,
 )
 
+# model_type values ``from_hf_dict`` has equations for ("mistral" and
+# "mixtral" are the llama block, the latter with capacity-routed experts
+# when ``num_local_experts`` is set)
+KNOWN_MODEL_TYPES = frozenset(
+    ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe"))
+
 PRESETS: dict[str, ModelConfig] = {
     "meta-llama/Llama-3.2-1B": LLAMA_3_2_1B,
     "meta-llama/Llama-3.2-3B": LLAMA_3_2_3B,
@@ -391,6 +523,23 @@ def tiny_config(model_type: str = "llama", **overrides: Any) -> ModelConfig:
         base.update(
             attention_bias=True,
             attention_out_bias=False,
+            tie_word_embeddings=True,
+        )
+    if model_type == "lfm2_moe":
+        # two leading dense blocks, then two periods of the published
+        # pattern (the first 10 of LFM2-8B-A1B's layer_types), 8 experts
+        # top-2: every kind of layer, no width of the real model
+        base.update(
+            num_hidden_layers=10,
+            layer_types=("conv", "conv", "full_attention", "conv", "conv",
+                         "conv", "full_attention", "conv", "conv", "conv"),
+            rms_norm_eps=1e-5,
+            qk_norm=True,
+            num_experts=8,
+            num_experts_per_tok=2,
+            num_dense_layers=2,
+            moe_intermediate_size=32,
+            use_expert_bias=True,
             tie_word_embeddings=True,
         )
     base.update(overrides)

@@ -43,6 +43,11 @@ from llm_np_cp_tpu.cache import (
 from llm_np_cp_tpu.config import ModelConfig
 from llm_np_cp_tpu.ops.activations import ACT2FN, softcap
 from llm_np_cp_tpu.ops.attention import causal_mask, gqa_attention
+from llm_np_cp_tpu.ops.moe import (
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_ROUTE,
+    moe_dropless,
+)
 from llm_np_cp_tpu.ops.norms import rms_norm
 from llm_np_cp_tpu.ops.rope import apply_rope, rope_cos_sin
 from llm_np_cp_tpu.quant import quant_einsum
@@ -62,8 +67,15 @@ SCOPE_ATTN = "attn"
 SCOPE_O_PROJ = "o_proj"      # output projection, post-norm, residual
 SCOPE_MLP = "mlp"            # input norm, MLP, post-norm, residual
 SCOPE_TAIL = "tail"          # final norm, head, sampling
+# a stack with conv layers and routed experts adds three: the gated short
+# convolution whole (norm, in_proj, gates, filter, out_proj, state read /
+# write), and the two halves of the expert layer (ops/moe.py); its dense
+# feed-forwards stay under ``mlp``
+SCOPE_CONV = "conv"
+# ... which only a stack with such layers enters
+HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS)
 STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
-               SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL)
+               SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL) + HYBRID_SCOPES
 
 
 # ----------------------------------------------------------------------
@@ -78,7 +90,10 @@ def param_shapes(config: ModelConfig) -> dict[str, Any]:
     NH = config.num_attention_heads
     NK = config.num_key_value_heads
     I = config.intermediate_size
-    V = config.vocab_size
+    if config.is_hybrid:
+        return _top_level_shapes(config, [
+            _group_shapes(config, op, ff, n)
+            for op, ff, _, n in config.layer_groups()])
     layers: dict[str, tuple[int, ...]] = {
         "ln_attn_in": (L, H),
         "q_proj": (L, H, NH * D),
@@ -118,6 +133,12 @@ def param_shapes(config: ModelConfig) -> dict[str, Any]:
     if config.sandwich_norms:
         layers["ln_attn_out"] = (L, H)
         layers["ln_mlp_out"] = (L, H)
+    return _top_level_shapes(config, layers)
+
+
+def _top_level_shapes(config: ModelConfig, layers: Any) -> dict[str, Any]:
+    """The pytree around the layers: embedding, final norm, untied head."""
+    H, V = config.hidden_size, config.vocab_size
     spec: dict[str, Any] = {
         "embed_tokens": (V, H),
         "layers": layers,
@@ -126,6 +147,46 @@ def param_shapes(config: ModelConfig) -> dict[str, Any]:
     if not config.tie_word_embeddings:
         spec["lm_head"] = (H, V)
     return spec
+
+
+def _group_shapes(
+    config: ModelConfig, op: str, ff: str, n: int
+) -> dict[str, tuple[int, ...]]:
+    """One run of ``n`` like layers of a hybrid stack (``layer_groups``):
+    the operator's leaves, then the feed-forward's, stacked on ``n``."""
+    H, D = config.hidden_size, config.head_dim
+    NH, NK = config.num_attention_heads, config.num_key_value_heads
+    if config.attention_bias or config.mlp_bias or config.conv_bias:
+        raise NotImplementedError("a hybrid stack has no biased projection")
+    if op == "conv":
+        shapes = {
+            "ln_conv_in": (n, H),
+            "in_proj": (n, H, 3 * H),  # B, C, x — in that order
+            "conv_filter": (n, H, config.conv_L_cache),  # tap j meets z[t-(L-1)+j]
+            "out_proj": (n, H, H),
+        }
+    else:
+        shapes = {
+            "ln_attn_in": (n, H),
+            "q_proj": (n, H, NH * D),
+            "k_proj": (n, H, NK * D),
+            "v_proj": (n, H, NK * D),
+            "o_proj": (n, NH * D, H),
+        }
+        if config.qk_norm:
+            shapes.update(ln_q=(n, D), ln_k=(n, D))
+    shapes["ln_mlp_in"] = (n, H)
+    if ff == "experts":
+        E, I = config.num_experts, config.moe_intermediate_size
+        shapes.update(router=(n, H, E), w1=(n, E, H, I), w3=(n, E, H, I),
+                      w2=(n, E, I, H))
+        if config.use_expert_bias:
+            shapes["expert_bias"] = (n, E)
+    else:
+        I = config.intermediate_size
+        shapes.update(gate_proj=(n, H, I), up_proj=(n, H, I),
+                      down_proj=(n, I, H))
+    return shapes
 
 
 # jitted init program per (config, dtype) — see init_params
@@ -163,10 +224,33 @@ def init_params(
                     # norm gammas: zeros under unit-offset (so 1+w == 1), ones otherwise
                     init = 0.0 if config.rms_norm_unit_offset else 1.0
                     return jnp.full(shape, init, dtype=dtype)
+                if name == "expert_bias":
+                    # selection-only bias, float32 and not zero: choosing
+                    # by score + bias and by score differ for some tokens.
+                    # 0.02 is a tenth of the scores' own spread: the load
+                    # stays as even as the scores make it (at 0.1 the
+                    # bias decided, one expert got 5 x the mean and a
+                    # dozen (layer, expert) pairs none: PERF.md §6, PR 32)
+                    return jax.random.normal(key, shape, jnp.float32) * 0.02
                 if name.endswith("_bias"):
                     # biases start small-but-nonzero so tests exercise the add path
                     return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
-                scale = 0.02
+                own_share = config.init_expert_specific
+                if name in ("w1", "w3", "w2") and own_share is not None:
+                    # dropless experts [n, E, in, out] of a configuration
+                    # that asks for correlated draws: one shared draw and
+                    # ``own_share`` of each expert's own, at the overall
+                    # scale every other matrix has
+                    k_shared, k_own = jax.random.split(key)
+                    shared = jax.random.normal(
+                        k_shared, shape[:1] + (1,) + shape[2:], jnp.float32)
+                    own = jax.random.normal(k_own, shape, jnp.float32)
+                    return ((shared + own_share * own) * (
+                        0.02 / math.sqrt(1.0 + own_share ** 2))
+                    ).astype(dtype)
+                # a conv filter's three taps are of order 1 (the published
+                # code's default init is uniform in +-1/sqrt(3))
+                scale = 0.3 if name == "conv_filter" else 0.02
                 return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(dtype)
 
             return [make(k, p, s) for k, (p, s) in zip(keys, paths_leaves)]
@@ -206,8 +290,11 @@ def scan_unroll(config: ModelConfig) -> int:
     return unroll
 
 
-def _project(x: jnp.ndarray, w: Any) -> jnp.ndarray:
-    return quant_einsum("bsh,ho->bso", x, w).astype(x.dtype)
+def _project(x: jnp.ndarray, w: Any, out_dtype: Any = None) -> jnp.ndarray:
+    """``x @ w`` accumulated in float32, rounded to ``x``'s dtype — or to
+    ``out_dtype``: a projection that writes to a residual stream kept
+    wider than the block computes in hands over what it accumulated."""
+    return quant_einsum("bsh,ho->bso", x, w).astype(out_dtype or x.dtype)
 
 
 def embed_inputs(params: Params, input_ids: jnp.ndarray, config: ModelConfig) -> jnp.ndarray:
@@ -321,12 +408,11 @@ def epilogue_gate_error(
     return kernel_error(epilogue_kernel_name(hq == "int8"))
 
 
-def run_decoder_layer(
+def attention_block(
     w: Params,
     x: jnp.ndarray,
     *,
     config: ModelConfig,
-    act: Any,
     cos: jnp.ndarray,
     sin: jnp.ndarray,
     mask_global: jnp.ndarray | None = None,
@@ -336,30 +422,11 @@ def run_decoder_layer(
     kv_update: Any = None,
     output_attentions: bool = False,
     attn_fn: Any = None,
-) -> tuple[
-    jnp.ndarray,
-    tuple[jnp.ndarray, jnp.ndarray],
-    jnp.ndarray | None,
-    jnp.ndarray,
-]:
-    """One decoder block (pre-norm or Gemma sandwich-norm residual).
-
-    w: one layer's weight dict (un-stacked leaves).
-    kv_update: optional ``(k, v) -> (k_att, v_att)`` hook — the cache write;
-        when None, attention runs over the freshly projected K/V (the
-        reference's cache-less mode, llama3.2_model.py:874-880).
-    sliding: traced bool — selects ``mask_local`` (and the flash kernel's
-        window) for Gemma-2's alternating local layers.
-    attn_fn: optional ``(q, k_att, v_att, sliding) -> attn`` override — the
-        serving engine's paged decode path supplies the block-table-native
-        kernel here (its visibility comes from per-row scalars, not a
-        [B, Sq, Skv] mask, so ``mask_global``/``mask_local`` may be None).
-
-    Returns ``(x_out, (k_att, v_att), attn_weights | None, moe_aux_loss)``
-    (aux loss is 0.0 for dense layers).  Shared by ``forward``'s lax.scan,
-    the pipeline-parallel schedule (parallel/pipeline.py), and the serve
-    engine's paged decode scan, so all trace identical layer math.
-    """
+) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray], jnp.ndarray | None]:
+    """The attention operator of a block with its residual: ``(x_out,
+    (k_att, v_att), attn_weights | None)``.  The first half of
+    ``run_decoder_layer`` (see there for the arguments), and the operator
+    of a hybrid stack's attention layers."""
     if attn_fn is None:
         mask = (
             jnp.where(sliding, mask_local, mask_global)
@@ -374,13 +441,20 @@ def run_decoder_layer(
         return y + bias.astype(y.dtype) if bias is not None else y
 
     with jax.named_scope(SCOPE_QKV):
+        # the block computes in the gammas' dtype (compute_dtype); a
+        # residual stream kept wider (a hybrid stack's float32 one) is
+        # normed as it is and added to as it is — a no-op cast otherwise
         h = rms_norm(
             x, w["ln_attn_in"], eps=config.rms_norm_eps,
             unit_offset=config.rms_norm_unit_offset,
-        )
+        ).astype(w["ln_attn_in"].dtype)
         q = _proj_b(h, "q_proj").reshape(b, s, config.num_attention_heads, config.head_dim)
         k = _proj_b(h, "k_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
         v = _proj_b(h, "v_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
+        if config.qk_norm:
+            # RMSNorm over head_dim on every q and k head, BEFORE RoPE
+            q = rms_norm(q, w["ln_q"], eps=config.rms_norm_eps)
+            k = rms_norm(k, w["ln_k"], eps=config.rms_norm_eps)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
 
@@ -447,7 +521,7 @@ def run_decoder_layer(
                 attn, attn_weights = attn
 
     with jax.named_scope(SCOPE_O_PROJ):
-        attn = _project(attn.reshape(b, s, -1), w["o_proj"])
+        attn = _project(attn.reshape(b, s, -1), w["o_proj"], x.dtype)
         if "o_bias" in w:
             attn = attn + w["o_bias"].astype(attn.dtype)
         if config.sandwich_norms:
@@ -457,11 +531,26 @@ def run_decoder_layer(
             )
         x = x + attn
 
+    return x, (k_att, v_att), attn_weights
+
+
+
+def ff_block(
+    w: Params, x: jnp.ndarray, *, config: ModelConfig, act: Any,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The dense (or capacity-routed) feed-forward of a block with its
+    residual: ``(x_out, moe_aux_loss)``."""
+
+    def _proj_b(x, wname, out_dtype=None):
+        y = _project(x, w[wname], out_dtype)
+        bias = w.get(wname.replace("_proj", "_bias"))
+        return y + bias.astype(y.dtype) if bias is not None else y
+
     with jax.named_scope(SCOPE_MLP):
         h = rms_norm(
             x, w["ln_mlp_in"], eps=config.rms_norm_eps,
             unit_offset=config.rms_norm_unit_offset,
-        )
+        ).astype(w["ln_mlp_in"].dtype)
         moe_aux = jnp.zeros((), jnp.float32)
         if config.is_moe:
             from llm_np_cp_tpu.ops.moe import moe_mlp
@@ -475,15 +564,232 @@ def run_decoder_layer(
         else:
             gate = act(_proj_b(h, "gate_proj"))
             up = _proj_b(h, "up_proj")
-            mlp = _proj_b(gate * up, "down_proj")
+            mlp = _proj_b(gate * up, "down_proj", x.dtype)
         if config.sandwich_norms:
             mlp = rms_norm(
                 mlp, w["ln_mlp_out"], eps=config.rms_norm_eps,
                 unit_offset=config.rms_norm_unit_offset,
             )
         x = x + mlp
-    return x, (k_att, v_att), attn_weights, moe_aux
+    return x, moe_aux
 
+
+
+def conv_block(
+    w: Params,
+    x: jnp.ndarray,
+    *,
+    config: ModelConfig,
+    history: Any,
+    token_mask: jnp.ndarray | None = None,
+) -> jnp.ndarray:
+    """The gated short convolution of a block with its residual (LFM2's
+    ``conv`` operator): ``[B, C, X] = split3(in_proj(u))``, ``z = B * X``,
+    ``c_t = sum_j filter[:, j] * z_{t-(L-1)+j}`` (depthwise, causal, one
+    ``L``-tap filter a channel), ``out_proj(C * c)``.
+
+    history: ``z -> [z_{t-1}, .., z_{t-(L-1)}]``, each shaped like ``z`` —
+        where a token's predecessors IN ITS OWN SEQUENCE come from is the
+        caller's: the same array shifted (a cache-less forward), a
+        carried state in front of it (a cache), the packed neighbours or
+        the slot's state (the serve tick).  The hook also keeps what the
+        next step needs; ``z`` before a sequence's start is 0.
+    token_mask: ``[b, s]`` bool — False at padding, whose ``z`` is 0."""
+    taps = config.conv_L_cache
+    with jax.named_scope(SCOPE_CONV):
+        h = rms_norm(x, w["ln_conv_in"], eps=config.rms_norm_eps).astype(
+            w["ln_conv_in"].dtype)
+        gate_b, gate_c, xin = jnp.split(_project(h, w["in_proj"]), 3, axis=-1)
+        z = gate_b * xin
+        if token_mask is not None:
+            z = jnp.where(token_mask[..., None], z, jnp.zeros_like(z))
+        filt = w["conv_filter"].astype(jnp.float32)  # [H, L]
+        c = z.astype(jnp.float32) * filt[:, taps - 1]
+        for d, z_prev in enumerate(history(z), start=1):
+            c = c + z_prev.astype(jnp.float32) * filt[:, taps - 1 - d]
+        y = _project(gate_c * c.astype(h.dtype), w["out_proj"], x.dtype)
+        return x + y
+
+
+def experts_block(
+    w: Params,
+    x: jnp.ndarray,
+    *,
+    config: ModelConfig,
+    act: Any,
+    live: jnp.ndarray | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The dropless routed feed-forward of a block with its residual:
+    ``(x_out, chosen experts [b, s, k], load [E] int32)``.  ``live``
+    ``[b, s]`` marks real tokens (ops/moe.moe_dropless).  The router
+    reads the normed activations in the residual stream's own dtype
+    (float32 in a hybrid stack: nothing is rounded on the way to a
+    discrete choice); the experts multiply them in the served dtype."""
+    b, s, hdim = x.shape
+    with jax.named_scope(SCOPE_MOE_ROUTE):
+        h = rms_norm(x, w["ln_mlp_in"], eps=config.rms_norm_eps)
+    out, chosen, load = moe_dropless(
+        h.reshape(b * s, hdim), w["router"], w.get("expert_bias"),
+        w["w1"], w["w3"], w["w2"], act=act,
+        top_k=config.num_experts_per_tok,
+        norm_topk_prob=config.norm_topk_prob,
+        scaling=config.routed_scaling_factor,
+        live=None if live is None else live.reshape(b * s),
+        out_dtype=x.dtype,
+    )
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        x = x + out.reshape(b, s, hdim)
+    return x, chosen.reshape(b, s, -1), load
+
+
+def scan_group(body: Any, carry: Any, xs: Any, count: int) -> tuple:
+    """``lax.scan(body, carry, xs)`` over one run of a hybrid stack's
+    like layers; a run of one is the body itself (no loop, and the
+    leading 1 of its leaves is a bitcast)."""
+    if count > 1:
+        return lax.scan(body, carry, xs)
+    carry, ys = body(carry, jax.tree.map(lambda a: a[0], xs))
+    return carry, jax.tree.map(lambda a: a[None], ys)
+
+def run_decoder_layer(
+    w: Params,
+    x: jnp.ndarray,
+    *,
+    config: ModelConfig,
+    act: Any,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    mask_global: jnp.ndarray | None = None,
+    mask_local: jnp.ndarray | None = None,
+    sliding: jnp.ndarray | bool = False,
+    attn_impl: str = "xla",
+    kv_update: Any = None,
+    output_attentions: bool = False,
+    attn_fn: Any = None,
+) -> tuple[
+    jnp.ndarray,
+    tuple[jnp.ndarray, jnp.ndarray],
+    jnp.ndarray | None,
+    jnp.ndarray,
+]:
+    """One decoder block (pre-norm or Gemma sandwich-norm residual).
+
+    w: one layer's weight dict (un-stacked leaves).
+    kv_update: optional ``(k, v) -> (k_att, v_att)`` hook — the cache write;
+        when None, attention runs over the freshly projected K/V (the
+        reference's cache-less mode, llama3.2_model.py:874-880).
+    sliding: traced bool — selects ``mask_local`` (and the flash kernel's
+        window) for Gemma-2's alternating local layers.
+    attn_fn: optional ``(q, k_att, v_att, sliding) -> attn`` override — the
+        serving engine's paged decode path supplies the block-table-native
+        kernel here (its visibility comes from per-row scalars, not a
+        [B, Sq, Skv] mask, so ``mask_global``/``mask_local`` may be None).
+
+    Returns ``(x_out, (k_att, v_att), attn_weights | None, moe_aux_loss)``
+    (aux loss is 0.0 for dense layers).  Shared by ``forward``'s lax.scan,
+    the pipeline-parallel schedule (parallel/pipeline.py), and the serve
+    engine's paged decode scan, so all trace identical layer math.
+    """
+    x, kv_att, attn_weights = attention_block(
+        w, x, config=config, cos=cos, sin=sin, mask_global=mask_global,
+        mask_local=mask_local, sliding=sliding, attn_impl=attn_impl,
+        kv_update=kv_update, output_attentions=output_attentions,
+        attn_fn=attn_fn,
+    )
+    x, moe_aux = ff_block(w, x, config=config, act=act)
+    return x, kv_att, attn_weights, moe_aux
+
+
+
+def _hybrid_stack(
+    groups: list,
+    x: jnp.ndarray,
+    config: ModelConfig,
+    cache: KVCache | None,
+    *,
+    offset: jnp.ndarray,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    mask: jnp.ndarray,
+    token_mask: jnp.ndarray | None,
+) -> tuple:
+    """``forward``'s layer loop for a stack of more than one kind of
+    layer: every run of like layers (``config.layer_groups``) is scanned
+    over its own stacked leaves, an attention run carrying its cache
+    slabs and a conv run its short-convolution state as ``xs`` / ``ys``.
+    Returns ``(x, (k, v) | None, conv state | None, chosen experts
+    [expert layers, B, S, k])``."""
+    if cache is not None and (cache.quantized or offset.ndim == 1):
+        raise NotImplementedError(
+            "a hybrid layer stack runs a float cache with one length: an "
+            "int8 cache and per-row rollback (batched speculative decoding) "
+            "are not implemented for it"
+        )
+    act = ACT2FN[config.hidden_act]
+    b, s, hdim = x.shape
+    taps = config.conv_L_cache
+    # The residual stream is float32 between the blocks (each block
+    # norms it, computes in the served dtype and adds its result back):
+    # summed in bf16, two computations of the same tokens at different
+    # batch shapes drift apart an ulp at a time, and a router turns such
+    # a drift into another expert (measured on the chip: PERF.md §6)
+    stream_dtype, x = x.dtype, x.astype(jnp.float32)
+    new_k, new_v, new_conv, experts = [], [], [], []
+    a0 = c0 = 0  # attention / conv layers seen so far
+    for w_g, (op, ff, _, n) in zip(groups, config.layer_groups()):
+        if op == "attn":
+            xs = ((cache.k[a0:a0 + n], cache.v[a0:a0 + n])
+                  if cache is not None else (jnp.zeros((n, 0)),) * 2)
+            a0 += n
+        else:
+            xs = (cache.conv[c0:c0 + n] if cache is not None
+                  else jnp.zeros((n, b, taps - 1, hdim), x.dtype),)
+            c0 += n
+
+        def body(x, layer, op=op, ff=ff):
+            w, *state = layer
+            ys: dict[str, Any] = {}
+            if op == "attn":
+                k_l, v_l = state
+                x, kv_att, _ = attention_block(
+                    w, x, config=config, cos=cos, sin=sin, mask_global=mask,
+                    kv_update=(
+                        (lambda k, v: update_layer(k_l, v_l, k, v, offset))
+                        if cache is not None else None),
+                )
+                if cache is not None:
+                    ys["k"], ys["v"] = kv_att
+            else:
+                def history(z, state=state[0]):
+                    # the carried state in front of this call's tokens
+                    # (zeros before a sequence's start): token t's d-th
+                    # predecessor sits d places before it
+                    ext = jnp.concatenate([state.astype(z.dtype), z], axis=1)
+                    ys["conv"] = ext[:, s:]
+                    return [ext[:, taps - 1 - d:taps - 1 - d + s]
+                            for d in range(1, taps)]
+
+                x = conv_block(w, x, config=config, history=history,
+                               token_mask=token_mask)
+            if ff == "experts":
+                x, ys["experts"], _ = experts_block(
+                    w, x, config=config, act=act, live=token_mask)
+            else:
+                x, _ = ff_block(w, x, config=config, act=act)
+            return x, ys
+
+        x, ys = scan_group(body, x, (w_g, *xs), n)
+        if "k" in ys:
+            new_k.append(ys["k"])
+            new_v.append(ys["v"])
+        if "conv" in ys and cache is not None:
+            new_conv.append(ys["conv"].astype(cache.conv.dtype))
+        if "experts" in ys:
+            experts.append(ys["experts"])
+    cat = lambda parts: jnp.concatenate(parts, axis=0) if parts else None
+    x = x.astype(stream_dtype)
+    return (x, (cat(new_k), cat(new_v)) if cache is not None else None,
+            cat(new_conv), cat(experts))
 
 def forward(
     params: Params,
@@ -500,8 +806,13 @@ def forward(
     output_router_losses: bool = False,
     attn_impl: str = "xla",
     skip_logits: bool = False,
+    output_experts: bool = False,
 ) -> tuple:
     """Run the decoder.
+
+    output_experts=True (a stack with dropless expert layers) adds
+    ``aux["experts"]``: every expert layer's chosen experts,
+    ``[expert layers, B, S, k]`` int32.
 
     skip_logits=True returns the PRE-final-norm hidden states in the
     logits slot ([B, S, H], or [B, 1, H] under logits_last_only)
@@ -627,6 +938,32 @@ def forward(
         )
     else:
         mask_local = mask_global
+
+    if config.is_hybrid:
+        if output_attentions or output_hidden_states or attn_impl != "xla":
+            raise NotImplementedError(
+                "a hybrid layer stack runs attn_impl='xla' and collects "
+                "neither attentions nor hidden states"
+            )
+        x, new_kv, new_conv, experts = _hybrid_stack(
+            params["layers"], x, config, cache, offset=offset, cos=cos,
+            sin=sin, mask=mask_global, token_mask=(
+                jnp.broadcast_to(attn_mask, (b, s))
+                if attn_mask is not None else None),
+        )
+        logits = (
+            (x[:, -1:, :] if logits_last_only else x) if skip_logits
+            else final_logits(params, x, config, last_only=logits_last_only)
+        )
+        new_cache = None
+        if cache is not None:
+            new_cache = KVCache(
+                k=new_kv[0], v=new_kv[1], valid=cache_valid,
+                length=offset + s, conv=new_conv,
+            )
+        if output_experts:
+            return logits, new_cache, {"experts": experts}
+        return logits, new_cache
 
     lp = params["layers"]
     num_layers = config.num_hidden_layers

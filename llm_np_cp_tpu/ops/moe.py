@@ -1,4 +1,12 @@
-"""Sparse Mixture-of-Experts MLP (Mixtral-style top-k routing).
+"""Sparse Mixture-of-Experts MLPs: two routed layers.
+
+``moe_mlp`` (below, first) is Mixtral-style CAPACITY routing for the
+training / expert-parallel path; ``moe_dropless`` (at the end of the file)
+is the dropless sigmoid-routed layer LFM2-MoE is served and run offline
+with: every (token, expert) pair is computed, so a token's output does
+not depend on what else is in the batch.
+
+Capacity routing (Mixtral-style top-k routing).
 
 Framework extension: neither reference family is MoE (SURVEY §2.9 lists
 expert parallelism as N/A), but a real EP workload needs a real sparse
@@ -108,3 +116,118 @@ def moe_mlp(
     aux_loss = e * jnp.sum(route_frac * prob_frac)
 
     return out.reshape(b, s, h), aux_loss
+
+
+# ----------------------------------------------------------------------
+# Dropless sigmoid-routed experts (LFM2-MoE)
+# ----------------------------------------------------------------------
+
+# ``jax.named_scope`` names (models/transformer.STEP_SCOPES lists them, so
+# serve/opmap.py cuts a device profile by them)
+SCOPE_MOE_ROUTE = "moe_route"      # gate, sigmoid, bias, top-k, normalise, sort
+SCOPE_MOE_EXPERTS = "moe_experts"  # grouped matmuls, weighted combine
+
+
+def route_sigmoid_topk(
+    x: jnp.ndarray,              # [T, H]
+    router_w: jnp.ndarray,       # [H, E]
+    expert_bias: jnp.ndarray | None,  # [E] — selection only
+    *,
+    top_k: int,
+    norm_topk_prob: bool = True,
+    scaling: float = 1.0,
+    score_dtype: jnp.dtype = jnp.float32,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``(chosen experts [T, k] int32, their weights [T, k] f32)``.
+
+    Scores are ``sigmoid(x @ router_w)`` over ALL experts, in float32
+    from end to end: ``x`` is read as it is given (the float32 normed
+    activations where the caller keeps them — models/transformer
+    ``experts_block``), the product is taken at the highest matmul
+    precision and never rounded below float32.  The 4th and 5th score of
+    a token are often a few bf16 ulps apart, and a flipped choice is a
+    discrete change of the output that no dense layer has.  The top k are
+    chosen by ``score + expert_bias``; the weights are the scores WITHOUT
+    the bias, divided by their sum + 1e-6 (``norm_topk_prob``), times
+    ``scaling``.  ``score_dtype`` exists for the tests that show a bf16
+    router fails the float32 tolerance."""
+    logits = jnp.einsum(
+        "th,he->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits.astype(score_dtype)).astype(jnp.float32)
+    select = scores
+    if expert_bias is not None:
+        select = scores + expert_bias.astype(jnp.float32)
+    _, idx = lax.top_k(select, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), w * scaling
+
+
+def moe_dropless(
+    x: jnp.ndarray,         # [T, H] — float32 where the caller has it
+    router_w: jnp.ndarray,  # [H, E] — E: every expert of the layer
+    expert_bias: jnp.ndarray | None,
+    w1: jnp.ndarray,        # [E_held, H, I]  gate
+    w3: jnp.ndarray,        # [E_held, H, I]  up
+    w2: jnp.ndarray,        # [E_held, I, H]  down
+    *,
+    act,
+    top_k: int,
+    norm_topk_prob: bool = True,
+    scaling: float = 1.0,
+    live: jnp.ndarray | None = None,  # [T] bool — False: routed nowhere
+    first_expert: int = 0,
+    out_dtype: jnp.dtype | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Dropless routed SwiGLU experts: ``(out [T, H], chosen [T, k],
+    load [E_held] int32)``.
+
+    The (token, expert) pairs — a static ``T * k`` of them — are sorted by
+    expert and each projection is ONE grouped matmul over the experts
+    held (``lax.ragged_dot`` with the per-expert pair counts): no
+    capacity, no dropped token, and an expert nobody chose reads no
+    weights.  The layer routes over all ``E`` experts and holds
+    ``w1.shape[0]`` of them from ``first_expert`` on (all of them on one
+    chip); a pair whose expert is not held adds nothing here — that part
+    of the result is another holder's.  ``live`` marks the tokens that
+    are real: a dead lane of the serve tick's dense axis is routed to no
+    expert, so it neither reads an expert's weights nor counts in
+    ``load``.  The router reads ``x`` as given; the experts multiply it
+    in THEIR dtype (the served one) and the weighted sum, taken in
+    float32, comes back in ``out_dtype`` (default: the experts')."""
+    t, h = x.shape
+    held = w1.shape[0]
+    with jax.named_scope(SCOPE_MOE_ROUTE):
+        idx, wts = route_sigmoid_topk(
+            x, router_w, expert_bias, top_k=top_k,
+            norm_topk_prob=norm_topk_prob, scaling=scaling,
+        )
+        local = idx - first_expert
+        here = (local >= 0) & (local < held)
+        if live is not None:
+            here = here & live[:, None]
+        # pairs of no expert held sort last, past every group
+        pair_expert = jnp.where(here, local, held).reshape(t * top_k)
+        order = jnp.argsort(pair_expert, stable=True)
+        inverse = jnp.argsort(order)
+        load = jnp.sum(
+            pair_expert[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        x = x.astype(w1.dtype)
+        xs = x[order // top_k]  # [T*k, H], grouped by expert
+        gate = lax.ragged_dot(xs, w1, load, preferred_element_type=jnp.float32)
+        up = lax.ragged_dot(xs, w3, load, preferred_element_type=jnp.float32)
+        hidden = (act(gate.astype(x.dtype)) * up.astype(x.dtype)).astype(x.dtype)
+        ys = lax.ragged_dot(hidden, w2, load,
+                            preferred_element_type=jnp.float32)
+        # back to (token, choice) order; the weighted sum over a token's
+        # k experts in float32.  Rows past the last group hold nothing
+        # the sum may use.
+        ys = ys[inverse].reshape(t, top_k, h)
+        out = jnp.sum(
+            jnp.where(here[..., None], ys * wts[..., None], 0.0), axis=1)
+    return out.astype(out_dtype or x.dtype), idx, load

@@ -18,6 +18,18 @@ decode slots in the engine's fixed-width batch point their tables at it,
 so the packed decode step can write unconditionally (no data-dependent
 shapes) and garbage lands somewhere harmless.
 
+A configuration with conv layers (``config.conv_layers``, LFM2) has pages
+for its ATTENTION layers only (``L = len(config.attn_layers)``) and a
+second, constant-size store beside them in the same manager:
+
+    state: [conv layers, slots, conv_L_cache - 1, hidden]
+
+one row a slot, no blocks: the last gated inputs of the slot's sequence,
+which the short convolution of its next token reads.  The step carries
+and writes it like the pages (donated); a slot's row is never read by a
+sequence's first token (a position-0 token's history is zero), so
+admitting a request into a freed slot needs no clear.
+
 int8 mode mirrors ``KVCache``'s quantized slabs: per-token-per-head
 absmax scales (cache.quantize_kv layout) ride in parallel
 ``[L, NB, BS, K]`` f32 pages.
@@ -124,10 +136,17 @@ class PagedKV(NamedTuple):
     v: jnp.ndarray  # [L, NB, BS, K, D]
     k_scale: jnp.ndarray | None = None  # [L, NB, BS, K] f32 (int8 mode)
     v_scale: jnp.ndarray | None = None
+    # the short-convolution state of a configuration with conv layers
+    # (module docstring): not paged, one row a slot; None otherwise
+    state: jnp.ndarray | None = None  # [conv layers, slots, L_cache-1, H]
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    def pool_arrays(self) -> tuple[jnp.ndarray, ...]:
+        """The paged arrays (``[L, NB, BS, ..]``), without the state."""
+        return tuple(a for a in self[:4] if a is not None)
 
     @property
     def num_blocks(self) -> int:
@@ -153,6 +172,7 @@ class BlockPool:
         dtype: jnp.dtype = jnp.bfloat16,
         enable_prefix_cache: bool = False,
         shardings: "PagedKV | None" = None,
+        state_slots: int = 0,
     ) -> None:
         if block_size < 8 or block_size % 8:
             # Mosaic's second-minor alignment rule for the decode kernels;
@@ -169,7 +189,7 @@ class BlockPool:
         else:
             self.prefix_cache = None
         shape = (
-            config.num_hidden_layers,
+            len(config.attn_layers),
             num_blocks,
             block_size,
             config.num_key_value_heads,
@@ -209,6 +229,25 @@ class BlockPool:
             v_scale=(zeros(shape[:-1], jnp.float32, where.v_scale)
                      if quantized else None),
         )
+
+        # the short-convolution state of a configuration with conv layers
+        # (module docstring), in the activations' dtype whatever the K/V
+        # pages are quantized to; None for every other configuration
+        if config.conv_layers:
+            if state_slots < 1:
+                raise ValueError(
+                    "a configuration with conv layers needs state_slots "
+                    "(one state row a slot)")
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self.pages = self.pages._replace(state=zeros(
+                (len(config.conv_layers), state_slots,
+                 config.conv_L_cache - 1, config.hidden_size),
+                jnp.bfloat16 if quantized else dtype,
+                # a placement mesh pins the state beside the pages
+                None if shardings is None else NamedSharding(
+                    shardings.k.mesh, PartitionSpec()),
+            ))
 
     # -- accounting (delegates; the scheduler talks to these) ----------
     @property
@@ -279,7 +318,7 @@ class BlockPool:
 
         if self.pages is None:  # supervisor yanked the dead engine's slabs
             return {"kv_bytes_total": 0, "kv_bytes_shard": 0, "kv_shards": 1}
-        arrs = [a for a in self.pages if a is not None]
+        arrs = self.pages.pool_arrays()
         total = sum(a.nbytes for a in arrs)
         shard = 0
         for a in arrs:

@@ -120,15 +120,22 @@ from llm_np_cp_tpu.cache import KVCache, quantize_kv
 from llm_np_cp_tpu.config import ModelConfig
 from llm_np_cp_tpu.generate import IncrementalDetok, make_ragged_prefill_step
 from llm_np_cp_tpu.models.transformer import (
+    SCOPE_CONV,
     SCOPE_EMBED,
     SCOPE_TAIL,
+    attention_block,
+    conv_block,
     embed_inputs,
+    experts_block,
+    ff_block,
     final_logits,
     forward,
     run_decoder_layer,
+    scan_group,
     scan_unroll,
 )
 from llm_np_cp_tpu.ops.activations import ACT2FN
+from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS
 from llm_np_cp_tpu.ops.rope import rope_cos_sin
 from llm_np_cp_tpu.ops.sampling import Sampler
 from llm_np_cp_tpu.serve.block_pool import BlockPool, PagedKV
@@ -324,7 +331,7 @@ def _pool_is_row_major(pages: PagedKV) -> bool:
     return all(
         getattr(a.format.layout, "major_to_minor", None)
         == tuple(range(a.ndim))
-        for a in pages if a is not None
+        for a in pages.pool_arrays()
     )
 
 
@@ -456,6 +463,27 @@ class ServeEngine:
                 "host_tier requires enable_prefix_cache=True: the tier "
                 "is keyed by the prefix cache's chained content hashes"
             )
+        if config.conv_layers:
+            # a conv layer's state is a function of the WHOLE sequence so
+            # far and the engine keeps no snapshot of it: whatever skips
+            # prefill, rolls tokens back or cuts the hidden dimension over
+            # chips is refused here, by the flag that asked for it
+            refused = [
+                (enable_prefix_cache, "--prefix-cache (enable_prefix_cache): "
+                 "a prefix hit skips the prefill that builds the state"),
+                (spec_k > 0, "--spec-k (spec_k): rejected draft tokens "
+                 "cannot be rolled back out of the state"),
+                (mesh_plan is not None and mesh_plan.model > 1,
+                 "--mesh model>1 (mesh_plan): the state and the conv / "
+                 "expert weights have no sharding rule"),
+                (mixed_step == "off", "--mixed-step off (mixed_step): only "
+                 "the unified tick carries the state"),
+            ]
+            for hit, why in refused:
+                if hit:
+                    raise ValueError(
+                        f"model_type {config.model_type!r} has conv layers "
+                        f"with a recurrent state; refused: {why}")
         from llm_np_cp_tpu.ops.pallas.support import (
             gate_attn_impl,
             kernel_error,
@@ -578,6 +606,11 @@ class ServeEngine:
                 self.mixed, self.ragged_attn_impl = True, "xla"
             else:
                 self.mixed = False
+        if config.conv_layers and not self.mixed:
+            raise ValueError(
+                f"model_type {config.model_type!r} has conv layers and is "
+                "served by the unified tick only, which is unavailable "
+                f"here ({err}); --mixed-step on takes its XLA attention")
         # -- speculative serving (draft-then-verify in the unified tick):
         # per-request host-side prompt-lookup draft streams propose up to
         # spec_k tokens; the mixed step packs each speculating request as
@@ -700,6 +733,7 @@ class ServeEngine:
             config, num_blocks, block_size, dtype=cache_dtype,
             enable_prefix_cache=enable_prefix_cache,
             shardings=self._pool_shardings,
+            state_slots=max_slots,
         )
         if tracer is not None:
             jax.block_until_ready(self.pool.pages)
@@ -727,8 +761,7 @@ class ServeEngine:
         # bytes one pool block holds across all layers (K+V + int8
         # scale pages) — the unit every tier ledger counts in
         self._block_nbytes = int(sum(
-            a.nbytes // a.shape[1] for a in self.pool.pages
-            if a is not None
+            a.nbytes // a.shape[1] for a in self.pool.pages.pool_arrays()
         ))
         # per-tick tier observables (engine-thread-owned, reset at tick
         # start, reported in the tick trace args when the tier is on)
@@ -819,6 +852,9 @@ class ServeEngine:
                     "logits tail", epi_err,
                 )
 
+        # dropless expert layers (unified tick only): their per-expert token
+        # counts come back with the tick's one fetch
+        self._n_expert_layers = len(config.expert_layers)
         if self.mixed:
             # -- unified tick: ONE jitted program, bucketed packed width.
             # The temp prefill cache, scatter_prefill, gather_prefix and
@@ -984,8 +1020,11 @@ class ServeEngine:
         would retrace every tick."""
         if self._pool_shardings is None:
             return pages
-        return jax.tree.map(lax.with_sharding_constraint, pages,
-                            self._pool_shardings)
+        # the conv state (placement meshes only: TP is refused for it)
+        # is not paged and has no sharding of its own to pin
+        return jax.tree.map(
+            lax.with_sharding_constraint, pages._replace(state=None),
+            self._pool_shardings)._replace(state=pages.state)
 
     def _make_temp_cache(self) -> KVCache:
         cache = KVCache.init(self.config, 1, self.max_seq_len,
@@ -1869,10 +1908,13 @@ class ServeEngine:
             quantized=quantized, n_meta=6, q_head_axis=1,
         )
 
+        hybrid = config.is_hybrid
+        max_slots = geometry[1]
+
         @partial(jax.jit, donate_argnums=(1,))
         def mixed_step(
             params: Params,
-            pages: PagedKV,
+            pages: PagedKV,  # the pool and, beside it, the conv state
             ops: jnp.ndarray,  # the packed operand (mixed_operand_layout)
         ):
             with jax.named_scope(SCOPE_EMBED):
@@ -1905,17 +1947,29 @@ class ServeEngine:
             # not keep row-major would be relaid out WHOLE every tick
             # that way: its layers get their slabs as ``xs`` / ``ys``.
             nb = pages.k.shape[1]
-            pools = tuple(a for a in pages if a is not None)
-            layers = jnp.arange(num_layers, dtype=jnp.int32)
+            pools, state = pages.pool_arrays(), pages.state
+            n_paged = pages.k.shape[0]  # layers that have pages
+            layers = jnp.arange(n_paged, dtype=jnp.int32)
 
-            def layer_step(carry: Any, xs: tuple) -> tuple:
-                w, sliding, layer, *slabs = xs
-                if carry_pool:
-                    x, kp, vp, *scale_pages = carry
-                    base = layer * nb
-                else:
-                    x, (kp, vp, *scale_pages), base = carry, slabs, 0
+            def paged_hooks(kp, vp, scale_pages, base, layer=None,
+                            written=None):
+                """One layer's cache write and attention over the pages
+                it is given: ``(kv_update, attn_fn)``.  The pages are a
+                pool of blocks (one layer's slab, or the pool flat over
+                layer and block with the layer's ``base``) — or, with
+                ``layer``, the WHOLE pool ``[L, NB, ..]`` as the device
+                keeps it: the write then lands in place at ``[layer,
+                block, slot]``, the updated arrays are left in
+                ``written``, and attention reads the layer's slab."""
                 blk = base + tok_blk
+
+                def put(pool, val):
+                    if layer is None:
+                        return pool.at[blk, tok_off].set(val)
+                    return pool.at[layer, blk, tok_off].set(val)
+
+                def slab(pool):
+                    return pool if layer is None else pool[layer]
 
                 def kv_update(k, v):  # fresh projections [1, D, K, Dh]
                     # the few lanes that pad the dense axis all write
@@ -1925,18 +1979,19 @@ class ServeEngine:
                         ksp, vsp = scale_pages
                         kq, ks = quantize_kv(k)
                         vq, vs = quantize_kv(v)
-                        return (
-                            (kp.at[blk, tok_off].set(kq[0]),
-                             ksp.at[blk, tok_off].set(ks[0])),
-                            (vp.at[blk, tok_off].set(vq[0]),
-                             vsp.at[blk, tok_off].set(vs[0])),
-                        )
+                        new = (put(kp, kq[0]), put(vp, vq[0]),
+                               put(ksp, ks[0]), put(vsp, vs[0]))
+                        if written is not None:
+                            written.extend(new)
+                        return ((slab(new[0]), slab(new[2])),
+                                (slab(new[1]), slab(new[3])))
                     # explicit cast: f32 activations into a bf16 pool
                     # is the intended rounding, not an implicit promotion
-                    return (
-                        kp.at[blk, tok_off].set(k[0].astype(kp.dtype)),
-                        vp.at[blk, tok_off].set(v[0].astype(vp.dtype)),
-                    )
+                    new = (put(kp, k[0].astype(kp.dtype)),
+                           put(vp, v[0].astype(vp.dtype)))
+                    if written is not None:
+                        written.extend(new)
+                    return slab(new[0]), slab(new[1])
 
                 def attn_fn(q, k_att, v_att, sliding_l):
                     if quantized:
@@ -1967,6 +2022,16 @@ class ServeEngine:
                         )
                     return out[None]
 
+                return kv_update, attn_fn
+
+            def layer_step(carry: Any, xs: tuple) -> tuple:
+                w, sliding, layer, *slabs = xs
+                if carry_pool:
+                    x, kp, vp, *scale_pages = carry
+                    base = layer * nb
+                else:
+                    x, (kp, vp, *scale_pages), base = carry, slabs, 0
+                kv_update, attn_fn = paged_hooks(kp, vp, scale_pages, base)
                 x, kv_att, _, _ = run_decoder_layer(
                     w, x, config=config, act=act, cos=cos, sin=sin,
                     sliding=sliding, kv_update=kv_update, attn_fn=attn_fn,
@@ -1976,16 +2041,26 @@ class ServeEngine:
                     kv_att = (kp2, vp2, ksp2, vsp2)
                 return ((x, *kv_att), None) if carry_pool else (x, kv_att)
 
-            xs = (params["layers"], is_sliding, layers)
-            if carry_pool:
+            loads = None
+            if hybrid:
+                # runs of like layers carry the pool as the device keeps
+                # it, written in place at [layer, block, slot]
+                x, new_pools, new_state, loads = hybrid_layers(
+                    params["layers"], x, pools, state,
+                    paged_hooks=paged_hooks, act=act, cos=cos, sin=sin,
+                    layers=layers, ops=o)
+                new_pages = PagedKV(*new_pools)._replace(state=new_state)
+            elif carry_pool:
+                xs = (params["layers"], is_sliding, layers)
                 (x, *flat), _ = lax.scan(
                     layer_step,
-                    (x, *(a.reshape((num_layers * nb,) + a.shape[2:])
+                    (x, *(a.reshape((n_paged * nb,) + a.shape[2:])
                           for a in pools)),
                     xs, unroll=scan_unroll(config))
                 new_pages = PagedKV(*(
                     a.reshape(p.shape) for a, p in zip(flat, pools)))
             else:
+                xs = (params["layers"], is_sliding, layers)
                 x, ys = lax.scan(layer_step, x, xs + pools,
                                  unroll=scan_unroll(config))
                 new_pages = PagedKV(*ys)
@@ -2043,7 +2118,116 @@ class ServeEngine:
                 packed = _pack_sync(
                     nxt, _stop_hits(nxt, stop_tokens), accept
                 )
+                if loads is not None:
+                    # every expert layer's per-expert token counts ride
+                    # the tick's one fetch, behind the rows' outcome
+                    packed = jnp.concatenate(
+                        [packed.reshape(-1), loads.reshape(-1)])
             return packed, new_pages
+
+        def hybrid_layers(groups, x, pools, state, *, paged_hooks,
+                          act, cos, sin, layers, ops):
+            """The layer loop of a stack of more than one kind of layer:
+            each run of like layers (``config.layer_groups``) is one scan
+            over its own stacked leaves, and every run carries the pool
+            WHOLE, as the device keeps it: an attention layer writes at
+            ``[layer, block, slot]`` in place and attends its own slab
+            (``paged_hooks(layer=)``; no slab is written back, and no
+            reshape asks the device for another order).  A conv run takes
+            and gives back its layers' rows of the conv state.  Returns
+            ``(x, pool, state, per-expert loads [expert layers, E] |
+            None)``."""
+            tok_row, tok_live = ops["tok_row"], ops["tok_live"]
+            positions = ops["positions"]
+            d_w = tok_row.shape[0]
+            taps = config.conv_L_cache
+            with jax.named_scope(SCOPE_CONV):
+                # where token i's predecessors in its own sequence are:
+                # ``run`` of them are the packed tokens before it (a row's
+                # tokens are consecutive on the dense axis), the rest the
+                # slot's state
+                idx = jnp.arange(d_w, dtype=jnp.int32)
+                joined = jnp.concatenate([
+                    jnp.zeros((1,), jnp.bool_),
+                    (tok_row[1:] == tok_row[:-1]) & tok_live[1:]
+                    & tok_live[:-1]])
+                run = idx - lax.cummax(jnp.where(joined, 0, idx))
+                ends = tok_live & ~jnp.concatenate(
+                    [joined[1:], jnp.zeros((1,), jnp.bool_)])
+                # a row's last token of the tick leaves the row's state
+                row_out = jnp.where(ends, tok_row, max_slots)  # else: dropped
+
+            def conv_history(z, state_l, kept):
+                zz = z[0]  # [D, H]
+                rows = state_l[tok_row].astype(zz.dtype)  # [D, taps-1, H]
+                hist = []
+                for d in range(1, taps):
+                    from_state = jnp.take_along_axis(
+                        rows, jnp.clip(taps - 1 - d + run, 0, taps - 2)[
+                            :, None, None], axis=1)[:, 0]
+                    h_d = jnp.where((run >= d)[:, None],
+                                    jnp.roll(zz, d, axis=0), from_state)
+                    # before the sequence's start there is nothing: a
+                    # slot's stale rows are never read by a new request
+                    hist.append(jnp.where((positions >= d)[:, None], h_d,
+                                          jnp.zeros_like(h_d)))
+                new_rows = jnp.stack(hist[:taps - 2][::-1] + [zz], axis=1)
+                kept.append(state_l.at[row_out].set(
+                    new_rows.astype(state_l.dtype), mode="drop"))
+                return [h[None] for h in hist]
+
+            states, loads = [], []
+            a0 = c0 = 0
+            # float32 between the blocks, as models.forward keeps it
+            # (transformer._hybrid_stack says why)
+            stream_dtype, x = x.dtype, x.astype(jnp.float32)
+            for w_g, (op, ff, _, n) in zip(groups, config.layer_groups()):
+                if op == "attn":
+                    xs = (layers[a0:a0 + n],)
+                    a0 += n
+                else:
+                    with jax.named_scope(SCOPE_CONV):
+                        xs = (state[c0:c0 + n],)
+                    c0 += n
+
+                def body(carry, layer, op=op, ff=ff):
+                    x, *pool = carry
+                    w, extra = layer
+                    ys: dict[str, Any] = {}
+                    if op == "attn":
+                        kp, vp, *scale_pages = pool
+                        pool = []  # kv_update leaves the written arrays here
+                        kv_update, attn_fn = paged_hooks(
+                            kp, vp, scale_pages, 0, layer=extra, written=pool)
+                        x, _, _ = attention_block(
+                            w, x, config=config, cos=cos, sin=sin,
+                            kv_update=kv_update, attn_fn=attn_fn,
+                        )
+                    else:
+                        kept: list = []
+                        x = conv_block(
+                            w, x, config=config,
+                            history=lambda z: conv_history(z, extra, kept))
+                        ys["state"] = kept[0]
+                    if ff == "experts":
+                        x, _, ys["load"] = experts_block(
+                            w, x, config=config, act=act,
+                            live=tok_live[None])
+                    else:
+                        x, _ = ff_block(w, x, config=config, act=act)
+                    return (x, *pool), ys
+
+                (x, *pools), ys = scan_group(
+                    body, (x, *pools), (w_g, *xs), n)
+                if "state" in ys:
+                    states.append(ys["state"])
+                if "load" in ys:
+                    loads.append(ys["load"])
+            with jax.named_scope(SCOPE_CONV):
+                new_state = (jnp.concatenate(states, axis=0)
+                             if states else state)
+            return (x.astype(stream_dtype), tuple(pools), new_state,
+                    jnp.concatenate(loads, axis=0) if loads else None)
 
         return mixed_step
 
@@ -3355,6 +3539,7 @@ class ServeEngine:
         n_spec_acc = 0
         tel = None
         cost = None
+        expert_load = None
         if decode_rows or prefill_segs:
             if self.telemetry is not None:
                 # the analytic byte/FLOP bill MUST run before the
@@ -3404,6 +3589,15 @@ class ServeEngine:
             # host-side (see _pack_sync on the other two)
             out_host = np.asarray(out)
             self.n_host_fetches += 1
+            if self._n_expert_layers:
+                # the same fetch carries every expert layer's per-expert
+                # token counts behind the rows' outcome (_make_mixed_step)
+                n_rows = out_host.size - (
+                    self._n_expert_layers * self.config.num_experts)
+                expert_load = out_host[n_rows:].reshape(
+                    self._n_expert_layers, -1)
+                out_host = out_host[:n_rows].reshape(
+                    -1, self._spec_w + 3)
             nxt_host = out_host[:, : self._spec_w]
             accept_host = out_host[:, self._spec_w + 2]
             cpu5 = time.thread_time_ns() if self.tracer is not None else 0
@@ -3504,6 +3698,21 @@ class ServeEngine:
             decode_tokens=n_decode_tok,
             dense_lanes=dense_width,
         )
+        if expert_load is not None:
+            worst = expert_load[int(np.argmax(expert_load.max(axis=1)))]
+            moe = {
+                # experts that got a token, summed over the expert layers
+                "experts_touched": int(np.count_nonzero(expert_load)),
+                # tokens an expert, worst layer: the most and the mean
+                "expert_load_max": int(worst.max()),
+                "expert_load_mean": round(float(worst.mean()), 3),
+                "state_slots_live": len(self.scheduler.running),
+            }
+            self.metrics.on_experts(
+                touched=moe["experts_touched"],
+                load_max=moe["expert_load_max"],
+                load_mean=moe["expert_load_mean"],
+                state_slots_live=moe["state_slots_live"])
         outliers: list[dict] = []
         if self.tracer is not None and t0 >= 0.0:
             t7 = self._phase_mark(None)
@@ -3535,6 +3744,11 @@ class ServeEngine:
                 "thread_cpu_us": round(
                     (time.thread_time_ns() - cpu0 - (cpu5 - cpu4)) / 1e3, 1),
             }
+            if expert_load is not None:
+                # the expert layers as the step counted them (the tick's
+                # one fetch): summarize_trace's transfers section and
+                # the benchmark's moe.* readers
+                targs.update(moe)
             if self.spec_k:
                 # the draft/verify split for summarize_trace and the
                 # sentinel: how many verify lanes rode this tick's
@@ -3700,16 +3914,26 @@ class ServeEngine:
         from llm_np_cp_tpu.models.transformer import STEP_SCOPES
         from llm_np_cp_tpu.serve import opmap
 
-        pool = opmap.pool_shapes(
-            (a.dtype.name, a.sharding.shard_shape(a.shape))
-            for a in self.pool.pages if a is not None
-        )
+        moved = [(a.dtype.name, a.sharding.shard_shape(a.shape))
+                 for a in self.pool.pages.pool_arrays()]
+        state = self.pool.pages.state
+        if state is not None:
+            # the conv state is marked like the pool: whole, and as the
+            # rows each run of conv layers takes and gives back
+            moved.append((state.dtype.name, state.shape))
+            moved.extend(
+                (state.dtype.name, (n,) + state.shape[1:])
+                for op, _, _, n in self.config.layer_groups() if op == "conv")
+        pool = opmap.pool_shapes(moved)
         return opmap.merge(
             opmap.op_map_from_hlo(
                 self._mixed_step.lower(
                     self.params, self.pool.pages,
                     self._put(self._dead_mixed_operands(*program)),
-                ).compile().as_text(), STEP_SCOPES, pool)
+                ).compile().as_text(), STEP_SCOPES, pool,
+                # the grouped matmuls of the expert layers, which a TPU
+                # compiles to custom calls of its own naming
+                named=(("ragged-dot", SCOPE_MOE_EXPERTS),))
             for program in self.mixed_buckets
         )
 

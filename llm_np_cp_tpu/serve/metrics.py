@@ -179,6 +179,17 @@ class ServeMetrics:
         # dispatched at, summed over dispatches: tokens / lanes is the
         # share of the matmuls' rows that hold a token
         self.mixed_dense_lanes = 0
+        # dropless expert layers (exact counters, one observation a
+        # dispatching tick): experts that got at least one token, summed
+        # over the expert layers; and of the worst layer the most tokens
+        # an expert got beside the mean — max / mean is the skew a
+        # grouped matmul pays for.  conv_state_slots: slots whose
+        # short-convolution state is live (a gauge)
+        self.moe_ticks = 0
+        self.moe_experts_touched = 0
+        self.moe_load_max = 0
+        self.moe_load_mean = 0.0
+        self.conv_state_slots = 0
         # speculative draft-then-verify accounting (exact counters +
         # a real accept-length histogram over SPEC_ACCEPT_BUCKETS —
         # one observation per verify round, value = accepted drafts)
@@ -268,6 +279,17 @@ class ServeMetrics:
         add/remove_replica."""
         with self._lock:
             self.lifecycle_actions[action] += 1
+
+    def on_experts(self, *, touched: int, load_max: int, load_mean: float,
+                   state_slots_live: int) -> None:
+        """One dispatching tick of a stack with dropless expert layers
+        (and, beside them, conv layers with a per-slot state)."""
+        with self._lock:
+            self.moe_ticks += 1
+            self.moe_experts_touched += touched
+            self.moe_load_max += load_max
+            self.moe_load_mean += load_mean
+            self.conv_state_slots = state_slots_live
 
     def on_spec(self, *, drafted: int, accepted: int) -> None:
         """One speculative verify round for one request: ``drafted``
@@ -455,6 +477,13 @@ class ServeMetrics:
             out["mixed_prefill_tokens"] = self.mixed_prefill_tokens
             out["mixed_decode_tokens"] = self.mixed_decode_tokens
             out["mixed_dense_lanes"] = self.mixed_dense_lanes
+            if self.moe_ticks:
+                # only where an expert layer ran (like the spec block)
+                out["moe_ticks"] = self.moe_ticks
+                out["moe_experts_touched"] = self.moe_experts_touched
+                out["moe_expert_load_max"] = self.moe_load_max
+                out["moe_expert_load_mean"] = self.moe_load_mean
+                out["conv_state_slots_live"] = self.conv_state_slots
             if self.spec_rounds:
                 # reported only once a verify round ran (like the SLO
                 # block): a fabricated 0-acceptance series on a
@@ -638,6 +667,23 @@ class ServeMetrics:
              "dispatches (mixed_tokens_total / this = the share of "
              "lanes that hold a token)",
              [("", s["mixed_dense_lanes"])])
+        if "moe_ticks" in s:
+            emit("moe_ticks_total", "counter",
+                 "Dispatching ticks that ran dropless expert layers",
+                 [("", s["moe_ticks"])])
+            emit("moe_experts_touched_total", "counter",
+                 "Experts that got at least one token, summed over the "
+                 "expert layers and over ticks",
+                 [("", s["moe_experts_touched"])])
+            emit("moe_expert_load_total", "counter",
+                 "Tokens an expert of the most loaded layer got, summed "
+                 "over ticks: the most loaded expert and the mean "
+                 "(max / mean is the skew a grouped matmul pays for)",
+                 [('{kind="max"}', s["moe_expert_load_max"]),
+                  ('{kind="mean"}', s["moe_expert_load_mean"])])
+            emit("conv_state_slots_live", "gauge",
+                 "Slots whose short-convolution state is live",
+                 [("", s["conv_state_slots_live"])])
         # -- speculative decoding (only once a verify round ran — a
         # constant-zero series on a plain engine would read as a broken
         # speculation deployment on a fleet dashboard)

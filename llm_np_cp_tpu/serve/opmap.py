@@ -98,12 +98,19 @@ def _pool_kind(shape: str, pool: dict[str, str]) -> str:
 
 
 def op_map_from_hlo(text: str, scopes: Iterable[str],
-                    pool: dict[str, str]) -> dict[str, list]:
+                    pool: dict[str, str],
+                    named: Iterable[tuple[str, str]] = ()) -> dict[str, list]:
     """``{instruction name: [scope, result shape, pool kind]}`` for the
     instructions of one compiled module that run as operations: those of
     the entry computation and of the loop bodies, conditions and branches
-    reachable from it."""
+    reachable from it.  ``named``: ``(instruction-name prefix, scope)``
+    for operations the compiler REWRITES and names itself, dropping the
+    ``op_name`` they were traced under (a TPU turns ``lax.ragged_dot``
+    into the custom calls ``%ragged-dot-none`` / ``%ragged-dot-metadata``
+    with ``op_name="ragged-dot-none"``): such an operation is told by its
+    own name, where its ``op_name`` names no scope."""
     scopes = frozenset(scopes)
+    named = tuple(named)
     computations: dict[str, list[tuple]] = {}
     calls: dict[str, set[str]] = {}
     entry = current = None
@@ -132,9 +139,11 @@ def op_map_from_hlo(text: str, scopes: Iterable[str],
         if opcode in _SILENT:
             continue
         op = _OP_NAME.search(line)
-        computations[current].append((
-            name, _scope_of(op.group(1), scopes) if op else "",
-            shape[:SHAPE_LIMIT]))
+        scope = _scope_of(op.group(1), scopes) if op else ""
+        if not scope:
+            scope = next((sc for prefix, sc in named
+                          if name.startswith(prefix)), "")
+        computations[current].append((name, scope, shape[:SHAPE_LIMIT]))
     out: dict[str, list] = {}
     todo, seen = [entry] if entry else [], set()
     while todo:

@@ -43,7 +43,7 @@ import numpy as np
 from safetensors import safe_open
 
 from llm_np_cp_tpu.config import ModelConfig
-from llm_np_cp_tpu.models import gemma2, llama, qwen2
+from llm_np_cp_tpu.models import gemma2, lfm2_moe, llama, qwen2
 from llm_np_cp_tpu.models.transformer import param_shapes
 
 log = logging.getLogger("llm_np_cp_tpu")
@@ -109,6 +109,10 @@ def _read_shard(
 
 
 def _key_maps(config: ModelConfig):
+    if config.is_hybrid:
+        # a per-layer tensor's place is not leaf[layer] there: the
+        # family's own table (models/lfm2_moe.layer_tensors) says where
+        return {}, lfm2_moe.TOP_KEY_MAP
     family = {"gemma2": gemma2, "qwen2": qwen2}.get(config.model_type, llama)
     return family.LAYER_KEY_MAP, family.TOP_KEY_MAP
 
@@ -190,14 +194,21 @@ def load_params(
     shapes = param_shapes(config)
 
     # Preallocated stacked host buffers.
+    def buffers(leaves: dict) -> dict:
+        # the experts' selection bias stays float32 whatever is served
+        return {name: np.empty(shape, dtype=(
+            np.float32 if name == "expert_bias" else np_dtype))
+            for name, shape in leaves.items()}
+
     host: dict[str, Any] = {
         "embed_tokens": np.empty(shapes["embed_tokens"], dtype=np_dtype),
         "final_norm": np.empty(shapes["final_norm"], dtype=np_dtype),
-        "layers": {
-            name: np.empty(shape, dtype=np_dtype)
-            for name, shape in shapes["layers"].items()
-        },
+        "layers": ([buffers(g) for g in shapes["layers"]]
+                   if config.is_hybrid else buffers(shapes["layers"])),
     }
+    # a hybrid stack's per-layer tensors: HF key → (run, leaf, index, T?)
+    placed = {key: rest for key, *rest in lfm2_moe.layer_tensors(config)
+              } if config.is_hybrid else {}
     if "lm_head" in shapes:
         host["lm_head"] = np.empty(shapes["lm_head"], dtype=np_dtype)
 
@@ -213,6 +224,8 @@ def load_params(
         value = f.get_tensor(key)
         if transpose:
             value = value.T
+        if value.ndim == 3 and dest.ndim == 2 and value.shape[1] == 1:
+            value = value[:, 0]  # a depthwise Conv1d weight [H, 1, L]
         if dest.shape != value.shape:
             raise ValueError(
                 f"{key}: checkpoint shape {value.shape} != expected {dest.shape}"
@@ -221,8 +234,16 @@ def load_params(
 
     def consume(f: Any, native: bool) -> None:
         for key in f.keys():
+            if key in placed:
+                run, leaf, index, transpose = placed[key]
+                dest = host["layers"][run][leaf][index]
+                if native and leaf == "conv_filter":
+                    dest = dest[:, None, :]  # as stored: [H, 1, L]
+                fill(f, native, key, dest, transpose)
+                filled.add(key)
+                continue
             m = _LAYER_RE.match(key)
-            if m:
+            if m and not placed:
                 idx, suffix = int(m.group(1)), m.group(2)
                 if suffix not in layer_map:
                     continue  # e.g. rotary inv_freq buffers
@@ -255,6 +276,13 @@ def load_params(
     for path in shard_files(model_dir):
         _read_shard(path, use_native, consume)
 
+    if placed:
+        missing = sorted(set(placed) - filled)
+        if missing:
+            raise ValueError(
+                f"checkpoint incomplete: {len(missing)} tensors missing "
+                f"({', '.join(missing[:6])}"
+                + (", ..." if len(missing) > 6 else "") + ")")
     _check_complete(host, filled, config)
 
     def place(path_: tuple, buf: np.ndarray):
@@ -270,6 +298,9 @@ def load_params(
     for k, v in host.items():
         if isinstance(v, dict):
             params[k] = {k2: place((k, k2), v2) for k2, v2 in v.items()}
+        elif isinstance(v, list):
+            params[k] = [{k2: place((k, i, k2), v2) for k2, v2 in g.items()}
+                         for i, g in enumerate(v)]
         else:
             params[k] = place((k,), v)
     return params, config
@@ -280,13 +311,20 @@ def _tree_get(tree: Any, path: tuple):
     for p in path:
         if node is None:
             return None
-        node = node.get(p) if isinstance(node, dict) else None
+        if isinstance(node, dict):
+            node = node.get(p)
+        elif isinstance(node, (list, tuple)) and isinstance(p, int):
+            node = node[p] if p < len(node) else None
+        else:
+            node = None
     return node
 
 
 def _check_complete(host: dict, filled: set, config: ModelConfig) -> None:
     missing: list[str] = []
     for name in host:
+        if name == "layers" and isinstance(host["layers"], list):
+            continue  # a hybrid stack: checked against its own table
         if name == "layers":
             for lname in host["layers"]:
                 for i in range(config.num_hidden_layers):

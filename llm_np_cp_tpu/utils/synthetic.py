@@ -23,6 +23,7 @@ import numpy as np
 from safetensors.numpy import save_file
 
 from llm_np_cp_tpu.config import ModelConfig
+from llm_np_cp_tpu.models import lfm2_moe
 from llm_np_cp_tpu.models.transformer import param_shapes
 from llm_np_cp_tpu.utils.loading import _key_maps
 
@@ -70,6 +71,22 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
             "original_max_position_embeddings":
                 config.rope_scaling_original_max_position,
         }
+    if config.model_type == "lfm2_moe":
+        d.pop("rms_norm_eps")
+        d.pop("attention_bias")
+        d.update(
+            norm_eps=config.rms_norm_eps,
+            layer_types=list(config.layer_types),
+            conv_L_cache=config.conv_L_cache,
+            conv_bias=config.conv_bias,
+            num_dense_layers=config.num_dense_layers,
+            num_experts=config.num_experts,
+            num_experts_per_tok=config.num_experts_per_tok,
+            moe_intermediate_size=config.moe_intermediate_size,
+            use_expert_bias=config.use_expert_bias,
+            norm_topk_prob=config.norm_topk_prob,
+            routed_scaling_factor=config.routed_scaling_factor,
+        )
     if config.model_type == "gemma2":
         d.update(
             final_logit_softcapping=config.final_logit_softcapping,
@@ -94,6 +111,13 @@ def hf_tensor_shapes(config: ModelConfig) -> dict[str, tuple[int, ...]]:
     for hf_key, (name, transpose) in top_map.items():
         if name in shapes and not isinstance(shapes[name], dict):
             out[hf_key] = stored(shapes[name], transpose)
+    if config.is_hybrid:
+        for key, run, leaf, index, transpose in lfm2_moe.layer_tensors(config):
+            shape = shapes["layers"][run][leaf][len(index):]
+            if leaf == "conv_filter":  # a depthwise Conv1d weight [H, 1, L]
+                shape = (shape[0], 1, shape[1])
+            out[key] = stored(shape, transpose)
+        return out
     for suffix, (name, transpose) in layer_map.items():
         if name in shapes["layers"]:
             per_layer = shapes["layers"][name][1:]
@@ -112,6 +136,13 @@ def hf_state_dict(params: Mapping[str, Any],
         if name in params:
             t = params[name]
             out[hf_key] = np.ascontiguousarray(t.T if transpose else t)
+    if config.is_hybrid:
+        for key, run, leaf, index, transpose in lfm2_moe.layer_tensors(config):
+            t = np.asarray(params["layers"][run][leaf][index])
+            if leaf == "conv_filter":
+                t = t[:, None, :]
+            out[key] = np.ascontiguousarray(t.T if transpose else t)
+        return out
     for suffix, (name, transpose) in layer_map.items():
         if name in params["layers"]:
             for i, t in enumerate(params["layers"][name]):

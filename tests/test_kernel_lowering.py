@@ -31,7 +31,11 @@ from jax import export
 
 from llm_np_cp_tpu.config import tiny_config
 from llm_np_cp_tpu.models import init_params
-from llm_np_cp_tpu.models.transformer import SCOPE_KV_WRITE, STEP_SCOPES
+from llm_np_cp_tpu.models.transformer import (
+    HYBRID_SCOPES,
+    SCOPE_KV_WRITE,
+    STEP_SCOPES,
+)
 from llm_np_cp_tpu.ops.pallas.decode_attention import decode_attention
 from llm_np_cp_tpu.ops.pallas.flash_attention import flash_attention
 from llm_np_cp_tpu.serve import ServeEngine, opmap
@@ -221,10 +225,10 @@ SLOTS, BLOCKS, BLOCK, CHUNK = 4, 256, 64, 64
 
 
 def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS, slots=SLOTS,
-                           program=None):
-    """The unified step of a small Qwen2.5-shaped engine, compiled for a
-    described v5e as ``program`` (its widest by default)."""
-    cfg = tiny_config(
+                           program=None, cfg=None):
+    """The unified step of a small Qwen2.5-shaped engine (or of ``cfg``),
+    compiled for a described v5e as ``program`` (its widest by default)."""
+    cfg = cfg or tiny_config(
         "qwen2", num_hidden_layers=3, hidden_size=1536,
         intermediate_size=1024, num_attention_heads=12,
         num_key_value_heads=2, head_dim=128, vocab_size=2048,
@@ -285,7 +289,8 @@ def test_compiled_tick_writes_the_pool_in_place(v5e_sharding, cache_dtype):
         engine._mixed_layouts[engine.mixed_buckets[-1]][1],))]
 
     ops = _pool_ops(engine, compiled)
-    assert {scope for scope, _, _ in ops.values()} >= set(STEP_SCOPES)
+    assert {scope for scope, _, _ in ops.values()} >= (
+        set(STEP_SCOPES) - set(HYBRID_SCOPES))
     slabs = {n: v for n, v in ops.items() if v[2] == "slab"}
     assert not slabs, f"a layer's slab is sliced out or rebuilt: {slabs}"
     moved = {n: v for n, v in ops.items()
@@ -456,7 +461,7 @@ def test_int8_pool_on_a_v5e_is_why_the_slab_form_stays(
         written = {v[2] for v in _pool_ops(engine, compiled).values()
                    if v[0] == SCOPE_KV_WRITE and v[2]}
         assert written == {"pool" if carried else "slab"}
-    pool_bytes = sum(a.nbytes for a in engine.pool.pages)
+    pool_bytes = sum(a.nbytes for a in engine.pool.pages.pool_arrays())
     assert temps[False] < pool_bytes < temps[True], temps
 
 
@@ -494,3 +499,43 @@ def test_a_pool_on_the_cpu_is_row_major():
     for dtype in (jnp.float32, jnp.int8):
         pool = BlockPool(tiny_config("llama"), 8, 8, dtype=dtype)
         assert _pool_is_row_major(pool.pages)
+
+
+def test_hybrid_tick_on_a_v5e_names_its_experts_and_rebuilds_no_pool(
+        v5e_sharding, monkeypatch):
+    """A stack of conv / attention layers over dense / expert feed-forwards
+    (LFM2-MoE's shapes in small: ``head_dim`` 64, which a v5e does not keep
+    row-major).  Two things its compiler did to earlier forms of the step
+    (PERF.md section 6, PR 32): a scan over stacked expert layers copied a
+    layer's expert tensors out of the stack every step, and concatenating
+    per-layer slabs became whole-pool pad + maximum passes."""
+    import llm_np_cp_tpu.serve.engine as engine_mod
+
+    # what Array.format says of such a pool on the chip; the CPU's says yes
+    monkeypatch.setattr(engine_mod, "_pool_is_row_major", lambda pages: False)
+    cfg = tiny_config(
+        "lfm2_moe", hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=256, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=64, vocab_size=2048)
+    engine, compiled = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, program=(SLOTS * 8, 8))
+    text = compiled.as_text()
+    assert "input_output_alias" in text  # the pool and the state come back in place
+    pool = opmap.pool_shapes(
+        [(a.dtype.name, a.shape) for a in engine.pool.pages.pool_arrays()]
+        + [(engine.pool.pages.state.dtype.name, engine.pool.pages.state.shape)])
+    ops = opmap.op_map_from_hlo(
+        text, STEP_SCOPES, pool, named=(("ragged-dot", "moe_experts"),))
+    grouped = [n for n in ops if n.startswith("ragged-dot-none")]
+    # 8 expert layers x 3 projections, each told by its own name
+    assert len(grouped) == 24 and all(
+        ops[n][0] == "moe_experts" for n in grouped)
+    assert {"conv", "moe_route", "moe_experts", "attn", "mlp"} <= {
+        v[0] for v in ops.values()}
+    # (that an expert layer is never stacked, so that no scan slices a
+    # layer's experts out of a stack, is config.layer_groups' rule:
+    # tests/test_lfm2_moe.py)
+    # the pool is neither padded nor concatenated back together
+    rebuilt = [n for n, v in ops.items() if v[2] == "pool"
+               and re.match(r"(pad|concatenate|maximum)", n)]
+    assert not rebuilt, rebuilt

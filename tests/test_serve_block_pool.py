@@ -137,7 +137,8 @@ def test_block_pool_slabs_are_born_on_their_sharding():
     with jax.transfer_guard_device_to_device("disallow"):
         pool = BlockPool(cfg, num_blocks=4, block_size=8, dtype=jnp.int8,
                          shardings=shardings)
-    for slab in pool.pages:
+    assert pool.pages.state is None  # no conv layer, no state beside the pages
+    for slab in pool.pages.pool_arrays():
         assert slab.devices() == {far}
         assert slab.sharding == NamedSharding(mesh, P())
 

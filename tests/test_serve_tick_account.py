@@ -17,7 +17,11 @@ import pytest
 
 sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
 from llm_np_cp_tpu.config import tiny_config
-from llm_np_cp_tpu.models.transformer import STEP_SCOPES, init_params
+from llm_np_cp_tpu.models.transformer import (
+    HYBRID_SCOPES,
+    STEP_SCOPES,
+    init_params,
+)
 from llm_np_cp_tpu.ops.sampling import Sampler
 from llm_np_cp_tpu.serve import ServeEngine, TraceRecorder, opmap
 from llm_np_cp_tpu.serve.tracing import MIXED_TICK_PHASES
@@ -244,7 +248,9 @@ def test_op_map_names_every_scope_and_marks_the_pool(tiny, cache_dtype):
     assert op_map and all(key.startswith("%") and " " in key for key in op_map)
     known = [v for v in op_map.values() if v is not None]
     assert all(len(v) == 2 for v in known)
-    assert {scope for scope, _ in known} == set(STEP_SCOPES) | {""}
+    # (a stack of one kind of layer enters none of the hybrid scopes)
+    assert {scope for scope, _ in known} == (
+        set(STEP_SCOPES) - set(HYBRID_SCOPES)) | {""}
     # the layer loop carries the pool and writes it in place: nothing the
     # step computes has the shape of one layer's slab
     assert {kind for _, kind in known} == {"pool", ""}

@@ -492,6 +492,14 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
             a.get("prefill_tokens", 0) + a.get("decode_tokens", 0)
             + a.get("spec_draft_tokens", 0) for a in dense
         ) / sum(a["dense_width"] for a in dense)
+    moe = [e["args"] for e in ticks if "experts_touched" in e["args"]]
+    if moe:
+        # dropless expert layers: what the step counted, back with the
+        # tick's one fetch (experts that got a token, summed over the
+        # expert layers; the worst layer's most loaded expert and mean)
+        for key in ("experts_touched", "expert_load_max",
+                    "expert_load_mean", "state_slots_live"):
+            out[key] = sum(a.get(key, 0) for a in moe) / len(moe)
     cpu = [e["args"]["thread_cpu_us"] for e in ticks
            if "thread_cpu_us" in e["args"]]
     if cpu:
@@ -682,7 +690,13 @@ def format_summary(events: list[dict], top: int = 5) -> str:
                         for name in MIXED_TICK_PHASES)
             + f"\ntick {acct['tick_us']:.0f}us; h2d "
             f"{acct['h2d_count']:.0f} transfers, "
-            f"{acct['h2d_bytes']:.0f} bytes; pack wrote "
+            f"{acct['h2d_bytes']:.0f} bytes"
+            + (f"; experts touched {acct['experts_touched']:.1f} a tick, "
+               f"load max {acct['expert_load_max']:.1f} / mean "
+               f"{acct['expert_load_mean']:.2f} tokens an expert, "
+               f"{acct['state_slots_live']:.1f} conv-state slots live"
+               if "experts_touched" in acct else "")
+            + "; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
             f"arrays; context "
             f"{acct['context_tokens']:.0f} tokens/dispatch; packed width "
