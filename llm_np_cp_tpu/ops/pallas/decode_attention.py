@@ -36,6 +36,27 @@ Design (round-5 rewrite; the r4 kernel ran at 58% of the XLA path):
 - int8 cache mode dequantizes the whole [block_s, K, D] block in VMEM
   with a single multiply (HBM streams 1-byte values + f32 scales).
 
+The serving kernels below take the cache as a POOL of pages and a block
+table a row.  ``_paged_kernel`` (the phase-split tick) walks the table
+one page a grid step.  ``_ragged_kernel`` (the unified tick, every
+benchmark cell) has a grid of query tiles x GROUPS of pages: a kv grid
+step attends ``P = ragged_pages_per_step(...)`` consecutive pages of its
+tile's row — as many as cover 512 kv positions, no more than the table
+is wide, inside ``_VMEM_BUDGET_BYTES``; read off the page's shape and
+dtype, 8 pages of 64 tokens in every cell.  The pool stays in HBM and
+the kernel copies pages itself, one DMA a page with the id from the
+scalar-prefetched table, into one half of a two-half VMEM buffer: a live
+step first starts the copies of the NEXT live step (this tile's next
+group, or the first group of the next tile that has one) into the other
+half, then waits for its own, so a group is in flight while one is
+attended; a slot past the row's last page starts no copy, a step past it
+does nothing, a dead tile has no live step.  A call's time then follows
+the pages it reads and its live steps, not the table's width (PERF.md
+§6, PR 33: 437 → 206 us a call at 64 decode rows of 410 tokens).  Pool
+arrays whose pages a DMA cannot cut out in whole tiles (scale pages, two
+int8 heads, ``head_dim`` 64) ride ``P`` blocked operands and the
+automatic pipeline instead (``_dma_slices_pages``).
+
 Benchmark-gated like every kernel here (SURVEY §7 step 7): wired as
 ``attn_impl="flash_decode"``, default stays XLA, and Generator probes
 Mosaic support once at construction, downgrading to XLA with a warning
@@ -484,45 +505,176 @@ def paged_decode_attention(
 # Query-tile width for the ragged kernel's packed token axis.  Every
 # row's token segment is padded up to a multiple of this so each q tile
 # belongs to exactly ONE row (the scalar-prefetched tile metadata then
-# names that row's block table).  8 = the f32 sublane tile; a decode row
-# costs one tile (7 masked query lanes) — acceptable, because the win of
-# the unified tick is ONE dispatch streaming the weights once for
-# prefill AND decode, not per-lane occupancy.
+# names that row's pages).  8 = the f32 sublane tile; a decode row costs
+# one tile (7 masked query lanes).  What a tile costs is its row's pages
+# in groups of ``ragged_pages_per_step``, not the pool's table width one
+# page at a time (PERF.md §6, PR 33); several decode rows to a tile is a
+# mechanism of its own (PERF.md §7).
 RAGGED_Q_TILE = 8
+
+# kv positions one grid step of the ragged kernel attends: the group of
+# pages it streams is as many as cover this much context (PERF.md §6,
+# PR 33, has the sweep on the chip that chose it)
+_RAGGED_STEP_POSITIONS = 512
 
 # meta rows for _ragged_kernel (computed in-graph per layer — the
 # sliding-window bound is a traced per-layer value)
-_RM_START, _RM_NB, _RM_PAD, _RM_QPOS0, _RM_QLEN, _RM_ROW, _RM_WIN = range(7)
+(_RM_START, _RM_COUNT, _RM_PAD, _RM_QPOS0, _RM_QLEN, _RM_WIN, _RM_ROW,
+ _RM_NEXT, _RM_FIRST) = range(9)
+
+
+def _sublane_tile(rows: int, dtype) -> int:
+    """Rows of the tile Mosaic lays an array's second-minor dimension
+    in: the smallest power-of-two multiple of the dtype's packing (1 for
+    f32, 2 for bf16, 4 for int8) that covers ``rows``, at most 8
+    sublanes of 32 bits."""
+    tile = packing = max(4 // jnp.dtype(dtype).itemsize, 1)
+    while tile < min(rows, 8 * packing):
+        tile *= 2
+    return tile
+
+
+def _vmem_bytes(shape: tuple[int, ...], dtype) -> int:
+    """Bytes an array takes in VMEM: its last two dims in whole tiles of
+    ``_sublane_tile`` rows by 128 lanes (``[64, 2, 128]`` bf16 takes
+    what it holds, ``[64, 8, 64]`` bf16 twice that, an ``[64, 2]`` f32
+    scale page 64 times)."""
+    *lead, rows, cols = shape
+    tile = _sublane_tile(rows, dtype)
+    n = (-(-rows // tile) * tile * -(-cols // 128) * 128
+         * jnp.dtype(dtype).itemsize)
+    for dim in lead:
+        n *= dim
+    return n
+
+
+def _dma_slices_pages(pool: jnp.ndarray) -> bool:
+    """Whether a DMA can cut one page out of ``pool`` ``[NB, ...]``:
+    Mosaic slices a tiled array in whole tiles only, so a page's last
+    two dims have to fill theirs (bf16 ``[.., 2, 128]`` does; an int8
+    ``[.., 2, 128]``, a ``[.., 8, 64]`` or a scale page does not)."""
+    rows, cols = pool.shape[-2:]
+    return cols % 128 == 0 and rows % _sublane_tile(rows, pool.dtype) == 0
+
+
+def ragged_pages_per_step(
+    mb: int, block_s: int, kv_heads: int, head_dim: int, kv_dtype,
+    quantized: bool,
+) -> int:
+    """``P``: the pages of a tile's row one grid step of the ragged
+    kernel streams and attends — the largest count that covers at most
+    ``_RAGGED_STEP_POSITIONS`` kv positions, is no wider than the block
+    table (``mb``), and keeps what a page slot takes of VMEM inside
+    ``_VMEM_BUDGET_BYTES``: K and V in both buffer halves and, for an
+    int8 pool, the scale pages likewise plus the group dequantized whole
+    (about twelve float32 copies of a page between K and V — the v5e
+    compiler wanted 16.8 MB of scoped VMEM at six ``[64, 4, 256]`` pages
+    and 24.2 MB at eight, of its 16).  From shapes alone: every caller
+    gets the ``P`` of its page shape."""
+    slot = 2 * 2 * _vmem_bytes((block_s, kv_heads, head_dim), kv_dtype)
+    if quantized:
+        slot += 2 * 2 * _vmem_bytes((block_s, kv_heads), jnp.float32)
+        slot += 12 * 4 * block_s * kv_heads * head_dim
+    return max(1, min(_RAGGED_STEP_POSITIONS // block_s, mb,
+                      _VMEM_BUDGET_BYTES // slot))
 
 
 def _ragged_kernel(
     meta_ref, tables_ref, *refs,
     scale: float, softcap: float | None, quantized: bool, kv_heads: int,
-    group: int, block_s: int, q_tile: int, head_dim: int,
+    group: int, block_s: int, q_tile: int, head_dim: int, pages: int,
+    mb: int, by_hand: tuple[bool, ...],
 ):
     """Mixed-batch block-table attention: each q tile holds up to
     ``q_tile`` consecutive tokens of ONE row (a prefill-chunk slice, or a
-    decode row's single token with the tail masked), and the kv grid
-    step fetches the pool block named by the row's scalar-prefetched
-    table — the generalization of ``_paged_kernel`` from one query row
-    to a query tile.  Visibility is derived in-kernel from the tile's
-    (pad, qpos0, qlen, window) scalars: token i at cache slot
-    ``qpos0 + i`` sees kv slots in
+    decode row's single token with the tail masked), and a kv grid step
+    attends a GROUP of ``pages`` consecutive pages of that row.  The pool
+    stays in HBM; a live step waits for its own group's copies (one DMA a
+    page, the page id read from the scalar-prefetched table, into the
+    buffer half that is its turn) only after it has started the copies
+    of the NEXT live step — the next group of this tile, or the first
+    group of the next tile that has any — into the other half, so a
+    group is in flight while one is attended.  A slot of the group past
+    the row's last page starts no copy, a step past it does nothing, and
+    a dead tile (``tile_qlen == 0``) has no live step at all.  A pool
+    array whose pages a DMA cannot slice (``by_hand`` False,
+    ``_dma_slices_pages``: scale pages, two int8 kv heads, a
+    ``head_dim`` of 64) comes as ``pages`` blocked operands instead,
+    fetched by the automatic pipeline.
+
+    The online-softmax update runs once a group, on a
+    ``[K * q_tile * G, pages * block_s]`` score sheet.  Visibility is
+    derived in-kernel from the tile's (pad, qpos0, qlen, window)
+    scalars: token i at cache slot ``qpos0 + i`` sees kv slots in
     ``[max(pad, slot - win + 1), slot]`` — causal within the tile's own
     freshly-written K/V too, because the caller scatters the whole
     packed batch into the pool before attending (same discipline as the
-    paged decode step)."""
-    if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    paged decode step).  A page slot no copy filled holds zeros or an
+    earlier page; its positions lie beyond every query slot and the
+    causal mask drops them."""
+    it = iter(refs)
+    q_ref = next(it)
+    # a pool array: the whole of it in HBM, or its ``pages`` page blocks
+    sources = [next(it) if hand else [next(it) for _ in range(pages)]
+               for hand in by_hand]
+    o_ref, m_ref, l_ref, acc_ref = (next(it) for _ in range(4))
+    # ...and, copied by hand, its two halves of a group in VMEM
+    bufs = [next(it) if hand else None for hand in by_hand]
+    copied = [(src, buf) for src, buf in zip(sources, bufs) if buf is not None]
+    if copied:
+        sem, half_ref = next(it), next(it)
     ti = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
-    start, nb = meta_ref[_RM_START, ti], meta_ref[_RM_NB, ti]
+    n_tiles = pl.num_programs(0)
+    start, count = meta_ref[_RM_START, ti], meta_ref[_RM_COUNT, ti]
     pad, qpos0 = meta_ref[_RM_PAD, ti], meta_ref[_RM_QPOS0, ti]
     qlen, win = meta_ref[_RM_QLEN, ti], meta_ref[_RM_WIN, ti]
+    width = pages * block_s
+
+    def group_copies(tile, step, half, wait: bool):
+        """Start (or wait for) one copy a LIVE page slot of group
+        ``step`` of ``tile``, into buffer half ``half``."""
+        live = jnp.minimum(meta_ref[_RM_COUNT, tile] - step * pages, pages)
+        first = (meta_ref[_RM_ROW, tile] * mb + meta_ref[_RM_START, tile]
+                 + step * pages)
+
+        def slot(p, carry):
+            page = tables_ref[first + p]
+            for pool_ref, buf in copied:
+                copy = pltpu.make_async_copy(
+                    pool_ref.at[page], buf.at[half, p], sem.at[half])
+                copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, live, slot, 0)
+
+    def fetch_group():
+        """→ the buffer half this step attends, its group waited for."""
+        @pl.when((j == 0) & (meta_ref[_RM_FIRST, ti] == ti))
+        def _first_live_step():
+            # nothing is in flight yet: the buffers start as zeros (a
+            # slot no copy fills must not hold NaN bits under the mask)
+            # and this step fetches its own group
+            for _, buf in copied:
+                buf[...] = jnp.zeros_like(buf)
+            half_ref[0] = 0
+            group_copies(ti, j, 0, wait=False)
+
+        half = half_ref[0]
+        # the next live step: this tile's next group, else the first
+        # group of the next tile that has one (``n_tiles``: none)
+        more = (j + 1) * pages < count
+        next_tile = jnp.where(more, ti, meta_ref[_RM_NEXT, ti])
+
+        @pl.when(next_tile < n_tiles)
+        def _prefetch():
+            group_copies(next_tile, jnp.where(more, j + 1, 0), 1 - half,
+                         wait=False)
+
+        group_copies(ti, j, half, wait=True)
+        half_ref[0] = 1 - half
+        return half
 
     @pl.when(j == 0)
     def _init():
@@ -530,12 +682,14 @@ def _ragged_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(start + j < nb)
+    @pl.when(j * pages < count)
     def _update():
+        half = fetch_group() if copied else None
+
         # rank-2 iota (Mosaic rejects rank-1 iota on TPU)
-        q_idx = jax.lax.broadcasted_iota(jnp.int32, (q_tile, block_s), 0)
-        kv_pos = (start + j) * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, (q_tile, block_s), 1
+        q_idx = jax.lax.broadcasted_iota(jnp.int32, (q_tile, width), 0)
+        kv_pos = (start + j * pages) * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (q_tile, width), 1
         )
         q_slot = qpos0 + q_idx
         mask = (
@@ -543,17 +697,24 @@ def _ragged_kernel(
             & (kv_pos >= pad)
             & (kv_pos > q_slot - win)  # sliding window (win huge = global)
             & (kv_pos <= q_slot)       # causal
-        )  # [q_tile, block_s]
-        kb = k_ref[0]  # [block_s, K, D]
-        vb = v_ref[0]
+        )  # [q_tile, width]
+
+        def group_of(src, buf):  # the step's pages end to end: [width, ..]
+            if buf is not None:
+                return buf[half].reshape((width,) + buf.shape[3:])
+            if pages == 1:
+                return src[0][0]
+            return jnp.concatenate([r[0] for r in src], axis=0)
+
+        kb, vb, *scales = (group_of(*sb) for sb in zip(sources, bufs))
         dtype = q_ref.dtype
         if quantized:
-            kb = kb.astype(dtype) * ks_ref[0][..., None].astype(dtype)
-            vb = vb.astype(dtype) * vs_ref[0][..., None].astype(dtype)
+            kb = kb.astype(dtype) * scales[0][..., None].astype(dtype)
+            vb = vb.astype(dtype) * scales[1][..., None].astype(dtype)
         # per-kv-head MXU dots over the whole tile, concatenated to ONE
-        # [K*q_tile*G, block_s] score sheet (rows ordered (ki, qi, gi))
+        # [K*q_tile*G, width] score sheet (rows ordered (ki, qi, gi))
         # so the mask/softcap/exp/rescale VPU pipeline runs once per
-        # block at full width — the _decode_kernel r5 lesson applied
+        # group at full width — the _decode_kernel r5 lesson applied
         s = jnp.concatenate(
             [
                 jax.lax.dot_general(
@@ -564,18 +725,18 @@ def _ragged_kernel(
                 for ki in range(kv_heads)
             ],
             axis=0,
-        ) * scale  # [K*q_tile*G, block_s]
+        ) * scale  # [K*q_tile*G, width]
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
         # mask rows order (qi, gi), identical for every kv head
         mask_qg = jnp.broadcast_to(
-            mask[:, None, :], (q_tile, group, block_s)
-        ).reshape(q_tile * group, block_s)
+            mask[:, None, :], (q_tile, group, width)
+        ).reshape(q_tile * group, width)
         mask_full = jnp.concatenate([mask_qg] * kv_heads, axis=0)
         s = jnp.where(mask_full, s, NEG_INF)
 
         # AMLA additive-max update (see _amla_rescale): ln2-grid max,
-        # block rescale = exponent-field integer add, not a multiply
+        # group rescale = exponent-field integer add, not a multiply
         m_prev = m_ref[:]
         m_new = _amla_max(m_prev, s)
         p = jnp.exp(s - m_new)
@@ -657,12 +818,17 @@ def ragged_paged_attention(
     Token i of a tile sees kv slots ``[max(pad, slot_i - window + 1),
     slot_i]`` where ``slot_i = tile_qpos0 + i`` — exactly the visibility
     the phase-split engine's chunked prefill mask + paged decode step
-    encode, so outputs are parity-testable against both.  Blocks outside
-    the tile's visible range are never DMA'd (clamped index map, same
-    skip as ``paged_decode_attention``).
+    encode, so outputs are parity-testable against both.
+
+    The grid is ``(T / RAGGED_Q_TILE, ceil(MB / P))``: a kv step streams
+    and attends ``P = ragged_pages_per_step(...)`` pages of the tile's
+    row (a function of the shapes alone), copied from the pool in HBM
+    into one half of a VMEM buffer while the other half is attended.
+    Pages outside the tile's visible range are never copied, a dead tile
+    streams nothing, and steps past the row's last page do nothing.
 
     int8 pool mode: k_scale/v_scale [NB, BS, K] f32 scale pages ride
-    along and the kernel dequantizes per block in VMEM.
+    along and the kernel dequantizes per group in VMEM.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -695,75 +861,88 @@ def ragged_paged_attention(
     nb_pool, block_s, kh, _ = k_pages.shape
     g = h // kh
     mb = tables.shape[1]
+    pages = ragged_pages_per_step(mb, block_s, kh, d, k_pages.dtype, quantized)
+    steps = -(-mb // pages)
 
     qf = q.reshape(t, kh, g, d)
-    # per-tile kv block bounds: the window lower bound is tightest at the
+    # per-tile kv page bounds: the window lower bound is tightest at the
     # tile's FIRST token; the causal upper bound is set by its LAST live
     # token.  The in-kernel mask handles per-token exactness — these only
-    # decide which blocks are streamed at all.
+    # decide which pages are streamed at all (none for a dead tile).
     row_pad = pads[tile_row]
     lo = jnp.maximum(row_pad, tile_qpos0 - window + 1)
     hi = tile_qpos0 + jnp.maximum(tile_qlen, 1) - 1
     start = jnp.clip(lo // block_s, 0, jnp.maximum(mb - 1, 0))
     nb = jnp.clip(hi // block_s + 1, 1, mb)
+    count = jnp.where(tile_qlen > 0, jnp.maximum(nb - start, 0), 0)
+    # the next tile with a page to stream, and the first of all: what a
+    # tile's last live step prefetches, and which step has to fetch for
+    # itself (``nt`` = none)
+    tiles = jnp.arange(nt, dtype=jnp.int32)
+    later = jax.lax.cummin(jnp.where(count > 0, tiles, nt), reverse=True)
     meta = jnp.stack([
-        start, nb, row_pad, tile_qpos0, tile_qlen, tile_row,
-        jnp.broadcast_to(window, tile_row.shape),
-    ]).astype(jnp.int32)  # [7, NT]
+        start, count, row_pad, tile_qpos0, tile_qlen,
+        jnp.broadcast_to(window, tile_row.shape), tile_row,
+        jnp.append(later[1:], nt), jnp.broadcast_to(later[0], tile_row.shape),
+    ]).astype(jnp.int32)  # [9, NT]
 
-    def _kv_map(ti, j, meta_ref, tables_ref):
-        row = meta_ref[_RM_ROW, ti]
-        jj = jnp.minimum(
-            meta_ref[_RM_START, ti] + j, meta_ref[_RM_NB, ti] - 1
-        )
-        return (tables_ref[row, jj], 0, 0, 0)
+    def tile_map(ti, j, meta_ref, tables_ref):
+        return (ti, 0, 0, 0)
 
-    def _scale_map(ti, j, meta_ref, tables_ref):
-        row = meta_ref[_RM_ROW, ti]
-        jj = jnp.minimum(
-            meta_ref[_RM_START, ti] + j, meta_ref[_RM_NB, ti] - 1
-        )
-        return (tables_ref[row, jj], 0, 0)
+    def page_spec(p, block):
+        """Page slot ``p`` of the step's group as a blocked operand; past
+        the tile's last page it names that page, and keeps it."""
+        zeros = (0,) * (len(block) - 1)
 
-    kv_spec = pl.BlockSpec((1, block_s, kh, d), _kv_map,
-                           memory_space=pltpu.VMEM)
-    q_spec = pl.BlockSpec(
-        (qt, kh, g, d),
-        lambda ti, j, meta_ref, tables_ref: (ti, 0, 0, 0),
-        memory_space=pltpu.VMEM,
-    )
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qf, k_pages, v_pages]
-    if quantized:
-        scale_spec = pl.BlockSpec((1, block_s, kh), _scale_map,
-                                  memory_space=pltpu.VMEM)
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+        def index_map(ti, j, meta_ref, tables_ref):
+            last = jnp.maximum(meta_ref[_RM_COUNT, ti], 1) - 1
+            block_i = meta_ref[_RM_START, ti] + jnp.minimum(
+                j * pages + p, last)
+            return (tables_ref[meta_ref[_RM_ROW, ti] * mb + block_i], *zeros)
+
+        return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+
+    pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
+    by_hand = tuple(_dma_slices_pages(a) for a in pools)
+    tile_spec = pl.BlockSpec(
+        (qt, kh, g, d), tile_map, memory_space=pltpu.VMEM)
+    in_specs, operands = [tile_spec], [qf]
+    for a, hand in zip(pools, by_hand):
+        if hand:
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+            operands.append(a)
+        else:
+            in_specs += [page_spec(p, (1,) + a.shape[1:])
+                         for p in range(pages)]
+            operands += [a] * pages
+    rows = kh * qt * g
+    scratch = [
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, d), jnp.float32),
+        # two halves of a group of pages for each array copied by hand
+        *[pltpu.VMEM((2, pages) + a.shape[1:], a.dtype)
+          for a, hand in zip(pools, by_hand) if hand],
+    ]
+    if any(by_hand):
+        scratch += [pltpu.SemaphoreType.DMA((2,)),  # one a half
+                    pltpu.SMEM((1,), jnp.int32)]  # whose turn it is
     out = pl.pallas_call(
         functools.partial(
             _ragged_kernel, scale=scale, softcap=logit_softcap,
             quantized=quantized, kv_heads=kh, group=g, block_s=block_s,
-            q_tile=qt, head_dim=d,
+            q_tile=qt, head_dim=d, pages=pages, mb=mb, by_hand=by_hand,
         ),
-        out_shape=jax.ShapeDtypeStruct((t, kh, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(nt, mb),
+            grid=(nt, steps),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (qt, kh, g, d),
-                lambda ti, j, meta_ref, tables_ref: (ti, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((kh * qt * g, 1), jnp.float32),
-                pltpu.VMEM((kh * qt * g, 1), jnp.float32),
-                pltpu.VMEM((kh * qt * g, d), jnp.float32),
-            ],
+            out_specs=tile_spec,
+            scratch_shapes=scratch,
         ),
         interpret=interpret,
-    )(meta, tables, *operands)
-
+    )(meta, tables.reshape(-1).astype(jnp.int32), *operands)
     return out.reshape(t, h, d)
 
 

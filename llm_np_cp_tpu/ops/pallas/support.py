@@ -262,20 +262,24 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         )
 
         # a representative mixed tick: row 0 prefills a 2-tile chunk
-        # (ragged tail), row 1 decodes one token deep into its 4th
-        # block behind a whole-block pad, row 2 prefills its 2nd chunk
-        # across a block boundary, then a dead padding tile
+        # (ragged tail), row 1 decodes one token deep into its 10th
+        # block behind a whole-block pad — past the first GROUP of pages
+        # a kv grid step streams (8 of block size 64, 4 of 128), so the
+        # next group's copies are in flight under it —, row 2 prefills
+        # its 2nd chunk across a block boundary, then a dead padding tile
         qt = RAGGED_Q_TILE
         n_tiles = 6
+        mb_r, nbp_r = 12, 40
         window = jnp.int32(shape.window or (1 << 30))
 
         def make_args():
-            q, pages = normals((n_tiles * qt, h, d), (nbp, bs, kh, d))
+            q, pages = normals((n_tiles * qt, h, d), (nbp_r, bs, kh, d))
+            # (a host constant: no device op, no compile of its own)
             tables = jnp.asarray(
-                [[2, 1, 4, 9], [3, 5, 0, 11], [6, 7, 8, 10]], jnp.int32)
+                (np.arange(3 * mb_r) * 7 % 37 + 1).reshape(3, mb_r), jnp.int32)
             tile_row = jnp.asarray([0, 0, 1, 2, 2, 0], jnp.int32)
             tile_qpos0 = jnp.asarray(
-                [5, 5 + qt, 3 * bs + 7, bs - 4, bs - 4 + qt, 0], jnp.int32)
+                [5, 5 + qt, 9 * bs + 7, bs - 4, bs - 4 + qt, 0], jnp.int32)
             tile_qlen = jnp.asarray([qt, qt - 3, 1, qt, qt, 0], jnp.int32)
             pads = jnp.asarray([5, bs + 2, 0], jnp.int32)
             return (q, tables, tile_row, tile_qpos0, tile_qlen, pads,

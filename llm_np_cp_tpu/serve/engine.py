@@ -3531,6 +3531,7 @@ class ServeEngine:
         tp = th = t4 = t5 = t3
         cpu4 = cpu5 = 0
         ctx_tokens = array_rows = h2d_count = h2d_bytes = 0
+        attn_pages = attn_grid_steps = attn_step_pages = 0
         packed_width = dense_width = 0
         n_prefill_tok = sum(n for _, n in prefill_segs)
         n_decode_tok = len(decode_rows)
@@ -3557,6 +3558,9 @@ class ServeEngine:
                 ctx_tokens = sum(
                     r.cache_len - r.pad + r.draft_len for r in decode_rows
                 ) + sum(r.prefill_done + n for r, n in prefill_segs)
+                attn_pages, attn_grid_steps, attn_step_pages = (
+                    self._attn_page_account(
+                        host_ops, packed_width, dense_width))
                 h2d_count = 1
                 h2d_bytes = host_ops.nbytes
             tp = (self._phase_mark("serve.h2d")
@@ -3729,6 +3733,14 @@ class ServeEngine:
                 "context_tokens": ctx_tokens,
                 "packed_width": packed_width,
                 "dense_width": dense_width,
+                # what ONE layer's attention call is asked to stream:
+                # the pages in every tile's visible range, summed over
+                # tiles (a global layer's range), and the kv grid steps
+                # the program takes for them — tiles x groups of
+                # ``attn_pages_per_step`` pages
+                "attn_pages": attn_pages,
+                "attn_grid_steps": attn_grid_steps,
+                "attn_pages_per_step": attn_step_pages,
                 # rows _pack_mixed wrote by whole-array assignments
                 # (plain decode rows): how much of pack went the fast way
                 "pack_array_rows": array_rows,
@@ -3879,6 +3891,39 @@ class ServeEngine:
         the historical draft-free accounting exactly."""
         return int(mixed_tick_kv_read(self, decode_rows, prefill_segs,
                                       per_request=False)[0])
+
+    def _attn_page_account(
+        self, host_ops: np.ndarray, t_w: int, d_w: int,
+    ) -> tuple[int, int, int]:
+        """(pages, kv grid steps, P) of one layer's ragged attention call
+        on this packed batch — the tracer's tick args ``attn_pages`` /
+        ``attn_grid_steps`` / ``attn_pages_per_step``.  Pages: over the
+        live tiles, the blocks from the row's left pad to the tile's
+        last token (what a global layer streams; a sliding layer starts
+        later).  Steps: the program's tiles x ``ceil(max_blocks / P)``."""
+        from llm_np_cp_tpu.ops.pallas.decode_attention import (
+            ragged_pages_per_step,
+        )
+        from llm_np_cp_tpu.parallel.sharding import MODEL_AXIS
+
+        layout = self._mixed_layouts[t_w, d_w][0]
+
+        def section(name):
+            off, shape = layout[name]
+            return host_ops[off:off + shape[0]]
+
+        qlen = section("tile_qlen")
+        live = qlen > 0
+        last = (section("tile_qpos0") + qlen - 1)[live] // self.block_size
+        first = section("pads")[section("tile_row")[live]] // self.block_size
+        k = self.pool.pages.k
+        # (the kv heads ONE chip holds: the kernel runs inside shard_map)
+        shards = self.mesh.shape[MODEL_AXIS] if self._kv_sharded else 1
+        per_step = ragged_pages_per_step(
+            self.max_blocks_per_seq, self.block_size, k.shape[-2] // shards,
+            k.shape[-1], k.dtype, self.cache_dtype == jnp.int8)
+        steps = (t_w // self._q_tile) * -(-self.max_blocks_per_seq // per_step)
+        return int((last - first + 1).sum()), steps, per_step
 
     def _dead_mixed_operands(self, t_w: int, d_w: int) -> np.ndarray:
         """The mixed step's operand for an all-dead batch of the program

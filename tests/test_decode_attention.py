@@ -407,13 +407,19 @@ def _pack_segments(segs, dead_tiles=0):
     """``[(row, qpos0, n_tokens)]`` → the kernel's per-TILE metadata, the
     XLA twin's per-TOKEN metadata and the packed width, laid out the way
     the engine's packer does: each segment on its own q tiles, the tail
-    of its last tile dead, ``dead_tiles`` whole dead tiles at the end
-    (the bucket's padding)."""
+    of its last tile dead, a zero-token segment one dead tile where it
+    stands, ``dead_tiles`` whole dead tiles at the end (the bucket's
+    padding)."""
     from llm_np_cp_tpu.ops.pallas.decode_attention import RAGGED_Q_TILE as qt
 
     tile_row, tile_qpos0, tile_qlen = [], [], []
     tok_row, tok_slot, tok_live = [], [], []
     for row, qpos0, n in segs:
+        if n == 0:  # a dead tile BETWEEN live ones (a row that left)
+            tile_row.append(row), tile_qpos0.append(0), tile_qlen.append(0)
+            tok_row += [row] * qt
+            tok_slot += [0] * qt
+            tok_live += [False] * qt
         for i in range(0, n, qt):
             live = min(qt, n - i)
             tile_row.append(row)
@@ -626,3 +632,127 @@ def test_ragged_row_mixes(mix, h, kh):
                              dead_tiles=dead_tiles)
     # the window bit: contexts here are longer than 12 slots
     assert not np.allclose(full, windowed, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# A kv grid step is a GROUP of P pages (PERF.md §6, PR 33).  The cases
+# above fit one group; these walk several, at block size 64 where P = 8:
+# rows that end one page short of a group, on its edge and one past it, a
+# table width P does not divide, dead tiles between live ones, a window
+# that starts mid-group, one row under 16 tiles, verify slices, the int8
+# pool, and the page shapes of the benchmark's other configurations.
+# ---------------------------------------------------------------------------
+
+_BS = 64
+
+# name: (h, kh, d, mb, segs, pads per row, window, int8 pool, pages copied
+# by hand).  Whole rows of 128 lanes are what a DMA can slice: head_dim
+# 128 runs the Qwen cells' path, the narrow heads above the blocked one.
+_GROUP_CASES = {
+    "rows-of-P-1-P-P+1-pages": (
+        8, 2, 128, 16, _decode_segs([7 * _BS, 8 * _BS, 8 * _BS + 1, 449]),
+        [0, 70, 3, 0], _NO_WINDOW, False, True),
+    "mb-42-not-a-multiple-of-P": (
+        8, 2, 128, 42, _decode_segs([42 * _BS, 41 * _BS - 6, 1100, 30]),
+        [5, 0, 200, 0], _NO_WINDOW, False, True),
+    "dead-tiles-between-live-ones": (
+        4, 1, 128, 16,
+        [(0, 500, 1), (3, 0, 0), (1, 70, 1), (0, 0, 0), (2, 0, 0),
+         (2, 900, 3)],
+        [0, 9, 130, 0], _NO_WINDOW, False, True),
+    "window-starts-mid-group": (
+        8, 2, 128, 16, [(0, 999, 1), (1, 640, 1), (2, 350, 5), (3, 700, 12)],
+        [0, 500, 0, 64], 300, False, True),
+    "prefill-chunk-of-16-tiles-on-one-row": (
+        4, 2, 128, 16, [(0, 410, 1), (1, 600, 128)], [0, 17], _NO_WINDOW,
+        False, True),
+    "verify-tiles-of-2-to-5": (
+        8, 2, 128, 16, [(0, 520, 2), (1, 300, 3), (2, 62, 4), (3, 510, 5)],
+        [0, 0, 7, 129], _NO_WINDOW, False, True),
+    # two int8 kv heads fill half a tile: K/V and scales all blocked
+    "int8-pool-kh2-blocked": (
+        8, 2, 128, 16, [(0, 515, 1), (1, 800, 11), (2, 40, 1)], [3, 64, 0],
+        _NO_WINDOW, True, False),
+    # four do: K/V copied by hand, the scale pages blocked beside them
+    "int8-pool-kh4-copied": (
+        8, 4, 128, 16, [(0, 515, 1), (1, 800, 11), (2, 40, 1)], [3, 64, 0],
+        _NO_WINDOW, True, True),
+    # head_dim 64 is half a row of lanes: blocked
+    "lfm2-page-kh8-d64-blocked": (
+        32, 8, 64, 16, [(0, 600, 1), (1, 447, 1), (2, 512, 3)], [0, 0, 66],
+        _NO_WINDOW, False, False),
+    "qwen3b-page-g8-d128": (
+        16, 2, 128, 16, [(0, 1023, 1), (1, 100, 9), (2, 512, 1)], [0, 3, 0],
+        _NO_WINDOW, False, True),
+}
+
+
+def _group_pool(rng, kh, d, mb, rows):
+    """A pool and a table of distinct pages a row, block 0 left as the
+    scratch block no table names."""
+    nbp = rows * mb + 1
+    tables = (rng.permutation(nbp - 1) + 1).reshape(rows, mb)
+    return (_rand(rng, (nbp, _BS, kh, d)), _rand(rng, (nbp, _BS, kh, d)),
+            jnp.asarray(tables, jnp.int32))
+
+
+@pytest.mark.parametrize("case", list(_GROUP_CASES))
+def test_ragged_groups_of_pages(case):
+    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        ragged_pages_per_step,
+    )
+
+    h, kh, d, mb, segs, pads, window, int8, _ = _GROUP_CASES[case]
+    rng = np.random.default_rng(len(case) * 13 + h)
+    pages_k, pages_v, tables = _group_pool(rng, kh, d, mb, len(pads))
+    kw = {}
+    if int8:
+        (pages_k, ks), (pages_v, vs) = quantize_kv(pages_k), quantize_kv(pages_v)
+        kw = dict(scales=(ks, vs),
+                  float_pages=(dequantize_kv(pages_k, ks, jnp.float32),
+                               dequantize_kv(pages_v, vs, jnp.float32)))
+    # the case means what its name says only while a step is 8 pages
+    # (4 where four int8 heads are dequantized in VMEM)
+    p = ragged_pages_per_step(mb, _BS, kh, d, pages_k.dtype, int8)
+    assert p == (4 if int8 and kh == 4 else 8) and -(-mb // p) > 1
+    dead = 1
+    _check_ragged(_packed_q(rng, segs, h, d, dead), pages_k, pages_v, tables,
+                  segs, jnp.asarray(pads, jnp.int32), scale=d**-0.5,
+                  window=window, dead_tiles=dead, **kw)
+
+
+def test_ragged_wholly_dead_batch_is_zeros():
+    """A program dispatched with no live tile (every row left between
+    plan and pack) streams nothing and returns zeros, not NaN and not a
+    page's contents."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        RAGGED_Q_TILE,
+        ragged_paged_attention,
+    )
+
+    rng = np.random.default_rng(5)
+    pages_k, pages_v, tables = _group_pool(rng, 2, 16, 16, 2)
+    nt = 4
+    zeros = jnp.zeros((nt,), jnp.int32)
+    out = ragged_paged_attention(
+        _rand(rng, (nt * RAGGED_Q_TILE, 8, 16)), pages_k * jnp.nan, pages_v,
+        tables, zeros, zeros + 700, zeros, jnp.zeros((2,), jnp.int32),
+        jnp.asarray(_NO_WINDOW, jnp.int32), scale=0.25)
+    assert np.all(np.asarray(out) == 0.0)
+
+
+@pytest.mark.parametrize("case", list(_GROUP_CASES))
+def test_ragged_group_cases_take_the_path_they_name(case):
+    """Which pool arrays the kernel copies page by page itself and which
+    ride the automatic pipeline is read off their shapes
+    (``_dma_slices_pages``): the cases above cover both."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import _dma_slices_pages
+
+    _, kh, d, _, _, _, _, int8, by_hand = _GROUP_CASES[case]
+    page = jax.ShapeDtypeStruct(
+        (9, _BS, kh, d), jnp.int8 if int8 else jnp.float32)
+    assert _dma_slices_pages(page) is by_hand
+    if int8:  # a scale a head: never whole lanes
+        assert not _dma_slices_pages(
+            jax.ShapeDtypeStruct((9, _BS, kh), jnp.float32))

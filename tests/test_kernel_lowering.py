@@ -343,12 +343,8 @@ def test_decode_program_is_dense_outside_attention(v5e_sharding):
     ``attn``, between the two row gathers (at 512 the matmuls were
     compute-bound on lanes that hold nothing: PERF.md §6, PR 30 / 31).
     The pool is still the donated buffer written in place.  And the
-    ragged kernel is the parent's call at the same 64 tiles, to the
-    byte: what this program changed is around it."""
-    from llm_np_cp_tpu.ops.pallas.decode_attention import (
-        ragged_paged_attention,
-    )
-
+    ragged kernel is the function's own call at the same 64 tiles, to
+    the byte: what this program changed is around it."""
     t_w, d_w, slots = 512, 64, 64
     engine, compiled = _compile_widest_bucket(
         v5e_sharding, jnp.bfloat16, blocks=600, slots=slots,
@@ -409,29 +405,154 @@ def test_decode_program_is_dense_outside_attention(v5e_sharding):
         a.nbytes for a in pages if a is not None)
     assert {v[2] for v in ops.values() if v[0] != SCOPE_KV_WRITE} == {""}
 
-    # the parent's call: q tile-aligned all the way, ``[512, H, D]``
-    # straight into the kernel, on the same pool and tables
-    def aval(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+    # the function alone: q tile-aligned all the way, ``[512, H, D]``
+    # straight into the kernel, on the same (flat) pool and tables
+    assert cfg.attn_scale == hd ** -0.5 and not cfg.attn_logit_softcapping
+    alone = _lower_ragged(
+        v5e_sharding, nt=t_w // engine._q_tile, mb=engine.max_blocks_per_seq,
+        h=cfg.num_attention_heads, kh=kh, d=hd, dtype=pages.k.dtype,
+        blocks=pages.k.shape[0] * pages.k.shape[1], rows=slots)
+    assert _ragged_kernel_call(text) == alone
 
-    flat = (pages.k.shape[0] * pages.k.shape[1],) + pages.k.shape[2:]
-    i32, nt = jnp.int32, t_w // engine._q_tile
+
+# ----------------------------------------------------------------------
+# a kv grid step of the ragged kernel is a group of P pages (PR 33)
+# ----------------------------------------------------------------------
+
+def _lower_ragged(sharding, *, nt, mb, h, kh, d, dtype=jnp.bfloat16,
+                  block_s=64, blocks=1026, rows=64):
+    """``ragged_paged_attention`` alone, compiled for the described v5e at
+    a cell's geometry → ``_ragged_kernel_call`` of the compiled text."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        ragged_paged_attention,
+    )
+
+    def aval(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    i32 = jnp.int32
+    page = aval((blocks, block_s, kh, d), dtype)
+    args = [aval((nt * 8, h, d), jnp.bfloat16), page, page,
+            aval((rows, mb), i32), aval((nt,), i32), aval((nt,), i32),
+            aval((nt,), i32), aval((rows,), i32), aval((), i32)]
+    kw = {}
+    if dtype == jnp.int8:
+        kw = dict(k_scale=aval((blocks, block_s, kh), jnp.float32),
+                  v_scale=aval((blocks, block_s, kh), jnp.float32))
     real = jax.default_backend
     jax.default_backend = lambda: "tpu"
     try:
-        parent = jax.jit(functools.partial(
-            ragged_paged_attention, scale=cfg.attn_scale,
-            logit_softcap=cfg.attn_logit_softcapping,
-        )).lower(
-            aval((t_w, cfg.num_attention_heads, hd), pages.k.dtype),
-            aval(flat, pages.k.dtype), aval(flat, pages.k.dtype),
-            aval((slots, engine.max_blocks_per_seq), i32),
-            aval((nt,), i32), aval((nt,), i32), aval((nt,), i32),
-            aval((slots,), i32), aval((), i32),
-        ).compile()
+        compiled = jax.jit(functools.partial(
+            ragged_paged_attention, scale=d ** -0.5)).lower(
+                *args, **kw).compile()
     finally:
         jax.default_backend = real
-    assert _ragged_kernel_call(text) == _ragged_kernel_call(parent.as_text())
+    return _ragged_kernel_call(compiled.as_text())
+
+
+def _kernel_grid(module_text):
+    bounds = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>",
+                       module_text).group(1)
+    return tuple(int(x) for x in bounds.split(","))
+
+
+def _kernel_vmem_scratch(module_text):
+    """Every VMEM scratch array of the kernel's signature holding pages
+    (rank 5: half, slot, block, heads, dim) as (shape, dtype name)."""
+    sig = next(ln for ln in module_text.splitlines() if "^bb0(" in ln)
+    return [(tuple(int(x) for x in dims.rstrip("x").split("x")), dt)
+            for dims, dt in re.findall(
+                r"memref<((?:\d+x){5})(\w+), #tpu.memory_space<vmem>>", sig)]
+
+
+@pytest.mark.parametrize("nt,mb,grid", [
+    # the closed cells: 64 decode tiles under a 16-block table — 1,024
+    # one-page steps before PR 33
+    (64, 16, (64, 2)),
+    (96, 16, (96, 2)),
+    # chat-open's table is 42 wide: 6 steps a tile where it walked 42
+    (8, 42, (8, 6)),
+    (64, 42, (64, 6)),
+], ids=["closed-512", "closed-768", "chat-open-64", "chat-open-512"])
+def test_ragged_kernel_grid_is_tiles_by_groups_of_pages(
+        v5e_sharding, nt, mb, grid):
+    """What PR 33 is for: the lowered call's grid is ``(tiles, ceil(mb /
+    P))`` with P = 8 pages of 64 positions, the pool stays in HBM (the
+    kernel's own copies fetch it: two halves of P pages for K and for
+    V), and those buffers are what the P rule budgeted."""
+    from llm_np_cp_tpu.ops.pallas import decode_attention as da
+
+    h, kh, d = 12, 2, 128  # Qwen2.5-1.5B
+    result, operands, module = _lower_ragged(
+        v5e_sharding, nt=nt, mb=mb, h=h, kh=kh, d=d,
+        blocks=28 * 64, rows=64)
+    assert _kernel_grid(module) == grid
+    p = da.ragged_pages_per_step(mb, 64, kh, d, jnp.bfloat16, False)
+    assert p == 8 and grid[1] == -(-mb // p)
+    scratch = _kernel_vmem_scratch(module)
+    assert scratch == [((2, p, 64, kh, d), "bf16")] * 2, scratch
+    held = sum(da._vmem_bytes(shape, jnp.bfloat16) for shape, _ in scratch)
+    assert held == 2 * 2 * p * 32768 <= da._VMEM_BUDGET_BYTES
+    # the pool operands are taken as they lie (no window of them is
+    # pipelined): memory space "any", whole
+    assert module.count("#tpu.memory_space<any>") >= 2
+    assert f"{28 * 64}x64x{kh}x{d}xbf16" in module
+
+
+@pytest.mark.parametrize("kh,d,dtype,mb,block_s,want", [
+    # hand arithmetic (bytes in VMEM: the last two dims in whole tiles).
+    # Qwen2.5-1.5B / 3B / the 7B's shard: a [64, 2, 128] bf16 page is
+    # 32,768 B, K + V in two halves 131,072 B a slot: 64 would fit 8 MiB;
+    # 512 positions / 64 = 8 decides
+    (2, 128, jnp.bfloat16, 16, 64, 8),
+    (2, 128, jnp.bfloat16, 42, 64, 8),
+    # LFM2: [64, 8, 64] bf16 pads 64 lanes to 128: 131,072 B a page,
+    # 524,288 a slot, 16 fit; 8
+    (8, 64, jnp.bfloat16, 16, 64, 8),
+    # Gemma-2 2B: [64, 4, 256] bf16 = 131,072 B: the same
+    (4, 256, jnp.bfloat16, 16, 64, 8),
+    # int8 Qwen page: [64, 2, 128] int8 pads 2 rows to 4: 32,768 B x 4,
+    # scale pages [64, 2] f32 32,768 B x 4, dequantized 12 x 65,536:
+    # 1,048,576 B a slot, 8 fit
+    (2, 128, jnp.int8, 16, 64, 8),
+    # int8 Gemma page: 65,536 x 4 + 32,768 x 4 + 12 x 262,144 =
+    # 3,538,944 B a slot: 2 fit
+    (4, 256, jnp.int8, 16, 64, 2),
+    # block size 128: 512 / 128 = 4; a table narrower than that: mb
+    (2, 128, jnp.bfloat16, 16, 128, 4),
+    (2, 128, jnp.bfloat16, 3, 64, 3),
+    # a page so large that two halves of one slot fill the budget:
+    # [64, 32, 256] f32 = 2 MiB, K + V x 2 halves = 8 MiB: 1
+    (32, 256, jnp.float32, 16, 64, 1),
+], ids=["qwen-closed", "qwen-chat-open", "lfm2", "gemma2", "qwen-int8",
+        "gemma2-int8", "block-128", "narrow-table", "huge-page"])
+def test_pages_per_step_by_hand(kh, d, dtype, mb, block_s, want):
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        ragged_pages_per_step,
+    )
+
+    assert ragged_pages_per_step(
+        mb, block_s, kh, d, dtype, dtype == jnp.int8) == want
+
+
+@pytest.mark.parametrize("h,kh,d,dtype", [
+    (16, 2, 128, jnp.bfloat16),   # Qwen2.5-3B: groups of 8
+    (32, 8, 64, jnp.bfloat16),    # LFM2 / Llama-3.2-1B: pages blocked
+    (8, 4, 256, jnp.bfloat16),    # Gemma-2 2B
+    (12, 2, 128, jnp.int8),       # int8 pool: every array blocked
+    (8, 4, 256, jnp.int8),        # int8 K / V copied, scales blocked
+    (4, 1, 128, jnp.bfloat16),    # one bf16 kv head: half a tile, blocked
+], ids=["qwen3b", "lfm2", "gemma2", "qwen-int8", "gemma2-int8", "kh1"])
+def test_ragged_kernel_compiles_at_every_page_shape(
+        v5e_sharding, h, kh, d, dtype):
+    """The other page shapes the kernel serves, at the closed cells'
+    geometry: each compiles for the v5e inside its scoped VMEM."""
+    from llm_np_cp_tpu.ops.pallas import decode_attention as da
+
+    _, _, module = _lower_ragged(
+        v5e_sharding, nt=64, mb=16, h=h, kh=kh, d=d, dtype=dtype)
+    p = da.ragged_pages_per_step(16, 64, kh, d, dtype, dtype == jnp.int8)
+    assert _kernel_grid(module) == (64, -(-16 // p))
 
 
 def test_int8_pool_on_a_v5e_is_why_the_slab_form_stays(

@@ -79,6 +79,7 @@ def traced(request, tiny):
     engine = _engine(cfg, params, tracer=tracer)
     hand = []
     pack = engine._pack_mixed
+    bs = engine.block_size
 
     def counting_pack(decode_rows, prefill_segs):
         sizes = [1 + r.draft_len for r in decode_rows] + \
@@ -96,6 +97,16 @@ def traced(request, tiny):
             context=sum(r.prompt.size + len(r.generated) for r in decode_rows)
             + sum(r.prefill_done + n for r, n in prefill_segs),
             width=width, dense=dense,
+            # one layer's attention call: per query tile, the blocks from
+            # the row's left pad to the tile's last token
+            pages=sum(
+                (r.pad + r.prompt.size + len(r.generated) - 1) // bs
+                - r.pad // bs + 1 for r in decode_rows)
+            + sum(
+                (r.pad + r.prefill_done + min(i + engine._q_tile, n) - 1)
+                // bs - r.pad // bs + 1
+                for r, n in prefill_segs
+                for i in range(0, n, engine._q_tile)),
         ))
         packed = pack(decode_rows, prefill_segs)
         hand[-1]["bytes"] = packed[0].nbytes
@@ -148,6 +159,11 @@ def test_tick_args_equal_a_hand_count_of_the_planned_rows(traced):
         assert args["packed_width"] == want["width"]
         assert args["dense_width"] == want["dense"]
         assert args["thread_cpu_us"] >= 0.0
+        # the attention call: pages asked for, and the kv grid it takes
+        # (tiles x groups of P pages; this table is one group wide)
+        assert args["attn_pages"] == want["pages"]
+        assert args["attn_pages_per_step"] == engine.max_blocks_per_seq == 8
+        assert args["attn_grid_steps"] == want["width"] // engine._q_tile
         h2d = next(p for p in phases if p["name"] == "h2d")
         assert args["pack_array_rows"] == want["array_rows"]
         # ONE transfer a tick: the packed operand
@@ -618,7 +634,14 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
     assert 0.0 < acct["dense_occupancy"] <= 1.0
     assert acct["pack_array_rows"] == pytest.approx(
         sum(h["array_rows"] for h in hand) / len(hand))
+    assert acct["attn_pages"] == pytest.approx(
+        sum(h["pages"] for h in hand) / len(hand))
+    assert acct["attn_slot_share"] == pytest.approx(
+        sum(h["pages"] for h in hand)
+        / sum(h["width"] // engine._q_tile * 8 for h in hand))
     out = format_summary(events)
+    assert (f"attention streams {acct['attn_pages']:.0f} pages a layer in "
+            f"{acct['attn_grid_steps']:.0f} kv grid steps") in out
     assert "== tick account" in out and "pack " in out
     assert (f"h2d 1 transfers, {acct['h2d_bytes']:.0f} bytes; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "

@@ -492,6 +492,16 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
             a.get("prefill_tokens", 0) + a.get("decode_tokens", 0)
             + a.get("spec_draft_tokens", 0) for a in dense
         ) / sum(a["dense_width"] for a in dense)
+    paged = [e["args"] for e in ticks if e["args"].get("attn_grid_steps")]
+    if paged:
+        # one layer's attention call: the pages its tiles stream, the kv
+        # grid steps it takes, and how many of the steps' page slots
+        # (steps x P) hold a live page
+        out["attn_pages"] = sum(a["attn_pages"] for a in paged) / len(paged)
+        out["attn_grid_steps"] = sum(
+            a["attn_grid_steps"] for a in paged) / len(paged)
+        out["attn_slot_share"] = sum(a["attn_pages"] for a in paged) / sum(
+            a["attn_grid_steps"] * a["attn_pages_per_step"] for a in paged)
     moe = [e["args"] for e in ticks if "experts_touched" in e["args"]]
     if moe:
         # dropless expert layers: what the step counted, back with the
@@ -699,7 +709,12 @@ def format_summary(events: list[dict], top: int = 5) -> str:
             + "; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
             f"arrays; context "
-            f"{acct['context_tokens']:.0f} tokens/dispatch; packed width "
+            f"{acct['context_tokens']:.0f} tokens/dispatch"
+            + (f"; attention streams {acct['attn_pages']:.0f} pages a layer "
+               f"in {acct['attn_grid_steps']:.0f} kv grid steps, "
+               f"{acct['attn_slot_share']:.0%} of their page slots live"
+               if "attn_pages" in acct else "")
+            + "; packed width "
             + " ".join(f"{w}x{n}" for w, n in acct["packed_widths"].items())
             + ("; programs (packed x dense width: ticks) "
                + " ".join(f"{p}:{n}" for p, n in acct["programs"].items())
