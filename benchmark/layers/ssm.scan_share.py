@@ -1,0 +1,16 @@
+"""Own time of the operations the op map puts under the ``ssm_scan`` scope
+(the state-space recurrence: the steps dt, the decay, the recurrent state read, its update and write, y),
+in % of device busy time.  A program without the scope (every stack
+without state-space layers, and the parent of PR 34) reads nothing."""
+import sys
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).parent))  # tracefile.py lies beside the readers
+import tracefile  # noqa: E402
+
+
+def read(run: dict) -> float | None:
+    table = tracefile.op_table(run)
+    if not table or not any(v and v[0] == "ssm_scan" for v in table.values()):
+        return None
+    return tracefile.scope_share(run, lambda scope, kind: scope == "ssm_scan")

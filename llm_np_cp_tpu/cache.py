@@ -59,6 +59,11 @@ class KVCache(NamedTuple):
     # every row, most recent last.  ``L`` above then counts the attention
     # layers only (config.attn_layers): a conv layer has no K/V.
     conv: jnp.ndarray | None = None  # [n_conv, B, conv_L_cache - 1, H]
+    # recurrent state of a configuration with state-space mixers
+    # (config.ssm_layers), float32 whatever the cache's dtype; ``conv``
+    # then holds the history of the convolution in front of the mixer.
+    # Both are laid out by ``config.state_shapes``.
+    ssm: jnp.ndarray | None = None  # [n_ssm, B, heads, d_head, d_state] f32
 
     @classmethod
     def init(
@@ -88,13 +93,10 @@ class KVCache(NamedTuple):
             config.head_dim,
         )
         quantized = dtype == jnp.int8
-        n_conv = len(config.conv_layers)
         return cls(
-            conv=jnp.zeros(
-                (n_conv, batch_size, config.conv_L_cache - 1,
-                 config.hidden_size),
-                jnp.bfloat16 if quantized else dtype,
-            ) if n_conv else None,
+            **{name: jnp.zeros(shp, dt) for name, (shp, dt) in
+               config.state_shapes(
+                   batch_size, jnp.bfloat16 if quantized else dtype).items()},
             k=jnp.zeros(shape, dtype=dtype),
             v=jnp.zeros(shape, dtype=dtype),
             valid=jnp.zeros((batch_size, max_seq_len), dtype=jnp.bool_),

@@ -128,6 +128,42 @@ class ModelConfig:
     # says why; the program has no value of its own.
     init_expert_specific: float | None = None
 
+    # --- A state-space mixer in parallel with attention in every layer
+    # (Falcon-H1, ``model_type: falcon_h1``).  ``mamba_d_ssm is None`` is
+    # every other family.  The mixer is Mamba-2: ``mamba_n_heads`` heads of
+    # ``mamba_d_head`` channels, a ``[d_head, d_state]`` float32 recurrent
+    # state a head, B and C shared by the heads of a group, a depthwise
+    # causal convolution of ``mamba_d_conv`` taps over [x, B, C] in front.
+    # Both mixers read ONE normed input and their results are summed into
+    # the residual stream; the muP multipliers below are applied where the
+    # published modeling code applies them (models/transformer.py).
+    mamba_d_ssm: int | None = None
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
+    # the chunk of the program's own scan (ops/ssm.py): the published
+    # kernel's chunk, not part of the mathematics
+    mamba_chunk_size: int = 128
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)  # gate, down
+    ssm_multipliers: tuple[float, ...] = (1.0,) * 5  # z, x, B, C, dt
+    # Seeded random weights only (models.init_params; no forward reads it):
+    # the standard deviation ``ssm_in_proj`` is drawn with, where a
+    # configuration's FILE states one and says why (at 0.02, the value of
+    # every other matrix, the recurrent state's share of the mixer's output
+    # is a thousandth of the skip ``D x`` and no comparison of outputs can
+    # see a broken state: PERF.md section 6, PR 34).
+    init_ssm_in_proj_std: float | None = None
+
     def __post_init__(self) -> None:
         # Note: hidden_size need not equal heads*head_dim (Gemma-2-2B:
         # 2304 hidden, 8 heads of 256), so no divisibility constraint there.
@@ -142,6 +178,15 @@ class ModelConfig:
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"num_hidden_layers is {self.num_hidden_layers}"
             )
+        if self.mamba_d_ssm is not None:
+            if self.mamba_d_ssm != self.mamba_n_heads * self.mamba_d_head:
+                raise ValueError(
+                    f"mamba_d_ssm {self.mamba_d_ssm} is not mamba_n_heads "
+                    f"{self.mamba_n_heads} x mamba_d_head {self.mamba_d_head}")
+            if self.mamba_n_heads % self.mamba_n_groups:
+                raise ValueError(
+                    f"mamba_n_heads {self.mamba_n_heads} not divisible by "
+                    f"mamba_n_groups {self.mamba_n_groups}")
 
     # ------------------------------------------------------------------
     @property
@@ -178,10 +223,14 @@ class ModelConfig:
     def is_hybrid(self) -> bool:
         """The stack has more than one kind of layer: params are groups
         of like layers (``layer_groups``), not one stacked pytree."""
-        return self.layer_types is not None
+        return self.layer_types is not None or self.mamba_d_ssm is not None
 
     def layer_op(self, layer_idx: int) -> str:
-        """``"attn"`` or ``"conv"``: the operator of layer ``layer_idx``."""
+        """``"attn"``, ``"conv"`` or ``"attn_ssm"`` (attention and a
+        state-space mixer side by side, both reading one normed input):
+        the operator of layer ``layer_idx``."""
+        if self.mamba_d_ssm is not None:
+            return "attn_ssm"
         if self.layer_types is None:
             return "attn"
         return "conv" if self.layer_types[layer_idx] == "conv" else "attn"
@@ -197,13 +246,53 @@ class ModelConfig:
         """Layers that hold K/V, in order: the only ones a cache or a
         pool has pages for (page ``i`` belongs to ``attn_layers[i]``)."""
         return tuple(i for i in range(self.num_hidden_layers)
-                     if self.layer_op(i) == "attn")
+                     if self.layer_op(i) in ("attn", "attn_ssm"))
 
     @property
     def conv_layers(self) -> tuple[int, ...]:
         """Layers that carry a short-convolution state, in order."""
         return tuple(i for i in range(self.num_hidden_layers)
                      if self.layer_op(i) == "conv")
+
+    @property
+    def ssm_layers(self) -> tuple[int, ...]:
+        """Layers that carry a state-space mixer's recurrent state (and
+        the history of the convolution in front of it), in order."""
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.layer_op(i) == "attn_ssm")
+
+    @property
+    def carries_state(self) -> bool:
+        """A sequence carries more than K/V between steps: a function of
+        the WHOLE sequence so far, which no block of a pool holds."""
+        return bool(self.conv_layers or self.ssm_layers)
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the state-space mixer's convolution: [x, B, C]."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    def state_shapes(self, slots: int, dtype: Any) -> dict[str, tuple]:
+        """What a sequence carries between steps besides K/V, as
+        ``{leaf: (shape, dtype)}`` with ``slots`` rows a layer; empty for
+        a stack of attention layers alone.  ``conv`` is the history of a
+        short convolution (a conv layer's gated inputs, or the [x, B, C]
+        inputs in front of a state-space mixer) in the served ``dtype``;
+        ``ssm`` is the mixer's recurrent state, float32 whatever is
+        served: it is rounded once a token for hundreds of tokens.  The
+        ONE statement of these shapes: the pool (``PagedKV.state``) and
+        the offline cache (``KVCache.conv`` / ``.ssm``) both read it."""
+        out: dict[str, tuple] = {}
+        if self.conv_layers:
+            out["conv"] = ((len(self.conv_layers), slots,
+                            self.conv_L_cache - 1, self.hidden_size), dtype)
+        if self.ssm_layers:
+            n = len(self.ssm_layers)
+            out["conv"] = ((n, slots, self.mamba_d_conv - 1,
+                            self.mamba_conv_dim), dtype)
+            out["ssm"] = ((n, slots, self.mamba_n_heads, self.mamba_d_head,
+                           self.mamba_d_state), "float32")
+        return out
 
     @property
     def expert_layers(self) -> tuple[int, ...]:
@@ -321,6 +410,45 @@ class ModelConfig:
                 routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
                 tie_word_embeddings=d.get("tie_word_embeddings", True),
                 init_expert_specific=d.get("init_expert_specific"),
+            )
+        if model_type == "falcon_h1":
+            # Falcon-H1: every block runs a Mamba-2 mixer beside GQA
+            # attention on one normed input; plain RMSNorm weights, no
+            # projection bias, an untied head, muP multipliers as keys
+            for key in ("attention_bias", "mlp_bias", "mamba_proj_bias",
+                        "projectors_bias"):
+                if d.get(key, False):
+                    raise ValueError(f"falcon_h1 with {key} is not implemented")
+            if not (d.get("mamba_rms_norm", True)
+                    and not d.get("mamba_norm_before_gate", False)
+                    and d.get("mamba_use_mlp", True)
+                    and d.get("attn_layer_indices") is None):
+                raise ValueError(
+                    "falcon_h1 is implemented with mamba_rms_norm, "
+                    "mamba_use_mlp, no mamba_norm_before_gate and attention "
+                    "in every layer (attn_layer_indices null)")
+            d_ssm = d.get("mamba_d_ssm") or (
+                d["mamba_expand"] * d["hidden_size"])
+            kwargs.update(
+                mamba_d_ssm=d_ssm,
+                mamba_n_heads=d["mamba_n_heads"],
+                mamba_d_head=d["mamba_d_head"],
+                mamba_d_state=d["mamba_d_state"],
+                mamba_n_groups=d.get("mamba_n_groups", 1),
+                mamba_d_conv=d.get("mamba_d_conv", 4),
+                mamba_conv_bias=d.get("mamba_conv_bias", True),
+                mamba_chunk_size=d.get("mamba_chunk_size", 128),
+                init_ssm_in_proj_std=d.get("init_ssm_in_proj_std"),
+                tie_word_embeddings=d.get("tie_word_embeddings", False),
+                mlp_multipliers=tuple(
+                    float(v) for v in d.get("mlp_multipliers", (1.0, 1.0))),
+                ssm_multipliers=tuple(
+                    float(v) for v in d.get("ssm_multipliers", (1.0,) * 5)),
+                **{k: float(d.get(k, 1.0)) for k in (
+                    "embedding_multiplier", "lm_head_multiplier",
+                    "key_multiplier", "attention_in_multiplier",
+                    "attention_out_multiplier", "ssm_in_multiplier",
+                    "ssm_out_multiplier")},
             )
         if model_type == "qwen2":
             # Qwen-2/2.5: llama architecture with Q/K/V projection biases
@@ -479,7 +607,8 @@ QWEN_2_5_1_5B = dataclasses.replace(
 # "mixtral" are the llama block, the latter with capacity-routed experts
 # when ``num_local_experts`` is set)
 KNOWN_MODEL_TYPES = frozenset(
-    ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe"))
+    ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe",
+     "falcon_h1"))
 
 PRESETS: dict[str, ModelConfig] = {
     "meta-llama/Llama-3.2-1B": LLAMA_3_2_1B,
@@ -541,6 +670,24 @@ def tiny_config(model_type: str = "llama", **overrides: Any) -> ModelConfig:
             moe_intermediate_size=32,
             use_expert_bias=True,
             tie_word_embeddings=True,
+        )
+    if model_type == "falcon_h1":
+        # 2 groups of 2 state-space heads, d_state 16, every multiplier
+        # different from 1 (and from each other): no width of the model
+        base.update(
+            rms_norm_eps=1e-5,
+            tie_word_embeddings=False,
+            mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16,
+            mamba_d_state=16, mamba_n_groups=2, mamba_d_conv=4,
+            mamba_chunk_size=8,
+            embedding_multiplier=5.5, lm_head_multiplier=0.125,
+            key_multiplier=0.7, attention_in_multiplier=1.25,
+            attention_out_multiplier=0.6, ssm_in_multiplier=1.5,
+            ssm_out_multiplier=0.8, mlp_multipliers=(0.9, 0.45),
+            ssm_multipliers=(0.85, 1.2, 1.4, 1.1, 0.75),
+            # the state's share of the mixer's output level with the
+            # skip's (at 0.02 it is a thousandth: a test could not see it)
+            init_ssm_in_proj_std=0.2,
         )
     base.update(overrides)
     return ModelConfig(**base)

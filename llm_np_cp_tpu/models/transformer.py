@@ -72,8 +72,15 @@ SCOPE_TAIL = "tail"          # final norm, head, sampling
 # write), and the two halves of the expert layer (ops/moe.py); its dense
 # feed-forwards stay under ``mlp``
 SCOPE_CONV = "conv"
+# a state-space mixer beside attention adds two: everything around the
+# recurrence (in_proj, multipliers, the convolution and its history, the
+# gated norm, out_proj; the input norm is the attention's), and the
+# recurrence itself with the state it reads and writes (ops/ssm.py)
+SCOPE_SSM_PROJ = "ssm_proj"
+SCOPE_SSM_SCAN = "ssm_scan"
 # ... which only a stack with such layers enters
-HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS)
+HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
+                 SCOPE_SSM_PROJ, SCOPE_SSM_SCAN)
 STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
                SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL) + HYBRID_SCOPES
 
@@ -158,6 +165,8 @@ def _group_shapes(
     NH, NK = config.num_attention_heads, config.num_key_value_heads
     if config.attention_bias or config.mlp_bias or config.conv_bias:
         raise NotImplementedError("a hybrid stack has no biased projection")
+    if op not in ("conv", "attn", "attn_ssm"):
+        raise ValueError(f"unknown layer operator {op!r}")
     if op == "conv":
         shapes = {
             "ln_conv_in": (n, H),
@@ -175,6 +184,20 @@ def _group_shapes(
         }
         if config.qk_norm:
             shapes.update(ln_q=(n, D), ln_k=(n, D))
+    if op == "attn_ssm":
+        # the state-space mixer beside the attention; in_proj's columns
+        # are [z, x, B, C, dt], the convolution runs over [x, B, C]
+        d_ssm, heads = config.mamba_d_ssm, config.mamba_n_heads
+        conv_dim = config.mamba_conv_dim
+        shapes.update(
+            ssm_in_proj=(n, H, d_ssm + conv_dim + heads),
+            ssm_conv=(n, conv_dim, config.mamba_d_conv),  # tap j meets u[t-(K-1)+j]
+            ssm_dt_bias=(n, heads), ssm_A_log=(n, heads), ssm_D=(n, heads),
+            ln_ssm=(n, d_ssm),
+            ssm_out_proj=(n, d_ssm, H),
+        )
+        if config.mamba_conv_bias:
+            shapes["ssm_conv_bias"] = (n, conv_dim)
     shapes["ln_mlp_in"] = (n, H)
     if ff == "experts":
         E, I = config.num_experts, config.moe_intermediate_size
@@ -232,6 +255,18 @@ def init_params(
                     # bias decided, one expert got 5 x the mean and a
                     # dozen (layer, expert) pairs none: PERF.md §6, PR 32)
                     return jax.random.normal(key, shape, jnp.float32) * 0.02
+                if name in ("ssm_A_log", "ssm_dt_bias", "ssm_D"):
+                    # the recurrence's own scalars, one a head, float32:
+                    # A = -a with a uniform in [1, 16]; a step
+                    # softplus(dt_bias) log-uniform in [1e-3, 1e-1]; D = 1
+                    # (the published code's initialisation)
+                    u = jax.random.uniform(key, shape, jnp.float32)
+                    if name == "ssm_A_log":
+                        return jnp.log(1.0 + 15.0 * u)
+                    if name == "ssm_D":
+                        return jnp.ones(shape, jnp.float32)
+                    step = jnp.exp(math.log(1e-3) + u * math.log(1e2))
+                    return step + jnp.log(-jnp.expm1(-step))
                 if name.endswith("_bias"):
                     # biases start small-but-nonzero so tests exercise the add path
                     return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
@@ -250,7 +285,9 @@ def init_params(
                     ).astype(dtype)
                 # a conv filter's three taps are of order 1 (the published
                 # code's default init is uniform in +-1/sqrt(3))
-                scale = 0.3 if name == "conv_filter" else 0.02
+                scale = 0.3 if name in ("conv_filter", "ssm_conv") else 0.02
+                if name == "ssm_in_proj" and config.init_ssm_in_proj_std:
+                    scale = config.init_ssm_in_proj_std
                 return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(dtype)
 
             return [make(k, p, s) for k, (p, s) in zip(keys, paths_leaves)]
@@ -310,6 +347,8 @@ def embed_inputs(params: Params, input_ids: jnp.ndarray, config: ModelConfig) ->
     if config.scale_embeddings:
         normalizer = jnp.array(math.sqrt(config.hidden_size), dtype=dtype)
         x = x * normalizer
+    if config.embedding_multiplier != 1.0:
+        x = x * jnp.array(config.embedding_multiplier, dtype=dtype)
     return x
 
 
@@ -327,6 +366,8 @@ def final_logits(
         logits = quant_einsum("bsh,vh->bsv", x, params["embed_tokens"])
     else:
         logits = quant_einsum("bsh,hv->bsv", x, params["lm_head"])
+    if config.lm_head_multiplier != 1.0:
+        logits = logits.astype(jnp.float32) * config.lm_head_multiplier
     if config.final_logit_softcapping is not None:
         logits = softcap(logits, config.final_logit_softcapping)
     return logits.astype(jnp.float32)
@@ -382,6 +423,7 @@ def sample_epilogue_tail(
         eps=config.rms_norm_eps,
         unit_offset=config.rms_norm_unit_offset,
         logit_softcap=config.final_logit_softcapping,
+        logit_scale=config.lm_head_multiplier,
     )
 
 
@@ -422,11 +464,17 @@ def attention_block(
     kv_update: Any = None,
     output_attentions: bool = False,
     attn_fn: Any = None,
+    normed: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray], jnp.ndarray | None]:
     """The attention operator of a block with its residual: ``(x_out,
     (k_att, v_att), attn_weights | None)``.  The first half of
     ``run_decoder_layer`` (see there for the arguments), and the operator
-    of a hybrid stack's attention layers."""
+    of a hybrid stack's attention layers.
+
+    normed: the block's input norm of ``x``, where a second mixer reads
+        the same one (``input_norm``): the operator then returns what it
+        ADDS to the stream, ``attention_out_multiplier`` applied, and the
+        caller sums the mixers into the residual."""
     if attn_fn is None:
         mask = (
             jnp.where(sliding, mask_local, mask_global)
@@ -441,16 +489,14 @@ def attention_block(
         return y + bias.astype(y.dtype) if bias is not None else y
 
     with jax.named_scope(SCOPE_QKV):
-        # the block computes in the gammas' dtype (compute_dtype); a
-        # residual stream kept wider (a hybrid stack's float32 one) is
-        # normed as it is and added to as it is — a no-op cast otherwise
-        h = rms_norm(
-            x, w["ln_attn_in"], eps=config.rms_norm_eps,
-            unit_offset=config.rms_norm_unit_offset,
-        ).astype(w["ln_attn_in"].dtype)
+        h = input_norm(w, x, config) if normed is None else normed
+        if config.attention_in_multiplier != 1.0:
+            h = h * jnp.array(config.attention_in_multiplier, h.dtype)
         q = _proj_b(h, "q_proj").reshape(b, s, config.num_attention_heads, config.head_dim)
         k = _proj_b(h, "k_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
         v = _proj_b(h, "v_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
+        if config.key_multiplier != 1.0:
+            k = k * jnp.array(config.key_multiplier, k.dtype)
         if config.qk_norm:
             # RMSNorm over head_dim on every q and k head, BEFORE RoPE
             q = rms_norm(q, w["ln_q"], eps=config.rms_norm_eps)
@@ -529,10 +575,23 @@ def attention_block(
                 attn, w["ln_attn_out"], eps=config.rms_norm_eps,
                 unit_offset=config.rms_norm_unit_offset,
             )
-        x = x + attn
+        if normed is not None:
+            x = attn * config.attention_out_multiplier
+        else:
+            x = x + attn
 
     return x, (k_att, v_att), attn_weights
 
+
+def input_norm(w: Params, x: jnp.ndarray, config: ModelConfig) -> jnp.ndarray:
+    """A block's input norm, in the dtype the block computes in
+    (``compute_dtype``: the gammas').  A residual stream kept wider (a
+    hybrid stack's float32 one) is normed as it is and added to as it
+    is — a no-op cast otherwise."""
+    return rms_norm(
+        x, w["ln_attn_in"], eps=config.rms_norm_eps,
+        unit_offset=config.rms_norm_unit_offset,
+    ).astype(w["ln_attn_in"].dtype)
 
 
 def ff_block(
@@ -562,9 +621,14 @@ def ff_block(
                 group_size=config.moe_group_size,
             )
         else:
-            gate = act(_proj_b(h, "gate_proj"))
+            gate_m, down_m = config.mlp_multipliers
+            gate = _proj_b(h, "gate_proj")
+            if gate_m != 1.0:
+                gate = gate * jnp.array(gate_m, gate.dtype)
             up = _proj_b(h, "up_proj")
-            mlp = _proj_b(gate * up, "down_proj", x.dtype)
+            mlp = _proj_b(act(gate) * up, "down_proj", x.dtype)
+            if down_m != 1.0:
+                mlp = mlp * down_m
         if config.sandwich_norms:
             mlp = rms_norm(
                 mlp, w["ln_mlp_out"], eps=config.rms_norm_eps,
@@ -609,6 +673,84 @@ def conv_block(
             c = c + z_prev.astype(jnp.float32) * filt[:, taps - 1 - d]
         y = _project(gate_c * c.astype(h.dtype), w["out_proj"], x.dtype)
         return x + y
+
+
+def ssm_block(
+    w: Params,
+    u: jnp.ndarray,
+    *,
+    config: ModelConfig,
+    history: Any,
+    scan: Any,
+    token_mask: jnp.ndarray | None = None,
+    out_dtype: Any = None,
+) -> jnp.ndarray:
+    """The state-space mixer of a block (Mamba-2, Falcon-H1's), WITHOUT a
+    residual: what it adds to the stream, ``ssm_out_multiplier`` applied.
+    ``[z, x, B, C, dt] = in_proj(u * ssm_in_multiplier) * m`` (``m`` holds
+    ``ssm_multipliers`` on the five slices), ``[x, B, C] <- silu(conv1d)``
+    (depthwise, causal, ``mamba_d_conv`` taps + bias), ``dt <-
+    softplus(dt + dt_bias)``, the recurrence (ops/ssm.py), then the gated
+    norm ``RMSNorm_groups(y * silu(z)) * w`` and ``out_proj``.
+
+    u: the block's input norm ``[B, S, H]``, which attention reads too.
+    history: the hook of ``conv_block``, over the convolution's inputs.
+    scan: ``(x [B, S, nh, P], dt [B, S, nh], a [nh], b, c [B, S, ng, N],
+        d_skip [nh]) -> y [B, S, nh, P]`` float32 — the recurrence over
+        the tokens as the CALLER lays sequences out, which owns the state
+        (a cache's, the serving tick's rows).
+    token_mask: ``[b, s]`` bool — False at padding, which neither enters
+        the convolution nor moves the state (``dt = 0``)."""
+    b_, s_ = u.shape[:2]
+    taps, d_ssm = config.mamba_d_conv, config.mamba_d_ssm
+    nh, ng, n = config.mamba_n_heads, config.mamba_n_groups, config.mamba_d_state
+    f32 = jnp.float32
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        h = u
+        if config.ssm_in_multiplier != 1.0:
+            h = h * jnp.array(config.ssm_in_multiplier, h.dtype)
+        # the five slices' multipliers as one per-channel vector
+        m = jnp.concatenate([jnp.full((width,), mult, h.dtype) for mult, width in zip(
+            config.ssm_multipliers, (d_ssm, d_ssm, ng * n, ng * n, nh))])
+        p = _project(h, w["ssm_in_proj"]) * m
+        z, xbc, dt = jnp.split(p, (d_ssm, d_ssm + config.mamba_conv_dim), axis=-1)
+        if token_mask is not None:
+            xbc = jnp.where(token_mask[..., None], xbc, jnp.zeros_like(xbc))
+        filt = w["ssm_conv"].astype(f32)  # [C, K]
+        acc = xbc.astype(f32) * filt[:, taps - 1]
+        for d, prev in enumerate(history(xbc), start=1):
+            acc = acc + prev.astype(f32) * filt[:, taps - 1 - d]
+        if "ssm_conv_bias" in w:
+            acc = acc + w["ssm_conv_bias"].astype(f32)
+        xbc = jax.nn.silu(acc).astype(h.dtype)
+        x, b, c = jnp.split(xbc, (d_ssm, d_ssm + ng * n), axis=-1)
+    with jax.named_scope(SCOPE_SSM_SCAN):
+        dt = jax.nn.softplus(dt.astype(f32) + w["ssm_dt_bias"].astype(f32))
+        if token_mask is not None:
+            dt = jnp.where(token_mask[..., None], dt, 0.0)
+        y = scan(
+            x.reshape(b_, s_, nh, -1), dt, -jnp.exp(w["ssm_A_log"].astype(f32)),
+            b.reshape(b_, s_, ng, n), c.reshape(b_, s_, ng, n), w["ssm_D"])
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        # gated norm: the mean square over each GROUP's channels, float32
+        g = (y.reshape(b_, s_, d_ssm) * jax.nn.silu(z.astype(f32))).reshape(
+            b_, s_, ng, -1)
+        g = g * lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                          + config.rms_norm_eps)
+        g = (g.reshape(b_, s_, d_ssm) * w["ln_ssm"].astype(f32)).astype(h.dtype)
+        return (_project(g, w["ssm_out_proj"], out_dtype)
+                * config.ssm_out_multiplier)
+
+
+def shifted_history(state: jnp.ndarray, z: jnp.ndarray, taps: int) -> tuple:
+    """A convolution's ``history`` hook over whole sequences ``z [B, S,
+    C]`` that continue ``state [B, taps - 1, C]`` (zeros before a
+    sequence's start): ``([z_{t-1}, .., z_{t-(taps-1)}], state after)`` —
+    token t's d-th predecessor sits d places before it."""
+    s = z.shape[1]
+    ext = jnp.concatenate([state.astype(z.dtype), z], axis=1)
+    return ([ext[:, taps - 1 - d:taps - 1 - d + s] for d in range(1, taps)],
+            ext[:, s:])
 
 
 def experts_block(
@@ -716,9 +858,10 @@ def _hybrid_stack(
     """``forward``'s layer loop for a stack of more than one kind of
     layer: every run of like layers (``config.layer_groups``) is scanned
     over its own stacked leaves, an attention run carrying its cache
-    slabs and a conv run its short-convolution state as ``xs`` / ``ys``.
-    Returns ``(x, (k, v) | None, conv state | None, chosen experts
-    [expert layers, B, S, k])``."""
+    slabs, a conv run its short-convolution state and a run with a
+    state-space mixer both and the recurrent state as ``xs`` / ``ys``.
+    Returns ``(x, (k, v) | None, {"conv", "ssm"} states | None each,
+    chosen experts [expert layers, B, S, k])``."""
     if cache is not None and (cache.quantized or offset.ndim == 1):
         raise NotImplementedError(
             "a hybrid layer stack runs a float cache with one length: an "
@@ -726,49 +869,68 @@ def _hybrid_stack(
             "are not implemented for it"
         )
     act = ACT2FN[config.hidden_act]
-    b, s, hdim = x.shape
-    taps = config.conv_L_cache
+    b = x.shape[0]
     # The residual stream is float32 between the blocks (each block
     # norms it, computes in the served dtype and adds its result back):
     # summed in bf16, two computations of the same tokens at different
     # batch shapes drift apart an ulp at a time, and a router turns such
     # a drift into another expert (measured on the chip: PERF.md §6)
     stream_dtype, x = x.dtype, x.astype(jnp.float32)
-    new_k, new_v, new_conv, experts = [], [], [], []
-    a0 = c0 = 0  # attention / conv layers seen so far
+    new_k, new_v, new_conv, new_ssm, experts = [], [], [], [], []
+    a0 = c0 = 0  # layers with K/V / with a state seen so far
+    # what a sequence carries besides K/V (zeros without a cache: every
+    # sequence starts here), as ``config.state_shapes`` lays it out
+    fresh = ({name: jnp.zeros(shape, dt) for name, (shape, dt)
+              in config.state_shapes(b, stream_dtype).items()}
+             if cache is None else {"conv": cache.conv, "ssm": cache.ssm})
     for w_g, (op, ff, _, n) in zip(groups, config.layer_groups()):
-        if op == "attn":
-            xs = ((cache.k[a0:a0 + n], cache.v[a0:a0 + n])
-                  if cache is not None else (jnp.zeros((n, 0)),) * 2)
+        xs: dict[str, Any] = {}
+        if op != "conv":
+            if cache is not None:
+                xs.update(k=cache.k[a0:a0 + n], v=cache.v[a0:a0 + n])
             a0 += n
-        else:
-            xs = (cache.conv[c0:c0 + n] if cache is not None
-                  else jnp.zeros((n, b, taps - 1, hdim), x.dtype),)
+        if op != "attn":
+            xs.update({name: a[c0:c0 + n] for name, a in fresh.items()
+                       if a is not None})
             c0 += n
 
         def body(x, layer, op=op, ff=ff):
-            w, *state = layer
+            w, state = layer
             ys: dict[str, Any] = {}
-            if op == "attn":
-                k_l, v_l = state
-                x, kv_att, _ = attention_block(
+
+            def history(z):
+                hist, ys["conv"] = shifted_history(
+                    state["conv"], z, state["conv"].shape[1] + 1)
+                return hist
+
+            if op != "conv":
+                normed = input_norm(w, x, config) if op == "attn_ssm" else None
+                mixed, kv_att, _ = attention_block(
                     w, x, config=config, cos=cos, sin=sin, mask_global=mask,
                     kv_update=(
-                        (lambda k, v: update_layer(k_l, v_l, k, v, offset))
+                        (lambda k, v: update_layer(
+                            state["k"], state["v"], k, v, offset))
                         if cache is not None else None),
+                    normed=normed,
                 )
                 if cache is not None:
                     ys["k"], ys["v"] = kv_att
-            else:
-                def history(z, state=state[0]):
-                    # the carried state in front of this call's tokens
-                    # (zeros before a sequence's start): token t's d-th
-                    # predecessor sits d places before it
-                    ext = jnp.concatenate([state.astype(z.dtype), z], axis=1)
-                    ys["conv"] = ext[:, s:]
-                    return [ext[:, taps - 1 - d:taps - 1 - d + s]
-                            for d in range(1, taps)]
+                if op == "attn_ssm":
+                    from llm_np_cp_tpu.ops.ssm import ssm_scan
 
+                    def scan(xh, dt, a, bm, cm, d_skip):
+                        y, ys["ssm"] = ssm_scan(
+                            state["ssm"], xh, dt, a, bm, cm, d_skip,
+                            chunk=config.mamba_chunk_size)
+                        return y
+
+                    mixed = mixed + ssm_block(
+                        w, normed, config=config, history=history, scan=scan,
+                        token_mask=token_mask, out_dtype=x.dtype)
+                    x = x + mixed
+                else:
+                    x = mixed
+            else:
                 x = conv_block(w, x, config=config, history=history,
                                token_mask=token_mask)
             if ff == "experts":
@@ -778,18 +940,21 @@ def _hybrid_stack(
                 x, _ = ff_block(w, x, config=config, act=act)
             return x, ys
 
-        x, ys = scan_group(body, x, (w_g, *xs), n)
+        x, ys = scan_group(body, x, (w_g, xs), n)
         if "k" in ys:
             new_k.append(ys["k"])
             new_v.append(ys["v"])
-        if "conv" in ys and cache is not None:
-            new_conv.append(ys["conv"].astype(cache.conv.dtype))
+        if cache is not None:
+            if "conv" in ys:
+                new_conv.append(ys["conv"].astype(cache.conv.dtype))
+            if "ssm" in ys:
+                new_ssm.append(ys["ssm"])
         if "experts" in ys:
             experts.append(ys["experts"])
     cat = lambda parts: jnp.concatenate(parts, axis=0) if parts else None
     x = x.astype(stream_dtype)
     return (x, (cat(new_k), cat(new_v)) if cache is not None else None,
-            cat(new_conv), cat(experts))
+            {"conv": cat(new_conv), "ssm": cat(new_ssm)}, cat(experts))
 
 def forward(
     params: Params,
@@ -945,7 +1110,7 @@ def forward(
                 "a hybrid layer stack runs attn_impl='xla' and collects "
                 "neither attentions nor hidden states"
             )
-        x, new_kv, new_conv, experts = _hybrid_stack(
+        x, new_kv, new_state, experts = _hybrid_stack(
             params["layers"], x, config, cache, offset=offset, cos=cos,
             sin=sin, mask=mask_global, token_mask=(
                 jnp.broadcast_to(attn_mask, (b, s))
@@ -959,7 +1124,7 @@ def forward(
         if cache is not None:
             new_cache = KVCache(
                 k=new_kv[0], v=new_kv[1], valid=cache_valid,
-                length=offset + s, conv=new_conv,
+                length=offset + s, **new_state,
             )
         if output_experts:
             return logits, new_cache, {"experts": experts}

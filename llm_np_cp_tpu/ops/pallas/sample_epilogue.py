@@ -67,6 +67,7 @@ def _epilogue_kernel(
     softcap: float | None,
     block_v: int,
     vocab: int,
+    scale: float,
 ):
     if quantized:
         x_ref, g_ref, w_ref, s_ref, o_ref, xn_ref, bv_ref, bi_ref = refs
@@ -110,6 +111,8 @@ def _epilogue_kernel(
         )
     if quantized:
         s = s * s_ref[:]  # [1, block_v] f32 per-column scales
+    if scale != 1.0:
+        s = s * scale  # the head's multiplier, on the f32 logits
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
     # mask the tail tile's fake columns (rank-2 iota: Mosaic rejects
@@ -139,7 +142,7 @@ def _epilogue_kernel(
     jax.jit,
     static_argnames=(
         "tied", "eps", "unit_offset", "logit_softcap", "block_v",
-        "interpret",
+        "interpret", "logit_scale",
     ),
 )
 def sample_epilogue(
@@ -154,6 +157,7 @@ def sample_epilogue(
     logit_softcap: float | None = None,
     block_v: int = BLOCK_V,
     interpret: bool | None = None,
+    logit_scale: float = 1.0,
 ) -> jnp.ndarray:
     """Greedy-sample the next token for each row of ``x`` without ever
     materializing the logits.
@@ -165,6 +169,8 @@ def sample_epilogue(
     f32 per-vocab-column scales (quant.py's ``"q"`` mode).  → [N] int32
     token ids, bit-identical to ``Sampler(kind="greedy")`` over
     ``final_logits`` (models/transformer.py) — pinned in tests.
+    ``logit_scale`` is a head's constant multiplier (applied to the f32
+    logits before the softcap, as ``final_logits`` does).
 
     Rows are padded to the f32 sublane tile internally; pad rows are
     zeros, normalize to zeros, and their draw is sliced off.
@@ -216,7 +222,7 @@ def sample_epilogue(
         functools.partial(
             _epilogue_kernel, tied=tied, quantized=quantized, eps=eps,
             unit_offset=unit_offset, softcap=logit_softcap, block_v=bv,
-            vocab=v,
+            vocab=v, scale=logit_scale,
         ),
         out_shape=jax.ShapeDtypeStruct((n8, 1), jnp.int32),
         grid=(nv,),

@@ -29,7 +29,10 @@ from llm_np_cp_tpu.config import ModelConfig
 def _inv_freq(config: ModelConfig) -> jnp.ndarray:
     dim = config.head_dim
     inv_freq = 1.0 / (
-        config.rope_theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+        # float(): a published theta of 1e11, read from JSON as an int,
+        # is more than an int32 operand holds
+        float(config.rope_theta)
+        ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     )
     if config.rope_scaling_type == "llama3":
         # Smoothly interpolate: high-frequency (short wavelength) components

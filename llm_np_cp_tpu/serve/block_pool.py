@@ -18,17 +18,22 @@ decode slots in the engine's fixed-width batch point their tables at it,
 so the packed decode step can write unconditionally (no data-dependent
 shapes) and garbage lands somewhere harmless.
 
-A configuration with conv layers (``config.conv_layers``, LFM2) has pages
-for its ATTENTION layers only (``L = len(config.attn_layers)``) and a
-second, constant-size store beside them in the same manager:
+A configuration whose sequences carry more than K/V between steps has
+pages for its layers WITH K/V only (``L = len(config.attn_layers)``) and a
+second, constant-size store beside them in the same manager, a small
+pytree keyed by what the configuration's layers carry
+(``config.state_shapes``, the one statement of these shapes):
 
-    state: [conv layers, slots, conv_L_cache - 1, hidden]
+    state["conv"]: [layers, slots, taps - 1, channels]     served dtype
+    state["ssm"]:  [layers, slots, heads, d_head, d_state]  float32
 
-one row a slot, no blocks: the last gated inputs of the slot's sequence,
-which the short convolution of its next token reads.  The step carries
-and writes it like the pages (donated); a slot's row is never read by a
-sequence's first token (a position-0 token's history is zero), so
-admitting a request into a freed slot needs no clear.
+one row a slot, no blocks: the last inputs of a short convolution (a conv
+layer's, or the one in front of a state-space mixer), and the mixer's
+recurrent state — float32 whatever is served, it is rounded once a token
+for hundreds of tokens.  The step carries the leaves and writes them in
+place at ``[layer, row]`` like the pages (donated); a slot's row is never
+read by a sequence's first token (a position-0 token's history and state
+are zero), so admitting a request into a freed slot needs no clear.
 
 int8 mode mirrors ``KVCache``'s quantized slabs: per-token-per-head
 absmax scales (cache.quantize_kv layout) ride in parallel
@@ -136,9 +141,10 @@ class PagedKV(NamedTuple):
     v: jnp.ndarray  # [L, NB, BS, K, D]
     k_scale: jnp.ndarray | None = None  # [L, NB, BS, K] f32 (int8 mode)
     v_scale: jnp.ndarray | None = None
-    # the short-convolution state of a configuration with conv layers
-    # (module docstring): not paged, one row a slot; None otherwise
-    state: jnp.ndarray | None = None  # [conv layers, slots, L_cache-1, H]
+    # what a sequence carries besides K/V (module docstring): not paged,
+    # one row a slot, ``{"conv": .., "ssm": ..}`` as the configuration's
+    # layers need; None for a stack of attention layers alone
+    state: dict[str, jnp.ndarray] | None = None
 
     @property
     def quantized(self) -> bool:
@@ -230,24 +236,25 @@ class BlockPool:
                      if quantized else None),
         )
 
-        # the short-convolution state of a configuration with conv layers
-        # (module docstring), in the activations' dtype whatever the K/V
-        # pages are quantized to; None for every other configuration
-        if config.conv_layers:
+        # what a sequence carries besides K/V (module docstring): the
+        # convolution history in the activations' dtype whatever the K/V
+        # pages are quantized to, the recurrent state float32; None for
+        # every other configuration
+        shapes = config.state_shapes(
+            state_slots, jnp.bfloat16 if quantized else dtype)
+        if shapes:
             if state_slots < 1:
                 raise ValueError(
-                    "a configuration with conv layers needs state_slots "
-                    "(one state row a slot)")
+                    "a configuration whose layers carry a state needs "
+                    "state_slots (one state row a slot)")
             from jax.sharding import NamedSharding, PartitionSpec
 
-            self.pages = self.pages._replace(state=zeros(
-                (len(config.conv_layers), state_slots,
-                 config.conv_L_cache - 1, config.hidden_size),
-                jnp.bfloat16 if quantized else dtype,
-                # a placement mesh pins the state beside the pages
-                None if shardings is None else NamedSharding(
-                    shardings.k.mesh, PartitionSpec()),
-            ))
+            # a placement mesh pins the state beside the pages
+            where_state = None if shardings is None else NamedSharding(
+                shardings.k.mesh, PartitionSpec())
+            self.pages = self.pages._replace(state={
+                name: zeros(shape, jnp.dtype(dt), where_state)
+                for name, (shape, dt) in shapes.items()})
 
     # -- accounting (delegates; the scheduler talks to these) ----------
     @property

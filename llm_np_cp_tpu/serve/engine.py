@@ -122,6 +122,7 @@ from llm_np_cp_tpu.generate import IncrementalDetok, make_ragged_prefill_step
 from llm_np_cp_tpu.models.transformer import (
     SCOPE_CONV,
     SCOPE_EMBED,
+    SCOPE_SSM_PROJ,
     SCOPE_TAIL,
     attention_block,
     conv_block,
@@ -130,9 +131,11 @@ from llm_np_cp_tpu.models.transformer import (
     ff_block,
     final_logits,
     forward,
+    input_norm,
     run_decoder_layer,
     scan_group,
     scan_unroll,
+    ssm_block,
 )
 from llm_np_cp_tpu.ops.activations import ACT2FN
 from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS
@@ -463,26 +466,31 @@ class ServeEngine:
                 "host_tier requires enable_prefix_cache=True: the tier "
                 "is keyed by the prefix cache's chained content hashes"
             )
-        if config.conv_layers:
-            # a conv layer's state is a function of the WHOLE sequence so
-            # far and the engine keeps no snapshot of it: whatever skips
-            # prefill, rolls tokens back or cuts the hidden dimension over
-            # chips is refused here, by the flag that asked for it
+        if config.carries_state:
+            # a recurrent state (a conv layer's history, a state-space
+            # mixer's) is a function of the WHOLE sequence so far and the
+            # engine keeps no snapshot of it: whatever skips prefill,
+            # rolls tokens back, restores blocks from elsewhere or cuts
+            # the hidden dimension over chips is refused here, by the flag
+            # that asked for it
             refused = [
                 (enable_prefix_cache, "--prefix-cache (enable_prefix_cache): "
                  "a prefix hit skips the prefill that builds the state"),
+                (host_tier is not None, "--kv-tier host (host_tier): blocks "
+                 "restored from the host tier carry no state"),
                 (spec_k > 0, "--spec-k (spec_k): rejected draft tokens "
                  "cannot be rolled back out of the state"),
                 (mesh_plan is not None and mesh_plan.model > 1,
-                 "--mesh model>1 (mesh_plan): the state and the conv / "
-                 "expert weights have no sharding rule"),
+                 "--mesh model>1 (mesh_plan): the state and the mixer / "
+                 "conv / expert weights have no sharding rule"),
                 (mixed_step == "off", "--mixed-step off (mixed_step): only "
                  "the unified tick carries the state"),
             ]
+            kind = "conv" if config.conv_layers else "state-space"
             for hit, why in refused:
                 if hit:
                     raise ValueError(
-                        f"model_type {config.model_type!r} has conv layers "
+                        f"model_type {config.model_type!r} has {kind} layers "
                         f"with a recurrent state; refused: {why}")
         from llm_np_cp_tpu.ops.pallas.support import (
             gate_attn_impl,
@@ -606,10 +614,10 @@ class ServeEngine:
                 self.mixed, self.ragged_attn_impl = True, "xla"
             else:
                 self.mixed = False
-        if config.conv_layers and not self.mixed:
+        if config.carries_state and not self.mixed:
             raise ValueError(
-                f"model_type {config.model_type!r} has conv layers and is "
-                "served by the unified tick only, which is unavailable "
+                f"model_type {config.model_type!r} carries a recurrent state "
+                "and is served by the unified tick only, which is unavailable "
                 f"here ({err}); --mixed-step on takes its XLA attention")
         # -- speculative serving (draft-then-verify in the unified tick):
         # per-request host-side prompt-lookup draft streams propose up to
@@ -739,7 +747,7 @@ class ServeEngine:
             jax.block_until_ready(self.pool.pages)
             tracer.complete("pool_alloc", t_pool, cat="setup", args={
                 "blocks": num_blocks, "bytes": int(sum(
-                    a.nbytes for a in self.pool.pages if a is not None)),
+                    a.nbytes for a in jax.tree.leaves(self.pool.pages))),
             })
         self.scheduler = Scheduler(
             self.pool,
@@ -915,6 +923,10 @@ class ServeEngine:
             tracer.complete("engine_build", t_build, cat="setup", args={
                 "tick": "unified" if self.mixed else "split",
                 "buckets": len(self.mixed_buckets),
+                # what the slots carry besides K/V (0 without such layers)
+                "state_bytes": int(sum(a.nbytes for a in jax.tree.leaves(
+                    self.pool.pages.state))),
+                "state_slots": max_slots,
             })
 
     def _make_buckets(
@@ -1910,6 +1922,8 @@ class ServeEngine:
 
         hybrid = config.is_hybrid
         max_slots = geometry[1]
+        # the scope of the bookkeeping every layer's state shares
+        state_scope = SCOPE_SSM_PROJ if config.ssm_layers else SCOPE_CONV
 
         @partial(jax.jit, donate_argnums=(1,))
         def mixed_step(
@@ -2133,15 +2147,16 @@ class ServeEngine:
             WHOLE, as the device keeps it: an attention layer writes at
             ``[layer, block, slot]`` in place and attends its own slab
             (``paged_hooks(layer=)``; no slab is written back, and no
-            reshape asks the device for another order).  A conv run takes
-            and gives back its layers' rows of the conv state.  Returns
-            ``(x, pool, state, per-expert loads [expert layers, E] |
-            None)``."""
+            reshape asks the device for another order).  What a sequence
+            carries besides K/V (``state``: a convolution's history, a
+            state-space mixer's recurrent state) is carried the same way,
+            whole, and written in place at ``[layer, row]``: no run takes
+            its layers' rows out or puts them back.  Returns ``(x, pool,
+            state, per-expert loads [expert layers, E] | None)``."""
             tok_row, tok_live = ops["tok_row"], ops["tok_live"]
             positions = ops["positions"]
             d_w = tok_row.shape[0]
-            taps = config.conv_L_cache
-            with jax.named_scope(SCOPE_CONV):
+            with jax.named_scope(state_scope):
                 # where token i's predecessors in its own sequence are:
                 # ``run`` of them are the packed tokens before it (a row's
                 # tokens are consecutive on the dense axis), the rest the
@@ -2156,10 +2171,28 @@ class ServeEngine:
                     [joined[1:], jnp.zeros((1,), jnp.bool_)])
                 # a row's last token of the tick leaves the row's state
                 row_out = jnp.where(ends, tok_row, max_slots)  # else: dropped
+                if config.ssm_layers:
+                    # the rows as the recurrence advances them: where a
+                    # row's tokens start, how many it has, whether its
+                    # sequence starts here (a slot's old state is never
+                    # read by a new request, nor touched by a tick the
+                    # row is not in)
+                    start = jnp.zeros((max_slots,), jnp.int32).at[
+                        jnp.where(tok_live & ~joined, tok_row, max_slots)
+                    ].set(idx, mode="drop")
+                    count = jnp.zeros((max_slots,), jnp.int32).at[
+                        jnp.where(tok_live, tok_row, max_slots)
+                    ].add(1, mode="drop")
+                    fresh = (count > 0) & (positions[start] == 0)
 
-            def conv_history(z, state_l, kept):
-                zz = z[0]  # [D, H]
-                rows = state_l[tok_row].astype(zz.dtype)  # [D, taps-1, H]
+            def conv_history(z, kept, layer):
+                """``conv_block`` / ``ssm_block``'s hook over the packed
+                axis: every token's predecessors, and ``kept`` (the whole
+                history leaf) with the rows' new histories written in
+                place at ``[layer, row]``."""
+                taps = kept.shape[2] + 1
+                zz = z[0]  # [D, C]
+                rows = kept[layer, tok_row].astype(zz.dtype)  # [D, taps-1, C]
                 hist = []
                 for d in range(1, taps):
                     from_state = jnp.take_along_axis(
@@ -2172,61 +2205,80 @@ class ServeEngine:
                     hist.append(jnp.where((positions >= d)[:, None], h_d,
                                           jnp.zeros_like(h_d)))
                 new_rows = jnp.stack(hist[:taps - 2][::-1] + [zz], axis=1)
-                kept.append(state_l.at[row_out].set(
-                    new_rows.astype(state_l.dtype), mode="drop"))
-                return [h[None] for h in hist]
+                return [h[None] for h in hist], kept.at[layer, row_out].set(
+                    new_rows.astype(kept.dtype), mode="drop")
 
-            states, loads = [], []
+            loads = []
             a0 = c0 = 0
             # float32 between the blocks, as models.forward keeps it
             # (transformer._hybrid_stack says why)
             stream_dtype, x = x.dtype, x.astype(jnp.float32)
             for w_g, (op, ff, _, n) in zip(groups, config.layer_groups()):
-                if op == "attn":
-                    xs = (layers[a0:a0 + n],)
+                # a layer's place among the layers with pages / a state
+                xs: dict[str, Any] = {}
+                if op != "conv":
+                    xs["paged"] = layers[a0:a0 + n]
                     a0 += n
-                else:
-                    with jax.named_scope(SCOPE_CONV):
-                        xs = (state[c0:c0 + n],)
+                if op != "attn":
+                    xs["state"] = jnp.arange(c0, c0 + n, dtype=jnp.int32)
                     c0 += n
 
                 def body(carry, layer, op=op, ff=ff):
-                    x, *pool = carry
-                    w, extra = layer
+                    x, pool, state = carry
+                    w, at = layer
+                    state = dict(state)
                     ys: dict[str, Any] = {}
-                    if op == "attn":
+
+                    def history(z):
+                        hist, state["conv"] = conv_history(
+                            z, state["conv"], at["state"])
+                        return hist
+
+                    if op == "conv":
+                        x = conv_block(w, x, config=config, history=history)
+                    else:
                         kp, vp, *scale_pages = pool
                         pool = []  # kv_update leaves the written arrays here
                         kv_update, attn_fn = paged_hooks(
-                            kp, vp, scale_pages, 0, layer=extra, written=pool)
-                        x, _, _ = attention_block(
+                            kp, vp, scale_pages, 0, layer=at["paged"],
+                            written=pool)
+                        normed = (input_norm(w, x, config)
+                                  if op == "attn_ssm" else None)
+                        mixed, _, _ = attention_block(
                             w, x, config=config, cos=cos, sin=sin,
                             kv_update=kv_update, attn_fn=attn_fn,
+                            normed=normed,
                         )
-                    else:
-                        kept: list = []
-                        x = conv_block(
-                            w, x, config=config,
-                            history=lambda z: conv_history(z, extra, kept))
-                        ys["state"] = kept[0]
+                        pool = tuple(pool)
+                    if op == "attn_ssm":
+                        from llm_np_cp_tpu.ops.ssm import ssm_packed
+
+                        def scan(xh, dt, a, bm, cm, d_skip):
+                            y, state["ssm"] = ssm_packed(
+                                state["ssm"], at["state"], xh[0], dt[0], a,
+                                bm[0], cm[0], d_skip, tok_row=tok_row,
+                                start=start, count=count,
+                                fresh=fresh, chunk=config.mamba_chunk_size)
+                            return y[None]
+
+                        x = x + (mixed + ssm_block(
+                            w, normed, config=config, history=history,
+                            scan=scan, out_dtype=x.dtype))
+                    elif op == "attn":
+                        x = mixed
                     if ff == "experts":
                         x, _, ys["load"] = experts_block(
                             w, x, config=config, act=act,
                             live=tok_live[None])
                     else:
                         x, _ = ff_block(w, x, config=config, act=act)
-                    return (x, *pool), ys
+                    return (x, pool, state), ys
 
-                (x, *pools), ys = scan_group(
-                    body, (x, *pools), (w_g, *xs), n)
-                if "state" in ys:
-                    states.append(ys["state"])
+                (x, pools, state), ys = scan_group(
+                    body, (x, tuple(pools), state), (w_g, xs), n)
                 if "load" in ys:
                     loads.append(ys["load"])
-            with jax.named_scope(SCOPE_CONV):
-                new_state = (jnp.concatenate(states, axis=0)
-                             if states else state)
-            return (x.astype(stream_dtype), tuple(pools), new_state,
+            return (x.astype(stream_dtype), tuple(pools), state,
                     jnp.concatenate(loads, axis=0) if loads else None)
 
         return mixed_step
@@ -3717,6 +3769,18 @@ class ServeEngine:
                 load_max=moe["expert_load_max"],
                 load_mean=moe["expert_load_mean"],
                 state_slots_live=moe["state_slots_live"])
+        ssm = None
+        if self.config.ssm_layers and active:
+            ssm = {
+                # rows whose recurrent state the dispatch read and wrote
+                # (every layer's), and the live tokens through the scan
+                "ssm_state_rows": active,
+                "ssm_scan_tokens": n_prefill_tok + n_decode_tok,
+                "state_slots_live": len(self.scheduler.running),
+            }
+            self.metrics.on_ssm(
+                rows=active, tokens=ssm["ssm_scan_tokens"],
+                state_slots_live=ssm["state_slots_live"])
         outliers: list[dict] = []
         if self.tracer is not None and t0 >= 0.0:
             t7 = self._phase_mark(None)
@@ -3761,6 +3825,8 @@ class ServeEngine:
                 # one fetch): summarize_trace's transfers section and
                 # the benchmark's moe.* readers
                 targs.update(moe)
+            if ssm is not None:
+                targs.update(ssm)
             if self.spec_k:
                 # the draft/verify split for summarize_trace and the
                 # sentinel: how many verify lanes rode this tick's
@@ -3959,17 +4025,12 @@ class ServeEngine:
         from llm_np_cp_tpu.models.transformer import STEP_SCOPES
         from llm_np_cp_tpu.serve import opmap
 
-        moved = [(a.dtype.name, a.sharding.shard_shape(a.shape))
-                 for a in self.pool.pages.pool_arrays()]
-        state = self.pool.pages.state
-        if state is not None:
-            # the conv state is marked like the pool: whole, and as the
-            # rows each run of conv layers takes and gives back
-            moved.append((state.dtype.name, state.shape))
-            moved.extend(
-                (state.dtype.name, (n,) + state.shape[1:])
-                for op, _, _, n in self.config.layer_groups() if op == "conv")
-        pool = opmap.pool_shapes(moved)
+        # the K/V pool alone: what a sequence carries beside it (a
+        # convolution's history, a recurrent state) is written by its own
+        # mixer, under that mixer's scope, and is no pool move
+        pool = opmap.pool_shapes(
+            (a.dtype.name, a.sharding.shard_shape(a.shape))
+            for a in self.pool.pages.pool_arrays())
         return opmap.merge(
             opmap.op_map_from_hlo(
                 self._mixed_step.lower(

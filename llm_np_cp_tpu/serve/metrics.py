@@ -190,6 +190,12 @@ class ServeMetrics:
         self.moe_load_max = 0
         self.moe_load_mean = 0.0
         self.conv_state_slots = 0
+        # state-space mixers (exact counters, one observation a
+        # dispatching tick): rows whose recurrent state a dispatch read
+        # and wrote, live tokens through the scan
+        self.ssm_ticks = 0
+        self.ssm_state_rows = 0
+        self.ssm_scan_tokens = 0
         # speculative draft-then-verify accounting (exact counters +
         # a real accept-length histogram over SPEC_ACCEPT_BUCKETS —
         # one observation per verify round, value = accepted drafts)
@@ -289,6 +295,16 @@ class ServeMetrics:
             self.moe_experts_touched += touched
             self.moe_load_max += load_max
             self.moe_load_mean += load_mean
+            self.conv_state_slots = state_slots_live
+
+    def on_ssm(self, *, rows: int, tokens: int, state_slots_live: int) -> None:
+        """One dispatching tick of a stack with state-space mixers: the
+        rows whose recurrent state it read and wrote, the live tokens it
+        sent through the scan."""
+        with self._lock:
+            self.ssm_ticks += 1
+            self.ssm_state_rows += rows
+            self.ssm_scan_tokens += tokens
             self.conv_state_slots = state_slots_live
 
     def on_spec(self, *, drafted: int, accepted: int) -> None:
@@ -484,6 +500,12 @@ class ServeMetrics:
                 out["moe_expert_load_max"] = self.moe_load_max
                 out["moe_expert_load_mean"] = self.moe_load_mean
                 out["conv_state_slots_live"] = self.conv_state_slots
+            if self.ssm_ticks:
+                # only where a state-space mixer ran
+                out["ssm_ticks"] = self.ssm_ticks
+                out["ssm_state_rows"] = self.ssm_state_rows
+                out["ssm_scan_tokens"] = self.ssm_scan_tokens
+                out["ssm_state_slots_live"] = self.conv_state_slots
             if self.spec_rounds:
                 # reported only once a verify round ran (like the SLO
                 # block): a fabricated 0-acceptance series on a
@@ -684,6 +706,21 @@ class ServeMetrics:
             emit("conv_state_slots_live", "gauge",
                  "Slots whose short-convolution state is live",
                  [("", s["conv_state_slots_live"])])
+        if "ssm_ticks" in s:
+            emit("ssm_ticks_total", "counter",
+                 "Dispatching ticks that ran state-space mixers",
+                 [("", s["ssm_ticks"])])
+            emit("ssm_state_rows_total", "counter",
+                 "Rows whose recurrent state a dispatch read and wrote "
+                 "(every state-space layer's), summed over ticks",
+                 [("", s["ssm_state_rows"])])
+            emit("ssm_scan_tokens_total", "counter",
+                 "Live tokens through the state-space scan, summed over "
+                 "ticks",
+                 [("", s["ssm_scan_tokens"])])
+            emit("ssm_state_slots_live", "gauge",
+                 "Slots whose recurrent state is live",
+                 [("", s["ssm_state_slots_live"])])
         # -- speculative decoding (only once a verify round ran — a
         # constant-zero series on a plain engine would read as a broken
         # speculation deployment on a fleet dashboard)

@@ -43,7 +43,7 @@ import numpy as np
 from safetensors import safe_open
 
 from llm_np_cp_tpu.config import ModelConfig
-from llm_np_cp_tpu.models import gemma2, lfm2_moe, llama, qwen2
+from llm_np_cp_tpu.models import falcon_h1, gemma2, lfm2_moe, llama, qwen2
 from llm_np_cp_tpu.models.transformer import param_shapes
 
 log = logging.getLogger("llm_np_cp_tpu")
@@ -108,11 +108,24 @@ def _read_shard(
             raise ValueError(f"{path.name}: {e}") from e
 
 
+# leaves that stay float32 whatever is served: the experts' selection
+# bias, a state-space recurrence's own scalars
+F32_LEAVES = frozenset(("expert_bias",)) | falcon_h1.F32_LEAVES
+# depthwise Conv1d weights, stored [C, 1, taps]
+CONV1D_LEAVES = frozenset(("conv_filter", "ssm_conv"))
+
+
+def hybrid_family(config: ModelConfig):
+    """The family module whose ``layer_tensors`` places a hybrid stack's
+    checkpoint tensors (``(HF key, run, leaf, index, transpose?)``)."""
+    return falcon_h1 if config.model_type == "falcon_h1" else lfm2_moe
+
+
 def _key_maps(config: ModelConfig):
     if config.is_hybrid:
         # a per-layer tensor's place is not leaf[layer] there: the
-        # family's own table (models/lfm2_moe.layer_tensors) says where
-        return {}, lfm2_moe.TOP_KEY_MAP
+        # family's own table (``layer_tensors``) says where
+        return {}, hybrid_family(config).TOP_KEY_MAP
     family = {"gemma2": gemma2, "qwen2": qwen2}.get(config.model_type, llama)
     return family.LAYER_KEY_MAP, family.TOP_KEY_MAP
 
@@ -195,9 +208,8 @@ def load_params(
 
     # Preallocated stacked host buffers.
     def buffers(leaves: dict) -> dict:
-        # the experts' selection bias stays float32 whatever is served
         return {name: np.empty(shape, dtype=(
-            np.float32 if name == "expert_bias" else np_dtype))
+            np.float32 if name in F32_LEAVES else np_dtype))
             for name, shape in leaves.items()}
 
     host: dict[str, Any] = {
@@ -207,7 +219,8 @@ def load_params(
                    if config.is_hybrid else buffers(shapes["layers"])),
     }
     # a hybrid stack's per-layer tensors: HF key → (run, leaf, index, T?)
-    placed = {key: rest for key, *rest in lfm2_moe.layer_tensors(config)
+    placed = {key: rest for key, *rest
+              in hybrid_family(config).layer_tensors(config)
               } if config.is_hybrid else {}
     if "lm_head" in shapes:
         host["lm_head"] = np.empty(shapes["lm_head"], dtype=np_dtype)
@@ -230,14 +243,14 @@ def load_params(
             raise ValueError(
                 f"{key}: checkpoint shape {value.shape} != expected {dest.shape}"
             )
-        dest[...] = value.astype(np_dtype)
+        dest[...] = value.astype(dest.dtype)
 
     def consume(f: Any, native: bool) -> None:
         for key in f.keys():
             if key in placed:
                 run, leaf, index, transpose = placed[key]
                 dest = host["layers"][run][leaf][index]
-                if native and leaf == "conv_filter":
+                if native and leaf in CONV1D_LEAVES:
                     dest = dest[:, None, :]  # as stored: [H, 1, L]
                 fill(f, native, key, dest, transpose)
                 filled.add(key)

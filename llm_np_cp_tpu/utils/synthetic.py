@@ -23,9 +23,12 @@ import numpy as np
 from safetensors.numpy import save_file
 
 from llm_np_cp_tpu.config import ModelConfig
-from llm_np_cp_tpu.models import lfm2_moe
 from llm_np_cp_tpu.models.transformer import param_shapes
-from llm_np_cp_tpu.utils.loading import _key_maps
+from llm_np_cp_tpu.utils.loading import (
+    CONV1D_LEAVES,
+    _key_maps,
+    hybrid_family,
+)
 
 # elements drawn per RNG call — the unit of parallelism, so one large
 # tensor (a 233M-element embedding table) still uses every worker
@@ -87,6 +90,26 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
             norm_topk_prob=config.norm_topk_prob,
             routed_scaling_factor=config.routed_scaling_factor,
         )
+    if config.model_type == "falcon_h1":
+        d.update(
+            mamba_d_ssm=config.mamba_d_ssm,
+            mamba_n_heads=config.mamba_n_heads,
+            mamba_d_head=config.mamba_d_head,
+            mamba_d_state=config.mamba_d_state,
+            mamba_n_groups=config.mamba_n_groups,
+            mamba_d_conv=config.mamba_d_conv,
+            mamba_conv_bias=config.mamba_conv_bias,
+            mamba_chunk_size=config.mamba_chunk_size,
+            mlp_multipliers=list(config.mlp_multipliers),
+            ssm_multipliers=list(config.ssm_multipliers),
+            **{k: getattr(config, k) for k in (
+                "embedding_multiplier", "lm_head_multiplier",
+                "key_multiplier", "attention_in_multiplier",
+                "attention_out_multiplier", "ssm_in_multiplier",
+                "ssm_out_multiplier")},
+        )
+        if config.init_ssm_in_proj_std is not None:
+            d["init_ssm_in_proj_std"] = config.init_ssm_in_proj_std
     if config.model_type == "gemma2":
         d.update(
             final_logit_softcapping=config.final_logit_softcapping,
@@ -112,9 +135,9 @@ def hf_tensor_shapes(config: ModelConfig) -> dict[str, tuple[int, ...]]:
         if name in shapes and not isinstance(shapes[name], dict):
             out[hf_key] = stored(shapes[name], transpose)
     if config.is_hybrid:
-        for key, run, leaf, index, transpose in lfm2_moe.layer_tensors(config):
+        for key, run, leaf, index, transpose in hybrid_family(config).layer_tensors(config):
             shape = shapes["layers"][run][leaf][len(index):]
-            if leaf == "conv_filter":  # a depthwise Conv1d weight [H, 1, L]
+            if leaf in CONV1D_LEAVES:  # a depthwise Conv1d weight [H, 1, L]
                 shape = (shape[0], 1, shape[1])
             out[key] = stored(shape, transpose)
         return out
@@ -137,9 +160,9 @@ def hf_state_dict(params: Mapping[str, Any],
             t = params[name]
             out[hf_key] = np.ascontiguousarray(t.T if transpose else t)
     if config.is_hybrid:
-        for key, run, leaf, index, transpose in lfm2_moe.layer_tensors(config):
+        for key, run, leaf, index, transpose in hybrid_family(config).layer_tensors(config):
             t = np.asarray(params["layers"][run][leaf][index])
-            if leaf == "conv_filter":
+            if leaf in CONV1D_LEAVES:
                 t = t[:, None, :]
             out[key] = np.ascontiguousarray(t.T if transpose else t)
         return out

@@ -225,7 +225,7 @@ SLOTS, BLOCKS, BLOCK, CHUNK = 4, 256, 64, 64
 
 
 def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS, slots=SLOTS,
-                           program=None, cfg=None):
+                           program=None, cfg=None, chunk=CHUNK):
     """The unified step of a small Qwen2.5-shaped engine (or of ``cfg``),
     compiled for a described v5e as ``program`` (its widest by default)."""
     cfg = cfg or tiny_config(
@@ -237,7 +237,7 @@ def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS, slots=SLOTS,
         lambda: init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
     engine = ServeEngine(
         abstract, cfg, max_slots=slots, num_blocks=blocks, block_size=BLOCK,
-        max_seq_len=BLOCK * 8, prefill_chunk=CHUNK, cache_dtype=cache_dtype,
+        max_seq_len=BLOCK * 8, prefill_chunk=chunk, cache_dtype=cache_dtype,
         mixed_step="on",
     )
     assert engine.mixed and engine.ragged_attn_impl == "pallas"
@@ -265,7 +265,7 @@ def _pool_ops(engine, compiled):
     """serve/opmap.py's parse of the compiled text: every operation that
     runs, as [scope, result shape, "pool" | "slab" | ""]."""
     pool = opmap.pool_shapes(
-        (a.dtype.name, a.shape) for a in engine.pool.pages if a is not None)
+        (a.dtype.name, a.shape) for a in jax.tree.leaves(engine.pool.pages))
     return opmap.op_map_from_hlo(compiled.as_text(), STEP_SCOPES, pool)
 
 
@@ -644,7 +644,8 @@ def test_hybrid_tick_on_a_v5e_names_its_experts_and_rebuilds_no_pool(
     assert "input_output_alias" in text  # the pool and the state come back in place
     pool = opmap.pool_shapes(
         [(a.dtype.name, a.shape) for a in engine.pool.pages.pool_arrays()]
-        + [(engine.pool.pages.state.dtype.name, engine.pool.pages.state.shape)])
+        + [(a.dtype.name, a.shape)
+           for a in jax.tree.leaves(engine.pool.pages.state)])
     ops = opmap.op_map_from_hlo(
         text, STEP_SCOPES, pool, named=(("ragged-dot", "moe_experts"),))
     grouped = [n for n in ops if n.startswith("ragged-dot-none")]
@@ -660,3 +661,63 @@ def test_hybrid_tick_on_a_v5e_names_its_experts_and_rebuilds_no_pool(
     rebuilt = [n for n, v in ops.items() if v[2] == "pool"
                and re.match(r"(pad|concatenate|maximum)", n)]
     assert not rebuilt, rebuilt
+    # nor is the conv state: it is carried whole and written in place at
+    # [layer, row] - no run's rows are sliced out, stacked or concatenated
+    state = engine.pool.pages.state["conv"]
+    whole = opmap.hlo_shape(state.dtype.name, state.shape)
+    # (an asynchronous copy-start / copy-done pair is the compiler
+    # prefetching these 8 KB into faster memory, not a rebuild)
+    moved = [n for n, v in ops.items() if v[1] == whole
+             and re.match(r"(copy|pad|concatenate|slice|gather)(\.\d+)?$", n)]
+    assert not moved, moved
+    assert any(v[1] == whole for v in ops.values()), "the state is not written"
+
+
+def test_state_space_tick_on_a_v5e_updates_the_state_in_place_row_by_row(
+        v5e_sharding):
+    """The benchmark's state-space configuration AT ITS PUBLISHED SHAPES (6
+    layers of a Mamba-2 mixer beside attention, 64 slots: a recurrent state
+    of 6 x 64 rows of 4 MiB float32, 1.5 GiB), its widest program - decode
+    rows' rank-one updates and a prefill chunk's passes in one step.  What
+    the compiler may not do with the state, each of which it did to an
+    earlier form of the step (PERF.md section 6, PR 34): copy it whole
+    (carried through the layer scan it is donated and aliased), materialise
+    a layer's rows beside it (a 256 MiB slab: the chunk form over all rows
+    did), or gather a state row per TOKEN.  A gather of four rows out of the
+    whole state was compiled as a pass over ALL of it (+1.5 GiB of
+    temporaries): a prefill chunk's rows are sliced one at a time."""
+    import json
+    from pathlib import Path
+
+    from llm_np_cp_tpu.config import ModelConfig
+
+    cfg = ModelConfig.from_hf_dict(json.loads((
+        Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+        / "falcon-h1-34b-6l.json").read_text()))
+    engine, compiled = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, slots=64, blocks=1026,
+        chunk=128, program=(768, 320))
+    assert engine.epilogue_impl == "fused"  # a head of 5,120 x 261,120
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    state = engine.pool.pages.state["ssm"]
+    assert state.shape == (6, 64, 32, 128, 256) and state.dtype == jnp.float32
+    slab_bytes = state.nbytes // 6  # one layer's rows: 256 MiB
+    # every temporary of the step together is less than ONE layer's rows
+    # (measured: 100 MiB): no whole-state copy, no slab beside the state, no
+    # [tokens, 32, 128, 256] gather (320 tokens: 1.25 GiB)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < slab_bytes, temp
+    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, opmap.pool_shapes(
+        [(a.dtype.name, a.shape) for a in jax.tree.leaves(engine.pool.pages)]))
+    whole = opmap.hlo_shape("float32", state.shape)
+    rows = {opmap.hlo_shape("float32", (n,) + state.shape[1:]) for n in (1, 6)} | {
+        opmap.hlo_shape("float32", state.shape[1:])}
+    # whatever gives the whole state back is the in-place update, under its
+    # scope; nothing gives a layer's rows back at all
+    writes = [n for n, v in ops.items() if v[1] == whole]
+    assert writes and all(ops[n][0] == "ssm_scan" for n in writes), writes
+    assert all("fusion" in n for n in writes), writes
+    assert not [n for n, v in ops.items() if v[1] in rows - {whole}]
+    assert {"ssm_proj", "ssm_scan", "attn", "mlp", "tail"} <= {
+        v[0] for v in ops.values()}

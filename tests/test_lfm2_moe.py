@@ -278,7 +278,8 @@ def test_pool_holds_attention_layers_only_and_state_beside_it(tiny):
     engine = _engine(cfg, params)
     pages = engine.pool.pages
     assert pages.k.shape == (2, 48, 8, 2, 16)  # 2 of 10 layers have K/V
-    assert pages.state.shape == (8, 4, 2, 64)  # conv layers, slots, taps - 1, H
+    # conv layers, slots, taps - 1, H: the one leaf of the state pytree
+    assert {k: v.shape for k, v in pages.state.items()} == {"conv": (8, 4, 2, 64)}
     assert len(pages.pool_arrays()) == 2
     # the bucket set is the one a stack of one kind of layer gets
     plain = ServeEngine(
@@ -503,13 +504,19 @@ def test_tick_arguments_counters_and_scopes(tiny):
     for name in ("moe_ticks_total", "moe_experts_touched_total",
                  'moe_expert_load_total{kind="max"}', "conv_state_slots_live"):
         assert name in text, name
-    # the op map knows the new scopes and marks the conv state like the pool
+    # the op map knows the new scopes; the conv state is the mixer's own,
+    # not a pool move (PR 34: `pool.move_share` reads the K/V pool alone)
     assert {"conv", "moe_route", "moe_experts"} <= set(STEP_SCOPES)
     table = engine.device_op_map()
     scopes = {v[0] for v in table.values() if v}
     assert {"conv", "moe_route", "moe_experts", "mlp", "attn"} <= scopes
-    assert any(v and v[1] for k, v in table.items() if "f32[1,4,2,64]" in k
-               or "f32[4,2,64]" in k), "no operation moves the conv state"
+    # ... which is written in place, whole: no run of layers takes its rows
+    # out of it or puts them back (PR 34)
+    moves = [v for k, v in table.items() if "f32[8,4,2,64]" in k]
+    assert moves, "no operation moves the conv state"
+    assert any(v and v[0] == "conv" for v in moves), moves
+    assert not any(v and v[1] for v in moves), moves
+    assert not any("f32[1,4,2,64]" in k or "f32[2,4,2,64]" in k for k in table)
 
 
 # ----------------------------------------------------------------------

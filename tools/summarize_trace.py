@@ -510,6 +510,12 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
         for key in ("experts_touched", "expert_load_max",
                     "expert_load_mean", "state_slots_live"):
             out[key] = sum(a.get(key, 0) for a in moe) / len(moe)
+    ssm = [e["args"] for e in ticks if "ssm_state_rows" in e["args"]]
+    if ssm:
+        # state-space mixers: rows whose recurrent state the dispatch read
+        # and wrote, live tokens through the scan
+        for key in ("ssm_state_rows", "ssm_scan_tokens", "state_slots_live"):
+            out[key] = sum(a.get(key, 0) for a in ssm) / len(ssm)
     cpu = [e["args"]["thread_cpu_us"] for e in ticks
            if "thread_cpu_us" in e["args"]]
     if cpu:
@@ -692,6 +698,12 @@ def format_summary(events: list[dict], top: int = 5) -> str:
                 + ")"
             )
     acct = tick_account(events)
+    # one slot's state, all layers (the engine_build span says what the
+    # slots carry besides K/V, and how many slots there are)
+    build = next((e.get("args", {}) for e in events
+                  if e.get("name") == "engine_build"), {})
+    state_row_bytes = (build.get("state_bytes", 0)
+                       / max(build.get("state_slots", 0), 1))
     if acct is not None:
         lines.append(
             f"== tick account ({acct['ticks']:.0f} dispatching ticks, "
@@ -706,6 +718,13 @@ def format_summary(events: list[dict], top: int = 5) -> str:
                f"{acct['expert_load_mean']:.2f} tokens an expert, "
                f"{acct['state_slots_live']:.1f} conv-state slots live"
                if "experts_touched" in acct else "")
+            + (f"; state-space scan {acct['ssm_scan_tokens']:.1f} tokens a "
+               f"tick, {acct['ssm_state_rows']:.1f} rows' recurrent state "
+               f"read and written"
+               + (f" ({acct['ssm_state_rows'] * state_row_bytes / 2**20:.0f}"
+                  " MiB a tick each way)" if state_row_bytes else "")
+               + f", {acct['state_slots_live']:.1f} state slots live"
+               if "ssm_state_rows" in acct else "")
             + "; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
             f"arrays; context "
