@@ -104,6 +104,7 @@ static [slots, K+1] extension of the step, so zero-recompiles survives.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
 import math
@@ -165,6 +166,11 @@ Params = dict[str, Any]
 # hooks: ``nullcontext()`` per tick would be a per-tick allocation on
 # the hot path — exactly what the tracing-off discipline forbids.
 _NULL_CTX = contextlib.nullcontext()
+
+# Kinds of an item ``ServeEngine._owed`` holds: a token, the token that
+# ends a prefill (the request's track turns to ``decode`` after it), a
+# terminal reason.
+_OWED_TOKEN, _OWED_FIRST, _OWED_FINISH = 0, 1, 2
 
 
 def _ceil_to(n: int, g: int) -> int:
@@ -815,6 +821,13 @@ class ServeEngine:
         # live (queued or running) requests by id — the abort/deadline
         # index; entries leave on finish and abort
         self._requests: dict[int, Request] = {}
+        # what the unified tick's ``accept`` owes the outside, in emit
+        # order: ``(kind, request, token | terminal reason)``.  Tick N's
+        # items are handed out (``_publish``) behind tick N+1's
+        # dispatch, or on the spot when no dispatch follows
+        self._owed: collections.deque[tuple[int, Request, Any]] = (
+            collections.deque())
+        self._publishing = False
         # device dispatches issued by this engine (every jitted-step
         # call) — the CPU-measurable observable for the unified tick's
         # "strictly fewer dispatches per tick" claim
@@ -2822,17 +2835,70 @@ class ServeEngine:
                     args={"action": action, **self.actions.state_args()},
                 )
 
-    def _emit(self, req: Request, token: int) -> None:
-        req.generated.append(int(token))
+    # -- a token's way out, cut in two ---------------------------------
+    # ``accept`` is what the NEXT plan reads (the token in
+    # ``req.generated``, the timestamps, finish decided and the slot and
+    # blocks released); ``publish`` is what the outside is handed
+    # (metrics, detokenizer, callbacks, request log, tracer, journal).
+    # The split tick does both at once (``_emit`` / ``_maybe_finish``);
+    # the unified tick accepts tick N and publishes it behind tick N+1's
+    # dispatch (``_accept`` → ``_owed`` → ``_publish``).
+
+    def _accept_token(self, req: Request, token: int) -> None:
+        req.generated.append(token)
         if req.first_token_time is None:
             req.first_token_time = self.clock()
+
+    def _accept_finish(self, req: Request) -> str | None:
+        """Decide finish on the request's newest token; when it ends the
+        stream, release the request (slot and blocks are free for the
+        next admission) and return the reason."""
+        hit_stop = bool(
+            self.stop_tokens and req.generated
+            and req.generated[-1] in self.stop_tokens
+        )
+        if not (req.done or hit_stop):
+            return None
+        # a stop token on the last budgeted step still reports
+        # "stop": the model chose to end, the budget merely agreed
+        req.finish_reason = "stop" if hit_stop else "length"
+        req.finish_time = self.clock()
+        self.scheduler.finish(req)
+        self._requests.pop(req.req_id, None)
+        self._draft_states.pop(req.req_id, None)
+        return req.finish_reason
+
+    def _publish_token(self, req: Request, token: int) -> None:
         self.metrics.on_token(req)
         if req.callback is not None:
             delta = None
             detok = self._detok.get(req.req_id)
             if detok is not None:
                 delta = detok.push(token)
-            req.callback(req, int(token), delta)
+            req.callback(req, token, delta)
+
+    def _publish_finish(self, req: Request, reason: str) -> None:
+        self._flush_detok(req)
+        self.metrics.on_finish(req)
+        if self.tenants is not None:
+            self.tenants.on_terminal(req)
+        if self.journal is not None:
+            # flush the final delivery delta (the finishing tick's
+            # token would otherwise be missed — the request left the
+            # live set at accept), then mark terminal so the replay set
+            # stays exact
+            self.journal.end_tick((req,))
+            self.journal.terminal(req.req_id, reason)
+        self._log_request(req, reason)
+        if self.tracer is not None:
+            self.tracer.request_end(req.req_id, reason,
+                                    args=self._targs(req))
+        self._emit_event(req, reason)
+
+    def _emit(self, req: Request, token: int) -> None:
+        """The split tick's emit: accepted and published at once."""
+        self._accept_token(req, int(token))
+        self._publish_token(req, int(token))
 
     def _emit_event(self, req: Request, event: str) -> None:
         if req.on_event is not None:
@@ -2849,40 +2915,106 @@ class ServeEngine:
                 req.extra["final_text_delta"] = tail
 
     def _maybe_finish(self, req: Request) -> bool:
+        """The split tick's finish check, published at once."""
         if req.state is not RequestState.RUNNING:
             # aborted out from under us (e.g. from a token callback) —
             # already unwound, nothing left to finish
             return True
-        hit_stop = bool(
-            self.stop_tokens and req.generated
-            and req.generated[-1] in self.stop_tokens
-        )
-        if req.done or hit_stop:
-            # a stop token on the last budgeted step still reports
-            # "stop": the model chose to end, the budget merely agreed
-            req.finish_reason = "stop" if hit_stop else "length"
-            req.finish_time = self.clock()
-            self.scheduler.finish(req)
-            self._requests.pop(req.req_id, None)
-            self._draft_states.pop(req.req_id, None)
-            self._flush_detok(req)
-            self.metrics.on_finish(req)
-            if self.tenants is not None:
-                self.tenants.on_terminal(req)
-            if self.journal is not None:
-                # flush the final delivery delta (the finishing tick's
-                # token would otherwise be missed — the request leaves
-                # the live set before the tick's watermark), then mark
-                # terminal so the replay set stays exact
-                self.journal.end_tick((req,))
-                self.journal.terminal(req.req_id, req.finish_reason)
-            self._log_request(req, req.finish_reason)
-            if self.tracer is not None:
-                self.tracer.request_end(req.req_id, req.finish_reason,
-                                        args=self._targs(req))
-            self._emit_event(req, req.finish_reason)
-            return True
-        return False
+        reason = self._accept_finish(req)
+        if reason is None:
+            return False
+        self._publish_finish(req, reason)
+        return True
+
+    def _accept(self, req: Request, token: int,
+                kind: int = _OWED_TOKEN) -> bool:
+        """The unified tick's accept of one sampled token of a RUNNING
+        row: into ``req.generated``, finish decided, and the token (then
+        the terminal) owed to the outside.  True when the request
+        finished on it."""
+        self._accept_token(req, token)
+        self._owed.append((kind, req, token))
+        reason = self._accept_finish(req)
+        if reason is None:
+            return False
+        self._owed.append((_OWED_FINISH, req, reason))
+        return True
+
+    def _publish(self, overlapped: bool, limit: int | None = None) -> int:
+        """Hand out what ``accept`` owes, in emit order: per request
+        every token once, then its terminal.  ``overlapped`` says
+        whether a dispatch is in flight (the tick's ``deliver`` phase
+        behind its dispatch) or the list is drained on the spot (no
+        dispatch follows).  ``limit``: the first so many items alone
+        (an aborted request's, ``_settle_owed``).  The journal's
+        delivery watermark moves HERE, after the callbacks, so it never
+        runs ahead of what they were handed.  A callback may abort any
+        request: ``abort`` then drops that request's remaining items.
+        Returns the number of items handed out."""
+        owed = self._owed
+        if not owed or self._publishing:
+            return 0
+        whole = limit is None
+        if whole:
+            limit = len(owed)
+        self._publishing = True
+        n = 0
+        try:
+            while owed and n < limit:
+                kind, req, val = owed.popleft()
+                n += 1
+                if kind == _OWED_FINISH:
+                    self._publish_finish(req, val)
+                    continue
+                self._publish_token(req, val)
+                if (kind == _OWED_FIRST and self.tracer is not None
+                        and req.state is RequestState.RUNNING):
+                    # (not finished on it, not aborted from its callback,
+                    # not preempted back into the queue since)
+                    self.tracer.request_phase(req.req_id, "decode")
+        finally:
+            self._publishing = False
+        if not whole:
+            return n  # its abort writes the watermark with the terminal
+        self.metrics.on_publish(overlapped)
+        if self.journal is not None:
+            # ONE delivery-watermark record for the whole tick (rows for
+            # every live request whose count advanced) — batched per
+            # tick, never per token.  The list is empty, so every live
+            # request's ``generated`` is what its callback was handed; a
+            # verify round's rows carry every ACCEPTED token: rejected
+            # drafts never reach req.generated.  (Finished and aborted
+            # requests wrote theirs with their terminal.)
+            self.journal.end_tick(self._requests.values())
+        return n
+
+    def publish_owed(self) -> int:
+        """Hand out what the last tick still owes, on the spot — for a
+        caller that reads requests' ``generated`` between ticks as what
+        was delivered (a direct-mode drain or restart, serve/replica)."""
+        return self._publish(False)
+
+    def _settle_owed(self, req: Request) -> None:
+        """What an aborted request is still owed, settled before its
+        ``aborted`` event.  Between ticks the tokens go out first, as
+        they would have by now on a tick that publishes at once.  From
+        inside a token callback (a publish is running) they are dropped
+        and taken back out of ``req.generated``: the ``aborted`` event
+        follows the token whose callback asked for it.  Either way
+        ``req.generated`` ends as exactly what the callback was
+        handed."""
+        owed = self._owed
+        mine = [item for item in owed if item[1] is req]
+        if not mine:
+            return
+        rest = [item for item in owed if item[1] is not req]
+        owed.clear()
+        if self._publishing:
+            owed.extend(rest)
+            del req.generated[-len(mine):]
+        else:
+            owed.extend(mine + rest)
+            self._publish(False, limit=len(mine))
 
     def abort(self, request_id: int) -> bool:
         """Cancel a live request — queued, prefilled, or mid-decode.
@@ -2896,6 +3028,14 @@ class ServeEngine:
         no-op, not an error (the HTTP layer aborts on every client
         disconnect, including disconnects after [DONE]).
 
+        Tokens of the request that the unified tick accepted and has not
+        published yet are settled first (``_settle_owed``): handed out
+        when the abort comes between ticks, dropped when it comes from
+        inside a token callback — ``req.generated`` holds exactly what
+        the callback was handed, and the ``aborted`` event follows it.
+        A request that already finished at ``accept`` is terminal: its
+        owed tokens and its own terminal still go out.
+
         NOT thread-safe, like every other engine entry point: callers
         off the tick thread go through the HTTP runner's command queue.
         """
@@ -2903,6 +3043,7 @@ class ServeEngine:
         if req is None:
             return False
         self._draft_states.pop(request_id, None)
+        self._settle_owed(req)
         self.scheduler.abort(req)
         req.finish_reason = "aborted"
         req.finish_time = self.clock()
@@ -2920,6 +3061,9 @@ class ServeEngine:
             self.tracer.request_end(req.req_id, "aborted",
                                     args=self._targs(req))
         self._emit_event(req, "aborted")
+        if not self.scheduler.has_work:
+            # no tick follows: what other requests are owed goes out now
+            self._publish(False)
         return True
 
     def _sweep_deadlines(self) -> None:
@@ -3074,7 +3218,16 @@ class ServeEngine:
         """One scheduler tick; returns True while work remains.  Unified
         engines (``mixed_step``) run the single-dispatch mixed tick,
         phase-split engines the admission→prefill→grow→decode pipeline
-        below."""
+        below.
+
+        The unified tick's contract with callbacks: the tokens and
+        terminals a tick accepted reach ``callback`` / ``on_event``
+        during the NEXT ``step()`` (behind its dispatch), per request in
+        order and exactly once — or before this ``step()`` returns when
+        it returns False.  While it returns True, ``req.generated`` may
+        be one tick ahead of what the callbacks were handed
+        (``publish_owed`` closes the gap); inside a token callback it
+        may hold the rest of that tick's tokens already."""
         if self.mixed:
             return self._step_mixed()
         return self._step_split()
@@ -3426,7 +3579,7 @@ class ServeEngine:
     def _finish_mixed_prefill(self, req: Request, tok: int) -> None:
         """A row's prefill reached its target this tick: register its
         prompt blocks with the prefix cache (they are already IN the
-        pool — direct writes, nothing to copy) and emit the first
+        pool — direct writes, nothing to copy) and accept the first
         token sampled by the same dispatch."""
         req.prefilled = True
         req.extra.pop("prefill_content", None)
@@ -3438,9 +3591,7 @@ class ServeEngine:
             self.metrics.on_prefix(
                 requested=len(keys), hits=req.n_shared_blocks
             )
-        self._emit(req, tok)
-        if not self._maybe_finish(req) and self.tracer is not None:
-            self.tracer.request_phase(req.req_id, "decode")
+        self._accept(req, tok, _OWED_FIRST)
 
     def _draft_tick(self) -> int:
         """Propose draft tokens for every speculating decode row —
@@ -3513,10 +3664,16 @@ class ServeEngine:
         """One unified tick: deadline sweep + admission, draft proposal,
         block growth, token-budget planning, then ONE mixed ragged
         dispatch covering every planned prefill chunk slice, plain
-        decode row, and speculative verify slice.  Phase slices
+        decode row, and speculative verify slice.  The tokens of the
+        PREVIOUS tick go out (``deliver``: ``_publish``) while this
+        tick's program runs, between the dispatch and the fetch; after
+        the fetch only ``accept`` stays on the device's critical path —
+        what the next plan reads.  A tick that dispatches nothing, and a
+        tick after which no work is left, hands out what is owed on the
+        spot: a token never waits across an idle engine.  Phase slices
         (``admission`` / ``draft`` / ``grow`` / ``plan`` / ``pack`` /
-        ``h2d`` / ``mixed_dispatch`` / ``host_sync`` / ``deliver`` /
-        ``account``, serve/tracing.MIXED_TICK_PHASES) keep the
+        ``h2d`` / ``mixed_dispatch`` / ``deliver`` / ``host_sync`` /
+        ``accept`` / ``account``, serve/tracing.MIXED_TICK_PHASES) keep the
         consecutive-timestamps sum-to-tick invariant, and each runs
         under a ``serve.<phase>`` profiler annotation (``_phase_mark``)
         so a device profile holds the host's phases on its own clock;
@@ -3580,7 +3737,7 @@ class ServeEngine:
         t3 = (self._phase_mark("serve.pack")
               if self.tracer is not None else -1.0)
 
-        tp = th = t4 = t5 = t3
+        tp = th = t4 = t3
         cpu4 = cpu5 = 0
         ctx_tokens = array_rows = h2d_count = h2d_bytes = 0
         attn_pages = attn_grid_steps = attn_step_pages = 0
@@ -3593,7 +3750,10 @@ class ServeEngine:
         tel = None
         cost = None
         expert_load = None
-        if decode_rows or prefill_segs:
+        td0 = 0.0
+        out = None
+        dispatched = bool(decode_rows or prefill_segs)
+        if dispatched:
             if self.telemetry is not None:
                 # the analytic byte/FLOP bill MUST run before the
                 # accept walk below — verify lanes live in draft_len
@@ -3629,8 +3789,18 @@ class ServeEngine:
                 out, self.pool.pages = self._dispatch_mixed(
                     ops, bool(prefill_segs)
                 )
-            t4 = (self._phase_mark("serve.host_sync")
+            t4 = (self._phase_mark("serve.deliver")
                   if self.tracer is not None else -1.0)
+        # the PREVIOUS tick's tokens and terminals go out here: behind
+        # this tick's dispatch — the program is queued, the device is
+        # busy, and the interpreter is the event loop's while this
+        # thread blocks in the fetch below — or on the spot when nothing
+        # was dispatched (then the dispatch phases and host_sync are
+        # empty, and deliver runs from the plan's end)
+        publish_rows = self._publish(dispatched)
+        tpub = t5 = (self._phase_mark("serve.host_sync")
+                     if self.tracer is not None else -1.0)
+        if dispatched:
             cpu4 = time.thread_time_ns() if self.tracer is not None else 0
             if self.faults is not None:
                 # injected host_sync regression (the split tick's twin
@@ -3657,10 +3827,10 @@ class ServeEngine:
             nxt_host = out_host[:, : self._spec_w]
             accept_host = out_host[:, self._spec_w + 2]
             cpu5 = time.thread_time_ns() if self.tracer is not None else 0
-            t5 = (self._phase_mark("serve.deliver")
+            t5 = (self._phase_mark("serve.accept")
                   if self.tracer is not None else -1.0)
             if cost is not None and self.telemetry is not None:
-                # attribution lands BEFORE the deliver walks so a
+                # attribution lands BEFORE the accept walks so a
                 # finishing request's canonical log line carries its
                 # final tick's cost
                 tel = self.telemetry.finish(cost, self.clock() - td0)
@@ -3679,14 +3849,25 @@ class ServeEngine:
                     # breakeven: a MEASURED prefill token rate, refined
                     # every dispatching tick
                     self.host_tier.note_prefill_rate(1.0 / per_tok)
+            # accept: only what the NEXT plan reads (``_accept``).  A row
+            # whose request was aborted since the dispatch — from a
+            # callback of the publish above — is skipped whole: its K/V
+            # writes went to blocks no later program reads before they
+            # are rewritten, like a rejected draft's.
             for r, n in prefill_segs:
+                if r.state is not RequestState.RUNNING:
+                    continue
                 r.prefill_done += n
                 if r.prefill_done >= r.prefill_target:
                     self._finish_mixed_prefill(r, int(nxt_host[r.slot, 0]))
-            for r in decode_rows:
+            # the token column, read once for all decode rows
+            first = nxt_host[[r.slot for r in decode_rows], 0].tolist()
+            for r, tok in zip(decode_rows, first):
+                if r.state is not RequestState.RUNNING:
+                    r.draft_len = 0
+                    continue
                 if not r.draft_len:
-                    self._emit(r, int(nxt_host[r.slot, 0]))
-                    self._maybe_finish(r)
+                    self._accept(r, tok)
                     continue
                 # the accept walk: the verifier sampled every position
                 # of this row's slice with the SAME (seed, content-pos)
@@ -3701,25 +3882,22 @@ class ServeEngine:
                 # cache_len and are overwritten before ever attended.
                 r.extra.pop("spec_draft")
                 n_match = int(accept_host[r.slot])
-                w = 1 + r.draft_len
                 acc = 0
-                for j in range(w):
-                    tok = int(nxt_host[r.slot, j])
-                    self._emit(r, tok)
-                    if j < n_match:
-                        # the draft paid off even when this token ENDS
-                        # the stream (a drafted stop token) — count it
-                        # before the finish check, or accepted/rejected
-                        # systematically misreport on short extractive
-                        # completions
-                        acc += 1
-                        if self._maybe_finish(r):
-                            break  # stop token / budget (abort included)
-                    else:
+                for j, tok in enumerate(
+                        nxt_host[r.slot, : 1 + r.draft_len].tolist()):
+                    done = self._accept(r, tok)
+                    if j >= n_match:
                         # the correction or the bonus slot — the round
                         # is over either way
-                        self._maybe_finish(r)
                         break
+                    # the draft paid off even when this token ENDS the
+                    # stream (a drafted stop token) — count it before
+                    # the finish check, or accepted/rejected
+                    # systematically misreport on short extractive
+                    # completions
+                    acc += 1
+                    if done:
+                        break  # stop token / budget
                 n_spec_acc += acc
                 drafted = r.draft_len
                 r.draft_len = 0
@@ -3727,12 +3905,10 @@ class ServeEngine:
 
         t6 = (self._phase_mark("serve.account")
               if self.tracer is not None else -1.0)
-        if self.journal is not None:
-            # same per-tick watermark batching as the split tick; a
-            # verify round's rows carry every ACCEPTED token this tick
-            # delivered — rejected drafts never reach req.generated, so
-            # they never reach the journal and replay stays exact
-            self.journal.end_tick(self._requests.values())
+        drained_rows = 0
+        if not self.scheduler.has_work:
+            # no tick follows this one: what it accepted goes out now
+            drained_rows = self._publish(False)
         if self.host_tier is not None and (
             self._tier_spill_bytes or self._tier_restore_bytes
         ):
@@ -3812,8 +3988,16 @@ class ServeEngine:
                 # number of device→host transfers this tick — the
                 # one-fetch contract says the latter is exactly 1 on
                 # dispatching ticks (bench + tests pin it)
-                "host_sync_us": round(max(t5 - t4, 0.0), 1),
+                "host_sync_us": round(max(t5 - tpub, 0.0), 1),
                 "host_fetches": self.n_host_fetches - fetches0,
+                # the publish of the previous tick's tokens: the items
+                # handed out in ``deliver``, and whether a dispatch was
+                # in flight meanwhile (1) or they went out on the spot
+                # (0); ``publish_drained_rows`` is this tick's own items,
+                # handed out at its end because no tick follows
+                "publish_rows": publish_rows,
+                "publish_overlapped": int(dispatched and publish_rows > 0),
+                "publish_drained_rows": drained_rows,
                 # the tick thread's own CPU time outside host_sync:
                 # tick - host_sync - this = time it neither computed
                 # nor waited for the device (the GIL, a blocking put)
@@ -3846,8 +4030,8 @@ class ServeEngine:
                 ("grow", td, t2), ("plan", t2, t3),
                 ("pack", t3, tp),
                 ("h2d", tp, th, {"count": h2d_count, "bytes": h2d_bytes}),
-                ("mixed_dispatch", th, t4),
-                ("host_sync", t4, t5), ("deliver", t5, t6),
+                ("mixed_dispatch", th, t4), ("deliver", t4, tpub),
+                ("host_sync", tpub, t5), ("accept", t5, t6),
                 ("account", t6, t7),
             ), args=targs)
             if self.sentinel is not None:
@@ -3860,15 +4044,16 @@ class ServeEngine:
                     ("admission", t0, t1), ("draft", t1, td),
                     ("grow", td, t2), ("plan", t2, t3),
                     ("pack", t3, tp), ("h2d", tp, th),
-                    ("mixed_dispatch", th, t4),
-                    ("host_sync", t4, t5), ("deliver", t5, t6),
+                    ("mixed_dispatch", th, t4), ("deliver", t4, tpub),
+                    ("host_sync", tpub, t5), ("accept", t5, t6),
                     ("account", t6, t7),
                 ) + (
                     (("roofline_deficit", 0.0, tel["deficit_us"]),)
                     if tel is not None else ()
                 ))
         self._actions_tick(outliers)
-        return self.scheduler.has_work
+        # an owed list is work: the next tick hands it out
+        return self.scheduler.has_work or bool(self._owed)
 
     def _dispatch_mixed(self, ops: jnp.ndarray, has_prefill: bool) -> tuple:
         """One mixed dispatch with the split path's runtime-degradation

@@ -179,6 +179,11 @@ class ServeMetrics:
         # dispatched at, summed over dispatches: tokens / lanes is the
         # share of the matmuls' rows that hold a token
         self.mixed_dense_lanes = 0
+        # the unified tick hands tick N's tokens out behind tick N+1's
+        # dispatch: publishes that ran with a dispatch in flight, and
+        # publishes made on the spot (no dispatch followed)
+        self.publish_overlapped = 0
+        self.publish_immediate = 0
         # dropless expert layers (exact counters, one observation a
         # dispatching tick): experts that got at least one token, summed
         # over the expert layers; and of the worst layer the most tokens
@@ -272,6 +277,15 @@ class ServeMetrics:
             for vals in (self.queue_depth, self.occupancy,
                          self.active_slots, self.kv_bytes_tick):
                 self._trim(vals)
+
+    def on_publish(self, overlapped: bool) -> None:
+        """The unified tick handed out a tick's tokens: behind the next
+        dispatch (``overlapped``) or on the spot."""
+        with self._lock:
+            if overlapped:
+                self.publish_overlapped += 1
+            else:
+                self.publish_immediate += 1
 
     def on_anomaly(self, phase: str) -> None:
         """The tick sentinel named ``phase`` as an outlier this tick."""
@@ -493,6 +507,8 @@ class ServeMetrics:
             out["mixed_prefill_tokens"] = self.mixed_prefill_tokens
             out["mixed_decode_tokens"] = self.mixed_decode_tokens
             out["mixed_dense_lanes"] = self.mixed_dense_lanes
+            out["publish_overlapped_ticks"] = self.publish_overlapped
+            out["publish_immediate_ticks"] = self.publish_immediate
             if self.moe_ticks:
                 # only where an expert layer ran (like the spec block)
                 out["moe_ticks"] = self.moe_ticks
@@ -689,6 +705,14 @@ class ServeMetrics:
              "dispatches (mixed_tokens_total / this = the share of "
              "lanes that hold a token)",
              [("", s["mixed_dense_lanes"])])
+        emit("publish_overlapped_ticks_total", "counter",
+             "Unified ticks whose tokens were handed out behind the next "
+             "tick's dispatch (off the device's critical path)",
+             [("", s["publish_overlapped_ticks"])])
+        emit("publish_immediate_ticks_total", "counter",
+             "Unified ticks whose tokens were handed out on the spot "
+             "(no dispatch followed)",
+             [("", s["publish_immediate_ticks"])])
         if "moe_ticks" in s:
             emit("moe_ticks_total", "counter",
                  "Dispatching ticks that ran dropless expert layers",

@@ -398,11 +398,22 @@ class ReplicaSet:
         """Simulate one replica's death: mark it dead (the router stops
         naming it; its sticky prefixes re-home) and return its in-flight
         requests — what a supervisor would replay.  The dead engine is
-        left untouched for inspection, exactly like a hung tick thread's
-        engine object."""
+        left for inspection like a hung tick thread's engine object,
+        after what its last tick owed went out (``_inflight_settled``)."""
         self.alive[idx] = False
         self.router.forget_replica(idx)
-        return list(self.engines[idx]._requests.values())
+        return self._inflight_settled(self.engines[idx])
+
+    @staticmethod
+    def _inflight_settled(engine: Any) -> list[Request]:
+        """An engine's in-flight requests with ``generated`` equal to
+        what their callbacks were handed.  In direct mode a request's
+        ``generated`` IS the replay ledger (the HTTP runner keeps its
+        own), so what the unified tick accepted and still owes goes out
+        first — a request that finished at accept gets its terminal and
+        is no longer in flight."""
+        engine.publish_owed()
+        return sorted(engine._requests.values(), key=lambda r: r.req_id)
 
     def restart_replica(self, idx: int) -> None:
         """Supervised-restart discipline, driven synchronously: rebuild
@@ -468,10 +479,7 @@ class ReplicaSet:
         # replays it twice.  Same rule as the HTTP fleet's _drain_dead.
         src_journal = getattr(self.engines[idx], "journal", None)
         drained: list[int] = []
-        inflight = sorted(
-            self.engines[idx]._requests.values(), key=lambda r: r.req_id
-        )
-        for req in inflight:
+        for req in self._inflight_settled(self.engines[idx]):
             key, _ = self.router.affinity_chain(req.prompt)
             peer, _ = self.router.route(
                 key, loads=self._loads(),
@@ -547,8 +555,7 @@ class ReplicaSet:
         version either way)."""
         stops = tuple(old.stop_tokens or ())
         n = 0
-        for req in sorted(old._requests.values(),
-                          key=lambda r: r.req_id):
+        for req in self._inflight_settled(old):
             lineage = {
                 "replays": int(req.extra.get("replays", 0)) + 1,
                 "drains": int(req.extra.get("drains", 0)),

@@ -96,11 +96,17 @@ TICK_PHASES = (
 # ``pack`` builds the one numpy operand, ``h2d`` places it (its slice
 # carries the count and bytes of the transfer), ``mixed_dispatch`` is
 # the jitted call alone (what the ``serve.mixed_dispatch`` annotation
-# wraps), ``deliver`` the emit / accept walks with their callbacks,
-# ``account`` the journal watermark and the metrics of the tick.
+# wraps), ``deliver`` the publish of the PREVIOUS tick's tokens (their
+# callbacks, metrics, request log, the journal's watermark) while this
+# tick's program runs — on the spot in a tick that dispatches nothing —,
+# ``host_sync`` the one fetch, ``accept`` what the next plan reads of it
+# (tokens into the requests, finish decided, slots and blocks released),
+# ``account`` the metrics of the tick.  Tick args ``publish_rows`` /
+# ``publish_overlapped`` say what ``deliver`` handed out and whether a
+# dispatch was in flight.
 MIXED_TICK_PHASES = (
     "admission", "draft", "grow", "plan", "pack", "h2d", "mixed_dispatch",
-    "host_sync", "deliver", "account",
+    "deliver", "host_sync", "accept", "account",
 )
 # jax.monitoring duration events the compile watcher reads
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

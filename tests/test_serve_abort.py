@@ -321,3 +321,74 @@ def test_metrics_concurrent_scrape_is_consistent(tiny):
     assert not failures, failures[:3]
     snap = engine.metrics.snapshot()
     assert snap["finished"] == 12
+
+
+# ---------------------------------------------------------------------------
+# Aborts against the unified tick's owed list (PR 35): tick N's tokens are
+# published behind tick N+1's dispatch, so an abort can find tokens the
+# request's callback has not been handed yet
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("where", ["between-ticks", "from-the-callback",
+                                   "from-a-peers-callback"])
+def test_abort_leaves_generated_equal_to_what_the_callback_was_handed(
+        tiny, where):
+    """Between ticks the owed tokens go out before the ``aborted`` event
+    (as a tick that publishes at once would have handed them out by
+    then); from inside a token callback they are dropped.  Either way the
+    terminal follows the last token the callback saw, ``req.generated``
+    is exactly what it was handed — a prefix of the offline stream —,
+    the row's in-flight result is skipped, the pool is whole and the
+    peer's stream is untouched."""
+    cfg, params = tiny
+    engine = _engine(cfg, params, max_slots=2, mixed_step="on")
+    rng = np.random.default_rng(35)
+    log: dict[int, list] = {0: [], 1: []}
+
+    def on_event(req, event):
+        log[req.req_id].append(event)
+
+    def cb(req, tok, delta):
+        log[req.req_id].append(tok)
+        n = sum(1 for t in log[req.req_id] if not isinstance(t, str))
+        if where == "from-the-callback" and req.req_id == 1 and n == 3:
+            engine.abort(1)
+        if where == "from-a-peers-callback" and req.req_id == 0 and n == 3:
+            engine.abort(1)
+
+    keep = engine.submit(rng.integers(1, cfg.vocab_size, size=5), 12,
+                         request_id=0, callback=cb, on_event=on_event)
+    kill = engine.submit(rng.integers(1, cfg.vocab_size, size=9), 12,
+                         request_id=1, callback=cb, on_event=on_event)
+    if where == "between-ticks":
+        for _ in range(4):
+            assert engine.step()
+        owed = sum(1 for item in engine._owed if item[1] is kill)
+        assert owed == 1 and len(kill.generated) == len(log[1]) + 1
+        accepted = list(kill.generated)
+        assert engine.abort(1)
+        # tick 4's token went out first, then the terminal
+        assert log[1] == accepted + ["aborted"]
+        assert not any(item[1] is kill for item in engine._owed)
+        # the peer's owed token stays owed: a tick follows
+        assert any(item[1] is keep for item in engine._owed)
+    engine.run_until_complete()
+    assert kill.finish_reason == "aborted" and log[1][-1] == "aborted"
+    assert log[1][:-1] == kill.generated
+    assert kill.generated == _offline(cfg, params, kill)[:len(kill.generated)]
+    if where == "between-ticks":
+        assert kill.generated == accepted and len(accepted) > 1
+    elif where == "from-the-callback":
+        assert len(kill.generated) == 3
+    else:
+        # the peer's third token is published BEFORE this request's token
+        # of the same tick (decode rows publish in row order): that one
+        # was owed and is dropped
+        assert 0 < len(kill.generated) < 3
+    assert log[0][-1] == "length" and log[0][:-1] == keep.generated
+    assert keep.generated == _offline(cfg, params, keep)
+    assert engine.pool.stats()["request_held"] == 0 and not engine._owed
+    snap = engine.metrics.snapshot()
+    assert snap["aborted"] == 1 and snap["finished"] == 1
+    # metrics count what was handed out, nothing dropped
+    assert snap["total_generated_tokens"] == 12 + len(kill.generated)

@@ -456,6 +456,62 @@ def test_chaos_e2e_16_streams_crash_kernel_fault_and_429s(tiny):
 
 
 @pytest.mark.http
+@pytest.mark.parametrize("spec_k", [0, 3], ids=["plain", "spec3"])
+def test_tick_crash_between_accept_and_publish_replays_identically(
+        tiny, spec_k):
+    """The ``tick_crash`` site fires between two ticks: the dead engine
+    had ACCEPTED its last tick's tokens and published none of them.
+    They were never handed out, so they are in no replay ledger; the
+    rebuilt engine regenerates them with the same (seed, position) keys
+    and every client reads one whole stream, equal to the uninterrupted
+    run's, each token once."""
+    cfg, params = tiny
+    inj = FaultInjector("tick_crash@5")
+    engine = _engine(cfg, params, max_slots=4, num_blocks=64, spec_k=spec_k,
+                     fault_injector=inj)
+    engine.warmup([12], max_new_tokens=10)
+    rng = np.random.default_rng(35)
+    reqs = [(np.resize(rng.integers(1, cfg.vocab_size, size=3), n).tolist(),
+             10) for n in (7, 12, 9)]
+    lost: list[int] = []
+    clone = type(engine).clone_fresh
+
+    def counting_clone(self, **kw):
+        # what the dying engine still owed when the supervisor rebuilt it
+        lost.append(sum(1 for kind, _, _ in self._owed if kind != 2))
+        return clone(self, **kw)
+
+    async def main():
+        srv = HttpServer(engine, model_id="tiny", drain_timeout=30.0,
+                         tick_deadline=5.0, max_restarts=2,
+                         restart_backoff_s=0.05)
+        await srv.start("127.0.0.1", 0)
+        results = await asyncio.gather(*[
+            astream_completion(
+                srv.host, srv.port,
+                {"prompt": p, "max_tokens": m, "stream": True,
+                 "speculative": bool(spec_k)}, timeout=120)
+            for p, m in reqs])
+        srv.begin_drain()
+        await asyncio.wait_for(srv.serve_until_shutdown(), timeout=60)
+        return srv, results
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(type(engine), "clone_fresh", counting_clone)
+        srv, results = asyncio.run(asyncio.wait_for(main(), timeout=300))
+    assert srv.runner.restarts == 1 and inj.injected["tick_crash"] == 1
+    assert lost and lost[0] >= 1, "the crash fell on an engine owing nothing"
+    for (p, m), res in zip(reqs, results):
+        assert res["status"] == 200 and res["finish_reason"] == "length"
+        assert res["token_ids"] == _offline(cfg, params, p, m)
+    live = srv.runner.engine
+    assert live is not engine and not live._owed
+    assert live.pool.stats()["request_held"] == 0
+    snap = live.metrics.snapshot()
+    assert snap["finished"] == 3 and snap["recovered"] >= 1
+
+
+@pytest.mark.http
 def test_http_reset_site_aborts_stream_and_client_survives(tiny):
     """The http_reset site: a mid-stream RST aborts the request
     server-side (blocks decref) and the client sees a connection error,

@@ -568,3 +568,106 @@ def test_http_e2e_concurrent_streams_abort_deadline_sigterm_drain(tiny):
     assert snap["aborted"] == 2
     assert snap["finish_reasons"]["aborted"] == 2
     assert snap["finish_reasons"]["length"] == 8
+    # the SIGTERM drain left nothing owed: every accepted token and every
+    # terminal was handed to its stream before the server went away, and
+    # the ticks hid their publish behind the next dispatch meanwhile
+    assert not engine._owed
+    assert snap["publish_overlapped_ticks"] > snap["publish_immediate_ticks"]
+
+
+# ---------------------------------------------------------------------------
+# The replay ledger against the deferred publish (PR 35): the tick hands
+# tick N's tokens to the bridge behind tick N+1's dispatch; whatever cuts
+# a stream short, the ledger holds exactly what the bridge pushed at the
+# client, and the terminal is the last thing pushed
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("where", ["command-queue", "token-callback"])
+def test_abort_leaves_the_replay_ledger_equal_to_what_the_client_was_sent(
+        tiny, where):
+    cfg, params = tiny
+    engine = _engine(cfg, params)
+    prompt, peer = [8] * 9, [5] * 6
+
+    async def main():
+        srv = HttpServer(engine, model_id="tiny", drain_timeout=10.0)
+        runner = srv.runner
+        pushed: dict[int, list] = {}
+        ledgers: dict[int, dict] = {}
+        push, stash, claim = (runner._push, runner._stash_resumable,
+                              runner._claim_insert)
+
+        def spy_push(rid, item):
+            if item[0] in ("token", "finish"):
+                pushed.setdefault(rid, []).append(item[1])
+            push(rid, item)
+
+        def spy_stash(rid, rec, reason, tail):
+            ledgers[rid] = dict(tokens=list(rec["tokens"]), reason=reason)
+            stash(rid, rec, reason, tail)
+
+        def spy_claim(rid, fin):
+            ledgers[rid] = dict(tokens=list(fin["tokens"]),
+                                reason=fin["reason"])
+            claim(rid, fin)
+
+        runner._push, runner._stash_resumable, runner._claim_insert = (
+            spy_push, spy_stash, spy_claim)
+        if where == "token-callback":
+            bridge = runner._bridge
+
+            def aborting_bridge(gen):
+                cb, on_event = bridge(gen)
+                seen: dict[int, int] = {}
+
+                def cb_then_abort(req, tok, delta):
+                    cb(req, tok, delta)
+                    seen[req.req_id] = seen.get(req.req_id, 0) + 1
+                    if len(req.prompt) == len(prompt) and seen[req.req_id] == 3:
+                        engine.abort(req.req_id)
+
+                return cb_then_abort, on_event
+
+            runner._bridge = aborting_bridge
+        await srv.start("127.0.0.1", 0)
+        cut, whole = await asyncio.gather(
+            astream_completion(
+                srv.host, srv.port,
+                {"prompt": prompt, "max_tokens": 40, "stream": True},
+                disconnect_after=2 if where == "command-queue" else None),
+            astream_completion(
+                srv.host, srv.port,
+                {"prompt": peer, "max_tokens": 12, "stream": True}))
+        deadline = time.time() + 20
+        while time.time() < deadline and len(ledgers) < 2:
+            await asyncio.sleep(0.02)
+        srv.begin_drain()
+        await srv.serve_until_shutdown()
+        return cut, whole, pushed, ledgers
+
+    cut, whole, pushed, ledgers = asyncio.run(
+        asyncio.wait_for(main(), timeout=120))
+    assert whole["finish_reason"] == "length"
+    assert whole["token_ids"] == _offline_tokens(cfg, params, peer, 12)
+    (rid_cut,) = [r for r, l in ledgers.items() if l["reason"] == "aborted"]
+    (rid_whole,) = [r for r in ledgers if r != rid_cut]
+    for rid in (rid_cut, rid_whole):
+        # the terminal is the last thing pushed, after every token; the
+        # ledger is the tokens pushed, no more and no fewer
+        assert pushed[rid][-1] == ledgers[rid]["reason"]
+        assert pushed[rid][:-1] == ledgers[rid]["tokens"]
+    want = _offline_tokens(cfg, params, prompt, 40)
+    sent = ledgers[rid_cut]["tokens"]
+    assert sent == want[:len(sent)] and 0 < len(sent) < 40
+    if where == "token-callback":
+        assert cut["finish_reason"] == "aborted"
+        assert cut["token_ids"] == sent and len(sent) == 3
+    else:
+        assert cut["finish_reason"] == "disconnected"
+        assert cut["token_ids"] == sent[:2]
+    assert ledgers[rid_whole]["tokens"] == whole["token_ids"]
+    assert not engine._owed and not engine.scheduler.has_work
+    assert engine.pool.stats()["request_held"] == 0
+    snap = engine.metrics.snapshot()
+    assert snap["aborted"] == 1 and snap["finished"] == 1
+    assert snap["total_generated_tokens"] == 12 + len(sent)

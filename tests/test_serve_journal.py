@@ -277,13 +277,23 @@ def test_mid_flight_state_replays_token_identical(tiny, tmp_path):
     engine = _engine(cfg, params, journal=j, max_slots=2)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (6, 11, 17)]
-    reqs = [engine.submit(p, 8, seed=i) for i, p in enumerate(prompts)]
+    partial: dict[int, list[int]] = {i: [] for i in range(len(prompts))}
+    reqs = [engine.submit(
+        p, 8, seed=i,
+        callback=lambda rq, tok, _d: partial[rq.req_id].append(tok))
+        for i, p in enumerate(prompts)]
     for _ in range(4):
         engine.step()
-    partial = {r.req_id: list(r.generated) for r in reqs}
+    # the death falls BETWEEN a tick's accept and its publish: the
+    # engine holds tokens no callback was handed (they are lost with it)
     assert any(partial.values()), "mid-flight please"
+    assert engine._owed and any(
+        len(r.generated) > len(partial[r.req_id]) for r in reqs)
     assert j.flush(5.0)
     j.close()  # simulated process death: unterminated state on disk
+    # the journal's watermark is never ahead of what was published
+    state, _, _ = scan_journal(path)
+    assert {rid: rec["tokens"] for rid, rec in state.items()} == partial
 
     j2 = RequestJournal(path)
     engine2 = _engine(cfg, params, journal=j2, max_slots=2)
@@ -309,6 +319,46 @@ def test_mid_flight_state_replays_token_identical(tiny, tmp_path):
     j2.close()
 
 
+@pytest.mark.parametrize("spec_k", [0, 3], ids=["plain", "spec3"])
+def test_watermark_is_never_ahead_of_the_published_tokens(tiny, tmp_path,
+                                                          spec_k):
+    """The journal's delivery watermark moves with the publish, not with
+    the accept: after EVERY tick the journaled tokens of a live request
+    are exactly what its callback was handed — while ``req.generated``
+    may already hold the tick's accepted tokens — so a kill at any tick
+    boundary replays from what the client has, never from more."""
+    cfg, params = tiny
+    path = str(tmp_path / "j")
+    j = RequestJournal(path)
+    engine = _engine(cfg, params, journal=j, max_slots=2, spec_k=spec_k)
+    rng = np.random.default_rng(4)
+    prompts = [np.resize(rng.integers(1, cfg.vocab_size, size=3), n)
+               for n in (6, 11, 9)]
+    sent: dict[int, list[int]] = {i: [] for i in range(len(prompts))}
+    reqs = [engine.submit(
+        p, 8, seed=i, speculative=bool(spec_k),
+        callback=lambda rq, tok, _d: sent[rq.req_id].append(tok))
+        for i, p in enumerate(prompts)]
+    ahead = 0
+    for _ in range(200):
+        more = engine.step()
+        assert j.flush(5.0)
+        state, _, _ = scan_journal(path)
+        for r in reqs:
+            assert r.generated[:len(sent[r.req_id])] == sent[r.req_id]
+            if r.req_id in state:  # live: journaled == handed out
+                assert state[r.req_id]["tokens"] == sent[r.req_id]
+            ahead += len(r.generated) > len(sent[r.req_id])
+        if not more:
+            break
+    assert ahead, "no tick ever owed a token: the publish was not deferred"
+    assert not engine._owed and all(sent[r.req_id] == r.generated
+                                    for r in reqs)
+    state, _, _ = scan_journal(path)
+    assert state == {}  # every terminal written, after its last token
+    j.close()
+
+
 # ---------------------------------------------------------------------------
 # HTTP resume protocol (in-process)
 # ---------------------------------------------------------------------------
@@ -327,10 +377,15 @@ def test_http_resume_replays_suffix_then_live(tiny, tmp_path):
     j = RequestJournal(path)
     engine = _engine(cfg, params, journal=j, max_slots=2)
     prompts = [[5] * 6, [7, 3, 9, 2, 8], [11] * 9]
-    reqs = [engine.submit(p, 8, seed=i) for i, p in enumerate(prompts)]
+    # what the dead process had SENT (its last tick's accepted tokens
+    # were never published: they are not the client's, nor the journal's)
+    partial: dict[int, list[int]] = {i: [] for i in range(len(prompts))}
+    reqs = [engine.submit(
+        p, 8, seed=i,
+        callback=lambda rq, tok, _d: partial[rq.req_id].append(tok))
+        for i, p in enumerate(prompts)]
     for _ in range(4):
         engine.step()
-    partial = {r.req_id: list(r.generated) for r in reqs}
     assert j.flush(5.0)
     j.close()  # kill -9 analogue
 

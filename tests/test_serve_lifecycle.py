@@ -580,6 +580,53 @@ def test_elastic_scale_down_under_load(tiny):
     assert snap["finished"] == 13
 
 
+@pytest.mark.parametrize("how", ["remove", "roll", "kill-restart"])
+def test_direct_mode_moves_hand_every_token_to_the_callback_once(tiny, how):
+    """In direct mode ``req.generated`` is the replay ledger, and the
+    unified tick holds a tick's accepted tokens until the next dispatch:
+    a drain, a roll or a restart first hands out what the replica still
+    owes (``publish_owed``), so the adopting engine — which teacher-
+    forces ``generated`` and re-emits none of it — starts exactly where
+    the callbacks stopped.  Every stream reaches its callback whole,
+    each token once, in order, the terminal last."""
+    cfg, params = tiny
+    rng = np.random.default_rng(35)
+    prompts = [rng.integers(1, cfg.vocab_size, size=int(rng.integers(4, 14)))
+               for _ in range(8)]
+    control = ReplicaSet([_engine(cfg, params) for _ in range(2)])
+    for i, p in enumerate(prompts):
+        control.submit(p, 7, seed=i)
+    control.run_until_complete()
+    want = {r.req_id: list(r.generated) for r in control.finished}
+
+    fleet = ReplicaSet([_engine(cfg, params) for _ in range(2)])
+    log: dict[int, list] = {}
+    for i, p in enumerate(prompts):
+        req = fleet.submit(
+            p, 7, seed=i,
+            callback=lambda r, t, d: log[r.req_id].append(t),
+            on_event=lambda r, e: log[r.req_id].append(e))
+        log[req.req_id] = []
+    for _ in range(3):
+        fleet.step()
+    victim = next(i for i, e in enumerate(fleet.engines) if e._requests)
+    assert fleet.engines[victim]._owed, "nothing owed: the move proves nothing"
+    if how == "remove":
+        assert fleet.remove_replica(victim)
+    elif how == "roll":
+        fleet.rolling_upgrade(lambda: params, version=1, steps_between=1)
+    else:
+        assert fleet.kill_replica(victim)
+        assert not fleet.engines[victim]._owed
+        fleet.step()  # the peer keeps serving meanwhile
+        fleet.restart_replica(victim)
+    fleet.run_until_complete()
+    assert {r.req_id: list(r.generated) for r in fleet.finished} == want
+    for rid, tokens in want.items():
+        assert log[rid] == tokens + ["length"], rid
+    assert not any(e._owed for e in fleet.engines)
+
+
 def test_spills_recover_after_add_replica(tiny):
     """A two-replica fleet spilling under hot-prefix pressure stops
     spilling once ``add_replica`` grows it: the warmed clone (shared

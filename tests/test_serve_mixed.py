@@ -1120,3 +1120,174 @@ def test_every_tick_has_a_program_on_todays_attention_rung(tiny, geometry):
     if geometry == "64-slots-spec7":
         # the rows' tokens fill their tiles: no second program
         assert len(programs) == len(ladder)
+
+
+# ---------------------------------------------------------------------------
+# accept / publish (PR 35): tick N's tokens and terminals reach the
+# callbacks behind tick N+1's dispatch — per request every token once, in
+# order, the terminal after the last token; nothing waits across an idle
+# engine
+# ---------------------------------------------------------------------------
+
+class _HoldBackTok:
+    """One letter a token; an even number of tokens ends in a half-merged
+    character, so the detokenizer holds the tail back for the terminal
+    event's ``final_text_delta``."""
+
+    def decode(self, ids, skip_special_tokens=True):
+        text = "".join(chr(97 + int(i) % 26) for i in ids)
+        return text + ("�" if len(ids) and len(ids) % 2 == 0 else "")
+
+
+def _serve_logged(engine, prompts, budgets, *, spec=False, stagger=True):
+    """Serve the workload with logging callbacks and hold every
+    ``step()`` to its contract.  → ``{rid: [("token", tok, delta)...,
+    ("end", reason, final_text_delta)]}``."""
+    log = {}
+
+    def cb(req, tok, delta):
+        log[req.req_id].append(("token", tok, delta))
+
+    def on_event(req, event):
+        if event in ("length", "stop", "aborted"):
+            log[req.req_id].append(
+                ("end", event, req.extra.get("final_text_delta")))
+
+    reqs = []
+
+    def handed(r):
+        return [t for kind, t, _ in log[r.req_id] if kind == "token"]
+
+    def check(more):
+        for r in reqs:
+            assert r.generated[:len(handed(r))] == handed(r)
+        if not more:
+            # no tick follows: nothing is owed, nothing was held back
+            assert not engine._owed
+            assert all(handed(r) == r.generated for r in reqs)
+
+    for j, (p, n) in enumerate(zip(prompts, budgets)):
+        log[j] = []
+        reqs.append(engine.submit(p, n, seed=j, request_id=j, callback=cb,
+                                  on_event=on_event, speculative=spec))
+        if stagger:
+            check(engine.step())
+    while True:
+        more = engine.step()
+        check(more)
+        if not more:
+            break
+    assert len(engine.scheduler.finished) == len(prompts)
+    return log
+
+
+def _tiled(rng, vocab, lens):
+    return [np.resize(rng.integers(1, vocab, size=3), n) for n in lens]
+
+
+@pytest.mark.parametrize("spec_k", [0, 3], ids=["plain", "spec3"])
+def test_publish_hands_out_what_the_immediate_tick_hands_out(tiny, spec_k):
+    """The unified tick's callbacks, per request, are exactly those of a
+    tick that publishes at once (the split tick): every token once, in
+    order, each with its text delta, then the terminal with the
+    detokenizer's held-back tail — with verify rounds on and off."""
+    cfg, params = tiny
+    rng = np.random.default_rng(35)
+    prompts = _tiled(rng, cfg.vocab_size, (9, 12, 7, 10, 8))
+    budgets = (6, 7, 5, 8, 6)
+    kw = dict(tokenizer=_HoldBackTok(), max_slots=4)
+    mixed = _engine(cfg, params, mixed="on", spec_k=spec_k, **kw)
+    got = _serve_logged(mixed, prompts, budgets, spec=bool(spec_k))
+    want = _serve_logged(_engine(cfg, params, mixed="off", **kw),
+                         prompts, budgets)
+    assert got == want
+    for j, n in enumerate(budgets):
+        *tokens, end = got[j]
+        assert [k for k, _, _ in tokens] == ["token"] * n
+        assert end[:2] == ("end", "length")
+        # an even budget ends on the held-back character: the tail rides
+        # the terminal, and the text is whole
+        assert (end[2] is not None) == (n % 2 == 0)
+        text = "".join(d or "" for _, _, d in tokens) + (end[2] or "")
+        assert text == _HoldBackTok().decode([t for _, t, _ in tokens])
+    snap = mixed.metrics.snapshot()
+    assert snap["total_generated_tokens"] == sum(budgets)
+    assert snap["finished"] == len(budgets)
+    # the mechanism ran: most ticks' tokens went out behind a dispatch
+    assert snap["publish_overlapped_ticks"] > snap["publish_immediate_ticks"] >= 1
+    if spec_k:
+        assert snap["spec_accepted_tokens"] > 0, "no verify round paid off"
+
+
+def test_an_abort_that_empties_the_engine_publishes_what_others_are_owed(tiny):
+    """A finished at accept (its last token and its terminal are owed), B
+    still running: aborting B leaves no work, so no tick follows — A's
+    items go out with the abort, not after an idle wait."""
+    cfg, params = tiny
+    engine = _engine(cfg, params, mixed="on", max_slots=2)
+    events = []
+    a = engine.submit(np.arange(1, 7), 2, on_event=lambda r, e: events.append(
+        (r.req_id, e)), callback=lambda r, t, d: events.append((r.req_id, t)))
+    b = engine.submit(np.arange(2, 9), 20, on_event=lambda r, e: events.append(
+        (r.req_id, e)), callback=lambda r, t, d: events.append((r.req_id, t)))
+    while a.finish_reason is None:
+        assert engine.step()
+    assert (a.req_id, "length") not in events and engine._owed
+    assert engine.scheduler.has_work  # B
+    assert engine.abort(b.req_id)
+    assert not engine.scheduler.has_work and not engine._owed
+    assert [e for e in events if e[0] == a.req_id] == [
+        (a.req_id, a.generated[0]), (a.req_id, a.generated[1]),
+        (a.req_id, "length")]
+    of_b = [e for e in events if e[0] == b.req_id]
+    assert of_b[-1] == (b.req_id, "aborted")
+    assert [t for _, t in of_b[:-1]] == b.generated
+    assert engine.pool.stats()["request_held"] == 0
+
+
+def test_a_tick_that_dispatches_nothing_publishes_on_the_spot(tiny):
+    cfg, params = tiny
+    from llm_np_cp_tpu.serve.tracing import TraceRecorder
+
+    tracer = TraceRecorder()
+    engine = _engine(cfg, params, mixed="on", tracer=tracer)
+    got = []
+    req = engine.submit(np.arange(1, 9), 6,
+                        callback=lambda r, t, d: got.append(t))
+    assert engine.step() and engine.step()
+    assert engine._owed and len(got) < len(req.generated)
+    plan = engine.scheduler.plan_tick
+    engine.scheduler.plan_tick = lambda *a, **k: ([], [])  # nothing planned
+    fetches = engine.n_host_fetches
+    assert engine.step()
+    engine.scheduler.plan_tick = plan
+    assert engine.n_host_fetches == fetches  # nothing was dispatched
+    assert not engine._owed and got == req.generated
+    tick = [e for e in tracer.events() if e.get("cat") == "tick"][-1]
+    assert tick["args"]["publish_rows"] >= 1
+    assert tick["args"]["publish_overlapped"] == 0
+    assert tick["args"]["packed_width"] == 0
+    engine.run_until_complete()
+    assert got == req.generated and len(got) == 6
+
+
+@pytest.mark.parametrize("spec_k", [0, 7], ids=["plain", "spec7"])
+def test_64_slot_streams_are_the_immediate_ticks_streams(tiny, spec_k):
+    """The benchmark cells' shape — 64 slots, chunks of 128 — with 64
+    requests in flight, prefill chunks beside decode rows: every
+    request's callback stream is, token for token, the stream of a tick
+    that publishes at once, with speculation on and off."""
+    cfg, params = tiny
+    rng = np.random.default_rng(64)
+    lens = rng.integers(5, 40, size=64)
+    prompts = _tiled(rng, cfg.vocab_size, lens)
+    budgets = [int(n) for n in rng.integers(10, 16, size=64)]
+    geometry = dict(max_slots=64, num_blocks=64 * 3 + 8, block_size=64,
+                    max_seq_len=192, prefill_chunk=128)
+    mixed = _engine(cfg, params, mixed="on", spec_k=spec_k, **geometry)
+    got = _serve_logged(mixed, prompts, budgets, spec=bool(spec_k),
+                        stagger=False)
+    assert max(mixed.metrics.active_slots) == 64
+    want = _serve_logged(_engine(cfg, params, mixed="off", **geometry),
+                         prompts, budgets, stagger=False)
+    assert got == want

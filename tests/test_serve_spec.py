@@ -291,9 +291,14 @@ def test_spec_abort_mid_verify(tiny):
     engine = _engine(cfg, params, spec_k=4, max_slots=2)
 
     killed: list[int] = []
+    handed: list[int] = []
 
     def kill_after_3(req, tok, delta):
-        if len(req.generated) == 3:
+        # (the callback counts what IT was handed: the tick publishes
+        # behind the next dispatch, so req.generated may already hold
+        # the rest of the verify round the walk accepted)
+        handed.append(tok)
+        if len(handed) == 3:
             killed.append(req.req_id)
             engine.abort(req.req_id)
 
@@ -303,8 +308,9 @@ def test_spec_abort_mid_verify(tiny):
     engine.run_until_complete()
     assert killed == [r0.req_id]
     assert r0.finish_reason == "aborted"
-    assert len(r0.generated) == 3, (
-        "accept walk kept emitting past the abort"
+    assert len(r0.generated) == 3 and r0.generated == handed, (
+        "the publish kept emitting past the abort, or the request keeps "
+        "tokens its callback never saw"
     )
     assert engine.pool.stats()["request_held"] == 0
     assert r0.req_id not in engine._draft_states

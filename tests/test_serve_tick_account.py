@@ -653,6 +653,64 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
     assert tick_account([]) is None
 
 
+def test_publish_counter_follows_the_order_of_the_tick(traced):
+    """``deliver`` comes BEFORE ``host_sync`` and hands out the previous
+    tick's items: its tick args say how many and whether a dispatch was
+    in flight; the run's last tick hands its own out at its end
+    (``publish_drained_rows``); ``/metrics`` counts both kinds; and the
+    tool prints the share of dispatching ticks that hid their publish."""
+    engine, tracer, hand = traced
+    ticks = _ticks_with_phases(tracer.events())
+    order = [p["name"] for p in ticks[0][1]]
+    assert (order.index("mixed_dispatch") + 1 == order.index("deliver")
+            == order.index("host_sync") - 1 == order.index("accept") - 2)
+    dispatching = [t["args"] for t, _ in ticks if t["args"]["packed_width"]]
+    assert len(dispatching) == len(hand)
+    # the first dispatching tick has nothing to hand out; every later one
+    # hands out what the tick before it accepted, behind its dispatch
+    assert dispatching[0]["publish_rows"] == 0
+    assert dispatching[0]["publish_overlapped"] == 0
+    for prev, args in zip(dispatching, dispatching[1:]):
+        assert args["publish_overlapped"] == 1 and args["publish_rows"] >= 1
+        assert args["host_fetches"] == 1  # the one-fetch contract holds
+        assert prev["publish_drained_rows"] == 0
+    # the last tick leaves no work: its own tokens and terminals go out
+    # on the spot, and nothing stays owed
+    assert dispatching[-1]["publish_drained_rows"] >= 2
+    assert not engine._owed
+    handed = sum(a["publish_rows"] + a["publish_drained_rows"]
+                 for a in dispatching)
+    snap = engine.metrics.snapshot()
+    # every token and every terminal is one item, handed out once
+    assert handed == snap["total_generated_tokens"] + snap["finished"]
+    assert snap["publish_overlapped_ticks"] == len(dispatching) - 1
+    assert snap["publish_immediate_ticks"] == 1
+    prom = engine.metrics.prometheus().splitlines()
+    assert (f"llm_serve_publish_overlapped_ticks_total "
+            f"{len(dispatching) - 1}") in prom
+    assert "llm_serve_publish_immediate_ticks_total 1" in prom
+    acct = tick_account(tracer.events())
+    assert acct["publish_ticks"] == len(dispatching) - 1
+    assert acct["publish_overlapped_share"] == 1.0
+    assert acct["accept_us"] > 0.0 and acct["deliver_us"] > 0.0
+    out = format_summary(tracer.events())
+    assert (f"publish: 100.0% of {len(dispatching) - 1} dispatching ticks "
+            "handed the previous tick's") in out
+    # the tick's sentinel sees the same phases, in the same order
+    from llm_np_cp_tpu.serve.slo import TickSentinel
+
+    seen = []
+    sentinel = TickSentinel()
+    observe = sentinel.observe
+    sentinel.observe = lambda phases: (seen.append(
+        [p[0] for p in phases]), observe(phases))[1]
+    watched = _engine(engine.config, engine.params, tracer=TraceRecorder(),
+                      sentinel=sentinel)
+    watched.submit(np.arange(1, 6), 3)
+    watched.run_until_complete()
+    assert seen and all(names == list(MIXED_TICK_PHASES) for names in seen)
+
+
 def test_emit_stamps_keep_the_item_shape_and_order():
     """The recorder pairs emits and writes in order, per request, across
     threads; what the HTTP layer hands over keeps its shape."""

@@ -451,6 +451,62 @@ def test_mixed_tick_phases_and_summarize_utilization(tiny, tmp_path):
     assert mixed_utilization([]) is None
 
 
+@pytest.mark.parametrize("budget", [1, 5], ids=["one-token", "five-tokens"])
+def test_request_track_follows_the_publish_not_the_accept(tiny, budget):
+    """A request's track is what the OUTSIDE saw: ``decode`` begins where
+    its first token is handed to the callback (the emit end of the
+    first-write lag), inside the ``deliver`` phase of the tick AFTER the
+    one that sampled it, and the ``finish`` instant follows the last
+    token's callback — while the tick's own slices keep the new order
+    and the sum-to-tick invariant."""
+    from llm_np_cp_tpu.serve.tracing import MIXED_TICK_PHASES
+
+    cfg, params = tiny
+    tracer = TraceRecorder()
+    engine = _engine(cfg, params, tracer=tracer, mixed_step="on",
+                     num_blocks=48)
+    handed = []
+    req = engine.submit(
+        np.arange(1, 8), budget,
+        callback=lambda r, t, d: handed.append(tracer.now_us()))
+    engine.run_until_complete()
+    assert len(handed) == budget == len(req.generated)
+    events = tracer.events()
+    track = [e for e in events if e.get("cat") == "request"
+             and e.get("id") == req.req_id]
+    names = [(e["name"], e["ph"]) for e in track]
+    finish = next(e for e in track if e["name"] == "finish")
+    assert finish["args"]["reason"] == "length"
+    assert finish["ts"] >= handed[-1]
+    if budget == 1:
+        # finished on its first token: no decode span was ever opened
+        assert ("decode", "b") not in names
+    else:
+        decode = next(e for e in track if (e["name"], e["ph"]) == ("decode", "b"))
+        assert handed[0] <= decode["ts"] <= handed[1]
+    ticks = [(e, events[i + 1:i + 1 + len(MIXED_TICK_PHASES)])
+             for i, e in enumerate(events)
+             if e.get("cat") == "tick" and e.get("ph") == "X"]
+    for tick, phases in ticks:
+        assert [p["name"] for p in phases] == list(MIXED_TICK_PHASES)
+        for a, b in zip(phases, phases[1:]):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1e-6)
+    # every callback ran inside a ``deliver`` slice — or, for the run's
+    # last tick (no tick follows), inside its ``account`` slice
+    slices = [(p["name"], p["ts"], p["ts"] + p["dur"])
+              for _, phases in ticks for p in phases]
+    where = [next(n for n, a, b in slices if a <= t <= b) for t in handed]
+    assert where[-1] == "account" and set(where[:-1]) <= {"deliver"}
+    # ...of the tick after the one whose fetch brought the token
+    first_tick = next(i for i, (t, _) in enumerate(ticks)
+                      if t["args"]["host_fetches"])
+    deliver_of = {i: next(p for p in ph if p["name"] == "deliver")
+                  for i, (_, ph) in enumerate(ticks)}
+    if budget > 1:
+        d = deliver_of[first_tick + 1]
+        assert d["ts"] <= handed[0] <= d["ts"] + d["dur"]
+
+
 # ---------------------------------------------------------------------------
 # Prometheus histograms + phase metrics (the scrape answers
 # "queueing or compute?" without a trace file)

@@ -215,7 +215,8 @@ LOCK_STATE: tuple[dict, ...] = (
             "decode_hist_sum", "queue_depth", "occupancy", "active_slots",
             "kv_bytes_tick", "prefix_blocks_requested",
             "prefix_blocks_hit", "mixed_prefill_tokens",
-            "mixed_decode_tokens", "mixed_dense_lanes", "t_start",
+            "mixed_decode_tokens", "mixed_dense_lanes",
+            "publish_overlapped", "publish_immediate", "t_start",
             "t_last",
             "anomaly_ticks", "lifecycle_actions",
             "roofline_ticks", "kv_read_bytes_total",

@@ -19,8 +19,10 @@ from ``GET /debug/trace``) and prints:
   spilled/restored bytes and restore-latency percentiles from the
   host-tier tick args;
 - **tick account** (unified tick) — the cut host phases in tick order
-  (pack / h2d / mixed_dispatch / deliver / account) with the transfers'
-  count and bytes, the tick thread's own CPU time and what is left over
+  (pack / h2d / mixed_dispatch / deliver / host_sync / accept /
+  account) with the transfers' count and bytes, the share of ticks
+  whose ``deliver`` handed the previous tick's tokens out behind the
+  dispatch, the tick thread's own CPU time and what is left over
   (neither CPU nor the device wait), the live context per dispatch,
   and the ticks by packed width (tile lanes inside attention) and by
   program (``packed x dense`` width) with the share of dense lanes
@@ -81,7 +83,7 @@ LIFECYCLE_COLUMNS = ("queued", "prefill", "decode", "http")
 # prints them in tick order)
 MIXED_TICK_PHASES = (
     "admission", "draft", "grow", "plan", "pack", "h2d", "mixed_dispatch",
-    "host_sync", "deliver", "account",
+    "deliver", "host_sync", "accept", "account",
 )
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -516,6 +518,15 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
         # and wrote, live tokens through the scan
         for key in ("ssm_state_rows", "ssm_scan_tokens", "state_slots_live"):
             out[key] = sum(a.get(key, 0) for a in ssm) / len(ssm)
+    pub = [e["args"] for e in ticks if e["args"].get("publish_rows")]
+    if pub:
+        # ``deliver`` hands the PREVIOUS tick's tokens out: behind this
+        # tick's dispatch (overlapped) wherever there is one to hide
+        # behind — every dispatching tick counted here has one
+        out["publish_ticks"] = len(pub)
+        out["publish_overlapped_share"] = sum(
+            a.get("publish_overlapped", 0) for a in pub) / len(pub)
+        out["publish_rows"] = sum(a["publish_rows"] for a in pub) / len(pub)
     cpu = [e["args"]["thread_cpu_us"] for e in ticks
            if "thread_cpu_us" in e["args"]]
     if cpu:
@@ -742,6 +753,11 @@ def format_summary(events: list[dict], top: int = 5) -> str:
             + (f"; tick thread CPU {acct['thread_cpu_us']:.0f}us, "
                f"neither CPU nor device wait {acct['host_wait_us']:.0f}us"
                if "thread_cpu_us" in acct else "")
+            + (f"\npublish: {acct['publish_overlapped_share']:.1%} of "
+               f"{acct['publish_ticks']} dispatching ticks handed the "
+               f"previous tick's {acct['publish_rows']:.1f} items out "
+               "behind the dispatch (deliver off the device's critical "
+               "path)" if "publish_ticks" in acct else "")
         )
     setup = setup_spans(events)
     if setup:
