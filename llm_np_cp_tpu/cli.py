@@ -728,6 +728,9 @@ def _build_serve_engine(args, params, config, *, prog: str,
         # every backend compile from here on is a cat "compile" span: a
         # recompile inside a measured window shows in the trace itself
         tracer.watch_compiles()
+        # ...and every run of the garbage collector a cat "gc" span: a
+        # stall of the whole interpreter that no phase can show
+        tracer.watch_gc()
         # ...and is keyed in the persistent cache WITH its metadata, so
         # the text of a warm step names this source's scopes and not
         # those of whichever build filled the cache (device_op_map)
@@ -976,6 +979,8 @@ def _dump_trace(tracer, args, prog: str) -> None:
     # takes the RECORDER, not the engine: a supervised restart mutes the
     # dead engine's tracer attribute, but the recorder object (shared by
     # every rebuilt engine) holds the full timeline
+    if tracer is not None:
+        tracer.unwatch_gc()  # the collector's hook goes with the recorder
     if args.trace_out and tracer is not None:
         n = tracer.dump(args.trace_out)
         print(f"[{prog}] wrote {n} trace events to {args.trace_out}"

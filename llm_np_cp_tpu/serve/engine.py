@@ -932,6 +932,11 @@ class ServeEngine:
         # tick loops bump it at their single packed host_sync transfer
         # and the tick trace args carry the per-tick count
         self.n_host_fetches = 0
+        # mixed dispatches so far: the number a dispatch carries everywhere
+        # (tick arg ``seq`` of its tick, metadata of its
+        # ``serve.mixed_dispatch`` / ``serve.host_sync`` annotations), so a
+        # profile's events join the recorder's ticks without a fitted clock
+        self.n_mixed_dispatches = 0
         if tracer is not None:
             tracer.complete("engine_build", t_build, cat="setup", args={
                 "tick": "unified" if self.mixed else "split",
@@ -2788,21 +2793,23 @@ class ServeEngine:
             })
         return outliers
 
-    def _phase_mark(self, name: str | None) -> float:
+    def _phase_mark(self, name: str | None, **meta: int) -> float:
         """The boundary between two phases of the unified tick, with a
         tracer attached (every caller holds the ``tracer is not None``
         guard): close the tick thread's open ``serve.<phase>`` profiler
         annotation, stamp the recorder's clock, open ``name`` (None:
         nothing — the dispatch brings its own annotation, the tick's
-        end opens none).  A tick that died mid-phase leaves its
-        annotation to the next mark."""
+        end opens none) with ``meta`` as the annotation's metadata (the
+        dispatch's ``seq``; the name itself never changes).  A tick that
+        died mid-phase leaves its annotation to the next mark."""
         ann = self._phase_ann
         if ann is not None:
             ann.__exit__(None, None, None)
             self._phase_ann = None
         now = self.tracer.now_us() if self.tracer is not None else -1.0
         if name is not None:
-            self._phase_ann = ann = jax.profiler.TraceAnnotation(name)
+            self._phase_ann = ann = jax.profiler.TraceAnnotation(
+                name, **meta)
             ann.__enter__()
         return now
 
@@ -3738,6 +3745,9 @@ class ServeEngine:
               if self.tracer is not None else -1.0)
 
         tp = th = t4 = t3
+        tw = -1.0
+        seq_meta: dict[str, int] = {}
+        device_done = False
         cpu4 = cpu5 = 0
         ctx_tokens = array_rows = h2d_count = h2d_bytes = 0
         attn_pages = attn_grid_steps = attn_step_pages = 0
@@ -3784,7 +3794,12 @@ class ServeEngine:
             th = (self._phase_mark(None)
                   if self.tracer is not None else -1.0)
             td0 = self.clock()
-            with (jax.profiler.TraceAnnotation("serve.mixed_dispatch")
+            self.n_mixed_dispatches += 1
+            # the NAME is what the harness counts ticks by; ``seq`` rides
+            # as metadata and joins this event to the recorder's tick
+            seq_meta = {"seq": self.n_mixed_dispatches}
+            with (jax.profiler.TraceAnnotation(
+                      "serve.mixed_dispatch", **seq_meta)
                   if self.tracer is not None else _NULL_CTX):
                 out, self.pool.pages = self._dispatch_mixed(
                     ops, bool(prefill_segs)
@@ -3798,7 +3813,7 @@ class ServeEngine:
         # was dispatched (then the dispatch phases and host_sync are
         # empty, and deliver runs from the plan's end)
         publish_rows = self._publish(dispatched)
-        tpub = t5 = (self._phase_mark("serve.host_sync")
+        tpub = t5 = (self._phase_mark("serve.host_sync", **seq_meta)
                      if self.tracer is not None else -1.0)
         if dispatched:
             cpu4 = time.thread_time_ns() if self.tracer is not None else 0
@@ -3812,7 +3827,20 @@ class ServeEngine:
             # exactly this fetch): the step packed samples + stop mask
             # + watermark + accept length into one int32 array; the
             # accept walk below reads the token + accept columns
-            # host-side (see _pack_sync on the other two)
+            # host-side (see _pack_sync on the other two).  With a
+            # recorder the fetch is cut where the work changes kind: had
+            # the device finished before the host came to wait, the host
+            # — not the step — set this tick's length (``is_ready`` asks,
+            # it does not wait; its first call on a result costs 12 us on
+            # a v5e, so it is the recorder's too); then the wait for the
+            # program, a stamp, and the same one transfer — the copy and
+            # the way back into the interpreter
+            if self.tracer is not None:
+                device_done = out.is_ready()
+                jax.block_until_ready(out)
+                tw = self.tracer.now_us()
+            # lint: disable=R2 -- the recorder's block_until_ready above
+            # waits and moves no data: this is still the tick's one fetch
             out_host = np.asarray(out)
             self.n_host_fetches += 1
             if self._n_expert_layers:
@@ -3929,6 +3957,7 @@ class ServeEngine:
             prefill_tokens=n_prefill_tok,
             decode_tokens=n_decode_tok,
             dense_lanes=dense_width,
+            host_bound=device_done,
         )
         if expert_load is not None:
             worst = expert_load[int(np.argmax(expert_load.max(axis=1)))]
@@ -4004,6 +4033,15 @@ class ServeEngine:
                 "thread_cpu_us": round(
                     (time.thread_time_ns() - cpu0 - (cpu5 - cpu4)) / 1e3, 1),
             }
+            if dispatched:
+                # the dispatch's number (the same one its two profiler
+                # annotations carry), the part of host_sync spent waiting
+                # for the program — the rest is the copy and the way back
+                # into the interpreter — and whether the device had
+                # already finished when the host came to wait
+                targs.update(seq_meta)
+                targs["device_wait_us"] = round(max(tw - tpub, 0.0), 1)
+                targs["device_done_at_sync"] = int(device_done)
             if expert_load is not None:
                 # the expert layers as the step counted them (the tick's
                 # one fetch): summarize_trace's transfers section and

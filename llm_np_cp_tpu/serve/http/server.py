@@ -100,6 +100,16 @@ _REASONS = {
 MAX_BODY_BYTES = 8 << 20
 
 
+def _own_cpu_clock() -> int | None:
+    """The calling thread's CPU clock, or None where the platform has
+    none.  Taken BY the thread itself: a clock id outlives its thread as
+    an error, a thread handle as a dangling pointer."""
+    try:
+        return time.pthread_getcpuclockid(threading.get_ident())
+    except (AttributeError, OSError):
+        return None
+
+
 class EngineRunner:
     """Supervises the engine tick loop on a worker thread and bridges it
     to asyncio handlers.
@@ -160,6 +170,14 @@ class EngineRunner:
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._watchdog: threading.Thread | None = None
+        # the tick thread's and the loop thread's CPU clocks, read at
+        # the scrape and nowhere else (``thread_cpu_seconds``); a tick
+        # thread that ends leaves its CPU in ``_tick_cpu_retired``, so
+        # the counter survives a supervised restart
+        self._cpu_lock = threading.Lock()
+        self._tick_cpu_clock: int | None = None
+        self._tick_cpu_retired = 0.0
+        self._loop_cpu_clock: int | None = None
         # rid → (loop, asyncio.Queue); written by both threads, but each
         # rid is registered exactly once (submit) and removed exactly
         # once (engine thread, on the terminal event / reject)
@@ -410,6 +428,7 @@ class EngineRunner:
 
     # -- event-loop side ----------------------------------------------
     def start(self) -> None:
+        self._loop_cpu_clock = _own_cpu_clock()  # the caller IS the loop
         self._spawn_thread(self._gen)
         if self.tick_deadline is not None:
             self._watchdog = threading.Thread(
@@ -730,8 +749,29 @@ class EngineRunner:
         )
         self._thread.start()
 
+    def thread_cpu_seconds(self) -> dict[str, float]:
+        """CPU seconds of the tick thread (every generation's) and of
+        the event-loop thread as ``/metrics`` counters; a name is absent
+        where the platform has no per-thread CPU clock."""
+        out: dict[str, float] = {}
+        with self._cpu_lock:  # against a tick thread retiring its clock
+            tick, loop = self._tick_cpu_clock, self._loop_cpu_clock
+            try:
+                if tick is not None:
+                    out["tick_thread_cpu_seconds_total"] = (
+                        self._tick_cpu_retired + time.clock_gettime(tick))
+                if loop is not None:
+                    out["loop_thread_cpu_seconds_total"] = (
+                        time.clock_gettime(loop))
+            except OSError:
+                pass  # a thread that is gone: the next scrape has it
+        return out
+
     def _run(self, gen: int, delay: float = 0.0,
              replay: list[dict] | None = None) -> None:
+        clock = _own_cpu_clock()
+        with self._cpu_lock:
+            self._tick_cpu_clock = clock
         try:
             if delay:
                 time.sleep(delay)  # exponential backoff before rebuild
@@ -747,6 +787,12 @@ class EngineRunner:
 
             traceback.print_exc()
             self._on_engine_death(f"{type(e).__name__}: {e}", gen)
+        finally:
+            with self._cpu_lock:
+                if clock is not None:
+                    self._tick_cpu_retired += time.thread_time()
+                if self._tick_cpu_clock == clock:
+                    self._tick_cpu_clock = None
 
     def _loop(self, gen: int) -> None:
         engine = self.engine
@@ -1434,6 +1480,7 @@ class HttpServer:
                 "draining": 1.0 if self.draining else 0.0,
                 **journal_gauges,
             })
+        cpu = getattr(self.runner, "thread_cpu_seconds", None)
         # the runner's engine, NOT self.engine: a supervised restart
         # rebinds it, and a scrape must see the live pool/scheduler
         engine = self.runner.engine
@@ -1467,7 +1514,7 @@ class HttpServer:
                 1.0 if engine.decode_degraded else 0.0
             ),
             **journal_gauges,
-        })
+        }, extra_counters=cpu() if cpu is not None else None)
         tenants = getattr(engine, "tenants", None)
         if tenants is not None:
             # tenant-labeled series (serve/tenants.py) ride the same

@@ -184,6 +184,10 @@ class ServeMetrics:
         # publishes made on the spot (no dispatch followed)
         self.publish_overlapped = 0
         self.publish_immediate = 0
+        # dispatching ticks at whose fetch the device had already
+        # finished: the host, not the step, set their length (asked only
+        # while a recorder is attached: 0 without one)
+        self.host_bound_ticks = 0
         # dropless expert layers (exact counters, one observation a
         # dispatching tick): experts that got at least one token, summed
         # over the expert layers; and of the worst layer the most tokens
@@ -258,9 +262,10 @@ class ServeMetrics:
         self, *, queue_depth: int, occupancy: float, active_slots: int,
         preemptions_total: int, kv_bytes: int = 0,
         prefill_tokens: int = 0, decode_tokens: int = 0,
-        dense_lanes: int = 0,
+        dense_lanes: int = 0, host_bound: bool = False,
     ) -> None:
         with self._lock:
+            self.host_bound_ticks += host_bound
             self.mixed_prefill_tokens += prefill_tokens
             self.mixed_decode_tokens += decode_tokens
             self.mixed_dense_lanes += dense_lanes
@@ -509,6 +514,7 @@ class ServeMetrics:
             out["mixed_dense_lanes"] = self.mixed_dense_lanes
             out["publish_overlapped_ticks"] = self.publish_overlapped
             out["publish_immediate_ticks"] = self.publish_immediate
+            out["host_bound_ticks"] = self.host_bound_ticks
             if self.moe_ticks:
                 # only where an expert layer ran (like the spec block)
                 out["moe_ticks"] = self.moe_ticks
@@ -593,13 +599,15 @@ class ServeMetrics:
         self, extra_gauges: dict[str, float] | None = None,
         prefix: str = "llm_serve",
         const_labels: dict[str, str] | None = None,
+        extra_counters: dict[str, float] | None = None,
     ) -> str:
         """Text exposition format (0.0.4) for a ``GET /metrics`` scrape.
 
         Rendered from ``snapshot()`` (so a scrape is one locked copy, no
         torn reads).  ``extra_gauges`` lets the HTTP server add live
         gauges the metrics object cannot know (current queue depth, pool
-        free blocks, in-flight streams).  ``const_labels`` are spliced
+        free blocks, in-flight streams), ``extra_counters`` the same for
+        counters (the threads' CPU clocks).  ``const_labels`` are spliced
         into EVERY sample's labelset — how a multi-replica server tags
         each engine's series with ``replica="N"`` so counters and
         histograms aggregate across the fleet.
@@ -713,6 +721,11 @@ class ServeMetrics:
              "Unified ticks whose tokens were handed out on the spot "
              "(no dispatch followed)",
              [("", s["publish_immediate_ticks"])])
+        emit("host_bound_ticks_total", "counter",
+             "Unified ticks at whose fetch the device had already "
+             "finished: the host, not the device step, set their length "
+             "(counted while a trace recorder is attached)",
+             [("", s["host_bound_ticks"])])
         if "moe_ticks" in s:
             emit("moe_ticks_total", "counter",
                  "Dispatching ticks that ran dropless expert layers",
@@ -909,8 +922,10 @@ class ServeMetrics:
                        if f"{base}_{p}" in s]
             if samples:
                 emit(f"{base}_quantile", "gauge", help_, samples)
-        for key, value in (extra_gauges or {}).items():
-            emit(key, "gauge", "Live server gauge", [("", float(value))])
+        for kind, extras in (("gauge", extra_gauges),
+                             ("counter", extra_counters)):
+            for key, value in (extras or {}).items():
+                emit(key, kind, f"Live server {kind}", [("", float(value))])
         return "\n".join(lines) + "\n"
 
     def format(self) -> str:

@@ -1315,6 +1315,10 @@ class ReplicaRunner:
         end."""
         blocks: list[str] = []
         seen_meta: set[str] = set()
+        # one event loop serves the fleet: its CPU rides once, at the
+        # end; every replica's tick thread under its own label
+        loop_cpu: dict[str, float] = {}
+        loop_name = "loop_thread_cpu_seconds_total"
         for i, runner in enumerate(self.replicas):
             if i in self._removed:
                 # a removed replica's frozen counters would read as a
@@ -1356,9 +1360,13 @@ class ReplicaRunner:
                 # mid-roll the scrape shows both versions side by side,
                 # and pre-upgrade series keep their exact labelsets
                 const["version"] = str(wv)
+            cpu = runner.thread_cpu_seconds()
+            if loop_name in cpu:
+                loop_cpu.setdefault(loop_name, cpu.pop(loop_name))
             text = engine.metrics.prometheus(
                 extra_gauges=per_gauges,
                 const_labels=const,
+                extra_counters=cpu,
             )
             ledger = getattr(engine, "tenants", None)
             if ledger is not None:
@@ -1392,11 +1400,13 @@ class ReplicaRunner:
             "llm_serve_faults_injected_total "
             f"{self.faults.injected_total if self.faults is not None else 0.0:g}"
         )
-        for key, value in (extra_gauges or {}).items():
-            router += (
-                f"\n# HELP llm_serve_{key} Live server gauge"
-                f"\n# TYPE llm_serve_{key} gauge"
-                f"\nllm_serve_{key} {float(value):.10g}"
-            )
+        for kind, extras in (("gauge", extra_gauges or {}),
+                             ("counter", loop_cpu)):
+            for key, value in extras.items():
+                router += (
+                    f"\n# HELP llm_serve_{key} Live server {kind}"
+                    f"\n# TYPE llm_serve_{key} {kind}"
+                    f"\nllm_serve_{key} {float(value):.10g}"
+                )
         blocks.append(router)
         return "\n".join(blocks) + "\n"

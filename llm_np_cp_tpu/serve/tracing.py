@@ -38,6 +38,10 @@ dump at ui.perfetto.dev or chrome://tracing):
   backend compile the process sees while the recorder is attached,
   with whether the persistent cache served it; the export names the
   tick phase or set-up span each fell in.
+- **collector spans** (``cat: "gc"``, ``watch_gc``): every run of the
+  garbage collector while the recorder watches, on the thread it ran
+  on — a stall of the whole interpreter no other span shows; the
+  export names the tick phase that held each (``within``).
 - ``otherData`` of the dump carries what is not an event: the
   device-side **op map** (serve/opmap.py) from a profile's name for an
   operation of the step to its named scope.
@@ -59,6 +63,7 @@ grow without bound); ``dropped`` counts what the ring displaced.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import re
@@ -170,6 +175,25 @@ def _on_duration_event(event: str, duration_secs: float, **_kw: Any) -> None:
             rec._on_compile_event(event, duration_secs)
 
 
+# Recorders that asked for collector spans.  ``gc.callbacks`` holds the
+# one forwarding hook below only while a recorder watches: the last one
+# to leave (``unwatch_gc``, or its own collection) takes the hook out.
+_gc_watchers: "weakref.WeakSet[TraceRecorder]" = weakref.WeakSet()
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    watchers = list(_gc_watchers)
+    if not watchers:
+        _drop_gc_hook()
+    for rec in watchers:
+        rec._on_gc(phase, info)
+
+
+def _drop_gc_hook() -> None:
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
 class TraceRecorder:
     def __init__(
         self,
@@ -215,6 +239,15 @@ class TraceRecorder:
         self._compile_tl = threading.local()
         # rid → [emit stamps not yet written, frames, lag sum, lag max]
         self._streams: dict[int, list] = {}
+        # collector slices (watch_gc).  Kept apart and appended WITHOUT
+        # the lock: the collector can start on a thread that holds it
+        # (any allocation inside ``_append``), and a hook that waited
+        # for the lock there would never return.  Under a ring they take
+        # at most a quarter of it, and the export keeps the total inside
+        self._gc_events: deque | list = (
+            deque(maxlen=max(ring // 4, 1)) if ring is not None else []
+        )
+        self._gc_tl = threading.local()
 
     # -- clock ---------------------------------------------------------
     def now_us(self) -> float:
@@ -270,6 +303,38 @@ class TraceRecorder:
             self.n_cache_hits += hit
         self.complete("backend_compile", end - duration_secs * 1e6, end,
                       cat="compile", args={"cache_hit": hit})
+
+    # -- collector spans -----------------------------------------------
+    def watch_gc(self) -> None:
+        """From now on every run of the garbage collector becomes a
+        ``cat: "gc"`` slice on the thread it ran on (``generation``,
+        ``collected``), until ``unwatch_gc``."""
+        _gc_watchers.add(self)
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+    def unwatch_gc(self) -> None:
+        _gc_watchers.discard(self)
+        if not _gc_watchers:
+            _drop_gc_hook()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        tl = self._gc_tl
+        if phase == "start":
+            tl.t0 = self.now_us()
+            return
+        t0 = getattr(tl, "t0", None)
+        if t0 is None:
+            return  # the collection began before the recorder watched
+        tl.t0 = None
+        self._gc_events.append({
+            "name": "gc", "cat": "gc", "ph": "X", "ts": t0,
+            "dur": max(self.now_us() - t0, 0.0), "pid": self._pid,
+            "tid": threading.get_ident(),
+            "args": {"generation": info["generation"],
+                     "collected": info["collected"],
+                     "thread": threading.current_thread().name},
+        })
 
     # -- low-level event append (callers hold no lock) -----------------
     def _ensure_thread_named(self, tid: int) -> None:
@@ -463,21 +528,31 @@ class TraceRecorder:
         })
 
     # -- export --------------------------------------------------------
+    def _merged(self) -> list[dict]:
+        # caller holds the lock: the events and the collector's slices,
+        # the oldest events making room where a ring bounds the total
+        slices = list(self._gc_events)
+        events = list(self._events)
+        if self.ring is not None:
+            events = events[max(len(events) + len(slices) - self.ring, 0):]
+        return events + slices
+
     def __len__(self) -> int:
         with self._lock:
-            return len(self._events)
+            n = len(self._events) + len(self._gc_events)
+        return n if self.ring is None else min(n, self.ring)
 
     def events(self) -> list[dict]:
         """Point-in-time copy (the ring keeps mutating underneath)."""
         with self._lock:
-            return list(self._events)
+            return self._merged()
 
     def to_dict(self) -> dict:
         with self._lock:
-            events = list(self._events)
+            events = self._merged()
             other = dict(self._other)
         return {
-            "traceEvents": _name_compile_sites(events),
+            "traceEvents": _name_sites(events),
             "displayTimeUnit": "ms",
             "otherData": {
                 "dropped_events": self.dropped,
@@ -494,31 +569,36 @@ class TraceRecorder:
         return len(payload["traceEvents"])
 
 
-def _name_compile_sites(events: list[dict]) -> list[dict]:
-    """Give every compile span ``args.within``: the shortest tick phase
-    or set-up span of its own thread that holds its midpoint (None when
-    it fell between them).  Done at export: a tick's phases are appended
-    when the tick ends, after the compile it contained.  One pass over
-    the events, each host span looked up among its thread's compiles."""
-    mids: dict[int, list[tuple[float, int]]] = {}  # tid → [(midpoint, i)]
+def _name_sites(events: list[dict]) -> list[dict]:
+    """Give every compile and collector span ``args.within``: the
+    shortest tick phase or set-up span that holds its midpoint (None
+    when it fell between them) — of its own thread for a compile, of any
+    thread for a collection, which stops them all.  Done at export: a
+    tick's phases are appended when the tick ends, after what it
+    contained.  One pass over the events, each host span looked up among
+    the midpoints it may hold."""
+    # tid → [(midpoint, i)]; collections under None: every thread's
+    mids: dict[int | None, list[tuple[float, int]]] = {}
     for i, ev in enumerate(events):
-        if ev.get("cat") == "compile":
-            mids.setdefault(ev["tid"], []).append(
-                (ev["ts"] + ev["dur"] / 2, i))
+        cat = ev.get("cat")
+        if cat == "compile" or cat == "gc":
+            mids.setdefault(ev["tid"] if cat == "compile" else None,
+                            []).append((ev["ts"] + ev["dur"] / 2, i))
     if not mids:
         return events
     for of_thread in mids.values():
         of_thread.sort()
-    within: dict[int, dict] = {}  # compile's index → its shortest holder
+    within: dict[int, dict] = {}  # span's index → its shortest holder
     for ev in events:
         if ev.get("ph") != "X" or ev.get("cat") not in ("phase", "setup"):
             continue
-        of_thread = mids.get(ev["tid"], ())
-        lo = bisect_left(of_thread, (ev["ts"], -1))
-        hi = bisect_right(of_thread, (ev["ts"] + ev["dur"], len(events)))
-        for _, i in of_thread[lo:hi]:
-            if i not in within or ev["dur"] < within[i]["dur"]:
-                within[i] = ev
+        for of_thread in (mids.get(ev["tid"], ()), mids.get(None, ())):
+            lo = bisect_left(of_thread, (ev["ts"], -1))
+            hi = bisect_right(of_thread,
+                              (ev["ts"] + ev["dur"], len(events)))
+            for _, i in of_thread[lo:hi]:
+                if i not in within or ev["dur"] < within[i]["dur"]:
+                    within[i] = ev
     out = list(events)
     for of_thread in mids.values():
         for _, i in of_thread:

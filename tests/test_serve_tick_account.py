@@ -199,7 +199,7 @@ def test_every_phase_runs_under_its_annotation_and_dispatch_ends_with_it(
     log = []
 
     class FakeAnnotation:
-        def __init__(self, name):
+        def __init__(self, name, **meta):
             self.name = name
 
         def __enter__(self):
@@ -486,7 +486,7 @@ def test_compile_span_names_the_set_up_span_it_fell_in():
     phase = {"name": "deliver", "cat": "phase", "ph": "X", "pid": 1,
              "tid": spans[-1]["tid"], "ts": spans[-1]["ts"] - 1.0,
              "dur": spans[-1]["dur"] + 2.0}
-    named = tracing._name_compile_sites(tracer.events() + [phase])
+    named = tracing._name_sites(tracer.events() + [phase])
     assert stray_compiles(named)["deliver"] == 1
     assert all(ev["dur"] > 0 and ev["name"] == "backend_compile" for ev in spans)
     # a recorder that is gone stops being fed
@@ -505,7 +505,7 @@ def test_tracing_on_adds_no_compile_and_off_runs_no_tracing_code(tiny):
     def drive(tracer):
         engine = _engine(cfg, params, tracer=tracer)
         marks = []
-        engine._phase_mark = lambda name: marks.append(name) or 0.0
+        engine._phase_mark = lambda name, **meta: marks.append(name) or 0.0
         rng = np.random.default_rng(5)
         for n in (5, 17, 9):
             engine.submit(rng.integers(1, cfg.vocab_size, size=n), 6)

@@ -730,3 +730,337 @@ def test_histograms_survive_sample_trimming(tiny):
     n = int(re.search(r"^llm_serve_ttft_seconds_count (\d+)$",
                       prom, re.M).group(1))
     assert n == 100  # ...histogram exact
+
+
+# ----------------------------------------------------------------------
+# the tick on one clock: a dispatch's number on the recorder's tick and on
+# its two profiler annotations, the fetch cut with a recorder only, the
+# ticks the host set, the collector as spans, both threads' CPU at the scrape
+# ----------------------------------------------------------------------
+
+def _dispatching(events):
+    return [e for e in events if e.get("name") == "tick"
+            and e["args"].get("packed_width")]
+
+
+class _SpyAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps name + metadata."""
+
+    log: list = []
+
+    def __init__(self, name, **meta):
+        type(self).log.append((name, meta))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_seq_numbers_dispatching_ticks_and_reaches_both_annotations(
+        tiny, monkeypatch):
+    cfg, params = tiny
+    _SpyAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _SpyAnnotation)
+    tracer = TraceRecorder()
+    engine = _engine(cfg, params, tracer=tracer)
+    for n in (5, 9, 7):
+        engine.submit(np.arange(1, n + 1), 5)
+    engine.run_until_complete()
+    engine.step()  # nothing left: a tick that dispatches nothing
+    ticks = [e for e in tracer.events() if e.get("name") == "tick"]
+    seqs = [e["args"]["seq"] for e in ticks if "seq" in e["args"]]
+    assert seqs == list(range(1, len(seqs) + 1)) and len(seqs) >= 5
+    # a tick carries the number exactly when it dispatched
+    for e in ticks:
+        assert ("seq" in e["args"]) == bool(e["args"]["packed_width"])
+    assert any("seq" not in e["args"] for e in ticks)
+    assert engine.n_mixed_dispatches == len(seqs)
+    # the same number is the metadata of the tick's two annotations, whose
+    # NAMES stay what the harness counts ticks by
+    for name in ("serve.mixed_dispatch", "serve.host_sync"):
+        got = [meta["seq"] for n, meta in _SpyAnnotation.log
+               if n == name and meta]
+        assert got == seqs, name
+    # ... and no other annotation carries metadata
+    assert all(not meta for n, meta in _SpyAnnotation.log
+               if n not in ("serve.mixed_dispatch", "serve.host_sync"))
+    idle_syncs = [meta for n, meta in _SpyAnnotation.log
+                  if n == "serve.host_sync" and not meta]
+    assert len(idle_syncs) == len(ticks) - len(seqs)
+
+
+def test_the_fetch_is_cut_with_a_recorder_and_stays_one_fetch(tiny):
+    cfg, params = tiny
+    tracer = TraceRecorder()
+    engine = _engine(cfg, params, tracer=tracer)
+    for n in (5, 9):
+        engine.submit(np.arange(1, n + 1), 6)
+    engine.run_until_complete()
+    ticks = _dispatching(tracer.events())
+    assert ticks
+    for e in ticks:
+        a = e["args"]
+        assert 0.0 <= a["device_wait_us"] <= a["host_sync_us"]
+        assert a["host_fetches"] == 1
+        assert a["device_done_at_sync"] in (0, 1)
+    idle = [e for e in tracer.events() if e.get("name") == "tick"
+            and not e["args"].get("packed_width")]
+    assert all("device_wait_us" not in e["args"]
+               and "device_done_at_sync" not in e["args"] for e in idle)
+
+
+def test_without_a_recorder_the_tick_neither_waits_nor_annotates(
+        tiny, monkeypatch):
+    cfg, params = tiny
+    engine = _engine(cfg, params)
+    assert engine.tracer is None
+    prompts = [np.arange(1, n + 1) for n in (5, 9)]
+    for p in prompts:
+        engine.submit(p, 4)
+    engine.run_until_complete()  # compile everything once
+    calls = Counter()
+    real_wait, real_asarray = jax.block_until_ready, np.asarray
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: calls.update(wait=1) or real_wait(x))
+    monkeypatch.setattr(
+        jax.profiler, "TraceAnnotation",
+        lambda *a, **k: calls.update(annotation=1) or _SpyAnnotation(*a, **k))
+    import llm_np_cp_tpu.serve.engine as engine_mod
+
+    def asarray(x, *a, **k):  # the tick's one fetch: a device array in
+        if isinstance(x, jax.Array):
+            calls.update(fetch=1)
+        return real_asarray(x, *a, **k)
+
+    monkeypatch.setattr(engine_mod.np, "asarray", asarray)
+    counter = CompileCounter()
+    fetches0, dispatches0 = engine.n_host_fetches, engine.n_mixed_dispatches
+    with counter.watch():
+        for p in prompts:
+            engine.submit(p, 4)
+        engine.run_until_complete()
+    monkeypatch.undo()
+    dispatched = engine.n_mixed_dispatches - dispatches0
+    assert dispatched >= 4
+    assert calls["wait"] == 0 and calls["annotation"] == 0
+    # the fetch is the one np.asarray(out) a dispatching tick it was
+    assert calls["fetch"] == dispatched == engine.n_host_fetches - fetches0
+    assert counter.count == 0, counter.events
+    assert_tracing_hooks_guarded()
+
+
+def test_host_bound_ticks_counter_counts_the_ones(tiny):
+    import time
+
+    cfg, params = tiny
+    tracer = TraceRecorder()
+    engine = _engine(cfg, params, tracer=tracer)
+    # a slow consumer: its token's publish (``deliver``, behind the next
+    # dispatch) outlasts the tiny step, so the device is done when the
+    # host comes to wait - the host sets those ticks
+    engine.submit(np.arange(1, 8), 6,
+                  callback=lambda req, tok, text: time.sleep(0.05))
+    engine.submit(np.arange(1, 6), 6)
+    engine.run_until_complete()
+    flags = [e["args"]["device_done_at_sync"]
+             for e in _dispatching(tracer.events())]
+    assert set(flags) <= {0, 1} and sum(flags) >= 1
+    assert engine.metrics.snapshot()["host_bound_ticks"] == sum(flags)
+    text = engine.metrics.prometheus()
+    assert f"llm_serve_host_bound_ticks_total {sum(flags)}" in text
+    assert "# TYPE llm_serve_host_bound_ticks_total counter" in text
+    # the question costs 12 us a tick on the chip, so it is the recorder's:
+    # an engine without one does not ask, and counts nothing
+    plain = _engine(cfg, params)
+    plain.submit(np.arange(1, 8), 4,
+                 callback=lambda req, tok, text: time.sleep(0.05))
+    plain.run_until_complete()
+    assert plain.metrics.snapshot()["host_bound_ticks"] == 0
+    assert "llm_serve_host_bound_ticks_total 0" in plain.metrics.prometheus()
+
+
+def test_a_collection_inside_a_traced_tick_is_a_gc_slice_with_within(tiny):
+    import gc
+
+    from llm_np_cp_tpu.serve import tracing
+
+    cfg, params = tiny
+    tracer = TraceRecorder()
+    tracer.watch_gc()
+    assert tracing._on_gc in gc.callbacks
+    engine = _engine(cfg, params, tracer=tracer)
+    # the callback runs in ``deliver``, behind the next tick's dispatch
+    engine.submit(np.arange(1, 8), 4,
+                  callback=lambda req, tok, text: gc.collect())
+    engine.run_until_complete()
+    dump = tracer.to_dict()["traceEvents"]
+    forced = [e for e in dump if e.get("cat") == "gc"
+              and e["args"]["generation"] == 2]
+    assert len(forced) >= 3
+    for e in forced:
+        assert e["ph"] == "X" and e["dur"] >= 0.0
+        assert e["args"]["collected"] >= 0
+        assert e["tid"] == next(t["tid"] for t in dump if t["name"] == "tick")
+    within = Counter(e["args"]["within"] for e in forced)
+    assert within["deliver"] >= 2, within
+    assert set(within) <= set(MIXED_TICK_PHASES) | {None}
+    # a second recorder shares the one hook; the last to leave removes it
+    other = TraceRecorder()
+    other.watch_gc()
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    tracer.unwatch_gc()
+    assert tracing._on_gc in gc.callbacks
+    n = len(tracer)
+    gc.collect()
+    assert len(tracer) == n and len(other) == 1
+    other.unwatch_gc()
+    assert tracing._on_gc not in gc.callbacks
+    # a recorder that is dropped without unwatching takes the hook with it
+    lost = TraceRecorder()
+    lost.watch_gc()
+    del lost
+    gc.collect()
+    gc.collect()
+    assert tracing._on_gc not in gc.callbacks
+
+
+@pytest.mark.http
+def test_metrics_show_both_threads_cpu_rising(tiny):
+    import asyncio
+
+    from llm_np_cp_tpu.serve.http.client import http_get, post_completion
+    from llm_np_cp_tpu.serve.http.server import HttpServer
+
+    cfg, params = tiny
+    engine = _engine(cfg, params)  # no recorder: the scrape alone reads them
+    names = ("tick_thread_cpu_seconds_total", "loop_thread_cpu_seconds_total")
+
+    def counters(body):
+        text = body.decode()
+        for name in names:
+            assert f"# TYPE llm_serve_{name} counter" in text
+        return [float(re.search(rf"^llm_serve_{name} (\S+)$", text, re.M)
+                      .group(1)) for name in names]
+
+    async def main():
+        srv = HttpServer(engine, model_id="tiny", drain_timeout=10.0)
+        await srv.start("127.0.0.1", 0)
+        host, port = srv.host, srv.port
+        loop = asyncio.get_running_loop()
+        scrapes = []
+        for _ in range(2):
+            st, _obj = await loop.run_in_executor(
+                None, post_completion, host, port,
+                {"prompt": [4, 2, 9], "max_tokens": 8})
+            assert st == 200
+            st, body = await loop.run_in_executor(
+                None, http_get, host, port, "/metrics")
+            assert st == 200
+            scrapes.append(counters(body))
+        srv.begin_drain()
+        await srv.serve_until_shutdown()
+        return scrapes
+
+    first, second = asyncio.run(asyncio.wait_for(main(), timeout=120))
+    assert all(v > 0.0 for v in first)
+    assert all(b > a for a, b in zip(first, second)), (first, second)
+
+
+def test_summarize_prints_the_host_line(tiny):
+    import gc
+
+    from tools.summarize_trace import host_account
+
+    cfg, params = tiny
+    tracer = TraceRecorder()
+    tracer.watch_gc()
+    try:
+        engine = _engine(cfg, params, tracer=tracer)
+        engine.submit(np.arange(1, 8), 5,
+                      callback=lambda req, tok, text: gc.collect())
+        engine.submit(np.arange(1, 6), 5)
+        engine.run_until_complete()
+    finally:
+        tracer.unwatch_gc()
+    events = tracer.to_dict()["traceEvents"]
+    host = host_account(events)
+    ticks = _dispatching(events)
+    assert host["ticks"] == len(ticks)
+    assert host["host_bound_share"] == pytest.approx(
+        sum(e["args"]["device_done_at_sync"] for e in ticks) / len(ticks))
+    assert host["fetch_us"] == pytest.approx(sum(
+        e["args"]["host_sync_us"] - e["args"]["device_wait_us"]
+        for e in ticks) / len(ticks))
+    assert host["gc_count"] >= 3 and host["gc_by_phase_us"]["deliver"] > 0.0
+    text = format_summary(events, top=0)
+    line = next(ln for ln in text.splitlines() if ln.startswith("host: "))
+    assert "found the device done at the fetch" in line
+    assert "collector" in line and "deliver" in line
+    # a dump from before the dispatches were numbered has no such line
+    old = [dict(e, args={k: v for k, v in e["args"].items() if k != "seq"})
+           if e.get("name") == "tick" else e for e in events]
+    assert host_account(old) is None
+    assert "host: " not in format_summary(old, top=0)
+
+
+def test_summarize_joins_a_profile_by_seq_and_corrects_the_device_lead(
+        monkeypatch):
+    """The tool's own copy of the join, on a profile made by hand: three
+    dispatches 12.2 ms apart, exposed 2.4 ms = wake 0.7 + serial 1.2 +
+    launch 0.5, the device's line written 0.9 ms early."""
+    from types import SimpleNamespace as NS
+
+    from tools.summarize_trace import tick_timeline
+
+    ms, lead = 1e6, 0.9e6
+    host, modules, events = [], [], []
+
+    def ev(name, start, end, **stats):
+        return NS(name=name, start_ns=start, duration_ns=end - start,
+                  stats=list(stats.items()))
+
+    for i, seq in enumerate((7, 8, 9)):
+        d0 = i * 12.2 * ms
+        p0, p1 = d0 + 0.5 * ms, d0 + 10.3 * ms
+        host += [ev("serve.mixed_dispatch", d0, d0 + 0.4 * ms, seq=seq),
+                 ev("serve.host_sync", d0 + 5.2 * ms, p1 + 0.7 * ms, seq=seq),
+                 ev("serve.deliver", d0 + 0.4 * ms, d0 + 5.2 * ms),
+                 ev("DoEnqueueProgram", d0 + 0.4 * ms, d0 + 0.45 * ms,
+                    run_id=100 + seq),
+                 ev("CompleteCallbacks", p1 + 0.2 * ms, p1 + 0.25 * ms,
+                    run_id=100 + seq)]
+        modules.append(ev("jit_mixed_step(1)", p0 - lead, p1 - lead,
+                          run_id=100 + seq))
+        events.append({"name": "tick", "cat": "tick", "ph": "X",
+                       "ts": i * 12200.0, "dur": 12000.0,
+                       "args": {"seq": seq, "device_wait_us": 5400.0,
+                                "host_sync_us": 5800.0}})
+    planes = [
+        NS(name="/device:TPU:0", lines=[
+            NS(name="XLA Modules", events=modules),
+            NS(name="XLA Ops", events=[])]),
+        NS(name="/host:CPU", lines=[NS(name="python3", events=host)]),
+    ]
+    monkeypatch.setattr(
+        jax.profiler.ProfileData, "from_file",
+        staticmethod(lambda path: NS(planes=planes)))
+    got = tick_timeline(events, "ignored.xplane.pb")
+    assert got["ticks"] == 2
+    assert got["exposed_us"] == pytest.approx(2400.0)
+    assert got["serial_us"] == pytest.approx(1200.0)
+    # the lead's bounds: 0.8 ms (the enqueue) to 1.1 ms (the callbacks)
+    assert got["lead_us"] == pytest.approx(950.0)
+    assert got["lead_error_us"] == pytest.approx(150.0)
+    assert got["wake_gap_us"] == pytest.approx(650.0)
+    assert got["launch_gap_us"] == pytest.approx(550.0)
+    assert (got["wake_gap_us"] + got["serial_us"] + got["launch_gap_us"]
+            == pytest.approx(got["exposed_us"]))
+    # an idle tick between two dispatches takes the first of them out
+    idle = {"name": "tick", "cat": "tick", "ph": "X", "ts": 6000.0,
+            "dur": 10.0, "args": {}}
+    assert tick_timeline(events + [idle], "x")["ticks"] == 1
+    assert tick_timeline(
+        [dict(e, args={}) for e in events], "x") is None

@@ -26,7 +26,14 @@ from ``GET /debug/trace``) and prints:
   (neither CPU nor the device wait), the live context per dispatch,
   and the ticks by packed width (tile lanes inside attention) and by
   program (``packed x dense`` width) with the share of dense lanes
-  that held a token;
+  that held a token; then the host's side of the tick on one line: the
+  share of ticks at whose fetch the device had already finished (the
+  host set them), the fetch after the wait (copy + the way back into
+  the interpreter), the collector's time a tick by the phase that held
+  it, and with ``--profile`` the exposed host a tick (device program
+  end → next program start, on the profile's clock, joined to the
+  ticks by the dispatch's ``seq``) cut into wake gap, serial host and
+  launch gap;
 - **set-up** — the ``cat: "setup"`` spans (load + place, engine build
   and its probes, every warm-up program with its seconds and whether it
   compiled, the op map, listen) and the
@@ -538,6 +545,133 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
     return out
 
 
+def host_account(events: list[dict]) -> dict[str, Any] | None:
+    """What a tick's numbered dispatch says of the host (tick args ``seq``,
+    ``device_wait_us``, ``device_done_at_sync``) and the collector's
+    slices: the share of dispatching ticks at whose fetch the device had
+    already finished, the mean wait for the program and the mean fetch
+    after it (``host_sync_us`` - ``device_wait_us``), and ``cat: "gc"``
+    time a dispatching tick from the first such tick on, whole and by
+    the phase that held it.  None for a dump whose ticks carry no
+    ``seq``."""
+    numbered = [e for e in events if e.get("ph") == "X"
+                and e.get("cat") == "tick" and "seq" in (e.get("args") or {})]
+    if not numbered:
+        return None
+    ticks, n = [e["args"] for e in numbered], len(numbered)
+    first = min(e["ts"] for e in numbered)  # set-up's collections are not a tick's
+    runs = [e for e in events if e.get("cat") == "gc" and e["ts"] >= first]
+    gc_by: dict[str, float] = defaultdict(float)
+    for ev in runs:
+        gc_by[(ev.get("args") or {}).get("within") or "between spans"] += (
+            ev["dur"])
+    return {
+        "ticks": n,
+        "host_bound_share": sum(a["device_done_at_sync"] for a in ticks) / n,
+        "device_wait_us": sum(a["device_wait_us"] for a in ticks) / n,
+        "fetch_us": sum(a["host_sync_us"] - a["device_wait_us"]
+                        for a in ticks) / n,
+        "gc_count": len(runs),
+        "gc_us": sum(gc_by.values()) / n,
+        "gc_by_phase_us": {k: v / n for k, v in sorted(
+            gc_by.items(), key=lambda kv: -kv[1])},
+    }
+
+
+def tick_timeline(events: list[dict], profile: str) -> dict[str, Any] | None:
+    """The exposed host a tick and its three parts: the dispatch's ``seq``
+    joins the profile's ``serve.mixed_dispatch`` / ``serve.host_sync``
+    annotations and the device's program executions (``XLA Modules``,
+    first device plane) to the recorder's ticks.  A tick k counts when
+    the profile saw it and its successor whole and the recorder's next
+    tick IS that successor (no idle tick between).  The exposed host is
+    read on the device's line and the serial host on the host's; the
+    wake gap and the launch gap cross the two, and the device's line
+    leads the host's by an amount that differs from capture to capture,
+    so both are corrected by the middle of the lead's causal bounds (no
+    program starts before the runtime's ``DoEnqueueProgram`` of its
+    ``run_id`` nor before its dispatch annotation; none ends after the
+    runtime's ``CompleteCallbacks`` nor after ``host_sync`` +
+    ``device_wait_us``).  The benchmark's ``layers/ticktimeline.py`` does
+    the same join; this is the tool's own copy (needs jax to read the
+    profile, like the device scopes)."""
+    from bisect import bisect_right
+
+    from jax.profiler import ProfileData
+
+    by_seq: dict[str, dict[int, tuple[float, float]]] = {
+        "serve.mixed_dispatch": {}, "serve.host_sync": {}}
+    by_run: dict[str, dict[int, float]] = {
+        "DoEnqueueProgram": {}, "CompleteCallbacks": {}}
+    modules: list[tuple[float, float, Any]] = []
+    for plane in ProfileData.from_file(profile).planes:
+        device = plane.name.startswith("/device:")
+        if device and modules:
+            continue
+        for line in plane.lines:
+            if device:
+                if line.name == "XLA Modules":
+                    modules = sorted(
+                        (float(e.start_ns), float(e.start_ns + e.duration_ns),
+                         dict(e.stats).get("run_id")) for e in line.events)
+                continue
+            for e in line.events:
+                if e.name in by_seq or e.name in by_run:
+                    stats = dict(e.stats)
+                    if e.name in by_seq and "seq" in stats:
+                        by_seq[e.name][int(stats["seq"])] = (
+                            float(e.start_ns),
+                            float(e.start_ns + e.duration_ns))
+                    elif "run_id" in stats:
+                        by_run[e.name][stats["run_id"]] = float(e.start_ns)
+    dispatch, sync = by_seq["serve.mixed_dispatch"], by_seq["serve.host_sync"]
+    seqs = sorted(dispatch)
+    starts = [dispatch[q][0] for q in seqs]
+    program: dict[int, list[float]] = {}
+    lead_lo: list[float] = []
+    lead_hi: list[float] = []
+    for m0, m1, run_id in modules:  # a program belongs where its midpoint lies
+        i = bisect_right(starts, (m0 + m1) / 2) - 1
+        if i >= 0:
+            span = program.setdefault(seqs[i], [m0, m1])
+            span[0], span[1] = min(span[0], m0), max(span[1], m1)
+        if run_id in by_run["DoEnqueueProgram"]:
+            lead_lo.append(by_run["DoEnqueueProgram"][run_id] - m0)
+        if run_id in by_run["CompleteCallbacks"]:
+            lead_hi.append(by_run["CompleteCallbacks"][run_id] - m1)
+    ticks = sorted((e for e in events if e.get("ph") == "X"
+                    and e.get("cat") == "tick"), key=lambda e: e["ts"])
+    args = {e["args"]["seq"]: e["args"] for e in ticks
+            if "seq" in (e.get("args") or {})}
+    follows = {(a.get("args") or {}).get("seq"): (b.get("args") or {}).get("seq")
+               for a, b in zip(ticks, ticks[1:])}
+    parts = {"wake_gap": 0.0, "serial": 0.0, "launch_gap": 0.0}
+    n = 0
+    for q in seqs:
+        if (follows.get(q) != q + 1 or q not in sync or q + 1 not in dispatch
+                or q not in program or q + 1 not in program):
+            continue
+        n += 1
+        parts["wake_gap"] += sync[q][1] - program[q][1]
+        parts["serial"] += dispatch[q + 1][0] - sync[q][1]
+        parts["launch_gap"] += program[q + 1][0] - dispatch[q + 1][0]
+        lead_lo.append(dispatch[q + 1][0] - program[q + 1][0])
+        lead_hi.append(sync[q][0] + args[q].get("device_wait_us", 0.0) * 1e3
+                       - program[q][1] if "device_wait_us" in args[q]
+                       else sync[q][1] - program[q][1])
+    if not n:
+        return None
+    lead = (max(lead_lo) + min(lead_hi)) / 2
+    out: dict[str, Any] = {k + "_us": v / n / 1e3 for k, v in parts.items()}
+    out["exposed_us"] = sum(out.values())
+    out["wake_gap_us"] -= lead / 1e3
+    out["launch_gap_us"] += lead / 1e3
+    out["lead_us"] = lead / 1e3
+    out["lead_error_us"] = (min(lead_hi) - max(lead_lo)) / 2e3
+    out["ticks"] = n
+    return out
+
+
 def setup_spans(events: list[dict]) -> list[dict]:
     """The ``cat: "setup"`` spans in start order, each with the number
     of backend compiles that fell in it (its children's included) and
@@ -666,7 +800,8 @@ def request_table(events: list[dict]) -> dict[Any, dict]:
     return dict(table)
 
 
-def format_summary(events: list[dict], top: int = 5) -> str:
+def format_summary(events: list[dict], top: int = 5,
+                   profile: str | None = None) -> str:
     lines: list[str] = []
     totals = phase_totals(events)
     stats = tick_stats(events)
@@ -758,6 +893,29 @@ def format_summary(events: list[dict], top: int = 5) -> str:
                f"previous tick's {acct['publish_rows']:.1f} items out "
                "behind the dispatch (deliver off the device's critical "
                "path)" if "publish_ticks" in acct else "")
+        )
+        host = host_account(events)
+        line = tick_timeline(events, profile) if host and profile else None
+        if host is not None:
+            lines.append(
+                "host: "
+                + (f"exposed {line['exposed_us']:.0f}us a tick = wake gap "
+                   f"{line['wake_gap_us']:.0f} + serial "
+                   f"{line['serial_us']:.0f} + launch gap "
+                   f"{line['launch_gap_us']:.0f} (device program end to "
+                   f"next program start, {line['ticks']} ticks of the "
+                   "profile, joined by seq; the two gaps corrected by the "
+                   f"device line's lead {line['lead_us']:.0f} +- "
+                   f"{line['lead_error_us']:.0f}us); " if line else "")
+                + f"{host['host_bound_share']:.1%} of {host['ticks']} "
+                "dispatching ticks found the device done at the fetch "
+                f"(the host set them); wait {host['device_wait_us']:.0f}us,"
+                f" fetch after it {host['fetch_us']:.0f}us; collector "
+                f"{host['gc_us']:.1f}us a tick in {host['gc_count']} runs"
+                + (" (" + ", ".join(
+                    f"{k} {v:.1f}" for k, v in
+                    list(host["gc_by_phase_us"].items())[:4]) + ")"
+                   if host["gc_by_phase_us"] else "")
         )
     setup = setup_spans(events)
     if setup:
@@ -866,7 +1024,8 @@ def main(argv: list[str] | None = None) -> str:
     p.add_argument("--profile", default=None, metavar="XPLANE",
                    help="a jax.profiler .xplane.pb of the same run: adds "
                    "device time by the step's named scopes, from the op "
-                   "map in the dump (needs jax to read the profile)")
+                   "map in the dump, and the exposed host a tick in its "
+                   "three parts (needs jax to read the profile)")
     p.add_argument("--request-log", default=None, metavar="PATH",
                    help="canonical request log (--request-log JSONL) "
                    "for the same run: adds the per-tenant request/"
@@ -881,7 +1040,8 @@ def main(argv: list[str] | None = None) -> str:
             out += (f"\nwrote {len(merged['traceEvents'])} merged "
                     f"events to {args.merge}")
     else:
-        out = format_summary(load_trace(args.trace[0]), top=args.top)
+        out = format_summary(load_trace(args.trace[0]), top=args.top,
+                             profile=args.profile)
     if args.profile is not None:
         with open(args.trace[0]) as f:
             data = json.load(f)
