@@ -557,9 +557,36 @@ def _dma_slices_pages(pool: jnp.ndarray) -> bool:
     return cols % 128 == 0 and rows % _sublane_tile(rows, pool.dtype) == 0
 
 
+def _lane_pack(kv_heads: int, head_dim: int) -> int:
+    """kv heads of a MERGED page (``[BS, K * D]``) that share one row of
+    128 lanes, and are attended together: 2 at ``head_dim`` 64.  A head
+    taken alone out of such a row sits at a lane offset that is no
+    multiple of a tile — a relayout of the whole group every step; the
+    row is sliced whole instead, ``q`` comes with each head's values on
+    its own lanes and zeros on its neighbours' (``_lane_spread``), and
+    the MXU, 128 deep whatever it is given, does the same work."""
+    pack = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    return pack if kv_heads % pack == 0 else 1
+
+
+def _lane_spread(qf: jnp.ndarray, q_tile: int, pack: int) -> jnp.ndarray:
+    """``q [T, K, G, D]`` → ``[T / q_tile, K / pack, pack * q_tile * G,
+    pack * D]`` for the merged page form: the rows of a tile's ``pack``
+    heads that share a row of lanes one under the other — order (head,
+    token, group), the order of the kernel's score sheet — each with its
+    values at its own head's lanes and zeros elsewhere, so that ONE dot
+    against the shared ``[.., pack * D]`` slice of K scores them all."""
+    t, kh, g, d = qf.shape
+    q6 = qf.reshape(t // q_tile, q_tile, kh // pack, pack, g, d)
+    q6 = q6.transpose(0, 2, 3, 1, 4, 5)  # [NT, C, pack, q_tile, G, D]
+    wide = jnp.einsum("ncsqgd,sr->ncsqgrd", q6,
+                      jnp.eye(pack, dtype=qf.dtype))
+    return wide.reshape(t // q_tile, kh // pack, pack * q_tile * g, pack * d)
+
+
 def ragged_pages_per_step(
     mb: int, block_s: int, kv_heads: int, head_dim: int, kv_dtype,
-    quantized: bool,
+    quantized: bool, merged: bool = False,
 ) -> int:
     """``P``: the pages of a tile's row one grid step of the ragged
     kernel streams and attends — the largest count that covers at most
@@ -570,8 +597,11 @@ def ragged_pages_per_step(
     (about twelve float32 copies of a page between K and V — the v5e
     compiler wanted 16.8 MB of scoped VMEM at six ``[64, 4, 256]`` pages
     and 24.2 MB at eight, of its 16).  From shapes alone: every caller
-    gets the ``P`` of its page shape."""
-    slot = 2 * 2 * _vmem_bytes((block_s, kv_heads, head_dim), kv_dtype)
+    gets the ``P`` of its page shape.  A ``merged`` page ``[BS, K * D]``
+    takes what it holds (``[64, 8, 64]`` twice that)."""
+    page = ((block_s, kv_heads * head_dim) if merged
+            else (block_s, kv_heads, head_dim))
+    slot = 2 * 2 * _vmem_bytes(page, kv_dtype)
     if quantized:
         slot += 2 * 2 * _vmem_bytes((block_s, kv_heads), jnp.float32)
         slot += 12 * 4 * block_s * kv_heads * head_dim
@@ -583,7 +613,7 @@ def _ragged_kernel(
     meta_ref, tables_ref, *refs,
     scale: float, softcap: float | None, quantized: bool, kv_heads: int,
     group: int, block_s: int, q_tile: int, head_dim: int, pages: int,
-    mb: int, by_hand: tuple[bool, ...],
+    mb: int, by_hand: tuple[bool, ...], pack: int = 0,
 ):
     """Mixed-batch block-table attention: each q tile holds up to
     ``q_tile`` consecutive tokens of ONE row (a prefill-chunk slice, or a
@@ -598,8 +628,8 @@ def _ragged_kernel(
     the row's last page starts no copy, a step past it does nothing, and
     a dead tile (``tile_qlen == 0``) has no live step at all.  A pool
     array whose pages a DMA cannot slice (``by_hand`` False,
-    ``_dma_slices_pages``: scale pages, two int8 kv heads, a
-    ``head_dim`` of 64) comes as ``pages`` blocked operands instead,
+    ``_dma_slices_pages``: scale pages, two int8 kv heads, an
+    unmerged ``head_dim`` of 64) comes as ``pages`` blocked operands instead,
     fetched by the automatic pipeline.
 
     The online-softmax update runs once a group, on a
@@ -611,7 +641,15 @@ def _ragged_kernel(
     packed batch into the pool before attending (same discipline as the
     paged decode step).  A page slot no copy filled holds zeros or an
     earlier page; its positions lie beyond every query slot and the
-    causal mask drops them."""
+    causal mask drops them.
+
+    ``pack`` > 0: the pages are MERGED, ``[block_s, kv_heads *
+    head_dim]`` with the heads side by side on the lanes, and ``q`` comes
+    as ``_lane_spread`` lays it out: the ``pack`` heads of one row of
+    lanes are scored by one dot against that whole slice of K, their
+    ``p @ V`` comes out ``pack * head_dim`` wide (each head's result on
+    its own lanes, its neighbours' beside it) and ``_finalize`` takes
+    each head's lanes."""
     it = iter(refs)
     q_ref = next(it)
     # a pool array: the whole of it in HBM, or its ``pages`` page blocks
@@ -711,6 +749,25 @@ def _ragged_kernel(
         if quantized:
             kb = kb.astype(dtype) * scales[0][..., None].astype(dtype)
             vb = vb.astype(dtype) * scales[1][..., None].astype(dtype)
+        if pack:
+            # merged pages [width, K * D]: the heads of one row of lanes
+            # together, a static whole-row slice of the group each
+            lanes = pack * head_dim
+            dots, rows = kv_heads // pack, pack * q_tile * group
+
+            def q_of(c):
+                return q_ref[0, c]
+
+            def kv_of(b, c):
+                return b[:, c * lanes:(c + 1) * lanes]
+        else:
+            dots, rows = kv_heads, q_tile * group
+
+            def q_of(ki):
+                return q_ref[:, ki].reshape(q_tile * group, head_dim)
+
+            def kv_of(b, ki):
+                return b[:, ki]
         # per-kv-head MXU dots over the whole tile, concatenated to ONE
         # [K*q_tile*G, width] score sheet (rows ordered (ki, qi, gi))
         # so the mask/softcap/exp/rescale VPU pipeline runs once per
@@ -718,11 +775,10 @@ def _ragged_kernel(
         s = jnp.concatenate(
             [
                 jax.lax.dot_general(
-                    q_ref[:, ki].reshape(q_tile * group, head_dim),
-                    kb[:, ki], (((1,), (1,)), ((), ())),
+                    q_of(i), kv_of(kb, i), (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
-                for ki in range(kv_heads)
+                for i in range(dots)
             ],
             axis=0,
         ) * scale  # [K*q_tile*G, width]
@@ -750,14 +806,14 @@ def _ragged_kernel(
         pv = jnp.concatenate(
             [
                 jax.lax.dot_general(
-                    pb[ki * q_tile * group:(ki + 1) * q_tile * group],
-                    vb[:, ki], (((1,), (0,)), ((), ())),
+                    pb[i * rows:(i + 1) * rows],
+                    kv_of(vb, i), (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
-                for ki in range(kv_heads)
+                for i in range(dots)
             ],
             axis=0,
-        )  # [K*q_tile*G, D]
+        )  # [K*q_tile*G, D]  (merged: pack * D wide)
         acc_ref[:] = _amla_rescale(acc_ref[:], k_steps) + pv
         m_ref[:] = m_new
 
@@ -766,10 +822,12 @@ def _ragged_kernel(
         l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
         acc = acc_ref[:] / l
         for ki in range(kv_heads):
+            mine = acc[ki * q_tile * group:(ki + 1) * q_tile * group]
+            if pack > 1:  # this head's lanes of the row it shares
+                lane0 = ki % pack * head_dim
+                mine = mine[:, lane0:lane0 + head_dim]
             o_ref[:, ki] = (
-                acc[ki * q_tile * group:(ki + 1) * q_tile * group]
-                .reshape(q_tile, group, head_dim)
-                .astype(o_ref.dtype)
+                mine.reshape(q_tile, group, head_dim).astype(o_ref.dtype)
             )
 
 
@@ -805,7 +863,11 @@ def ragged_paged_attention(
     packer guarantees this; dead lanes between segments are masked via
     ``tile_qlen``).  k_pages/v_pages [NB, BS, K, D] — a pool of pages:
     one layer's slab, or (the unified tick) the whole pool flat over
-    layer and block with the layer's offset already in ``tables``.
+    layer and block with the layer's offset already in ``tables`` — or
+    MERGED, [NB, BS, K * D] (float pools; ``K`` follows from ``q``'s
+    ``D``): the form a ``head_dim``-64 pool is stored in, because a TPU
+    keeps THAT in the order its shape lists and a DMA can cut a page out
+    of it (serve/block_pool.py).
     tables [R, MB] int32 page ids per engine row.  Per TILE
     (T / RAGGED_Q_TILE entries): ``tile_row`` — the owning engine row,
     ``tile_qpos0`` — the cache slot of the tile's first token,
@@ -858,11 +920,23 @@ def ragged_paged_attention(
             f"tile metadata must have T/RAGGED_Q_TILE = {nt} entries, "
             f"got {tile_row.shape}"
         )
-    nb_pool, block_s, kh, _ = k_pages.shape
+    merged = k_pages.ndim == 3
+    if merged:
+        if quantized or k_pages.shape[-1] % d:
+            raise ValueError(
+                f"merged pages [NB, BS, K * D] are float pages of whole "
+                f"heads of q's head_dim {d}; got {k_pages.dtype}"
+                f"{list(k_pages.shape)}")
+        _, block_s, kd = k_pages.shape
+        kh = kd // d
+    else:
+        _, block_s, kh, _ = k_pages.shape
     g = h // kh
     mb = tables.shape[1]
-    pages = ragged_pages_per_step(mb, block_s, kh, d, k_pages.dtype, quantized)
+    pages = ragged_pages_per_step(
+        mb, block_s, kh, d, k_pages.dtype, quantized, merged=merged)
     steps = -(-mb // pages)
+    pack = _lane_pack(kh, d) if merged else 0
 
     qf = q.reshape(t, kh, g, d)
     # per-tile kv page bounds: the window lower bound is tightest at the
@@ -907,6 +981,11 @@ def ragged_paged_attention(
     tile_spec = pl.BlockSpec(
         (qt, kh, g, d), tile_map, memory_space=pltpu.VMEM)
     in_specs, operands = [tile_spec], [qf]
+    if merged:
+        spread = _lane_spread(qf, qt, pack)
+        in_specs, operands = [pl.BlockSpec(
+            (1,) + spread.shape[1:], tile_map,
+            memory_space=pltpu.VMEM)], [spread]
     for a, hand in zip(pools, by_hand):
         if hand:
             in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
@@ -919,7 +998,7 @@ def ragged_paged_attention(
     scratch = [
         pltpu.VMEM((rows, 1), jnp.float32),
         pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((rows, d), jnp.float32),
+        pltpu.VMEM((rows, max(pack, 1) * d), jnp.float32),
         # two halves of a group of pages for each array copied by hand
         *[pltpu.VMEM((2, pages) + a.shape[1:], a.dtype)
           for a, hand in zip(pools, by_hand) if hand],
@@ -932,6 +1011,7 @@ def ragged_paged_attention(
             _ragged_kernel, scale=scale, softcap=logit_softcap,
             quantized=quantized, kv_heads=kh, group=g, block_s=block_s,
             q_tile=qt, head_dim=d, pages=pages, mb=mb, by_hand=by_hand,
+            pack=pack,
         ),
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -970,7 +1050,9 @@ def ragged_paged_attention_xla(
     it is the PROBE-FAILURE fallback and the parity oracle, not the fast
     path."""
     t, h, d = q.shape
-    _, block_s, kh, _ = k_pages.shape
+    block_s = k_pages.shape[1]
+    # (merged pages [NB, BS, K * D]: the gathered view takes the heads apart)
+    kh = k_pages.shape[2] // d if k_pages.ndim == 3 else k_pages.shape[2]
     mb = tables.shape[1]
     s_max = mb * block_s
 

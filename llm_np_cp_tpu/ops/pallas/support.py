@@ -272,8 +272,14 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         mb_r, nbp_r = 12, 40
         window = jnp.int32(shape.window or (1 << 30))
 
+        # the pages in the form the serve pool stores such heads in:
+        # merged [BS, K * D] where it merges them (head_dim 64)
+        from llm_np_cp_tpu.serve.block_pool import merges_pages
+
+        page = (kh * d,) if merges_pages(kh, d, int8) else (kh, d)
+
         def make_args():
-            q, pages = normals((n_tiles * qt, h, d), (nbp_r, bs, kh, d))
+            q, pages = normals((n_tiles * qt, h, d), (nbp_r, bs) + page)
             # (a host constant: no device op, no compile of its own)
             tables = jnp.asarray(
                 (np.arange(3 * mb_r) * 7 % 37 + 1).reshape(3, mb_r), jnp.int32)

@@ -176,16 +176,26 @@ def paged_kv_specs(config: ModelConfig, plan: MeshPlan,
     else — layer, block, in-block slot — stays unsharded so block
     tables remain plain replicated scalars and the scalar-prefetch
     kernels see per-shard-identical indices.  int8 scale pages
-    ``[L, NB, BS, K]`` shard like the values minus D."""
-    from llm_np_cp_tpu.serve.block_pool import PagedKV
+    ``[L, NB, BS, K]`` shard like the values minus D.  A merged pool
+    (``block_pool.merges_pages``: ``[L, NB, BS, K * D]``) is cut on the
+    merged axis — kv-major, so a shard is still whole heads."""
+    from llm_np_cp_tpu.serve.block_pool import (
+        PagedKV,
+        PageForm,
+        merges_pages,
+    )
 
     kv = MODEL_AXIS if _kv_heads_shardable(config, plan) else None
     scale = P(None, None, None, kv) if quantized else None
+    merged = merges_pages(
+        config.num_key_value_heads, config.head_dim, quantized)
+    page = P(None, None, None, kv) if merged else P(None, None, None, kv, None)
     return normalize_specs(PagedKV(
-        k=P(None, None, None, kv, None),
-        v=P(None, None, None, kv, None),
+        k=page,
+        v=page,
         k_scale=scale,
         v_scale=scale,
+        form=PageForm(config.head_dim) if merged else None,
     ))
 
 

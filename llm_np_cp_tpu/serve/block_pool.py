@@ -12,6 +12,20 @@ Layout (the contiguous cache's [L, B, S, K, D] with S factored into
 pages):
 
     k, v: [num_layers, num_blocks, block_size, kv_heads, head_dim]
+    k, v: [num_layers, num_blocks, block_size, kv_heads * head_dim]   (merged)
+
+A TPU lays an array out by its own rule, and a float page whose
+``head_dim`` is not a whole row of 128 lanes (64: Llama-3.2-1B, LFM2) is
+NOT kept in the order its shape lists: half of every tile would be
+empty, so the device permutes the dimensions, and every program that
+hands such a pool to a kernel relays it out on the way in and on the way
+out (PERF.md section 6, PR 38).  The same page with its heads side by
+side on the lanes, ``[BS, K * D]``, fills its tiles and lies as its shape
+says: where ``K * D`` is a multiple of 128 such a pool is allocated
+MERGED (``merges_pages``, from the shapes and the dtype alone).
+``PagedKV`` says what a page is (``kv_heads``, ``head_dim``, ``merged``,
+``token_shape``): a program that touches the pool asks it and reshapes
+what it writes or what it gathered, never the pool.
 
 Block 0 is RESERVED as a scratch block and never allocated: inactive
 decode slots in the engine's fixed-width batch point their tables at it,
@@ -50,6 +64,7 @@ slabs update in place.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, NamedTuple
 
 import jax
@@ -133,22 +148,68 @@ class FreeList:
                 self._free.append(i)
 
 
+def merges_pages(kv_heads: int, head_dim: int, quantized: bool) -> bool:
+    """Whether a pool of such pages is allocated ``[L, NB, BS, K * D]``
+    (module docstring): a float page whose ``[BS, K, D]`` form a TPU
+    would not keep row-major (``head_dim`` short of a whole row of
+    lanes — compiled for a described v5e, bf16 and float32 alike:
+    tests/test_kernel_lowering.py) and whose heads side by side fill
+    whole rows.  int8 pages keep their form beside their scale pages."""
+    return (not quantized and head_dim % 128 != 0
+            and (kv_heads * head_dim) % 128 == 0)
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class PageForm:
+    """What a merged page's shape no longer says: a leafless node of the
+    ``PagedKV`` pytree, so it rides every jitted step as part of the
+    tree's structure."""
+
+    head_dim: int
+
+
 class PagedKV(NamedTuple):
     """Device-side pages: the pytree the engine's jitted steps thread
     through (and donate).  Scales are None for float pools."""
 
-    k: jnp.ndarray  # [L, NB, BS, K, D]
-    v: jnp.ndarray  # [L, NB, BS, K, D]
+    k: jnp.ndarray  # [L, NB, BS, K, D], or merged [L, NB, BS, K * D]
+    v: jnp.ndarray
     k_scale: jnp.ndarray | None = None  # [L, NB, BS, K] f32 (int8 mode)
     v_scale: jnp.ndarray | None = None
     # what a sequence carries besides K/V (module docstring): not paged,
     # one row a slot, ``{"conv": .., "ssm": ..}`` as the configuration's
     # layers need; None for a stack of attention layers alone
     state: dict[str, jnp.ndarray] | None = None
+    # set on a merged pool only; every other page says it by its shape
+    form: PageForm | None = None
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    @property
+    def merged(self) -> bool:
+        """Pages stored ``[BS, K * D]``, the kv heads side by side."""
+        return self.form is not None
+
+    @property
+    def head_dim(self) -> int:
+        return self.form.head_dim if self.merged else self.k.shape[-1]
+
+    @property
+    def kv_heads(self) -> int:
+        """The kv heads of the array as the caller holds it (one shard's
+        inside ``shard_map``)."""
+        if self.merged:
+            return self.k.shape[-1] // self.head_dim
+        return self.k.shape[-2]
+
+    @property
+    def token_shape(self) -> tuple[int, ...]:
+        """One token's K (or V) as a page holds it: ``(K, D)`` or ``(K *
+        D,)`` — what a value written into the pool is reshaped to."""
+        return tuple(self.k.shape[3:])
 
     def pool_arrays(self) -> tuple[jnp.ndarray, ...]:
         """The paged arrays (``[L, NB, BS, ..]``), without the state."""
@@ -194,14 +255,11 @@ class BlockPool:
             self.prefix_cache: PrefixCache | None = PrefixCache(self.free_list)
         else:
             self.prefix_cache = None
-        shape = (
-            len(config.attn_layers),
-            num_blocks,
-            block_size,
-            config.num_key_value_heads,
-            config.head_dim,
-        )
         quantized = self.dtype == jnp.int8
+        kh, d = config.num_key_value_heads, config.head_dim
+        merged = merges_pages(kh, d, quantized)
+        shape = (len(config.attn_layers), num_blocks, block_size) + (
+            (kh * d,) if merged else (kh, d))
         # mesh-sharded mode: a PagedKV of NamedShardings (kv-head axis on
         # "model", see parallel/sharding.paged_kv_specs) commits the slabs
         # onto the mesh; the FREE LIST stays global — allocation is a
@@ -234,6 +292,7 @@ class BlockPool:
                      if quantized else None),
             v_scale=(zeros(shape[:-1], jnp.float32, where.v_scale)
                      if quantized else None),
+            form=PageForm(d) if merged else None,
         )
 
         # what a sequence carries besides K/V (module docstring): the

@@ -332,11 +332,13 @@ def _pool_is_row_major(pages: PagedKV) -> bool:
     paged-attention kernels read it: dimensions major to minor as the
     shape lists them.  A TPU lays an array out by its own rule, and the
     rule permutes the dimensions of a ``[.., BS, K, D]`` array whose
-    minor two would waste most of a tile: ``D`` under 128, one bf16 or up
-    to two int8 kv heads on the chip, and every ``[.., BS, K]`` scale
-    page (compiled for a described v5e: PERF.md §4).  A kernel cannot
-    read such a pool where it lies, so the unified step then keeps one
-    layer's slab, not the pool, as the unit it relays out."""
+    minor two would waste most of a tile: ``D`` under 128 (which is why
+    a float pool of such heads is allocated merged, ``[.., BS, K * D]``:
+    serve/block_pool.py), one bf16 or up to two int8 kv heads on the
+    chip, and every ``[.., BS, K]`` scale page (compiled for a described
+    v5e: PERF.md §4).  A kernel cannot read such a pool where it lies,
+    so the unified step then keeps one layer's slab, not the pool, as
+    the unit it relays out."""
     return all(
         getattr(a.format.layout, "major_to_minor", None)
         == tuple(range(a.ndim))
@@ -922,6 +924,7 @@ class ServeEngine:
         else:
             self.tick_token_budget = 0
             self.mixed_buckets: tuple[tuple[int, int], ...] = ()
+            self.pool_carried = False  # (the split tick's scan takes slabs)
             # -- jitted programs (fixed set; tick loop never adds more)
             self._prefill_step = make_ragged_prefill_step(config)
             self._decode_step = self._make_decode_step(decode_attn_impl)
@@ -941,6 +944,11 @@ class ServeEngine:
             tracer.complete("engine_build", t_build, cat="setup", args={
                 "tick": "unified" if self.mixed else "split",
                 "buckets": len(self.mixed_buckets),
+                # how the tick's layer loop holds the pool (1: flat over
+                # (layer, block), written in place; 0: by layer slabs)
+                # and what one page of it is
+                "pool_carried": int(self.pool_carried),
+                "pool_page_shape": self.pool_page_shape,
                 # what the slots carry besides K/V (0 without such layers)
                 "state_bytes": int(sum(a.nbytes for a in jax.tree.leaves(
                     self.pool.pages.state))),
@@ -1028,6 +1036,21 @@ class ServeEngine:
         return (f"tp={self.mesh_plan.model} over "
                 f"{self.mesh_plan.num_devices} {dev.platform} devices "
                 f"({kv})")
+
+    @property
+    def pool_page_shape(self) -> str:
+        """One page of the pool as it is stored: ``64x2x128``, or
+        ``64x512`` where the kv heads lie side by side (merged)."""
+        return "x".join(str(n) for n in self.pool.pages.k.shape[2:])
+
+    def pool_form_gauges(self) -> dict[str, float]:
+        """``/metrics``: whether the tick's layer loop carries the pool
+        flat and writes it in place (``pool_carried``), and the page's
+        shape as a label."""
+        return {
+            "pool_carried": float(self.pool_carried),
+            f'pool_page_shape{{shape="{self.pool_page_shape}"}}': 1.0,
+        }
 
     def _put(self, a: Any) -> jnp.ndarray:
         """Per-tick operand placement.  Under a mesh every host-built
@@ -1300,23 +1323,25 @@ class ServeEngine:
             # Shared prefix blocks before ``start`` are NEVER written.
             nb = ids.shape[0]
 
-            def put(slab, page, trailing):  # slab [L, 1, max_seq_len, *t]
+            def put(slab, page):  # slab [L, 1, max_seq_len, *t]
+                # the fresh slots as the pool holds a token (``[K, D]``,
+                # merged ``[K * D]``, a scale page's ``[K]``): the value
+                # is reshaped, never the pool
                 l = slab.shape[0]
                 fresh = lax.dynamic_slice_in_dim(slab, start * bs, nb * bs, 1)
                 return page.at[:, ids].set(
-                    fresh.reshape((l, nb, bs) + trailing)
+                    fresh.reshape((l, nb, bs) + page.shape[3:])
                 )
 
-            kh, d = cache.k.shape[-2:]
-            new = PagedKV(
-                k=put(cache.k[:, 0], pages.k, (kh, d)),
-                v=put(cache.v[:, 0], pages.v, (kh, d)),
+            new = pages._replace(
+                k=put(cache.k[:, 0], pages.k),
+                v=put(cache.v[:, 0], pages.v),
                 k_scale=(
-                    put(cache.k_scale[:, 0], pages.k_scale, (kh,))
+                    put(cache.k_scale[:, 0], pages.k_scale)
                     if quantized else None
                 ),
                 v_scale=(
-                    put(cache.v_scale[:, 0], pages.v_scale, (kh,))
+                    put(cache.v_scale[:, 0], pages.v_scale)
                     if quantized else None
                 ),
             )
@@ -1350,7 +1375,8 @@ class ServeEngine:
             def put(slab, page, trailing):
                 return slab.at[:, :, : h * bs].set(get(page, trailing))
 
-            kh, d = pages.k.shape[-2:]
+            # (the gathered view takes the cache's form, not the pool)
+            kh, d = pages.kv_heads, pages.head_dim
             pos = jnp.arange(cap, dtype=jnp.int32)[None, :]
             valid = (pos >= pad) & (pos < h * bs)
             return KVCache(
@@ -1374,10 +1400,11 @@ class ServeEngine:
         """(pages, blk, k, v[, ks, vs]) → pages with one staged
         host-tier block written at pool block ``blk`` — the landing
         step of a restore.  ``blk`` arrives as a traced device scalar
-        and the staged arrays have the block's fixed [L, BS, K, D]
-        layout, so the program compiles ONCE for the process however
-        many blocks restore (the tier's zero-new-recompiles contract,
-        compile_counter tiered section)."""
+        and the staged arrays have the block's fixed layout as the pool
+        holds it ([L, BS, K, D]; merged [L, BS, K * D]), so the program
+        compiles ONCE for the process however many blocks restore (the
+        tier's zero-new-recompiles contract, compile_counter tiered
+        section)."""
         quantized = self.cache_dtype == jnp.int8
         constrain_pages = self._constrain_pages
 
@@ -1386,7 +1413,7 @@ class ServeEngine:
             def restore_block(pages: PagedKV, blk: jnp.ndarray,
                               k: jnp.ndarray, v: jnp.ndarray,
                               ks: jnp.ndarray, vs: jnp.ndarray):
-                new = PagedKV(
+                new = pages._replace(
                     k=pages.k.at[:, blk].set(k),
                     v=pages.v.at[:, blk].set(v),
                     k_scale=pages.k_scale.at[:, blk].set(ks),
@@ -1397,7 +1424,7 @@ class ServeEngine:
             @partial(jax.jit, donate_argnums=(0,))
             def restore_block(pages: PagedKV, blk: jnp.ndarray,
                               k: jnp.ndarray, v: jnp.ndarray):
-                new = PagedKV(
+                new = pages._replace(
                     k=pages.k.at[:, blk].set(k),
                     v=pages.v.at[:, blk].set(v),
                 )
@@ -1639,7 +1666,7 @@ class ServeEngine:
             seeds: jnp.ndarray,    # [B] uint32 — per-request RNG seed
         ):
             l_axis, b = pages.k.shape[0], tables.shape[0]
-            kh, d = pages.k.shape[-2:]
+            kh, d = pages.kv_heads, pages.head_dim
             s_max = tables.shape[1] * bs
 
             def gather(page, trailing):  # [L, NB, bs, *t] → [L, B, S_max, *t]
@@ -1690,30 +1717,32 @@ class ServeEngine:
 
             # Extract the newly written K/V column (slot ``lengths`` per
             # row) from the gathered view and scatter it into the pool.
-            def col(slab):  # [L, B, S_max, ...] → [L, B, ...] at per-row offset
-                return jax.vmap(
+            def col(slab, page):
+                # [L, B, S_max, ...] → [L, B, ...] at per-row offset, as
+                # the pool holds a token (the column reshaped, not the pool)
+                new = jax.vmap(
                     lambda sl, off: lax.dynamic_index_in_dim(
                         sl, off, axis=1, keepdims=False
                     ),
                     in_axes=(1, 0), out_axes=1,
                 )(slab, lengths)
+                return new.reshape(new.shape[:2] + page.shape[3:])
 
             blk = jnp.take_along_axis(tables, (lengths // bs)[:, None], axis=1)[:, 0]
             off = lengths % bs
             # inactive rows all hit (scratch block 0, slot 0); duplicate
             # scatter indices there are harmless — the data is garbage by
             # construction and never gathered through a real table
-            new_pages = PagedKV(
-                k=pages.k.at[:, blk, off].set(col(cache.k)),
-                v=pages.v.at[:, blk, off].set(col(cache.v)),
-                k_scale=(
-                    pages.k_scale.at[:, blk, off].set(col(cache.k_scale))
-                    if quantized else None
-                ),
-                v_scale=(
-                    pages.v_scale.at[:, blk, off].set(col(cache.v_scale))
-                    if quantized else None
-                ),
+            def write(page, slab):
+                return page.at[:, blk, off].set(col(slab, page))
+
+            new_pages = pages._replace(
+                k=write(pages.k, cache.k),
+                v=write(pages.v, cache.v),
+                k_scale=(write(pages.k_scale, cache.k_scale)
+                         if quantized else None),
+                v_scale=(write(pages.v_scale, cache.v_scale)
+                         if quantized else None),
             )
             # one-fetch contract, W=1 degenerate case: [B, 4] packed
             # (token, stop-hit, watermark, accept)
@@ -1745,6 +1774,7 @@ class ServeEngine:
         use_epilogue = self.epilogue_impl == "fused"
         stop_tokens = self.stop_tokens
         constrain_pages = self._constrain_pages
+        merged = self.pool.pages.merged
         attn_call = self._shard_attn(
             partial(
                 paged_decode_attention,
@@ -1805,10 +1835,13 @@ class ServeEngine:
                         )
                     # explicit cast: f32 activations into a bf16 pool
                     # is the intended rounding, not an implicit promotion
-                    return (
-                        kp.at[blk, off].set(k[:, 0].astype(kp.dtype)),
-                        vp.at[blk, off].set(v[:, 0].astype(vp.dtype)),
-                    )
+                    # (and the column in the form the pool holds a token)
+                    def put(page, val):
+                        return page.at[blk, off].set(
+                            val[:, 0].astype(page.dtype).reshape(
+                                val.shape[:1] + page.shape[2:]))
+
+                    return put(kp, k), put(vp, v)
 
                 def attn_fn(q, k_att, v_att, sliding_l):
                     if quantized:
@@ -1825,6 +1858,15 @@ class ServeEngine:
                             sliding_l, jnp.maximum(pads, vis - win), pads
                         )
                     scales = (ksp2, vsp2) if quantized else ()
+                    if merged:
+                        # this kernel reads ``[NB, BS, K, D]`` pages: a
+                        # merged slab is handed over in that form (a
+                        # relayout a layer on a TPU; the unified tick's
+                        # kernel reads merged pages as they lie)
+                        kp2, vp2 = (
+                            a.reshape(a.shape[:2] + (
+                                config.num_key_value_heads, config.head_dim))
+                            for a in (kp2, vp2))
                     return attn_call(
                         q, kp2, vp2, *scales, tables, vis, row_pads,
                     )
@@ -1843,7 +1885,7 @@ class ServeEngine:
                 xs += (pages.k_scale, pages.v_scale)
             xs += (is_sliding,)
             x, ys = lax.scan(layer_step, x, xs, unroll=scan_unroll(config))
-            new_pages = PagedKV(
+            new_pages = pages._replace(
                 k=ys[0], v=ys[1],
                 k_scale=ys[2] if quantized else None,
                 v_scale=ys[3] if quantized else None,
@@ -1887,10 +1929,13 @@ class ServeEngine:
         place: layer ``l`` writes and attends pages ``l * NB + block``,
         and the donated buffer comes back as the result with nothing
         pool- or slab-sized copied (5 / 3.3 / 2.9 ms a tick of the
-        1.5B cell went on that, PERF.md §6 PR 25).  The one exception
-        is a pool the device does not keep row-major
-        (``_pool_is_row_major``), which goes through as ``xs`` / ``ys``
-        slabs.  No temp prefill cache, no
+        1.5B cell went on that, PERF.md §6 PR 25) — in the dense stack's
+        one scan and in every run of a hybrid stack's layers alike
+        (``hybrid_layers``; PR 38).  The one exception is a pool the
+        device does not keep row-major (``_pool_is_row_major``: int8
+        pages and their scale pages), which goes through as ``xs`` /
+        ``ys`` slabs (a hybrid stack: whole, written at ``[layer, block,
+        slot]``).  No temp prefill cache, no
         ``gather_prefix`` copy (shared prefix blocks are read in place),
         no separate sample dispatch (logits are gathered at each row's
         last packed token and sampled in-graph with the SAME
@@ -1996,9 +2041,14 @@ class ServeEngine:
                 blk = base + tok_blk
 
                 def put(pool, val):
+                    # the fresh rows in the form the pool holds a token
+                    # (a merged page: ``[tokens, K * D]``, 64 KB of a
+                    # decode tick): the value is reshaped, never the pool
                     if layer is None:
-                        return pool.at[blk, tok_off].set(val)
-                    return pool.at[layer, blk, tok_off].set(val)
+                        return pool.at[blk, tok_off].set(
+                            val.reshape(val.shape[:1] + pool.shape[2:]))
+                    return pool.at[layer, blk, tok_off].set(
+                        val.reshape(val.shape[:1] + pool.shape[3:]))
 
                 def slab(pool):
                     return pool if layer is None else pool[layer]
@@ -2074,29 +2124,32 @@ class ServeEngine:
                 return ((x, *kv_att), None) if carry_pool else (x, kv_att)
 
             loads = None
+            if carry_pool:
+                pools = tuple(a.reshape((n_paged * nb,) + a.shape[2:])
+                              for a in pools)
             if hybrid:
-                # runs of like layers carry the pool as the device keeps
-                # it, written in place at [layer, block, slot]
+                # every run of like layers carries the pool: flat like
+                # the dense scan's, or (a pool the device permutes) whole
+                # and written in place at [layer, block, slot]
                 x, new_pools, new_state, loads = hybrid_layers(
                     params["layers"], x, pools, state,
                     paged_hooks=paged_hooks, act=act, cos=cos, sin=sin,
-                    layers=layers, ops=o)
-                new_pages = PagedKV(*new_pools)._replace(state=new_state)
+                    layers=layers, ops=o, nb=nb)
             elif carry_pool:
                 xs = (params["layers"], is_sliding, layers)
-                (x, *flat), _ = lax.scan(
-                    layer_step,
-                    (x, *(a.reshape((n_paged * nb,) + a.shape[2:])
-                          for a in pools)),
-                    xs, unroll=scan_unroll(config))
-                new_pages = PagedKV(*(
-                    a.reshape(p.shape) for a, p in zip(flat, pools)))
+                (x, *new_pools), _ = lax.scan(
+                    layer_step, (x, *pools), xs, unroll=scan_unroll(config))
+                new_state = None
             else:
                 xs = (params["layers"], is_sliding, layers)
-                x, ys = lax.scan(layer_step, x, xs + pools,
-                                 unroll=scan_unroll(config))
-                new_pages = PagedKV(*ys)
-            new_pages = constrain_pages(new_pages)
+                x, new_pools = lax.scan(layer_step, x, xs + pools,
+                                        unroll=scan_unroll(config))
+                new_state = None
+            # (the arrays back in the pool's own shape: a bitcast again)
+            new_pages = constrain_pages(pages._replace(
+                **{name: a.reshape(p.shape) for name, a, p in zip(
+                    PagedKV._fields, new_pools, pages.pool_arrays())},
+                state=new_state))
             # sampling ONLY at each row's sample slots — [R, W] indices
             # into the dense axis: column 0 is the plain sample (decode
             # rows and completing prefill segments), columns 1..k' are a
@@ -2158,14 +2211,22 @@ class ServeEngine:
             return packed, new_pages
 
         def hybrid_layers(groups, x, pools, state, *, paged_hooks,
-                          act, cos, sin, layers, ops):
+                          act, cos, sin, layers, ops, nb):
             """The layer loop of a stack of more than one kind of layer:
             each run of like layers (``config.layer_groups``) is one scan
             over its own stacked leaves, and every run carries the pool
-            WHOLE, as the device keeps it: an attention layer writes at
-            ``[layer, block, slot]`` in place and attends its own slab
-            (``paged_hooks(layer=)``; no slab is written back, and no
-            reshape asks the device for another order).  What a sequence
+            the way the dense scan does, FLAT over (layer, block): an
+            attention layer scatters into its blocks ``layer * nb +
+            block`` in place and hands the kernel the flat pool with its
+            tables moved by that base — nothing pool- or slab-sized is
+            copied (tests/test_kernel_lowering.py; the whole-pool carry
+            it replaced handed the kernel ``pool[layer]``, a slab copy a
+            layer: PERF.md section 6, PR 38).  Only a pool the device
+            does not keep row-major (``carry_pool`` False: int8 pages and
+            their scale pages on a TPU) is carried WHOLE ``[L, NB, ..]``
+            instead, written at ``[layer, block, slot]`` and attended by
+            its slab (``paged_hooks(layer=)``), since a flat reshape
+            would relay all of it out.  What a sequence
             carries besides K/V (``state``: a convolution's history, a
             state-space mixer's recurrent state) is carried the same way,
             whole, and written in place at ``[layer, row]``: no run takes
@@ -2256,18 +2317,28 @@ class ServeEngine:
                         x = conv_block(w, x, config=config, history=history)
                     else:
                         kp, vp, *scale_pages = pool
-                        pool = []  # kv_update leaves the written arrays here
-                        kv_update, attn_fn = paged_hooks(
-                            kp, vp, scale_pages, 0, layer=at["paged"],
-                            written=pool)
+                        if carry_pool:
+                            kv_update, attn_fn = paged_hooks(
+                                kp, vp, scale_pages, at["paged"] * nb)
+                        else:
+                            written: list = []  # kv_update's writes
+                            kv_update, attn_fn = paged_hooks(
+                                kp, vp, scale_pages, 0, layer=at["paged"],
+                                written=written)
                         normed = (input_norm(w, x, config)
                                   if op == "attn_ssm" else None)
-                        mixed, _, _ = attention_block(
+                        mixed, kv_att, _ = attention_block(
                             w, x, config=config, cos=cos, sin=sin,
                             kv_update=kv_update, attn_fn=attn_fn,
                             normed=normed,
                         )
-                        pool = tuple(pool)
+                        if not carry_pool:
+                            pool = tuple(written)
+                        elif quantized:
+                            (kp2, ksp2), (vp2, vsp2) = kv_att
+                            pool = (kp2, vp2, ksp2, vsp2)
+                        else:
+                            pool = tuple(kv_att)
                     if op == "attn_ssm":
                         from llm_np_cp_tpu.ops.ssm import ssm_packed
 
@@ -4205,12 +4276,13 @@ class ServeEngine:
         live = qlen > 0
         last = (section("tile_qpos0") + qlen - 1)[live] // self.block_size
         first = section("pads")[section("tile_row")[live]] // self.block_size
-        k = self.pool.pages.k
+        pages = self.pool.pages
         # (the kv heads ONE chip holds: the kernel runs inside shard_map)
         shards = self.mesh.shape[MODEL_AXIS] if self._kv_sharded else 1
         per_step = ragged_pages_per_step(
-            self.max_blocks_per_seq, self.block_size, k.shape[-2] // shards,
-            k.shape[-1], k.dtype, self.cache_dtype == jnp.int8)
+            self.max_blocks_per_seq, self.block_size,
+            pages.kv_heads // shards, pages.head_dim, pages.k.dtype,
+            pages.quantized, merged=pages.merged)
         steps = (t_w // self._q_tile) * -(-self.max_blocks_per_seq // per_step)
         return int((last - first + 1).sum()), steps, per_step
 

@@ -1499,6 +1499,7 @@ class HttpServer:
             "pool_blocks_cache_only": stats["cache_only"],
             "pool_kv_bytes_shard": stats["kv_bytes_shard"],
             "pool_kv_shards": stats["kv_shards"],
+            **engine.pool_form_gauges(),
             "inflight_streams": self.runner.inflight,
             "queue_depth_live": engine.scheduler.queue_depth,
             "draining": 1.0 if self.draining else 0.0,

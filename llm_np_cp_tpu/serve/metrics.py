@@ -607,9 +607,10 @@ class ServeMetrics:
         torn reads).  ``extra_gauges`` lets the HTTP server add live
         gauges the metrics object cannot know (current queue depth, pool
         free blocks, in-flight streams), ``extra_counters`` the same for
-        counters (the threads' CPU clocks).  ``const_labels`` are spliced
-        into EVERY sample's labelset — how a multi-replica server tags
-        each engine's series with ``replica="N"`` so counters and
+        counters (the threads' CPU clocks); a key may carry labels of its
+        own (``pool_page_shape{shape="64x512"}``).  ``const_labels`` are
+        spliced into EVERY sample's labelset — how a multi-replica server
+        tags each engine's series with ``replica="N"`` so counters and
         histograms aggregate across the fleet.
         """
         s = self.snapshot()
@@ -925,7 +926,10 @@ class ServeMetrics:
         for kind, extras in (("gauge", extra_gauges),
                              ("counter", extra_counters)):
             for key, value in (extras or {}).items():
-                emit(key, kind, f"Live server {kind}", [("", float(value))])
+                # ``name{label="x"}``: a sample with labels of its own
+                name, brace, labels = key.partition("{")
+                emit(name, kind, f"Live server {kind}",
+                     [(brace + labels, float(value))])
         return "\n".join(lines) + "\n"
 
     def format(self) -> str:

@@ -460,12 +460,21 @@ def _ragged_reference(q, pages_k, pages_v, tables, tok, pads, window, *,
     return out
 
 
+def _merge(pages):
+    """``[NB, BS, K, D]`` → the same pool contents as the serve pool
+    stores a ``head_dim``-64 page: ``[NB, BS, K * D]``, heads side by
+    side (serve/block_pool.py)."""
+    return pages.reshape(pages.shape[:2] + (-1,))
+
+
 def _check_ragged(q, pages_k, pages_v, tables, segs, pads, *, scale,
                   window=_NO_WINDOW, logit_softcap=None, dead_tiles=0,
-                  scales=None, float_pages=None):
+                  scales=None, float_pages=None, merged=False):
     """Kernel == XLA twin == reference on the live lanes; the kernel's
     dead lanes are exactly zero (what the step scatters to scratch and
-    the host discards must never be NaN or another row's output)."""
+    the host discards must never be NaN or another row's output).
+    ``merged``: kernel and twin get the pages ``[NB, BS, K * D]``; the
+    reference keeps the 4-D pool they were made from."""
     from llm_np_cp_tpu.ops.pallas.decode_attention import (
         ragged_paged_attention,
         ragged_paged_attention_xla,
@@ -477,10 +486,12 @@ def _check_ragged(q, pages_k, pages_v, tables, segs, pads, *, scale,
     if scales is not None:
         kw.update(k_scale=scales[0], v_scale=scales[1])
     win = jnp.asarray(window, jnp.int32)
+    kernel_k, kernel_v = ((_merge(pages_k), _merge(pages_v)) if merged
+                          else (pages_k, pages_v))
     got = np.asarray(ragged_paged_attention(
-        q, pages_k, pages_v, tables, *tile, pads, win, **kw))
+        q, kernel_k, kernel_v, tables, *tile, pads, win, **kw))
     twin = np.asarray(ragged_paged_attention_xla(
-        q, pages_k, pages_v, tables, *tok, pads, win, **kw))
+        q, kernel_k, kernel_v, tables, *tok, pads, win, **kw))
     fk, fv = float_pages if float_pages is not None else (pages_k, pages_v)
     want = _ragged_reference(q, fk, fv, tables, tok, pads, window,
                              scale=scale, logit_softcap=logit_softcap)
@@ -502,8 +513,13 @@ def _packed_q(rng, segs, h, d, dead_tiles=0):
     return _rand(rng, (width, h, d))
 
 
+_PAGE_FORMS = pytest.mark.parametrize(
+    "merged", [False, True], ids=["4d", "merged"])
+
+
+@_PAGE_FORMS
 @pytest.mark.parametrize("h,kh", [(4, 4), (8, 2), (4, 1)])
-def test_ragged_matches_gathered_contiguous(h, kh):
+def test_ragged_matches_gathered_contiguous(h, kh, merged):
     rng = np.random.default_rng(h * 7 + kh)
     d, nbp, bs = 16, 8, 16
     pages_k = _rand(rng, (nbp, bs, kh, d))
@@ -513,7 +529,7 @@ def test_ragged_matches_gathered_contiguous(h, kh):
     segs = _decode_segs([40, 17, 64])  # mid-block, 1-past, full
     pads = jnp.asarray([3, 0, 10], jnp.int32)
     _check_ragged(_packed_q(rng, segs, h, d), pages_k, pages_v, tables,
-                  segs, pads, scale=d**-0.5)
+                  segs, pads, scale=d**-0.5, merged=merged)
 
 
 def test_ragged_softcap_parity():
@@ -609,9 +625,10 @@ _RAGGED_MIXES = {
 }
 
 
+@_PAGE_FORMS
 @pytest.mark.parametrize("h,kh", [(4, 4), (8, 2), (4, 1)])
 @pytest.mark.parametrize("mix", list(_RAGGED_MIXES))
-def test_ragged_row_mixes(mix, h, kh):
+def test_ragged_row_mixes(mix, h, kh, merged):
     """Every token of a multi-token segment attends causally INSIDE its
     own freshly written segment (the step scatters the whole packed
     batch before attending), under a sliding window narrower than the
@@ -626,10 +643,10 @@ def test_ragged_row_mixes(mix, h, kh):
     pads = jnp.asarray([3, 0, 10, 6], jnp.int32)
     q = _packed_q(rng, segs, h, d, dead_tiles)
     full = _check_ragged(q, pages_k, pages_v, tables, segs, pads,
-                         scale=d**-0.5, dead_tiles=dead_tiles)
+                         scale=d**-0.5, dead_tiles=dead_tiles, merged=merged)
     windowed = _check_ragged(q, pages_k, pages_v, tables, segs, pads,
                              scale=d**-0.5, window=12,
-                             dead_tiles=dead_tiles)
+                             dead_tiles=dead_tiles, merged=merged)
     # the window bit: contexts here are longer than 12 slots
     assert not np.allclose(full, windowed, atol=1e-3)
 
@@ -756,3 +773,99 @@ def test_ragged_group_cases_take_the_path_they_name(case):
     if int8:  # a scale a head: never whole lanes
         assert not _dma_slices_pages(
             jax.ShapeDtypeStruct((9, _BS, kh), jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# MERGED pages (PERF.md section 6, PR 38): a float pool whose head_dim is
+# short of a row of lanes is stored ``[NB, BS, K * D]``.  The kernel takes
+# such pages as they lie: a DMA can cut one out, the heads that share a row
+# of 128 lanes are scored by one dot, and each head's lanes are its own.
+# Same pool contents, both forms: the merged kernel == the 4-D kernel == the
+# XLA twin (on merged pages) == the reference.
+# ---------------------------------------------------------------------------
+
+# name: (h, kh, d, mb, segs, pads per row, window)
+_MERGED_CASES = {
+    # LFM2 / Llama-3.2-1B pages, K * D = 512: decode rows that end one page
+    # short of a group, on its edge, one past it and mid-group
+    "kd512-rows-of-P-1-P-P+1-pages": (
+        32, 8, 64, 16, _decode_segs([7 * _BS, 8 * _BS, 8 * _BS + 1, 449]),
+        [0, 70, 3, 0], _NO_WINDOW),
+    # K * D = 256: a prefill chunk of 16 tiles on one row beside a decode row
+    "kd256-prefill-chunk-of-16-tiles": (
+        8, 4, 64, 16, [(0, 410, 1), (1, 600, 128)], [0, 17], _NO_WINDOW),
+    # K * D = 128 (Qwen2.5-0.5B, one row of lanes): a sliding window that
+    # starts mid-group
+    "kd128-window-starts-mid-group": (
+        14, 2, 64, 16, [(0, 999, 1), (1, 640, 1), (2, 350, 5), (3, 700, 12)],
+        [0, 500, 0, 64], 300),
+    "kd128-dead-tiles-between-live-ones": (
+        4, 2, 64, 16,
+        [(0, 500, 1), (3, 0, 0), (1, 70, 1), (0, 0, 0), (2, 0, 0),
+         (2, 900, 3)],
+        [0, 9, 130, 0], _NO_WINDOW),
+    "kd512-verify-tiles-of-2-to-5": (
+        32, 8, 64, 16, [(0, 520, 2), (1, 300, 3), (2, 62, 4), (3, 510, 5)],
+        [0, 0, 7, 129], _NO_WINDOW),
+    # a table width P does not divide
+    "kd512-mb-42-not-a-multiple-of-P": (
+        16, 8, 64, 42, _decode_segs([42 * _BS, 41 * _BS - 6, 1100, 30]),
+        [5, 0, 200, 0], _NO_WINDOW),
+    # four heads of 32 to a row of lanes
+    "kd128-four-heads-of-32": (
+        8, 4, 32, 16, [(0, 600, 1), (1, 447, 1), (2, 512, 3)], [0, 0, 66],
+        _NO_WINDOW),
+    # heads that fill their own rows (nothing to take apart): still merged
+    "kd256-heads-of-128": (
+        8, 2, 128, 16, [(0, 1023, 1), (1, 100, 9), (2, 512, 1)], [0, 3, 0],
+        _NO_WINDOW),
+}
+
+
+@pytest.mark.parametrize("case", list(_MERGED_CASES))
+def test_ragged_merged_pages(case):
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        _dma_slices_pages,
+        _lane_pack,
+        ragged_pages_per_step,
+    )
+
+    h, kh, d, mb, segs, pads, window = _MERGED_CASES[case]
+    rng = np.random.default_rng(len(case) * 17 + h)
+    pages_k, pages_v, tables = _group_pool(rng, kh, d, mb, len(pads))
+    assert (kh * d) % 128 == 0 and f"kd{kh * d}" in case
+    # several groups a row, copied by the kernel's own DMAs
+    p = ragged_pages_per_step(mb, _BS, kh, d, pages_k.dtype, False, merged=True)
+    assert p == 8 and -(-mb // p) > 1
+    assert _dma_slices_pages(_merge(pages_k))
+    assert _lane_pack(kh, d) == max(128 // d, 1)
+    dead = 1
+    q = _packed_q(rng, segs, h, d, dead)
+    kw = dict(scale=d**-0.5, window=window, dead_tiles=dead)
+    pads = jnp.asarray(pads, jnp.int32)
+    got = _check_ragged(q, pages_k, pages_v, tables, segs, pads, merged=True,
+                        **kw)
+    plain = _check_ragged(q, pages_k, pages_v, tables, segs, pads, **kw)
+    np.testing.assert_allclose(got, plain, atol=2e-5)
+
+
+def test_ragged_merged_pages_refuse_what_they_cannot_be():
+    """int8 pages stay beside their scale pages, and a merged page is whole
+    heads of ``q``'s ``head_dim``."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        RAGGED_Q_TILE,
+        ragged_paged_attention,
+    )
+
+    one = jnp.zeros((1,), jnp.int32)
+    args = (jnp.zeros((1, 1), jnp.int32), one, one + 3, one + 1, one,
+            jnp.asarray(_NO_WINDOW, jnp.int32))
+    q = jnp.zeros((RAGGED_Q_TILE, 4, 8))
+    scales = jnp.zeros((2, 8, 2), jnp.float32)
+    with pytest.raises(ValueError, match="merged pages"):
+        ragged_paged_attention(
+            q, jnp.zeros((2, 8, 16), jnp.int8), jnp.zeros((2, 8, 16), jnp.int8),
+            *args, k_scale=scales, v_scale=scales, scale=0.35)
+    with pytest.raises(ValueError, match="merged pages"):
+        ragged_paged_attention(
+            q, jnp.zeros((2, 8, 12)), jnp.zeros((2, 8, 12)), *args, scale=0.35)

@@ -39,7 +39,7 @@ from llm_np_cp_tpu.models.transformer import (
 from llm_np_cp_tpu.ops.pallas.decode_attention import decode_attention
 from llm_np_cp_tpu.ops.pallas.flash_attention import flash_attention
 from llm_np_cp_tpu.serve import ServeEngine, opmap
-from llm_np_cp_tpu.serve.block_pool import PagedKV
+from llm_np_cp_tpu.serve.block_pool import PagedKV, PageForm
 
 
 def _export_tpu(fn, *args):
@@ -420,9 +420,11 @@ def test_decode_program_is_dense_outside_attention(v5e_sharding):
 # ----------------------------------------------------------------------
 
 def _lower_ragged(sharding, *, nt, mb, h, kh, d, dtype=jnp.bfloat16,
-                  block_s=64, blocks=1026, rows=64):
+                  block_s=64, blocks=1026, rows=64, merged=False,
+                  whole=False):
     """``ragged_paged_attention`` alone, compiled for the described v5e at
-    a cell's geometry → ``_ragged_kernel_call`` of the compiled text."""
+    a cell's geometry → ``_ragged_kernel_call`` of the compiled text (or,
+    ``whole``, the compiled program).  ``merged``: pages ``[BS, K * D]``."""
     from llm_np_cp_tpu.ops.pallas.decode_attention import (
         ragged_paged_attention,
     )
@@ -431,7 +433,7 @@ def _lower_ragged(sharding, *, nt, mb, h, kh, d, dtype=jnp.bfloat16,
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
     i32 = jnp.int32
-    page = aval((blocks, block_s, kh, d), dtype)
+    page = aval((blocks, block_s) + ((kh * d,) if merged else (kh, d)), dtype)
     args = [aval((nt * 8, h, d), jnp.bfloat16), page, page,
             aval((rows, mb), i32), aval((nt,), i32), aval((nt,), i32),
             aval((nt,), i32), aval((rows,), i32), aval((), i32)]
@@ -447,7 +449,7 @@ def _lower_ragged(sharding, *, nt, mb, h, kh, d, dtype=jnp.bfloat16,
                 *args, **kw).compile()
     finally:
         jax.default_backend = real
-    return _ragged_kernel_call(compiled.as_text())
+    return compiled if whole else _ragged_kernel_call(compiled.as_text())
 
 
 def _kernel_grid(module_text):
@@ -456,13 +458,15 @@ def _kernel_grid(module_text):
     return tuple(int(x) for x in bounds.split(","))
 
 
-def _kernel_vmem_scratch(module_text):
+def _kernel_vmem_scratch(module_text, rank=5):
     """Every VMEM scratch array of the kernel's signature holding pages
-    (rank 5: half, slot, block, heads, dim) as (shape, dtype name)."""
+    (rank 5: half, slot, block, heads, dim; rank 4 where the pages are
+    merged) as (shape, dtype name)."""
     sig = next(ln for ln in module_text.splitlines() if "^bb0(" in ln)
     return [(tuple(int(x) for x in dims.rstrip("x").split("x")), dt)
             for dims, dt in re.findall(
-                r"memref<((?:\d+x){5})(\w+), #tpu.memory_space<vmem>>", sig)]
+                r"memref<((?:\d+x){%d})(\w+), #tpu.memory_space<vmem>>"
+                % rank, sig)]
 
 
 @pytest.mark.parametrize("nt,mb,grid", [
@@ -499,7 +503,8 @@ def test_ragged_kernel_grid_is_tiles_by_groups_of_pages(
     assert f"{28 * 64}x64x{kh}x{d}xbf16" in module
 
 
-@pytest.mark.parametrize("kh,d,dtype,mb,block_s,want", [
+@pytest.mark.parametrize("kh,d,dtype,mb,block_s,want,merged", [
+    (kh, d, dtype, mb, block_s, want, False) for kh, d, dtype, mb, block_s, want in [
     # hand arithmetic (bytes in VMEM: the last two dims in whole tiles).
     # Qwen2.5-1.5B / 3B / the 7B's shard: a [64, 2, 128] bf16 page is
     # 32,768 B, K + V in two halves 131,072 B a slot: 64 would fit 8 MiB;
@@ -524,35 +529,88 @@ def test_ragged_kernel_grid_is_tiles_by_groups_of_pages(
     # a page so large that two halves of one slot fill the budget:
     # [64, 32, 256] f32 = 2 MiB, K + V x 2 halves = 8 MiB: 1
     (32, 256, jnp.float32, 16, 64, 1),
-], ids=["qwen-closed", "qwen-chat-open", "lfm2", "gemma2", "qwen-int8",
-        "gemma2-int8", "block-128", "narrow-table", "huge-page"])
-def test_pages_per_step_by_hand(kh, d, dtype, mb, block_s, want):
+]] + [
+    # merged pages [BS, K * D] take what they hold.  LFM2 as it is stored
+    # since PR 38: [64, 512] bf16 = 65,536 B, 262,144 a slot, 32 fit; 8
+    (8, 64, jnp.bfloat16, 16, 64, 8, True),
+    # 32 float32 heads of 64: [64, 32, 64] pads to 1 MiB a page, K + V in
+    # two halves 4 MiB: 2 fit - merged [64, 2048] is 512 KiB: 4
+    (32, 64, jnp.float32, 16, 64, 2, False),
+    (32, 64, jnp.float32, 16, 64, 4, True),
+], ids=["qwen-closed", "qwen-chat-open", "lfm2-unmerged", "gemma2",
+        "qwen-int8", "gemma2-int8", "block-128", "narrow-table", "huge-page",
+        "lfm2", "f32-32x64-unmerged", "f32-32x64-merged"])
+def test_pages_per_step_by_hand(kh, d, dtype, mb, block_s, want, merged):
     from llm_np_cp_tpu.ops.pallas.decode_attention import (
         ragged_pages_per_step,
     )
 
     assert ragged_pages_per_step(
-        mb, block_s, kh, d, dtype, dtype == jnp.int8) == want
+        mb, block_s, kh, d, dtype, dtype == jnp.int8, merged=merged) == want
 
 
-@pytest.mark.parametrize("h,kh,d,dtype", [
-    (16, 2, 128, jnp.bfloat16),   # Qwen2.5-3B: groups of 8
-    (32, 8, 64, jnp.bfloat16),    # LFM2 / Llama-3.2-1B: pages blocked
-    (8, 4, 256, jnp.bfloat16),    # Gemma-2 2B
-    (12, 2, 128, jnp.int8),       # int8 pool: every array blocked
-    (8, 4, 256, jnp.int8),        # int8 K / V copied, scales blocked
-    (4, 1, 128, jnp.bfloat16),    # one bf16 kv head: half a tile, blocked
-], ids=["qwen3b", "lfm2", "gemma2", "qwen-int8", "gemma2-int8", "kh1"])
+@pytest.mark.parametrize("h,kh,d,dtype,merged", [
+    (16, 2, 128, jnp.bfloat16, False),  # Qwen2.5-3B: groups of 8
+    # LFM2 / Llama-3.2-1B as the pool stores them: [64, 512] pages, which
+    # a DMA can cut - the kernel's own copies, at head_dim 64
+    (32, 8, 64, jnp.bfloat16, True),
+    (8, 4, 256, jnp.bfloat16, False),   # Gemma-2 2B
+    (12, 2, 128, jnp.int8, False),      # int8 pool: every array blocked
+    (8, 4, 256, jnp.int8, False),       # int8 K / V copied, scales blocked
+    (4, 1, 128, jnp.bfloat16, False),   # one bf16 kv head: half a tile, blocked
+    (32, 8, 64, jnp.bfloat16, False),   # [64, 8, 64] pages: blocked operands
+    (14, 2, 64, jnp.bfloat16, True),    # Qwen2.5-0.5B: one row of lanes, g = 7
+    (16, 4, 32, jnp.float32, True),     # four float32 heads to a row of lanes
+], ids=["qwen3b", "lfm2", "gemma2", "qwen-int8", "gemma2-int8", "kh1",
+        "lfm2-unmerged", "qwen0.5b", "f32-4x32"])
 def test_ragged_kernel_compiles_at_every_page_shape(
-        v5e_sharding, h, kh, d, dtype):
+        v5e_sharding, h, kh, d, dtype, merged):
     """The other page shapes the kernel serves, at the closed cells'
     geometry: each compiles for the v5e inside its scoped VMEM."""
     from llm_np_cp_tpu.ops.pallas import decode_attention as da
 
     _, _, module = _lower_ragged(
-        v5e_sharding, nt=64, mb=16, h=h, kh=kh, d=d, dtype=dtype)
-    p = da.ragged_pages_per_step(16, 64, kh, d, dtype, dtype == jnp.int8)
+        v5e_sharding, nt=64, mb=16, h=h, kh=kh, d=d, dtype=dtype,
+        merged=merged)
+    p = da.ragged_pages_per_step(
+        16, 64, kh, d, dtype, dtype == jnp.int8, merged=merged)
     assert _kernel_grid(module) == (64, -(-16 // p))
+    if merged:
+        # the pool stays in HBM as it lies (memory space "any", whole) and
+        # the kernel's own DMAs fetch it: two halves of P merged pages for
+        # K and for V, each taking in VMEM exactly what it holds
+        name = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+        # (rank 4 are also q's and the result's tile, ahead of them)
+        scratch = _kernel_vmem_scratch(module, rank=4)[-2:]
+        assert scratch == [((2, p, 64, kh * d), name)] * 2, scratch
+        assert module.count("#tpu.memory_space<any>") >= 2
+        assert f"1026x64x{kh * d}x{name}" in module
+
+
+def test_ragged_kernel_on_merged_pages_copies_no_pool(v5e_sharding):
+    """Why the pool is stored merged (PERF.md section 6, PR 38): handed a
+    ``[NB, 64, 8, 64]`` bf16 pool, which a v5e does not keep in that order,
+    the compiled call relays BOTH arrays out whole first (a copy of K and
+    one of V: two pools of temporaries); handed the same pages ``[NB, 64,
+    512]`` it copies nothing pool-shaped and holds no temporary."""
+    blocks = 4 * 1026
+    found = {}
+    for merged in (False, True):
+        compiled = _lower_ragged(
+            v5e_sharding, nt=64, mb=16, h=32, kh=8, d=64, blocks=blocks,
+            merged=merged, whole=True)
+        pool = opmap.hlo_shape("bfloat16", (blocks, 64) + (
+            (512,) if merged else (8, 64)))
+        ops = opmap.op_map_from_hlo(
+            compiled.as_text(), STEP_SCOPES, {pool: "pool"})
+        found[merged] = (
+            sorted(n for n, v in ops.items() if v[2] == "pool"),
+            compiled.memory_analysis().temp_size_in_bytes)
+    pool_bytes = blocks * 64 * 512 * 2
+    copies, temp = found[False]
+    assert len(copies) == 2 and all(n.startswith("copy") for n in copies)
+    assert temp >= 2 * pool_bytes
+    assert found[True] == ([], 0), found[True]
 
 
 def test_int8_pool_on_a_v5e_is_why_the_slab_form_stays(
@@ -605,12 +663,48 @@ class _Laid:
     (PagedKV(_Laid(0, 1, 2, 3, 4), _Laid(0, 1, 2, 3, 4),
              _Laid(0, 2, 3, 1), _Laid(0, 2, 3, 1)), False),
     (PagedKV(_Laid(0, 2, 3, 4, 1), _Laid(0, 2, 3, 4, 1)), False),
-], ids=["bf16-2x128", "int8-2x128", "int8-4x128", "bf16-8x64"])
+    # ...which is why such pages are stored merged, [BS, K * D] (PR 38)
+    (PagedKV(_Laid(0, 1, 2, 3), _Laid(0, 1, 2, 3), form=PageForm(64)), True),
+], ids=["bf16-2x128", "int8-2x128", "int8-4x128", "bf16-8x64",
+        "bf16-64x512-merged"])
 def test_the_step_carries_the_pool_where_the_device_keeps_it_row_major(
         pages, carried):
     from llm_np_cp_tpu.serve.engine import _pool_is_row_major
 
     assert _pool_is_row_major(pages) is carried
+
+
+@pytest.mark.parametrize("shape,dtype,row_major", [
+    # the LFM2 cell's pool as it was and as it is stored (PR 38)
+    ((4, 1026, 64, 8, 64), jnp.bfloat16, False),
+    ((4, 1026, 64, 512), jnp.bfloat16, True),
+    # float32 pages of head_dim 64 are permuted too: the rule is shapes
+    ((4, 1026, 64, 8, 64), jnp.float32, False),
+    ((4, 1026, 64, 512), jnp.float32, True),
+    # one row of lanes (Qwen2.5-0.5B: 2 x 64)
+    ((4, 1026, 64, 2, 64), jnp.bfloat16, False),
+    ((4, 1026, 64, 128), jnp.bfloat16, True),
+    # the pools that stay as they are: Qwen, Falcon-H1, Gemma-2
+    ((28, 1026, 64, 2, 128), jnp.bfloat16, True),
+    ((6, 1026, 64, 4, 128), jnp.bfloat16, True),
+    ((26, 1026, 64, 4, 256), jnp.bfloat16, True),
+], ids=["bf16-8x64", "bf16-512-merged", "f32-8x64", "f32-512-merged",
+        "bf16-2x64", "bf16-128-merged", "qwen", "falcon-h1", "gemma2"])
+def test_a_v5e_keeps_a_merged_page_in_the_order_of_its_shape(
+        v5e_sharding, shape, dtype, row_major):
+    """What ``Array.format`` of a pool says on the chip, asked of the
+    compiler for a described v5e: the layout it gives an array of this
+    shape as a program's argument and result.  ``block_pool.merges_pages``
+    states the same rule from the shapes alone."""
+    from llm_np_cp_tpu.serve.block_pool import merges_pages
+
+    compiled = jax.jit(lambda a: a.at[0, 0, 0].set(1)).lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)).compile()
+    kept = compiled.input_formats[0][0].layout.major_to_minor
+    assert (kept == tuple(range(len(shape)))) is row_major, kept
+    assert compiled.output_formats.layout.major_to_minor == kept
+    if len(shape) == 5:  # what the pool's rule makes of such heads
+        assert merges_pages(*shape[3:], False) is not row_major
 
 
 def test_a_pool_on_the_cpu_is_row_major():
@@ -622,26 +716,72 @@ def test_a_pool_on_the_cpu_is_row_major():
         assert _pool_is_row_major(pool.pages)
 
 
+def _assert_tick_copies_no_pool(engine, compiled, named=()):
+    """The compiled unified step of a stack whose layer loop carries the
+    pool flat over (layer, block): the donated arrays come back as the
+    result, every temporary of the step together is smaller than ONE
+    layer's slab of K, and no operation gives back something shaped like
+    the pool or like a layer's slab of it except the K/V write itself."""
+    pages = engine.pool.pages
+    assert engine.pool_carried
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    k_slab_bytes = int(np.prod(pages.k.shape[1:])) * pages.k.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < k_slab_bytes, (temp, k_slab_bytes)
+    pool = opmap.pool_shapes(
+        (a.dtype.name, a.shape) for a in pages.pool_arrays())
+    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, pool, named=named)
+    moved = {n: v for n, v in ops.items()
+             if v[2] and v[0] != SCOPE_KV_WRITE}
+    assert not moved, f"pool- or slab-shaped outside the K/V write: {moved}"
+    # what is left is the write: scatters on the carried (flat) pool
+    flat = opmap.hlo_shape(
+        pages.k.dtype.name,
+        (pages.k.shape[0] * pages.k.shape[1],) + pages.k.shape[2:])
+    writes = [v[1] for v in ops.values() if v[2]]
+    assert writes and set(writes) == {flat}, writes
+    return ops
+
+
+@pytest.mark.parametrize("form", ["flat-bf16-8x64", "whole-int8"])
 def test_hybrid_tick_on_a_v5e_names_its_experts_and_rebuilds_no_pool(
-        v5e_sharding, monkeypatch):
+        v5e_sharding, monkeypatch, form):
     """A stack of conv / attention layers over dense / expert feed-forwards
-    (LFM2-MoE's shapes in small: ``head_dim`` 64, which a v5e does not keep
-    row-major).  Two things its compiler did to earlier forms of the step
-    (PERF.md section 6, PR 32): a scan over stacked expert layers copied a
-    layer's expert tensors out of the stack every step, and concatenating
-    per-layer slabs became whole-pool pad + maximum passes."""
+    (LFM2-MoE's shapes in small: 8 kv heads of 64).  Two things its
+    compiler did to earlier forms of the step (PERF.md section 6, PR 32): a
+    scan over stacked expert layers copied a layer's expert tensors out of
+    the stack every step, and concatenating per-layer slabs became
+    whole-pool pad + maximum passes.  And the one PR 38 removed: the pool
+    ``[.., 64, 8, 64]``, which a v5e permutes, was relaid out whole on the
+    way in and out of every tick and a layer's slab copied for the kernel —
+    stored ``[.., 64, 512]`` it lies as its shape says, the loop carries it
+    flat (nothing forces ``_pool_is_row_major`` here: the CPU's pool and
+    the described v5e agree) and the tick copies nothing pool-shaped.  An
+    int8 pool is still permuted (pages and scale pages): that stack keeps
+    the whole-pool carry, written at [layer, block, slot]."""
     import llm_np_cp_tpu.serve.engine as engine_mod
 
-    # what Array.format says of such a pool on the chip; the CPU's says yes
-    monkeypatch.setattr(engine_mod, "_pool_is_row_major", lambda pages: False)
+    flat = form == "flat-bf16-8x64"
+    if not flat:
+        # what Array.format says of an int8 pool on the chip; the CPU's
+        # says yes
+        monkeypatch.setattr(
+            engine_mod, "_pool_is_row_major", lambda pages: False)
     cfg = tiny_config(
         "lfm2_moe", hidden_size=256, intermediate_size=512,
-        moe_intermediate_size=256, num_attention_heads=4,
-        num_key_value_heads=2, head_dim=64, vocab_size=2048)
+        moe_intermediate_size=256, num_attention_heads=16,
+        num_key_value_heads=8, head_dim=64, vocab_size=2048)
     engine, compiled = _compile_widest_bucket(
-        v5e_sharding, jnp.bfloat16, cfg=cfg, program=(SLOTS * 8, 8))
+        v5e_sharding, jnp.bfloat16 if flat else jnp.int8, cfg=cfg,
+        program=(SLOTS * 8, 8))
     text = compiled.as_text()
     assert "input_output_alias" in text  # the pool and the state come back in place
+    assert engine.pool.pages.merged is flat
+    if flat:
+        assert engine.pool.pages.k.shape[2:] == (BLOCK, 512)
+        _assert_tick_copies_no_pool(
+            engine, compiled, named=(("ragged-dot", "moe_experts"),))
     pool = opmap.pool_shapes(
         [(a.dtype.name, a.shape) for a in engine.pool.pages.pool_arrays()]
         + [(a.dtype.name, a.shape)
@@ -673,8 +813,38 @@ def test_hybrid_tick_on_a_v5e_names_its_experts_and_rebuilds_no_pool(
     assert any(v[1] == whole for v in ops.values()), "the state is not written"
 
 
+@pytest.fixture(scope="module")
+def falcon_h1_tick(v5e_sharding):
+    """The benchmark's state-space configuration at its published shapes,
+    its widest program compiled for the described v5e: (engine, compiled)."""
+    import json
+    from pathlib import Path
+
+    from llm_np_cp_tpu.config import ModelConfig
+
+    cfg = ModelConfig.from_hf_dict(json.loads((
+        Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+        / "falcon-h1-34b-6l.json").read_text()))
+    return _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, slots=64, blocks=1026,
+        chunk=128, program=(768, 320))
+
+
+def test_state_space_tick_on_a_v5e_copies_no_slab_of_the_pool(falcon_h1_tick):
+    """Falcon-H1's pages (``[64, 4, 128]`` bf16) a v5e keeps row-major, and
+    the whole-pool carry still handed the kernel ``pool[layer]``: a copy of
+    a layer's 67 MB K and V slab, six layers a tick
+    (``dynamic-slice_bitcast_fusion bf16[1026,64,4,128]``, PERF.md section
+    6, PR 38).  Carried flat, the scan over its six ``attn_ssm`` layers
+    scatters in place and the kernel reads the pool where it lies."""
+    engine, compiled = falcon_h1_tick
+    pages = engine.pool.pages
+    assert not pages.merged and pages.k.shape == (6, 1026, 64, 4, 128)
+    _assert_tick_copies_no_pool(engine, compiled)
+
+
 def test_state_space_tick_on_a_v5e_updates_the_state_in_place_row_by_row(
-        v5e_sharding):
+        falcon_h1_tick):
     """The benchmark's state-space configuration AT ITS PUBLISHED SHAPES (6
     layers of a Mamba-2 mixer beside attention, 64 slots: a recurrent state
     of 6 x 64 rows of 4 MiB float32, 1.5 GiB), its widest program - decode
@@ -686,17 +856,7 @@ def test_state_space_tick_on_a_v5e_updates_the_state_in_place_row_by_row(
     did), or gather a state row per TOKEN.  A gather of four rows out of the
     whole state was compiled as a pass over ALL of it (+1.5 GiB of
     temporaries): a prefill chunk's rows are sliced one at a time."""
-    import json
-    from pathlib import Path
-
-    from llm_np_cp_tpu.config import ModelConfig
-
-    cfg = ModelConfig.from_hf_dict(json.loads((
-        Path(__file__).resolve().parents[1] / "benchmark" / "configs"
-        / "falcon-h1-34b-6l.json").read_text()))
-    engine, compiled = _compile_widest_bucket(
-        v5e_sharding, jnp.bfloat16, cfg=cfg, slots=64, blocks=1026,
-        chunk=128, program=(768, 320))
+    engine, compiled = falcon_h1_tick
     assert engine.epilogue_impl == "fused"  # a head of 5,120 x 261,120
     text = compiled.as_text()
     assert "input_output_alias" in text

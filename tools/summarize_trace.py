@@ -936,6 +936,15 @@ def format_summary(events: list[dict], top: int = 5,
                     f"{sp['args'].get('dense', sp['args'].get('width'))}"
                     f"{'*' if sp['args'].get('compiled') else ''} "
                     f"{sp['dur_us'] / 1e6:.2f}" for sp in warm))
+        build = next((sp["args"] for sp in setup
+                      if sp["name"] == "engine_build"), {})
+        if "pool_carried" in build:
+            # how the tick's layer loop holds the K/V pool (PR 38)
+            lines.append(
+                f"  pool: pages {build.get('pool_page_shape')}, "
+                + ("carried flat over (layer, block) and written in place"
+                   if build["pool_carried"] else
+                   "moved by layer slabs (not row-major on this device)"))
         stray = stray_compiles(events)
         lines.append("  compiles outside set-up: " + (", ".join(
             f"{n} in {where}" for where, n in sorted(stray.items()))
