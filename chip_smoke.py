@@ -8,6 +8,7 @@ One process, one command, from the repo root:
     python chip_smoke.py --mesh model=2 --replicas 2   # 2 x (TP=2, kv-sharded)
     python chip_smoke.py --kernels                     # every Pallas kernel x shape
     python chip_smoke.py --state-space                 # recurrent state + scan, tiny preset
+    python chip_smoke.py --latent                      # latent (MLA) pool + its kernel, tiny preset
     JAX_PLATFORMS=cpu python chip_smoke.py --rehearsal # tiny preset, CPU
 
 It drives the path a user runs — ``llm_np_cp_tpu.cli serve`` with CLI
@@ -669,13 +670,22 @@ def run_serve(rep: Report, args) -> None:
         shutil.rmtree(workdir, ignore_errors=True)
 
 
-def run_state_space(rep: Report, rehearsal: bool) -> None:
-    """The tiny ``falcon_h1`` preset (a Mamba-2 mixer beside attention in
-    every layer) through ``ServeEngine`` directly for a few ticks - a
+# the tiny presets ``run_tiny_preset`` serves: flag -> (model type, what
+# the report calls it)
+TINY_PRESETS = {"state_space": ("falcon_h1", "state-space"),
+                "latent": ("deepseek_v3", "latent")}
+
+
+def run_tiny_preset(rep: Report, rehearsal: bool, which: str) -> None:
+    """A tiny preset through ``ServeEngine`` directly for a few ticks - a
     prompt of several prefill chunks beside decode rows, a slot reused -
     and every served token under ``models.forward`` of the same weights:
-    a one-minute check of the recurrent state and the scan (ops/ssm.py)
-    for whoever changes either."""
+    a one-minute check for whoever changes what the preset has and no
+    dense stack does.  ``state_space``: ``falcon_h1`` (a Mamba-2 mixer
+    beside attention in every layer: the recurrent state and the scan,
+    ops/ssm.py).  ``latent``: ``deepseek_v3`` (latent attention over a pool
+    of one row a token, ops/pallas/latent_attention.py; shared experts
+    beside sigmoid-routed ones)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -685,10 +695,11 @@ def run_state_space(rep: Report, rehearsal: bool) -> None:
     from llm_np_cp_tpu.ops.sampling import Sampler
     from llm_np_cp_tpu.serve import ServeEngine
 
-    cfg = tiny_config("falcon_h1")
+    model_type, name = TINY_PRESETS[which]
+    cfg = tiny_config(model_type)
     dtype = jnp.float32 if rehearsal else jnp.bfloat16
     params = init_params(jax.random.PRNGKey(SEED), cfg, dtype=dtype)
-    with rep.phase("state-space: engine build + serve"):
+    with rep.phase(f"{name}: engine build + serve"):
         engine = ServeEngine(
             params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
             num_blocks=32, block_size=8, max_seq_len=96, prefill_chunk=8,
@@ -698,21 +709,28 @@ def run_state_space(rep: Report, rehearsal: bool) -> None:
                               max_new_tokens=12, seed=i)
                 for i, n in enumerate((5, 37, 11))]  # the third reuses a slot
         engine.run_until_complete()
-    rep.facts["state_space"] = dict(
+    rep.facts[which] = dict(
         tick="unified" if engine.mixed else "split",
         ragged_attn=engine.ragged_attn_impl, epilogue=engine.epilogue_impl,
         dispatches=engine.n_dispatches, requests=[])
     rep.check(engine.mixed and engine.ragged_attn_impl == "pallas"
               and engine.epilogue_impl == "fused",
-              f"state-space stack served by the unified tick "
+              f"{name} stack served by the unified tick "
               f"(ragged attention {engine.ragged_attn_impl}, epilogue "
               f"{engine.epilogue_impl})")
-    state = engine.pool.pages.state["ssm"]
-    rep.check(state.dtype == jnp.float32 and float(jnp.abs(state).max()) > 0,
-              f"recurrent state {state.shape} {state.dtype.name} beside the "
-              "pool, written")
+    pages = engine.pool.pages
+    if which == "state_space":
+        state = pages.state["ssm"]
+        rep.check(state.dtype == jnp.float32 and float(jnp.abs(state).max()) > 0,
+                  f"recurrent state {state.shape} {state.dtype.name} beside "
+                  "the pool, written")
+    else:
+        rep.check(pages.latent and pages.v is None and engine.pool_carried
+                  and float(jnp.abs(pages.k.astype(jnp.float32)).max()) > 0,
+                  f"latent pool {pages.k.shape} {pages.k.dtype.name} (rows of "
+                  f"{pages.head_dim} values, no V beside them), written in place")
     plain = jax.jit(lambda p, ids: forward(p, ids, cfg)[0][0])
-    with rep.phase("state-space: plain forward"):
+    with rep.phase(f"{name}: plain forward"):
         for r in reqs:
             seq = list(r.prompt) + list(r.generated)
             ids = np.zeros((64,), np.int32)  # one shape; causal
@@ -723,12 +741,12 @@ def run_state_space(rep: Report, rehearsal: bool) -> None:
             top = at.max(-1)
             gap = (top - at[np.arange(len(r.generated)), r.generated]) / (
                 top - at.mean(-1))
-            rep.facts["state_space"]["requests"].append(dict(
+            rep.facts[which]["requests"].append(dict(
                 prompt_len=len(r.prompt), tokens=list(map(int, r.generated)),
                 worst_gap=float(gap.max())))
             rep.check(len(r.generated) == 12 and bool(np.isfinite(at).all())
                       and float(gap.max()) <= LOGIT_GAP_TOLERANCE,
-                      f"state-space request (prompt {len(r.prompt)}): "
+                      f"{name} request (prompt {len(r.prompt)}): "
                       f"{len(r.generated)} tokens, the worst sits "
                       f"{float(gap.max()):.2%} of the spread below the plain "
                       f"forward's maximum (tolerance {LOGIT_GAP_TOLERANCE:.0%})")
@@ -775,6 +793,11 @@ def main(argv: list[str] | None = None) -> int:
                     help="serve the tiny falcon_h1 preset (a state-space "
                     "mixer beside attention) for a few ticks and compare "
                     "with models.forward instead of driving the server")
+    ap.add_argument("--latent", action="store_true",
+                    help="serve the tiny deepseek_v3 preset (latent attention "
+                    "over a pool of one row a token, shared experts) for a "
+                    "few ticks and compare with models.forward instead of "
+                    "driving the server")
     ap.add_argument("--reference", default=None, metavar="REPORT.json",
                     help="an earlier run's report: compare token streams")
     ap.add_argument("--report", default=None, metavar="PATH",
@@ -833,11 +856,12 @@ def main(argv: list[str] | None = None) -> int:
     pkg_log.setLevel(logging.INFO)
     faulthandler.dump_traceback_later(WATCHDOG_S, exit=True,
                                       file=sys.__stderr__)
+    tiny = next((k for k in TINY_PRESETS if getattr(args, k)), None)
     try:
         if args.kernels:
             run_kernels(rep, args.rehearsal)
-        elif args.state_space:
-            run_state_space(rep, args.rehearsal)
+        elif tiny:
+            run_tiny_preset(rep, args.rehearsal, tiny)
         else:
             run_serve(rep, args)
     finally:
@@ -845,7 +869,7 @@ def main(argv: list[str] | None = None) -> int:
         pkg_log.setLevel(level)
         faulthandler.cancel_dump_traceback_later()
     rep.facts["log_warnings"] = watch.warnings
-    if not args.kernels and not args.state_space:
+    if not args.kernels and not tiny:
         rep.facts["shards_read_by"] = watch.shards
         say(f"shards read by: {watch.shards}")
     rep.check(not watch.warnings, f"{len(watch.warnings)} fallback warnings "
@@ -854,7 +878,7 @@ def main(argv: list[str] | None = None) -> int:
     rep.facts.update(phases=rep.phases, failures=rep.failures,
                      ok=not rep.failures)
 
-    topo = "kernels" if args.kernels else "state-space" if args.state_space else "-".join(
+    topo = "kernels" if args.kernels else TINY_PRESETS[tiny][1] if tiny else "-".join(
         filter(None, [args.mesh.replace("=", ""),
                       f"replicas{args.replicas}" if args.replicas > 1 else ""]))
     out = Path(args.report) if args.report else (

@@ -45,8 +45,11 @@ def align_capacity(n: int) -> int:
 
 
 class KVCache(NamedTuple):
-    k: jnp.ndarray  # [L, B, S_max, K, D]
-    v: jnp.ndarray  # [L, B, S_max, K, D]
+    # what a token leaves a layer is the configuration's
+    # (``ModelConfig.kv_token_shapes``): K and V per kv head, or latent
+    # attention's one row ``[c' | k_pe]`` in ``k`` and no ``v``
+    k: jnp.ndarray  # [L, B, S_max, K, D]; latent: [L, B, S_max, rank + rope]
+    v: jnp.ndarray | None  # [L, B, S_max, K, D]
     valid: jnp.ndarray  # [B, S_max] bool — written AND not a pad token
     length: jnp.ndarray  # int32 scalar
     # int8 cache mode (dtype=jnp.int8): per-token-per-head absmax scales;
@@ -85,20 +88,19 @@ class KVCache(NamedTuple):
         divisibility automatic.  ``init`` itself honours the exact value
         it is given so tests can build odd-capacity caches on purpose.
         """
-        shape = (
-            len(config.attn_layers),
-            batch_size,
-            max_seq_len,
-            config.num_key_value_heads,
-            config.head_dim,
-        )
+        lead = (len(config.attn_layers), batch_size, max_seq_len)
+        token = config.kv_token_shapes()
+        shape = lead + token["k"]
         quantized = dtype == jnp.int8
+        if quantized and config.is_latent:
+            raise NotImplementedError(
+                "an int8 cache of latent rows is not implemented")
         return cls(
             **{name: jnp.zeros(shp, dt) for name, (shp, dt) in
                config.state_shapes(
                    batch_size, jnp.bfloat16 if quantized else dtype).items()},
             k=jnp.zeros(shape, dtype=dtype),
-            v=jnp.zeros(shape, dtype=dtype),
+            v=jnp.zeros(shape, dtype=dtype) if "v" in token else None,
             valid=jnp.zeros((batch_size, max_seq_len), dtype=jnp.bool_),
             length=jnp.zeros((), dtype=jnp.int32),
             k_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
@@ -159,12 +161,12 @@ def update_layer(
     k_new = k_new.astype(k_layer.dtype)
     v_new = v_new.astype(v_layer.dtype)
     return (
-        _write_at(k_layer, k_new, offset),
-        _write_at(v_layer, v_new, offset),
+        write_at(k_layer, k_new, offset),
+        write_at(v_layer, v_new, offset),
     )
 
 
-def _write_at(slab: jnp.ndarray, new: jnp.ndarray, offset: jnp.ndarray) -> jnp.ndarray:
+def write_at(slab: jnp.ndarray, new: jnp.ndarray, offset: jnp.ndarray) -> jnp.ndarray:
     """dynamic_update_slice of ``new`` into ``slab`` along the seq axis
     (axis 1 of a [B, S_max, ...] array of any trailing rank), at a scalar
     offset or per-row [B] offsets (vmapped)."""
@@ -216,8 +218,8 @@ def update_layer_quantized(
     kq, ks = quantize_kv(k_new)
     vq, vs = quantize_kv(v_new)
     return (
-        _write_at(k_layer, kq, offset),
-        _write_at(v_layer, vq, offset),
-        _write_at(ks_layer, ks, offset),
-        _write_at(vs_layer, vs, offset),
+        write_at(k_layer, kq, offset),
+        write_at(v_layer, vq, offset),
+        write_at(ks_layer, ks, offset),
+        write_at(vs_layer, vs, offset),
     )

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from pathlib import Path
 from typing import Any, Mapping
 
@@ -127,6 +128,11 @@ class ModelConfig:
     # configuration's file sets it (key ``init_expert_specific``) and
     # says why; the program has no value of its own.
     init_expert_specific: float | None = None
+    # ... and the scale a random routed expert's output projection (``w2``)
+    # is drawn at (None: 0.02, every matrix's): how much of the residual
+    # stream one routed expert's answer is, which is what a flipped choice
+    # between two near-tied experts changes
+    init_expert_out_std: float | None = None
 
     # --- A state-space mixer in parallel with attention in every layer
     # (Falcon-H1, ``model_type: falcon_h1``).  ``mamba_d_ssm is None`` is
@@ -164,6 +170,33 @@ class ModelConfig:
     # see a broken state: PERF.md section 6, PR 34).
     init_ssm_in_proj_std: float | None = None
 
+    # --- Latent attention (MLA; ``model_type: deepseek_v3``).
+    # ``kv_lora_rank is None`` is every other family.  A token leaves ONE
+    # row a layer in a cache, ``[c' kv_lora_rank | k_pe qk_rope_head_dim]``
+    # (the compressed K/V after ``kv_a_layernorm``, and the one rotated
+    # key part all heads share), instead of K and V per kv head; a query
+    # head is ``[q_nope | q_pe]``, its value ``v_head_dim`` wide.
+    # ``head_dim`` is the rotated width (``qk_rope_head_dim``), so the
+    # RoPE tables are the ones every family builds.
+    kv_lora_rank: int | None = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # RoPE over pairs (2i, 2i+1) instead of (i, i + d/2): ops/rope.py
+    rope_interleave: bool = False
+    # shared experts beside the routed ones: ONE SwiGLU of this width
+    # (``n_shared_experts x moe_intermediate_size``) on every token
+    shared_expert_intermediate_size: int | None = None
+    # the share of the routed experts THIS program holds: the router is
+    # ``num_experts`` wide, the expert tensors ``num_experts_held`` from
+    # ``first_expert`` on (None: all of them); a pair whose expert is not
+    # held adds nothing here, that part of the sum is another holder's
+    num_experts_held: int | None = None
+    first_expert: int = 0
+    # what ``norm_topk_prob`` adds to the sum of a token's top-k scores
+    # (LFM2's published code: 1e-6; DeepSeek-V3's: 1e-20)
+    router_norm_eps: float = 1e-6
+
     def __post_init__(self) -> None:
         # Note: hidden_size need not equal heads*head_dim (Gemma-2-2B:
         # 2304 hidden, 8 heads of 256), so no divisibility constraint there.
@@ -178,6 +211,18 @@ class ModelConfig:
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"num_hidden_layers is {self.num_hidden_layers}"
             )
+        if self.is_latent and (
+                self.head_dim != self.qk_rope_head_dim
+                or self.qk_rope_head_dim % 2):
+            raise ValueError(
+                f"latent attention rotates head_dim {self.head_dim} columns: "
+                f"qk_rope_head_dim is {self.qk_rope_head_dim} (and is even)")
+        if self.num_experts is not None and not (
+                0 <= self.first_expert
+                and self.first_expert + self.experts_held <= self.num_experts):
+            raise ValueError(
+                f"experts {self.first_expert}..+{self.experts_held} are not "
+                f"among the router's {self.num_experts}")
         if self.mamba_d_ssm is not None:
             if self.mamba_d_ssm != self.mamba_n_heads * self.mamba_d_head:
                 raise ValueError(
@@ -209,10 +254,13 @@ class ModelConfig:
 
         Llama: 1/sqrt(head_dim) (llama3.2_model.py:467-469).  Gemma-2:
         query_pre_attn_scalar**-0.5 — the reference assigns this then ignores
-        it (gemma2_model.py:434); we apply it.
+        it (gemma2_model.py:434); we apply it.  Latent attention: the whole
+        query head's width, ``qk_nope_head_dim + qk_rope_head_dim``.
         """
         if self.query_pre_attn_scalar is not None:
             return float(self.query_pre_attn_scalar) ** -0.5
+        if self.is_latent:
+            return float(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
         return float(self.head_dim) ** -0.5
 
     def layer_is_sliding(self, layer_idx: int) -> bool:
@@ -221,14 +269,21 @@ class ModelConfig:
     # -- the per-layer declaration (what a layer IS, not a schedule) ----
     @property
     def is_hybrid(self) -> bool:
-        """The stack has more than one kind of layer: params are groups
-        of like layers (``layer_groups``), not one stacked pytree."""
-        return self.layer_types is not None or self.mamba_d_ssm is not None
+        """The stack is not the one scanned body every dense family has
+        (an operator other than GQA attention, or a routed feed-forward,
+        in any layer): params are groups of like layers
+        (``layer_groups``), not one stacked pytree."""
+        return any(
+            self.layer_op(i) != "attn" or self.layer_ff(i) != "dense"
+            for i in range(self.num_hidden_layers))
 
     def layer_op(self, layer_idx: int) -> str:
-        """``"attn"``, ``"conv"`` or ``"attn_ssm"`` (attention and a
-        state-space mixer side by side, both reading one normed input):
-        the operator of layer ``layer_idx``."""
+        """``"attn"``, ``"conv"``, ``"attn_ssm"`` (attention and a
+        state-space mixer side by side, both reading one normed input)
+        or ``"latent"`` (attention over one compressed row a token): the
+        operator of layer ``layer_idx``."""
+        if self.is_latent:
+            return "latent"
         if self.mamba_d_ssm is not None:
             return "attn_ssm"
         if self.layer_types is None:
@@ -243,10 +298,11 @@ class ModelConfig:
 
     @property
     def attn_layers(self) -> tuple[int, ...]:
-        """Layers that hold K/V, in order: the only ones a cache or a
-        pool has pages for (page ``i`` belongs to ``attn_layers[i]``)."""
+        """Layers that hold K/V (or a latent row), in order: the only
+        ones a cache or a pool has pages for (page ``i`` belongs to
+        ``attn_layers[i]``)."""
         return tuple(i for i in range(self.num_hidden_layers)
-                     if self.layer_op(i) in ("attn", "attn_ssm"))
+                     if self.layer_op(i) != "conv")
 
     @property
     def conv_layers(self) -> tuple[int, ...]:
@@ -293,6 +349,37 @@ class ModelConfig:
             out["ssm"] = ((n, slots, self.mamba_n_heads, self.mamba_d_head,
                            self.mamba_d_state), "float32")
         return out
+
+    @property
+    def is_latent(self) -> bool:
+        """Attention reads one compressed row a token (MLA)."""
+        return self.kv_lora_rank is not None
+
+    def kv_token_shapes(self) -> dict[str, tuple[int, ...]]:
+        """What ONE token leaves in a cache, a layer that has pages, as
+        ``{leaf: shape}``: K and V per kv head, or (latent attention) the
+        one row ``[c' | k_pe]`` whose first ``kv_lora_rank`` columns are
+        also the values, and no ``v``.  The ONE statement of it: the pool
+        (``PagedKV``), the offline cache (``KVCache``) and a
+        configuration's cost file read it (a store may pad a row to the
+        device's lanes: serve/block_pool.py says where)."""
+        if self.is_latent:
+            return {"k": (self.kv_lora_rank + self.qk_rope_head_dim,)}
+        kd = (self.num_key_value_heads, self.head_dim)
+        return {"k": kd, "v": kd}
+
+    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
+        """Bytes a token holds in a cache over all layers with pages, as
+        the algorithm needs them (``kv_token_shapes``; int8 scale pages
+        not counted)."""
+        return len(self.attn_layers) * itemsize * sum(
+            math.prod(shape) for shape in self.kv_token_shapes().values())
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this program holds."""
+        return (self.num_experts if self.num_experts_held is None
+                else self.num_experts_held)
 
     @property
     def expert_layers(self) -> tuple[int, ...]:
@@ -449,6 +536,72 @@ class ModelConfig:
                     "key_multiplier", "attention_in_multiplier",
                     "attention_out_multiplier", "ssm_in_multiplier",
                     "ssm_out_multiplier")},
+            )
+        if model_type == "deepseek_v3":
+            # DeepSeek-V3 family (Kanana-2): latent attention without a
+            # query latent, a leading dense block, then sigmoid-routed
+            # experts chosen by score + a correction bias (``noaux_tc``)
+            # beside shared experts; RoPE on the 64 rotated columns alone.
+            # What has no equations here is refused by its key.
+            if d.get("q_lora_rank") is not None:
+                raise ValueError(
+                    "deepseek_v3 with q_lora_rank (a query latent) is not "
+                    "implemented")
+            if rope_scaling is not None:
+                raise ValueError(
+                    "deepseek_v3 with rope_scaling (YaRN and its mscale) is "
+                    "not implemented")
+            if d.get("n_group", 1) > 1 or d.get("topk_group", 1) > 1:
+                raise ValueError(
+                    "deepseek_v3 with n_group / topk_group > 1 (group-limited "
+                    "routing) is not implemented")
+            if d.get("scoring_func", "sigmoid") != "sigmoid":
+                raise ValueError(
+                    f"deepseek_v3 with scoring_func "
+                    f"{d['scoring_func']!r} is not implemented (sigmoid)")
+            if d.get("topk_method", "noaux_tc") != "noaux_tc":
+                raise ValueError(
+                    f"deepseek_v3 with topk_method {d['topk_method']!r} is "
+                    "not implemented (noaux_tc)")
+            if d.get("moe_layer_freq", 1) != 1:
+                raise ValueError(
+                    "deepseek_v3 with moe_layer_freq != 1 is not implemented")
+            if d.get("attention_bias", False):
+                raise ValueError(
+                    "deepseek_v3 with attention_bias is not implemented")
+            rope_dim = d["qk_rope_head_dim"]
+            if d.get("head_dim", rope_dim) != rope_dim:
+                raise ValueError(
+                    f"deepseek_v3 head_dim {d['head_dim']} is not "
+                    f"qk_rope_head_dim {rope_dim}")
+            # ``n_routed_experts`` is what is HELD; a file that states one
+            # chip's share of a deployment names the router's width and
+            # the first expert held under keys of its own
+            held = d["n_routed_experts"]
+            router = d.get("router_experts", held)
+            shared = d.get("n_shared_experts") or 0
+            kwargs.update(
+                head_dim=rope_dim,
+                kv_lora_rank=d["kv_lora_rank"],
+                qk_nope_head_dim=d["qk_nope_head_dim"],
+                qk_rope_head_dim=rope_dim,
+                v_head_dim=d["v_head_dim"],
+                rope_interleave=d.get("rope_interleave", False),
+                num_experts=router,
+                num_experts_held=None if held == router else held,
+                first_expert=d.get("first_expert", 0),
+                num_experts_per_tok=d["num_experts_per_tok"],
+                num_dense_layers=d.get("first_k_dense_replace", 0),
+                moe_intermediate_size=d["moe_intermediate_size"],
+                shared_expert_intermediate_size=(
+                    shared * d["moe_intermediate_size"] or None),
+                use_expert_bias=True,  # e_score_correction_bias
+                norm_topk_prob=d.get("norm_topk_prob", True),
+                routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+                router_norm_eps=1e-20,
+                tie_word_embeddings=d.get("tie_word_embeddings", False),
+                init_expert_specific=d.get("init_expert_specific"),
+                init_expert_out_std=d.get("init_expert_out_std"),
             )
         if model_type == "qwen2":
             # Qwen-2/2.5: llama architecture with Q/K/V projection biases
@@ -608,7 +761,7 @@ QWEN_2_5_1_5B = dataclasses.replace(
 # when ``num_local_experts`` is set)
 KNOWN_MODEL_TYPES = frozenset(
     ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe",
-     "falcon_h1"))
+     "falcon_h1", "deepseek_v3"))
 
 PRESETS: dict[str, ModelConfig] = {
     "meta-llama/Llama-3.2-1B": LLAMA_3_2_1B,
@@ -688,6 +841,21 @@ def tiny_config(model_type: str = "llama", **overrides: Any) -> ModelConfig:
             # the state's share of the mixer's output level with the
             # skip's (at 0.02 it is a thousandth: a test could not see it)
             init_ssm_in_proj_std=0.2,
+        )
+    if model_type == "deepseek_v3":
+        # a leading dense block, then two expert layers: 8 routed experts
+        # top-2 beside a shared SwiGLU, latent attention over rows of
+        # 32 + 8 values; no width of the real model
+        base.update(
+            num_key_value_heads=4,
+            head_dim=8,
+            tie_word_embeddings=False,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, rope_interleave=True,
+            num_experts=8, num_experts_per_tok=2, num_dense_layers=1,
+            moe_intermediate_size=32, shared_expert_intermediate_size=64,
+            use_expert_bias=True, routed_scaling_factor=2.448,
+            router_norm_eps=1e-20,
         )
     base.update(overrides)
     return ModelConfig(**base)

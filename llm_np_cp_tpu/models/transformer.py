@@ -36,6 +36,7 @@ from jax import lax
 
 from llm_np_cp_tpu.cache import (
     KVCache,
+    write_at,
     dequantize_kv,
     update_layer,
     update_layer_quantized,
@@ -78,9 +79,12 @@ SCOPE_CONV = "conv"
 # recurrence itself with the state it reads and writes (ops/ssm.py)
 SCOPE_SSM_PROJ = "ssm_proj"
 SCOPE_SSM_SCAN = "ssm_scan"
+# shared experts beside the routed ones add one: the SwiGLU every token
+# takes (its input norm is the router's, under ``moe_route``)
+SCOPE_MOE_SHARED = "moe_shared"
 # ... which only a stack with such layers enters
 HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
-                 SCOPE_SSM_PROJ, SCOPE_SSM_SCAN)
+                 SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, SCOPE_MOE_SHARED)
 STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
                SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL) + HYBRID_SCOPES
 
@@ -165,9 +169,23 @@ def _group_shapes(
     NH, NK = config.num_attention_heads, config.num_key_value_heads
     if config.attention_bias or config.mlp_bias or config.conv_bias:
         raise NotImplementedError("a hybrid stack has no biased projection")
-    if op not in ("conv", "attn", "attn_ssm"):
+    if op not in ("conv", "attn", "attn_ssm", "latent"):
         raise ValueError(f"unknown layer operator {op!r}")
-    if op == "conv":
+    if op == "latent":
+        # latent attention: a query head is [q_nope | q_pe]; kv_a_proj's
+        # columns are [c | k_pe] (k_pe ONE for all heads), kv_b_proj's per
+        # head [k_nope | v], read from the normed c
+        dn, dr = config.qk_nope_head_dim, config.qk_rope_head_dim
+        rank, dv = config.kv_lora_rank, config.v_head_dim
+        shapes = {
+            "ln_attn_in": (n, H),
+            "q_proj": (n, H, NH * (dn + dr)),
+            "kv_a_proj": (n, H, rank + dr),
+            "ln_kv_a": (n, rank),
+            "kv_b_proj": (n, rank, NH * (dn + dv)),
+            "o_proj": (n, NH * dv, H),
+        }
+    elif op == "conv":
         shapes = {
             "ln_conv_in": (n, H),
             "in_proj": (n, H, 3 * H),  # B, C, x — in that order
@@ -200,11 +218,19 @@ def _group_shapes(
             shapes["ssm_conv_bias"] = (n, conv_dim)
     shapes["ln_mlp_in"] = (n, H)
     if ff == "experts":
+        # the router scores every expert of the layer; the tensors are
+        # the experts HELD (all of them unless the configuration states
+        # a share)
         E, I = config.num_experts, config.moe_intermediate_size
-        shapes.update(router=(n, H, E), w1=(n, E, H, I), w3=(n, E, H, I),
-                      w2=(n, E, I, H))
+        held = config.experts_held
+        shapes.update(router=(n, H, E), w1=(n, held, H, I),
+                      w3=(n, held, H, I), w2=(n, held, I, H))
         if config.use_expert_bias:
             shapes["expert_bias"] = (n, E)
+        if config.shared_expert_intermediate_size:
+            Is = config.shared_expert_intermediate_size
+            shapes.update(shared_gate=(n, H, Is), shared_up=(n, H, Is),
+                          shared_down=(n, Is, H))
     else:
         I = config.intermediate_size
         shapes.update(gate_proj=(n, H, I), up_proj=(n, H, I),
@@ -288,6 +314,8 @@ def init_params(
                 scale = 0.3 if name in ("conv_filter", "ssm_conv") else 0.02
                 if name == "ssm_in_proj" and config.init_ssm_in_proj_std:
                     scale = config.init_ssm_in_proj_std
+                if name == "w2" and config.init_expert_out_std:
+                    scale = config.init_expert_out_std
                 return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(dtype)
 
             return [make(k, p, s) for k, (p, s) in zip(keys, paths_leaves)]
@@ -583,6 +611,109 @@ def attention_block(
     return x, (k_att, v_att), attn_weights
 
 
+def latent_attention_block(
+    w: Params,
+    x: jnp.ndarray,
+    *,
+    config: ModelConfig,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    mask: jnp.ndarray | None = None,
+    kv_update: Any = None,
+    attn_fn: Any = None,
+    q_block: int = 256,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Latent attention (MLA, no query latent) with its residual:
+    ``(x_out, the cache rows as kv_update left them)``.
+
+    A token's cached row is ``[c' | k_pe]``: ``c' = rmsnorm(c)`` of the
+    compressed K/V and the ONE rotated key part every head shares, after
+    the norm and after RoPE.  Two forms of the same function:
+
+    - EXPANDED (``attn_fn is None``: ``models.forward``, what decides
+      ``correct``): ``c' Wkv_b`` gives every head its ``[k_nope | v]``,
+      ``k = [k_nope | k_pe]``, softmax attention over ``q_block`` queries
+      at a time (the scores of a whole batch never exist at once);
+    - ABSORBED (the serve tick): with ``Wkv_b`` cut per head into ``W_UK``
+      and ``W_UV``, ``score = (q_nope W_UK) . c' + q_pe . k_pe`` and
+      ``out = (softmax . c') W_UV`` — multi-query attention of all heads
+      over the cached rows themselves, whose first ``kv_lora_rank``
+      columns are also the values.  ``attn_fn(q_lat [b, s, heads, rank +
+      rope], rows) -> [b, s, heads, rank]`` is the caller's (a kernel
+      over pages).
+
+    kv_update: ``row [b, s, rank + rope] -> rows``: the cache write;
+        returns what attention reads (all rows so far ``[b, S, rank +
+        rope]`` for the expanded form, the pages for ``attn_fn``).
+    mask: bool ``[b, s, S]`` (expanded form only)."""
+    b, s = x.shape[:2]
+    nh = config.num_attention_heads
+    dn, dr = config.qk_nope_head_dim, config.qk_rope_head_dim
+    rank, dv = config.kv_lora_rank, config.v_head_dim
+    w_kv_b = w["kv_b_proj"].reshape(rank, nh, dn + dv)
+    with jax.named_scope(SCOPE_QKV):
+        h = input_norm(w, x, config)
+        q = _project(h, w["q_proj"]).reshape(b, s, nh, dn + dr)
+        kv_a = _project(h, w["kv_a_proj"])
+        c = rms_norm(kv_a[..., :rank], w["ln_kv_a"], eps=config.rms_norm_eps)
+        q_pe = apply_rope(q[..., dn:], cos, sin,
+                          interleave=config.rope_interleave)
+        k_pe = apply_rope(kv_a[..., None, rank:], cos, sin,
+                          interleave=config.rope_interleave)[..., 0, :]
+        row = jnp.concatenate([c, k_pe], axis=-1)
+        if attn_fn is not None:
+            q_lat = jnp.concatenate([
+                jnp.einsum("bshd,rhd->bshr", q[..., :dn], w_kv_b[..., :dn],
+                           preferred_element_type=jnp.float32).astype(q.dtype),
+                q_pe], axis=-1)
+
+    with jax.named_scope(SCOPE_KV_WRITE):
+        rows = kv_update(row) if kv_update is not None else row
+
+    with jax.named_scope(SCOPE_ATTN):
+        if attn_fn is not None:
+            o_lat = attn_fn(q_lat, rows)
+        else:
+            kv = jnp.einsum("bsr,rhd->bshd", rows[..., :rank].astype(q.dtype),
+                            w_kv_b, preferred_element_type=jnp.float32
+                            ).astype(q.dtype)
+            k = jnp.concatenate([
+                kv[..., :dn], jnp.broadcast_to(
+                    rows[:, :, None, rank:].astype(q.dtype),
+                    kv.shape[:3] + (dr,))], axis=-1)
+            q_full = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+            attn = _attend_in_query_blocks(
+                q_full, k, kv[..., dn:], mask, scale=config.attn_scale,
+                block=q_block)
+
+    with jax.named_scope(SCOPE_O_PROJ):
+        if attn_fn is not None:
+            attn = jnp.einsum("bshr,rhd->bshd", o_lat, w_kv_b[..., dn:],
+                              preferred_element_type=jnp.float32
+                              ).astype(q.dtype)
+        x = x + _project(attn.reshape(b, s, nh * dv), w["o_proj"], x.dtype)
+    return x, rows
+
+
+def _attend_in_query_blocks(q, k, v, mask, *, scale: float, block: int):
+    """``gqa_attention`` over ``block`` queries at a time (one kv head a
+    query head): ``[b, s, h, dv]``.  The float32 scores of 4 x 2,176
+    tokens x 32 heads at once would be 2.4 GB beside the weights."""
+    b, s = q.shape[:2]
+    if s <= block:
+        return gqa_attention(q, k, v, mask, scale=scale)
+    n = -(-s // block)
+    pad = n * block - s
+    qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    mp = jnp.pad(jnp.broadcast_to(mask, (b, s, k.shape[1])),
+                 ((0, 0), (0, pad), (0, 0)))
+    out = lax.map(
+        lambda qm: gqa_attention(qm[0], k, v, qm[1], scale=scale),
+        (jnp.moveaxis(qp.reshape(b, n, block, *q.shape[2:]), 1, 0),
+         jnp.moveaxis(mp.reshape(b, n, block, -1), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, n * block, *out.shape[3:])[:, :s]
+
+
 def input_norm(w: Params, x: jnp.ndarray, config: ModelConfig) -> jnp.ndarray:
     """A block's input norm, in the dtype the block computes in
     (``compute_dtype``: the gammas').  A residual stream kept wider (a
@@ -766,7 +897,10 @@ def experts_block(
     ``[b, s]`` marks real tokens (ops/moe.moe_dropless).  The router
     reads the normed activations in the residual stream's own dtype
     (float32 in a hybrid stack: nothing is rounded on the way to a
-    discrete choice); the experts multiply them in the served dtype."""
+    discrete choice); the experts multiply them in the served dtype.
+    The tensors are the experts HELD, from ``config.first_expert`` on
+    (``load`` counts those); shared experts, where the configuration has
+    them, are one SwiGLU on every token, added ONCE whatever is held."""
     b, s, hdim = x.shape
     with jax.named_scope(SCOPE_MOE_ROUTE):
         h = rms_norm(x, w["ln_mlp_in"], eps=config.rms_norm_eps)
@@ -776,11 +910,19 @@ def experts_block(
         top_k=config.num_experts_per_tok,
         norm_topk_prob=config.norm_topk_prob,
         scaling=config.routed_scaling_factor,
+        norm_eps=config.router_norm_eps,
         live=None if live is None else live.reshape(b * s),
+        first_expert=config.first_expert,
         out_dtype=x.dtype,
     )
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         x = x + out.reshape(b, s, hdim)
+    if "shared_gate" in w:
+        with jax.named_scope(SCOPE_MOE_SHARED):
+            hs = h.astype(w["shared_gate"].dtype)
+            x = x + _project(
+                act(_project(hs, w["shared_gate"]))
+                * _project(hs, w["shared_up"]), w["shared_down"], x.dtype)
     return x, chosen.reshape(b, s, -1), load
 
 
@@ -887,9 +1029,11 @@ def _hybrid_stack(
         xs: dict[str, Any] = {}
         if op != "conv":
             if cache is not None:
-                xs.update(k=cache.k[a0:a0 + n], v=cache.v[a0:a0 + n])
+                xs["k"] = cache.k[a0:a0 + n]
+                if cache.v is not None:  # a latent row has no V beside it
+                    xs["v"] = cache.v[a0:a0 + n]
             a0 += n
-        if op != "attn":
+        if op in ("conv", "attn_ssm"):
             xs.update({name: a[c0:c0 + n] for name, a in fresh.items()
                        if a is not None})
             c0 += n
@@ -903,7 +1047,16 @@ def _hybrid_stack(
                     state["conv"], z, state["conv"].shape[1] + 1)
                 return hist
 
-            if op != "conv":
+            if op == "latent":
+                x, rows = latent_attention_block(
+                    w, x, config=config, cos=cos, sin=sin, mask=mask,
+                    kv_update=(
+                        (lambda row: write_at(
+                            state["k"], row.astype(state["k"].dtype), offset))
+                        if cache is not None else None))
+                if cache is not None:
+                    ys["k"] = rows
+            elif op != "conv":
                 normed = input_norm(w, x, config) if op == "attn_ssm" else None
                 mixed, kv_att, _ = attention_block(
                     w, x, config=config, cos=cos, sin=sin, mask_global=mask,
@@ -943,6 +1096,7 @@ def _hybrid_stack(
         x, ys = scan_group(body, x, (w_g, xs), n)
         if "k" in ys:
             new_k.append(ys["k"])
+        if "v" in ys:
             new_v.append(ys["v"])
         if cache is not None:
             if "conv" in ys:

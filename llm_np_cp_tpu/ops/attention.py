@@ -71,7 +71,7 @@ def gqa_attention(
     """Attention over grouped KV heads.
 
     q: [B, Sq, H, D]  (H = K * G query heads)
-    k, v: [B, Skv, K, D]
+    k: [B, Skv, K, D]; v: [B, Skv, K, Dv]
     mask: bool, broadcastable to [B, Sq, Skv] (True = attend)
 
     Returns [B, Sq, H, D] in q.dtype (weights additionally if requested —
@@ -103,7 +103,8 @@ def gqa_attention(
         "bkgqs,bskd->bqkgd", probs.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     )
-    out = out.reshape(b, sq, h, d).astype(q.dtype)
+    # (a value head may be narrower than a query head: latent attention)
+    out = out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
     if return_weights:
         return out, probs.reshape(b, h, sq, skv)
     return out

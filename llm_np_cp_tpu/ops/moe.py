@@ -136,6 +136,7 @@ def route_sigmoid_topk(
     top_k: int,
     norm_topk_prob: bool = True,
     scaling: float = 1.0,
+    norm_eps: float = 1e-6,
     score_dtype: jnp.dtype = jnp.float32,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``(chosen experts [T, k] int32, their weights [T, k] f32)``.
@@ -148,8 +149,8 @@ def route_sigmoid_topk(
     a token are often a few bf16 ulps apart, and a flipped choice is a
     discrete change of the output that no dense layer has.  The top k are
     chosen by ``score + expert_bias``; the weights are the scores WITHOUT
-    the bias, divided by their sum + 1e-6 (``norm_topk_prob``), times
-    ``scaling``.  ``score_dtype`` exists for the tests that show a bf16
+    the bias, divided by their sum + ``norm_eps`` (``norm_topk_prob``; the
+    configuration's: LFM2 1e-6, DeepSeek-V3 1e-20), times ``scaling``.  ``score_dtype`` exists for the tests that show a bf16
     router fails the float32 tolerance."""
     logits = jnp.einsum(
         "th,he->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -161,7 +162,7 @@ def route_sigmoid_topk(
     _, idx = lax.top_k(select, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
     return idx.astype(jnp.int32), w * scaling
 
 
@@ -177,6 +178,7 @@ def moe_dropless(
     top_k: int,
     norm_topk_prob: bool = True,
     scaling: float = 1.0,
+    norm_eps: float = 1e-6,
     live: jnp.ndarray | None = None,  # [T] bool — False: routed nowhere
     first_expert: int = 0,
     out_dtype: jnp.dtype | None = None,
@@ -202,7 +204,7 @@ def moe_dropless(
     with jax.named_scope(SCOPE_MOE_ROUTE):
         idx, wts = route_sigmoid_topk(
             x, router_w, expert_bias, top_k=top_k,
-            norm_topk_prob=norm_topk_prob, scaling=scaling,
+            norm_topk_prob=norm_topk_prob, scaling=scaling, norm_eps=norm_eps,
         )
         local = idx - first_expert
         here = (local >= 0) & (local < held)

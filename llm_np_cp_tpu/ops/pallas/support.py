@@ -54,6 +54,7 @@ KERNELS = (
     "decode_attention", "decode_attention_int8",
     "paged_decode_attention", "paged_decode_attention_int8",
     "ragged_paged_attention", "ragged_paged_attention_int8",
+    "ragged_latent_attention",
     "sample_epilogue", "sample_epilogue_int8",
 )
 
@@ -80,6 +81,10 @@ class KernelShape:
     # lm-head layout the epilogue streams: the tied [V, H] embedding
     # table (every supported family) or an untied [H, V] head
     tied: bool = True
+    # latent attention (MLA): a cached row is ``[c' latent_rank | k_pe
+    # head_dim]`` with no head axis, which ``ragged_latent_attention``
+    # alone reads; None: K and V per kv head, every other kernel
+    latent_rank: int | None = None
 
     @classmethod
     def of(cls, name: str, config) -> "KernelShape":
@@ -111,6 +116,14 @@ PROBE_SHAPES = (
     PROBE_SHAPE,
     dataclasses.replace(PROBE_SHAPE, name="probe/untied", tied=False,
                         final_softcap=30.0, unit_offset=True),
+)
+# ... and the latent kernel's at the one published row it serves
+# (Kanana-2 / DeepSeek-V3: 32 heads over rows of 512 + 64 values, stored
+# 640 wide): its score sheet and its 576-of-640 columns ARE the layout
+# question, so a smaller probe would answer another one.
+LATENT_PROBE_SHAPE = KernelShape(
+    "probe/latent", heads=32, kv_heads=1, head_dim=64, hidden=256, vocab=300,
+    latent_rank=512,
 )
 
 
@@ -254,6 +267,26 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
             reference,
         )
 
+    # the mixed tick both ragged kernels' cases attend (six tiles over
+    # three rows of a 12-block table into a 40-block pool)
+    n_tiles, mb_r, nbp_r = 6, 12, 40
+
+    def ragged_tick(qt):
+        """``(tables, tile_row, tile_qpos0, tile_qlen, pads)``: host
+        constants (no device op, no compile of their own)."""
+        return (
+            jnp.asarray((np.arange(3 * mb_r) * 7 % 37 + 1).reshape(3, mb_r),
+                        jnp.int32),
+            jnp.asarray([0, 0, 1, 2, 2, 0], jnp.int32),
+            jnp.asarray([5, 5 + qt, 9 * bs + 7, bs - 4, bs - 4 + qt, 0],
+                        jnp.int32),
+            jnp.asarray([qt, qt - 3, 1, qt, qt, 0], jnp.int32),
+            jnp.asarray([5, bs + 2, 0], jnp.int32))
+
+    def live_lanes(tile_qlen, qt):
+        lane = jnp.arange(n_tiles * qt, dtype=jnp.int32) % qt
+        return lane, lane < jnp.repeat(tile_qlen, qt)
+
     if base == "ragged_paged_attention":
         from llm_np_cp_tpu.ops.pallas.decode_attention import (
             RAGGED_Q_TILE,
@@ -268,8 +301,6 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         # next group's copies are in flight under it —, row 2 prefills
         # its 2nd chunk across a block boundary, then a dead padding tile
         qt = RAGGED_Q_TILE
-        n_tiles = 6
-        mb_r, nbp_r = 12, 40
         window = jnp.int32(shape.window or (1 << 30))
 
         # the pages in the form the serve pool stores such heads in:
@@ -280,20 +311,7 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
 
         def make_args():
             q, pages = normals((n_tiles * qt, h, d), (nbp_r, bs) + page)
-            # (a host constant: no device op, no compile of its own)
-            tables = jnp.asarray(
-                (np.arange(3 * mb_r) * 7 % 37 + 1).reshape(3, mb_r), jnp.int32)
-            tile_row = jnp.asarray([0, 0, 1, 2, 2, 0], jnp.int32)
-            tile_qpos0 = jnp.asarray(
-                [5, 5 + qt, 9 * bs + 7, bs - 4, bs - 4 + qt, 0], jnp.int32)
-            tile_qlen = jnp.asarray([qt, qt - 3, 1, qt, qt, 0], jnp.int32)
-            pads = jnp.asarray([5, bs + 2, 0], jnp.int32)
-            return (q, tables, tile_row, tile_qpos0, tile_qlen, pads,
-                    *kv_operands(pages))
-
-        def live_lanes(tile_qlen):
-            lane = jnp.arange(n_tiles * qt, dtype=jnp.int32) % qt
-            return lane, lane < jnp.repeat(tile_qlen, qt)
+            return (q, *ragged_tick(qt), *kv_operands(pages))
 
         # dead lanes are unspecified in both implementations: zero them
         def run(q, tables, tile_row, tile_qpos0, tile_qlen, pads, *ops):
@@ -301,15 +319,60 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
                 q, ops[0], ops[1], tables, tile_row, tile_qpos0, tile_qlen,
                 pads, window, scale=scale, logit_softcap=softcap,
                 interpret=interpret, **kv_kwargs(ops))
-            return jnp.where(live_lanes(tile_qlen)[1][:, None, None], out, 0)
+            return jnp.where(
+                live_lanes(tile_qlen, qt)[1][:, None, None], out, 0)
 
         def reference(q, tables, tile_row, tile_qpos0, tile_qlen, pads,
                       *ops):
-            lane, live = live_lanes(tile_qlen)
+            lane, live = live_lanes(tile_qlen, qt)
             out = ragged_paged_attention_xla(
                 q, ops[0], ops[1], tables, jnp.repeat(tile_row, qt),
                 jnp.repeat(tile_qpos0, qt) + lane, live, pads, window,
                 scale=scale, logit_softcap=softcap, **kv_kwargs(ops))
+            return jnp.where(live[:, None, None], out, 0)
+
+        return make_args, run, reference
+
+    if base == "ragged_latent_attention":
+        from llm_np_cp_tpu.ops.pallas.decode_attention import RAGGED_Q_TILE
+        from llm_np_cp_tpu.ops.pallas.latent_attention import (
+            ragged_latent_attention,
+            ragged_latent_attention_xla,
+        )
+        from llm_np_cp_tpu.serve.block_pool import latent_page_width
+
+        # the ragged case's mixed tick (a 2-tile chunk, a decode row past
+        # the first group of pages, a chunk across a block boundary, a
+        # dead tile) over pages of latent rows, stored as the pool stores
+        # them: zeros past ``rank + rope``
+        qt = RAGGED_Q_TILE
+        rank = shape.latent_rank
+        row = rank + d
+        width = latent_page_width(row)
+
+        def make_args():
+            q, pool = normals((n_tiles * qt, h, width), (nbp_r, bs, width))
+            live = jnp.arange(width) < row
+            return (jnp.where(live, q, 0), jnp.where(live, pool, 0),
+                    *ragged_tick(qt))
+
+        # (a score is a sum over ``row`` products of unit normals: the
+        # scale keeps the softmax off one-hot, where bf16 ``p`` is exact)
+        scale = float(row) ** -0.5 / 4
+
+        def run(q, pool, tables, tile_row, tile_qpos0, tile_qlen, pads):
+            out = ragged_latent_attention(
+                q, pool, tables, tile_row, tile_qpos0, tile_qlen, pads,
+                scale=scale, rank=rank, interpret=interpret)
+            return jnp.where(
+                live_lanes(tile_qlen, qt)[1][:, None, None], out, 0)
+
+        def reference(q, pool, tables, tile_row, tile_qpos0, tile_qlen, pads):
+            lane, live = live_lanes(tile_qlen, qt)
+            out = ragged_latent_attention_xla(
+                q[..., :row], pool, tables, jnp.repeat(tile_row, qt),
+                jnp.repeat(tile_qpos0, qt) + lane, live, pads,
+                scale=scale, rank=rank)
             return jnp.where(live[:, None, None], out, 0)
 
         return make_args, run, reference
@@ -367,10 +430,13 @@ def kernel_cases(shapes=None):
     """Every ``(kernel, shape, block_size)`` the on-chip matrix covers:
     all of ``KERNELS`` at the probe shapes and the three family shapes,
     the paged kernels at both serve block sizes."""
-    shapes = shapes if shapes is not None else (*PROBE_SHAPES,
-                                                *family_shapes())
+    shapes = shapes if shapes is not None else (
+        *PROBE_SHAPES, LATENT_PROBE_SHAPE, *family_shapes())
     for shape in shapes:
         for kernel in KERNELS:
+            if (kernel == "ragged_latent_attention") != (
+                    shape.latent_rank is not None):
+                continue  # latent rows and their one kernel
             if not shape.tied and not kernel.startswith("sample_epilogue"):
                 continue  # only the epilogue distinguishes head layouts
             paged = kernel.startswith(("paged_", "ragged_"))
@@ -417,8 +483,9 @@ def _probe(kernel: str, backend: str) -> str | None:
         return "forced failure (test hook)"
     if backend != "tpu":
         return None
+    latent = kernel == "ragged_latent_attention"
     try:
-        for shape in PROBE_SHAPES:
+        for shape in (LATENT_PROBE_SHAPE,) if latent else PROBE_SHAPES:
             if shape.tied or kernel.startswith("sample_epilogue"):
                 make_args, run, _ = kernel_case(kernel, shape,
                                                 SERVE_BLOCK_SIZES[0])
@@ -438,11 +505,14 @@ def paged_kernel_name(int8_cache: bool) -> str:
     )
 
 
-def ragged_kernel_name(int8_cache: bool) -> str:
+def ragged_kernel_name(int8_cache: bool, latent: bool = False) -> str:
     """Probe/kernel name for the mixed prefill+decode ragged kernel
     (the unified-tick dispatch) — same one-rule discipline as
     ``paged_kernel_name``, shared by the engine's ``mixed_step`` gate
-    and the CLI's pre-build check."""
+    and the CLI's pre-build check.  ``latent``: the pool holds latent
+    rows, which a kernel of its own reads."""
+    if latent:
+        return "ragged_latent_attention"
     return (
         "ragged_paged_attention_int8" if int8_cache
         else "ragged_paged_attention"

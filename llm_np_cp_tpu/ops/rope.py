@@ -69,16 +69,30 @@ def rotate_half(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([-x2, x1], axis=-1)
 
 
+def deinterleave(x: jnp.ndarray) -> jnp.ndarray:
+    """``[x0, x1, x2, ..] -> [x0, x2, .. | x1, x3, ..]`` on the last
+    axis: a checkpoint that rotates the PAIRS ``(2i, 2i+1)``
+    (``rope_interleave``, DeepSeek-V3) holds them side by side; moved
+    apart, pair ``i`` is ``(i, i + d/2)`` and ``rotate_half`` rotates it.
+    Queries and keys are both left in the moved order: a dot product
+    does not see a permutation both sides share."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
 def apply_rope(
-    x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
+    x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray, *,
+    interleave: bool = False,
 ) -> jnp.ndarray:
     """Rotate ``x``: [..., S, n_heads, head_dim] with cos/sin [..., S, head_dim].
+    ``interleave``: ``x`` holds its pairs side by side (``deinterleave``).
 
     The head axis sits between the sequence axis and head_dim, so cos/sin
     broadcast with one unsqueeze (the reference's ``unsqueeze_dim=1`` on a
     [b, h, s, d] layout — llama3.2_model.py:77-82; we keep [b, s, h, d]
     because it writes into the KV cache without a transpose).
     """
+    if interleave:
+        x = deinterleave(x)
     cos = cos[..., None, :]
     sin = sin[..., None, :]
     return (x * cos + rotate_half(x) * sin).astype(x.dtype)

@@ -185,6 +185,12 @@ def paged_kv_specs(config: ModelConfig, plan: MeshPlan,
         merges_pages,
     )
 
+    if config.is_latent:
+        # one row a token and no head axis to cut: the pool is whole on
+        # every device of a placement mesh (the engine refuses model > 1)
+        return normalize_specs(PagedKV(
+            k=P(), v=None,
+            form=PageForm(config.kv_token_shapes()["k"][0], latent=True)))
     kv = MODEL_AXIS if _kv_heads_shardable(config, plan) else None
     scale = P(None, None, None, kv) if quantized else None
     merged = merges_pages(

@@ -159,22 +159,38 @@ def merges_pages(kv_heads: int, head_dim: int, quantized: bool) -> bool:
             and (kv_heads * head_dim) % 128 == 0)
 
 
+def latent_page_width(width: int) -> int:
+    """Columns a pool gives a latent row ``[c' | k_pe]`` of ``width``
+    values (latent attention: one row a token, no head axis): whole rows
+    of 128 lanes, zeros past ``width`` (576 -> 640).  Compiled for a
+    described v5e (tests/test_kernel_lowering.py): a ``[.., BS, 576]``
+    array is NOT kept in the order of its shape (the block axis becomes
+    the minor one), nor is ``k_pe`` as a ``[.., BS, 64]`` array of its
+    own; ``[.., BS, 640]`` is, and a DMA cuts a page out of it."""
+    return -(-width // 128) * 128
+
+
 @jax.tree_util.register_static
 @dataclasses.dataclass(frozen=True)
 class PageForm:
-    """What a merged page's shape no longer says: a leafless node of the
-    ``PagedKV`` pytree, so it rides every jitted step as part of the
-    tree's structure."""
+    """What a page's shape no longer says (a merged page's ``head_dim``;
+    a latent page's unpadded row width, as ``head_dim``, and that it is
+    one): a leafless node of the ``PagedKV`` pytree, so it rides every
+    jitted step as part of the tree's structure."""
 
     head_dim: int
+    latent: bool = False
 
 
 class PagedKV(NamedTuple):
     """Device-side pages: the pytree the engine's jitted steps thread
     through (and donate).  Scales are None for float pools."""
 
-    k: jnp.ndarray  # [L, NB, BS, K, D], or merged [L, NB, BS, K * D]
-    v: jnp.ndarray
+    # [L, NB, BS, K, D], or merged [L, NB, BS, K * D], or latent rows
+    # [L, NB, BS, latent_page_width(rank + rope)] with no ``v`` beside
+    # them (the rows' first ``rank`` columns are the values)
+    k: jnp.ndarray
+    v: jnp.ndarray | None
     k_scale: jnp.ndarray | None = None  # [L, NB, BS, K] f32 (int8 mode)
     v_scale: jnp.ndarray | None = None
     # what a sequence carries besides K/V (module docstring): not paged,
@@ -189,18 +205,26 @@ class PagedKV(NamedTuple):
         return self.k_scale is not None
 
     @property
+    def latent(self) -> bool:
+        """Pages of latent rows: one array, no head axis, no ``v``."""
+        return self.form is not None and self.form.latent
+
+    @property
     def merged(self) -> bool:
         """Pages stored ``[BS, K * D]``, the kv heads side by side."""
-        return self.form is not None
+        return self.form is not None and not self.form.latent
 
     @property
     def head_dim(self) -> int:
-        return self.form.head_dim if self.merged else self.k.shape[-1]
+        """A kv head's width (a latent page: its row's, unpadded)."""
+        return self.form.head_dim if self.form else self.k.shape[-1]
 
     @property
     def kv_heads(self) -> int:
         """The kv heads of the array as the caller holds it (one shard's
         inside ``shard_map``)."""
+        if self.latent:
+            return 1
         if self.merged:
             return self.k.shape[-1] // self.head_dim
         return self.k.shape[-2]
@@ -256,10 +280,19 @@ class BlockPool:
         else:
             self.prefix_cache = None
         quantized = self.dtype == jnp.int8
-        kh, d = config.num_key_value_heads, config.head_dim
-        merged = merges_pages(kh, d, quantized)
-        shape = (len(config.attn_layers), num_blocks, block_size) + (
-            (kh * d,) if merged else (kh, d))
+        # what a token leaves a layer is the configuration's statement
+        token = config.kv_token_shapes()
+        latent = config.is_latent
+        if latent and quantized:
+            raise ValueError("an int8 pool of latent rows is not implemented")
+        if latent:
+            (d,), merged = token["k"], False
+            page: tuple[int, ...] = (latent_page_width(d),)
+        else:
+            kh, d = token["k"]
+            merged = merges_pages(kh, d, quantized)
+            page = (kh * d,) if merged else (kh, d)
+        shape = (len(config.attn_layers), num_blocks, block_size) + page
         # mesh-sharded mode: a PagedKV of NamedShardings (kv-head axis on
         # "model", see parallel/sharding.paged_kv_specs) commits the slabs
         # onto the mesh; the FREE LIST stays global — allocation is a
@@ -287,12 +320,12 @@ class BlockPool:
 
         self.pages = PagedKV(
             k=zeros(shape, dtype, where.k),
-            v=zeros(shape, dtype, where.v),
+            v=None if latent else zeros(shape, dtype, where.v),
             k_scale=(zeros(shape[:-1], jnp.float32, where.k_scale)
                      if quantized else None),
             v_scale=(zeros(shape[:-1], jnp.float32, where.v_scale)
                      if quantized else None),
-            form=PageForm(d) if merged else None,
+            form=PageForm(d, latent) if merged or latent else None,
         )
 
         # what a sequence carries besides K/V (module docstring): the

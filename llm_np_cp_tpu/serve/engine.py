@@ -133,6 +133,7 @@ from llm_np_cp_tpu.models.transformer import (
     final_logits,
     forward,
     input_norm,
+    latent_attention_block,
     run_decoder_layer,
     scan_group,
     scan_unroll,
@@ -500,6 +501,31 @@ class ServeEngine:
                     raise ValueError(
                         f"model_type {config.model_type!r} has {kind} layers "
                         f"with a recurrent state; refused: {why}")
+        if config.is_latent:
+            # a latent pool holds one row a token and layer, no K and V
+            # per head: what reads, moves, quantizes or cuts K/V pages by
+            # head has no rule for it yet, and is refused here by the
+            # flag that asked for it (nothing of it is silently wrong)
+            refused = [
+                (host_tier is not None, "--kv-tier host (host_tier): the "
+                 "tier spills and restores K and V pages"),
+                (enable_prefix_cache, "--prefix-cache (enable_prefix_cache): "
+                 "shared latent blocks are untested"),
+                (spec_k > 0, "--speculative-serve / --spec-k (spec_k): the "
+                 "verifier's lanes are untested on latent pages"),
+                (jnp.dtype(cache_dtype) == jnp.int8, "--cache-dtype int8 "
+                 "(cache_dtype): a latent row has no per-head scale"),
+                (mesh_plan is not None and mesh_plan.model > 1,
+                 "--mesh model>1 (mesh_plan): a latent row has no head "
+                 "axis to cut, and the expert share no sharding rule"),
+                (mixed_step == "off", "--mixed-step off (mixed_step): only "
+                 "the unified tick reads latent pages"),
+            ]
+            for hit, why in refused:
+                if hit:
+                    raise ValueError(
+                        f"model_type {config.model_type!r} keeps a latent "
+                        f"(compressed) KV cache; refused: {why}")
         from llm_np_cp_tpu.ops.pallas.support import (
             gate_attn_impl,
             kernel_error,
@@ -605,7 +631,8 @@ class ServeEngine:
             self.mixed = False
         else:
             t_probe = tracer.now_us() if tracer is not None else -1.0
-            err = kernel_error(ragged_kernel_name(int8_cache))
+            err = kernel_error(
+                ragged_kernel_name(int8_cache, latent=config.is_latent))
             if tracer is not None:
                 tracer.complete("probe.ragged_attn", t_probe, cat="setup",
                                 args={"ok": err is None})
@@ -622,11 +649,12 @@ class ServeEngine:
                 self.mixed, self.ragged_attn_impl = True, "xla"
             else:
                 self.mixed = False
-        if config.carries_state and not self.mixed:
+        if (config.carries_state or config.is_latent) and not self.mixed:
             raise ValueError(
-                f"model_type {config.model_type!r} carries a recurrent state "
-                "and is served by the unified tick only, which is unavailable "
-                f"here ({err}); --mixed-step on takes its XLA attention")
+                f"model_type {config.model_type!r} (a recurrent state, or a "
+                "latent cache) is served by the unified tick only, which is "
+                f"unavailable here ({err}); --mixed-step on takes its XLA "
+                "attention")
         # -- speculative serving (draft-then-verify in the unified tick):
         # per-request host-side prompt-lookup draft streams propose up to
         # spec_k tokens; the mixed step packs each speculating request as
@@ -953,6 +981,14 @@ class ServeEngine:
                 "state_bytes": int(sum(a.nbytes for a in jax.tree.leaves(
                     self.pool.pages.state))),
                 "state_slots": max_slots,
+                # what a token holds in the pool over all layers, as the
+                # algorithm needs it (``ModelConfig.kv_token_shapes``) and
+                # as the pool stores it; the routed experts held
+                "page_bytes_per_token": config.kv_bytes_per_token(
+                    self.cache_dtype.itemsize),
+                "pool_bytes_per_token": self._block_nbytes // block_size,
+                "experts_held": (config.experts_held
+                                 if config.num_experts else 0),
             })
 
     def _make_buckets(
@@ -1961,6 +1997,10 @@ class ServeEngine:
             ragged_paged_attention,
             ragged_paged_attention_xla,
         )
+        from llm_np_cp_tpu.ops.pallas.latent_attention import (
+            ragged_latent_attention,
+            ragged_latent_attention_xla,
+        )
 
         config, sampler = self.config, self.sampler
         quantized = self.cache_dtype == jnp.int8
@@ -1985,6 +2025,11 @@ class ServeEngine:
 
         hybrid = config.is_hybrid
         max_slots = geometry[1]
+        if config.is_latent and not carry_pool:
+            raise ValueError(
+                "a latent pool is read where it lies (flat over layer and "
+                "block); this device does not keep "
+                f"{self.pool_page_shape} pages in the order of their shape")
         # the scope of the bookkeeping every layer's state shares
         state_scope = SCOPE_SSM_PROJ if config.ssm_layers else SCOPE_CONV
 
@@ -2106,6 +2151,42 @@ class ServeEngine:
 
                 return kv_update, attn_fn
 
+            def latent_hooks(lp, base):
+                """A latent-attention layer's cache write and attention
+                over the pool flat over (layer, block), the layer's
+                blocks from ``base`` on: ``(kv_update, attn_fn)`` as
+                ``latent_attention_block`` takes them.  A row is written
+                in the width the pool stores it (zeros past ``rank +
+                rope``: block_pool.latent_page_width) and the absorbed
+                query padded likewise — the value, never the pool."""
+                blk = base + tok_blk
+                width = lp.shape[-1]
+
+                def widen(a):
+                    return jnp.pad(a.astype(lp.dtype), (
+                        (0, 0),) * (a.ndim - 1) + ((0, width - a.shape[-1]),))
+
+                def kv_update(row):  # fresh rows [1, D, rank + rope]
+                    return lp.at[blk, tok_off].set(widen(row[0]))
+
+                def attn_fn(q_lat, pool):  # [1, D, H, rank + rope]
+                    layer_tables = tables + base
+                    if use_kernel:
+                        out = ragged_latent_attention(
+                            widen(q_lat[0])[lane_tok], pool, layer_tables,
+                            tile_row, tile_qpos0, tile_qlen, pads,
+                            scale=config.attn_scale,
+                            rank=config.kv_lora_rank)[tok_lane]
+                    else:
+                        out = ragged_latent_attention_xla(
+                            q_lat[0].astype(lp.dtype), pool, layer_tables,
+                            tok_row, tok_slot, tok_live, pads,
+                            scale=config.attn_scale,
+                            rank=config.kv_lora_rank)
+                    return out[None].astype(q_lat.dtype)
+
+                return kv_update, attn_fn
+
             def layer_step(carry: Any, xs: tuple) -> tuple:
                 w, sliding, layer, *slabs = xs
                 if carry_pool:
@@ -2133,8 +2214,8 @@ class ServeEngine:
                 # and written in place at [layer, block, slot]
                 x, new_pools, new_state, loads = hybrid_layers(
                     params["layers"], x, pools, state,
-                    paged_hooks=paged_hooks, act=act, cos=cos, sin=sin,
-                    layers=layers, ops=o, nb=nb)
+                    paged_hooks=paged_hooks, latent_hooks=latent_hooks,
+                    act=act, cos=cos, sin=sin, layers=layers, ops=o, nb=nb)
             elif carry_pool:
                 xs = (params["layers"], is_sliding, layers)
                 (x, *new_pools), _ = lax.scan(
@@ -2211,7 +2292,7 @@ class ServeEngine:
             return packed, new_pages
 
         def hybrid_layers(groups, x, pools, state, *, paged_hooks,
-                          act, cos, sin, layers, ops, nb):
+                          latent_hooks, act, cos, sin, layers, ops, nb):
             """The layer loop of a stack of more than one kind of layer:
             each run of like layers (``config.layer_groups``) is one scan
             over its own stacked leaves, and every run carries the pool
@@ -2298,14 +2379,14 @@ class ServeEngine:
                 if op != "conv":
                     xs["paged"] = layers[a0:a0 + n]
                     a0 += n
-                if op != "attn":
+                if op in ("conv", "attn_ssm"):
                     xs["state"] = jnp.arange(c0, c0 + n, dtype=jnp.int32)
                     c0 += n
 
                 def body(carry, layer, op=op, ff=ff):
                     x, pool, state = carry
                     w, at = layer
-                    state = dict(state)
+                    state = None if state is None else dict(state)
                     ys: dict[str, Any] = {}
 
                     def history(z):
@@ -2315,6 +2396,14 @@ class ServeEngine:
 
                     if op == "conv":
                         x = conv_block(w, x, config=config, history=history)
+                    elif op == "latent":
+                        # one array of rows, always carried flat
+                        kv_update, attn_fn = latent_hooks(
+                            pool[0], at["paged"] * nb)
+                        x, rows = latent_attention_block(
+                            w, x, config=config, cos=cos, sin=sin,
+                            kv_update=kv_update, attn_fn=attn_fn)
+                        pool = (rows,)
                     else:
                         kp, vp, *scale_pages = pool
                         if carry_pool:
@@ -3918,7 +4007,7 @@ class ServeEngine:
                 # the same fetch carries every expert layer's per-expert
                 # token counts behind the rows' outcome (_make_mixed_step)
                 n_rows = out_host.size - (
-                    self._n_expert_layers * self.config.num_experts)
+                    self._n_expert_layers * self.config.experts_held)
                 expert_load = out_host[n_rows:].reshape(
                     self._n_expert_layers, -1)
                 out_host = out_host[:n_rows].reshape(
@@ -4036,14 +4125,19 @@ class ServeEngine:
                 # experts that got a token, summed over the expert layers
                 "experts_touched": int(np.count_nonzero(expert_load)),
                 # tokens an expert, worst layer: the most and the mean
+                # (over the experts HELD, as everything here)
                 "expert_load_max": int(worst.max()),
                 "expert_load_mean": round(float(worst.mean()), 3),
+                # (token, expert) pairs whose expert is held, all layers:
+                # every pair where all experts are, a share's share
+                "pairs_held": int(expert_load.sum()),
                 "state_slots_live": len(self.scheduler.running),
             }
             self.metrics.on_experts(
                 touched=moe["experts_touched"],
                 load_max=moe["expert_load_max"],
                 load_mean=moe["expert_load_mean"],
+                pairs_held=moe["pairs_held"],
                 state_slots_live=moe["state_slots_live"])
         ssm = None
         if self.config.ssm_layers and active:
@@ -4264,6 +4358,9 @@ class ServeEngine:
         from llm_np_cp_tpu.ops.pallas.decode_attention import (
             ragged_pages_per_step,
         )
+        from llm_np_cp_tpu.ops.pallas.latent_attention import (
+            latent_pages_per_step,
+        )
         from llm_np_cp_tpu.parallel.sharding import MODEL_AXIS
 
         layout = self._mixed_layouts[t_w, d_w][0]
@@ -4279,7 +4376,10 @@ class ServeEngine:
         pages = self.pool.pages
         # (the kv heads ONE chip holds: the kernel runs inside shard_map)
         shards = self.mesh.shape[MODEL_AXIS] if self._kv_sharded else 1
-        per_step = ragged_pages_per_step(
+        per_step = latent_pages_per_step(
+            self.max_blocks_per_seq, self.block_size, pages.k.shape[-1],
+            pages.k.dtype,
+        ) if pages.latent else ragged_pages_per_step(
             self.max_blocks_per_seq, self.block_size,
             pages.kv_heads // shards, pages.head_dim, pages.k.dtype,
             pages.quantized, merged=pages.merged)

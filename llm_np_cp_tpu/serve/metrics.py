@@ -198,6 +198,7 @@ class ServeMetrics:
         self.moe_experts_touched = 0
         self.moe_load_max = 0
         self.moe_load_mean = 0.0
+        self.moe_pairs_held = 0
         self.conv_state_slots = 0
         # state-space mixers (exact counters, one observation a
         # dispatching tick): rows whose recurrent state a dispatch read
@@ -306,7 +307,7 @@ class ServeMetrics:
             self.lifecycle_actions[action] += 1
 
     def on_experts(self, *, touched: int, load_max: int, load_mean: float,
-                   state_slots_live: int) -> None:
+                   state_slots_live: int, pairs_held: int = 0) -> None:
         """One dispatching tick of a stack with dropless expert layers
         (and, beside them, conv layers with a per-slot state)."""
         with self._lock:
@@ -314,6 +315,7 @@ class ServeMetrics:
             self.moe_experts_touched += touched
             self.moe_load_max += load_max
             self.moe_load_mean += load_mean
+            self.moe_pairs_held += pairs_held
             self.conv_state_slots = state_slots_live
 
     def on_ssm(self, *, rows: int, tokens: int, state_slots_live: int) -> None:
@@ -521,6 +523,7 @@ class ServeMetrics:
                 out["moe_experts_touched"] = self.moe_experts_touched
                 out["moe_expert_load_max"] = self.moe_load_max
                 out["moe_expert_load_mean"] = self.moe_load_mean
+                out["moe_pairs_held"] = self.moe_pairs_held
                 out["conv_state_slots_live"] = self.conv_state_slots
             if self.ssm_ticks:
                 # only where a state-space mixer ran
@@ -741,6 +744,10 @@ class ServeMetrics:
                  "(max / mean is the skew a grouped matmul pays for)",
                  [('{kind="max"}', s["moe_expert_load_max"]),
                   ('{kind="mean"}', s["moe_expert_load_mean"])])
+            emit("moe_pairs_held_total", "counter",
+                 "(token, expert) pairs whose expert this engine holds, "
+                 "summed over the expert layers and over ticks",
+                 [("", s["moe_pairs_held"])])
             emit("conv_state_slots_live", "gauge",
                  "Slots whose short-convolution state is live",
                  [("", s["conv_state_slots_live"])])

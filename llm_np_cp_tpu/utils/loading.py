@@ -43,7 +43,14 @@ import numpy as np
 from safetensors import safe_open
 
 from llm_np_cp_tpu.config import ModelConfig
-from llm_np_cp_tpu.models import falcon_h1, gemma2, lfm2_moe, llama, qwen2
+from llm_np_cp_tpu.models import (
+    deepseek_v3,
+    falcon_h1,
+    gemma2,
+    lfm2_moe,
+    llama,
+    qwen2,
+)
 from llm_np_cp_tpu.models.transformer import param_shapes
 
 log = logging.getLogger("llm_np_cp_tpu")
@@ -118,7 +125,8 @@ CONV1D_LEAVES = frozenset(("conv_filter", "ssm_conv"))
 def hybrid_family(config: ModelConfig):
     """The family module whose ``layer_tensors`` places a hybrid stack's
     checkpoint tensors (``(HF key, run, leaf, index, transpose?)``)."""
-    return falcon_h1 if config.model_type == "falcon_h1" else lfm2_moe
+    return {"falcon_h1": falcon_h1, "deepseek_v3": deepseek_v3}.get(
+        config.model_type, lfm2_moe)
 
 
 def _key_maps(config: ModelConfig):

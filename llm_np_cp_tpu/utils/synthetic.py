@@ -110,6 +110,29 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
         )
         if config.init_ssm_in_proj_std is not None:
             d["init_ssm_in_proj_std"] = config.init_ssm_in_proj_std
+    if config.model_type == "deepseek_v3":
+        moe_i = config.moe_intermediate_size
+        d.update(
+            kv_lora_rank=config.kv_lora_rank, q_lora_rank=None,
+            qk_nope_head_dim=config.qk_nope_head_dim,
+            qk_rope_head_dim=config.qk_rope_head_dim,
+            v_head_dim=config.v_head_dim,
+            rope_interleave=config.rope_interleave, rope_scaling=None,
+            n_routed_experts=config.experts_held,
+            router_experts=config.num_experts,
+            first_expert=config.first_expert,
+            n_shared_experts=(
+                (config.shared_expert_intermediate_size or 0) // moe_i),
+            num_experts_per_tok=config.num_experts_per_tok,
+            first_k_dense_replace=config.num_dense_layers,
+            moe_intermediate_size=moe_i, moe_layer_freq=1,
+            routed_scaling_factor=config.routed_scaling_factor,
+            scoring_func="sigmoid", topk_method="noaux_tc",
+            n_group=1, topk_group=1,
+            norm_topk_prob=config.norm_topk_prob,
+        )
+        if config.init_expert_out_std is not None:
+            d["init_expert_out_std"] = config.init_expert_out_std
     if config.model_type == "gemma2":
         d.update(
             final_logit_softcapping=config.final_logit_softcapping,

@@ -172,6 +172,8 @@ def _default_path(case):
     # the kernels the CLI's default serve path selects, at the probe
     # shapes and at the smoke's model (Qwen2.5-1.5B), CLI block size
     kernel, shape, bs = case
+    if kernel == "ragged_latent_attention":  # one shape: the published row
+        return bs == 64
     return (kernel in ("ragged_paged_attention", "sample_epilogue")
             and shape.name in ("probe", "probe/untied", "qwen2.5-1.5b")
             and bs in (None, 64))
@@ -705,6 +707,76 @@ def test_a_v5e_keeps_a_merged_page_in_the_order_of_its_shape(
     assert compiled.output_formats.layout.major_to_minor == kept
     if len(shape) == 5:  # what the pool's rule makes of such heads
         assert merges_pages(*shape[3:], False) is not row_major
+
+
+@pytest.mark.parametrize("shape,row_major,held", [
+    # the latent row as ONE array: the block axis becomes the minor one
+    ((24, 3458, 64, 576), False, 6_341_787_648),
+    # c' and k_pe as two arrays: c' lies as its shape says, k_pe does not,
+    # neither by itself nor merged over the block
+    ((24, 3458, 64, 512), True, 5_438_963_712),
+    ((24, 3458, 64, 64), False, 704_643_072),
+    ((24, 3458, 64 * 64), False, 679_870_464),
+    # what the pool stores: whole rows of 128 lanes, zeros past 576
+    ((24, 3458, 64, 640), True, 6_798_704_640),
+    # k_pe two tokens to a row of lanes would lie as its shape says and
+    # pad nothing (with c': 1,152 B a token): no kernel reads it yet
+    ((24, 3458, 32, 128), True, 679_870_464),
+], ids=["576", "c-512", "kpe-64", "kpe-merged-4096", "640-stored",
+        "kpe-2-tokens-a-row"])
+def test_which_latent_page_a_v5e_keeps_in_the_order_of_its_shape(
+        v5e_sharding, shape, row_major, held):
+    """The candidates for a page of latent rows ``[c' 512 | k_pe 64]`` at
+    the benchmark cell's pool (24 layers x 3,458 blocks x 64 tokens, bf16;
+    the algorithm needs 6,118,834,176 B), asked of the compiler for a
+    described v5e: the order it keeps and the bytes it really holds.
+    ``block_pool.latent_page_width`` states the choice."""
+    from llm_np_cp_tpu.serve.block_pool import latent_page_width
+
+    compiled = jax.jit(lambda a: a.at[0, 0, 0].set(1)).lower(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e_sharding)
+    ).compile()
+    kept = compiled.input_formats[0][0].layout.major_to_minor
+    assert (kept == tuple(range(len(shape)))) is row_major, kept
+    assert compiled.memory_analysis().argument_size_in_bytes == held
+    assert latent_page_width(576) == 640
+    assert 24 * 3458 * 64 * 576 * 2 == 6_118_834_176
+
+
+def test_latent_tick_on_a_v5e_reads_the_pool_where_it_lies(v5e_sharding):
+    """The unified step of a latent-attention stack at the published
+    attention widths (32 heads over rows of 512 + 64, stored 640 wide; a
+    leading dense block, then expert layers with shared experts), its
+    widest program compiled for the described v5e: the latent kernel
+    lowers inside the step under its own name, the pool (one array, flat
+    over layer and block) comes back in place, nothing pool- or
+    slab-shaped is made but the ``kv_write`` scatters, and all the step's
+    temporaries together are smaller than one layer's slab of rows."""
+    cfg = tiny_config(
+        "deepseek_v3", hidden_size=256, intermediate_size=512,
+        num_attention_heads=32, num_key_value_heads=32, head_dim=64,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, moe_intermediate_size=128,
+        shared_expert_intermediate_size=256, num_experts_held=4,
+        first_expert=2, vocab_size=2048)
+    # (1,026 blocks: a pool of 63 MB the compiler parks in VMEM whole, a
+    # copy in and a copy out that no served pool is small enough for)
+    engine, compiled = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, blocks=1026)
+    pages = engine.pool.pages
+    assert pages.latent and pages.k.shape == (3, 1026, BLOCK, 640)
+    assert pages.v is None
+    ops = _assert_tick_copies_no_pool(
+        engine, compiled, named=(("ragged-dot", "moe_experts"),))
+    text = compiled.as_text()
+    # a call a layer, under the kernel's own name, and no other's
+    assert len(re.findall(r"%ragged_latent_attention[.\d]* = ", text)) == 3
+    assert "%ragged_paged_attention" not in text
+    assert {"qkv", "kv_write", "attn", "o_proj", "mlp", "moe_route",
+            "moe_experts", "moe_shared"} <= {v[0] for v in ops.values()}
+    # two expert layers x three projections over the four experts held
+    grouped = [n for n in ops if n.startswith("ragged-dot-none")]
+    assert len(grouped) == 6
 
 
 def test_a_pool_on_the_cpu_is_row_major():

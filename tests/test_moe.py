@@ -160,3 +160,61 @@ def test_moe_mlp_routes_all_tokens_with_ample_capacity():
     )
     want = (act(x @ g1) * (x @ u1)) @ d1
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+
+
+# ----------------------------------------------------------------------
+# sigmoid routing: the normaliser's epsilon is the configuration's, and a
+# holder of a share computes the pairs whose expert it holds
+# ----------------------------------------------------------------------
+
+def _sigmoid_layer(seed=0, *, t=24, h=16, i=8, e=8):
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape, s=1.0: jnp.asarray(rng.normal(size=shape) * s, jnp.float32)
+    return dict(x=draw(t, h), router_w=draw(h, e), expert_bias=draw(e, s=0.3),
+                w1=draw(e, h, i, s=0.3), w3=draw(e, h, i, s=0.3),
+                w2=draw(e, i, h, s=0.3))
+
+
+@pytest.mark.parametrize("eps, family", [(1e-6, "lfm2_moe"), (1e-20, "deepseek_v3")])
+def test_the_normalisers_epsilon_is_the_configurations(eps, family):
+    from llm_np_cp_tpu.ops.moe import route_sigmoid_topk
+
+    assert tiny_config(family).router_norm_eps == eps
+    layer = _sigmoid_layer()
+    x, bias = layer["x"], layer["expert_bias"]
+    # a router of zeros scores every expert 0.5: a token's two weights sum
+    # to 1 / (1 + eps), which float32 tells from 1 at 1e-6 and not at 1e-20
+    idx, wts = route_sigmoid_topk(
+        x, layer["router_w"] * 0.0, bias, top_k=2, norm_eps=eps)
+    assert idx.shape == (24, 2) and wts.dtype == jnp.float32
+    total = np.asarray(wts.sum(-1), np.float64)
+    np.testing.assert_allclose(total, 1.0 / (1.0 + eps), rtol=1e-7)
+    assert (total < 1.0).all() == (eps == 1e-6)
+    # the default is LFM2's, whose cell must not move
+    _, default = route_sigmoid_topk(x, layer["router_w"], bias, top_k=2)
+    _, lfm2 = route_sigmoid_topk(x, layer["router_w"], bias, top_k=2, norm_eps=1e-6)
+    assert np.array_equal(default, lfm2)
+
+
+@pytest.mark.parametrize("held", [1, 2, 4, 8])
+def test_the_holders_shares_of_a_sigmoid_routed_layer_add_up(held):
+    from llm_np_cp_tpu.ops.moe import moe_dropless
+
+    layer = _sigmoid_layer(seed=held)
+    kw = dict(act=jax.nn.silu, top_k=3, scaling=2.448, norm_eps=1e-20)
+    x, router_w, bias = layer["x"], layer["router_w"], layer["expert_bias"]
+    with jax.default_matmul_precision("highest"):
+        whole, chosen, load = moe_dropless(
+            x, router_w, bias, layer["w1"], layer["w3"], layer["w2"], **kw)
+        total, loads = jnp.zeros_like(whole), []
+        for first in range(0, 8, held):
+            cut = slice(first, first + held)
+            part, part_chosen, part_load = moe_dropless(
+                x, router_w, bias, layer["w1"][cut], layer["w3"][cut],
+                layer["w2"][cut], first_expert=first, **kw)
+            assert np.array_equal(part_chosen, chosen)  # one router, 8 wide
+            assert part_load.shape == (held,)
+            total, loads = total + part, loads + [part_load]
+    assert np.array_equal(jnp.concatenate(loads), load)
+    assert int(load.sum()) == 24 * 3
+    assert float(jnp.abs(total - whole).max()) < 1e-5 * float(jnp.abs(whole).max())
