@@ -30,12 +30,16 @@ GShard/Switch behavior, and the price of static shapes under jit.
 
 from __future__ import annotations
 
+import functools
 import math
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from llm_np_cp_tpu.ops.pallas import grouped_matmul as gmm
+from llm_np_cp_tpu.ops.pallas import support
 from llm_np_cp_tpu.quant import quant_einsum
 
 
@@ -125,7 +129,7 @@ def moe_mlp(
 # ``jax.named_scope`` names (models/transformer.STEP_SCOPES lists them, so
 # serve/opmap.py cuts a device profile by them)
 SCOPE_MOE_ROUTE = "moe_route"      # gate, sigmoid, bias, top-k, normalise, sort
-SCOPE_MOE_EXPERTS = "moe_experts"  # grouped matmuls, weighted combine
+SCOPE_MOE_EXPERTS = "moe_experts"  # row gather, grouped matmuls, weighted combine
 
 
 def route_sigmoid_topk(
@@ -166,6 +170,30 @@ def route_sigmoid_topk(
     return idx.astype(jnp.int32), w * scaling
 
 
+def expert_row_tile(w: Any, rows: int, experts: int,
+                    interpret: bool | None = None) -> int | None:
+    """The row tile ops/pallas/grouped_matmul multiplies ``rows`` (token,
+    expert) pairs routed over ``experts`` in, or None where expert weights
+    ``w [E_held, H, I]`` (an array, or its shape and dtype) go through
+    ``lax.ragged_dot``: told from the backend, the dtype and the shape,
+    and on a TPU from the kernel's probe (a Mosaic refusal is one warning
+    and the compiler's own lowering, not a dead server).  ``interpret``:
+    ``moe_dropless``'s."""
+    if not (hasattr(w, "dtype") and jnp.issubdtype(w.dtype, jnp.floating)
+            and w.shape[1] % 128 == 0 and w.shape[2] % 128 == 0):
+        return None  # quant.py's trees; matrices that are not whole lanes
+    if interpret is None and (
+            jax.default_backend() != "tpu"
+            or support.kernel_or_warn("grouped_matmul", "lax.ragged_dot")):
+        return None
+    return gmm.row_tile(rows, experts)
+
+
+# (jitted: a stack's expert layers are alike, so the layer is traced once a
+# program and not once a layer — a warm start re-traces all nine programs)
+@functools.partial(jax.jit, static_argnames=(
+    "act", "top_k", "norm_topk_prob", "scaling", "norm_eps", "first_expert",
+    "out_dtype", "interpret"))
 def moe_dropless(
     x: jnp.ndarray,         # [T, H] — float32 where the caller has it
     router_w: jnp.ndarray,  # [H, E] — E: every expert of the layer
@@ -182,15 +210,23 @@ def moe_dropless(
     live: jnp.ndarray | None = None,  # [T] bool — False: routed nowhere
     first_expert: int = 0,
     out_dtype: jnp.dtype | None = None,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Dropless routed SwiGLU experts: ``(out [T, H], chosen [T, k],
     load [E_held] int32)``.
 
     The (token, expert) pairs — a static ``T * k`` of them — are sorted by
-    expert and each projection is ONE grouped matmul over the experts
-    held (``lax.ragged_dot`` with the per-expert pair counts): no
+    expert and multiplied by the experts held in grouped matmuls: no
     capacity, no dropped token, and an expert nobody chose reads no
-    weights.  The layer routes over all ``E`` experts and holds
+    weights.  On a TPU that is ``ops/pallas/grouped_matmul``: the groups
+    laid out in row tiles of one expert each, one call for ``act(gate) *
+    up`` and one for down, each streaming a touched expert's matrices
+    once; anywhere else, and for weights the kernel does not take
+    (``expert_row_tile``), three ``lax.ragged_dot`` over the sorted rows.
+    ``interpret``: as the Pallas kernels take it — None lets the backend
+    decide (the kernel compiled on a TPU, ``ragged_dot`` elsewhere), True
+    runs the kernel in the interpreter (tests), False compiles it.  The
+    layer routes over all ``E`` experts and holds
     ``w1.shape[0]`` of them from ``first_expert`` on (all of them on one
     chip); a pair whose expert is not held adds nothing here — that part
     of the result is another holder's.  ``live`` marks the tokens that
@@ -201,6 +237,7 @@ def moe_dropless(
     float32, comes back in ``out_dtype`` (default: the experts')."""
     t, h = x.shape
     held = w1.shape[0]
+    tm = expert_row_tile(w1, t * top_k, router_w.shape[-1], interpret)
     with jax.named_scope(SCOPE_MOE_ROUTE):
         idx, wts = route_sigmoid_topk(
             x, router_w, expert_bias, top_k=top_k,
@@ -218,17 +255,31 @@ def moe_dropless(
             pair_expert[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :],
             axis=0, dtype=jnp.int32,
         )
+        token = order // top_k  # of a sorted row
+        if tm is not None:
+            # each group in whole row tiles: the kernel's rows, and where
+            # a pair's row went
+            layout = gmm.align_groups(load, t * top_k, tm)
+            token, inverse = token[layout.src], layout.dest[inverse]
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         x = x.astype(w1.dtype)
-        xs = x[order // top_k]  # [T*k, H], grouped by expert
-        gate = lax.ragged_dot(xs, w1, load, preferred_element_type=jnp.float32)
-        up = lax.ragged_dot(xs, w3, load, preferred_element_type=jnp.float32)
-        hidden = (act(gate.astype(x.dtype)) * up.astype(x.dtype)).astype(x.dtype)
-        ys = lax.ragged_dot(hidden, w2, load,
-                            preferred_element_type=jnp.float32)
+        xs = x[token]  # [rows, H], grouped by expert
+        if tm is None:
+            gate = lax.ragged_dot(
+                xs, w1, load, preferred_element_type=jnp.float32)
+            up = lax.ragged_dot(
+                xs, w3, load, preferred_element_type=jnp.float32)
+            hidden = (act(gate.astype(x.dtype))
+                      * up.astype(x.dtype)).astype(x.dtype)
+            ys = lax.ragged_dot(hidden, w2, load,
+                                preferred_element_type=jnp.float32)
+        else:
+            ys = gmm.grouped_experts(
+                xs, w1, w3, w2, layout, act=act, tm=tm,
+                interpret=bool(interpret))
         # back to (token, choice) order; the weighted sum over a token's
-        # k experts in float32.  Rows past the last group hold nothing
-        # the sum may use.
+        # k experts in float32.  Rows of no group hold nothing the sum
+        # may use.
         ys = ys[inverse].reshape(t, top_k, h)
         out = jnp.sum(
             jnp.where(here[..., None], ys * wts[..., None], 0.0), axis=1)

@@ -16,6 +16,7 @@ process if the toolchain is broken; gating is the TPU-native upgrade.
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
 import logging
@@ -56,6 +57,7 @@ KERNELS = (
     "ragged_paged_attention", "ragged_paged_attention_int8",
     "ragged_latent_attention",
     "sample_epilogue", "sample_epilogue_int8",
+    "grouped_matmul",
 )
 
 # Pool block sizes the serve path uses (cli --block-size default 64,
@@ -229,6 +231,49 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
                 q, kv_float(ops), kv_float(ops), mask[:, None, :],
                 scale=scale, logit_softcap=softcap),
         )
+
+    if base == "grouped_matmul":
+        from jax import lax
+
+        from llm_np_cp_tpu.ops.pallas import grouped_matmul as gmm
+
+        # the routed experts' two calls (ops/moe.moe_dropless) over 70
+        # sorted rows of six experts: an empty first one, groups of one
+        # row, a tile + 1 and two tiles, an empty one between, and eleven
+        # rows of no group; the twin is three lax.ragged_dot
+        rows, inter, tm = 70, 384, 16
+        hid = shape.hidden
+        sizes = (0, 17, 1, 32, 0, 9)
+        grouped = jnp.arange(rows)[:, None] < sum(sizes)
+
+        def make_args():
+            x, w1, w3, w2 = normals(
+                (rows, hid), (len(sizes), hid, inter),
+                (len(sizes), hid, inter), (len(sizes), inter, hid))
+            thin = jnp.asarray(hid ** -0.5, bf16)
+            return (x, w1 * thin, w3 * thin,
+                    w2 * jnp.asarray(inter ** -0.5, bf16),
+                    jnp.asarray(sizes, jnp.int32))
+
+        def run(x, w1, w3, w2, sizes):
+            layout = gmm.align_groups(sizes, rows, tm)
+            ys = gmm.grouped_experts(
+                x[layout.src], w1, w3, w2, layout, act=jax.nn.silu, tm=tm,
+                interpret=interpret)
+            return jnp.where(grouped, ys[layout.dest], 0)
+
+        def reference(x, w1, w3, w2, sizes):
+            gate, up = (
+                lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
+                for w in (w1, w3))
+            hidden = jax.nn.silu(gate.astype(bf16)) * up.astype(bf16)
+            return jnp.where(grouped, lax.ragged_dot(
+                hidden, w2, sizes, preferred_element_type=jnp.float32), 0)
+
+        # (jitted: the probe runs what a case gives it as it is, and op
+        # by op the layout alone is fifty compiles of a third of a second
+        # at every start of a server)
+        return jax.jit(make_args), jax.jit(run), reference
 
     bs = block_size
     nbp, mb = 24, 4
@@ -483,6 +528,16 @@ def _probe(kernel: str, backend: str) -> str | None:
         return "forced failure (test hook)"
     if backend != "tpu":
         return None
+    if not isinstance(jnp.zeros(()), jax.core.Tracer):
+        return _compile_and_run(kernel)
+    # asked while a program is being traced (ops/moe.moe_dropless under a
+    # jitted forward that no engine built): a trace is its thread's, so a
+    # thread of its own runs the probe eagerly
+    with concurrent.futures.ThreadPoolExecutor(1) as pool:
+        return pool.submit(_compile_and_run, kernel).result()
+
+
+def _compile_and_run(kernel: str) -> str | None:
     latent = kernel == "ragged_latent_attention"
     try:
         for shape in (LATENT_PROBE_SHAPE,) if latent else PROBE_SHAPES:
@@ -539,6 +594,22 @@ def kernel_error(kernel: str) -> str | None:
 
 def kernel_available(kernel: str) -> bool:
     return kernel_error(kernel) is None
+
+
+_WARNED: set[str] = set()
+
+
+def kernel_or_warn(kernel: str, fallback: str) -> str | None:
+    """``kernel_error(kernel)``, logged as ONE warning a process that
+    names what runs in the kernel's place (a caller inside a layer asks
+    once a layer and program)."""
+    err = kernel_error(kernel)
+    if err is not None and kernel not in _WARNED:
+        _WARNED.add(kernel)
+        log.warning(
+            "Pallas kernel %s is unavailable on %s (%s); falling back to %s",
+            kernel, jax.default_backend(), err, fallback)
+    return err
 
 
 def gate_attn_impl(impl: str, *, int8_cache: bool = False) -> str:

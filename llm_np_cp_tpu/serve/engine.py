@@ -140,7 +140,7 @@ from llm_np_cp_tpu.models.transformer import (
     ssm_block,
 )
 from llm_np_cp_tpu.ops.activations import ACT2FN
-from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS
+from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS, expert_row_tile
 from llm_np_cp_tpu.ops.rope import rope_cos_sin
 from llm_np_cp_tpu.ops.sampling import Sampler
 from llm_np_cp_tpu.serve.block_pool import BlockPool, PagedKV
@@ -906,6 +906,15 @@ class ServeEngine:
         # dropless expert layers (unified tick only): their per-expert token
         # counts come back with the tick's one fetch
         self._n_expert_layers = len(config.expert_layers)
+        self._expert_row_tiles: dict[int, int | None] = {}
+        if self._n_expert_layers:
+            # the grouped matmul's verdict, asked here and not first while
+            # the step is being traced (ops/moe.expert_row_tile asks there)
+            t_probe = tracer.now_us() if tracer is not None else -1.0
+            tile = self._expert_row_tile(max_slots)
+            if tracer is not None:
+                tracer.complete("probe.grouped_matmul", t_probe, cat="setup",
+                                args={"ok": tile is not None})
         if self.mixed:
             # -- unified tick: ONE jitted program, bucketed packed width.
             # The temp prefill cache, scatter_prefill, gather_prefix and
@@ -4133,6 +4142,13 @@ class ServeEngine:
                 "pairs_held": int(expert_load.sum()),
                 "state_slots_live": len(self.scheduler.running),
             }
+            tm = self._expert_row_tile(dense_width)
+            if tm is not None:
+                # what the grouped matmul multiplied: each (layer, expert)
+                # group in whole tiles of ``tm`` rows, so that tiles x tm
+                # / pairs_held is the work over the pairs
+                moe["expert_row_tile"] = tm
+                moe["expert_row_tiles"] = int((-(-expert_load // tm)).sum())
             self.metrics.on_experts(
                 touched=moe["experts_touched"],
                 load_max=moe["expert_load_max"],
@@ -4432,11 +4448,27 @@ class ServeEngine:
                     self.params, self.pool.pages,
                     self._put(self._dead_mixed_operands(*program)),
                 ).compile().as_text(), STEP_SCOPES, pool,
-                # the grouped matmuls of the expert layers, which a TPU
+                # the expert layers' way out of the Pallas grouped matmul
+                # (ops/moe.expert_row_tile: the probe refused, matrices
+                # not whole lanes wide): lax.ragged_dot, which a TPU
                 # compiles to custom calls of its own naming
                 named=(("ragged-dot", SCOPE_MOE_EXPERTS),))
             for program in self.mixed_buckets
         )
+
+    def _expert_row_tile(self, dense_width: int) -> int | None:
+        """The row tile the expert layers of the program ``dense_width``
+        tokens wide multiply their pairs in — ``moe_dropless``'s own
+        choice, asked the way it asks — or None where they run
+        ``lax.ragged_dot``.  Asked once a width: a tick asks again."""
+        if dense_width not in self._expert_row_tiles:
+            w1 = next(
+                run["w1"] for run in self.params["layers"] if "w1" in run)
+            self._expert_row_tiles[dense_width] = expert_row_tile(
+                jax.ShapeDtypeStruct(w1.shape[-3:], w1.dtype),
+                dense_width * self.config.num_experts_per_tok,
+                self.config.num_experts)
+        return self._expert_row_tiles[dense_width]
 
     def _dispatch_decode(self, *args: jnp.ndarray) -> tuple:
         """One decode dispatch with runtime kernel degradation: if the

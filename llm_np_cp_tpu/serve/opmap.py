@@ -108,7 +108,12 @@ def op_map_from_hlo(text: str, scopes: Iterable[str],
     ``op_name`` they were traced under (a TPU turns ``lax.ragged_dot``
     into the custom calls ``%ragged-dot-none`` / ``%ragged-dot-metadata``
     with ``op_name="ragged-dot-none"``): such an operation is told by its
-    own name, where its ``op_name`` names no scope."""
+    own name, where its ``op_name`` names no scope.  Since PR 42 the
+    expert layers of a traced TPU run reach ``lax.ragged_dot`` only
+    through ``ops/moe.expert_row_tile``'s way out — the grouped matmul's
+    probe refused by Mosaic, or expert matrices that are not whole lanes
+    wide; a Pallas call keeps the ``op_name`` it was traced under and
+    needs no such rescue."""
     scopes = frozenset(scopes)
     named = tuple(named)
     computations: dict[str, list[tuple]] = {}

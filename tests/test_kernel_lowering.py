@@ -174,6 +174,8 @@ def _default_path(case):
     kernel, shape, bs = case
     if kernel == "ragged_latent_attention":  # one shape: the published row
         return bs == 64
+    if kernel == "grouped_matmul":  # the cells' own shapes: further down
+        return shape.name == "probe"
     return (kernel in ("ragged_paged_attention", "sample_epilogue")
             and shape.name in ("probe", "probe/untied", "qwen2.5-1.5b")
             and bs in (None, 64))
@@ -253,13 +255,18 @@ def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS, slots=SLOTS,
     assert program in engine.mixed_buckets
     avals.append(aval(engine._dead_mixed_operands(*program)))
     # the kernels pick interpret mode from the backend they see: show
-    # them the one they are being compiled for
-    real = jax.default_backend
+    # them the one they are being compiled for (and the expert layers,
+    # which ask the grouped matmul's probe as they are traced, a verdict
+    # no CPU can give)
+    from llm_np_cp_tpu.ops.pallas import support
+
+    real, real_error = jax.default_backend, support.kernel_error
     jax.default_backend = lambda: "tpu"
+    support.kernel_error = lambda kernel: None
     try:
         compiled = engine._mixed_step.lower(*avals).compile()
     finally:
-        jax.default_backend = real
+        jax.default_backend, support.kernel_error = real, real_error
     return engine, compiled
 
 
@@ -743,6 +750,89 @@ def test_which_latent_page_a_v5e_keeps_in_the_order_of_its_shape(
     assert 24 * 3458 * 64 * 576 * 2 == 6_118_834_176
 
 
+# ----------------------------------------------------------------------
+# the routed experts' grouped matmul at the cells' shapes (kernel alone)
+# ----------------------------------------------------------------------
+#
+# (token, expert) pairs of a program, experts the router spreads them
+# over, experts held, hidden, an expert's width: LFM2-8B-A1B's steady tick
+# and widest program, Kanana-2's narrowest, steady and widest, and the
+# rows of the benchmark's check (run.py ``check_reference``: 4 sequences
+# padded to the mix's longest through ``models.forward``)
+EXPERT_SHAPES = {
+    "lfm2-steady-256": (64 * 4, 32, 32, 2048, 1792),
+    "lfm2-widest-1280": (320 * 4, 32, 32, 2048, 1792),
+    "lfm2-check-14336": (3584 * 4, 32, 32, 2048, 1792),
+    "kanana-narrowest-48": (8 * 6, 128, 16, 2048, 768),
+    "kanana-steady-576": (96 * 6, 128, 16, 2048, 768),
+    "kanana-widest-2112": (352 * 6, 128, 16, 2048, 768),
+    "kanana-check-52224": (8704 * 6, 128, 16, 2048, 768),
+}
+
+
+@pytest.mark.parametrize("case", EXPERT_SHAPES)
+def test_grouped_matmul_compiles_at_the_cells_shapes(v5e_sharding, case):
+    """Both calls of a layer, with the layout they multiply in, exported
+    and compiled for the described v5e: the weights stay where they lie
+    (operands of the calls as the program's arguments have them, no copy
+    of one into VMEM), and the kernel's VMEM is its blocks' — the same at
+    the check's rows as at a tick's, under half of what a v5e has."""
+    from jax import lax
+
+    from llm_np_cp_tpu.ops.pallas import grouped_matmul as gmm
+
+    rows, experts, held, h, inter = EXPERT_SHAPES[case]
+    tm = gmm.row_tile(rows, experts)
+
+    def layer(x, w1, w3, w2, sizes):
+        layout = gmm.align_groups(sizes, rows, tm)
+        return gmm.grouped_experts(
+            x[layout.src], w1, w3, w2, layout, act=jax.nn.silu, tm=tm,
+            interpret=False)[layout.dest]
+
+    def aval(shape, dtype=jnp.bfloat16, **kw):
+        return jax.ShapeDtypeStruct(shape, dtype, **kw)
+
+    shapes = [((rows, h),), ((held, h, inter),), ((held, h, inter),),
+              ((held, inter, h),), ((held,), jnp.int32)]
+    _export_tpu(layer, *(aval(*s) for s in shapes))
+    text = jax.jit(layer).lower(
+        *(aval(*s, sharding=v5e_sharding) for s in shapes)).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 2 and all("%grouped_matmul" in ln for ln in calls)
+    for shape in ((held, h, inter), (held, inter, h)):
+        made = re.findall(
+            rf"= {re.escape(opmap.hlo_shape('bfloat16', shape))}\S* "
+            r"(?!parameter)[\w-]+\(", text)
+        assert not made, f"the weights {shape} are made anew: {made}"
+    laid = gmm.tile_count(rows, held, tm) * tm
+    assert f"bf16[{laid},{inter}]" in calls[0] and f"f32[{laid},{h}]" in calls[1]
+    for ln in calls:
+        scope, = re.findall(
+            r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+            r'"size":"(\d+)"', ln)
+        assert int(scope) < 64 * 2**20, scope
+
+
+def _assert_experts_run_the_kernel(ops, *, layers, weights):
+    """The routed experts of a compiled step (serve/opmap.py's parse, no
+    ``named=`` rescue): two calls of the grouped matmul a layer, each under
+    ``moe_experts`` by the ``op_name`` it was traced under, no
+    ``ragged-dot`` left, and no operation that gives back an array shaped
+    like a layer's expert weights (``weights``: ``[E_held, K, N]`` — a
+    copy, a pad or a transpose of them; with the run's leading 1 too)."""
+    calls = [n for n in ops if n.startswith("grouped_matmul")]
+    assert len(calls) == 2 * layers, sorted(ops)
+    assert all(ops[n][0] == "moe_experts" for n in calls)
+    assert not [n for n in ops if "ragged-dot" in n]
+    shaped = {opmap.hlo_shape("bfloat16", lead + tuple(perm))
+              for shape in weights for lead in ((), (1,))
+              for perm in (shape, (shape[0], shape[2], shape[1]))}
+    moved = {n: v[1] for n, v in ops.items() if v[1] in shaped}
+    assert not moved, f"expert weights copied, padded or transposed: {moved}"
+
+
 def test_latent_tick_on_a_v5e_reads_the_pool_where_it_lies(v5e_sharding):
     """The unified step of a latent-attention stack at the published
     attention widths (32 heads over rows of 512 + 64, stored 640 wide; a
@@ -766,17 +856,17 @@ def test_latent_tick_on_a_v5e_reads_the_pool_where_it_lies(v5e_sharding):
     pages = engine.pool.pages
     assert pages.latent and pages.k.shape == (3, 1026, BLOCK, 640)
     assert pages.v is None
-    ops = _assert_tick_copies_no_pool(
-        engine, compiled, named=(("ragged-dot", "moe_experts"),))
+    ops = _assert_tick_copies_no_pool(engine, compiled)
     text = compiled.as_text()
     # a call a layer, under the kernel's own name, and no other's
     assert len(re.findall(r"%ragged_latent_attention[.\d]* = ", text)) == 3
     assert "%ragged_paged_attention" not in text
     assert {"qkv", "kv_write", "attn", "o_proj", "mlp", "moe_route",
             "moe_experts", "moe_shared"} <= {v[0] for v in ops.values()}
-    # two expert layers x three projections over the four experts held
-    grouped = [n for n in ops if n.startswith("ragged-dot-none")]
-    assert len(grouped) == 6
+    # two expert layers x two calls (gate and up in one, down) over the
+    # four experts held, where they lie
+    _assert_experts_run_the_kernel(ops, layers=2, weights=[
+        (4, 256, 128), (4, 128, 256)])
 
 
 def test_a_pool_on_the_cpu_is_row_major():
@@ -788,7 +878,7 @@ def test_a_pool_on_the_cpu_is_row_major():
         assert _pool_is_row_major(pool.pages)
 
 
-def _assert_tick_copies_no_pool(engine, compiled, named=()):
+def _assert_tick_copies_no_pool(engine, compiled):
     """The compiled unified step of a stack whose layer loop carries the
     pool flat over (layer, block): the donated arrays come back as the
     result, every temporary of the step together is smaller than ONE
@@ -803,7 +893,7 @@ def _assert_tick_copies_no_pool(engine, compiled, named=()):
     assert temp < k_slab_bytes, (temp, k_slab_bytes)
     pool = opmap.pool_shapes(
         (a.dtype.name, a.shape) for a in pages.pool_arrays())
-    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, pool, named=named)
+    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, pool)
     moved = {n: v for n, v in ops.items()
              if v[2] and v[0] != SCOPE_KV_WRITE}
     assert not moved, f"pool- or slab-shaped outside the K/V write: {moved}"
@@ -852,18 +942,15 @@ def test_hybrid_tick_on_a_v5e_names_its_experts_and_rebuilds_no_pool(
     assert engine.pool.pages.merged is flat
     if flat:
         assert engine.pool.pages.k.shape[2:] == (BLOCK, 512)
-        _assert_tick_copies_no_pool(
-            engine, compiled, named=(("ragged-dot", "moe_experts"),))
+        _assert_tick_copies_no_pool(engine, compiled)
     pool = opmap.pool_shapes(
         [(a.dtype.name, a.shape) for a in engine.pool.pages.pool_arrays()]
         + [(a.dtype.name, a.shape)
            for a in jax.tree.leaves(engine.pool.pages.state)])
-    ops = opmap.op_map_from_hlo(
-        text, STEP_SCOPES, pool, named=(("ragged-dot", "moe_experts"),))
-    grouped = [n for n in ops if n.startswith("ragged-dot-none")]
-    # 8 expert layers x 3 projections, each told by its own name
-    assert len(grouped) == 24 and all(
-        ops[n][0] == "moe_experts" for n in grouped)
+    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, pool)
+    # 8 expert layers x 2 calls, each under the scope it was traced in
+    _assert_experts_run_the_kernel(ops, layers=8, weights=[
+        (8, 256, 256)])
     assert {"conv", "moe_route", "moe_experts", "attn", "mlp"} <= {
         v[0] for v in ops.values()}
     # (that an expert layer is never stacked, so that no scan slices a

@@ -498,6 +498,9 @@ def test_tick_arguments_counters_and_scopes(tiny):
         assert a["expert_load_mean"] == pytest.approx(2 * 2 / 8)
         assert a["expert_load_mean"] <= a["expert_load_max"] <= 2
         assert a["state_slots_live"] <= 2 and a["host_fetches"] == 1
+        # (row tiles are the Pallas grouped matmul's: on the CPU the
+        # experts run lax.ragged_dot, which has none)
+        assert "expert_row_tiles" not in a
     # the counts came back with the tick's one fetch
     assert engine.n_host_fetches - fetches == engine.n_dispatches
     text = engine.metrics.prometheus()
@@ -517,6 +520,17 @@ def test_tick_arguments_counters_and_scopes(tiny):
     assert any(v and v[0] == "conv" for v in moves), moves
     assert not any(v and v[1] for v in moves), moves
     assert not any("f32[1,4,2,64]" in k or "f32[2,4,2,64]" in k for k in table)
+    # where the kernel runs (a row tile of 16, as on a TPU) each tick says
+    # how many tiles its groups took, from the same fetched counts: at most
+    # 2 rows an expert here, so one tile a touched (layer, expert)
+    engine._expert_row_tile = lambda dense_width: 16
+    seen = len(tracer.events())
+    engine.submit(_prompts([9], seed=5)[0], max_new_tokens=3, seed=0)
+    engine.run_until_complete()
+    tiled = [e["args"] for e in tracer.events()[seen:]
+             if e.get("name") == "tick" and "experts_touched" in e["args"]]
+    assert tiled and all(a["expert_row_tile"] == 16 for a in tiled)
+    assert all(a["expert_row_tiles"] == a["experts_touched"] for a in tiled)
 
 
 # ----------------------------------------------------------------------
