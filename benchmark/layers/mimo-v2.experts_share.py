@@ -1,0 +1,22 @@
+"""Own time of the operations the op map puts under the ``moe_experts``
+scope of a ``mimo_v2`` stack (the row gather, the grouped matmuls over the 16
+experts HELD, the un-sort and the weighted combine: what
+``mimo-v2.experts_roofline`` divides by), in % of device busy time.  The
+router has its own reading, ``mimo-v2.route_share``.  Another architecture,
+or a program without the scope, reads nothing."""
+import sys
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).parent))  # tracefile.py lies beside the readers
+sys.path.insert(0, str(Path(__file__).parents[1]))  # costs_mimo_v2.py
+import costs_mimo_v2  # noqa: E402,F401
+import tracefile  # noqa: E402
+
+
+def read(run: dict) -> float | None:
+    if run["config"].get("model_type") != "mimo_v2":
+        return None
+    table = tracefile.op_table(run)
+    if not table or not any(v and v[0] == "moe_experts" for v in table.values()):
+        return None
+    return tracefile.scope_share(run, lambda scope, kind: scope == "moe_experts")

@@ -129,7 +129,13 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    help="KV pool block size in cache slots (multiple of 8)")
     p.add_argument("--num-blocks", type=int, default=0,
                    help="KV pool blocks; 0 sizes the pool so every slot "
-                   "can hold a worst-case request plus one spare block")
+                   "can hold a worst-case request plus one spare block.  "
+                   "A model whose window layers hold pages of their own "
+                   "(mimo_v2) gets a second, bounded page class beside "
+                   "these, sized by the engine (the window + the widest "
+                   "slice a tick writes + a block, a slot): the banner "
+                   "reads pool=<blocks>x<block size> (<dtype>) + window "
+                   "<blocks>x<block size> (<ring> a slot)")
     p.add_argument("--cache-dtype", choices=["bf16", "f32", "int8"],
                    default="bf16")
     p.add_argument("--attn-impl", choices=["gather", "paged", "auto"],
@@ -244,8 +250,9 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
     p.add_argument("--arch", default=None, metavar="MODEL_TYPE",
                    help="the operator's statement of what the loaded "
                    "checkpoint must be: start-up fails unless its "
-                   "config's model_type is MODEL_TYPE (llama, qwen2, "
-                   "gemma2, lfm2_moe, ...) — so that a deployment never "
+                   "config's model_type is MODEL_TYPE (llama, mistral, "
+                   "mixtral, qwen2, gemma2, lfm2_moe, falcon_h1, "
+                   "deepseek_v3, mimo_v2) — so that a deployment never "
                    "serves another architecture under a model's name")
     p.add_argument("--chaos-spec", default=None, metavar="SPEC",
                    help="fault-injection schedule (serve/faults.py): "
@@ -1207,8 +1214,13 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
             topo = f"{args.replicas} replicas x ({topo})"
     banner = (
         f"[serve] model={args.model} slots={args.slots} "
-        f"pool={num_blocks}x{args.block_size} ({args.cache_dtype}), "
-        f"attn={engine.decode_attn_impl}, "
+        f"pool={num_blocks}x{args.block_size} ({args.cache_dtype})"
+        # a pool with a window class (window layers with pages of their
+        # own): its blocks, and the ring of them a slot owns
+        + (f" + window {engine.pool.window.num_blocks}x{args.block_size} "
+           f"({engine.window_blocks} a slot)"
+           if engine.pool.window is not None else "")
+        + f", attn={engine.decode_attn_impl}, "
         f"epilogue={engine.epilogue_impl}, topo={topo}, "
         f"prefix_cache={'on' if args.prefix_cache else 'off'}, "
         f"kv_tier={args.kv_tier}, "

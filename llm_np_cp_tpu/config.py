@@ -37,6 +37,19 @@ from typing import Any, Mapping
 
 
 @dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer (``ModelConfig.attn_kind``)."""
+
+    kind: str  # "global" | "window"
+    kv_heads: int
+    key_dim: int
+    value_dim: int
+    rope_theta: float
+    window: int | None
+    sink: bool
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Static architecture description for a decoder-only transformer."""
 
@@ -77,9 +90,13 @@ class ModelConfig:
     final_logit_softcapping: float | None = None
     attn_logit_softcapping: float | None = None
     sliding_window: int | None = None
-    # Layers with (layer_idx % 2 == 0) use the sliding window when
-    # `sliding_window` is set; odd layers stay global (Gemma-2's hybrid
-    # schedule, config key `cache_implementation: hybrid`, gemma2_model.py:104).
+    # Which layers are WINDOW layers when `sliding_window` is set, as data
+    # of the layer declaration: layer ``i`` is one where
+    # ``window_pattern[i % len(window_pattern)]`` is 1.  The default is
+    # Gemma-2's alternation (window, global, ..: config key
+    # `cache_implementation: hybrid`, gemma2_model.py:104); MiMo-V2 states
+    # its published ``hybrid_layer_pattern`` whole.
+    window_pattern: tuple[int, ...] = (1, 0)
     query_pre_attn_scalar: float | None = None
 
     # --- Layer-scan unroll (performance knob, no numeric effect): unroll
@@ -197,6 +214,20 @@ class ModelConfig:
     # (LFM2's published code: 1e-6; DeepSeek-V3's: 1e-20)
     router_norm_eps: float = 1e-6
 
+    # --- Window and global layers that differ in more than the mask
+    # (MiMo-V2, ``model_type: mimo_v2``): a window layer has its own kv
+    # heads and RoPE base and a learned per-head SINK logit in its
+    # softmax's denominator; K heads are ``head_dim`` wide of which the
+    # leading ``rope_dim`` columns are rotated, V heads ``v_head_dim``;
+    # the values are multiplied by ``attention_value_scale``.
+    # ``swa_num_key_value_heads is None`` is every other family: one kind
+    # of K/V page, one pool class (``attn_kind``).
+    swa_num_key_value_heads: int | None = None
+    swa_rope_theta: float | None = None
+    swa_sink: bool = False
+    rope_dim: int | None = None  # None: all of head_dim
+    attention_value_scale: float = 1.0
+
     def __post_init__(self) -> None:
         # Note: hidden_size need not equal heads*head_dim (Gemma-2-2B:
         # 2304 hidden, 8 heads of 256), so no divisibility constraint there.
@@ -217,6 +248,19 @@ class ModelConfig:
             raise ValueError(
                 f"latent attention rotates head_dim {self.head_dim} columns: "
                 f"qk_rope_head_dim is {self.qk_rope_head_dim} (and is even)")
+        if self.two_page_classes:
+            if self.sliding_window is None or self.is_latent or (
+                    self.num_attention_heads % self.swa_num_key_value_heads):
+                raise ValueError(
+                    "window layers with kv heads of their own "
+                    f"({self.swa_num_key_value_heads}) need a sliding_window, "
+                    "K/V per head and query heads they divide "
+                    f"({self.num_attention_heads})")
+        if self.rope_dim is not None and not (
+                0 < self.rope_dim <= self.head_dim and self.rope_dim % 2 == 0):
+            raise ValueError(
+                f"rope_dim {self.rope_dim} is not an even number of head_dim "
+                f"{self.head_dim}'s columns")
         if self.num_experts is not None and not (
                 0 <= self.first_expert
                 and self.first_expert + self.experts_held <= self.num_experts):
@@ -264,7 +308,53 @@ class ModelConfig:
         return float(self.head_dim) ** -0.5
 
     def layer_is_sliding(self, layer_idx: int) -> bool:
-        return self.sliding_window is not None and layer_idx % 2 == 0
+        return self.sliding_window is not None and bool(
+            self.window_pattern[layer_idx % len(self.window_pattern)])
+
+    @property
+    def value_dim(self) -> int:
+        """A value head's width (a key head's unless stated)."""
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def two_page_classes(self) -> bool:
+        """Window layers hold K/V pages of a shape of their own: a pool
+        keeps them as a second class, bounded by the window."""
+        return self.swa_num_key_value_heads is not None
+
+    def attn_kind(self, kind: str) -> "AttnKind":
+        """What an attention layer of ``kind`` (``"global"`` /
+        ``"window"``) is: kv heads, key and value widths, RoPE base,
+        window (None: the whole context) and whether its softmax has a
+        sink.  The ONE statement of it: parameters, caches, pools, the
+        forward and the cost files read it."""
+        window = kind == "window"
+        return AttnKind(
+            kind=kind,
+            kv_heads=(self.swa_num_key_value_heads
+                      if window and self.two_page_classes
+                      else self.num_key_value_heads),
+            key_dim=self.head_dim, value_dim=self.value_dim,
+            rope_theta=float(self.swa_rope_theta if window and
+                             self.swa_rope_theta else self.rope_theta),
+            window=self.sliding_window if window else None,
+            sink=window and self.swa_sink)
+
+    @property
+    def window_layers(self) -> tuple[int, ...]:
+        """Layers whose pages are the window class's, in order (none
+        where the pool has one class)."""
+        if not self.two_page_classes:
+            return ()
+        return tuple(i for i in self.attn_layers if self.layer_is_sliding(i))
+
+    @property
+    def global_layers(self) -> tuple[int, ...]:
+        """Layers whose pages are the growing class's, in order."""
+        if not self.two_page_classes:
+            return self.attn_layers
+        return tuple(i for i in self.attn_layers
+                     if not self.layer_is_sliding(i))
 
     # -- the per-layer declaration (what a layer IS, not a schedule) ----
     @property
@@ -279,13 +369,17 @@ class ModelConfig:
 
     def layer_op(self, layer_idx: int) -> str:
         """``"attn"``, ``"conv"``, ``"attn_ssm"`` (attention and a
-        state-space mixer side by side, both reading one normed input)
-        or ``"latent"`` (attention over one compressed row a token): the
-        operator of layer ``layer_idx``."""
+        state-space mixer side by side, both reading one normed input),
+        ``"latent"`` (attention over one compressed row a token) or
+        ``"swa"`` (a window layer whose K/V differ in shape from the
+        global layers': ``attn_kind("window")``): the operator of layer
+        ``layer_idx``."""
         if self.is_latent:
             return "latent"
         if self.mamba_d_ssm is not None:
             return "attn_ssm"
+        if self.two_page_classes and self.layer_is_sliding(layer_idx):
+            return "swa"
         if self.layer_types is None:
             return "attn"
         return "conv" if self.layer_types[layer_idx] == "conv" else "attn"
@@ -300,7 +394,8 @@ class ModelConfig:
     def attn_layers(self) -> tuple[int, ...]:
         """Layers that hold K/V (or a latent row), in order: the only
         ones a cache or a pool has pages for (page ``i`` belongs to
-        ``attn_layers[i]``)."""
+        ``attn_layers[i]``; with two page classes, to ``global_layers[i]``
+        / ``window_layers[i]`` of its class)."""
         return tuple(i for i in range(self.num_hidden_layers)
                      if self.layer_op(i) != "conv")
 
@@ -355,9 +450,10 @@ class ModelConfig:
         """Attention reads one compressed row a token (MLA)."""
         return self.kv_lora_rank is not None
 
-    def kv_token_shapes(self) -> dict[str, tuple[int, ...]]:
-        """What ONE token leaves in a cache, a layer that has pages, as
-        ``{leaf: shape}``: K and V per kv head, or (latent attention) the
+    def kv_token_shapes(self, kind: str = "global") -> dict[str, tuple[int, ...]]:
+        """What ONE token leaves in a cache, a layer of ``kind`` that has
+        pages, as ``{leaf: shape}``: K and V per kv head (a layer kind's
+        own heads and widths: ``attn_kind``), or (latent attention) the
         one row ``[c' | k_pe]`` whose first ``kv_lora_rank`` columns are
         also the values, and no ``v``.  The ONE statement of it: the pool
         (``PagedKV``), the offline cache (``KVCache``) and a
@@ -365,15 +461,23 @@ class ModelConfig:
         device's lanes: serve/block_pool.py says where)."""
         if self.is_latent:
             return {"k": (self.kv_lora_rank + self.qk_rope_head_dim,)}
-        kd = (self.num_key_value_heads, self.head_dim)
-        return {"k": kd, "v": kd}
+        a = self.attn_kind(kind)
+        return {"k": (a.kv_heads, a.key_dim), "v": (a.kv_heads, a.value_dim)}
 
-    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
-        """Bytes a token holds in a cache over all layers with pages, as
-        the algorithm needs them (``kv_token_shapes``; int8 scale pages
-        not counted)."""
-        return len(self.attn_layers) * itemsize * sum(
-            math.prod(shape) for shape in self.kv_token_shapes().values())
+    def kv_bytes_per_token(self, itemsize: int = 2,
+                           kind: str | None = None) -> int:
+        """Bytes a token holds in a cache over all layers with pages (of
+        ``kind``, where one is named), as the algorithm needs them
+        (``kv_token_shapes``; int8 scale pages not counted; a window
+        layer counted as if it kept every token — what a pool bounds)."""
+        def of(k: str, layers: int) -> int:
+            return layers * itemsize * sum(
+                math.prod(shape)
+                for shape in self.kv_token_shapes(k).values())
+        n_window = len(self.window_layers)
+        per_kind = {"global": of("global", len(self.attn_layers) - n_window),
+                    "window": of("window", n_window)}
+        return per_kind[kind] if kind else sum(per_kind.values())
 
     @property
     def experts_held(self) -> int:
@@ -387,7 +491,9 @@ class ModelConfig:
                      if self.layer_ff(i) == "experts")
 
     def layer_groups(self) -> tuple[tuple[str, str, int, int], ...]:
-        """The stack as runs of like layers, ``(op, ff, first, count)``:
+        """The stack as runs of like layers, ``(op, ff, first, count)``
+        (a window layer with pages of its own is an operator of its own,
+        ``"swa"``):
         each run is one stacked pytree and one scanned body.  An expert
         layer is always a run of its own: the grouped matmul wants each
         expert tensor as a whole buffer, and a scan would copy a layer's
@@ -603,6 +709,104 @@ class ModelConfig:
                 init_expert_specific=d.get("init_expert_specific"),
                 init_expert_out_std=d.get("init_expert_out_std"),
             )
+        if model_type == "mimo_v2":
+            # MiMo-V2 (MiMo-V2-Flash / V2.5): global and window attention
+            # layers by ``hybrid_layer_pattern`` (0 / 1), each kind with
+            # its own kv heads and RoPE base, K heads ``head_dim`` wide
+            # (the leading ``int(head_dim * partial_rotary_factor)``
+            # columns rotated), V heads ``v_head_dim``, a learned sink
+            # logit a head in the window layers' softmax, values times
+            # ``attention_value_scale``; dense SwiGLU where
+            # ``moe_layer_freq`` is 0 (leading layers), else sigmoid-routed
+            # experts chosen by score + a correction bias, no shared
+            # expert.  What has no equations here is refused by its key.
+            depth = d["num_hidden_layers"]
+            pattern = tuple(int(v) for v in d["hybrid_layer_pattern"])
+            freq = d.get("moe_layer_freq", 1)
+            freq = (tuple(int(v) for v in freq) if isinstance(freq, (list, tuple))
+                    else (int(freq),) * depth)
+            for key, seq in (("hybrid_layer_pattern", pattern),
+                             ("moe_layer_freq", freq)):
+                if len(seq) != depth or set(seq) - {0, 1}:
+                    raise ValueError(
+                        f"mimo_v2 {key} names {len(seq)} layers "
+                        f"({sorted(set(seq))}), num_hidden_layers is {depth} "
+                        "(one 0 / 1 a layer)")
+            n_dense = next((i for i, v in enumerate(freq) if v), depth)
+            if not all(freq[n_dense:]):
+                raise ValueError(
+                    "mimo_v2 with a dense feed-forward after an expert layer "
+                    "(moe_layer_freq not leading zeros) is not implemented")
+            if d.get("add_full_attention_sink_bias", False):
+                raise ValueError(
+                    "mimo_v2 with add_full_attention_sink_bias (a sink in "
+                    "the global layers) is not implemented")
+            if d.get("n_group", 1) > 1 or d.get("topk_group", 1) > 1:
+                raise ValueError(
+                    "mimo_v2 with n_group / topk_group > 1 (group-limited "
+                    "routing) is not implemented")
+            if d.get("n_shared_experts"):
+                raise ValueError(
+                    "mimo_v2 with n_shared_experts is not implemented")
+            if (rope_scaling or {}).get(
+                    "rope_type", (rope_scaling or {}).get("type", "default")
+            ) != "default":
+                raise ValueError(
+                    "mimo_v2 with rope_scaling other than default is not "
+                    "implemented")
+            if d.get("scoring_func", "sigmoid") != "sigmoid":
+                raise ValueError(
+                    f"mimo_v2 with scoring_func {d['scoring_func']!r} is not "
+                    "implemented (sigmoid)")
+            if d.get("topk_method", "noaux_tc") != "noaux_tc":
+                raise ValueError(
+                    f"mimo_v2 with topk_method {d['topk_method']!r} is not "
+                    "implemented (noaux_tc)")
+            if d.get("attention_bias", False):
+                raise ValueError(
+                    "mimo_v2 with attention_bias is not implemented")
+            for key, same in (("swa_num_attention_heads", num_heads),
+                              ("swa_head_dim", head_dim),
+                              ("swa_v_head_dim", d.get("v_head_dim", head_dim)),
+                              ("sliding_window_size", d.get("sliding_window"))):
+                if d.get(key, same) != same:
+                    raise ValueError(
+                        f"mimo_v2 with {key} {d[key]} (window layers whose "
+                        f"query heads or widths differ from the global "
+                        f"layers' {same}) is not implemented")
+            held = d["n_routed_experts"]
+            router = d.get("router_experts", held)
+            scaling = d.get("routed_scaling_factor")
+            kwargs.update(
+                rms_norm_eps=d.get("layernorm_epsilon",
+                                   d.get("rms_norm_eps", 1e-5)),
+                sliding_window=d["sliding_window"],
+                window_pattern=pattern,
+                swa_num_key_value_heads=d.get(
+                    "swa_num_key_value_heads", kwargs["num_key_value_heads"]),
+                swa_rope_theta=float(d.get("swa_rope_theta",
+                                           kwargs["rope_theta"])),
+                swa_sink=bool(d.get("add_swa_attention_sink_bias", False)),
+                v_head_dim=d.get("v_head_dim", head_dim),
+                # an even number of columns: they rotate in pairs
+                rope_dim=int(head_dim * d.get("partial_rotary_factor", 1.0))
+                // 2 * 2,
+                attention_value_scale=float(
+                    d.get("attention_value_scale") or 1.0),
+                num_experts=router,
+                num_experts_held=None if held == router else held,
+                first_expert=d.get("first_expert", 0),
+                num_experts_per_tok=d["num_experts_per_tok"],
+                num_dense_layers=n_dense,
+                moe_intermediate_size=d["moe_intermediate_size"],
+                use_expert_bias=True,  # e_score_correction_bias
+                norm_topk_prob=d.get("norm_topk_prob", True),
+                routed_scaling_factor=1.0 if scaling is None else float(scaling),
+                router_norm_eps=1e-20,
+                tie_word_embeddings=d.get("tie_word_embeddings", False),
+                init_expert_specific=d.get("init_expert_specific"),
+                init_expert_out_std=d.get("init_expert_out_std"),
+            )
         if model_type == "qwen2":
             # Qwen-2/2.5: llama architecture with Q/K/V projection biases
             # and an unbiased o_proj (HF Qwen2Attention), untied head on
@@ -761,7 +965,7 @@ QWEN_2_5_1_5B = dataclasses.replace(
 # when ``num_local_experts`` is set)
 KNOWN_MODEL_TYPES = frozenset(
     ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe",
-     "falcon_h1", "deepseek_v3"))
+     "falcon_h1", "deepseek_v3", "mimo_v2"))
 
 PRESETS: dict[str, ModelConfig] = {
     "meta-llama/Llama-3.2-1B": LLAMA_3_2_1B,
@@ -855,6 +1059,23 @@ def tiny_config(model_type: str = "llama", **overrides: Any) -> ModelConfig:
             num_experts=8, num_experts_per_tok=2, num_dense_layers=1,
             moe_intermediate_size=32, shared_expert_intermediate_size=64,
             use_expert_bias=True, routed_scaling_factor=2.448,
+            router_norm_eps=1e-20,
+        )
+    if model_type == "mimo_v2":
+        # MiMo-V2's shape at toy sizes: a leading dense global layer, then
+        # expert layers ``w w g w`` (2 kinds, unequal kv heads), keys 24
+        # wide of which 8 rotate, values 16, window 8, a sink in the window
+        # layers, 16 experts top-4 (a test holds 4); no width of the model
+        base.update(
+            num_hidden_layers=5,
+            num_attention_heads=4, num_key_value_heads=1,
+            swa_num_key_value_heads=2, head_dim=24, v_head_dim=16,
+            rope_dim=8, rope_theta=1e7, swa_rope_theta=1e4,
+            rms_norm_eps=1e-5, tie_word_embeddings=False,
+            sliding_window=8, window_pattern=(0, 1, 1, 0, 1), swa_sink=True,
+            attention_value_scale=0.707,
+            num_experts=16, num_experts_per_tok=4, num_dense_layers=1,
+            moe_intermediate_size=32, use_expert_bias=True,
             router_norm_eps=1e-20,
         )
     base.update(overrides)

@@ -43,7 +43,11 @@ from llm_np_cp_tpu.cache import (
 )
 from llm_np_cp_tpu.config import ModelConfig
 from llm_np_cp_tpu.ops.activations import ACT2FN, softcap
-from llm_np_cp_tpu.ops.attention import causal_mask, gqa_attention
+from llm_np_cp_tpu.ops.attention import (
+    attend_in_query_blocks,
+    causal_mask,
+    gqa_attention,
+)
 from llm_np_cp_tpu.ops.moe import (
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTE,
@@ -82,9 +86,15 @@ SCOPE_SSM_SCAN = "ssm_scan"
 # shared experts beside the routed ones add one: the SwiGLU every token
 # takes (its input norm is the router's, under ``moe_route``)
 SCOPE_MOE_SHARED = "moe_shared"
+# a stack whose window layers hold pages of their own tells its two kinds
+# of attention apart (entered inside ``attn``: the innermost scope names
+# an operation, serve/opmap.py)
+SCOPE_ATTN_GLOBAL = "attn_global"
+SCOPE_ATTN_WINDOW = "attn_window"
 # ... which only a stack with such layers enters
 HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
-                 SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, SCOPE_MOE_SHARED)
+                 SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, SCOPE_MOE_SHARED,
+                 SCOPE_ATTN_GLOBAL, SCOPE_ATTN_WINDOW)
 STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
                SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL) + HYBRID_SCOPES
 
@@ -169,7 +179,7 @@ def _group_shapes(
     NH, NK = config.num_attention_heads, config.num_key_value_heads
     if config.attention_bias or config.mlp_bias or config.conv_bias:
         raise NotImplementedError("a hybrid stack has no biased projection")
-    if op not in ("conv", "attn", "attn_ssm", "latent"):
+    if op not in ("conv", "attn", "attn_ssm", "latent", "swa"):
         raise ValueError(f"unknown layer operator {op!r}")
     if op == "latent":
         # latent attention: a query head is [q_nope | q_pe]; kv_a_proj's
@@ -193,13 +203,18 @@ def _group_shapes(
             "out_proj": (n, H, H),
         }
     else:
+        # a layer kind's own kv heads and value width (config.attn_kind)
+        kind = config.attn_kind("window" if op == "swa" else "global")
+        NK, Dv = kind.kv_heads, kind.value_dim
         shapes = {
             "ln_attn_in": (n, H),
             "q_proj": (n, H, NH * D),
             "k_proj": (n, H, NK * D),
-            "v_proj": (n, H, NK * D),
-            "o_proj": (n, NH * D, H),
+            "v_proj": (n, H, NK * Dv),
+            "o_proj": (n, NH * Dv, H),
         }
+        if kind.sink:
+            shapes["attn_sink"] = (n, NH)  # one learned logit a query head
         if config.qk_norm:
             shapes.update(ln_q=(n, D), ln_k=(n, D))
     if op == "attn_ssm":
@@ -293,6 +308,13 @@ def init_params(
                         return jnp.ones(shape, jnp.float32)
                     step = jnp.exp(math.log(1e-3) + u * math.log(1e2))
                     return step + jnp.log(-jnp.expm1(-step))
+                if name == "attn_sink":
+                    # float32, of the order of a row's largest scores (a
+                    # tenth to a half of the softmax's denominator over a
+                    # full window of seeded keys): at 0 a sink would be a
+                    # five-hundredth of it and no comparison could see
+                    # one dropped
+                    return 5.0 + jax.random.normal(key, shape, jnp.float32)
                 if name.endswith("_bias"):
                     # biases start small-but-nonzero so tests exercise the add path
                     return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
@@ -506,7 +528,7 @@ def attention_block(
     if attn_fn is None:
         mask = (
             jnp.where(sliding, mask_local, mask_global)
-            if config.sliding_window is not None
+            if config.sliding_window is not None and mask_local is not None
             else mask_global
         )
     b, s = x.shape[:2]
@@ -521,8 +543,13 @@ def attention_block(
         if config.attention_in_multiplier != 1.0:
             h = h * jnp.array(config.attention_in_multiplier, h.dtype)
         q = _proj_b(h, "q_proj").reshape(b, s, config.num_attention_heads, config.head_dim)
-        k = _proj_b(h, "k_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
-        v = _proj_b(h, "v_proj").reshape(b, s, config.num_key_value_heads, config.head_dim)
+        # (the kv heads are the layer's own: a window layer may have more
+        # than a global one, and a value head another width than a key's)
+        k = _proj_b(h, "k_proj").reshape(b, s, -1, config.head_dim)
+        v = _proj_b(h, "v_proj").reshape(b, s, k.shape[2], config.value_dim)
+        if config.attention_value_scale != 1.0:
+            # linear in v: on the values (K times fewer than the outputs)
+            v = v * jnp.array(config.attention_value_scale, v.dtype)
         if config.key_multiplier != 1.0:
             k = k * jnp.array(config.key_multiplier, k.dtype)
         if config.qk_norm:
@@ -590,6 +617,7 @@ def attention_block(
                 scale=config.attn_scale,
                 logit_softcap=config.attn_logit_softcapping,
                 return_weights=output_attentions,
+                sink=w.get("attn_sink"),
             )
             if output_attentions:
                 attn, attn_weights = attn
@@ -699,19 +727,9 @@ def _attend_in_query_blocks(q, k, v, mask, *, scale: float, block: int):
     """``gqa_attention`` over ``block`` queries at a time (one kv head a
     query head): ``[b, s, h, dv]``.  The float32 scores of 4 x 2,176
     tokens x 32 heads at once would be 2.4 GB beside the weights."""
-    b, s = q.shape[:2]
-    if s <= block:
+    if q.shape[1] <= block:
         return gqa_attention(q, k, v, mask, scale=scale)
-    n = -(-s // block)
-    pad = n * block - s
-    qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    mp = jnp.pad(jnp.broadcast_to(mask, (b, s, k.shape[1])),
-                 ((0, 0), (0, pad), (0, 0)))
-    out = lax.map(
-        lambda qm: gqa_attention(qm[0], k, v, qm[1], scale=scale),
-        (jnp.moveaxis(qp.reshape(b, n, block, *q.shape[2:]), 1, 0),
-         jnp.moveaxis(mp.reshape(b, n, block, -1), 1, 0)))
-    return jnp.moveaxis(out, 0, 1).reshape(b, n * block, *out.shape[3:])[:, :s]
+    return attend_in_query_blocks(q, k, v, mask, block=block, scale=scale)
 
 
 def input_norm(w: Params, x: jnp.ndarray, config: ModelConfig) -> jnp.ndarray:
@@ -904,17 +922,39 @@ def experts_block(
     b, s, hdim = x.shape
     with jax.named_scope(SCOPE_MOE_ROUTE):
         h = rms_norm(x, w["ln_mlp_in"], eps=config.rms_norm_eps)
-    out, chosen, load = moe_dropless(
-        h.reshape(b * s, hdim), w["router"], w.get("expert_bias"),
-        w["w1"], w["w3"], w["w2"], act=act,
-        top_k=config.num_experts_per_tok,
-        norm_topk_prob=config.norm_topk_prob,
-        scaling=config.routed_scaling_factor,
-        norm_eps=config.router_norm_eps,
-        live=None if live is None else live.reshape(b * s),
-        first_expert=config.first_expert,
-        out_dtype=x.dtype,
-    )
+
+    def routed(h, live):  # [T, H], [T] | None
+        return moe_dropless(
+            h, w["router"], w.get("expert_bias"),
+            w["w1"], w["w3"], w["w2"], act=act,
+            top_k=config.num_experts_per_tok,
+            norm_topk_prob=config.norm_topk_prob,
+            scaling=config.routed_scaling_factor,
+            norm_eps=config.router_norm_eps,
+            live=live, first_expert=config.first_expert,
+            out_dtype=x.dtype,
+        )
+
+    t, chunk = b * s, EXPERT_CHUNK_PAIRS // config.num_experts_per_tok
+    flat_live = None if live is None else live.reshape(t)
+    if t <= 2 * chunk:
+        out, chosen, load = routed(h.reshape(t, hdim), flat_live)
+    else:
+        # a long plain forward (the benchmark's check: 4 x 4,864 tokens x
+        # 8 experts): the sorted rows of all pairs at once, in and out in
+        # float32, are 6 GB beside the weights.  Routing is a token's
+        # own, so ``chunk`` tokens at a time give the same result
+        n = -(-t // chunk)
+        pad = n * chunk - t
+        hp = jnp.pad(h.reshape(t, hdim), ((0, pad), (0, 0)))
+        lp = jnp.pad(jnp.ones((t,), jnp.bool_) if flat_live is None
+                     else flat_live, (0, pad))
+        out, chosen, load = lax.map(
+            lambda hl: routed(*hl),
+            (hp.reshape(n, chunk, hdim), lp.reshape(n, chunk)))
+        out = out.reshape(n * chunk, hdim)[:t]
+        chosen = chosen.reshape(n * chunk, -1)[:t]
+        load = load.sum(axis=0)
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         x = x + out.reshape(b, s, hdim)
     if "shared_gate" in w:
@@ -924,6 +964,12 @@ def experts_block(
                 act(_project(hs, w["shared_gate"]))
                 * _project(hs, w["shared_up"]), w["shared_down"], x.dtype)
     return x, chosen.reshape(b, s, -1), load
+
+
+# (token, expert) pairs ``experts_block`` sorts and multiplies at once:
+# above twice this it goes over the tokens in chunks of it (a serving
+# tick's pairs, and every accepted check's, are far below)
+EXPERT_CHUNK_PAIRS = 32768
 
 
 def scan_group(body: Any, carry: Any, xs: Any, count: int) -> tuple:
@@ -996,9 +1042,13 @@ def _hybrid_stack(
     sin: jnp.ndarray,
     mask: jnp.ndarray,
     token_mask: jnp.ndarray | None,
+    mask_local: jnp.ndarray | None = None,
+    rope_window: tuple | None = None,
 ) -> tuple:
     """``forward``'s layer loop for a stack of more than one kind of
-    layer: every run of like layers (``config.layer_groups``) is scanned
+    layer (``mask_local`` and ``rope_window``: a window layer's mask and
+    its own RoPE tables, where the configuration has such layers): every
+    run of like layers (``config.layer_groups``) is scanned
     over its own stacked leaves, an attention run carrying its cache
     slabs, a conv run its short-convolution state and a run with a
     state-space mixer both and the recurrent state as ``xs`` / ``ys``.
@@ -1010,6 +1060,11 @@ def _hybrid_stack(
             "int8 cache and per-row rollback (batched speculative decoding) "
             "are not implemented for it"
         )
+    if cache is not None and config.two_page_classes:
+        raise NotImplementedError(
+            "the offline cache holds one kind of K/V page: window layers "
+            "with kv heads of their own are served by the paged pool "
+            "(serve/block_pool.py) or run without a cache")
     act = ACT2FN[config.hidden_act]
     b = x.shape[0]
     # The residual stream is float32 between the blocks (each block
@@ -1058,8 +1113,10 @@ def _hybrid_stack(
                     ys["k"] = rows
             elif op != "conv":
                 normed = input_norm(w, x, config) if op == "attn_ssm" else None
+                l_cos, l_sin = rope_window if op == "swa" else (cos, sin)
                 mixed, kv_att, _ = attention_block(
-                    w, x, config=config, cos=cos, sin=sin, mask_global=mask,
+                    w, x, config=config, cos=l_cos, sin=l_sin,
+                    mask_global=mask_local if op == "swa" else mask,
                     kv_update=(
                         (lambda k, v: update_layer(
                             state["k"], state["v"], k, v, offset))
@@ -1269,6 +1326,11 @@ def forward(
             sin=sin, mask=mask_global, token_mask=(
                 jnp.broadcast_to(attn_mask, (b, s))
                 if attn_mask is not None else None),
+            mask_local=mask_local,
+            rope_window=(rope_cos_sin(
+                positions, config, dtype=jnp.float32,
+                theta=config.swa_rope_theta)
+                if config.swa_rope_theta else (cos, sin)),
         )
         logits = (
             (x[:, -1:, :] if logits_last_only else x) if skip_logits

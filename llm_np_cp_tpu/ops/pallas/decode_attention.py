@@ -520,7 +520,7 @@ _RAGGED_STEP_POSITIONS = 512
 # meta rows for _ragged_kernel (computed in-graph per layer — the
 # sliding-window bound is a traced per-layer value)
 (_RM_START, _RM_COUNT, _RM_PAD, _RM_QPOS0, _RM_QLEN, _RM_WIN, _RM_ROW,
- _RM_NEXT, _RM_FIRST) = range(9)
+ _RM_NEXT, _RM_FIRST, _RM_BASE) = range(10)
 
 
 def _sublane_tile(rows: int, dtype) -> int:
@@ -566,6 +566,8 @@ def _lane_pack(kv_heads: int, head_dim: int) -> int:
     its own lanes and zeros on its neighbours' (``_lane_spread``), and
     the MXU, 128 deep whatever it is given, does the same work."""
     pack = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    if head_dim == 192:  # a lane and a half: two heads are three whole rows
+        pack = 2
     return pack if kv_heads % pack == 0 else 1
 
 
@@ -614,6 +616,8 @@ def _ragged_kernel(
     scale: float, softcap: float | None, quantized: bool, kv_heads: int,
     group: int, block_s: int, q_tile: int, head_dim: int, pages: int,
     mb: int, by_hand: tuple[bool, ...], pack: int = 0,
+    value_dim: int | None = None, v_pack: int | None = None,
+    has_sink: bool = False, has_base: bool = False,
 ):
     """Mixed-batch block-table attention: each q tile holds up to
     ``q_tile`` consecutive tokens of ONE row (a prefill-chunk slice, or a
@@ -649,9 +653,21 @@ def _ragged_kernel(
     lanes are scored by one dot against that whole slice of K, their
     ``p @ V`` comes out ``pack * head_dim`` wide (each head's result on
     its own lanes, its neighbours' beside it) and ``_finalize`` takes
-    each head's lanes."""
+    each head's lanes.  A value head may have another width than a key
+    head (``value_dim``; merged V pages pack by their own width,
+    ``v_pack``: at 128 lanes a head is sliced alone).
+
+    ``has_sink``: a ``[rows, 1]`` float32 operand holds one learned logit
+    a query head, laid out like the score sheet's rows; it seeds the
+    running maximum (on AMLA's grid) and the denominator of a tile before
+    its first page — a column of the softmax that has no value.
+    ``has_base``: meta row ``_RM_BASE`` is the logical block the row's
+    table starts at (a window chain's first block is not position 0)."""
+    value_dim = head_dim if value_dim is None else value_dim
+    v_pack = pack if v_pack is None else v_pack
     it = iter(refs)
     q_ref = next(it)
+    sink_ref = next(it) if has_sink else None
     # a pool array: the whole of it in HBM, or its ``pages`` page blocks
     sources = [next(it) if hand else [next(it) for _ in range(pages)]
                for hand in by_hand]
@@ -716,17 +732,24 @@ def _ragged_kernel(
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        if has_sink:
+            b = sink_ref[:]
+            m0 = jnp.ceil(b * _LOG2E) * _LN2  # the grid point above it
+            m_ref[:] = m0
+            l_ref[:] = jnp.exp(b - m0)
+        else:
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     @pl.when(j * pages < count)
     def _update():
         half = fetch_group() if copied else None
+        base = meta_ref[_RM_BASE, ti] if has_base else 0
 
         # rank-2 iota (Mosaic rejects rank-1 iota on TPU)
         q_idx = jax.lax.broadcasted_iota(jnp.int32, (q_tile, width), 0)
-        kv_pos = (start + j * pages) * block_s + jax.lax.broadcasted_iota(
+        kv_pos = (base + start + j * pages) * block_s + jax.lax.broadcasted_iota(
             jnp.int32, (q_tile, width), 1
         )
         q_slot = qpos0 + q_idx
@@ -758,7 +781,7 @@ def _ragged_kernel(
             def q_of(c):
                 return q_ref[0, c]
 
-            def kv_of(b, c):
+            def k_of(b, c):
                 return b[:, c * lanes:(c + 1) * lanes]
         else:
             dots, rows = kv_heads, q_tile * group
@@ -766,7 +789,18 @@ def _ragged_kernel(
             def q_of(ki):
                 return q_ref[:, ki].reshape(q_tile * group, head_dim)
 
-            def kv_of(b, ki):
+            def k_of(b, ki):
+                return b[:, ki]
+        if v_pack:
+            v_lanes = v_pack * value_dim
+            v_dots, v_rows = kv_heads // v_pack, v_pack * q_tile * group
+
+            def v_of(b, c):
+                return b[:, c * v_lanes:(c + 1) * v_lanes]
+        else:
+            v_dots, v_rows = kv_heads, q_tile * group
+
+            def v_of(b, ki):
                 return b[:, ki]
         # per-kv-head MXU dots over the whole tile, concatenated to ONE
         # [K*q_tile*G, width] score sheet (rows ordered (ki, qi, gi))
@@ -775,7 +809,7 @@ def _ragged_kernel(
         s = jnp.concatenate(
             [
                 jax.lax.dot_general(
-                    q_of(i), kv_of(kb, i), (((1,), (1,)), ((), ())),
+                    q_of(i), k_of(kb, i), (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
                 for i in range(dots)
@@ -806,14 +840,14 @@ def _ragged_kernel(
         pv = jnp.concatenate(
             [
                 jax.lax.dot_general(
-                    pb[i * rows:(i + 1) * rows],
-                    kv_of(vb, i), (((1,), (0,)), ((), ())),
+                    pb[i * v_rows:(i + 1) * v_rows],
+                    v_of(vb, i), (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
-                for i in range(dots)
+                for i in range(v_dots)
             ],
             axis=0,
-        )  # [K*q_tile*G, D]  (merged: pack * D wide)
+        )  # [K*q_tile*G, Dv]  (merged: v_pack * Dv wide)
         acc_ref[:] = _amla_rescale(acc_ref[:], k_steps) + pv
         m_ref[:] = m_new
 
@@ -823,11 +857,11 @@ def _ragged_kernel(
         acc = acc_ref[:] / l
         for ki in range(kv_heads):
             mine = acc[ki * q_tile * group:(ki + 1) * q_tile * group]
-            if pack > 1:  # this head's lanes of the row it shares
-                lane0 = ki % pack * head_dim
-                mine = mine[:, lane0:lane0 + head_dim]
+            if v_pack > 1:  # this head's lanes of the row it shares
+                lane0 = ki % v_pack * value_dim
+                mine = mine[:, lane0:lane0 + value_dim]
             o_ref[:, ki] = (
-                mine.reshape(q_tile, group, head_dim).astype(o_ref.dtype)
+                mine.reshape(q_tile, group, value_dim).astype(o_ref.dtype)
             )
 
 
@@ -850,6 +884,8 @@ def ragged_paged_attention(
     scale: float,
     logit_softcap: float | None = None,
     interpret: bool | None = None,
+    sink: jnp.ndarray | None = None,
+    block0: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Mixed prefill+decode GQA attention straight off a paged KV pool.
 
@@ -891,6 +927,16 @@ def ragged_paged_attention(
 
     int8 pool mode: k_scale/v_scale [NB, BS, K] f32 scale pages ride
     along and the kernel dequantizes per group in VMEM.
+
+    ``v_pages`` may hold heads of another width than ``k_pages``
+    (``[.., K, Dv]`` or merged ``[.., K * Dv]``): the result is ``[T, H,
+    Dv]``.  ``sink`` [H] float32: a learned logit a query head in every
+    row's softmax denominator, with no value (None, a static absence,
+    compiles to the kernel without one).  ``block0`` [R] int32: the
+    logical block of each row's table column 0 (None: 0) — a window
+    layer's chain holds only the blocks its window still reaches, so its
+    table starts at ``block0[row]`` and kv position ``p`` lies in column
+    ``p // BS - block0[row]``.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -929,14 +975,17 @@ def ragged_paged_attention(
                 f"{list(k_pages.shape)}")
         _, block_s, kd = k_pages.shape
         kh = kd // d
+        dv = v_pages.shape[-1] // kh
     else:
         _, block_s, kh, _ = k_pages.shape
+        dv = v_pages.shape[-1]
     g = h // kh
     mb = tables.shape[1]
     pages = ragged_pages_per_step(
         mb, block_s, kh, d, k_pages.dtype, quantized, merged=merged)
     steps = -(-mb // pages)
     pack = _lane_pack(kh, d) if merged else 0
+    v_pack = _lane_pack(kh, dv) if merged else 0
 
     qf = q.reshape(t, kh, g, d)
     # per-tile kv page bounds: the window lower bound is tightest at the
@@ -946,8 +995,9 @@ def ragged_paged_attention(
     row_pad = pads[tile_row]
     lo = jnp.maximum(row_pad, tile_qpos0 - window + 1)
     hi = tile_qpos0 + jnp.maximum(tile_qlen, 1) - 1
-    start = jnp.clip(lo // block_s, 0, jnp.maximum(mb - 1, 0))
-    nb = jnp.clip(hi // block_s + 1, 1, mb)
+    row_b0 = 0 if block0 is None else block0[tile_row]
+    start = jnp.clip(lo // block_s - row_b0, 0, jnp.maximum(mb - 1, 0))
+    nb = jnp.clip(hi // block_s + 1 - row_b0, 1, mb)
     count = jnp.where(tile_qlen > 0, jnp.maximum(nb - start, 0), 0)
     # the next tile with a page to stream, and the first of all: what a
     # tile's last live step prefetches, and which step has to fetch for
@@ -958,7 +1008,8 @@ def ragged_paged_attention(
         start, count, row_pad, tile_qpos0, tile_qlen,
         jnp.broadcast_to(window, tile_row.shape), tile_row,
         jnp.append(later[1:], nt), jnp.broadcast_to(later[0], tile_row.shape),
-    ]).astype(jnp.int32)  # [9, NT]
+        *([] if block0 is None else [row_b0]),
+    ]).astype(jnp.int32)  # [9, NT] (a table with a base: [10, NT])
 
     def tile_map(ti, j, meta_ref, tables_ref):
         return (ti, 0, 0, 0)
@@ -980,12 +1031,24 @@ def ragged_paged_attention(
     by_hand = tuple(_dma_slices_pages(a) for a in pools)
     tile_spec = pl.BlockSpec(
         (qt, kh, g, d), tile_map, memory_space=pltpu.VMEM)
+    out_spec = pl.BlockSpec(
+        (qt, kh, g, dv), tile_map, memory_space=pltpu.VMEM)
     in_specs, operands = [tile_spec], [qf]
     if merged:
         spread = _lane_spread(qf, qt, pack)
         in_specs, operands = [pl.BlockSpec(
             (1,) + spread.shape[1:], tile_map,
             memory_space=pltpu.VMEM)], [spread]
+    rows = kh * qt * g
+    if sink is not None:
+        # one logit a query head, in the order of the score sheet's rows
+        # (kv head, token of the tile, head of the group)
+        in_specs.append(pl.BlockSpec(
+            (rows, 1), lambda ti, j, meta_ref, tables_ref: (0, 0),
+            memory_space=pltpu.VMEM))
+        operands.append(jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(kh, 1, g), (kh, qt, g)
+        ).reshape(rows, 1))
     for a, hand in zip(pools, by_hand):
         if hand:
             in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
@@ -994,11 +1057,10 @@ def ragged_paged_attention(
             in_specs += [page_spec(p, (1,) + a.shape[1:])
                          for p in range(pages)]
             operands += [a] * pages
-    rows = kh * qt * g
     scratch = [
         pltpu.VMEM((rows, 1), jnp.float32),
         pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((rows, max(pack, 1) * d), jnp.float32),
+        pltpu.VMEM((rows, max(v_pack, 1) * dv), jnp.float32),
         # two halves of a group of pages for each array copied by hand
         *[pltpu.VMEM((2, pages) + a.shape[1:], a.dtype)
           for a, hand in zip(pools, by_hand) if hand],
@@ -1011,19 +1073,20 @@ def ragged_paged_attention(
             _ragged_kernel, scale=scale, softcap=logit_softcap,
             quantized=quantized, kv_heads=kh, group=g, block_s=block_s,
             q_tile=qt, head_dim=d, pages=pages, mb=mb, by_hand=by_hand,
-            pack=pack,
+            pack=pack, value_dim=dv, v_pack=v_pack,
+            has_sink=sink is not None, has_base=block0 is not None,
         ),
-        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, kh, g, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(nt, steps),
             in_specs=in_specs,
-            out_specs=tile_spec,
+            out_specs=out_spec,
             scratch_shapes=scratch,
         ),
         interpret=interpret,
     )(meta, tables.reshape(-1).astype(jnp.int32), *operands)
-    return out.reshape(t, h, d)
+    return out.reshape(t, h, dv)
 
 
 def ragged_paged_attention_xla(
@@ -1041,6 +1104,8 @@ def ragged_paged_attention_xla(
     v_scale: jnp.ndarray | None = None,
     scale: float,
     logit_softcap: float | None = None,
+    sink: jnp.ndarray | None = None,
+    block0: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """XLA reference/fallback for ``ragged_paged_attention`` (per-TOKEN
     metadata instead of per-tile): gathers each engine row's blocks into
@@ -1057,7 +1122,8 @@ def ragged_paged_attention_xla(
     s_max = mb * block_s
 
     def gathered(pages, scales):
-        view = pages[tables].reshape(tables.shape[0], s_max, kh, d)
+        # (a value head may be narrower than a key head)
+        view = pages[tables].reshape(tables.shape[0], s_max, kh, -1)
         if scales is None:
             return view
         sv = scales[tables].reshape(tables.shape[0], s_max, kh)
@@ -1070,6 +1136,8 @@ def ragged_paged_attention_xla(
     k_t = k_rows[tok_row]  # [T, S_max, K, D]
     v_t = v_rows[tok_row]
     kv_idx = jnp.arange(s_max, dtype=jnp.int32)[None, :]
+    if block0 is not None:  # the table's column 0 is that logical block
+        kv_idx = kv_idx + (block0[tok_row] * block_s)[:, None]
     lower = jnp.maximum(pads[tok_row], tok_slot - window + 1)[:, None]
     mask = (
         (kv_idx >= lower) & (kv_idx <= tok_slot[:, None])
@@ -1079,7 +1147,7 @@ def ragged_paged_attention_xla(
 
     out = gqa_attention(
         q[:, None], k_t, v_t, mask[:, None, :],
-        scale=scale, logit_softcap=logit_softcap,
+        scale=scale, logit_softcap=logit_softcap, sink=sink,
     )
     return out[:, 0]
 

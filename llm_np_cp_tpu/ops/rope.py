@@ -26,12 +26,13 @@ import jax.numpy as jnp
 from llm_np_cp_tpu.config import ModelConfig
 
 
-def _inv_freq(config: ModelConfig) -> jnp.ndarray:
-    dim = config.head_dim
+def _inv_freq(config: ModelConfig, theta: float | None = None) -> jnp.ndarray:
+    # the rotated columns: all of a head, or its leading ``rope_dim``
+    dim = config.rope_dim or config.head_dim
     inv_freq = 1.0 / (
         # float(): a published theta of 1e11, read from JSON as an int,
         # is more than an int32 operand holds
-        float(config.rope_theta)
+        float(theta or config.rope_theta)
         ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     )
     if config.rope_scaling_type == "llama3":
@@ -54,10 +55,15 @@ def _inv_freq(config: ModelConfig) -> jnp.ndarray:
 
 
 def rope_cos_sin(
-    positions: jnp.ndarray, config: ModelConfig, dtype: jnp.dtype = jnp.float32
+    positions: jnp.ndarray, config: ModelConfig, dtype: jnp.dtype = jnp.float32,
+    theta: float | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """cos/sin tables for ``positions`` (any leading shape) → [..., head_dim]."""
-    inv_freq = _inv_freq(config)
+    """cos/sin tables for ``positions`` (any leading shape) → [...,
+    head_dim] — or ``[..., rope_dim]`` where the configuration rotates
+    only a head's leading columns (``apply_rope`` reads the width off the
+    table).  ``theta``: a layer kind's own base (``ModelConfig.attn_kind``;
+    default: ``rope_theta``)."""
+    inv_freq = _inv_freq(config, theta)
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., dim/2]
     emb = jnp.concatenate([freqs, freqs], axis=-1)  # [..., dim]
     return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
@@ -95,4 +101,12 @@ def apply_rope(
         x = deinterleave(x)
     cos = cos[..., None, :]
     sin = sin[..., None, :]
+    rd = cos.shape[-1]
+    if rd < x.shape[-1]:
+        # partial rotation: the leading ``rd`` columns rotate (pairs
+        # ``(i, i + rd/2)``), the rest pass as they are
+        xr = x[..., :rd]
+        return jnp.concatenate(
+            [(xr * cos + rotate_half(xr) * sin).astype(x.dtype), x[..., rd:]],
+            axis=-1)
     return (x * cos + rotate_half(x) * sin).astype(x.dtype)

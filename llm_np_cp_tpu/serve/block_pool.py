@@ -49,6 +49,28 @@ place at ``[layer, row]`` like the pages (donated); a slot's row is never
 read by a sequence's first token (a position-0 token's history and state
 are zero), so admitting a request into a freed slot needs no clear.
 
+A configuration whose WINDOW layers hold K/V of a shape of their own
+(``config.two_page_classes``: MiMo-V2's window layers have 8 kv heads, its
+global layers 4) has TWO page classes in this one manager:
+
+    k, v:       [global layers, num_blocks, BS, K * D] / [.., K * Dv]
+    window k,v: [window layers, 1 + slots * W, BS, Kw * D] / [.., Kw * Dv]
+
+The first is the class above: a request's chain of it grows with its
+context, out of the free list.  The second is BOUNDED: a query of a
+window layer sees only the last ``sliding_window`` positions, so a block
+whose last token has left every later query's window is never read again.
+Each decode slot owns a RING of ``W`` blocks of the window class
+(``window_blocks_per_slot``: the window, the widest slice a tick writes,
+and a block for the boundaries); logical block ``j`` of the slot's cache
+lives in ring entry ``j % W``, and the slot's chain is the run of logical
+blocks the coming tick can still see or writes — ``WindowRings.advance``
+moves it as the row advances, host-side, with no device work: a block
+that drops off the front is RECYCLED (its ring entry is the next one
+written).  Held full-length, MiMo-V2.5's five window layers of a period
+would be 25,600 of a token's 30,720 bytes; bounded, a token costs the two
+global layers' 5,120 and a slot a constant.
+
 int8 mode mirrors ``KVCache``'s quantized slabs: per-token-per-head
 absmax scales (cache.quantize_kv layout) ride in parallel
 ``[L, NB, BS, K]`` f32 pages.
@@ -65,6 +87,7 @@ slabs update in place.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, NamedTuple
 
 import jax
@@ -148,6 +171,89 @@ class FreeList:
                 self._free.append(i)
 
 
+def window_blocks_per_slot(window: int, widest_slice: int,
+                           block_size: int) -> int:
+    """Blocks of the window class a decode slot's ring holds: what one
+    tick can need at once — the ``window - 1`` positions before the first
+    query of the widest slice a tick writes (``widest_slice`` tokens: the
+    prefill chunk, or the budget if that is smaller), the slice itself,
+    and one block more because the run starts anywhere inside a block.
+    The ONE statement of the rule: the engine sizes the class by it and
+    ``WindowRings.advance`` refuses a tick that would pass it."""
+    return -(-(window - 1 + widest_slice) // block_size) + 1
+
+
+class WindowRings:
+    """The window class's allocator (module docstring): slot ``s`` owns
+    blocks ``1 + s * W .. 1 + (s + 1) * W - 1`` (block 0 is the class's
+    scratch block, as in the free list), and holds the logical blocks
+    ``first[s] .. end[s] - 1`` of its request's cache live.  Pure Python /
+    NumPy, like ``FreeList``: testable without a device."""
+
+    def __init__(self, slots: int, per_slot: int, block_size: int,
+                 window: int) -> None:
+        self.slots, self.per_slot = slots, per_slot
+        self.block_size, self.window = block_size, window
+        self.first = np.zeros(slots, np.int64)
+        self.end = np.zeros(slots, np.int64)
+        self.recycled_total = 0
+
+    @property
+    def num_blocks(self) -> int:
+        return 1 + self.slots * self.per_slot
+
+    @property
+    def in_use(self) -> int:
+        return int((self.end - self.first).sum())
+
+    def block(self, slot: int, logical: int) -> int:
+        """The physical block that holds logical block ``logical`` of
+        ``slot``'s cache while it is live."""
+        return 1 + slot * self.per_slot + logical % self.per_slot
+
+    def chain(self, slot: int) -> list[int]:
+        """The slot's live blocks, oldest first."""
+        return [self.block(slot, j)
+                for j in range(int(self.first[slot]), int(self.end[slot]))]
+
+    def advance(self, slots: Any, starts: Any, n: int) -> int:
+        """The requests in ``slots`` (one, or an array of distinct ones)
+        write cache slots ``starts .. starts + n - 1`` this tick: keep
+        what their queries can see (from ``start - window + 1`` on), add
+        what they write; returns how many blocks dropped off the front
+        (recycled: their ring entries are written next)."""
+        slots = np.atleast_1d(np.asarray(slots, np.intp))
+        starts = np.atleast_1d(np.asarray(starts, np.int64))
+        bs = self.block_size
+        first = np.maximum(starts - self.window + 1, 0) // bs
+        end = (starts + n - 1) // bs + 1
+        if (end - first > self.per_slot).any():
+            raise AssertionError(
+                f"a tick of {n} tokens at slot {int(starts.max())} needs "
+                f"{int((end - first).max())} window blocks, the ring holds "
+                f"{self.per_slot}")
+        recycled = int(np.maximum(
+            np.minimum(first, self.end[slots]) - self.first[slots], 0).sum())
+        self.first[slots], self.end[slots] = first, end
+        self.recycled_total += recycled
+        return recycled
+
+    def table(self, slots: Any) -> np.ndarray:
+        """``[per_slot]`` int32 (``[n, per_slot]`` for an array of slots):
+        a slot's table for the coming tick — column ``c`` is logical
+        block ``first + c`` (scratch 0 past the live run)."""
+        at = np.asarray(slots, np.intp)
+        cols = np.arange(self.per_slot)
+        first, live = self.first[at], (self.end - self.first)[at]
+        tab = 1 + at[..., None] * self.per_slot + (
+            (first[..., None] + cols) % self.per_slot)
+        return np.where(cols < live[..., None], tab, 0).astype(np.int32)
+
+    def release(self, slot: int) -> None:
+        """The slot's request left it (finished, aborted, preempted)."""
+        self.first[slot] = self.end[slot] = 0
+
+
 def merges_pages(kv_heads: int, head_dim: int, quantized: bool) -> bool:
     """Whether a pool of such pages is allocated ``[L, NB, BS, K * D]``
     (module docstring): a float page whose ``[BS, K, D]`` form a TPU
@@ -199,6 +305,9 @@ class PagedKV(NamedTuple):
     state: dict[str, jnp.ndarray] | None = None
     # set on a merged pool only; every other page says it by its shape
     form: PageForm | None = None
+    # the WINDOW class's pages (module docstring), ``(k, v)`` stored
+    # merged; None where the pool has one class
+    window: tuple[jnp.ndarray, jnp.ndarray] | None = None
 
     @property
     def quantized(self) -> bool:
@@ -236,8 +345,13 @@ class PagedKV(NamedTuple):
         return tuple(self.k.shape[3:])
 
     def pool_arrays(self) -> tuple[jnp.ndarray, ...]:
-        """The paged arrays (``[L, NB, BS, ..]``), without the state."""
+        """The paged arrays (``[L, NB, BS, ..]``) of the class whose
+        chains grow, without the state."""
         return tuple(a for a in self[:4] if a is not None)
+
+    def all_arrays(self) -> tuple[jnp.ndarray, ...]:
+        """Every paged array: both classes'."""
+        return self.pool_arrays() + (self.window or ())
 
     @property
     def num_blocks(self) -> int:
@@ -264,6 +378,7 @@ class BlockPool:
         enable_prefix_cache: bool = False,
         shardings: "PagedKV | None" = None,
         state_slots: int = 0,
+        window_blocks: int = 0,
     ) -> None:
         if block_size < 8 or block_size % 8:
             # Mosaic's second-minor alignment rule for the decode kernels;
@@ -288,11 +403,15 @@ class BlockPool:
         if latent:
             (d,), merged = token["k"], False
             page: tuple[int, ...] = (latent_page_width(d),)
+            v_page = page
         else:
             kh, d = token["k"]
-            merged = merges_pages(kh, d, quantized)
+            merged = merges_pages(kh, d, quantized) or config.two_page_classes
             page = (kh * d,) if merged else (kh, d)
-        shape = (len(config.attn_layers), num_blocks, block_size) + page
+            # (a value head may have another width than a key head)
+            v_page = (kh * token["v"][1],) if merged else token["v"]
+        lead = (len(config.global_layers), num_blocks, block_size)
+        shape = lead + page
         # mesh-sharded mode: a PagedKV of NamedShardings (kv-head axis on
         # "model", see parallel/sharding.paged_kv_specs) commits the slabs
         # onto the mesh; the FREE LIST stays global — allocation is a
@@ -320,13 +439,36 @@ class BlockPool:
 
         self.pages = PagedKV(
             k=zeros(shape, dtype, where.k),
-            v=None if latent else zeros(shape, dtype, where.v),
+            v=None if latent else zeros(lead + v_page, dtype, where.v),
             k_scale=(zeros(shape[:-1], jnp.float32, where.k_scale)
                      if quantized else None),
             v_scale=(zeros(shape[:-1], jnp.float32, where.v_scale)
                      if quantized else None),
             form=PageForm(d, latent) if merged or latent else None,
         )
+
+        # the window class (module docstring): a ring of ``window_blocks``
+        # blocks a slot, stored merged like the class above
+        self.window: WindowRings | None = None
+        if config.two_page_classes:
+            if quantized or shardings is not None:
+                raise ValueError(
+                    "a pool with a window class is a float pool on one "
+                    "device (int8 pages and a model axis have no rule for "
+                    "two page classes)")
+            if window_blocks < 2 or state_slots < 1:
+                raise ValueError(
+                    "a configuration whose window layers hold pages of "
+                    "their own needs state_slots and window_blocks "
+                    "(window_blocks_per_slot)")
+            self.window = WindowRings(
+                state_slots, window_blocks, block_size, config.sliding_window)
+            wt = config.kv_token_shapes("window")
+            w_lead = (len(config.window_layers), self.window.num_blocks,
+                      block_size)
+            self.pages = self.pages._replace(window=tuple(
+                zeros(w_lead + (math.prod(wt[leaf]),), dtype, None)
+                for leaf in ("k", "v")))
 
         # what a sequence carries besides K/V (module docstring): the
         # convolution history in the activations' dtype whatever the K/V
@@ -399,6 +541,12 @@ class BlockPool:
             "cache_only": cache_only,
             "request_held": allocated - cache_only,
         }
+        if self.window is not None:
+            out.update(
+                window_blocks_capacity=self.window.num_blocks - 1,
+                window_blocks_in_use=self.window.in_use,
+                window_blocks_per_slot=self.window.per_slot,
+                window_blocks_recycled_total=self.window.recycled_total)
         out.update(self.shard_stats())
         return out
 
@@ -413,11 +561,9 @@ class BlockPool:
         the same block ids, so per-shard occupancy IS ``occupancy`` by
         construction — that invariant is the whole point of replicated
         block tables."""
-        import math
-
         if self.pages is None:  # supervisor yanked the dead engine's slabs
             return {"kv_bytes_total": 0, "kv_bytes_shard": 0, "kv_shards": 1}
-        arrs = self.pages.pool_arrays()
+        arrs = self.pages.all_arrays()
         total = sum(a.nbytes for a in arrs)
         shard = 0
         for a in arrs:

@@ -121,6 +121,8 @@ from llm_np_cp_tpu.cache import KVCache, quantize_kv
 from llm_np_cp_tpu.config import ModelConfig
 from llm_np_cp_tpu.generate import IncrementalDetok, make_ragged_prefill_step
 from llm_np_cp_tpu.models.transformer import (
+    SCOPE_ATTN_GLOBAL,
+    SCOPE_ATTN_WINDOW,
     SCOPE_CONV,
     SCOPE_EMBED,
     SCOPE_SSM_PROJ,
@@ -143,7 +145,11 @@ from llm_np_cp_tpu.ops.activations import ACT2FN
 from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS, expert_row_tile
 from llm_np_cp_tpu.ops.rope import rope_cos_sin
 from llm_np_cp_tpu.ops.sampling import Sampler
-from llm_np_cp_tpu.serve.block_pool import BlockPool, PagedKV
+from llm_np_cp_tpu.serve.block_pool import (
+    BlockPool,
+    PagedKV,
+    window_blocks_per_slot,
+)
 from llm_np_cp_tpu.serve.faults import FaultInjected, FaultInjector
 from llm_np_cp_tpu.serve.metrics import ServeMetrics
 from llm_np_cp_tpu.serve.prefix_cache import prefix_block_keys
@@ -230,7 +236,7 @@ def _pack_sync(
 
 def mixed_operand_layout(
     t_w: int, d_w: int, q_tile: int, max_slots: int, max_blocks: int,
-    spec_w: int,
+    spec_w: int, window_blocks: int = 0,
 ) -> tuple[dict[str, tuple[int, tuple[int, ...]]], int]:
     """The unified step's ONE host-built operand, stated once: an int32
     vector whose sections are the packed batch — ``{section: (offset,
@@ -251,7 +257,13 @@ def mixed_operand_layout(
     sections are not int32 values: ``tok_live`` is a bool written 0 / 1,
     ``seeds`` the bits of a uint32.  The length grows with both widths:
     a program is one aval and one compile, and an engine's programs have
-    lengths of their own (``mixed_operand_program`` inverts it)."""
+    lengths of their own (``mixed_operand_program`` inverts it).  A pool
+    with a window class (``window_blocks`` a slot > 0) adds the SECOND
+    table as one more section: ``wtables``, every row's chain of the
+    window class for this tick, and ``wfirst``, the logical block its
+    column 0 is (the chain's first block is not position 0); where a
+    token is written in that class follows from the two in-graph.  A pool
+    with one class has neither section: its operand is what it was."""
     nt = t_w // q_tile
     shapes = {
         "tokens": (d_w,),      # packed input ids
@@ -271,6 +283,9 @@ def mixed_operand_layout(
         "seeds": (max_slots,),
         "verify_len": (max_slots,),         # live sample slots per row
     }
+    if window_blocks:
+        shapes["wtables"] = (max_slots, window_blocks)  # scratch-0 padded
+        shapes["wfirst"] = (max_slots,)  # logical block of column 0
     layout, size = {}, 0
     for name, shape in shapes.items():
         layout[name] = (size, shape)
@@ -343,7 +358,7 @@ def _pool_is_row_major(pages: PagedKV) -> bool:
     return all(
         getattr(a.format.layout, "major_to_minor", None)
         == tuple(range(a.ndim))
-        for a in pages.pool_arrays()
+        for a in pages.all_arrays()
     )
 
 
@@ -526,6 +541,33 @@ class ServeEngine:
                     raise ValueError(
                         f"model_type {config.model_type!r} keeps a latent "
                         f"(compressed) KV cache; refused: {why}")
+        if config.two_page_classes:
+            # window layers with K/V of a shape of their own live in a
+            # second, bounded page class (serve/block_pool.py): what
+            # shares, restores, quantizes or cuts the ONE class there was
+            # has no rule for two yet, and is refused here by the flag
+            # that asked for it
+            refused = [
+                (enable_prefix_cache, "--prefix-cache (enable_prefix_cache): "
+                 "a shared prefix has no window blocks to hand a new slot"),
+                (spec_k > 0, "--speculative-serve / --spec-k (spec_k): a "
+                 "rejected draft cannot be rolled back out of a ring whose "
+                 "blocks it recycled"),
+                (host_tier is not None, "--kv-tier host (host_tier): the "
+                 "tier spills and restores blocks of one class"),
+                (jnp.dtype(cache_dtype) == jnp.int8, "--cache-dtype int8 "
+                 "(cache_dtype): int8 pages of two classes are untested"),
+                (mesh_plan is not None and mesh_plan.model > 1,
+                 "--mesh model>1 (mesh_plan): two kv-head counts have no "
+                 "sharding rule, nor has the expert share"),
+                (mixed_step == "off", "--mixed-step off (mixed_step): only "
+                 "the unified tick packs the second table"),
+            ]
+            for hit, why in refused:
+                if hit:
+                    raise ValueError(
+                        f"model_type {config.model_type!r} keeps window "
+                        f"layers in a page class of their own; refused: {why}")
         from llm_np_cp_tpu.ops.pallas.support import (
             gate_attn_impl,
             kernel_error,
@@ -649,10 +691,12 @@ class ServeEngine:
                 self.mixed, self.ragged_attn_impl = True, "xla"
             else:
                 self.mixed = False
-        if (config.carries_state or config.is_latent) and not self.mixed:
+        if (config.carries_state or config.is_latent
+                or config.two_page_classes) and not self.mixed:
             raise ValueError(
-                f"model_type {config.model_type!r} (a recurrent state, or a "
-                "latent cache) is served by the unified tick only, which is "
+                f"model_type {config.model_type!r} (a recurrent state, a "
+                "latent cache or two page classes) is served by the unified "
+                "tick only, which is "
                 f"unavailable here ({err}); --mixed-step on takes its XLA "
                 "attention")
         # -- speculative serving (draft-then-verify in the unified tick):
@@ -773,17 +817,30 @@ class ServeEngine:
         )
 
         t_pool = tracer.now_us() if tracer is not None else -1.0
+        # a pool with a window class: the ring a slot holds, from the
+        # window and the widest slice one tick writes into a row (a
+        # prefill chunk, or the whole budget where that is smaller)
+        self.window_blocks = 0
+        if config.two_page_classes:
+            self.window_blocks = window_blocks_per_slot(
+                config.sliding_window,
+                min(self.prefill_chunk,
+                    tick_token_budget or self.prefill_chunk),
+                block_size)
         self.pool = BlockPool(
             config, num_blocks, block_size, dtype=cache_dtype,
             enable_prefix_cache=enable_prefix_cache,
             shardings=self._pool_shardings,
             state_slots=max_slots,
+            window_blocks=self.window_blocks,
         )
         if tracer is not None:
             jax.block_until_ready(self.pool.pages)
             tracer.complete("pool_alloc", t_pool, cat="setup", args={
                 "blocks": num_blocks, "bytes": int(sum(
                     a.nbytes for a in jax.tree.leaves(self.pool.pages))),
+                "window_blocks": (self.pool.window.num_blocks
+                                  if self.pool.window is not None else 0),
             })
         self.scheduler = Scheduler(
             self.pool,
@@ -794,8 +851,12 @@ class ServeEngine:
             ),
             prefill_plan=self._prefill_plan,
             max_queue=max_queue,
+            on_slot_release=(self.pool.window.release
+                             if self.pool.window is not None else None),
         )
         self.metrics = ServeMetrics(clock=clock)
+        # window blocks this tick's rows let go (tick args)
+        self._window_recycled_tick = 0
         # -- host-RAM KV block tier (serve/host_tier.HostTier): spilled
         # prefix blocks keyed by the SAME chained content hash the
         # prefix cache uses, restored at admission as ordinary claimed
@@ -937,7 +998,7 @@ class ServeEngine:
             self._mixed_geometry = (
                 self._q_tile, max_slots, self.max_blocks_per_seq,
                 self._spec_w,
-            )
+            ) + ((self.window_blocks,) if self.window_blocks else ())
             # spec engines get verify headroom in the default budget:
             # drafts only ever spend budget prefill left over, so
             # without the extra room a busy admission window would trim
@@ -998,6 +1059,16 @@ class ServeEngine:
                 "pool_bytes_per_token": self._block_nbytes // block_size,
                 "experts_held": (config.experts_held
                                  if config.num_experts else 0),
+                # a pool with a window class: what a token holds in each
+                # (``page_bytes_per_token`` counts the window layers as
+                # if they kept every token: what the class bounds), and
+                # the ring a slot owns
+                **({"pool_bytes_per_token_global": config.kv_bytes_per_token(
+                        self.cache_dtype.itemsize, "global"),
+                    "pool_bytes_per_token_window": config.kv_bytes_per_token(
+                        self.cache_dtype.itemsize, "window"),
+                    "window_blocks_per_slot": self.window_blocks}
+                   if self.window_blocks else {}),
             })
 
     def _make_buckets(
@@ -1092,10 +1163,29 @@ class ServeEngine:
         """``/metrics``: whether the tick's layer loop carries the pool
         flat and writes it in place (``pool_carried``), and the page's
         shape as a label."""
-        return {
+        out = {
             "pool_carried": float(self.pool_carried),
             f'pool_page_shape{{shape="{self.pool_page_shape}"}}': 1.0,
         }
+        rings = self.pool.window
+        if rings is not None:
+            # both page classes, in blocks and in what a block holds over
+            # the class's layers; the live context they serve
+            item, bs = self.cache_dtype.itemsize, self.block_size
+            out.update({
+                "kv_global_blocks_in_use": float(
+                    self.pool.free_list.num_allocated),
+                "kv_window_blocks_in_use": float(rings.in_use),
+                "kv_global_block_bytes": float(
+                    bs * self.config.kv_bytes_per_token(item, "global")),
+                "kv_window_block_bytes": float(
+                    bs * self.config.kv_bytes_per_token(item, "window")),
+                "window_blocks_recycled_total": float(rings.recycled_total),
+                "context_tokens_live": float(sum(
+                    r.cache_len - r.pad
+                    for r in list(self.scheduler.running))),
+            })
+        return out
 
     def _put(self, a: Any) -> jnp.ndarray:
         """Per-tick operand placement.  Under a mesh every host-built
@@ -2034,11 +2124,13 @@ class ServeEngine:
 
         hybrid = config.is_hybrid
         max_slots = geometry[1]
-        if config.is_latent and not carry_pool:
+        if (config.is_latent or config.two_page_classes) and not carry_pool:
             raise ValueError(
-                "a latent pool is read where it lies (flat over layer and "
-                "block); this device does not keep "
-                f"{self.pool_page_shape} pages in the order of their shape")
+                "a latent pool, or one with a window class, is read where "
+                "it lies (flat over layer and block); this device does not "
+                f"keep {self.pool_page_shape} pages in the order of their "
+                "shape")
+        window_blocks = self.window_blocks
         # the scope of the bookkeeping every layer's state shares
         state_scope = SCOPE_SSM_PROJ if config.ssm_layers else SCOPE_CONV
 
@@ -2064,6 +2156,11 @@ class ServeEngine:
                 cos, sin = rope_cos_sin(
                     o["positions"][None, :], config, dtype=jnp.float32
                 )
+                # a window layer's own RoPE base, where it has one
+                rope_window = (rope_cos_sin(
+                    o["positions"][None, :], config, dtype=jnp.float32,
+                    theta=config.swa_rope_theta)
+                    if config.swa_rope_theta else (cos, sin))
             act = ACT2FN[config.hidden_act]
             is_sliding = jnp.array(
                 [config.layer_is_sliding(i) for i in range(num_layers)],
@@ -2143,19 +2240,64 @@ class ServeEngine:
                     # the attention callables take "a pool of pages and
                     # block ids": this layer's ids in the pool it is given
                     layer_tables = tables + base
-                    if use_kernel:
-                        # the one place the tile-aligned axis exists:
-                        # spread the tokens over their tiles, attend,
-                        # bring each token's row back
-                        out = attn_call(
-                            q[0][lane_tok], kp2, vp2, *scales, layer_tables,
-                            tile_row, tile_qpos0, tile_qlen, pads, win_eff,
-                        )[tok_lane]
-                    else:
-                        out = attn_call(
-                            q[0], kp2, vp2, *scales, layer_tables, tok_row,
-                            tok_slot, tok_live, pads, win_eff,
-                        )
+                    # (a stack with a window class tells its two kinds of
+                    # attention apart in a device profile)
+                    with (jax.named_scope(SCOPE_ATTN_GLOBAL) if window_blocks
+                          else _NULL_CTX):
+                        if use_kernel:
+                            # the one place the tile-aligned axis exists:
+                            # spread the tokens over their tiles, attend,
+                            # bring each token's row back
+                            out = attn_call(
+                                q[0][lane_tok], kp2, vp2, *scales,
+                                layer_tables, tile_row, tile_qpos0,
+                                tile_qlen, pads, win_eff,
+                            )[tok_lane]
+                        else:
+                            out = attn_call(
+                                q[0], kp2, vp2, *scales, layer_tables,
+                                tok_row, tok_slot, tok_live, pads, win_eff,
+                            )
+                    return out[None]
+
+                return kv_update, attn_fn
+
+            def window_hooks(kp, vp, base, sink):
+                """A window layer's cache write and attention over the
+                WINDOW class's pages flat over (layer, block), the
+                layer's blocks from ``base`` on: ``(kv_update, attn_fn)``
+                as ``attention_block`` takes them.  A row's chain is the
+                second table (``wtables``; column 0 is logical block
+                ``wfirst[row]``): a token is written at the column of its
+                slot's block, and the kernel is told where the table
+                starts."""
+                wtables, wfirst = o["wtables"], o["wfirst"]
+                bs = kp.shape[1]
+                col = jnp.clip(tok_slot // bs - wfirst[tok_row], 0,
+                               window_blocks - 1)
+                blk = base + jnp.where(tok_live, wtables[tok_row, col], 0)
+
+                def kv_update(k, v):  # fresh projections [1, D, K, Dh]
+                    def put(pool, val):
+                        return pool.at[blk, tok_off].set(
+                            val[0].reshape(val.shape[1], -1).astype(pool.dtype))
+
+                    return put(kp, k), put(vp, v)
+
+                def attn_fn(q, k_att, v_att, sliding_l):
+                    kw = dict(scale=config.attn_scale, sink=sink,
+                              block0=wfirst)
+                    span = jnp.int32(win)
+                    with jax.named_scope(SCOPE_ATTN_WINDOW):
+                        if use_kernel:
+                            out = ragged_paged_attention(
+                                q[0][lane_tok], k_att, v_att, wtables + base,
+                                tile_row, tile_qpos0, tile_qlen, pads, span,
+                                **kw)[tok_lane]
+                        else:
+                            out = ragged_paged_attention_xla(
+                                q[0], k_att, v_att, wtables + base, tok_row,
+                                tok_slot, tok_live, pads, span, **kw)
                     return out[None]
 
                 return kv_update, attn_fn
@@ -2217,13 +2359,17 @@ class ServeEngine:
             if carry_pool:
                 pools = tuple(a.reshape((n_paged * nb,) + a.shape[2:])
                               for a in pools)
+            # the window class rides beside the first, flat likewise
+            wpools = tuple(a.reshape((-1,) + a.shape[2:])
+                           for a in pages.window or ())
             if hybrid:
                 # every run of like layers carries the pool: flat like
                 # the dense scan's, or (a pool the device permutes) whole
                 # and written in place at [layer, block, slot]
-                x, new_pools, new_state, loads = hybrid_layers(
-                    params["layers"], x, pools, state,
+                x, new_pools, wpools, new_state, loads = hybrid_layers(
+                    params["layers"], x, pools, wpools, state,
                     paged_hooks=paged_hooks, latent_hooks=latent_hooks,
+                    window_hooks=window_hooks, rope_window=rope_window,
                     act=act, cos=cos, sin=sin, layers=layers, ops=o, nb=nb)
             elif carry_pool:
                 xs = (params["layers"], is_sliding, layers)
@@ -2240,6 +2386,9 @@ class ServeEngine:
                 **{name: a.reshape(p.shape) for name, a, p in zip(
                     PagedKV._fields, new_pools, pages.pool_arrays())},
                 state=new_state))
+            if pages.window is not None:
+                new_pages = new_pages._replace(window=tuple(
+                    a.reshape(p.shape) for a, p in zip(wpools, pages.window)))
             # sampling ONLY at each row's sample slots — [R, W] indices
             # into the dense axis: column 0 is the plain sample (decode
             # rows and completing prefill segments), columns 1..k' are a
@@ -2300,8 +2449,9 @@ class ServeEngine:
                         [packed.reshape(-1), loads.reshape(-1)])
             return packed, new_pages
 
-        def hybrid_layers(groups, x, pools, state, *, paged_hooks,
-                          latent_hooks, act, cos, sin, layers, ops, nb):
+        def hybrid_layers(groups, x, pools, wpools, state, *, paged_hooks,
+                          latent_hooks, window_hooks, rope_window, act,
+                          cos, sin, layers, ops, nb):
             """The layer loop of a stack of more than one kind of layer:
             each run of like layers (``config.layer_groups``) is one scan
             over its own stacked leaves, and every run carries the pool
@@ -2320,8 +2470,12 @@ class ServeEngine:
             carries besides K/V (``state``: a convolution's history, a
             state-space mixer's recurrent state) is carried the same way,
             whole, and written in place at ``[layer, row]``: no run takes
-            its layers' rows out or puts them back.  Returns ``(x, pool,
-            state, per-expert loads [expert layers, E] | None)``."""
+            its layers' rows out or puts them back.  ``wpools``: the
+            window class's pages (flat over ITS layers and blocks; empty
+            where the pool has one class), which a window layer (``swa``)
+            writes and attends through the second table.  Returns ``(x,
+            pool, window pool, state, per-expert loads [expert layers, E]
+            | None)``."""
             tok_row, tok_live = ops["tok_row"], ops["tok_live"]
             positions = ops["positions"]
             d_w = tok_row.shape[0]
@@ -2378,14 +2532,20 @@ class ServeEngine:
                     new_rows.astype(kept.dtype), mode="drop")
 
             loads = []
-            a0 = c0 = 0
+            a0 = c0 = w0 = 0
+            # the window class's blocks a layer (``wpools``: its pages,
+            # flat over its own layers and blocks; empty without one)
+            nbw = 1 + max_slots * window_blocks
             # float32 between the blocks, as models.forward keeps it
             # (transformer._hybrid_stack says why)
             stream_dtype, x = x.dtype, x.astype(jnp.float32)
             for w_g, (op, ff, _, n) in zip(groups, config.layer_groups()):
                 # a layer's place among the layers with pages / a state
                 xs: dict[str, Any] = {}
-                if op != "conv":
+                if op == "swa":
+                    xs["paged"] = jnp.arange(w0, w0 + n, dtype=jnp.int32)
+                    w0 += n
+                elif op != "conv":
                     xs["paged"] = layers[a0:a0 + n]
                     a0 += n
                 if op in ("conv", "attn_ssm"):
@@ -2393,7 +2553,7 @@ class ServeEngine:
                     c0 += n
 
                 def body(carry, layer, op=op, ff=ff):
-                    x, pool, state = carry
+                    x, pool, wpool, state = carry
                     w, at = layer
                     state = None if state is None else dict(state)
                     ys: dict[str, Any] = {}
@@ -2413,6 +2573,15 @@ class ServeEngine:
                             w, x, config=config, cos=cos, sin=sin,
                             kv_update=kv_update, attn_fn=attn_fn)
                         pool = (rows,)
+                    elif op == "swa":
+                        # the window class's pages, always carried flat
+                        kv_update, attn_fn = window_hooks(
+                            *wpool, at["paged"] * nbw, w.get("attn_sink"))
+                        x, kv_att, _ = attention_block(
+                            w, x, config=config, cos=rope_window[0],
+                            sin=rope_window[1], kv_update=kv_update,
+                            attn_fn=attn_fn)
+                        wpool = tuple(kv_att)
                     else:
                         kp, vp, *scale_pages = pool
                         if carry_pool:
@@ -2459,13 +2628,13 @@ class ServeEngine:
                             live=tok_live[None])
                     else:
                         x, _ = ff_block(w, x, config=config, act=act)
-                    return (x, pool, state), ys
+                    return (x, pool, wpool, state), ys
 
-                (x, pools, state), ys = scan_group(
-                    body, (x, tuple(pools), state), (w_g, xs), n)
+                (x, pools, wpools, state), ys = scan_group(
+                    body, (x, tuple(pools), wpools, state), (w_g, xs), n)
                 if "load" in ys:
                     loads.append(ys["load"])
-            return (x.astype(stream_dtype), tuple(pools), state,
+            return (x.astype(stream_dtype), tuple(pools), wpools, state,
                     jnp.concatenate(loads, axis=0) if loads else None)
 
         return mixed_step
@@ -3633,6 +3802,7 @@ class ServeEngine:
         rows and prefill chunks, a few a tick, go segment by segment
         (``_fill_segment``)."""
         qb = self._q_tile
+        self._window_recycled_tick = 0
         sizes = [1 + r.draft_len for r in decode_rows]
         sizes.extend(n for _, n in prefill_segs)
         dense = list(itertools.accumulate(sizes, initial=0))
@@ -3667,6 +3837,19 @@ class ServeEngine:
                 cur, lane)
         return ops, program, len(plain)
 
+    def _advance_window(self, sec: dict[str, np.ndarray], slot: Any,
+                        start: Any, n: int) -> None:
+        """The row in ``slot`` (or the rows: arrays) writes cache slots
+        ``start .. start + n - 1`` this tick: move its chain of the
+        window class (a block no query of this or a later tick can see
+        goes back to the slot's ring: block_pool.WindowRings — host
+        bookkeeping, no device work) and write the chain into the
+        operand's second table."""
+        rings = self.pool.window
+        self._window_recycled_tick += rings.advance(slot, start, n)
+        sec["wtables"][slot] = rings.table(slot)
+        sec["wfirst"][slot] = rings.first[slot]
+
     def _fill_decode_rows(self, sec: dict[str, np.ndarray],
                           rows: Sequence[Request],
                           curs: Sequence[int],
@@ -3691,6 +3874,8 @@ class ServeEngine:
             blk.append(ids[last // bs])
         slot = np.asarray(slot, np.intp)
         sl = np.asarray(sl, np.int32)
+        if self.window_blocks:
+            self._advance_window(sec, slot, sl, 1)
         pos = sl - np.asarray(pad, np.int32)
         cur = np.asarray(curs, np.intp)
         lane = np.asarray(lanes, np.intp)
@@ -3727,6 +3912,8 @@ class ServeEngine:
         slot = r.slot
         blocks = np.asarray(r.block_ids, np.int32)
         sec["tables"][slot, :blocks.size] = blocks
+        if self.window_blocks:
+            self._advance_window(sec, slot, start_slot, n)
         sec["pads"][slot] = r.pad
         sec["seeds"][slot] = np.uint32(r.seed)
         idx = np.arange(n, dtype=np.int32)
@@ -4189,6 +4376,17 @@ class ServeEngine:
                 # the program takes for them — tiles x groups of
                 # ``attn_pages_per_step`` pages
                 "attn_pages": attn_pages,
+                # a pool with a window class: what one layer of EACH kind
+                # is asked to stream (a window layer's range starts
+                # ``window - 1`` slots before the tile's first token), the
+                # window blocks the rows hold and those this tick's pack
+                # let go
+                **({"attn_pages_global": attn_pages,
+                    "attn_pages_window": self._attn_window_pages(host_ops, (
+                        packed_width, dense_width)),
+                    "window_blocks_live": self.pool.window.in_use,
+                    "window_blocks_recycled": self._window_recycled_tick}
+                   if self.window_blocks and dispatched else {}),
                 "attn_grid_steps": attn_grid_steps,
                 "attn_pages_per_step": attn_step_pages,
                 # rows _pack_mixed wrote by whole-array assignments
@@ -4402,6 +4600,23 @@ class ServeEngine:
         steps = (t_w // self._q_tile) * -(-self.max_blocks_per_seq // per_step)
         return int((last - first + 1).sum()), steps, per_step
 
+    def _attn_window_pages(self, host_ops: np.ndarray,
+                           program: tuple[int, int]) -> int:
+        """``_attn_page_account``'s pages for one WINDOW layer's call:
+        over the live tiles, the blocks from ``window - 1`` slots before
+        the tile's first token (or the row's left pad) to its last."""
+        layout = self._mixed_layouts[program][0]
+
+        def section(name):
+            off, shape = layout[name]
+            return host_ops[off:off + shape[0]]
+
+        qlen, qpos0 = section("tile_qlen"), section("tile_qpos0")
+        live = qlen > 0
+        bs, win = self.block_size, self.config.sliding_window
+        lo = np.maximum(section("pads")[section("tile_row")], qpos0 - win + 1)
+        return int(((qpos0 + qlen - 1)[live] // bs - lo[live] // bs + 1).sum())
+
     def _dead_mixed_operands(self, t_w: int, d_w: int) -> np.ndarray:
         """The mixed step's operand for an all-dead batch of the program
         ``(t_w, d_w)`` (a host array): every lane points at the scratch
@@ -4441,7 +4656,7 @@ class ServeEngine:
         # mixer, under that mixer's scope, and is no pool move
         pool = opmap.pool_shapes(
             (a.dtype.name, a.sharding.shard_shape(a.shape))
-            for a in self.pool.pages.pool_arrays())
+            for a in self.pool.pages.all_arrays())
         return opmap.merge(
             opmap.op_map_from_hlo(
                 self._mixed_step.lower(

@@ -1344,6 +1344,19 @@ class HttpServer:
             mesh = getattr(self.runner.engine, "mesh_desc", None)
             if mesh:
                 payload["mesh"] = mesh
+            rings = getattr(getattr(self.runner.engine, "pool", None),
+                            "window", None)
+            if rings is not None:
+                # a pool with a window class states both (the growing
+                # class's blocks in use, the window class's and its ring)
+                stats = self.runner.engine.pool.stats()
+                payload["pool"] = {
+                    "global_blocks_in_use": stats["allocated"],
+                    "global_blocks_capacity": stats["capacity"],
+                    "window_blocks_in_use": stats["window_blocks_in_use"],
+                    "window_blocks_capacity": stats["window_blocks_capacity"],
+                    "window_blocks_per_slot": stats["window_blocks_per_slot"],
+                }
             replica_states = getattr(self.runner, "replica_states", None)
             if replica_states is not None:
                 payload["replicas"] = replica_states()

@@ -217,6 +217,7 @@ class Scheduler:
         prefill_plan: Callable[[Request], tuple[list[int], int]] | None = None,
         decode_reserve: int = 1,
         max_queue: int | None = None,
+        on_slot_release: Callable[[int], None] | None = None,
     ) -> None:
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
@@ -238,6 +239,10 @@ class Scheduler:
             lambda req: ([], self._blocks_for_prefill(req))
         )
         self.max_queue = max_queue
+        # what else a decode slot holds for its request, let go with the
+        # slot on finish, abort and preemption alike (a pool's window
+        # class keeps a ring of blocks a slot: block_pool.WindowRings)
+        self._on_slot_release = on_slot_release
         self.queue: deque[Request] = deque()
         self.running: list[Request] = []  # admission order (oldest first)
         self.finished: list[Request] = []
@@ -467,5 +472,7 @@ class Scheduler:
 
     def _release_slot(self, req: Request) -> None:
         if req.slot >= 0:
+            if self._on_slot_release is not None:
+                self._on_slot_release(req.slot)
             self._free_slots.append(req.slot)
             req.slot = -1

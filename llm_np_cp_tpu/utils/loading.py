@@ -49,6 +49,7 @@ from llm_np_cp_tpu.models import (
     gemma2,
     lfm2_moe,
     llama,
+    mimo_v2,
     qwen2,
 )
 from llm_np_cp_tpu.models.transformer import param_shapes
@@ -117,7 +118,8 @@ def _read_shard(
 
 # leaves that stay float32 whatever is served: the experts' selection
 # bias, a state-space recurrence's own scalars
-F32_LEAVES = frozenset(("expert_bias",)) | falcon_h1.F32_LEAVES
+F32_LEAVES = (frozenset(("expert_bias",)) | falcon_h1.F32_LEAVES
+              | mimo_v2.F32_LEAVES)
 # depthwise Conv1d weights, stored [C, 1, taps]
 CONV1D_LEAVES = frozenset(("conv_filter", "ssm_conv"))
 
@@ -125,7 +127,8 @@ CONV1D_LEAVES = frozenset(("conv_filter", "ssm_conv"))
 def hybrid_family(config: ModelConfig):
     """The family module whose ``layer_tensors`` places a hybrid stack's
     checkpoint tensors (``(HF key, run, leaf, index, transpose?)``)."""
-    return {"falcon_h1": falcon_h1, "deepseek_v3": deepseek_v3}.get(
+    return {"falcon_h1": falcon_h1, "deepseek_v3": deepseek_v3,
+            "mimo_v2": mimo_v2}.get(
         config.model_type, lfm2_moe)
 
 

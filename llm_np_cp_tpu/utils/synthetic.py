@@ -133,6 +133,37 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
         )
         if config.init_expert_out_std is not None:
             d["init_expert_out_std"] = config.init_expert_out_std
+    if config.model_type == "mimo_v2":
+        depth = config.num_hidden_layers
+        d.pop("rms_norm_eps")
+        d.update(
+            layernorm_epsilon=config.rms_norm_eps,
+            hybrid_layer_pattern=[
+                int(config.layer_is_sliding(i)) for i in range(depth)],
+            moe_layer_freq=[
+                int(i >= config.num_dense_layers) for i in range(depth)],
+            sliding_window=config.sliding_window,
+            swa_num_key_value_heads=config.swa_num_key_value_heads,
+            swa_rope_theta=config.swa_rope_theta,
+            add_swa_attention_sink_bias=config.swa_sink,
+            add_full_attention_sink_bias=False,
+            v_head_dim=config.value_dim,
+            partial_rotary_factor=(
+                (config.rope_dim or config.head_dim) + 0.5) / config.head_dim,
+            attention_value_scale=config.attention_value_scale,
+            n_routed_experts=config.experts_held,
+            router_experts=config.num_experts,
+            first_expert=config.first_expert,
+            n_shared_experts=None,
+            num_experts_per_tok=config.num_experts_per_tok,
+            moe_intermediate_size=config.moe_intermediate_size,
+            routed_scaling_factor=config.routed_scaling_factor,
+            scoring_func="sigmoid", topk_method="noaux_tc",
+            n_group=1, topk_group=1,
+            norm_topk_prob=config.norm_topk_prob,
+        )
+        if config.init_expert_out_std is not None:
+            d["init_expert_out_std"] = config.init_expert_out_std
     if config.model_type == "gemma2":
         d.update(
             final_logit_softcapping=config.final_logit_softcapping,

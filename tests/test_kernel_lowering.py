@@ -716,6 +716,153 @@ def test_a_v5e_keeps_a_merged_page_in_the_order_of_its_shape(
         assert merges_pages(*shape[3:], False) is not row_major
 
 
+# ----------------------------------------------------------------------
+# MiMo-V2's two page classes (PR 43): keys 192 wide, values 128
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,row_major", [
+    # K heads of 192 are a lane and a half: [.., BS, K, 192] is permuted,
+    # its 128-wide V beside it is not
+    ((2, 1026, 64, 4, 192), False),
+    ((2, 1026, 64, 4, 128), True),
+    # ... stored merged, every one of the four is kept as its shape says:
+    # global K / V, window K / V (768 / 512 / 1,536 / 1,024 columns)
+    ((2, 1026, 64, 768), True),
+    ((2, 1026, 64, 512), True),
+    ((5, 321, 64, 1536), True),
+    ((5, 321, 64, 1024), True),
+], ids=["k-4x192", "v-4x128", "global-k", "global-v", "window-k", "window-v"])
+def test_a_v5e_keeps_mimo_v2s_pages_in_the_order_of_their_shape_merged(
+        v5e_sharding, shape, row_major):
+    compiled = jax.jit(lambda a: a.at[0, 0, 0].set(1)).lower(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e_sharding)
+    ).compile()
+    kept = compiled.input_formats[0][0].layout.major_to_minor
+    assert (kept == tuple(range(len(shape)))) is row_major, kept
+
+
+@pytest.mark.parametrize("kh,mb,blocks,sink,base", [
+    (8, 5, 5 * 321, True, True),      # a window layer: its ring's table
+    (4, 78, 2 * 4994, False, False),  # a global layer: the growing chain
+], ids=["window", "global"])
+def test_ragged_kernel_compiles_at_mimo_v2s_page_classes(
+        v5e_sharding, kh, mb, blocks, sink, base):
+    """64 query heads of 192 over ``kh`` kv heads, V pages 128 wide, at
+    the cell's widest program (136 tiles): the kernel lowers for the v5e
+    inside its scoped VMEM with a sink operand and a table whose column 0
+    is not position 0, K and V copied by its own DMAs out of merged pages
+    of their own widths; without either it has neither operand."""
+    from llm_np_cp_tpu.ops.pallas import decode_attention as da
+
+    def aval(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_sharding)
+
+    nt, h, d, dv, i32 = 136, 64, 192, 128, jnp.int32
+    args = [aval((nt * 8, h, d), jnp.bfloat16),
+            aval((blocks, 64, kh * d), jnp.bfloat16),
+            aval((blocks, 64, kh * dv), jnp.bfloat16),
+            aval((64, mb), i32), aval((nt,), i32), aval((nt,), i32),
+            aval((nt,), i32), aval((64,), i32), aval((), i32)]
+    kw = {}
+    if sink:
+        kw["sink"] = aval((h,), jnp.float32)
+    if base:
+        kw["block0"] = aval((64,), i32)
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        compiled = jax.jit(functools.partial(
+            da.ragged_paged_attention, scale=d ** -0.5)).lower(
+                *args, **kw).compile()
+    finally:
+        jax.default_backend = real
+    _, _, module = _ragged_kernel_call(compiled.as_text())
+    p = da.ragged_pages_per_step(mb, 64, kh, d, jnp.bfloat16, False, merged=True)
+    assert p == min(8, mb) and _kernel_grid(module) == (nt, -(-mb // p))
+    # two heads of 192 share three whole rows of lanes; a value head of
+    # 128 is a row of its own
+    assert (da._lane_pack(kh, d), da._lane_pack(kh, dv)) == (2, 1)
+    scratch = _kernel_vmem_scratch(module, rank=4)[-2:]
+    assert scratch == [((2, p, 64, kh * d), "bf16"),
+                       ((2, p, 64, kh * dv), "bf16")], scratch
+    # the sink: one float32 a score-sheet row (kv head, token, group
+    # head) beside the running maximum and the denominator
+    sig = next(ln for ln in module.splitlines() if "^bb0(" in ln)
+    assert sig.count(f"memref<{8 * h}x1xf32") == (3 if sink else 2)
+    assert f"{blocks}x64x{kh * d}xbf16" in module
+
+
+def test_a_stack_with_one_page_class_compiles_the_parents_tick(v5e_sharding):
+    """The second table exists only for a pool with a window class: the
+    Qwen-shaped engine's and a Gemma-2-shaped engine's widest programs
+    take the operand, the arguments and the temporaries they took at the
+    parent (PR 42; measured there and here with this file's compile, PR
+    43), and their operand has the parent's sections and no other."""
+    engine, compiled = _compile_widest_bucket(v5e_sharding, jnp.bfloat16)
+    program = engine.mixed_buckets[-1]
+    layout, words = engine._mixed_layouts[program]
+    assert list(layout) == [
+        "tokens", "positions", "tok_blk", "tok_off", "tok_row", "tok_slot",
+        "tok_live", "tok_lane", "lane_tok", "tile_row", "tile_qpos0",
+        "tile_qlen", "tables", "pads", "last_idx", "sample_pos", "seeds",
+        "verify_len"]
+    assert (program, words, len(engine._mixed_geometry)) == ((160, 136), 1360, 4)
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes, mem.argument_size_in_bytes) == (
+        806400, 118018048)
+    gemma = tiny_config(
+        "gemma2", hidden_size=256, intermediate_size=512,
+        num_attention_heads=8, num_key_value_heads=4, head_dim=128,
+        vocab_size=2048, num_hidden_layers=4, sliding_window=128)
+    engine, compiled = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=gemma, blocks=1026)
+    mem = compiled.memory_analysis()
+    assert (engine._mixed_layouts[engine.mixed_buckets[-1]][1],
+            mem.temp_size_in_bytes, mem.argument_size_in_bytes) == (
+        1360, 451584, 548422144)
+
+
+def test_mimo_v2_tick_on_a_v5e_reads_both_classes_where_they_lie(v5e_sharding):
+    """The unified step of a MiMo-V2-shaped stack at the published
+    attention widths (64 heads of 192 over 4 / 8 kv heads, values 128, a
+    sink, two page classes), its widest program compiled for the described
+    v5e: one ragged kernel call a layer under ``attn_global`` /
+    ``attn_window``, both classes written in place (nothing pool- or
+    slab-shaped made but the ``kv_write`` scatters), the experts in the
+    grouped matmul."""
+    cfg = tiny_config(
+        "mimo_v2", hidden_size=256, intermediate_size=512,
+        num_attention_heads=64, num_key_value_heads=4,
+        swa_num_key_value_heads=8, head_dim=192, v_head_dim=128, rope_dim=64,
+        sliding_window=128, moe_intermediate_size=128, num_experts_held=4,
+        first_expert=4, vocab_size=2048)
+    # (1,026 blocks and 64 slots' rings: pools the compiler cannot park
+    # in VMEM whole, as no served pool can be)
+    engine, compiled = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, blocks=1026, slots=64, chunk=128)
+    pages = engine.pool.pages
+    assert pages.k.shape == (2, 1026, BLOCK, 768)
+    assert pages.v.shape == (2, 1026, BLOCK, 512)
+    assert engine.window_blocks == 5
+    assert [a.shape for a in pages.window] == [
+        (3, 321, BLOCK, 1536), (3, 321, BLOCK, 1024)]
+    ops = _pool_ops(engine, compiled)
+    scopes = {v[0] for v in ops.values()}
+    assert {"qkv", "kv_write", "attn_global", "attn_window", "o_proj", "mlp",
+            "moe_route", "moe_experts"} <= scopes
+    text = compiled.as_text()
+    assert len(re.findall(r"%ragged_paged_attention[.\d]* = ", text)) == 5
+    # every pool-shaped result is a scatter under kv_write, in place
+    moved = {name: v for name, v in ops.items()
+             if v[2] and v[0] != "kv_write"}
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        a.nbytes for a in pages.pool_arrays())
+    _assert_experts_run_the_kernel(ops, layers=4, weights=[
+        (4, 256, 128), (4, 128, 256)])
+
+
 @pytest.mark.parametrize("shape,row_major,held", [
     # the latent row as ONE array: the block axis becomes the minor one
     ((24, 3458, 64, 576), False, 6_341_787_648),

@@ -511,6 +511,13 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
             a["attn_grid_steps"] for a in paged) / len(paged)
         out["attn_slot_share"] = sum(a["attn_pages"] for a in paged) / sum(
             a["attn_grid_steps"] * a["attn_pages_per_step"] for a in paged)
+    classes = [e["args"] for e in ticks if "attn_pages_window" in e["args"]]
+    if classes:
+        # a pool with a window class: what one layer of each kind streams,
+        # the window blocks the rows hold and those a tick's pack recycled
+        for key in ("attn_pages_global", "attn_pages_window",
+                    "window_blocks_live", "window_blocks_recycled"):
+            out[key] = sum(a[key] for a in classes) / len(classes)
     moe = [e["args"] for e in ticks if "experts_touched" in e["args"]]
     if moe:
         # dropless expert layers: what the step counted, back with the
@@ -879,6 +886,12 @@ def format_summary(events: list[dict], top: int = 5,
                f"in {acct['attn_grid_steps']:.0f} kv grid steps, "
                f"{acct['attn_slot_share']:.0%} of their page slots live"
                if "attn_pages" in acct else "")
+            + (f"; two page classes: a global layer streams "
+               f"{acct['attn_pages_global']:.0f} pages, a window layer "
+               f"{acct['attn_pages_window']:.0f}; "
+               f"{acct['window_blocks_live']:.0f} window blocks live, "
+               f"{acct['window_blocks_recycled']:.2f} recycled a tick"
+               if "attn_pages_window" in acct else "")
             + "; packed width "
             + " ".join(f"{w}x{n}" for w, n in acct["packed_widths"].items())
             + ("; programs (packed x dense width: ticks) "
