@@ -55,7 +55,14 @@ the pages it reads and its live steps, not the table's width (PERF.md
 §6, PR 33: 437 → 206 us a call at 64 decode rows of 410 tokens).  Pool
 arrays whose pages a DMA cannot cut out in whole tiles (scale pages, two
 int8 heads, ``head_dim`` 64) ride ``P`` blocked operands and the
-automatic pipeline instead (``_dma_slices_pages``).
+automatic pipeline instead (``_dma_slices_pages``).  A live step's
+update has two forms chosen from the tile's own ``tile_qlen``: the whole
+tile's ``K * 8 * G`` score rows, or — a tile of ONE live token — that
+token's rows alone.  Float ``[BS, K, D]`` pages the kernel copies itself
+are attended AS THEY LIE, ``[BS * K, D]`` with a position's kv heads on
+consecutive rows: one dot scores every kv head's query rows against all
+of them and a row keeps the columns of its own head, so no head's rows
+are ever taken out of a page (PERF.md §6, PR 44: 206 → 109 us a call).
 
 Benchmark-gated like every kernel here (SURVEY §7 step 7): wired as
 ``attn_impl="flash_decode"``, default stays XLA, and Generator probes
@@ -506,10 +513,15 @@ def paged_decode_attention(
 # row's token segment is padded up to a multiple of this so each q tile
 # belongs to exactly ONE row (the scalar-prefetched tile metadata then
 # names that row's pages).  8 = the f32 sublane tile; a decode row costs
-# one tile (7 masked query lanes).  What a tile costs is its row's pages
-# in groups of ``ragged_pages_per_step``, not the pool's table width one
-# page at a time (PERF.md §6, PR 33); several decode rows to a tile is a
-# mechanism of its own (PERF.md §7).
+# one tile.  What a tile costs is its row's pages in groups of
+# ``ragged_pages_per_step``, not the pool's table width one page at a
+# time (PERF.md §6, PR 33), and a tile of ONE live token (a decode row)
+# pays for that token's ``K * G`` score rows, each kv head's in whole
+# sublane tiles, not for a sheet of 8 tokens of which 7 are masked
+# (PERF.md §6, PR 44: a tenth of a live step; what a step of ``[BS, K,
+# D]`` pages paid most for was taking each kv head's rows out of them,
+# and they are attended as they lie now).  Several decode rows to a tile
+# is a mechanism of its own (PERF.md §7).
 RAGGED_Q_TILE = 8
 
 # kv positions one grid step of the ragged kernel attends: the group of
@@ -618,6 +630,7 @@ def _ragged_kernel(
     mb: int, by_hand: tuple[bool, ...], pack: int = 0,
     value_dim: int | None = None, v_pack: int | None = None,
     has_sink: bool = False, has_base: bool = False,
+    heads_in_rows: bool = False,
 ):
     """Mixed-batch block-table attention: each q tile holds up to
     ``q_tile`` consecutive tokens of ONE row (a prefill-chunk slice, or a
@@ -637,7 +650,14 @@ def _ragged_kernel(
     fetched by the automatic pipeline.
 
     The online-softmax update runs once a group, on a
-    ``[K * q_tile * G, pages * block_s]`` score sheet.  Visibility is
+    ``[K * q_tile * G, pages * block_s]`` score sheet — or, in a tile
+    whose ``tile_qlen`` is 1 (a decode row, the one-token tail of a
+    prefill segment: token 0 at slot ``qpos0`` either way), on that
+    token's rows alone: each kv head's ``G`` rows in whole sublane
+    tiles, read from and written to the scratch where the tile's sheet
+    has them (rows ordered (kv head, token, group head): a head's token
+    0 starts at a multiple of 8); the other lanes keep what ``_init``
+    gave them and ``_finalize`` turns into zeros.  Visibility is
     derived in-kernel from the tile's (pad, qpos0, qlen, window)
     scalars: token i at cache slot ``qpos0 + i`` sees kv slots in
     ``[max(pad, slot - win + 1), slot]`` — causal within the tile's own
@@ -662,7 +682,13 @@ def _ragged_kernel(
     running maximum (on AMLA's grid) and the denominator of a tile before
     its first page — a column of the softmax that has no value.
     ``has_base``: meta row ``_RM_BASE`` is the logical block the row's
-    table starts at (a window chain's first block is not position 0)."""
+    table starts at (a window chain's first block is not position 0).
+
+    ``heads_in_rows``: the ``[BS, K, D]`` pages come ``[BS * K, D]``,
+    the same bytes with a position's kv heads on consecutive rows, and
+    are attended so (``attend``): on a v5e a head's rows of such a page
+    are every ``K``-th HALF of a 32-bit row, and taking them out cost
+    more than everything else a live step did (PERF.md §6, PR 44)."""
     value_dim = head_dim if value_dim is None else value_dim
     v_pack = pack if v_pack is None else v_pack
     it = iter(refs)
@@ -742,32 +768,127 @@ def _ragged_kernel(
             l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j * pages < count)
-    def _update():
-        half = fetch_group() if copied else None
-        base = meta_ref[_RM_BASE, ti] if has_base else 0
+    head_rows = q_tile * group  # a kv head's rows of the scratch
+    # a ONE-token tile's rows of a kv head: its ``G`` group heads in whole
+    # sublane tiles (the rows past ``G`` are token 1's first, masked like
+    # every dead lane); a head's token 0 starts at a multiple of 8 in the
+    # scratch, whose rows are ordered (kv head, token, group head)
+    token_rows = -(-group // 8) * 8
 
+    def scratch_rows(one: bool):
+        """→ (load, store) of the scratch rows an update touches: all of
+        them, or (``one``) each kv head's ``token_rows`` of token 0."""
+        if not one:
+            def store(ref, rows):
+                ref[:] = rows
+
+            return (lambda ref: ref[:]), store
+
+        def load(ref):
+            return jnp.concatenate(
+                [ref[ki * head_rows:ki * head_rows + token_rows]
+                 for ki in range(kv_heads)], axis=0)
+
+        def store(ref, rows):
+            for ki in range(kv_heads):
+                ref[ki * head_rows:ki * head_rows + token_rows] = (
+                    rows[ki * token_rows:(ki + 1) * token_rows])
+
+        return load, store
+
+    def head_mask(one: bool, cols: int, heads: int = 1):
+        """A kv head's ``[rows, cols]`` of the score sheet's mask, rows
+        ordered (token, group head): which kv positions of this step's
+        group each of the tile's tokens (``one``: its ONE token) sees.
+        ``heads`` > 1: the columns are (position, kv head) pairs, a
+        position's heads side by side."""
+        tokens, rows = (1, token_rows) if one else (q_tile, head_rows)
+        base = meta_ref[_RM_BASE, ti] if has_base else 0
         # rank-2 iota (Mosaic rejects rank-1 iota on TPU)
-        q_idx = jax.lax.broadcasted_iota(jnp.int32, (q_tile, width), 0)
-        kv_pos = (base + start + j * pages) * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, (q_tile, width), 1
-        )
+        q_idx = jax.lax.broadcasted_iota(jnp.int32, (tokens, cols), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (tokens, cols), 1)
+        if heads > 1:
+            col = col >> (heads.bit_length() - 1)
+        kv_pos = (base + start + j * pages) * block_s + col
         q_slot = qpos0 + q_idx
-        mask = (
+        seen = (
             (q_idx < qlen)
             & (kv_pos >= pad)
             & (kv_pos > q_slot - win)  # sliding window (win huge = global)
             & (kv_pos <= q_slot)       # causal
-        )  # [q_tile, width]
+        )  # [tokens, cols]
+        if not one:
+            return jnp.broadcast_to(
+                seen[:, None, :], (q_tile, group, cols)).reshape(rows, cols)
+        mine = jnp.broadcast_to(seen, (rows, cols))
+        if rows != group:  # (the rows past ``G``: token 1's)
+            mine &= jax.lax.broadcasted_iota(
+                jnp.int32, (rows, cols), 0) < group
+        return mine
 
-        def group_of(src, buf):  # the step's pages end to end: [width, ..]
+    def online_softmax(s, mask, one: bool, weighted):
+        """The AMLA additive-max update (see ``_amla_rescale``: ln2-grid
+        max, group rescale = exponent-field integer add, not a multiply)
+        of the scratch rows under the score sheet ``s``; ``weighted(p)``
+        → ``p @ V``."""
+        load, store = scratch_rows(one)
+        if softcap is not None:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = load(m_ref)
+        m_new = _amla_max(m_prev, s)
+        # re-zero masked slots: a FULLY-masked query row (dead packing
+        # lane) has m == NEG_INF and would otherwise get p == 1
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        k_steps = _amla_steps(m_prev, m_new)
+        store(l_ref, _amla_rescale(load(l_ref), k_steps)
+              + jnp.sum(p, axis=-1, keepdims=True))
+        store(acc_ref, _amla_rescale(load(acc_ref), k_steps) + weighted(p))
+        store(m_ref, m_new)
+
+    def attend(half, one: bool):
+        """The update of this step's group of pages: the whole tile's
+        ``K * q_tile * G`` score rows, or (``one``) those of the tile's
+        ONE live token."""
+        rows = token_rows if one else head_rows  # a kv head's
+
+        def group_of(src, buf):  # the step's pages end to end
             if buf is not None:
-                return buf[half].reshape((width,) + buf.shape[3:])
+                return buf[half].reshape((-1,) + buf.shape[3:])
             if pages == 1:
                 return src[0][0]
             return jnp.concatenate([r[0] for r in src], axis=0)
 
         kb, vb, *scales = (group_of(*sb) for sb in zip(sources, bufs))
+
+        def q_rows(ki):  # (the tokens whose rows fill ``rows``)
+            n = -(-rows // group)
+            return q_ref[:n, ki].reshape(n * group, head_dim)[:rows]
+
+        if heads_in_rows:
+            # the pages as they lie, ``[width * K, D]`` with a position's
+            # kv heads on consecutive rows: ONE dot scores every kv
+            # head's query rows against all of them — a ``[K * rows,
+            # width * K]`` sheet of which a row keeps the columns of its
+            # own head — and one dot weights V's rows in the same order
+            # (what a row masked is zero, so the other heads' values add
+            # nothing).  No head's rows are taken out of the group.
+            cols = width * kv_heads
+            s = jax.lax.dot_general(
+                jnp.concatenate([q_rows(ki) for ki in range(kv_heads)], axis=0),
+                kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            mine = head_mask(one, cols, kv_heads)
+            col_head = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, cols), 1) & (kv_heads - 1)
+            mask = jnp.concatenate(
+                [mine & (col_head == ki) for ki in range(kv_heads)], axis=0)
+            online_softmax(
+                s, mask, one, lambda p: jax.lax.dot_general(
+                    p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            return
+
         dtype = q_ref.dtype
         if quantized:
             kb = kb.astype(dtype) * scales[0][..., None].astype(dtype)
@@ -776,34 +897,36 @@ def _ragged_kernel(
             # merged pages [width, K * D]: the heads of one row of lanes
             # together, a static whole-row slice of the group each
             lanes = pack * head_dim
-            dots, rows = kv_heads // pack, pack * q_tile * group
+            dots = kv_heads // pack
 
             def q_of(c):
-                return q_ref[0, c]
+                if not one:
+                    return q_ref[0, c]
+                return jnp.concatenate(
+                    [q_ref[0, c, s * head_rows:s * head_rows + rows]
+                     for s in range(pack)], axis=0)
 
             def k_of(b, c):
                 return b[:, c * lanes:(c + 1) * lanes]
         else:
-            dots, rows = kv_heads, q_tile * group
-
-            def q_of(ki):
-                return q_ref[:, ki].reshape(q_tile * group, head_dim)
+            dots, q_of = kv_heads, q_rows
 
             def k_of(b, ki):
                 return b[:, ki]
         if v_pack:
             v_lanes = v_pack * value_dim
-            v_dots, v_rows = kv_heads // v_pack, v_pack * q_tile * group
+            v_dots, v_rows = kv_heads // v_pack, v_pack * rows
 
             def v_of(b, c):
                 return b[:, c * v_lanes:(c + 1) * v_lanes]
         else:
-            v_dots, v_rows = kv_heads, q_tile * group
+            v_dots, v_rows = kv_heads, rows
 
             def v_of(b, ki):
                 return b[:, ki]
-        # per-kv-head MXU dots over the whole tile, concatenated to ONE
-        # [K*q_tile*G, width] score sheet (rows ordered (ki, qi, gi))
+
+        # per-kv-head MXU dots over the tile's live rows, concatenated to
+        # ONE [K * rows, width] score sheet (rows ordered (ki, qi, gi))
         # so the mask/softcap/exp/rescale VPU pipeline runs once per
         # group at full width — the _decode_kernel r5 lesson applied
         s = jnp.concatenate(
@@ -815,41 +938,41 @@ def _ragged_kernel(
                 for i in range(dots)
             ],
             axis=0,
-        ) * scale  # [K*q_tile*G, width]
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
+        ) * scale  # [K * rows, width]
         # mask rows order (qi, gi), identical for every kv head
-        mask_qg = jnp.broadcast_to(
-            mask[:, None, :], (q_tile, group, width)
-        ).reshape(q_tile * group, width)
-        mask_full = jnp.concatenate([mask_qg] * kv_heads, axis=0)
-        s = jnp.where(mask_full, s, NEG_INF)
+        mask = jnp.concatenate([head_mask(one, width)] * kv_heads, axis=0)
 
-        # AMLA additive-max update (see _amla_rescale): ln2-grid max,
-        # group rescale = exponent-field integer add, not a multiply
-        m_prev = m_ref[:]
-        m_new = _amla_max(m_prev, s)
-        p = jnp.exp(s - m_new)
-        # re-zero masked slots: a FULLY-masked query row (dead packing
-        # lane) has m == NEG_INF and would otherwise get p == 1
-        p = jnp.where(mask_full, p, 0.0)
-        k_steps = _amla_steps(m_prev, m_new)
-        l_ref[:] = (_amla_rescale(l_ref[:], k_steps)
-                    + jnp.sum(p, axis=-1, keepdims=True))
-        pb = p.astype(vb.dtype)
-        pv = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    pb[i * v_rows:(i + 1) * v_rows],
-                    v_of(vb, i), (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for i in range(v_dots)
-            ],
-            axis=0,
-        )  # [K*q_tile*G, Dv]  (merged: v_pack * Dv wide)
-        acc_ref[:] = _amla_rescale(acc_ref[:], k_steps) + pv
-        m_ref[:] = m_new
+        def weighted(p):
+            pb = p.astype(vb.dtype)
+            return jnp.concatenate(
+                [
+                    jax.lax.dot_general(
+                        pb[i * v_rows:(i + 1) * v_rows],
+                        v_of(vb, i), (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    for i in range(v_dots)
+                ],
+                axis=0,
+            )  # [K * rows, Dv]  (merged: v_pack * Dv wide)
+
+        online_softmax(s, mask, one, weighted)
+
+    @pl.when(j * pages < count)
+    def _update():
+        half = fetch_group() if copied else None
+
+        # a tile of ONE live token (a decode row, or a prefill segment's
+        # one-token tail: token 0 at slot ``qpos0`` either way) pays for
+        # that token's score rows, not for a sheet of ``q_tile`` tokens
+        # of which it would mask all but one
+        @pl.when(qlen == 1)
+        def _one_token():
+            attend(half, True)
+
+        @pl.when(qlen != 1)
+        def _tile():
+            attend(half, False)
 
     @pl.when(j == nj - 1)
     def _finalize():
@@ -924,6 +1047,10 @@ def ragged_paged_attention(
     into one half of a VMEM buffer while the other half is attended.
     Pages outside the tile's visible range are never copied, a dead tile
     streams nothing, and steps past the row's last page do nothing.
+    A tile of ONE live token attends that token's score rows alone (the
+    kernel branches on its ``tile_qlen``); float ``[BS, K, D]`` pages
+    the kernel copies itself are handed over ``[BS * K, D]`` — the same
+    bytes, a bitcast on the chip — and attended as they lie.
 
     int8 pool mode: k_scale/v_scale [NB, BS, K] f32 scale pages ride
     along and the kernel dequantizes per group in VMEM.
@@ -1029,6 +1156,16 @@ def ragged_paged_attention(
 
     pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
     by_hand = tuple(_dma_slices_pages(a) for a in pools)
+    # ``[BS, K, D]`` float pages the kernel copies itself go in as they
+    # lie, ``[BS * K, D]`` — a position's kv heads on consecutive rows,
+    # the same bytes in the same order (a bitcast on the chip: the
+    # device keeps both shapes alike) — and are attended so: see
+    # ``_ragged_kernel``
+    heads_in_rows = (not merged and not quantized and all(by_hand)
+                     and kh & (kh - 1) == 0)
+    if heads_in_rows:
+        pools = [a.reshape(a.shape[0], block_s * kh, a.shape[-1])
+                 for a in pools]
     tile_spec = pl.BlockSpec(
         (qt, kh, g, d), tile_map, memory_space=pltpu.VMEM)
     out_spec = pl.BlockSpec(
@@ -1075,6 +1212,7 @@ def ragged_paged_attention(
             q_tile=qt, head_dim=d, pages=pages, mb=mb, by_hand=by_hand,
             pack=pack, value_dim=dv, v_pack=v_pack,
             has_sink=sink is not None, has_base=block0 is not None,
+            heads_in_rows=heads_in_rows,
         ),
         out_shape=jax.ShapeDtypeStruct((t, kh, g, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -343,8 +343,11 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         # (ragged tail), row 1 decodes one token deep into its 10th
         # block behind a whole-block pad — past the first GROUP of pages
         # a kv grid step streams (8 of block size 64, 4 of 128), so the
-        # next group's copies are in flight under it —, row 2 prefills
-        # its 2nd chunk across a block boundary, then a dead padding tile
+        # next group's copies are in flight under it, and a tile of ONE
+        # live token, which takes the kernel's one-token update: a chip
+        # that cannot lower that branch degrades here, at start-up —,
+        # row 2 prefills its 2nd chunk across a block boundary, then a
+        # dead padding tile
         qt = RAGGED_Q_TILE
         window = jnp.int32(shape.window or (1 << 30))
 

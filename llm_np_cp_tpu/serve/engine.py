@@ -4107,6 +4107,7 @@ class ServeEngine:
         cpu4 = cpu5 = 0
         ctx_tokens = array_rows = h2d_count = h2d_bytes = 0
         attn_pages = attn_grid_steps = attn_step_pages = 0
+        attn_live_tiles = attn_decode_tiles = 0
         packed_width = dense_width = 0
         n_prefill_tok = sum(n for _, n in prefill_segs)
         n_decode_tok = len(decode_rows)
@@ -4136,7 +4137,8 @@ class ServeEngine:
                 ctx_tokens = sum(
                     r.cache_len - r.pad + r.draft_len for r in decode_rows
                 ) + sum(r.prefill_done + n for r, n in prefill_segs)
-                attn_pages, attn_grid_steps, attn_step_pages = (
+                (attn_pages, attn_grid_steps, attn_step_pages,
+                 attn_live_tiles, attn_decode_tiles) = (
                     self._attn_page_account(
                         host_ops, packed_width, dense_width))
                 h2d_count = 1
@@ -4389,6 +4391,13 @@ class ServeEngine:
                    if self.window_blocks and dispatched else {}),
                 "attn_grid_steps": attn_grid_steps,
                 "attn_pages_per_step": attn_step_pages,
+                # the dispatch's live query tiles, and those of them that
+                # hold ONE live token (a decode row, a prefill segment's
+                # one-token tail): the tiles whose kv steps attend that
+                # token's score rows alone (ops/pallas/decode_attention,
+                # latent_attention)
+                "attn_live_tiles": attn_live_tiles,
+                "attn_decode_tiles": attn_decode_tiles,
                 # rows _pack_mixed wrote by whole-array assignments
                 # (plain decode rows): how much of pack went the fast way
                 "pack_array_rows": array_rows,
@@ -4562,13 +4571,17 @@ class ServeEngine:
 
     def _attn_page_account(
         self, host_ops: np.ndarray, t_w: int, d_w: int,
-    ) -> tuple[int, int, int]:
-        """(pages, kv grid steps, P) of one layer's ragged attention call
-        on this packed batch — the tracer's tick args ``attn_pages`` /
-        ``attn_grid_steps`` / ``attn_pages_per_step``.  Pages: over the
-        live tiles, the blocks from the row's left pad to the tile's
-        last token (what a global layer streams; a sliding layer starts
-        later).  Steps: the program's tiles x ``ceil(max_blocks / P)``."""
+    ) -> tuple[int, int, int, int, int]:
+        """(pages, kv grid steps, P, live tiles, one-token tiles) of one
+        layer's ragged attention call on this packed batch — the tracer's
+        tick args ``attn_pages`` / ``attn_grid_steps`` /
+        ``attn_pages_per_step`` / ``attn_live_tiles`` /
+        ``attn_decode_tiles``.  Pages: over the live tiles, the blocks
+        from the row's left pad to the tile's last token (what a global
+        layer streams; a sliding layer starts later).  Steps: the
+        program's tiles x ``ceil(max_blocks / P)``.  One-token tiles:
+        the live tiles with ``tile_qlen == 1``, whose steps the kernel
+        attends for that token alone."""
         from llm_np_cp_tpu.ops.pallas.decode_attention import (
             ragged_pages_per_step,
         )
@@ -4598,7 +4611,8 @@ class ServeEngine:
             pages.kv_heads // shards, pages.head_dim, pages.k.dtype,
             pages.quantized, merged=pages.merged)
         steps = (t_w // self._q_tile) * -(-self.max_blocks_per_seq // per_step)
-        return int((last - first + 1).sum()), steps, per_step
+        return (int((last - first + 1).sum()), steps, per_step,
+                int(live.sum()), int((qlen == 1).sum()))
 
     def _attn_window_pages(self, host_ops: np.ndarray,
                            program: tuple[int, int]) -> int:

@@ -82,6 +82,8 @@ from llm_np_cp_tpu.serve.tracing import (
 )
 
 TERMINAL_EVENTS = ("stop", "length", "aborted")
+# what a response's disconnect watch puts into its event queue (``_watch``)
+_DISCONNECTED = object()
 
 
 class _ResumeEcho:
@@ -1843,15 +1845,15 @@ class HttpServer:
         # (A client that half-closes its write side after the body is
         # indistinguishable from a disconnect here and is also aborted;
         # real HTTP clients don't half-close.)
-        monitor = asyncio.ensure_future(self._watch_disconnect(reader))
+        monitor = self._watch(reader, aq)
         try:
             if payload.stream:
                 await self._stream_response(
-                    writer, aq, monitor, rid, payload, created,
+                    writer, aq, rid, payload, created,
                     extra_headers=resp_headers)
             else:
                 await self._unary_response(
-                    writer, aq, monitor, rid, payload, created,
+                    writer, aq, rid, payload, created,
                     extra_headers=resp_headers)
         finally:
             monitor.cancel()
@@ -1912,11 +1914,10 @@ class HttpServer:
             resume_headers = (
                 (("traceparent", make_traceparent(tp)),) if tp else ()
             )
-            monitor = asyncio.ensure_future(
-                self._watch_disconnect(reader))
+            monitor = self._watch(reader, aq)
             try:
                 await self._stream_response(
-                    writer, aq, monitor, rid, payload, created,
+                    writer, aq, rid, payload, created,
                     start_idx=last_idx, extra_headers=resume_headers)
             finally:
                 monitor.cancel()
@@ -1934,21 +1935,26 @@ class HttpServer:
             if not data:
                 return
 
-    async def _next_event(self, aq: asyncio.Queue,
-                          monitor: asyncio.Future) -> tuple | None:
-        """Next engine event, or None if the client disconnected first."""
-        getter = asyncio.ensure_future(aq.get())
-        done, _ = await asyncio.wait(
-            {getter, monitor}, return_when=asyncio.FIRST_COMPLETED,
-        )
-        if getter in done:
-            return getter.result()
-        getter.cancel()
-        with contextlib.suppress(asyncio.CancelledError):
-            await getter
-        return None
+    def _watch(self, reader: asyncio.StreamReader,
+               aq: asyncio.Queue) -> asyncio.Future:
+        """Start the disconnect watch of a response that reads ``aq``:
+        when the client hangs up (or the watch is cancelled, after the
+        response) ``_DISCONNECTED`` goes into the queue behind whatever
+        the engine has put there, so the handler waits on ONE thing, the
+        queue — a task and an ``asyncio.wait`` a token were a tenth of a
+        frame's cost on the loop thread, which is what the smallest
+        closed cell runs out of (PERF.md section 5)."""
+        monitor = asyncio.ensure_future(self._watch_disconnect(reader))
+        monitor.add_done_callback(lambda _f: aq.put_nowait(_DISCONNECTED))
+        return monitor
 
-    async def _stream_response(self, writer, aq, monitor, rid,
+    @staticmethod
+    async def _next_event(aq: asyncio.Queue) -> tuple | None:
+        """Next engine event, or None if the client disconnected first."""
+        ev = await aq.get()
+        return None if ev is _DISCONNECTED else ev
+
+    async def _stream_response(self, writer, aq, rid,
                                payload, created, start_idx: int = 0,
                                extra_headers: tuple = ()) -> None:
         # delivered-token index, carried as the SSE event id on every
@@ -1972,7 +1978,7 @@ class HttpServer:
             self.runner.abort(rid)
             return
         while True:
-            ev = await self._next_event(aq, monitor)
+            ev = await self._next_event(aq)
             if ev is None:  # client went away mid-stream
                 self.runner.abort(rid)
                 return
@@ -2008,13 +2014,13 @@ class HttpServer:
             if ev[0] == "finish":
                 return
 
-    async def _unary_response(self, writer, aq, monitor, rid,
+    async def _unary_response(self, writer, aq, rid,
                               payload, created,
                               extra_headers: tuple = ()) -> None:
         token_ids: list[int] = []
         text_parts: list[str] = []
         while True:
-            ev = await self._next_event(aq, monitor)
+            ev = await self._next_event(aq)
             if ev is None:
                 self.runner.abort(rid)
                 return

@@ -869,3 +869,180 @@ def test_ragged_merged_pages_refuse_what_they_cannot_be():
     with pytest.raises(ValueError, match="merged pages"):
         ragged_paged_attention(
             q, jnp.zeros((2, 8, 12)), jnp.zeros((2, 8, 12)), *args, scale=0.35)
+
+
+# ---------------------------------------------------------------------------
+# A tile of ONE live token (PERF.md section 6, PR 44): a decode row, or the
+# one-token tail of a prefill segment, attends that token's K x G score
+# rows alone — the kernel branches on the tile's own ``tile_qlen``, and
+# every form it has takes the branch.  Each case names the form; all are
+# held against the XLA twin, and against the per-token reference where the
+# reference knows the form (no sink, no table base, one head width).
+# ---------------------------------------------------------------------------
+
+# name: (h, kh, dk, dv, merged, segs, pads, window, softcap, int8, sink,
+# block0 a row)
+_ONE_TOKEN_CASES = {
+    # decode-only batches at (H, K) of the benchmark's five configurations
+    "decode-only-qwen1.5b-h12-k2": (
+        12, 2, 128, 128, False, _decode_segs([449, 64, 65, 1000]),
+        [0, 3, 0, 70], _NO_WINDOW, None, False, False, None),
+    "decode-only-qwen3b-h16-k2": (
+        16, 2, 128, 128, False, _decode_segs([512, 513, 30]),
+        [0, 0, 5], _NO_WINDOW, None, False, False, None),
+    "decode-only-falcon-h1-h20-k4": (
+        20, 4, 128, 128, False, _decode_segs([600, 447, 64]),
+        [0, 66, 0], _NO_WINDOW, None, False, False, None),
+    "decode-only-lfm2-h32-k8-merged-pack2": (
+        32, 8, 64, 64, True, _decode_segs([7 * _BS, 8 * _BS + 1, 449]),
+        [0, 70, 3], _NO_WINDOW, None, False, False, None),
+    "decode-only-mimo-global-h64-k4-dk192-dv128-merged": (
+        64, 4, 192, 128, True, _decode_segs([900, 129]),
+        [0, 0], _NO_WINDOW, None, False, False, None),
+    "decode-only-mimo-window-h64-k8-sink-block0": (
+        64, 8, 192, 128, True, _decode_segs([900, 300]),
+        [0, 0], 128, None, False, True, [12, 2]),
+    # decode and prefill tiles alternating in one call
+    "decode-and-prefill-tiles-alternating": (
+        12, 2, 128, 128, False,
+        [(0, 519, 1), (1, 96, 8), (2, 700, 1), (3, 20, 16), (0, 0, 0),
+         (1, 300, 1)],
+        [0, 0, 130, 3], _NO_WINDOW, None, False, False, None),
+    # a prefill segment of 8 n + 1 tokens: its tail tile holds one token,
+    # at a ``qpos0`` in mid-row (and mid-page), behind its own fresh K/V
+    "prefill-segment-of-8n+1-tokens": (
+        16, 2, 128, 128, False, [(0, 500, 17), (1, 61, 9), (2, 63, 1)],
+        [0, 9, 0], _NO_WINDOW, None, False, False, None),
+    "prefill-segment-of-8n+1-tokens-merged": (
+        32, 8, 64, 64, True, [(0, 500, 17), (1, 61, 9), (2, 63, 1)],
+        [0, 9, 0], _NO_WINDOW, None, False, False, None),
+    # a row with drafts beside plain decode rows: tiles of 2-8 tokens take
+    # the tile's whole sheet as before
+    "rows-with-drafts-qlen-2-to-8": (
+        20, 4, 128, 128, False,
+        [(0, 520, 2), (1, 300, 1), (2, 62, 5), (3, 510, 8), (1, 10, 1)],
+        [0, 0, 7, 129], _NO_WINDOW, None, False, False, None),
+    "window-starts-mid-group": (
+        12, 2, 128, 128, False,
+        [(0, 999, 1), (1, 640, 1), (2, 350, 9), (3, 700, 1)],
+        [0, 500, 0, 64], 300, None, False, False, None),
+    "softcap": (
+        16, 2, 128, 128, False, [(0, 515, 1), (1, 800, 9), (2, 40, 1)],
+        [3, 64, 0], _NO_WINDOW, 20.0, False, False, None),
+    # two int8 kv heads: every pool array on blocked operands
+    "int8-pool-kh2-blocked": (
+        12, 2, 128, 128, False, [(0, 515, 1), (1, 800, 9), (2, 40, 1)],
+        [3, 64, 0], _NO_WINDOW, None, True, False, None),
+    # four: K / V copied by hand, the scale pages blocked beside them
+    "int8-pool-kh4-copied": (
+        20, 4, 128, 128, False, [(0, 515, 1), (1, 800, 9), (2, 40, 1)],
+        [3, 64, 0], _NO_WINDOW, None, True, False, None),
+    # a sink on a head_dim-64 pool, merged, under a window from a base
+    "sink-merged-64-window-block0": (
+        16, 4, 64, 64, True, [(0, 700, 1), (1, 200, 17), (2, 131, 1)],
+        [0, 0, 0], 130, None, False, True, [8, 1, 0]),
+}
+
+
+def _one_token_run(case):
+    """→ (kernel's result, twin's, reference's or None, live lanes, each
+    tile's live tokens)."""
+    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        ragged_paged_attention,
+        ragged_paged_attention_xla,
+    )
+
+    (h, kh, dk, dv, merged, segs, pads, window, softcap, int8, sink,
+     block0) = _ONE_TOKEN_CASES[case]
+    rng = np.random.default_rng(len(case) * 19 + h)
+    rows, mb = len(pads), 16
+    nbp = rows * mb + 1
+    pages_k = _rand(rng, (nbp, _BS, kh, dk))
+    pages_v = _rand(rng, (nbp, _BS, kh, dv))
+    tables = np.asarray((rng.permutation(nbp - 1) + 1).reshape(rows, mb))
+    if block0 is not None:
+        # the table's column 0 is logical block ``block0[row]``: shift
+        # each row's pages so that position p still finds its own page
+        full = tables.copy()
+        for r, b0 in enumerate(block0):
+            tables[r] = np.roll(full[r], -b0)
+    tables = jnp.asarray(tables, jnp.int32)
+    dead = 1
+    tile, tok, width = _pack_segments(segs, dead)
+    q = _rand(rng, (width, h, dk))
+    kw = dict(scale=dk ** -0.5, logit_softcap=softcap)
+    float_k, float_v = pages_k, pages_v
+    if int8:
+        (pages_k, ks), (pages_v, vs) = quantize_kv(pages_k), quantize_kv(pages_v)
+        kw.update(k_scale=ks, v_scale=vs)
+        float_k = dequantize_kv(pages_k, ks, jnp.float32)
+        float_v = dequantize_kv(pages_v, vs, jnp.float32)
+    if sink:
+        kw["sink"] = jnp.asarray(3 + rng.standard_normal(h), jnp.float32)
+    if block0 is not None:
+        kw["block0"] = jnp.asarray(block0, jnp.int32)
+    if merged:
+        pages_k, pages_v = _merge(pages_k), _merge(pages_v)
+    pads = jnp.asarray(pads, jnp.int32)
+    win = jnp.asarray(window, jnp.int32)
+    got = np.asarray(ragged_paged_attention(
+        q, pages_k, pages_v, tables, *tile, pads, win, **kw))
+    twin = np.asarray(ragged_paged_attention_xla(
+        q, pages_k, pages_v, tables, *tok, pads, win, **kw))
+    want = None
+    # (the reference attends a token at a time: where the twin is itself
+    # held to it above, in this file's other ragged tests, a few will do)
+    if (not sink and block0 is None and dk == dv
+            and int(np.asarray(tok[2]).sum()) <= 12):
+        want = _ragged_reference(q, float_k, float_v, tables, tok, pads,
+                                 window, scale=dk ** -0.5,
+                                 logit_softcap=softcap)
+    return got, twin, want, np.asarray(tok[2]), np.asarray(tile[2])
+
+
+@pytest.mark.parametrize("case", list(_ONE_TOKEN_CASES))
+def test_ragged_one_token_tiles(case):
+    got, twin, want, live, tile_qlen = _one_token_run(case)
+    # the case drives the branch, and (but for the decode-only ones) the
+    # tile's whole sheet beside it in the same call
+    assert (tile_qlen == 1).any() and (tile_qlen == 0).any()
+    assert case.startswith("decode-only") or (tile_qlen > 1).any()
+    h, kh, dk, dv = _ONE_TOKEN_CASES[case][:4]
+    assert got.shape == (live.size, h, dv)
+    np.testing.assert_allclose(got[live], twin[live], atol=3e-5)
+    if want is not None:
+        np.testing.assert_allclose(got[live], want[live], atol=3e-5)
+    assert np.all(got[~live] == 0.0)
+
+
+def test_ragged_dead_lanes_are_zeros_in_both_branches():
+    """A one-token tile's seven dead lanes and a three-token tile's five
+    come back as exact zeros — not a masked token's softmax over nothing,
+    not another tile's leftovers in the scratch — whatever the pages hold:
+    the lanes the branch never touches keep what the tile's first step
+    gave them."""
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        RAGGED_Q_TILE,
+        ragged_paged_attention,
+    )
+
+    rng = np.random.default_rng(44)
+    h, kh, d, mb = 12, 2, 128, 16
+    pages_k, pages_v, tables = _group_pool(rng, kh, d, mb, 3)
+    # a full tile first, so the scratch holds live rows in every lane when
+    # the next tiles start; then a decode tile, a verify tile, a tail tile
+    segs = [(0, 200, 8), (1, 700, 1), (2, 530, 3), (0, 208, 9)]
+    tile, tok, width = _pack_segments(segs, 1)
+    live = np.asarray(tok[2])
+    out = np.asarray(ragged_paged_attention(
+        _rand(rng, (width, h, d)) * 4, pages_k * 50, pages_v, tables, *tile,
+        jnp.zeros((3,), jnp.int32), jnp.asarray(_NO_WINDOW, jnp.int32),
+        scale=d ** -0.5))
+    per_tile = out.reshape(-1, RAGGED_Q_TILE, h, d)
+    qlen = np.asarray(tile[2])
+    assert list(qlen) == [8, 1, 3, 8, 1, 0]
+    for ti, n in enumerate(qlen):
+        assert np.all(per_tile[ti, n:] == 0.0), ti
+        assert np.all(np.abs(per_tile[ti, :n]).sum(axis=(1, 2)) > 0.0), ti
+    assert np.isfinite(out).all() and np.all(out[~live] == 0.0)

@@ -107,3 +107,36 @@ def test_block_s_respects_vmem_budget():
     # int8 cache halves the stream → larger blocks allowed at same budget
     got8 = select_block_s(4096, 8, 128, 1, 512, True)
     assert got8 >= got
+
+
+@pytest.mark.parametrize("kernel", [
+    "ragged_paged_attention", "ragged_paged_attention_int8",
+    "ragged_latent_attention"])
+def test_ragged_probe_case_holds_a_one_token_tile(kernel):
+    """What the start-up probe compiles and runs on the chip holds every
+    kind of tile the kernels branch on — a whole tile, a partial one, a
+    tile of ONE live token (a decode row: its own update since PR 44 / PR
+    41) and a dead one — at the page form that takes the new path
+    (``[64, 2, 128]`` bf16 pages, attended as they lie), so a chip that
+    cannot lower a branch degrades at start-up and not in a tick (the
+    case against its XLA twin: ``chip_smoke.py --kernels`` on the chip,
+    ``tests/test_decode_attention.py`` in interpret mode)."""
+    from llm_np_cp_tpu.ops.pallas import decode_attention as da
+
+    latent = kernel == "ragged_latent_attention"
+    shape = support.LATENT_PROBE_SHAPE if latent else support.PROBE_SHAPE
+    make_args, _, _ = support.kernel_case(
+        kernel, shape, support.SERVE_BLOCK_SIZES[0], interpret=True)
+    args = make_args()
+    qt = da.RAGGED_Q_TILE
+    tile_qlen = np.asarray(args[5 if latent else 4])
+    assert tile_qlen.shape == (6,) and tile_qlen.dtype == np.int32
+    assert {0, 1, qt} <= set(tile_qlen.tolist())
+    assert any(1 < n < qt for n in tile_qlen)
+    if not latent:
+        pages = args[6]
+        assert pages.shape[1:] == (64, 2, 128)
+        int8 = kernel.endswith("_int8")
+        assert pages.dtype == (jnp.int8 if int8 else jnp.bfloat16)
+        # (int8 pages keep their scale pages beside them: the per-head form)
+        assert da._dma_slices_pages(pages) is not int8

@@ -502,14 +502,17 @@ def test_ragged_kernel_grid_is_tiles_by_groups_of_pages(
     assert _kernel_grid(module) == grid
     p = da.ragged_pages_per_step(mb, 64, kh, d, jnp.bfloat16, False)
     assert p == 8 and grid[1] == -(-mb // p)
-    scratch = _kernel_vmem_scratch(module)
-    assert scratch == [((2, p, 64, kh, d), "bf16")] * 2, scratch
+    # (since PR 44 a page comes [BS * K, D], its kv heads on consecutive
+    # rows: the same 32,768 bytes; rank 4 are also q's and the result's
+    # tile, ahead of the two buffers)
+    scratch = _kernel_vmem_scratch(module, rank=4)[-2:]
+    assert scratch == [((2, p, 64 * kh, d), "bf16")] * 2, scratch
     held = sum(da._vmem_bytes(shape, jnp.bfloat16) for shape, _ in scratch)
     assert held == 2 * 2 * p * 32768 <= da._VMEM_BUDGET_BYTES
     # the pool operands are taken as they lie (no window of them is
     # pipelined): memory space "any", whole
     assert module.count("#tpu.memory_space<any>") >= 2
-    assert f"{28 * 64}x64x{kh}x{d}xbf16" in module
+    assert f"{28 * 64}x{64 * kh}x{d}xbf16" in module
 
 
 @pytest.mark.parametrize("kh,d,dtype,mb,block_s,want,merged", [
@@ -584,6 +587,23 @@ def test_ragged_kernel_compiles_at_every_page_shape(
     p = da.ragged_pages_per_step(
         16, 64, kh, d, dtype, dtype == jnp.int8, merged=merged)
     assert _kernel_grid(module) == (64, -(-16 // p))
+    # BOTH updates in the one program (PR 44), the branch taken from the
+    # tile's own ``tile_qlen``: the whole tile's sheet and a one-token
+    # tile's rows, each with its dots — one for K and one for V where
+    # [BS, K, D] float pages are attended as they lie, else one a kv
+    # head (a row of lanes of a merged page)
+    as_they_lie = (not merged and dtype != jnp.int8 and kh & (kh - 1) == 0
+                   and da._dma_slices_pages(jax.ShapeDtypeStruct(
+                       (1026, 64, kh, d), dtype)))
+    per_update = 2 if as_they_lie else 2 * kh // (
+        da._lane_pack(kh, d) if merged else 1)
+    assert module.count("tpu.matmul") == 2 * per_update, (
+        module.count("tpu.matmul"), per_update)
+    if as_they_lie:
+        name = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+        scratch = _kernel_vmem_scratch(module, rank=4)[-2:]
+        assert scratch == [((2, p, 64 * kh, d), name)] * 2, scratch
+        assert f"1026x{64 * kh}x{d}x{name}" in module
     if merged:
         # the pool stays in HBM as it lies (memory space "any", whole) and
         # the kernel's own DMAs fetch it: two halves of P merged pages for
@@ -594,6 +614,29 @@ def test_ragged_kernel_compiles_at_every_page_shape(
         assert scratch == [((2, p, 64, kh * d), name)] * 2, scratch
         assert module.count("#tpu.memory_space<any>") >= 2
         assert f"1026x64x{kh * d}x{name}" in module
+
+
+@pytest.mark.parametrize("h,kh", [(12, 2), (16, 2), (20, 4)],
+                         ids=["qwen1.5b", "qwen3b", "falcon-h1"])
+def test_ragged_kernel_takes_4d_pages_as_they_lie_by_a_bitcast(
+        v5e_sharding, h, kh):
+    """PR 44: ``[NB, 64, K, 128]`` bf16 pages go to the kernel ``[NB,
+    64 * K, 128]``.  On a v5e that is the same bytes in the same order
+    (two bf16 rows to a 32-bit row either way): the compiled call holds
+    a bitcast of each pool, no copy of one, and no temporary."""
+    blocks = 28 * 1026
+    compiled = _lower_ragged(
+        v5e_sharding, nt=64, mb=16, h=h, kh=kh, d=128, blocks=blocks,
+        whole=True)
+    text = compiled.as_text()
+    flat = opmap.hlo_shape("bfloat16", (blocks, 64 * kh, 128))
+    casts = [ln for ln in text.splitlines()
+             if f"= {flat}" in ln and " bitcast(" in ln]
+    assert len(casts) == 2, casts
+    pool = opmap.hlo_shape("bfloat16", (blocks, 64, kh, 128))
+    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, {pool: "pool", flat: "pool"})
+    assert [n for n, v in ops.items() if v[2] == "pool"] == []
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_ragged_kernel_on_merged_pages_copies_no_pool(v5e_sharding):
@@ -785,6 +828,9 @@ def test_ragged_kernel_compiles_at_mimo_v2s_page_classes(
     scratch = _kernel_vmem_scratch(module, rank=4)[-2:]
     assert scratch == [((2, p, 64, kh * d), "bf16"),
                        ((2, p, 64, kh * dv), "bf16")], scratch
+    # the whole tile's update and a one-token tile's, each a dot a pair of
+    # key heads and one a value head
+    assert module.count("tpu.matmul") == 2 * (kh // 2 + kh)
     # the sink: one float32 a score-sheet row (kv head, token, group
     # head) beside the running maximum and the denominator
     sig = next(ln for ln in module.splitlines() if "^bb0(" in ln)

@@ -278,6 +278,11 @@ def test_tick_arguments_counters_scopes_and_the_pool_marking(tiny):
         assert a["expert_load_max"] <= tokens
         assert a["attn_pages"] > 0 and a["attn_grid_steps"] > 0
         assert a["attn_pages_per_step"] >= 1
+        # the tiles of ONE token (the latent kernel's decode branch): a
+        # decode row each, and a chunk of 9 = 8 + 1 tokens' tail
+        assert a["decode_tokens"] <= a["attn_decode_tiles"] <= a["attn_live_tiles"]
+        assert a["attn_live_tiles"] <= (
+            a["decode_tokens"] + -(-a["prefill_tokens"] // 8) + 1)
         held += a["pairs_held"]
     assert held > 0
     text = engine.metrics.prometheus()

@@ -107,9 +107,18 @@ def traced(request, tiny):
                 // bs - r.pad // bs + 1
                 for r, n in prefill_segs
                 for i in range(0, n, engine._q_tile)),
+            # its live query tiles, and those that hold ONE token: a
+            # decode row without drafts, a segment of 8 n + 1 tokens' tail
+            live_tiles=sum(-(-n // engine._q_tile) for n in sizes),
+            decode_tiles=sum(n % engine._q_tile == 1 for n in sizes),
         ))
         packed = pack(decode_rows, prefill_segs)
         hand[-1]["bytes"] = packed[0].nbytes
+        # ...as the packed operand itself says them
+        off, shape = engine._mixed_layouts[packed[1]][0]["tile_qlen"]
+        qlen = packed[0][off:off + shape[0]]
+        assert hand[-1]["live_tiles"] == int((qlen > 0).sum())
+        assert hand[-1]["decode_tiles"] == int((qlen == 1).sum())
         hand[-1]["array_rows"] = sum(not r.draft_len for r in decode_rows)
         return packed
 
@@ -164,6 +173,9 @@ def test_tick_args_equal_a_hand_count_of_the_planned_rows(traced):
         assert args["attn_pages"] == want["pages"]
         assert args["attn_pages_per_step"] == engine.max_blocks_per_seq == 8
         assert args["attn_grid_steps"] == want["width"] // engine._q_tile
+        # the tiles that take the kernel's one-token branch
+        assert args["attn_live_tiles"] == want["live_tiles"]
+        assert args["attn_decode_tiles"] == want["decode_tiles"]
         h2d = next(p for p in phases if p["name"] == "h2d")
         assert args["pack_array_rows"] == want["array_rows"]
         # ONE transfer a tick: the packed operand
@@ -639,9 +651,16 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
     assert acct["attn_slot_share"] == pytest.approx(
         sum(h["pages"] for h in hand)
         / sum(h["width"] // engine._q_tile * 8 for h in hand))
+    assert acct["attn_decode_tile_share"] == pytest.approx(
+        sum(h["decode_tiles"] for h in hand)
+        / sum(h["live_tiles"] for h in hand))
+    assert 0.0 < acct["attn_decode_tile_share"] <= 1.0
     out = format_summary(events)
     assert (f"attention streams {acct['attn_pages']:.0f} pages a layer in "
             f"{acct['attn_grid_steps']:.0f} kv grid steps") in out
+    assert (f"{acct['attn_decode_tiles']:.1f} of "
+            f"{acct['attn_live_tiles']:.1f} live query tiles a dispatch "
+            f"({acct['attn_decode_tile_share']:.0%}) hold one token") in out
     assert "== tick account" in out and "pack " in out
     assert (f"h2d 1 transfers, {acct['h2d_bytes']:.0f} bytes; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "

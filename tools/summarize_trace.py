@@ -511,6 +511,14 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
             a["attn_grid_steps"] for a in paged) / len(paged)
         out["attn_slot_share"] = sum(a["attn_pages"] for a in paged) / sum(
             a["attn_grid_steps"] * a["attn_pages_per_step"] for a in paged)
+    tiled = [a for a in paged if a.get("attn_live_tiles")]
+    if tiled:
+        # the live query tiles of a dispatch, and those that hold ONE
+        # token: the kernel attends such a tile's one token alone
+        for key in ("attn_live_tiles", "attn_decode_tiles"):
+            out[key] = sum(a[key] for a in tiled) / len(tiled)
+        out["attn_decode_tile_share"] = (
+            out["attn_decode_tiles"] / out["attn_live_tiles"])
     classes = [e["args"] for e in ticks if "attn_pages_window" in e["args"]]
     if classes:
         # a pool with a window class: what one layer of each kind streams,
@@ -886,6 +894,10 @@ def format_summary(events: list[dict], top: int = 5,
                f"in {acct['attn_grid_steps']:.0f} kv grid steps, "
                f"{acct['attn_slot_share']:.0%} of their page slots live"
                if "attn_pages" in acct else "")
+            + (f"; {acct['attn_decode_tiles']:.1f} of "
+               f"{acct['attn_live_tiles']:.1f} live query tiles a dispatch "
+               f"({acct['attn_decode_tile_share']:.0%}) hold one token"
+               if "attn_live_tiles" in acct else "")
             + (f"; two page classes: a global layer streams "
                f"{acct['attn_pages_global']:.0f} pages, a window layer "
                f"{acct['attn_pages_window']:.0f}; "
