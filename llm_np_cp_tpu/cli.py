@@ -939,7 +939,9 @@ def _build_serve_engine(args, params, config, *, prog: str,
               f"epilogue={'fused' if engine.epilogue_impl == 'fused' else 'xla'}), "
               + ("pool written in place" if engine.pool_carried else
                  "pool moved by layer slabs (not row-major on this device)")
-              + f", pages {engine.pool_page_shape}")
+              + f", pages {engine.pool_page_shape}"
+              + (f", state update: {engine.ssm_state_impl}"
+                 if engine.ssm_state_impl else ""))
     elif getattr(args, "mixed_step", "off") == "auto":
         print(f"[{prog}] --mixed-step auto: ragged kernel unavailable; "
               "using the phase-split tick "

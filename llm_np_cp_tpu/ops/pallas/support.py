@@ -58,6 +58,7 @@ KERNELS = (
     "ragged_latent_attention",
     "sample_epilogue", "sample_epilogue_int8",
     "grouped_matmul",
+    "ssm_state_update",
 )
 
 # Pool block sizes the serve path uses (cli --block-size default 64,
@@ -87,6 +88,9 @@ class KernelShape:
     # head_dim]`` with no head axis, which ``ragged_latent_attention``
     # alone reads; None: K and V per kv head, every other kernel
     latent_rank: int | None = None
+    # a recurrent state ``(layers, rows, heads, groups, P, N)``, which
+    # ``ssm_state_update`` alone advances; None: every other kernel
+    state: tuple[int, ...] | None = None
 
     @classmethod
     def of(cls, name: str, config) -> "KernelShape":
@@ -127,6 +131,18 @@ LATENT_PROBE_SHAPE = KernelShape(
     "probe/latent", heads=32, kv_heads=1, head_dim=64, hidden=256, vocab=300,
     latent_rank=512,
 )
+# ... and the state update's at a state small enough to make at every
+# start (0.8 MiB) with what the served one has: ``N`` two rows of lanes
+# wide, two groups, a whole row of heads a block.  The second is the
+# benchmark's state-space cell (Falcon-H1-34B cut to 6 layers, 64 slots:
+# 1.5 GiB), compiled by tests/test_kernel_lowering.py and run by the
+# on-chip matrix.
+STATE_PROBE_SHAPE = KernelShape(
+    "probe/state", heads=12, kv_heads=2, head_dim=128, hidden=256, vocab=300,
+    state=(2, 6, 8, 2, 32, 256),
+)
+FALCON_H1_STATE_SHAPE = dataclasses.replace(
+    STATE_PROBE_SHAPE, name="falcon-h1-34b-6l", state=(6, 64, 32, 2, 128, 256))
 
 
 def family_shapes() -> tuple[KernelShape, ...]:
@@ -273,6 +289,47 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         # (jitted: the probe runs what a case gives it as it is, and op
         # by op the layout alone is fifty compiles of a third of a second
         # at every start of a server)
+        return jax.jit(make_args), jax.jit(run), reference
+
+    if base == "ssm_state_update":
+        from llm_np_cp_tpu.ops.pallas import ssm_state_update as ssu
+
+        # a tick of a layer's rows (ops/ssm.ssm_packed's first pass): two
+        # of every six have no token in it, every third that has starts
+        # from nothing; the twin is the two results in plain jnp
+        layers, rows, nh, ng, p, n = shape.state
+        layer = layers - 1
+        f32 = jnp.float32
+
+        def make_args():
+            state, dtx, b, c, rate = normals(
+                (layers, rows, nh, p, n), (rows, nh, p), (rows, ng, n),
+                (rows, ng, n), (rows, nh), dtype=f32)
+            row = jnp.arange(rows)
+            count = jnp.where(row % 3 == 1, 0, 1 + row % 4).astype(jnp.int32)
+            return (state, jnp.exp(-jnp.abs(rate)), dtx,
+                    b * n ** -0.5, c * n ** -0.5, count,
+                    (row % 3 == 0) & (count > 0))
+
+        def flat(held, state):
+            return jnp.concatenate([held.ravel(), state[layer].ravel()])
+
+        def run(state, decay, dtx, b, c, count, fresh):
+            return flat(*ssu.ssm_state_update(
+                state, jnp.int32(layer), decay, dtx, b, c, count=count,
+                fresh=fresh, heads=ssu.head_block(nh, ng, p, n),
+                interpret=interpret))
+
+        def reference(state, decay, dtx, b, c, count, fresh):
+            there = (count > 0)[:, None, None, None, None]
+            h = jnp.where(fresh[:, None, None, None], 0.0, state[layer])
+            h = h.reshape(rows, ng, nh // ng, p, n)
+            held = jnp.sum(h * c[:, :, None, None, :], axis=-1)
+            new = (decay.reshape(rows, ng, -1, 1, 1) * h
+                   + dtx.reshape(rows, ng, -1, p, 1) * b[:, :, None, None, :])
+            return flat(jnp.where(there[..., 0], held, 0.0), state.at[layer].set(
+                jnp.where(there, new, h).reshape(state.shape[1:])))
+
         return jax.jit(make_args), jax.jit(run), reference
 
     bs = block_size
@@ -479,12 +536,15 @@ def kernel_cases(shapes=None):
     all of ``KERNELS`` at the probe shapes and the three family shapes,
     the paged kernels at both serve block sizes."""
     shapes = shapes if shapes is not None else (
-        *PROBE_SHAPES, LATENT_PROBE_SHAPE, *family_shapes())
+        *PROBE_SHAPES, LATENT_PROBE_SHAPE, STATE_PROBE_SHAPE,
+        FALCON_H1_STATE_SHAPE, *family_shapes())
     for shape in shapes:
         for kernel in KERNELS:
             if (kernel == "ragged_latent_attention") != (
                     shape.latent_rank is not None):
                 continue  # latent rows and their one kernel
+            if (kernel == "ssm_state_update") != (shape.state is not None):
+                continue  # a recurrent state and its one kernel
             if not shape.tied and not kernel.startswith("sample_epilogue"):
                 continue  # only the epilogue distinguishes head layouts
             paged = kernel.startswith(("paged_", "ragged_"))
@@ -541,9 +601,10 @@ def _probe(kernel: str, backend: str) -> str | None:
 
 
 def _compile_and_run(kernel: str) -> str | None:
-    latent = kernel == "ragged_latent_attention"
+    own = {"ragged_latent_attention": (LATENT_PROBE_SHAPE,),
+           "ssm_state_update": (STATE_PROBE_SHAPE,)}
     try:
-        for shape in (LATENT_PROBE_SHAPE,) if latent else PROBE_SHAPES:
+        for shape in own.get(kernel, PROBE_SHAPES):
             if shape.tied or kernel.startswith("sample_epilogue"):
                 make_args, run, _ = kernel_case(kernel, shape,
                                                 SERVE_BLOCK_SIZES[0])

@@ -14,7 +14,10 @@ recurrence: ``ssm_scan`` carries it over whole sequences (``models.forward``)
 and ``ssm_packed`` runs it over the serving tick's packed token axis, where
 it advances every row by its first token as ``q = 1`` (64 decode rows are 64
 rank-one updates: nothing 128 wide) and the rest of a prefill chunk in
-further passes over that chunk's own rows of the state.
+further passes over that chunk's own rows of the state.  On a TPU that
+first pass is one Pallas kernel over the rows the tick touches
+(``ops/pallas/ssm_state_update``: a row's state read once, both results
+taken from that copy, written once); ``state_update_heads`` says where.
 
 A token that is not there (padding, a row that is not in the tick) has
 ``dt = 0``: the decay is 1 and nothing enters, so the state passes through
@@ -24,8 +27,14 @@ highest matmul precision, whatever the model is served in.
 
 from __future__ import annotations
 
+from typing import Any
+
+import jax
 import jax.numpy as jnp
 from jax import lax
+
+from llm_np_cp_tpu.ops.pallas import ssm_state_update as ssu
+from llm_np_cp_tpu.ops.pallas import support
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -113,6 +122,47 @@ def ssm_scan(
     return y[:, :s], h_end
 
 
+def state_update_heads(state: Any, groups: int,
+                       interpret: bool | None = None) -> int | None:
+    """The heads a block of ``ops/pallas/ssm_state_update`` advances the
+    rows of ``state [L, R, nh, P, N]`` (an array, or its shape and dtype)
+    in, or None where ``ssm_packed``'s first pass is ``ssm_chunk``: told
+    from the backend, the dtype and the shape, and on a TPU from the
+    kernel's probe (a Mosaic refusal is one warning and the compiler's
+    two passes, not a dead server).  ``interpret``: ``ssm_packed``'s."""
+    if state.dtype != jnp.float32:
+        return None  # (a test keeps the state lower; see ``ssm_packed``)
+    heads = ssu.head_block(state.shape[2], groups, *state.shape[3:])
+    if heads is None or (interpret is None and (
+            jax.default_backend() != "tpu"
+            or support.kernel_or_warn("ssm_state_update", "ssm_chunk"))):
+        return None
+    return heads
+
+
+def _first_tokens(state, layer, x, dt, a, b, c, d_skip, count, fresh, heads,
+                  interpret):
+    """``ssm_chunk``'s ``q == 1`` branch over ``state[layer]``'s rows with
+    ``count > 0``, its operations in its order, with everything that
+    touches the state in the kernel: ``(y [R, nh, P], state)``."""
+    r, nh, p = x.shape
+    ng = b.shape[1]
+    j = nh // ng
+    f32 = jnp.float32
+    x4 = x.astype(f32).reshape(r, ng, j, p)
+    b, c = b.astype(f32), c.astype(f32)
+    dt4 = dt.reshape(r, ng, j)
+    decay = jnp.exp(dt4 * a.reshape(ng, j))
+    dtx = dt4[..., None] * x4
+    held, state = ssu.ssm_state_update(
+        state, layer, decay.reshape(r, nh), dtx.reshape(r, nh, p), b, c,
+        count=count, fresh=fresh, heads=heads, interpret=interpret)
+    from_state = decay[..., None] * held.reshape(r, ng, j, p)
+    cb = jnp.sum(c * b, axis=-1)[:, :, None, None]
+    y = (from_state + cb * dtx) + d_skip.astype(f32).reshape(ng, j, 1) * x4
+    return y.reshape(r, nh, p), state
+
+
 def ssm_packed(
     state: jnp.ndarray,  # [L, R, nh, P, N] float32: every layer's rows
     layer: jnp.ndarray,  # int32 scalar: the layer whose rows advance
@@ -128,6 +178,7 @@ def ssm_packed(
     count: jnp.ndarray,     # [R] int32: how many it has in this tick (0: none)
     fresh: jnp.ndarray,     # [R] bool: the row's sequence starts in this tick
     chunk: int,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The serving tick: a row's tokens are consecutive on the packed axis
     (``start``, ``count``); ``state[layer, r]`` is where row ``r``'s
@@ -135,10 +186,17 @@ def ssm_packed(
     is never read by a new request).  Returns ``(y [T, nh, P] float32,
     state)`` with ``state[layer]`` advanced IN PLACE.
 
-    Every row advances by its first token as ``q = 1``: the layer's rows
-    are read and written once, elementwise (on a v5e at the published
-    shapes 0.86 ms for a layer's 64 rows where the general form at ``q = 1``
-    takes 1.20: PERF.md section 6).  A row with more tokens (a prefill chunk)
+    Every row advances by its first token as ``q = 1``.  Where
+    ``state_update_heads`` allows, the Pallas kernel does it: only the rows
+    with a token are visited, each read once and written once (on a v5e at
+    the published shapes 0.85 ms a layer when all 64 rows have one, 0.73
+    for 55: PERF.md section 6, PR 45).  Elsewhere ``ssm_chunk`` does, over
+    all of the layer's rows, as two passes of the compiler's (1.20 ms).
+    ``interpret``: as the Pallas kernels take it — None lets the backend
+    decide (the kernel compiled on a TPU, ``ssm_chunk`` elsewhere), True
+    runs the kernel in the interpreter (tests), False compiles it.
+
+    A row with more tokens (a prefill chunk)
     then advances by itself, ``chunk`` tokens a pass, on its own row of the
     state: a decode row never meets the chunk form, and a tick of decode
     rows alone never enters the loop.  One row a pass and not several:
@@ -147,16 +205,25 @@ def ssm_packed(
     pass cost 5 ms and 771 MiB of temporaries more."""
     t = x.shape[0]
     first = jnp.clip(start, 0, t - 1)
-    h = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
-    h = jnp.where(fresh[:, None, None, None], 0.0, h.astype(jnp.float32))
-    y, h = ssm_chunk(
-        h, x[first][:, None], jnp.where(count > 0, 1.0, 0.0)[:, None, None]
-        * dt[first][:, None], a, b[first][:, None], c[first][:, None], d_skip)
-    # (the casts are no-ops: the state is float32 wherever the program
-    # allocates it; a test keeps it lower to show that the tolerance sees it)
-    state = lax.dynamic_update_index_in_dim(
-        state, h.astype(state.dtype), layer, 0)
-    y = y[tok_row, 0]  # right for a row's first token; the rest follow
+    heads = state_update_heads(state, b.shape[1], interpret)
+    if heads is not None:
+        y, state = _first_tokens(
+            state, layer, x[first], dt[first], a, b[first], c[first], d_skip,
+            count, fresh, heads, interpret)
+    else:
+        h = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+        h = jnp.where(fresh[:, None, None, None], 0.0, h.astype(jnp.float32))
+        y, h = ssm_chunk(
+            h, x[first][:, None],
+            jnp.where(count > 0, 1.0, 0.0)[:, None, None] * dt[first][:, None],
+            a, b[first][:, None], c[first][:, None], d_skip)
+        # (the casts are no-ops: the state is float32 wherever the program
+        # allocates it; a test keeps it lower to show that the tolerance
+        # sees it)
+        state = lax.dynamic_update_index_in_dim(
+            state, h.astype(state.dtype), layer, 0)
+        y = y[:, 0]
+    y = y[tok_row]  # right for a row's first token; the rest follow
     if t == 1:
         return y, state
     q = min(chunk, t - 1)

@@ -141,6 +141,7 @@ from llm_np_cp_tpu.models.transformer import (
     scan_unroll,
     ssm_block,
 )
+from llm_np_cp_tpu.ops import ssm as ssm_ops
 from llm_np_cp_tpu.ops.activations import ACT2FN
 from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS, expert_row_tile
 from llm_np_cp_tpu.ops.rope import rope_cos_sin
@@ -976,6 +977,21 @@ class ServeEngine:
             if tracer is not None:
                 tracer.complete("probe.grouped_matmul", t_probe, cat="setup",
                                 args={"ok": tile is not None})
+        # which form advances a recurrent state by a row's first token
+        # ("pallas": ops/pallas/ssm_state_update over the rows a tick
+        # touches; "xla": ``ssm_chunk`` over all of a layer's rows) —
+        # ``ssm_packed``'s own choice, asked here the way it asks and not
+        # first while the step is being traced; None without such layers
+        self.ssm_state_impl: str | None = None
+        if config.ssm_layers:
+            t_probe = tracer.now_us() if tracer is not None else -1.0
+            self.ssm_state_impl = "xla" if ssm_ops.state_update_heads(
+                self.pool.pages.state["ssm"],
+                config.mamba_n_groups) is None else "pallas"
+            if tracer is not None:
+                tracer.complete(
+                    "probe.ssm_state_update", t_probe, cat="setup",
+                    args={"ok": self.ssm_state_impl == "pallas"})
         if self.mixed:
             # -- unified tick: ONE jitted program, bucketed packed width.
             # The temp prefill cache, scatter_prefill, gather_prefix and
@@ -2607,10 +2623,8 @@ class ServeEngine:
                         else:
                             pool = tuple(kv_att)
                     if op == "attn_ssm":
-                        from llm_np_cp_tpu.ops.ssm import ssm_packed
-
                         def scan(xh, dt, a, bm, cm, d_skip):
-                            y, state["ssm"] = ssm_packed(
+                            y, state["ssm"] = ssm_ops.ssm_packed(
                                 state["ssm"], at["state"], xh[0], dt[0], a,
                                 bm[0], cm[0], d_skip, tok_row=tok_row,
                                 start=start, count=count,
@@ -4348,14 +4362,18 @@ class ServeEngine:
         if self.config.ssm_layers and active:
             ssm = {
                 # rows whose recurrent state the dispatch read and wrote
-                # (every layer's), and the live tokens through the scan
+                # (every layer's) — what the device moves under "pallas";
+                # under "xla" it moves every slot's row, these or not —,
+                # and the live tokens through the scan
                 "ssm_state_rows": active,
                 "ssm_scan_tokens": n_prefill_tok + n_decode_tok,
                 "state_slots_live": len(self.scheduler.running),
+                "ssm_state_impl": self.ssm_state_impl,
             }
             self.metrics.on_ssm(
                 rows=active, tokens=ssm["ssm_scan_tokens"],
-                state_slots_live=ssm["state_slots_live"])
+                state_slots_live=ssm["state_slots_live"],
+                kernel=self.ssm_state_impl == "pallas")
         outliers: list[dict] = []
         if self.tracer is not None and t0 >= 0.0:
             t7 = self._phase_mark(None)

@@ -206,6 +206,7 @@ class ServeMetrics:
         self.ssm_ticks = 0
         self.ssm_state_rows = 0
         self.ssm_scan_tokens = 0
+        self.ssm_state_kernel = 0  # gauge: 1 where the Pallas kernel moves them
         # speculative draft-then-verify accounting (exact counters +
         # a real accept-length histogram over SPEC_ACCEPT_BUCKETS —
         # one observation per verify round, value = accepted drafts)
@@ -318,15 +319,18 @@ class ServeMetrics:
             self.moe_pairs_held += pairs_held
             self.conv_state_slots = state_slots_live
 
-    def on_ssm(self, *, rows: int, tokens: int, state_slots_live: int) -> None:
+    def on_ssm(self, *, rows: int, tokens: int, state_slots_live: int,
+               kernel: bool) -> None:
         """One dispatching tick of a stack with state-space mixers: the
         rows whose recurrent state it read and wrote, the live tokens it
-        sent through the scan."""
+        sent through the scan, and whether the Pallas kernel moved those
+        rows alone (else the compiler's passes over every row)."""
         with self._lock:
             self.ssm_ticks += 1
             self.ssm_state_rows += rows
             self.ssm_scan_tokens += tokens
             self.conv_state_slots = state_slots_live
+            self.ssm_state_kernel = int(kernel)
 
     def on_spec(self, *, drafted: int, accepted: int) -> None:
         """One speculative verify round for one request: ``drafted``
@@ -531,6 +535,7 @@ class ServeMetrics:
                 out["ssm_state_rows"] = self.ssm_state_rows
                 out["ssm_scan_tokens"] = self.ssm_scan_tokens
                 out["ssm_state_slots_live"] = self.conv_state_slots
+                out["ssm_state_kernel"] = self.ssm_state_kernel
             if self.spec_rounds:
                 # reported only once a verify round ran (like the SLO
                 # block): a fabricated 0-acceptance series on a
@@ -766,6 +771,11 @@ class ServeMetrics:
             emit("ssm_state_slots_live", "gauge",
                  "Slots whose recurrent state is live",
                  [("", s["ssm_state_slots_live"])])
+            emit("ssm_state_kernel", "gauge",
+                 "1 where the Pallas state-update kernel advances the "
+                 "rows a tick touches, 0 where the compiler's passes "
+                 "advance every row",
+                 [("", s["ssm_state_kernel"])])
         # -- speculative decoding (only once a verify round ran — a
         # constant-zero series on a plain engine would read as a broken
         # speculation deployment on a fleet dashboard)

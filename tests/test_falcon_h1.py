@@ -28,6 +28,7 @@ Tolerances, and why:
   precision, differ by sums in another order (measured 1e-6).
 """
 
+import contextlib
 import sys
 from pathlib import Path
 
@@ -97,6 +98,32 @@ def tiny():
     return cfg, params
 
 
+# the preset with a state 128 wide: the narrowest the state-update kernel
+# takes (``ops/ssm.state_update_heads``; the preset's own 16 go through
+# ``ssm_chunk`` whoever asks)
+WIDE_HF = dict(TINY_HF, mamba_d_state=128)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    cfg = ModelConfig.from_hf_dict(WIDE_HF)
+    return cfg, init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+
+
+@contextlib.contextmanager
+def _state_kernel(form):
+    """``"pallas"``: whoever asks which form advances a state (the engine
+    when it is built, ``ssm_packed`` whenever a program is traced) is told
+    the kernel, run in the interpreter — what a TPU's probe answers, on a
+    CPU.  ``"xla"``: nothing is patched."""
+    real = ssm.state_update_heads
+    with pytest.MonkeyPatch.context() as mp:
+        if form == "pallas":
+            mp.setattr(ssm, "state_update_heads",
+                       lambda state, groups, interpret=None: real(state, groups, True))
+        yield
+
+
 def _spread(logits: np.ndarray) -> float:
     return float((logits.max(-1) - logits.mean(-1)).mean())
 
@@ -109,14 +136,14 @@ def _gap(got: np.ndarray, want: np.ndarray) -> float:
 _REF: dict = {}
 
 
-def _reference(params, seq, **kw) -> np.ndarray:
+def _reference(params, seq, hf=TINY_HF, **kw) -> np.ndarray:
     """The reference's logits for ``seq``, computed on the sequence padded
     to a multiple of 32 tokens (causal: what follows a position cannot
     change it), so that a few compiled programs serve every length."""
     n = -(-len(seq) // 32) * 32
-    key = (n, tuple(sorted(kw.items())))
+    key = (n, hf["mamba_d_state"], tuple(sorted(kw.items())))
     if key not in _REF:
-        _REF[key] = jax.jit(lambda p, ids: ref.forward(p, TINY_HF, ids, **kw))
+        _REF[key] = jax.jit(lambda p, ids: ref.forward(p, hf, ids, **kw))
     ids = np.zeros((n,), np.int32)
     ids[:len(seq)] = seq
     return np.asarray(_REF[key](params, ids))[:len(seq)]
@@ -253,18 +280,23 @@ def test_chunked_scan_is_the_token_by_token_recurrence(chunk, start):
             jnp.abs(want_h).max())
 
 
+@pytest.mark.parametrize("form", ["xla", "pallas"])
 @pytest.mark.parametrize("chunk", [4, 5])
-def test_packed_scan_resets_at_segment_boundaries_and_moves_no_other_row(chunk):
+def test_packed_scan_resets_at_segment_boundaries_and_moves_no_other_row(
+        chunk, form):
     """The tick's form: rows of 1, 11, 0, 6 and 3 tokens, consecutive on
     one packed axis of 24 lanes (3 of them dead).  Row 1 continues its
     state, rows 0, 3 and 4 start here (a slot's old state is not read),
     row 2 is not in the tick and keeps its state bit for bit.  Six rows
     have more tokens than one: each advances by itself, row 1 in three
-    passes or two."""
+    passes or two.  Through both forms of the first pass: ``ssm_chunk``
+    over every row, and the state-update kernel (in the interpreter, on a
+    state 128 wide) over the rows with a token."""
     counts, fresh = [1, 11, 0, 6, 3, 2, 2, 2], [True, False, False, True, True,
                                                  False, True, False]
     rows = len(counts)
-    i = _scan_inputs(2, max(counts), rows=rows)
+    i = _scan_inputs(2, max(counts), rows=rows, n=128 if form == "pallas" else 16)
+    assert (ssm.state_update_heads(i["h0"][None], 2, True) is None) is (form == "xla")
     state = jnp.stack([i["h0"] * 0.5, i["h0"]])  # two layers; layer 1 advances
     tok_row, start, packed = [], [], {k: [] for k in "x dt b c".split()}
     for r, n in enumerate(counts):
@@ -276,12 +308,13 @@ def test_packed_scan_resets_at_segment_boundaries_and_moves_no_other_row(chunk):
     t = len(tok_row) + dead
     pad = lambda v: jnp.concatenate(  # noqa: E731
         [v, jnp.ones((dead,) + v.shape[1:], v.dtype)])
-    y, new = jax.jit(ssm.ssm_packed, static_argnames="chunk")(
+    y, new = jax.jit(ssm.ssm_packed, static_argnames=("chunk", "interpret"))(
         state, jnp.int32(1), *(pad(jnp.concatenate(packed[k])) for k in ("x", "dt")),
         i["a"], *(pad(jnp.concatenate(packed[k])) for k in ("b", "c")),
         i["d_skip"], tok_row=jnp.asarray(tok_row + [0] * dead, jnp.int32),
         start=jnp.asarray(start, jnp.int32), count=jnp.asarray(counts, jnp.int32),
-        fresh=jnp.asarray(fresh), chunk=chunk)
+        fresh=jnp.asarray(fresh), chunk=chunk,
+        interpret=True if form == "pallas" else None)
     assert y.shape[0] == t
     assert np.array_equal(np.asarray(new[0]), np.asarray(state[0]))
     assert np.array_equal(np.asarray(new[1, 2]), np.asarray(state[1, 2]))
@@ -370,11 +403,11 @@ def _serve(engine, probe, reqs, between=None):
             return got
 
 
-def _worst_gap(params, reqs, got) -> float:
+def _worst_gap(params, reqs, got, hf=TINY_HF) -> float:
     worst = 0.0
     for r in reqs:
         seq = list(r.prompt) + list(r.generated)
-        want = _reference(params, seq)
+        want = _reference(params, seq, hf)
         p = len(r.prompt)
         have = np.stack(got[r.req_id])
         assert have.shape[0] == len(r.generated)
@@ -478,14 +511,23 @@ def test_a_sequences_logits_do_not_depend_on_the_rest_of_the_tick(
 # what the tolerance must refuse
 # ----------------------------------------------------------------------
 
+@pytest.mark.parametrize("form", ["xla", "pallas"])
 @pytest.mark.parametrize("broken", ["float32", "bf16_state", "zeroed_every_tick"])
 def test_a_state_kept_in_bf16_or_zeroed_between_ticks_fails_the_tolerance(
-        tiny, probe, shared, monkeypatch, broken):
+        tiny, wide, probe, shared, monkeypatch, broken, form):
     """The control of every comparison in this file: the SAME requests with
     the state as the program keeps it pass ``TOL``; kept in bf16, or zeroed
-    after every tick, they do not."""
-    cfg, params = tiny
-    engine = shared
+    after every tick, they do not.  ``pallas``: the preset with a state 128
+    wide served through the state-update kernel (a bf16 state the kernel
+    does not take: that engine says ``xla`` and fails all the same)."""
+    with _state_kernel(form):
+        _state_controls(tiny, wide, probe, shared, monkeypatch, broken, form)
+
+
+def _state_controls(tiny, wide, probe, shared, monkeypatch, broken, form):
+    cfg, params = tiny if form == "xla" else wide
+    hf = TINY_HF if form == "xla" else WIDE_HF
+    engine = shared if form == "xla" else None
     if broken == "bf16_state":
         real = ModelConfig.state_shapes
 
@@ -495,9 +537,12 @@ def test_a_state_kept_in_bf16_or_zeroed_between_ticks_fails_the_tolerance(
             return out
 
         monkeypatch.setattr(ModelConfig, "state_shapes", lower)
-        engine = _engine(cfg, params)
+        engine = None
+    engine = engine or _engine(cfg, params)
     assert engine.pool.pages.state["ssm"].dtype == (
         jnp.bfloat16 if broken == "bf16_state" else jnp.float32)
+    assert engine.ssm_state_impl == (
+        "pallas" if form == "pallas" and broken != "bf16_state" else "xla")
     reqs = [engine.submit(p, max_new_tokens=10, seed=i)
             for i, p in enumerate(_prompts([21, 6], seed=13))]
 
@@ -507,7 +552,7 @@ def test_a_state_kept_in_bf16_or_zeroed_between_ticks_fails_the_tolerance(
             engine.pool.pages = pages._replace(state=dict(
                 pages.state, ssm=jnp.zeros_like(pages.state["ssm"])))
 
-    worst = _worst_gap(params, reqs, _serve(engine, probe, reqs, between))
+    worst = _worst_gap(params, reqs, _serve(engine, probe, reqs, between), hf)
     if broken == "float32":
         assert worst < TOL
     else:
@@ -564,6 +609,43 @@ def test_unknown_model_type_and_unimplemented_variants_are_refused():
 # spans and counters
 # ----------------------------------------------------------------------
 
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_the_tick_says_which_form_advanced_the_state(wide, form):
+    """Tick argument ``ssm_state_impl`` on every dispatching tick, gauge
+    ``ssm_state_kernel`` and the ``probe.ssm_state_update`` set-up span:
+    a fallback shows in the trace and on ``/metrics``, not only in a log
+    line.  Both forms serve the same tokens."""
+    from llm_np_cp_tpu.serve.tracing import TraceRecorder
+
+    cfg, params = wide
+    tracer = TraceRecorder()
+    with _state_kernel(form):
+        engine = ServeEngine(params, cfg, max_slots=4, num_blocks=48,
+                             block_size=8, max_seq_len=64, prefill_chunk=8,
+                             cache_dtype=jnp.float32, tracer=tracer)
+        assert engine.ssm_state_impl == form
+        reqs = [engine.submit(p, max_new_tokens=5, seed=i)
+                for i, p in enumerate(_prompts([9, 12], seed=2))]
+        engine.run_until_complete()
+    span, = [e for e in tracer.events() if e.get("name") == "probe.ssm_state_update"]
+    assert span["args"]["ok"] is (form == "pallas")
+    ticks = [e["args"] for e in tracer.events()
+             if e.get("name") == "tick" and "ssm_state_rows" in e["args"]]
+    assert ticks and all(a["ssm_state_impl"] == form for a in ticks)
+    assert f"ssm_state_kernel {int(form == 'pallas')}" in engine.metrics.prometheus()
+    from tools.summarize_trace import format_summary, tick_account
+
+    assert tick_account(tracer.events())["ssm_kernel_share"] == (form == "pallas")
+    assert ("the state-update kernel in "
+            f"{'100.0' if form == 'pallas' else '0.0'}% of those ticks"
+            in format_summary(tracer.events(), top=0))
+    _TOKENS.setdefault("served", [r.generated for r in reqs])
+    assert [r.generated for r in reqs] == _TOKENS["served"]
+
+
+_TOKENS: dict = {}
+
+
 def test_tick_arguments_counters_and_scopes(tiny):
     from llm_np_cp_tpu.models.transformer import STEP_SCOPES
     from llm_np_cp_tpu.serve.tracing import TraceRecorder
@@ -587,6 +669,7 @@ def test_tick_arguments_counters_and_scopes(tiny):
     for ev in ticks:
         a = ev["args"]
         assert 1 <= a["ssm_state_rows"] <= 2 and a["host_fetches"] == 1
+        assert a["ssm_state_impl"] == "xla"  # no TPU, and a state 16 wide
         assert a["ssm_scan_tokens"] == a["prefill_tokens"] + a["decode_tokens"]
         assert a["state_slots_live"] <= 2
         rows, tokens = rows + a["ssm_state_rows"], tokens + a["ssm_scan_tokens"]
@@ -596,6 +679,7 @@ def test_tick_arguments_counters_and_scopes(tiny):
     assert f"ssm_state_rows_total {rows}" in text
     assert f"ssm_scan_tokens_total {tokens}" in text
     assert "ssm_ticks_total" in text and "ssm_state_slots_live" in text
+    assert "ssm_state_kernel 0" in text
     assert "moe_ticks_total" not in text
     # the op map knows the two scopes; the state's update is the scan's
     # own time, not a pool move (`pool.move_share` reads the K/V pool alone)
@@ -634,8 +718,12 @@ def test_a_stack_without_state_space_layers_reports_none_of_it():
     engine.submit(_prompts([5])[0], max_new_tokens=3)
     engine.run_until_complete()
     ticks = [e for e in tracer.events() if e.get("name") == "tick"]
-    assert ticks and not any("ssm_state_rows" in e["args"] for e in ticks)
+    assert ticks and not any(
+        k.startswith("ssm_") for e in ticks for k in e["args"])
     assert "ssm_" not in engine.metrics.prometheus()
+    assert engine.ssm_state_impl is None
+    assert not [e for e in tracer.events()
+                if e.get("name") == "probe.ssm_state_update"]
     build = next(e for e in tracer.events() if e.get("name") == "engine_build")
     assert build["args"]["state_bytes"] == 0
 

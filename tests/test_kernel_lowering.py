@@ -176,6 +176,8 @@ def _default_path(case):
         return bs == 64
     if kernel == "grouped_matmul":  # the cells' own shapes: further down
         return shape.name == "probe"
+    if kernel == "ssm_state_update":  # the probe's state and the cell's
+        return True
     return (kernel in ("ragged_paged_attention", "sample_epilogue")
             and shape.name in ("probe", "probe/untied", "qwen2.5-1.5b")
             and bs in (None, 64))
@@ -1165,10 +1167,7 @@ def test_hybrid_tick_on_a_v5e_names_its_experts_and_rebuilds_no_pool(
     assert any(v[1] == whole for v in ops.values()), "the state is not written"
 
 
-@pytest.fixture(scope="module")
-def falcon_h1_tick(v5e_sharding):
-    """The benchmark's state-space configuration at its published shapes,
-    its widest program compiled for the described v5e: (engine, compiled)."""
+def _falcon_h1_program(sharding, program):
     import json
     from pathlib import Path
 
@@ -1178,8 +1177,15 @@ def falcon_h1_tick(v5e_sharding):
         Path(__file__).resolve().parents[1] / "benchmark" / "configs"
         / "falcon-h1-34b-6l.json").read_text()))
     return _compile_widest_bucket(
-        v5e_sharding, jnp.bfloat16, cfg=cfg, slots=64, blocks=1026,
-        chunk=128, program=(768, 320))
+        sharding, jnp.bfloat16, cfg=cfg, slots=64, blocks=1026,
+        chunk=128, program=program)
+
+
+@pytest.fixture(scope="module")
+def falcon_h1_tick(v5e_sharding):
+    """The benchmark's state-space configuration at its published shapes,
+    its widest program compiled for the described v5e: (engine, compiled)."""
+    return _falcon_h1_program(v5e_sharding, (768, 320))
 
 
 def test_state_space_tick_on_a_v5e_copies_no_slab_of_the_pool(falcon_h1_tick):
@@ -1225,11 +1231,65 @@ def test_state_space_tick_on_a_v5e_updates_the_state_in_place_row_by_row(
     whole = opmap.hlo_shape("float32", state.shape)
     rows = {opmap.hlo_shape("float32", (n,) + state.shape[1:]) for n in (1, 6)} | {
         opmap.hlo_shape("float32", state.shape[1:])}
-    # whatever gives the whole state back is the in-place update, under its
-    # scope; nothing gives a layer's rows back at all
-    writes = [n for n, v in ops.items() if v[1] == whole]
+    # whatever gives the whole state back (alone, or beside another result)
+    # is an in-place update under its scope: the state-update kernel's call
+    # for every row's first token (beside it the ``[64, 128, 32]`` it read
+    # out of the state), and the chunk loop's write of ONE row, a fusion;
+    # the two loops only carry it.  Nothing gives a layer's rows back at all
+    writes = [n for n, v in ops.items()
+              if whole in v[1] and not n.startswith("while")]
     assert writes and all(ops[n][0] == "ssm_scan" for n in writes), writes
-    assert all("fusion" in n for n in writes), writes
+    kernel = [n for n in writes if n.startswith("ssm_state_update")]
+    assert len(kernel) == 1 and len(writes) == 2, writes
+    assert opmap.hlo_shape("float32", (64, 128, 32)) in ops[kernel[0]][1]
+    assert all("fusion" in n for n in writes if n not in kernel), writes
     assert not [n for n, v in ops.items() if v[1] in rows - {whole}]
     assert {"ssm_proj", "ssm_scan", "attn", "mlp", "tail"} <= {
         v[0] for v in ops.values()}
+
+
+
+def _takes(text, shape, beside):
+    """``{name: opcode}`` of the instructions that take a value shaped
+    ``shape`` as an operand, in the computation that holds the instruction
+    named ``beside*`` (serve/opmap.py's own reading of a line)."""
+    comps, current = {}, None
+    for line in text.splitlines():
+        if not line.startswith((" ", "\t")):
+            m = opmap._COMPUTATION.match(line)
+            current = comps.setdefault(m.group(2), []) if m else None
+            continue
+        m = opmap._INSTRUCTION.match(opmap._LAYOUT.sub("", line))
+        if m and current is not None:
+            current.append((*m.groups(), line[m.end():]))
+    body, = [c for c in comps.values() if any(n.startswith(beside) for n, *_ in c)]
+    holds = [n for n, result, _, _ in body if result == shape]
+    return {n: opcode for n, _, opcode, operands in body
+            if any(re.search(rf"%{re.escape(h)}\b", operands) for h in holds)}
+
+
+def test_steady_state_space_tick_reads_the_state_once_in_the_kernel(v5e_sharding):
+    """The program most ticks run (64 decode rows, ``512 x 64``): in a layer
+    the state is an operand of the kernel's call and of the tuple that
+    hands it to the chunk loop (no trip in such a tick), and of nothing
+    else — the compiler's two passes over all 64 rows (``add_dynamic-
+    update-slice_fusion f32[6,64,32,128,256]`` and the second read,
+    ``multiply_reduce_fusion f32[64,32,128]``: 0.87 s of a 3 s profile,
+    PERF.md section 6, PR 45) are not there, and the step still keeps
+    nothing the size of a layer's rows beside the state."""
+    engine, compiled = _falcon_h1_program(v5e_sharding, (512, 64))
+    text = compiled.as_text()
+    state = engine.pool.pages.state["ssm"]
+    whole = opmap.hlo_shape("float32", state.shape)
+    takers = _takes(text, whole, "ssm_state_update")
+    kernel = [n for n in takers if n.startswith("ssm_state_update")]
+    assert len(kernel) == 1 and takers[kernel[0]] == "custom-call", takers
+    assert set(takers.values()) <= {"custom-call", "tuple"}, takers
+    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, {})
+    assert ops[kernel[0]][0] == "ssm_scan"
+    gone = [n for n, v in ops.items() if v[0] == "ssm_scan" and (
+        n.startswith("add_dynamic-update-slice_fusion")
+        or v[1] == opmap.hlo_shape("float32", state.shape[1:]))]
+    assert not gone, gone
+    assert "input_output_alias" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < state.nbytes // 6

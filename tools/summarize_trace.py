@@ -540,6 +540,11 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
         # and wrote, live tokens through the scan
         for key in ("ssm_state_rows", "ssm_scan_tokens", "state_slots_live"):
             out[key] = sum(a.get(key, 0) for a in ssm) / len(ssm)
+        # ... and the share of those ticks in which the Pallas kernel moved
+        # those rows alone (else the compiler's passes moved every row;
+        # dumps older than the argument count as that)
+        out["ssm_kernel_share"] = sum(
+            a.get("ssm_state_impl") == "pallas" for a in ssm) / len(ssm)
     pub = [e["args"] for e in ticks if e["args"].get("publish_rows")]
     if pub:
         # ``deliver`` hands the PREVIOUS tick's tokens out: behind this
@@ -884,7 +889,9 @@ def format_summary(events: list[dict], top: int = 5,
                f"read and written"
                + (f" ({acct['ssm_state_rows'] * state_row_bytes / 2**20:.0f}"
                   " MiB a tick each way)" if state_row_bytes else "")
-               + f", {acct['state_slots_live']:.1f} state slots live"
+               + f", {acct['state_slots_live']:.1f} state slots live, the "
+               f"state-update kernel in {acct['ssm_kernel_share']:.1%} of "
+               "those ticks"
                if "ssm_state_rows" in acct else "")
             + "; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
