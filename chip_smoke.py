@@ -12,7 +12,7 @@ One process, one command, from the repo root:
     JAX_PLATFORMS=cpu python chip_smoke.py --rehearsal # tiny preset, CPU
 
 It drives the path a user runs — ``llm_np_cp_tpu.cli serve`` with CLI
-defaults (``--mixed-step auto``, ``--sample-epilogue auto``, greedy, bf16
+defaults (``--sample-epilogue auto``, greedy, bf16
 weights and cache): write a seeded random Qwen2.5-1.5B checkpoint in the
 HF layout (28 layers, hidden 1536, vocab 151,936 — full published width
 and depth), load it, place it, warm up (= compile), answer 16 requests
@@ -119,7 +119,7 @@ class Tee(io.TextIOBase):
     resolution report the smoke checks) and when the engine-built line
     appeared (the boundary between load+place and warm-up)."""
 
-    ENGINE_BUILT = ("unified tick ACTIVE", "--mixed-step auto:")
+    ENGINE_BUILT = "unified tick ACTIVE"
 
     def __init__(self, out) -> None:
         self.out = out
@@ -128,8 +128,7 @@ class Tee(io.TextIOBase):
 
     def write(self, s: str) -> int:
         self.kept.append(s)
-        if self.engine_built_at is None and any(
-                m in s for m in self.ENGINE_BUILT):
+        if self.engine_built_at is None and self.ENGINE_BUILT in s:
             self.engine_built_at = time.perf_counter()
         return self.out.write(s)
 
@@ -299,21 +298,15 @@ def check_resolution(rep: Report, args, banner: str) -> None:
     rep.facts["banner"] = line.group(0) if line else None
     tick = "unified" if m else "split"
     ragged, epilogue = (m.group(1), m.group(2)) if m else (None, None)
-    attn = re.search(r"attn=(\w+)", line.group(0)) if line else None
     topo = re.search(r"topo=(.*?), prefix_cache", line.group(0)) if line else None
     rep.facts["resolution"] = dict(
         tick=tick, ragged_attn_impl=ragged, epilogue_impl=epilogue,
-        decode_attn_impl=attn.group(1) if attn else None,
         topology=topo.group(1) if topo else None,
     )
     # a model-sharded mesh keeps the XLA logits tail by design (the
     # epilogue kernel streams the full lm head)
     want_epilogue = "xla" if tp > 1 else "fused"
-    why = ""
-    if tick != "unified":
-        fb = re.search(r"--mixed-step auto: .*", banner)
-        why = f" ({fb.group(0)})" if fb else ""
-    rep.check(tick == "unified", f"tick resolved to {tick}, want unified{why}")
+    rep.check(tick == "unified", f"tick resolved to {tick}, want unified")
     rep.check(ragged == "pallas",
               f"ragged attention resolved to {ragged}, want pallas")
     rep.check(epilogue == want_epilogue,

@@ -138,14 +138,6 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "<blocks>x<block size> (<ring> a slot)")
     p.add_argument("--cache-dtype", choices=["bf16", "f32", "int8"],
                    default="bf16")
-    p.add_argument("--attn-impl", choices=["gather", "paged", "auto"],
-                   default="gather",
-                   help="decode K/V access: 'gather' materializes the "
-                   "active batch's cache view through the block tables "
-                   "(the XLA path), 'paged' runs the block-table-native "
-                   "Pallas kernel with ZERO gather (requires the Mosaic "
-                   "compile probe to pass), 'auto' picks paged when the "
-                   "probe passes and falls back to gather")
     p.add_argument("--prefix-cache", action=argparse.BooleanOptionalAction,
                    default=False,
                    help="share fully-filled prompt-prefix blocks across "
@@ -172,24 +164,6 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    help="host-RAM budget for --kv-tier host, GiB "
                    "(LRU eviction past it; the tier is a cache, so "
                    "dropping is always safe)")
-    p.add_argument("--decode-attn", choices=["xla", "pallas"], default="xla",
-                   help="attention kernel for the GATHERED decode step "
-                   "(pallas fails at start-up if Mosaic refuses the "
-                   "kernel); ignored under --attn-impl paged")
-    p.add_argument("--mixed-step", choices=["auto", "on", "off"],
-                   default="auto",
-                   help="unified ragged prefill+decode tick: ONE device "
-                   "dispatch per tick runs a mixed batch of prefill "
-                   "chunk slices and decode rows against the paged pool "
-                   "(ragged_paged_attention), with prefill K/V written "
-                   "straight into pool blocks and decode co-scheduled "
-                   "under --tick-token-budget.  'auto' (default) takes "
-                   "the unified tick when the ragged kernel's Mosaic "
-                   "probe passes and falls back to the phase-split tick "
-                   "otherwise; 'on' forces it (XLA ragged fallback if "
-                   "the kernel is rejected); 'off' is the phase-split "
-                   "engine (--attn-impl/--decode-attn then select its "
-                   "decode path)")
     p.add_argument("--sample-epilogue", choices=["auto", "on", "off"],
                    default="auto",
                    help="fused sampling epilogue (tick-tail fusion): "
@@ -203,7 +177,7 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "final_logits+sampler tail (the parity oracle).  The "
                    "banner reports the resolution as epilogue=fused|xla")
     p.add_argument("--tick-token-budget", type=int, default=0, metavar="N",
-                   help="unified tick only: token budget per tick — "
+                   help="token budget per tick — "
                    "decode rows are budgeted first (never starved), "
                    "remaining tokens go to prefill chunk slices, so a "
                    "long prefill spreads over ticks instead of stalling "
@@ -220,8 +194,7 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "draft is a free token per HBM sweep.  Requests opt "
                    "in per-submit ('\"speculative\": true' on "
                    "/v1/completions; serve-bench marks its whole "
-                   "trace).  Requires the unified tick (--mixed-step "
-                   "auto/on); per-request fallback to plain decode "
+                   "trace).  Per-request fallback to plain decode "
                    "when rolling acceptance collapses")
     p.add_argument("--spec-k", type=int, default=4, metavar="N",
                    help="max draft tokens proposed per speculating "
@@ -516,16 +489,9 @@ def _validate_pool_flags(args) -> None:
             f"({args.slots}) so decode rows are never starved, got "
             f"{budget}"
         )
-    if getattr(args, "speculative_serve", False):
-        if getattr(args, "mixed_step", "off") == "off":
-            raise SystemExit(
-                "--speculative-serve rides the unified tick's batched "
-                "verifier; it cannot run with --mixed-step off"
-            )
-        if getattr(args, "spec_k", 4) < 1:
-            raise SystemExit(
-                f"--spec-k must be >= 1, got {args.spec_k}"
-            )
+    if (getattr(args, "speculative_serve", False)
+            and getattr(args, "spec_k", 4) < 1):
+        raise SystemExit(f"--spec-k must be >= 1, got {args.spec_k}")
     for flag in ("slo_ttft", "slo_tpot"):
         if getattr(args, flag, 0.0) < 0:
             raise SystemExit(
@@ -655,11 +621,8 @@ def _build_serve_engine(args, params, config, *, prog: str,
                         shared_host_tier=None, quiet=False,
                         early_spans=None):
     """The shared engine build for both serve subcommands: validate the
-    pool flags, resolve --attn-impl against the Mosaic probe (an EXPLICIT
-    paged request must fail with an actionable message when the kernel
-    does not compile — not a Pallas traceback at first dispatch, and not
-    a silent downgrade, which is what auto is for), size the pool, build.
-    """
+    pool flags, size the pool, build.  Which kernels the tick runs is the
+    engine's own probes' verdict; the banner reports it."""
     import jax
     import jax.numpy as jnp
 
@@ -681,36 +644,6 @@ def _build_serve_engine(args, params, config, *, prog: str,
     cache_dtype = {
         "bf16": jnp.bfloat16, "f32": jnp.float32, "int8": jnp.int8,
     }[args.cache_dtype]
-    gather_impl = "xla"
-    if args.decode_attn == "pallas":
-        _require_decode_kernel(args)
-        gather_impl = "flash_decode"
-    if args.attn_impl in ("paged", "auto"):
-        from llm_np_cp_tpu.ops.pallas.support import (
-            kernel_error,
-            paged_kernel_name,
-        )
-
-        paged_kernel = paged_kernel_name(args.cache_dtype == "int8")
-        t_probe = time.perf_counter()
-        err = kernel_error(paged_kernel)
-        early_spans.append(("probe.paged_attn", t_probe, time.perf_counter(),
-                            {"ok": err is None}))
-        if err is None:
-            decode_attn_impl = "paged"
-        elif args.attn_impl == "auto":
-            print(f"[{prog}] --attn-impl auto: paged kernel "
-                  f"unavailable ({err}); using the gather path")
-            decode_attn_impl = gather_impl
-        else:
-            raise SystemExit(
-                f"--attn-impl paged: the {paged_kernel} kernel does not "
-                f"compile on this backend ({err}); use --attn-impl "
-                "gather, or auto to fall back automatically"
-            )
-    else:
-        decode_attn_impl = gather_impl
-
     # tracing on iff requested (--trace-out / --trace-ring / implied by
     # --jax-profile — the TraceAnnotation scopes that correlate the
     # device profile only exist while a recorder is attached): the
@@ -896,13 +829,11 @@ def _build_serve_engine(args, params, config, *, prog: str,
         max_seq_len=max_seq_len,
         prefill_chunk=chunk,
         cache_dtype=cache_dtype,
-        decode_attn_impl=decode_attn_impl,
         enable_prefix_cache=args.prefix_cache,
         max_queue=max_queue,
         tokenizer=tokenizer,
         fault_injector=fault_injector,
         tracer=tracer,
-        mixed_step=getattr(args, "mixed_step", "off"),
         sample_epilogue=getattr(args, "sample_epilogue", "auto"),
         tick_token_budget=getattr(args, "tick_token_budget", 0) or None,
         mesh_plan=mesh_plan,
@@ -932,28 +863,20 @@ def _build_serve_engine(args, params, config, *, prog: str,
         return engine, num_blocks
     if engine.mesh is not None:
         print(f"[{prog}] mesh ACTIVE: {engine.mesh_desc}")
-    if engine.mixed:
-        print(f"[{prog}] unified tick ACTIVE: one mixed dispatch/tick, "
-              f"budget {engine.tick_token_budget} tokens "
-              f"(ragged attention: {engine.ragged_attn_impl}, "
-              f"epilogue={'fused' if engine.epilogue_impl == 'fused' else 'xla'}), "
-              + ("pool written in place" if engine.pool_carried else
-                 "pool moved by layer slabs (not row-major on this device)")
-              + f", pages {engine.pool_page_shape}"
-              + (f", state update: {engine.ssm_state_impl}"
-                 if engine.ssm_state_impl else ""))
-    elif getattr(args, "mixed_step", "off") == "auto":
-        print(f"[{prog}] --mixed-step auto: ragged kernel unavailable; "
-              "using the phase-split tick "
-              f"(epilogue={'fused' if engine.epilogue_impl == 'fused' else 'xla'})")
+    print(f"[{prog}] unified tick ACTIVE: one mixed dispatch/tick, "
+          f"budget {engine.tick_token_budget} tokens "
+          f"(ragged attention: {engine.ragged_attn_impl}, "
+          f"epilogue={'fused' if engine.epilogue_impl == 'fused' else 'xla'}), "
+          + ("pool written in place" if engine.pool_carried else
+             "pool moved by layer slabs (not row-major on this device)")
+          + f", pages {engine.pool_page_shape}"
+          + (f", state update: {engine.ssm_state_impl}"
+             if engine.ssm_state_impl else ""))
     if engine.spec_k:
         print(f"[{prog}] speculative serving ACTIVE: k={engine.spec_k} "
               "draft tokens/tick, prompt-lookup drafts verified in the "
               "mixed dispatch (per-request opt-in: "
               '"speculative": true)')
-    elif getattr(args, "speculative_serve", False):
-        print(f"[{prog}] --speculative-serve requested but the unified "
-              "tick is unavailable; serving plain decode")
     return engine, num_blocks
 
 
@@ -1067,11 +990,9 @@ def _run_serve_bench(argv: list[str], default_model: str) -> str:
         )
     _dump_trace(engine.tracer, args, "serve-bench")
     _close_otel(engine.tracer, "serve-bench")
-    tick = (
-        f"mixed:{engine.ragged_attn_impl}"
-        f"(budget={engine.tick_token_budget})"
-        if engine.mixed else "split"
-    ) + f",epilogue={engine.epilogue_impl}"
+    tick = (f"mixed:{engine.ragged_attn_impl}"
+            f"(budget={engine.tick_token_budget})"
+            f",epilogue={engine.epilogue_impl}")
     topo = engine.mesh_desc or "single chip"
     if args.replicas > 1:
         if topo.startswith("pinned to"):
@@ -1083,8 +1004,7 @@ def _run_serve_bench(argv: list[str], default_model: str) -> str:
     out = (
         f"[serve-bench] {args.requests} requests @ {args.rate} req/s, "
         f"slots={args.slots}, pool={num_blocks}x{args.block_size} "
-        f"({args.cache_dtype}), attn={engine.decode_attn_impl}, "
-        f"tick={tick}, topo={topo}, "
+        f"({args.cache_dtype}), tick={tick}, topo={topo}, "
         f"prefix_cache={'on' if args.prefix_cache else 'off'}, "
         f"kv_tier={args.kv_tier}\n"
     )
@@ -1222,8 +1142,7 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
         + (f" + window {engine.pool.window.num_blocks}x{args.block_size} "
            f"({engine.window_blocks} a slot)"
            if engine.pool.window is not None else "")
-        + f", attn={engine.decode_attn_impl}, "
-        f"epilogue={engine.epilogue_impl}, topo={topo}, "
+        + f", epilogue={engine.epilogue_impl}, topo={topo}, "
         f"prefix_cache={'on' if args.prefix_cache else 'off'}, "
         f"kv_tier={args.kv_tier}, "
         f"max_queue={args.max_queue or 'unbounded'}, "
@@ -1616,7 +1535,7 @@ def _run_tpu(args) -> str:
         cache_dtype=cache_dtype,
         prefill_attn_impl=attn_impl,
         prefill_chunk=args.prefill_chunk,
-        decode_attn_impl="flash_decode" if args.decode_attn == "pallas" else "xla",
+        decode_attn="flash_decode" if args.decode_attn == "pallas" else "xla",
         early_stop=args.early_stop,
     )
 

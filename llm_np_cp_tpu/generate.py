@@ -410,7 +410,7 @@ class Generator:
         cache_dtype: jnp.dtype = jnp.bfloat16,
         prefill_attn_impl: str = "xla",
         prefill_chunk: int | None = None,
-        decode_attn_impl: str = "xla",
+        decode_attn: str = "xla",
         early_stop: bool = False,
     ) -> None:
         self.params = params
@@ -418,13 +418,13 @@ class Generator:
         self.sampler = sampler or Sampler()
         self.stop_tokens = tuple(stop_tokens)
         self.cache_dtype = cache_dtype
-        if decode_attn_impl not in ("xla", "flash_decode"):
+        if decode_attn not in ("xla", "flash_decode"):
             # the CLI's user-facing name is "pallas"; catch it (and typos)
             # here instead of silently falling back to the XLA path in
             # run_decoder_layer
             raise ValueError(
-                f"decode_attn_impl must be 'xla' or 'flash_decode', "
-                f"got {decode_attn_impl!r}"
+                f"decode_attn must be 'xla' or 'flash_decode', "
+                f"got {decode_attn!r}"
             )
         # Mosaic gate: a Pallas impl that fails to compile on the live
         # backend downgrades to XLA with one warning instead of dying at
@@ -432,8 +432,8 @@ class Generator:
         from llm_np_cp_tpu.ops.pallas.support import gate_attn_impl
 
         prefill_attn_impl = gate_attn_impl(prefill_attn_impl)
-        decode_attn_impl = gate_attn_impl(
-            decode_attn_impl,
+        decode_attn = gate_attn_impl(
+            decode_attn,
             int8_cache=jnp.dtype(cache_dtype) == jnp.int8,
         )
         if prefill_chunk:
@@ -458,11 +458,11 @@ class Generator:
         )
         fused_epi = self.epilogue_impl == "fused"
         self._step = make_decode_step_fn(
-            config, self.sampler, decode_attn_impl,
+            config, self.sampler, decode_attn,
             fused_epilogue=fused_epi,
         )
         self._loop = make_decode_loop_fn(
-            config, self.sampler, self.stop_tokens, decode_attn_impl,
+            config, self.sampler, self.stop_tokens, decode_attn,
             early_stop=early_stop, fused_epilogue=fused_epi,
         )
 

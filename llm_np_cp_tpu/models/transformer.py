@@ -37,7 +37,6 @@ from jax import lax
 from llm_np_cp_tpu.cache import (
     KVCache,
     write_at,
-    dequantize_kv,
     update_layer,
     update_layer_quantized,
 )
@@ -55,7 +54,7 @@ from llm_np_cp_tpu.ops.moe import (
 )
 from llm_np_cp_tpu.ops.norms import rms_norm
 from llm_np_cp_tpu.ops.rope import apply_rope, rope_cos_sin
-from llm_np_cp_tpu.quant import quant_einsum
+from llm_np_cp_tpu.quant import dequantize_kv, quant_einsum
 
 Params = dict[str, Any]
 

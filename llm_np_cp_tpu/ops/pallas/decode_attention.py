@@ -36,10 +36,9 @@ Design (round-5 rewrite; the r4 kernel ran at 58% of the XLA path):
 - int8 cache mode dequantizes the whole [block_s, K, D] block in VMEM
   with a single multiply (HBM streams 1-byte values + f32 scales).
 
-The serving kernels below take the cache as a POOL of pages and a block
-table a row.  ``_paged_kernel`` (the phase-split tick) walks the table
-one page a grid step.  ``_ragged_kernel`` (the unified tick, every
-benchmark cell) has a grid of query tiles x GROUPS of pages: a kv grid
+The serving kernel below takes the cache as a POOL of pages and a block
+table a row.  ``_ragged_kernel`` (the served tick, every benchmark
+cell) has a grid of query tiles x GROUPS of pages: a kv grid
 step attends ``P = ragged_pages_per_step(...)`` consecutive pages of its
 tile's row — as many as cover 512 kv positions, no more than the table
 is wide, inside ``_VMEM_BUDGET_BYTES``; read off the page's shape and
@@ -93,8 +92,8 @@ _VMEM_BUDGET_BYTES = 8 * 2**20
 # full-width VPU multiplies (plus one transcendental) per block.  AMLA's
 # observation: if the running max is kept on the ln2 grid, alpha is an
 # EXACT power of two, and multiplying a float by 2^k is an integer ADD
-# on its exponent field.  The serving kernels below (_paged_kernel /
-# _ragged_kernel) use this additive-max formulation; quantizing the max
+# on its exponent field.  The serving kernel below (_ragged_kernel)
+# uses this additive-max formulation; quantizing the max
 # UP to the grid keeps every exp argument <= 0, so the only numerical
 # change is that p = exp(s - m) sits up to one octave lower — the
 # final acc/l ratio is mathematically unchanged (parity-pinned against
@@ -295,214 +294,6 @@ def _block_bounds(mask: jnp.ndarray, block_s: int, n_blocks: int) -> jnp.ndarray
     nb = jnp.clip(last // block_s + 1, 1, n_blocks)
     start = jnp.clip(first // block_s, 0, nb - 1)
     return jnp.stack([start, nb]).astype(jnp.int32)  # [2, B]
-
-
-def _paged_kernel(
-    meta_ref, tables_ref, *refs,
-    scale: float, softcap: float | None, quantized: bool, kv_heads: int,
-    group: int, block_s: int,
-):
-    """Block-table variant of ``_decode_kernel``: the kv grid step fetches
-    the POOL block named by the row's table (scalar-prefetched), so the
-    serving engine's gather→contiguous copy never materializes.  The
-    visibility mask is derived in-kernel from the row's (pad, length)
-    scalars instead of a streamed [B, S] mask operand."""
-    if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    bi = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    start, nb = meta_ref[0, bi], meta_ref[1, bi]
-    pad, length = meta_ref[2, bi], meta_ref[3, bi]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(start + j < nb)
-    def _update():
-        # rank-2 iota over the minor dim — Mosaic rejects rank-1 iota on
-        # TPU (the r3-postmortem failure class; interpret mode hides it)
-        pos = (start + j) * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_s), 1
-        )
-        mask = (pos >= pad) & (pos < length)  # [1, block_s]
-        kb = k_ref[0]  # [block_s, K, D]
-        vb = v_ref[0]
-        dtype = q_ref.dtype
-        if quantized:
-            # int8 pool blocks: HBM streams 1-byte values + f32 scale
-            # pages; dequant is one VMEM multiply per block (same
-            # contract as _decode_kernel's int8 mode)
-            kb = kb.astype(dtype) * ks_ref[0][..., None].astype(dtype)
-            vb = vb.astype(dtype) * vs_ref[0][..., None].astype(dtype)
-        s = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    q_ref[0, ki], kb[:, ki], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for ki in range(kv_heads)
-            ],
-            axis=0,
-        ) * scale  # [H, block_s]
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where(mask, s, NEG_INF)
-
-        # AMLA additive-max update: the running max lives on the ln2
-        # grid, so the block rescale is an exponent-field integer add
-        # instead of an exp() + two full-width multiplies
-        m_prev = m_ref[:]
-        m_new = _amla_max(m_prev, s)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        k_steps = _amla_steps(m_prev, m_new)
-        l_ref[:] = (_amla_rescale(l_ref[:], k_steps)
-                    + jnp.sum(p, axis=-1, keepdims=True))
-        pb = p.astype(vb.dtype)
-        pv = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    pb[ki * group:(ki + 1) * group], vb[:, ki],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for ki in range(kv_heads)
-            ],
-            axis=0,
-        )
-        acc_ref[:] = _amla_rescale(acc_ref[:], k_steps) + pv
-        m_ref[:] = m_new
-
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[0] = (acc_ref[:] / l).reshape(o_ref.shape[1:]).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("scale", "logit_softcap", "interpret")
-)
-def paged_decode_attention(
-    q: jnp.ndarray,
-    k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
-    tables: jnp.ndarray,
-    lengths: jnp.ndarray,
-    pads: jnp.ndarray,
-    *,
-    k_scale: jnp.ndarray | None = None,
-    v_scale: jnp.ndarray | None = None,
-    scale: float,
-    logit_softcap: float | None = None,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """One-token GQA attention straight off a paged KV pool.
-
-    q [B, 1, H, D]; k_pages/v_pages [NB, BS, K, D] (ONE layer's pool
-    slab, serve/block_pool.py layout); tables [B, MB] int32 block ids
-    (scratch-0 padded past each row's allocation); lengths [B] — visible
-    slots per row (the current token's K/V already written at slot
-    lengths-1); pads [B] — left-pad slots to skip.  → [B, 1, H, D].
-
-    Row b sees pool slot ``tables[b, pos // BS] * BS + pos % BS`` for
-    logical positions ``pads[b] <= pos < lengths[b]`` — equivalent to
-    gathering the row's blocks into a contiguous [B, MB*BS, K, D] view
-    and running ``decode_attention`` with the matching mask (pinned in
-    tests), but the gather never materializes: each grid step DMAs one
-    pool block found through the scalar-prefetched table, and blocks
-    outside [pads//BS, ceil(lengths/BS)) are skipped entirely.
-
-    int8 pool mode: pass k_pages/v_pages as int8 with ``k_scale``/
-    ``v_scale`` [NB, BS, K] f32 scale pages (the block_pool quantized
-    layout); the kernel streams 1-byte blocks and dequantizes in VMEM.
-
-    This is the serving-engine decode kernel (``attn_impl="paged"`` in
-    ServeEngine, kernel-gated via ops/pallas/support.py).
-    interpret=None auto-selects like decode_attention.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    quantized = k_scale is not None
-    if (
-        quantized != (k_pages.dtype == jnp.int8)
-        or quantized != (v_pages.dtype == jnp.int8)
-        or quantized != (v_scale is not None)
-    ):
-        raise ValueError(
-            "int8 k_pages AND v_pages require both k_scale and v_scale "
-            f"pages (and vice versa); got k={k_pages.dtype}, "
-            f"v={v_pages.dtype}, "
-            f"k_scale={'set' if k_scale is not None else None}, "
-            f"v_scale={'set' if v_scale is not None else None}"
-        )
-    b, one, h, d = q.shape
-    assert one == 1, f"paged_decode_attention is q_len=1 only, got {one}"
-    nb_pool, block_s, kh, _ = k_pages.shape
-    g = h // kh
-    mb = tables.shape[1]
-
-    qf = q.reshape(b, kh, g, d)
-    start = jnp.clip(pads // block_s, 0, jnp.maximum(mb - 1, 0))
-    nb = jnp.clip(-(-lengths // block_s), 1, mb)
-    meta = jnp.stack([start, nb, pads, lengths]).astype(jnp.int32)  # [4, B]
-
-    def _kv_map(bi, j, meta_ref, tables_ref):
-        jj = jnp.minimum(meta_ref[0, bi] + j, meta_ref[1, bi] - 1)
-        return (tables_ref[bi, jj], 0, 0, 0)
-
-    def _scale_map(bi, j, meta_ref, tables_ref):
-        jj = jnp.minimum(meta_ref[0, bi] + j, meta_ref[1, bi] - 1)
-        return (tables_ref[bi, jj], 0, 0)
-
-    kv_spec = pl.BlockSpec((1, block_s, kh, d), _kv_map,
-                           memory_space=pltpu.VMEM)
-    in_specs = [
-        pl.BlockSpec(
-            (1, kh, g, d),
-            lambda bi, j, meta_ref, tables_ref: (bi, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        kv_spec,
-        kv_spec,
-    ]
-    operands = [qf, k_pages, v_pages]
-    if quantized:
-        scale_spec = pl.BlockSpec((1, block_s, kh), _scale_map,
-                                  memory_space=pltpu.VMEM)
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_kernel, scale=scale, softcap=logit_softcap,
-            quantized=quantized, kv_heads=kh, group=g, block_s=block_s,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, mb),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, kh, g, d),
-                lambda bi, j, meta_ref, tables_ref: (bi, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((h, d), jnp.float32),
-            ],
-        ),
-        interpret=interpret,
-    )(meta, tables, *operands)
-
-    return out.reshape(b, 1, h, d)
 
 
 # ----------------------------------------------------------------------
@@ -1037,9 +828,9 @@ def ragged_paged_attention(
     both).  → [T, H, D].
 
     Token i of a tile sees kv slots ``[max(pad, slot_i - window + 1),
-    slot_i]`` where ``slot_i = tile_qpos0 + i`` — exactly the visibility
-    the phase-split engine's chunked prefill mask + paged decode step
-    encode, so outputs are parity-testable against both.
+    slot_i]`` where ``slot_i = tile_qpos0 + i`` — the visibility of a
+    causal forward over the row's tokens, so outputs are parity-testable
+    against ``models.forward``.
 
     The grid is ``(T / RAGGED_Q_TILE, ceil(MB / P))``: a kv step streams
     and attends ``P = ragged_pages_per_step(...)`` pages of the tile's
@@ -1265,7 +1056,7 @@ def ragged_paged_attention_xla(
         if scales is None:
             return view
         sv = scales[tables].reshape(tables.shape[0], s_max, kh)
-        from llm_np_cp_tpu.cache import dequantize_kv
+        from llm_np_cp_tpu.quant import dequantize_kv
 
         return dequantize_kv(view, sv, q.dtype)
 
@@ -1314,7 +1105,7 @@ def decode_attention(
     — verified against it in tests.
 
     int8 cache mode: pass k/v as int8 with ``k_scale``/``v_scale``
-    [B, S, K] (cache.quantize_kv layout); the kernel streams 1-byte
+    [B, S, K] (quant.quantize_kv layout); the kernel streams 1-byte
     values from HBM and dequantizes in VMEM — the combination that would
     otherwise materialize full dequantized slabs per step.
 

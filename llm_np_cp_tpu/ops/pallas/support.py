@@ -40,7 +40,7 @@ _RUNTIME_DISABLED: dict[str, str] = {}
 
 def disable_kernel(kernel: str, reason: str) -> None:
     """Record a dispatch-time fault for ``kernel``: every subsequent
-    ``kernel_error``/``gate_attn_impl`` call reports it unavailable."""
+    ``kernel_error`` call reports it unavailable."""
     _RUNTIME_DISABLED.setdefault(kernel, f"faulted at dispatch: {reason}")
     log.warning(
         "Pallas kernel %s disabled for this process (%s)", kernel, reason
@@ -53,7 +53,6 @@ KERNELS = (
     "softmax",
     "flash_attention",
     "decode_attention", "decode_attention_int8",
-    "paged_decode_attention", "paged_decode_attention_int8",
     "ragged_paged_attention", "ragged_paged_attention_int8",
     "ragged_latent_attention",
     "sample_epilogue", "sample_epilogue_int8",
@@ -173,7 +172,7 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
     ignored by the others."""
     import jax.random as jr
 
-    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
+    from llm_np_cp_tpu.quant import dequantize_kv, quantize_kv
     from llm_np_cp_tpu.ops.attention import causal_mask, gqa_attention
 
     int8 = kernel.endswith("_int8")
@@ -333,42 +332,6 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         return jax.jit(make_args), jax.jit(run), reference
 
     bs = block_size
-    nbp, mb = 24, 4
-    if base == "paged_decode_attention":
-        from llm_np_cp_tpu.ops.pallas.decode_attention import (
-            paged_decode_attention,
-        )
-
-        # block tables permute the pool; row 1's pad spans a whole block
-        # (start = 1) so the leading-block-skip path compiles too
-        b = 4
-
-        def make_args():
-            q, pages = normals((b, 1, h, d), (nbp, bs, kh, d))
-            tables = jnp.asarray(
-                [[2, 1, 7, 9], [3, 0, 5, 11], [4, 6, 8, 10],
-                 [12, 13, 14, 15]], jnp.int32)
-            lengths = jnp.asarray(
-                [4 * bs, 2 * bs - 1, bs // 2 + 3, 3 * bs + 1], jnp.int32)
-            pads = jnp.asarray([0, bs + 3, 5, 0], jnp.int32)
-            return (q, tables, lengths, pads, *kv_operands(pages))
-
-        def reference(q, tables, lengths, pads, *ops):
-            view = kv_float(ops)[tables].reshape(b, mb * bs, kh, d)
-            pos = jnp.arange(mb * bs, dtype=jnp.int32)[None, :]
-            mask = (pos >= pads[:, None]) & (pos < lengths[:, None])
-            return gqa_attention(q, view, view, mask[:, None, :],
-                                 scale=scale, logit_softcap=softcap)
-
-        return (
-            make_args,
-            lambda q, tables, lengths, pads, *ops: paged_decode_attention(
-                q, ops[0], ops[1], tables, lengths, pads, scale=scale,
-                logit_softcap=softcap, interpret=interpret,
-                **kv_kwargs(ops)),
-            reference,
-        )
-
     # the mixed tick both ragged kernels' cases attend (six tiles over
     # three rows of a 12-block table into a 40-block pool)
     n_tiles, mb_r, nbp_r = 6, 12, 40
@@ -547,7 +510,7 @@ def kernel_cases(shapes=None):
                 continue  # a recurrent state and its one kernel
             if not shape.tied and not kernel.startswith("sample_epilogue"):
                 continue  # only the epilogue distinguishes head layouts
-            paged = kernel.startswith(("paged_", "ragged_"))
+            paged = kernel.startswith("ragged_")
             for bs in SERVE_BLOCK_SIZES if paged else (None,):
                 yield kernel, shape, bs
 
@@ -614,22 +577,12 @@ def _compile_and_run(kernel: str) -> str | None:
     return None
 
 
-def paged_kernel_name(int8_cache: bool) -> str:
-    """Probe/kernel name for the block-table-native decode kernel — THE
-    one int8-gating rule, shared by ``gate_attn_impl`` and the CLI's
-    pre-build check so the two can't drift."""
-    return (
-        "paged_decode_attention_int8" if int8_cache
-        else "paged_decode_attention"
-    )
-
-
 def ragged_kernel_name(int8_cache: bool, latent: bool = False) -> str:
     """Probe/kernel name for the mixed prefill+decode ragged kernel
-    (the unified-tick dispatch) — same one-rule discipline as
-    ``paged_kernel_name``, shared by the engine's ``mixed_step`` gate
-    and the CLI's pre-build check.  ``latent``: the pool holds latent
-    rows, which a kernel of its own reads."""
+    (the tick's dispatch) — THE one int8-gating rule, shared by the
+    engine's gate, its runtime degradation and chip_smoke.py so they
+    can't drift.  ``latent``: the pool holds latent rows, which a
+    kernel of its own reads."""
     if latent:
         return "ragged_latent_attention"
     return (
@@ -641,7 +594,7 @@ def ragged_kernel_name(int8_cache: bool, latent: bool = False) -> str:
 def epilogue_kernel_name(int8_head: bool) -> str:
     """Probe/kernel name for the fused sampling epilogue (final norm →
     lm_head → greedy sample over vocab tiles) — same one-rule
-    discipline as ``paged_kernel_name``, shared by the serve engine's
+    discipline as ``ragged_kernel_name``, shared by the serve engine's
     epilogue gate and the offline Generator so the two can't drift.
     ``int8_head``: the lm-head weight is a quant.py int8 payload."""
     return "sample_epilogue_int8" if int8_head else "sample_epilogue"
@@ -688,7 +641,6 @@ def gate_attn_impl(impl: str, *, int8_cache: bool = False) -> str:
         "flash_decode": (
             "decode_attention_int8" if int8_cache else "decode_attention"
         ),
-        "paged": paged_kernel_name(int8_cache),
         "xla": None,
     }.get(impl)
     if kernel is None:

@@ -123,6 +123,28 @@ def _unpack4(p: jnp.ndarray) -> jnp.ndarray:
     return u.reshape(*p.shape[:-2], p.shape[-2] * 2, p.shape[-1])
 
 
+def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-token-per-head symmetric int8: x [..., D] float →
+    (int8 [..., D], f32 absmax/127 scale [...]).
+
+    Same numeric contract as quantize_array (weight-side int8) but
+    activation-shaped: squeezed scale tuple instead of a keepdims dict,
+    and the amax==0 guard keeps scale 0 (slot reads as exact zero) rather
+    than mapping it to 1.  Keep the two in sync if the contract changes.
+    """
+    scale = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1) / 127.0
+    safe = jnp.where(scale == 0.0, 1.0, scale)
+    q = jnp.clip(jnp.round(x.astype(jnp.float32) / safe[..., None]), -127, 127)
+    return q.astype(jnp.int8), scale.astype(jnp.float32)
+
+
+def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype: jnp.dtype) -> jnp.ndarray:
+    """int8 [..., D] × scale [...] → float [..., D].  Left unfused here on
+    purpose: XLA folds the convert+multiply into the attention einsum's
+    operand, so HBM reads stay int8."""
+    return q.astype(dtype) * scale[..., None].astype(dtype)
+
+
 def dequantize(w: Any, dtype: jnp.dtype = jnp.float32) -> jnp.ndarray:
     if not is_quantized(w):
         return w

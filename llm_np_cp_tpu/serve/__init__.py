@@ -10,7 +10,7 @@ by *Ragged Paged Attention* (PAPERS.md) for TPU inference.
 Modules:
 - ``block_pool``  — fixed-size KV blocks in one preallocated slab per
   layer, a free-list allocator, per-request block tables (int8 blocks
-  reuse cache.quantize_kv/dequantize_kv).
+  reuse quant.quantize_kv/dequantize_kv).
 - ``scheduler``   — continuous batching: admit queued requests into
   decode slots as others finish, evict-on-OOM with requeue; pure
   Python/NumPy, so policies are testable without a model.

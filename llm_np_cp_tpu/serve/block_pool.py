@@ -72,7 +72,7 @@ would be 25,600 of a token's 30,720 bytes; bounded, a token costs the two
 global layers' 5,120 and a slot a constant.
 
 int8 mode mirrors ``KVCache``'s quantized slabs: per-token-per-head
-absmax scales (cache.quantize_kv layout) ride in parallel
+absmax scales (quant.quantize_kv layout) ride in parallel
 ``[L, NB, BS, K]`` f32 pages.
 
 The allocator is host-side Python (a free list) — allocation happens at

@@ -1,105 +1,76 @@
-"""ServeEngine: jit-stable continuous-batching decode over the block pool.
+"""ServeEngine: jit-stable continuous-batching serving over the block pool.
 
-One engine owns params + three jitted programs and drives them from a
-host-side scheduler tick loop:
+One engine owns params + ONE jitted program, ``mixed_step``, and drives
+it from a host-side scheduler tick loop: one dispatch a tick.  A packed
+ragged batch of prefill chunk slices and decode rows runs through a
+single layer scan that CARRIES the pool (flat over layer and block) and
+scatters every token's K/V into it in place (no temp prefill cache and
+no copy program — shared prefix blocks are attended in place through
+the block table; no slab sliced out of the pool, no copy of the pool
+back), and attends via ``ragged_paged_attention`` over the carried
+pool, the layer's offset added to the block tables.  Which attention
+runs is the kernel probe's verdict (ops/pallas/support.py): the Pallas
+kernel, or its XLA twin ``ragged_paged_attention_xla`` with a warning
+that says so.  No flag names a tick.
 
-- **prefill** — the chunked ragged prefill step from generate.py
-  (``make_ragged_prefill_step``): each admitted request is LEFT-padded to
-  a multiple of ``prefill_chunk`` and consumed in fixed-width chunks, so
-  every prefill dispatch reuses ONE compiled program regardless of
-  prompt length; the resulting contiguous K/V is scattered into the
-  request's pool blocks in one jitted copy.
-- **decode** — one program over the PACKED slot batch: gather each
-  slot's K/V through its block table ([B, MB] int32 → a contiguous
-  [L, B, S_max, K, D] view), run the standard forward at per-row offsets
-  (the batched-speculative cache discipline: ``length`` is an int32 [B]
-  vector), sample per-row (keys derived in-graph from per-request seeds
-  + content position, so a preempted request resumes its exact RNG
-  stream), then scatter the new token's K/V column back into the pool.
-  Every shape is static: batch = ``max_slots``, table width =
-  ``max_blocks_per_seq``, pool = ``num_blocks`` — ticks never recompile
-  (asserted by tools/compile_counter + tests).
-- **sample-after-prefill** — the first token's sampler call.
+Every shape is static: the step's operand is ONE packed int32 buffer
+(``mixed_operand_layout``), pool = ``num_blocks``, table width =
+``max_blocks_per_seq``.  Inactive lanes point their tables at the
+reserved scratch block 0 and are fully masked, so the step runs
+branchless; their outputs are discarded host-side.  Sampling happens
+in-graph (keys derived from per-request seeds + content position, so a
+preempted request resumes its exact RNG stream).
 
-Inactive slots point their tables at the reserved scratch block 0 and
-carry length 0, so the decode step runs branchless at full width; their
-outputs are discarded host-side.
-
-Decode attention impls (``decode_attn_impl``, gated by the hardware
-compile probes in ops/pallas/support.py with XLA as the fallback):
-
-- ``"xla"`` — the materialized-gather path above.
-- ``"flash_decode"`` — same gather, attention through the mask-driven
-  Pallas decode kernel.
-- ``"paged"`` — ZERO-GATHER: the per-layer scan threads the pool slabs
-  themselves and ops/pallas/decode_attention.paged_decode_attention
-  reads K/V straight through the scalar-prefetched block tables, so the
-  [L, B, S_max] view never materializes and per-token HBM traffic scales
-  with each row's visible blocks instead of the padded table width
-  (asserted structurally via jaxpr inspection in tests).  int8 pools
-  stream quantized blocks + scale pages through the kernel.
+The scheduler's token-budget planner (``Scheduler.plan_tick``)
+co-schedules chunked prefill with decode under ``tick_token_budget``
+tokens per tick — decode rows first, so a long prefill can no longer
+stall the decoding batch (the PR-5 trace finding).  The step's token
+axis is DENSE (one lane a token, ``dense_width``); the 8-lane query
+tiles the ragged kernel wants (``packed_width`` lanes) exist only inside
+attention, between two row gathers.  A program is the pair, and the set
+is small and fixed (``mixed_buckets``: today's doubling ladder of tile
+widths, each with ONE dense width — its capacity under the token budget
+— plus one program for the steady decode tick, ``max_slots`` one-tile
+rows at the width of their tokens), so the step compiles once per
+program and NEVER per tick, whatever the prefill:decode row mix
+(compile-counter lint), and warm-up pays for one program more than the
+ladder has rungs.  ``/metrics`` counts the dense lanes dispatched
+(``mixed_dense_lanes_total``) beside the tokens in them
+(``mixed_tokens_total``).
 
 Prefix sharing (``enable_prefix_cache``): at admission the prompt's
 fully-filled leading blocks are looked up in a refcounted registry
 (serve/prefix_cache.py); hits are claimed into the request's block table
-and their prefill chunks are SKIPPED — only the shared K/V is copied
-into the temp prefill cache so the remaining chunks attend correctly.
+and their prefill chunks are SKIPPED — they consume no tick budget and
+are attended in place.
 
 Mesh-sharded serving (``mesh_plan=MeshPlan(model=N)``): the engine
 builds a ``jax.sharding.Mesh`` over its device slice, tensor-parallels
 the params via ``parallel/sharding.param_specs`` and the pool slabs via
 ``paged_kv_specs`` (kv-head-partitioned K/V pages, int8 scale pages
 included), and commits every per-tick operand — block tables above all
-— FULLY REPLICATED, so the scalar-prefetch kernels walk per-shard-
-identical indices over their head-slice of the slabs.  With kv heads
-divisible by the model axis the Pallas ``ragged_paged_attention`` /
-``paged_decode_attention`` kernels run UNMODIFIED inside ``shard_map``
-(``_shard_attn``); otherwise (the TP+GQA hard part) the engine holds
-the partitionable XLA paths.  Step in-avals are pinned — replicated
-operands, ``normalize_specs``-spelled slab/temp-cache shardings,
+— FULLY REPLICATED, so the scalar-prefetch kernel walks per-shard-
+identical indices over its head-slice of the slabs.  With kv heads
+divisible by the model axis the Pallas ``ragged_paged_attention``
+kernel runs UNMODIFIED inside ``shard_map`` (``_shard_attn``);
+otherwise (the TP+GQA hard part) the engine holds the partitionable XLA
+path.  Step in-avals are pinned — replicated operands,
 ``with_sharding_constraint`` on every returned ``PagedKV`` — so each
 program still compiles once per shape bucket and NEVER per tick under
 the mesh.  The engine is TP-only by design; data parallelism is N
 engine replicas behind a prefix-affinity router (serve/replica.py),
 each on its own mesh slice.
 
-Unified tick (``mixed_step="auto"``, the default, or ``"on"``; the tick
-``cli serve`` serves): the phase-split pipeline above collapses into
-ONE jit-stable ``mixed_step`` dispatch per tick — a packed ragged
-batch of prefill chunk slices and decode rows runs through a single layer scan that CARRIES the pool (flat over layer and
-block) and scatters every token's K/V into it in place (NO temp prefill
-cache, NO ``gather_prefix`` copy program — shared prefix blocks are
-attended in place through the block table; no slab sliced out of the
-pool, no copy of the pool back), and attends via
-``ragged_paged_attention`` over the carried pool, the layer's offset
-added to the block tables (probe-gated; XLA gather fallback).  The
-scheduler's token-budget planner (``Scheduler.plan_tick``) co-schedules
-chunked prefill with decode under ``tick_token_budget`` tokens per tick
-— decode rows first, so a long prefill can no longer stall the decoding
-batch (the PR-5 trace finding).  The step's token axis is DENSE (one
-lane a token, ``dense_width``); the 8-lane query tiles the ragged kernel
-wants (``packed_width`` lanes) exist only inside attention, between two
-row gathers.  A program is the pair, and the set is small and fixed
-(``mixed_buckets``: today's doubling ladder of tile widths, each with
-ONE dense width — its capacity under the token budget — plus one
-program for the steady decode tick, ``max_slots`` one-tile rows at the
-width of their tokens), so the step compiles once per program and NEVER
-per tick, whatever the prefill:decode row mix (compile-counter lint),
-and warm-up pays for one program more than the ladder has rungs.
-``/metrics`` counts the dense lanes dispatched
-(``mixed_dense_lanes_total``) beside the tokens in them
-(``mixed_tokens_total``).
-
-Speculative serving (``spec_k=K``, unified tick only): per-request
-HOST-SIDE prompt-lookup draft streams (serve/spec.py) propose up to K
-tokens per tick, packed as ragged verify slices of width ≤ K+1 into the
-same one dispatch; the step samples at every packed position with the
-deterministic (seed, content-pos) keys, so the accept walk emits the
-longest draft prefix matching the samples plus the first correction —
-token-identical to plain decode, up to K+1 tokens per HBM sweep.
-Requests opt in per-submit (``speculative=True``) and fall back
-per-request when rolling acceptance collapses; the verify lanes are a
-static [slots, K+1] extension of the step, so zero-recompiles survives.
+Speculative serving (``spec_k=K``): per-request HOST-SIDE prompt-lookup
+draft streams (serve/spec.py) propose up to K tokens per tick, packed
+as ragged verify slices of width ≤ K+1 into the same one dispatch; the
+step samples at every packed position with the deterministic (seed,
+content-pos) keys, so the accept walk emits the longest draft prefix
+matching the samples plus the first correction — token-identical to
+plain decode, up to K+1 tokens per HBM sweep.  Requests opt in
+per-submit (``speculative=True``) and fall back per-request when
+rolling acceptance collapses; the verify lanes are a static
+[slots, K+1] extension of the step, so zero-recompiles survives.
 """
 
 from __future__ import annotations
@@ -117,9 +88,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from llm_np_cp_tpu.cache import KVCache, quantize_kv
 from llm_np_cp_tpu.config import ModelConfig
-from llm_np_cp_tpu.generate import IncrementalDetok, make_ragged_prefill_step
+from llm_np_cp_tpu.generate import IncrementalDetok
 from llm_np_cp_tpu.models.transformer import (
     SCOPE_ATTN_GLOBAL,
     SCOPE_ATTN_WINDOW,
@@ -133,7 +103,6 @@ from llm_np_cp_tpu.models.transformer import (
     experts_block,
     ff_block,
     final_logits,
-    forward,
     input_norm,
     latent_attention_block,
     run_decoder_layer,
@@ -146,6 +115,7 @@ from llm_np_cp_tpu.ops.activations import ACT2FN
 from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS, expert_row_tile
 from llm_np_cp_tpu.ops.rope import rope_cos_sin
 from llm_np_cp_tpu.ops.sampling import Sampler
+from llm_np_cp_tpu.quant import quantize_kv
 from llm_np_cp_tpu.serve.block_pool import (
     BlockPool,
     PagedKV,
@@ -162,10 +132,7 @@ from llm_np_cp_tpu.serve.scheduler import (
     Scheduler,
     TenantThrottled,
 )
-from llm_np_cp_tpu.serve.telemetry import (
-    mixed_tick_kv_read,
-    split_tick_kv_read,
-)
+from llm_np_cp_tpu.serve.telemetry import mixed_tick_kv_read
 from llm_np_cp_tpu.serve.tracing import TraceRecorder, gen_trace_id
 
 Params = dict[str, Any]
@@ -206,11 +173,10 @@ def _pack_sync(
     bitmask over those columns, ``W+1`` the advance watermark (tokens
     the accept walk will emit this tick, pre-budget: up to the first
     stop inside the accepted prefix, else accept+1), ``W+2`` the
-    accept length.  The split tick is the degenerate W=1 case
-    ([R, 4]: token, finished, watermark, accept).
+    accept length.
 
-    The deliver walk reads the token and accept columns; finish/budget
-    semantics stay host-side in ``_maybe_finish`` (one source of
+    The accept walk reads the token and accept columns; finish/budget
+    semantics stay host-side in ``_accept_finish`` (one source of
     truth), so the stop-mask and watermark columns are currently
     redundant with it — they ride along because the packed row IS the
     contract (a consumer that wants the tick outcome without replaying
@@ -424,7 +390,6 @@ class ServeEngine:
         max_seq_len: int = 1024,
         prefill_chunk: int | None = None,
         cache_dtype: jnp.dtype = jnp.bfloat16,
-        decode_attn_impl: str = "xla",
         enable_prefix_cache: bool = False,
         max_queue: int | None = None,
         tokenizer: Any = None,
@@ -449,14 +414,18 @@ class ServeEngine:
         spec_min_accept: float = 0.1,
         spec_window: int = 64,
     ) -> None:
-        if decode_attn_impl not in ("xla", "flash_decode", "paged"):
+        # ``mixed_step`` names no tick any more: there is one.  The
+        # keyword stays only until the three builder-run scripts under
+        # benchmark/ stop passing it (ROADMAP W8); "auto" and "on" are
+        # the same thing
+        if mixed_step == "off":
             raise ValueError(
-                f"decode_attn_impl must be 'xla', 'flash_decode' or "
-                f"'paged', got {decode_attn_impl!r}"
-            )
-        if mixed_step not in ("auto", "on", "off"):
+                "mixed_step 'off' named the phase-split tick, which PR 46 "
+                "deleted: the engine has one tick (the unified ragged "
+                "tick); drop the keyword")
+        if mixed_step not in ("auto", "on"):
             raise ValueError(
-                f"mixed_step must be 'auto', 'on' or 'off', got "
+                f"mixed_step must be 'auto' or 'on' (the same thing), got "
                 f"{mixed_step!r}"
             )
         if sample_epilogue not in ("auto", "on", "off"):
@@ -480,12 +449,6 @@ class ServeEngine:
             raise ValueError(
                 f"spec_ngram must be >= 2, got {spec_ngram}"
             )
-        if spec_k and mixed_step == "off":
-            raise ValueError(
-                "speculative serving (spec_k > 0) rides the unified "
-                "tick's batched verifier; it cannot run with "
-                "mixed_step='off'"
-            )
         if host_tier is not None and not enable_prefix_cache:
             raise ValueError(
                 "host_tier requires enable_prefix_cache=True: the tier "
@@ -508,8 +471,6 @@ class ServeEngine:
                 (mesh_plan is not None and mesh_plan.model > 1,
                  "--mesh model>1 (mesh_plan): the state and the mixer / "
                  "conv / expert weights have no sharding rule"),
-                (mixed_step == "off", "--mixed-step off (mixed_step): only "
-                 "the unified tick carries the state"),
             ]
             kind = "conv" if config.conv_layers else "state-space"
             for hit, why in refused:
@@ -534,8 +495,6 @@ class ServeEngine:
                 (mesh_plan is not None and mesh_plan.model > 1,
                  "--mesh model>1 (mesh_plan): a latent row has no head "
                  "axis to cut, and the expert share no sharding rule"),
-                (mixed_step == "off", "--mixed-step off (mixed_step): only "
-                 "the unified tick reads latent pages"),
             ]
             for hit, why in refused:
                 if hit:
@@ -561,8 +520,6 @@ class ServeEngine:
                 (mesh_plan is not None and mesh_plan.model > 1,
                  "--mesh model>1 (mesh_plan): two kv-head counts have no "
                  "sharding rule, nor has the expert share"),
-                (mixed_step == "off", "--mixed-step off (mixed_step): only "
-                 "the unified tick packs the second table"),
             ]
             for hit, why in refused:
                 if hit:
@@ -570,8 +527,7 @@ class ServeEngine:
                         f"model_type {config.model_type!r} keeps window "
                         f"layers in a page class of their own; refused: {why}")
         from llm_np_cp_tpu.ops.pallas.support import (
-            gate_attn_impl,
-            kernel_error,
+            kernel_or_warn,
             ragged_kernel_name,
         )
 
@@ -581,12 +537,6 @@ class ServeEngine:
         # weights on a mesh, allocating the pool
         t_build = tracer.now_us() if tracer is not None else -1.0
         int8_cache = jnp.dtype(cache_dtype) == jnp.int8
-        decode_attn_impl = gate_attn_impl(
-            decode_attn_impl, int8_cache=int8_cache
-        )
-        if tracer is not None:
-            tracer.complete("probe.decode_attn", t_build, cat="setup",
-                            args={"impl": decode_attn_impl})
         # -- mesh-sharded mode (ROADMAP item 1): params tensor-parallel
         # over "model" via param_specs, pool slabs kv-head-partitioned
         # via paged_kv_specs, block tables / per-tick operands committed
@@ -601,7 +551,6 @@ class ServeEngine:
         self.mesh = None
         self._rep_sharding = None
         self._pool_shardings = None
-        self._temp_cache_shardings = None
         self._kv_sharded = False
         # model=1 with an explicit device slice is the DP-without-TP
         # placement: a one-device mesh pins this replica's params, pool
@@ -612,10 +561,8 @@ class ServeEngine:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             from llm_np_cp_tpu.parallel.sharding import (
-                cache_specs,
                 kv_heads_shardable,
                 make_mesh,
-                normalize_specs,
                 paged_kv_specs,
                 shard_params,
                 to_shardings,
@@ -641,65 +588,21 @@ class ServeEngine:
                 self.mesh, paged_kv_specs(config, mesh_plan,
                                           quantized=int8_cache)
             )
-            self._temp_cache_shardings = to_shardings(
-                self.mesh, normalize_specs(
-                    cache_specs(config, mesh_plan, quantized=int8_cache)
-                )
-            )
-            if mesh_plan.model > 1 and decode_attn_impl == "flash_decode":
-                # the mask-driven decode kernel has no shard_map harness;
-                # under a real TP mesh GSPMD would replicate its custom
-                # call — worse than the partitionable gather math it
-                # wraps (a one-device placement mesh is unaffected)
-                decode_attn_impl = "xla"
-            if (
-                mesh_plan.model > 1
-                and decode_attn_impl == "paged"
-                and not self._kv_sharded
-            ):
-                # kv heads don't divide the model axis (TP + GQA hard
-                # part): the slabs are replicated and the shard_map
-                # harness (which splits the head axes) does not apply —
-                # the partitionable gather path is the honest impl
-                decode_attn_impl = "xla"
-        self.decode_attn_impl = decode_attn_impl  # post-gate (tests/CLI)
-        # -- unified-tick gate: "auto" — the default here and of the CLI,
-        # so an engine built with no ``mixed_step`` is the engine that
-        # is served — takes the unified tick when the ragged kernel
-        # probe passes (conservative: a broken Mosaic toolchain keeps
-        # the phase-split path), "on" forces it (XLA ragged fallback if
-        # Mosaic rejects the kernel), "off" is the phase-split engine
-        self.ragged_attn_impl: str | None = None
-        if mixed_step == "off":
-            self.mixed = False
-        else:
-            t_probe = tracer.now_us() if tracer is not None else -1.0
-            err = kernel_error(
-                ragged_kernel_name(int8_cache, latent=config.is_latent))
-            if tracer is not None:
-                tracer.complete("probe.ragged_attn", t_probe, cat="setup",
-                                args={"ok": err is None})
-            if err is None:
-                self.mixed, self.ragged_attn_impl = True, "pallas"
-            elif mixed_step == "on":
-                import logging
-
-                logging.getLogger("llm_np_cp_tpu").warning(
-                    "mixed_step='on' with the ragged kernel unavailable "
-                    "(%s); the unified tick will use the XLA gather "
-                    "fallback attention", err,
-                )
-                self.mixed, self.ragged_attn_impl = True, "xla"
-            else:
-                self.mixed = False
-        if (config.carries_state or config.is_latent
-                or config.two_page_classes) and not self.mixed:
-            raise ValueError(
-                f"model_type {config.model_type!r} (a recurrent state, a "
-                "latent cache or two page classes) is served by the unified "
-                "tick only, which is "
-                f"unavailable here ({err}); --mixed-step on takes its XLA "
-                "attention")
+        # -- which attention the tick runs: the ragged Pallas kernel where
+        # its probe passes, else its XLA twin (said once a process, by a
+        # warning) — chosen from what the probe observes, as
+        # ``expert_row_tile`` and ``state_update_heads`` choose theirs.
+        # ``mixed`` stays as an attribute because scripts read it
+        # (chip_smoke.py, benchmark/tick_memory.py); it is always True
+        self.mixed = True
+        t_probe = tracer.now_us() if tracer is not None else -1.0
+        err = kernel_or_warn(
+            ragged_kernel_name(int8_cache, latent=config.is_latent),
+            "ragged_paged_attention_xla in the unified tick")
+        if tracer is not None:
+            tracer.complete("probe.ragged_attn", t_probe, cat="setup",
+                            args={"ok": err is None})
+        self.ragged_attn_impl = "pallas" if err is None else "xla"
         # -- speculative serving (draft-then-verify in the unified tick):
         # per-request host-side prompt-lookup draft streams propose up to
         # spec_k tokens; the mixed step packs each speculating request as
@@ -711,19 +614,6 @@ class ServeEngine:
         # operands), so it is an engine build parameter; requests opt in
         # per-submit and fall back per-request when rolling acceptance
         # collapses.
-        if spec_k and not self.mixed:
-            # mixed_step='auto' resolved to the phase-split engine (the
-            # ragged probe failed): speculation has no verifier to ride —
-            # serve plain rather than fail, and say so
-            import logging
-
-            logging.getLogger("llm_np_cp_tpu").warning(
-                "spec_k=%d requested but the unified tick is unavailable "
-                "(ragged kernel probe failed under mixed_step='auto'); "
-                "speculative serving disabled, requests decode plain",
-                spec_k,
-            )
-            spec_k = 0
         self.spec_k = spec_k
         self.spec_ngram = spec_ngram
         self.spec_min_accept = spec_min_accept
@@ -733,7 +623,7 @@ class ServeEngine:
         # after recovery from prompt + generated
         self._draft_states: dict[int, Any] = {}
         if (
-            self.mixed and self.mesh is not None
+            self.mesh is not None
             and self.mesh_plan.model > 1 and not self._kv_sharded
         ):
             # replicated kv heads under real TP: no shard_map harness for
@@ -793,8 +683,8 @@ class ServeEngine:
                 f"weights_version must be >= 0, got {weights_version}"
             )
         self.weights_version = int(weights_version)
-        # reason string once the paged decode step faulted at dispatch
-        # and the engine fell back to the gather impl (None = healthy)
+        # reason string once the tick faulted at dispatch and fell back
+        # to its XLA twins (``_degrade_mixed``; None = healthy)
         self.decode_degraded: str | None = None
         self.params = params
         self.config = config
@@ -992,62 +882,49 @@ class ServeEngine:
                 tracer.complete(
                     "probe.ssm_state_update", t_probe, cat="setup",
                     args={"ok": self.ssm_state_impl == "pallas"})
-        if self.mixed:
-            # -- unified tick: ONE jitted program, bucketed packed width.
-            # The temp prefill cache, scatter_prefill, gather_prefix and
-            # sample_first programs of the phase-split path do not exist
-            # in this mode — prefill K/V goes straight into pool blocks
-            # and sampling happens inside the mixed step.
-            from llm_np_cp_tpu.ops.pallas.decode_attention import (
-                RAGGED_Q_TILE,
-            )
+        # -- the tick: ONE jitted program, bucketed packed width —
+        # prefill K/V goes straight into pool blocks and sampling
+        # happens inside the mixed step.
+        from llm_np_cp_tpu.ops.pallas.decode_attention import (
+            RAGGED_Q_TILE,
+        )
 
-            self._q_tile = RAGGED_Q_TILE
-            # verify-lane width of the compiled step: every row carries
-            # spec_k+1 sample slots ([R, W] last_idx/sample_pos operands
-            # and an [R, W] token return) — plain rows use column 0 and
-            # the rest are discarded host-side, so the shape is static
-            # whatever each tick's draft widths turn out to be
-            self._spec_w = self.spec_k + 1
-            # what lays the step's packed operand out beside its two
-            # widths (mixed_operand_layout)
-            self._mixed_geometry = (
-                self._q_tile, max_slots, self.max_blocks_per_seq,
-                self._spec_w,
-            ) + ((self.window_blocks,) if self.window_blocks else ())
-            # spec engines get verify headroom in the default budget:
-            # drafts only ever spend budget prefill left over, so
-            # without the extra room a busy admission window would trim
-            # every draft to nothing and speculation would never engage
-            budget = tick_token_budget or (
-                max_slots * (1 + self.spec_k) + 2 * self.prefill_chunk
+        self._q_tile = RAGGED_Q_TILE
+        # verify-lane width of the compiled step: every row carries
+        # spec_k+1 sample slots ([R, W] last_idx/sample_pos operands
+        # and an [R, W] token return) — plain rows use column 0 and
+        # the rest are discarded host-side, so the shape is static
+        # whatever each tick's draft widths turn out to be
+        self._spec_w = self.spec_k + 1
+        # what lays the step's packed operand out beside its two
+        # widths (mixed_operand_layout)
+        self._mixed_geometry = (
+            self._q_tile, max_slots, self.max_blocks_per_seq,
+            self._spec_w,
+        ) + ((self.window_blocks,) if self.window_blocks else ())
+        # spec engines get verify headroom in the default budget:
+        # drafts only ever spend budget prefill left over, so
+        # without the extra room a busy admission window would trim
+        # every draft to nothing and speculation would never engage
+        budget = tick_token_budget or (
+            max_slots * (1 + self.spec_k) + 2 * self.prefill_chunk
+        )
+        if budget < max_slots:
+            raise ValueError(
+                f"tick_token_budget ({budget}) must be >= max_slots "
+                f"({max_slots}): every decode row needs one token per "
+                "tick before prefill fills the remainder"
             )
-            if budget < max_slots:
-                raise ValueError(
-                    f"tick_token_budget ({budget}) must be >= max_slots "
-                    f"({max_slots}): every decode row needs one token per "
-                    "tick before prefill fills the remainder"
-                )
-            self.tick_token_budget = budget
-            self.mixed_buckets = self._make_buckets(budget, max_slots)
-            # stated once a program: the packer looks its layout up
-            self._mixed_layouts = {
-                p: mixed_operand_layout(*p, *self._mixed_geometry)
-                for p in self.mixed_buckets}
-            self._mixed_step = self._make_mixed_step()
-        else:
-            self.tick_token_budget = 0
-            self.mixed_buckets: tuple[tuple[int, int], ...] = ()
-            self.pool_carried = False  # (the split tick's scan takes slabs)
-            # -- jitted programs (fixed set; tick loop never adds more)
-            self._prefill_step = make_ragged_prefill_step(config)
-            self._decode_step = self._make_decode_step(decode_attn_impl)
-            self._sample_first = self._make_sample_first()
-            self._scatter_prefill = self._make_scatter_prefill()
-            self._gather_prefix = self._make_gather_prefix()
-        # one-fetch ledger, initialized after the step builders: the
-        # tick loops bump it at their single packed host_sync transfer
-        # and the tick trace args carry the per-tick count
+        self.tick_token_budget = budget
+        self.mixed_buckets = self._make_buckets(budget, max_slots)
+        # stated once a program: the packer looks its layout up
+        self._mixed_layouts = {
+            p: mixed_operand_layout(*p, *self._mixed_geometry)
+            for p in self.mixed_buckets}
+        self._mixed_step = self._make_mixed_step()
+        # one-fetch ledger, initialized after the step builder: the
+        # tick bumps it at its single packed host_sync transfer and the
+        # tick trace args carry the per-tick count
         self.n_host_fetches = 0
         # mixed dispatches so far: the number a dispatch carries everywhere
         # (tick arg ``seq`` of its tick, metadata of its
@@ -1056,7 +933,7 @@ class ServeEngine:
         self.n_mixed_dispatches = 0
         if tracer is not None:
             tracer.complete("engine_build", t_build, cat="setup", args={
-                "tick": "unified" if self.mixed else "split",
+                "tick": "unified",
                 "buckets": len(self.mixed_buckets),
                 # how the tick's layer loop holds the pool (1: flat over
                 # (layer, block), written in place; 0: by layer slabs)
@@ -1230,24 +1107,6 @@ class ServeEngine:
             lax.with_sharding_constraint, pages._replace(state=None),
             self._pool_shardings)._replace(state=pages.state)
 
-    def _make_temp_cache(self) -> KVCache:
-        cache = KVCache.init(self.config, 1, self.max_seq_len,
-                             dtype=self.cache_dtype)
-        if self._temp_cache_shardings is not None:
-            cache = jax.tree.map(jax.device_put, cache,
-                                 self._temp_cache_shardings)
-        return cache
-
-    def _repin_temp_cache(self, cache: KVCache) -> KVCache:
-        """Re-commit a chunk-step output cache to the pinned temp-cache
-        shardings (a no-op transfer when GSPMD already kept them): every
-        ``prefill_step`` call must see identical in-avals or its
-        ONE-compile contract breaks on the second chunk."""
-        if self._temp_cache_shardings is None:
-            return cache
-        return jax.tree.map(jax.device_put, cache,
-                            self._temp_cache_shardings)
-
     def _shard_attn(self, fn: Callable, *, quantized: bool, n_meta: int,
                     q_head_axis: int) -> Callable:
         """Wrap a per-layer paged-attention callable for the mesh.
@@ -1413,29 +1272,15 @@ class ServeEngine:
 
     def compile_counts(self) -> dict[str, int]:
         """Compiled-program count per jitted step (the static-shape
-        contract: decode/prefill/sample stay at 1; scatter grows once per
-        distinct prefill block count).  tools/compile_counter.py wraps
-        this for the CI check.
-
-        Unified-tick engines report ONE program — ``mixed_step``, one
-        compile per packed-width bucket — and none of the phase-split
-        programs exist (the ``gather_prefix`` copy in particular is
-        deleted, pinned by the lint)."""
+        contract).  The tick is ONE step — ``mixed_step``, one compile
+        per program of ``mixed_buckets`` and never one per tick.
+        tools/compile_counter.py wraps this for the CI check."""
 
         def size(fn: Any) -> int:
             get = getattr(fn, "_cache_size", None)
             return int(get()) if get is not None else -1
 
-        if self.mixed:
-            out = {"mixed_step": size(self._mixed_step)}
-        else:
-            out = {
-                "prefill_step": size(self._prefill_step),
-                "decode_step": size(self._decode_step),
-                "sample_first": size(self._sample_first),
-                "scatter_prefill": size(self._scatter_prefill),
-                "gather_prefix": size(self._gather_prefix),
-            }
+        out = {"mixed_step": size(self._mixed_step)}
         if self._restore_block is not None:
             # the host tier's two programs: block id is traced and the
             # staged/sliced layout fixed, so each must stay at ONE
@@ -1447,106 +1292,6 @@ class ServeEngine:
     # ------------------------------------------------------------------
     # Jitted step builders
     # ------------------------------------------------------------------
-    def _make_sample_first(self) -> Callable:
-        sampler = self.sampler
-
-        @jax.jit
-        def sample_first(logits: jnp.ndarray, seed: jnp.ndarray, pos: jnp.ndarray):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
-            return sampler(key, logits)
-
-        return sample_first
-
-    def _make_scatter_prefill(self) -> Callable:
-        quantized = self.cache_dtype == jnp.int8
-        bs = self.block_size
-        constrain_pages = self._constrain_pages
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def scatter_prefill(
-            pages: PagedKV, cache: KVCache, ids: jnp.ndarray,
-            start: jnp.ndarray,
-        ):
-            # cache: batch-1 contiguous prefill cache at the FIXED temp
-            # capacity (max_seq_len); the nb*bs slots from block offset
-            # ``start`` (traced — prefix hits shift it without a
-            # retrace) hold this request's freshly prefilled content.
-            # Shared prefix blocks before ``start`` are NEVER written.
-            nb = ids.shape[0]
-
-            def put(slab, page):  # slab [L, 1, max_seq_len, *t]
-                # the fresh slots as the pool holds a token (``[K, D]``,
-                # merged ``[K * D]``, a scale page's ``[K]``): the value
-                # is reshaped, never the pool
-                l = slab.shape[0]
-                fresh = lax.dynamic_slice_in_dim(slab, start * bs, nb * bs, 1)
-                return page.at[:, ids].set(
-                    fresh.reshape((l, nb, bs) + page.shape[3:])
-                )
-
-            new = pages._replace(
-                k=put(cache.k[:, 0], pages.k),
-                v=put(cache.v[:, 0], pages.v),
-                k_scale=(
-                    put(cache.k_scale[:, 0], pages.k_scale)
-                    if quantized else None
-                ),
-                v_scale=(
-                    put(cache.v_scale[:, 0], pages.v_scale)
-                    if quantized else None
-                ),
-            )
-            return constrain_pages(new)
-
-        return scatter_prefill
-
-    def _make_gather_prefix(self) -> Callable:
-        """(temp cache, pages, shared ids [H], pad) → temp cache with the
-        shared blocks' K/V copied into slots [0, H*bs) and validity/
-        length restored — the state a full prefill of those chunks would
-        have left, so the remaining chunks attend correctly.  One small
-        copy program per distinct shared-block count (same compile class
-        as the scatter), instead of re-running the model over the shared
-        chunks."""
-        quantized = self.cache_dtype == jnp.int8
-        bs = self.block_size
-        cap = self.max_seq_len
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def gather_prefix(
-            cache: KVCache, pages: PagedKV, ids: jnp.ndarray,
-            pad: jnp.ndarray,
-        ):
-            h = ids.shape[0]
-            l = pages.k.shape[0]
-
-            def get(page, trailing):  # [L, NB, bs, *t] → [L, 1, h*bs, *t]
-                return page[:, ids].reshape((l, 1, h * bs) + trailing)
-
-            def put(slab, page, trailing):
-                return slab.at[:, :, : h * bs].set(get(page, trailing))
-
-            # (the gathered view takes the cache's form, not the pool)
-            kh, d = pages.kv_heads, pages.head_dim
-            pos = jnp.arange(cap, dtype=jnp.int32)[None, :]
-            valid = (pos >= pad) & (pos < h * bs)
-            return KVCache(
-                k=put(cache.k, pages.k, (kh, d)),
-                v=put(cache.v, pages.v, (kh, d)),
-                valid=valid,
-                length=jnp.full((), h * bs, jnp.int32),
-                k_scale=(
-                    put(cache.k_scale, pages.k_scale, (kh,))
-                    if quantized else None
-                ),
-                v_scale=(
-                    put(cache.v_scale, pages.v_scale, (kh,))
-                    if quantized else None
-                ),
-            )
-
-        return gather_prefix
-
     def _make_restore_block(self) -> Callable:
         """(pages, blk, k, v[, ks, vs]) → pages with one staged
         host-tier block written at pool block ``blk`` — the landing
@@ -1796,280 +1541,6 @@ class ServeEngine:
                 n += 1
         return n
 
-    def _make_decode_step(self, attn_impl: str) -> Callable:
-        if attn_impl == "paged":
-            return self._make_paged_decode_step()
-        config, sampler = self.config, self.sampler
-        bs = self.block_size
-        quantized = self.cache_dtype == jnp.int8
-        use_epilogue = self.epilogue_impl == "fused"
-        stop_tokens = self.stop_tokens
-        constrain_pages = self._constrain_pages
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def decode_step(
-            params: Params,
-            pages: PagedKV,
-            tables: jnp.ndarray,   # [B, MB] int32 (scratch-0 padded)
-            lengths: jnp.ndarray,  # [B] int32 — cache slots already written
-            pads: jnp.ndarray,     # [B] int32 — left pads per row
-            toks: jnp.ndarray,     # [B] int32 — current input token
-            seeds: jnp.ndarray,    # [B] uint32 — per-request RNG seed
-        ):
-            l_axis, b = pages.k.shape[0], tables.shape[0]
-            kh, d = pages.kv_heads, pages.head_dim
-            s_max = tables.shape[1] * bs
-
-            def gather(page, trailing):  # [L, NB, bs, *t] → [L, B, S_max, *t]
-                return page[:, tables].reshape((l_axis, b, s_max) + trailing)
-
-            pos = jnp.arange(s_max, dtype=jnp.int32)[None, :]
-            valid = (pos >= pads[:, None]) & (pos < lengths[:, None])
-            cache = KVCache(
-                k=gather(pages.k, (kh, d)),
-                v=gather(pages.v, (kh, d)),
-                valid=valid,
-                length=lengths,
-                k_scale=gather(pages.k_scale, (kh,)) if quantized else None,
-                v_scale=gather(pages.v_scale, (kh,)) if quantized else None,
-            )
-            content_pos = lengths - pads
-            if use_epilogue:
-                # fused tail (greedy-exact — see _make_mixed_step): the
-                # [B, 1, V] logits never materialize
-                from llm_np_cp_tpu.models.transformer import (
-                    sample_epilogue_tail,
-                )
-
-                hid, cache = forward(
-                    params, toks[:, None], config, cache,
-                    logits_last_only=True, pad_offsets=pads,
-                    attn_impl=attn_impl, skip_logits=True,
-                )
-                nxt = sample_epilogue_tail(params, hid[:, -1], config)
-            else:
-                logits, cache = forward(
-                    params, toks[:, None], config, cache,
-                    logits_last_only=True, pad_offsets=pads,
-                    attn_impl=attn_impl,
-                )
-                # Per-row keys from (request seed, content position): a
-                # request resumed after preemption replays the same
-                # stream, so stochastic samplers are
-                # preemption-transparent too.
-                keys = jax.vmap(
-                    lambda s, t: jax.random.fold_in(
-                        jax.random.PRNGKey(s), t
-                    )
-                )(seeds, content_pos)
-                nxt = jax.vmap(lambda k, lg: sampler(k, lg[None])[0])(
-                    keys, logits[:, -1]
-                )
-
-            # Extract the newly written K/V column (slot ``lengths`` per
-            # row) from the gathered view and scatter it into the pool.
-            def col(slab, page):
-                # [L, B, S_max, ...] → [L, B, ...] at per-row offset, as
-                # the pool holds a token (the column reshaped, not the pool)
-                new = jax.vmap(
-                    lambda sl, off: lax.dynamic_index_in_dim(
-                        sl, off, axis=1, keepdims=False
-                    ),
-                    in_axes=(1, 0), out_axes=1,
-                )(slab, lengths)
-                return new.reshape(new.shape[:2] + page.shape[3:])
-
-            blk = jnp.take_along_axis(tables, (lengths // bs)[:, None], axis=1)[:, 0]
-            off = lengths % bs
-            # inactive rows all hit (scratch block 0, slot 0); duplicate
-            # scatter indices there are harmless — the data is garbage by
-            # construction and never gathered through a real table
-            def write(page, slab):
-                return page.at[:, blk, off].set(col(slab, page))
-
-            new_pages = pages._replace(
-                k=write(pages.k, cache.k),
-                v=write(pages.v, cache.v),
-                k_scale=(write(pages.k_scale, cache.k_scale)
-                         if quantized else None),
-                v_scale=(write(pages.v_scale, cache.v_scale)
-                         if quantized else None),
-            )
-            # one-fetch contract, W=1 degenerate case: [B, 4] packed
-            # (token, stop-hit, watermark, accept)
-            packed = _pack_sync(
-                nxt[:, None], _stop_hits(nxt[:, None], stop_tokens),
-                jnp.zeros_like(nxt),
-            )
-            return packed, constrain_pages(new_pages)
-
-        return decode_step
-
-    def _make_paged_decode_step(self) -> Callable:
-        """The zero-gather decode step: the layer scan threads the pool
-        slabs themselves ([L, NB, BS, K, D] xs), each layer scatters the
-        new token's K/V column straight into its slab and attends with
-        ``paged_decode_attention`` through the scalar-prefetched block
-        tables — no [L, B, S_max] view ever materializes (pinned by a
-        jaxpr-inspection test).  Shapes are identical to the gather
-        step's host contract, so the tick loop is impl-agnostic."""
-        from llm_np_cp_tpu.ops.pallas.decode_attention import (
-            paged_decode_attention,
-        )
-
-        config, sampler = self.config, self.sampler
-        bs = self.block_size
-        quantized = self.cache_dtype == jnp.int8
-        win = config.sliding_window
-        num_layers = config.num_hidden_layers
-        use_epilogue = self.epilogue_impl == "fused"
-        stop_tokens = self.stop_tokens
-        constrain_pages = self._constrain_pages
-        merged = self.pool.pages.merged
-        attn_call = self._shard_attn(
-            partial(
-                paged_decode_attention,
-                scale=config.attn_scale,
-                logit_softcap=config.attn_logit_softcapping,
-            ),
-            quantized=quantized, n_meta=3, q_head_axis=2,
-        )
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def decode_step(
-            params: Params,
-            pages: PagedKV,
-            tables: jnp.ndarray,   # [B, MB] int32 (scratch-0 padded)
-            lengths: jnp.ndarray,  # [B] int32 — cache slots already written
-            pads: jnp.ndarray,     # [B] int32 — left pads per row
-            toks: jnp.ndarray,     # [B] int32 — current input token
-            seeds: jnp.ndarray,    # [B] uint32 — per-request RNG seed
-        ):
-            # this tick writes slot ``lengths`` per row; attention then
-            # sees slots [pads, lengths+1) — causality is positional
-            # (the query IS the newest token), so no mask tensor exists
-            blk = jnp.take_along_axis(
-                tables, (lengths // bs)[:, None], axis=1
-            )[:, 0]
-            off = lengths % bs
-            vis = lengths + 1
-            content_pos = lengths - pads
-
-            x = embed_inputs(params, toks[:, None], config)
-            cos, sin = rope_cos_sin(
-                content_pos[:, None], config, dtype=jnp.float32
-            )
-            act = ACT2FN[config.hidden_act]
-            is_sliding = jnp.array(
-                [config.layer_is_sliding(i) for i in range(num_layers)],
-                dtype=jnp.bool_,
-            )
-
-            def layer_step(x: jnp.ndarray, xs: tuple) -> tuple:
-                if quantized:
-                    w, kp, vp, ksp, vsp, sliding = xs
-                else:
-                    w, kp, vp, sliding = xs
-
-                def kv_update(k, v):  # fresh projections [B, 1, K, D]
-                    # inactive rows all write (scratch block 0, slot 0);
-                    # duplicate scatter indices there are harmless —
-                    # garbage by construction, never visible
-                    if quantized:
-                        kq, ks = quantize_kv(k)
-                        vq, vs = quantize_kv(v)
-                        return (
-                            (kp.at[blk, off].set(kq[:, 0]),
-                             ksp.at[blk, off].set(ks[:, 0])),
-                            (vp.at[blk, off].set(vq[:, 0]),
-                             vsp.at[blk, off].set(vs[:, 0])),
-                        )
-                    # explicit cast: f32 activations into a bf16 pool
-                    # is the intended rounding, not an implicit promotion
-                    # (and the column in the form the pool holds a token)
-                    def put(page, val):
-                        return page.at[blk, off].set(
-                            val[:, 0].astype(page.dtype).reshape(
-                                val.shape[:1] + page.shape[2:]))
-
-                    return put(kp, k), put(vp, v)
-
-                def attn_fn(q, k_att, v_att, sliding_l):
-                    if quantized:
-                        (kp2, ksp2), (vp2, vsp2) = k_att, v_att
-                    else:
-                        kp2, vp2 = k_att, v_att
-                        ksp2 = vsp2 = None
-                    row_pads = pads
-                    if win is not None:
-                        # the single query sits at slot ``vis - 1``; a
-                        # sliding layer sees slots > vis-1-win, i.e. an
-                        # effective left pad of vis - win
-                        row_pads = jnp.where(
-                            sliding_l, jnp.maximum(pads, vis - win), pads
-                        )
-                    scales = (ksp2, vsp2) if quantized else ()
-                    if merged:
-                        # this kernel reads ``[NB, BS, K, D]`` pages: a
-                        # merged slab is handed over in that form (a
-                        # relayout a layer on a TPU; the unified tick's
-                        # kernel reads merged pages as they lie)
-                        kp2, vp2 = (
-                            a.reshape(a.shape[:2] + (
-                                config.num_key_value_heads, config.head_dim))
-                            for a in (kp2, vp2))
-                    return attn_call(
-                        q, kp2, vp2, *scales, tables, vis, row_pads,
-                    )
-
-                x, kv_att, _, _ = run_decoder_layer(
-                    w, x, config=config, act=act, cos=cos, sin=sin,
-                    sliding=sliding, kv_update=kv_update, attn_fn=attn_fn,
-                )
-                if quantized:
-                    (kp2, ksp2), (vp2, vsp2) = kv_att
-                    return x, (kp2, vp2, ksp2, vsp2)
-                return x, kv_att
-
-            xs: tuple = (params["layers"], pages.k, pages.v)
-            if quantized:
-                xs += (pages.k_scale, pages.v_scale)
-            xs += (is_sliding,)
-            x, ys = lax.scan(layer_step, x, xs, unroll=scan_unroll(config))
-            new_pages = pages._replace(
-                k=ys[0], v=ys[1],
-                k_scale=ys[2] if quantized else None,
-                v_scale=ys[3] if quantized else None,
-            )
-            new_pages = constrain_pages(new_pages)
-            if use_epilogue:
-                # fused tail (greedy-exact — see _make_mixed_step)
-                from llm_np_cp_tpu.models.transformer import (
-                    sample_epilogue_tail,
-                )
-
-                nxt = sample_epilogue_tail(params, x[:, -1], config)
-            else:
-                logits = final_logits(params, x, config, last_only=True)
-                # same (seed, content position) key derivation as the
-                # gather step — the RNG stream is impl- and
-                # preemption-invariant
-                keys = jax.vmap(
-                    lambda s, t: jax.random.fold_in(
-                        jax.random.PRNGKey(s), t
-                    )
-                )(seeds, content_pos)
-                nxt = jax.vmap(lambda k, lg: sampler(k, lg[None])[0])(
-                    keys, logits[:, -1]
-                )
-            packed = _pack_sync(
-                nxt[:, None], _stop_hits(nxt[:, None], stop_tokens),
-                jnp.zeros_like(nxt),
-            )
-            return packed, new_pages
-
-        return decode_step
-
     def _make_mixed_step(self) -> Callable:
         """The unified-tick program: ONE dispatch runs a packed ragged
         batch of prefill chunk slices (q_len up to ``prefill_chunk``)
@@ -2086,12 +1557,11 @@ class ServeEngine:
         device does not keep row-major (``_pool_is_row_major``: int8
         pages and their scale pages), which goes through as ``xs`` /
         ``ys`` slabs (a hybrid stack: whole, written at ``[layer, block,
-        slot]``).  No temp prefill cache, no
-        ``gather_prefix`` copy (shared prefix blocks are read in place),
+        slot]``).  Shared prefix blocks are read in place, and there is
         no separate sample dispatch (logits are gathered at each row's
-        last packed token and sampled in-graph with the SAME
-        (seed, content position) key derivation as both split-path
-        samplers, so tokens are impl- and preemption-invariant).
+        last packed token and sampled in-graph with keys derived from
+        (seed, content position), so tokens are impl- and
+        preemption-invariant).
 
         The step's token axis is DENSE: one lane a token, ``D`` wide
         from the embedding through qkv, the K/V scatter, o_proj and the
@@ -2982,18 +2452,12 @@ class ServeEngine:
             max_seq_len=self.max_seq_len,
             prefill_chunk=self.prefill_chunk,
             cache_dtype=self.cache_dtype,
-            decode_attn_impl=self.decode_attn_impl,
             enable_prefix_cache=self.pool.prefix_cache is not None,
             max_queue=self.scheduler.max_queue,
             tokenizer=self.tokenizer,
             clock=self.clock,
             fault_injector=self.faults,
             tracer=self.tracer,
-            # the tick this engine RESOLVED to, not the mode it was asked
-            # for: after a runtime degradation (disable_kernel) "auto"
-            # would re-probe, fail, and rebuild a unified engine as the
-            # phase-split one — five cold programs in mid-traffic
-            mixed_step="on" if self.mixed else "off",
             sample_epilogue=self.sample_epilogue_mode,
             tick_token_budget=self.tick_token_budget or None,
             mesh_plan=self.mesh_plan,
@@ -3025,30 +2489,14 @@ class ServeEngine:
             # and identical geometry means identical tier jaxprs
             eng._restore_block = self._restore_block
             eng._slice_block = self._slice_block
-        if self.mixed:
-            if (
-                eng.mixed
-                and eng.ragged_attn_impl == self.ragged_attn_impl
-                and eng.epilogue_impl == self.epilogue_impl
-            ):
-                # same resolution → identical jaxpr; a runtime-degraded
-                # process (disable_kernel) rebuilds on the XLA fallback
-                # and compiles it once there, not per restart
-                eng._mixed_step = self._mixed_step
-            return eng
-        names = ["_prefill_step", "_sample_first", "_scatter_prefill",
-                 "_gather_prefix"]
         if (
-            eng.decode_attn_impl == self.decode_attn_impl
+            eng.ragged_attn_impl == self.ragged_attn_impl
             and eng.epilogue_impl == self.epilogue_impl
         ):
-            # the gate can downgrade the clone (e.g. the paged kernel was
-            # runtime-disabled between builds) — share the decode step
-            # only when both engines resolved to the same impls (the
-            # attention AND the sampling epilogue live in its jaxpr)
-            names.append("_decode_step")
-        for name in names:
-            setattr(eng, name, getattr(self, name))
+            # same resolution → identical jaxpr; a runtime-degraded
+            # process (disable_kernel) rebuilds on the XLA fallback
+            # and compiles it once there, not per restart
+            eng._mixed_step = self._mixed_step
         return eng
 
     def share_compiled_steps(self, src: "ServeEngine") -> None:
@@ -3072,18 +2520,9 @@ class ServeEngine:
                 and src._restore_block is not None:
             self._restore_block = src._restore_block
             self._slice_block = src._slice_block
-        if self.mixed and src.mixed \
-                and self.ragged_attn_impl == src.ragged_attn_impl \
+        if self.ragged_attn_impl == src.ragged_attn_impl \
                 and self.epilogue_impl == src.epilogue_impl:
             self._mixed_step = src._mixed_step
-            return
-        if not self.mixed and not src.mixed:
-            for name in ("_prefill_step", "_sample_first",
-                         "_scatter_prefill", "_gather_prefix"):
-                setattr(self, name, getattr(src, name))
-            if self.decode_attn_impl == src.decode_attn_impl \
-                    and self.epilogue_impl == src.epilogue_impl:
-                self._decode_step = src._decode_step
 
     def _same_placement(self, src: "ServeEngine") -> bool:
         """Do both engines place params/pool/operands on the same
@@ -3199,8 +2638,7 @@ class ServeEngine:
     # ``req.generated``, the timestamps, finish decided and the slot and
     # blocks released); ``publish`` is what the outside is handed
     # (metrics, detokenizer, callbacks, request log, tracer, journal).
-    # The split tick does both at once (``_emit`` / ``_maybe_finish``);
-    # the unified tick accepts tick N and publishes it behind tick N+1's
+    # The tick accepts tick N and publishes it behind tick N+1's
     # dispatch (``_accept`` → ``_owed`` → ``_publish``).
 
     def _accept_token(self, req: Request, token: int) -> None:
@@ -3254,11 +2692,6 @@ class ServeEngine:
                                     args=self._targs(req))
         self._emit_event(req, reason)
 
-    def _emit(self, req: Request, token: int) -> None:
-        """The split tick's emit: accepted and published at once."""
-        self._accept_token(req, int(token))
-        self._publish_token(req, int(token))
-
     def _emit_event(self, req: Request, event: str) -> None:
         if req.on_event is not None:
             req.on_event(req, event)
@@ -3272,18 +2705,6 @@ class ServeEngine:
             tail = detok.flush()
             if tail:
                 req.extra["final_text_delta"] = tail
-
-    def _maybe_finish(self, req: Request) -> bool:
-        """The split tick's finish check, published at once."""
-        if req.state is not RequestState.RUNNING:
-            # aborted out from under us (e.g. from a token callback) —
-            # already unwound, nothing left to finish
-            return True
-        reason = self._accept_finish(req)
-        if reason is None:
-            return False
-        self._publish_finish(req, reason)
-        return True
 
     def _accept(self, req: Request, token: int,
                 kind: int = _OWED_TOKEN) -> bool:
@@ -3355,8 +2776,7 @@ class ServeEngine:
 
     def _settle_owed(self, req: Request) -> None:
         """What an aborted request is still owed, settled before its
-        ``aborted`` event.  Between ticks the tokens go out first, as
-        they would have by now on a tick that publishes at once.  From
+        ``aborted`` event.  Between ticks the tokens go out first.  From
         inside a token callback (a publish is running) they are dropped
         and taken back out of ``req.generated``: the ``aborted`` event
         follows the token whose callback asked for it.  Either way
@@ -3457,330 +2877,29 @@ class ServeEngine:
         return sorted(running, key=lambda r: share.get(r.tenant, 0.0))
 
     # ------------------------------------------------------------------
-    def _prefill_request(self, req: Request) -> None:
-        """Chunked ragged prefill into a temp contiguous cache, scatter
-        into the request's blocks, sample + emit the first token.
-
-        Prefix-cache hits (``req.n_shared_blocks`` leading blocks claimed
-        at admission) SKIP their prefill chunks entirely: the shared K/V
-        is copied from the pool into the temp cache (bit-identical to
-        what those chunks would have computed — a slot's K/V depends only
-        on its token and position) and the remaining chunks run from that
-        offset.  Only the fresh blocks are scattered back; shared blocks
-        are never written."""
-        if self.faults is not None and self.faults.trip("prefill") is not None:
-            raise FaultInjected("prefill")
-        # host-tier hits land FIRST: the claimed blocks must hold real
-        # K/V before gather_prefix copies them into the temp cache (a
-        # miss un-covers the tail, which then prefills as fresh blocks)
-        self._enqueue_tier_restores(req)
-        self._apply_tier_restores([req])
-        t_tel = self.clock() if self.telemetry is not None else 0.0
-        content = req.effective_prompt()
-        w = self._prefill_width(req)
-        req.pad = w - content.size
-        n_shared = req.n_shared_blocks
-        shared_slots = n_shared * self.block_size
-        # FIXED temp capacity: a per-bucket cap would retrace the whole
-        # model prefill once per prompt-length bucket (a multi-second
-        # mid-traffic stall on TPU); only the cheap scatter/gather is
-        # allowed to specialize per block count
-        cap = self.max_seq_len
-        ids = np.zeros((1, w), dtype=np.int32)
-        mask = np.zeros((1, w), dtype=bool)
-        ids[0, req.pad:] = content
-        mask[0, req.pad:] = True
-        pads = self._put(np.asarray([req.pad], dtype=np.int32))
-        ids_d, mask_d = self._put(ids), self._put(mask)
-
-        cache = self._make_temp_cache()
-        if n_shared:
-            self.n_dispatches += 1
-            cache = self._gather_prefix(
-                cache, self.pool.pages,
-                self._put(np.asarray(req.block_ids[:n_shared], np.int32)),
-                self._put(np.int32(req.pad)),
-            )
-            cache = self._repin_temp_cache(cache)
-        t_pf = self.clock() if self.host_tier is not None else 0.0
-        last = None
-        for off in range(shared_slots, w, self.prefill_chunk):
-            end = off + self.prefill_chunk
-            # self.tracer re-read per hook, like step(): the supervisor
-            # mutes a zombie engine by clearing the attribute
-            t_chunk = (self.tracer.now_us()
-                       if self.tracer is not None else -1.0)
-            self.n_dispatches += 1
-            with (jax.profiler.TraceAnnotation("serve.prefill_chunk")
-                  if self.tracer is not None else _NULL_CTX):
-                last, cache = self._prefill_step(
-                    self.params, ids_d[:, off:end], cache,
-                    mask_d[:, off:end], pads,
-                )
-                cache = self._repin_temp_cache(cache)
-            if self.tracer is not None and t_chunk >= 0.0:
-                # dispatch time, not device time — async dispatch
-                # returns before the chunk computes; the device side
-                # lives in the --jax-profile capture under the
-                # TraceAnnotation scope above
-                self.tracer.complete(
-                    "prefill_chunk", t_chunk, cat="prefill", args={
-                        "rid": req.req_id, "offset": off,
-                        "width": end - off,
-                    })
-        self.n_dispatches += 1
-        self.pool.pages = self._scatter_prefill(
-            self.pool.pages, cache,
-            self._put(np.asarray(req.block_ids[n_shared:], dtype=np.int32)),
-            self._put(np.int32(n_shared)),
-        )
-        pc = self.pool.prefix_cache
-        keys = req.extra.pop("prefix_keys", None)
-        req.extra.pop("prefix_keys_width", None)
-        if pc is not None and keys:
-            # register this prefill's fully-filled prompt blocks so the
-            # NEXT matching prompt hits (claimed blocks are already
-            # registered — register only LRU-touches them)
-            pc.register(keys, req.block_ids[: len(keys)])
-            self.metrics.on_prefix(requested=len(keys), hits=n_shared)
-        self.n_dispatches += 1
-        tok = self._sample_first(
-            last,
-            self._put(np.uint32(req.seed)),
-            self._put(np.int32(content.size - 1)),
-        )
-        # lint: disable=R2 -- the phase-split design emits the first
-        # token inside the prefill phase (its wall time is accounted to
-        # prefill_s); the unified tick retired this extra sync
-        tok_host = int(np.asarray(tok)[0])
-        if self.host_tier is not None and w > shared_slots:
-            # measured prefill rate over the fresh chunks (the sync
-            # above closed the window) — the breakeven's recompute side
-            dt = self.clock() - t_pf
-            if dt > 0:
-                self.host_tier.note_prefill_rate((w - shared_slots) / dt)
-        if self.telemetry is not None:
-            # the chunk dispatches are per-request by construction: the
-            # whole bill (weights streamed per chunk, fresh K/V written,
-            # measured wall — the sync above closed the window) lands on
-            # this request, and the totals-only record keeps the metrics
-            # ledger conserving.  MUST run before _emit: a token
-            # callback may abort(), which zeroes the shared-block state
-            # the bill reads and writes the request-log line
-            self.metrics.on_telemetry(self.telemetry.prefill_cost(
-                self, req, self.clock() - t_tel
-            ))
-        self._emit(req, tok_host)
-
+    # The tick (mixed_step)
     # ------------------------------------------------------------------
     def step(self) -> bool:
-        """One scheduler tick; returns True while work remains.  Unified
-        engines (``mixed_step``) run the single-dispatch mixed tick,
-        phase-split engines the admission→prefill→grow→decode pipeline
-        below.
+        """One scheduler tick (``_step_mixed``); returns True while work
+        remains or a token is still owed to a callback.
 
-        The unified tick's contract with callbacks: the tokens and
-        terminals a tick accepted reach ``callback`` / ``on_event``
-        during the NEXT ``step()`` (behind its dispatch), per request in
-        order and exactly once — or before this ``step()`` returns when
-        it returns False.  While it returns True, ``req.generated`` may
+        The contract with callbacks: the tokens and terminals a tick
+        accepted reach ``callback`` / ``on_event`` during the NEXT
+        ``step()`` (behind its dispatch), per request in order and
+        exactly once — or before this ``step()`` returns when it
+        dispatched nothing or leaves no work behind, so ``False`` means
+        nothing is owed.  While it returns True, ``req.generated`` may
         be one tick ahead of what the callbacks were handed
         (``publish_owed`` closes the gap); inside a token callback it
         may hold the rest of that tick's tokens already."""
-        if self.mixed:
-            return self._step_mixed()
-        return self._step_split()
+        return self._step_mixed()
 
-    def _step_split(self) -> bool:
-        """One phase-split tick: deadline sweep, admissions (+prefill),
-        then one packed decode dispatch.  Returns True while work
-        remains.
-
-        With a tracer attached each tick emits one ``tick`` span and its
-        phase slices — ``admission`` (sweep + admit), ``prefill``,
-        ``grow`` (block growth / eviction), ``decode_dispatch``,
-        ``host_sync`` (the device→host token fetch) and ``deliver``
-        (callbacks + metrics) — measured at consecutive timestamps so
-        the phases sum to the tick span.  Tracing off: every hook is a
-        single is-None branch (no allocation, pinned by lint).
-
-        ``self.tracer`` is re-read at EVERY hook (never cached in a
-        local for the whole tick) for the same reason engine code reads
-        ``self.metrics`` per call: a supervisor restart mutes the dead
-        engine by clearing the attribute, and a watchdog-superseded but
-        still-running zombie tick must stop writing into the shared
-        recorder as soon as that mute lands — a tick-lifetime snapshot
-        would keep emitting stale spans into the timeline the rebuilt
-        engine now owns.  Timestamps default to -1 so a tick that
-        STARTED untraced never emits a garbage span if a tracer is
-        attached mid-tick."""
-        t0 = self.tracer.now_us() if self.tracer is not None else -1.0
-        fetches0 = self.n_host_fetches
-        if self.host_tier is not None:
-            self._tier_spill_bytes = 0
-            self._tier_restore_bytes = 0
-            self._tier_restore_us = 0.0
-        self._sweep_deadlines()
-        admitted = self.scheduler.admit()
-        t1 = self.tracer.now_us() if self.tracer is not None else -1.0
-        for req in admitted:
-            t_req = self.clock()
-            if req.admit_time is None:
-                req.admit_time = t_req
-            if self.tracer is not None:
-                self.tracer.request_phase(
-                    req.req_id, "prefill", args=self._targs(
-                        req, shared_blocks=req.n_shared_blocks,
-                        preemptions=req.n_preemptions,
-                    ))
-            self._prefill_request(req)
-            req.prefill_s += self.clock() - t_req
-            if not self._maybe_finish(req) and self.tracer is not None:
-                self.tracer.request_phase(req.req_id, "decode")
-        t2 = self.tracer.now_us() if self.tracer is not None else -1.0
-
-        # preempted requests are already requeued; slots rebuilt below
-        for req in self.scheduler.ensure_decode_blocks():
-            if self.tracer is not None:
-                self.tracer.request_instant(req.req_id, "evicted-requeued")
-                self.tracer.request_phase(req.req_id, "queued")
-            self._emit_event(req, "evicted-requeued")
-        t3 = self.tracer.now_us() if self.tracer is not None else -1.0
-
-        running = [
-            r for r in self.scheduler.running if r.generated
-        ]
-        t4 = t5 = t3
-        tel = None
-        cost = None
-        tdev0 = 0.0
-        if running:
-            b = self.scheduler.max_slots
-            mb = self.max_blocks_per_seq
-            tables = np.zeros((b, mb), dtype=np.int32)
-            lengths = np.zeros((b,), dtype=np.int32)
-            pads = np.zeros((b,), dtype=np.int32)
-            toks = np.zeros((b,), dtype=np.int32)
-            seeds = np.zeros((b,), dtype=np.uint32)
-            for r in running:
-                tables[r.slot, : len(r.block_ids)] = r.block_ids
-                # slots written so far: pads + content minus the latest
-                # generated token (this tick's input, written by the step)
-                lengths[r.slot] = r.cache_len - 1
-                pads[r.slot] = r.pad
-                toks[r.slot] = r.generated[-1]
-                seeds[r.slot] = np.uint32(r.seed)
-            if self.telemetry is not None:
-                # analytic byte bill for this dispatch; the measured
-                # wall closes over it after the host sync below
-                cost = self.telemetry.split_tick_cost(self, running)
-                tdev0 = self.clock()
-            with (jax.profiler.TraceAnnotation("serve.decode_dispatch")
-                  if self.tracer is not None else _NULL_CTX):
-                out, self.pool.pages = self._dispatch_decode(
-                    self._put(tables), self._put(lengths),
-                    self._put(pads), self._put(toks),
-                    self._put(seeds),
-                )
-            t4 = self.tracer.now_us() if self.tracer is not None else -1.0
-            if self.faults is not None:
-                # injected host_sync regression: a REAL stall inside
-                # the host_sync phase window, attributed by the
-                # sentinel to the right phase (ActionPolicy food)
-                hang = self.faults.trip("host_sync")
-                if hang is not None:
-                    time.sleep(hang)
-            # THE tick's one device→host transfer: the decode step
-            # returns the packed [B, 4] sync rows (token, stop-hit,
-            # watermark, accept — the mixed contract's W=1 case); the
-            # deliver loop below reads the token column and
-            # _maybe_finish re-derives finish host-side (see _pack_sync
-            # on the redundant columns)
-            out_host = np.asarray(out)
-            self.n_host_fetches += 1
-            t5 = self.tracer.now_us() if self.tracer is not None else -1.0
-            if cost is not None and self.telemetry is not None:
-                # attribution lands BEFORE the deliver loop so a
-                # finishing request's canonical log line carries its
-                # final tick's cost
-                tel = self.telemetry.finish(cost, self.clock() - tdev0)
-                self.telemetry.attribute(cost, tel["device_time_s"])
-                self.metrics.on_telemetry(tel)
-            for r in running:
-                self._emit(r, int(out_host[r.slot, 0]))
-                self._maybe_finish(r)
-
-        if self.journal is not None:
-            # ONE delivery-watermark record for the whole tick (rows
-            # for every live request whose count advanced) — batched
-            # per tick, never per token
-            self.journal.end_tick(self._requests.values())
-        if self.host_tier is not None and (
-            self._tier_spill_bytes or self._tier_restore_bytes
-        ):
-            self.metrics.on_tier_gauge(
-                resident_bytes=self.host_tier.resident_bytes,
-                breakeven=self.host_tier.breakeven_ratio(self.block_size),
-            )
-        self.metrics.on_tick(
-            queue_depth=self.scheduler.queue_depth,
-            occupancy=self.pool.occupancy,
-            active_slots=len(running) if running else 0,
-            preemptions_total=self.scheduler.n_preemptions,
-            kv_bytes=self._kv_bytes_tick(running) if running else 0,
-        )
-        outliers: list[dict] = []
-        if self.tracer is not None and t0 >= 0.0:
-            t6 = self.tracer.now_us()
-            targs: dict[str, Any] = {
-                "active_slots": len(running) if running else 0,
-                "queue_depth": self.scheduler.queue_depth,
-                "admitted": len(admitted),
-                # tick-tail observables (see _step_mixed): the one-fetch
-                # contract covers the DECODE fetch; the phase-split
-                # prefill's in-phase first-token sync is accounted to
-                # prefill and retired by the unified tick
-                "host_sync_us": round(max(t5 - t4, 0.0), 1),
-                "host_fetches": self.n_host_fetches - fetches0,
-            }
-            if self.host_tier is not None:
-                targs["tier_spill_bytes"] = self._tier_spill_bytes
-                targs["tier_restore_bytes"] = self._tier_restore_bytes
-                targs["tier_restore_us"] = round(self._tier_restore_us, 1)
-            if tel is not None:
-                targs.update(_roofline_targs(tel))
-            self.tracer.tick(t0, (
-                ("admission", t0, t1), ("prefill", t1, t2),
-                ("grow", t2, t3), ("decode_dispatch", t3, t4),
-                ("host_sync", t4, t5), ("deliver", t5, t6),
-            ), args=targs)
-            if self.sentinel is not None:
-                # same literal phase tuple the tracer records (R2
-                # recovers its exempt spans from the tick() literal, so
-                # the tuple cannot be hoisted into a shared local); the
-                # roofline deficit rides along as a pseudo-phase so a
-                # persistent utilization regression pages like a
-                # host_sync one
-                outliers = self._sentinel_observe((
-                    ("admission", t0, t1), ("prefill", t1, t2),
-                    ("grow", t2, t3), ("decode_dispatch", t3, t4),
-                    ("host_sync", t4, t5), ("deliver", t5, t6),
-                ) + (
-                    (("roofline_deficit", 0.0, tel["deficit_us"]),)
-                    if tel is not None else ()
-                ))
-        self._actions_tick(outliers)
-        return self.scheduler.has_work
-
-    # ------------------------------------------------------------------
-    # Unified tick (mixed_step)
-    # ------------------------------------------------------------------
     def _init_mixed_prefill(self, req: Request) -> None:
         """Admission bookkeeping for the unified tick: fix the request's
         left-pad and prefill target, pre-mark prefix-cache-covered
         content as done (covered chunks consume NO tick budget and are
-        attended in place through the block table — no gather_prefix
-        copy), and stash the teacher-forced content for the packer."""
+        attended in place through the block table), and stash the
+        teacher-forced content for the packer."""
         content = req.effective_prompt()
         w = self._prefill_width(req)
         req.pad = w - content.size
@@ -4059,8 +3178,13 @@ class ServeEngine:
         tick thread's own CPU time — and, on spec-enabled engines, the
         draft/accept token split — so tools/summarize_trace.py and the
         benchmark's readers can say where a tick went.
-        ``self.tracer`` is re-read at every hook for the same
-        zombie-mute reason as the split tick."""
+        ``self.tracer`` is re-read at EVERY hook (never cached in a
+        local for the whole tick): a supervisor restart mutes the dead
+        engine by clearing the attribute, and a watchdog-superseded but
+        still-running zombie tick must stop writing into the shared
+        recorder as soon as that mute lands.  Timestamps default to -1
+        so a tick that STARTED untraced never emits a garbage span if a
+        tracer is attached mid-tick."""
         t0 = (self._phase_mark("serve.admission")
               if self.tracer is not None else -1.0)
         cpu0 = time.thread_time_ns() if self.tracer is not None else 0
@@ -4190,8 +3314,8 @@ class ServeEngine:
         if dispatched:
             cpu4 = time.thread_time_ns() if self.tracer is not None else 0
             if self.faults is not None:
-                # injected host_sync regression (the split tick's twin
-                # site): a real stall in the host_sync phase window
+                # injected host_sync regression: a real stall in the
+                # host_sync phase window
                 hang = self.faults.trip("host_sync")
                 if hang is not None:
                     time.sleep(hang)
@@ -4500,8 +3624,8 @@ class ServeEngine:
         return self.scheduler.has_work or bool(self._owed)
 
     def _dispatch_mixed(self, ops: jnp.ndarray, has_prefill: bool) -> tuple:
-        """One mixed dispatch with the split path's runtime-degradation
-        contract: a ragged-kernel dispatch fault permanently falls back
+        """One mixed dispatch with runtime degradation: a ragged-kernel
+        dispatch fault permanently falls back
         to the XLA ragged attention for the process and retries the same
         tick; on the XLA fallback there is nothing left to degrade to,
         so faults propagate to the supervisor.  Chaos sites: ``prefill``
@@ -4525,16 +3649,17 @@ class ServeEngine:
             if not self._degrade_mixed(f"{type(e).__name__}: {e}"):
                 raise
             self.n_dispatches += 1
-            # lint: disable=R7 -- same donated-pages caveat as the split
-            # path's retry: injected faults fire BEFORE dispatch, so the
-            # chaos retry never sees consumed pages; a real post-donation
-            # fault raises on the deleted buffers here and the supervisor
-            # restart (which rebuilds the pool) takes over
+            # lint: disable=R7 -- the step donated the pool pages: injected
+            # faults fire BEFORE dispatch, so the chaos retry never sees
+            # consumed pages; a real post-donation fault raises on the
+            # deleted buffers here and the supervisor restart (which
+            # rebuilds the pool) takes over
             return self._mixed_step(self.params, self.pool.pages, ops)
 
     def _degrade_mixed(self, reason: str) -> bool:
-        """Pallas → XLA fallback for the unified tick, process-wide
-        (the paged decode step's degradation discipline).  The tick is
+        """Pallas → XLA fallback for the tick, process-wide: a supervisor
+        rebuild (``clone_fresh``) and any later engine in this process
+        must not re-select the faulted kernel.  The tick is
         ONE program, so its Pallas kernels — ragged attention AND the
         fused sampling epilogue — degrade as a unit: the host cannot
         attribute a dispatch fault to one kernel inside the jaxpr, and
@@ -4717,103 +3842,15 @@ class ServeEngine:
                 self.config.num_experts)
         return self._expert_row_tiles[dense_width]
 
-    def _dispatch_decode(self, *args: jnp.ndarray) -> tuple:
-        """One decode dispatch with runtime kernel degradation: if the
-        paged step faults at dispatch time (an injected chaos fault or a
-        real Mosaic/runtime error that the startup probe could not
-        foresee), permanently fall back to the gather impl for the whole
-        process and retry the SAME tick on it — requests see one slower
-        tick, never a failure.  On the gather impls there is nothing left
-        to degrade to, so faults propagate (the supervisor's problem)."""
-        faults = self.faults
-        if (
-            faults is not None
-            and faults.trip("decode") is not None
-            and not self._degrade_decode("chaos: injected decode-dispatch "
-                                         "fault")
-        ):
-            raise FaultInjected("decode")
-        self.n_dispatches += 1
-        try:
-            return self._decode_step(self.params, self.pool.pages, *args)
-        except Exception as e:  # noqa: BLE001 — any dispatch fault gates
-            if not self._degrade_decode(f"{type(e).__name__}: {e}"):
-                raise
-            self.n_dispatches += 1
-            # lint: disable=R7 -- the paged step donated the pool pages;
-            # if the fault struck after they were consumed this retry
-            # raises on the deleted buffers and the supervisor restart
-            # (which rebuilds the pool) takes over — injected faults
-            # fire before dispatch, so the chaos path always retries
-            # cleanly
-            return self._decode_step(self.params, self.pool.pages, *args)
-
-    def _degrade_decode(self, reason: str) -> bool:
-        """Paged attention → gather AND fused epilogue → XLA tail,
-        process-wide, as a unit (the step is one program — see
-        ``_degrade_mixed``).  Returns False when there is nothing left
-        to fall back to (gather impl with the XLA tail)."""
-        if self.decode_attn_impl == "paged" or self.epilogue_impl == "fused":
-            from llm_np_cp_tpu.ops.pallas.support import (
-                disable_kernel,
-                epilogue_kernel_name,
-                paged_kernel_name,
-            )
-
-            # process-wide: a supervisor rebuild (clone_fresh) and any
-            # future engine in this process must not re-select the
-            # faulted kernel
-            if self.decode_attn_impl == "paged":
-                disable_kernel(
-                    paged_kernel_name(self.cache_dtype == jnp.int8),
-                    reason,
-                )
-                self.decode_attn_impl = "xla"
-            if self.epilogue_impl == "fused":
-                from llm_np_cp_tpu.models.transformer import (
-                    head_quant_mode,
-                )
-
-                disable_kernel(
-                    epilogue_kernel_name(
-                        head_quant_mode(self.params, self.config)
-                        == "int8"
-                    ),
-                    reason,
-                )
-                self.epilogue_impl = "xla"
-            self.decode_degraded = reason
-            self._decode_step = self._make_decode_step(
-                self.decode_attn_impl
-            )
-            return True
-        return False
-
-    def _kv_bytes_tick(self, running: list[Request]) -> int:
-        """K/V bytes this tick's decode attention touches — the
-        observable for the gather→paged win.  The gather impls
-        materialize the full padded [L, B, S_max] view regardless of
-        content; the paged kernel streams only each row's visible blocks
-        (first-pad block through the length block — and on sliding-
-        window layers only the window's blocks, counted per layer).
-        The math lives in serve/telemetry (shared with the roofline
-        model's per-request attribution) so the two cannot drift."""
-        return int(split_tick_kv_read(self, running, per_request=False)[0])
-
     def warmup(
         self, prompt_lens: list[int], max_new_tokens: int = 2,
     ) -> None:
-        """Compile every phase program before measuring, then reset
+        """Compile every program of the tick before measuring, then reset
         metrics — so a subsequent replay reports steady-state serving
         numbers, not first-compile stalls (on TPU a model compile is
-        multi-second and would dominate TTFT p99).
-
-        prefill/decode/sample each compile once, so one dummy request
-        covers them.  The scatter and prefix-gather specialize per block
-        count, and a preemption re-prefill can produce ANY count up to
-        the workload's worst case — warm them all by scattering/gathering
-        a zero temp cache against the scratch block (garbage there is
-        harmless by construction)."""
+        multi-second and would dominate TTFT p99).  One dummy request
+        runs the loop end to end; every program of ``mixed_buckets`` its
+        ticks did not pick is then compiled on an all-dead batch."""
         if not prompt_lens:
             return
         # chaos is suspended for the warmup pass: it is compile-only, so
@@ -4862,7 +3899,7 @@ class ServeEngine:
             # children), then — outside it, the cost is tracing's own —
             # the device-side op map, once per recorder
             tracer.complete("warmup", t_warm, cat="setup")
-            if self.mixed and tracer.get_other("op_map") is None:
+            if tracer.get_other("op_map") is None:
                 t_map = tracer.now_us()
                 tracer.set_other("op_map", self.device_op_map())
                 tracer.complete("op_map", t_map, cat="setup", args={
@@ -4870,8 +3907,6 @@ class ServeEngine:
 
     def _warmup_body(self, prompt_lens: list[int], max_new_tokens: int,
                      tracer: TraceRecorder | None = None) -> None:
-        # two decode tokens compile the decode/sample/column-scatter
-        # programs; the workload's full budget only matters for b_max
         t_req = tracer.now_us() if tracer is not None else -1.0
         self.submit(np.ones(min(prompt_lens), np.int32),
                     min(2, max_new_tokens))
@@ -4894,56 +3929,25 @@ class ServeEngine:
             )
             # ...and the spill-path slicer (same traced-index contract)
             self._slice_block(self.pool.pages, self._put(np.int32(0)))
-        if self.mixed:
-            # one compile per program — the dummy request covered
-            # whichever its own ticks picked; warm the rest directly so
-            # mid-traffic composition churn can never trigger a compile
-            # stall
-            for t_w, d_w in self.mixed_buckets:
-                t_b = tracer.now_us() if tracer is not None else -1.0
-                missed = tracer.compile_misses if tracer is not None else 0
-                self._warm_mixed_bucket(t_w, d_w)
-                if tracer is not None:
-                    # compiled: a backend compile ran (the dummy request
-                    # above, or the persistent cache, had not covered it)
-                    tracer.complete(
-                        "warmup.bucket", t_b, cat="setup", args={
-                            "width": t_w, "dense": d_w,
-                            "compiled": tracer.compile_misses > missed,
-                        })
-            if self.pool.prefix_cache is not None:
-                self.pool.prefix_cache.clear()
-            self.scheduler.finished.clear()
-            self.metrics = ServeMetrics(clock=self.clock)
-            return
-        b_max = min(
-            self.pool.blocks_for(_ceil_to(
-                max(prompt_lens) + max_new_tokens - 1, self.prefill_chunk
-            )),
-            self.max_blocks_per_seq,
-        )
-        cache = self._make_temp_cache()
-        for nb in range(1, b_max + 1):
-            self.pool.pages = self._scatter_prefill(
-                self.pool.pages, cache, self._put(np.zeros(nb, np.int32)),
-                self._put(np.int32(0)),
-            )
+        # one compile per program — the dummy request covered
+        # whichever its own ticks picked; warm the rest directly so
+        # mid-traffic composition churn can never trigger a compile
+        # stall
+        for t_w, d_w in self.mixed_buckets:
+            t_b = tracer.now_us() if tracer is not None else -1.0
+            missed = tracer.compile_misses if tracer is not None else 0
+            self._warm_mixed_bucket(t_w, d_w)
+            if tracer is not None:
+                # compiled: a backend compile ran (the dummy request
+                # above, or the persistent cache, had not covered it)
+                tracer.complete(
+                    "warmup.bucket", t_b, cat="setup", args={
+                        "width": t_w, "dense": d_w,
+                        "compiled": tracer.compile_misses > missed,
+                    })
         if self.pool.prefix_cache is not None:
-            # a prefix hit can cover any share-unit multiple of blocks up
-            # to one chunk short of the worst width — warm each gather
-            # shape, then drop the dummy request's registered blocks so
-            # the measured span starts with a cold cache
-            unit = self._share_unit
-            h_max = (
-                (b_max * self.block_size - self.prefill_chunk)
-                // (unit * self.block_size)
-            ) * unit
-            for h in range(unit, max(h_max, 0) + 1, unit):
-                cache = self._make_temp_cache()
-                self._gather_prefix(
-                    cache, self.pool.pages, self._put(np.zeros(h, np.int32)),
-                    self._put(np.int32(0)),
-                )
+            # drop the dummy request's registered blocks so the measured
+            # span starts with a cold cache
             self.pool.prefix_cache.clear()
         # the dummy request is not part of any measured trace: drop it
         # from the finished ledger along with the metrics it produced

@@ -23,10 +23,11 @@ Spec grammar (events joined by ``;`` or ``,``)::
 
 Sites (each named where it is threaded in):
 
-- ``decode``      — engine decode dispatch (``ServeEngine.step``); on
-                    the paged impl this exercises the runtime
-                    gather-fallback path
-- ``prefill``     — ``ServeEngine._prefill_request`` entry
+- ``decode``      — the tick's dispatch (``ServeEngine._dispatch_mixed``);
+                    on the Pallas tick this exercises the runtime
+                    fallback to the XLA twins (``_degrade_mixed``)
+- ``prefill``     — the same dispatch, when the tick planned prefill
+                    tokens
 - ``tick_crash``  — the HTTP runner's tick loop (supervised restart)
 - ``tick_hang``   — ditto, but sleep ``ARG`` seconds (watchdog food)
 - ``ckpt_read``   — transient ``OSError`` during checkpoint shard reads

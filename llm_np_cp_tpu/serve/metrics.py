@@ -347,19 +347,15 @@ class ServeMetrics:
 
     def on_telemetry(self, tel: dict[str, Any]) -> None:
         """One telemetry record (serve/telemetry.py): a roofline-graded
-        dispatch (``roofline: True`` — the unified tick's one dispatch
-        or the split tick's decode dispatch) feeds the per-tick gauges
-        and the utilization histogram; a totals-only record (split-path
-        prefill, whose wall includes host Python) feeds just the byte/
-        time ledgers, which per-request attribution sums back to."""
+        dispatch (the tick's one dispatch) feeds the byte/time ledgers,
+        which per-request attribution sums back to, the per-tick gauges
+        and the utilization histogram."""
         with self._lock:
             self.kv_read_bytes_total += tel["kv_read_bytes"]
             self.kv_write_bytes_total += tel["kv_write_bytes"]
             self.weight_bytes_total += tel["weight_bytes"]
             self.device_time_s_total += tel["device_time_s"]
             self.hbm_gbps = tel.get("hbm_gbps", self.hbm_gbps)
-            if not tel.get("roofline", True):
-                return
             self.roofline_ticks += 1
             util = tel["roofline_util"]
             self.roofline_gbps.append(tel["achieved_gbps"])

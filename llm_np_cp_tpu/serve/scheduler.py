@@ -125,8 +125,8 @@ class Request:
     # content tokens whose K/V is already in the pool this admission
     # (prefix-cache hits pre-seed it — covered content never consumes
     # tick budget), the content length this admission must reach, and
-    # the completion flag the planner keys on.  The phase-split engine
-    # leaves these untouched; a preemption resets them with pad.
+    # the completion flag the planner keys on.  A preemption resets
+    # them with pad.
     prefill_done: int = 0
     prefill_target: int = 0
     prefilled: bool = False

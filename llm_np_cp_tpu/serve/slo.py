@@ -23,7 +23,7 @@ first-class:
   here computed from bucketed ring counters so a week-long server pays
   O(buckets) memory, not O(requests).
 - ``TickSentinel`` — rolling per-phase EWMA baselines over the engine's
-  tick-phase slices (``MIXED_TICK_PHASES`` / ``TICK_PHASES``).  An
+  tick-phase slices (``MIXED_TICK_PHASES``).  An
   outlier tick names the guilty phase — turning "p99 got worse" into
   "host_sync regressed at tick 1204" — via a trace instant and the
   ``llm_serve_anomaly_ticks_total{phase=}`` counter.

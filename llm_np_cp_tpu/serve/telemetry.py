@@ -48,9 +48,7 @@ by token share.  The engine accumulates them on ``Request``
 / ``device_time_s``) and the canonical request log carries them — the
 cost basis per-tenant SLOs will bill against (ROADMAP item 2).
 Attribution CONSERVES: per-request values sum to the tick totals
-(test-pinned), with the one documented exception that the split-path
-gather impls read every padded slot — that overhead is split evenly
-across the live rows rather than invented onto a phantom request.
+(test-pinned).
 
 CALIBRATION: the byte model is analytic, not measured — on CPU the
 absolute GB/s numbers are meaningless (no HBM) and on TPU they assume
@@ -181,49 +179,6 @@ def mixed_tick_kv_read(
     return total, per
 
 
-def split_tick_kv_read(
-    eng: Any, running: list, *, per_request: bool = True,
-) -> tuple[int, dict[int, float]]:
-    """K/V bytes one phase-split decode dispatch reads — total and per
-    request (the engine's ``_kv_bytes_tick`` delegates here; pass
-    ``per_request=False`` to skip the per-row dict for the every-tick
-    metrics gauge).  The gather impls materialize the full padded
-    [B, S_max] view including DEAD slots; that fixed overhead is split
-    evenly across the live rows (attribution must conserve, and there
-    is no request to bill padding to).  The paged kernel streams only
-    each row's visible blocks, so its attribution is exact."""
-    cfg = eng.config
-    per_slot = _per_slot_bytes(cfg, eng.cache_dtype.itemsize)
-    n_layers = cfg.num_hidden_layers
-    if eng.decode_attn_impl != "paged":
-        total = (eng.scheduler.max_slots * eng.max_seq_len
-                 * n_layers * per_slot)
-        if not per_request:
-            return total, {}
-        share = total / len(running) if running else 0.0
-        return total, {r.req_id: share for r in running}
-    bs = eng.block_size
-    win = cfg.sliding_window
-    n_sliding = (
-        sum(cfg.layer_is_sliding(i) for i in range(n_layers))
-        if win is not None else 0
-    )
-    per: dict[int, float] = {}
-    total_f = 0.0
-    for r in running:
-        nb_hi = -(-r.cache_len // bs)
-        full = (nb_hi - r.pad // bs) * bs
-        slot_layers = (n_layers - n_sliding) * full
-        if n_sliding:
-            pad_eff = max(r.pad, r.cache_len - win)
-            slot_layers += n_sliding * (nb_hi - pad_eff // bs) * bs
-        b = slot_layers * per_slot
-        total_f += b
-        if per_request:
-            per[r.req_id] = b
-    return int(total_f), per
-
-
 def _epilogue_logits_bytes(eng: Any, sample_rows: int) -> float:
     """HBM traffic of the step's SAMPLING TAIL: the XLA epilogue
     materializes ``[sample_rows, V]`` float32 logits (written by the
@@ -336,24 +291,6 @@ class TelemetryModel:
             ),
         )
 
-    def split_tick_cost(self, eng: Any, running: list) -> dict[str, Any]:
-        """The phase-split decode dispatch's bill (prefill dispatches
-        are attributed separately via ``prefill_cost`` — they are
-        per-request by construction)."""
-        kv_read, per_read = split_tick_kv_read(eng, running)
-        wslot = (_per_slot_bytes(eng.config, eng.cache_dtype.itemsize)
-                 * eng.config.num_hidden_layers)
-        rows = [
-            (r, 1, float(per_read[r.req_id]), float(wslot))
-            for r in running
-        ]
-        return self._cost(
-            "decode", rows, float(kv_read),
-            tail_bytes=_epilogue_logits_bytes(
-                eng, eng.scheduler.max_slots
-            ),
-        )
-
     # ------------------------------------------------------------------
     def finish(self, cost: dict[str, Any],
                device_time_s: float) -> dict[str, Any]:
@@ -367,7 +304,6 @@ class TelemetryModel:
         ideal_s = total / (self.hbm_gbps * 1e9)
         return {
             "kind": cost["kind"],
-            "roofline": True,
             "tokens": cost["tokens"],
             "device_time_s": float(device_time_s),
             "kv_read_bytes": cost["kv_read_bytes"],
@@ -398,39 +334,3 @@ class TelemetryModel:
             req.kv_bytes_written += kv_write
             req.weight_bytes_amortized += wb * frac
             req.device_time_s += device_time_s * frac
-
-    def prefill_cost(self, eng: Any, req: Any,
-                     device_time_s: float) -> dict[str, Any]:
-        """Split-path prefill attribution: the chunk dispatches are
-        per-request already, so their whole bill lands on ``req`` and
-        the returned record feeds the metrics TOTALS only
-        (``roofline: False`` — a chunk window includes host Python, so
-        it must not pollute the per-tick roofline gauges).  The chunk
-        attention reads the temp cache, not the pool; that traffic is
-        deliberately out of the model (both the request and the totals
-        skip it, so conservation holds)."""
-        shared_slots = req.n_shared_blocks * eng.block_size
-        w = eng._prefill_width(req)
-        fresh_tokens = w - shared_slots  # pads embed-gather too
-        n_chunks = max(fresh_tokens // eng.prefill_chunk, 0)
-        wslot = (_per_slot_bytes(eng.config, eng.cache_dtype.itemsize)
-                 * eng.config.num_hidden_layers)
-        fresh_slots = (
-            (len(req.block_ids) - req.n_shared_blocks) * eng.block_size
-        )
-        kv_write = float(fresh_slots * wslot)
-        weight = float(self.weight_bytes(fresh_tokens,
-                                         n_dispatches=n_chunks))
-        req.kv_bytes_written += kv_write
-        req.weight_bytes_amortized += weight
-        req.device_time_s += device_time_s
-        return {
-            "kind": "prefill",
-            "roofline": False,
-            "tokens": fresh_tokens,
-            "device_time_s": float(device_time_s),
-            "kv_read_bytes": 0.0,
-            "kv_write_bytes": kv_write,
-            "weight_bytes": weight,
-            "hbm_gbps": self.hbm_gbps,
-        }

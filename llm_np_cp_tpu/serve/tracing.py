@@ -9,8 +9,8 @@ trace-event JSON (stdlib only, like the rest of the HTTP stack; open the
 dump at ui.perfetto.dev or chrome://tracing):
 
 - **per-request spans** — async events (``ph`` b/e/n) on one track per
-  request id: ``queued`` → ``prefill`` (prefix-cache hits annotated,
-  one ``prefill_chunk`` slice per dispatched chunk) → ``decode`` →
+  request id: ``queued`` → ``prefill`` (prefix-cache hits annotated)
+  → ``decode`` →
   a terminal ``finish`` instant (reason-tagged), with instants for
   ``evicted-requeued`` preemptions and ``recovery-replay`` resubmits
   after a supervised restart.  The HTTP layer brackets the whole thing
@@ -20,8 +20,8 @@ dump at ui.perfetto.dev or chrome://tracing):
   the request has been written, and, where it closes ``http``, a
   ``stream_end`` instant with the emit-to-write lag of its frames.
 - **per-tick phase spans** — complete events (``ph`` X) on the engine
-  tick thread: ``admission`` / ``prefill`` / ``grow`` /
-  ``decode_dispatch`` / ``host_sync`` / ``deliver`` slices nested under
+  tick thread: one slice a phase of ``MIXED_TICK_PHASES`` (``admission``
+  … ``mixed_dispatch`` … ``host_sync`` … ``account``) nested under
   one ``tick`` event.  The phases are measured at consecutive
   timestamps, so they sum to the tick span by construction — the
   invariant tests pin.
@@ -82,18 +82,14 @@ from typing import Any, Callable
 # stdlib-only, so it carries its own copy, pinned equal to this one by
 # tests/test_serve_tracing.py.
 REQUEST_PHASES = ("queued", "prefill", "decode")
-# Tick-phase names, in tick order (see ServeEngine._step_split).
-TICK_PHASES = (
-    "admission", "prefill", "grow", "decode_dispatch", "host_sync",
-    "deliver",
-)
-# Unified-tick phase names (ServeEngine._step_mixed): the separate
-# prefill phase collapses into the single mixed dispatch, the
-# token-budget planner gets its own slice, and ``draft`` is the
+# Tick-phase names, in tick order (ServeEngine._step_mixed; the one
+# list — tools/summarize_trace.py mirrors it under this name): slices at
+# consecutive timestamps that sum to the tick.  Prefill and decode share
+# the single mixed dispatch, the token-budget planner has its own
+# slice, and ``draft`` is the
 # host-side speculative proposal pass (prompt-lookup over each
 # speculating request's history — dictionary probes, no device work;
-# ~0 on non-spec engines).  Same consecutive-timestamps sum-to-tick
-# contract; tick args additionally carry the prefill_tokens/
+# ~0 on non-spec engines).  Tick args additionally carry the prefill_tokens/
 # decode_tokens budget split — plus spec_draft_tokens/
 # spec_accept_tokens on spec-enabled engines — for
 # tools/summarize_trace.py's utilization line.

@@ -18,14 +18,19 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+from llm_np_cp_tpu.utils.runtime import configure_compile_cache  # noqa: E402
+
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
-# Entry points the tests call in-process (cli.run, chip_smoke.main) point
-# JAX's persistent compile cache at <checkout>/.jax_cache with a zero
-# persistence threshold; in THIS process that would serialize and write
-# every one of the suite's thousands of toy programs.  Children a test
-# spawns still use the cache, as they would outside a test.
-jax.config.update("jax_enable_compilation_cache", False)
+# The suite is one more entry point: it keeps JAX's persistent compile
+# cache where cli.run, chip_smoke.main and every child a test spawns keep
+# it (<checkout>/.jax_cache).  Most of a test's time is XLA compiling a
+# toy program that another test, in this worker or the next, has already
+# compiled: from a cold cache the serve files run a seventh faster than
+# with no cache (PR 46, ROADMAP D9).  It also has to be ON for
+# ``tools.compile_counter.CompileCounter`` to see anything: the event it
+# counts is recorded hit or miss, but not with the cache switched off.
+configure_compile_cache()
 
 
 # Markers (slow/http/chaos/mesh) are registered centrally in the
@@ -38,8 +43,9 @@ def _fresh_kernel_ledger():
     """``support._RUNTIME_DISABLED`` is process-wide BY DESIGN (a kernel
     that faulted at dispatch stays off across supervisor rebuilds), so a
     test that degrades an engine — on purpose, or because a dispatch
-    raised for any other reason — would silently turn every later
-    ``mixed_step="auto"`` engine in the run into the split tick."""
+    raised for any other reason — would silently take the Pallas kernels
+    away from every later engine of the run: they would serve, and be
+    tested, on the XLA twins."""
     yield
     from llm_np_cp_tpu.ops.pallas import support
 
@@ -50,6 +56,8 @@ def _fresh_kernel_ledger():
     # cached for every later test of its worker; this fixture is torn
     # down after ``monkeypatch``, and off the chip a probe costs nothing
     support._probe.cache_clear()
+    # ...and the "said once a process" ledger of the fallback warnings
+    support._WARNED.clear()
 
 
 @pytest.fixture

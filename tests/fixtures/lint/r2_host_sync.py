@@ -13,7 +13,7 @@ class FakeEngine:
         t0 = self.tracer.now_us() if self.tracer is not None else -1.0
         self._admit()
         t1 = self.tracer.now_us() if self.tracer is not None else -1.0
-        nxt = self._dispatch_decode(self._tables())
+        nxt = self._dispatch_mixed(self._tables())
         depth = self.queue_depth.item()  # BITE .item() in dispatch phase
         early = np.asarray(nxt)  # BITE asarray(dispatch result) pre-sync
         nxt.block_until_ready()  # BITE block_until_ready
@@ -26,7 +26,7 @@ class FakeEngine:
         t4 = self.tracer.now_us() if self.tracer is not None else -1.0
         if self.tracer is not None:
             self.tracer.tick(t0, (
-                ("admission", t0, t1), ("decode_dispatch", t1, t2),
+                ("admission", t0, t1), ("mixed_dispatch", t1, t2),
                 ("host_sync", t2, t3), ("deliver", t3, t4),
             ))
         return int(fin_host[0]) + wm  # host-side read: NOT a finding
@@ -43,7 +43,7 @@ class FakeEngine:
     def _lengths(self):
         return [1, 2]
 
-    def _dispatch_decode(self, tables):
+    def _dispatch_mixed(self, tables):
         return tables
 
     def _deliver(self, nxt_host, early, depth):

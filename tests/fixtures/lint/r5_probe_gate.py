@@ -3,8 +3,10 @@ a gated selection with no fallback sibling.  Parsed only."""
 
 from llm_np_cp_tpu.ops.pallas import flash_attention as fa_mod
 from llm_np_cp_tpu.ops.pallas.decode_attention import (
-    paged_decode_attention,
     ragged_paged_attention,
+)
+from llm_np_cp_tpu.ops.pallas.latent_attention import (
+    ragged_latent_attention,
 )
 from llm_np_cp_tpu.ops.pallas.sample_epilogue import sample_epilogue
 from llm_np_cp_tpu.ops.pallas.support import kernel_available
@@ -13,7 +15,7 @@ from llm_np_cp_tpu.ops.pallas.support import kernel_available
 class BadEngine:
     def decode(self, q, pages, tables, lengths, pads):
         # unconditional kernel call — no probe, no fallback
-        return paged_decode_attention(q, pages, pages, tables, lengths, pads)  # BITE
+        return ragged_latent_attention(q, pages, tables, lengths, pads)  # BITE
 
     def mixed(self, q, pages, meta):
         if kernel_available("ragged_paged_attention"):
@@ -30,3 +32,16 @@ class BadEngine:
         # an unconditional call must bite (R5 parses the gated-kernel
         # set out of _probe, so the new probes cover it automatically)
         return sample_epilogue(x, gamma, w, tied=True, eps=1e-6)  # BITE
+
+    def build(self):
+        use_kernel = kernel_available("ragged_paged_attention")
+
+        def step(q, pages, meta):
+            # a nested function that REBINDS the builder's gated name
+            # shadows it: the test below reads no gate
+            use_kernel = bool(meta)
+            if use_kernel:
+                return ragged_paged_attention(q, pages, pages, *meta)  # BITE
+            return q
+
+        return step if use_kernel else None

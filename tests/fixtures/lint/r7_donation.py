@@ -1,23 +1,23 @@
 """R7 bite fixture: donated buffers reused after a faulted dispatch
-(the ``_dispatch_decode`` retry caveat).  Parsed, never imported."""
+(the ``_dispatch_mixed`` retry caveat).  Parsed, never imported."""
 
 
 class Engine:
     def __init__(self):
-        self._decode_step = self._make_decode_step()
-        self._mixed_step = self._make_mixed_step()
+        self._tile_step = self._make_tile_step()
+        self._outer_step = self._make_outer_step()
         self._plain_step = self._make_plain_step()
 
-    def _make_decode_step(self):
+    def _make_tile_step(self):
         @partial(jax.jit, donate_argnums=(1,))
-        def decode_step(params, pages, tables):
+        def tile_step(params, pages, tables):
             return pages
 
-        return decode_step
+        return tile_step
 
-    def _make_mixed_step(self):
+    def _make_outer_step(self):
         # maker chaining: returns another maker's donating step
-        return self._make_decode_step()
+        return self._make_tile_step()
 
     def _make_plain_step(self):
         @jax.jit
@@ -26,26 +26,26 @@ class Engine:
 
         return plain_step
 
-    def _dispatch_decode(self, *args):
+    def _dispatch_tile(self, *args):
         try:
-            return self._decode_step(self.params, self.pool.pages, *args)
+            return self._tile_step(self.params, self.pool.pages, *args)
         except Exception:
             self._degrade()
-            return self._decode_step(self.params, self.pool.pages, *args)  # BITE
+            return self._tile_step(self.params, self.pool.pages, *args)  # BITE
 
-    def _dispatch_mixed(self, args):
+    def _dispatch_outer(self, args):
         try:
-            return self._mixed_step(self.params, self.pool.pages, *args)
+            return self._outer_step(self.params, self.pool.pages, *args)
         except Exception:
-            return self._mixed_step(self.params, self.pool.pages, *args)  # BITE
+            return self._outer_step(self.params, self.pool.pages, *args)  # BITE
 
     def _dispatch_rebuilt(self, *args):
         # FINE: the donated operand is rebuilt before the retry
         try:
-            return self._decode_step(self.params, self.pool.pages, *args)
+            return self._tile_step(self.params, self.pool.pages, *args)
         except Exception:
             fresh = self.pool.rebuild_pages()
-            return self._decode_step(self.params, fresh, *args)
+            return self._tile_step(self.params, fresh, *args)
 
     def _dispatch_plain(self, *args):
         # FINE: nothing donated, retrying with the same operand is legal

@@ -112,7 +112,7 @@ def test_priority_matches_config_dicts():
         n
         for n in list(bench.DECODE_CONFIGS) + list(bench.SPEC_CONFIGS)
         + list(bench.PREFILL_CONFIGS) + list(bench.RAGGED_CONFIGS)
-        + list(bench.SERVE_CONFIGS) + list(bench.SERVE_HTTP_CONFIGS)
+        + list(bench.SERVE_HTTP_CONFIGS)
         + list(bench.SERVE_CHAOS_CONFIGS) + list(bench.SERVE_MIXED_CONFIGS)
         + list(bench.SERVE_SPEC_CONFIGS) + list(bench.SERVE_SHARDED_CONFIGS)
         + list(bench.SERVE_RESTART_CONFIGS)
@@ -132,7 +132,6 @@ def test_warm_smoke_offline():
     assert set(res["warmed"]) == {n for n in bench.PRIORITY
                                  if n not in bench.SPEC_CONFIGS
                                  and n not in bench.EXTRA_CHILDREN
-                                 and n not in bench.SERVE_CONFIGS
                                  and n not in bench.SERVE_HTTP_CONFIGS
                                  and n not in bench.SERVE_CHAOS_CONFIGS
                                  and n not in bench.SERVE_MIXED_CONFIGS
@@ -153,7 +152,6 @@ def test_warm_limit_covers_top_priority_only():
                 if n not in bench.SPEC_CONFIGS
                 and n not in bench.EXTRA_CHILDREN
                 and n not in bench.RAGGED_CONFIGS
-                and n not in bench.SERVE_CONFIGS
                 and n not in bench.SERVE_HTTP_CONFIGS
                 and n not in bench.SERVE_CHAOS_CONFIGS
                 and n not in bench.SERVE_MIXED_CONFIGS]
@@ -170,39 +168,24 @@ def test_ragged_smoke_offline():
     assert res["cache_capacity"] % 128 == 0
 
 
-def test_serve_smoke_offline():
-    """The serving child (Poisson trace through ServeEngine's paged-pool
-    continuous batching) runs end-to-end on CPU with the tiny model and
-    reports the request-level numbers."""
-    res = bench._spawn("smoke_serve", 600)
-    assert res.get("ok") is True, res
-    assert res["throughput_tok_s"] > 0
-    assert res["ttft_s_p50"] > 0
-    # jit-stable ticks: ONE decode program regardless of trace length
-    assert res["compile_counts"]["decode_step"] == 1
-
-
 def test_serve_mixed_smoke_offline():
-    """The unified-tick child: the same long-prefill-heavy trace through
-    the phase-split, fused-epilogue, and XLA-tail engines — token parity
-    across ALL legs, at most one dispatch per unified tick (strictly
-    fewer total than phase-split), one mixed_step compile per
+    """The tick-tail child: the same long-prefill-heavy trace through
+    the fused-epilogue and XLA-tail engines — token parity between the
+    legs, at most one dispatch per tick, one mixed_step compile per
     packed-width bucket, and the tick-tail fusion observables: the
     fused leg resolves epilogue=fused, makes exactly ONE device fetch
     per tick (trace-verified host_sync column), and the Δhost_sync/
     Δroofline_util pair is reported for slo_gate."""
     res = bench._spawn("smoke_serve_mixed", 600)
     assert res.get("ok") is True, res
-    assert res["token_parity_mixed_vs_split"] is True
-    assert res["dispatch_win"] is True
-    assert res["dispatches_per_tick"] <= 1.0 < res["dispatches_per_tick_split"]
+    assert 0 < res["dispatches_per_tick"] <= 1.0
     legs = res["legs"]
+    assert set(legs) == {"mixed", "mixed_xla_tail"}
     assert legs["mixed"]["mixed_prefill_tokens"] > 0
     assert legs["mixed"]["mixed_decode_tokens"] > 0
     assert set(legs["mixed"]["compile_counts"]) == {"mixed_step"}
     assert (legs["mixed"]["compile_counts"]["mixed_step"]
             <= len(legs["mixed"]["buckets"]))
-    assert legs["split"]["compile_counts"]["decode_step"] == 1
     assert res["ragged_kernel_probe"] == "ok"  # interpret mode on CPU
     # the fused-vs-unfused pair (tick-tail fusion acceptance): token
     # parity at identical arrivals, the one-fetch ceiling on BOTH
@@ -384,7 +367,9 @@ def test_serve_restart_smoke_offline():
     pair recorded (with the off-thread fsync p99), and a clean final
     drain leaving an empty replay set."""
     res = bench._spawn("smoke_serve_restart", 600)
-    assert res.get("ok") is True, res
+    # (a string: pytest cuts a dict's repr short, and ``ok`` is eleven
+    # conditions)
+    assert res.get("ok") is True, json.dumps(res)
     assert res["token_parity_journaled_vs_plain"] is True
     assert res["token_parity_across_kill"] is True
     assert res["streams_resumed"] >= 1
@@ -395,7 +380,10 @@ def test_serve_restart_smoke_offline():
     assert res["journal_fsync_p99_s"] is not None
     assert res["journal_replayed_total"] >= 1
     assert res["journal_resumed_total"] >= 1
+    # counts, not a tokens/s floor: every journaled token arrived, and
+    # the resumed streams delivered theirs across the kill
     assert res["journal_overhead_ok"] is True
+    assert res["tokens_resumed"] >= res["streams_resumed"] >= 1
     assert res["drain_left_unterminated"] == 0
 
 

@@ -38,7 +38,7 @@ def test_rehearsal_passes_and_says_so(tmp_path, capsys):
     assert "platform: cpu" in out and "rehearsal: true" in out
     assert facts["resolution"] == {
         "tick": "unified", "ragged_attn_impl": "pallas",
-        "epilogue_impl": "fused", "decode_attn_impl": "xla",
+        "epilogue_impl": "fused",
         "topology": "single chip",
     }
     assert facts["compiles_after_warmup"] == 0
@@ -60,8 +60,8 @@ def test_without_rehearsal_a_cpu_run_fails_with_no_result(tmp_path, capsys):
 
 
 def test_forced_probe_failure_cannot_pass(tmp_path, capsys, monkeypatch):
-    """--mixed-step auto with a refused ragged kernel serves from the
-    split tick and exits 0; the smoke must not."""
+    """With a refused ragged kernel the server serves from the XLA
+    twins and exits 0; the smoke must not."""
     monkeypatch.setattr(support, "_FORCE_FAIL", True)
     support._probe.cache_clear()
     try:
@@ -70,8 +70,9 @@ def test_forced_probe_failure_cannot_pass(tmp_path, capsys, monkeypatch):
         monkeypatch.undo()
         support._probe.cache_clear()
     assert rc == 1
-    assert facts["resolution"]["tick"] == "split"
-    assert any("want unified" in f for f in facts["failures"])
+    assert facts["resolution"]["tick"] == "unified"
+    assert facts["resolution"]["ragged_attn_impl"] == "xla"
+    assert any("want pallas" in f for f in facts["failures"])
     assert any("want fused" in f for f in facts["failures"])
     assert '"ok": true' not in out
 

@@ -430,9 +430,9 @@ def test_cli_serve_bench_rejects_bad_block_size(fake_load):
         cli.run(["serve-bench", "--block-size=12"])
 
 
-def test_cli_serve_bench_paged_and_prefix_cache(fake_load, capsys):
-    """--attn-impl paged + --prefix-cache + --distinct-prompts runs
-    end-to-end (CPU interpret mode), reports the flags in the banner,
+def test_cli_serve_bench_prefix_cache(fake_load, capsys):
+    """--prefix-cache + --distinct-prompts on the default tick runs
+    end-to-end (CPU interpret mode), reports the flag in the banner,
     and the repeated prompts produce a REAL nonzero hit rate (a static
     banner string alone would pass even with sharing broken)."""
     import re
@@ -441,9 +441,10 @@ def test_cli_serve_bench_paged_and_prefix_cache(fake_load, capsys):
         "serve-bench", "--requests=8", "--rate=50", "--prompt-len=40",
         "--max-tokens=3", "--slots=2", "--block-size=8", "--seed=1",
         "--num-blocks=64", "--distinct-prompts=2",
-        "--attn-impl=paged", "--prefix-cache",
+        "--prefix-cache",
     ])
-    assert "attn=paged" in out and "prefix_cache=on" in out
+    assert "tick=mixed:pallas" in out and "prefix_cache=on" in out
+    assert "attn=" not in out
     m = re.search(r"prefix cache hit rate (\d\.\d+)", out)
     assert m, out
     assert float(m.group(1)) > 0, out
@@ -485,8 +486,6 @@ def test_cli_serve_bench_speculative_validation(fake_load):
     """Speculative flag errors fire BEFORE the model load."""
     base = ["serve-bench", "--requests=2", "--prompt-len=8",
             "--max-tokens=2", "--slots=2", "--block-size=8"]
-    with pytest.raises(SystemExit, match="unified tick"):
-        cli.run(base + ["--speculative-serve", "--mixed-step=off"])
     with pytest.raises(SystemExit, match="--spec-k"):
         cli.run(base + ["--speculative-serve", "--spec-k=0"])
 
@@ -530,8 +529,8 @@ def test_cli_serve_bench_trace_out_writes_valid_trace(fake_load, capsys,
                 and e["name"] == "finish"]
     assert len(finishes) == 4  # warmup's dummy request is NOT in there
     out = format_summary(events)
-    # the CLI default is the unified tick (--mixed-step auto, and the
-    # ragged kernel probe passes in CPU interpret mode)
+    # the unified tick (the ragged kernel probe passes in CPU interpret
+    # mode)
     assert "mixed_dispatch" in out
     assert "mixed_step utilization" in out
     # ring-bounded mode caps the buffer
@@ -578,43 +577,48 @@ def test_cli_serve_bench_observability_flags(fake_load, capsys, tmp_path):
         cli.run(["serve-bench", "--slo-ttft=-1"])
 
 
-def test_cli_serve_bench_rejects_paged_when_probe_fails(fake_load, monkeypatch):
-    """An EXPLICIT --attn-impl paged must die with an actionable message
-    when Mosaic rejects the kernel — not a Pallas traceback; auto falls
-    back to the gather path instead."""
+def test_cli_named_kernels_fail_loudly_and_serve_says_its_fallback(
+        fake_load, monkeypatch, capsys):
+    """With Mosaic refusing every kernel: the ``generate`` subcommand's
+    EXPLICIT kernel flags die with an actionable message — not a Pallas
+    traceback, not a silent downgrade — and ``serve-bench``, which names
+    no kernel, serves the unified tick over the XLA twins and says so in
+    its banner."""
     import llm_np_cp_tpu.ops.pallas.support as support
 
     monkeypatch.setattr(support, "_FORCE_FAIL", True)
-    support._probe.cache_clear()
-    try:
-        with pytest.raises(SystemExit, match="--attn-impl"):
-            cli.run([
-                "serve-bench", "--requests=2", "--rate=50", "--prompt-len=8",
-                "--max-tokens=2", "--slots=2", "--block-size=8",
-                "--attn-impl=paged",
-            ])
-        # the other explicit kernel flags name a kernel too: no silent
-        # downgrade to XLA behind a log line
-        with pytest.raises(SystemExit, match="--decode-attn pallas"):
-            cli.run([
-                "serve-bench", "--requests=2", "--rate=50", "--prompt-len=8",
-                "--max-tokens=2", "--slots=2", "--block-size=8",
-                "--decode-attn=pallas",
-            ])
-        with pytest.raises(SystemExit, match="--decode-attn pallas"):
-            cli.run(["--backend=tpu", "--max-tokens=2", "--dtype=f32",
-                     "--no-stream", "--decode-attn=pallas"])
-        with pytest.raises(SystemExit, match="--flash-prefill"):
-            cli.run(["--backend=tpu", "--max-tokens=2", "--dtype=f32",
-                     "--no-stream", "--flash-prefill"])
-        out = cli.run([
-            "serve-bench", "--requests=2", "--rate=50", "--prompt-len=8",
-            "--max-tokens=2", "--slots=2", "--block-size=8",
-            "--attn-impl=auto",
-        ])
-        assert "attn=xla" in out
-    finally:
-        support._probe.cache_clear()
+    support._probe.cache_clear()  # conftest clears it again afterwards
+    with pytest.raises(SystemExit, match="--decode-attn pallas"):
+        cli.run(["--backend=tpu", "--max-tokens=2", "--dtype=f32",
+                 "--no-stream", "--decode-attn=pallas"])
+    with pytest.raises(SystemExit, match="--flash-prefill"):
+        cli.run(["--backend=tpu", "--max-tokens=2", "--dtype=f32",
+                 "--no-stream", "--flash-prefill"])
+    out = cli.run([
+        "serve-bench", "--requests=2", "--rate=50", "--prompt-len=8",
+        "--max-tokens=2", "--slots=2", "--block-size=8",
+        "--speculative-serve", "--spec-k=2",
+    ])
+    assert "tick=mixed:xla" in out and "epilogue=xla" in out
+    printed = capsys.readouterr().out
+    assert ("unified tick ACTIVE" in printed
+            and "(ragged attention: xla, epilogue=xla)" in printed)
+    assert "speculative serving ACTIVE: k=2" in printed
+
+
+@pytest.mark.parametrize("flag", [
+    ["--mixed-step", "off"], ["--attn-impl", "paged"],
+    ["--decode-attn", "pallas"]], ids=lambda f: " ".join(f))
+@pytest.mark.parametrize("sub", ["serve", "serve-bench"])
+def test_removed_tick_flags_are_argparse_errors(sub, flag, capsys):
+    """No flag names a tick or one of its kernels: the three that chose
+    the phase-split engine and its decode paths are argparse's own
+    ``unrecognized arguments`` (exit 2), before any model is loaded."""
+    with pytest.raises(SystemExit) as exc:
+        cli.run([sub] + flag)
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert "unrecognized arguments" in err and flag[0] in err
 
 
 # ---------------------------------------------------------------------------
@@ -684,15 +688,16 @@ def test_cli_serve_http_stdlib_client_smoke(fake_load, tmp_path, capsys):
 @pytest.mark.parametrize("parser", ["build_http_serve_parser",
                                     "build_serve_parser"])
 def test_engine_and_cli_agree_on_the_default_tick(parser):
-    """One default, not two: an engine built with no ``mixed_step`` (every
-    test helper) is the engine ``cli serve`` builds with no
-    ``--mixed-step`` — the unified tick where its kernel compiles.  With
-    the constructor at "off" the serve suite guarded a tick no user
-    reached (ISSUE 29)."""
+    """Neither names a tick: the CLI has no flag for one, and the engine's
+    ``mixed_step`` keyword — kept for three scripts under benchmark/ —
+    defaults to the one engine there is."""
     import inspect
 
     from llm_np_cp_tpu.serve import ServeEngine
 
     args = getattr(cli, parser)("some/model").parse_args([])
-    default = inspect.signature(ServeEngine).parameters["mixed_step"].default
-    assert default == args.mixed_step == "auto"
+    for gone in ("mixed_step", "attn_impl", "decode_attn"):
+        assert not hasattr(args, gone)
+    sig = inspect.signature(ServeEngine).parameters
+    assert sig["mixed_step"].default == "auto"
+    assert "decode_attn" + "_impl" not in sig

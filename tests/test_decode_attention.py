@@ -154,7 +154,7 @@ def test_decode_loop_token_parity():
                   cache_dtype=jnp.float32).generate(prompt, 10).tokens
     b = Generator(params, cfg, sampler=Sampler(kind="greedy"),
                   cache_dtype=jnp.float32,
-                  decode_attn_impl="flash_decode").generate(prompt, 10).tokens
+                  decode_attn="flash_decode").generate(prompt, 10).tokens
     np.testing.assert_array_equal(a, b)
 
 
@@ -174,7 +174,7 @@ def test_decode_loop_gemma2_sliding_parity():
                   cache_dtype=jnp.float32).generate(prompt, 8).tokens
     b = Generator(params, cfg, sampler=Sampler(kind="greedy"),
                   cache_dtype=jnp.float32,
-                  decode_attn_impl="flash_decode").generate(prompt, 8).tokens
+                  decode_attn="flash_decode").generate(prompt, 8).tokens
     np.testing.assert_array_equal(a, b)
 
 
@@ -201,8 +201,8 @@ def test_generator_rejects_unknown_decode_impl():
 
     cfg = tiny_config("llama")
     params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
-    with pytest.raises(ValueError, match="decode_attn_impl"):
-        Generator(params, cfg, decode_attn_impl="pallas")
+    with pytest.raises(ValueError, match="decode_attn"):
+        Generator(params, cfg, decode_attn="pallas")
 
 
 def test_decode_loop_under_tp_mesh_parity():
@@ -229,7 +229,7 @@ def test_decode_loop_under_tp_mesh_parity():
     with jax.set_mesh(mesh):
         got = Generator(p_sh, cfg, sampler=Sampler(kind="greedy"),
                         cache_dtype=jnp.float32,
-                        decode_attn_impl="flash_decode").generate(prompt, 8).tokens
+                        decode_attn="flash_decode").generate(prompt, 8).tokens
     np.testing.assert_array_equal(want, got)
 
 
@@ -249,149 +249,13 @@ def test_ragged_batch_parity():
                   cache_dtype=jnp.float32).generate_ragged(prompts, 6).tokens
     b = Generator(params, cfg, sampler=Sampler(kind="greedy"),
                   cache_dtype=jnp.float32,
-                  decode_attn_impl="flash_decode").generate_ragged(prompts, 6).tokens
+                  decode_attn="flash_decode").generate_ragged(prompts, 6).tokens
     np.testing.assert_array_equal(a, b)
 
 
 # ---------------------------------------------------------------------------
-# Paged (block-table) variant: the serving-pool kernel (serve/block_pool.py
-# layout).  Equivalence contract from its docstring: row b attends to pool
-# slot tables[b, pos // BS] * BS + pos % BS for pads[b] <= pos < lengths[b]
-# — i.e. gathering the row's blocks contiguous and masking must match.
-# ---------------------------------------------------------------------------
-
-def _paged_reference(q, pages_k, pages_v, tables, lengths, pads, *,
-                     scale, logit_softcap=None):
-    b, mb = tables.shape
-    bs = pages_k.shape[1]
-    kh, d = pages_k.shape[-2:]
-    gk = pages_k[tables].reshape(b, mb * bs, kh, d)
-    gv = pages_v[tables].reshape(b, mb * bs, kh, d)
-    pos = jnp.arange(mb * bs)[None, :]
-    mask = (pos >= pads[:, None]) & (pos < lengths[:, None])
-    return gqa_attention(q, gk, gv, mask[:, None, :], scale=scale,
-                         logit_softcap=logit_softcap)
-
-
-@pytest.mark.parametrize("h,kh", [(4, 4), (8, 2), (4, 1)])
-def test_paged_matches_gathered_contiguous(h, kh):
-    from llm_np_cp_tpu.ops.pallas.decode_attention import paged_decode_attention
-
-    rng = np.random.default_rng(h * 7 + kh)
-    b, d, nbp, bs, mb = 3, 16, 8, 16, 4
-    q = _rand(rng, (b, 1, h, d))
-    pages_k = _rand(rng, (nbp, bs, kh, d))
-    pages_v = _rand(rng, (nbp, bs, kh, d))
-    # permuted tables with scratch-0 padding past each row's allocation
-    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [7, 6, 5, 4]], jnp.int32)
-    lengths = jnp.asarray([40, 17, 64], jnp.int32)  # mid-block, 1-past, full
-    pads = jnp.asarray([3, 0, 10], jnp.int32)
-    want = _paged_reference(q, pages_k, pages_v, tables, lengths, pads,
-                            scale=d**-0.5)
-    got = paged_decode_attention(q, pages_k, pages_v, tables, lengths, pads,
-                                 scale=d**-0.5)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
-def test_paged_softcap_parity():
-    from llm_np_cp_tpu.ops.pallas.decode_attention import paged_decode_attention
-
-    rng = np.random.default_rng(0)
-    b, h, kh, d, nbp, bs, mb = 2, 4, 2, 8, 6, 8, 3
-    q = _rand(rng, (b, 1, h, d)) * 3
-    pages_k = _rand(rng, (nbp, bs, kh, d)) * 3
-    pages_v = _rand(rng, (nbp, bs, kh, d))
-    tables = jnp.asarray([[5, 1, 2], [3, 4, 0]], jnp.int32)
-    lengths = jnp.asarray([24, 9], jnp.int32)
-    pads = jnp.asarray([2, 0], jnp.int32)
-    want = _paged_reference(q, pages_k, pages_v, tables, lengths, pads,
-                            scale=0.5, logit_softcap=20.0)
-    got = paged_decode_attention(q, pages_k, pages_v, tables, lengths, pads,
-                                 scale=0.5, logit_softcap=20.0)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
-def test_paged_int8_pool_matches_dequantized_gather():
-    """int8 pool blocks + scale pages through the paged kernel must match
-    the gathered-dequantized oracle bit-for-bit in f32 (the serve
-    engine's int8 pool decodes through this path under
-    attn_impl='paged')."""
-    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
-    from llm_np_cp_tpu.ops.pallas.decode_attention import paged_decode_attention
-
-    rng = np.random.default_rng(21)
-    b, h, kh, d, nbp, bs = 3, 8, 2, 16, 8, 16
-    q = _rand(rng, (b, 1, h, d))
-    kq, ks = quantize_kv(_rand(rng, (nbp, bs, kh, d)))
-    vq, vs = quantize_kv(_rand(rng, (nbp, bs, kh, d)))
-    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [7, 6, 5, 4]], jnp.int32)
-    lengths = jnp.asarray([40, 17, 64], jnp.int32)
-    pads = jnp.asarray([3, 0, 10], jnp.int32)
-    want = _paged_reference(
-        q, dequantize_kv(kq, ks, jnp.float32),
-        dequantize_kv(vq, vs, jnp.float32),
-        tables, lengths, pads, scale=d**-0.5,
-    )
-    got = paged_decode_attention(
-        q, kq, vq, tables, lengths, pads, k_scale=ks, v_scale=vs,
-        scale=d**-0.5,
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
-def test_paged_int8_requires_both_scales():
-    """int8 pages without scale pages (or scales with float pages) must
-    refuse rather than misread quantized blocks as floats."""
-    from llm_np_cp_tpu.ops.pallas.decode_attention import paged_decode_attention
-
-    q = jnp.zeros((1, 1, 4, 8))
-    pages = jnp.zeros((2, 8, 2, 8), jnp.int8)
-    scales = jnp.zeros((2, 8, 2), jnp.float32)
-    args = (jnp.zeros((1, 1), jnp.int32), jnp.asarray([4], jnp.int32),
-            jnp.asarray([0], jnp.int32))
-    with pytest.raises(ValueError, match="k_scale"):
-        paged_decode_attention(q, pages, pages, *args, scale=0.35)
-    with pytest.raises(ValueError, match="k_scale"):
-        paged_decode_attention(
-            q, pages, pages, *args, k_scale=scales, scale=0.35
-        )
-    with pytest.raises(ValueError, match="k_scale"):
-        paged_decode_attention(
-            q, pages.astype(jnp.float32), pages.astype(jnp.float32), *args,
-            k_scale=scales, v_scale=scales, scale=0.35,
-        )
-
-
-def test_paged_leading_block_skip_parity():
-    """Rows whose left pads span WHOLE blocks (start = pads // BS > 0):
-    the kernel's grid clamp (start + j < nb) and the scalar-prefetch
-    index map both begin at the first visible block, and nothing else in
-    the suite exercises start > 0 — yet the engine's bench config
-    (prefill_chunk = 2*block_size) routinely produces pads >= BS."""
-    from llm_np_cp_tpu.ops.pallas.decode_attention import paged_decode_attention
-
-    rng = np.random.default_rng(42)
-    b, h, kh, d, nbp, bs = 3, 8, 2, 16, 10, 8
-    q = _rand(rng, (b, 1, h, d))
-    pages_k = _rand(rng, (nbp, bs, kh, d))
-    pages_v = _rand(rng, (nbp, bs, kh, d))
-    tables = jnp.asarray(
-        [[1, 2, 3, 4], [5, 6, 7, 0], [9, 8, 7, 6]], jnp.int32
-    )
-    # start blocks 1, 2, 3: mid-block pad, exact-boundary pad, and a row
-    # whose single visible block is its LAST
-    lengths = jnp.asarray([30, 24, 32], jnp.int32)
-    pads = jnp.asarray([9, 16, 25], jnp.int32)
-    want = _paged_reference(q, pages_k, pages_v, tables, lengths, pads,
-                            scale=d**-0.5)
-    got = paged_decode_attention(q, pages_k, pages_v, tables, lengths, pads,
-                                 scale=d**-0.5)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# Ragged variant: the kernel the served tick runs (ServeEngine.mixed_step).
-# The same cases as the paged kernel above, on ``ragged_paged_attention``
+# Ragged variant: the kernel the served tick runs (ServeEngine.mixed_step),
+# over the serving pool (serve/block_pool.py layout): ``ragged_paged_attention``
 # against BOTH its XLA twin (the probe-failure fallback the engine degrades
 # to) and an independent per-token ``gqa_attention`` reference, then a
 # matrix of row mixes the packer produces: decode rows, prefill chunks and
@@ -548,7 +412,7 @@ def test_ragged_int8_pool_matches_dequantized_gather():
     """int8 pool blocks + scale pages through the ragged kernel must
     match the gathered-dequantized oracle in f32 (``--cache-dtype int8``
     serves through this path)."""
-    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
+    from llm_np_cp_tpu.quant import dequantize_kv, quantize_kv
 
     rng = np.random.default_rng(21)
     h, kh, d, nbp, bs = 8, 2, 16, 8, 16
@@ -715,7 +579,7 @@ def _group_pool(rng, kh, d, mb, rows):
 
 @pytest.mark.parametrize("case", list(_GROUP_CASES))
 def test_ragged_groups_of_pages(case):
-    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
+    from llm_np_cp_tpu.quant import dequantize_kv, quantize_kv
     from llm_np_cp_tpu.ops.pallas.decode_attention import (
         ragged_pages_per_step,
     )
@@ -947,7 +811,7 @@ _ONE_TOKEN_CASES = {
 def _one_token_run(case):
     """→ (kernel's result, twin's, reference's or None, live lanes, each
     tile's live tokens)."""
-    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
+    from llm_np_cp_tpu.quant import dequantize_kv, quantize_kv
     from llm_np_cp_tpu.ops.pallas.decode_attention import (
         ragged_paged_attention,
         ragged_paged_attention_xla,

@@ -583,8 +583,7 @@ class _Tier:
     (dict(host_tier=_Tier()), "host_tier"),
     (dict(spec_k=2), "--spec-k"),
     (dict(mesh_plan=MeshPlan(model=2)), "--mesh model>1"),
-    (dict(mixed_step="off"), "--mixed-step off"),
-], ids=["prefix-cache", "prefix-cache+tier", "tier", "spec-k", "mesh", "mixed-off"])
+], ids=["prefix-cache", "prefix-cache+tier", "tier", "spec-k", "mesh"])
 def test_start_up_refusals_name_the_flag(tiny, kw, flag):
     cfg, params = tiny
     pattern = ("host_tier" if flag == "host_tier" else

@@ -49,7 +49,7 @@ def test_forced_compile_failure_degrades_to_xla(tiny_model, clean_probe_cache, c
     with caplog.at_level("WARNING", logger="llm_np_cp_tpu"):
         gated = Generator(params, cfg, sampler=Sampler(kind="greedy"),
                           cache_dtype=jnp.float32,
-                          decode_attn_impl="flash_decode",
+                          decode_attn="flash_decode",
                           prefill_attn_impl="flash")
     assert "falling back to the XLA attention path" in caplog.text
     out = np.asarray(gated.generate(prompt, max_new_tokens=12, seed=0).tokens)

@@ -244,7 +244,6 @@ def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS, slots=SLOTS,
     engine = ServeEngine(
         abstract, cfg, max_slots=slots, num_blocks=blocks, block_size=BLOCK,
         max_seq_len=BLOCK * 8, prefill_chunk=chunk, cache_dtype=cache_dtype,
-        mixed_step="on",
     )
     assert engine.mixed and engine.ragged_attn_impl == "pallas"
 

@@ -16,8 +16,6 @@ import pytest
 
 from llm_np_cp_tpu.cache import (
     KVCache,
-    dequantize_kv,
-    quantize_kv,
     truncate,
     update_layer_quantized,
 )
@@ -25,6 +23,7 @@ from llm_np_cp_tpu.config import tiny_config
 from llm_np_cp_tpu.generate import Generator
 from llm_np_cp_tpu.models.transformer import forward, init_params
 from llm_np_cp_tpu.ops.sampling import Sampler
+from llm_np_cp_tpu.quant import dequantize_kv, quantize_kv
 
 
 @pytest.fixture(scope="module")
@@ -171,13 +170,12 @@ def test_int8_cache_flash_decode_parity(model):
                   cache_dtype=jnp.int8).generate(prompt, 10).tokens
     b = Generator(params, config, sampler=Sampler(kind="greedy"),
                   cache_dtype=jnp.int8,
-                  decode_attn_impl="flash_decode").generate(prompt, 10).tokens
+                  decode_attn="flash_decode").generate(prompt, 10).tokens
     np.testing.assert_array_equal(a, b)
 
 
 def test_decode_attention_int8_kernel_matches_dequant():
     """Kernel-level: int8+scales input == dequantize-then-attend."""
-    from llm_np_cp_tpu.cache import dequantize_kv, quantize_kv
     from llm_np_cp_tpu.ops.pallas.decode_attention import decode_attention
 
     rng = np.random.default_rng(8)

@@ -448,7 +448,6 @@ def test_int8_conv_state_fails_the_tolerance(tiny):
     (dict(enable_prefix_cache=True), "--prefix-cache"),
     (dict(spec_k=2), "--spec-k"),
     (dict(mesh_plan=MeshPlan(model=2)), "--mesh model>1"),
-    (dict(mixed_step="off"), "--mixed-step off"),
 ])
 def test_start_up_refusals_name_the_flag(tiny, kw, flag):
     cfg, params = tiny

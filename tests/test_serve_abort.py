@@ -150,16 +150,13 @@ def test_abort_decrefs_shared_prefix_without_corrupting_sharers(tiny):
     assert stats["cache_only"] == stats["allocated"]
 
 
-@pytest.mark.parametrize(
-    "tick_kw", [{}, {"mixed_step": "off"}], ids=["unified", "split"])
-def test_abort_churn_never_recompiles_decode(tiny, tick_kw):
+def test_abort_churn_never_recompiles_decode(tiny):
     """The compile-counter lint over an abort-churn trace: interleaved
     submits and aborts across queued/running states stay within the
     static-shape bounds — the step compiles at most once a packed-width
-    bucket (the default engine), decode exactly once (the split tick)."""
+    bucket."""
     cfg, params = tiny
-    engine = _engine(cfg, params, **tick_kw)
-    assert engine.mixed == (not tick_kw)
+    engine = _engine(cfg, params)
     rng = np.random.default_rng(4)
     lens = (5, 9, 13)
     for round_ in range(4):
@@ -170,18 +167,10 @@ def test_abort_churn_never_recompiles_decode(tiny, tick_kw):
         engine.step()
         engine.abort(live[round_ % len(live)].req_id)
         engine.run_until_complete()
-    chunk = engine.prefill_chunk
-    shapes = {
-        engine.pool.blocks_for(-(-n // chunk) * chunk) for n in lens
-    }
-    assert_serve_compiles_bounded(engine,
-                                  distinct_prefill_shapes=len(shapes))
+    assert_serve_compiles_bounded(engine)
     counts = engine.compile_counts()
-    if engine.mixed:
-        assert set(counts) == {"mixed_step"}
-        assert counts["mixed_step"] <= len(engine.mixed_buckets)
-    else:
-        assert counts["decode_step"] == 1
+    assert set(counts) == {"mixed_step"}
+    assert counts["mixed_step"] <= len(engine.mixed_buckets)
     assert engine.pool.stats()["request_held"] == 0
 
 
@@ -341,7 +330,7 @@ def test_abort_leaves_generated_equal_to_what_the_callback_was_handed(
     the row's in-flight result is skipped, the pool is whole and the
     peer's stream is untouched."""
     cfg, params = tiny
-    engine = _engine(cfg, params, max_slots=2, mixed_step="on")
+    engine = _engine(cfg, params, max_slots=2)
     rng = np.random.default_rng(35)
     log: dict[int, list] = {0: [], 1: []}
 

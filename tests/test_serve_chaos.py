@@ -1,8 +1,8 @@
 """Fault injection + supervised recovery (serve/faults.py, the
-EngineRunner supervisor, and the runtime paged→gather fallback).
+EngineRunner supervisor, and the runtime Pallas→XLA fallback).
 
 The contract being pinned: a crash is a blip, not an outage.  Under a
-seeded chaos schedule — tick-thread crash mid-decode, a paged-kernel
+seeded chaos schedule — tick-thread crash mid-decode, a kernel
 dispatch fault, transient 429s — every stream still completes, recovered
 requests are TOKEN-IDENTICAL to a fault-free offline run (the
 evict-requeue teacher-forcing discipline applied across an engine
@@ -127,61 +127,18 @@ def test_injector_probabilistic_sites_have_independent_streams():
 
 
 # ---------------------------------------------------------------------------
-# Runtime kernel degradation (paged dispatch fault → gather fallback)
+# Runtime kernel degradation (a dispatch fault → the XLA twins)
 # ---------------------------------------------------------------------------
-
-def test_decode_fault_degrades_paged_to_gather_token_identical(tiny):
-    """A paged decode-dispatch fault must cost one slower tick, not a
-    request: the engine permanently falls back to the gather impl (for
-    the whole process — the probe gate reports the kernel unavailable
-    afterwards) and the output stays token-identical."""
-    cfg, params = tiny
-    inj = FaultInjector("decode@2")
-    engine = _engine(cfg, params, decode_attn_impl="paged",
-                     mixed_step="off", fault_injector=inj)
-    assert engine.decode_attn_impl == "paged"
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (6, 11)]
-    reqs = [engine.submit(p, 6, seed=i) for i, p in enumerate(prompts)]
-    engine.run_until_complete()
-
-    assert engine.decode_attn_impl == "xla"
-    assert engine.decode_degraded and "injected" in engine.decode_degraded
-    assert inj.injected["decode"] == 1
-    for req, p in zip(reqs, prompts):
-        assert req.generated == _offline(cfg, params, p, 6)
-    # process-wide: the gate now refuses the faulted kernel, so a
-    # supervisor rebuild (or any later engine) selects gather
-    assert support.kernel_error("paged_decode_attention") is not None
-    assert support.gate_attn_impl("paged") == "xla"
-    assert _engine(cfg, params, decode_attn_impl="paged",
-                   mixed_step="off").decode_attn_impl == "xla"
-
-
-def test_decode_fault_on_gather_impl_propagates(tiny):
-    """No fallback below gather + the XLA sampling tail: the fault
-    surfaces (and a supervisor, not the engine, owns it).  With the
-    fused epilogue active a gather engine still has ONE step down —
-    the epilogue degrades to the XLA tail and the tick retries — so
-    the floor is pinned with ``sample_epilogue="off"``."""
-    cfg, params = tiny
-    engine = _engine(cfg, params, sample_epilogue="off", mixed_step="off",
-                     fault_injector=FaultInjector("decode@1"))
-    assert engine.epilogue_impl == "xla"
-    engine.submit(np.asarray([3, 5, 7], np.int32), 4)
-    with pytest.raises(FaultInjected):
-        engine.run_until_complete()
-
 
 def test_decode_fault_on_the_xla_tick_propagates(tiny, monkeypatch):
     """The served tick's floor: a process whose Mosaic probes fail
     resolves to the unified tick over the XLA ragged attention and the
-    XLA tail (``mixed_step="on"``), and there a dispatch fault has
+    XLA tail, and there a dispatch fault has
     nothing left to degrade to — it surfaces for the supervisor."""
     monkeypatch.setattr(support, "_FORCE_FAIL", True)
     support._probe.cache_clear()  # conftest clears it again afterwards
     cfg, params = tiny
-    engine = _engine(cfg, params, mixed_step="on",
+    engine = _engine(cfg, params,
                      fault_injector=FaultInjector("decode@1"))
     assert engine.mixed
     assert (engine.ragged_attn_impl, engine.epilogue_impl) == ("xla", "xla")
@@ -192,9 +149,9 @@ def test_decode_fault_on_the_xla_tick_propagates(tiny, monkeypatch):
 
 
 def test_decode_fault_degrades_fused_epilogue_then_propagates(tiny):
-    """The new floor semantics: on a gather engine with the fused
-    epilogue, the FIRST decode fault degrades the epilogue to the XLA
-    tail (process-wide, requests finish token-identically); once fully
+    """The floor: the FIRST decode fault degrades the tick's kernels —
+    ragged attention and the fused epilogue, as a unit — to their XLA
+    twins (process-wide, requests finish token-identically); once fully
     on XLA the next fault propagates."""
     cfg, params = tiny
     inj = FaultInjector("decode@2")
@@ -210,7 +167,7 @@ def test_decode_fault_degrades_fused_epilogue_then_propagates(tiny):
         assert support.kernel_error("sample_epilogue") is not None
         for req, p in zip(reqs, prompts):
             assert req.generated == _offline(cfg, params, p, 5)
-        # nothing left below gather+XLA-tail: the next fault surfaces
+        # nothing left below the XLA twins: the next fault surfaces
         engine.faults = FaultInjector("decode@1")
         engine.submit(prompts[0], 3)
         with pytest.raises(FaultInjected):
@@ -243,6 +200,10 @@ def test_restart_recovery_token_identical_and_zero_recompiles(tiny):
     shares the compiled step programs)."""
     cfg, params = tiny
     engine = _engine(cfg, params, max_slots=4)
+    # (every program warm, as a server's are before it listens: the replay
+    # packs ticks the first four never did, and the counter below counts a
+    # program's first compile like any other)
+    engine.warmup([6, 11, 17], max_new_tokens=8)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (6, 11, 17)]
     reqs = [engine.submit(p, 8, seed=i) for i, p in enumerate(prompts)]
@@ -299,7 +260,7 @@ def test_watchdog_restarts_hung_tick_and_stream_completes(tiny):
     rebuilt engine replays the stream, and the client sees one complete,
     token-identical response."""
     cfg, params = tiny
-    inj = FaultInjector("tick_hang@2=1.0")
+    inj = FaultInjector("tick_hang@2=3.0")
     engine = _engine(cfg, params, fault_injector=inj)
     prompt, n = [5] * 6, 6
     # compile outside the watchdog's clock: a first-tick jit compile on
@@ -307,8 +268,11 @@ def test_watchdog_restarts_hung_tick_and_stream_completes(tiny):
     engine.warmup([len(prompt)], max_new_tokens=n)
 
     async def main():
+        # (a deadline an honest tick of a loaded sandbox stays well
+        # under — 0.2 s read a 0.24 s tick as hung, PR 46's whole run —
+        # and a hang three times as long: the count below stays exact)
         srv = HttpServer(engine, model_id="tiny", drain_timeout=10.0,
-                         tick_deadline=0.2, max_restarts=2,
+                         tick_deadline=1.0, max_restarts=2,
                          restart_backoff_s=0.05)
         await srv.start("127.0.0.1", 0)
         res = await astream_completion(

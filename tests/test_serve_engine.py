@@ -14,7 +14,6 @@ the parity traffic doubles as the jit-stability evidence.
 import sys
 
 import jax
-import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -35,38 +34,44 @@ def tiny():
     return cfg, params
 
 
-def _offline_tokens(gen: Generator, req) -> list[int]:
-    res = gen.generate_ragged([req.prompt], req.max_new_tokens, seed=req.seed)
+# the parity oracle, one a (weights, cache dtype) of this module: its
+# programs compile once a prompt length and budget
+_ORACLES: dict = {}
+
+
+def _offline_tokens(cfg, params, cache_dtype, req) -> list[int]:
+    key = (id(params), jnp.dtype(cache_dtype).name)
+    if key not in _ORACLES:
+        # (``params`` is kept: its id stays this tree's)
+        _ORACLES[key] = (Generator(
+            params, cfg, sampler=Sampler(kind="greedy"),
+            cache_dtype=cache_dtype), params)
+    res = _ORACLES[key][0].generate_ragged(
+        [req.prompt], req.max_new_tokens, seed=req.seed)
     return [int(t) for t in np.asarray(res.tokens)[0][: req.max_new_tokens]]
 
 
 def _assert_parity(engine: ServeEngine, cfg, params, cache_dtype) -> None:
-    gen = Generator(
-        params, cfg, sampler=Sampler(kind="greedy"), cache_dtype=cache_dtype
-    )
     assert engine.scheduler.finished, "nothing finished — bad test setup"
     for req in engine.scheduler.finished:
-        assert req.generated == _offline_tokens(gen, req), (
+        assert req.generated == _offline_tokens(cfg, params, cache_dtype, req), (
             f"request {req.req_id} (preempted {req.n_preemptions}x) diverged "
             "from the offline run"
         )
 
 
-@pytest.mark.parametrize(
-    "tick_kw", [{}, {"mixed_step": "off"}], ids=["unified", "split"])
-def test_trace_parity_32_requests_and_bounded_compiles(tiny, tick_kw):
+def test_trace_parity_32_requests_and_bounded_compiles(tiny):
     """The acceptance criterion: a 32-request Poisson trace through the
     engine produces per-request greedy tokens identical to offline
-    ``generate_ragged``, and the jitted steps compile once per packed
-    width bucket (the default engine: the tick that is served) or once
-    per distinct phase shape (the phase-split tick) — never per tick."""
+    ``generate_ragged``, and the jitted step compiles once per packed
+    width bucket — never per tick."""
     cfg, params = tiny
     engine = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"),
         max_slots=4, num_blocks=48, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, **tick_kw,
+        cache_dtype=jnp.float32,
     )
-    assert engine.mixed == (not tick_kw)
+    assert engine.mixed
     rng = np.random.default_rng(0)
     trace = poisson_trace(
         rng, 32, rate_rps=40.0, prompt_len_range=(3, 14),
@@ -76,24 +81,12 @@ def test_trace_parity_32_requests_and_bounded_compiles(tiny, tick_kw):
     assert snap["finished"] == 32
     _assert_parity(engine, cfg, params, jnp.float32)
 
-    # distinct prefill shapes == distinct block allocations at prefill
-    # time (no preemptions here, so each request prefilled its prompt
-    # rounded up to whole chunks)
-    chunk = engine.prefill_chunk
-    shapes = {
-        engine.pool.blocks_for(-(-r.prompt_len // chunk) * chunk)
-        for r in engine.scheduler.finished
-    }
     assert engine.scheduler.n_preemptions == 0
-    assert_serve_compiles_bounded(engine, distinct_prefill_shapes=len(shapes))
+    assert_serve_compiles_bounded(engine)
     counts = engine.compile_counts()
-    if engine.mixed:
-        assert set(counts) == {"mixed_step"}
-        assert 1 <= counts["mixed_step"] <= len(engine.mixed_buckets)
-        assert snap["ticks"] > counts["mixed_step"]
-        return
-    assert counts["decode_step"] == 1
-    assert snap["ticks"] > counts["decode_step"] + counts["prefill_step"]
+    assert set(counts) == {"mixed_step"}
+    assert 1 <= counts["mixed_step"] <= len(engine.mixed_buckets)
+    assert snap["ticks"] > counts["mixed_step"]
 
 
 def test_eviction_requeue_parity(tiny):
@@ -120,8 +113,8 @@ def test_eviction_requeue_parity(tiny):
 
 
 def test_int8_block_pool_parity(tiny):
-    """int8 pool blocks (quantize on write, dequantize on gather — the
-    cache.quantize_kv discipline) must decode exactly like the
+    """int8 pool blocks (quantize on write, dequantize on read — the
+    quant.quantize_kv discipline) must decode exactly like the
     contiguous int8 ``KVCache``: same greedy tokens on the tiny
     fixture."""
     cfg, params = tiny
@@ -204,235 +197,162 @@ def test_submit_rejects_unadmittable_request(tiny):
 
 
 # ---------------------------------------------------------------------------
-# attn_impl="paged": the zero-gather decode path.  Same acceptance bar as
-# the gather path — offline parity, one decode compile — plus a structural
-# assertion that the [L, B, S_max] gathered view never exists in the traced
-# program.
+# ``step()``'s ONE contract: what it returns, and when a token reaches its
+# callback.
 # ---------------------------------------------------------------------------
 
-def _iter_eqns(jaxpr):
-    """Every eqn in ``jaxpr`` and all nested sub-jaxprs (pjit/scan/...)."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for v in eqn.params.values():
-            yield from _iter_param_eqns(v)
+class _Stream:
+    """Logging callbacks of one engine: per request the tokens handed
+    out, and every event in arrival order."""
+
+    def __init__(self):
+        self.tokens: dict[int, list[int]] = {}
+        self.events: list[tuple] = []
+
+    def callback(self, req, token, delta):
+        self.tokens.setdefault(req.req_id, []).append(token)
+        self.events.append((req.req_id, "token", token))
+
+    def on_event(self, req, event):
+        self.events.append((req.req_id, event))
+
+    def of(self, req):
+        return self.tokens.get(req.req_id, [])
 
 
-def _iter_param_eqns(v):
-    if isinstance(v, jax.extend.core.ClosedJaxpr):
-        yield from _iter_eqns(v.jaxpr)
-    elif isinstance(v, jax.extend.core.Jaxpr):
-        yield from _iter_eqns(v)
-    elif isinstance(v, (tuple, list)):
-        for x in v:
-            yield from _iter_param_eqns(x)
+def _contract_engine(cfg, params, spec, **kw):
+    return ServeEngine(
+        params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
+        num_blocks=24, block_size=8, max_seq_len=64,
+        cache_dtype=jnp.float32, spec_k=3 if spec else 0, **kw)
 
 
-def _decode_step_shapes(engine: ServeEngine) -> set[tuple[int, ...]]:
-    """Output shapes of every eqn in the traced decode step."""
-    b = engine.scheduler.max_slots
-    mb = engine.max_blocks_per_seq
-    args = (
-        engine.params, engine.pool.pages,
-        jnp.zeros((b, mb), jnp.int32), jnp.zeros((b,), jnp.int32),
-        jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-        jnp.zeros((b,), jnp.uint32),
-    )
-    jaxpr = jax.make_jaxpr(lambda *a: engine._decode_step(*a))(*args)
-    return {
-        tuple(eqn_var.aval.shape)
-        for eqn in _iter_eqns(jaxpr.jaxpr)
-        for eqn_var in eqn.outvars
-        if hasattr(eqn_var.aval, "shape")
-    }
+def _step_held_to_contract(engine, stream, reqs):
+    """One ``step()``, held to its contract for every request of
+    ``reqs``; returns what it returned."""
+    before = {r.req_id: list(r.generated) for r in reqs}
+    more = engine.step()
+    for r in reqs:
+        handed = stream.of(r)
+        # what was accepted before this step has been handed out by now
+        # (one tick late, never two), in order, each token once
+        if r.finish_reason != "aborted":
+            assert handed[:len(before[r.req_id])] == before[r.req_id]
+        # nothing is handed out that was not accepted
+        assert r.generated[:len(handed)] == handed
+        # ...and ``generated`` is at most this tick's tokens ahead
+        assert len(r.generated) - len(handed) <= engine.spec_k + 1
+    if not more:
+        # False: no work is left and nothing is owed
+        assert not engine._owed and engine.publish_owed() == 0
+        assert all(stream.of(r) == r.generated for r in reqs)
+    return more
 
 
-def test_paged_trace_parity_32_requests_and_bounded_compiles(tiny):
-    """The gather-path acceptance criterion, re-run under
-    attn_impl='paged' (CPU interpret mode runs the same kernel logic the
-    TPU compiles): 32-request trace == offline generate_ragged, decode
-    compiles ONCE."""
+@pytest.mark.parametrize("spec", [False, True], ids=["spec-off", "spec-on"])
+@pytest.mark.parametrize("scenario", [
+    "dispatching", "idle", "abort-empties-the-engine", "recovery-replay"])
+def test_step_has_one_contract(tiny, scenario, spec):
+    """``step()`` returns True while work remains or a token is owed;
+    the tokens and terminals a tick accepted reach the callbacks during
+    the NEXT ``step()``, per request in order and exactly once, or before
+    this one returns when it dispatched nothing or leaves no work."""
     cfg, params = tiny
-    engine = ServeEngine(
-        params, cfg, sampler=Sampler(kind="greedy"),
-        max_slots=4, num_blocks=48, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, decode_attn_impl="paged", mixed_step="off",
-    )
-    assert engine.decode_attn_impl == "paged"
-    rng = np.random.default_rng(0)
-    trace = poisson_trace(
-        rng, 32, rate_rps=40.0, prompt_len_range=(3, 14),
-        max_new_tokens=6, vocab_size=cfg.vocab_size,
-    )
-    snap = engine.replay_trace(trace)
-    assert snap["finished"] == 32
-    _assert_parity(engine, cfg, params, jnp.float32)
-    counts = engine.compile_counts()
-    assert counts["decode_step"] == 1
-    # the paged path streams less cache than the gather view per tick
-    assert 0 < snap["kv_bytes_tick_mean"]
+    stream = _Stream()
+    engine = _contract_engine(cfg, params, spec)
+    # a repeating prompt: a spec engine's drafts are taken
+    prompt = np.resize(np.asarray([5, 9, 3], np.int32), 11)
+    submit = dict(callback=stream.callback, on_event=stream.on_event,
+                  speculative=spec)
 
+    if scenario == "idle":
+        # nothing to do: False, no dispatch, no event — however often
+        assert not engine.step() and not engine.step()
+        assert engine.n_dispatches == 0 and not stream.events
+        # one token: the tick that accepts it leaves no work, so it is
+        # handed out before that ``step()`` returns
+        req = engine.submit(prompt[:5], 1, **submit)  # (one chunk)
+        assert not _step_held_to_contract(engine, stream, [req])
+        assert stream.events == [
+            (req.req_id, "token", req.generated[0]), (req.req_id, "length")]
+        n = engine.n_dispatches
+        assert not engine.step() and engine.n_dispatches == n == 1
+        return
 
-def test_paged_int8_pool_parity(tiny):
-    """int8 pool blocks flow through the paged kernel (quantize on the
-    in-scan write, scale pages streamed) with the same greedy tokens as
-    the gather path's dequantize-on-gather."""
-    cfg, params = tiny
-    engine = ServeEngine(
-        params, cfg, sampler=Sampler(kind="greedy"),
-        max_slots=3, num_blocks=16, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.int8, decode_attn_impl="paged", mixed_step="off",
-    )
-    assert engine.decode_attn_impl == "paged"
-    rng = np.random.default_rng(11)
-    for n in (6, 11, 4):
-        engine.submit(rng.integers(1, cfg.vocab_size, size=n), 5)
-    engine.run_until_complete()
-    assert len(engine.scheduler.finished) == 3
-    _assert_parity(engine, cfg, params, jnp.int8)
+    if scenario == "dispatching":
+        # (one length and one budget: the offline run compiles once)
+        reqs = [engine.submit(prompt, 8, **submit),
+                engine.submit(prompt[::-1].copy(), 8, **submit)]
+        ahead = 0
+        while _step_held_to_contract(engine, stream, reqs):
+            ahead += any(len(r.generated) > len(stream.of(r)) for r in reqs)
+        assert ahead, "no token was ever accepted a tick before its callback"
+        for r in reqs:
+            mine = [e for e in stream.events if e[0] == r.req_id]
+            assert mine[-1] == (r.req_id, "length")  # the terminal, last
+            assert [e[2] for e in mine[:-1]] == r.generated
+        _assert_parity(engine, cfg, params, jnp.float32)
+        if spec:
+            assert engine.metrics.snapshot()["spec_accepted_tokens"] > 0
+        return
 
+    if scenario == "abort-empties-the-engine":
+        # between ticks: what the request is owed goes out first, then its
+        # ``aborted``; no tick follows and ``step()`` says so
+        req = engine.submit(prompt, 20, **submit)
+        for _ in range(3):
+            assert _step_held_to_contract(engine, stream, [req])
+        assert engine._owed and len(req.generated) > len(stream.of(req))
+        assert engine.abort(req.req_id)
+        assert stream.of(req) == req.generated and not engine._owed
+        assert stream.events[-1] == (req.req_id, "aborted")
+        assert not engine.step()
+        # from inside a token callback: the tokens of that tick not yet
+        # handed out are dropped, and ``aborted`` follows the token whose
+        # callback asked for it
+        other = _Stream()
 
-def test_paged_gemma2_sliding_window_parity():
-    """Gemma-2's alternating sliding layers reach the paged kernel as an
-    effective left pad (row_pads = max(pads, vis - window)) instead of a
-    mask tensor — tokens must match the gather path exactly, or the
-    per-layer window math is off by one."""
-    cfg = tiny_config("gemma2")
-    assert cfg.sliding_window is not None
-    params = init_params(jax.random.PRNGKey(2), cfg, dtype=jnp.float32)
+        def cut(r, token, delta):
+            other.callback(r, token, delta)
+            if len(other.of(r)) == 2:
+                engine.abort(r.req_id)
 
-    def run(impl):
-        engine = ServeEngine(
-            params, cfg, sampler=Sampler(kind="greedy"),
-            max_slots=2, num_blocks=32, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, decode_attn_impl=impl, mixed_step="off",
-        )
-        rng = np.random.default_rng(5)
-        # long decodes so visible length crosses the window bound and
-        # several block boundaries on both layer kinds
-        for n in (9, 13):
-            engine.submit(rng.integers(1, cfg.vocab_size, size=n), 16)
-        engine.run_until_complete()
-        return {r.req_id: r.generated for r in engine.scheduler.finished}
+        late = engine.submit(prompt, 20, callback=cut,
+                             on_event=other.on_event, speculative=spec)
+        while engine.step():
+            pass
+        assert other.of(late) == late.generated and len(late.generated) == 2
+        assert other.events[-2:] == [
+            (late.req_id, "token", late.generated[1]),
+            (late.req_id, "aborted")]
+        assert engine.pool.stats()["request_held"] == 0
+        return
 
-    assert run("xla") == run("paged")
-
-
-def test_paged_decode_step_has_no_materialized_gather(tiny):
-    """Structural zero-gather assertion: the gathered cache view
-    [L, B, S_max, K, D] (or its per-layer [B, S_max, K, D] slice) exists
-    in the gather step's jaxpr and in NO eqn of the paged step's."""
-    cfg, params = tiny
-
-    def build(impl):
-        return ServeEngine(
-            params, cfg, sampler=Sampler(kind="greedy"),
-            max_slots=4, num_blocks=16, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, decode_attn_impl=impl, mixed_step="off",
-        )
-
-    l = cfg.num_hidden_layers
-    kh, d = cfg.num_key_value_heads, cfg.head_dim
-    b, s_max = 4, 64
-    gathered = {(l, b, s_max, kh, d), (b, s_max, kh, d)}
-
-    gather_shapes = _decode_step_shapes(build("xla"))
-    assert gathered & gather_shapes, (
-        "control failed: the gather step no longer materializes the "
-        "gathered view — update this test's shape expectations"
-    )
-    paged_shapes = _decode_step_shapes(build("paged"))
-    hit = gathered & paged_shapes
-    assert not hit, (
-        f"attn_impl='paged' materialized a gathered cache view {hit} — "
-        "the zero-gather contract is broken"
-    )
-
-
-def test_engine_rejects_unknown_decode_impl(tiny):
-    cfg, params = tiny
-    with pytest.raises(ValueError, match="decode_attn_impl"):
-        ServeEngine(params, cfg, decode_attn_impl="pallas")
-
-
-def test_paged_falls_back_to_xla_when_probe_fails(tiny, monkeypatch):
-    """The hardware gate: when Mosaic rejects the paged kernel the
-    engine downgrades to the gather path with a warning instead of dying
-    at first dispatch."""
-    import llm_np_cp_tpu.ops.pallas.support as support
-
-    monkeypatch.setattr(support, "_FORCE_FAIL", True)
-    support._probe.cache_clear()
-    try:
-        cfg, params = tiny
-        engine = ServeEngine(
-            params, cfg, max_slots=2, num_blocks=16, block_size=8,
-            max_seq_len=64, cache_dtype=jnp.float32,
-            decode_attn_impl="paged", mixed_step="off",
-        )
-        assert engine.decode_attn_impl == "xla"
-    finally:
-        support._probe.cache_clear()
-
-
-# ---------------------------------------------------------------------------
-# Refcounted prefix sharing: identical prompts reuse prompt blocks; a hit
-# must skip prefill chunks without changing a single output token.
-# ---------------------------------------------------------------------------
-
-def _count_prefill_calls(engine):
-    calls = [0]
-    orig = engine._prefill_step
-
-    def counting(*a, **k):
-        calls[0] += 1
-        return orig(*a, **k)
-
-    engine._prefill_step = counting
-    return calls
-
-
-@pytest.mark.parametrize("impl", ["xla", "paged"])
-def test_prefix_sharing_parity_and_fewer_prefill_dispatches(tiny, impl):
-    """4 repeats of 2 distinct prompts: the shared run must emit the
-    exact tokens of the unshared run (and offline), dispatch strictly
-    fewer prefill chunks, and report the hit rate in the metrics."""
-    cfg, params = tiny
-    rng = np.random.default_rng(3)
-    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (20, 17)]
-
-    def run(prefix: bool):
-        engine = ServeEngine(
-            params, cfg, sampler=Sampler(kind="greedy"),
-            max_slots=4, num_blocks=48, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, decode_attn_impl=impl, mixed_step="off",
-            enable_prefix_cache=prefix,
-        )
-        calls = _count_prefill_calls(engine)
-        for rep in range(4):
-            for j, p in enumerate(prompts):
-                engine.submit(p, 4, seed=j)
-        engine.run_until_complete()
-        tokens = {r.req_id: r.generated for r in engine.scheduler.finished}
-        return tokens, calls[0], engine
-
-    base_tokens, base_calls, _ = run(prefix=False)
-    shared_tokens, shared_calls, engine = run(prefix=True)
-    assert shared_tokens == base_tokens
-    assert shared_calls < base_calls, (
-        f"prefix sharing dispatched {shared_calls} prefill chunks, "
-        f"expected strictly fewer than the unshared {base_calls}"
-    )
-    snap = engine.metrics.snapshot()
-    assert snap["prefix_blocks_hit"] > 0
-    assert 0 < snap["prefix_hit_rate"] <= 1
-    _assert_parity(engine, cfg, params, jnp.float32)
-    # every request's references were released; only the cache's own
-    # remain, and they are all reclaimable
-    fl = engine.pool.free_list
-    assert fl.num_free + fl.num_allocated == fl.capacity
-    assert fl.num_allocated == len(engine.pool.prefix_cache)
-    assert engine.pool.prefix_cache.n_reclaimable == fl.num_allocated
+    assert scenario == "recovery-replay"
+    req = engine.submit(prompt, 10, request_id=7, seed=3, **submit)
+    for _ in range(3):
+        assert _step_held_to_contract(engine, stream, [req])
+    # what a supervisor reads between ticks as delivered: the gap closed
+    assert engine.publish_owed() > 0 and stream.of(req) == req.generated
+    delivered = list(req.generated)
+    assert 0 < len(delivered) < 10
+    rebuilt, replay = engine.clone_fresh(), _Stream()
+    again = rebuilt.recover(
+        prompt, 10, request_id=7, seed=3, generated=delivered,
+        callback=replay.callback, on_event=replay.on_event,
+        speculative=spec)
+    while True:
+        before = list(again.generated)
+        more = rebuilt.step()
+        handed = delivered + replay.of(again)
+        assert handed[:len(before)] == before
+        assert again.generated[:len(handed)] == handed
+        if not more:
+            break
+    # the replayed tokens are not handed out again; the stream goes on
+    assert delivered + replay.of(again) == again.generated
+    assert replay.events[-1] == (7, "length") and not rebuilt._owed
+    _assert_parity(rebuilt, cfg, params, jnp.float32)
 
 
 def test_prefix_sharing_eviction_stress_parity(tiny):

@@ -53,15 +53,14 @@ def tiny():
     return cfg, params
 
 
-def _engine(cfg, params, epilogue="auto", mixed="on", **kw):
+def _engine(cfg, params, epilogue="auto", **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("num_blocks", 48)
     kw.setdefault("block_size", 8)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("cache_dtype", jnp.float32)
     kw.setdefault("sampler", Sampler(kind="greedy"))
-    return ServeEngine(params, cfg, mixed_step=mixed,
-                       sample_epilogue=epilogue, **kw)
+    return ServeEngine(params, cfg, sample_epilogue=epilogue, **kw)
 
 
 def _tokens(engine):
@@ -208,7 +207,6 @@ def test_engine_gate_resolution(tiny):
     cfg, params = tiny
     assert _engine(cfg, params).epilogue_impl == "fused"
     assert _engine(cfg, params, epilogue="off").epilogue_impl == "xla"
-    assert _engine(cfg, params, mixed="off").epilogue_impl == "fused"
     # non-greedy samplers keep the XLA tail (the fused draw is only
     # bit-identical for greedy) — even under "on", with a warning
     stoch = _engine(cfg, params, epilogue="on",
@@ -286,7 +284,7 @@ def test_fused_trace_parity_32_requests_bf16(tiny):
     assert fused.epilogue_impl == "fused"
     assert oracle.epilogue_impl == "xla"
     assert _tokens(fused) == _tokens(oracle)
-    assert_serve_compiles_bounded(fused, distinct_prefill_shapes=0)
+    assert_serve_compiles_bounded(fused)
     _assert_offline_parity(fused, cfg, params, jnp.bfloat16, limit=6)
 
 
@@ -540,18 +538,14 @@ def test_one_fetch_per_tick_and_summarize_host_sync(tiny, tmp_path):
     trace = poisson_trace(rng, 8, rate_rps=50.0, prompt_len_range=(3, 12),
                           max_new_tokens=6, vocab_size=cfg.vocab_size)
 
-    def tick_args(mixed):
-        tracer = TraceRecorder()
-        engine = _engine(cfg, params, mixed=mixed, tracer=tracer)
-        snap = engine.replay_trace(trace)
-        assert snap["finished"] == 8
-        return tracer, [
-            e["args"] for e in tracer.events()
-            if e.get("ph") == "X" and e.get("cat") == "tick"
-            and "host_fetches" in (e.get("args") or {})
-        ]
-
-    tracer, args = tick_args("on")
+    tracer = TraceRecorder()
+    engine = _engine(cfg, params, tracer=tracer)
+    assert engine.replay_trace(trace)["finished"] == 8
+    args = [
+        e["args"] for e in tracer.events()
+        if e.get("ph") == "X" and e.get("cat") == "tick"
+        and "host_fetches" in (e.get("args") or {})
+    ]
     assert args, "no tick args recorded"
     assert all(a["host_fetches"] <= 1 for a in args)
     dispatching = [a for a in args
@@ -561,10 +555,6 @@ def test_one_fetch_per_tick_and_summarize_host_sync(tiny, tmp_path):
         "a dispatching tick made more (or fewer) than ONE device fetch"
     )
     assert all(a["host_sync_us"] >= 0.0 for a in args)
-
-    # the split tick carries the same contract on its decode fetch
-    _, split_args = tick_args("off")
-    assert split_args and all(a["host_fetches"] <= 1 for a in split_args)
 
     # summarize_trace's host_sync column off a dumped fixture
     path = tmp_path / "fused_trace.json"

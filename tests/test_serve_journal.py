@@ -692,7 +692,10 @@ def _spawn_server(tmp_path, tag, *, port=0, journal=None, chaos=None,
     raise AssertionError(f"server {tag} never wrote its port file")
 
 
-def _drive(host, port, reqs, *, retries, timeout=150.0):
+def _drive(host, port, reqs, *, retries, timeout=150.0, max_backoff_s=2.0,
+           give_up=None):
+    """``give_up``: a ``threading.Event`` that ends the drive at once (the
+    respawn failed: no retry will ever find a server)."""
     async def main():
         async def one(i, item):
             prompt, n, seed = item
@@ -702,10 +705,26 @@ def _drive(host, port, reqs, *, retries, timeout=150.0):
                 {"prompt": prompt, "max_tokens": n, "seed": seed,
                  "stream": True},
                 timeout=timeout, retries=retries, backoff_s=0.3,
-                max_backoff_s=2.0,
+                max_backoff_s=max_backoff_s,
             )
-        return await asyncio.gather(
-            *(one(i, item) for i, item in enumerate(reqs)))
+
+        async def watch(tasks):
+            while give_up is not None and not all(t.done() for t in tasks):
+                if give_up.is_set():
+                    for t in tasks:
+                        t.cancel()
+                    return
+                await asyncio.sleep(0.1)
+
+        tasks = [asyncio.ensure_future(one(i, item))
+                 for i, item in enumerate(reqs)]
+        watcher = asyncio.ensure_future(watch(tasks))
+        try:
+            results = await asyncio.gather(*tasks)
+        except asyncio.CancelledError:
+            results = None  # gave up: the caller says why
+        await watcher
+        return results
     return asyncio.run(main())
 
 
@@ -742,29 +761,42 @@ def test_kill9_restart_resume_e2e(tiny, tmp_path):
     proc1, host, port = _spawn_server(
         tmp_path, "kill", journal=jpath, chaos="proc_kill@30")
 
+    import threading
+
     killed = {"t": None}
     respawned = {}
+    respawn_failed = threading.Event()
 
     def respawn_when_dead():
         proc1.wait()
         killed["t"] = time.perf_counter()
-        p2, h2, pt2 = _spawn_server(
-            tmp_path, "restart", port=port, journal=jpath)
-        assert (h2, pt2) == (host, port)
+        try:
+            # (returns once the new server listens: warm, ready)
+            p2, h2, pt2 = _spawn_server(
+                tmp_path, "restart", port=port, journal=jpath)
+            assert (h2, pt2) == (host, port)
+        except BaseException as e:  # noqa: BLE001 — reported below
+            respawned["error"] = e
+            respawn_failed.set()
+            return
         respawned["proc"] = p2
-
-    import threading
 
     watcher = threading.Thread(target=respawn_when_dead, daemon=True)
     watcher.start()
     try:
-        results = _drive(host, port, reqs, retries=10)
+        # the clients wait for the respawned server to LISTEN (it does
+        # once it is warm), however long its start takes on this machine:
+        # short retries whose count does not matter, ended by the
+        # respawn's own failure and by nothing else (a budget of 16-20 s
+        # of retries lost one run in three to a slow start)
+        results = _drive(host, port, reqs, retries=100_000,
+                         max_backoff_s=0.5, give_up=respawn_failed)
     finally:
         watcher.join(timeout=240)
         proc2 = respawned.get("proc")
     assert killed["t"] is not None, "proc_kill never fired"
     assert proc1.returncode == -signal.SIGKILL
-    assert proc2 is not None, "restart never came up"
+    assert proc2 is not None, f"restart never came up: {respawned.get('error')}"
 
     try:
         # byte-identical streams across the kill

@@ -83,7 +83,6 @@ def _engine(cfg, params, **kw):
     # "on" (not "auto"): the unified tick is the path plan_tick budget
     # shedding acts on, and forcing it keeps the compile-count pins
     # deterministic on CPU (XLA ragged fallback)
-    kw.setdefault("mixed_step", "on")
     return ServeEngine(params, cfg, sampler=Sampler(kind="greedy"), **kw)
 
 

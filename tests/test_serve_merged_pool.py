@@ -4,11 +4,10 @@ A float pool whose ``head_dim`` is short of a row of lanes (64) and whose
 kv heads side by side fill whole rows is allocated ``[L, NB, BS, K * D]``
 (serve/block_pool.py ``merges_pages``): the order a TPU keeps and the
 ragged kernel reads.  The benchmark's hybrid stacks run such a pool through
-the unified tick only (a recurrent state refuses the rest, engine.py); the
+the tick alone (a recurrent state refuses the rest, engine.py); the
 dense ``head_dim``-64 models (Llama-3.2-1B, Qwen2.5-0.5B) run it through
-everything else too: the split tick's insert / gather / column write, a
-prefix-cache hit read in place or gathered, a host-tier spill and restore.
-Each path here serves a tiny dense model whose pool comes out merged
+everything else too: a prefix-cache hit read in place, a host-tier spill
+and restore.  Each path here serves a tiny dense model whose pool comes out merged
 (``K * D`` = 128) and must emit, token for token, what ``models.forward``
 does on the same weights.
 
@@ -79,45 +78,26 @@ def _serve_in_turn(engine, prompts, rounds=1):
         for p in prompts:
             engine.submit(p, MAX_NEW)
             engine.run_until_complete()
-    if engine.host_tier is not None:
-        engine.host_tier.drain()
+            if engine.host_tier is not None:
+                engine.host_tier.drain()  # (the spill writer, joined)
 
 
 # path: (engine arguments, prompt lengths, how they are served, what the
 # metrics must show of the mechanism the path is named for)
 _PATHS = {
-    # the unified tick: scatter of [tokens, K * D] rows into the flat
-    # pool, the ragged kernel over merged pages
-    "unified-tick": (dict(mixed_step="on"), (13, 5, 22), "together", None),
+    # the tick: scatter of [tokens, K * D] rows into the flat pool, the
+    # ragged kernel over merged pages
+    "unified-tick": ({}, (13, 5, 22), "together", None),
     # the same tick over the XLA oracle attention (a failed kernel probe)
-    "unified-tick-xla-attention": (
-        dict(mixed_step="on"), (13, 5, 22), "together", "xla"),
-    # the split tick: prefill into a contiguous cache, the insert
-    # (scatter_prefill), decode over the gathered view, the column write
-    "split-tick-gather": (
-        dict(mixed_step="off", decode_attn_impl="xla"), (13, 5, 22),
-        "together", None),
-    # ...and its zero-gather decode step: the layer scan over slabs, the
-    # column write into a merged slab, the paged kernel given [.., K, D]
-    "split-tick-paged": (
-        dict(mixed_step="off", decode_attn_impl="paged"), (13, 5, 22),
-        "together", None),
-    # a prefix-cache hit, read in place by the unified tick
+    "unified-tick-xla-attention": ({}, (13, 5, 22), "together", "xla"),
+    # a prefix-cache hit, read in place
     "prefix-hit-unified": (
-        dict(mixed_step="on", enable_prefix_cache=True), (24, 24), "twice",
-        "prefix"),
-    # ...and copied out of the pool by the split tick (gather_prefix)
-    "prefix-hit-split": (
-        dict(mixed_step="off", enable_prefix_cache=True), (24, 24), "twice",
-        "prefix"),
+        dict(enable_prefix_cache=True), (24, 24), "twice", "prefix"),
     # blocks spilled to the host tier (slice_block) and restored
     # (restore_block) once the working set has outgrown the pool
     "host-tier-restore": (
-        dict(mixed_step="on", enable_prefix_cache=True, num_blocks=12,
-             max_slots=2), (24,) * 6, "twice", "tier"),
-    "host-tier-restore-split": (
-        dict(mixed_step="off", enable_prefix_cache=True, num_blocks=12,
-             max_slots=2), (24,) * 6, "twice", "tier"),
+        dict(enable_prefix_cache=True, num_blocks=12, max_slots=2),
+        (24,) * 6, "twice", "tier"),
 }
 
 
@@ -137,10 +117,8 @@ def test_merged_pool_token_parity_with_forward(served, path, monkeypatch):
     assert (pages.kv_heads, pages.head_dim, pages.token_shape) == (
         2, 64, (128,))
     assert engine.pool_page_shape == "8x128"
-    if engine.mixed:
-        assert engine.pool_carried
-        assert engine.ragged_attn_impl == (
-            "xla" if shows == "xla" else "pallas")
+    assert engine.pool_carried
+    assert engine.ragged_attn_impl == ("xla" if shows == "xla" else "pallas")
     prompts = _prompts(np.random.default_rng(len(path)), cfg, sizes)
     if how == "together":
         _serve_together(engine, prompts)
@@ -261,7 +239,7 @@ def test_what_says_how_the_tick_holds_the_pool(heads, shape):
                       num_attention_heads=4)
     params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
     tracer = TraceRecorder()
-    engine = _engine(cfg, params, tracer=tracer, mixed_step="on")
+    engine = _engine(cfg, params, tracer=tracer)
     events = tracer.to_dict()["traceEvents"]
     build, = (e for e in events if e.get("name") == "engine_build")
     assert build["args"]["pool_carried"] == 1
@@ -273,7 +251,4 @@ def test_what_says_how_the_tick_holds_the_pool(heads, shape):
     line, = (ln for ln in format_summary(events).splitlines()
              if ln.lstrip().startswith("pool:"))
     assert f"pages {shape}" in line and "written in place" in line
-    # the split tick's scan takes the pool by layer slabs
-    split = _engine(cfg, params, mixed_step="off")
-    assert not split.pool_carried
-    assert split.pool_form_gauges()["pool_carried"] == 0.0
+    assert engine.pool_form_gauges()["pool_carried"] == 1.0

@@ -52,7 +52,6 @@ def _engine(cfg, params, plan=None, devices=None, **kw):
     kw.setdefault("block_size", 8)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("cache_dtype", jnp.float32)
-    kw.setdefault("mixed_step", "auto")
     return ServeEngine(params, cfg, sampler=Sampler(kind="greedy"),
                        mesh_plan=plan, mesh_devices=devices, **kw)
 

@@ -326,6 +326,69 @@ def test_planner_respects_tiny_budget_progress_guarantee():
     assert any(n == 1 for rec in records for _, n in rec[1])
 
 
+def _planned(sched, specs):
+    """Admit ``specs`` — ``(prompt_len, prefilled, draft_len)`` a request —
+    straight into the state a tick would find them in."""
+    reqs = _requests([(p, 8) for p, _, _ in specs])
+    for r in reqs:
+        sched.add(r)
+    assert sched.admit() == reqs
+    for r, (p, prefilled, draft) in zip(reqs, specs):
+        r.prefill_target, r.prefilled = p, prefilled
+        r.prefill_done = p if prefilled else 0
+        if prefilled:
+            r.generated.append(1)
+        r.draft_len = draft
+    return reqs
+
+
+# what ``plan_tick`` decides, one policy point a case: (requests as
+# ``_planned`` takes them, budget, chunk, prefill order) → (decode rows,
+# prefill segments, draft widths left on the decode rows)
+PLAN_CASES = {
+    # a decode row's base token comes before any prefill, its drafts after
+    "drafts-spend-what-prefill-leaves": (
+        [(4, True, 3), (20, False, 0)], 12, 8, None,
+        [0], [(1, 8)], [3]),
+    "drafts-are-trimmed-to-the-slack": (
+        [(4, True, 3), (20, False, 0)], 10, 8, None,
+        [0], [(1, 8)], [1]),
+    "drafts-are-trimmed-to-nothing-never-the-base-token": (
+        [(4, True, 3), (4, True, 2), (20, False, 0)], 4, 8, None,
+        [0, 1], [(2, 2)], [0, 0]),
+    # a segment is the chunk, the rest of the prompt or the rest of the
+    # budget, whichever is least
+    "a-chunk-caps-a-segment": (
+        [(20, False, 0)], 64, 8, None, [], [(0, 8)], []),
+    "the-prompts-tail-caps-a-segment": (
+        [(5, False, 0), (3, False, 0)], 64, 8, None,
+        [], [(0, 5), (1, 3)], []),
+    "a-spent-budget-plans-no-prefill": (
+        [(4, True, 0), (4, True, 0), (20, False, 0)], 2, 8, None,
+        [0, 1], [], [0, 0]),
+    # the fairness hook reorders the candidates, nothing else
+    "prefill-order-is-the-hooks": (
+        [(20, False, 0), (20, False, 0)], 12, 8,
+        lambda running: list(reversed(running)),
+        [], [(1, 8), (0, 4)], []),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLAN_CASES))
+def test_plan_tick_policy(case):
+    specs, budget, chunk, order, decode, prefill, drafts = PLAN_CASES[case]
+    sched = _mk(n_blocks=64, slots=len(specs))
+    _planned(sched, specs)
+    got_decode, got_prefill = sched.plan_tick(
+        budget, chunk, prefill_order=order)
+    assert [r.req_id for r in got_decode] == decode
+    assert [(r.req_id, n) for r, n in got_prefill] == prefill
+    assert [r.draft_len for r in got_decode] == drafts
+    spent = (len(got_decode) + sum(n for _, n in got_prefill)
+             + sum(r.draft_len for r in got_decode))
+    assert spent <= budget, "budgets are exact"
+
+
 def test_no_growth_at_exact_block_boundary():
     """At cache_len == blocks*BLOCK the tick's write slot (cache_len-1)
     still fits the allocation — growing there under pool exhaustion

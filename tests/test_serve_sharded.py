@@ -3,7 +3,7 @@
 The acceptance bar is output invisibility: a TP-sharded engine — params
 column/row-sharded, pool slabs kv-head-partitioned, block tables
 replicated — must reproduce the single-chip engine's token streams
-EXACTLY (unified tick and phase-split, int8 pools, prefix sharing,
+EXACTLY (int8 pools, prefix sharing,
 gemma sliding windows, abort, supervised recovery), with zero compiles
 across ticks once warm (the static-shape contract extended to
 placement) and the slabs actually partitioned (pinned by inspecting
@@ -57,7 +57,6 @@ def _engine(cfg, params, plan=None, **kw):
     kw.setdefault("block_size", 8)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("cache_dtype", jnp.float32)
-    kw.setdefault("mixed_step", "auto")
     return ServeEngine(params, cfg, sampler=Sampler(kind="greedy"),
                        mesh_plan=plan, **kw)
 
@@ -96,28 +95,6 @@ def test_tp_trace_parity_32_requests(tiny, tp):
     # the unified tick keeps its Pallas ragged kernel under the mesh
     # (shard_map harness; interpret mode on CPU, Mosaic on TPU)
     assert sharded.mixed and sharded.ragged_attn_impl == "pallas"
-
-
-def test_tp_phase_split_parity(tiny):
-    cfg, params = tiny
-    trace = _trace(cfg, n=16)
-
-    def run(plan):
-        engine = _engine(cfg, params, plan, mixed_step="off")
-        engine.replay_trace(trace)
-        return engine
-
-    single, sharded = run(None), run(MeshPlan(model=4))
-    assert not sharded.mixed
-    assert _tokens(sharded) == _tokens(single)
-    # prefill widths: content rounded to whole chunks (= block_size
-    # here), scattered as whole blocks
-    shapes = {
-        -(-(-(-int(t["prompt"].size) // 8) * 8) // 8) for t in trace
-    }
-    assert_serve_compiles_bounded(
-        sharded, distinct_prefill_shapes=len(shapes),
-    )
 
 
 def test_tp_offline_parity_and_int8(tiny):
@@ -279,7 +256,7 @@ def test_zero_compiles_across_sharded_ticks(tiny):
     with CompileCounter().watch() as counter:
         engine.replay_trace(trace)
     assert counter.count == 0, f"sharded ticks compiled: {counter.events}"
-    assert_serve_compiles_bounded(engine, distinct_prefill_shapes=0)
+    assert_serve_compiles_bounded(engine)
 
 
 def test_mesh_plan_rejects_non_tp_axes(tiny):

@@ -55,7 +55,6 @@ def _engine(cfg, params, spec_k=4, **kw):
     kw.setdefault("block_size", 8)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("cache_dtype", jnp.float32)
-    kw.setdefault("mixed_step", "on")
     return ServeEngine(params, cfg, sampler=Sampler(kind="greedy"),
                        spec_k=spec_k, **kw)
 
@@ -208,7 +207,7 @@ def test_spec_trace_parity_32_requests(tiny):
     # ... and accepted drafts are free tokens: strictly fewer ticks than
     # plain decode on this repetitive workload
     assert ssnap["ticks"] < plain.metrics.snapshot()["ticks"]
-    assert_serve_compiles_bounded(spec, distinct_prefill_shapes=0)
+    assert_serve_compiles_bounded(spec)
     # offline ground truth (the engine-vs-offline chain: spec == plain
     # == generate_ragged)
     gen = Generator(params, cfg, sampler=Sampler(kind="greedy"),
@@ -461,10 +460,8 @@ def test_spec_zero_compiles_across_verify_width_churn(tiny):
 # Gating & validation
 # ---------------------------------------------------------------------------
 
-def test_spec_rejects_phase_split_engine(tiny):
+def test_spec_rejects_bad_widths(tiny):
     cfg, params = tiny
-    with pytest.raises(ValueError, match="unified tick"):
-        _engine(cfg, params, spec_k=4, mixed_step="off")
     with pytest.raises(ValueError, match="spec_k"):
         _engine(cfg, params, spec_k=-1)
     # construction-time, not first-draft-tick-inside-the-supervisor
@@ -511,26 +508,8 @@ def test_spec_stop_token_parity_and_terminal_draft_counted(tiny):
     )
 
 
-def test_spec_auto_fallback_serves_plain(tiny, monkeypatch):
-    """mixed_step='auto' with the ragged probe failing: spec_k degrades
-    to 0 with a warning, requests decode plain (fallback semantics)."""
-    import llm_np_cp_tpu.ops.pallas.support as support
-
-    monkeypatch.setattr(support, "_FORCE_FAIL", True)
-    support._probe.cache_clear()
-    try:
-        cfg, params = tiny
-        eng = _engine(cfg, params, spec_k=4, mixed_step="auto")
-        assert not eng.mixed and eng.spec_k == 0
-        req = eng.submit(np.ones(6, np.int32), 3, speculative=True)
-        eng.run_until_complete()
-        assert len(req.generated) == 3
-    finally:
-        support._probe.cache_clear()
-
-
 def test_spec_on_the_xla_tick_keeps_speculating(tiny, monkeypatch):
-    """mixed_step='on' with the ragged probe failing: the unified tick
+    """With the ragged probe failing the tick
     runs over the XLA ragged attention and speculation STAYS on — the
     verifier is the tick's, not the kernel's — with the tokens plain
     decode emits."""
@@ -550,7 +529,7 @@ def test_spec_on_the_xla_tick_keeps_speculating(tiny, monkeypatch):
     plain = run(spec_k=0)
     monkeypatch.setattr(support, "_FORCE_FAIL", True)
     support._probe.cache_clear()  # conftest clears it again afterwards
-    spec = run(spec_k=4, mixed_step="on")
+    spec = run(spec_k=4)
     assert spec.mixed and spec.ragged_attn_impl == "xla"
     assert spec.spec_k == 4
     assert spec.metrics.snapshot()["spec_accepted_tokens"] > 0

@@ -23,13 +23,20 @@ from llm_np_cp_tpu.serve import ServeEngine
 from tools.compile_counter import CompileCounter, assert_serve_compiles_bounded
 
 
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = tiny_config("llama")
+    params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    return cfg, params
+
+
 def _engine(cfg, params, **kw):
-    """The default engine — the tick ``cli serve`` serves; the tests of
-    the phase-split tick's own programs ask for it (``mixed_step="off"``)."""
+    """The default engine — the tick ``cli serve`` serves."""
     kw.setdefault("num_blocks", 24)
+    kw.setdefault("max_slots", 2)
     return ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"),
-        max_slots=2, block_size=8, max_seq_len=64,
+        block_size=8, max_seq_len=64,
         cache_dtype=jnp.float32, **kw,
     )
 
@@ -60,34 +67,6 @@ def test_steady_state_ticks_compile_nothing():
         f"steady-state serving compiled: {counter.events}"
     )
     assert engine.compile_counts() == warm_counts
-
-
-def test_paged_prefix_steady_state_ticks_compile_nothing():
-    """The paged decode path with prefix sharing: after a warm pass over
-    the phase shapes (prompt-length buckets AND shared-prefix depths),
-    repeated traffic — including prefix hits and refcount churn — must
-    trigger ZERO backend compiles, and decode must have compiled exactly
-    once."""
-    cfg = tiny_config("llama")
-    params = init_params(jax.random.PRNGKey(2), cfg, dtype=jnp.float32)
-    engine = _engine(cfg, params, num_blocks=32, decode_attn_impl="paged",
-                     mixed_step="off", enable_prefix_cache=True)
-    assert not engine.mixed and engine.decode_attn_impl == "paged"
-    # warm: both block-count buckets, then a repeat so the prefix-hit
-    # path (gather_prefix per shared depth) compiles too
-    _drive(engine, cfg, lens=(4, 12), seed0=0)
-    _drive(engine, cfg, lens=(4, 12), seed0=0)
-    warm_counts = dict(engine.compile_counts())
-    assert warm_counts["decode_step"] == 1
-
-    counter = CompileCounter()
-    with counter.watch():
-        _drive(engine, cfg, lens=(4, 12, 4, 12, 4), seed0=0)
-    assert counter.count == 0, (
-        f"paged+prefix steady-state serving compiled: {counter.events}"
-    )
-    assert engine.compile_counts() == warm_counts
-    assert engine.metrics.prefix_blocks_hit > 0
 
 
 def test_prefix_steady_state_ticks_compile_nothing():
@@ -125,34 +104,11 @@ def test_compile_counts_bounded_by_buckets():
     assert engine.mixed
     _drive(engine, cfg, lens=(3, 5, 9, 14, 2, 11, 8, 16), seed0=0)
     assert engine.scheduler.n_preemptions == 0
-    assert_serve_compiles_bounded(engine, distinct_prefill_shapes=0)
+    assert_serve_compiles_bounded(engine)
     counts = engine.compile_counts()
     assert set(counts) == {"mixed_step"}
     assert 1 <= counts["mixed_step"] <= len(engine.mixed_buckets)
     assert engine.metrics.n_ticks > counts["mixed_step"]
-
-
-def test_compile_counts_bounded_by_phase_shapes():
-    """The phase-split tick's per-program contract: decode/sample/prefill
-    compile once (the temp prefill cache has a fixed capacity), scatter
-    at most once per distinct prefill block count, regardless of how
-    many requests or ticks ran."""
-    cfg = tiny_config("llama")
-    params = init_params(jax.random.PRNGKey(1), cfg, dtype=jnp.float32)
-    engine = _engine(cfg, params, mixed_step="off")
-    lens = (3, 5, 9, 14, 2, 11, 8, 16)
-    _drive(engine, cfg, lens=lens, seed0=0)
-    chunk = engine.prefill_chunk
-    shapes = {
-        engine.pool.blocks_for(-(-r.prompt_len // chunk) * chunk)
-        for r in engine.scheduler.finished
-    }
-    assert engine.scheduler.n_preemptions == 0
-    assert_serve_compiles_bounded(engine, distinct_prefill_shapes=len(shapes))
-    counts = engine.compile_counts()
-    assert counts["decode_step"] == 1
-    assert counts["sample_first"] == 1
-    assert counts["prefill_step"] == 1
 
 
 def _parent_rungs(engine):
@@ -242,4 +198,118 @@ def test_warmup_pays_for_one_program_more_than_the_parent(geometry):
     assert engine.compile_counts() == warm
     assert picked == set(engine.mixed_buckets), (
         sorted(set(engine.mixed_buckets) - picked))
-    assert_serve_compiles_bounded(engine, distinct_prefill_shapes=0)
+    assert_serve_compiles_bounded(engine)
+
+
+@pytest.fixture(scope="module")
+def cells_engine():
+    """An engine at the benchmark cells' geometry, never dispatched: its
+    program set and ``_pick_bucket`` are host arithmetic."""
+    cfg = tiny_config("llama")
+    params = init_params(jax.random.PRNGKey(3), cfg, dtype=jnp.float32)
+    kw, _ = PROGRAM_GEOMETRIES["cells-64-slots"]
+    return ServeEngine(params, cfg, sampler=Sampler(kind="greedy"),
+                       cache_dtype=jnp.float32, **kw)
+
+
+# (tile lanes, tokens) of a tick → the program it runs
+PICKS = {
+    "one-decode-row": ((8, 1), (8, 8)),
+    "the-steady-decode-tick": ((512, 64), (512, 64)),
+    "63-rows-and-a-chunk": ((63 * 8 + 128, 63 + 128), (768, 320)),
+    "a-chunk-alone": ((128, 128), (128, 128)),
+    "the-whole-budget": ((320 + 64 * 7, 320), (768, 320)),
+    "one-token-past-the-steady-width": ((512, 65), (512, 320)),
+}
+
+
+@pytest.mark.parametrize("tick", sorted(PICKS))
+def test_a_tick_takes_the_narrowest_program_that_holds_it(cells_engine, tick):
+    """``_pick_bucket``: the first program, in ``(tile lanes, dense
+    width)`` order, that holds the tick's tiles AND its tokens — so the
+    steady decode tick (64 one-tile rows) runs 64 lanes wide, and one
+    token more falls back on the rung's capacity."""
+    (lanes, tokens), want = PICKS[tick]
+    engine = cells_engine
+    assert engine._pick_bucket(lanes, tokens) == want
+    assert want in engine.mixed_buckets
+    held = [p for p in engine.mixed_buckets
+            if p[0] >= lanes and p[1] >= tokens]
+    assert want == min(held)
+    with pytest.raises(AssertionError, match="budget accounting"):
+        engine._pick_bucket(engine.mixed_buckets[-1][0] + 8, 1)
+
+
+# (moved here from test_serve_mixed.py, PR 46: the compile-count contract
+# of the default engine, and a third of that file's time — one xdist worker
+# runs a file whole)
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("mesh", [None, 2], ids=["one-device", "model=2"])
+@pytest.mark.parametrize("prefix", [False, True], ids=["noprefix", "prefix"])
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.int8],
+                         ids=["f32", "int8"])
+def test_default_engine_names_only_mixed_step(tiny, cache_dtype, prefix, mesh):
+    """Whatever the pool's dtype, with or without the prefix cache, on
+    one device or tensor-parallel: the default engine is the unified
+    tick, its one program is ``mixed_step`` (plus the host tier's two
+    where a tier is attached), warm-up compiles it once a bucket and
+    traffic afterwards — prefix hits included — compiles nothing."""
+    from llm_np_cp_tpu.parallel.sharding import MeshPlan
+    from llm_np_cp_tpu.serve.host_tier import HostTier
+
+    cfg, params = tiny
+    tier = HostTier(8 << 20) if prefix else None
+    engine = ServeEngine(
+        params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
+        num_blocks=32, block_size=8, max_seq_len=64,
+        cache_dtype=cache_dtype, enable_prefix_cache=prefix,
+        host_tier=tier,
+        mesh_plan=MeshPlan(model=mesh) if mesh else None,
+    )
+    assert engine.mixed
+    want = {"mixed_step"} | ({"restore_block", "slice_block"} if prefix
+                             else set())
+    assert set(engine.compile_counts()) == want
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (19, 6)]
+    engine.warmup([19, 6], max_new_tokens=4)
+    warm = engine.compile_counts()
+    assert warm["mixed_step"] == len(engine.mixed_buckets)
+    with CompileCounter().watch() as counter:
+        for _ in range(2):
+            for j, p in enumerate(prompts):
+                engine.submit(p, 4, seed=j)
+            engine.run_until_complete()
+    assert counter.count == 0, counter.events
+    assert engine.compile_counts() == warm
+    assert_serve_compiles_bounded(engine)
+    assert (engine.metrics.prefix_blocks_hit > 0) == prefix
+    if tier is not None:
+        tier.close()
+
+
+def test_mixed_zero_compiles_across_ragged_composition_churn(tiny):
+    """After warmup compiles every packed-width bucket, ticks whose
+    prefill:decode row mix churns arbitrarily (fresh prompts, varied
+    lengths and budgets-worth of chunk slices, decode-only tails) must
+    trigger ZERO backend compiles."""
+    cfg, params = tiny
+    engine = _engine(cfg, params, max_slots=4, num_blocks=48)
+    rng = np.random.default_rng(4)
+    lens = (3, 26, 7, 14, 9, 21)
+    engine.warmup([int(n) for n in lens], max_new_tokens=8)
+    warm = dict(engine.compile_counts())
+    assert warm["mixed_step"] == len(engine.mixed_buckets)
+
+    counter = CompileCounter()
+    with counter.watch():
+        for rep in range(3):
+            for i, n in enumerate(lens):
+                engine.submit(rng.integers(1, cfg.vocab_size, size=n),
+                              3 + (i % 5), seed=rep * 10 + i)
+            engine.run_until_complete()
+    assert counter.count == 0, (
+        f"composition churn compiled: {counter.events}"
+    )
+    assert engine.compile_counts() == warm

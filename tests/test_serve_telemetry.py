@@ -164,7 +164,7 @@ def test_model_accepts_quantized_params_tree(tiny):
 
 def test_mixed_tick_cost_conservation(tiny):
     cfg, params = tiny
-    engine = _engine(cfg, params, mixed_step="on",
+    engine = _engine(cfg, params,
                      telemetry=TelemetryModel(cfg, params))
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, cfg.vocab_size, size=n)
@@ -178,33 +178,17 @@ def test_mixed_tick_cost_conservation(tiny):
     assert snap["hbm_gbps"] == HBM_GBPS_DEFAULT
 
 
-def test_split_path_cost_conservation_including_prefill(tiny):
-    """The phase-split engine: decode dispatches are roofline-graded,
-    prefill chunk dispatches land their whole bill on their request
-    via a totals-only record — the ledger still conserves."""
-    cfg, params = tiny
-    engine = _engine(cfg, params, mixed_step="off",
-                     telemetry=TelemetryModel(cfg, params))
-    rng = np.random.default_rng(8)
-    prompts = [rng.integers(1, cfg.vocab_size, size=n)
-               for n in (4, 17, 26, 8)]
-    _run(engine, prompts, max_tokens=5)
-    snap = _assert_conserves(engine)
-    # prefill wrote fresh K/V and streamed weights per chunk
-    assert snap["kv_write_bytes_total"] > 0
-    assert all(r.kv_bytes_written > 0 for r in engine.scheduler.finished)
-
-
-def test_split_prefill_abort_from_callback_conserves(tiny, tmp_path):
+def test_abort_from_first_token_callback_conserves(tiny, tmp_path):
     """An abort fired from the FIRST token's callback (the supported
-    abort-from-callback pattern) writes the request-log line during the
-    abort — attribution must land before that, and from the request's
-    pre-abort block state, so the line carries a real cost block and
-    the ledgers still conserve."""
+    abort-from-callback pattern; the callback runs a tick after the
+    token was accepted) writes the request-log line during the abort —
+    the attribution of every tick the request rode has landed by then,
+    so the line carries a real cost block and the ledgers still
+    conserve."""
     cfg, params = tiny
     path = str(tmp_path / "requests.jsonl")
     rl = RequestLog(path)
-    engine = _engine(cfg, params, mixed_step="off",
+    engine = _engine(cfg, params,
                      telemetry=TelemetryModel(cfg, params),
                      request_log=rl)
 
@@ -244,7 +228,7 @@ def test_spec_verify_lanes_conservation(tiny):
     really covered them, accepted or not) and attribution still sums
     to the tick totals."""
     cfg, params = tiny
-    engine = _engine(cfg, params, mixed_step="on", spec_k=3,
+    engine = _engine(cfg, params, spec_k=3,
                      telemetry=TelemetryModel(cfg, params))
     rng = np.random.default_rng(9)
     prompts = _tiled_prompts(rng, cfg.vocab_size, (12, 19, 8))
@@ -260,7 +244,7 @@ def test_prefix_shared_blocks_conservation(tiny):
     blocks (billed to it) but never re-writes them — conservation
     holds and the sharers' write bill is visibly smaller."""
     cfg, params = tiny
-    engine = _engine(cfg, params, mixed_step="on", num_blocks=64,
+    engine = _engine(cfg, params, num_blocks=64,
                      enable_prefix_cache=True,
                      telemetry=TelemetryModel(cfg, params))
     rng = np.random.default_rng(10)
@@ -277,7 +261,7 @@ def test_prefix_shared_blocks_conservation(tiny):
 
 def test_int8_pool_conservation(tiny):
     cfg, params = tiny
-    engine = _engine(cfg, params, mixed_step="on",
+    engine = _engine(cfg, params,
                      cache_dtype=jnp.int8,
                      telemetry=TelemetryModel(cfg, params))
     rng = np.random.default_rng(11)
@@ -292,7 +276,7 @@ def test_int8_pool_conservation(tiny):
 
 def test_off_by_default_and_attach_adds_zero_recompiles(tiny):
     cfg, params = tiny
-    engine = _engine(cfg, params, mixed_step="on")
+    engine = _engine(cfg, params)
     assert engine.telemetry is None  # the default IS off
     rng = np.random.default_rng(12)
     prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (5, 13)]
@@ -330,17 +314,14 @@ def test_off_by_default_and_attach_adds_zero_recompiles(tiny):
 def test_tick_args_and_summarize_roofline_fixture(tiny, tmp_path):
     cfg, params = tiny
     events = []
-    for mode in ("on", "off"):
-        engine = _engine(cfg, params, mixed_step=mode,
-                         telemetry=TelemetryModel(cfg, params),
-                         tracer=TraceRecorder())
-        rng = np.random.default_rng(13)
-        prompts = [rng.integers(1, cfg.vocab_size, size=n)
-                   for n in (7, 16, 11)]
-        _run(engine, prompts, max_tokens=4)
-        path = tmp_path / f"trace_{mode}.json"
-        engine.tracer.dump(str(path))
-        events += json.loads(path.read_text())["traceEvents"]
+    engine = _engine(cfg, params, telemetry=TelemetryModel(cfg, params),
+                     tracer=TraceRecorder())
+    rng = np.random.default_rng(13)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (7, 16, 11)]
+    _run(engine, prompts, max_tokens=4)
+    path = tmp_path / "trace.json"
+    engine.tracer.dump(str(path))
+    events += json.loads(path.read_text())["traceEvents"]
 
     ticks = [e for e in events
              if e.get("ph") == "X" and e.get("cat") == "tick"
@@ -357,7 +338,7 @@ def test_tick_args_and_summarize_roofline_fixture(tiny, tmp_path):
         assert a["device_time_s"] > 0
 
     roof = roofline(events)
-    assert set(roof) == {"mixed", "split"}
+    assert set(roof) == {"mixed"}
     for kind, r in roof.items():
         assert r["ticks"] > 0
         assert r["gbps_p50"] <= r["gbps_p99"]
@@ -365,7 +346,7 @@ def test_tick_args_and_summarize_roofline_fixture(tiny, tmp_path):
         assert r["device_s_total"] > 0
     out = format_summary(events)
     assert "== roofline ==" in out
-    assert "mixed" in out and "split" in out
+    assert "mixed" in out
     # telemetry-off traces don't grow a roofline section
     assert roofline([{"ph": "X", "cat": "tick", "args": {}}]) is None
 
@@ -377,7 +358,7 @@ def test_tick_args_and_summarize_roofline_fixture(tiny, tmp_path):
 def test_sentinel_baselines_roofline_deficit(tiny):
     cfg, params = tiny
     sentinel = TickSentinel(warmup_ticks=4, min_us=1.0)
-    engine = _engine(cfg, params, mixed_step="on",
+    engine = _engine(cfg, params,
                      telemetry=TelemetryModel(cfg, params),
                      tracer=TraceRecorder(), sentinel=sentinel)
     rng = np.random.default_rng(14)
@@ -403,7 +384,7 @@ def test_request_log_cost_fields_conserve(tiny, tmp_path):
     cfg, params = tiny
     path = str(tmp_path / "requests.jsonl")
     rl = RequestLog(path)
-    engine = _engine(cfg, params, mixed_step="on",
+    engine = _engine(cfg, params,
                      telemetry=TelemetryModel(cfg, params),
                      request_log=rl)
     rng = np.random.default_rng(15)
@@ -430,7 +411,7 @@ def test_request_log_omits_cost_without_telemetry(tiny, tmp_path):
     cfg, params = tiny
     path = str(tmp_path / "requests.jsonl")
     rl = RequestLog(path)
-    engine = _engine(cfg, params, mixed_step="on", request_log=rl)
+    engine = _engine(cfg, params, request_log=rl)
     rng = np.random.default_rng(16)
     _run(engine, [rng.integers(1, cfg.vocab_size, size=8)], max_tokens=3)
     rl.close()
@@ -442,10 +423,9 @@ def test_request_log_omits_cost_without_telemetry(tiny, tmp_path):
 # Metrics plane
 # ---------------------------------------------------------------------------
 
-def _tel_record(*, roofline_flag=True, util=0.5, gbps=400.0):
+def _tel_record(*, util=0.5, gbps=400.0):
     return {
-        "kind": "mixed" if roofline_flag else "prefill",
-        "roofline": roofline_flag,
+        "kind": "mixed",
         "tokens": 4,
         "device_time_s": 0.01,
         "kv_read_bytes": 1000.0,
@@ -465,19 +445,14 @@ def test_metrics_ledgers_gauges_and_prometheus():
     assert "roofline" not in m.prometheus()
     m.on_telemetry(_tel_record(util=0.004))
     m.on_telemetry(_tel_record(util=0.3, gbps=300.0))
-    # a totals-only record (split-path prefill): ledger yes, gauge no
-    rec = _tel_record(roofline_flag=False)
-    del rec["achieved_gbps"], rec["roofline_util"], rec["mfu"]
-    del rec["deficit_us"]
-    m.on_telemetry(rec)
     s = m.snapshot()
     assert s["roofline_ticks"] == 2
-    assert s["kv_read_bytes_total"] == 3000.0
-    assert s["device_time_s_total"] == pytest.approx(0.03)
+    assert s["kv_read_bytes_total"] == 2000.0
+    assert s["device_time_s_total"] == pytest.approx(0.02)
     assert s["roofline_gbps_last"] == 300.0
     assert s["roofline_util_mean"] == pytest.approx((0.004 + 0.3) / 2)
     text = m.prometheus()
-    assert 'llm_serve_device_bytes_total{kind="kv_read"} 3000' in text
+    assert 'llm_serve_device_bytes_total{kind="kv_read"} 2000' in text
     assert "llm_serve_roofline_util " in text
     assert "llm_serve_hbm_gbps_target 800" in text
     assert "llm_serve_mfu " in text
@@ -492,7 +467,7 @@ def test_fleet_aggregate_recomputes_utilization_from_sums(tiny):
     cfg, params = tiny
     model = TelemetryModel(cfg, params)
     fleet = ReplicaSet([
-        _engine(cfg, params, mixed_step="on", telemetry=model)
+        _engine(cfg, params, telemetry=model)
         for _ in range(2)
     ])
     rng = np.random.default_rng(17)
@@ -568,7 +543,7 @@ class _StubCollector:
 def test_otlp_round_trip_from_live_engine(tiny):
     cfg, params = tiny
     collector = _StubCollector()
-    engine = _engine(cfg, params, mixed_step="on",
+    engine = _engine(cfg, params,
                      telemetry=TelemetryModel(cfg, params),
                      tracer=TraceRecorder())
     exporter = OtlpExporter(collector.endpoint,

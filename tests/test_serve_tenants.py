@@ -217,7 +217,7 @@ def test_per_tenant_cost_conservation(tiny, tmp_path):
     log_path = str(tmp_path / "reqs.jsonl")
     rlog = RequestLog(log_path)
     led = TenantLedger()
-    engine = _engine(cfg, params, mixed_step="on",
+    engine = _engine(cfg, params,
                      telemetry=TelemetryModel(cfg, params),
                      request_log=rlog, tenants=led,
                      enable_prefix_cache=True)
@@ -287,7 +287,7 @@ def test_token_parity_and_zero_compiles_with_tenancy_on(tiny):
     rng = np.random.default_rng(2)
     trace = poisson_trace(rng, 10, rate_rps=50.0, prompt_len_range=(3, 18),
                           max_new_tokens=5, vocab_size=cfg.vocab_size)
-    plain = _engine(cfg, params, mixed_step="on")
+    plain = _engine(cfg, params)
     plain.replay_trace(trace)
     # submission order, not raw req_id: the tenancy leg's warmup dummy
     # shifts ids by one
@@ -297,7 +297,7 @@ def test_token_parity_and_zero_compiles_with_tenancy_on(tiny):
 
     led = TenantLedger(fairness=True,
                        policy=SLOPolicy(ttft_s=60.0, tpot_s=60.0))
-    engine = _engine(cfg, params, mixed_step="on", tenants=led)
+    engine = _engine(cfg, params, tenants=led)
     engine.warmup([int(t["prompt"].size) for t in trace],
                   max_new_tokens=5)
     tagged = [dict(item, tenant=("a" if i % 2 else "b"))
@@ -324,7 +324,7 @@ def _fairness_leg(cfg, params, *, fairness, policy=None):
     state = {"t": 0.0}
     led = TenantLedger(fairness=fairness, policy=policy,
                        clock=lambda: state["t"])
-    engine = _engine(cfg, params, mixed_step="on", max_slots=4,
+    engine = _engine(cfg, params, max_slots=4,
                      num_blocks=64, tick_token_budget=16,
                      tenants=led, clock=lambda: state["t"])
     rng = np.random.default_rng(5)

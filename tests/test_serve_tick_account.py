@@ -49,7 +49,6 @@ def _engine(cfg, params, **kw):
     kw.setdefault("block_size", 8)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("cache_dtype", jnp.float32)
-    kw.setdefault("mixed_step", "on")
     return ServeEngine(params, cfg, sampler=Sampler(kind="greedy"), **kw)
 
 
@@ -436,7 +435,7 @@ def test_setup_spans_cover_build_and_each_warmup_bucket(tiny):
         by.setdefault(ev["name"], []).append(ev)
     # exactly these, each once but the buckets: nothing else leaks in
     assert {name: len(evs) for name, evs in by.items()} == {
-        "probe.decode_attn": 1, "probe.ragged_attn": 1, "pool_alloc": 1,
+        "probe.ragged_attn": 1, "pool_alloc": 1,
         "probe.sample_epilogue": 1, "engine_build": 1, "warmup.request": 1,
         "warmup.bucket": len(engine.mixed_buckets), "warmup": 1, "op_map": 1,
     }

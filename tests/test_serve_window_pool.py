@@ -249,13 +249,32 @@ def test_the_pool_has_two_page_classes_and_one_manager(tiny):
     (dict(enable_prefix_cache=True), "--prefix-cache"),
     (dict(spec_k=2), "--spec-k"),
     (dict(cache_dtype=jnp.int8), "--cache-dtype int8"),
-    (dict(mixed_step="off"), "--mixed-step off"),
 ])
 def test_what_two_page_classes_cannot_do_yet_is_refused_by_flag(tiny, kw, match):
     cfg, _, params = tiny
     with pytest.raises(ValueError, match=match):
         ServeEngine(params, cfg, max_slots=2, num_blocks=16, block_size=8,
                     max_seq_len=64, **kw)
+
+
+def test_without_the_kernel_both_classes_take_the_xla_twin(
+        tiny, monkeypatch, caplog):
+    """A failed ragged probe: the engine is still the unified tick with
+    both page classes, over ``ragged_paged_attention_xla``, and says so."""
+    from llm_np_cp_tpu.ops.pallas import support
+
+    cfg, _, params = tiny
+    monkeypatch.setattr(support, "_FORCE_FAIL", True)
+    support._probe.cache_clear()  # conftest clears it again afterwards
+    with caplog.at_level("WARNING", logger="llm_np_cp_tpu"):
+        engine = ServeEngine(params, cfg, max_slots=2, num_blocks=10,
+                             block_size=8, max_seq_len=96, prefill_chunk=16,
+                             tick_token_budget=18, cache_dtype=jnp.float32)
+    assert engine.mixed and engine.ragged_attn_impl == "xla"
+    assert engine.window_blocks and engine.pool.window is not None
+    assert set(engine.compile_counts()) == {"mixed_step"}
+    assert any("ragged_paged_attention_xla" in r.getMessage()
+               for r in caplog.records)
 
 
 def test_the_offline_cache_refuses_two_page_classes(tiny):

@@ -10,9 +10,8 @@ probes:
   compiles.
 - ``assert_serve_compiles_bounded(engine)`` — checks the engine's own
   per-program compile counts (``ServeEngine.compile_counts()``) against
-  the static-shape contract: decode/sample/prefill compile ONCE (the
-  temp prefill cache is padded to a fixed capacity), scatter once per
-  distinct prefill block count (phase shapes), never per tick.
+  the static-shape contract: the tick's one step compiles once per
+  program of ``mixed_buckets``, never per tick.
 - ``assert_tracing_hooks_guarded()`` — the tracing-off discipline lint:
   every ``serve/tracing.py`` hook must sit behind an ``is None`` check,
   so with tracing off the per-tick cost is attribute loads + branches —
@@ -33,7 +32,9 @@ from typing import Iterator
 
 # Event keys that indicate an XLA computation was compiled: every
 # compile request records ``/jax/compilation_cache/compile_requests_use_cache``
-# (persistent-cache hit or not); match loosely on purpose.
+# (persistent-cache hit or not); match loosely on purpose.  With
+# ``jax_enable_compilation_cache`` switched off JAX records NO such event
+# and the counter is blind — a process that counts must leave it on.
 _COMPILE_MARKERS = ("compile", "lowering")
 
 
@@ -62,32 +63,15 @@ class CompileCounter:
             monitoring.unregister_event_listener(self._listener)
 
 
-def assert_serve_compiles_bounded(
-    engine, *, distinct_prefill_shapes: int,
-    distinct_prefix_shapes: int | None = None,
-) -> None:
-    """The static-shape contract for every serve/ jitted step — for BOTH
-    decode impls: the gather step and the paged (block-table-native)
-    step share the host contract, so ``decode_step`` must stay at ONE
-    compile regardless of ``attn_impl``, prompt-length buckets, prefix
-    hits, or refcount state.
-
-    distinct_prefill_shapes: how many distinct prefill block counts the
-    driven workload legitimately produced (== number of distinct temp
-    cache capacities).  distinct_prefix_shapes: distinct shared-prefix
-    block counts (prefix-cache hits; the small gather-prefix copy is the
-    only other program allowed to specialize) — None means "don't
-    check".  Anything above these bounds means a step's shapes depend on
-    per-tick state — the exact bug this lint exists to catch.
-
-    Unified-tick engines (``engine.mixed``) have ONE program under a
-    stricter contract: ``mixed_step`` compiles at most once per
-    program (``engine.mixed_buckets``: ``(packed, dense)`` width pairs,
-    one more than the tile ladder has rungs) regardless of the
-    prefill:decode row composition, and NONE of the phase-split
-    programs exist — in particular the deleted ``gather_prefix`` copy
-    must not reappear (its job, copying shared prefix K/V into the temp
-    cache, no longer exists: shared blocks are attended in place).
+def assert_serve_compiles_bounded(engine) -> None:
+    """The static-shape contract of the serve tick: the engine has ONE
+    jitted step, ``mixed_step``, which compiles at most once per program
+    (``engine.mixed_buckets``: ``(packed, dense)`` width pairs, one more
+    than the tile ladder has rungs) regardless of the prefill:decode row
+    composition, prompt-length buckets, prefix hits or refcount state.
+    Anything above that bound means the step's shapes depend on per-tick
+    state — the exact bug this lint exists to catch.  No other program
+    may exist beside it but the host tier's two.
     """
     counts = engine.compile_counts()
     problems = []
@@ -102,60 +86,17 @@ def assert_serve_compiles_bounded(
                 "programs take the block id as a traced scalar, so "
                 "spills/restores never specialize per block)"
             )
-    if getattr(engine, "mixed", False):
-        if set(counts) != {"mixed_step"}:
-            problems.append(
-                f"unified-tick engine reports programs {sorted(counts)}; "
-                "only mixed_step may exist (gather_prefix / "
-                "scatter_prefill / prefill_step are deleted on this path)"
-            )
-        if counts.get("mixed_step", 0) > len(engine.mixed_buckets):
-            problems.append(
-                f"mixed_step compiled {counts['mixed_step']}x for "
-                f"{len(engine.mixed_buckets)} (packed, dense) width "
-                "programs (must be <= one per program, never per tick or per "
-                "prefill:decode composition)"
-            )
-        if any(v < 0 for v in counts.values()):
-            problems.append(
-                f"compile counts unavailable on this jax version: {counts}"
-            )
-        if problems:
-            raise AssertionError(
-                "serve/ static-shape lint failed:\n  "
-                + "\n  ".join(problems)
-            )
-        return
-    if counts["decode_step"] > 1:
+    if set(counts) != {"mixed_step"}:
         problems.append(
-            f"decode_step compiled {counts['decode_step']}x (must be 1 "
-            f"for attn_impl={engine.decode_attn_impl!r}: packed batch/"
-            "table/pool shapes are all static)"
+            f"the engine reports programs {sorted(counts)}; only "
+            "mixed_step may exist"
         )
-    if counts["sample_first"] > 1:
+    if counts.get("mixed_step", 0) > len(engine.mixed_buckets):
         problems.append(
-            f"sample_first compiled {counts['sample_first']}x (must be 1)"
-        )
-    if counts["prefill_step"] > 1:
-        problems.append(
-            f"prefill_step compiled {counts['prefill_step']}x (must be 1: "
-            "the temp prefill cache is padded to a fixed capacity so "
-            "prompt-length buckets never retrace the model)"
-        )
-    if counts["scatter_prefill"] > distinct_prefill_shapes:
-        problems.append(
-            f"scatter_prefill compiled {counts['scatter_prefill']}x for "
-            f"{distinct_prefill_shapes} distinct prefill shapes "
-            "(must be <= one per phase shape, never per tick)"
-        )
-    if (
-        distinct_prefix_shapes is not None
-        and counts.get("gather_prefix", 0) > distinct_prefix_shapes
-    ):
-        problems.append(
-            f"gather_prefix compiled {counts['gather_prefix']}x for "
-            f"{distinct_prefix_shapes} distinct shared-prefix shapes "
-            "(must be <= one per shared block count, never per hit)"
+            f"mixed_step compiled {counts['mixed_step']}x for "
+            f"{len(engine.mixed_buckets)} (packed, dense) width "
+            "programs (must be <= one per program, never per tick or per "
+            "prefill:decode composition)"
         )
     if any(v < 0 for v in counts.values()):
         problems.append(
@@ -215,56 +156,34 @@ def _self_check() -> None:
 
     cfg = tiny_config("llama")
     params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
-    # the phase-split tick's contract first (it has to be asked for: an
-    # engine built with no ``mixed_step`` is the unified tick, below)
-    eng = ServeEngine(
-        params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
-        num_blocks=16, block_size=8, max_seq_len=64, cache_dtype=jnp.float32,
-        mixed_step="off",
-    )
-    rng = np.random.default_rng(0)
-    for n in (5, 9, 5, 13):
-        eng.submit(rng.integers(1, 200, size=n), 6)
-    eng.run_until_complete()
-    shapes = {-(-(-(-n // 8) * 8) // 8) for n in (5, 9, 5, 13)}
-    assert_serve_compiles_bounded(engine=eng, distinct_prefill_shapes=len(shapes))
-    print(f"compile counts OK (gather): {eng.compile_counts()}")
-
-    # the paged decode path with prefix sharing: ticks across
-    # prompt-length buckets, repeated prompts (refcount churn: claim,
-    # share, release), and the prefix-gather must stay within the same
-    # bounds — decode still compiles exactly once
+    # prompt-length buckets and prefix sharing: ticks across prompt
+    # lengths, repeated prompts (refcount churn: claim, share, release)
+    # must stay within the bound — one compile a program, none a tick
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=32, block_size=8, max_seq_len=64, cache_dtype=jnp.float32,
-        decode_attn_impl="paged", mixed_step="off",
         enable_prefix_cache=True,
     )
+    rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 200, size=n) for n in (5, 9, 13, 17)]
+    eng.warmup([int(p.size) for p in prompts], max_new_tokens=6)
     for _ in range(3):  # repeats after round 1 hit the prefix cache
         for p in prompts:
             eng.submit(p, 6)
     eng.run_until_complete()
-    shapes = {-(-(-(-p.size // 8) * 8) // 8) for p in prompts}
-    prefix_shapes = {
-        r.n_shared_blocks for r in eng.scheduler.finished if r.n_shared_blocks
-    }
     assert eng.metrics.prefix_blocks_hit > 0, "no prefix hits — bad workload"
-    assert_serve_compiles_bounded(
-        engine=eng, distinct_prefill_shapes=len(shapes) + len(prefix_shapes),
-        distinct_prefix_shapes=len(prefix_shapes),
-    )
-    print(f"compile counts OK (paged+prefix): {eng.compile_counts()}")
+    assert_serve_compiles_bounded(eng)
+    print(f"compile counts OK (prefix sharing): {eng.compile_counts()}")
 
     # abort churn: cancelling requests queued / mid-decode, with prefix
     # sharers still live, must stay inside the SAME bounds — abort is
-    # host-side unwinding only (tables rebuilt per tick), so decode stays
-    # at ONE compile and no new phase shapes appear
+    # host-side unwinding only (operands rebuilt per tick), so no new
+    # program appears
     warm = dict(eng.compile_counts())
     for round_ in range(3):
         live = [eng.submit(p, 6) for p in prompts]
-        eng.step()  # admit + prefill whoever fits
-        eng.abort(live[0].req_id)              # mid-decode (or queued)
+        eng.step()  # admit + the first chunks of whoever fits
+        eng.abort(live[0].req_id)              # mid-prefill (or queued)
         eng.abort(live[-1].req_id)             # queue tail
         eng.run_until_complete()
     assert eng.compile_counts() == warm, (
@@ -275,16 +194,15 @@ def _self_check() -> None:
     print(f"compile counts OK (abort churn): {eng.compile_counts()}")
 
     # supervised restart + recovery replay: a rebuilt engine
-    # (clone_fresh, identical geometry) SHARES the compiled step
-    # programs, and replaying in-flight requests teacher-forced
-    # (engine.recover — the evict-requeue path across a rebuild) must
-    # not compile ANYTHING new — restart cost is pool rebuild + replay
-    # prefills, never a retrace.  The decode step in particular stays at
-    # its single compile across the rebuild.
-    warm = dict(eng.compile_counts())
+    # (clone_fresh, identical geometry) SHARES the compiled step, and
+    # replaying in-flight requests teacher-forced (engine.recover — the
+    # evict-requeue path across a rebuild) must not compile ANYTHING
+    # new — restart cost is pool rebuild + replay prefills, never a
+    # retrace
     live = [eng.submit(p, 6) for p in prompts]
     for _ in range(2):
         eng.step()  # some requests mid-decode, some still queued
+    eng.publish_owed()
     rebuilt = eng.clone_fresh()
     for r in live:
         rebuilt.recover(
@@ -292,11 +210,11 @@ def _self_check() -> None:
             generated=list(r.generated),
         )
     rebuilt.run_until_complete()
+    assert rebuilt._mixed_step is eng._mixed_step
     assert rebuilt.compile_counts() == warm, (
         f"engine restart + recovery replay recompiled: "
         f"{warm} -> {rebuilt.compile_counts()}"
     )
-    assert rebuilt.compile_counts()["decode_step"] == 1
     held = rebuilt.pool.stats()["request_held"]
     assert held == 0, f"recovery replay leaked {held} blocks"
     print(f"compile counts OK (restart+recovery): {rebuilt.compile_counts()}")
@@ -304,21 +222,16 @@ def _self_check() -> None:
     # the unified tick: after warmup compiles every packed-width bucket,
     # churning the ragged composition (prefill-heavy, decode-only, and
     # mixed ticks; varied prompt lengths and budgets-worth of chunk
-    # slices) must trigger ZERO further compiles, and the phase-split
-    # programs — the deleted gather_prefix copy above all — must not
-    # exist on this engine at all
+    # slices) must trigger ZERO further compiles
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=32, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, mixed_step="on",
+        cache_dtype=jnp.float32,
         enable_prefix_cache=True,
     )
     mixed_prompts = [rng.integers(1, 200, size=n) for n in (26, 4, 17, 9)]
     eng.warmup([int(p.size) for p in mixed_prompts], max_new_tokens=8)
     warm = dict(eng.compile_counts())
-    assert "gather_prefix" not in warm, (
-        f"deleted gather_prefix program reappeared: {warm}"
-    )
     with CompileCounter().watch() as counter:
         for rep in range(3):  # round 2+ hits the prefix cache too
             for i, p in enumerate(mixed_prompts):
@@ -328,7 +241,7 @@ def _self_check() -> None:
         f"unified-tick composition churn compiled: {counter.events}"
     )
     assert eng.compile_counts() == warm
-    assert_serve_compiles_bounded(engine=eng, distinct_prefill_shapes=0)
+    assert_serve_compiles_bounded(eng)
     held = eng.pool.stats()["request_held"]
     assert held == 0, f"unified tick leaked {held} blocks"
     print(f"compile counts OK (unified tick): {eng.compile_counts()}")
@@ -347,7 +260,7 @@ def _self_check() -> None:
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=12, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, mixed_step="on",
+        cache_dtype=jnp.float32,
         enable_prefix_cache=True, host_tier=tier,
     )
     tier_prompts = [rng.integers(1, 200, size=24) for _ in range(6)]
@@ -373,7 +286,7 @@ def _self_check() -> None:
     assert tier_stats["restored_blocks"] > 0, (
         "tier never restored — bad self-check workload"
     )
-    assert_serve_compiles_bounded(engine=eng, distinct_prefill_shapes=0)
+    assert_serve_compiles_bounded(eng)
     live = [eng.submit(p, 4) for p in tier_prompts[:2]]
     eng.step()
     rebuilt = eng.clone_fresh()
@@ -407,7 +320,7 @@ def _self_check() -> None:
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=32, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, mixed_step="on", spec_k=3,
+        cache_dtype=jnp.float32, spec_k=3,
     )
     # repetitive prompts so prompt-lookup actually proposes (verify
     # widths churn through 0..k); one random prompt keeps plain rows in
@@ -466,7 +379,7 @@ def _self_check() -> None:
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=32, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, mixed_step="on",
+        cache_dtype=jnp.float32,
     )
     assert eng.epilogue_impl == "fused", (
         f"self-check expects the fused epilogue here, got "
@@ -543,7 +456,7 @@ def _self_check() -> None:
         eng = ServeEngine(
             mesh_params, mesh_cfg, sampler=Sampler(kind="greedy"),
             max_slots=2, num_blocks=32, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, mixed_step="on",
+            cache_dtype=jnp.float32,
             enable_prefix_cache=True, mesh_plan=MeshPlan(model=2),
         )
         mesh_prompts = [rng.integers(1, 200, size=n) for n in (26, 4, 17)]
@@ -557,7 +470,7 @@ def _self_check() -> None:
         assert counter.count == 0, (
             f"sharded unified-tick churn compiled: {counter.events}"
         )
-        assert_serve_compiles_bounded(engine=eng, distinct_prefill_shapes=0)
+        assert_serve_compiles_bounded(eng)
         # replica restart: clone_fresh + teacher-forced recovery on the
         # SAME mesh slice must not compile anything
         live = [eng.submit(p, 6) for p in mesh_prompts]
@@ -577,22 +490,7 @@ def _self_check() -> None:
         assert rebuilt_mesh.compile_counts() == warm
         held = rebuilt_mesh.pool.stats()["request_held"]
         assert held == 0, f"sharded restart leaked {held} blocks"
-        # the sharded phase-split engine obeys the same bounds
-        eng = ServeEngine(
-            mesh_params, mesh_cfg, sampler=Sampler(kind="greedy"),
-            max_slots=2, num_blocks=32, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, mixed_step="off",
-            mesh_plan=MeshPlan(model=2),
-        )
-        for p in mesh_prompts:
-            eng.submit(p, 6)
-        eng.run_until_complete()
-        shapes = {-(-(-(-int(p.size) // 8) * 8) // 8) for p in mesh_prompts}
-        assert_serve_compiles_bounded(
-            engine=eng, distinct_prefill_shapes=len(shapes),
-        )
-        print(f"compile counts OK (mesh tp=2): {warm} / "
-              f"{eng.compile_counts()}")
+        print(f"compile counts OK (mesh tp=2): {warm}")
     else:
         print("compile counts: mesh section SKIPPED (1 device)")
 
@@ -656,7 +554,7 @@ def _self_check() -> None:
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=32, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, mixed_step="on",
+        cache_dtype=jnp.float32,
         telemetry=TelemetryModel(cfg, params),
         tracer=TraceRecorder(ring=50_000),
     )
@@ -699,7 +597,7 @@ def _self_check() -> None:
         ServeEngine(
             params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
             num_blocks=32, block_size=8, max_seq_len=64,
-            cache_dtype=jnp.float32, mixed_step="on",
+            cache_dtype=jnp.float32,
         )
         for _ in range(3)
     ])
@@ -743,7 +641,7 @@ def _self_check() -> None:
     eng = ServeEngine(
         params, cfg, sampler=Sampler(kind="greedy"), max_slots=2,
         num_blocks=32, block_size=8, max_seq_len=64,
-        cache_dtype=jnp.float32, mixed_step="on", tenants=ledger,
+        cache_dtype=jnp.float32, tenants=ledger,
     )
     ten_prompts = [rng.integers(1, 200, size=n) for n in (19, 7, 11)]
     eng.warmup([int(p.size) for p in ten_prompts], max_new_tokens=8)

@@ -22,8 +22,7 @@ Mechanics (no shadow table — the donating set is parsed from the code):
   donate_argnums=...)``) inside a ``_make_*`` builder method; the
   engine attribute it lands on is recovered from ``self.X =
   self._make_Y(...)`` assignments (builders that return another
-  builder's result, like ``_make_decode_step`` →
-  ``_make_paged_decode_step``, chain transitively);
+  builder's result chain transitively);
 - a finding is a call to a donating attribute inside an ``except``
   handler whose TRY body also calls it, passing a textually identical
   expression at a donated argument position — the donated operand was

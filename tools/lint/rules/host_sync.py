@@ -25,9 +25,7 @@ Within that scope:
 - ``np.asarray(x)`` / ``np.array(x)`` / ``float(x)`` / ``int(x)`` —
   flagged only when ``x`` mentions a DEVICE-ORIGIN name: a local
   assigned from a jitted-step/dispatch call (``self._dispatch_*``,
-  ``self._decode_step``, ``self._mixed_step``, ``self._prefill_step``,
-  ``self._sample_first``, ``self._scatter_prefill``,
-  ``self._gather_prefix``).  Host-side numpy packing stays legal.
+  ``self._mixed_step``).  Host-side numpy packing stays legal.
 
 ONE-FETCH TIGHTENING (the tick-tail fusion contract): the exempt
 ``host_sync``/``deliver`` spans are no longer a free-fire zone — the
@@ -62,8 +60,7 @@ EXEMPT_PHASES = {"host_sync", "deliver"}
 FLEET_TICK_METHODS = ("ReplicaSet.step",)
 # engine attributes whose call results live on device
 _DEVICE_CALL_RE = re.compile(
-    r"^_(dispatch_\w+|mixed_step|decode_step|prefill_step|sample_first"
-    r"|scatter_prefill|gather_prefix)$"
+    r"^_(dispatch_\w+|mixed_step)$"
 )
 _NP_SYNC = {("np", "asarray"), ("np", "array"), ("numpy", "asarray"),
             ("numpy", "array")}

@@ -4,16 +4,16 @@ support.py probe, with an XLA fallback sibling.
 Whether Mosaic accepts a kernel's BlockSpecs is only knowable at compile
 time on real hardware (the r3 postmortem), so every selection site must
 ask ``ops/pallas/support.py`` first (``gate_attn_impl`` /
-``kernel_error`` / ``kernel_available``) and hold an XLA path to fall
+``kernel_error`` / ``kernel_or_warn`` / ``kernel_available``) and hold an XLA path to fall
 back to.  This rule checks, statically, that serve code cannot reach a
 kernel any other way:
 
 1. The GATED KERNEL SET is parsed out of ``support.py``'s ``KERNELS``
    tuple — the lint can never drift from what the probes cover.
 2. A gate-taint analysis over each serve module marks every name/
-   attribute derived from a gate-function result (``decode_attn_impl =
-   gate_attn_impl(...)``, ``self.mixed`` assigned under ``if
-   kernel_error(...) is None``), propagating through assignments,
+   attribute derived from a gate-function result (``err =
+   kernel_or_warn(...)``, ``self.ragged_attn_impl`` assigned from ``err
+   is None``), propagating through assignments,
    conditional branches, and call arguments into callee parameters.
 3. Every reference to a gated kernel symbol must sit under a
    conditional whose test reads gate taint — either directly in its
@@ -40,7 +40,8 @@ from tools.lint.core import (
 RULE_ID = "R5"
 
 SUPPORT_PATH = "llm_np_cp_tpu/ops/pallas/support.py"
-GATE_FUNCS = {"gate_attn_impl", "kernel_error", "kernel_available"}
+GATE_FUNCS = {"gate_attn_impl", "kernel_error", "kernel_or_warn",
+              "kernel_available"}
 PALLAS_PREFIX = "llm_np_cp_tpu.ops.pallas"
 # symbols from ops/pallas that are NOT device kernels (metadata and the
 # XLA fallbacks live in the same modules)
@@ -75,7 +76,7 @@ def _gated_imports(sf: SourceFile) -> tuple[dict[str, str], set[str]]:
     """→ (kernel alias → kernel symbol, pallas MODULE aliases).
 
     Covers both spellings: ``from ...pallas.decode_attention import
-    paged_decode_attention [as x]`` binds the kernel directly, while
+    ragged_paged_attention [as x]`` binds the kernel directly, while
     ``from ...ops.pallas import decode_attention`` / ``import
     ...pallas.decode_attention as da`` bind a module whose attributes
     reach the kernels — both must be gate-checked."""
@@ -98,6 +99,18 @@ def _gated_imports(sf: SourceFile) -> tuple[dict[str, str], set[str]]:
                 if alias.name.startswith(PALLAS_PREFIX):
                     modules.add(alias.asname or alias.name.split(".")[-1])
     return symbols, modules
+
+
+def _bound_names(fn: ast.AST) -> set[str]:
+    """The names ``fn`` binds itself: its parameters and what its own
+    body assigns (nested functions not followed)."""
+    a = fn.args
+    bound = {p.arg for p in a.posonlyargs + a.args + a.kwonlyargs}
+    bound |= {p.arg for p in (a.vararg, a.kwarg) if p is not None}
+    for node in walk_within(fn, skip_nested=True):
+        if isinstance(node, ast.Name) and isinstance(node.ctx, ast.Store):
+            bound.add(node.id)
+    return bound
 
 
 class _Taint:
@@ -123,7 +136,15 @@ class _Taint:
                 break
 
     def expr_tainted(self, node: ast.AST, fn: ast.AST) -> bool:
-        names = self.local.get(fn, set())
+        # a nested function reads its builder's locals as closure
+        # variables (``use_kernel`` inside the jitted step) — but not a
+        # name an inner function binds itself: that one shadows it
+        names = set(self.local.get(fn, ()))
+        shadowed = _bound_names(fn)
+        for anc in self.sf.ancestors(fn):
+            if anc in self.local:
+                names |= self.local[anc] - shadowed
+                shadowed |= _bound_names(anc)
         for n in ast.walk(node):
             if isinstance(n, ast.Name) and n.id in names:
                 return True
@@ -256,7 +277,7 @@ class _Rule:
                         )
         # kernel uses: direct symbol aliases, plus attribute access
         # through an imported pallas module (``decode_attention.
-        # paged_decode_attention(...)`` must not bypass the rule)
+        # ragged_paged_attention(...)`` must not bypass the rule)
         uses: list[tuple[ast.AST, str, str]] = []
         for node in ast.walk(sf.tree):
             if (isinstance(node, ast.Name)

@@ -6,15 +6,14 @@ Reads the Chrome trace-event JSON written by ``--trace-out`` (or scraped
 from ``GET /debug/trace``) and prints:
 
 - **per-phase totals** — count / total / mean / max for every tick-phase
-  slice (admission, prefill, grow, decode_dispatch, host_sync, deliver)
-  and the prefill_chunk dispatches, plus the phase-coverage ratio
+  slice (``MIXED_TICK_PHASES`` below), plus the phase-coverage ratio
   (phase time / tick time — the tracer's own sanity invariant);
 - **top-K slowest ticks** — timestamp, duration, and the tick's args
   (active slots, queue depth, admissions), the starting point for any
   p99 hunt;
 - **roofline** (when the trace was recorded with ``--roofline``) —
   per-tick achieved GB/s and roofline-utilization percentiles from the
-  telemetry tick args, split vs mixed ticks reported separately;
+  telemetry tick args;
 - **kv_tier** (when the trace was recorded with ``--kv-tier host``) —
   spilled/restored bytes and restore-latency percentiles from the
   host-tier tick args;
@@ -237,7 +236,7 @@ def mixed_utilization(events: list[dict]) -> dict[str, float] | None:
     draft/verify/accept-length split lands here too (verify lanes =
     drafted tokens riding the one dispatch; accept rate = how many paid
     off; emitted decode tokens = decode_tokens + spec_accept_tokens).
-    None when no tick carries the args (a phase-split trace)."""
+    None when no tick carries the args."""
     pairs = [
         (e.get("args") or {}, float(e.get("dur", 0.0)))
         for e in events
@@ -302,36 +301,28 @@ def _pct(vals: list[float], q: float) -> float:
 def roofline(events: list[dict]) -> dict[str, dict[str, float]] | None:
     """Roofline telemetry from the per-tick ``roofline_gbps``/
     ``roofline_util`` args (serve/telemetry.py stamps them when
-    ``--roofline`` is on): achieved-GB/s and utilization percentiles,
-    split by tick kind — ``mixed`` (unified ticks carry
-    ``prefill_tokens``) vs ``split`` (phase-split decode dispatches).
-    None when no tick carries the args (telemetry was off)."""
-    out: dict[str, dict[str, float]] = {}
-    by_kind: dict[str, list[dict]] = defaultdict(list)
-    for ev in events:
-        if ev.get("ph") != "X" or ev.get("cat") != "tick":
-            continue
-        args = ev.get("args") or {}
-        if "roofline_util" not in args:
-            continue
-        kind = "mixed" if "prefill_tokens" in args else "split"
-        by_kind[kind].append(args)
-    for kind, ticks in by_kind.items():
-        gbps = [a["roofline_gbps"] for a in ticks]
-        util = [a["roofline_util"] for a in ticks]
-        out[kind] = {
-            "ticks": len(ticks),
-            "gbps_p50": _pct(gbps, 50),
-            "gbps_p90": _pct(gbps, 90),
-            "gbps_p99": _pct(gbps, 99),
-            "util_p50": _pct(util, 50),
-            "util_p99": _pct(util, 99),
-            "util_mean": sum(util) / len(util),
-            "device_s_total": sum(
-                a.get("device_time_s", 0.0) for a in ticks
-            ),
-        }
-    return out or None
+    ``--roofline`` is on): achieved-GB/s and utilization percentiles
+    under the one tick kind there is, ``mixed``.  None when no tick
+    carries the args (telemetry was off)."""
+    ticks = [
+        ev["args"] for ev in events
+        if ev.get("ph") == "X" and ev.get("cat") == "tick"
+        and "roofline_util" in (ev.get("args") or {})
+    ]
+    if not ticks:
+        return None
+    gbps = [a["roofline_gbps"] for a in ticks]
+    util = [a["roofline_util"] for a in ticks]
+    return {"mixed": {
+        "ticks": len(ticks),
+        "gbps_p50": _pct(gbps, 50),
+        "gbps_p90": _pct(gbps, 90),
+        "gbps_p99": _pct(gbps, 99),
+        "util_p50": _pct(util, 50),
+        "util_p99": _pct(util, 99),
+        "util_mean": sum(util) / len(util),
+        "device_s_total": sum(a.get("device_time_s", 0.0) for a in ticks),
+    }}
 
 
 def kv_tier(events: list[dict]) -> dict[str, float] | None:
@@ -454,7 +445,7 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
     packed widths (tile lanes inside attention) the dispatches used
     and — where the ticks say it — their programs, ``packed x dense``
     width, with the share of dense lanes that held a token.  None
-    for a trace without the cut phases (split tick, older dumps)."""
+    for a trace without the cut phases (older dumps)."""
     ticks = [e for e in events if e.get("ph") == "X"
              and e.get("cat") == "tick" and "packed_width" in
              (e.get("args") or {}) and e["args"]["packed_width"]]
