@@ -68,6 +68,11 @@ class KVCache(NamedTuple):
     # then holds the history of the convolution in front of the mixer.
     # Both are laid out by ``config.state_shapes``.
     ssm: jnp.ndarray | None = None  # [n_ssm, B, heads, d_head, d_state] f32
+    # matrix state of a configuration with delta-rule linear-attention
+    # layers (config.kda_layers), float32 likewise; ``conv`` then holds
+    # the history of the convolution over [q | k | v] in front of it, and
+    # ``L`` above counts the latent layers only
+    kda: jnp.ndarray | None = None  # [n_kda, B, heads, d, d] f32
 
     @classmethod
     def init(

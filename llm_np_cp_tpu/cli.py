@@ -225,7 +225,8 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "checkpoint must be: start-up fails unless its "
                    "config's model_type is MODEL_TYPE (llama, mistral, "
                    "mixtral, qwen2, gemma2, lfm2_moe, falcon_h1, "
-                   "deepseek_v3, mimo_v2) — so that a deployment never "
+                   "deepseek_v3, mimo_v2, ling_hybrid) — so that a "
+                   "deployment never "
                    "serves another architecture under a model's name")
     p.add_argument("--chaos-spec", default=None, metavar="SPEC",
                    help="fault-injection schedule (serve/faults.py): "
@@ -740,6 +741,12 @@ def _build_serve_engine(args, params, config, *, prog: str,
                   f"dispatches against {telemetry.hbm_gbps:g} GB/s "
                   "(achieved GB/s + MFU on /metrics, per-request cost "
                   "attribution in the request log)")
+            if config.kda_layers:
+                print(f"[{prog}] roofline telemetry: its bill streams every "
+                      "weight once a dispatch and knows no recurrent state — "
+                      f"it does NOT price model_type {config.model_type!r} "
+                      "(experts touched, a matrix state read and written a "
+                      "row): read its utilization as a ratio, not a grade")
     slo_ttft = getattr(args, "slo_ttft", 0.0) or None
     slo_tpot = getattr(args, "slo_tpot", 0.0) or None
     slo_policy = None
@@ -863,6 +870,7 @@ def _build_serve_engine(args, params, config, *, prog: str,
         return engine, num_blocks
     if engine.mesh is not None:
         print(f"[{prog}] mesh ACTIVE: {engine.mesh_desc}")
+    state_impl = engine.ssm_state_impl or engine.kda_state_impl
     print(f"[{prog}] unified tick ACTIVE: one mixed dispatch/tick, "
           f"budget {engine.tick_token_budget} tokens "
           f"(ragged attention: {engine.ragged_attn_impl}, "
@@ -870,8 +878,7 @@ def _build_serve_engine(args, params, config, *, prog: str,
           + ("pool written in place" if engine.pool_carried else
              "pool moved by layer slabs (not row-major on this device)")
           + f", pages {engine.pool_page_shape}"
-          + (f", state update: {engine.ssm_state_impl}"
-             if engine.ssm_state_impl else ""))
+          + (f", state update: {state_impl}" if state_impl else ""))
     if engine.spec_k:
         print(f"[{prog}] speculative serving ACTIVE: k={engine.spec_k} "
               "draft tokens/tick, prompt-lookup drafts verified in the "

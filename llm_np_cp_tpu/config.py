@@ -228,6 +228,33 @@ class ModelConfig:
     rope_dim: int | None = None  # None: all of head_dim
     attention_value_scale: float = 1.0
 
+    # --- Delta-rule linear-attention layers (KDA) among latent ones
+    # (Ling-3.0, ``model_type: ling_hybrid``).  ``layer_group_size is
+    # None`` is every other family.  Layer ``i`` is latent attention where
+    # ``(i + 1) % layer_group_size == 0`` and a KDA layer elsewhere:
+    # ``num_attention_heads`` heads of ``kda_head_dim`` channels (keys and
+    # values alike), a ``[kda_head_dim, kda_head_dim]`` float32 matrix
+    # state a head, a depthwise causal convolution of ``kda_conv_taps``
+    # taps over q, k and v in front, a per-channel log-decay in
+    # ``[kda_lower_bound, 0]`` (ops/kda.py has the equations).
+    layer_group_size: int | None = None
+    kda_head_dim: int = 0
+    kda_conv_taps: int = 4
+    kda_lower_bound: float = -5.0
+    # Seeded random weights only (models.init_params; no forward reads
+    # it): the span ``(slowest, fastest)`` of a token's log-decay over a
+    # head's channels that ``kda_dt_bias`` is drawn for, where a
+    # configuration's FILE states one and says why (with zero biases the
+    # gate sits at ``kda_lower_bound / 2``, the state forgets in two
+    # tokens and no comparison of outputs can see one carried wrongly)
+    init_kda_log_decay: tuple[float, float] | None = None
+    # group-limited routing (DeepSeek-V3's): the router's experts in
+    # ``n_group`` groups of consecutive ones, a token's choice limited to
+    # its ``topk_group`` best groups (ops/moe.route_sigmoid_topk).
+    # ``n_group == 1`` is no limit and traces nothing.
+    n_group: int = 1
+    topk_group: int = 1
+
     def __post_init__(self) -> None:
         # Note: hidden_size need not equal heads*head_dim (Gemma-2-2B:
         # 2304 hidden, 8 heads of 256), so no divisibility constraint there.
@@ -267,6 +294,22 @@ class ModelConfig:
             raise ValueError(
                 f"experts {self.first_expert}..+{self.experts_held} are not "
                 f"among the router's {self.num_experts}")
+        if self.layer_group_size is not None and (
+                not self.is_latent or self.kda_head_dim < 1
+                or self.layer_group_size < 2):
+            raise ValueError(
+                "KDA layers among latent ones (layer_group_size "
+                f"{self.layer_group_size}) need kv_lora_rank, a kda_head_dim "
+                "and a group of at least 2 layers")
+        if self.n_group > 1 and (
+                self.num_experts is None or self.num_experts % self.n_group
+                or not 0 < self.topk_group <= self.n_group
+                or self.topk_group * (self.num_experts // self.n_group)
+                < self.num_experts_per_tok):
+            raise ValueError(
+                f"group-limited routing: {self.n_group} groups over "
+                f"{self.num_experts} experts, {self.topk_group} kept, for "
+                f"{self.num_experts_per_tok} experts a token")
         if self.mamba_d_ssm is not None:
             if self.mamba_d_ssm != self.mamba_n_heads * self.mamba_d_head:
                 raise ValueError(
@@ -370,10 +413,14 @@ class ModelConfig:
     def layer_op(self, layer_idx: int) -> str:
         """``"attn"``, ``"conv"``, ``"attn_ssm"`` (attention and a
         state-space mixer side by side, both reading one normed input),
-        ``"latent"`` (attention over one compressed row a token) or
-        ``"swa"`` (a window layer whose K/V differ in shape from the
+        ``"latent"`` (attention over one compressed row a token),
+        ``"kda"`` (delta-rule linear attention: a matrix state, no pages)
+        or ``"swa"`` (a window layer whose K/V differ in shape from the
         global layers': ``attn_kind("window")``): the operator of layer
         ``layer_idx``."""
+        if self.layer_group_size is not None and (
+                (layer_idx + 1) % self.layer_group_size):
+            return "kda"
         if self.is_latent:
             return "latent"
         if self.mamba_d_ssm is not None:
@@ -397,7 +444,7 @@ class ModelConfig:
         ``attn_layers[i]``; with two page classes, to ``global_layers[i]``
         / ``window_layers[i]`` of its class)."""
         return tuple(i for i in range(self.num_hidden_layers)
-                     if self.layer_op(i) != "conv")
+                     if self.layer_op(i) not in ("conv", "kda"))
 
     @property
     def conv_layers(self) -> tuple[int, ...]:
@@ -413,10 +460,22 @@ class ModelConfig:
                      if self.layer_op(i) == "attn_ssm")
 
     @property
+    def kda_layers(self) -> tuple[int, ...]:
+        """Layers that carry a delta-rule matrix state (and the history
+        of the convolution in front of it), in order."""
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.layer_op(i) == "kda")
+
+    @property
+    def kda_dim(self) -> int:
+        """Channels of one of a KDA layer's streams (q, k, v, decay)."""
+        return self.num_attention_heads * self.kda_head_dim
+
+    @property
     def carries_state(self) -> bool:
         """A sequence carries more than K/V between steps: a function of
         the WHOLE sequence so far, which no block of a pool holds."""
-        return bool(self.conv_layers or self.ssm_layers)
+        return bool(self.conv_layers or self.ssm_layers or self.kda_layers)
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -430,9 +489,12 @@ class ModelConfig:
         short convolution (a conv layer's gated inputs, or the [x, B, C]
         inputs in front of a state-space mixer) in the served ``dtype``;
         ``ssm`` is the mixer's recurrent state, float32 whatever is
-        served: it is rounded once a token for hundreds of tokens.  The
-        ONE statement of these shapes: the pool (``PagedKV.state``) and
-        the offline cache (``KVCache.conv`` / ``.ssm``) both read it."""
+        served: it is rounded once a token for hundreds of tokens; ``kda``
+        is a delta-rule layer's matrix state, float32 likewise (its
+        ``conv`` holds the q, k and v streams side by side).  The ONE
+        statement of these shapes: the pool (``PagedKV.state``) and the
+        offline cache (``KVCache.conv`` / ``.ssm`` / ``.kda``) both read
+        it."""
         out: dict[str, tuple] = {}
         if self.conv_layers:
             out["conv"] = ((len(self.conv_layers), slots,
@@ -443,11 +505,19 @@ class ModelConfig:
                             self.mamba_conv_dim), dtype)
             out["ssm"] = ((n, slots, self.mamba_n_heads, self.mamba_d_head,
                            self.mamba_d_state), "float32")
+        if self.kda_layers:
+            n, d = len(self.kda_layers), self.kda_head_dim
+            out["conv"] = ((n, slots, self.kda_conv_taps - 1,
+                            3 * self.kda_dim), dtype)
+            out["kda"] = ((n, slots, self.num_attention_heads, d, d),
+                          "float32")
         return out
 
     @property
     def is_latent(self) -> bool:
-        """Attention reads one compressed row a token (MLA)."""
+        """The layers that have pages hold one compressed row a token
+        (MLA): every layer, or (``layer_group_size``) the one latent
+        layer of each group of delta-rule ones."""
         return self.kv_lora_rank is not None
 
     def kv_token_shapes(self, kind: str = "global") -> dict[str, tuple[int, ...]]:
@@ -657,10 +727,6 @@ class ModelConfig:
                 raise ValueError(
                     "deepseek_v3 with rope_scaling (YaRN and its mscale) is "
                     "not implemented")
-            if d.get("n_group", 1) > 1 or d.get("topk_group", 1) > 1:
-                raise ValueError(
-                    "deepseek_v3 with n_group / topk_group > 1 (group-limited "
-                    "routing) is not implemented")
             if d.get("scoring_func", "sigmoid") != "sigmoid":
                 raise ValueError(
                     f"deepseek_v3 with scoring_func "
@@ -705,10 +771,14 @@ class ModelConfig:
                 norm_topk_prob=d.get("norm_topk_prob", True),
                 routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
                 router_norm_eps=1e-20,
+                n_group=d.get("n_group") or 1,
+                topk_group=d.get("topk_group") or 1,
                 tie_word_embeddings=d.get("tie_word_embeddings", False),
                 init_expert_specific=d.get("init_expert_specific"),
                 init_expert_out_std=d.get("init_expert_out_std"),
             )
+        if model_type == "ling_hybrid":
+            kwargs.update(_ling_hybrid_kwargs(d, head_dim))
         if model_type == "mimo_v2":
             # MiMo-V2 (MiMo-V2-Flash / V2.5): global and window attention
             # layers by ``hybrid_layer_pattern`` (0 / 1), each kind with
@@ -741,10 +811,6 @@ class ModelConfig:
                 raise ValueError(
                     "mimo_v2 with add_full_attention_sink_bias (a sink in "
                     "the global layers) is not implemented")
-            if d.get("n_group", 1) > 1 or d.get("topk_group", 1) > 1:
-                raise ValueError(
-                    "mimo_v2 with n_group / topk_group > 1 (group-limited "
-                    "routing) is not implemented")
             if d.get("n_shared_experts"):
                 raise ValueError(
                     "mimo_v2 with n_shared_experts is not implemented")
@@ -803,6 +869,8 @@ class ModelConfig:
                 norm_topk_prob=d.get("norm_topk_prob", True),
                 routed_scaling_factor=1.0 if scaling is None else float(scaling),
                 router_norm_eps=1e-20,
+                n_group=d.get("n_group") or 1,
+                topk_group=d.get("topk_group") or 1,
                 tie_word_embeddings=d.get("tie_word_embeddings", False),
                 init_expert_specific=d.get("init_expert_specific"),
                 init_expert_out_std=d.get("init_expert_out_std"),
@@ -960,12 +1028,101 @@ QWEN_2_5_1_5B = dataclasses.replace(
     head_dim=128,
 )
 
+def _ling_hybrid_kwargs(d: Mapping[str, Any], head_dim: int) -> dict[str, Any]:
+    """``from_hf_dict`` for Ling-3.0 (``model_type: ling_hybrid``, this
+    package's own name for the family: the published row states none this
+    program could tell from its predecessors).  Groups of
+    ``layer_group_size`` layers, the last of each latent attention without
+    a query latent (DeepSeek-V3's operator, RoPE on ``qk_rope_head_dim``
+    columns by halves), the others KDA (ops/kda.py); ``first_k_dense_replace``
+    leading dense SwiGLU blocks, then sigmoid-routed experts chosen by score
+    + bias under a group limit beside one shared expert; an untied head.
+    What has no equations here is refused by its key."""
+    for key in ("q_lora_rank", "rope_scaling"):
+        if d.get(key) is not None:
+            raise ValueError(f"ling_hybrid with {key} is not implemented")
+    for key in ("use_nGPT", "value_norm", "up_proj_norm", "scale_router_input",
+                "use_kda_lora", "mtp_use_kda", "use_mla_nope", "use_bias",
+                "use_qkv_bias", "attention_bias"):
+        if d.get(key, False):
+            raise ValueError(f"ling_hybrid with {key} is not implemented")
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        limits = list(d.get(key) or ())[:d["num_hidden_layers"]]
+        if any(limits):
+            raise ValueError(
+                f"ling_hybrid with a non-zero {key} entry (a clamped SwiGLU, "
+                f"layer {next(i for i, v in enumerate(limits) if v)}) is not "
+                "implemented")
+    score = d.get("score_function", d.get("scoring_func", "sigmoid"))
+    if score != "sigmoid" or d.get("scoring_func", score) != score:
+        raise ValueError(
+            f"ling_hybrid with score_function {score!r} is not implemented "
+            "(sigmoid)")
+    if d.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(
+            f"ling_hybrid with topk_method {d['topk_method']!r} is not "
+            "implemented (noaux_tc)")
+    for key, only in (("no_kda_lora", True), ("kda_safe_gate", True),
+                      ("use_qk_norm", True), ("linear_silu", True),
+                      ("group_norm_size", 1),
+                      ("num_kv_heads_for_linear_attn", 0),
+                      ("gated_attention_proj_granularity_type", "head_wise")):
+        if d.get(key, only) != only:
+            raise ValueError(
+                f"ling_hybrid with {key} {d[key]!r} is not implemented "
+                f"({only!r})")
+    rope_dim = d["qk_rope_head_dim"]
+    if d.get("rotary_dim", rope_dim) != rope_dim:
+        raise ValueError(
+            f"ling_hybrid rotary_dim {d['rotary_dim']} is not "
+            f"qk_rope_head_dim {rope_dim}")
+    # ``num_experts`` is what is HELD; a file that states one chip's share
+    # names the router's width and the first expert held (as deepseek_v3)
+    held = d["num_experts"]
+    router = d.get("router_experts", held)
+    moe_i = d["moe_intermediate_size"]
+    shared = d.get("moe_shared_expert_intermediate_size")
+    if shared is None:
+        shared = (d.get("num_shared_experts") or 0) * moe_i
+    span = d.get("init_kda_log_decay")
+    return dict(
+        head_dim=rope_dim,
+        kda_head_dim=head_dim,
+        layer_group_size=d["layer_group_size"],
+        kda_conv_taps=d.get("short_conv_kernel_size", 4),
+        kda_lower_bound=float(d.get("kda_lower_bound", -5.0)),
+        init_kda_log_decay=None if span is None else (
+            float(span[0]), float(span[1])),
+        kv_lora_rank=d["kv_lora_rank"],
+        qk_nope_head_dim=d["qk_nope_head_dim"],
+        qk_rope_head_dim=rope_dim,
+        v_head_dim=d["v_head_dim"],
+        rope_interleave=False,
+        num_experts=router,
+        num_experts_held=None if held == router else held,
+        first_expert=d.get("first_expert", 0),
+        num_experts_per_tok=d["num_experts_per_tok"],
+        num_dense_layers=d.get("first_k_dense_replace", 0),
+        moe_intermediate_size=moe_i,
+        shared_expert_intermediate_size=shared or None,
+        use_expert_bias=bool(d.get("moe_router_enable_expert_bias", True)),
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        router_norm_eps=1e-20,
+        n_group=d.get("n_group") or 1,
+        topk_group=d.get("topk_group") or 1,
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        init_expert_specific=d.get("init_expert_specific"),
+        init_expert_out_std=d.get("init_expert_out_std"),
+    )
+
+
 # model_type values ``from_hf_dict`` has equations for ("mistral" and
 # "mixtral" are the llama block, the latter with capacity-routed experts
 # when ``num_local_experts`` is set)
 KNOWN_MODEL_TYPES = frozenset(
     ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe",
-     "falcon_h1", "deepseek_v3", "mimo_v2"))
+     "falcon_h1", "deepseek_v3", "mimo_v2", "ling_hybrid"))
 
 PRESETS: dict[str, ModelConfig] = {
     "meta-llama/Llama-3.2-1B": LLAMA_3_2_1B,
@@ -1077,6 +1234,24 @@ def tiny_config(model_type: str = "llama", **overrides: Any) -> ModelConfig:
             num_experts=16, num_experts_per_tok=4, num_dense_layers=1,
             moe_intermediate_size=32, use_expert_bias=True,
             router_norm_eps=1e-20,
+        )
+    if model_type == "ling_hybrid":
+        # Ling-3.0's shape at toy sizes: two groups of THREE layers (kda,
+        # kda, latent) so that both operators and both feed-forwards
+        # appear in six: a leading dense block, 16 experts in 4 groups of
+        # which 2 stay, top-4, a shared expert; no width of the model
+        base.update(
+            num_hidden_layers=6, layer_group_size=3,
+            num_key_value_heads=4, head_dim=8, kda_head_dim=16,
+            tie_word_embeddings=False,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, rope_theta=6e6,
+            num_experts=16, num_experts_per_tok=4, num_dense_layers=1,
+            n_group=4, topk_group=2,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32,
+            use_expert_bias=True, routed_scaling_factor=2.5,
+            router_norm_eps=1e-20,
+            init_kda_log_decay=(-0.005, -0.5),
         )
     base.update(overrides)
     return ModelConfig(**base)

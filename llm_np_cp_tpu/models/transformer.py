@@ -90,10 +90,17 @@ SCOPE_MOE_SHARED = "moe_shared"
 # an operation, serve/opmap.py)
 SCOPE_ATTN_GLOBAL = "attn_global"
 SCOPE_ATTN_WINDOW = "attn_window"
+# a delta-rule linear-attention layer adds two, as the state-space mixer
+# does: everything around the recurrence (input norm, the projections, the
+# convolution and its history, gates, the output norm, out_proj), and
+# everything that touches the matrix state (ops/kda.py)
+SCOPE_KDA_PROJ = "kda_proj"
+SCOPE_KDA_SCAN = "kda_scan"
 # ... which only a stack with such layers enters
 HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
                  SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, SCOPE_MOE_SHARED,
-                 SCOPE_ATTN_GLOBAL, SCOPE_ATTN_WINDOW)
+                 SCOPE_ATTN_GLOBAL, SCOPE_ATTN_WINDOW,
+                 SCOPE_KDA_PROJ, SCOPE_KDA_SCAN)
 STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
                SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL) + HYBRID_SCOPES
 
@@ -178,9 +185,28 @@ def _group_shapes(
     NH, NK = config.num_attention_heads, config.num_key_value_heads
     if config.attention_bias or config.mlp_bias or config.conv_bias:
         raise NotImplementedError("a hybrid stack has no biased projection")
-    if op not in ("conv", "attn", "attn_ssm", "latent", "swa"):
+    if op not in ("conv", "attn", "attn_ssm", "latent", "swa", "kda"):
         raise ValueError(f"unknown layer operator {op!r}")
-    if op == "latent":
+    if op == "kda":
+        # delta-rule linear attention (ops/kda.py): four full-rank
+        # projections onto heads x kda_head_dim channels (q, k, v and the
+        # per-channel decay's), a scalar a head for beta and for the
+        # output gate, one depthwise filter a channel of each of q, k and
+        # v, the decay's own scalars, an output norm of one head's width
+        nd, taps = config.kda_dim, config.kda_conv_taps
+        shapes = {
+            "ln_attn_in": (n, H),
+            "kda_q_proj": (n, H, nd), "kda_k_proj": (n, H, nd),
+            "kda_v_proj": (n, H, nd), "kda_a_proj": (n, H, nd),
+            "kda_beta_proj": (n, H, NH), "kda_gate_proj": (n, H, NH),
+            # tap j meets u[t-(K-1)+j]
+            "kda_q_conv": (n, nd, taps), "kda_k_conv": (n, nd, taps),
+            "kda_v_conv": (n, nd, taps),
+            "kda_A_log": (n, NH), "kda_dt_bias": (n, nd),
+            "ln_kda_out": (n, config.kda_head_dim),
+            "kda_out_proj": (n, nd, H),
+        }
+    elif op == "latent":
         # latent attention: a query head is [q_nope | q_pe]; kv_a_proj's
         # columns are [c | k_pe] (k_pe ONE for all heads), kv_b_proj's per
         # head [k_nope | v], read from the normed c
@@ -252,6 +278,11 @@ def _group_shapes(
     return shapes
 
 
+# depthwise causal convolution filters ``[channels, taps]``: drawn of order
+# 1 by ``init_params``, stored ``[channels, 1, taps]`` by a checkpoint
+CONV_FILTER_LEAVES = frozenset(
+    ("conv_filter", "ssm_conv", "kda_q_conv", "kda_k_conv", "kda_v_conv"))
+
 # jitted init program per (config, dtype) — see init_params
 _INIT_PROGRAMS: dict = {}
 
@@ -307,6 +338,25 @@ def init_params(
                         return jnp.ones(shape, jnp.float32)
                     step = jnp.exp(math.log(1e-3) + u * math.log(1e2))
                     return step + jnp.log(-jnp.expm1(-step))
+                if name == "kda_A_log":
+                    # one rate a head about 1 (float32): exp(A_log) in
+                    # [0.8, 1.25]
+                    return (jax.random.uniform(key, shape, jnp.float32)
+                            - 0.5) * (2 * math.log(1.25))
+                if name == "kda_dt_bias":
+                    # float32, one a channel.  Zero unless the
+                    # configuration states a span ``(slowest, fastest)``
+                    # of log-decays a token: then log-uniform over it,
+                    # through the inverse of the gate at ``W_a x = 0`` and
+                    # ``A_log = 0`` (the projection moves each token's
+                    # about that)
+                    if config.init_kda_log_decay is None:
+                        return jnp.zeros(shape, jnp.float32)
+                    slow, fast = (math.log(-g) for g in
+                                  config.init_kda_log_decay)
+                    share = jnp.exp(slow + (fast - slow) * jax.random.uniform(
+                        key, shape, jnp.float32)) / -config.kda_lower_bound
+                    return jnp.log(share) - jnp.log1p(-share)
                 if name == "attn_sink":
                     # float32, of the order of a row's largest scores (a
                     # tenth to a half of the softmax's denominator over a
@@ -332,7 +382,7 @@ def init_params(
                     ).astype(dtype)
                 # a conv filter's three taps are of order 1 (the published
                 # code's default init is uniform in +-1/sqrt(3))
-                scale = 0.3 if name in ("conv_filter", "ssm_conv") else 0.02
+                scale = 0.3 if name in CONV_FILTER_LEAVES else 0.02
                 if name == "ssm_in_proj" and config.init_ssm_in_proj_std:
                     scale = config.init_ssm_in_proj_std
                 if name == "w2" and config.init_expert_out_std:
@@ -890,6 +940,71 @@ def ssm_block(
                 * config.ssm_out_multiplier)
 
 
+def kda_block(
+    w: Params,
+    x: jnp.ndarray,
+    *,
+    config: ModelConfig,
+    history: Any,
+    scan: Any,
+    token_mask: jnp.ndarray | None = None,
+) -> jnp.ndarray:
+    """A delta-rule linear-attention layer's operator (KDA, Ling-3.0's)
+    with its residual.  With ``h`` the block's input norm: ``[q, k, v] <-
+    silu(conv1d([W_q h, W_k h, W_v h]))`` (depthwise, causal,
+    ``kda_conv_taps`` taps, a filter a channel), ``q`` and ``k``
+    L2-normalised per head and ``q`` scaled by ``d^-0.5``; the log-decay
+    ``g = L sigmoid(exp(A_log) (W_a h + dt_bias))`` per channel in ``[L,
+    0]`` (``L = kda_lower_bound``), ``beta = sigmoid(w_beta . h)`` a head;
+    the recurrence (ops/kda.py); then ``W_o [RMSNorm_d(o) * sigmoid(W_gamma
+    h)]`` with one gate a head.  No RoPE: the decay carries position.
+
+    history: the hook of ``conv_block``, over the convolution's inputs.
+    scan: ``(q, k, v, g [B, S, H, d], beta [B, S, H]) -> o [B, S, H, d]``
+        float32 — the recurrence over the tokens as the CALLER lays
+        sequences out, which owns the state (a cache's, the tick's rows).
+    token_mask: ``[b, s]`` bool — False at padding, which neither enters
+        the convolution nor moves the state (``g = 0``, ``beta = 0``)."""
+    b_, s_ = x.shape[:2]
+    nh, d, taps = config.num_attention_heads, config.kda_head_dim, config.kda_conv_taps
+    f32 = jnp.float32
+    with jax.named_scope(SCOPE_KDA_PROJ):
+        h = input_norm(w, x, config)
+        qkv = jnp.concatenate(
+            [_project(h, w[name]) for name in
+             ("kda_q_proj", "kda_k_proj", "kda_v_proj")], axis=-1)
+        if token_mask is not None:
+            qkv = jnp.where(token_mask[..., None], qkv, jnp.zeros_like(qkv))
+        filt = jnp.concatenate(
+            [w[name].astype(f32) for name in
+             ("kda_q_conv", "kda_k_conv", "kda_v_conv")])  # [3 H d, K]
+        acc = qkv.astype(f32) * filt[:, taps - 1]
+        for back, prev in enumerate(history(qkv), start=1):
+            acc = acc + prev.astype(f32) * filt[:, taps - 1 - back]
+        q, k, v = (t.reshape(b_, s_, nh, d) for t in jnp.split(
+            jax.nn.silu(acc).astype(h.dtype).astype(f32), 3, axis=-1))
+        unit = lambda t: t * lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+        q, k = unit(q) * d ** -0.5, unit(k)
+        rate = jnp.exp(w["kda_A_log"].astype(f32))[:, None]
+        g = config.kda_lower_bound * jax.nn.sigmoid(rate * (
+            _project(h, w["kda_a_proj"]).astype(f32)
+            + w["kda_dt_bias"].astype(f32)).reshape(b_, s_, nh, d))
+        beta = jax.nn.sigmoid(_project(h, w["kda_beta_proj"]).astype(f32))
+        if token_mask is not None:
+            g = jnp.where(token_mask[..., None, None], g, 0.0)
+            beta = jnp.where(token_mask[..., None], beta, 0.0)
+    with jax.named_scope(SCOPE_KDA_SCAN):
+        o = scan(q, k, v, g, beta)
+    with jax.named_scope(SCOPE_KDA_PROJ):
+        o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                          + config.rms_norm_eps)
+        gate = jax.nn.sigmoid(_project(h, w["kda_gate_proj"]).astype(f32))
+        o = (o * w["ln_kda_out"].astype(f32) * gate[..., None]).astype(h.dtype)
+        return x + _project(o.reshape(b_, s_, nh * d), w["kda_out_proj"],
+                            x.dtype)
+
+
 def shifted_history(state: jnp.ndarray, z: jnp.ndarray, taps: int) -> tuple:
     """A convolution's ``history`` hook over whole sequences ``z [B, S,
     C]`` that continue ``state [B, taps - 1, C]`` (zeros before a
@@ -932,6 +1047,7 @@ def experts_block(
             norm_eps=config.router_norm_eps,
             live=live, first_expert=config.first_expert,
             out_dtype=x.dtype,
+            n_group=config.n_group, topk_group=config.topk_group,
         )
 
     t, chunk = b * s, EXPERT_CHUNK_PAIRS // config.num_experts_per_tok
@@ -1051,7 +1167,7 @@ def _hybrid_stack(
     over its own stacked leaves, an attention run carrying its cache
     slabs, a conv run its short-convolution state and a run with a
     state-space mixer both and the recurrent state as ``xs`` / ``ys``.
-    Returns ``(x, (k, v) | None, {"conv", "ssm"} states | None each,
+    Returns ``(x, (k, v) | None, {"conv", "ssm", "kda"} states | None each,
     chosen experts [expert layers, B, S, k])``."""
     if cache is not None and (cache.quantized or offset.ndim == 1):
         raise NotImplementedError(
@@ -1072,22 +1188,23 @@ def _hybrid_stack(
     # batch shapes drift apart an ulp at a time, and a router turns such
     # a drift into another expert (measured on the chip: PERF.md §6)
     stream_dtype, x = x.dtype, x.astype(jnp.float32)
-    new_k, new_v, new_conv, new_ssm, experts = [], [], [], [], []
+    new_k, new_v, new_conv, new_ssm, new_kda, experts = [], [], [], [], [], []
     a0 = c0 = 0  # layers with K/V / with a state seen so far
     # what a sequence carries besides K/V (zeros without a cache: every
     # sequence starts here), as ``config.state_shapes`` lays it out
     fresh = ({name: jnp.zeros(shape, dt) for name, (shape, dt)
               in config.state_shapes(b, stream_dtype).items()}
-             if cache is None else {"conv": cache.conv, "ssm": cache.ssm})
+             if cache is None else {"conv": cache.conv, "ssm": cache.ssm,
+                                    "kda": cache.kda})
     for w_g, (op, ff, _, n) in zip(groups, config.layer_groups()):
         xs: dict[str, Any] = {}
-        if op != "conv":
+        if op not in ("conv", "kda"):
             if cache is not None:
                 xs["k"] = cache.k[a0:a0 + n]
                 if cache.v is not None:  # a latent row has no V beside it
                     xs["v"] = cache.v[a0:a0 + n]
             a0 += n
-        if op in ("conv", "attn_ssm"):
+        if op in ("conv", "attn_ssm", "kda"):
             xs.update({name: a[c0:c0 + n] for name, a in fresh.items()
                        if a is not None})
             c0 += n
@@ -1110,6 +1227,17 @@ def _hybrid_stack(
                         if cache is not None else None))
                 if cache is not None:
                     ys["k"] = rows
+            elif op == "kda":
+                from llm_np_cp_tpu.ops import kda
+
+                def scan(q, k, v, g, beta):
+                    o, ys["kda"] = kda.kda_scan(
+                        state["kda"], q, k, v, g, beta, chunk=kda.CHUNK,
+                        lower_bound=config.kda_lower_bound)
+                    return o
+
+                x = kda_block(w, x, config=config, history=history,
+                              scan=scan, token_mask=token_mask)
             elif op != "conv":
                 normed = input_norm(w, x, config) if op == "attn_ssm" else None
                 l_cos, l_sin = rope_window if op == "swa" else (cos, sin)
@@ -1159,12 +1287,15 @@ def _hybrid_stack(
                 new_conv.append(ys["conv"].astype(cache.conv.dtype))
             if "ssm" in ys:
                 new_ssm.append(ys["ssm"])
+            if "kda" in ys:
+                new_kda.append(ys["kda"])
         if "experts" in ys:
             experts.append(ys["experts"])
     cat = lambda parts: jnp.concatenate(parts, axis=0) if parts else None
     x = x.astype(stream_dtype)
     return (x, (cat(new_k), cat(new_v)) if cache is not None else None,
-            {"conv": cat(new_conv), "ssm": cat(new_ssm)}, cat(experts))
+            {"conv": cat(new_conv), "ssm": cat(new_ssm), "kda": cat(new_kda)},
+            cat(experts))
 
 def forward(
     params: Params,

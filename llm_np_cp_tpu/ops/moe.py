@@ -142,6 +142,8 @@ def route_sigmoid_topk(
     scaling: float = 1.0,
     norm_eps: float = 1e-6,
     score_dtype: jnp.dtype = jnp.float32,
+    n_group: int = 1,
+    topk_group: int = 1,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``(chosen experts [T, k] int32, their weights [T, k] f32)``.
 
@@ -154,8 +156,14 @@ def route_sigmoid_topk(
     discrete change of the output that no dense layer has.  The top k are
     chosen by ``score + expert_bias``; the weights are the scores WITHOUT
     the bias, divided by their sum + ``norm_eps`` (``norm_topk_prob``; the
-    configuration's: LFM2 1e-6, DeepSeek-V3 1e-20), times ``scaling``.  ``score_dtype`` exists for the tests that show a bf16
-    router fails the float32 tolerance."""
+    configuration's: LFM2 1e-6, DeepSeek-V3 1e-20), times ``scaling``.
+    With ``n_group > 1`` the choice is group-limited (DeepSeek-V3's
+    ``noaux_tc``): the experts are ``n_group`` groups of consecutive
+    ones, a group's score is the sum of its two best ``score + bias``,
+    and only experts of a token's ``topk_group`` best groups can be
+    chosen; ``n_group == 1`` traces nothing of it.  ``score_dtype``
+    exists for the tests that show a bf16 router fails the float32
+    tolerance."""
     logits = jnp.einsum(
         "th,he->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
@@ -163,6 +171,15 @@ def route_sigmoid_topk(
     select = scores
     if expert_bias is not None:
         select = scores + expert_bias.astype(jnp.float32)
+    if n_group > 1:
+        t, e = select.shape
+        grouped = select.reshape(t, n_group, e // n_group)
+        group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = lax.top_k(group_score, topk_group)  # [T, topk_group]
+        keep = jnp.any(
+            kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype),
+            axis=1)  # [T, n_group]
+        select = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(t, e)
     _, idx = lax.top_k(select, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
@@ -193,7 +210,7 @@ def expert_row_tile(w: Any, rows: int, experts: int,
 # program and not once a layer — a warm start re-traces all nine programs)
 @functools.partial(jax.jit, static_argnames=(
     "act", "top_k", "norm_topk_prob", "scaling", "norm_eps", "first_expert",
-    "out_dtype", "interpret"))
+    "out_dtype", "interpret", "n_group", "topk_group"))
 def moe_dropless(
     x: jnp.ndarray,         # [T, H] — float32 where the caller has it
     router_w: jnp.ndarray,  # [H, E] — E: every expert of the layer
@@ -211,6 +228,8 @@ def moe_dropless(
     first_expert: int = 0,
     out_dtype: jnp.dtype | None = None,
     interpret: bool | None = None,
+    n_group: int = 1,    # group-limited routing: ``route_sigmoid_topk``
+    topk_group: int = 1,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Dropless routed SwiGLU experts: ``(out [T, H], chosen [T, k],
     load [E_held] int32)``.
@@ -242,6 +261,7 @@ def moe_dropless(
         idx, wts = route_sigmoid_topk(
             x, router_w, expert_bias, top_k=top_k,
             norm_topk_prob=norm_topk_prob, scaling=scaling, norm_eps=norm_eps,
+            n_group=n_group, topk_group=topk_group,
         )
         local = idx - first_expert
         here = (local >= 0) & (local < held)

@@ -58,6 +58,7 @@ KERNELS = (
     "sample_epilogue", "sample_epilogue_int8",
     "grouped_matmul",
     "ssm_state_update",
+    "kda_state_update",
 )
 
 # Pool block sizes the serve path uses (cli --block-size default 64,
@@ -90,6 +91,9 @@ class KernelShape:
     # a recurrent state ``(layers, rows, heads, groups, P, N)``, which
     # ``ssm_state_update`` alone advances; None: every other kernel
     state: tuple[int, ...] | None = None
+    # a delta-rule layer's matrix state ``(layers, rows, heads, d)`` (a
+    # head's ``[d, d]``), which ``kda_state_update`` alone advances
+    kda_state: tuple[int, ...] | None = None
 
     @classmethod
     def of(cls, name: str, config) -> "KernelShape":
@@ -142,6 +146,17 @@ STATE_PROBE_SHAPE = KernelShape(
 )
 FALCON_H1_STATE_SHAPE = dataclasses.replace(
     STATE_PROBE_SHAPE, name="falcon-h1-34b-6l", state=(6, 64, 32, 2, 128, 256))
+
+
+# ... and the delta-rule state update's: a small state of the served
+# head (128 x 128, a whole row of heads a block), and the benchmark's
+# cell (Ling-3.0-flash cut to 7 layers: 6 KDA layers, 64 slots, 768 MiB)
+KDA_PROBE_SHAPE = KernelShape(
+    "probe/kda", heads=12, kv_heads=2, head_dim=128, hidden=256, vocab=300,
+    kda_state=(2, 6, 4, 128),
+)
+LING_V3_STATE_SHAPE = dataclasses.replace(
+    KDA_PROBE_SHAPE, name="ling-3.0-flash-7l-ep4", kda_state=(6, 64, 32, 128))
 
 
 def family_shapes() -> tuple[KernelShape, ...]:
@@ -445,6 +460,43 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
 
         return make_args, run, reference
 
+    if base == "kda_state_update":
+        from llm_np_cp_tpu.ops.pallas import kda_state_update as ksu
+
+        # a tick of a layer's rows (ops/kda.kda_packed's first pass): two
+        # of every six have no token in it, every third that has starts
+        # from nothing; the twin is the same step in plain jnp
+        layers, rows, nh, d = shape.kda_state
+        layer = layers - 1
+        f32 = jnp.float32
+
+        def make_args():
+            state, rate, k, q, v, b = normals(
+                (layers, rows, nh, d, d), (rows, nh, d), (rows, nh, d),
+                (rows, nh, d), (rows, nh, d), (rows, nh), dtype=f32)
+            row = jnp.arange(rows)
+            count = jnp.where(row % 3 == 1, 0, 1 + row % 4).astype(jnp.int32)
+            unit = lambda a: a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+            return (state, jnp.exp(-jnp.abs(rate)), unit(k),
+                    unit(q) * d ** -0.5, v, jax.nn.sigmoid(b), count,
+                    (row % 3 == 0) & (count > 0))
+
+        def flat(o, state):
+            return jnp.concatenate([o.ravel(), state[layer].ravel()])
+
+        def run(state, decay, k, q, v, beta, count, fresh):
+            return flat(*ksu.kda_state_update(
+                state, jnp.int32(layer), decay, k, q, v, beta, count=count,
+                fresh=fresh, interpret=interpret))
+
+        def reference(state, decay, k, q, v, beta, count, fresh):
+            return flat(*ksu.kda_state_update_xla(
+                state, jnp.int32(layer), decay, k, q, v, beta, count=count,
+                fresh=fresh))
+
+        return jax.jit(make_args), jax.jit(run), reference
+
     if base == "sample_epilogue":
         from llm_np_cp_tpu.ops.norms import rms_norm
         from llm_np_cp_tpu.ops.pallas.sample_epilogue import sample_epilogue
@@ -500,7 +552,8 @@ def kernel_cases(shapes=None):
     the paged kernels at both serve block sizes."""
     shapes = shapes if shapes is not None else (
         *PROBE_SHAPES, LATENT_PROBE_SHAPE, STATE_PROBE_SHAPE,
-        FALCON_H1_STATE_SHAPE, *family_shapes())
+        FALCON_H1_STATE_SHAPE, KDA_PROBE_SHAPE, LING_V3_STATE_SHAPE,
+        *family_shapes())
     for shape in shapes:
         for kernel in KERNELS:
             if (kernel == "ragged_latent_attention") != (
@@ -508,6 +561,9 @@ def kernel_cases(shapes=None):
                 continue  # latent rows and their one kernel
             if (kernel == "ssm_state_update") != (shape.state is not None):
                 continue  # a recurrent state and its one kernel
+            if (kernel == "kda_state_update") != (
+                    shape.kda_state is not None):
+                continue  # a matrix state and its one kernel
             if not shape.tied and not kernel.startswith("sample_epilogue"):
                 continue  # only the epilogue distinguishes head layouts
             paged = kernel.startswith("ragged_")
@@ -565,7 +621,8 @@ def _probe(kernel: str, backend: str) -> str | None:
 
 def _compile_and_run(kernel: str) -> str | None:
     own = {"ragged_latent_attention": (LATENT_PROBE_SHAPE,),
-           "ssm_state_update": (STATE_PROBE_SHAPE,)}
+           "ssm_state_update": (STATE_PROBE_SHAPE,),
+           "kda_state_update": (KDA_PROBE_SHAPE,)}
     try:
         for shape in own.get(kernel, PROBE_SHAPES):
             if shape.tied or kernel.startswith("sample_epilogue"):

@@ -40,11 +40,15 @@ pytree keyed by what the configuration's layers carry
 
     state["conv"]: [layers, slots, taps - 1, channels]     served dtype
     state["ssm"]:  [layers, slots, heads, d_head, d_state]  float32
+    state["kda"]:  [layers, slots, heads, d, d]             float32
 
 one row a slot, no blocks: the last inputs of a short convolution (a conv
-layer's, or the one in front of a state-space mixer), and the mixer's
-recurrent state — float32 whatever is served, it is rounded once a token
-for hundreds of tokens.  The step carries the leaves and writes them in
+layer's, or the one in front of a state-space mixer or of a delta-rule
+layer's q, k and v), and the mixer's recurrent state or the delta-rule
+layer's matrix state — float32 whatever is served, it is rounded once a
+token for hundreds of tokens.  A stack may have BOTH pages and a state for
+different layers: a delta-rule stack's one latent layer a group has pages
+(``form.latent``), its other layers rows of the state.  The step carries the leaves and writes them in
 place at ``[layer, row]`` like the pages (donated); a slot's row is never
 read by a sequence's first token (a position-0 token's history and state
 are zero), so admitting a request into a freed slot needs no clear.
@@ -300,8 +304,8 @@ class PagedKV(NamedTuple):
     k_scale: jnp.ndarray | None = None  # [L, NB, BS, K] f32 (int8 mode)
     v_scale: jnp.ndarray | None = None
     # what a sequence carries besides K/V (module docstring): not paged,
-    # one row a slot, ``{"conv": .., "ssm": ..}`` as the configuration's
-    # layers need; None for a stack of attention layers alone
+    # one row a slot, ``{"conv": .., "ssm": .., "kda": ..}`` as the
+    # configuration's layers need; None for a stack of attention layers alone
     state: dict[str, jnp.ndarray] | None = None
     # set on a merged pool only; every other page says it by its shape
     form: PageForm | None = None

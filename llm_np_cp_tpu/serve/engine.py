@@ -95,6 +95,7 @@ from llm_np_cp_tpu.models.transformer import (
     SCOPE_ATTN_WINDOW,
     SCOPE_CONV,
     SCOPE_EMBED,
+    SCOPE_KDA_PROJ,
     SCOPE_SSM_PROJ,
     SCOPE_TAIL,
     attention_block,
@@ -104,12 +105,14 @@ from llm_np_cp_tpu.models.transformer import (
     ff_block,
     final_logits,
     input_norm,
+    kda_block,
     latent_attention_block,
     run_decoder_layer,
     scan_group,
     scan_unroll,
     ssm_block,
 )
+from llm_np_cp_tpu.ops import kda as kda_ops
 from llm_np_cp_tpu.ops import ssm as ssm_ops
 from llm_np_cp_tpu.ops.activations import ACT2FN
 from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS, expert_row_tile
@@ -472,7 +475,8 @@ class ServeEngine:
                  "--mesh model>1 (mesh_plan): the state and the mixer / "
                  "conv / expert weights have no sharding rule"),
             ]
-            kind = "conv" if config.conv_layers else "state-space"
+            kind = ("conv" if config.conv_layers else
+                    "delta-rule" if config.kda_layers else "state-space")
             for hit, why in refused:
                 if hit:
                     raise ValueError(
@@ -882,6 +886,17 @@ class ServeEngine:
                 tracer.complete(
                     "probe.ssm_state_update", t_probe, cat="setup",
                     args={"ok": self.ssm_state_impl == "pallas"})
+        # ... and a delta-rule layer's matrix state likewise
+        # (ops/pallas/kda_state_update / its twin: ``kda_packed``'s choice)
+        self.kda_state_impl: str | None = None
+        if config.kda_layers:
+            t_probe = tracer.now_us() if tracer is not None else -1.0
+            self.kda_state_impl = "pallas" if kda_ops.state_update_impl(
+                self.pool.pages.state["kda"]) else "xla"
+            if tracer is not None:
+                tracer.complete(
+                    "probe.kda_state_update", t_probe, cat="setup",
+                    args={"ok": self.kda_state_impl == "pallas"})
         # -- the tick: ONE jitted program, bucketed packed width —
         # prefill K/V goes straight into pool blocks and sampling
         # happens inside the mixed step.
@@ -1618,7 +1633,8 @@ class ServeEngine:
                 "shape")
         window_blocks = self.window_blocks
         # the scope of the bookkeeping every layer's state shares
-        state_scope = SCOPE_SSM_PROJ if config.ssm_layers else SCOPE_CONV
+        state_scope = (SCOPE_SSM_PROJ if config.ssm_layers else
+                       SCOPE_KDA_PROJ if config.kda_layers else SCOPE_CONV)
 
         @partial(jax.jit, donate_argnums=(1,))
         def mixed_step(
@@ -1980,7 +1996,7 @@ class ServeEngine:
                     [joined[1:], jnp.zeros((1,), jnp.bool_)])
                 # a row's last token of the tick leaves the row's state
                 row_out = jnp.where(ends, tok_row, max_slots)  # else: dropped
-                if config.ssm_layers:
+                if config.ssm_layers or config.kda_layers:
                     # the rows as the recurrence advances them: where a
                     # row's tokens start, how many it has, whether its
                     # sequence starts here (a slot's old state is never
@@ -2031,10 +2047,10 @@ class ServeEngine:
                 if op == "swa":
                     xs["paged"] = jnp.arange(w0, w0 + n, dtype=jnp.int32)
                     w0 += n
-                elif op != "conv":
+                elif op not in ("conv", "kda"):
                     xs["paged"] = layers[a0:a0 + n]
                     a0 += n
-                if op in ("conv", "attn_ssm"):
+                if op in ("conv", "attn_ssm", "kda"):
                     xs["state"] = jnp.arange(c0, c0 + n, dtype=jnp.int32)
                     c0 += n
 
@@ -2051,6 +2067,18 @@ class ServeEngine:
 
                     if op == "conv":
                         x = conv_block(w, x, config=config, history=history)
+                    elif op == "kda":
+                        def scan(q, k, v, g, beta):
+                            o, state["kda"] = kda_ops.kda_packed(
+                                state["kda"], at["state"], q[0], k[0], v[0],
+                                g[0], beta[0], tok_row=tok_row, start=start,
+                                count=count, fresh=fresh,
+                                chunk=kda_ops.CHUNK,
+                                lower_bound=config.kda_lower_bound)
+                            return o[None]
+
+                        x = kda_block(w, x, config=config, history=history,
+                                      scan=scan)
                     elif op == "latent":
                         # one array of rows, always carried flat
                         kv_update, attn_fn = latent_hooks(
@@ -3482,22 +3510,26 @@ class ServeEngine:
                 load_mean=moe["expert_load_mean"],
                 pairs_held=moe["pairs_held"],
                 state_slots_live=moe["state_slots_live"])
-        ssm = None
-        if self.config.ssm_layers and active:
-            ssm = {
-                # rows whose recurrent state the dispatch read and wrote
-                # (every layer's) — what the device moves under "pallas";
-                # under "xla" it moves every slot's row, these or not —,
-                # and the live tokens through the scan
-                "ssm_state_rows": active,
-                "ssm_scan_tokens": n_prefill_tok + n_decode_tok,
-                "state_slots_live": len(self.scheduler.running),
-                "ssm_state_impl": self.ssm_state_impl,
-            }
-            self.metrics.on_ssm(
-                rows=active, tokens=ssm["ssm_scan_tokens"],
-                state_slots_live=ssm["state_slots_live"],
-                kernel=self.ssm_state_impl == "pallas")
+        # rows whose recurrent state the dispatch read and wrote (every
+        # layer's) — what the device moves under "pallas"; under "xla" it
+        # moves every slot's row, these or not —, and the live tokens
+        # through the recurrence: a state-space mixer's (``ssm_*``) or a
+        # delta-rule layer's matrix state (``kda_*``)
+        state_args: dict[str, Any] = {}
+        for kind, layers, impl, report in (
+                ("ssm", self.config.ssm_layers, self.ssm_state_impl,
+                 self.metrics.on_ssm),
+                ("kda", self.config.kda_layers, self.kda_state_impl,
+                 self.metrics.on_kda)):
+            if layers and active:
+                state_args.update({
+                    f"{kind}_state_rows": active,
+                    f"{kind}_scan_tokens": n_prefill_tok + n_decode_tok,
+                    "state_slots_live": len(self.scheduler.running),
+                    f"{kind}_state_impl": impl})
+                report(rows=active, tokens=n_prefill_tok + n_decode_tok,
+                       state_slots_live=len(self.scheduler.running),
+                       kernel=impl == "pallas")
         outliers: list[dict] = []
         if self.tracer is not None and t0 >= 0.0:
             t7 = self._phase_mark(None)
@@ -3577,8 +3609,7 @@ class ServeEngine:
                 # one fetch): summarize_trace's transfers section and
                 # the benchmark's moe.* readers
                 targs.update(moe)
-            if ssm is not None:
-                targs.update(ssm)
+            targs.update(state_args)
             if self.spec_k:
                 # the draft/verify split for summarize_trace and the
                 # sentinel: how many verify lanes rode this tick's

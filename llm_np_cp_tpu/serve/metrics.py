@@ -207,6 +207,11 @@ class ServeMetrics:
         self.ssm_state_rows = 0
         self.ssm_scan_tokens = 0
         self.ssm_state_kernel = 0  # gauge: 1 where the Pallas kernel moves them
+        # ... and of a stack with delta-rule linear-attention layers
+        self.kda_ticks = 0
+        self.kda_state_rows = 0
+        self.kda_scan_tokens = 0
+        self.kda_state_kernel = 0
         # speculative draft-then-verify accounting (exact counters +
         # a real accept-length histogram over SPEC_ACCEPT_BUCKETS —
         # one observation per verify round, value = accepted drafts)
@@ -331,6 +336,18 @@ class ServeMetrics:
             self.ssm_scan_tokens += tokens
             self.conv_state_slots = state_slots_live
             self.ssm_state_kernel = int(kernel)
+
+    def on_kda(self, *, rows: int, tokens: int, state_slots_live: int,
+               kernel: bool) -> None:
+        """``on_ssm`` for a stack with delta-rule linear-attention layers:
+        the rows whose matrix state a dispatch read and wrote, the live
+        tokens through the recurrence, and which form advanced them."""
+        with self._lock:
+            self.kda_ticks += 1
+            self.kda_state_rows += rows
+            self.kda_scan_tokens += tokens
+            self.conv_state_slots = state_slots_live
+            self.kda_state_kernel = int(kernel)
 
     def on_spec(self, *, drafted: int, accepted: int) -> None:
         """One speculative verify round for one request: ``drafted``
@@ -532,6 +549,13 @@ class ServeMetrics:
                 out["ssm_scan_tokens"] = self.ssm_scan_tokens
                 out["ssm_state_slots_live"] = self.conv_state_slots
                 out["ssm_state_kernel"] = self.ssm_state_kernel
+            if self.kda_ticks:
+                # only where a delta-rule layer ran
+                out["kda_ticks"] = self.kda_ticks
+                out["kda_state_rows"] = self.kda_state_rows
+                out["kda_scan_tokens"] = self.kda_scan_tokens
+                out["kda_state_slots_live"] = self.conv_state_slots
+                out["kda_state_kernel"] = self.kda_state_kernel
             if self.spec_rounds:
                 # reported only once a verify round ran (like the SLO
                 # block): a fabricated 0-acceptance series on a
@@ -772,6 +796,26 @@ class ServeMetrics:
                  "rows a tick touches, 0 where the compiler's passes "
                  "advance every row",
                  [("", s["ssm_state_kernel"])])
+        if "kda_ticks" in s:
+            emit("kda_ticks_total", "counter",
+                 "Dispatching ticks that ran delta-rule linear-attention "
+                 "layers",
+                 [("", s["kda_ticks"])])
+            emit("kda_state_rows_total", "counter",
+                 "Rows whose matrix state a dispatch read and wrote (every "
+                 "delta-rule layer's), summed over ticks",
+                 [("", s["kda_state_rows"])])
+            emit("kda_scan_tokens_total", "counter",
+                 "Live tokens through the delta-rule recurrence, summed "
+                 "over ticks",
+                 [("", s["kda_scan_tokens"])])
+            emit("kda_state_slots_live", "gauge",
+                 "Slots whose matrix state is live",
+                 [("", s["kda_state_slots_live"])])
+            emit("kda_state_kernel", "gauge",
+                 "1 where the Pallas state-update kernel advances the "
+                 "rows a tick touches, 0 where its twin advances every row",
+                 [("", s["kda_state_kernel"])])
         # -- speculative decoding (only once a verify round ran — a
         # constant-zero series on a plain engine would read as a broken
         # speculation deployment on a fleet dashboard)

@@ -48,11 +48,12 @@ from llm_np_cp_tpu.models import (
     falcon_h1,
     gemma2,
     lfm2_moe,
+    ling_hybrid,
     llama,
     mimo_v2,
     qwen2,
 )
-from llm_np_cp_tpu.models.transformer import param_shapes
+from llm_np_cp_tpu.models.transformer import CONV_FILTER_LEAVES, param_shapes
 
 log = logging.getLogger("llm_np_cp_tpu")
 
@@ -119,16 +120,16 @@ def _read_shard(
 # leaves that stay float32 whatever is served: the experts' selection
 # bias, a state-space recurrence's own scalars
 F32_LEAVES = (frozenset(("expert_bias",)) | falcon_h1.F32_LEAVES
-              | mimo_v2.F32_LEAVES)
+              | mimo_v2.F32_LEAVES | ling_hybrid.F32_LEAVES)
 # depthwise Conv1d weights, stored [C, 1, taps]
-CONV1D_LEAVES = frozenset(("conv_filter", "ssm_conv"))
+CONV1D_LEAVES = CONV_FILTER_LEAVES
 
 
 def hybrid_family(config: ModelConfig):
     """The family module whose ``layer_tensors`` places a hybrid stack's
     checkpoint tensors (``(HF key, run, leaf, index, transpose?)``)."""
     return {"falcon_h1": falcon_h1, "deepseek_v3": deepseek_v3,
-            "mimo_v2": mimo_v2}.get(
+            "mimo_v2": mimo_v2, "ling_hybrid": ling_hybrid}.get(
         config.model_type, lfm2_moe)
 
 

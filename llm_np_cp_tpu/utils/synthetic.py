@@ -128,7 +128,7 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
             moe_intermediate_size=moe_i, moe_layer_freq=1,
             routed_scaling_factor=config.routed_scaling_factor,
             scoring_func="sigmoid", topk_method="noaux_tc",
-            n_group=1, topk_group=1,
+            n_group=config.n_group, topk_group=config.topk_group,
             norm_topk_prob=config.norm_topk_prob,
         )
         if config.init_expert_out_std is not None:
@@ -164,6 +164,36 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
         )
         if config.init_expert_out_std is not None:
             d["init_expert_out_std"] = config.init_expert_out_std
+    if config.model_type == "ling_hybrid":
+        moe_i = config.moe_intermediate_size
+        d.update(
+            head_dim=config.kda_head_dim,
+            layer_group_size=config.layer_group_size,
+            short_conv_kernel_size=config.kda_conv_taps,
+            kda_lower_bound=config.kda_lower_bound, kda_safe_gate=True,
+            no_kda_lora=True, use_qk_norm=True,
+            kv_lora_rank=config.kv_lora_rank, q_lora_rank=None,
+            qk_nope_head_dim=config.qk_nope_head_dim,
+            qk_rope_head_dim=config.qk_rope_head_dim,
+            rotary_dim=config.qk_rope_head_dim,
+            v_head_dim=config.v_head_dim,
+            num_experts=config.experts_held,
+            router_experts=config.num_experts,
+            first_expert=config.first_expert,
+            moe_shared_expert_intermediate_size=(
+                config.shared_expert_intermediate_size or 0),
+            num_experts_per_tok=config.num_experts_per_tok,
+            first_k_dense_replace=config.num_dense_layers,
+            moe_intermediate_size=moe_i,
+            moe_router_enable_expert_bias=config.use_expert_bias,
+            routed_scaling_factor=config.routed_scaling_factor,
+            score_function="sigmoid",
+            n_group=config.n_group, topk_group=config.topk_group,
+            norm_topk_prob=config.norm_topk_prob,
+        )
+        for key in ("init_kda_log_decay", "init_expert_out_std"):
+            if getattr(config, key) is not None:
+                d[key] = getattr(config, key)
     if config.model_type == "gemma2":
         d.update(
             final_logit_softcapping=config.final_logit_softcapping,

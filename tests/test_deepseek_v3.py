@@ -109,7 +109,9 @@ def test_a_token_leaves_one_row_of_576_values_a_layer():
 @pytest.mark.parametrize("change, match", [
     (dict(q_lora_rank=1536), "q_lora_rank"),
     (dict(rope_scaling={"rope_type": "yarn", "factor": 64}), "rope_scaling"),
-    (dict(n_group=8, topk_group=4), "n_group"),
+    # (groups are data since PR 47; more groups kept than there are is
+    # still refused)
+    (dict(n_group=8, topk_group=9), "group-limited routing"),
     (dict(scoring_func="softmax"), "scoring_func"),
     (dict(topk_method="greedy"), "topk_method"),
     (dict(moe_layer_freq=2), "moe_layer_freq"),

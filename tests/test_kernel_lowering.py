@@ -176,8 +176,8 @@ def _default_path(case):
         return bs == 64
     if kernel == "grouped_matmul":  # the cells' own shapes: further down
         return shape.name == "probe"
-    if kernel == "ssm_state_update":  # the probe's state and the cell's
-        return True
+    if kernel in ("ssm_state_update", "kda_state_update"):
+        return True  # the probe's state and the cell's
     return (kernel in ("ragged_paged_attention", "sample_epilogue")
             and shape.name in ("probe", "probe/untied", "qwen2.5-1.5b")
             and bs in (None, 64))
@@ -1292,3 +1292,50 @@ def test_steady_state_space_tick_reads_the_state_once_in_the_kernel(v5e_sharding
     assert not gone, gone
     assert "input_output_alias" in text
     assert compiled.memory_analysis().temp_size_in_bytes < state.nbytes // 6
+
+
+def test_ling_v3_steady_tick_on_a_v5e_moves_the_matrix_state_in_the_kernel(
+        v5e_sharding):
+    """The benchmark's delta-rule configuration AT ITS PUBLISHED SHAPES (6
+    KDA layers of 32 heads x 128 x 128 float32 a slot beside one latent
+    layer, 64 slots: a matrix state of 768 MiB), the program most ticks run
+    (64 decode rows, ``512 x 64``): the state is an operand of each KDA
+    layer's kernel call and of the tuple that hands it to that layer's chunk
+    loop (no trip in such a tick), and of nothing else; the calls sit under
+    ``kda_scan``; the state and the pool come back in place; and the step
+    keeps nothing the size of a layer's rows beside the state."""
+    import json
+    from pathlib import Path
+
+    from llm_np_cp_tpu.config import ModelConfig
+
+    cfg = ModelConfig.from_hf_dict(json.loads((
+        Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+        / "ling-3.0-flash-7l-ep4.json").read_text()))
+    engine, compiled = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, slots=64, blocks=1026,
+        chunk=128, program=(512, 64))
+    assert engine.epilogue_impl == "fused"  # a head of 2,560 x 157,184
+    pages = engine.pool.pages
+    assert pages.latent and pages.k.shape == (1, 1026, 64, 640)
+    state = pages.state["kda"]
+    assert state.shape == (6, 64, 32, 128, 128) and state.dtype == jnp.float32
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    whole = opmap.hlo_shape("float32", state.shape)
+    takers = _takes(text, whole, "kda_state_update")
+    kernel = [n for n in takers if n.startswith("kda_state_update")]
+    # (every KDA layer is a run of its own: six calls in the one step)
+    assert len(kernel) == 6 and all(
+        takers[n] == "custom-call" for n in kernel), takers
+    assert set(takers.values()) <= {"custom-call", "tuple"}, takers
+    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, {})
+    assert all(ops[n][0] == "kda_scan" for n in kernel)
+    assert {"kda_proj", "kda_scan", "moe_route", "moe_experts", "moe_shared",
+            "attn", "tail"} <= {v[0] for v in ops.values()}
+    # no operation gives back a layer's rows (128 MiB), or one layer of
+    # the state: nothing the size of a slab lies beside the state
+    rows = {opmap.hlo_shape("float32", (n,) + state.shape[1:]) for n in (1,)} | {
+        opmap.hlo_shape("float32", state.shape[1:])}
+    assert not [n for n, v in ops.items() if v[1] in rows]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * state.nbytes // 6

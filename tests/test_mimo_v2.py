@@ -114,7 +114,9 @@ def test_the_benchmark_configuration_is_the_row_cut_to_one_chips_share():
 
 @pytest.mark.parametrize("change, match", [
     ({"add_full_attention_sink_bias": True}, "add_full_attention_sink_bias"),
-    ({"n_group": 2}, "n_group"),
+    # (groups are data since PR 47; groups the experts do not divide into
+    # are still refused)
+    ({"n_group": 3}, "group-limited routing"),
     ({"n_shared_experts": 1}, "n_shared_experts"),
     ({"rope_scaling": {"rope_type": "yarn", "factor": 4}}, "rope_scaling"),
     ({"hybrid_layer_pattern": PATTERN[:-1]}, "hybrid_layer_pattern"),

@@ -525,17 +525,22 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
         for key in ("experts_touched", "expert_load_max",
                     "expert_load_mean", "state_slots_live"):
             out[key] = sum(a.get(key, 0) for a in moe) / len(moe)
-    ssm = [e["args"] for e in ticks if "ssm_state_rows" in e["args"]]
-    if ssm:
-        # state-space mixers: rows whose recurrent state the dispatch read
-        # and wrote, live tokens through the scan
-        for key in ("ssm_state_rows", "ssm_scan_tokens", "state_slots_live"):
-            out[key] = sum(a.get(key, 0) for a in ssm) / len(ssm)
+    for kind in ("ssm", "kda"):
+        # state-space mixers (``ssm_*``) / delta-rule linear-attention
+        # layers (``kda_*``, scopes ``kda_proj`` / ``kda_scan``): rows whose
+        # recurrent state the dispatch read and wrote, live tokens through
+        # the recurrence
+        rec = [e["args"] for e in ticks if f"{kind}_state_rows" in e["args"]]
+        if not rec:
+            continue
+        for key in (f"{kind}_state_rows", f"{kind}_scan_tokens",
+                    "state_slots_live"):
+            out[key] = sum(a.get(key, 0) for a in rec) / len(rec)
         # ... and the share of those ticks in which the Pallas kernel moved
         # those rows alone (else the compiler's passes moved every row;
         # dumps older than the argument count as that)
-        out["ssm_kernel_share"] = sum(
-            a.get("ssm_state_impl") == "pallas" for a in ssm) / len(ssm)
+        out[f"{kind}_kernel_share"] = sum(
+            a.get(f"{kind}_state_impl") == "pallas" for a in rec) / len(rec)
     pub = [e["args"] for e in ticks if e["args"].get("publish_rows")]
     if pub:
         # ``deliver`` hands the PREVIOUS tick's tokens out: behind this
@@ -875,15 +880,19 @@ def format_summary(events: list[dict], top: int = 5,
                f"{acct['expert_load_mean']:.2f} tokens an expert, "
                f"{acct['state_slots_live']:.1f} conv-state slots live"
                if "experts_touched" in acct else "")
-            + (f"; state-space scan {acct['ssm_scan_tokens']:.1f} tokens a "
-               f"tick, {acct['ssm_state_rows']:.1f} rows' recurrent state "
-               f"read and written"
-               + (f" ({acct['ssm_state_rows'] * state_row_bytes / 2**20:.0f}"
-                  " MiB a tick each way)" if state_row_bytes else "")
-               + f", {acct['state_slots_live']:.1f} state slots live, the "
-               f"state-update kernel in {acct['ssm_kernel_share']:.1%} of "
-               "those ticks"
-               if "ssm_state_rows" in acct else "")
+            + "".join(
+                f"; {what} {acct[kind + '_scan_tokens']:.1f} tokens a "
+                f"tick, {acct[kind + '_state_rows']:.1f} rows' {state} "
+                f"read and written"
+                + (f" ({acct[kind + '_state_rows'] * state_row_bytes / 2**20:.0f}"
+                   " MiB a tick each way)" if state_row_bytes else "")
+                + f", {acct['state_slots_live']:.1f} state slots live, the "
+                f"state-update kernel in {acct[kind + '_kernel_share']:.1%} "
+                "of those ticks"
+                for kind, what, state in (
+                    ("ssm", "state-space scan", "recurrent state"),
+                    ("kda", "delta-rule recurrence", "matrix state"))
+                if kind + "_state_rows" in acct)
             + "; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
             f"arrays; context "
