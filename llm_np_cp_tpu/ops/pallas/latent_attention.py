@@ -21,6 +21,21 @@ at 32 heads the dead lanes would otherwise make a decode tick's
 attention compute-bound on a v5e (69.6 kFLOP a (token, position) against
 1,152 bytes).
 
+The query side is sized by a tile's LIVE tokens (PR 48).  ``q`` and the
+result are the step's DENSE token axis where it lies in HBM (one lane a
+token, no alignment: serve/engine.mixed_operand_layout); the tiles are
+metadata alone, and the kernel is told each tile's first dense token.
+It copies a tile's ``tile_qlen`` tokens in itself (a token a copy, the
+next live tile's started a tile ahead, as the pages are a step ahead),
+clears and finalises ``tile_qlen == 1``'s 32 score rows or a chunk
+tile's 256, and sends exactly the live tokens' rows out - a partial last
+tile of a chunk must not write past its segment, whose next dense lanes
+are another row's.  A dead tile and a dead step do nothing.  A dense
+lane no tile owns comes back as zeros the kernel stores (the next layer
+writes such a lane's row into scratch block 0, and ``0 x NaN`` is NaN).
+Spread over 1,024 tile lanes and gathered back by the caller, the same
+281 tokens cost 75 us a call of 636 on a v5e (PERF.md section 6, PR 48).
+
 The pool stores a row padded with zeros to whole rows of 128 lanes
 (serve/block_pool.latent_page_width: 576 -> 640): a ``[.., BS, 576]``
 array is not kept in the order of its shape on a TPU.  ``q`` comes
@@ -65,16 +80,58 @@ def latent_pages_per_step(mb: int, block_s: int, width: int, dtype) -> int:
                       _VMEM_BUDGET_BYTES // slot))
 
 
+def _result_width(rank: int) -> int:
+    """The width the kernel writes a token's result rows in: whole rows
+    of 128 lanes, because a copy cuts nothing narrower out of an array
+    (a tiny preset's ``rank`` 32; the published 512 is its own width)."""
+    return -(-rank // 128) * 128
+
+
+def latent_vmem_scratch(heads: int, width: int, rank: int, pages: int,
+                        block_s: int, dtype, page_dtype) -> list[tuple]:
+    """The kernel's VMEM buffers, ``(shape, dtype)`` in the order it
+    takes them: float32 ``m`` / ``l`` / ``acc`` of a whole tile's score
+    rows, two halves of a group of pages, two halves of a tile's queries
+    and of its results, one token's rows of zeros."""
+    rows = RAGGED_Q_TILE * heads
+    out = _result_width(rank)
+    return [
+        ((rows, 1), jnp.float32), ((rows, 1), jnp.float32),
+        ((rows, rank), jnp.float32),
+        ((2, pages, block_s, width), page_dtype),
+        ((2, RAGGED_Q_TILE, heads, width), dtype),
+        ((2, RAGGED_Q_TILE, heads, out), dtype),
+        ((heads, out), dtype),
+    ]
+
+
+# this kernel's own meta row, after the ragged kernel's nine: the DENSE
+# token a tile's first lane holds
+_RM_TOK = 9
+
+# the kernel's scalars between grid steps (one SMEM vector): which half
+# of the page buffer / the query buffer / the result buffer is current,
+# the result tokens each half still has on their way out, and the zero
+# rows stored for the lanes no tile owns
+# (``_ST_PEND`` is two scalars, one a half)
+_ST_HALF, _ST_QHALF, _ST_OHALF, _ST_PEND, _ST_ZEROS, _ST_SIZE = 0, 1, 2, 3, 5, 6
+
+
 def _latent_kernel(
-    meta_ref, tables_ref, q_ref, pool_ref, o_ref, m_ref, l_ref, acc_ref,
-    buf, sem, half_ref, *,
+    meta_ref, tables_ref, owned_ref, q_ref, pool_ref, o_ref,
+    m_ref, l_ref, acc_ref, buf, q_buf, o_buf, zero_buf,
+    sem, q_sem, o_sem, zero_sem, state, *,
     scale: float, heads: int, rank: int, block_s: int, q_tile: int,
     pages: int, mb: int,
 ):
     """One (query tile, group of pages) step; see the module docstring
     and ``decode_attention._ragged_kernel``, whose fetch discipline this
     is: a live step starts the NEXT live step's copies into the other
-    buffer half before it waits for its own."""
+    buffer half before it waits for its own.  ``q_ref`` / ``o_ref`` are
+    the dense arrays where they lie: a tile's first step waits for its
+    own live tokens' queries (started a tile ahead), its last live step
+    divides, casts and sends those tokens' rows out, and a tile or a
+    step with nothing to attend does nothing at all."""
     ti = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -84,29 +141,67 @@ def _latent_kernel(
     qlen = meta_ref[_RM_QLEN, ti]
     width = pages * block_s
 
+    def copies(n, copy_of, wait: bool):
+        """Start, or wait for, the ``n`` copies ``copy_of(0 .. n - 1)``."""
+        def one(i, carry):
+            copy = copy_of(i)
+            copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+
     def group_copies(tile, step, half, wait: bool):
         live = jnp.minimum(meta_ref[_RM_COUNT, tile] - step * pages, pages)
         first = (meta_ref[_RM_ROW, tile] * mb + meta_ref[_RM_START, tile]
                  + step * pages)
+        copies(live, lambda p: pltpu.make_async_copy(
+            pool_ref.at[tables_ref[first + p]], buf.at[half, p],
+            sem.at[half]), wait)
 
-        def slot(p, carry):
-            copy = pltpu.make_async_copy(
-                pool_ref.at[tables_ref[first + p]], buf.at[half, p],
-                sem.at[half])
-            copy.wait() if wait else copy.start()
-            return carry
+    def query_copies(tile, half, wait: bool):
+        """A tile's live tokens, a copy each: dense ``q`` -> ``q_buf``."""
+        tok = meta_ref[_RM_TOK, tile]
+        copies(meta_ref[_RM_QLEN, tile], lambda i: pltpu.make_async_copy(
+            q_ref.at[tok + i], q_buf.at[half, i], q_sem.at[half]), wait)
 
-        jax.lax.fori_loop(0, live, slot, 0)
+    def result_copies(half, tok, n, wait: bool):
+        """``n`` tokens of ``o_buf[half]`` -> dense lanes ``tok ..``: a
+        partial tile sends its live tokens alone (the next lanes of the
+        dense axis are another row's)."""
+        copies(n, lambda i: pltpu.make_async_copy(
+            o_buf.at[half, i], o_ref.at[tok + i], o_sem.at[half]), wait)
+
+    def zero_copy(lane):
+        return pltpu.make_async_copy(zero_buf, o_ref.at[lane], zero_sem.at[0])
+
+    @pl.when((ti == 0) & (j == 0))
+    def _prologue():
+        for i in range(_ST_SIZE):
+            state[i] = 0
+        # a slot no copy fills must not hold NaN bits under the mask
+        buf[...] = jnp.zeros_like(buf)
+        # every lane of the result is defined: one no tile owns is zeros
+        # (the next layer writes its row into scratch block 0)
+        zero_buf[...] = jnp.zeros_like(zero_buf)
+
+        def lane(d, n):
+            dead = owned_ref[d] == 0
+
+            @pl.when(dead)
+            def _store():
+                zero_copy(d).start()
+
+            return n + dead.astype(jnp.int32)
+
+        state[_ST_ZEROS] = jax.lax.fori_loop(0, o_ref.shape[0], lane, 0)
 
     def fetch_group():
         @pl.when((j == 0) & (meta_ref[_RM_FIRST, ti] == ti))
         def _first_live_step():
-            # a slot no copy fills must not hold NaN bits under the mask
-            buf[...] = jnp.zeros_like(buf)
-            half_ref[0] = 0
             group_copies(ti, j, 0, wait=False)
+            query_copies(ti, 0, wait=False)
 
-        half = half_ref[0]
+        half, qhalf = state[_ST_HALF], state[_ST_QHALF]
         more = (j + 1) * pages < count
         next_tile = jnp.where(more, ti, meta_ref[_RM_NEXT, ti])
 
@@ -115,20 +210,22 @@ def _latent_kernel(
             group_copies(next_tile, jnp.where(more, j + 1, 0), 1 - half,
                          wait=False)
 
+        @pl.when(j == 0)
+        def _queries():
+            @pl.when(meta_ref[_RM_NEXT, ti] < n_tiles)
+            def _next_tile():
+                query_copies(meta_ref[_RM_NEXT, ti], 1 - qhalf, wait=False)
+
+            query_copies(ti, qhalf, wait=True)
+
         group_copies(ti, j, half, wait=True)
-        half_ref[0] = 1 - half
-        return half
+        state[_ST_HALF] = 1 - half
+        return half, qhalf, more
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    def attend(kb, tokens: int):
+    def attend(kb, q, tokens: int):
         """The online-softmax update of the tile's first ``tokens``
-        tokens (``tokens * heads`` score rows, token-major) over the
-        group ``kb [width, W]``."""
+        tokens (``q [tokens * heads, W]``, token-major) over the group
+        ``kb [width, W]``."""
         rows = tokens * heads
         q_idx = jax.lax.broadcasted_iota(jnp.int32, (tokens, width), 0)
         kv_pos = (start + j * pages) * block_s + jax.lax.broadcasted_iota(
@@ -137,7 +234,6 @@ def _latent_kernel(
         mask = (q_idx < qlen) & (kv_pos >= pad) & (kv_pos <= q_slot)
         mask = jnp.broadcast_to(
             mask[:, None, :], (tokens, heads, width)).reshape(rows, width)
-        q = q_ref[:tokens].reshape(rows, q_ref.shape[-1])
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -157,21 +253,50 @@ def _latent_kernel(
 
     @pl.when(j * pages < count)
     def _update():
-        half = fetch_group()
+        half, qhalf, more = fetch_group()
         kb = buf[half].reshape((width,) + buf.shape[3:])
+
+        def tile_step(tokens: int):
+            """The step of a tile that holds ``tokens`` lanes: it clears,
+            attends and finalises ``tokens * heads`` score rows."""
+            rows = tokens * heads
+
+            @pl.when(j == 0)
+            def _init():
+                m_ref[:rows] = jnp.full((rows, 1), NEG_INF, m_ref.dtype)
+                l_ref[:rows] = jnp.zeros((rows, 1), l_ref.dtype)
+                acc_ref[:rows] = jnp.zeros((rows, rank), acc_ref.dtype)
+
+            attend(kb, q_buf[qhalf, :tokens].reshape(rows, q_buf.shape[-1]),
+                   tokens)
+
+            @pl.when(jnp.logical_not(more))
+            def _finalize():
+                ohalf = state[_ST_OHALF]
+                # the tile before last sent its rows from this half
+                result_copies(ohalf, 0, state[_ST_PEND + ohalf], wait=True)
+                l = jnp.where(l_ref[:rows] == 0.0, 1.0, l_ref[:rows])
+                o_buf[ohalf, :tokens, :, :rank] = (acc_ref[:rows] / l).reshape(
+                    tokens, heads, rank).astype(o_buf.dtype)
+                result_copies(ohalf, meta_ref[_RM_TOK, ti], qlen, wait=False)
+                state[_ST_PEND + ohalf] = qlen
+                state[_ST_OHALF] = 1 - ohalf
+                state[_ST_QHALF] = 1 - qhalf
 
         @pl.when(qlen == 1)
         def _decode_row():
-            attend(kb, 1)
+            tile_step(1)
 
         @pl.when(qlen != 1)
         def _chunk():
-            attend(kb, q_tile)
+            tile_step(q_tile)
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[...] = (acc_ref[:] / l).reshape(o_ref.shape).astype(o_ref.dtype)
+    @pl.when((ti == n_tiles - 1) & (j == nj - 1))
+    def _epilogue():
+        for half in range(2):
+            result_copies(half, 0, state[_ST_PEND + half], wait=True)
+
+        copies(state[_ST_ZEROS], lambda i: zero_copy(0), wait=True)
 
 
 @functools.partial(
@@ -183,6 +308,7 @@ def ragged_latent_attention(
     tile_row: jnp.ndarray,
     tile_qpos0: jnp.ndarray,
     tile_qlen: jnp.ndarray,
+    tile_tok: jnp.ndarray,
     pads: jnp.ndarray,
     *,
     scale: float,
@@ -191,27 +317,32 @@ def ragged_latent_attention(
 ) -> jnp.ndarray:
     """Mixed prefill + decode latent attention straight off a paged pool.
 
-    q ``[T, H, W]``: the packed token axis in ``RAGGED_Q_TILE``-aligned
-    row segments, every head's absorbed query, ``W`` the pool's row
-    width (zeros past ``rank + rope``).  pool ``[NB, BS, W]``: one
-    layer's pages, or the whole pool flat over (layer, block) with the
-    layer's offset already in ``tables`` ``[R, MB]``.  ``tile_row`` /
-    ``tile_qpos0`` / ``tile_qlen`` per tile and ``pads`` per row as
-    ``ragged_paged_attention`` takes them.  Returns ``[T, H, rank]``:
-    softmax over the visible rows times their first ``rank`` columns."""
+    q ``[D, H, W]``: the step's DENSE token axis (one lane a token, a
+    row's tokens consecutive, no alignment), every head's absorbed query,
+    ``W`` the pool's row width (zeros past ``rank + rope``).  pool ``[NB,
+    BS, W]``: one layer's pages, or the whole pool flat over (layer,
+    block) with the layer's offset already in ``tables`` ``[R, MB]``.
+    ``tile_row`` / ``tile_qpos0`` / ``tile_qlen`` per query tile and
+    ``pads`` per row as ``ragged_paged_attention`` takes them, and
+    ``tile_tok``, the dense lane of each tile's first token: a tile is
+    the ``tile_qlen <= RAGGED_Q_TILE`` tokens from there on, and the
+    kernel moves those alone.  Returns ``[D, H, rank]``: softmax over
+    the visible rows times their first ``rank`` columns for every token
+    a tile holds, zeros for a lane no tile owns."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    t, h, w = q.shape
+    d, h, w = q.shape
     qt = RAGGED_Q_TILE
-    if t % qt or tile_row.shape != (t // qt,):
+    nt, = tile_row.shape
+    if not (tile_qpos0.shape == tile_qlen.shape == tile_tok.shape == (nt,)):
         raise ValueError(
-            f"packed token axis ({t}) must be whole tiles of {qt} with one "
-            f"metadata entry each, got {tile_row.shape}")
+            f"one metadata entry a query tile ({nt} of them), got qpos0 "
+            f"{tile_qpos0.shape}, qlen {tile_qlen.shape}, tok "
+            f"{tile_tok.shape}")
     if pool.ndim != 3 or pool.shape[-1] != w or rank > w:
         raise ValueError(
             f"a latent pool is [NB, BS, W] with W = q's width >= rank; got "
             f"pool {list(pool.shape)}, q width {w}, rank {rank}")
-    nt = t // qt
     _, block_s, _ = pool.shape
     mb = tables.shape[1]
     pages = latent_pages_per_step(mb, block_s, w, pool.dtype)
@@ -229,38 +360,41 @@ def ragged_latent_attention(
         start, count, row_pad, tile_qpos0, tile_qlen,
         jnp.zeros_like(tile_row), tile_row,
         jnp.append(later[1:], nt), jnp.broadcast_to(later[0], tile_row.shape),
-    ]).astype(jnp.int32)  # [9, NT], the ragged kernel's rows
+        tile_tok,
+    ]).astype(jnp.int32)  # [10, NT]: the ragged kernel's rows + _RM_TOK
+    # the dense lanes some tile attends (and so writes)
+    lane = jnp.arange(d, dtype=jnp.int32)[:, None]
+    last = tile_tok + jnp.where(count > 0, tile_qlen, 0)
+    owned = jnp.any((lane >= tile_tok[None]) & (lane < last[None]), axis=1)
 
-    def tile_map(ti, j, meta_ref, tables_ref):
-        return (ti, 0, 0)
-
-    rows = qt * h
     out = pl.pallas_call(
         functools.partial(
             _latent_kernel, scale=scale, heads=h, rank=rank,
             block_s=block_s, q_tile=qt, pages=pages, mb=mb),
-        out_shape=jax.ShapeDtypeStruct((t, h, rank), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((d, h, _result_width(rank)), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(nt, steps),
             in_specs=[
-                pl.BlockSpec((qt, h, w), tile_map, memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(
-                (qt, h, rank), tile_map, memory_space=pltpu.VMEM),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, rank), jnp.float32),
-                pltpu.VMEM((2, pages, block_s, w), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((1,), jnp.int32),
+                *(pltpu.VMEM(shape, dtype) for shape, dtype in
+                  latent_vmem_scratch(h, w, rank, pages, block_s, q.dtype,
+                                      pool.dtype)),
+                pltpu.SemaphoreType.DMA((2,)),  # pages, one a half
+                pltpu.SemaphoreType.DMA((2,)),  # queries in
+                pltpu.SemaphoreType.DMA((2,)),  # results out
+                pltpu.SemaphoreType.DMA((1,)),  # the zero rows
+                pltpu.SMEM((_ST_SIZE,), jnp.int32),
             ],
         ),
         interpret=interpret,
-    )(meta, tables.reshape(-1).astype(jnp.int32), q, pool)
-    return out
+    )(meta, tables.reshape(-1).astype(jnp.int32), owned.astype(jnp.int32),
+      q, pool)
+    return out[..., :rank]
 
 
 def ragged_latent_attention_xla(

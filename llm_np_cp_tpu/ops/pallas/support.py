@@ -427,36 +427,49 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         # the ragged case's mixed tick (a 2-tile chunk, a decode row past
         # the first group of pages, a chunk across a block boundary, a
         # dead tile) over pages of latent rows, stored as the pool stores
-        # them: zeros past ``rank + rope``
+        # them: zeros past ``rank + rope``.  The token axis is DENSE, as
+        # the tick's: a tile's tokens lie from ``tile_tok`` on, the
+        # chunk's partial tile is followed at once by the decode row's
+        # token, and two lanes at the end belong to no tile
         qt = RAGGED_Q_TILE
         rank = shape.latent_rank
         row = rank + d
         width = latent_page_width(row)
+        tick = ragged_tick(qt)  # tables, tile_row, tile_qpos0, tile_qlen, pads
+        qlen = np.asarray(tick[3])
+        n_live = int(qlen.sum())
+        tile_tok = jnp.asarray(
+            np.where(qlen > 0, np.cumsum(qlen) - qlen, 0), jnp.int32)
+        # the twin's per-token metadata of the same tick
+        tok_row = np.zeros(n_live + 2, np.int32)
+        tok_slot = np.zeros(n_live + 2, np.int32)
+        tok_row[:n_live] = np.repeat(np.asarray(tick[1]), qlen)
+        tok_slot[:n_live] = np.concatenate([
+            p0 + np.arange(n) for p0, n in zip(np.asarray(tick[2]), qlen)])
+        tok_live = jnp.arange(n_live + 2) < n_live
 
         def make_args():
-            q, pool = normals((n_tiles * qt, h, width), (nbp_r, bs, width))
+            q, pool = normals((n_live + 2, h, width), (nbp_r, bs, width))
             live = jnp.arange(width) < row
             return (jnp.where(live, q, 0), jnp.where(live, pool, 0),
-                    *ragged_tick(qt))
+                    *tick[:4], tile_tok, tick[4])
 
         # (a score is a sum over ``row`` products of unit normals: the
         # scale keeps the softmax off one-hot, where bf16 ``p`` is exact)
         scale = float(row) ** -0.5 / 4
 
-        def run(q, pool, tables, tile_row, tile_qpos0, tile_qlen, pads):
-            out = ragged_latent_attention(
-                q, pool, tables, tile_row, tile_qpos0, tile_qlen, pads,
-                scale=scale, rank=rank, interpret=interpret)
-            return jnp.where(
-                live_lanes(tile_qlen, qt)[1][:, None, None], out, 0)
+        def run(q, pool, tables, tile_row, tile_qpos0, tile_qlen, tile_tok,
+                pads):
+            return ragged_latent_attention(
+                q, pool, tables, tile_row, tile_qpos0, tile_qlen, tile_tok,
+                pads, scale=scale, rank=rank, interpret=interpret)
 
-        def reference(q, pool, tables, tile_row, tile_qpos0, tile_qlen, pads):
-            lane, live = live_lanes(tile_qlen, qt)
+        def reference(q, pool, tables, tile_row, tile_qpos0, tile_qlen,
+                      tile_tok, pads):
             out = ragged_latent_attention_xla(
-                q[..., :row], pool, tables, jnp.repeat(tile_row, qt),
-                jnp.repeat(tile_qpos0, qt) + lane, live, pads,
-                scale=scale, rank=rank)
-            return jnp.where(live[:, None, None], out, 0)
+                q[..., :row], pool, tables, jnp.asarray(tok_row),
+                jnp.asarray(tok_slot), tok_live, pads, scale=scale, rank=rank)
+            return jnp.where(tok_live[:, None, None], out, 0)
 
         return make_args, run, reference
 

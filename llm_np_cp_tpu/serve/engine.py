@@ -1586,7 +1586,9 @@ class ServeEngine:
         inside ``attn_fn``: a row gather spreads ``q`` over it, the
         kernel runs, a row gather brings each token's result back.  Run
         at ``T``, the matmuls of a full decode batch were compute-bound
-        on dead lanes (PERF.md §6, PR 30 / 31).
+        on dead lanes (PERF.md §6, PR 30 / 31).  The latent kernel has
+        the tiles as metadata alone: it reads ``q`` and writes its
+        result on the dense axis (PR 48).
 
         The host hands the step ONE operand, an int32 vector whose
         static slices are the packed batch (``mixed_operand_layout``):
@@ -1624,7 +1626,7 @@ class ServeEngine:
         )
 
         hybrid = config.is_hybrid
-        max_slots = geometry[1]
+        q_tile, max_slots = geometry[:2]
         if (config.is_latent or config.two_page_classes) and not carry_pool:
             raise ValueError(
                 "a latent pool, or one with a window class, is read where "
@@ -1825,11 +1827,16 @@ class ServeEngine:
                 def attn_fn(q_lat, pool):  # [1, D, H, rank + rope]
                     layer_tables = tables + base
                     if use_kernel:
+                        # the kernel reads and writes the DENSE axis: it
+                        # is told each tile's first token (what the packer
+                        # wrote for the tile's first lane) and moves a
+                        # tile's live tokens itself
                         out = ragged_latent_attention(
-                            widen(q_lat[0])[lane_tok], pool, layer_tables,
-                            tile_row, tile_qpos0, tile_qlen, pads,
+                            widen(q_lat[0]), pool, layer_tables,
+                            tile_row, tile_qpos0, tile_qlen,
+                            lane_tok[::q_tile], pads,
                             scale=config.attn_scale,
-                            rank=config.kv_lora_rank)[tok_lane]
+                            rank=config.kv_lora_rank)
                     else:
                         out = ragged_latent_attention_xla(
                             q_lat[0].astype(lp.dtype), pool, layer_tables,

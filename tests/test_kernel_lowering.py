@@ -1027,6 +1027,34 @@ def _assert_experts_run_the_kernel(ops, *, layers, weights):
     assert not moved, f"expert weights copied, padded or transposed: {moved}"
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_latent_kernel_at_the_tiny_presets_row_compiles_for_v5e(
+        v5e_sharding, dtype):
+    """``tiny_config("deepseek_v3")``'s row (4 heads over 32 + 8 values,
+    stored 128 wide) is what ``chip_smoke.py --latent`` serves on the
+    chip: the kernel's own copies of a token's rows must cut whole rows
+    of lanes out of ``q`` and out of its result, which is 32 wide there
+    (Mosaic: "Slice shape along dimension 2 must be aligned to tiling
+    (128), but is 32" - the interpreter checks no alignment)."""
+    from llm_np_cp_tpu.ops.pallas.latent_attention import (
+        ragged_latent_attention,
+    )
+
+    def aval(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_sharding)
+
+    def run(q, pool, tables, row, qpos0, qlen, tok, pads):
+        return ragged_latent_attention(
+            q, pool, tables, row, qpos0, qlen, tok, pads, scale=40 ** -0.5,
+            rank=32, interpret=False)
+
+    compiled = jax.jit(run).lower(
+        aval((64, 4, 128), dtype), aval((40, 16, 128), dtype), aval((4, 8)),
+        *[aval((16,))] * 4, aval((4,))).compile()
+    assert "ragged_latent_attention" in compiled.as_text()
+
+
 def test_latent_tick_on_a_v5e_reads_the_pool_where_it_lies(v5e_sharding):
     """The unified step of a latent-attention stack at the published
     attention widths (32 heads over rows of 512 + 64, stored 640 wide; a
@@ -1055,6 +1083,33 @@ def test_latent_tick_on_a_v5e_reads_the_pool_where_it_lies(v5e_sharding):
     # a call a layer, under the kernel's own name, and no other's
     assert len(re.findall(r"%ragged_latent_attention[.\d]* = ", text)) == 3
     assert "%ragged_paged_attention" not in text
+    # the kernel reads the queries and writes its result on the DENSE
+    # token axis: nothing is spread over the tile-aligned one and nothing
+    # gathered back from it (PR 48), so the query and the result exist in
+    # their dense forms alone
+    t_w, d_w = engine.mixed_buckets[-1]
+    assert t_w > d_w
+    for width in (640, 512):
+        assert f"[{t_w},32,{width}]" not in text
+        assert f"bf16[{d_w},32,{width}]" in text
+    # ... and what it holds in VMEM at the published row (a tile's score
+    # rows in float32, both halves of pages, queries and results) is
+    # inside the budget the page buffer alone is sized by
+    from llm_np_cp_tpu.ops.pallas.decode_attention import (
+        _VMEM_BUDGET_BYTES,
+        _vmem_bytes,
+    )
+    from llm_np_cp_tpu.ops.pallas.latent_attention import (
+        latent_pages_per_step,
+        latent_vmem_scratch,
+    )
+
+    held = sum(_vmem_bytes(shape, dtype) for shape, dtype in
+               latent_vmem_scratch(
+                   32, 640, 512,
+                   latent_pages_per_step(8, BLOCK, 640, jnp.bfloat16), BLOCK,
+                   jnp.bfloat16, jnp.bfloat16))
+    assert 3 * 2**20 < held < _VMEM_BUDGET_BYTES
     assert {"qkv", "kv_write", "attn", "o_proj", "mlp", "moe_route",
             "moe_experts", "moe_shared"} <= {v[0] for v in ops.values()}
     # two expert layers x two calls (gate and up in one, down) over the

@@ -99,14 +99,14 @@ def test_absorbed_attention_over_the_cached_rows_is_expanded_attention(impl):
                 rank=cfg.kv_lora_rank)
             return out[None]
         n_tiles = -(-s // QT)
-        q = jnp.zeros((n_tiles * QT,) + q_lat.shape[2:3] + (width,), q_lat.dtype)
-        q = q.at[:s, :, :q_lat.shape[-1]].set(q_lat[0])
+        q = jnp.zeros((s,) + q_lat.shape[2:3] + (width,), q_lat.dtype)
+        q = q.at[:, :, :q_lat.shape[-1]].set(q_lat[0])
+        first = jnp.arange(n_tiles, dtype=jnp.int32) * QT
         out = ragged_latent_attention(
-            q, pool, tables, jnp.zeros((n_tiles,), jnp.int32),
-            jnp.arange(n_tiles, dtype=jnp.int32) * QT,
-            jnp.minimum(s - jnp.arange(n_tiles) * QT, QT).astype(jnp.int32),
-            pads, scale=cfg.attn_scale, rank=cfg.kv_lora_rank, interpret=True)
-        return out[None, :s]
+            q, pool, tables, jnp.zeros((n_tiles,), jnp.int32), first,
+            jnp.minimum(s - first, QT), first, pads, scale=cfg.attn_scale,
+            rank=cfg.kv_lora_rank, interpret=True)
+        return out[None]
 
     with jax.default_matmul_precision("highest"):
         expanded, rows = latent_attention_block(
@@ -156,9 +156,12 @@ def test_kernel_matches_its_xla_twin_on_a_mixed_tick(h, rank, rope, width,
     q, pool, tables, tile_row, tile_qpos0, tile_qlen, pads = _mixed_tick(
         h, rank, rope, width, block_s, dtype)
     scale = (rank + rope) ** -0.5
+    # (the token axis laid out in whole tiles: a tile's first token is
+    # its first lane, and the lanes past ``tile_qlen`` belong to no tile)
     got = ragged_latent_attention(
-        q, pool, tables, tile_row, tile_qpos0, tile_qlen, pads, scale=scale,
-        rank=rank, interpret=True)
+        q, pool, tables, tile_row, tile_qpos0, tile_qlen,
+        jnp.arange(6, dtype=jnp.int32) * QT, pads, scale=scale, rank=rank,
+        interpret=True)
     assert got.shape == (6 * QT, h, rank) and got.dtype == dtype
     lane = jnp.arange(q.shape[0]) % QT
     live = lane < jnp.repeat(tile_qlen, QT)
@@ -191,15 +194,152 @@ def test_pages_a_step_cover_512_positions_within_the_table():
     assert latent_pages_per_step(36, 64, 640, jnp.bfloat16) == 8
     assert latent_pages_per_step(4, 64, 640, jnp.bfloat16) == 4
     assert latent_pages_per_step(8, 8, 128, jnp.float32) == 8
-    with pytest.raises(ValueError, match="whole tiles"):
+    one = jnp.zeros((1,), jnp.int32)
+    with pytest.raises(ValueError, match="one metadata entry a query tile"):
         ragged_latent_attention(
             jnp.zeros((12, 4, 128)), jnp.zeros((4, 8, 128)),
-            jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
-            jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
-            jnp.zeros((1,), jnp.int32), scale=1.0, rank=32, interpret=True)
+            jnp.zeros((1, 2), jnp.int32), one, one, one + 1,
+            jnp.zeros((2,), jnp.int32), one, scale=1.0, rank=32,
+            interpret=True)
     with pytest.raises(ValueError, match="latent pool is"):
         ragged_latent_attention(
             jnp.zeros((8, 4, 64)), jnp.zeros((4, 8, 128)),
-            jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
-            jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
-            jnp.zeros((1,), jnp.int32), scale=1.0, rank=32, interpret=True)
+            jnp.zeros((1, 2), jnp.int32), one, one, one + 1, one, one,
+            scale=1.0, rank=32, interpret=True)
+
+
+# ----------------------------------------------------------------------
+# the dense token axis: a tile moves, clears and finalises its live
+# tokens alone (PR 48)
+# ----------------------------------------------------------------------
+
+_H, _RANK, _ROPE, _WIDTH, _MB, _NBP = 4, 32, 8, 128, 12, 40
+
+
+def _pack(segments, d_w, dead_after=()):
+    """A packed batch as the tick's packer lays it out.  ``segments``:
+    ``(row, first cache slot, tokens, dense lane of the first)`` in TILE
+    order (which need not be the dense order); a dead tile follows every
+    segment whose index is in ``dead_after``.  Returns the tile metadata
+    ``(tile_row, tile_qpos0, tile_qlen, tile_tok)`` and the twin's
+    per-token ``(tok_row, tok_slot, tok_live)`` over ``d_w`` dense
+    lanes (a lane no segment covers is dead)."""
+    tiles = []
+    tok_row, tok_slot = np.zeros(d_w, np.int32), np.zeros(d_w, np.int32)
+    tok_live = np.zeros(d_w, bool)
+    for i, (row, slot, n, lane) in enumerate(segments):
+        assert not tok_live[lane:lane + n].any()
+        tok_row[lane:lane + n] = row
+        tok_slot[lane:lane + n] = slot + np.arange(n)
+        tok_live[lane:lane + n] = True
+        tiles += [(row, slot + q0, min(QT, n - q0), lane + q0)
+                  for q0 in range(0, n, QT)]
+        if i in dead_after:
+            tiles.append((0, 0, 0, 0))
+    meta = tuple(jnp.asarray(c, jnp.int32) for c in zip(*tiles))
+    return meta, (jnp.asarray(tok_row), jnp.asarray(tok_slot),
+                  jnp.asarray(tok_live))
+
+
+def _operands(d_w, block_s, dtype, poison=None):
+    """``q [d_w, H, W]``, a pool and three rows' tables and pads (row 1
+    left-padded by more than a block).  ``poison``: the dense lanes whose
+    queries, with scratch block 0, are set to NaN."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    live = jnp.arange(_WIDTH) < _RANK + _ROPE
+    q = jnp.where(live, jax.random.normal(k1, (d_w, _H, _WIDTH)), 0)
+    pool = jnp.where(live, jax.random.normal(k2, (_NBP, block_s, _WIDTH)), 0)
+    if poison is not None:
+        q = jnp.where(poison[:, None, None], jnp.nan, q)
+        pool = pool.at[0].set(jnp.nan)
+    tables = jnp.asarray(
+        (np.arange(3 * _MB) * 7 % 37 + 1).reshape(3, _MB), jnp.int32)
+    pads = jnp.asarray([5, block_s + 2, 0], jnp.int32)
+    return q.astype(dtype), pool.astype(dtype), tables, pads
+
+
+def _attend(q, pool, tables, pads, meta):
+    return ragged_latent_attention(
+        q, pool, tables, *meta, pads, scale=(_RANK + _ROPE) ** -0.5,
+        rank=_RANK, interpret=True).astype(jnp.float32)
+
+
+def _twin(q, pool, tables, pads, toks):
+    tok_row, tok_slot, tok_live = toks
+    out = ragged_latent_attention_xla(
+        q[..., :_RANK + _ROPE], pool, tables, tok_row, tok_slot, tok_live,
+        pads, scale=(_RANK + _ROPE) ** -0.5, rank=_RANK)
+    return jnp.where(tok_live[:, None, None], out.astype(jnp.float32), 0)
+
+
+def _tick(block_s):
+    """Two decode rows' one-token tiles around a dead tile, a 19-token
+    chunk of the left-padded row (two full tiles and a PARTIAL one of
+    ``tile_qlen`` 3) whose last token lies right before row 2's, dead
+    tiles between live ones, one dead dense lane in the middle and three
+    at the end."""
+    first = block_s + 2  # row 1's first live slot
+    segments = [
+        (0, 9 * block_s + 7, 1, 0),      # a decode row deep in block 10
+        (1, first + 11, 19, 2),          # lanes 2..20: tiles of 8, 8, 3
+        (2, 3 * block_s + 1, 1, 21),     # the partial tile's neighbour
+    ]
+    return _pack(segments, 25, dead_after=(0, 1))
+
+
+@pytest.mark.parametrize("case", [
+    "f32", "bf16", "poisoned-dead-lanes", "one-token-tile-is-a-chunk-lane",
+    "partial-tile-before-its-neighbour", "partial-tile-after-its-neighbour",
+])
+def test_kernel_moves_the_live_tokens_of_the_dense_axis_alone(case):
+    dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
+    block_s = 16 if case == "bf16" else 8
+    tol = support.KERNEL_TOLERANCE if case == "bf16" else 2e-5
+    meta, toks = _tick(block_s)
+    assert list(np.asarray(meta[2])) == [1, 0, QT, QT, 3, 0, 1]
+    live = np.asarray(toks[2])
+    assert live.sum() == 21 and not live[1] and not live[22:].any()
+    q, pool, tables, pads = _operands(25, block_s, dtype)
+    want = _twin(q, pool, tables, pads, toks)
+    assert float(jnp.abs(want).max()) > 0.1
+
+    if case in ("f32", "bf16"):
+        got = _attend(q, pool, tables, pads, meta)
+        assert float(jnp.abs(got - want).max()) < tol
+        # a lane no tile owns is zeros, not what the buffer held
+        assert not np.asarray(got)[~live].any()
+    elif case == "poisoned-dead-lanes":
+        # the dead lanes' queries and scratch block 0 hold NaN: no live
+        # token's result may touch either, and the dead lanes of the
+        # RESULT are finite (the next layer writes their rows to block 0)
+        qn, pooln, _, _ = _operands(25, block_s, dtype, poison=~toks[2])
+        got = _attend(qn, pooln, tables, pads, meta)
+        assert bool(jnp.isfinite(got).all())
+        assert not np.asarray(got)[~live].any()
+        assert float(jnp.abs(got - want).max()) < tol
+    elif case == "one-token-tile-is-a-chunk-lane":
+        # the chunk's tokens 8..15 (a full tile) each attended as a tile
+        # of ONE token: the same rows of the same sheet, to the bit
+        chunk = _attend(q, pool, tables, pads, meta)
+        singles, _ = _pack(
+            [(1, block_s + 2 + 11 + 8 + i, 1, 10 + i) for i in range(QT)], 25)
+        alone = _attend(q, pool, tables, pads, singles)
+        assert np.array_equal(np.asarray(alone)[10:18], np.asarray(chunk)[10:18])
+        assert not np.asarray(alone)[:10].any()
+    else:
+        # the partial tile's 3 tokens end at lane 20; lane 21 is another
+        # row's.  Whichever of the two tiles the grid reaches first, the
+        # neighbour's lane holds the neighbour's own result
+        segments = [(1, block_s + 2 + 11, 19, 2), (2, 3 * block_s + 1, 1, 21)]
+        if case == "partial-tile-after-its-neighbour":
+            segments.reverse()
+        meta2, toks2 = _pack(segments, 25)
+        got = _attend(q, pool, tables, pads, meta2)
+        want2 = _twin(q, pool, tables, pads, toks2)
+        assert float(jnp.abs(want2[21]).max()) > 0.05
+        assert float(jnp.abs(got - want2).max()) < tol
+        # ... to the bit what it is with no chunk in the tick at all
+        alone, _ = _pack([s for s in segments if s[0] == 2], 25)
+        assert np.array_equal(
+            np.asarray(got)[21],
+            np.asarray(_attend(q, pool, tables, pads, alone))[21])

@@ -643,6 +643,11 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
     assert acct["dense_occupancy"] == pytest.approx(
         sum(h["tokens"] for h in hand) / sum(h["dense"] for h in hand))
     assert 0.0 < acct["dense_occupancy"] <= 1.0
+    # ... and of the tile-aligned axis inside attention (a decode row is
+    # one token of a tile's eight lanes): at most the dense axis' share
+    assert acct["tile_lane_occupancy"] == pytest.approx(
+        sum(h["tokens"] for h in hand) / sum(h["width"] for h in hand))
+    assert 0.0 < acct["tile_lane_occupancy"] <= acct["dense_occupancy"]
     assert acct["pack_array_rows"] == pytest.approx(
         sum(h["array_rows"] for h in hand) / len(hand))
     assert acct["attn_pages"] == pytest.approx(
@@ -659,7 +664,9 @@ def test_summarize_tick_account_and_device_scopes(traced, tmp_path):
             f"{acct['attn_grid_steps']:.0f} kv grid steps") in out
     assert (f"{acct['attn_decode_tiles']:.1f} of "
             f"{acct['attn_live_tiles']:.1f} live query tiles a dispatch "
-            f"({acct['attn_decode_tile_share']:.0%}) hold one token") in out
+            f"({acct['attn_decode_tile_share']:.0%}) hold one token; "
+            f"{acct['tile_lane_occupancy']:.0%} of the tiled axis' lanes "
+            "held a token") in out
     assert "== tick account" in out and "pack " in out
     assert (f"h2d 1 transfers, {acct['h2d_bytes']:.0f} bytes; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "

@@ -444,8 +444,9 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
     computing nor waiting for the device), the live context, the
     packed widths (tile lanes inside attention) the dispatches used
     and — where the ticks say it — their programs, ``packed x dense``
-    width, with the share of dense lanes that held a token.  None
-    for a trace without the cut phases (older dumps)."""
+    width, with the share of dense lanes that held a token and the
+    share of the tile-aligned axis' lanes that did.  None for a trace
+    without the cut phases (older dumps)."""
     ticks = [e for e in events if e.get("ph") == "X"
              and e.get("cat") == "tick" and "packed_width" in
              (e.get("args") or {}) and e["args"]["packed_width"]]
@@ -488,10 +489,14 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
             programs[a["packed_width"], a["dense_width"]] += 1
         out["programs"] = {
             f"{t}x{d}": n for (t, d), n in sorted(programs.items())}
-        out["dense_occupancy"] = sum(
+        tokens = sum(
             a.get("prefill_tokens", 0) + a.get("decode_tokens", 0)
-            + a.get("spec_draft_tokens", 0) for a in dense
-        ) / sum(a["dense_width"] for a in dense)
+            + a.get("spec_draft_tokens", 0) for a in dense)
+        out["dense_occupancy"] = tokens / sum(a["dense_width"] for a in dense)
+        # ... and of the tile-aligned axis inside attention: a decode row
+        # is one token in a tile of eight lanes
+        out["tile_lane_occupancy"] = tokens / sum(
+            a["packed_width"] for a in dense)
     paged = [e["args"] for e in ticks if e["args"].get("attn_grid_steps")]
     if paged:
         # one layer's attention call: the pages its tiles stream, the kv
@@ -905,6 +910,8 @@ def format_summary(events: list[dict], top: int = 5,
                f"{acct['attn_live_tiles']:.1f} live query tiles a dispatch "
                f"({acct['attn_decode_tile_share']:.0%}) hold one token"
                if "attn_live_tiles" in acct else "")
+            + (f"; {acct['tile_lane_occupancy']:.0%} of the tiled axis' "
+               "lanes held a token" if "tile_lane_occupancy" in acct else "")
             + (f"; two page classes: a global layer streams "
                f"{acct['attn_pages_global']:.0f} pages, a window layer "
                f"{acct['attn_pages_window']:.0f}; "
