@@ -129,7 +129,7 @@ def moe_mlp(
 # ``jax.named_scope`` names (models/transformer.STEP_SCOPES lists them, so
 # serve/opmap.py cuts a device profile by them)
 SCOPE_MOE_ROUTE = "moe_route"      # gate, sigmoid, bias, top-k, normalise, sort
-SCOPE_MOE_EXPERTS = "moe_experts"  # row gather, grouped matmuls, weighted combine
+SCOPE_MOE_EXPERTS = "moe_experts"  # the grouped matmuls: rows in, weighted sums out
 
 
 def route_sigmoid_topk(
@@ -206,6 +206,23 @@ def expert_row_tile(w: Any, rows: int, experts: int,
     return gmm.row_tile(rows, experts)
 
 
+def expert_rows_in_call(w: Any, tokens: int, top_k: int,
+                        tm: int | None) -> bool:
+    """Whether the grouped matmul's two calls move the rows themselves at
+    row tile ``tm`` (``expert_row_tile``'s) — gather a tile's tokens, add
+    its weighted result to theirs — or XLA gathers every laid row and
+    un-sorts and sums all ``tokens * top_k`` pairs around them: the
+    former wherever the kernel runs and the tokens' rows fit its VMEM
+    (``grouped_matmul.token_rows_fit``: every program of a served tick;
+    not a plain forward over thousands of tokens)."""
+    if tm is None:
+        return False
+    held, h, inter = w.shape
+    return gmm.token_rows_fit(
+        tokens, gmm.tile_count(tokens * top_k, held, tm) * tm, h, inter,
+        jnp.dtype(w.dtype).itemsize)
+
+
 # (jitted: a stack's expert layers are alike, so the layer is traced once a
 # program and not once a layer — a warm start re-traces all nine programs)
 @functools.partial(jax.jit, static_argnames=(
@@ -240,8 +257,13 @@ def moe_dropless(
     weights.  On a TPU that is ``ops/pallas/grouped_matmul``: the groups
     laid out in row tiles of one expert each, one call for ``act(gate) *
     up`` and one for down, each streaming a touched expert's matrices
-    once; anywhere else, and for weights the kernel does not take
-    (``expert_row_tile``), three ``lax.ragged_dot`` over the sorted rows.
+    once — and, where the tokens' rows fit them (``expert_rows_in_call``:
+    every program of a served tick), moving the rows themselves: a tile
+    gathers its tokens out of ``x`` and adds its weighted result into
+    ``[T, H]``, so nothing in the layer is as long as the pairs routed,
+    only as the pairs held; anywhere else, and for weights the kernel
+    does not take (``expert_row_tile``), three ``lax.ragged_dot`` over
+    the sorted rows, between XLA's gather, un-sort and masked sum.
     ``interpret``: as the Pallas kernels take it — None lets the backend
     decide (the kernel compiled on a TPU, ``ragged_dot`` elsewhere), True
     runs the kernel in the interpreter (tests), False compiles it.  The
@@ -257,6 +279,7 @@ def moe_dropless(
     t, h = x.shape
     held = w1.shape[0]
     tm = expert_row_tile(w1, t * top_k, router_w.shape[-1], interpret)
+    in_call = expert_rows_in_call(w1, t, top_k, tm)
     with jax.named_scope(SCOPE_MOE_ROUTE):
         idx, wts = route_sigmoid_topk(
             x, router_w, expert_bias, top_k=top_k,
@@ -270,18 +293,35 @@ def moe_dropless(
         # pairs of no expert held sort last, past every group
         pair_expert = jnp.where(here, local, held).reshape(t * top_k)
         order = jnp.argsort(pair_expert, stable=True)
-        inverse = jnp.argsort(order)
         load = jnp.sum(
             pair_expert[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :],
             axis=0, dtype=jnp.int32,
         )
-        token = order // top_k  # of a sorted row
+        if not in_call:
+            inverse = jnp.argsort(order)  # the sorted row of a pair
         if tm is not None:
-            # each group in whole row tiles: the kernel's rows, and where
-            # a pair's row went
+            # each group in whole row tiles: the kernel's rows, and the
+            # pair each holds (two gathers a layer: one of a few thousand
+            # scalars is 30-40 us on a v5e, PERF.md section 6, PR 49)
             layout = gmm.align_groups(load, t * top_k, tm)
-            token, inverse = token[layout.src], layout.dest[inverse]
+            order = order[layout.src]
+            if in_call:
+                # a laid row's weight: its pair's (a row that pads names
+                # sorted row 0's, which the calls never read)
+                weight = wts.reshape(t * top_k)[order]
+            else:
+                inverse = layout.dest[inverse]  # where a pair's row went
+        token = order // top_k  # of a sorted row; of a laid one
     with jax.named_scope(SCOPE_MOE_EXPERTS):
+        if in_call:
+            # the rows enter and leave inside the calls, those of the
+            # pairs held alone: a tile cuts its tokens out of ``x`` and
+            # adds its weighted result to their rows of ``out``, which
+            # starts from zeros — nothing here is as long as ``T * k``
+            out = gmm.grouped_experts(
+                x.astype(jnp.float32), w1, w3, w2, layout, token, weight,
+                act=act, tm=tm, interpret=bool(interpret))
+            return out.astype(out_dtype or w1.dtype), idx, load
         x = x.astype(w1.dtype)
         xs = x[token]  # [rows, H], grouped by expert
         if tm is None:

@@ -270,35 +270,49 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         # the routed experts' two calls (ops/moe.moe_dropless) over 70
         # sorted rows of six experts: an empty first one, groups of one
         # row, a tile + 1 and two tiles, an empty one between, and eleven
-        # rows of no group; the twin is three lax.ragged_dot
-        rows, inter, tm = 70, 384, 16
+        # rows of no group; the twin is three lax.ragged_dot.  Both forms
+        # of the pair: laid rows in and out (the first 70 rows of the
+        # result, XLA's gather around them), and the rows moved in the
+        # calls — a sorted row's token cut out of the 40 tokens' float32
+        # rows, the results added by token under a weight (the other 40;
+        # the last token is nobody's)
+        rows, tokens, inter, tm = 70, 40, 384, 16
         hid = shape.hidden
         sizes = (0, 17, 1, 32, 0, 9)
         grouped = jnp.arange(rows)[:, None] < sum(sizes)
+        token = jnp.arange(rows, dtype=jnp.int32) % (tokens - 1)
+        weight = 0.25 + (jnp.arange(rows) % 5).astype(jnp.float32) / 8
 
         def make_args():
             x, w1, w3, w2 = normals(
-                (rows, hid), (len(sizes), hid, inter),
+                (tokens, hid), (len(sizes), hid, inter),
                 (len(sizes), hid, inter), (len(sizes), inter, hid))
             thin = jnp.asarray(hid ** -0.5, bf16)
-            return (x, w1 * thin, w3 * thin,
+            return (x.astype(jnp.float32), w1 * thin, w3 * thin,
                     w2 * jnp.asarray(inter ** -0.5, bf16),
                     jnp.asarray(sizes, jnp.int32))
 
         def run(x, w1, w3, w2, sizes):
             layout = gmm.align_groups(sizes, rows, tm)
+            kw = dict(act=jax.nn.silu, tm=tm, interpret=interpret)
             ys = gmm.grouped_experts(
-                x[layout.src], w1, w3, w2, layout, act=jax.nn.silu, tm=tm,
-                interpret=interpret)
-            return jnp.where(grouped, ys[layout.dest], 0)
+                x.astype(bf16)[token[layout.src]], w1, w3, w2, layout, **kw)
+            by_token = gmm.grouped_experts(
+                x, w1, w3, w2, layout, token[layout.src], weight[layout.src],
+                **kw)
+            return jnp.concatenate(
+                [jnp.where(grouped, ys[layout.dest], 0), by_token])
 
         def reference(x, w1, w3, w2, sizes):
+            xs = x.astype(bf16)[token]
             gate, up = (
-                lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
+                lax.ragged_dot(xs, w, sizes, preferred_element_type=jnp.float32)
                 for w in (w1, w3))
             hidden = jax.nn.silu(gate.astype(bf16)) * up.astype(bf16)
-            return jnp.where(grouped, lax.ragged_dot(
+            ys = jnp.where(grouped, lax.ragged_dot(
                 hidden, w2, sizes, preferred_element_type=jnp.float32), 0)
+            return jnp.concatenate([ys, jnp.zeros(
+                (tokens, hid), jnp.float32).at[token].add(weight[:, None] * ys)])
 
         # (jitted: the probe runs what a case gives it as it is, and op
         # by op the layout alone is fifty compiles of a third of a second

@@ -115,7 +115,11 @@ from llm_np_cp_tpu.models.transformer import (
 from llm_np_cp_tpu.ops import kda as kda_ops
 from llm_np_cp_tpu.ops import ssm as ssm_ops
 from llm_np_cp_tpu.ops.activations import ACT2FN
-from llm_np_cp_tpu.ops.moe import SCOPE_MOE_EXPERTS, expert_row_tile
+from llm_np_cp_tpu.ops.moe import (
+    SCOPE_MOE_EXPERTS,
+    expert_row_tile,
+    expert_rows_in_call,
+)
 from llm_np_cp_tpu.ops.rope import rope_cos_sin
 from llm_np_cp_tpu.ops.sampling import Sampler
 from llm_np_cp_tpu.quant import quantize_kv
@@ -863,6 +867,7 @@ class ServeEngine:
         # counts come back with the tick's one fetch
         self._n_expert_layers = len(config.expert_layers)
         self._expert_row_tiles: dict[int, int | None] = {}
+        self._expert_rows_impls: dict[int, str] = {}
         if self._n_expert_layers:
             # the grouped matmul's verdict, asked here and not first while
             # the step is being traced (ops/moe.expert_row_tile asks there)
@@ -3502,9 +3507,21 @@ class ServeEngine:
                 # (token, expert) pairs whose expert is held, all layers:
                 # every pair where all experts are, a share's share
                 "pairs_held": int(expert_load.sum()),
+                # ... of the pairs the routers made: the live tokens' k
+                # choices in every expert layer.  held / routed is the
+                # share of them whose rows this chip moves
+                "pairs_routed": (
+                    (n_prefill_tok + n_decode_tok)
+                    * self.config.num_experts_per_tok * self._n_expert_layers),
                 "state_slots_live": len(self.scheduler.running),
             }
             tm = self._expert_row_tile(dense_width)
+            # who moves the rows: "kernel" — the grouped matmul's calls
+            # gather a tile's tokens and add its weighted result to theirs,
+            # the pairs held alone; "xla" — a gather of every laid row, an
+            # un-sort and a sum over all the pairs routed (lax.ragged_dot's
+            # path, rows that do not fit the calls)
+            moe["expert_rows_impl"] = self._expert_rows_impl(dense_width)
             if tm is not None:
                 # what the grouped matmul multiplied: each (layer, expert)
                 # group in whole tiles of ``tm`` rows, so that tiles x tm
@@ -3872,13 +3889,29 @@ class ServeEngine:
         choice, asked the way it asks — or None where they run
         ``lax.ragged_dot``.  Asked once a width: a tick asks again."""
         if dense_width not in self._expert_row_tiles:
-            w1 = next(
-                run["w1"] for run in self.params["layers"] if "w1" in run)
             self._expert_row_tiles[dense_width] = expert_row_tile(
-                jax.ShapeDtypeStruct(w1.shape[-3:], w1.dtype),
+                self._expert_weights(),
                 dense_width * self.config.num_experts_per_tok,
                 self.config.num_experts)
         return self._expert_row_tiles[dense_width]
+
+    def _expert_rows_impl(self, dense_width: int) -> str:
+        """Who moves the expert layers' rows in the program ``dense_width``
+        tokens wide, ``moe_dropless``'s own choice asked the way it asks:
+        "kernel" | "xla".  Asked once a width."""
+        if dense_width not in self._expert_rows_impls:
+            self._expert_rows_impls[dense_width] = (
+                "kernel" if expert_rows_in_call(
+                    self._expert_weights(), dense_width,
+                    self.config.num_experts_per_tok,
+                    self._expert_row_tile(dense_width)) else "xla")
+        return self._expert_rows_impls[dense_width]
+
+    def _expert_weights(self) -> jax.ShapeDtypeStruct:
+        """One expert layer's gate weights ``[E_held, H, I]``, as the
+        choices of ops/moe.py read them: shape and dtype."""
+        w1 = next(run["w1"] for run in self.params["layers"] if "w1" in run)
+        return jax.ShapeDtypeStruct(w1.shape[-3:], w1.dtype)
 
     def warmup(
         self, prompt_lens: list[int], max_new_tokens: int = 2,

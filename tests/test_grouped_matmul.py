@@ -79,7 +79,7 @@ def test_tile_count_holds_every_split_of_the_rows(rows, held, tm, want):
 ], ids=[*CASES, "no-row-at-all", "support-case"])
 def test_groups_are_laid_out_in_whole_tiles_of_one_expert(sizes, rows, tm):
     layout = _align(jnp.asarray(sizes, jnp.int32), rows, tm)
-    tile_expert, live, src, dest = (np.asarray(a) for a in layout)
+    tile_expert, live, tile_rows, src, dest = (np.asarray(a) for a in layout)
     tiles = gmm.tile_count(rows, len(sizes), tm)
     assert tile_expert.shape == (tiles,) and src.shape == (tiles * tm,)
     per = [-(-s // tm) for s in sizes]
@@ -97,6 +97,14 @@ def test_groups_are_laid_out_in_whole_tiles_of_one_expert(sizes, rows, tm):
     assert (src[dest[:grouped]] == np.arange(grouped)).all()
     assert (tile_expert[dest[:grouped] // tm] == expert_of_row).all()
     assert (dest[grouped:] == 0).all() and (dest < live[0] * tm).all() | (grouped == 0)
+    # a tile's real rows are its first ``tile_rows``: the group's whole
+    # tiles and then what is left of it, none past the live tiles; a row
+    # that pads reads sorted row 0
+    want = [min(tm, n - i * tm) for n in sizes for i in range(-(-n // tm))]
+    assert tile_rows.tolist() == want + [0] * (tiles - len(want))
+    real = np.arange(tm)[None, :] < tile_rows[:, None]
+    assert sorted(src.reshape(tiles, tm)[real]) == list(range(grouped))
+    assert (src.reshape(tiles, tm)[~real] == 0).all()
 
 
 # ----------------------------------------------------------------------
@@ -130,6 +138,74 @@ def test_tiles_past_the_live_ones_are_no_steps_of_the_grid():
     out = gmm.grouped_matmul(laid, (w,), layout.tile_expert, layout.live,
                              tm=tm, out_dtype=F32, interpret=True)
     assert np.isfinite(np.asarray(out[:3 * tm])).all()
+
+
+def _laid(sizes, rows, tm, tokens, seed=0):
+    """A layout with a token and a weight for every laid row, as
+    ``moe_dropless`` makes them: the sorted rows' tokens drawn at random
+    (the last three are nobody's), weight zero where a row pads."""
+    layout = _align(jnp.asarray(sizes, jnp.int32), rows, tm)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    pads = (jnp.arange(tm)[None, :] >= layout.rows[:, None]).reshape(-1)
+    token = jnp.where(
+        pads, 0, jax.random.randint(keys[0], pads.shape, 0, tokens - 3))
+    weight = jnp.where(pads, 0.0, jax.random.uniform(keys[1], pads.shape, F32))
+    return layout, pads, token, weight
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", CASES)
+def test_a_tile_gathers_its_tokens_rows_itself(case, dtype):
+    """``gather``: the tokens' float32 rows held whole, a tile's real rows
+    cut out by ``token`` and rounded to the weights' dtype — the result
+    of XLA's ``x.astype(dtype)[token]`` through the same call, to the
+    bit, on every real row."""
+    sizes, rows, tm = CASES[case]
+    tokens = 24
+    layout, pads, token, _ = _laid(sizes, rows, tm, tokens)
+    x, (w,), _ = _operands(sizes, tokens, 128, 256, F32)
+    w = w.astype(dtype)
+    tiles = dict(tile_expert=layout.tile_expert, live=layout.live, tm=tm,
+                 out_dtype=F32, interpret=True)
+    got = gmm.grouped_matmul(x, (w,), gather=True, tile_rows=layout.rows,
+                             token=token, **tiles)
+    want = gmm.grouped_matmul(x.astype(dtype)[token], (w,), **tiles)
+    real = ~np.asarray(pads)
+    assert got.shape == want.shape and real.sum() == sum(sizes)
+    assert np.array_equal(np.asarray(got)[real], np.asarray(want)[real])
+
+
+@pytest.mark.parametrize("tokens,blocks", [(24, 1), (21, 1), (16, 2)], ids=[
+    "whole-sublanes", "and-5", "two-column-blocks"])
+@pytest.mark.parametrize("case", CASES)
+def test_a_tile_adds_its_weighted_rows_to_their_tokens(
+        case, tokens, blocks, monkeypatch):
+    """``weight``: the result by token, in float32 — the laid rows of
+    the plain call weighted and summed by token over the REAL rows.  A row
+    that pads, and every row past the live tiles, is NaN here: none is
+    added, and a token no real row names reads exact zeros."""
+    sizes, rows, tm = CASES[case]
+    if blocks == 2:  # room for 128 of the 256 columns: the block is cut
+        monkeypatch.setattr(
+            gmm, "_BLOCK_VMEM_BYTES", 2 * 128 * 128 * 2 + 2 * tokens * 128 * 4)
+    assert gmm.column_block(128, 256, 1, 2, kept_rows=tokens) == 256 // blocks
+    layout, pads, token, weight = _laid(sizes, rows, tm, tokens, seed=1)
+    x, (w,), _ = _operands(sizes, rows, 128, 256, jnp.bfloat16)
+    laid = jnp.where(pads[:, None], jnp.nan, x[layout.src])
+    tiles = dict(tile_expert=layout.tile_expert, live=layout.live, tm=tm,
+                 interpret=True)
+    got = gmm.grouped_matmul(laid, (w,), tile_rows=layout.rows, token=token,
+                             weight=weight, tokens=tokens, **tiles)
+    ys = gmm.grouped_matmul(x[layout.src], (w,), out_dtype=F32, **tiles)
+    want = np.zeros((tokens, 256), np.float64)
+    real = ~np.asarray(pads)
+    np.add.at(want, np.asarray(token)[real],
+              (np.asarray(ys, np.float64) * np.asarray(weight)[:, None])[real])
+    assert got.shape == (tokens, 256) and got.dtype == F32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+    named = np.zeros(tokens, bool)
+    named[np.asarray(token)[real]] = True
+    assert (np.asarray(got)[~named] == 0).all() and not named[-3:].any()
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
@@ -231,6 +307,81 @@ def test_moe_dropless_through_the_kernel_is_the_layer_on_ragged_dot(dtype, atol)
     assert float(jnp.abs(k_out[:19]).max()) > 0.1
 
 
+# what the rows' way through the calls is held to: the same layer on the
+# same kernel with XLA's gather of every laid row, un-sort and masked sum
+# over ALL ``T * k`` pairs around it (``expert_rows_in_call`` False)
+LAYER_CASES = {
+    # live tokens of 24, experts held of 8 from ``first_expert``, top k
+    "dead-lanes-first-expert-2": dict(live=19, held=4, first=2, top_k=2),
+    "nothing-held": dict(live=0, held=4, first=2, top_k=2),
+    "every-pair-held": dict(live=24, held=8, first=0, top_k=4),
+    "one-expert-held-of-eight": dict(live=24, held=1, first=7, top_k=3),
+    "rows-that-do-not-fit-fall-back": dict(
+        live=19, held=4, first=2, top_k=2, scalars=16),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", LAYER_CASES)
+def test_rows_moved_in_the_calls_are_the_all_pairs_combine(case, dtype, monkeypatch):
+    """float32, to rounding: only the ORDER in which a token's terms are
+    added differs (by expert, not by choice).  Dead lanes and tokens none
+    of whose experts is held read exact zeros; the rows of another
+    holder's pairs are not moved at all."""
+    spec = LAYER_CASES[case]
+    (x, router, bias, w1, w3, w2), kw = _layer(dtype)
+    first, held = spec["first"], spec["held"]
+    if held > 4:
+        w1, w3, w2 = (jnp.concatenate([w, w * 0.5]) for w in (w1, w3, w2))
+    w1, w3, w2 = w1[:held], w3[:held], w2[:held]
+    kw = dict(kw, live=jnp.arange(24) < spec["live"], first_expert=first,
+              top_k=spec["top_k"], interpret=True)
+    if "scalars" in spec:
+        monkeypatch.setattr(gmm, "_SCALAR_ROWS", spec["scalars"])
+    layer = moe.moe_dropless.__wrapped__
+    in_call = moe.expert_rows_in_call(w1, 24, spec["top_k"], 16)
+    assert in_call == ("scalars" not in spec)
+    got, chosen, load = jax.jit(lambda *a: layer(*a, **kw))(x, router, bias, w1, w3, w2)
+    text = jax.jit(lambda *a: layer(*a, **kw)).lower(
+        x, router, bias, w1, w3, w2).as_text()
+    monkeypatch.setattr(moe, "expert_rows_in_call", lambda *a: False)
+    want, w_chosen, w_load = jax.jit(lambda *a: layer(*a, **kw))(
+        x, router, bias, w1, w3, w2)
+    assert np.array_equal(chosen, w_chosen) and np.array_equal(load, w_load)
+    here = (np.asarray(chosen) >= first) & (np.asarray(chosen) < first + held)
+    here &= (np.arange(24) < spec["live"])[:, None]
+    assert int(load.sum()) == here.sum()
+    scale = max(float(jnp.abs(want).max()), 1e-6)
+    assert float(jnp.abs(got - want).max()) <= 4e-7 * scale
+    assert (np.asarray(got)[~here.any(-1)] == 0).all()
+    if case == "dead-lanes-first-expert-2":
+        # a token with two held experts, one with none, a group padded
+        assert (here.sum(-1) == 2).any() and (~here.any(-1))[:19].any()
+        assert (np.asarray(load) % 16 != 0).any()
+    if case == "every-pair-held":
+        assert here.all()
+    # one sort of the pairs where the calls move the rows, two around XLA's
+    assert text.count("stablehlo.sort") == (1 if in_call else 2)
+
+
+@pytest.mark.parametrize("tokens,top_k,experts,held,h,inter,fits", [
+    (352, 6, 128, 16, 2048, 768, True),     # Kanana-2's widest program
+    (8, 6, 128, 16, 2048, 768, True),       # ... its narrowest
+    (576, 8, 256, 16, 4096, 2048, True),    # MiMo-V2's widest
+    (320, 4, 32, 32, 2048, 1792, True),     # LFM2's widest
+    (64, 8, 512, 128, 2560, 768, True),     # Ling-3.0's steady tick
+    (3584, 4, 32, 32, 2048, 1792, False),   # the benchmark's check, LFM2
+    (8704, 6, 128, 16, 2048, 768, False),   # ... Kanana-2
+    (8192, 8, 256, 16, 4096, 2048, False),  # ... MiMo-V2, two chunks' worth
+])
+def test_the_rows_are_moved_in_the_calls_where_they_fit(
+        tokens, top_k, experts, held, h, inter, fits):
+    w = jax.ShapeDtypeStruct((held, h, inter), jnp.bfloat16)
+    tm = gmm.row_tile(tokens * top_k, experts)
+    assert moe.expert_rows_in_call(w, tokens, top_k, tm) is fits
+    assert moe.expert_rows_in_call(w, tokens, top_k, None) is False  # ragged_dot
+
+
 def test_moe_dropless_through_the_kernel_with_no_pair_held():
     args, kw = _layer(jnp.bfloat16)
     kw = dict(kw, live=jnp.zeros(24, bool))
@@ -277,10 +428,13 @@ def test_kernel_is_a_probe_case_against_three_ragged_dots():
         "grouped_matmul", support.PROBE_SHAPE, interpret=True)
     args = make_args()
     got, want = jax.jit(run)(*args), jax.jit(reference)(*args)
-    assert got.shape == (70, support.PROBE_SHAPE.hidden) and got.dtype == F32
+    # laid rows in and out (70 sorted rows), then the 40 tokens' sums
+    assert got.shape == (70 + 40, support.PROBE_SHAPE.hidden) and got.dtype == F32
     assert float(jnp.abs(want[:59]).max()) > 0.1
+    assert float(jnp.abs(want[70:109]).max()) > 0.1
     assert float(jnp.abs(got - want).max()) <= support.KERNEL_TOLERANCE
-    assert float(jnp.abs(got[59:]).max()) == 0.0
+    assert float(jnp.abs(got[59:70]).max()) == 0.0
+    assert float(jnp.abs(got[109]).max()) == 0.0  # a token of no row
 
 
 def test_the_probe_runs_where_a_traced_caller_asks(monkeypatch):
@@ -307,10 +461,13 @@ def test_engine_counts_row_tiles_at_the_tile_its_program_multiplies_in(monkeypat
 
     w1 = jax.ShapeDtypeStruct((1, 32, 2048, 1792), jnp.bfloat16)
     def stub():
-        return types.SimpleNamespace(
+        engine = types.SimpleNamespace(
             params={"layers": [{"mlp_gate": None}, {"w1": w1}]},
             config=types.SimpleNamespace(num_experts_per_tok=4, num_experts=32),
             _expert_row_tiles={})
+        engine._expert_weights = functools.partial(
+            ServeEngine._expert_weights, engine)
+        return engine
 
     assert ServeEngine._expert_row_tile(stub(), 64) is None  # ragged_dot here
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -319,3 +476,6 @@ def test_engine_counts_row_tiles_at_the_tile_its_program_multiplies_in(monkeypat
     assert ServeEngine._expert_row_tile(engine, 64) == 16
     assert ServeEngine._expert_row_tile(engine, 320) == 64
     assert engine._expert_row_tiles == {64: 16, 320: 64}
+    # the tick's ``expert_rows_impl`` is ``moe_dropless``'s own question
+    assert moe.expert_rows_in_call(engine._expert_weights(), 64, 4, 16)
+    assert not moe.expert_rows_in_call(engine._expert_weights(), 64, 4, None)

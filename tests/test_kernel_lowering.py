@@ -907,7 +907,8 @@ def test_mimo_v2_tick_on_a_v5e_reads_both_classes_where_they_lie(v5e_sharding):
     assert mem.alias_size_in_bytes >= sum(
         a.nbytes for a in pages.pool_arrays())
     _assert_experts_run_the_kernel(ops, layers=4, weights=[
-        (4, 256, 128), (4, 128, 256)])
+        (4, 256, 128), (4, 128, 256)],
+        pairs=(engine.mixed_buckets[-1][1], cfg.num_experts_per_tok, 256))
 
 
 @pytest.mark.parametrize("shape,row_major,held", [
@@ -964,58 +965,132 @@ EXPERT_SHAPES = {
 }
 
 
-@pytest.mark.parametrize("case", EXPERT_SHAPES)
-def test_grouped_matmul_compiles_at_the_cells_shapes(v5e_sharding, case):
-    """Both calls of a layer, with the layout they multiply in, exported
-    and compiled for the described v5e: the weights stay where they lie
-    (operands of the calls as the program's arguments have them, no copy
-    of one into VMEM), and the kernel's VMEM is its blocks' — the same at
-    the check's rows as at a tick's, under half of what a v5e has."""
-    from jax import lax
-
+def _compile_expert_calls(v5e_sharding, rows, experts, held, h, inter, top_k):
+    """A layer's two calls at ``rows`` pairs of ``rows // top_k`` tokens,
+    in the form ``moe_dropless`` takes them there
+    (``ops/moe.expert_rows_in_call``), compiled for the described v5e:
+    ``(the calls' lines, the whole text, laid rows, moved in the calls)``."""
+    from llm_np_cp_tpu.ops import moe
     from llm_np_cp_tpu.ops.pallas import grouped_matmul as gmm
 
-    rows, experts, held, h, inter = EXPERT_SHAPES[case]
+    tokens = rows // top_k
     tm = gmm.row_tile(rows, experts)
+    laid = gmm.tile_count(rows, held, tm) * tm
+    in_call = moe.expert_rows_in_call(
+        jax.ShapeDtypeStruct((held, h, inter), jnp.bfloat16), tokens, top_k, tm)
 
-    def layer(x, w1, w3, w2, sizes):
+    def layer(x, w1, w3, w2, sizes, token, weight):
         layout = gmm.align_groups(sizes, rows, tm)
+        kw = dict(act=jax.nn.silu, tm=tm, interpret=False)
+        if in_call:
+            return gmm.grouped_experts(
+                x, w1, w3, w2, layout, token, weight, **kw)
         return gmm.grouped_experts(
-            x[layout.src], w1, w3, w2, layout, act=jax.nn.silu, tm=tm,
-            interpret=False)[layout.dest]
+            x.astype(w1.dtype)[token], w1, w3, w2, layout, **kw)[layout.dest]
 
     def aval(shape, dtype=jnp.bfloat16, **kw):
         return jax.ShapeDtypeStruct(shape, dtype, **kw)
 
-    shapes = [((rows, h),), ((held, h, inter),), ((held, h, inter),),
-              ((held, inter, h),), ((held,), jnp.int32)]
+    shapes = [((tokens, h), jnp.float32), ((held, h, inter),),
+              ((held, h, inter),), ((held, inter, h),), ((held,), jnp.int32),
+              ((laid,), jnp.int32), ((laid,), jnp.float32)]
     _export_tpu(layer, *(aval(*s) for s in shapes))
     text = jax.jit(layer).lower(
         *(aval(*s, sharding=v5e_sharding) for s in shapes)).compile().as_text()
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 2 and all("%grouped_matmul" in ln for ln in calls)
+    return calls, text, laid, in_call
+
+
+@pytest.mark.parametrize("case", EXPERT_SHAPES)
+def test_grouped_matmul_compiles_at_the_cells_shapes(v5e_sharding, case):
+    """Both calls of a layer, with the layout they multiply in, exported
+    and compiled for the described v5e: the weights stay where they lie
+    (operands of the calls as the program's arguments have them, no copy
+    of one into VMEM), and the kernel's VMEM is its blocks'.  At a tick's
+    rows the calls move the rows themselves — the tokens' float32 rows in,
+    their weighted sums out, no gather and no array as long as the pairs
+    around them; at the check's (a plain forward over thousands of
+    tokens, whose rows do not fit) XLA gathers and un-sorts as before."""
+    rows, experts, held, h, inter = EXPERT_SHAPES[case]
+    top_k = 4 if case.startswith("lfm2") else 6
+    calls, text, laid, in_call = _compile_expert_calls(
+        v5e_sharding, rows, experts, held, h, inter, top_k)
+    assert in_call == ("check" not in case)
     for shape in ((held, h, inter), (held, inter, h)):
         made = re.findall(
             rf"= {re.escape(opmap.hlo_shape('bfloat16', shape))}\S* "
             r"(?!parameter)[\w-]+\(", text)
         assert not made, f"the weights {shape} are made anew: {made}"
-    laid = gmm.tile_count(rows, held, tm) * tm
-    assert f"bf16[{laid},{inter}]" in calls[0] and f"f32[{laid},{h}]" in calls[1]
+    out_rows = rows // top_k if in_call else laid
+    assert f"bf16[{laid},{inter}]" in calls[0]
+    assert f"f32[{out_rows},{h}]" in calls[1]
+    if in_call:  # nothing but the two calls and the layout's arithmetic
+        assert "gather" not in text and f"[{laid},{h}]" not in text
     for ln in calls:
         scope, = re.findall(
             r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
             r'"size":"(\d+)"', ln)
-        assert int(scope) < 64 * 2**20, scope
+        assert int(scope) < (100 if in_call else 64) * 2**20, scope
 
 
-def _assert_experts_run_the_kernel(ops, *, layers, weights):
+# the four expert cells' programs (benchmark/cells/*.json: slots, the
+# tick's token budget; ``ServeEngine._make_buckets``): the dense widths of
+# the tile ladder's rungs and of the steady decode tick
+EXPERT_PROGRAMS = {
+    # experts, held, hidden, an expert's width, top k, dense widths
+    "lfm2-8b-a1b": (32, 32, 2048, 1792, 4, (8, 16, 32, 64, 128, 256, 320)),
+    "kanana-2-30b-a3b-ep8": (
+        128, 16, 2048, 768, 6, (8, 16, 32, 64, 96, 128, 256, 352)),
+    "mimo-v2.5-ep16": (
+        256, 16, 4096, 2048, 8, (8, 16, 32, 64, 128, 256, 512, 576)),
+    "ling-3.0-flash-ep4": (
+        512, 128, 2560, 768, 8, (8, 16, 32, 64, 128, 256, 320)),
+}
+
+
+@pytest.mark.parametrize("config", EXPERT_PROGRAMS)
+def test_expert_calls_move_the_rows_at_every_program_width(v5e_sharding, config):
+    """Every program of the four expert cells: the first call holds the
+    tokens' ``[T, H]`` float32 whole and the second a float32 block ``[T,
+    tn]`` of its result beside their weight blocks, both inside the
+    call's ``vmem_limit_bytes`` (the widest is MiMo-V2's, 576 x 4096)."""
+    experts, held, h, inter, top_k, widths = EXPERT_PROGRAMS[config]
+    for tokens in widths:
+        calls, text, laid, in_call = _compile_expert_calls(
+            v5e_sharding, tokens * top_k, experts, held, h, inter, top_k)
+        assert in_call, tokens
+        assert f"bf16[{laid},{inter}]" in calls[0], tokens
+        assert f"f32[{tokens},{h}]" in calls[1], tokens
+        assert "gather" not in text, tokens
+
+
+def _assert_experts_run_the_kernel(ops, *, layers, weights, pairs):
     """The routed experts of a compiled step (serve/opmap.py's parse, no
     ``named=`` rescue): two calls of the grouped matmul a layer, each under
     ``moe_experts`` by the ``op_name`` it was traced under, no
     ``ragged-dot`` left, and no operation that gives back an array shaped
     like a layer's expert weights (``weights``: ``[E_held, K, N]`` — a
-    copy, a pad or a transpose of them; with the run's leading 1 too)."""
+    copy, a pad or a transpose of them; with the run's leading 1 too).
+    The calls move the rows themselves (PR 49): nothing under
+    ``moe_experts`` is as long as the program's ``pairs`` (tokens x k) and
+    a hidden vector wide — no gather of the laid rows, no un-sort, no
+    ``[T, k, H]`` for a masked sum — and the second call of a layer gives
+    the tokens' ``[T, H]`` float32."""
+    tokens, top_k, hidden = pairs
+    long_rows = {}
+    for name, (scope, shape, _) in ops.items():
+        for dims in re.findall(r"\w+\[([\d,]+)\]", shape):
+            dims = [int(d) for d in dims.split(",")]
+            if scope == "moe_experts" and dims[-1] == hidden and (
+                    np.prod(dims[:-1]) >= tokens * top_k
+                    and not name.startswith("grouped_matmul")):
+                long_rows[name] = shape
+    assert not long_rows, f"rows of every pair under moe_experts: {long_rows}"
+    by_token = opmap.hlo_shape("float32", (tokens, hidden))
+    assert sum(v[1] == by_token and n.startswith("grouped_matmul")
+               for n, v in ops.items()) == layers
     calls = [n for n in ops if n.startswith("grouped_matmul")]
     assert len(calls) == 2 * layers, sorted(ops)
     assert all(ops[n][0] == "moe_experts" for n in calls)
@@ -1115,7 +1190,7 @@ def test_latent_tick_on_a_v5e_reads_the_pool_where_it_lies(v5e_sharding):
     # two expert layers x two calls (gate and up in one, down) over the
     # four experts held, where they lie
     _assert_experts_run_the_kernel(ops, layers=2, weights=[
-        (4, 256, 128), (4, 128, 256)])
+        (4, 256, 128), (4, 128, 256)], pairs=(d_w, 2, 256))
 
 
 def test_a_pool_on_the_cpu_is_row_major():
@@ -1199,7 +1274,7 @@ def test_hybrid_tick_on_a_v5e_names_its_experts_and_rebuilds_no_pool(
     ops = opmap.op_map_from_hlo(text, STEP_SCOPES, pool)
     # 8 expert layers x 2 calls, each under the scope it was traced in
     _assert_experts_run_the_kernel(ops, layers=8, weights=[
-        (8, 256, 256)])
+        (8, 256, 256)], pairs=(8, 2, 256))
     assert {"conv", "moe_route", "moe_experts", "attn", "mlp"} <= {
         v[0] for v in ops.values()}
     # (that an expert layer is never stacked, so that no scan slices a

@@ -500,6 +500,11 @@ def test_tick_arguments_counters_and_scopes(tiny):
         # (row tiles are the Pallas grouped matmul's: on the CPU the
         # experts run lax.ragged_dot, which has none)
         assert "expert_row_tiles" not in a
+        # ... and there XLA moves the rows of all the pairs routed: the
+        # tick's live tokens x top-2 x 8 expert layers, every one held
+        assert a["expert_rows_impl"] == "xla"
+        assert a["pairs_routed"] == a["pairs_held"] == (
+            a["prefill_tokens"] + a["decode_tokens"]) * 2 * 8
     # the counts came back with the tick's one fetch
     assert engine.n_host_fetches - fetches == engine.n_dispatches
     text = engine.metrics.prometheus()
@@ -530,6 +535,9 @@ def test_tick_arguments_counters_and_scopes(tiny):
              if e.get("name") == "tick" and "experts_touched" in e["args"]]
     assert tiled and all(a["expert_row_tile"] == 16 for a in tiled)
     assert all(a["expert_row_tiles"] == a["experts_touched"] for a in tiled)
+    # (its calls do not move the rows of a preset 64 wide: the tick says
+    # so from the same question ``moe_dropless`` asks)
+    assert all(a["expert_rows_impl"] == "xla" for a in tiled)
 
 
 # ----------------------------------------------------------------------

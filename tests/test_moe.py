@@ -218,3 +218,38 @@ def test_the_holders_shares_of_a_sigmoid_routed_layer_add_up(held):
     assert np.array_equal(jnp.concatenate(loads), load)
     assert int(load.sum()) == 24 * 3
     assert float(jnp.abs(total - whole).max()) < 1e-5 * float(jnp.abs(whole).max())
+
+
+@pytest.mark.parametrize("held", [1, 2, 4, 8])
+def test_the_holders_shares_add_up_where_the_calls_move_the_rows(held):
+    """The same sum through the Pallas grouped matmul (in the interpreter),
+    whose two calls gather a holder's rows and add their weighted results
+    by token themselves: each holder moves the rows of ITS pairs alone —
+    a token none of whose experts it holds reads exact zeros there — and
+    the shares add up to the layer on ``lax.ragged_dot`` over all eight
+    experts, float32 to rounding."""
+    from llm_np_cp_tpu.ops.moe import expert_rows_in_call, moe_dropless
+
+    layer = _sigmoid_layer(seed=held, h=128, i=128)
+    kw = dict(act=jax.nn.silu, top_k=3, scaling=2.448, norm_eps=1e-20,
+              live=jnp.arange(24) < 21)
+    x, router_w, bias = layer["x"], layer["router_w"] * 0.1, layer["expert_bias"]
+    assert expert_rows_in_call(layer["w1"][:held], 24, 3, 16)
+    with jax.default_matmul_precision("highest"):
+        whole, chosen, load = moe_dropless(
+            x, router_w, bias, layer["w1"], layer["w3"], layer["w2"], **kw)
+        total = jnp.zeros_like(whole)
+        for first in range(0, 8, held):
+            cut = slice(first, first + held)
+            part, part_chosen, part_load = moe_dropless(
+                x, router_w, bias, layer["w1"][cut], layer["w3"][cut],
+                layer["w2"][cut], first_expert=first, interpret=True, **kw)
+            assert np.array_equal(part_chosen, chosen)
+            assert np.array_equal(part_load, load[cut])
+            here = (np.asarray(chosen) >= first) & (np.asarray(chosen) < first + held)
+            here[21:] = False  # dead lanes
+            assert (np.asarray(part)[~here.any(-1)] == 0).all()
+            assert here.any(-1).all() == (np.asarray(part) != 0).any(-1).all()
+            total = total + part
+    assert int(load.sum()) == 21 * 3
+    assert float(jnp.abs(total - whole).max()) < 1e-5 * float(jnp.abs(whole).max())

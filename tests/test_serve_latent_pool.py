@@ -271,6 +271,10 @@ def test_tick_arguments_counters_scopes_and_the_pool_marking(tiny):
         # 2 expert layers x 4 held experts; top-2: a pair is held or not
         assert 0 <= a["experts_touched"] <= 8
         assert 0 <= a["pairs_held"] <= 2 * 2 * tokens
+        # the pairs the two layers' routers made (top-2 of 8, 4 held); on
+        # the CPU XLA moves the rows of all of them (lax.ragged_dot's path)
+        assert a["pairs_routed"] == 2 * 2 * tokens
+        assert a["expert_rows_impl"] == "xla"
         assert a["expert_load_max"] <= tokens
         assert a["attn_pages"] > 0 and a["attn_grid_steps"] > 0
         assert a["attn_pages_per_step"] >= 1
@@ -281,6 +285,17 @@ def test_tick_arguments_counters_scopes_and_the_pool_marking(tiny):
             a["decode_tokens"] + -(-a["prefill_tokens"] // 8) + 1)
         held += a["pairs_held"]
     assert held > 0
+    from tools.summarize_trace import format_summary, tick_account
+
+    acct = tick_account(tracer.events())
+    every = [e["args"] for e in events
+             if e.get("name") == "tick" and "pairs_held" in e["args"]]
+    assert acct["pairs_held_share"] == pytest.approx(
+        sum(a["pairs_held"] for a in every)
+        / sum(a["pairs_routed"] for a in every))
+    assert 0.2 < acct["pairs_held_share"] < 0.8  # half the experts are held
+    assert acct["expert_rows_kernel_share"] == 0.0
+    assert "of the pairs routed are held" in format_summary(tracer.events(), top=0)
     text = engine.metrics.prometheus()
     assert "moe_pairs_held_total" in text and "moe_experts_touched_total" in text
     total = next(float(line.split()[-1]) for line in text.splitlines()

@@ -530,6 +530,16 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
         for key in ("experts_touched", "expert_load_max",
                     "expert_load_mean", "state_slots_live"):
             out[key] = sum(a.get(key, 0) for a in moe) / len(moe)
+        routed = sum(a.get("pairs_routed", 0) for a in moe)
+        if routed:
+            # the (token, expert) pairs this chip holds of those the
+            # routers made, and the share of the ticks in which the grouped
+            # matmul's calls moved the held pairs' rows themselves (else
+            # XLA moved the rows of all the pairs routed)
+            out["pairs_held_share"] = sum(
+                a["pairs_held"] for a in moe) / routed
+            out["expert_rows_kernel_share"] = sum(
+                a.get("expert_rows_impl") == "kernel" for a in moe) / len(moe)
     for kind in ("ssm", "kda"):
         # state-space mixers (``ssm_*``) / delta-rule linear-attention
         # layers (``kda_*``, scopes ``kda_proj`` / ``kda_scan``): rows whose
@@ -885,6 +895,10 @@ def format_summary(events: list[dict], top: int = 5,
                f"{acct['expert_load_mean']:.2f} tokens an expert, "
                f"{acct['state_slots_live']:.1f} conv-state slots live"
                if "experts_touched" in acct else "")
+            + (f"; {acct['pairs_held_share']:.1%} of the pairs routed are "
+               f"held, their rows moved in the grouped matmul's calls in "
+               f"{acct['expert_rows_kernel_share']:.1%} of those ticks"
+               if "pairs_held_share" in acct else "")
             + "".join(
                 f"; {what} {acct[kind + '_scan_tokens']:.1f} tokens a "
                 f"tick, {acct[kind + '_state_rows']:.1f} rows' {state} "
