@@ -130,12 +130,13 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
     p.add_argument("--num-blocks", type=int, default=0,
                    help="KV pool blocks; 0 sizes the pool so every slot "
                    "can hold a worst-case request plus one spare block.  "
-                   "A model whose window layers hold pages of their own "
-                   "(mimo_v2) gets a second, bounded page class beside "
-                   "these, sized by the engine (the window + the widest "
-                   "slice a tick writes + a block, a slot): the banner "
-                   "reads pool=<blocks>x<block size> (<dtype>) + window "
-                   "<blocks>x<block size> (<ring> a slot)")
+                   "A model whose window layers are a kind of their own "
+                   "(mimo_v2, afmoe) gets a second, bounded page class "
+                   "beside these, sized by the engine (the window + the "
+                   "widest slice a tick writes + a block, a slot): the "
+                   "banner reads pool=<blocks>x<block size> (<dtype>) "
+                   "global x<layers> + window <blocks>x<block size> "
+                   "x<layers> (ring of <ring> a slot)")
     p.add_argument("--cache-dtype", choices=["bf16", "f32", "int8"],
                    default="bf16")
     p.add_argument("--prefix-cache", action=argparse.BooleanOptionalAction,
@@ -225,7 +226,7 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "checkpoint must be: start-up fails unless its "
                    "config's model_type is MODEL_TYPE (llama, mistral, "
                    "mixtral, qwen2, gemma2, lfm2_moe, falcon_h1, "
-                   "deepseek_v3, mimo_v2, ling_hybrid) — so that a "
+                   "deepseek_v3, mimo_v2, ling_hybrid, afmoe) — so that a "
                    "deployment never "
                    "serves another architecture under a model's name")
     p.add_argument("--chaos-spec", default=None, metavar="SPEC",
@@ -747,6 +748,13 @@ def _build_serve_engine(args, params, config, *, prog: str,
                       f"it does NOT price model_type {config.model_type!r} "
                       "(experts touched, a matrix state read and written a "
                       "row): read its utilization as a ratio, not a grade")
+            if config.two_page_classes:
+                print(f"[{prog}] roofline telemetry: its bill streams every "
+                      "weight once a dispatch and prices ONE kind of K/V "
+                      "page (the global layers' heads) — it does NOT price "
+                      f"model_type {config.model_type!r} (experts touched, "
+                      "two page classes): read its utilization as a ratio, "
+                      "not a grade")
     slo_ttft = getattr(args, "slo_ttft", 0.0) or None
     slo_tpot = getattr(args, "slo_tpot", 0.0) or None
     slo_policy = None
@@ -1146,8 +1154,10 @@ def _run_http_serve(argv: list[str], default_model: str) -> str:
         f"pool={num_blocks}x{args.block_size} ({args.cache_dtype})"
         # a pool with a window class (window layers with pages of their
         # own): its blocks, and the ring of them a slot owns
-        + (f" + window {engine.pool.window.num_blocks}x{args.block_size} "
-           f"({engine.window_blocks} a slot)"
+        + (f" global x{len(config.global_layers)} + window "
+           f"{engine.pool.window.num_blocks}x{args.block_size} "
+           f"x{len(config.window_layers)} (ring of {engine.window_blocks} "
+           "a slot)"
            if engine.pool.window is not None else "")
         + f", epilogue={engine.epilogue_impl}, topo={topo}, "
         f"prefix_cache={'on' if args.prefix_cache else 'off'}, "

@@ -44,7 +44,7 @@ class AttnKind:
     kv_heads: int
     key_dim: int
     value_dim: int
-    rope_theta: float
+    rope_theta: float | None  # None: the kind carries no positional encoding
     window: int | None
     sink: bool
 
@@ -228,6 +228,18 @@ class ModelConfig:
     rope_dim: int | None = None  # None: all of head_dim
     attention_value_scale: float = 1.0
 
+    # --- Window and global layers of ONE shape that are still two kinds
+    # (Trinity / AFMoE, ``model_type: afmoe``): the window layers rotate
+    # q and k, the global layers carry no positional encoding at all
+    # (``global_rope`` False); every layer multiplies its attention's
+    # result by ``sigmoid(W_g h)``, per head and column, before ``o_proj``
+    # (``attn_output_gate``); and the window layers' pages are a bounded
+    # class of their own although a page of theirs has the global layers'
+    # shape (``window_page_class``: a class is a KIND's, not a shape's).
+    attn_output_gate: bool = False
+    global_rope: bool = True
+    window_page_class: bool = False
+
     # --- Delta-rule linear-attention layers (KDA) among latent ones
     # (Ling-3.0, ``model_type: ling_hybrid``).  ``layer_group_size is
     # None`` is every other family.  Layer ``i`` is latent attention where
@@ -276,11 +288,12 @@ class ModelConfig:
                 f"latent attention rotates head_dim {self.head_dim} columns: "
                 f"qk_rope_head_dim is {self.qk_rope_head_dim} (and is even)")
         if self.two_page_classes:
+            kv_heads = self.attn_kind("window").kv_heads
             if self.sliding_window is None or self.is_latent or (
-                    self.num_attention_heads % self.swa_num_key_value_heads):
+                    self.num_attention_heads % kv_heads):
                 raise ValueError(
-                    "window layers with kv heads of their own "
-                    f"({self.swa_num_key_value_heads}) need a sliding_window, "
+                    "window layers in a page class of their own "
+                    f"({kv_heads} kv heads) need a sliding_window, "
                     "K/V per head and query heads they divide "
                     f"({self.num_attention_heads})")
         if self.rope_dim is not None and not (
@@ -361,25 +374,35 @@ class ModelConfig:
 
     @property
     def two_page_classes(self) -> bool:
-        """Window layers hold K/V pages of a shape of their own: a pool
-        keeps them as a second class, bounded by the window."""
-        return self.swa_num_key_value_heads is not None
+        """Window layers hold their K/V pages in a class of their own,
+        bounded by the window: a pool keeps two.  A class belongs to a
+        KIND of layer, not to a page shape: MiMo-V2's window layers have
+        kv heads of their own, AFMoE's (``window_page_class``) the global
+        layers' 8 heads of 128 and are bounded all the same."""
+        return self.swa_num_key_value_heads is not None or self.window_page_class
 
     def attn_kind(self, kind: str) -> "AttnKind":
         """What an attention layer of ``kind`` (``"global"`` /
-        ``"window"``) is: kv heads, key and value widths, RoPE base,
-        window (None: the whole context) and whether its softmax has a
-        sink.  The ONE statement of it: parameters, caches, pools, the
-        forward and the cost files read it."""
+        ``"window"``) is: kv heads, key and value widths, RoPE base
+        (None: no positional encoding), window (None: the whole context)
+        and whether its softmax has a sink.  The ONE statement of it:
+        parameters, caches, pools, the forward and the cost files read
+        it.  Two kinds need not differ in shape, and do not decide the
+        pool's classes by it: that is ``two_page_classes``.  Gemma-2's
+        alternating family states neither kv heads of its own nor
+        ``window_page_class``: its two kinds share the one class and its
+        window layers hold full-length chains."""
         window = kind == "window"
+        theta = (self.swa_rope_theta if window and self.swa_rope_theta
+                 else self.rope_theta)
         return AttnKind(
             kind=kind,
             kv_heads=(self.swa_num_key_value_heads
-                      if window and self.two_page_classes
+                      if window and self.swa_num_key_value_heads is not None
                       else self.num_key_value_heads),
             key_dim=self.head_dim, value_dim=self.value_dim,
-            rope_theta=float(self.swa_rope_theta if window and
-                             self.swa_rope_theta else self.rope_theta),
+            rope_theta=(float(theta) if window or self.global_rope
+                        else None),
             window=self.sliding_window if window else None,
             sink=window and self.swa_sink)
 
@@ -415,9 +438,9 @@ class ModelConfig:
         state-space mixer side by side, both reading one normed input),
         ``"latent"`` (attention over one compressed row a token),
         ``"kda"`` (delta-rule linear attention: a matrix state, no pages)
-        or ``"swa"`` (a window layer whose K/V differ in shape from the
-        global layers': ``attn_kind("window")``): the operator of layer
-        ``layer_idx``."""
+        or ``"swa"`` (a window layer whose pages are a class of their
+        own, ``two_page_classes``, whatever their shape:
+        ``attn_kind("window")``): the operator of layer ``layer_idx``."""
         if self.layer_group_size is not None and (
                 (layer_idx + 1) % self.layer_group_size):
             return "kda"
@@ -875,6 +898,8 @@ class ModelConfig:
                 init_expert_specific=d.get("init_expert_specific"),
                 init_expert_out_std=d.get("init_expert_out_std"),
             )
+        if model_type == "afmoe":
+            kwargs.update(_afmoe_kwargs(d))
         if model_type == "qwen2":
             # Qwen-2/2.5: llama architecture with Q/K/V projection biases
             # and an unbiased o_proj (HF Qwen2Attention), untied head on
@@ -1117,12 +1142,94 @@ def _ling_hybrid_kwargs(d: Mapping[str, Any], head_dim: int) -> dict[str, Any]:
     )
 
 
+def _afmoe_kwargs(d: Mapping[str, Any]) -> dict[str, Any]:
+    """``from_hf_dict`` for the AFMoE family (``model_type: afmoe``;
+    Trinity-Large): gated GQA attention with q/k RMSNorm, window layers
+    (``layer_types`` ``sliding_attention``: RoPE, a ``sliding_window``)
+    and global ones (``full_attention``: no positional encoding), four
+    norms a layer with the post-norms inside the residual, the embedding
+    times ``sqrt(hidden_size)`` (``mup_enabled``), ``num_dense_layers``
+    leading dense SwiGLU blocks, then one shared expert beside
+    sigmoid-routed ones chosen by score + ``expert_bias``, normalised
+    (``route_norm``) and times ``route_scale``; an untied head.  What has
+    no equations here is refused by its key."""
+    depth = d["num_hidden_layers"]
+    layer_types = tuple(d["layer_types"])
+    if len(layer_types) != depth:
+        raise ValueError(
+            f"afmoe layer_types names {len(layer_types)} layers, "
+            f"num_hidden_layers is {depth}")
+    bad = set(layer_types) - {"sliding_attention", "full_attention"}
+    if bad:
+        raise ValueError(
+            f"afmoe with layer_types {sorted(bad)} is not implemented "
+            "(sliding_attention, full_attention)")
+    for key in ("n_group", "num_expert_groups", "topk_group",
+                "num_limited_groups"):
+        if (d.get(key) or 1) != 1:
+            raise ValueError(
+                f"afmoe with {key} {d[key]} (group-limited routing) is not "
+                "implemented (1)")
+    if d.get("rope_scaling") is not None:
+        raise ValueError("afmoe with rope_scaling is not implemented (null)")
+    if d.get("score_func", "sigmoid") != "sigmoid":
+        raise ValueError(
+            f"afmoe with score_func {d['score_func']!r} is not implemented "
+            "(sigmoid)")
+    if not d.get("route_norm", True):
+        raise ValueError(
+            "afmoe with route_norm false (unnormalised routing weights) is "
+            "not implemented")
+    if d.get("num_shared_experts", 1) != 1:
+        raise ValueError(
+            f"afmoe with num_shared_experts {d['num_shared_experts']} is not "
+            "implemented (1)")
+    for key in ("attention_bias", "mlp_bias"):
+        if d.get(key, False):
+            raise ValueError(f"afmoe with {key} is not implemented")
+    if any(t == "sliding_attention" for t in layer_types) and not d.get(
+            "sliding_window"):
+        raise ValueError("afmoe with sliding_attention layers needs a "
+                         "sliding_window")
+    # ``num_experts`` is what is HELD; a file that states one chip's share
+    # names the router's width and the first expert held (as deepseek_v3)
+    held = d["num_experts"]
+    router = d.get("router_experts", held)
+    moe_i = d["moe_intermediate_size"]
+    return dict(
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        sandwich_norms=True,
+        scale_embeddings=bool(d.get("mup_enabled", False)),
+        qk_norm=True,
+        attn_output_gate=True,
+        global_rope=False,
+        window_page_class=True,
+        sliding_window=d.get("sliding_window"),
+        window_pattern=tuple(int(t == "sliding_attention")
+                             for t in layer_types),
+        num_experts=router,
+        num_experts_held=None if held == router else held,
+        first_expert=d.get("first_expert", 0),
+        num_experts_per_tok=d["num_experts_per_tok"],
+        num_dense_layers=d.get("num_dense_layers", 0),
+        moe_intermediate_size=moe_i,
+        shared_expert_intermediate_size=moe_i,
+        use_expert_bias=True,
+        norm_topk_prob=True,
+        routed_scaling_factor=float(d.get("route_scale", 1.0)),
+        router_norm_eps=1e-20,
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        init_expert_specific=d.get("init_expert_specific"),
+        init_expert_out_std=d.get("init_expert_out_std"),
+    )
+
+
 # model_type values ``from_hf_dict`` has equations for ("mistral" and
 # "mixtral" are the llama block, the latter with capacity-routed experts
 # when ``num_local_experts`` is set)
 KNOWN_MODEL_TYPES = frozenset(
     ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe",
-     "falcon_h1", "deepseek_v3", "mimo_v2", "ling_hybrid"))
+     "falcon_h1", "deepseek_v3", "mimo_v2", "ling_hybrid", "afmoe"))
 
 PRESETS: dict[str, ModelConfig] = {
     "meta-llama/Llama-3.2-1B": LLAMA_3_2_1B,
@@ -1252,6 +1359,23 @@ def tiny_config(model_type: str = "llama", **overrides: Any) -> ModelConfig:
             use_expert_bias=True, routed_scaling_factor=2.5,
             router_norm_eps=1e-20,
             init_kda_log_decay=(-0.005, -0.5),
+        )
+    if model_type == "afmoe":
+        # Trinity's shape at toy sizes: a leading dense window layer, then
+        # expert layers ``s f s s`` (2 kinds of ONE shape, 2 kv heads
+        # each), window 8, a gate, q/k norms, sandwich norms, no RoPE in
+        # the global layer, 16 experts top-4 beside one shared expert (a
+        # test holds 4); no width of the model
+        base.update(
+            num_hidden_layers=5,
+            rms_norm_eps=1e-5, tie_word_embeddings=False,
+            sandwich_norms=True, scale_embeddings=True, qk_norm=True,
+            attn_output_gate=True, global_rope=False, window_page_class=True,
+            sliding_window=8, window_pattern=(1, 1, 0, 1, 1),
+            num_experts=16, num_experts_per_tok=4, num_dense_layers=1,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32,
+            use_expert_bias=True, routed_scaling_factor=2.448,
+            router_norm_eps=1e-20,
         )
     base.update(overrides)
     return ModelConfig(**base)

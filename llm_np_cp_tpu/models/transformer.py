@@ -96,11 +96,14 @@ SCOPE_ATTN_WINDOW = "attn_window"
 # everything that touches the matrix state (ops/kda.py)
 SCOPE_KDA_PROJ = "kda_proj"
 SCOPE_KDA_SCAN = "kda_scan"
+# an output gate on attention adds one: the gate's projection, its
+# sigmoid and the product with the attention's result (before ``o_proj``)
+SCOPE_ATTN_GATE = "attn_gate"
 # ... which only a stack with such layers enters
 HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
                  SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, SCOPE_MOE_SHARED,
                  SCOPE_ATTN_GLOBAL, SCOPE_ATTN_WINDOW,
-                 SCOPE_KDA_PROJ, SCOPE_KDA_SCAN)
+                 SCOPE_KDA_PROJ, SCOPE_KDA_SCAN, SCOPE_ATTN_GATE)
 STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
                SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL) + HYBRID_SCOPES
 
@@ -242,6 +245,11 @@ def _group_shapes(
             shapes["attn_sink"] = (n, NH)  # one learned logit a query head
         if config.qk_norm:
             shapes.update(ln_q=(n, D), ln_k=(n, D))
+        if config.attn_output_gate:
+            # one gate a query head and column of its result
+            shapes["attn_gate_proj"] = (n, H, NH * Dv)
+        if config.sandwich_norms:
+            shapes["ln_attn_out"] = (n, H)
     if op == "attn_ssm":
         # the state-space mixer beside the attention; in_proj's columns
         # are [z, x, B, C, dt], the convolution runs over [x, B, C]
@@ -257,6 +265,8 @@ def _group_shapes(
         if config.mamba_conv_bias:
             shapes["ssm_conv_bias"] = (n, conv_dim)
     shapes["ln_mlp_in"] = (n, H)
+    if config.sandwich_norms:
+        shapes["ln_mlp_out"] = (n, H)
     if ff == "experts":
         # the router scores every expert of the layer; the tensors are
         # the experts HELD (all of them unless the configuration states
@@ -554,8 +564,8 @@ def attention_block(
     x: jnp.ndarray,
     *,
     config: ModelConfig,
-    cos: jnp.ndarray,
-    sin: jnp.ndarray,
+    cos: jnp.ndarray | None,
+    sin: jnp.ndarray | None,
     mask_global: jnp.ndarray | None = None,
     mask_local: jnp.ndarray | None = None,
     sliding: jnp.ndarray | bool = False,
@@ -570,6 +580,9 @@ def attention_block(
     ``run_decoder_layer`` (see there for the arguments), and the operator
     of a hybrid stack's attention layers.
 
+    cos, sin: the RoPE tables of the tokens' positions, or None for a
+        layer that carries no positional encoding (``AttnKind.rope_theta``
+        None): q and k are then attended as projected (and normed).
     normed: the block's input norm of ``x``, where a second mixer reads
         the same one (``input_norm``): the operator then returns what it
         ADDS to the stream, ``attention_out_multiplier`` applied, and the
@@ -605,8 +618,9 @@ def attention_block(
             # RMSNorm over head_dim on every q and k head, BEFORE RoPE
             q = rms_norm(q, w["ln_q"], eps=config.rms_norm_eps)
             k = rms_norm(k, w["ln_k"], eps=config.rms_norm_eps)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cos is not None:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
     with jax.named_scope(SCOPE_KV_WRITE):
         if kv_update is not None:
@@ -670,6 +684,15 @@ def attention_block(
             )
             if output_attentions:
                 attn, attn_weights = attn
+
+    if config.attn_output_gate:
+        # per head and column, BEFORE o_proj; read from the same normed
+        # input as q
+        with jax.named_scope(SCOPE_ATTN_GATE):
+            gate = jax.nn.sigmoid(
+                _project(h, w["attn_gate_proj"]).astype(jnp.float32))
+            attn = (attn.reshape(b, s, -1).astype(jnp.float32) * gate
+                    ).astype(attn.dtype)
 
     with jax.named_scope(SCOPE_O_PROJ):
         attn = _project(attn.reshape(b, s, -1), w["o_proj"], x.dtype)
@@ -1016,23 +1039,25 @@ def shifted_history(state: jnp.ndarray, z: jnp.ndarray, taps: int) -> tuple:
             ext[:, s:])
 
 
-def experts_block(
+def experts_parts(
     w: Params,
     x: jnp.ndarray,
     *,
     config: ModelConfig,
     act: Any,
     live: jnp.ndarray | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """The dropless routed feed-forward of a block with its residual:
-    ``(x_out, chosen experts [b, s, k], load [E] int32)``.  ``live``
-    ``[b, s]`` marks real tokens (ops/moe.moe_dropless).  The router
-    reads the normed activations in the residual stream's own dtype
-    (float32 in a hybrid stack: nothing is rounded on the way to a
-    discrete choice); the experts multiply them in the served dtype.
-    The tensors are the experts HELD, from ``config.first_expert`` on
-    (``load`` counts those); shared experts, where the configuration has
-    them, are one SwiGLU on every token, added ONCE whatever is held."""
+) -> tuple[jnp.ndarray, Any, jnp.ndarray, jnp.ndarray]:
+    """What an expert layer's feed-forward computes before anything is
+    added to the stream: ``(routed [b, s, H], shared, chosen experts [b *
+    s, k], load [E] int32)``.  ``routed`` is the weighted sum over a
+    token's chosen experts that are HELD (``config.first_expert`` on);
+    ``shared()`` traces the shared experts' SwiGLU on every token (None
+    where the configuration has none): the caller says where, so that a
+    stack adds the two as it always has.  ``live`` ``[b, s]`` marks real
+    tokens (ops/moe.moe_dropless).  The router reads the normed
+    activations in the residual stream's own dtype (float32 in a hybrid
+    stack: nothing is rounded on the way to a discrete choice); the
+    experts multiply them in the served dtype."""
     b, s, hdim = x.shape
     with jax.named_scope(SCOPE_MOE_ROUTE):
         h = rms_norm(x, w["ln_mlp_in"], eps=config.rms_norm_eps)
@@ -1070,15 +1095,50 @@ def experts_block(
         out = out.reshape(n * chunk, hdim)[:t]
         chosen = chosen.reshape(n * chunk, -1)[:t]
         load = load.sum(axis=0)
-    with jax.named_scope(SCOPE_MOE_EXPERTS):
-        x = x + out.reshape(b, s, hdim)
-    if "shared_gate" in w:
+
+    def shared():
+        hs = h.astype(w["shared_gate"].dtype)
+        return _project(
+            act(_project(hs, w["shared_gate"]))
+            * _project(hs, w["shared_up"]), w["shared_down"], x.dtype)
+
+    return (out.reshape(b, s, hdim), shared if "shared_gate" in w else None,
+            chosen, load)
+
+
+def experts_block(
+    w: Params,
+    x: jnp.ndarray,
+    *,
+    config: ModelConfig,
+    act: Any,
+    live: jnp.ndarray | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The dropless routed feed-forward of a block with its residual:
+    ``(x_out, chosen experts [b, s, k], load [E] int32)``
+    (``experts_parts``).  The tensors are the experts HELD, from
+    ``config.first_expert`` on (``load`` counts those); shared experts,
+    where the configuration has them, are one SwiGLU on every token,
+    added ONCE whatever is held.  A pre-norm stack adds the routed part
+    and the shared one to the stream one after the other; a stack with
+    sandwich norms adds ``RMSNorm(routed + shared; ln_mlp_out)``, the
+    post-norm of their SUM (under a share: of the partial sum this
+    program holds), and closes the residual once."""
+    routed, shared, chosen, load = experts_parts(
+        w, x, config=config, act=act, live=live)
+    if config.sandwich_norms:
         with jax.named_scope(SCOPE_MOE_SHARED):
-            hs = h.astype(w["shared_gate"].dtype)
-            x = x + _project(
-                act(_project(hs, w["shared_gate"]))
-                * _project(hs, w["shared_up"]), w["shared_down"], x.dtype)
-    return x, chosen.reshape(b, s, -1), load
+            m = routed if shared is None else routed + shared()
+        with jax.named_scope(SCOPE_MOE_EXPERTS):
+            x = x + rms_norm(m, w["ln_mlp_out"], eps=config.rms_norm_eps,
+                             unit_offset=config.rms_norm_unit_offset)
+    else:
+        with jax.named_scope(SCOPE_MOE_EXPERTS):
+            x = x + routed
+        if shared is not None:
+            with jax.named_scope(SCOPE_MOE_SHARED):
+                x = x + shared()
+    return x, chosen.reshape(*x.shape[:2], -1), load
 
 
 # (token, expert) pairs ``experts_block`` sorts and multiplies at once:
@@ -1240,7 +1300,9 @@ def _hybrid_stack(
                               scan=scan, token_mask=token_mask)
             elif op != "conv":
                 normed = input_norm(w, x, config) if op == "attn_ssm" else None
-                l_cos, l_sin = rope_window if op == "swa" else (cos, sin)
+                l_cos, l_sin = (rope_window if op == "swa" else
+                                (cos, sin) if config.global_rope else
+                                (None, None))
                 mixed, kv_att, _ = attention_block(
                     w, x, config=config, cos=l_cos, sin=l_sin,
                     mask_global=mask_local if op == "swa" else mask,

@@ -404,7 +404,7 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
         # merged [BS, K * D] where it merges them (head_dim 64)
         from llm_np_cp_tpu.serve.block_pool import merges_pages
 
-        page = (kh * d,) if merges_pages(kh, d, int8) else (kh, d)
+        page = (kh * d,) if merges_pages(kh, d, int8, h // kh) else (kh, d)
 
         def make_args():
             q, pages = normals((n_tiles * qt, h, d), (nbp_r, bs) + page)

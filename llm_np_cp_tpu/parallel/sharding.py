@@ -194,7 +194,8 @@ def paged_kv_specs(config: ModelConfig, plan: MeshPlan,
     kv = MODEL_AXIS if _kv_heads_shardable(config, plan) else None
     scale = P(None, None, None, kv) if quantized else None
     merged = merges_pages(
-        config.num_key_value_heads, config.head_dim, quantized)
+        config.num_key_value_heads, config.head_dim, quantized,
+        config.num_query_groups)
     page = P(None, None, None, kv) if merged else P(None, None, None, kv, None)
     return normalize_specs(PagedKV(
         k=page,

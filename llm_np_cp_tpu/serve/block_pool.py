@@ -53,12 +53,21 @@ place at ``[layer, row]`` like the pages (donated); a slot's row is never
 read by a sequence's first token (a position-0 token's history and state
 are zero), so admitting a request into a freed slot needs no clear.
 
-A configuration whose WINDOW layers hold K/V of a shape of their own
-(``config.two_page_classes``: MiMo-V2's window layers have 8 kv heads, its
-global layers 4) has TWO page classes in this one manager:
+A configuration whose WINDOW layers are a kind of their own
+(``config.two_page_classes``) has TWO page classes in this one manager.  A
+class is a KIND's, not a shape's: MiMo-V2's window layers have 8 kv heads
+where its global layers have 4, AFMoE's (Trinity) the same 8 heads of 128
+in both kinds, and both keep their window layers' pages bounded:
 
     k, v:       [global layers, num_blocks, BS, K * D] / [.., K * Dv]
     window k,v: [window layers, 1 + slots * W, BS, Kw * D] / [.., Kw * Dv]
+
+Both classes are stored MERGED whatever their heads, so that the kernel
+takes a head as a static slice of a page's lanes (toy heads that no rule
+merges included).  AFMoE's 8 heads of 128 at a query group of 6 are merged by
+``merges_pages``'s own rule, in one class or two: as ``[BS, K, D]`` pages the
+kernel would attend all K heads in one score sheet of which a row keeps a
+K-th, and the v5e compiler refuses it there.
 
 The first is the class above: a request's chain of it grows with its
 context, out of the free list.  The second is BOUNDED: a query of a
@@ -258,15 +267,29 @@ class WindowRings:
         self.first[slot] = self.end[slot] = 0
 
 
-def merges_pages(kv_heads: int, head_dim: int, quantized: bool) -> bool:
+def merges_pages(kv_heads: int, head_dim: int, quantized: bool,
+                 group: int | None = None) -> bool:
     """Whether a pool of such pages is allocated ``[L, NB, BS, K * D]``
     (module docstring): a float page whose ``[BS, K, D]`` form a TPU
     would not keep row-major (``head_dim`` short of a whole row of
     lanes — compiled for a described v5e, bf16 and float32 alike:
     tests/test_kernel_lowering.py) and whose heads side by side fill
-    whole rows.  int8 pages keep their form beside their scale pages."""
-    return (not quantized and head_dim % 128 != 0
-            and (kv_heads * head_dim) % 128 == 0)
+    whole rows — or, where the caller says how many query heads share a
+    kv head (``group``), heads of whole rows of lanes whose ``[BS, K,
+    D]`` form the kernel cannot attend: it scores all ``K`` heads of
+    such a page group in ONE sheet of ``K * 8 * G`` rows by ``512 * K``
+    columns, a row keeping a ``K``-th of it (ops/pallas/decode_attention,
+    "heads in rows"), and past ``K * K * G = 256`` (8 heads at a group
+    of 4: 4 MiB of float32 scores) the v5e compiler refuses the kernel
+    for its scoped VMEM (8 heads at a group of 6: 560 KB over; PERF.md
+    section 6, PR 50).  Merged, a head is a static slice of a page's
+    lanes and the sheet ``K`` times smaller.  int8 pages keep their form
+    beside their scale pages."""
+    if quantized:
+        return False
+    if head_dim % 128 != 0:
+        return (kv_heads * head_dim) % 128 == 0
+    return group is not None and kv_heads * kv_heads * group > 256
 
 
 def latent_page_width(width: int) -> int:
@@ -410,7 +433,11 @@ class BlockPool:
             v_page = page
         else:
             kh, d = token["k"]
-            merged = merges_pages(kh, d, quantized) or config.two_page_classes
+            # (a pool of two classes stores BOTH merged, whatever their
+            # heads: module docstring)
+            merged = merges_pages(
+                kh, d, quantized, config.num_attention_heads // kh
+            ) or config.two_page_classes
             page = (kh * d,) if merged else (kh, d)
             # (a value head may have another width than a key head)
             v_page = (kh * token["v"][1],) if merged else token["v"]

@@ -510,8 +510,8 @@ class ServeEngine:
                         f"model_type {config.model_type!r} keeps a latent "
                         f"(compressed) KV cache; refused: {why}")
         if config.two_page_classes:
-            # window layers with K/V of a shape of their own live in a
-            # second, bounded page class (serve/block_pool.py): what
+            # window layers live in a second, bounded page class, whatever
+            # the shape of their pages (serve/block_pool.py): what
             # shares, restores, quantizes or cuts the ONE class there was
             # has no rule for two yet, and is refused here by the flag
             # that asked for it
@@ -980,7 +980,12 @@ class ServeEngine:
                         self.cache_dtype.itemsize, "global"),
                     "pool_bytes_per_token_window": config.kv_bytes_per_token(
                         self.cache_dtype.itemsize, "window"),
-                    "window_blocks_per_slot": self.window_blocks}
+                    "window_blocks_per_slot": self.window_blocks,
+                    # ... and the bytes each class holds on the device
+                    "global_class_bytes": int(sum(
+                        a.nbytes for a in self.pool.pages.pool_arrays())),
+                    "window_class_bytes": int(sum(
+                        a.nbytes for a in self.pool.pages.window))}
                    if self.window_blocks else {}),
             })
 
@@ -2047,6 +2052,8 @@ class ServeEngine:
 
             loads = []
             a0 = c0 = w0 = 0
+            # (a global layer may carry no positional encoding)
+            g_cos, g_sin = (cos, sin) if config.global_rope else (None, None)
             # the window class's blocks a layer (``wpools``: its pages,
             # flat over its own layers and blocks; empty without one)
             nbw = 1 + max_slots * window_blocks
@@ -2121,7 +2128,7 @@ class ServeEngine:
                         normed = (input_norm(w, x, config)
                                   if op == "attn_ssm" else None)
                         mixed, kv_att, _ = attention_block(
-                            w, x, config=config, cos=cos, sin=sin,
+                            w, x, config=config, cos=g_cos, sin=g_sin,
                             kv_update=kv_update, attn_fn=attn_fn,
                             normed=normed,
                         )
@@ -3557,6 +3564,25 @@ class ServeEngine:
         outliers: list[dict] = []
         if self.tracer is not None and t0 >= 0.0:
             t7 = self._phase_mark(None)
+            class_args: dict[str, int] = {}
+            if self.window_blocks and dispatched:
+                # a pool with a window class: what one layer of EACH kind
+                # is asked to stream (a window layer's range starts
+                # ``window - 1`` slots before the tile's first token), the
+                # window blocks the rows hold and those this tick's pack
+                # let go
+                class_args = {
+                    "attn_pages_global": attn_pages,
+                    "attn_pages_window": self._attn_window_pages(host_ops, (
+                        packed_width, dense_width)),
+                    "window_blocks_live": self.pool.window.in_use,
+                    "window_blocks_recycled": self._window_recycled_tick}
+                self.metrics.on_page_classes(
+                    pages_global=attn_pages,
+                    pages_window=class_args["attn_pages_window"],
+                    live_tiles=attn_live_tiles,
+                    prefill_tiles=attn_live_tiles - attn_decode_tiles,
+                    recycled=self._window_recycled_tick)
             targs = {
                 "active_slots": active,
                 "queue_depth": self.scheduler.queue_depth,
@@ -3576,17 +3602,7 @@ class ServeEngine:
                 # the program takes for them — tiles x groups of
                 # ``attn_pages_per_step`` pages
                 "attn_pages": attn_pages,
-                # a pool with a window class: what one layer of EACH kind
-                # is asked to stream (a window layer's range starts
-                # ``window - 1`` slots before the tile's first token), the
-                # window blocks the rows hold and those this tick's pack
-                # let go
-                **({"attn_pages_global": attn_pages,
-                    "attn_pages_window": self._attn_window_pages(host_ops, (
-                        packed_width, dense_width)),
-                    "window_blocks_live": self.pool.window.in_use,
-                    "window_blocks_recycled": self._window_recycled_tick}
-                   if self.window_blocks and dispatched else {}),
+                **class_args,
                 "attn_grid_steps": attn_grid_steps,
                 "attn_pages_per_step": attn_step_pages,
                 # the dispatch's live query tiles, and those of them that
@@ -3596,6 +3612,9 @@ class ServeEngine:
                 # latent_attention)
                 "attn_live_tiles": attn_live_tiles,
                 "attn_decode_tiles": attn_decode_tiles,
+                # ... and those that hold more (a prefill chunk's tiles,
+                # each of which streams its row's visible pages again)
+                "attn_prefill_tiles": attn_live_tiles - attn_decode_tiles,
                 # rows _pack_mixed wrote by whole-array assignments
                 # (plain decode rows): how much of pack went the fast way
                 "pack_array_rows": array_rows,

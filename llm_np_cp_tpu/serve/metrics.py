@@ -200,6 +200,17 @@ class ServeMetrics:
         self.moe_load_mean = 0.0
         self.moe_pairs_held = 0
         self.conv_state_slots = 0
+        # a pool with a window class (exact counters, one observation a
+        # dispatching tick while a trace recorder is attached): the pages
+        # one layer of each kind streams, the live query tiles and those
+        # of them with more than one token (a prefill chunk's), the ring
+        # blocks the tick's rows let go
+        self.class_ticks = 0
+        self.attn_pages_global = 0
+        self.attn_pages_window = 0
+        self.attn_live_tiles = 0
+        self.attn_prefill_tiles = 0
+        self.window_blocks_recycled = 0
         # state-space mixers (exact counters, one observation a
         # dispatching tick): rows whose recurrent state a dispatch read
         # and wrote, live tokens through the scan
@@ -323,6 +334,21 @@ class ServeMetrics:
             self.moe_load_mean += load_mean
             self.moe_pairs_held += pairs_held
             self.conv_state_slots = state_slots_live
+
+    def on_page_classes(self, *, pages_global: int, pages_window: int,
+                        live_tiles: int, prefill_tiles: int,
+                        recycled: int) -> None:
+        """One dispatching tick of a pool with a window class: what one
+        layer of each kind streams (pages, summed over the live query
+        tiles: a tile re-reads its row's pages), the live tiles and the
+        prefill tiles among them, the window blocks recycled."""
+        with self._lock:
+            self.class_ticks += 1
+            self.attn_pages_global += pages_global
+            self.attn_pages_window += pages_window
+            self.attn_live_tiles += live_tiles
+            self.attn_prefill_tiles += prefill_tiles
+            self.window_blocks_recycled += recycled
 
     def on_ssm(self, *, rows: int, tokens: int, state_slots_live: int,
                kernel: bool) -> None:
@@ -542,6 +568,14 @@ class ServeMetrics:
                 out["moe_expert_load_mean"] = self.moe_load_mean
                 out["moe_pairs_held"] = self.moe_pairs_held
                 out["conv_state_slots_live"] = self.conv_state_slots
+            if self.class_ticks:
+                # only where a pool with a window class was traced
+                out["page_class_ticks"] = self.class_ticks
+                out["attn_pages_global"] = self.attn_pages_global
+                out["attn_pages_window"] = self.attn_pages_window
+                out["attn_live_tiles"] = self.attn_live_tiles
+                out["attn_prefill_tiles"] = self.attn_prefill_tiles
+                out["window_blocks_recycled"] = self.window_blocks_recycled
             if self.ssm_ticks:
                 # only where a state-space mixer ran
                 out["ssm_ticks"] = self.ssm_ticks
@@ -776,6 +810,26 @@ class ServeMetrics:
             emit("conv_state_slots_live", "gauge",
                  "Slots whose short-convolution state is live",
                  [("", s["conv_state_slots_live"])])
+        if "page_class_ticks" in s:
+            emit("page_class_ticks_total", "counter",
+                 "Dispatching ticks of a pool with a window class "
+                 "(counted while a trace recorder is attached)",
+                 [("", s["page_class_ticks"])])
+            emit("attn_pages_streamed_total", "counter",
+                 "Pages ONE layer of a kind streams, summed over the "
+                 "live query tiles (a tile re-reads its row's pages) "
+                 "and over ticks",
+                 [('{kind="global"}', s["attn_pages_global"]),
+                  ('{kind="window"}', s["attn_pages_window"])])
+            emit("attn_query_tiles_total", "counter",
+                 "Live query tiles of the dispatches, and those of them "
+                 "that hold more than one token (a prefill chunk's)",
+                 [('{kind="live"}', s["attn_live_tiles"]),
+                  ('{kind="prefill"}', s["attn_prefill_tiles"])])
+            emit("window_ring_recycled_blocks_total", "counter",
+                 "Window-class blocks the dispatches' rows let go (their "
+                 "ring entries are written next), summed over ticks",
+                 [("", s["window_blocks_recycled"])])
         if "ssm_ticks" in s:
             emit("ssm_ticks_total", "counter",
                  "Dispatching ticks that ran state-space mixers",

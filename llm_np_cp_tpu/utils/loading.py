@@ -44,6 +44,7 @@ from safetensors import safe_open
 
 from llm_np_cp_tpu.config import ModelConfig
 from llm_np_cp_tpu.models import (
+    afmoe,
     deepseek_v3,
     falcon_h1,
     gemma2,
@@ -129,7 +130,8 @@ def hybrid_family(config: ModelConfig):
     """The family module whose ``layer_tensors`` places a hybrid stack's
     checkpoint tensors (``(HF key, run, leaf, index, transpose?)``)."""
     return {"falcon_h1": falcon_h1, "deepseek_v3": deepseek_v3,
-            "mimo_v2": mimo_v2, "ling_hybrid": ling_hybrid}.get(
+            "mimo_v2": mimo_v2, "ling_hybrid": ling_hybrid,
+            "afmoe": afmoe}.get(
         config.model_type, lfm2_moe)
 
 

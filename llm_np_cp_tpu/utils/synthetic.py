@@ -164,6 +164,28 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
         )
         if config.init_expert_out_std is not None:
             d["init_expert_out_std"] = config.init_expert_out_std
+    if config.model_type == "afmoe":
+        depth = config.num_hidden_layers
+        d.update(
+            layer_types=[
+                "sliding_attention" if config.layer_is_sliding(i)
+                else "full_attention" for i in range(depth)],
+            sliding_window=config.sliding_window,
+            mup_enabled=config.scale_embeddings,
+            num_dense_layers=config.num_dense_layers,
+            num_experts=config.experts_held,
+            router_experts=config.num_experts,
+            first_expert=config.first_expert,
+            num_shared_experts=1,
+            num_experts_per_tok=config.num_experts_per_tok,
+            moe_intermediate_size=config.moe_intermediate_size,
+            route_norm=True, route_scale=config.routed_scaling_factor,
+            score_func="sigmoid", rope_scaling=None,
+            n_group=1, num_expert_groups=1, topk_group=1,
+            num_limited_groups=1,
+        )
+        if config.init_expert_out_std is not None:
+            d["init_expert_out_std"] = config.init_expert_out_std
     if config.model_type == "ling_hybrid":
         moe_i = config.moe_intermediate_size
         d.update(

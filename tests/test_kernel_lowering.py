@@ -839,6 +839,59 @@ def test_ragged_kernel_compiles_at_mimo_v2s_page_classes(
     assert f"{blocks}x64x{kh * d}xbf16" in module
 
 
+@pytest.mark.parametrize("mb,blocks,base,merged", [
+    (67, 4 * 2145, True, True),     # a window layer: its ring's table
+    (140, 4482, False, True),       # the global layer: the growing chain
+    (140, 4482, False, False),      # ... as [BS, K, D] pages: refused
+], ids=["window", "global", "global-heads-in-rows"])
+def test_ragged_kernel_at_afmoes_page_classes(
+        v5e_sharding, mb, blocks, base, merged):
+    """48 query heads of 128 over 8 kv heads (Trinity: K 8, G 6) at the
+    cell's widest program (128 tiles): stored MERGED, a head is a whole
+    row of a page's lanes and the kernel lowers for the v5e with a table
+    whose column 0 is not position 0 (the window class) or without (the
+    global one).  As ``[BS, K, D]`` pages the kernel would attend all 8
+    heads in one ``[K * rows, BS * K]`` sheet of which a row keeps an
+    eighth, and the compiler refuses it: why a pool of two classes stores
+    both merged whatever their heads (serve/block_pool.py)."""
+    from llm_np_cp_tpu.ops.pallas import decode_attention as da
+
+    def aval(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_sharding)
+
+    nt, h, kh, d, i32 = 128, 48, 8, 128, jnp.int32
+    page = (64, kh * d) if merged else (64, kh, d)
+    args = [aval((nt * 8, h, d), jnp.bfloat16),
+            aval((blocks,) + page, jnp.bfloat16),
+            aval((blocks,) + page, jnp.bfloat16),
+            aval((32, mb), i32), aval((nt,), i32), aval((nt,), i32),
+            aval((nt,), i32), aval((32,), i32), aval((), i32)]
+    kw = {"block0": aval((32,), i32)} if base else {}
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        lowered = jax.jit(functools.partial(
+            da.ragged_paged_attention, scale=d ** -0.5)).lower(*args, **kw)
+        if not merged:
+            with pytest.raises(Exception, match="vmem"):
+                lowered.compile()
+            return
+        compiled = lowered.compile()
+    finally:
+        jax.default_backend = real
+    _, _, module = _ragged_kernel_call(compiled.as_text())
+    p = da.ragged_pages_per_step(mb, 64, kh, d, jnp.bfloat16, False, merged=True)
+    assert p == 8 and _kernel_grid(module) == (nt, -(-mb // p))
+    assert (da._lane_pack(kh, d), da._dma_slices_pages(
+        jnp.zeros((2,) + page, jnp.bfloat16))) == (1, True)
+    scratch = _kernel_vmem_scratch(module, rank=4)[-2:]
+    assert scratch == [((2, p, 64, kh * d), "bf16")] * 2, scratch
+    # the whole tile's update and a one-token tile's, a dot a head for
+    # the scores and one for the values
+    assert module.count("tpu.matmul") == 2 * 2 * kh
+    assert f"{blocks}x64x{kh * d}xbf16" in module
+
+
 def test_a_stack_with_one_page_class_compiles_the_parents_tick(v5e_sharding):
     """The second table exists only for a pool with a window class: the
     Qwen-shaped engine's and a Gemma-2-shaped engine's widest programs

@@ -515,6 +515,9 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
             out[key] = sum(a[key] for a in tiled) / len(tiled)
         out["attn_decode_tile_share"] = (
             out["attn_decode_tiles"] / out["attn_live_tiles"])
+        # the others hold a prefill chunk's tokens: each streams its
+        # row's visible pages again
+        out["attn_prefill_tile_share"] = 1.0 - out["attn_decode_tile_share"]
     classes = [e["args"] for e in ticks if "attn_pages_window" in e["args"]]
     if classes:
         # a pool with a window class: what one layer of each kind streams,
@@ -931,6 +934,10 @@ def format_summary(events: list[dict], top: int = 5,
                f"{acct['attn_pages_window']:.0f}; "
                f"{acct['window_blocks_live']:.0f} window blocks live, "
                f"{acct['window_blocks_recycled']:.2f} recycled a tick"
+               + (f"; {acct['attn_prefill_tile_share']:.0%} of the live "
+                  "query tiles hold a prefill chunk's tokens (each streams "
+                  "its row's pages again)"
+                  if "attn_prefill_tile_share" in acct else "")
                if "attn_pages_window" in acct else "")
             + "; packed width "
             + " ".join(f"{w}x{n}" for w, n in acct["packed_widths"].items())
