@@ -133,7 +133,8 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "A model whose window layers are a kind of their own "
                    "(mimo_v2, afmoe) gets a second, bounded page class "
                    "beside these, sized by the engine (the window + the "
-                   "widest slice a tick writes + a block, a slot): the "
+                   "widest slice a tick writes, which is "
+                   "--tick-token-budget, + a block, a slot): the "
                    "banner reads pool=<blocks>x<block size> (<dtype>) "
                    "global x<layers> + window <blocks>x<block size> "
                    "x<layers> (ring of <ring> a slot)")
@@ -179,12 +180,19 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "banner reports the resolution as epilogue=fused|xla")
     p.add_argument("--tick-token-budget", type=int, default=0, metavar="N",
                    help="token budget per tick — "
-                   "decode rows are budgeted first (never starved), "
-                   "remaining tokens go to prefill chunk slices, so a "
-                   "long prefill spreads over ticks instead of stalling "
-                   "the decode batch.  Must be >= --slots; larger = "
-                   "faster TTFT, smaller = steadier decode cadence.  "
-                   "0 = slots + 2*prefill_chunk")
+                   "decode rows are budgeted first (never starved); "
+                   "then every mid-prefill row gets one prefill chunk "
+                   "(2 x --block-size tokens, at most 256), oldest "
+                   "first; what is STILL left goes to the oldest "
+                   "prompt, so budget - slots is the prompt lane: a "
+                   "prompt's first token costs about prompt / lane "
+                   "ticks, and a tick leaves budget unspent only when "
+                   "no row can use it.  Must be >= --slots; larger = "
+                   "faster TTFT, smaller = steadier decode cadence (a "
+                   "tick with a prompt aboard runs the widest program).  "
+                   "Also the widest slice a tick writes into one row: "
+                   "a window page class's rings are sized by it "
+                   "(--num-blocks).  0 = slots + 2*prefill_chunk")
     p.add_argument("--speculative-serve", action="store_true",
                    help="speculative decoding inside the unified tick: "
                    "per-request host-side prompt-lookup drafts verified "

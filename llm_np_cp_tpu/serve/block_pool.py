@@ -189,7 +189,8 @@ def window_blocks_per_slot(window: int, widest_slice: int,
     """Blocks of the window class a decode slot's ring holds: what one
     tick can need at once — the ``window - 1`` positions before the first
     query of the widest slice a tick writes (``widest_slice`` tokens: the
-    prefill chunk, or the budget if that is smaller), the slice itself,
+    tick's token budget, which the planner hands a lone prompt whole:
+    ``Scheduler.plan_tick``), the slice itself,
     and one block more because the run starts anywhere inside a block.
     The ONE statement of the rule: the engine sizes the class by it and
     ``WindowRings.advance`` refuses a tick that would pass it."""

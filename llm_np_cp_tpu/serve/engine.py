@@ -24,7 +24,10 @@ preempted request resumes its exact RNG stream).
 The scheduler's token-budget planner (``Scheduler.plan_tick``)
 co-schedules chunked prefill with decode under ``tick_token_budget``
 tokens per tick — decode rows first, so a long prefill can no longer
-stall the decoding batch (the PR-5 trace finding).  The step's token
+stall the decoding batch (the PR-5 trace finding); then a chunk for
+every mid-prefill row, then the rest of the budget for the oldest
+prompt, so a row's slice of one tick is as long as the budget allows
+(the window class's rings are sized for it).  The step's token
 axis is DENSE (one lane a token, ``dense_width``); the 8-lane query
 tiles the ragged kernel wants (``packed_width`` lanes) exist only inside
 attention, between two row gathers.  A program is the pair, and the set
@@ -715,17 +718,30 @@ class ServeEngine:
             math.lcm(self.block_size, self.prefill_chunk) // self.block_size
         )
 
+        # spec engines get verify headroom in the default budget:
+        # drafts only ever spend budget prefill left over, so
+        # without the extra room a busy admission window would trim
+        # every draft to nothing and speculation would never engage
+        budget = tick_token_budget or (
+            max_slots * (1 + self.spec_k) + 2 * self.prefill_chunk
+        )
+        if budget < max_slots:
+            raise ValueError(
+                f"tick_token_budget ({budget}) must be >= max_slots "
+                f"({max_slots}): every decode row needs one token per "
+                "tick before prefill fills the remainder"
+            )
+        self.tick_token_budget = budget
+
         t_pool = tracer.now_us() if tracer is not None else -1.0
         # a pool with a window class: the ring a slot holds, from the
-        # window and the widest slice one tick writes into a row (a
-        # prefill chunk, or the whole budget where that is smaller)
+        # window and the widest slice one tick writes into a row — the
+        # whole budget, which the planner hands the oldest prompt when
+        # nothing else wants it (Scheduler.plan_tick)
         self.window_blocks = 0
         if config.two_page_classes:
             self.window_blocks = window_blocks_per_slot(
-                config.sliding_window,
-                min(self.prefill_chunk,
-                    tick_token_budget or self.prefill_chunk),
-                block_size)
+                config.sliding_window, budget, block_size)
         self.pool = BlockPool(
             config, num_blocks, block_size, dtype=cache_dtype,
             enable_prefix_cache=enable_prefix_cache,
@@ -922,20 +938,6 @@ class ServeEngine:
             self._q_tile, max_slots, self.max_blocks_per_seq,
             self._spec_w,
         ) + ((self.window_blocks,) if self.window_blocks else ())
-        # spec engines get verify headroom in the default budget:
-        # drafts only ever spend budget prefill left over, so
-        # without the extra room a busy admission window would trim
-        # every draft to nothing and speculation would never engage
-        budget = tick_token_budget or (
-            max_slots * (1 + self.spec_k) + 2 * self.prefill_chunk
-        )
-        if budget < max_slots:
-            raise ValueError(
-                f"tick_token_budget ({budget}) must be >= max_slots "
-                f"({max_slots}): every decode row needs one token per "
-                "tick before prefill fills the remainder"
-            )
-        self.tick_token_budget = budget
         self.mixed_buckets = self._make_buckets(budget, max_slots)
         # stated once a program: the packer looks its layout up
         self._mixed_layouts = {
@@ -1568,9 +1570,10 @@ class ServeEngine:
 
     def _make_mixed_step(self) -> Callable:
         """The unified-tick program: ONE dispatch runs a packed ragged
-        batch of prefill chunk slices (q_len up to ``prefill_chunk``)
-        and decode rows (q_len 1) through the layer scan, scattering
-        every token's K/V straight into its pool block and attending
+        batch of prefill slices (q_len up to the tick's token budget:
+        ``Scheduler.plan_tick``) and decode rows (q_len 1) through the
+        layer scan, scattering every token's K/V
+        straight into its pool block and attending
         through the block tables.  The pool is the scan's carry,
         reshaped once to ``[L*NB, ...]`` (a bitcast) and written in
         place: layer ``l`` writes and attends pages ``l * NB + block``,
@@ -3499,6 +3502,7 @@ class ServeEngine:
             ),
             prefill_tokens=n_prefill_tok,
             decode_tokens=n_decode_tok,
+            prefill_rows=len(prefill_segs),
             dense_lanes=dense_width,
             host_bound=device_done,
         )
@@ -3589,6 +3593,9 @@ class ServeEngine:
                 "admitted": len(admitted),
                 "prefill_tokens": n_prefill_tok,
                 "decode_tokens": n_decode_tok,
+                # the mid-prefill rows the prompt tokens went to (one
+                # segment a row: plan_tick)
+                "prefill_rows": len(prefill_segs),
                 # the dispatch as the device sees it: the live context
                 # its rows attend (summed over rows), and the program —
                 # the width inside attention (tile lanes) and the width

@@ -175,6 +175,10 @@ class ServeMetrics:
         # budget was actually spent — exact counters, never trimmed
         self.mixed_prefill_tokens = 0
         self.mixed_decode_tokens = 0
+        # ...and the rows that prefill spend went to, one a planned row
+        # a tick: prefill tokens / segments is what a row gets of a tick
+        # (a chunk where rows share the lane, the lane where one has it)
+        self.prefill_segments = 0
         # ...and the lanes of the step's dense token axis it was
         # dispatched at, summed over dispatches: tokens / lanes is the
         # share of the matmuls' rows that hold a token
@@ -280,11 +284,13 @@ class ServeMetrics:
         self, *, queue_depth: int, occupancy: float, active_slots: int,
         preemptions_total: int, kv_bytes: int = 0,
         prefill_tokens: int = 0, decode_tokens: int = 0,
+        prefill_rows: int = 0,
         dense_lanes: int = 0, host_bound: bool = False,
     ) -> None:
         with self._lock:
             self.host_bound_ticks += host_bound
             self.mixed_prefill_tokens += prefill_tokens
+            self.prefill_segments += prefill_rows
             self.mixed_decode_tokens += decode_tokens
             self.mixed_dense_lanes += dense_lanes
             self.n_ticks += 1
@@ -556,6 +562,7 @@ class ServeMetrics:
                 out["tier_breakeven_ratio"] = self.tier_breakeven or 0.0
             out["mixed_prefill_tokens"] = self.mixed_prefill_tokens
             out["mixed_decode_tokens"] = self.mixed_decode_tokens
+            out["prefill_segments"] = self.prefill_segments
             out["mixed_dense_lanes"] = self.mixed_dense_lanes
             out["publish_overlapped_ticks"] = self.publish_overlapped
             out["publish_immediate_ticks"] = self.publish_immediate
@@ -771,6 +778,15 @@ class ServeMetrics:
              "Unified-tick token budget spent, split by work kind",
              [('{kind="prefill"}', s["mixed_prefill_tokens"]),
               ('{kind="decode"}', s["mixed_decode_tokens"])])
+        emit("prefill_segments_total", "counter",
+             "Prefill segments planned: one a mid-prefill row a tick",
+             [("", s["prefill_segments"])])
+        emit("prefill_segment_tokens_total", "counter",
+             "Prompt tokens in those segments (mixed_tokens_total's "
+             "prefill kind without a label, so that a ratio of counter "
+             "deltas reads it): tokens / segments is what a row gets of "
+             "one tick",
+             [("", s["mixed_prefill_tokens"])])
         emit("mixed_dense_lanes_total", "counter",
              "Lanes of the unified step's dense token axis, summed over "
              "dispatches (mixed_tokens_total / this = the share of "

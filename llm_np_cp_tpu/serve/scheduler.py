@@ -320,14 +320,23 @@ class Scheduler:
           has finished prefill gets its one decode token before any
           prefill work is budgeted — a long prefill can no longer stall
           the decoding batch, it only fills the REMAINING budget.
-        - **prefill fills the rest, oldest first**: mid-prefill rows
-          (admission order, so FIFO completion order is preserved) take
-          up to ``max_chunk`` tokens each from what is left.  Token
-          granularity: a segment smaller than a full chunk is legal, so
-          any ``budget >= max_slots`` guarantees forward progress.
-          ``prefill_order`` overrides the candidate ORDER only (the
-          tenant-fairness hook — smallest cost share first, a stable
-          re-sort so ties keep admission order); ``None`` is the
+        - **prefill fills the rest, a fair share first**: mid-prefill
+          rows (admission order, so FIFO completion order is preserved)
+          take up to ``max_chunk`` tokens each from what is left — a
+          short prompt behind a long one still gets its chunk in the
+          same tick.  Token granularity: a segment smaller than a full
+          chunk is legal, so any ``budget >= max_slots`` guarantees
+          forward progress.
+        - **the lane is work-conserving**: what the budget STILL holds
+          after the fair share and the drafts goes to the same rows in
+          the same order — the oldest takes the rest of its prompt or
+          the rest of the budget, then the next — so a long prompt's
+          first token costs prompt / lane ticks, not one tick a chunk,
+          and a tick leaves budget unspent only when no row can use it.
+          A row appears ONCE in the segments, its two grants summed.
+          ``prefill_order`` overrides the candidate ORDER of both passes
+          (the tenant-fairness hook — smallest cost share first, a
+          stable re-sort so ties keep admission order); ``None`` is the
           byte-identical oldest-first default.
         - **budgets are exact**: the planned token count never exceeds
           ``budget`` (pinned by tests/test_serve_scheduler.py).
@@ -335,34 +344,39 @@ class Scheduler:
           done at admission (``Request.prefill_done``), so shared blocks
           consume zero budget — the cap applies to work, not to reuse.
         - **verify widths are tokens**: a speculating decode row's draft
-          lanes (``Request.draft_len``) are budgeted AFTER prefill, out
-          of whatever budget remains — speculation spends the tick's
-          slack, so enabling it can never stall an admission's TTFT.
-          Drafts that don't fit are trimmed (``draft_len`` shrinks),
-          never the row's base token.
+          lanes (``Request.draft_len``) are budgeted AFTER the fair
+          share and BEFORE the leftover pass, out of whatever budget
+          remains — speculation spends the tick's slack, so enabling it
+          can never stall an admission's TTFT, and the leftover pass
+          never trims a draft that fits.  Drafts that don't fit are
+          trimmed (``draft_len`` shrinks), never the row's base token.
 
         Pure accounting (no allocation): callers run it after admission
         and block growth, then build the packed mixed batch from it.
         """
         decode = [r for r in self.running if r.prefilled and r.generated]
         left = budget - len(decode)
-        prefill: list[tuple[Request, int]] = []
         candidates = (
             self.running if prefill_order is None
             else prefill_order(self.running)
         )
-        for r in candidates:
-            if r.prefilled or left <= 0:
-                continue
-            n = min(max_chunk, r.prefill_target - r.prefill_done, left)
-            if n > 0:
-                prefill.append((r, n))
-                left -= n
+        waiting = [r for r in candidates if not r.prefilled]
+        grants = []  # the fair share: a chunk a row while the budget lasts
+        for r in waiting:
+            n = max(min(max_chunk, r.prefill_target - r.prefill_done, left), 0)
+            grants.append(n)
+            left -= n
         for r in decode:
             if r.draft_len > left:
                 r.draft_len = max(left, 0)
             left -= r.draft_len
-        return decode, prefill
+        for i, r in enumerate(waiting):  # the leftover, to the oldest
+            if left <= 0:
+                break
+            n = min(r.prefill_target - r.prefill_done - grants[i], left)
+            grants[i] += n
+            left -= n
+        return decode, [(r, n) for r, n in zip(waiting, grants) if n > 0]
 
     # ------------------------------------------------------------------
     def ensure_decode_blocks(self) -> list[Request]:

@@ -72,7 +72,8 @@ def probe():
 
 
 def _engine(cfg, params, attn="pallas", **kw):
-    # (two slots and a budget of 18: three programs to compile, not five)
+    # (two slots and a budget of 18: three programs to compile, not five;
+    # a ring of 5 blocks: the window's 7 slots + a budget-wide slice + 1)
     kw.setdefault("max_slots", 2)
     kw.setdefault("num_blocks", 12)
     kw.setdefault("block_size", 8)
@@ -115,7 +116,7 @@ def _serve(engine, reqs):
 # every sequence goes to the reference at ONE length: its plain jax.numpy
 # compiles each operation anew for each length, and a causal model's logits
 # at a position do not depend on what follows it
-REF_LEN = 48
+REF_LEN = 64
 
 
 def _worst_gap(params, hf, reqs, got, **kw) -> float:
@@ -139,14 +140,14 @@ def _prompts(lengths, seed=0):
 
 SERVE_CASES = {
     # a prompt in slices of 16 beside a short one that decodes while it
-    # prefills: contexts past four windows of 8, the ring of 4 blocks
+    # prefills: contexts past six windows of 8, the ring of 5 blocks
     # turns over more than once
-    "pallas": dict(lengths=[37, 5], new=3, attn="pallas"),
-    "xla": dict(lengths=[37, 5], new=5, attn="xla"),
+    "pallas": dict(lengths=[53, 5], new=5, attn="pallas"),
+    "xla": dict(lengths=[53, 5], new=5, attn="xla"),
     # heads of 128, the published width: both classes stored merged, a head
     # one whole row of lanes of a page (``_lane_pack`` 1), behind a table
     # that starts past position 0
-    "pallas_d128": dict(lengths=[37], new=3, attn="pallas", head_dim=128),
+    "pallas_d128": dict(lengths=[53], new=3, attn="pallas", head_dim=128),
 }
 
 
@@ -177,9 +178,9 @@ def test_served_logits_match_the_references_full_forward(tiny, case):
     # both classes back to empty; the bounded one never held more than its
     # rings while the contexts grew past them, and its blocks went round
     assert stats["allocated"] == 0 and stats["window_blocks_in_use"] == 0
-    assert stats["window_blocks_per_slot"] == engine.window_blocks == 4
-    assert max(grown) <= len(reqs) * 4
-    assert -(-(len(reqs[0].prompt) + spec["new"]) // 8) > 4  # > a ring
+    assert stats["window_blocks_per_slot"] == engine.window_blocks == 5
+    assert max(grown) <= len(reqs) * 5
+    assert -(-(len(reqs[0].prompt) + spec["new"]) // 8) > 5  # > a ring
     assert stats["window_blocks_recycled_total"] >= 3
 
 
@@ -190,11 +191,13 @@ def test_one_shape_lands_in_two_classes(tiny):
     engine = _engine(cfg, params)
     pages, rings = engine.pool.pages, engine.pool.window
     # 1 global layer and 4 window layers of 2 kv heads of 16: toy heads are
-    # stored merged in both classes; 2 slots x a ring of 4 + scratch
+    # stored merged in both classes; 2 slots x a ring of 5 + scratch
     assert pages.k.shape == (1, 12, 8, 32) and pages.v.shape == (1, 12, 8, 32)
-    assert [a.shape for a in pages.window] == [(4, 9, 8, 32)] * 2
-    assert pages.merged and rings.num_blocks == 9
-    # the published widths: window 4,096, a chunk of 128, blocks of 64
+    assert [a.shape for a in pages.window] == [(4, 11, 8, 32)] * 2
+    assert pages.merged and rings.num_blocks == 11
+    # the published widths: window 4,096, the cell's budget of 800 (a
+    # slice of one chunk of 128 wanted 67), blocks of 64
+    assert window_blocks_per_slot(4096, 800, 64) == 78
     assert window_blocks_per_slot(4096, 128, 64) == 67
     gauges = engine.pool_form_gauges()
     assert gauges["kv_global_block_bytes"] == 8 * 1 * 2 * 2 * 16 * 4
@@ -266,8 +269,8 @@ def test_the_tick_reports_both_classes_and_the_experts(tiny):
     engine.run_until_complete()
     events = tracer.events()
     build = next(e for e in events if e["name"] == "engine_build")["args"]
-    assert build["window_blocks_per_slot"] == 4
-    assert build["window_class_bytes"] == 2 * 4 * 9 * 8 * 32 * 4
+    assert build["window_blocks_per_slot"] == 5
+    assert build["window_class_bytes"] == 2 * 4 * 11 * 8 * 32 * 4
     assert build["global_class_bytes"] == 2 * 1 * 12 * 8 * 32 * 4
     ticks = [e["args"] for e in events
              if e["name"] == "tick" and "attn_pages_window" in e["args"]]

@@ -773,8 +773,8 @@ def test_a_v5e_keeps_a_merged_page_in_the_order_of_its_shape(
     # global K / V, window K / V (768 / 512 / 1,536 / 1,024 columns)
     ((2, 1026, 64, 768), True),
     ((2, 1026, 64, 512), True),
-    ((5, 321, 64, 1536), True),
-    ((5, 321, 64, 1024), True),
+    ((5, 769, 64, 1536), True),
+    ((5, 769, 64, 1024), True),
 ], ids=["k-4x192", "v-4x128", "global-k", "global-v", "window-k", "window-v"])
 def test_a_v5e_keeps_mimo_v2s_pages_in_the_order_of_their_shape_merged(
         v5e_sharding, shape, row_major):
@@ -786,7 +786,7 @@ def test_a_v5e_keeps_mimo_v2s_pages_in_the_order_of_their_shape_merged(
 
 
 @pytest.mark.parametrize("kh,mb,blocks,sink,base", [
-    (8, 5, 5 * 321, True, True),      # a window layer: its ring's table
+    (8, 12, 5 * 769, True, True),     # a window layer: its ring's table
     (4, 78, 2 * 4994, False, False),  # a global layer: the growing chain
 ], ids=["window", "global"])
 def test_ragged_kernel_compiles_at_mimo_v2s_page_classes(
@@ -840,7 +840,7 @@ def test_ragged_kernel_compiles_at_mimo_v2s_page_classes(
 
 
 @pytest.mark.parametrize("mb,blocks,base,merged", [
-    (67, 4 * 2145, True, True),     # a window layer: its ring's table
+    (78, 4 * 2497, True, True),     # a window layer: its ring's table
     (140, 4482, False, True),       # the global layer: the growing chain
     (140, 4482, False, False),      # ... as [BS, K, D] pages: refused
 ], ids=["window", "global", "global-heads-in-rows"])
@@ -943,9 +943,10 @@ def test_mimo_v2_tick_on_a_v5e_reads_both_classes_where_they_lie(v5e_sharding):
     pages = engine.pool.pages
     assert pages.k.shape == (2, 1026, BLOCK, 768)
     assert pages.v.shape == (2, 1026, BLOCK, 512)
-    assert engine.window_blocks == 5
+    # (the default budget, 64 + 2 x 128: a ring of 127 + 320 slots + 1)
+    assert engine.window_blocks == 8
     assert [a.shape for a in pages.window] == [
-        (3, 321, BLOCK, 1536), (3, 321, BLOCK, 1024)]
+        (3, 513, BLOCK, 1536), (3, 513, BLOCK, 1024)]
     ops = _pool_ops(engine, compiled)
     scopes = {v[0] for v in ops.values()}
     assert {"qkv", "kv_write", "attn_global", "attn_window", "o_proj", "mlp",

@@ -483,7 +483,9 @@ def test_tick_arguments_counters_and_scopes(tiny):
                          max_seq_len=64, prefill_chunk=8,
                          cache_dtype=jnp.float32, tracer=tracer)
     assert engine.epilogue_impl == "fused"
-    for i, p in enumerate(_prompts([9, 12], seed=2)):
+    # (both prompts fit the first tick's budget of 20 — a chunk each, then
+    # the leftover — so the two rows decode in step)
+    for i, p in enumerate(_prompts([9, 11], seed=2)):
         engine.submit(p, max_new_tokens=5, seed=i)
     fetches = engine.n_host_fetches
     engine.run_until_complete()

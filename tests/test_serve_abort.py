@@ -76,14 +76,15 @@ def test_abort_queued_request_frees_nothing_and_fires_event(tiny):
 
 
 def test_abort_mid_prefill_returns_all_blocks(tiny):
-    """Abort right after the admission tick — the prompt's first chunk
-    is in the pool, the rest is not, no token has been emitted: the
-    freshly written prefill blocks all come back."""
+    """Abort right after the admission tick — the prompt's first slice
+    (a lone row takes the whole budget, 18 tokens here) is in the pool,
+    the rest is not, no token has been emitted: the freshly written
+    prefill blocks all come back."""
     cfg, params = tiny
     engine = _engine(cfg, params)
     rng = np.random.default_rng(1)
-    req = engine.submit(rng.integers(1, cfg.vocab_size, size=14), 10)
-    engine.step()  # admits + writes the first prefill chunk
+    req = engine.submit(rng.integers(1, cfg.vocab_size, size=30), 10)
+    engine.step()  # admits + writes the first prefill slice
     assert req.state is RequestState.RUNNING
     assert 0 < req.prefill_done < req.prefill_target  # genuinely mid-prefill
     assert not req.generated

@@ -870,7 +870,7 @@ PACK_CASES = {
     "decode+prefill-chunk": dict(lens=(5, 6, 4, 7, 3), new=5, stagger=True),
     "multi-tile-prefill": dict(lens=(4, 29, 21), new=6, stagger=True,
                                prefill_chunk=16),
-    "spec-rows": dict(lens=(12, 9, 5), new=10, spec_k=3),
+    "spec-rows": dict(lens=(12, 9, 5), new=10, spec_k=3, stagger=True),
     "slots-reused-out-of-order": dict(
         lens=(4, 6, 3, 5, 4, 7, 3, 6), new=(2, 9, 4, 7, 3, 5, 8, 2),
         max_slots=3),

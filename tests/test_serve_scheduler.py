@@ -212,7 +212,25 @@ def _simulate_mixed(sched, budget, chunk, shared_done=None,
         # -- invariants, every tick --------------------------------------
         planned = len(decode) + sum(n for _, n in prefill)
         assert planned <= budget, "budget overrun"
-        assert all(1 <= n <= chunk for _, n in prefill), "chunk cap"
+        rows = [r.req_id for r, _ in prefill]
+        assert len(rows) == len(set(rows)), "one segment a row"
+        assert all(1 <= n <= r.prefill_target - r.prefill_done
+                   for r, n in prefill), "a segment stays inside its prompt"
+        # the fair share: every mid-prefill row gets its chunk (or its
+        # tail) while the budget lasts, oldest first ...
+        got = {r.req_id: n for r, n in prefill}
+        room = budget - len(decode)
+        for r in sched.running:
+            if not r.prefilled:
+                share = min(chunk, r.prefill_target - r.prefill_done, room)
+                assert got.get(r.req_id, 0) >= share, "fair share"
+                room -= share
+        # ... and the lane is work-conserving: budget is left unspent
+        # only when no row could use it
+        if planned < budget:
+            assert all(
+                got.get(r.req_id, 0) == r.prefill_target - r.prefill_done
+                for r in sched.running if not r.prefilled), "idle lane"
         # decode rows are NEVER starved: every prefilled running request
         # with a token to feed is in the decode batch
         ready = [r for r in sched.running if r.prefilled and r.generated]
@@ -311,6 +329,24 @@ def test_planner_multiple_prefills_share_budget_oldest_first():
     assert [r.req_id for r in sched.finished] == [0, 1]
 
 
+@pytest.mark.parametrize("budget, ticks", [(9, 16), (33, 4), (121, 2)])
+def test_planner_long_prompt_costs_prompt_over_lane_ticks(budget, ticks):
+    """A long prompt beside a short one that decodes from the second tick
+    on: its first token comes after about prompt / lane ticks, whatever
+    the chunk — a lane of one chunk is the one-chunk-a-tick pace, a lane
+    of the whole prompt two ticks (the first carries the short prompt's
+    4 tokens too)."""
+    sched = _mk(n_blocks=64, slots=2)
+    for r in _requests([(4, 40), (120, 1)]):
+        sched.add(r)
+    records, budgeted = _simulate_mixed(sched, budget=budget, chunk=8)
+    long_ticks = [rec for rec in records if any(
+        rid == 1 for rid, _ in rec[1])]
+    assert len(long_ticks) == ticks == 1 + -(
+        -(120 - (budget - 4)) // (budget - 1))
+    assert budgeted == {0: 4, 1: 120}
+
+
 def test_planner_respects_tiny_budget_progress_guarantee():
     """budget == max_slots is the liveness floor: even with every other
     slot decoding, a mid-prefill row advances at least one token per
@@ -356,10 +392,11 @@ PLAN_CASES = {
     "drafts-are-trimmed-to-nothing-never-the-base-token": (
         [(4, True, 3), (4, True, 2), (20, False, 0)], 4, 8, None,
         [0, 1], [(2, 2)], [0, 0]),
-    # a segment is the chunk, the rest of the prompt or the rest of the
-    # budget, whichever is least
-    "a-chunk-caps-a-segment": (
-        [(20, False, 0)], 64, 8, None, [], [(0, 8)], []),
+    # the fair share is the chunk, the rest of the prompt or the rest of
+    # the budget, whichever is least
+    "a-chunk-caps-the-fair-share": (
+        [(20, False, 0), (20, False, 0)], 12, 8, None,
+        [], [(0, 8), (1, 4)], []),
     "the-prompts-tail-caps-a-segment": (
         [(5, False, 0), (3, False, 0)], 64, 8, None,
         [], [(0, 5), (1, 3)], []),
@@ -371,6 +408,40 @@ PLAN_CASES = {
         [(20, False, 0), (20, False, 0)], 12, 8,
         lambda running: list(reversed(running)),
         [], [(1, 8), (0, 4)], []),
+    # what the fair share leaves goes to the oldest prompt: one segment
+    # a row, its two grants summed
+    "a-lone-row-takes-its-whole-prompt": (
+        [(20, False, 0)], 64, 8, None, [], [(0, 20)], []),
+    "the-leftover-goes-to-the-oldest": (
+        [(40, False, 0), (40, False, 0)], 30, 8, None,
+        [], [(0, 22), (1, 8)], []),
+    "the-leftover-cascades-when-the-oldest-finishes": (
+        [(12, False, 0), (40, False, 0)], 30, 8, None,
+        [], [(0, 12), (1, 18)], []),
+    "rows-that-all-finish-leave-budget-unspent": (
+        [(10, False, 0), (10, False, 0), (40, False, 0)], 64, 8, None,
+        [], [(0, 10), (1, 10), (2, 40)], []),
+    "a-short-prompt-behind-a-long-one-keeps-its-chunk": (
+        [(100, False, 0), (6, False, 0)], 20, 8, None,
+        [], [(0, 14), (1, 6)], []),
+    "the-budget-is-spent-to-the-token-beside-decode-rows": (
+        [(4, True, 0), (100, False, 0), (100, False, 0)], 20, 8, None,
+        [0], [(1, 11), (2, 8)], [0]),
+    # drafts are budgeted between the two passes: they spend what the
+    # fair share left, the leftover pass what the drafts left
+    "drafts-are-budgeted-before-the-leftover": (
+        [(4, True, 3), (40, False, 0)], 20, 8, None,
+        [0], [(1, 16)], [3]),
+    "the-leftover-never-trims-a-draft-that-fits": (
+        [(4, True, 3), (4, True, 3), (40, False, 0)], 16, 8, None,
+        [0, 1], [(2, 8)], [3, 3]),
+    "prefill-order-leads-both-passes": (
+        [(40, False, 0), (40, False, 0)], 30, 8,
+        lambda running: list(reversed(running)),
+        [], [(1, 22), (0, 8)], []),
+    "a-decode-only-tick-is-unchanged": (
+        [(4, True, 0), (4, True, 2)], 16, 8, None,
+        [0, 1], [], [0, 2]),
 }
 
 
@@ -387,6 +458,10 @@ def test_plan_tick_policy(case):
     spent = (len(got_decode) + sum(n for _, n in got_prefill)
              + sum(r.draft_len for r in got_decode))
     assert spent <= budget, "budgets are exact"
+    done = {r.req_id: r.prefill_done + n for r, n in got_prefill}
+    if any(done.get(r.req_id, 0) < r.prefill_target
+           for r in sched.running if not r.prefilled):
+        assert spent == budget, "a row could have used what was left"
 
 
 def test_no_growth_at_exact_block_boundary():

@@ -56,8 +56,10 @@ def test_steady_state_ticks_compile_nothing():
     cfg = tiny_config("llama")
     params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
     engine = _engine(cfg, params)
-    # warm: 1-block and 2-block prefills (block_size=8, chunk=8)
-    _drive(engine, cfg, lens=(4, 12), seed0=0)
+    # warm (block_size=8, chunk=8, budget 18): two prompts in one tick
+    # on the widest rung (4 + 12) and on the middle one (5 + 4), two
+    # decode rows, then a lone prompt and a lone decode row
+    _drive(engine, cfg, lens=(4, 12, 5, 4, 6), seed0=0)
     warm_counts = dict(engine.compile_counts())
 
     counter = CompileCounter()

@@ -82,7 +82,8 @@ def probe():
 
 
 def _engine(cfg, params, attn="pallas", **kw):
-    # (two slots and a budget of 18: three programs to compile, not five)
+    # (two slots and a budget of 18: three programs to compile, not five;
+    # a ring of 5 blocks: the window's 7 slots + a budget-wide slice + 1)
     kw.setdefault("max_slots", 2)
     kw.setdefault("num_blocks", 10)
     kw.setdefault("block_size", 8)
@@ -176,7 +177,7 @@ def test_served_logits_match_the_references_full_forward(tiny, case):
     # both classes back to empty; window blocks went round their rings
     assert stats["allocated"] == 0 and stats["window_blocks_in_use"] == 0
     assert stats["window_blocks_recycled_total"] >= 2
-    assert stats["window_blocks_per_slot"] == engine.window_blocks == 4
+    assert stats["window_blocks_per_slot"] == engine.window_blocks == 5
 
 
 def test_a_bf16_program_is_within_its_tolerance_and_a_bf16_pool_of_float32_is_not(
@@ -221,24 +222,28 @@ def test_the_pool_has_two_page_classes_and_one_manager(tiny):
     engine = _engine(cfg, params)
     pages, rings = engine.pool.pages, engine.pool.window
     # 2 global layers of 1 kv head (K 24 / V 16 wide), 3 window layers of
-    # 2 (K 48 / V 32), both stored merged; 2 slots x a ring of 4 + scratch
+    # 2 (K 48 / V 32), both stored merged; 2 slots x a ring of 5 + scratch
     assert pages.k.shape == (2, 10, 8, 24) and pages.v.shape == (2, 10, 8, 16)
-    assert [a.shape for a in pages.window] == [(3, 9, 8, 48), (3, 9, 8, 32)]
+    assert [a.shape for a in pages.window] == [(3, 11, 8, 48), (3, 11, 8, 32)]
     assert pages.merged and pages.kv_heads == 1 and pages.head_dim == 24
-    assert len(pages.all_arrays()) == 4 and rings.num_blocks == 9
-    # window 8, a slice of 16, blocks of 8: 23 slots span 3 blocks, + 1
+    assert len(pages.all_arrays()) == 4 and rings.num_blocks == 11
+    # window 8, a slice of the budget's 18, blocks of 8: 25 slots span 4
+    # blocks, + 1 (a slice of one chunk of 16 wanted 4)
+    assert engine.window_blocks == window_blocks_per_slot(8, 18, 8) == 5
     assert window_blocks_per_slot(8, 16, 8) == 4
+    # the published widths: window 128 and the cell's budget of 576
+    assert window_blocks_per_slot(128, 576, 64) == 12
     assert window_blocks_per_slot(128, 128, 64) == 5
     # the second table is one more section of the ONE operand
     layout, size = engine._mixed_layouts[engine.mixed_buckets[-1]]
-    assert layout["wtables"][1] == (2, 4) and layout["wfirst"][1] == (2,)
+    assert layout["wtables"][1] == (2, 5) and layout["wfirst"][1] == (2,)
     plain_cfg = tiny_config("qwen2")
     plain = ServeEngine(
         init_params(jax.random.PRNGKey(0), plain_cfg, dtype=jnp.float32),
         plain_cfg, max_slots=2, num_blocks=10, block_size=8, max_seq_len=96,
         prefill_chunk=16, tick_token_budget=18, cache_dtype=jnp.float32)
     plain_layout, plain_size = plain._mixed_layouts[plain.mixed_buckets[-1]]
-    assert "wtables" not in plain_layout and plain_size == size - 2 * 4 - 2
+    assert "wtables" not in plain_layout and plain_size == size - 2 * 5 - 2
     gauges = engine.pool_form_gauges()
     assert gauges["kv_global_block_bytes"] == 8 * 2 * (24 + 16) * 4
     assert gauges["kv_window_block_bytes"] == 8 * 3 * 2 * (24 + 16) * 4
