@@ -136,6 +136,8 @@ from llm_np_cp_tpu.serve.metrics import ServeMetrics
 from llm_np_cp_tpu.serve.prefix_cache import prefix_block_keys
 from llm_np_cp_tpu.serve.request_log import request_record
 from llm_np_cp_tpu.serve.scheduler import (
+    TTFT_COUNTS,
+    TTFT_STAMPS,
     QueueFull,
     Request,
     RequestState,
@@ -2190,8 +2192,16 @@ class ServeEngine:
         trace_id: str | None = None,
         speculative: bool = False,
         tenant: str = "default",
+        received_time: float | None = None,
+        enqueue_time: float | None = None,
         _recovered: bool = False,
     ) -> Request:
+        """Queue one request.  ``received_time`` / ``enqueue_time`` are
+        the HTTP layer's two stamps on this engine's clock (socket
+        accept; the command put into the tick thread's inbox): the first
+        two of the request's way to its first token
+        (scheduler.TTFT_STAMPS), which this call continues with
+        ``submit_time``."""
         prompt = np.asarray(prompt_ids, dtype=np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -2262,6 +2272,8 @@ class ServeEngine:
             # resumes drafting
             speculative=bool(speculative),
             tenant=tenant,
+            received_time=received_time,
+            enqueue_time=enqueue_time,
         )
         req.submit_time = self.clock()
         if deadline_s is not None:
@@ -2335,9 +2347,16 @@ class ServeEngine:
         speculative: bool = False,
         tenant: str = "default",
         weights_version: int | None = None,
+        stamps: dict | None = None,
     ) -> Request:
         """Resubmit a request that was in flight when a previous engine
         instance died, with its already-delivered tokens teacher-forced.
+
+        ``stamps`` (``scheduler.first_stamps`` of the request's earlier
+        life, taken on a clock this engine shares — ``clone_fresh``
+        does) puts the FIRST stamps and tick counts of its way to the
+        first token back, as a preemption requeue keeps them: the stages
+        then cover the restart instead of starting anew.
 
         ``trace_id`` continues the request's ORIGINAL W3C trace (a
         replay is a link in the same trace, never a fresh one);
@@ -2382,6 +2401,9 @@ class ServeEngine:
         )
         if deadline_at is not None:
             req.deadline = deadline_at
+        for name, value in (stamps or {}).items():
+            if name in TTFT_STAMPS or name in TTFT_COUNTS:
+                setattr(req, name, value)
         req.generated = [int(t) for t in generated]
         if weights_version is not None:
             # the ORIGINAL admission's weight version, not this engine's:
@@ -2796,12 +2818,22 @@ class ServeEngine:
                 if kind == _OWED_FINISH:
                     self._publish_finish(req, val)
                     continue
+                t_emit = None
+                if kind == _OWED_FIRST and req.first_emit_time is None:
+                    # the emit, stamped BEFORE the callback: where the
+                    # way to the first token ends on the tick thread
+                    req.first_emit_time = t_emit = self.clock()
                 self._publish_token(req, val)
                 if (kind == _OWED_FIRST and self.tracer is not None
                         and req.state is RequestState.RUNNING):
                     # (not finished on it, not aborted from its callback,
-                    # not preempted back into the queue since)
-                    self.tracer.request_phase(req.req_id, "decode")
+                    # not preempted back into the queue since); ``decode``
+                    # begins AT the emit, ahead of the frame it stands
+                    # for (a re-prefill's: now)
+                    self.tracer.request_phase(
+                        req.req_id, "decode",
+                        ts_us=(self.tracer.us_at(t_emit)
+                               if t_emit is not None else None))
         finally:
             self._publishing = False
         if not whole:
@@ -3137,7 +3169,80 @@ class ServeEngine:
             self.metrics.on_prefix(
                 requested=len(keys), hits=req.n_shared_blocks
             )
+        first = req.first_token_time is None
         self._accept(req, tok, _OWED_FIRST)
+        if first and self.tracer is not None:
+            # the accept of the first token, with the number of the
+            # dispatch that sampled it (the tick's and its two profiler
+            # annotations' ``seq``)
+            self.tracer.request_instant(
+                req.req_id, "first_token",
+                ts_us=self.tracer.us_at(req.first_token_time),
+                args={"seq": self.n_mixed_dispatches})
+
+    def _note_prefill_grants(
+        self, prefill_segs: list[tuple[Request, int]],
+    ) -> tuple[int, int, int]:
+        """The plan's prompt grants, read for what they say of the lane:
+        a row's fair share of a tick is ``min(prefill_chunk, remaining)``
+        and what its grant holds beyond that is leftover of the prompt
+        lane (``plan_tick``'s second pass).  Returns the leftover handed
+        out, the rows that received it (0: a decode-only or
+        fair-share-only tick) and the mid-prefill rows granted nothing.
+
+        On the way it keeps each row's tick counts and stamps its
+        ``lane_time`` / ``last_chunk_time`` (scheduler.TTFT_STAMPS) —
+        ONE clock read a tick in which a row takes the lane or its last
+        chunk, none otherwise; a row past its first token (a re-prefill
+        after preemption) keeps what it has.  Per mid-prefill row, never
+        per decode row: the starved walk runs only in a tick that
+        granted some row nothing."""
+        chunk = self.prefill_chunk
+        now = None
+        lane_tokens = lane_rows = 0
+        for r, n in prefill_segs:
+            left = r.prefill_target - r.prefill_done
+            over = n - min(chunk, left)
+            if over > 0:
+                lane_tokens += over
+                lane_rows += 1
+            if r.first_token_time is not None:
+                continue
+            r.prefill_ticks += 1
+            if over > 0:
+                r.lane_ticks += 1
+            if r.lane_time is None and (over > 0 or n >= left):
+                r.lane_time = now = self.clock() if now is None else now
+                if self.tracer is not None:
+                    self.tracer.request_instant(
+                        r.req_id, "lane", ts_us=self.tracer.us_at(now),
+                        args={
+                            # mid-prefill rows older than this one when
+                            # it was admitted, and the prompt tokens it
+                            # was granted before it got the lane
+                            "rows_ahead": r.extra.pop("rows_ahead", 0),
+                            "fair_tokens": r.prefill_done - max(
+                                r.n_shared_blocks * self.block_size
+                                - r.pad, 0),
+                        })
+            if n >= left and r.last_chunk_time is None:
+                r.last_chunk_time = now = self.clock() if now is None else now
+                if self.tracer is not None:
+                    self.tracer.request_instant(
+                        r.req_id, "last_chunk", ts_us=self.tracer.us_at(now),
+                        args={"prefill_ticks": r.prefill_ticks,
+                              "lane_ticks": r.lane_ticks,
+                              "starved_ticks": r.starved_ticks,
+                              # the dispatch this plan becomes
+                              "seq": self.n_mixed_dispatches + 1})
+        starved = self.scheduler.n_mid_prefill - len(prefill_segs)
+        if starved > 0:
+            granted = {id(r) for r, _ in prefill_segs}
+            for r in self.scheduler.running:
+                if (not r.prefilled and r.first_token_time is None
+                        and id(r) not in granted):
+                    r.starved_ticks += 1
+        return lane_tokens, lane_rows, starved
 
     def _draft_tick(self) -> int:
         """Propose draft tokens for every speculating decode row —
@@ -3245,6 +3350,12 @@ class ServeEngine:
             self._tier_restore_us = 0.0
         self._sweep_deadlines()
         admitted = self.scheduler.admit()
+        # mid-prefill rows older than the first of these admissions (the
+        # request track's ``lane`` instant says how many stood ahead)
+        rows_ahead = (
+            sum(1 for r in self.scheduler.running if not r.prefilled)
+            - len(admitted)
+            if admitted and self.tracer is not None else 0)
         for req in admitted:
             if req.admit_time is None:
                 req.admit_time = self.clock()
@@ -3256,6 +3367,9 @@ class ServeEngine:
             self._enqueue_tier_restores(req)
             self._init_mixed_prefill(req)
             if self.tracer is not None:
+                if req.lane_time is None:
+                    req.extra["rows_ahead"] = rows_ahead
+                rows_ahead += 1
                 self.tracer.request_phase(
                     req.req_id, "prefill", args=self._targs(
                         req, shared_blocks=req.n_shared_blocks,
@@ -3285,6 +3399,9 @@ class ServeEngine:
                 else None
             ),
         )
+        lane_tokens, lane_rows, starved_rows = (
+            self._note_prefill_grants(prefill_segs)
+            if self.scheduler.n_mid_prefill else (0, 0, 0))
         t3 = (self._phase_mark("serve.pack")
               if self.tracer is not None else -1.0)
 
@@ -3505,6 +3622,8 @@ class ServeEngine:
             prefill_rows=len(prefill_segs),
             dense_lanes=dense_width,
             host_bound=device_done,
+            lane_tick=lane_rows > 0,
+            starved_rows=starved_rows,
         )
         if expert_load is not None:
             worst = expert_load[int(np.argmax(expert_load.max(axis=1)))]
@@ -3596,6 +3715,11 @@ class ServeEngine:
                 # the mid-prefill rows the prompt tokens went to (one
                 # segment a row: plan_tick)
                 "prefill_rows": len(prefill_segs),
+                # the tick's kind: the leftover of the prompt lane handed
+                # out beyond the rows' fair shares and the rows that got
+                # it (0 = a decode-only or fair-share-only tick)
+                "lane_tokens": lane_tokens,
+                "lane_rows": lane_rows,
                 # the dispatch as the device sees it: the live context
                 # its rows attend (summed over rows), and the program —
                 # the width inside attention (tile lanes) and the width

@@ -77,6 +77,11 @@ class CompletionPayload:
     # generated by the server) and the router's verdict for this submit
     trace_id: str | None = None
     route_spilled: bool = False
+    # the first two stamps of the request's way to its first token, on
+    # the engine's clock: socket accept (the handler) and the command's
+    # put into the tick thread's inbox (``EngineRunner.submit``)
+    received_time: float | None = None
+    enqueue_time: float | None = None
 
 
 def parse_completion_request(

@@ -74,7 +74,11 @@ from llm_np_cp_tpu.serve.http.protocol import (
 )
 from llm_np_cp_tpu.serve.http.sse import DONE_SENTINEL, sse_event
 from llm_np_cp_tpu.serve.metrics import ServeMetrics
-from llm_np_cp_tpu.serve.scheduler import QueueFull, TenantThrottled
+from llm_np_cp_tpu.serve.scheduler import (
+    QueueFull,
+    TenantThrottled,
+    first_stamps,
+)
 from llm_np_cp_tpu.serve.tracing import (
     gen_trace_id,
     make_traceparent,
@@ -339,6 +343,7 @@ class EngineRunner:
                 speculative=bool(rec.get("spec", False)),
                 tenant=rec.get("tenant", "default"),
                 weights_version=rec.get("wv"),
+                stamps=rec.get("stamps"),
             )
         except Exception as e:  # noqa: BLE001 — per-request fate
             # a request the rebuilt pool cannot re-admit fails alone,
@@ -476,6 +481,14 @@ class EngineRunner:
     def submit(self, rid: int, payload: Any,
                loop: asyncio.AbstractEventLoop, aq: asyncio.Queue) -> None:
         self._live[rid] = (loop, aq)
+        # the command enters the tick thread's inbox: from here to the
+        # engine's ``submit_time`` the request waits for the running
+        # tick to end (stage ``inbox_wait``; the payload carries the
+        # stamp, with the handler's ``received_time``, into ``submit``)
+        payload.enqueue_time = t_enq = self.engine.clock()
+        tr = getattr(self.engine, "tracer", None)
+        if tr is not None:
+            tr.request_instant(rid, "enqueued", ts_us=tr.us_at(t_enq))
         self._cmds.put(("submit", rid, payload))
         # crash race: if the tick thread died terminally between the
         # handler's pre-check and this registration, its backstop flush
@@ -607,6 +620,8 @@ class EngineRunner:
                     trace_id=getattr(payload, "trace_id", None),
                     speculative=getattr(payload, "speculative", False),
                     tenant=getattr(payload, "tenant", "default"),
+                    received_time=getattr(payload, "received_time", None),
+                    enqueue_time=getattr(payload, "enqueue_time", None),
                 )
             except TenantThrottled as e:
                 # same 429 + Retry-After contract as a full queue, but
@@ -946,6 +961,12 @@ class EngineRunner:
         for rec in replay:
             if gen != self._gen:
                 return  # superseded mid-replay — the newer thread redoes it
+            # the rebuilt engine shares the old one's clock: a replayed
+            # request keeps the first stamps of its way to its first
+            # token, as a preemption requeue does
+            prev = old._requests.get(rec["rid"])
+            if prev is not None:
+                rec["stamps"] = first_stamps(prev)
             # an upgrade's leftover streams keep generating detached (a
             # journal-recovered client may attach later); a crash
             # restart's streams must have a live client
@@ -1298,13 +1319,13 @@ class HttpServer:
         # request spans start AT SOCKET ACCEPT: time spent reading and
         # parsing the request is part of what the client experiences,
         # and must be separable from engine queue wait in the trace.
-        # -1 sentinel (engine.step discipline): if the tracer appears
-        # only AFTER accept (the supervised-restart mute window), the
-        # span must not start at the trace epoch
-        tracer = self.tracer
-        t_accept = tracer.now_us() if tracer is not None else -1.0
+        # One read of the engine's clock serves the span and the
+        # request's ``received_time`` stamp (``TraceRecorder.us_at`` puts
+        # it on the trace's axis, also for a recorder that appears only
+        # AFTER accept: the supervised-restart mute window)
+        t_recv = self.runner.engine.clock()
         try:
-            await self._handle(reader, writer, t_accept)
+            await self._handle(reader, writer, t_recv)
         except (ConnectionResetError, BrokenPipeError, asyncio.TimeoutError):
             pass
         finally:
@@ -1316,7 +1337,7 @@ class HttpServer:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter,
-                      t_accept: float = -1.0) -> None:
+                      t_recv: float | None = None) -> None:
         try:
             method, path, headers, body = await asyncio.wait_for(
                 self._read_request(reader), timeout=30.0,
@@ -1408,7 +1429,7 @@ class HttpServer:
                     405, "use POST for /v1/completions"))
             else:
                 await self._completions(reader, writer, body, headers,
-                                        t_accept)
+                                        t_recv)
         elif path.startswith("/v1/completions/"):
             # stream resume by id: GET /v1/completions/cmpl-N with a
             # Last-Event-ID header replays the journaled suffix over
@@ -1425,7 +1446,7 @@ class HttpServer:
                 await self._respond_error(writer, e)
                 return
             await self._resume(reader, writer, rid, last_idx,
-                               self.model_id, t_accept)
+                               self.model_id, t_recv)
         else:
             await self._respond_error(writer, HTTPError(
                 404, f"no route for {method} {path}"))
@@ -1721,7 +1742,7 @@ class HttpServer:
     async def _completions(self, reader: asyncio.StreamReader,
                            writer: asyncio.StreamWriter,
                            body: bytes, headers: dict[str, str],
-                           t_accept: float = -1.0) -> None:
+                           t_recv: float | None = None) -> None:
         if self.draining or self.runner.crashed:
             msg = ("engine tick thread crashed: " + self.runner.crashed
                    if self.runner.crashed
@@ -1752,7 +1773,7 @@ class HttpServer:
                 # the other)
                 rid, last_idx, echo_model = resume
                 await self._resume(reader, writer, rid, last_idx,
-                                   echo_model, t_accept)
+                                   echo_model, t_recv)
                 return
             # 503-first load shedding (serve/lifecycle.ActionPolicy):
             # when the SLO error budget burns past threshold, FRESH
@@ -1784,6 +1805,9 @@ class HttpServer:
         # fresh trace, never a 400)
         ctx = parse_traceparent(headers.get("traceparent"))
         payload.trace_id = ctx[0] if ctx is not None else gen_trace_id()
+        # socket accept, on the engine's clock: the first stamp of the
+        # request's way to its first token (scheduler.TTFT_STAMPS)
+        payload.received_time = t_recv
 
         loop = asyncio.get_running_loop()
         aq: asyncio.Queue = asyncio.Queue()
@@ -1792,11 +1816,10 @@ class HttpServer:
         if tracer is not None:
             # the http bracket span: accept → response done, enclosing
             # the engine's queued/prefill/decode spans on the same
-            # track.  t_accept < 0 means the tracer appeared after
-            # accept (restart mute window) — begin at now, not at the
-            # trace epoch
+            # track; it begins AT the request's ``received_time``
             tracer.async_begin(rid, "http",
-                               ts_us=t_accept if t_accept >= 0.0 else None,
+                               ts_us=(tracer.us_at(t_recv)
+                                      if t_recv is not None else None),
                                args={"stream": bool(payload.stream),
                                      "trace": payload.trace_id})
         try:
@@ -1863,7 +1886,7 @@ class HttpServer:
     async def _resume(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter, rid: int,
                       last_idx: int, echo_model: str,
-                      t_accept: float = -1.0) -> None:
+                      t_recv: float | None = None) -> None:
         """Re-attach a dropped SSE stream (serve/journal.py resume
         protocol): replay the delivered-token suffix from the client's
         Last-Event-ID, then continue live.  404 when the id is unknown
@@ -1881,7 +1904,8 @@ class HttpServer:
         tracer = self.tracer
         if tracer is not None:
             tracer.async_begin(rid, "http",
-                               ts_us=t_accept if t_accept >= 0.0 else None,
+                               ts_us=(tracer.us_at(t_recv)
+                                      if t_recv is not None else None),
                                args={"resume": True,
                                      "last_event_id": last_idx})
         try:

@@ -31,13 +31,29 @@ flat dict (``snapshot()``) so the CLI, bench.py, tests, and the HTTP
                              exact byte/time ledgers per-request cost
                              attribution sums back to (present only
                              when a TelemetryModel is attached)
-- ``queue_wait_s_*`` / ``prefill_s_*`` — per-request phase splits
-                             (submit → first admission; cumulative
-                             prefill dispatch time incl. re-prefills),
-                             derived from the same timestamps that feed
-                             the request spans in serve/tracing.py — so
-                             a scrape answers "queueing or compute?"
-                             without a trace file.
+- ``queue_wait_s_*``       — the wait for a SLOT, counted from the
+                             moment the tick thread took the command
+                             (``submit_time`` is stamped between two
+                             ticks) to the first admission: the wait for
+                             the running tick to end is NOT in it
+- ``prefill_s_*``          — a tick's dispatch + sync wall shared out by
+                             token count, summed over the request's
+                             prefill segments (re-prefills included): a
+                             cost share, not a latency
+- ``ttft_stage_<stage>_s_*`` — the latencies: a request's way to its
+                             first token cut into consecutive stages
+                             (scheduler.TTFT_STAGES: ``parse``,
+                             ``inbox_wait``, ``slot_wait``,
+                             ``lane_wait``, ``prefill``, ``final_tick``,
+                             ``publish_lag``) from stamps taken where
+                             the work happens, the same ones the request
+                             track's instants sit at — so a scrape says
+                             where a slow first token waited, without a
+                             trace file.  ``lane_ticks`` (dispatching
+                             ticks that handed a prompt leftover of the
+                             lane) and ``prefill_starved_rows``
+                             (mid-prefill rows a tick granted nothing)
+                             count the lane beside them.
 
 Percentiles are p50/p90/p99 over whatever was recorded — no windowing.
 
@@ -67,7 +83,7 @@ from typing import Any
 
 import numpy as np
 
-from llm_np_cp_tpu.serve.scheduler import Request
+from llm_np_cp_tpu.serve.scheduler import TTFT_STAGES, Request, ttft_stages
 
 # Fixed histogram buckets (upper bounds, seconds / tokens-per-second).
 # Fixed so series are comparable across runs and joinable across
@@ -139,10 +155,15 @@ class ServeMetrics:
         self.finish_reasons: Counter[str] = Counter()
         self.ttft_s: list[float] = []
         self.decode_tok_s: list[float] = []
-        # per-request phase splits (queueing vs compute), recorded at
-        # terminal time from Request.admit_time / Request.prefill_s
+        # the wait for a slot (from the tick thread's take of the
+        # command) and the prefill cost share, recorded at terminal time
+        # from Request.admit_time / Request.prefill_s
         self.queue_wait_s: list[float] = []
         self.prefill_s: list[float] = []
+        # ...and the latencies: the stages of the way to the first
+        # token, of requests that emitted one (scheduler.ttft_stages)
+        self.ttft_stage_s: dict[str, list[float]] = {
+            stage: [] for stage in TTFT_STAGES}
         # exact cumulative histogram state (never trimmed): per-bucket
         # increments + running sum; bucket i counts values <= bucket[i],
         # the trailing slot is the +Inf overflow
@@ -179,6 +200,11 @@ class ServeMetrics:
         # a tick: prefill tokens / segments is what a row gets of a tick
         # (a chunk where rows share the lane, the lane where one has it)
         self.prefill_segments = 0
+        # ...dispatching ticks that handed a row more than its fair
+        # share (the prompt lane's leftover), and mid-prefill rows a
+        # tick granted nothing, summed over ticks
+        self.lane_ticks = 0
+        self.prefill_starved_rows = 0
         # ...and the lanes of the step's dense token axis it was
         # dispatched at, summed over dispatches: tokens / lanes is the
         # share of the matmuls' rows that hold a token
@@ -286,11 +312,14 @@ class ServeMetrics:
         prefill_tokens: int = 0, decode_tokens: int = 0,
         prefill_rows: int = 0,
         dense_lanes: int = 0, host_bound: bool = False,
+        lane_tick: bool = False, starved_rows: int = 0,
     ) -> None:
         with self._lock:
             self.host_bound_ticks += host_bound
             self.mixed_prefill_tokens += prefill_tokens
             self.prefill_segments += prefill_rows
+            self.lane_ticks += lane_tick
+            self.prefill_starved_rows += starved_rows
             self.mixed_decode_tokens += decode_tokens
             self.mixed_dense_lanes += dense_lanes
             self.n_ticks += 1
@@ -514,6 +543,10 @@ class ServeMetrics:
         if req.prefill_s:
             self.prefill_s.append(req.prefill_s)
             self._trim(self.prefill_s)
+        if req.first_emit_time is not None:
+            for stage, seconds in ttft_stages(req).items():
+                self.ttft_stage_s[stage].append(seconds)
+                self._trim(self.ttft_stage_s[stage])
 
     # ------------------------------------------------------------------
     def snapshot(self) -> dict[str, Any]:
@@ -540,6 +573,7 @@ class ServeMetrics:
             decode = list(self.decode_tok_s)
             qwait = list(self.queue_wait_s)
             prefill = list(self.prefill_s)
+            stages = {k: list(v) for k, v in self.ttft_stage_s.items()}
             qd = [float(q) for q in self.queue_depth]
             occ = list(self.occupancy)
             act = [float(a) for a in self.active_slots]
@@ -563,6 +597,8 @@ class ServeMetrics:
             out["mixed_prefill_tokens"] = self.mixed_prefill_tokens
             out["mixed_decode_tokens"] = self.mixed_decode_tokens
             out["prefill_segments"] = self.prefill_segments
+            out["lane_ticks"] = self.lane_ticks
+            out["prefill_starved_rows"] = self.prefill_starved_rows
             out["mixed_dense_lanes"] = self.mixed_dense_lanes
             out["publish_overlapped_ticks"] = self.publish_overlapped
             out["publish_immediate_ticks"] = self.publish_immediate
@@ -640,6 +676,8 @@ class ServeMetrics:
         out.update(_pcts(decode, "decode_tok_s"))
         out.update(_pcts(qwait, "queue_wait_s"))
         out.update(_pcts(prefill, "prefill_s"))
+        for stage, vals in stages.items():
+            out.update(_pcts(vals, f"ttft_stage_{stage}_s"))
         out.update(_pcts(qd, "queue_depth"))
         out.update(_pcts(occ, "occupancy"))
         out.update(_pcts(act, "active_slots"))
@@ -787,6 +825,15 @@ class ServeMetrics:
              "deltas reads it): tokens / segments is what a row gets of "
              "one tick",
              [("", s["mixed_prefill_tokens"])])
+        emit("lane_ticks_total", "counter",
+             "Dispatching ticks that handed a mid-prefill row more than "
+             "its fair share of the prompt lane (min(chunk, remaining)): "
+             "lane_ticks_total / ticks_total is how busy the lane was",
+             [("", s["lane_ticks"])])
+        emit("prefill_starved_rows_total", "counter",
+             "Mid-prefill rows a tick granted no prompt token, summed "
+             "over ticks (the budget ran out before their fair share)",
+             [("", s["prefill_starved_rows"])])
         emit("mixed_dense_lanes_total", "counter",
              "Lanes of the unified step's dense token axis, summed over "
              "dispatches (mixed_tokens_total / this = the share of "
@@ -1024,17 +1071,21 @@ class ServeMetrics:
 
         # -- trace-wide quantile gauges alongside the histograms (the
         # single-process view; percentile windows, see max_samples) and
-        # the per-request phase split — "queueing or compute?" straight
-        # off the scrape, no trace file needed
+        # the per-request splits, no trace file needed: the wait for a
+        # slot, the prefill cost share, and below them the stage family
+        quantiles = (("0.5", "p50"), ("0.9", "p90"), ("0.99", "p99"))
         for base, help_ in (
             ("ttft_s", "TTFT quantiles over the recorded window"),
             ("decode_tok_s",
              "Decode-rate quantiles over the recorded window"),
             ("queue_wait_s",
-             "Submit to first admission into a decode slot, per request"),
+             "The wait for a decode slot per request: the tick thread's "
+             "take of the command to first admission (the wait for the "
+             "running tick is ttft_stage inbox_wait)"),
             ("prefill_s",
-             "Cumulative prefill dispatch time per request "
-             "(re-prefills after preemption/recovery included)"),
+             "A request's token share of its prefill ticks' dispatch + "
+             "sync wall, re-prefills included: a cost share, not a "
+             "latency (see ttft_stage_seconds_quantile)"),
             ("tier_restore_s",
              "Host-tier restore staging latency per restored span"),
             ("roofline_gbps",
@@ -1045,11 +1096,22 @@ class ServeMetrics:
              "dispatch window"),
         ):
             samples = [(f'{{quantile="{q}"}}', s[f"{base}_{p}"])
-                       for q, p in (("0.5", "p50"), ("0.9", "p90"),
-                                    ("0.99", "p99"))
-                       if f"{base}_{p}" in s]
+                       for q, p in quantiles if f"{base}_{p}" in s]
             if samples:
                 emit(f"{base}_quantile", "gauge", help_, samples)
+        samples = [(f'{{stage="{stage}",quantile="{q}"}}',
+                    s[f"ttft_stage_{stage}_s_{p}"])
+                   for stage in TTFT_STAGES for q, p in quantiles
+                   if f"ttft_stage_{stage}_s_{p}" in s]
+        if samples:
+            emit("ttft_stage_seconds_quantile", "gauge",
+                 "A request's way to its first token in consecutive "
+                 "stages on the engine clock: parse (socket accept to "
+                 "the tick thread's inbox), inbox_wait (the running "
+                 "tick), slot_wait (= queue_wait_s), lane_wait "
+                 "(admission to the prompt lane), prefill (lane to the "
+                 "last chunk's tick), final_tick (that tick to the "
+                 "accept), publish_lag (accept to the emit)", samples)
         for kind, extras in (("gauge", extra_gauges),
                              ("counter", extra_counters)):
             for key, value in (extras or {}).items():
@@ -1115,6 +1177,9 @@ class ServeMetrics:
             f"p99 {g('queue_wait_s_p99')}; "
             f"prefill_s p50 {g('prefill_s_p50')}  "
             f"p99 {g('prefill_s_p99')}\n"
+            "first token by stage, p50 s: " + "  ".join(
+                f"{stage} {g(f'ttft_stage_{stage}_s_p50', '{:.4f}')}"
+                for stage in TTFT_STAGES) + "\n"
             f"decode_tok_s p50 {g('decode_tok_s_p50', '{:.1f}')}  "
             f"p90 {g('decode_tok_s_p90', '{:.1f}')}\n"
             f"queue_depth p50 {g('queue_depth_p50', '{:.1f}')}  "

@@ -17,10 +17,16 @@ needs in one place:
 - survival      — ``preemptions`` (evict-requeue), ``replays``
   (supervised-restart / journal recoveries), ``drains`` (adoptions by
   a peer after a replica went terminally dark);
-- latency       — the per-phase breakdown (``queue_wait_s``,
-  ``prefill_s``, ``ttft_s``, ``decode_s``, ``total_s``) from the same
-  Request timestamps that feed the trace spans, so log and trace agree
-  by construction;
+- latency       — the per-phase breakdown (``queue_wait_s``: the wait
+  for a slot from the tick thread's take of the command; ``prefill_s``:
+  the request's token share of its prefill ticks' wall, a cost share;
+  ``ttft_s``, ``decode_s``, ``total_s``) and, for a request that emitted
+  a token, ``ttft_stages``: its way to the first token cut into
+  consecutive stages (scheduler.TTFT_STAGES, seconds) with the ticks it
+  took (``prefill_ticks``, of them ``lane_ticks`` with leftover of the
+  prompt lane, ``starved_ticks`` granted nothing) — all from the same
+  Request stamps the trace's spans and instants sit at, so log and
+  trace agree by construction;
 - outcome       — ``reason`` (stop/length/aborted), token counts, and
   the ``slo`` verdict (when a policy is configured);
 - cost          — device-cost attribution (serve/telemetry.py, when a
@@ -46,6 +52,8 @@ import json
 import threading
 import time
 from typing import Any, Callable
+
+from llm_np_cp_tpu.serve.scheduler import TTFT_COUNTS, ttft_stages
 
 
 def request_record(
@@ -96,6 +104,10 @@ def request_record(
             phases["ttft_s"] = req.first_token_time - base
         phases["decode_s"] = finish - req.first_token_time
     rec["phases"] = {k: round(v, 6) for k, v in phases.items()}
+    if req.first_emit_time is not None:
+        rec["ttft_stages"] = {
+            k: round(v, 6) for k, v in ttft_stages(req).items()}
+        rec.update({name: getattr(req, name) for name in TTFT_COUNTS})
     if req.device_time_s or req.kv_bytes_read or req.weight_bytes_amortized:
         # device-cost attribution (serve/telemetry.py): the request's
         # exact KV traffic plus its token-share of streamed weights and

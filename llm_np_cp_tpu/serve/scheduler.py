@@ -158,15 +158,50 @@ class Request:
     weight_bytes_amortized: float = 0.0
     device_time_s: float = 0.0
     # -- metrics timestamps -------------------------------------------
+    # The way to the first token, cut where the work happens: eight
+    # stamps on the engine clock, each taken ONCE (a preemption requeue
+    # keeps the first, as ``admit_time`` always did), consecutive by
+    # construction — ``ttft_stages`` turns them into the stage family
+    # (``TTFT_STAGES``) that /metrics, the request log and the request
+    # track report.  ``received_time`` (socket accept) and
+    # ``enqueue_time`` (the command handed to the tick thread's inbox)
+    # are the HTTP layer's, carried in through ``ServeEngine.submit``;
+    # a direct-mode request has neither.
+    received_time: float | None = None
+    enqueue_time: float | None = None
+    # stamped when the TICK THREAD takes the command between two ticks,
+    # not when the request arrived: the wait for the running tick to end
+    # lies before it (stage ``inbox_wait``)
     submit_time: float | None = None
-    # first admission into a decode slot (queue_wait_s = admit_time -
-    # submit_time; preemption requeues keep the FIRST admission — the
-    # user-visible wait ended when work first started)
+    # first admission into a decode slot.  queue_wait_s = admit_time -
+    # submit_time is therefore the wait for a SLOT alone (stage
+    # ``slot_wait``), counted from the moment the tick thread took the
+    # command; preemption requeues keep the FIRST admission — the
+    # user-visible wait ended when work first started
     admit_time: float | None = None
-    # cumulative wall time spent in prefill dispatch for this request
-    # (re-prefills after preemption/recovery add to it)
+    # the plan of the first tick that handed this row more than its fair
+    # share of the prompt lane (``min(prefill_chunk, remaining)``), or
+    # that completes its prompt; and the plan of the tick whose dispatch
+    # carries its last prompt token.  A prompt of one chunk reads both
+    # in its only tick: ONE clock reading, stage ``prefill`` 0
+    lane_time: float | None = None
+    last_chunk_time: float | None = None
+    # a tick's dispatch + sync wall shared out by token count, summed
+    # over this request's prefill segments (re-prefills after
+    # preemption / recovery add to it): a COST share, not a latency —
+    # the latencies are the stages
     prefill_s: float = 0.0
+    # the accept of the first token (tick N's fetch) ...
     first_token_time: float | None = None
+    # ... and its publish, just before the callback (behind tick N+1's
+    # dispatch, or on the spot where no tick follows)
+    first_emit_time: float | None = None
+    # ticks on the way to the first token: planned with a grant, of
+    # those the ones whose grant held leftover of the lane, and
+    # mid-prefill ticks that granted this row nothing
+    prefill_ticks: int = 0
+    lane_ticks: int = 0
+    starved_ticks: int = 0
     finish_time: float | None = None
     extra: dict[str, Any] = dataclasses.field(default_factory=dict)
 
@@ -196,6 +231,44 @@ class Request:
         return np.concatenate(
             [self.prompt, np.asarray(self.generated, dtype=np.int32)]
         )
+
+
+# A request's way to its first token as consecutive stamps on the engine
+# clock, and the stage each pair of neighbours bounds.  The always-on
+# family ends at ``first_emit_time``; the eighth stage (``write_lag``,
+# first emit -> first SSE frame written) needs the loop thread's stamp,
+# which exists with a trace recorder only (``first_write``).
+TTFT_STAMPS = (
+    "received_time", "enqueue_time", "submit_time", "admit_time",
+    "lane_time", "last_chunk_time", "first_token_time", "first_emit_time",
+)
+TTFT_STAGES = (
+    "parse", "inbox_wait", "slot_wait", "lane_wait", "prefill",
+    "final_tick", "publish_lag",
+)
+TTFT_COUNTS = ("prefill_ticks", "lane_ticks", "starved_ticks")
+
+
+def ttft_stages(req: Request) -> dict[str, float]:
+    """Stage -> seconds, for every stage both of whose stamps the request
+    has: the stages of a request the HTTP layer brought in sum to
+    ``first_emit_time - received_time``."""
+    stamps = [getattr(req, name) for name in TTFT_STAMPS]
+    return {
+        stage: t1 - t0
+        for stage, t0, t1 in zip(TTFT_STAGES, stamps, stamps[1:])
+        if t0 is not None and t1 is not None
+    }
+
+
+def first_stamps(req: Request) -> dict[str, float | int]:
+    """The stamps a request has and its tick counts: what
+    ``ServeEngine.recover(stamps=)`` puts back on the request's next life
+    in a rebuilt engine that shares the clock."""
+    out = {name: getattr(req, name) for name in TTFT_STAMPS
+           if getattr(req, name) is not None}
+    out.update({name: getattr(req, name) for name in TTFT_COUNTS})
+    return out
 
 
 class Scheduler:
@@ -249,6 +322,9 @@ class Scheduler:
         self.aborted: list[Request] = []
         self._free_slots: list[int] = list(range(max_slots - 1, -1, -1))
         self.n_preemptions = 0
+        # mid-prefill rows the last ``plan_tick`` saw, granted or not
+        # (the engine's stage walk tells a starved row by it)
+        self.n_mid_prefill = 0
 
     # ------------------------------------------------------------------
     @property
@@ -361,6 +437,7 @@ class Scheduler:
             else prefill_order(self.running)
         )
         waiting = [r for r in candidates if not r.prefilled]
+        self.n_mid_prefill = len(waiting)
         grants = []  # the fair share: a chunk a row while the budget lasts
         for r in waiting:
             n = max(min(max_chunk, r.prefill_target - r.prefill_done, left), 0)

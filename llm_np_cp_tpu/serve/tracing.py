@@ -19,6 +19,20 @@ dump at ui.perfetto.dev or chrome://tracing):
   to the socket: a ``first_write`` instant when the first SSE frame of
   the request has been written, and, where it closes ``http``, a
   ``stream_end`` instant with the emit-to-write lag of its frames.
+  Between ``http`` and ``first_write`` the way to the first token is cut
+  where the work happens, at the stamps ``Request`` keeps on the engine
+  clock (scheduler.TTFT_STAMPS; ``us_at`` puts each on this axis, so
+  one clock read serves the counter and the span): ``http`` begins at
+  ``received_time``, instants ``enqueued`` (the command in the tick
+  thread's inbox), ``lane`` (the plan of the first tick that gave the
+  row leftover of the prompt lane or completed its prompt; args
+  ``rows_ahead``, ``fair_tokens``), ``last_chunk`` (the plan of the
+  tick that carries its last prompt token; args the three tick counts
+  and the dispatch's ``seq``), ``first_token`` (the accept; args
+  ``seq``), and ``decode`` begins at ``first_emit_time``, just BEFORE
+  the first token's callback.  A ``seq`` is the one the tick and its
+  two profiler annotations carry: a request's final tick is found on
+  the device's line by it.
 - **per-tick phase spans** — complete events (``ph`` X) on the engine
   tick thread: one slice a phase of ``MIXED_TICK_PHASES`` (``admission``
   … ``mixed_dispatch`` … ``host_sync`` … ``account``) nested under
@@ -104,7 +118,10 @@ REQUEST_PHASES = ("queued", "prefill", "decode")
 # (tokens into the requests, finish decided, slots and blocks released),
 # ``account`` the metrics of the tick.  Tick args ``publish_rows`` /
 # ``publish_overlapped`` say what ``deliver`` handed out and whether a
-# dispatch was in flight.
+# dispatch was in flight; ``lane_tokens`` / ``lane_rows`` say which KIND
+# of tick it was — the leftover of the prompt lane handed out beyond the
+# rows' fair shares and the rows that got it (0 rows: a decode-only or
+# fair-share-only tick).
 MIXED_TICK_PHASES = (
     "admission", "draft", "grow", "plan", "pack", "h2d", "mixed_dispatch",
     "deliver", "host_sync", "accept", "account",
@@ -437,11 +454,13 @@ class TraceRecorder:
         })
 
     def request_phase(self, rid: int, phase: str, *,
+                      ts_us: float | None = None,
                       args: dict | None = None) -> None:
         """Transition request ``rid`` into ``phase``: end whatever
         lifecycle span is open and begin the new one (back-to-back, one
-        timestamp — no gap, no overlap)."""
-        now = self.now_us()
+        timestamp — no gap, no overlap; ``ts_us``: a stamp taken a moment
+        before the call)."""
+        now = self.now_us() if ts_us is None else ts_us
         with self._lock:
             open_phase = self._req_phase.get(rid)
             self._req_phase[rid] = phase

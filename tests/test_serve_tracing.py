@@ -446,11 +446,12 @@ def test_mixed_tick_phases_and_summarize_utilization(tiny, tmp_path):
 @pytest.mark.parametrize("budget", [1, 5], ids=["one-token", "five-tokens"])
 def test_request_track_follows_the_publish_not_the_accept(tiny, budget):
     """A request's track is what the OUTSIDE saw: ``decode`` begins where
-    its first token is handed to the callback (the emit end of the
-    first-write lag), inside the ``deliver`` phase of the tick AFTER the
-    one that sampled it, and the ``finish`` instant follows the last
-    token's callback — while the tick's own slices keep the new order
-    and the sum-to-tick invariant."""
+    its first token is emitted — the stamp ``first_emit_time``, taken just
+    BEFORE the callback, so the frame's write can never precede it (the
+    emit end of the first-write lag) —, inside the ``deliver`` phase of
+    the tick AFTER the one that sampled it, and the ``finish`` instant
+    follows the last token's callback — while the tick's own slices keep
+    the new order and the sum-to-tick invariant."""
     from llm_np_cp_tpu.serve.tracing import MIXED_TICK_PHASES
 
     cfg, params = tiny
@@ -475,7 +476,9 @@ def test_request_track_follows_the_publish_not_the_accept(tiny, budget):
         assert ("decode", "b") not in names
     else:
         decode = next(e for e in track if (e["name"], e["ph"]) == ("decode", "b"))
-        assert handed[0] <= decode["ts"] <= handed[1]
+        assert decode["ts"] == tracer.us_at(req.first_emit_time)
+        assert req.first_token_time <= req.first_emit_time
+        assert decode["ts"] <= handed[0] <= handed[1]
     ticks = [(e, events[i + 1:i + 1 + len(MIXED_TICK_PHASES)])
              for i, e in enumerate(events)
              if e.get("cat") == "tick" and e.get("ph") == "X"]
@@ -496,7 +499,7 @@ def test_request_track_follows_the_publish_not_the_accept(tiny, budget):
                   for i, (_, ph) in enumerate(ticks)}
     if budget > 1:
         d = deliver_of[first_tick + 1]
-        assert d["ts"] <= handed[0] <= d["ts"] + d["dur"]
+        assert d["ts"] <= decode["ts"] <= handed[0] <= d["ts"] + d["dur"]
 
 
 # ---------------------------------------------------------------------------
@@ -1056,3 +1059,450 @@ def test_summarize_joins_a_profile_by_seq_and_corrects_the_device_lead(
     assert tick_timeline(events + [idle], "x")["ticks"] == 1
     assert tick_timeline(
         [dict(e, args={}) for e in events], "x") is None
+
+
+# ----------------------------------------------------------------------
+# a request's first token, stage by stage (scheduler.TTFT_STAMPS): eight
+# stamps on the engine clock, each taken once where the work happens, the
+# request track's instants at the same readings, the tick by its kind
+# ----------------------------------------------------------------------
+
+class _Clock:
+    """A clock that advances 1/1024 s a reading: every stamp is distinct,
+    ordered as the reads were, and sums of differences are exact."""
+
+    def __init__(self):
+        self.n = 0
+
+    def __call__(self):
+        self.n += 1
+        return self.n / 1024.0
+
+
+def _staged_engine(cfg, params, *, chunk=8, slots=4, **kw):
+    clock = _Clock()
+    tracer = TraceRecorder(clock=clock)
+    engine = _engine(
+        cfg, params, max_slots=slots, num_blocks=96, max_seq_len=128,
+        prefill_chunk=chunk, tick_token_budget=slots + 2 * chunk,
+        clock=clock, tracer=tracer, **kw)
+    return engine, tracer, clock
+
+
+def _submit_as_http(engine, clock, prompt, max_new, **kw):
+    """What the HTTP layer does around ``ServeEngine.submit``: a stamp at
+    socket accept, one at the command's put into the inbox."""
+    received = clock()
+    return engine.submit(prompt, max_new, received_time=received,
+                         enqueue_time=clock(), **kw)
+
+
+def _track(events, rid):
+    """{"begin": {span: ts}, "instant": {name: event}} of one request, the
+    FIRST of each name (as ``benchmark/layers/tracefile.request_tracks``)."""
+    out = {"begin": {}, "instant": {}}
+    for ev in events:
+        if ev.get("cat") != "request" or ev.get("id") != rid:
+            continue
+        if ev["ph"] == "b":
+            out["begin"].setdefault(ev["name"], ev["ts"])
+        elif ev["ph"] == "n":
+            out["instant"].setdefault(ev["name"], ev)
+    return out
+
+
+def _tick_of(ticks, ts):
+    """Index of the recorder's tick whose span holds ``ts``."""
+    return next(i for i, t in enumerate(ticks)
+                if t["ts"] <= ts <= t["ts"] + t["dur"])
+
+
+@pytest.fixture(scope="module")
+def staged_run(tiny):
+    """32 requests, prompts of 3 to 40 tokens against a chunk of 8 and a
+    budget of ``slots + 2 x chunk``, submitted in waves between ticks as
+    the HTTP layer would (both of its stamps passed in)."""
+    from llm_np_cp_tpu.serve.request_log import request_record
+
+    cfg, params = tiny
+    engine, tracer, clock = _staged_engine(cfg, params)
+    rng = np.random.default_rng(53)
+    reqs, records = [], {}
+    lens = rng.integers(3, 41, size=32)
+
+    def log(req, event):
+        if event in ("stop", "length", "aborted"):
+            records[req.req_id] = request_record(
+                req, reason=event, clock=clock)
+
+    for wave in range(8):
+        for n in lens[4 * wave:4 * wave + 4]:
+            reqs.append(_submit_as_http(
+                engine, clock, rng.integers(1, cfg.vocab_size, size=int(n)),
+                int(rng.integers(1, 6)), on_event=log))
+        for _ in range(3):
+            engine.step()
+    engine.run_until_complete()
+    assert all(r.finish_reason == "length" for r in reqs)
+    return engine, tracer, reqs, records
+
+
+def test_stages_are_consecutive_and_the_instants_sit_at_the_stamps(staged_run):
+    from llm_np_cp_tpu.serve.scheduler import (
+        TTFT_STAGES,
+        TTFT_STAMPS,
+        ttft_stages,
+    )
+
+    engine, tracer, reqs, _ = staged_run
+    events = tracer.events()
+    assert len(reqs) == 32
+    for req in reqs:
+        stamps = [getattr(req, name) for name in TTFT_STAMPS]
+        assert None not in stamps, (req.req_id, stamps)
+        assert stamps == sorted(stamps)
+        stages = ttft_stages(req)
+        assert tuple(stages) == TTFT_STAGES
+        assert all(v >= 0.0 for v in stages.values())
+        # exactly: the clock's readings are multiples of 2**-10
+        assert sum(stages.values()) == req.first_emit_time - req.received_time
+        assert stages["slot_wait"] == req.admit_time - req.submit_time
+        tr = _track(events, req.req_id)
+        at = tracer.us_at
+        assert tr["begin"]["queued"] > at(req.enqueue_time)
+        assert tr["instant"]["lane"]["ts"] == at(req.lane_time)
+        assert tr["instant"]["last_chunk"]["ts"] == at(req.last_chunk_time)
+        assert tr["instant"]["first_token"]["ts"] == at(req.first_token_time)
+        assert at(req.admit_time) < tr["begin"]["prefill"] <= at(req.lane_time)
+        if req.max_new_tokens > 1:
+            assert tr["begin"]["decode"] == at(req.first_emit_time)
+        else:  # finished on its first token: no decode span was opened
+            assert "decode" not in tr["begin"]
+        counts = tr["instant"]["last_chunk"]["args"]
+        assert counts["prefill_ticks"] == req.prefill_ticks >= 1
+        assert counts["lane_ticks"] == req.lane_ticks
+        assert counts["starved_ticks"] == req.starved_ticks
+        assert req.lane_ticks <= req.prefill_ticks
+    # the dispatch a request's last chunk rode and the one that sampled its
+    # first token are ONE dispatch, the recorder's tick of that ``seq``
+    ticks = {e["args"]["seq"]: e for e in _dispatching(events)}
+    for req in reqs:
+        tr = _track(events, req.req_id)
+        seq = tr["instant"]["last_chunk"]["args"]["seq"]
+        assert tr["instant"]["first_token"]["args"]["seq"] == seq
+        t = ticks[seq]
+        assert (t["ts"] <= tr["instant"]["last_chunk"]["ts"]
+                <= tr["instant"]["first_token"]["ts"] <= t["ts"] + t["dur"])
+    # prompts longer than the lane met each other in it
+    assert any(r.lane_ticks > 1 for r in reqs)
+    assert any(r.lane_time > r.admit_time + 4 / 1024 for r in reqs)
+
+
+def test_two_long_prompts_share_the_lane_oldest_first(tiny):
+    """Budget ``slots + 2 x chunk``: each of two mid-prefill rows takes
+    its chunk and the leftover (``slots``) goes to the older, so the older
+    holds the lane from its first planned tick and the younger waits, a
+    chunk a tick, until the older's prompt runs out."""
+    cfg, params = tiny
+    chunk, slots = 8, 4
+    engine, tracer, clock = _staged_engine(cfg, params, chunk=chunk,
+                                           slots=slots)
+    old = engine.submit(np.arange(1, 62), 2)    # 61 tokens
+    young = engine.submit(np.arange(2, 63), 2)  # 61 tokens
+    engine.step()
+    assert old.admit_time is not None and young.admit_time is not None
+    engine.run_until_complete()
+    # a prompt of ONE chunk, alone: lane and last chunk in its only tick
+    short = engine.submit(np.arange(1, chunk + 1), 2)
+    engine.run_until_complete()
+    events = tracer.events()
+    ticks = [e for e in events if e.get("name") == "tick"]
+    tr_old, tr_young = _track(events, old.req_id), _track(events, young.req_id)
+
+    first_planned = _tick_of(ticks, tracer.us_at(old.admit_time))
+    assert _tick_of(ticks, tr_old["instant"]["lane"]["ts"]) == first_planned
+    assert tr_old["instant"]["lane"]["args"] == {
+        "rows_ahead": 0, "fair_tokens": 0}
+    old_last = _tick_of(ticks, tr_old["instant"]["last_chunk"]["ts"])
+    young_lane = _tick_of(ticks, tr_young["instant"]["lane"]["ts"])
+    assert young_lane >= old_last > first_planned
+    lane = tr_young["instant"]["lane"]["args"]
+    assert lane["rows_ahead"] == 1
+    # planned a chunk a tick, every tick from its admission to its lane
+    assert lane["fair_tokens"] == chunk * (young_lane - first_planned) > 0
+    assert young.starved_ticks == 0 == old.starved_ticks
+    # the older took chunk + slots a tick, five lane ticks, and its last
+    # token in a sixth, which is no more than its fair share: there the
+    # leftover passed to the younger
+    assert (old.lane_ticks, old.prefill_ticks) == (5, 6)
+    assert young_lane == old_last
+    assert young.prefill_ticks > young.lane_ticks >= 1
+    # the tick says its kind: leftover went to ONE row while both prefilled
+    both = ticks[first_planned]["args"]
+    assert (both["lane_rows"], both["lane_tokens"]) == (1, slots)
+    assert both["prefill_rows"] == 2
+
+    assert short.lane_time == short.last_chunk_time
+    assert short.prefill_ticks == 1 and short.lane_ticks == 0
+    from llm_np_cp_tpu.serve.scheduler import ttft_stages
+
+    assert ttft_stages(short)["prefill"] == 0.0
+    tr = _track(events, short.req_id)
+    assert tr["instant"]["lane"]["ts"] == tr["instant"]["last_chunk"]["ts"]
+    # its tick carried a fair share alone: neither kind of the two
+    own = ticks[_tick_of(ticks, tr["instant"]["lane"]["ts"])]["args"]
+    assert own["lane_rows"] == 0 and own["prefill_tokens"] == chunk
+
+
+def test_a_row_the_budget_runs_out_before_is_starved(tiny):
+    """Three long prompts under a budget of two chunks: the fair share
+    reaches the two oldest, the third is mid-prefill and granted nothing."""
+    cfg, params = tiny
+    clock = _Clock()
+    engine = _engine(cfg, params, max_slots=4, num_blocks=96, max_seq_len=128,
+                     prefill_chunk=8, tick_token_budget=16, clock=clock)
+    reqs = [engine.submit(np.arange(1, 34), 2) for _ in range(3)]
+    engine.run_until_complete()
+    assert [r.starved_ticks for r in reqs][:2] == [0, 0]
+    assert reqs[2].starved_ticks >= 4
+    snap = engine.metrics.snapshot()
+    assert snap["prefill_starved_rows"] == sum(r.starved_ticks for r in reqs)
+    assert (f"llm_serve_prefill_starved_rows_total "
+            f"{snap['prefill_starved_rows']}") in engine.metrics.prometheus()
+    # a budget of two chunks leaves no leftover: no tick was a lane tick
+    # until a prompt's tail freed part of a chunk
+    assert snap["lane_ticks"] <= 2
+
+
+def test_stage_family_counters_and_request_log_agree_with_the_trace(staged_run):
+    from llm_np_cp_tpu.serve.scheduler import TTFT_STAGES
+
+    engine, tracer, reqs, records = staged_run
+    events = tracer.events()
+    snap = engine.metrics.snapshot()
+    ticks = [e for e in events if e.get("name") == "tick"]
+    assert snap["lane_ticks"] == sum(
+        1 for t in ticks if t["args"]["lane_rows"] > 0) > 0
+    assert snap["lane_ticks"] <= snap["ticks"]
+    assert sum(t["args"]["lane_tokens"] for t in ticks) > 0
+    assert all((t["args"]["lane_tokens"] > 0) == (t["args"]["lane_rows"] > 0)
+               for t in ticks)
+    # the stages, re-derived from the request track alone (microseconds)
+    derived = {stage: [] for stage in TTFT_STAGES}
+    for req in reqs:
+        tr = _track(events, req.req_id)
+        edges = [tracer.us_at(req.received_time),
+                 tracer.us_at(req.enqueue_time),
+                 tr["begin"]["queued"], tr["begin"]["prefill"],
+                 tr["instant"]["lane"]["ts"],
+                 tr["instant"]["last_chunk"]["ts"],
+                 tr["instant"]["first_token"]["ts"],
+                 tracer.us_at(req.first_emit_time)]
+        rec = records[req.req_id]
+        assert tuple(rec["ttft_stages"]) == TTFT_STAGES
+        for stage, a, b in zip(TTFT_STAGES, edges, edges[1:]):
+            derived[stage].append((b - a) / 1e6)
+            # ``queued`` / ``prefill`` begin a clock read after their stamp
+            assert rec["ttft_stages"][stage] == pytest.approx(
+                (b - a) / 1e6, abs=2.1 / 1024)
+        for name in ("prefill_ticks", "lane_ticks", "starved_ticks"):
+            assert rec[name] == tr["instant"]["last_chunk"]["args"][name]
+        assert rec["phases"]["queue_wait_s"] == pytest.approx(
+            rec["ttft_stages"]["slot_wait"])
+    prom = engine.metrics.prometheus()
+    for line in prom.splitlines():
+        assert line.startswith("# ") or PROM_LINE.fullmatch(line), line
+    assert prom.count("# TYPE llm_serve_ttft_stage_seconds_quantile gauge") == 1
+    for stage in TTFT_STAGES:
+        got = float(re.search(
+            rf'^llm_serve_ttft_stage_seconds_quantile'
+            rf'{{stage="{stage}",quantile="0.5"}} (\S+)$', prom, re.M).group(1))
+        assert got == pytest.approx(snap[f"ttft_stage_{stage}_s_p50"])
+        assert got == pytest.approx(np.percentile(derived[stage], 50),
+                                    abs=2.1 / 1024)
+    assert f"llm_serve_lane_ticks_total {snap['lane_ticks']}" in prom
+    # the old names keep their meanings beside the family
+    assert snap["queue_wait_s_p50"] == pytest.approx(
+        snap["ttft_stage_slot_wait_s_p50"])
+    assert "llm_serve_prefill_s_quantile" in prom
+
+
+def test_preemption_and_recovery_replay_keep_the_first_stamps(tiny):
+    from llm_np_cp_tpu.serve.scheduler import (
+        TTFT_STAMPS,
+        first_stamps,
+        ttft_stages,
+    )
+
+    cfg, params = tiny
+    clock = _Clock()
+    tracer = TraceRecorder(clock=clock)
+    # a pool tight enough to preempt decoding rows
+    engine = _engine(cfg, params, num_blocks=6, clock=clock, tracer=tracer)
+    rng = np.random.default_rng(5)
+    reqs = [_submit_as_http(engine, clock,
+                            rng.integers(1, cfg.vocab_size, size=5), 12)
+            for _ in range(6)]
+    seen: dict[int, dict] = {}
+    for _ in range(400):
+        if not engine.step():
+            break
+        for r in reqs:
+            if r.first_emit_time is not None and r.req_id not in seen:
+                seen[r.req_id] = first_stamps(r)
+    hit = [r for r in reqs if r.n_preemptions]
+    assert hit, "pool was not tight enough to exercise eviction"
+    for r in hit:
+        assert r.finish_reason == "length"
+        if r.req_id in seen:  # preempted after its first token went out
+            assert first_stamps(r) == seen[r.req_id]
+    assert any(r.req_id in seen for r in hit)
+    # its track holds ONE of each instant, the first life's
+    events = tracer.events()
+    for r in hit:
+        for name in ("lane", "last_chunk", "first_token"):
+            assert sum(1 for e in events if e.get("id") == r.req_id
+                       and e.get("name") == name) == 1
+
+    # a mid-flight rebuild: the replay carries the first stamps over
+    live = _submit_as_http(engine, clock, np.arange(1, 8), 8)
+    for _ in range(3):
+        engine.step()
+    assert live.first_token_time is not None and live.generated
+    before = first_stamps(live)
+    rebuilt = engine.clone_fresh()
+    again = rebuilt.recover(live.prompt, 8, request_id=live.req_id,
+                            seed=live.seed, generated=list(live.generated),
+                            stamps=before)
+    rebuilt.run_until_complete()
+    assert again.finish_reason == "length"
+    kept = first_stamps(again)
+    assert {k: kept[k] for k in before} == before
+    assert set(TTFT_STAMPS) <= set(kept)
+    stages = ttft_stages(again)
+    assert all(v >= 0.0 for v in stages.values())
+    assert sum(stages.values()) == again.first_emit_time - again.received_time
+    # without them the replay starts anew (as a journal replay in another
+    # process must: its clock is another)
+    fresh = engine.clone_fresh().recover(
+        live.prompt, 8, request_id=live.req_id, seed=live.seed,
+        generated=list(live.generated))
+    assert fresh.received_time is None and fresh.lane_time is None
+    assert fresh.submit_time > live.submit_time
+
+
+def test_the_stamps_add_no_compile_and_the_hooks_stay_guarded(tiny):
+    cfg, params = tiny
+    engine, tracer, clock = _staged_engine(cfg, params)
+    engine.tracer = None
+    prompts = [np.arange(1, n + 1) for n in (5, 30, 12)]
+    for p in prompts:
+        _submit_as_http(engine, clock, p, 3)
+    engine.run_until_complete()  # compile everything once
+    counter = CompileCounter()
+    for rec in (None, tracer, None):
+        engine.tracer = rec
+        with counter.watch():
+            for p in prompts:
+                _submit_as_http(engine, clock, p, 3)
+            engine.run_until_complete()
+        assert counter.count == 0, counter.events
+    assert any(e.get("name") == "lane" for e in tracer.events())
+    assert_tracing_hooks_guarded()
+    from tools.lint.rules.guarded_hook import scan_hook_guard_files
+
+    assert not scan_hook_guard_files((
+        "llm_np_cp_tpu/serve/engine.py",
+        "llm_np_cp_tpu/serve/http/server.py"), hooks=("tracer",))
+
+
+@pytest.mark.http
+def test_over_http_the_track_runs_from_accept_to_the_first_write(tiny):
+    """The two stamps of the loop thread (socket accept, the inbox) reach
+    the request, and with a recorder the eighth stage closes the track:
+    the instants and span begins from ``http`` to ``first_write`` are
+    consecutive, so the eight stages sum to first write - accept."""
+    import asyncio
+
+    from llm_np_cp_tpu.serve.http.client import astream_completion
+    from llm_np_cp_tpu.serve.http.server import HttpServer
+    from llm_np_cp_tpu.serve.scheduler import TTFT_STAGES
+
+    cfg, params = tiny
+    tracer = TraceRecorder()
+    engine = _engine(cfg, params, tracer=tracer)
+
+    async def main():
+        srv = HttpServer(engine, model_id="tiny", drain_timeout=10.0)
+        await srv.start("127.0.0.1", 0)
+        results = await asyncio.gather(*(
+            astream_completion(
+                srv.host, srv.port,
+                {"prompt": [4, 2, 9, 7][: 2 + i % 3], "max_tokens": 4,
+                 "stream": True}, timeout=60)
+            for i in range(6)))
+        srv.begin_drain()
+        await srv.serve_until_shutdown()
+        return results
+
+    results = asyncio.run(asyncio.wait_for(main(), timeout=120))
+    assert all(r["status"] == 200 for r in results)
+    events = tracer.events()
+    rids = {e["id"] for e in events if e.get("name") == "http"}
+    assert len(rids) == 6
+    for rid in rids:
+        tr = _track(events, rid)
+        edges = [tr["begin"]["http"], tr["instant"]["enqueued"]["ts"],
+                 tr["begin"]["queued"], tr["begin"]["prefill"],
+                 tr["instant"]["lane"]["ts"],
+                 tr["instant"]["last_chunk"]["ts"],
+                 tr["instant"]["first_token"]["ts"], tr["begin"]["decode"],
+                 tr["instant"]["first_write"]["ts"]]
+        assert edges == sorted(edges), (rid, edges)
+        assert edges[0] < edges[1] < edges[2]
+        assert tr["instant"]["first_write"]["args"]["lag_us"] >= 0.0
+    snap = engine.metrics.snapshot()
+    for stage in TTFT_STAGES:
+        assert snap[f"ttft_stage_{stage}_s_p50"] >= 0.0, stage
+    assert snap["ttft_stage_parse_s_p50"] > 0.0
+    assert snap["ttft_stage_inbox_wait_s_p50"] > 0.0
+
+
+def test_summarize_prints_the_first_token_by_stage(staged_run):
+    from tools.summarize_trace import (
+        TTFT_STAGES,
+        format_ttft_stages,
+        ttft_stage_table,
+    )
+
+    from llm_np_cp_tpu.serve import scheduler
+
+    assert TTFT_STAGES == scheduler.TTFT_STAGES + ("write_lag",)
+    engine, tracer, reqs, _ = staged_run
+    events = tracer.to_dict()["traceEvents"]
+    table = ttft_stage_table(events)
+    # the direct-mode run has no ``http`` span: its track starts at queued
+    assert list(table["stages"]) == [
+        "slot_wait", "lane_wait", "prefill", "final_tick", "publish_lag"]
+    multi = [r for r in reqs if r.max_new_tokens > 1]
+    assert table["stages"]["final_tick"]["n"] == len(reqs)
+    assert table["stages"]["publish_lag"]["n"] == len(multi)
+    lane_wait = sorted(1e6 * (r.lane_time - r.admit_time) for r in reqs)
+    assert table["stages"]["lane_wait"]["p50_us"] == pytest.approx(
+        lane_wait[round(0.5 * (len(reqs) - 1))], abs=2e6 / 1024)
+    assert table["counts"]["prefill_ticks"] == sorted(
+        r.prefill_ticks for r in reqs)[round(0.5 * (len(reqs) - 1))]
+    ticks = _dispatching(events)
+    assert sum(rec["n"] for rec in table["ticks"].values()) == len(ticks)
+    assert table["ticks"]["prefill"]["n"] == sum(
+        1 for t in ticks if t["args"]["lane_rows"] > 0)
+    assert table["ticks"]["decode"]["n"] == sum(
+        1 for t in ticks if not t["args"]["prefill_tokens"])
+    text = format_summary(events, top=0)
+    assert format_ttft_stages(table) in text
+    assert "tick wall by kind: decode " in text
+    # a dump from before the track carried the instants prints no table
+    old = [e for e in events if e.get("name") not in (
+        "lane", "last_chunk", "first_token", "enqueued")]
+    assert ttft_stage_table(old) is None
+    assert "first token by stage" not in format_summary(old, top=0)

@@ -46,6 +46,15 @@ from ``GET /debug/trace``) and prints:
   frame's emit-to-write lag, the stream's mean and largest lag and its
   number of frames) per request,
   with eviction/recovery counts and the finish reason;
+- **first token by stage** — a request's way from socket accept to its
+  first written frame cut where the work happens (the request track's
+  ``http`` begin, ``enqueued``, ``queued``, ``prefill``, ``lane``,
+  ``last_chunk``, ``first_token``, ``decode`` begin, ``first_write``):
+  each stage's p50 / p95 over the dump's requests, the medians of the
+  ticks a prompt took (with a grant, with leftover of the prompt lane,
+  with nothing), and the tick's wall time by its kind — a tick that
+  handed a prompt leftover of the lane, a decode-only tick, a tick of
+  fair-share chunks alone;
 - **tenants** (when ``--request-log PATH`` points at the canonical
   request log for the same run) — per-tenant request / token / cost
   breakdown joined from the wide-event lines: requests by finish
@@ -90,6 +99,19 @@ LIFECYCLE_COLUMNS = ("queued", "prefill", "decode", "http")
 MIXED_TICK_PHASES = (
     "admission", "draft", "grow", "plan", "pack", "h2d", "mixed_dispatch",
     "deliver", "host_sync", "accept", "account",
+)
+# The stages of a request's way to its first token, each between two
+# neighbours of these edges of its track (serve.scheduler.TTFT_STAGES
+# plus ``write_lag``, which only a recorder sees): a span's begin
+# (``ph: b``) or an instant (``ph: n``), the FIRST of its name.
+TTFT_EDGES = (
+    ("http", "b"), ("enqueued", "n"), ("queued", "b"), ("prefill", "b"),
+    ("lane", "n"), ("last_chunk", "n"), ("first_token", "n"),
+    ("decode", "b"), ("first_write", "n"),
+)
+TTFT_STAGES = (
+    "parse", "inbox_wait", "slot_wait", "lane_wait", "prefill",
+    "final_tick", "publish_lag", "write_lag",
 )
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -789,6 +811,68 @@ def format_device_scopes(dev: dict[str, Any]) -> str:
     return "\n".join(lines)
 
 
+def ttft_stage_table(events: list[dict]) -> dict[str, Any] | None:
+    """The way to the first token over the dump's requests: per stage the
+    count, p50 and p95 (us), the medians of the three tick counts the
+    ``last_chunk`` instant carries, and per kind of tick — ``prefill``
+    (tick arg ``lane_rows`` > 0: leftover of the prompt lane handed out),
+    ``decode`` (no prompt token aboard), ``fair`` (fair-share chunks
+    alone) — the dispatching ticks and their mean wall (us).  None for a
+    dump from before the track carried the ``lane`` instant."""
+    first: dict[Any, dict] = defaultdict(dict)
+    counts: dict[str, list[int]] = defaultdict(list)
+    for ev in events:
+        if ev.get("cat") != "request":
+            continue
+        key = (ev["name"], ev["ph"])
+        if key in TTFT_EDGES and key not in first[ev.get("id")]:
+            first[ev.get("id")][key] = ev["ts"]
+            if ev["name"] == "last_chunk":
+                for k, v in (ev.get("args") or {}).items():
+                    if k != "seq":
+                        counts[k].append(v)
+    if not any(("lane", "n") in tr for tr in first.values()):
+        return None
+    stages = {}
+    for stage, a, b in zip(TTFT_STAGES, TTFT_EDGES, TTFT_EDGES[1:]):
+        vals = [tr[b] - tr[a] for tr in first.values()
+                if a in tr and b in tr]
+        if vals:
+            stages[stage] = {"n": len(vals), "p50_us": _pct(vals, 50),
+                             "p95_us": _pct(vals, 95)}
+    kinds: dict[str, list[float]] = defaultdict(list)
+    for ev in events:
+        args = ev.get("args") or {}
+        if ev.get("name") != "tick" or "lane_rows" not in args \
+                or "seq" not in args:
+            continue
+        kinds["prefill" if args["lane_rows"] > 0 else
+              "fair" if args.get("prefill_tokens") else "decode"
+              ].append(ev["dur"])
+    return {
+        "stages": stages,
+        "counts": {k: _pct(v, 50) for k, v in counts.items()},
+        "ticks": {k: {"n": len(v), "mean_us": sum(v) / len(v)}
+                  for k, v in kinds.items()},
+    }
+
+
+def format_ttft_stages(table: dict[str, Any]) -> str:
+    lines = ["== first token by stage ==",
+             f"{'stage':<12} {'requests':>8} {'p50_ms':>9} {'p95_ms':>9}"]
+    for stage, rec in table["stages"].items():
+        lines.append(f"{stage:<12} {rec['n']:>8} {rec['p50_us'] / 1e3:>9.3f} "
+                     f"{rec['p95_us'] / 1e3:>9.3f}")
+    if table["counts"]:
+        lines.append("ticks a prompt took (median): " + ", ".join(
+            f"{k} {v}" for k, v in table["counts"].items()))
+    if table["ticks"]:
+        lines.append("tick wall by kind: " + "; ".join(
+            f"{kind} {rec['mean_us'] / 1e3:.2f} ms x {rec['n']}"
+            for kind, rec in sorted(table["ticks"].items())))
+    return "\n".join(lines)
+
+
 def slowest_ticks(events: list[dict], k: int) -> list[dict]:
     ticks = [e for e in events
              if e.get("ph") == "X" and e.get("cat") == "tick"]
@@ -1036,6 +1120,9 @@ def format_summary(events: list[dict], top: int = 5,
                 if "restore_us_p50" in tier else ""
             )
         )
+    staged = ttft_stage_table(events)
+    if staged is not None:
+        lines.append(format_ttft_stages(staged))
     lines.append(f"== top {top} slowest ticks ==")
     for ev in slowest_ticks(events, top):
         args = ev.get("args") or {}
