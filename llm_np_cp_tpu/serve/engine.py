@@ -146,6 +146,7 @@ from llm_np_cp_tpu.serve.scheduler import (
 )
 from llm_np_cp_tpu.serve.telemetry import mixed_tick_kv_read
 from llm_np_cp_tpu.serve.tracing import TraceRecorder, gen_trace_id
+from llm_np_cp_tpu.utils.runtime import compile_cache_bypassed
 
 Params = dict[str, Any]
 
@@ -425,6 +426,7 @@ class ServeEngine:
         spec_ngram: int = 3,
         spec_min_accept: float = 0.1,
         spec_window: int = 64,
+        steps_from: "ServeEngine | None" = None,
     ) -> None:
         # ``mixed_step`` names no tick any more: there is one.  The
         # keyword stays only until the three builder-run scripts under
@@ -946,6 +948,27 @@ class ServeEngine:
             p: mixed_operand_layout(*p, *self._mixed_geometry)
             for p in self.mixed_buckets}
         self._mixed_step = self._make_mixed_step()
+        # -- the layout the step reads each weight in, asked of the
+        # compiler ONCE (``step_weight_formats``); the weights are put
+        # there now, so no tick re-lays one out.  A rebuild of an engine
+        # of this geometry (``clone_fresh``) takes that engine's compiled
+        # step and, with it, the formats it was compiled for
+        if (steps_from is not None
+                and steps_from.ragged_attn_impl == self.ragged_attn_impl
+                and steps_from.epilogue_impl == self.epilogue_impl):
+            # same resolution → identical jaxpr; a runtime-degraded
+            # process (disable_kernel) rebuilds on the XLA fallback
+            # and compiles it once there, not per restart
+            self._mixed_step = steps_from._mixed_step
+            self._weight_formats = steps_from._weight_formats
+        else:
+            self._weight_formats = self._decide_weight_formats()
+        # leaves and bytes this build moved (the ``engine_build`` span)
+        self.weights_reput = self._lay_out_weights()
+        # what a program still re-lays out an execution, in bytes, by
+        # program ("512x64"; ``device_op_map`` reads it from the compiled
+        # text; a ``/metrics`` gauge).  Empty until a recorder asked
+        self.weight_relayout_bytes: dict[str, int] = {}
         # one-fetch ledger, initialized after the step builder: the
         # tick bumps it at its single packed host_sync transfer and the
         # tick trace args carry the per-tick count
@@ -964,6 +987,10 @@ class ServeEngine:
                 # and what one page of it is
                 "pool_carried": int(self.pool_carried),
                 "pool_page_shape": self.pool_page_shape,
+                # weight leaves (and their bytes) put into the layout the
+                # step reads them in; 0 where they lay there already
+                "weights_reput": self.weights_reput[0],
+                "weights_reput_bytes": self.weights_reput[1],
                 # what the slots carry besides K/V (0 without such layers)
                 "state_bytes": int(sum(a.nbytes for a in jax.tree.leaves(
                     self.pool.pages.state))),
@@ -1056,6 +1083,108 @@ class ServeEngine:
             f"lanes > the largest program {self.mixed_buckets[-1]} — "
             "budget accounting is broken"
         )
+
+    # ------------------------------------------------------------------
+    # The layout the step reads each weight in
+    # ------------------------------------------------------------------
+    def step_weight_formats(self, params: Params, pages: PagedKV) -> Any:
+        """The ``Format`` (layout and sharding) the step reads each weight
+        leaf in when the COMPILER chooses: the pytree of ``params``, from
+        ``compiled.input_formats`` of the step jitted with the weights'
+        layouts left open (``Layout.AUTO``; the pool and the packed
+        operand keep theirs).  ``params`` / ``pages``: arrays, or shapes
+        that carry a sharding (tests/test_kernel_lowering.py compiles for
+        a described chip).
+
+        ``jax.jit`` hands a program every argument in the device's default
+        layout and the compiler may not change an entry parameter's: where
+        a projection's dot wants its weight's contracting axis minor (a
+        result split into heads straight after the dot, which the compiler
+        folds into it), it copied the WHOLE weight every execution —
+        seven 100 MB ``q_proj`` a tick in one cell, 2.2 ms of 21 (PERF.md
+        section 6, PR 54).  Asked instead, it says which layout each leaf
+        should arrive in, and the engine puts it there once.
+
+        ONE program decides: the one a decode-only tick with every slot
+        live runs — most ticks of most traffic.  The other programs are
+        compiled for the weights as they then lie (a plain ``jax.jit``
+        compiles for the layout a committed argument has), so all agree
+        and the engine holds one set of weights."""
+        from jax.experimental.layout import Format, Layout
+
+        program = self._pick_bucket(
+            self.scheduler.max_slots * self._q_tile, self.scheduler.max_slots)
+
+        def shape(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        step = jax.jit(
+            self._mixed_step.__wrapped__, donate_argnums=(1,),
+            in_shardings=(
+                jax.tree.map(lambda a: Format(Layout.AUTO, a.sharding),
+                             params),
+                jax.tree.map(lambda a: Format(None, a.sharding), pages),
+                Format(None, pages.k.sharding)))
+        return step.lower(
+            jax.tree.map(shape, params), jax.tree.map(shape, pages),
+            shape(self._dead_mixed_operands(*program)),
+        ).compile().input_formats[0][0]
+
+    def _decide_weight_formats(self) -> Any:
+        """``step_weight_formats`` for this engine's weights and pool, or
+        None where the weights stay as they come: under a mesh (no cell
+        runs one; a sharded leaf's layout is the partitioner's business as
+        much as the compiler's, and ``shard_params`` has just placed
+        them), for an engine built on shapes alone, and on a device that
+        keeps its arrays UNTILED (``Array.format``, as
+        ``_pool_is_row_major`` asks it: the CPU).  There either axis
+        order of a matrix is a stride away, the compiler asks for no
+        other (every leaf of every tiny preset comes back as it is), and
+        the question — a trace and a compile of the steady program, 1-4 s
+        of every engine a test builds — is not put."""
+        leaves = jax.tree.leaves(self.params)
+        if self.mesh is not None or not all(
+                isinstance(a, jax.Array) for a in leaves):
+            return None
+        # (the device's way with a matrix: one leaf of rank >= 2 says it)
+        matrix = max(leaves, key=lambda a: a.ndim, default=None)
+        if matrix is None or not getattr(matrix.format.layout, "tiling", None):
+            return None
+        return self.step_weight_formats(self.params, self.pool.pages)
+
+    def _lay_out_weights(self) -> tuple[int, int]:
+        """Put every weight leaf whose layout differs from the one the
+        step reads it in (``_weight_formats``) into that layout, leaf by
+        leaf: the engine then holds the new leaf and no reference to the
+        old one, the caller's pytree is what it was, and a leaf that lies
+        right stays the same buffer.  Returns (leaves, bytes) moved."""
+        if self._weight_formats is None:
+            return 0, 0
+        leaves, tree = jax.tree.flatten(self.params)
+        # (a leaf the step never reads has no format to ask for)
+        turn = {i: fmt for i, (leaf, fmt) in enumerate(zip(
+            leaves, jax.tree.leaves(self._weight_formats)))
+            if fmt.layout is not None and leaf.format.layout != fmt.layout}
+        if turn:
+            # the copy is a program whose RESULT has the asked layout: the
+            # one kind the persistent compile cache hands back wrong
+            with compile_cache_bypassed():
+                for i, fmt in turn.items():
+                    leaves[i] = jax.device_put(leaves[i], fmt)
+            self.params = jax.tree.unflatten(tree, leaves)
+        return len(turn), sum(leaves[i].nbytes for i in turn)
+
+    def weight_layout_gauges(self) -> dict[str, float]:
+        """``/metrics``: the bytes an execution of each program spends
+        re-laying out a weight (``step_weight_relayout_bytes{program=}``,
+        0 expected; known once a recorder read the compiled programs:
+        ``device_op_map``), and what this engine's build moved to get
+        there."""
+        out = {f'step_weight_relayout_bytes{{program="{program}"}}': float(n)
+               for program, n in self.weight_relayout_bytes.items()}
+        out["weights_reput"] = float(self.weights_reput[0])
+        out["weights_reput_bytes"] = float(self.weights_reput[1])
+        return out
 
     # ------------------------------------------------------------------
     # Mesh helpers (all no-ops on a single chip)
@@ -2510,7 +2639,9 @@ class ServeEngine:
         ``params``/``weights_version`` override the weights — the
         rolling-upgrade rebuild (serve/replica.py): the jitted steps
         take params as a call ARGUMENT, so a swap to same-shaped
-        weights reuses every warm compile, and a swap that changes the
+        weights reuses every warm compile (the new engine's build puts
+        them into the layouts the shared step was compiled for:
+        ``_lay_out_weights``), and a swap that changes the
         param avals re-traces once per shared callable — once per
         FLEET, because rolled peers adopt the first rebuilt replica's
         callables via ``share_compiled_steps``."""
@@ -2551,6 +2682,9 @@ class ServeEngine:
             spec_ngram=self.spec_ngram,
             spec_min_accept=self.spec_min_accept,
             spec_window=self.spec_window,
+            # the compiled step and the formats it reads its weights in:
+            # the build puts ``params`` (these, or a roll's new ones) there
+            steps_from=self,
         )
         eng.metrics = self.metrics
         eng.decode_degraded = self.decode_degraded
@@ -2561,14 +2695,6 @@ class ServeEngine:
             # and identical geometry means identical tier jaxprs
             eng._restore_block = self._restore_block
             eng._slice_block = self._slice_block
-        if (
-            eng.ragged_attn_impl == self.ragged_attn_impl
-            and eng.epilogue_impl == self.epilogue_impl
-        ):
-            # same resolution → identical jaxpr; a runtime-degraded
-            # process (disable_kernel) rebuilds on the XLA fallback
-            # and compiles it once there, not per restart
-            eng._mixed_step = self._mixed_step
         return eng
 
     def share_compiled_steps(self, src: "ServeEngine") -> None:
@@ -2595,6 +2721,10 @@ class ServeEngine:
         if self.ragged_attn_impl == src.ragged_attn_impl \
                 and self.epilogue_impl == src.epilogue_impl:
             self._mixed_step = src._mixed_step
+            # ... and this engine's weights go where THAT step reads them
+            self._weight_formats = src._weight_formats
+            self.weights_reput = tuple(map(
+                sum, zip(self.weights_reput, self._lay_out_weights())))
 
     def _same_placement(self, src: "ServeEngine") -> bool:
         """Do both engines place params/pool/operands on the same
@@ -4019,19 +4149,24 @@ class ServeEngine:
         pool = opmap.pool_shapes(
             (a.dtype.name, a.sharding.shard_shape(a.shape))
             for a in self.pool.pages.all_arrays())
-        return opmap.merge(
-            opmap.op_map_from_hlo(
-                self._mixed_step.lower(
-                    self.params, self.pool.pages,
-                    self._put(self._dead_mixed_operands(*program)),
-                ).compile().as_text(), STEP_SCOPES, pool,
+        maps = []
+        for program in self.mixed_buckets:
+            text = self._mixed_step.lower(
+                self.params, self.pool.pages,
+                self._put(self._dead_mixed_operands(*program)),
+            ).compile().as_text()
+            # the same text says what the program still re-lays out of
+            # its weights an execution (0 expected: ``_lay_out_weights``)
+            self.weight_relayout_bytes["%dx%d" % program] = sum(
+                nbytes for _, _, nbytes, _ in opmap.weight_relayouts(text))
+            maps.append(opmap.op_map_from_hlo(
+                text, STEP_SCOPES, pool,
                 # the expert layers' way out of the Pallas grouped matmul
                 # (ops/moe.expert_row_tile: the probe refused, matrices
                 # not whole lanes wide): lax.ragged_dot, which a TPU
                 # compiles to custom calls of its own naming
-                named=(("ragged-dot", SCOPE_MOE_EXPERTS),))
-            for program in self.mixed_buckets
-        )
+                named=(("ragged-dot", SCOPE_MOE_EXPERTS),)))
+        return opmap.merge(maps)
 
     def _expert_row_tile(self, dense_width: int) -> int | None:
         """The row tile the expert layers of the program ``dense_width``
@@ -4123,8 +4258,12 @@ class ServeEngine:
             if tracer.get_other("op_map") is None:
                 t_map = tracer.now_us()
                 tracer.set_other("op_map", self.device_op_map())
+                tracer.set_other("weight_relayout_bytes",
+                                 dict(self.weight_relayout_bytes))
                 tracer.complete("op_map", t_map, cat="setup", args={
-                    "buckets": len(self.mixed_buckets)})
+                    "buckets": len(self.mixed_buckets),
+                    "weight_relayout_bytes": sum(
+                        self.weight_relayout_bytes.values())})
 
     def _warmup_body(self, prompt_lens: list[int], max_new_tokens: int,
                      tracer: TraceRecorder | None = None) -> None:

@@ -1536,6 +1536,7 @@ class HttpServer:
             "pool_kv_bytes_shard": stats["kv_bytes_shard"],
             "pool_kv_shards": stats["kv_shards"],
             **engine.pool_form_gauges(),
+            **engine.weight_layout_gauges(),
             "inflight_streams": self.runner.inflight,
             "queue_depth_live": engine.scheduler.queue_depth,
             "draining": 1.0 if self.draining else 0.0,

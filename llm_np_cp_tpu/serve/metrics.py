@@ -1114,11 +1114,15 @@ class ServeMetrics:
                  "accept), publish_lag (accept to the emit)", samples)
         for kind, extras in (("gauge", extra_gauges),
                              ("counter", extra_counters)):
+            series: dict[str, list[tuple[str, float]]] = {}
             for key, value in (extras or {}).items():
                 # ``name{label="x"}``: a sample with labels of its own
+                # (one series a name, however many labelsets it has)
                 name, brace, labels = key.partition("{")
-                emit(name, kind, f"Live server {kind}",
-                     [(brace + labels, float(value))])
+                series.setdefault(name, []).append(
+                    (brace + labels, float(value)))
+            for name, samples in series.items():
+                emit(name, kind, f"Live server {kind}", samples)
         return "\n".join(lines) + "\n"
 
     def format(self) -> str:

@@ -23,6 +23,15 @@ and keyed the way a profile names an operation (``merge``):
 ``benchmark/layers`` and ``tools/summarize_trace.py`` look operations up
 in it and derive nothing.  This module is text processing only and
 imports no JAX.
+
+The same text says whether an execution RE-LAYS OUT a weight
+(``weight_relayouts``): an entry parameter keeps the layout its caller
+gave it, so where the program wants another the compiler copies the whole
+weight, every execution (seven 100 MB ``%copy bf16[1,4096,12288]`` a tick
+of one cell: PERF.md section 6, PR 54).  The engine puts its weights where
+the step reads them when it is built (``ServeEngine.step_weight_formats``);
+the count of what is left is published per program beside the map
+(``otherData["weight_relayout_bytes"]``) and as a ``/metrics`` gauge.
 """
 
 from __future__ import annotations
@@ -47,6 +56,11 @@ _BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
 _TO_APPLY = re.compile(r"\bto_apply=%?([\w.\-]+)")
 _ARRAY = re.compile(r"\b[a-z]+\d*\[[\d,]*\]")
 SHAPE_LIMIT = 200
+_OPERANDS = re.compile(r"%([\w.\-]+)")
+_DTYPE_BITS = re.compile(r"[a-z]+(\d+)")
+# a weight smaller than this is not worth a line: a norm's scale is
+# re-read with the activations it scales, whatever its layout
+RELAYOUT_MIN_ELEMENTS = 1 << 20
 
 _HLO_DTYPES = {
     "bfloat16": "bf16", "float16": "f16", "float32": "f32",
@@ -159,6 +173,79 @@ def op_map_from_hlo(text: str, scopes: Iterable[str],
         todo.extend(calls[comp])
         for name, scope, shape in computations[comp]:
             out[name] = [scope, shape, _pool_kind(shape, pool)]
+    return out
+
+
+def _elements(array: str) -> int:
+    n = 1
+    for d in array[array.index("[") + 1:-1].split(","):
+        n *= int(d) if d else 1
+    return n
+
+
+def _array_bytes(array: str) -> int:
+    bits = _DTYPE_BITS.match(array)
+    return _elements(array) * (int(bits.group(1)) if bits else 8) // 8
+
+
+def weight_relayouts(text: str, argument: str = "params",
+                     min_elements: int = RELAYOUT_MIN_ELEMENTS) -> list[tuple]:
+    """The operations of one compiled module that write a WEIGHT out
+    again in another layout, every execution: ``[(instruction name,
+    result shape, bytes written, the parameter's label)]``.
+
+    Such an operation stands in the ENTRY computation, is a ``copy``, a
+    ``transpose`` or a loop fusion, reads a parameter traced as (part of)
+    the jitted function's ``argument`` (``op_name="params['layers'][0]
+    ['q_proj']"``) - directly, or through what only renames it: a
+    ``bitcast``, or the ``copy-start`` / ``copy-done`` pair that
+    prefetches it into another memory space - and its result has as many
+    elements as that parameter (at least ``min_elements``).  So it is
+    told by its OPERAND: a wide program's activations are as large, and
+    the pool, the recurrent state and the packed operand are other
+    arguments.  A fusion NESTED in another computation (the
+    ``%bitcast_fusion`` inside a matmul's fusion reads the same weight)
+    is part of the operation that calls it and moves nothing of its
+    own."""
+    entry = text.find("\nENTRY ")
+    if entry < 0:
+        return []
+    weights: dict[str, tuple[str, int]] = {}  # name -> (label, elements)
+    out = []
+    for line in text[entry + 1:].splitlines()[1:]:
+        if not line.startswith((" ", "\t")):
+            break  # the entry computation's closing brace
+        bare = _LAYOUT.sub("", line)
+        m = _INSTRUCTION.match(bare)
+        if not m:
+            continue
+        name, shape, opcode = m.groups()
+        if opcode == "parameter":
+            op = _OP_NAME.search(line)
+            arrays = _ARRAY.findall(shape)
+            if op and len(arrays) == 1 and re.match(
+                    rf"{re.escape(argument)}\b", op.group(1)):
+                weights[name] = (op.group(1).replace("\\'", "'"),
+                                 _elements(arrays[0]))
+            continue
+        operands = _OPERANDS.findall(
+            bare[m.end():].split(")", 1)[0])
+        read = [weights[o] for o in operands if o in weights]
+        if not read:
+            continue
+        if opcode in ("bitcast", "copy-start", "copy-done"):
+            weights[name] = read[0]
+            continue
+        if opcode not in ("copy", "transpose") and not (
+                opcode == "fusion" and "kind=kLoop" in line):
+            continue
+        arrays = _ARRAY.findall(shape)
+        if len(arrays) != 1:
+            continue
+        for label, elements in read:
+            if elements >= min_elements and _elements(arrays[0]) == elements:
+                out.append((name, arrays[0], _array_bytes(arrays[0]), label))
+                break
     return out
 
 

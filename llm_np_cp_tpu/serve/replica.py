@@ -1336,6 +1336,7 @@ class ReplicaRunner:
                 "pool_kv_bytes_shard": stats["kv_bytes_shard"],
                 "pool_kv_shards": stats["kv_shards"],
                 **engine.pool_form_gauges(),
+                **engine.weight_layout_gauges(),
                 "inflight_streams": runner.inflight,
                 "queue_depth_live": engine.scheduler.queue_depth,
                 "restarts_total": runner.restarts,

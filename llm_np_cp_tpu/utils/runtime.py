@@ -25,8 +25,10 @@ guard launchers call before starting a child that needs the device.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from pathlib import Path
+from typing import Iterator
 
 REPO_ROOT = Path(__file__).resolve().parents[2]
 DEFAULT_CACHE_DIR = REPO_ROOT / ".jax_cache"
@@ -44,6 +46,34 @@ def configure_compile_cache() -> str:
         return env_dir  # JAX reads the variable itself
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
     return str(DEFAULT_CACHE_DIR)
+
+
+@contextlib.contextmanager
+def compile_cache_bypassed() -> Iterator[None]:
+    """What compiles inside is neither read from the persistent cache nor
+    written to it.  For the ONE kind of program the cache hands back
+    wrong (jax 0.9.0, the CPU and a v5e alike, PERF.md section 6, PR 54):
+    an executable whose RESULT has another layout than the device's
+    default — ``jax.device_put(x, Format(layout))`` is one, a jitted
+    identity with that out-layout — comes back from the cache writing the
+    same bytes but labelling its result with the default layout, so every
+    reader takes a transposed weight for a plain one.  A program whose
+    PARAMETERS have such layouts survives the cache (so the step's
+    programs stay cached).  The switch is JAX's own, process-wide, and
+    read once a process: flipping it means resetting the cache object on
+    both sides (a compile on another thread meanwhile skips the cache,
+    nothing worse)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 def require_uninitialized_backend(what: str) -> None:

@@ -19,7 +19,10 @@ right numbers: ``chip_smoke.py --kernels`` runs the same cases there
 against their XLA twins.
 """
 
+import contextlib
 import functools
+import json
+import pathlib
 import re
 import types
 
@@ -255,20 +258,26 @@ def _compile_widest_bucket(sharding, cache_dtype, blocks=BLOCKS, slots=SLOTS,
     program = program or engine.mixed_buckets[-1]
     assert program in engine.mixed_buckets
     avals.append(aval(engine._dead_mixed_operands(*program)))
-    # the kernels pick interpret mode from the backend they see: show
-    # them the one they are being compiled for (and the expert layers,
-    # which ask the grouped matmul's probe as they are traced, a verdict
-    # no CPU can give)
+    with _traced_for_a_tpu():
+        compiled = engine._mixed_step.lower(*avals).compile()
+    return engine, compiled
+
+
+@contextlib.contextmanager
+def _traced_for_a_tpu():
+    """The kernels pick interpret mode from the backend they see: show
+    them the one they are being compiled for (and the expert layers,
+    which ask the grouped matmul's probe as they are traced, a verdict
+    no CPU can give)."""
     from llm_np_cp_tpu.ops.pallas import support
 
     real, real_error = jax.default_backend, support.kernel_error
     jax.default_backend = lambda: "tpu"
     support.kernel_error = lambda kernel: None
     try:
-        compiled = engine._mixed_step.lower(*avals).compile()
+        yield
     finally:
         jax.default_backend, support.kernel_error = real, real_error
-    return engine, compiled
 
 
 def _pool_ops(engine, compiled):
@@ -1523,3 +1532,114 @@ def test_ling_v3_steady_tick_on_a_v5e_moves_the_matrix_state_in_the_kernel(
         opmap.hlo_shape("float32", state.shape[1:])}
     assert not [n for n, v in ops.items() if v[1] in rows]
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * state.nbytes // 6
+
+
+# ----------------------------------------------------------------------
+# no weight is re-laid-out inside a tick (whole step, for a v5e)
+# ----------------------------------------------------------------------
+#
+# ``jax.jit`` hands a program its arguments in the device's default layout
+# and the compiler may not change an entry parameter's: where a dot wants
+# its weight the other way round it copies the WHOLE weight, every
+# execution.  The engine asks the compiler which layout the steady decode
+# program reads each weight in (``ServeEngine.step_weight_formats``) and
+# puts the weights there when it is built; every program is then compiled
+# for the weights as they lie.  This compiles both ends of the ladder that
+# way for a described v5e, at the cells' published widths, and reads the
+# compiled text for what is left (``serve/opmap.weight_relayouts``).
+
+_CONFIGS = pathlib.Path(__file__).resolve().parent.parent / "benchmark/configs"
+
+# (configuration, the cell's slots and token budget, the keys that cut its
+# depth to one or two layers a kind: every width as published)
+LAYOUT_CASES = [
+    ("qwen2.5-1.5b", 64, None, dict(num_hidden_layers=2)),
+    ("qwen2.5-3b", 64, None, dict(num_hidden_layers=2)),
+    ("lfm2-8b-a1b-16l", 64, None, dict(
+        num_hidden_layers=3, layer_types=["conv", "full_attention", "conv"])),
+    ("falcon-h1-34b-6l", 64, None, dict(num_hidden_layers=1)),
+    ("kanana-2-30b-a3b-24l-ep8", 96, None, dict(num_hidden_layers=2)),
+    ("mimo-v2.5-7l-ep16", 64, 576, dict(
+        num_hidden_layers=3, hybrid_layer_pattern=[0, 1, 0],
+        moe_layer_freq=[0, 1, 1])),
+    ("ling-3.0-flash-7l-ep4", 64, None, dict(
+        num_hidden_layers=2, layer_group_size=2,
+        expert_swiglu_limit_list=[0, 0],
+        share_expert_swiglu_limit_list=[0, 0])),
+    ("trinity-large-5l-ep8", 32, 800, dict(
+        num_hidden_layers=2,
+        layer_types=["sliding_attention", "full_attention"])),
+]
+
+
+@pytest.mark.parametrize("name,slots,budget,cut", LAYOUT_CASES,
+                         ids=[c[0] for c in LAYOUT_CASES])
+def test_no_program_of_the_tick_re_lays_out_a_weight(
+        v5e_sharding, name, slots, budget, cut):
+    """The steady decode program and the widest program of every
+    configuration a cell uses hold NO entry-level ``copy`` / ``transpose``
+    / loop fusion whose operand is a WEIGHT parameter (or a ``bitcast`` /
+    ``copy-done`` of one) and whose result is as large (>= 1 M elements)
+    - told by operand, not by size: a wide program's activations are as
+    large, and the pool, ``kv_write`` and the recurrent / conv state are
+    other arguments.
+
+    Before the engine laid its weights out (the parent of PR 54, weights
+    in the default layout), one tick's program held, at ENTRY level, at
+    the cells' full depth (blocks 1026, chunk 128; bytes WRITTEN a tick):
+
+    - ``mimo-v2.5-7l-ep16``: 7 x ``%copy bf16[1,4096,12288]{1,2,0}
+      copy(params['layers'][i]['q_proj'])`` + 5 x ``k_proj [1,4096,1536]``
+      + 2 x ``k_proj [1,4096,768]``: 780 MB (the chip: 2.2 ms of 21);
+    - ``kanana-2-30b-a3b-24l-ep8``: 24 x ``q_proj [1,2048,6144]`` + 24 x
+      ``kv_b_proj [1,512,8192]``: 805 MB;
+    - ``ling-3.0-flash-7l-ep4``: 6 x ``copy bf16[4096,2560]`` under
+      ``kda_proj/bsh,ho->bso/dot_general`` + ``q_proj [1,2560,6144]`` +
+      ``kv_b_proj [1,512,8192]``: 166 MB;
+    - ``trinity-large-5l-ep8``: 1 x ``copy bf16[3072,25024]
+      copy(params['lm_head'])``: 154 MB;
+    - both Qwen, ``lfm2-8b-a1b-16l``, ``falcon-h1-34b-6l``: none of a
+      weight at ENTRY level.
+
+    Each a transposing copy of a weight PARAMETER where the projection's
+    result is split into heads straight after the dot (64 x 192, the
+    absorbed ``kv_b_proj``) and the compiler folds the split into the
+    dot.  Those four cases fail on that parent, at this test's depth
+    too (327 / 67 / 61 / 154 MB) - and so does ``falcon-h1``: its ONE
+    layer here is no loop, and ``%copy bf16[512,5120]`` of ``v_proj``
+    (5 MB) stands at ENTRY level where the cell's six scanned layers
+    keep it in the loop's body (``%copy bf16[1,5120,512]{1,2,0}`` under
+    ``while/body/dynamic_slice``), out of this parse's sight."""
+    from llm_np_cp_tpu.config import ModelConfig
+
+    spec = json.loads((_CONFIGS / f"{name}.json").read_text())
+    spec.update(cut)
+    cfg = ModelConfig.from_hf_dict(spec)
+    abstract = jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
+    engine = ServeEngine(
+        abstract, cfg, max_slots=slots, num_blocks=258, block_size=BLOCK,
+        max_seq_len=BLOCK * 16, prefill_chunk=128, cache_dtype=jnp.bfloat16,
+        tick_token_budget=budget)
+    assert engine._weight_formats is None  # shapes alone: nothing to put
+
+    def aval(x, sharding=v5e_sharding):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    params, pages = jax.tree.map(aval, abstract), jax.tree.map(
+        aval, engine.pool.pages)
+    steady = engine._pick_bucket(slots * engine._q_tile, slots)
+    with _traced_for_a_tpu():
+        formats = engine.step_weight_formats(params, pages)
+        # the weights as the engine's build leaves them
+        laid = jax.tree.map(
+            lambda a, f: a if f.layout is None else aval(a, f),
+            params, formats)
+        for program in (steady, engine.mixed_buckets[-1]):
+            text = engine._mixed_step.lower(
+                laid, pages, aval(engine._dead_mixed_operands(*program)),
+            ).compile().as_text()
+            left = opmap.weight_relayouts(text)
+            assert not left, (
+                f"program {program} writes {sum(n for _, _, n, _ in left)} B "
+                f"of weights out again every tick: {left[:4]}")

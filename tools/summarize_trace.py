@@ -1089,6 +1089,17 @@ def format_summary(events: list[dict], top: int = 5,
                 + ("carried flat over (layer, block) and written in place"
                    if build["pool_carried"] else
                    "moved by layer slabs (not row-major on this device)"))
+        if "weights_reput" in build:
+            # the layouts the step reads its weights in (PR 54): what the
+            # build moved, and what a program still re-lays out a tick
+            left = next((sp["args"].get("weight_relayout_bytes")
+                         for sp in setup if sp["name"] == "op_map"), None)
+            lines.append(
+                f"  weights: {build['weights_reput']} leaves "
+                f"({build['weights_reput_bytes'] / 2**20:.1f} MiB) put into "
+                "the layout the step reads them in; re-laid-out a tick, "
+                "all programs: " + ("not read" if left is None
+                                    else f"{left / 2**20:.1f} MiB"))
         stray = stray_compiles(events)
         lines.append("  compiles outside set-up: " + (", ".join(
             f"{n} in {where}" for where, n in sorted(stray.items()))
