@@ -45,6 +45,11 @@ def align_capacity(n: int) -> int:
     return -(-n // CAPACITY_ALIGN) * CAPACITY_ALIGN
 
 
+# what a sequence carries besides K/V, as ``ModelConfig.state_shapes`` names
+# it and ``KVCache`` holds it
+STATE_LEAVES = ("conv", "ssm", "kda", "retention", "retention_z")
+
+
 class KVCache(NamedTuple):
     # what a token leaves a layer is the configuration's
     # (``ModelConfig.kv_token_shapes``): K and V per kv head, or latent
@@ -73,6 +78,12 @@ class KVCache(NamedTuple):
     # the history of the convolution over [q | k | v] in front of it, and
     # ``L`` above counts the latent layers only
     kda: jnp.ndarray | None = None  # [n_kda, B, heads, d, d] f32
+    # the two leaves of a configuration with power-retention layers
+    # (config.retention_layers), float32 likewise: a kv head's gated sums of
+    # ``phi(k) v^T`` and of ``k k^T``; ``k`` and ``v`` above then have NO
+    # layer (an attention-free stack: ``L`` is 0)
+    retention: jnp.ndarray | None = None    # [n, B, kv heads, rows, d] f32
+    retention_z: jnp.ndarray | None = None  # [n, B, kv heads, d, d] f32
 
     @classmethod
     def init(

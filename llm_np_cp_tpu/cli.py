@@ -130,6 +130,9 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
     p.add_argument("--num-blocks", type=int, default=0,
                    help="KV pool blocks; 0 sizes the pool so every slot "
                    "can hold a worst-case request plus one spare block.  "
+                   "A model with no layer that has pages (brumby: power "
+                   "retention in every layer) has no page class: 0 blocks "
+                   "whatever is asked, capacity is slots x state.  "
                    "A model whose window layers are a kind of their own "
                    "(mimo_v2, afmoe) gets a second, bounded page class "
                    "beside these, sized by the engine (the window + the "
@@ -234,7 +237,8 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "checkpoint must be: start-up fails unless its "
                    "config's model_type is MODEL_TYPE (llama, mistral, "
                    "mixtral, qwen2, gemma2, lfm2_moe, falcon_h1, "
-                   "deepseek_v3, mimo_v2, ling_hybrid, afmoe) — so that a "
+                   "deepseek_v3, mimo_v2, ling_hybrid, afmoe, brumby) — so "
+                   "that a "
                    "deployment never "
                    "serves another architecture under a model's name")
     p.add_argument("--chaos-spec", default=None, metavar="SPEC",
@@ -750,7 +754,7 @@ def _build_serve_engine(args, params, config, *, prog: str,
                   f"dispatches against {telemetry.hbm_gbps:g} GB/s "
                   "(achieved GB/s + MFU on /metrics, per-request cost "
                   "attribution in the request log)")
-            if config.kda_layers:
+            if config.kda_layers or config.retention_layers:
                 print(f"[{prog}] roofline telemetry: its bill streams every "
                       "weight once a dispatch and knows no recurrent state — "
                       f"it does NOT price model_type {config.model_type!r} "
@@ -843,6 +847,11 @@ def _build_serve_engine(args, params, config, *, prog: str,
         prefill_chunk=chunk,
     )
     num_blocks = args.num_blocks or sized_blocks
+    if not config.has_pages:
+        # no layer has pages (an attention-free stack): the pool has no
+        # page class, capacity is slots x state, and a request's context is
+        # bounded by the model's positions (serve/block_pool.py)
+        num_blocks = 0
     engine = ServeEngine(
         params, config,
         sampler=Sampler(kind=args.sampler),
@@ -886,7 +895,8 @@ def _build_serve_engine(args, params, config, *, prog: str,
         return engine, num_blocks
     if engine.mesh is not None:
         print(f"[{prog}] mesh ACTIVE: {engine.mesh_desc}")
-    state_impl = engine.ssm_state_impl or engine.kda_state_impl
+    state_impl = (engine.ssm_state_impl or engine.kda_state_impl
+                  or engine.retention_state_impl)
     print(f"[{prog}] unified tick ACTIVE: one mixed dispatch/tick, "
           f"budget {engine.tick_token_budget} tokens "
           f"(ragged attention: {engine.ragged_attn_impl}, "

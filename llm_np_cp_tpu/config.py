@@ -267,6 +267,16 @@ class ModelConfig:
     n_group: int = 1
     topk_group: int = 1
 
+    # --- Power-retention layers in EVERY layer (Brumby, ``model_type:
+    # brumby``): an attention-free stack.  ``retention_degree is None`` is
+    # every other family.  A layer's mixer is linear attention whose
+    # feature map is the symmetric ``retention_degree``-th power of the key
+    # (2: the monomials ``k_a k_b``), with a scalar forget gate a KV head
+    # and token and a normaliser (ops/retention.py has the equations): q /
+    # k / v / o projections, q / k RMSNorm and RoPE as a GQA layer has
+    # them, no pages — a float32 state a kv head instead.
+    retention_degree: int | None = None
+
     def __post_init__(self) -> None:
         # Note: hidden_size need not equal heads*head_dim (Gemma-2-2B:
         # 2304 hidden, 8 heads of 256), so no divisibility constraint there.
@@ -323,6 +333,13 @@ class ModelConfig:
                 f"group-limited routing: {self.n_group} groups over "
                 f"{self.num_experts} experts, {self.topk_group} kept, for "
                 f"{self.num_experts_per_tok} experts a token")
+        if self.retention_degree is not None and (
+                self.retention_degree != 2 or self.head_dim % 8
+                or self.value_dim != self.head_dim):
+            raise ValueError(
+                f"power retention is implemented at degree 2 (got "
+                f"{self.retention_degree}) over heads of whole blocks of 8 "
+                f"channels (head_dim {self.head_dim}), values as wide as keys")
         if self.mamba_d_ssm is not None:
             if self.mamba_d_ssm != self.mamba_n_heads * self.mamba_d_head:
                 raise ValueError(
@@ -437,10 +454,13 @@ class ModelConfig:
         """``"attn"``, ``"conv"``, ``"attn_ssm"`` (attention and a
         state-space mixer side by side, both reading one normed input),
         ``"latent"`` (attention over one compressed row a token),
-        ``"kda"`` (delta-rule linear attention: a matrix state, no pages)
-        or ``"swa"`` (a window layer whose pages are a class of their
+        ``"kda"`` (delta-rule linear attention: a matrix state, no pages),
+        ``"retention"`` (power retention: a symmetric-power matrix state a
+        kv head, no pages) or ``"swa"`` (a window layer whose pages are a class of their
         own, ``two_page_classes``, whatever their shape:
         ``attn_kind("window")``): the operator of layer ``layer_idx``."""
+        if self.retention_degree is not None:
+            return "retention"
         if self.layer_group_size is not None and (
                 (layer_idx + 1) % self.layer_group_size):
             return "kda"
@@ -467,7 +487,15 @@ class ModelConfig:
         ``attn_layers[i]``; with two page classes, to ``global_layers[i]``
         / ``window_layers[i]`` of its class)."""
         return tuple(i for i in range(self.num_hidden_layers)
-                     if self.layer_op(i) not in ("conv", "kda"))
+                     if self.layer_op(i) not in STATE_ONLY_OPS)
+
+    @property
+    def has_pages(self) -> bool:
+        """Some layer holds K/V (or a latent row): a cache or a pool has a
+        page class.  False for a stack of state-only operators alone (an
+        attention-free model): the ONE statement of it the pool, the engine
+        and the CLI's sizing read."""
+        return bool(self.attn_layers)
 
     @property
     def conv_layers(self) -> tuple[int, ...]:
@@ -490,6 +518,21 @@ class ModelConfig:
                      if self.layer_op(i) == "kda")
 
     @property
+    def retention_layers(self) -> tuple[int, ...]:
+        """Layers that carry a power-retention state, in order."""
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.layer_op(i) == "retention")
+
+    @property
+    def retention_rows(self) -> int:
+        """Rows of a kv head's power-retention state: the distinct
+        monomials ``k_a k_b`` in whole registers of 8
+        (ops/pallas/retention_state_update.phi_rows: 8,704 where the
+        mathematics needs 8,256, at ``head_dim`` 128)."""
+        nb = self.head_dim // 8
+        return 64 * nb * (nb + 1) // 2
+
+    @property
     def kda_dim(self) -> int:
         """Channels of one of a KDA layer's streams (q, k, v, decay)."""
         return self.num_attention_heads * self.kda_head_dim
@@ -498,7 +541,19 @@ class ModelConfig:
     def carries_state(self) -> bool:
         """A sequence carries more than K/V between steps: a function of
         the WHOLE sequence so far, which no block of a pool holds."""
-        return bool(self.conv_layers or self.ssm_layers or self.kda_layers)
+        return bool(self.state_kind)
+
+    @property
+    def state_kind(self) -> str | None:
+        """What the recurrent state of this stack's layers is, in the words
+        a refusal or a banner uses (None: K/V alone)."""
+        for layers, kind in ((self.conv_layers, "conv"),
+                             (self.kda_layers, "delta-rule"),
+                             (self.ssm_layers, "state-space"),
+                             (self.retention_layers, "power-retention")):
+            if layers:
+                return kind
+        return None
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -514,9 +569,16 @@ class ModelConfig:
         ``ssm`` is the mixer's recurrent state, float32 whatever is
         served: it is rounded once a token for hundreds of tokens; ``kda``
         is a delta-rule layer's matrix state, float32 likewise (its
-        ``conv`` holds the q, k and v streams side by side).  The ONE
-        statement of these shapes: the pool (``PagedKV.state``) and the
-        offline cache (``KVCache.conv`` / ``.ssm`` / ``.kda``) both read
+        ``conv`` holds the q, k and v streams side by side);
+        ``retention`` and ``retention_z`` are a power-retention layer's two
+        leaves, float32 likewise: a kv head's ``S [retention_rows,
+        head_dim]`` (the gated sum of ``phi(k) v^T``) and ``Z [head_dim,
+        head_dim]`` (the gated sum of ``k k^T``: ``phi(q) . z`` for the
+        published sum of keys' features IS ``q^T Z q``) — TWO leaves and
+        not one of ``head_dim + 1`` columns, which a TPU would pad to 256
+        lanes.  The ONE statement of these shapes: the pool
+        (``PagedKV.state``) and the offline cache (``KVCache.conv`` /
+        ``.ssm`` / ``.kda`` / ``.retention`` / ``.retention_z``) both read
         it."""
         out: dict[str, tuple] = {}
         if self.conv_layers:
@@ -534,6 +596,13 @@ class ModelConfig:
                             3 * self.kda_dim), dtype)
             out["kda"] = ((n, slots, self.num_attention_heads, d, d),
                           "float32")
+        if self.retention_layers:
+            lead = (len(self.retention_layers), slots,
+                    self.num_key_value_heads)
+            out["retention"] = (
+                lead + (self.retention_rows, self.head_dim), "float32")
+            out["retention_z"] = (
+                lead + (self.head_dim, self.head_dim), "float32")
         return out
 
     @property
@@ -900,6 +969,8 @@ class ModelConfig:
             )
         if model_type == "afmoe":
             kwargs.update(_afmoe_kwargs(d))
+        if model_type == "brumby":
+            kwargs.update(_brumby_kwargs(d))
         if model_type == "qwen2":
             # Qwen-2/2.5: llama architecture with Q/K/V projection biases
             # and an unbiased o_proj (HF Qwen2Attention), untied head on
@@ -1224,12 +1295,39 @@ def _afmoe_kwargs(d: Mapping[str, Any]) -> dict[str, Any]:
     )
 
 
+def _brumby_kwargs(d: Mapping[str, Any]) -> dict[str, Any]:
+    """``from_hf_dict`` for Brumby (``model_type: brumby``; Brumby-14B-Base):
+    Qwen3's block — pre-norm, q / k RMSNorm over ``head_dim`` before RoPE,
+    no projection bias, dense SwiGLU, an untied head — with EVERY attention
+    layer replaced by power retention of degree 2 (ops/retention.py).  The
+    published config has no key for the degree, the gate or the normaliser
+    (benchmark/configs/brumby-14b-5l.json lists what is ASSUMED);
+    ``max_window_layers`` and ``sliding_window`` are read by nothing.  What
+    has no equations here is refused by its key."""
+    if d.get("rope_scaling") is not None:
+        raise ValueError("brumby with rope_scaling is not implemented (null)")
+    for key in ("use_sliding_window", "attention_bias", "mlp_bias",
+                "tie_word_embeddings"):
+        if d.get(key, False):
+            raise ValueError(f"brumby with {key} is not implemented")
+    return dict(
+        retention_degree=int(d.get("retention_degree", 2)),
+        qk_norm=True,
+        tie_word_embeddings=False,
+    )
+
+
+# operators that carry a state and hold no pages (``attn_layers`` leaves
+# them out; a stack of them alone has no page class at all)
+STATE_ONLY_OPS = ("conv", "kda", "retention")
+
 # model_type values ``from_hf_dict`` has equations for ("mistral" and
 # "mixtral" are the llama block, the latter with capacity-routed experts
 # when ``num_local_experts`` is set)
 KNOWN_MODEL_TYPES = frozenset(
     ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe",
-     "falcon_h1", "deepseek_v3", "mimo_v2", "ling_hybrid", "afmoe"))
+     "falcon_h1", "deepseek_v3", "mimo_v2", "ling_hybrid", "afmoe",
+     "brumby"))
 
 PRESETS: dict[str, ModelConfig] = {
     "meta-llama/Llama-3.2-1B": LLAMA_3_2_1B,
@@ -1376,6 +1474,15 @@ def tiny_config(model_type: str = "llama", **overrides: Any) -> ModelConfig:
             moe_intermediate_size=32, shared_expert_intermediate_size=32,
             use_expert_bias=True, routed_scaling_factor=2.448,
             router_norm_eps=1e-20,
+        )
+    if model_type == "brumby":
+        # Brumby's shape at toy sizes: 2 kv heads read by 4 query heads of
+        # 8 channels (36 distinct monomials a kv head, held as 64 rows), q /
+        # k norms, RoPE, an untied head; no width of the model
+        base.update(
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            rope_theta=1e6, tie_word_embeddings=False, qk_norm=True,
+            retention_degree=2,
         )
     base.update(overrides)
     return ModelConfig(**base)

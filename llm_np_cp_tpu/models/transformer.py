@@ -35,12 +35,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from llm_np_cp_tpu.cache import (
+    STATE_LEAVES,
     KVCache,
     write_at,
     update_layer,
     update_layer_quantized,
 )
-from llm_np_cp_tpu.config import ModelConfig
+from llm_np_cp_tpu.config import STATE_ONLY_OPS, ModelConfig
 from llm_np_cp_tpu.ops.activations import ACT2FN, softcap
 from llm_np_cp_tpu.ops.attention import (
     attend_in_query_blocks,
@@ -96,6 +97,11 @@ SCOPE_ATTN_WINDOW = "attn_window"
 # everything that touches the matrix state (ops/kda.py)
 SCOPE_KDA_PROJ = "kda_proj"
 SCOPE_KDA_SCAN = "kda_scan"
+# a power-retention layer adds two, likewise: the input norm, the q / k / v
+# / gate projections, the q / k norms, RoPE and ``o_proj``, and everything
+# that touches the state (ops/retention.py)
+SCOPE_RETENTION_PROJ = "retention_proj"
+SCOPE_RETENTION_SCAN = "retention_scan"
 # an output gate on attention adds one: the gate's projection, its
 # sigmoid and the product with the attention's result (before ``o_proj``)
 SCOPE_ATTN_GATE = "attn_gate"
@@ -103,7 +109,8 @@ SCOPE_ATTN_GATE = "attn_gate"
 HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
                  SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, SCOPE_MOE_SHARED,
                  SCOPE_ATTN_GLOBAL, SCOPE_ATTN_WINDOW,
-                 SCOPE_KDA_PROJ, SCOPE_KDA_SCAN, SCOPE_ATTN_GATE)
+                 SCOPE_KDA_PROJ, SCOPE_KDA_SCAN, SCOPE_ATTN_GATE,
+                 SCOPE_RETENTION_PROJ, SCOPE_RETENTION_SCAN)
 STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
                SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL) + HYBRID_SCOPES
 
@@ -188,9 +195,20 @@ def _group_shapes(
     NH, NK = config.num_attention_heads, config.num_key_value_heads
     if config.attention_bias or config.mlp_bias or config.conv_bias:
         raise NotImplementedError("a hybrid stack has no biased projection")
-    if op not in ("conv", "attn", "attn_ssm", "latent", "swa", "kda"):
+    if op not in ("conv", "attn", "attn_ssm", "latent", "swa", "kda",
+                  "retention"):
         raise ValueError(f"unknown layer operator {op!r}")
-    if op == "kda":
+    if op == "retention":
+        # power retention (ops/retention.py): a GQA layer's projections and
+        # q / k norms, and one forget-gate logit a KV head
+        shapes = {
+            "ln_attn_in": (n, H),
+            "q_proj": (n, H, NH * D), "k_proj": (n, H, NK * D),
+            "v_proj": (n, H, NK * D), "o_proj": (n, NH * D, H),
+            "ln_q": (n, D), "ln_k": (n, D),
+            "ret_gate_proj": (n, H, NK),
+        }
+    elif op == "kda":
         # delta-rule linear attention (ops/kda.py): four full-rank
         # projections onto heads x kda_head_dim channels (q, k, v and the
         # per-channel decay's), a scalar a head for beta and for the
@@ -1028,6 +1046,50 @@ def kda_block(
                             x.dtype)
 
 
+def retention_block(
+    w: Params,
+    x: jnp.ndarray,
+    *,
+    config: ModelConfig,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    scan: Any,
+    token_mask: jnp.ndarray | None = None,
+) -> jnp.ndarray:
+    """A power-retention layer's operator (Brumby's) with its residual.
+    With ``h`` the block's input norm: ``q = RoPE(RMSNorm_d(W_q h))``, ``k =
+    RoPE(RMSNorm_d(W_k h))``, ``v = W_v h`` as a GQA layer has them, the
+    log-gate ``log g = logsigmoid(W_g h)`` one a KV head (float32 from the
+    projection's accumulator on); the recurrence (ops/retention.py), float32;
+    then ``W_o`` over the heads' results side by side.
+
+    scan: ``(q [B, S, H, d], k, v [B, S, Hk, d], log_g [B, S, Hk]) -> o [B,
+        S, H, d]`` float32 — the recurrence over the tokens as the CALLER
+        lays sequences out, which owns the state (a cache's, the tick's
+        rows).
+    token_mask: ``[b, s]`` bool — False at padding, which does not move the
+        state (``k = 0``, ``log g = 0``)."""
+    b_, s_ = x.shape[:2]
+    d = config.head_dim
+    f32 = jnp.float32
+    with jax.named_scope(SCOPE_RETENTION_PROJ):
+        h = input_norm(w, x, config)
+        q = _project(h, w["q_proj"]).reshape(b_, s_, -1, d)
+        k = _project(h, w["k_proj"]).reshape(b_, s_, -1, d)
+        v = _project(h, w["v_proj"]).reshape(b_, s_, -1, d)
+        q = apply_rope(rms_norm(q, w["ln_q"], eps=config.rms_norm_eps), cos, sin)
+        k = apply_rope(rms_norm(k, w["ln_k"], eps=config.rms_norm_eps), cos, sin)
+        log_g = jax.nn.log_sigmoid(_project(h, w["ret_gate_proj"], f32))
+        if token_mask is not None:
+            k = jnp.where(token_mask[..., None, None], k, jnp.zeros_like(k))
+            log_g = jnp.where(token_mask[..., None], log_g, 0.0)
+    with jax.named_scope(SCOPE_RETENTION_SCAN):
+        o = scan(q, k, v, log_g)
+    with jax.named_scope(SCOPE_RETENTION_PROJ):
+        return x + _project(o.astype(h.dtype).reshape(b_, s_, -1),
+                            w["o_proj"], x.dtype)
+
+
 def shifted_history(state: jnp.ndarray, z: jnp.ndarray, taps: int) -> tuple:
     """A convolution's ``history`` hook over whole sequences ``z [B, S,
     C]`` that continue ``state [B, taps - 1, C]`` (zeros before a
@@ -1227,8 +1289,9 @@ def _hybrid_stack(
     over its own stacked leaves, an attention run carrying its cache
     slabs, a conv run its short-convolution state and a run with a
     state-space mixer both and the recurrent state as ``xs`` / ``ys``.
-    Returns ``(x, (k, v) | None, {"conv", "ssm", "kda"} states | None each,
-    chosen experts [expert layers, B, S, k])``."""
+    Returns ``(x, (k, v) | None, {"conv", "ssm", "kda", "retention",
+    "retention_z"} states | None each, chosen experts [expert layers, B, S,
+    k])``."""
     if cache is not None and (cache.quantized or offset.ndim == 1):
         raise NotImplementedError(
             "a hybrid layer stack runs a float cache with one length: an "
@@ -1248,23 +1311,24 @@ def _hybrid_stack(
     # batch shapes drift apart an ulp at a time, and a router turns such
     # a drift into another expert (measured on the chip: PERF.md §6)
     stream_dtype, x = x.dtype, x.astype(jnp.float32)
-    new_k, new_v, new_conv, new_ssm, new_kda, experts = [], [], [], [], [], []
+    new_k, new_v, experts = [], [], []
+    new_state: dict[str, list] = {name: [] for name in STATE_LEAVES}
     a0 = c0 = 0  # layers with K/V / with a state seen so far
     # what a sequence carries besides K/V (zeros without a cache: every
     # sequence starts here), as ``config.state_shapes`` lays it out
     fresh = ({name: jnp.zeros(shape, dt) for name, (shape, dt)
               in config.state_shapes(b, stream_dtype).items()}
-             if cache is None else {"conv": cache.conv, "ssm": cache.ssm,
-                                    "kda": cache.kda})
+             if cache is None else
+             {name: getattr(cache, name) for name in STATE_LEAVES})
     for w_g, (op, ff, _, n) in zip(groups, config.layer_groups()):
         xs: dict[str, Any] = {}
-        if op not in ("conv", "kda"):
+        if op not in STATE_ONLY_OPS:
             if cache is not None:
                 xs["k"] = cache.k[a0:a0 + n]
                 if cache.v is not None:  # a latent row has no V beside it
                     xs["v"] = cache.v[a0:a0 + n]
             a0 += n
-        if op in ("conv", "attn_ssm", "kda"):
+        if op in STATE_ONLY_OPS or op == "attn_ssm":
             xs.update({name: a[c0:c0 + n] for name, a in fresh.items()
                        if a is not None})
             c0 += n
@@ -1298,6 +1362,18 @@ def _hybrid_stack(
 
                 x = kda_block(w, x, config=config, history=history,
                               scan=scan, token_mask=token_mask)
+            elif op == "retention":
+                from llm_np_cp_tpu.ops import retention
+
+                def scan(q, k, v, log_g):
+                    o, ys["retention"], ys["retention_z"] = (
+                        retention.retention_scan(
+                            state["retention"], state["retention_z"], q, k,
+                            v, log_g, chunk=retention.CHUNK))
+                    return o
+
+                x = retention_block(w, x, config=config, cos=cos, sin=sin,
+                                    scan=scan, token_mask=token_mask)
             elif op != "conv":
                 normed = input_norm(w, x, config) if op == "attn_ssm" else None
                 l_cos, l_sin = (rope_window if op == "swa" else
@@ -1345,18 +1421,19 @@ def _hybrid_stack(
         if "v" in ys:
             new_v.append(ys["v"])
         if cache is not None:
-            if "conv" in ys:
-                new_conv.append(ys["conv"].astype(cache.conv.dtype))
-            if "ssm" in ys:
-                new_ssm.append(ys["ssm"])
-            if "kda" in ys:
-                new_kda.append(ys["kda"])
+            for name in STATE_LEAVES:
+                if name in ys:
+                    new_state[name].append(
+                        ys[name].astype(getattr(cache, name).dtype))
         if "experts" in ys:
             experts.append(ys["experts"])
     cat = lambda parts: jnp.concatenate(parts, axis=0) if parts else None
     x = x.astype(stream_dtype)
-    return (x, (cat(new_k), cat(new_v)) if cache is not None else None,
-            {"conv": cat(new_conv), "ssm": cat(new_ssm), "kda": cat(new_kda)},
+    # (a stack with no layer that has pages hands its empty slabs back)
+    return (x, ((cat(new_k) if new_k else cache.k,
+                 cat(new_v) if new_v else cache.v)
+                if cache is not None else None),
+            {name: cat(parts) for name, parts in new_state.items()},
             cat(experts))
 
 def forward(

@@ -59,6 +59,7 @@ KERNELS = (
     "grouped_matmul",
     "ssm_state_update",
     "kda_state_update",
+    "retention_state_update",
 )
 
 # Pool block sizes the serve path uses (cli --block-size default 64,
@@ -94,6 +95,11 @@ class KernelShape:
     # a delta-rule layer's matrix state ``(layers, rows, heads, d)`` (a
     # head's ``[d, d]``), which ``kda_state_update`` alone advances
     kda_state: tuple[int, ...] | None = None
+    # a power-retention layer's state ``(layers, rows, kv heads, d)`` (a kv
+    # head's ``[phi_rows(d), d]`` and ``[d, d]``, read through ``heads /
+    # kv_heads`` query heads), which ``retention_state_update`` alone
+    # advances
+    retention_state: tuple[int, ...] | None = None
 
     @classmethod
     def of(cls, name: str, config) -> "KernelShape":
@@ -157,6 +163,20 @@ KDA_PROBE_SHAPE = KernelShape(
 )
 LING_V3_STATE_SHAPE = dataclasses.replace(
     KDA_PROBE_SHAPE, name="ling-3.0-flash-7l-ep4", kda_state=(6, 64, 32, 128))
+
+
+# ... and the power-retention state update's: two kv heads of the served
+# head (8,704 x 128 + 128 x 128 float32: 4.3 MiB each) read by a group of
+# five, and the benchmark's cell (Brumby-14B: 32 slots of 8 kv heads read by
+# 40 query heads; TWO of its five layers, 2.2 GiB — a call moves one layer's
+# rows, and the matrix holds the state twice while it compares)
+RETENTION_PROBE_SHAPE = KernelShape(
+    "probe/retention", heads=10, kv_heads=2, head_dim=128, hidden=256,
+    vocab=300, retention_state=(2, 3, 2, 128),
+)
+BRUMBY_STATE_SHAPE = dataclasses.replace(
+    RETENTION_PROBE_SHAPE, name="brumby-14b-5l", heads=40, kv_heads=8,
+    retention_state=(2, 32, 8, 128))
 
 
 def family_shapes() -> tuple[KernelShape, ...]:
@@ -524,6 +544,47 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
 
         return jax.jit(make_args), jax.jit(run), reference
 
+    if base == "retention_state_update":
+        from llm_np_cp_tpu.ops.pallas import retention_state_update as rsu
+
+        # a tick of a layer's rows (ops/retention.retention_packed's first
+        # pass): a row of every three has no token in it, a row of every
+        # three starts from nothing; the twin is the same step in plain
+        # jnp.  q and k are of order 1 a channel (an RMSNorm's), the
+        # output a weighted mean of values of order 1
+        layers, rows, nh, d = shape.retention_state
+        group = shape.heads // shape.kv_heads
+        layer = layers - 1
+        f32 = jnp.float32
+
+        def make_args():
+            s, z, gate, k, q, v = normals(
+                (layers, rows, nh, rsu.phi_rows(d), d),
+                (layers, rows, nh, d, d), (rows, nh), (rows, nh, d),
+                (rows, nh, group, d), (rows, nh, d), dtype=f32)
+            row = jnp.arange(rows)
+            count = jnp.where(row % 3 == 1, 0, 1 + row % 4).astype(jnp.int32)
+            # a state some keys have been summed into: Z positive definite
+            z = jnp.einsum("lrhab,lrhcb->lrhac", z, z) / d
+            return (s, z, jax.nn.sigmoid(gate), k, q, v, count,
+                    (row % 3 == 0) & (count > 0))
+
+        def flat(o, s, z):
+            return jnp.concatenate(
+                [o.ravel(), z[layer].ravel(), s[layer].ravel()])
+
+        def run(s, z, gate, k, q, v, count, fresh):
+            return flat(*rsu.retention_state_update(
+                s, z, jnp.int32(layer), gate, k, q, v, count=count,
+                fresh=fresh, interpret=interpret))
+
+        def reference(s, z, gate, k, q, v, count, fresh):
+            return flat(*rsu.retention_state_update_xla(
+                s, z, jnp.int32(layer), gate, k, q, v, count=count,
+                fresh=fresh))
+
+        return jax.jit(make_args), jax.jit(run), reference
+
     if base == "sample_epilogue":
         from llm_np_cp_tpu.ops.norms import rms_norm
         from llm_np_cp_tpu.ops.pallas.sample_epilogue import sample_epilogue
@@ -580,7 +641,7 @@ def kernel_cases(shapes=None):
     shapes = shapes if shapes is not None else (
         *PROBE_SHAPES, LATENT_PROBE_SHAPE, STATE_PROBE_SHAPE,
         FALCON_H1_STATE_SHAPE, KDA_PROBE_SHAPE, LING_V3_STATE_SHAPE,
-        *family_shapes())
+        RETENTION_PROBE_SHAPE, BRUMBY_STATE_SHAPE, *family_shapes())
     for shape in shapes:
         for kernel in KERNELS:
             if (kernel == "ragged_latent_attention") != (
@@ -591,6 +652,9 @@ def kernel_cases(shapes=None):
             if (kernel == "kda_state_update") != (
                     shape.kda_state is not None):
                 continue  # a matrix state and its one kernel
+            if (kernel == "retention_state_update") != (
+                    shape.retention_state is not None):
+                continue  # a power-retention state and its one kernel
             if not shape.tied and not kernel.startswith("sample_epilogue"):
                 continue  # only the epilogue distinguishes head layouts
             paged = kernel.startswith("ragged_")
@@ -649,7 +713,8 @@ def _probe(kernel: str, backend: str) -> str | None:
 def _compile_and_run(kernel: str) -> str | None:
     own = {"ragged_latent_attention": (LATENT_PROBE_SHAPE,),
            "ssm_state_update": (STATE_PROBE_SHAPE,),
-           "kda_state_update": (KDA_PROBE_SHAPE,)}
+           "kda_state_update": (KDA_PROBE_SHAPE,),
+           "retention_state_update": (RETENTION_PROBE_SHAPE,)}
     try:
         for shape in own.get(kernel, PROBE_SHAPES):
             if shape.tied or kernel.startswith("sample_epilogue"):

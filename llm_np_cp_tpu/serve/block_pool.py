@@ -41,6 +41,8 @@ pytree keyed by what the configuration's layers carry
     state["conv"]: [layers, slots, taps - 1, channels]     served dtype
     state["ssm"]:  [layers, slots, heads, d_head, d_state]  float32
     state["kda"]:  [layers, slots, heads, d, d]             float32
+    state["retention"]:   [layers, slots, kv heads, rows, d]  float32
+    state["retention_z"]: [layers, slots, kv heads, d, d]     float32
 
 one row a slot, no blocks: the last inputs of a short convolution (a conv
 layer's, or the one in front of a state-space mixer or of a delta-rule
@@ -52,6 +54,14 @@ different layers: a delta-rule stack's one latent layer a group has pages
 place at ``[layer, row]`` like the pages (donated); a slot's row is never
 read by a sequence's first token (a position-0 token's history and state
 are zero), so admitting a request into a freed slot needs no clear.
+
+A stack may also have a state and NO pages at all (``config.attn_layers``
+empty: an attention-free model, every layer power retention).  Its pool has
+no page class: ``k`` and ``v`` are arrays of no layer and no block, the
+allocator is ``NoBlocks`` (capacity 0: nothing to hand out, nothing to run
+out of), ``blocks_for`` is 0 for any length, and capacity is slots x state
+and nothing else — a request is admitted by a free slot, never preempted for
+a block, and its context is bounded by the model's positions alone.
 
 A configuration whose WINDOW layers are a kind of their own
 (``config.two_page_classes``) has TWO page classes in this one manager.  A
@@ -182,6 +192,28 @@ class FreeList:
             if self._ref[i] == 0:
                 del self._ref[i]
                 self._free.append(i)
+
+
+class NoBlocks:
+    """``FreeList``'s interface over no blocks at all: the allocator of a
+    pool with no page class (module docstring).  A request needs 0 blocks
+    and gets them; nothing else can be asked of it."""
+
+    num_blocks = num_free = num_allocated = capacity = 0
+
+    def refcount(self, block_id: int) -> int:
+        return 0
+
+    def alloc(self, n: int) -> list[int] | None:
+        return [] if n == 0 else None
+
+    def incref(self, ids: list[int]) -> None:
+        if ids:
+            raise ValueError(f"incref on {ids}: the pool has no page class")
+
+    def free(self, ids: list[int]) -> None:
+        if ids:
+            raise ValueError(f"free of {ids}: the pool has no page class")
 
 
 def window_blocks_per_slot(window: int, widest_slice: int,
@@ -415,7 +447,12 @@ class BlockPool:
         self.config = config
         self.block_size = block_size
         self.dtype = jnp.dtype(dtype)
-        self.free_list = FreeList(num_blocks)
+        # a stack with no layer that has pages has no page class (module
+        # docstring): whatever was asked for, there are no blocks
+        self.paged = config.has_pages
+        if not self.paged:
+            num_blocks = 0
+        self.free_list = FreeList(num_blocks) if self.paged else NoBlocks()
         if enable_prefix_cache:
             from llm_np_cp_tpu.serve.prefix_cache import PrefixCache
 
@@ -551,8 +588,9 @@ class BlockPool:
         return (self.capacity - self.num_free) / max(self.capacity, 1)
 
     def blocks_for(self, n_tokens: int) -> int:
-        """Blocks needed to hold ``n_tokens`` cache slots."""
-        return -(-n_tokens // self.block_size)
+        """Blocks needed to hold ``n_tokens`` cache slots (none, of a
+        pool with no page class)."""
+        return -(-n_tokens // self.block_size) if self.paged else 0
 
     def stats(self) -> dict[str, int]:
         """Point-in-time accounting for scrapes and tests: raw free-list

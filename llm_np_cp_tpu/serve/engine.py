@@ -91,7 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from llm_np_cp_tpu.config import ModelConfig
+from llm_np_cp_tpu.config import STATE_ONLY_OPS, ModelConfig
 from llm_np_cp_tpu.generate import IncrementalDetok
 from llm_np_cp_tpu.models.transformer import (
     SCOPE_ATTN_GLOBAL,
@@ -99,6 +99,7 @@ from llm_np_cp_tpu.models.transformer import (
     SCOPE_CONV,
     SCOPE_EMBED,
     SCOPE_KDA_PROJ,
+    SCOPE_RETENTION_PROJ,
     SCOPE_SSM_PROJ,
     SCOPE_TAIL,
     attention_block,
@@ -110,12 +111,14 @@ from llm_np_cp_tpu.models.transformer import (
     input_norm,
     kda_block,
     latent_attention_block,
+    retention_block,
     run_decoder_layer,
     scan_group,
     scan_unroll,
     ssm_block,
 )
 from llm_np_cp_tpu.ops import kda as kda_ops
+from llm_np_cp_tpu.ops import retention as retention_ops
 from llm_np_cp_tpu.ops import ssm as ssm_ops
 from llm_np_cp_tpu.ops.activations import ACT2FN
 from llm_np_cp_tpu.ops.moe import (
@@ -214,6 +217,12 @@ def _pack_sync(
     )
 
 
+# the sections of the packed operand that say where a token lies in a pool
+# and which attention tile it is in: what a pool with no page class leaves out
+PAGE_SECTIONS = ("tok_blk", "tok_off", "tok_slot", "tok_lane", "lane_tok",
+                 "tile_row", "tile_qpos0", "tile_qlen", "tables")
+
+
 def mixed_operand_layout(
     t_w: int, d_w: int, q_tile: int, max_slots: int, max_blocks: int,
     spec_w: int, window_blocks: int = 0,
@@ -243,7 +252,12 @@ def mixed_operand_layout(
     window class for this tick, and ``wfirst``, the logical block its
     column 0 is (the chain's first block is not position 0); where a
     token is written in that class follows from the two in-graph.  A pool
-    with one class has neither section: its operand is what it was."""
+    with one class has neither section: its operand is what it was.  A pool
+    with NO page class (``max_blocks`` 0: no layer of the stack has pages,
+    serve/block_pool.py) has no block, no cache slot and no attention tile
+    to speak of: its operand is the dense token axis and the rows' sections
+    (``PAGE_SECTIONS`` are left out), and its programs differ by ``d_w``
+    alone."""
     nt = t_w // q_tile
     shapes = {
         "tokens": (d_w,),      # packed input ids
@@ -266,6 +280,9 @@ def mixed_operand_layout(
     if window_blocks:
         shapes["wtables"] = (max_slots, window_blocks)  # scratch-0 padded
         shapes["wfirst"] = (max_slots,)  # logical block of column 0
+    if not max_blocks:
+        for name in PAGE_SECTIONS:
+            del shapes[name]
     layout, size = {}, 0
     for name, shape in shapes.items():
         layout[name] = (size, shape)
@@ -486,12 +503,11 @@ class ServeEngine:
                  "--mesh model>1 (mesh_plan): the state and the mixer / "
                  "conv / expert weights have no sharding rule"),
             ]
-            kind = ("conv" if config.conv_layers else
-                    "delta-rule" if config.kda_layers else "state-space")
             for hit, why in refused:
                 if hit:
                     raise ValueError(
-                        f"model_type {config.model_type!r} has {kind} layers "
+                        f"model_type {config.model_type!r} has "
+                        f"{config.state_kind} layers "
                         f"with a recurrent state; refused: {why}")
         if config.is_latent:
             # a latent pool holds one row a token and layer, no K and V
@@ -714,6 +730,13 @@ class ServeEngine:
         # gather width S_max = max_blocks_per_seq * block_size)
         self.max_seq_len = _ceil_to(max_seq_len, block_size)
         self.max_blocks_per_seq = self.max_seq_len // block_size
+        # a stack with no layer that has pages (serve/block_pool.py): no
+        # table, no block, and a request's context bounded by the model's
+        # positions alone
+        self._paged = config.has_pages
+        if not self._paged:
+            self.max_seq_len = config.max_position_embeddings
+            self.max_blocks_per_seq = 0
         # prefix-share granularity in BLOCKS: shared prefixes must cover
         # whole blocks (pool granularity) AND whole prefill chunks (so
         # skipped prefill work is exactly the shared region — a partial
@@ -769,6 +792,8 @@ class ServeEngine:
                 self._prefill_width(req)
             ),
             prefill_plan=self._prefill_plan,
+            # (a pool with no page class has no block to keep spare)
+            decode_reserve=int(self._paged),
             max_queue=max_queue,
             on_slot_release=(self.pool.window.release
                              if self.pool.window is not None else None),
@@ -786,6 +811,7 @@ class ServeEngine:
         # scale pages) — the unit every tier ledger counts in
         self._block_nbytes = int(sum(
             a.nbytes // a.shape[1] for a in self.pool.pages.pool_arrays()
+            if a.size
         ))
         # per-tick tier observables (engine-thread-owned, reset at tick
         # start, reported in the tick trace args when the tier is on)
@@ -922,6 +948,18 @@ class ServeEngine:
                 tracer.complete(
                     "probe.kda_state_update", t_probe, cat="setup",
                     args={"ok": self.kda_state_impl == "pallas"})
+        # ... and a power-retention layer's state likewise
+        # (ops/pallas/retention_state_update / its twin)
+        self.retention_state_impl: str | None = None
+        if config.retention_layers:
+            t_probe = tracer.now_us() if tracer is not None else -1.0
+            self.retention_state_impl = (
+                "pallas" if retention_ops.state_update_impl(
+                    self.pool.pages.state["retention"]) else "xla")
+            if tracer is not None:
+                tracer.complete(
+                    "probe.retention_state_update", t_probe, cat="setup",
+                    args={"ok": self.retention_state_impl == "pallas"})
         # -- the tick: ONE jitted program, bucketed packed width —
         # prefill K/V goes straight into pool blocks and sampling
         # happens inside the mixed step.
@@ -929,7 +967,9 @@ class ServeEngine:
             RAGGED_Q_TILE,
         )
 
-        self._q_tile = RAGGED_Q_TILE
+        # (no attention, no tile: a token is its own lane, and the tiled
+        # width of a program is its dense width)
+        self._q_tile = RAGGED_Q_TILE if self._paged else 1
         # verify-lane width of the compiled step: every row carries
         # spec_k+1 sample slots ([R, W] last_idx/sample_pos operands
         # and an [R, W] token return) — plain rows use column 0 and
@@ -1048,13 +1088,20 @@ class ServeEngine:
         # each of up to max_slots segments wastes < qb lanes to alignment
         a_max = _ceil_to(budget + max_slots * (qb - 1), qb)
         ladder = []
-        t = qb
+        # (the first rung: a tile, which where no layer has pages would be
+        # one token; 8 tokens there)
+        t = max(qb, 8)
         while t < a_max:
             ladder.append(t)
             t *= 2
         ladder.append(a_max)
         cap = _ceil_to(budget, qb)
         programs = [(t, min(t, cap)) for t in ladder]
+        if not self._paged:
+            # a program IS its dense width: the steady tick's rung holds
+            # the rows already, and a second program of that rung would be
+            # a second name for it
+            return tuple(sorted(set(programs)))
         t_rows = next(t for t in ladder if t >= max_slots * qb)
         d_rows = _ceil_to(max_slots * self._spec_w, qb)
         # the operand's length is all the jitted step knows of its
@@ -1757,7 +1804,9 @@ class ServeEngine:
         stop_tokens = self.stop_tokens
         big_win = jnp.int32(1 << 30)
         constrain_pages = self._constrain_pages
-        carry_pool = self.pool_carried = _pool_is_row_major(self.pool.pages)
+        # (a pool with no page class holds nothing a device could permute)
+        carry_pool = self.pool_carried = (
+            not self._paged or _pool_is_row_major(self.pool.pages))
         geometry, programs = self._mixed_geometry, self.mixed_buckets
         attn_call = self._shard_attn(
             partial(
@@ -1780,7 +1829,9 @@ class ServeEngine:
         window_blocks = self.window_blocks
         # the scope of the bookkeeping every layer's state shares
         state_scope = (SCOPE_SSM_PROJ if config.ssm_layers else
-                       SCOPE_KDA_PROJ if config.kda_layers else SCOPE_CONV)
+                       SCOPE_KDA_PROJ if config.kda_layers else
+                       SCOPE_RETENTION_PROJ if config.retention_layers else
+                       SCOPE_CONV)
 
         @partial(jax.jit, donate_argnums=(1,))
         def mixed_step(
@@ -1792,14 +1843,20 @@ class ServeEngine:
                 o = split_mixed_operands(ops, mixed_operand_layout(
                     *mixed_operand_program(ops.shape[0], programs, *geometry),
                     *geometry)[0])
-                tokens, tables, pads = o["tokens"], o["tables"], o["pads"]
-                tok_blk, tok_off = o["tok_blk"], o["tok_off"]
-                tok_row, tok_slot = o["tok_row"], o["tok_slot"]
-                tok_live, seeds = o["tok_live"], o["seeds"]
-                tile_row, tile_qpos0 = o["tile_row"], o["tile_qpos0"]
-                tile_qlen, verify_len = o["tile_qlen"], o["verify_len"]
+                tokens, pads = o["tokens"], o["pads"]
+                tok_row, tok_live, seeds = o["tok_row"], o["tok_live"], o["seeds"]
+                verify_len = o["verify_len"]
                 last_idx, sample_pos = o["last_idx"], o["sample_pos"]
-                lane_tok, tok_lane = o["lane_tok"], o["tok_lane"]
+                # where a token lies in the pool and in attention's tiles
+                # (absent from the operand of a pool with no page class,
+                # whose stack has no layer that would read them)
+                tables, tok_blk, tok_off, tok_slot = (
+                    o.get(name) for name in
+                    ("tables", "tok_blk", "tok_off", "tok_slot"))
+                tile_row, tile_qpos0, tile_qlen, lane_tok, tok_lane = (
+                    o.get(name) for name in
+                    ("tile_row", "tile_qpos0", "tile_qlen", "lane_tok",
+                     "tok_lane"))
                 x = embed_inputs(params, tokens[None, :], config)  # [1, D, H]
                 cos, sin = rope_cos_sin(
                     o["positions"][None, :], config, dtype=jnp.float32
@@ -2147,7 +2204,8 @@ class ServeEngine:
                     [joined[1:], jnp.zeros((1,), jnp.bool_)])
                 # a row's last token of the tick leaves the row's state
                 row_out = jnp.where(ends, tok_row, max_slots)  # else: dropped
-                if config.ssm_layers or config.kda_layers:
+                if (config.ssm_layers or config.kda_layers
+                        or config.retention_layers):
                     # the rows as the recurrence advances them: where a
                     # row's tokens start, how many it has, whether its
                     # sequence starts here (a slot's old state is never
@@ -2200,10 +2258,10 @@ class ServeEngine:
                 if op == "swa":
                     xs["paged"] = jnp.arange(w0, w0 + n, dtype=jnp.int32)
                     w0 += n
-                elif op not in ("conv", "kda"):
+                elif op not in STATE_ONLY_OPS:
                     xs["paged"] = layers[a0:a0 + n]
                     a0 += n
-                if op in ("conv", "attn_ssm", "kda"):
+                if op in STATE_ONLY_OPS or op == "attn_ssm":
                     xs["state"] = jnp.arange(c0, c0 + n, dtype=jnp.int32)
                     c0 += n
 
@@ -2232,6 +2290,18 @@ class ServeEngine:
 
                         x = kda_block(w, x, config=config, history=history,
                                       scan=scan)
+                    elif op == "retention":
+                        def scan(q, k, v, log_g):
+                            (o, state["retention"], state["retention_z"]
+                             ) = retention_ops.retention_packed(
+                                state["retention"], state["retention_z"],
+                                at["state"], q[0], k[0], v[0], log_g[0],
+                                tok_row=tok_row, start=start, count=count,
+                                fresh=fresh, chunk=retention_ops.CHUNK)
+                            return o[None]
+
+                        x = retention_block(
+                            w, x, config=config, cos=cos, sin=sin, scan=scan)
                     elif op == "latent":
                         # one array of rows, always carried flat
                         kv_update, attn_fn = latent_hooks(
@@ -3205,38 +3275,40 @@ class ServeEngine:
         ``_fill_segment`` writes for each, without a numpy call per row
         and field.  The block table is the one write left per row."""
         bs = self.block_size
-        tables = sec["tables"]
+        tables = sec.get("tables")  # None: a pool with no page class
         slot, tok, sl, pad, seed, blk = [], [], [], [], [], []
         for r in rows:
-            ids = r.block_ids
             last = r.cache_len - 1
-            tables[r.slot, :len(ids)] = ids
+            if tables is not None:
+                ids = r.block_ids
+                tables[r.slot, :len(ids)] = ids
+                blk.append(ids[last // bs])
             slot.append(r.slot)
             tok.append(r.generated[-1])
             sl.append(last)
             pad.append(r.pad)
             seed.append(r.seed)
-            blk.append(ids[last // bs])
         slot = np.asarray(slot, np.intp)
         sl = np.asarray(sl, np.int32)
         if self.window_blocks:
             self._advance_window(sec, slot, sl, 1)
         pos = sl - np.asarray(pad, np.int32)
         cur = np.asarray(curs, np.intp)
-        lane = np.asarray(lanes, np.intp)
-        tile = lane // self._q_tile
         sec["tokens"][cur] = tok
         sec["positions"][cur] = pos
-        sec["tok_blk"][cur] = blk
-        sec["tok_off"][cur] = sl % bs
         sec["tok_row"][cur] = slot
-        sec["tok_slot"][cur] = sl
         sec["tok_live"][cur] = 1
-        sec["tok_lane"][cur] = lane
-        sec["lane_tok"][lane] = cur
-        sec["tile_row"][tile] = slot
-        sec["tile_qpos0"][tile] = sl
-        sec["tile_qlen"][tile] = 1
+        if tables is not None:
+            lane = np.asarray(lanes, np.intp)
+            tile = lane // self._q_tile
+            sec["tok_blk"][cur] = blk
+            sec["tok_off"][cur] = sl % bs
+            sec["tok_slot"][cur] = sl
+            sec["tok_lane"][cur] = lane
+            sec["lane_tok"][lane] = cur
+            sec["tile_row"][tile] = slot
+            sec["tile_qpos0"][tile] = sl
+            sec["tile_qlen"][tile] = 1
         sec["pads"][slot] = pad
         sec["seeds"][slot] = np.asarray(seed, np.uint32)
         sec["verify_len"][slot] = 1
@@ -3255,8 +3327,6 @@ class ServeEngine:
         qb, bs = self._q_tile, self.block_size
         n = toks.size
         slot = r.slot
-        blocks = np.asarray(r.block_ids, np.int32)
-        sec["tables"][slot, :blocks.size] = blocks
         if self.window_blocks:
             self._advance_window(sec, slot, start_slot, n)
         sec["pads"][slot] = r.pad
@@ -3265,18 +3335,21 @@ class ServeEngine:
         sl = start_slot + idx
         sec["tokens"][cur:cur + n] = toks
         sec["positions"][cur:cur + n] = sl - r.pad
-        sec["tok_blk"][cur:cur + n] = blocks[sl // bs]
-        sec["tok_off"][cur:cur + n] = sl % bs
         sec["tok_row"][cur:cur + n] = slot
-        sec["tok_slot"][cur:cur + n] = sl
         sec["tok_live"][cur:cur + n] = 1
-        sec["tok_lane"][cur:cur + n] = lane + idx
-        sec["lane_tok"][lane:lane + n] = cur + idx
-        q0 = np.arange(0, n, qb)  # each tile's first token
-        tiles = slice(lane // qb, lane // qb + q0.size)
-        sec["tile_row"][tiles] = slot
-        sec["tile_qpos0"][tiles] = start_slot + q0
-        sec["tile_qlen"][tiles] = np.minimum(qb, n - q0)
+        if "tables" in sec:  # (absent: a pool with no page class)
+            blocks = np.asarray(r.block_ids, np.int32)
+            sec["tables"][slot, :blocks.size] = blocks
+            sec["tok_blk"][cur:cur + n] = blocks[sl // bs]
+            sec["tok_off"][cur:cur + n] = sl % bs
+            sec["tok_slot"][cur:cur + n] = sl
+            sec["tok_lane"][cur:cur + n] = lane + idx
+            sec["lane_tok"][lane:lane + n] = cur + idx
+            q0 = np.arange(0, n, qb)  # each tile's first token
+            tiles = slice(lane // qb, lane // qb + q0.size)
+            sec["tile_row"][tiles] = slot
+            sec["tile_qpos0"][tiles] = start_slot + q0
+            sec["tile_qlen"][tiles] = np.minimum(qb, n - q0)
         if n_verify:
             first = n - n_verify  # verify slots = the last n_verify
             sec["verify_len"][slot] = n_verify
@@ -3745,7 +3818,7 @@ class ServeEngine:
             preemptions_total=self.scheduler.n_preemptions,
             kv_bytes=(
                 self._kv_bytes_tick_mixed(decode_rows, prefill_segs)
-                if active else 0
+                if active and self._paged else 0
             ),
             prefill_tokens=n_prefill_tok,
             decode_tokens=n_decode_tok,
@@ -3798,13 +3871,16 @@ class ServeEngine:
         # layer's) — what the device moves under "pallas"; under "xla" it
         # moves every slot's row, these or not —, and the live tokens
         # through the recurrence: a state-space mixer's (``ssm_*``) or a
-        # delta-rule layer's matrix state (``kda_*``)
+        # delta-rule layer's matrix state (``kda_*``) or a power-retention
+        # layer's (``retention_*``)
         state_args: dict[str, Any] = {}
         for kind, layers, impl, report in (
                 ("ssm", self.config.ssm_layers, self.ssm_state_impl,
                  self.metrics.on_ssm),
                 ("kda", self.config.kda_layers, self.kda_state_impl,
-                 self.metrics.on_kda)):
+                 self.metrics.on_kda),
+                ("retention", self.config.retention_layers,
+                 self.retention_state_impl, self.metrics.on_retention)):
             if layers and active:
                 state_args.update({
                     f"{kind}_state_rows": active,
@@ -4069,6 +4145,8 @@ class ServeEngine:
         from llm_np_cp_tpu.parallel.sharding import MODEL_AXIS
 
         layout = self._mixed_layouts[t_w, d_w][0]
+        if not self._paged:
+            return 0, 0, 0, 0, 0  # no layer attends anything
 
         def section(name):
             off, shape = layout[name]

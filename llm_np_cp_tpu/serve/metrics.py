@@ -253,6 +253,11 @@ class ServeMetrics:
         self.kda_state_rows = 0
         self.kda_scan_tokens = 0
         self.kda_state_kernel = 0
+        # ... and with power-retention layers
+        self.retention_ticks = 0
+        self.retention_state_rows = 0
+        self.retention_scan_tokens = 0
+        self.retention_state_kernel = 0
         # speculative draft-then-verify accounting (exact counters +
         # a real accept-length histogram over SPEC_ACCEPT_BUCKETS —
         # one observation per verify round, value = accepted drafts)
@@ -409,6 +414,18 @@ class ServeMetrics:
             self.kda_scan_tokens += tokens
             self.conv_state_slots = state_slots_live
             self.kda_state_kernel = int(kernel)
+
+    def on_retention(self, *, rows: int, tokens: int, state_slots_live: int,
+                     kernel: bool) -> None:
+        """``on_ssm`` for a stack with power-retention layers: the rows
+        whose state a dispatch read and wrote, the live tokens through the
+        recurrence, and which form advanced them."""
+        with self._lock:
+            self.retention_ticks += 1
+            self.retention_state_rows += rows
+            self.retention_scan_tokens += tokens
+            self.conv_state_slots = state_slots_live
+            self.retention_state_kernel = int(kernel)
 
     def on_spec(self, *, drafted: int, accepted: int) -> None:
         """One speculative verify round for one request: ``drafted``
@@ -633,6 +650,13 @@ class ServeMetrics:
                 out["kda_scan_tokens"] = self.kda_scan_tokens
                 out["kda_state_slots_live"] = self.conv_state_slots
                 out["kda_state_kernel"] = self.kda_state_kernel
+            if self.retention_ticks:
+                # only where a power-retention layer ran
+                out["retention_ticks"] = self.retention_ticks
+                out["retention_state_rows"] = self.retention_state_rows
+                out["retention_scan_tokens"] = self.retention_scan_tokens
+                out["retention_state_slots_live"] = self.conv_state_slots
+                out["retention_state_kernel"] = self.retention_state_kernel
             if self.spec_rounds:
                 # reported only once a verify round ran (like the SLO
                 # block): a fabricated 0-acceptance series on a
@@ -933,6 +957,25 @@ class ServeMetrics:
                  "1 where the Pallas state-update kernel advances the "
                  "rows a tick touches, 0 where its twin advances every row",
                  [("", s["kda_state_kernel"])])
+        if "retention_ticks" in s:
+            emit("retention_ticks_total", "counter",
+                 "Dispatching ticks that ran power-retention layers",
+                 [("", s["retention_ticks"])])
+            emit("retention_state_rows_total", "counter",
+                 "Rows whose power-retention state a dispatch read and "
+                 "wrote (every layer's), summed over ticks",
+                 [("", s["retention_state_rows"])])
+            emit("retention_scan_tokens_total", "counter",
+                 "Live tokens through the power-retention recurrence, "
+                 "summed over ticks",
+                 [("", s["retention_scan_tokens"])])
+            emit("retention_state_slots_live", "gauge",
+                 "Slots whose power-retention state is live",
+                 [("", s["retention_state_slots_live"])])
+            emit("retention_state_kernel", "gauge",
+                 "1 where the Pallas state-update kernel advances the "
+                 "rows a tick touches, 0 where its twin advances every row",
+                 [("", s["retention_state_kernel"])])
         # -- speculative decoding (only once a verify round ran — a
         # constant-zero series on a plain engine would read as a broken
         # speculation deployment on a fleet dashboard)

@@ -277,7 +277,10 @@ class Scheduler:
     ``allocator`` is anything with the FreeList interface (alloc/free/
     num_free); ``blocks_for_prefill(req)`` maps a request to the block
     count its prefill will occupy (the engine's bucketing decides this —
-    the scheduler does not assume a layout).
+    the scheduler does not assume a layout).  An allocator that says how
+    many blocks a length needs (``blocks_for``: a ``BlockPool``) is asked;
+    one with no page class answers 0, and a request then grows by no block
+    and is never preempted for one.
     """
 
     def __init__(
@@ -300,6 +303,8 @@ class Scheduler:
         self.max_slots = max_slots
         self.block_size = block_size
         self.decode_reserve = decode_reserve
+        self._blocks_for = getattr(allocator, "blocks_for", None) or (
+            lambda n_slots: -(-n_slots // block_size))
         self._blocks_for_prefill = blocks_for_prefill or (
             lambda req: -(-req.total_len // block_size)
         )
@@ -472,7 +477,7 @@ class Scheduler:
             # short only when cache_len EXCEEDS it (at an exact block
             # boundary the last slot still fits — growing there would
             # preempt a victim for a block that may never be used)
-            while req.cache_len > len(req.block_ids) * self.block_size:
+            while self._blocks_for(req.cache_len) > len(req.block_ids):
                 ids = self.allocator.alloc(1)
                 if ids is not None:
                     req.block_ids.extend(ids)
@@ -488,8 +493,8 @@ class Scheduler:
             # pressure the draft is trimmed to the blocks that exist and
             # the scheduling trajectory stays identical to plain decode
             if req.state is RequestState.RUNNING and req.draft_len:
-                while (req.cache_len + req.draft_len
-                       > len(req.block_ids) * self.block_size):
+                while (self._blocks_for(req.cache_len + req.draft_len)
+                       > len(req.block_ids)):
                     ids = self.allocator.alloc(1)
                     if ids is None:
                         req.draft_len = max(

@@ -45,6 +45,7 @@ from safetensors import safe_open
 from llm_np_cp_tpu.config import ModelConfig
 from llm_np_cp_tpu.models import (
     afmoe,
+    brumby,
     deepseek_v3,
     falcon_h1,
     gemma2,
@@ -131,7 +132,7 @@ def hybrid_family(config: ModelConfig):
     checkpoint tensors (``(HF key, run, leaf, index, transpose?)``)."""
     return {"falcon_h1": falcon_h1, "deepseek_v3": deepseek_v3,
             "mimo_v2": mimo_v2, "ling_hybrid": ling_hybrid,
-            "afmoe": afmoe}.get(
+            "afmoe": afmoe, "brumby": brumby}.get(
         config.model_type, lfm2_moe)
 
 

@@ -186,6 +186,11 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
         )
         if config.init_expert_out_std is not None:
             d["init_expert_out_std"] = config.init_expert_out_std
+    if config.model_type == "brumby":
+        d.update(rope_scaling=None, use_sliding_window=False,
+                 sliding_window=None,
+                 max_window_layers=config.num_hidden_layers,
+                 retention_degree=config.retention_degree)
     if config.model_type == "ling_hybrid":
         moe_i = config.moe_intermediate_size
         d.update(

@@ -179,7 +179,8 @@ def _default_path(case):
         return bs == 64
     if kernel == "grouped_matmul":  # the cells' own shapes: further down
         return shape.name == "probe"
-    if kernel in ("ssm_state_update", "kda_state_update"):
+    if kernel in ("ssm_state_update", "kda_state_update",
+                  "retention_state_update"):
         return True  # the probe's state and the cell's
     return (kernel in ("ragged_paged_attention", "sample_epilogue")
             and shape.name in ("probe", "probe/untied", "qwen2.5-1.5b")
@@ -1532,6 +1533,54 @@ def test_ling_v3_steady_tick_on_a_v5e_moves_the_matrix_state_in_the_kernel(
         opmap.hlo_shape("float32", state.shape[1:])}
     assert not [n for n, v in ops.items() if v[1] in rows]
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * state.nbytes // 6
+
+
+def test_brumby_steady_tick_on_a_v5e_moves_the_state_in_the_kernel(
+        v5e_sharding):
+    """The benchmark's attention-free configuration AT ITS PUBLISHED WIDTHS
+    (5 power-retention layers, 8 kv heads of 8,704 x 128 + 128 x 128 float32
+    a slot; 2 slots here: the state is host memory in this build), a
+    decode-only tick: a pool with NO page class compiles; each of the state's
+    two leaves is an operand of the layer scan's kernel call and of what
+    hands it on, and of nothing that computes; the call sits under
+    ``retention_scan``; the state comes back in place; the operand is the
+    dense axis and the rows' sections alone; and the step keeps nothing the
+    size of a slot's state beside it (``phi`` is never in HBM: 2 MiB of
+    temporaries)."""
+    import json
+    from pathlib import Path
+
+    from llm_np_cp_tpu.config import ModelConfig
+
+    cfg = ModelConfig.from_hf_dict(json.loads((
+        Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+        / "brumby-14b-5l.json").read_text()))
+    engine, compiled = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, slots=2, blocks=0, chunk=128,
+        program=(8, 8))
+    assert engine.epilogue_impl == "fused"  # a head of 5,120 x 151,936
+    pages = engine.pool.pages
+    assert pages.k.size == 0 and engine.pool.num_blocks == 0
+    s, z = pages.state["retention"], pages.state["retention_z"]
+    assert s.shape == (5, 2, 8, 8704, 128) and z.shape == (5, 2, 8, 128, 128)
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    ints = re.findall(r"= ((?:[su]\d+|pred)\[[\d,]*\])\S* parameter\(",
+                      text[text.index("\nENTRY"):])
+    assert ints == [opmap.hlo_shape("int32", (4 * 8 + 5 * 2,))]
+    ops = opmap.op_map_from_hlo(text, STEP_SCOPES, {})
+    kernel = [n for n in ops if n.startswith("retention_state_update")]
+    assert kernel and all(ops[n][0] == "retention_scan" for n in kernel)
+    scopes = {v[0] for v in ops.values()}
+    assert {"retention_proj", "retention_scan", "mlp", "tail"} <= scopes
+    assert not {"attn", "qkv", "kv_write"} & scopes
+    for leaf in (s, z):
+        takers = _takes(text, opmap.hlo_shape("float32", leaf.shape),
+                        "retention_state_update")
+        assert any(n.startswith("retention_state_update") for n in takers)
+        assert set(takers.values()) <= {
+            "custom-call", "tuple", "get-tuple-element", "while"}, takers
+    assert compiled.memory_analysis().temp_size_in_bytes < s.nbytes // 10 // 8
 
 
 # ----------------------------------------------------------------------

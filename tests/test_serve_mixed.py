@@ -509,9 +509,11 @@ def test_a_failed_probe_serves_the_xla_twin_token_for_token(
 
 
 @pytest.mark.parametrize("arch", [
-    "qwen2", "gemma2", "lfm2_moe", "falcon_h1", "deepseek_v3", "mimo_v2"])
+    "qwen2", "gemma2", "lfm2_moe", "falcon_h1", "deepseek_v3", "mimo_v2",
+    "brumby"])
 def test_every_architecture_builds_the_one_tick(arch):
-    """Each of the benchmark's six architectures, at its tiny preset: the
+    """Each of seven of the benchmark's architectures (the last with no
+    layer that has pages: a pool with no page class), at its tiny preset: the
     engine is the unified tick, ``compile_counts()`` names ``mixed_step``
     alone after a short trace, and nothing of the phase-split engine is
     left on it."""
@@ -535,6 +537,7 @@ def test_every_architecture_builds_the_one_tick(arch):
     for gone in _GONE:
         assert not hasattr(engine, gone), gone
     assert engine.pool.stats()["request_held"] == 0
+    assert (engine.pool.num_blocks == 0) == (arch == "brumby")
 
 
 def test_mixed_xla_fallback_parity(tiny, monkeypatch):

@@ -224,6 +224,8 @@ LOCK_STATE: tuple[dict, ...] = (
             "kv_write_bytes_total", "weight_bytes_total",
             "device_time_s_total", "hbm_gbps", "roofline_gbps",
             "roofline_util", "mfu_tick", "util_hist", "util_hist_sum",
+            "retention_ticks", "retention_state_rows",
+            "retention_scan_tokens", "retention_state_kernel",
         },
         # "caller holds the lock" helpers — annotated, not inferred
         "lock_assumed": {"_record_latencies", "_trim"},

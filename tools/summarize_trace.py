@@ -565,11 +565,12 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
                 a["pairs_held"] for a in moe) / routed
             out["expert_rows_kernel_share"] = sum(
                 a.get("expert_rows_impl") == "kernel" for a in moe) / len(moe)
-    for kind in ("ssm", "kda"):
+    for kind in ("ssm", "kda", "retention"):
         # state-space mixers (``ssm_*``) / delta-rule linear-attention
-        # layers (``kda_*``, scopes ``kda_proj`` / ``kda_scan``): rows whose
-        # recurrent state the dispatch read and wrote, live tokens through
-        # the recurrence
+        # layers (``kda_*``, scopes ``kda_proj`` / ``kda_scan``) /
+        # power-retention layers (``retention_*``, scopes ``retention_proj``
+        # / ``retention_scan``): rows whose recurrent state the dispatch read
+        # and wrote, live tokens through the recurrence
         rec = [e["args"] for e in ticks if f"{kind}_state_rows" in e["args"]]
         if not rec:
             continue
@@ -997,7 +998,9 @@ def format_summary(events: list[dict], top: int = 5,
                 "of those ticks"
                 for kind, what, state in (
                     ("ssm", "state-space scan", "recurrent state"),
-                    ("kda", "delta-rule recurrence", "matrix state"))
+                    ("kda", "delta-rule recurrence", "matrix state"),
+                    ("retention", "power-retention recurrence",
+                     "symmetric-power state"))
                 if kind + "_state_rows" in acct)
             + "; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
