@@ -312,13 +312,33 @@ def _block_bounds(mask: jnp.ndarray, block_s: int, n_blocks: int) -> jnp.ndarray
 # (PERF.md §6, PR 44: a tenth of a live step; what a step of ``[BS, K,
 # D]`` pages paid most for was taking each kv head's rows out of them,
 # and they are attended as they lie now).  Several decode rows to a tile
-# is a mechanism of its own (PERF.md §7).
+# is a mechanism of its own (PERF.md §7); many tokens of ONE row to a tile
+# is the wide tile (``ragged_wide_tile``, PERF.md §6, PR 57).
 RAGGED_Q_TILE = 8
 
 # kv positions one grid step of the ragged kernel attends: the group of
 # pages it streams is as many as cover this much context (PERF.md §6,
 # PR 33, has the sweep on the chip that chose it)
 _RAGGED_STEP_POSITIONS = 512
+# ... in a call that may hold wide tiles (``ragged_wide_tile``): such a
+# call's grid is a prompt tick's widest, most of its tiles dead, and what
+# it pays for most besides the wide tiles' sheets is its grid steps, so
+# fewer of twice the positions (PERF.md section 6, PR 57, the probe)
+_RAGGED_WIDE_STEP_POSITIONS = 1024
+
+# FLOPs a byte of pages above which a tile of ``RAGGED_Q_TILE`` tokens is
+# no longer bound by its pages' bytes, so that reading them once for more
+# tokens buys nothing: a third of a v5e's 240 (197 TFLOP/s over 819 GB/s) —
+# between what gained on the chip (Trinity's pages, 48: a prompt tick's
+# calls −35 %) and what lost (MiMo-V2's, 102 and 205: two heads of 192 to
+# a 384-deep dot, a group of 8 / 16: +1.8 ms a prompt tick at a tile of
+# 32; PERF.md section 6, PR 57)
+_WIDE_MAX_INTENSITY = 80
+
+# Score rows one dot of a WIDE tile's update holds: a wide tile's tokens
+# are scored a kv head and a block of tokens at a time, each block a
+# sheet of at most this many rows by the step's positions (float32: 1 MB)
+_WIDE_SHEET_ROWS = 512
 
 # meta rows for _ragged_kernel (computed in-graph per layer — the
 # sliding-window bound is a traced per-layer value)
@@ -391,7 +411,7 @@ def _lane_spread(qf: jnp.ndarray, q_tile: int, pack: int) -> jnp.ndarray:
 
 def ragged_pages_per_step(
     mb: int, block_s: int, kv_heads: int, head_dim: int, kv_dtype,
-    quantized: bool, merged: bool = False,
+    quantized: bool, merged: bool = False, wide: bool = False,
 ) -> int:
     """``P``: the pages of a tile's row one grid step of the ragged
     kernel streams and attends — the largest count that covers at most
@@ -403,15 +423,104 @@ def ragged_pages_per_step(
     compiler wanted 16.8 MB of scoped VMEM at six ``[64, 4, 256]`` pages
     and 24.2 MB at eight, of its 16).  From shapes alone: every caller
     gets the ``P`` of its page shape.  A ``merged`` page ``[BS, K * D]``
-    takes what it holds (``[64, 8, 64]`` twice that)."""
+    takes what it holds (``[64, 8, 64]`` twice that).  ``wide``: of a
+    call that may hold wide tiles, ``_RAGGED_WIDE_STEP_POSITIONS``."""
     page = ((block_s, kv_heads * head_dim) if merged
             else (block_s, kv_heads, head_dim))
     slot = 2 * 2 * _vmem_bytes(page, kv_dtype)
     if quantized:
         slot += 2 * 2 * _vmem_bytes((block_s, kv_heads), jnp.float32)
         slot += 12 * 4 * block_s * kv_heads * head_dim
-    return max(1, min(_RAGGED_STEP_POSITIONS // block_s, mb,
-                      _VMEM_BUDGET_BYTES // slot))
+    positions = (_RAGGED_WIDE_STEP_POSITIONS if wide
+                 else _RAGGED_STEP_POSITIONS)
+    return max(1, min(positions // block_s, mb, _VMEM_BUDGET_BYTES // slot))
+
+
+def ragged_wide_tile(
+    kv_heads: int, group: int, head_dim: int, value_dim: int, kv_dtype,
+    merged: bool,
+) -> int:
+    """Lanes of the WIDE query tile a packer should lay a prompt chunk's
+    tokens in over such pages (0: none): that many to a tile stream their
+    row's pages once where tiles of ``RAGGED_Q_TILE`` would stream them
+    once each.  From shapes alone, as ``P``: the widest tile the kernel
+    has for the pages (``_wide_tile_limit``), where a tile of
+    ``RAGGED_Q_TILE`` tokens is bound by its pages' bytes — the FLOPs of
+    its two dots a byte of K and V under ``_WIDE_MAX_INTENSITY`` — and
+    none where its arithmetic already is what it waits for."""
+    pack = _lane_pack(kv_heads, head_dim)
+    v_pack = _lane_pack(kv_heads, value_dim)
+    flops = 2 * RAGGED_Q_TILE * group * (pack * head_dim + v_pack * value_dim)
+    bytes_ = (head_dim + value_dim) * jnp.dtype(kv_dtype).itemsize
+    if flops > _WIDE_MAX_INTENSITY * bytes_:
+        return 0
+    return _wide_tile_limit(kv_heads, group, head_dim, value_dim, kv_dtype,
+                            merged)
+
+
+def _wide_tile_limit(
+    kv_heads: int, group: int, head_dim: int, value_dim: int, kv_dtype,
+    merged: bool,
+) -> int:
+    """The widest tile ``ragged_paged_attention`` takes over such pages
+    (0: none).  Merged float pages only (a head is a static slice of a
+    page's lanes: a wide tile's sheets are a kv head's), and a query
+    group whose ``RAGGED_Q_TILE`` tokens are whole sublane tiles of ``q``
+    (a tile of a wide block reads its rows at a dynamic offset).  The
+    width is the largest of 64 / 32 / 16 whose block — ``q`` and the
+    result in both buffers, the running maximum, denominator and
+    accumulator of every score row — leaves the pages' buffers and a
+    sheet their room in a scoped VMEM of 16 MiB
+    (``_wide_compiler_params`` asks for what it counts)."""
+    packing = max(4 // jnp.dtype(kv_dtype).itemsize, 1)
+    if (not merged or not jnp.issubdtype(kv_dtype, jnp.floating)
+            or (RAGGED_Q_TILE * group) % (8 * packing)
+            # (pages the kernel copies itself: ``_dma_slices_pages``)
+            or kv_heads * head_dim % 128 or kv_heads * value_dim % 128):
+        return 0
+    for wide in (64, 32, 16):
+        if _wide_block_bytes(wide, kv_heads, group, head_dim, value_dim,
+                             kv_dtype) <= 9 * 2**20:
+            return wide
+    return 0
+
+
+def _wide_block_bytes(wide: int, kv_heads: int, group: int, head_dim: int,
+                      value_dim: int, dtype) -> int:
+    """VMEM a wide tile's block takes beside the pages' buffers: ``q``
+    (lane-spread) and the result in both pipeline buffers, and the
+    float32 scratch — a running maximum and a denominator a score row
+    (a column each: a sublane tile of 128 lanes holds 8) and the
+    accumulator."""
+    pack, v_pack = _lane_pack(kv_heads, head_dim), _lane_pack(kv_heads, value_dim)
+    rows = kv_heads * wide * group
+    return (2 * _vmem_bytes((rows, pack * head_dim), dtype)
+            + 2 * wide * kv_heads * _vmem_bytes((group, value_dim), dtype)
+            + 2 * _vmem_bytes((rows, 1), jnp.float32)
+            + _vmem_bytes((rows, v_pack * value_dim), jnp.float32))
+
+
+def _wide_compiler_params(wide: int, kv_heads: int, group: int,
+                          head_dim: int, value_dim: int, block_s: int,
+                          pages: int, dtype) -> dict:
+    """``pallas_call``'s ``compiler_params`` for a call with wide tiles
+    (none without: that call is the one it was): the scoped VMEM its
+    block, its pages' two halves and the sheets of a wide update's
+    temporaries (counted amply: a ceiling, not an allocation — the masked
+    and the plain form of a block's update keep theirs apart) come to,
+    where that is past the 16 MiB a kernel gets unasked."""
+    if not wide:
+        return {}
+    kv = 2 * pages * (
+        _vmem_bytes((block_s, kv_heads * head_dim), dtype)
+        + _vmem_bytes((block_s, kv_heads * value_dim), dtype))
+    sheets = 16 * _WIDE_SHEET_ROWS * pages * block_s * 4
+    need = (_wide_block_bytes(wide, kv_heads, group, head_dim, value_dim, dtype)
+            + kv + sheets + (4 << 20))
+    if need <= 16 * 2**20:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(need, 64 << 20))}
 
 
 def _ragged_kernel(
@@ -421,7 +530,7 @@ def _ragged_kernel(
     mb: int, by_hand: tuple[bool, ...], pack: int = 0,
     value_dim: int | None = None, v_pack: int | None = None,
     has_sink: bool = False, has_base: bool = False,
-    heads_in_rows: bool = False,
+    heads_in_rows: bool = False, wide: int = 0,
 ):
     """Mixed-batch block-table attention: each q tile holds up to
     ``q_tile`` consecutive tokens of ONE row (a prefill-chunk slice, or a
@@ -479,7 +588,21 @@ def _ragged_kernel(
     the same bytes with a position's kv heads on consecutive rows, and
     are attended so (``attend``): on a v5e a head's rows of such a page
     are every ``K``-th HALF of a 32-bit row, and taking them out cost
-    more than everything else a live step did (PERF.md §6, PR 44)."""
+    more than everything else a live step did (PERF.md §6, PR 44).
+
+    ``wide`` > 0 (merged pages): the call may hold WIDE tiles — a
+    prompt chunk's ``q_tile < tile_qlen <= wide`` consecutive tokens of
+    one row, on ``wide`` lanes from a multiple of ``wide`` on, named by
+    the FIRST of the ``wide / q_tile`` tiles those lanes make (the
+    others are dead and leave the lanes alone).  Such a tile walks its
+    row's pages ONCE: a group is copied once and scored against all of
+    the tile's tokens, a kv head and a block of tokens
+    (``_WIDE_SHEET_ROWS`` score rows) at a time, where ``wide / q_tile``
+    tiles would each have streamed it again.  A block of ``q`` and of
+    the result is then ``wide`` lanes (rows ordered (kv head, token of
+    the block, group head), as the scratch's); a tile of ``q_tile``
+    lanes reads and writes its own lanes of it, and its update is the
+    one it has without."""
     value_dim = head_dim if value_dim is None else value_dim
     v_pack = pack if v_pack is None else v_pack
     it = iter(refs)
@@ -502,6 +625,11 @@ def _ragged_kernel(
     pad, qpos0 = meta_ref[_RM_PAD, ti], meta_ref[_RM_QPOS0, ti]
     qlen, win = meta_ref[_RM_QLEN, ti], meta_ref[_RM_WIN, ti]
     width = pages * block_s
+    # a tile's lanes of its block of ``q`` and of the result (``wide``:
+    # the block is ``wide`` lanes and holds ``wide / q_tile`` tiles)
+    block_q = wide or q_tile
+    lane0 = ti % (wide // q_tile) * q_tile if wide else 0
+    block_rows = block_q * group  # a kv head's rows of a block
 
     def group_copies(tile, step, half, wait: bool):
         """Start (or wait for) one copy a LIVE page slot of group
@@ -547,19 +675,40 @@ def _ragged_kernel(
         half_ref[0] = 1 - half
         return half
 
-    @pl.when(j == 0)
-    def _init():
+    head_rows = q_tile * group  # a kv head's rows of the scratch
+
+    def init(rows: int, tiles: int = 1):
+        """The scratch's first ``rows`` rows before a tile's first page
+        (``tiles``: the sink's rows, a tile's, that many times over)."""
         if has_sink:
             b = sink_ref[:]
+            if tiles > 1:  # (kv head, token, group head), more tokens
+                b = jnp.concatenate(
+                    [b[ki * head_rows:(ki + 1) * head_rows]
+                     for ki in range(kv_heads) for _ in range(tiles)], axis=0)
             m0 = jnp.ceil(b * _LOG2E) * _LN2  # the grid point above it
-            m_ref[:] = m0
-            l_ref[:] = jnp.exp(b - m0)
+            m_ref[:rows] = m0
+            l_ref[:rows] = jnp.exp(b - m0)
         else:
-            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:rows] = jnp.full((rows, 1), NEG_INF, m_ref.dtype)
+            l_ref[:rows] = jnp.zeros((rows, 1), l_ref.dtype)
+        acc_ref[:rows] = jnp.zeros((rows,) + acc_ref.shape[1:], acc_ref.dtype)
 
-    head_rows = q_tile * group  # a kv head's rows of the scratch
+    if wide:
+        # a tile of ``q_tile`` lanes keeps the rows it has without; a
+        # wide one's are all the scratch has
+        @pl.when((j == 0) & (qlen <= q_tile))
+        def _init():
+            init(kv_heads * head_rows)
+
+        @pl.when((j == 0) & (qlen > q_tile))
+        def _init_wide():
+            init(kv_heads * block_rows, wide // q_tile)
+    else:
+        @pl.when(j == 0)
+        def _init():
+            init(kv_heads * head_rows)
+
     # a ONE-token tile's rows of a kv head: its ``G`` group heads in whole
     # sublane tiles (the rows past ``G`` are token 1's first, masked like
     # every dead lane); a head's token 0 starts at a multiple of 8 in the
@@ -570,10 +719,12 @@ def _ragged_kernel(
         """→ (load, store) of the scratch rows an update touches: all of
         them, or (``one``) each kv head's ``token_rows`` of token 0."""
         if not one:
-            def store(ref, rows):
-                ref[:] = rows
+            n = kv_heads * head_rows  # (all the scratch has without ``wide``)
 
-            return (lambda ref: ref[:]), store
+            def store(ref, rows):
+                ref[:n] = rows
+
+            return (lambda ref: ref[:n]), store
 
         def load(ref):
             return jnp.concatenate(
@@ -617,12 +768,12 @@ def _ragged_kernel(
                 jnp.int32, (rows, cols), 0) < group
         return mine
 
-    def online_softmax(s, mask, one: bool, weighted):
+    def online_softmax(s, mask, rows, weighted):
         """The AMLA additive-max update (see ``_amla_rescale``: ln2-grid
         max, group rescale = exponent-field integer add, not a multiply)
-        of the scratch rows under the score sheet ``s``; ``weighted(p)``
-        → ``p @ V``."""
-        load, store = scratch_rows(one)
+        of the scratch rows (``rows``: their ``(load, store)``) under the
+        score sheet ``s``; ``weighted(p)`` → ``p @ V``."""
+        load, store = rows
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
         s = jnp.where(mask, s, NEG_INF)
@@ -675,7 +826,7 @@ def _ragged_kernel(
             mask = jnp.concatenate(
                 [mine & (col_head == ki) for ki in range(kv_heads)], axis=0)
             online_softmax(
-                s, mask, one, lambda p: jax.lax.dot_general(
+                s, mask, scratch_rows(one), lambda p: jax.lax.dot_general(
                     p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32))
             return
@@ -691,10 +842,14 @@ def _ragged_kernel(
             dots = kv_heads // pack
 
             def q_of(c):
-                if not one:
+                if not one and not wide:
                     return q_ref[0, c]
+                # (``wide``: the tile's lanes of the block's rows)
+                at = lane0 * group
+                if wide:
+                    at = pl.multiple_of(at, head_rows)
                 return jnp.concatenate(
-                    [q_ref[0, c, s * head_rows:s * head_rows + rows]
+                    [q_ref[0, c, pl.ds(s * block_rows + at, rows)]
                      for s in range(pack)], axis=0)
 
             def k_of(b, c):
@@ -747,7 +902,65 @@ def _ragged_kernel(
                 axis=0,
             )  # [K * rows, Dv]  (merged: v_pack * Dv wide)
 
-        online_softmax(s, mask, one, weighted)
+        online_softmax(s, mask, scratch_rows(one), weighted)
+
+    def attend_wide(half):
+        """The update of this step's group under a WIDE tile: the group
+        as one tile's step has it, scored a kv head and a block of the
+        tile's tokens at a time (the sheet of all of them at once would
+        be ``wide / q_tile`` times a tile's, most of a scoped VMEM), the
+        block's mask built once for every kv head."""
+        kb, vb = (buf[half].reshape((-1,) + buf.shape[3:]) for buf in bufs)
+        lanes, v_lanes = pack * head_dim, v_pack * value_dim
+        tokens = wide
+        while tokens * group > _WIDE_SHEET_ROWS and tokens > q_tile:
+            tokens //= 2
+        rows = tokens * group
+        # a score row's token, without a division: (row * m) >> 16
+        m = -(-(1 << 16) // group)
+        assert all(r * m >> 16 == r // group for r in range(rows))
+        base = meta_ref[_RM_BASE, ti] if has_base else 0
+        def block_update(first, mask):
+            for ki in range(kv_heads):
+                c, cv = ki // pack, ki // v_pack
+                q0 = ki % pack * block_rows + first * group
+                r0 = ki * block_rows + first * group
+
+                def load(ref, r0=r0):
+                    return ref[r0:r0 + rows]
+
+                def store(ref, val, r0=r0):
+                    ref[r0:r0 + rows] = val
+
+                s = jax.lax.dot_general(
+                    q_ref[0, c, q0:q0 + rows],
+                    kb[:, c * lanes:(c + 1) * lanes],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                online_softmax(
+                    s, mask, (load, store),
+                    lambda p, cv=cv: jax.lax.dot_general(
+                        p.astype(vb.dtype),
+                        vb[:, cv * v_lanes:(cv + 1) * v_lanes],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+
+        kv_pos = (base + start + j * pages) * block_s + (
+            jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1))
+        for first in range(0, wide, tokens):
+            # what a row of the block sees of the group: the positions
+            # from its window's (or its row's) first to its own, none
+            # where its token is past the tile's last.  (A group INSIDE
+            # what every token sees needs no mask; the update's two forms
+            # under a branch cost more than the selects they saved:
+            # PERF.md section 6, PR 57)
+            tok = first + (jax.lax.broadcasted_iota(
+                jnp.int32, (rows, 1), 0) * m >> 16)
+            q_slot = qpos0 + tok
+            last = jnp.where(tok < qlen, q_slot, -1)
+            block_update(first, (
+                (kv_pos >= jnp.maximum(pad, q_slot - win + 1))
+                & (kv_pos <= last)))
 
     @pl.when(j * pages < count)
     def _update():
@@ -761,26 +974,51 @@ def _ragged_kernel(
         def _one_token():
             attend(half, True)
 
-        @pl.when(qlen != 1)
+        @pl.when((qlen != 1) & (qlen <= q_tile) if wide else qlen != 1)
         def _tile():
             attend(half, False)
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        acc = acc_ref[:] / l
+        if wide:
+            @pl.when(qlen > q_tile)
+            def _wide():
+                attend_wide(half)
+
+    def finalize(tokens: int, lanes):
+        """The scratch's ``tokens`` tokens a kv head → those ``lanes`` of
+        the result's block."""
+        rows, n = tokens * group, kv_heads * tokens * group
+        l = jnp.where(l_ref[:n] == 0.0, 1.0, l_ref[:n])
+        acc = acc_ref[:n] / l
         for ki in range(kv_heads):
-            mine = acc[ki * q_tile * group:(ki + 1) * q_tile * group]
+            mine = acc[ki * rows:(ki + 1) * rows]
             if v_pack > 1:  # this head's lanes of the row it shares
-                lane0 = ki % v_pack * value_dim
-                mine = mine[:, lane0:lane0 + value_dim]
-            o_ref[:, ki] = (
-                mine.reshape(q_tile, group, value_dim).astype(o_ref.dtype)
+                at = ki % v_pack * value_dim
+                mine = mine[:, at:at + value_dim]
+            o_ref[lanes, ki] = (
+                mine.reshape(tokens, group, value_dim).astype(o_ref.dtype)
             )
+
+    if wide:
+        # the tile that names this block's lanes, where it is a wide one,
+        # has written them all: the tiles after it leave them alone
+        named = meta_ref[_RM_QLEN, ti - lane0 // q_tile] > q_tile
+
+        @pl.when((j == nj - 1) & (lane0 == 0) & named)
+        def _finalize_wide():
+            finalize(wide, slice(None))
+
+        @pl.when((j == nj - 1) & jnp.logical_not(named))
+        def _finalize():
+            finalize(q_tile, pl.ds(pl.multiple_of(lane0, q_tile), q_tile))
+    else:
+        @pl.when(j == nj - 1)
+        def _finalize():
+            finalize(q_tile, slice(None))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "logit_softcap", "interpret")
+    jax.jit,
+    static_argnames=("scale", "logit_softcap", "interpret", "wide_tile"),
 )
 def ragged_paged_attention(
     q: jnp.ndarray,
@@ -800,6 +1038,7 @@ def ragged_paged_attention(
     interpret: bool | None = None,
     sink: jnp.ndarray | None = None,
     block0: jnp.ndarray | None = None,
+    wide_tile: int = 0,
 ) -> jnp.ndarray:
     """Mixed prefill+decode GQA attention straight off a paged KV pool.
 
@@ -855,6 +1094,14 @@ def ragged_paged_attention(
     layer's chain holds only the blocks its window still reaches, so its
     table starts at ``block0[row]`` and kv position ``p`` lies in column
     ``p // BS - block0[row]``.
+
+    ``wide_tile`` (static; ``ragged_wide_tile`` says which pages should
+    have one, ``_wide_tile_limit`` which can): the packed axis may hold WIDE tiles — ``RAGGED_Q_TILE <
+    tile_qlen <= wide_tile`` consecutive tokens of one row on
+    ``wide_tile`` lanes that start at a multiple of it, named by the
+    first of those lanes' ``wide_tile / RAGGED_Q_TILE`` tile entries
+    (the others read 0).  Such a tile streams its row's pages once for
+    all its tokens; a call without one (0) is the call it was.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -900,10 +1147,21 @@ def ragged_paged_attention(
     g = h // kh
     mb = tables.shape[1]
     pages = ragged_pages_per_step(
-        mb, block_s, kh, d, k_pages.dtype, quantized, merged=merged)
+        mb, block_s, kh, d, k_pages.dtype, quantized, merged=merged,
+        wide=bool(wide_tile))
     steps = -(-mb // pages)
     pack = _lane_pack(kh, d) if merged else 0
     v_pack = _lane_pack(kh, dv) if merged else 0
+    if wide_tile and (
+            wide_tile > _wide_tile_limit(kh, g, d, dv, k_pages.dtype, merged)
+            or wide_tile % (2 * qt) or t % wide_tile):
+        raise ValueError(
+            f"a wide tile of {wide_tile} lanes on a packed axis of {t} over "
+            f"{k_pages.dtype}{list(k_pages.shape[1:])} pages: "
+            "_wide_tile_limit says which pages have one, and how wide at most")
+    # lanes of a block of ``q`` and of the result, and tiles to a block
+    wq = wide_tile or qt
+    sub = wq // qt
 
     qf = q.reshape(t, kh, g, d)
     # per-tile kv page bounds: the window lower bound is tightest at the
@@ -930,7 +1188,7 @@ def ragged_paged_attention(
     ]).astype(jnp.int32)  # [9, NT] (a table with a base: [10, NT])
 
     def tile_map(ti, j, meta_ref, tables_ref):
-        return (ti, 0, 0, 0)
+        return (ti // sub if wide_tile else ti, 0, 0, 0)
 
     def page_spec(p, block):
         """Page slot ``p`` of the step's group as a blocked operand; past
@@ -954,16 +1212,20 @@ def ragged_paged_attention(
     # ``_ragged_kernel``
     heads_in_rows = (not merged and not quantized and all(by_hand)
                      and kh & (kh - 1) == 0)
+    if wide_tile and not all(by_hand):
+        raise ValueError(
+            f"a wide tile attends pages the kernel copies itself; a DMA "
+            f"cuts no page out of {k_pages.dtype}{list(k_pages.shape)}")
     if heads_in_rows:
         pools = [a.reshape(a.shape[0], block_s * kh, a.shape[-1])
                  for a in pools]
     tile_spec = pl.BlockSpec(
         (qt, kh, g, d), tile_map, memory_space=pltpu.VMEM)
     out_spec = pl.BlockSpec(
-        (qt, kh, g, dv), tile_map, memory_space=pltpu.VMEM)
+        (wq, kh, g, dv), tile_map, memory_space=pltpu.VMEM)
     in_specs, operands = [tile_spec], [qf]
     if merged:
-        spread = _lane_spread(qf, qt, pack)
+        spread = _lane_spread(qf, wq, pack)
         in_specs, operands = [pl.BlockSpec(
             (1,) + spread.shape[1:], tile_map,
             memory_space=pltpu.VMEM)], [spread]
@@ -986,9 +1248,9 @@ def ragged_paged_attention(
                          for p in range(pages)]
             operands += [a] * pages
     scratch = [
-        pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((rows, max(v_pack, 1) * dv), jnp.float32),
+        pltpu.VMEM((sub * rows, 1), jnp.float32),
+        pltpu.VMEM((sub * rows, 1), jnp.float32),
+        pltpu.VMEM((sub * rows, max(v_pack, 1) * dv), jnp.float32),
         # two halves of a group of pages for each array copied by hand
         *[pltpu.VMEM((2, pages) + a.shape[1:], a.dtype)
           for a, hand in zip(pools, by_hand) if hand],
@@ -1003,7 +1265,7 @@ def ragged_paged_attention(
             q_tile=qt, head_dim=d, pages=pages, mb=mb, by_hand=by_hand,
             pack=pack, value_dim=dv, v_pack=v_pack,
             has_sink=sink is not None, has_base=block0 is not None,
-            heads_in_rows=heads_in_rows,
+            heads_in_rows=heads_in_rows, wide=wide_tile,
         ),
         out_shape=jax.ShapeDtypeStruct((t, kh, g, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1014,6 +1276,8 @@ def ragged_paged_attention(
             scratch_shapes=scratch,
         ),
         interpret=interpret,
+        **_wide_compiler_params(wide_tile, kh, g, d, dv, block_s, pages,
+                                k_pages.dtype),
     )(meta, tables.reshape(-1).astype(jnp.int32), *operands)
     return out.reshape(t, h, dv)
 

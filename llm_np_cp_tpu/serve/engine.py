@@ -37,7 +37,12 @@ widths, each with ONE dense width — its capacity under the token budget
 rows at the width of their tokens), so the step compiles once per
 program and NEVER per tick, whatever the prefill:decode row mix
 (compile-counter lint), and warm-up pays for one program more than the
-ladder has rungs.  ``/metrics`` counts the dense lanes dispatched
+ladder has rungs.  Where the pool's pages have a WIDE query tile
+(``ragged_wide_tile``: merged float pages) the rungs past ``max_slots``
+one-tile rows — the ones only a tick with a prompt chunk runs — lay a
+prompt segment's tokens 64 (or 32, 16) to a tile, which walks its row's
+pages once where eight tiles of 8 would each stream them again
+(``_wide_program``, ``_pack_mixed``); the programs are the same set.  ``/metrics`` counts the dense lanes dispatched
 (``mixed_dense_lanes_total``) beside the tokens in them
 (``mixed_tokens_total``).
 
@@ -801,6 +806,9 @@ class ServeEngine:
         self.metrics = ServeMetrics(clock=clock)
         # window blocks this tick's rows let go (tick args)
         self._window_recycled_tick = 0
+        # the query tiles of this tick's pack that hold more than one
+        # token, and the tokens in them (``/metrics``)
+        self._prefill_tiles_tick = self._prefill_tile_tokens_tick = 0
         # -- host-RAM KV block tier (serve/host_tier.HostTier): spilled
         # prefix blocks keyed by the SAME chained content hash the
         # prefix cache uses, restored at admission as ordinary claimed
@@ -970,6 +978,10 @@ class ServeEngine:
         # (no attention, no tile: a token is its own lane, and the tiled
         # width of a program is its dense width)
         self._q_tile = RAGGED_Q_TILE if self._paged else 1
+        # lanes of the WIDE query tile a prompt chunk's tokens are laid
+        # in where the pages have one (0: none), in the programs only a
+        # tick with a chunk runs (``_wide_program``)
+        self._wide_tile = self._resolve_wide_tile()
         # verify-lane width of the compiled step: every row carries
         # spec_k+1 sample slots ([R, W] last_idx/sample_pos operands
         # and an [R, W] token return) — plain rows use column 0 and
@@ -983,6 +995,10 @@ class ServeEngine:
             self._spec_w,
         ) + ((self.window_blocks,) if self.window_blocks else ())
         self.mixed_buckets = self._make_buckets(budget, max_slots)
+        # the rung that holds ``max_slots`` one-tile rows: the widest a
+        # decode-only tick runs (``_wide_program``)
+        self._steady_lanes = min(
+            t for t, _ in self.mixed_buckets if t >= max_slots * self._q_tile)
         # stated once a program: the packer looks its layout up
         self._mixed_layouts = {
             p: mixed_operand_layout(*p, *self._mixed_geometry)
@@ -1086,7 +1102,8 @@ class ServeEngine:
         ``max_slots * (1 + spec_k)``."""
         qb = self._q_tile
         # each of up to max_slots segments wastes < qb lanes to alignment
-        a_max = _ceil_to(budget + max_slots * (qb - 1), qb)
+        # (a rung of wide tiles is whole ones)
+        a_max = _ceil_to(budget + max_slots * (qb - 1), self._wide_tile or qb)
         ladder = []
         # (the first rung: a tile, which where no layer has pages would be
         # one token; 8 tokens there)
@@ -1116,6 +1133,40 @@ class ServeEngine:
         if d_rows < min(t_rows, cap):
             programs.append((t_rows, d_rows))
         return tuple(sorted(set(programs)))
+
+    def _resolve_wide_tile(self) -> int:
+        """Lanes of the wide query tile this engine's packer lays a prompt
+        chunk in (0: none): what ``ragged_wide_tile`` says of the pool's
+        pages — of both classes where it has two, the narrower: the tile
+        metadata is one for every layer.  A latent pool's kernel has no
+        wide tile (PERF.md section 7)."""
+        from llm_np_cp_tpu.ops.pallas.decode_attention import ragged_wide_tile
+        from llm_np_cp_tpu.parallel.sharding import MODEL_AXIS
+
+        pages, cfg = self.pool.pages, self.config
+        if not self._paged or pages.latent or not pages.merged:
+            return 0
+        shards = self.mesh.shape[MODEL_AXIS] if self._kv_sharded else 1
+        widths = []
+        for kind in ("global", "window")[:1 + cfg.two_page_classes]:
+            token = cfg.kv_token_shapes(kind)
+            kh, d = token["k"]
+            widths.append(ragged_wide_tile(
+                kh // shards, cfg.num_attention_heads // kh, d,
+                token["v"][1], pages.k.dtype, True))
+        wide = min(widths)
+        # (a budget no segment of which fills a wide tile has none)
+        return wide if self.tick_token_budget >= wide else 0
+
+    def _wide_program(self, t_w: int) -> int:
+        """The wide tile of the program whose tiled axis is ``t_w`` lanes
+        (0: its tiles are all ``RAGGED_Q_TILE`` lanes): the rungs past
+        the one that holds ``max_slots`` one-tile rows, which only a
+        tick with a prompt chunk needs — the programs a decode-only tick
+        runs keep the attention call they have."""
+        wide = self._wide_tile
+        return wide if wide and t_w > self._steady_lanes \
+            and t_w % wide == 0 else 0
 
     def _pick_bucket(self, n_lanes: int, n_tokens: int) -> tuple[int, int]:
         """The program for a tick of ``n_tokens`` tokens whose segments
@@ -1808,15 +1859,22 @@ class ServeEngine:
         carry_pool = self.pool_carried = (
             not self._paged or _pool_is_row_major(self.pool.pages))
         geometry, programs = self._mixed_geometry, self.mixed_buckets
-        attn_call = self._shard_attn(
-            partial(
-                ragged_paged_attention if use_kernel
-                else ragged_paged_attention_xla,
-                scale=config.attn_scale,
-                logit_softcap=config.attn_logit_softcapping,
-            ),
-            quantized=quantized, n_meta=6, q_head_axis=1,
-        )
+
+        def attn_call_of(wide: int) -> Callable:
+            """The layers' attention over the pages of the first class,
+            in a program whose prompt chunks lie in tiles of ``wide``
+            lanes (0: none does; ``_wide_program``)."""
+            return self._shard_attn(
+                partial(
+                    ragged_paged_attention if use_kernel
+                    else ragged_paged_attention_xla,
+                    scale=config.attn_scale,
+                    logit_softcap=config.attn_logit_softcapping,
+                    # (the twin takes a token's metadata: no tile of any width)
+                    **({"wide_tile": wide} if use_kernel else {}),
+                ),
+                quantized=quantized, n_meta=6, q_head_axis=1,
+            )
 
         hybrid = config.is_hybrid
         q_tile, max_slots = geometry[:2]
@@ -1840,9 +1898,13 @@ class ServeEngine:
             ops: jnp.ndarray,  # the packed operand (mixed_operand_layout)
         ):
             with jax.named_scope(SCOPE_EMBED):
+                program = mixed_operand_program(
+                    ops.shape[0], programs, *geometry)
                 o = split_mixed_operands(ops, mixed_operand_layout(
-                    *mixed_operand_program(ops.shape[0], programs, *geometry),
-                    *geometry)[0])
+                    *program, *geometry)[0])
+                # (this program's tile shapes: a static of the trace)
+                wide = self._wide_program(program[0])
+                attn_call = attn_call_of(wide)
                 tokens, pads = o["tokens"], o["pads"]
                 tok_row, tok_live, seeds = o["tok_row"], o["tok_live"], o["seeds"]
                 verify_len = o["verify_len"]
@@ -1998,7 +2060,7 @@ class ServeEngine:
                             out = ragged_paged_attention(
                                 q[0][lane_tok], k_att, v_att, wtables + base,
                                 tile_row, tile_qpos0, tile_qlen, pads, span,
-                                **kw)[tok_lane]
+                                wide_tile=wide, **kw)[tok_lane]
                         else:
                             out = ragged_paged_attention_xla(
                                 q[0], k_att, v_att, wtables + base, tok_row,
@@ -3215,15 +3277,37 @@ class ServeEngine:
         A decode row with no drafts is one token in one tile: all of
         them are written together (``_fill_decode_rows``).  Speculating
         rows and prefill chunks, a few a tick, go segment by segment
-        (``_fill_segment``)."""
+        (``_fill_segment``).
+
+        In a program with WIDE tiles (``_wide_program``: the rungs only
+        a tick with a prompt chunk runs) a prompt segment's tokens go
+        ``wide`` to a tile as far as whole tiles reach — those tiles
+        first on the tiled axis, each at a multiple of ``wide`` — and
+        what is left of it in tiles of ``q_tile`` with everything else
+        behind them: the same lanes in all (``wide`` is whole tiles), so
+        the program is the one the totals pick either way."""
         qb = self._q_tile
         self._window_recycled_tick = 0
+        self._prefill_tiles_tick = self._prefill_tile_tokens_tick = 0
         sizes = [1 + r.draft_len for r in decode_rows]
         sizes.extend(n for _, n in prefill_segs)
         dense = list(itertools.accumulate(sizes, initial=0))
         tiled = list(itertools.accumulate(
             (_ceil_to(n, qb) for n in sizes), initial=0))
         program = self._pick_bucket(tiled[-1], dense[-1])
+        n_dec = len(decode_rows)
+        wide = self._wide_program(program[0])
+        wide_at: list[int] = []
+        if wide:
+            # the whole wide tiles of each prompt segment, then every
+            # segment's tiles of ``q_tile`` (a prompt segment's: of what
+            # is left of it)
+            full = [n // wide * wide for _, n in prefill_segs]
+            wide_at = list(itertools.accumulate(full, initial=0))
+            tiled = list(itertools.accumulate(
+                (_ceil_to(n - w, qb)
+                 for n, w in zip(sizes, [0] * n_dec + full)),
+                initial=wide_at[-1]))
         layout, size = self._mixed_layouts[program]
         ops = np.zeros(size, np.int32)
         sec = split_mixed_operands(ops, layout)
@@ -3239,9 +3323,8 @@ class ServeEngine:
             toks.extend(int(t) for t in r.extra["spec_draft"][: r.draft_len])
             self._fill_segment(sec, r, np.asarray(toks, np.int32),
                                r.cache_len - 1, len(toks), cur, lane)
-        n_dec = len(decode_rows)
-        for (r, n), cur, lane in zip(
-                prefill_segs, dense[n_dec:], tiled[n_dec:]):
+        for i, ((r, n), cur, lane) in enumerate(zip(
+                prefill_segs, dense[n_dec:], tiled[n_dec:])):
             content = r.extra["prefill_content"]
             self._fill_segment(
                 sec, r,
@@ -3249,7 +3332,7 @@ class ServeEngine:
                            np.int32),
                 r.pad + r.prefill_done,
                 1 if r.prefill_done + n >= r.prefill_target else 0,
-                cur, lane)
+                cur, lane, *((wide, wide_at[i]) if wide else ()))
         return ops, program, len(plain)
 
     def _advance_window(self, sec: dict[str, np.ndarray], slot: Any,
@@ -3317,13 +3400,18 @@ class ServeEngine:
 
     def _fill_segment(self, sec: dict[str, np.ndarray], r: Request,
                       toks: np.ndarray, start_slot: int, n_verify: int,
-                      cur: int, lane: int) -> None:
+                      cur: int, lane: int, wide: int = 0,
+                      wide_lane: int = 0) -> None:
         """One row's token segment at dense index ``cur`` and tile lane
         ``lane`` (a q-tile multiple): its tokens occupy cache slots
         ``start_slot..`` and its LAST ``n_verify`` tokens are sampled —
         a speculating row samples its whole verify slice (input +
         drafts), a completing prefill 1 (its last token), a mid-prefill
-        chunk 0."""
+        chunk 0.  ``wide``: the segment's first ``n // wide`` whole wide
+        tiles lie from lane ``wide_lane`` on (a multiple of ``wide``;
+        the first of a wide tile's ``wide / q_tile`` entries names it,
+        the others stay dead) and only what is left of it from ``lane``
+        on."""
         qb, bs = self._q_tile, self.block_size
         n = toks.size
         slot = r.slot
@@ -3343,13 +3431,30 @@ class ServeEngine:
             sec["tok_blk"][cur:cur + n] = blocks[sl // bs]
             sec["tok_off"][cur:cur + n] = sl % bs
             sec["tok_slot"][cur:cur + n] = sl
-            sec["tok_lane"][cur:cur + n] = lane + idx
-            sec["lane_tok"][lane:lane + n] = cur + idx
-            q0 = np.arange(0, n, qb)  # each tile's first token
+            full = n // wide * wide if wide else 0
+            lanes = lane + idx
+            if full:
+                lanes[:full] += wide_lane - lane
+                lanes[full:] -= full
+                w0 = np.arange(0, full, wide)  # each wide tile's first token
+                tiles = (wide_lane + w0) // qb
+                sec["tile_row"][tiles] = slot
+                sec["tile_qpos0"][tiles] = start_slot + w0
+                sec["tile_qlen"][tiles] = wide
+            sec["tok_lane"][cur:cur + n] = lanes
+            sec["lane_tok"][lanes] = cur + idx
+            q0 = np.arange(full, n, qb)  # each tile's first token
             tiles = slice(lane // qb, lane // qb + q0.size)
+            qlen = np.minimum(qb, n - q0)
             sec["tile_row"][tiles] = slot
             sec["tile_qpos0"][tiles] = start_slot + q0
-            sec["tile_qlen"][tiles] = np.minimum(qb, n - q0)
+            sec["tile_qlen"][tiles] = qlen
+            # the tiles that hold more than one token, and the tokens in
+            # them (``/metrics`` attn_prefill_tile_tokens)
+            many = qlen > 1
+            self._prefill_tiles_tick += full // wide if full else 0
+            self._prefill_tiles_tick += int(many.sum())
+            self._prefill_tile_tokens_tick += full + int(qlen[many].sum())
         if n_verify:
             first = n - n_verify  # verify slots = the last n_verify
             sec["verify_len"][slot] = n_verify
@@ -3827,6 +3932,9 @@ class ServeEngine:
             host_bound=device_done,
             lane_tick=lane_rows > 0,
             starved_rows=starved_rows,
+            prefill_tiles=self._prefill_tiles_tick if dispatched else 0,
+            prefill_tile_tokens=(
+                self._prefill_tile_tokens_tick if dispatched else 0),
         )
         if expert_load is not None:
             worst = expert_load[int(np.argmax(expert_load.max(axis=1)))]
@@ -4165,7 +4273,8 @@ class ServeEngine:
         ) if pages.latent else ragged_pages_per_step(
             self.max_blocks_per_seq, self.block_size,
             pages.kv_heads // shards, pages.head_dim, pages.k.dtype,
-            pages.quantized, merged=pages.merged)
+            pages.quantized, merged=pages.merged,
+            wide=bool(self._wide_program(t_w)))
         steps = (t_w // self._q_tile) * -(-self.max_blocks_per_seq // per_step)
         return (int((last - first + 1).sum()), steps, per_step,
                 int(live.sum()), int((qlen == 1).sum()))

@@ -200,6 +200,12 @@ class ServeMetrics:
         # a tick: prefill tokens / segments is what a row gets of a tick
         # (a chunk where rows share the lane, the lane where one has it)
         self.prefill_segments = 0
+        # ...the query tiles the packer laid with more than one token (a
+        # prompt chunk's, a verify slice's) and the tokens in them: their
+        # ratio is how wide a prefill tile was (8 lanes, or the wide
+        # tile's 16-64 where the pages have one)
+        self.prefill_tiles = 0
+        self.prefill_tile_tokens = 0
         # ...dispatching ticks that handed a row more than its fair
         # share (the prompt lane's leftover), and mid-prefill rows a
         # tick granted nothing, summed over ticks
@@ -318,11 +324,14 @@ class ServeMetrics:
         prefill_rows: int = 0,
         dense_lanes: int = 0, host_bound: bool = False,
         lane_tick: bool = False, starved_rows: int = 0,
+        prefill_tiles: int = 0, prefill_tile_tokens: int = 0,
     ) -> None:
         with self._lock:
             self.host_bound_ticks += host_bound
             self.mixed_prefill_tokens += prefill_tokens
             self.prefill_segments += prefill_rows
+            self.prefill_tiles += prefill_tiles
+            self.prefill_tile_tokens += prefill_tile_tokens
             self.lane_ticks += lane_tick
             self.prefill_starved_rows += starved_rows
             self.mixed_decode_tokens += decode_tokens
@@ -614,6 +623,10 @@ class ServeMetrics:
             out["mixed_prefill_tokens"] = self.mixed_prefill_tokens
             out["mixed_decode_tokens"] = self.mixed_decode_tokens
             out["prefill_segments"] = self.prefill_segments
+            out["attn_prefill_tiles_packed"] = self.prefill_tiles
+            out["attn_prefill_tile_tokens"] = (
+                self.prefill_tile_tokens / self.prefill_tiles
+                if self.prefill_tiles else 0.0)
             out["lane_ticks"] = self.lane_ticks
             out["prefill_starved_rows"] = self.prefill_starved_rows
             out["mixed_dense_lanes"] = self.mixed_dense_lanes
@@ -849,6 +862,14 @@ class ServeMetrics:
              "deltas reads it): tokens / segments is what a row gets of "
              "one tick",
              [("", s["mixed_prefill_tokens"])])
+        emit("attn_prefill_tile_tokens", "gauge",
+             "Mean tokens a query tile of more than one token held (a "
+             "prompt chunk's, a verify slice's): 8 lanes a tile at most, "
+             "or the wide tile's where the pages have one",
+             [("", s["attn_prefill_tile_tokens"])])
+        emit("attn_prefill_tiles_packed_total", "counter",
+             "Query tiles the packer laid with more than one token",
+             [("", s["attn_prefill_tiles_packed"])])
         emit("lane_ticks_total", "counter",
              "Dispatching ticks that handed a mid-prefill row more than "
              "its fair share of the prompt lane (min(chunk, remaining)): "

@@ -155,24 +155,34 @@ def mixed_tick_kv_read(
         lo = max(pad, qpos0 - win + 1)
         return full, (qlast // bs - lo // bs + 1) * bs
 
-    def seg_bytes(pad: int, start: int, n: int) -> int:
+    def seg_bytes(pad: int, start: int, n: int, wide: int = 0) -> int:
+        # the segment's query tiles as the packer lays them: whole wide
+        # tiles as far as they reach, tiles of ``qb`` for the rest — each
+        # streams its row's visible pages once
+        full = n // wide * wide if wide else 0
+        firsts = list(range(0, full, wide or 1)) + list(range(full, n, qb))
         slot_layers = 0
-        for k in range(-(-n // qb)):
-            q0 = start + k * qb
-            ql = min(qb, n - k * qb)
-            g_full, g_win = tile_slots(pad, q0, q0 + ql - 1)
+        for q0, q1 in zip(firsts, firsts[1:] + [n]):
+            g_full, g_win = tile_slots(pad, start + q0, start + q1 - 1)
             slot_layers += (
                 (n_layers - n_sliding) * g_full + n_sliding * g_win
             )
         return slot_layers * per_slot
 
+    # (the tick's program, as ``_pack_mixed`` picks it, says whether its
+    # prompt segments lie in wide tiles)
+    sizes = [1 + r.draft_len for r in decode_rows]
+    sizes.extend(n for _, n in prefill_segs)
+    wide = eng._wide_program(eng._pick_bucket(
+        sum(-(-n // qb) * qb for n in sizes), sum(sizes))[0]) if (
+            prefill_segs and eng._wide_tile) else 0
     for r in decode_rows:
         b = seg_bytes(r.pad, r.cache_len - 1, 1 + r.draft_len)
         total += b
         if per_request:
             per[r.req_id] = b
     for r, n in prefill_segs:
-        b = seg_bytes(r.pad, r.pad + r.prefill_done, n)
+        b = seg_bytes(r.pad, r.pad + r.prefill_done, n, wide)
         total += b
         if per_request:
             per[r.req_id] = b

@@ -71,7 +71,7 @@ def probe():
     _BUILT.clear()
 
 
-def _engine(cfg, params, attn="pallas", **kw):
+def _engine(cfg, params, attn="pallas", wide=0, **kw):
     # (two slots and a budget of 18: three programs to compile, not five;
     # a ring of 5 blocks: the window's 7 slots + a budget-wide slice + 1)
     kw.setdefault("max_slots", 2)
@@ -87,7 +87,7 @@ def _engine(cfg, params, attn="pallas", **kw):
     if attn == "xla":  # the kernel's twin: what a failed probe falls back to
         engine.ragged_attn_impl = "xla"
         engine._mixed_step = engine._make_mixed_step()
-    key = (attn, cfg.head_dim, tuple(sorted(
+    key = (attn, wide, cfg.head_dim, tuple(sorted(
         (k, str(v)) for k, v in kw.items() if k != "tracer")))
     first = _BUILT.setdefault(key, engine)
     if first is not engine:
@@ -148,18 +148,33 @@ SERVE_CASES = {
     # one whole row of lanes of a page (``_lane_pack`` 1), behind a table
     # that starts past position 0
     "pallas_d128": dict(lengths=[53], new=3, attn="pallas", head_dim=128),
+    # WIDE query tiles (in small: 16 lanes where the cell's are 64): a
+    # slice of 17 or 18 tokens is one wide tile and a tile of 8 lanes, the
+    # row that decodes beside it keeps its one-token tile; the window (8)
+    # starts and the causal edge lies inside every wide tile
+    "pallas_wide": dict(lengths=[53, 5], new=5, attn="pallas", wide=16),
+    "pallas_wide_d128": dict(lengths=[53], new=3, attn="pallas",
+                             head_dim=128, wide=16),
 }
 
 
 @pytest.mark.parametrize("case", sorted(SERVE_CASES))
-def test_served_logits_match_the_references_full_forward(tiny, case):
+def test_served_logits_match_the_references_full_forward(
+        tiny, case, monkeypatch):
+    import llm_np_cp_tpu.ops.pallas.decode_attention as da
+
     cfg, hf, params = tiny
     spec = SERVE_CASES[case]
+    # (toy float32 pages would have the widest tile, 64 lanes, more than
+    # the toy budget: without ``wide`` the engine has none)
+    monkeypatch.setattr(
+        da, "ragged_wide_tile", lambda *a: spec.get("wide", 0))
     if "head_dim" in spec:
         cfg = tiny_config("afmoe", head_dim=spec["head_dim"])
         hf = hf_config_dict(cfg)
         params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
-    engine = _engine(cfg, params, spec["attn"])
+    engine = _engine(cfg, params, spec["attn"], wide=spec.get("wide", 0))
+    assert engine._wide_tile == spec.get("wide", 0)
     reqs = [engine.submit(p, max_new_tokens=spec["new"], seed=i)
             for i, p in enumerate(_prompts(spec["lengths"], seed=11))]
     grown = []
@@ -174,6 +189,10 @@ def test_served_logits_match_the_references_full_forward(tiny, case):
     got = _serve(engine, reqs)
     assert all(len(r.generated) == spec["new"] for r in reqs)
     assert _worst_gap(params, hf, reqs, got) < TOL_SERVED
+    # a prefill tile held 8 tokens at most, or a wide tile's 16
+    snap = engine.metrics.snapshot()
+    assert snap["attn_prefill_tiles_packed"] > 0
+    assert (snap["attn_prefill_tile_tokens"] > 8) == bool(spec.get("wide"))
     stats = engine.pool.stats()
     # both classes back to empty; the bounded one never held more than its
     # rings while the contexts grew past them, and its blocks went round
@@ -182,6 +201,79 @@ def test_served_logits_match_the_references_full_forward(tiny, case):
     assert max(grown) <= len(reqs) * 5
     assert -(-(len(reqs[0].prompt) + spec["new"]) // 8) > 5  # > a ring
     assert stats["window_blocks_recycled_total"] >= 3
+
+
+def test_the_packer_lays_a_chunks_whole_wide_tiles_first(tiny, monkeypatch):
+    """A tick of a program with wide tiles (in small: 16 lanes, the rung
+    past two one-tile rows): a prompt slice's whole wide tiles lie first
+    on the tiled axis, each named by the first of its two tile entries,
+    what is left of the slice and the decoding row's one-token tile behind
+    them; the two index maps tie every token to its lane and back; the
+    program is the one the totals pick without wide tiles."""
+    import llm_np_cp_tpu.ops.pallas.decode_attention as da
+    from llm_np_cp_tpu.serve.engine import split_mixed_operands
+
+    cfg, _, params = tiny
+    monkeypatch.setattr(da, "ragged_wide_tile", lambda *a: 16)
+    engine = _engine(cfg, params, wide=16)
+    assert {t: engine._wide_program(t) for t, _ in engine.mixed_buckets} == {
+        8: 0, 16: 0, 32: 16}
+    packed = []
+    real = engine._pack_mixed
+
+    def watched(decode_rows, prefill_segs):
+        out = real(decode_rows, prefill_segs)
+        packed.append((out[0].copy(), out[1],
+                       [1 + r.draft_len for r in decode_rows],
+                       [n for _, n in prefill_segs]))
+        return out
+
+    engine._pack_mixed = watched
+    engine.submit(_prompts([5], seed=3)[0], max_new_tokens=6)
+    engine.step()
+    engine.submit(_prompts([53], seed=2)[0], max_new_tokens=2)
+    engine.run_until_complete()
+    wide_ticks = 0
+    for ops, program, dec, pre in packed:
+        sec = split_mixed_operands(ops, engine._mixed_layouts[program][0])
+        sizes = dec + pre
+        assert program == engine._pick_bucket(
+            sum(-(-n // 8) * 8 for n in sizes), sum(sizes))
+        qlen = sec["tile_qlen"]
+        n_tok = sum(sizes)
+        # every token has its lane, and its lane names it
+        lanes = sec["tok_lane"][:n_tok]
+        assert len(set(lanes.tolist())) == n_tok
+        assert (sec["lane_tok"][lanes] == np.arange(n_tok)).all()
+        assert qlen.sum() == n_tok
+        wide = engine._wide_program(program[0])
+        full = [n // 16 * 16 for n in pre] if wide else []
+        n_wide = sum(full) // 16
+        # the wide tiles: first, at every second entry, the one after dead
+        assert (qlen[0:2 * n_wide:2] == 16).all()
+        assert (qlen[1:2 * n_wide:2] == 0).all()
+        assert (qlen[2 * n_wide:] <= 8).all()
+        if n_wide:
+            wide_ticks += 1
+            at = len(dec)  # (dense order: decode rows, then the slices)
+            tok0 = sum(dec)
+            for n, w in zip(pre, full):
+                # a slice's tokens: its wide part on consecutive lanes from
+                # a multiple of 16 on, then what is left of it
+                lane = sec["tok_lane"][tok0:tok0 + n]
+                assert lane[0] % 16 == 0 and (np.diff(lane[:w]) == 1).all()
+                assert (lane[:w] < 16 * n_wide).all()
+                assert (lane[w:] >= 16 * n_wide).all()
+                ti = lane[0] // 8
+                assert sec["tile_qpos0"][ti] == sec["tok_slot"][tok0]
+                tok0 += n
+                at += 1
+            # ... and a decode row's one token in a tile of its own behind
+            for i in range(len(dec)):
+                assert sec["tok_lane"][i] >= 16 * n_wide
+                assert qlen[sec["tok_lane"][i] // 8] == 1
+    assert wide_ticks >= 2 and any(d and any(
+        n >= 16 for n in p) for _, _, d, p in packed)
 
 
 def test_one_shape_lands_in_two_classes(tiny):

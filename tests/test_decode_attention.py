@@ -267,18 +267,36 @@ def test_ragged_batch_parity():
 _NO_WINDOW = 1 << 30
 
 
-def _pack_segments(segs, dead_tiles=0):
+def _pack_segments(segs, dead_tiles=0, wide=0):
     """``[(row, qpos0, n_tokens)]`` → the kernel's per-TILE metadata, the
     XLA twin's per-TOKEN metadata and the packed width, laid out the way
     the engine's packer does: each segment on its own q tiles, the tail
     of its last tile dead, a zero-token segment one dead tile where it
     stands, ``dead_tiles`` whole dead tiles at the end (the bucket's
-    padding)."""
+    padding).  ``wide``: a segment's whole tiles of ``wide`` tokens come
+    FIRST, each named by the first of its ``wide / 8`` tile entries (the
+    others dead), and what is left of it where it stood; the width is
+    rounded up to whole wide tiles by dead ones.  A segment ``(row,
+    qpos0, -n)`` is ONE wide tile of ``n < wide`` tokens."""
     from llm_np_cp_tpu.ops.pallas.decode_attention import RAGGED_Q_TILE as qt
 
     tile_row, tile_qpos0, tile_qlen = [], [], []
     tok_row, tok_slot, tok_live = [], [], []
-    for row, qpos0, n in segs:
+    rest = []
+    for row, qpos0, n in segs if wide else ():
+        # the tokens of the segment that go into wide tiles (``-n``: all)
+        whole = n // wide * wide if n > 0 else -n
+        for i in range(0, whole, wide):
+            live = min(wide, whole - i)
+            tile_row += [row] * (wide // qt)
+            tile_qpos0 += [qpos0 + i] + [0] * (wide // qt - 1)
+            tile_qlen += [live] + [0] * (wide // qt - 1)
+            tok_row += [row] * wide
+            tok_slot += [qpos0 + i + j if j < live else 0 for j in range(wide)]
+            tok_live += [j < live for j in range(wide)]
+        if n == 0 or 0 <= whole < n:  # what is left of it (or its dead tile)
+            rest.append((row, qpos0 + whole, n - whole))
+    for row, qpos0, n in rest if wide else segs:
         if n == 0:  # a dead tile BETWEEN live ones (a row that left)
             tile_row.append(row), tile_qpos0.append(0), tile_qlen.append(0)
             tok_row += [row] * qt
@@ -293,6 +311,8 @@ def _pack_segments(segs, dead_tiles=0):
                 tok_row.append(row)
                 tok_slot.append(qpos0 + i + j if j < live else 0)
                 tok_live.append(j < live)
+    if wide:
+        dead_tiles += -(len(tile_row) + dead_tiles) % (wide // qt)
     for _ in range(dead_tiles):
         tile_row.append(0), tile_qpos0.append(0), tile_qlen.append(0)
         tok_row += [0] * qt
@@ -305,7 +325,7 @@ def _pack_segments(segs, dead_tiles=0):
 
 
 def _ragged_reference(q, pages_k, pages_v, tables, tok, pads, window, *,
-                      scale, logit_softcap=None):
+                      scale, logit_softcap=None, sink=None):
     """One ``gqa_attention`` call a live token over its row's blocks
     gathered contiguous; dead lanes stay zero."""
     tok_row, tok_slot, tok_live = (np.asarray(a) for a in tok)
@@ -320,7 +340,7 @@ def _ragged_reference(q, pages_k, pages_v, tables, tok, pads, window, *,
         mask = ((pos >= lo) & (pos <= slot))[None, None, :]
         out[t] = np.asarray(gqa_attention(
             q[t][None, None], gk, gv, mask, scale=scale,
-            logit_softcap=logit_softcap))[0, 0]
+            logit_softcap=logit_softcap, sink=sink))[0, 0]
     return out
 
 
@@ -333,32 +353,36 @@ def _merge(pages):
 
 def _check_ragged(q, pages_k, pages_v, tables, segs, pads, *, scale,
                   window=_NO_WINDOW, logit_softcap=None, dead_tiles=0,
-                  scales=None, float_pages=None, merged=False):
+                  scales=None, float_pages=None, merged=False, wide=0,
+                  sink=None):
     """Kernel == XLA twin == reference on the live lanes; the kernel's
     dead lanes are exactly zero (what the step scatters to scratch and
     the host discards must never be NaN or another row's output).
     ``merged``: kernel and twin get the pages ``[NB, BS, K * D]``; the
-    reference keeps the 4-D pool they were made from."""
+    reference keeps the 4-D pool they were made from.  ``wide``: the
+    segments' whole wide tiles first (``_pack_segments``)."""
     from llm_np_cp_tpu.ops.pallas.decode_attention import (
         ragged_paged_attention,
         ragged_paged_attention_xla,
     )
 
-    tile, tok, width = _pack_segments(segs, dead_tiles)
+    tile, tok, width = _pack_segments(segs, dead_tiles, wide)
     assert q.shape[0] == width
-    kw = dict(scale=scale, logit_softcap=logit_softcap)
+    kw = dict(scale=scale, logit_softcap=logit_softcap, sink=sink)
     if scales is not None:
         kw.update(k_scale=scales[0], v_scale=scales[1])
     win = jnp.asarray(window, jnp.int32)
     kernel_k, kernel_v = ((_merge(pages_k), _merge(pages_v)) if merged
                           else (pages_k, pages_v))
     got = np.asarray(ragged_paged_attention(
-        q, kernel_k, kernel_v, tables, *tile, pads, win, **kw))
+        q, kernel_k, kernel_v, tables, *tile, pads, win, wide_tile=wide,
+        **kw))
     twin = np.asarray(ragged_paged_attention_xla(
         q, kernel_k, kernel_v, tables, *tok, pads, win, **kw))
     fk, fv = float_pages if float_pages is not None else (pages_k, pages_v)
     want = _ragged_reference(q, fk, fv, tables, tok, pads, window,
-                             scale=scale, logit_softcap=logit_softcap)
+                             scale=scale, logit_softcap=logit_softcap,
+                             sink=sink)
     live = np.asarray(tok[2])
     assert live.any() and (dead_tiles == 0 or not live.all())
     np.testing.assert_allclose(got[live], want[live], atol=2e-5)
@@ -372,9 +396,18 @@ def _decode_segs(lengths):
     return [(r, int(n) - 1, 1) for r, n in enumerate(lengths)]
 
 
-def _packed_q(rng, segs, h, d, dead_tiles=0):
-    width = _pack_segments(segs, dead_tiles)[2]
+def _packed_q(rng, segs, h, d, dead_tiles=0, wide=0):
+    width = _pack_segments(segs, dead_tiles, wide)[2]
     return _rand(rng, (width, h, d))
+
+
+def _by_token(out, segs, dead_tiles=0, wide=0):
+    """A packed result's live lanes in the order (row, cache slot): what
+    two layouts of the same segments have in common."""
+    tok_row, tok_slot, live = (
+        np.asarray(a) for a in _pack_segments(segs, dead_tiles, wide)[1])
+    lanes = np.flatnonzero(live)
+    return out[lanes[np.lexsort((tok_slot[lanes], tok_row[lanes]))]]
 
 
 _PAGE_FORMS = pytest.mark.parametrize(
@@ -683,6 +716,41 @@ _MERGED_CASES = {
     "kd256-heads-of-128": (
         8, 2, 128, 16, [(0, 1023, 1), (1, 100, 9), (2, 512, 1)], [0, 3, 0],
         _NO_WINDOW),
+    # WIDE tiles (PERF.md section 6, PR 57): a prompt chunk's tokens 64 /
+    # 32 / 16 to a tile, each of which walks its row's pages once.  A
+    # chunk of 150 = two tiles of 64 + three of 8 lanes, beside a decode
+    # row: the causal edge lies inside every wide tile, the row's pad
+    # before the first
+    "kd256-wide64-causal-edge-inside-the-tile": (
+        8, 4, 64, 16, [(0, 410, 1), (1, 600, 150)], [0, 17], _NO_WINDOW,
+        dict(wide=64)),
+    # a window of 40 positions starts INSIDE each wide tile's own span;
+    # one of 700 starts some groups of pages back, mid-group
+    "kd256-wide64-window-starts-inside-the-tile": (
+        8, 4, 64, 16, [(0, 410, 1), (1, 600, 150)], [0, 17], 40,
+        dict(wide=64)),
+    "kd256-wide64-window-starts-mid-group": (
+        8, 4, 64, 16, [(0, 999, 1), (1, 820, 130)], [0, 17], 700,
+        dict(wide=64)),
+    # chunks whose lengths no width divides, two rows' worth, between
+    # one-token decode tiles and a verify slice
+    "kd512-wide32-chunks-of-70-and-45-beside-decode-rows": (
+        32, 8, 64, 16,
+        [(0, 999, 1), (1, 300, 70), (2, 62, 4), (3, 130, 45)],
+        [0, 0, 7, 129], _NO_WINDOW, dict(wide=32)),
+    "kd128-wide16-four-heads-of-32": (
+        8, 4, 32, 16, [(0, 600, 1), (1, 447, 37), (2, 512, 3)], [0, 0, 66],
+        _NO_WINDOW, dict(wide=16)),
+    # heads of 128 (a head is a page's row of lanes) under learned sinks,
+    # behind a pad of more than a page
+    "kd256-wide64-heads-of-128-with-sinks": (
+        12, 2, 128, 16, [(0, 1023, 1), (1, 100, 200), (2, 512, 1)],
+        [0, 70, 0], 300, dict(wide=64, sink=True)),
+    # ONE wide tile that is not full (50 of 64 lanes live): the packer
+    # lays none, the kernel masks its dead rows as it does a tile's
+    "kd256-wide64-a-tile-of-50-tokens": (
+        8, 4, 64, 16, [(0, 410, 1), (1, 600, -50)], [0, 17], _NO_WINDOW,
+        dict(wide=64)),
 }
 
 
@@ -692,9 +760,11 @@ def test_ragged_merged_pages(case):
         _dma_slices_pages,
         _lane_pack,
         ragged_pages_per_step,
+        ragged_wide_tile,
     )
 
-    h, kh, d, mb, segs, pads, window = _MERGED_CASES[case]
+    h, kh, d, mb, segs, pads, window, *more = _MERGED_CASES[case]
+    wide, with_sink = (more[0].get(k, 0) for k in ("wide", "sink")) if more else (0, 0)
     rng = np.random.default_rng(len(case) * 17 + h)
     pages_k, pages_v, tables = _group_pool(rng, kh, d, mb, len(pads))
     assert (kh * d) % 128 == 0 and f"kd{kh * d}" in case
@@ -704,13 +774,32 @@ def test_ragged_merged_pages(case):
     assert _dma_slices_pages(_merge(pages_k))
     assert _lane_pack(kh, d) == max(128 // d, 1)
     dead = 1
-    q = _packed_q(rng, segs, h, d, dead)
-    kw = dict(scale=d**-0.5, window=window, dead_tiles=dead)
+    q = _packed_q(rng, segs, h, d, dead, wide)
+    sink = _rand(rng, (h,)) * 3 if with_sink else None
+    kw = dict(scale=d**-0.5, window=window, dead_tiles=dead, sink=sink)
     pads = jnp.asarray(pads, jnp.int32)
     got = _check_ragged(q, pages_k, pages_v, tables, segs, pads, merged=True,
-                        **kw)
-    plain = _check_ragged(q, pages_k, pages_v, tables, segs, pads, **kw)
-    np.testing.assert_allclose(got, plain, atol=2e-5)
+                        wide=wide, **kw)
+    if not wide:
+        plain = _check_ragged(q, pages_k, pages_v, tables, segs, pads, **kw)
+        np.testing.assert_allclose(got, plain, atol=2e-5)
+        return
+    assert f"wide{wide}" in case and wide <= ragged_wide_tile(
+        kh, h // kh, d, d, pages_k.dtype, True)
+    # the same tokens in tiles of 8 lanes alone (a tile of fewer than
+    # ``wide`` tokens: as a segment of that many): the same result
+    segs = [(r, p0, abs(n)) for r, p0, n in segs]
+    _, (row8, slot8, live8), width8 = _pack_segments(segs, dead)
+    lanes = np.flatnonzero(np.asarray(live8))
+    q8 = np.zeros((width8, h, d), np.float32)
+    q8[lanes[np.lexsort((np.asarray(slot8)[lanes],
+                         np.asarray(row8)[lanes]))]] = _by_token(
+        np.asarray(q), _MERGED_CASES[case][4], dead, wide)
+    plain = _check_ragged(jnp.asarray(q8), pages_k, pages_v, tables, segs,
+                          pads, merged=True, **kw)
+    np.testing.assert_allclose(
+        _by_token(got, _MERGED_CASES[case][4], dead, wide),
+        _by_token(plain, segs, dead), atol=2e-5)
 
 
 def test_ragged_merged_pages_refuse_what_they_cannot_be():

@@ -795,23 +795,36 @@ def test_a_v5e_keeps_mimo_v2s_pages_in_the_order_of_their_shape_merged(
     assert (kept == tuple(range(len(shape)))) is row_major, kept
 
 
-@pytest.mark.parametrize("kh,mb,blocks,sink,base", [
-    (8, 12, 5 * 769, True, True),     # a window layer: its ring's table
-    (4, 78, 2 * 4994, False, False),  # a global layer: the growing chain
-], ids=["window", "global"])
+@pytest.mark.parametrize("kh,mb,blocks,sink,base,wide", [
+    (8, 12, 5 * 769, True, True, 0),     # a window layer: its ring's table
+    (4, 78, 2 * 4994, False, False, 0),  # a global layer: the growing chain
+    # ... with chunks in tiles of 32 lanes: what the kernel CAN take over
+    # such pages and no packer lays (``ragged_wide_tile``: 0)
+    (8, 12, 5 * 769, True, True, 32),
+    (4, 78, 2 * 4994, False, False, 32),
+], ids=["window", "global", "window-wide", "global-wide"])
 def test_ragged_kernel_compiles_at_mimo_v2s_page_classes(
-        v5e_sharding, kh, mb, blocks, sink, base):
+        v5e_sharding, kh, mb, blocks, sink, base, wide):
     """64 query heads of 192 over ``kh`` kv heads, V pages 128 wide, at
-    the cell's widest program (136 tiles): the kernel lowers for the v5e
-    inside its scoped VMEM with a sink operand and a table whose column 0
-    is not position 0, K and V copied by its own DMAs out of merged pages
-    of their own widths; without either it has neither operand."""
+    the cell's widest program (136 tiles; 128 with wide tiles): the
+    kernel lowers for the v5e inside its scoped VMEM with a sink operand
+    and a table whose column 0 is not position 0, K and V copied by its
+    own DMAs out of merged pages of their own widths; without either it
+    has neither operand.  ``wide``: the call may hold wide tiles — a
+    block of ``q`` is 32 lanes, the scratch 32 tokens' score rows, and
+    what it takes of VMEM fits the limit the call asks for.  The kernel
+    takes such a call; the engine makes none: a tile of 8 tokens over
+    these pages (two heads of 192 to a 384-deep dot, a group of 8 / 16) is
+    bound by its arithmetic already, and at a tile of 32 the cell's prompt
+    tick ran 1.8 ms LONGER (PERF.md section 6, PR 57)."""
     from llm_np_cp_tpu.ops.pallas import decode_attention as da
 
     def aval(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_sharding)
 
-    nt, h, d, dv, i32 = 136, 64, 192, 128, jnp.int32
+    nt, h, d, dv, i32 = 128 if wide else 136, 64, 192, 128, jnp.int32
+    shape = (kh, h // kh, d, dv, jnp.bfloat16, True)
+    assert (da._wide_tile_limit(*shape), da.ragged_wide_tile(*shape)) == (32, 0)
     args = [aval((nt * 8, h, d), jnp.bfloat16),
             aval((blocks, 64, kh * d), jnp.bfloat16),
             aval((blocks, 64, kh * dv), jnp.bfloat16),
@@ -826,13 +839,17 @@ def test_ragged_kernel_compiles_at_mimo_v2s_page_classes(
     jax.default_backend = lambda: "tpu"
     try:
         compiled = jax.jit(functools.partial(
-            da.ragged_paged_attention, scale=d ** -0.5)).lower(
-                *args, **kw).compile()
+            da.ragged_paged_attention, scale=d ** -0.5,
+            wide_tile=wide)).lower(*args, **kw).compile()
     finally:
         jax.default_backend = real
     _, _, module = _ragged_kernel_call(compiled.as_text())
-    p = da.ragged_pages_per_step(mb, 64, kh, d, jnp.bfloat16, False, merged=True)
-    assert p == min(8, mb) and _kernel_grid(module) == (nt, -(-mb // p))
+    p = da.ragged_pages_per_step(mb, 64, kh, d, jnp.bfloat16, False,
+                                 merged=True, wide=bool(wide))
+    # (a call with wide tiles walks 1,024 positions a step — as many pages
+    # as the buffers' budget holds of the window class's wider K page)
+    assert p == (min(8, mb) if not wide else 10 if kh == 8 else 16)
+    assert _kernel_grid(module) == (nt, -(-mb // p))
     # two heads of 192 share three whole rows of lanes; a value head of
     # 128 is a row of its own
     assert (da._lane_pack(kh, d), da._lane_pack(kh, dv)) == (2, 1)
@@ -840,22 +857,32 @@ def test_ragged_kernel_compiles_at_mimo_v2s_page_classes(
     assert scratch == [((2, p, 64, kh * d), "bf16"),
                        ((2, p, 64, kh * dv), "bf16")], scratch
     # the whole tile's update and a one-token tile's, each a dot a pair of
-    # key heads and one a value head
-    assert module.count("tpu.matmul") == 2 * (kh // 2 + kh)
+    # key heads and one a value head; a wide tile's a dot a key head and
+    # block of 512 score rows and one a value head
+    blocks_w = max(wide * (h // kh) // da._WIDE_SHEET_ROWS, 1)
+    assert module.count("tpu.matmul") == 2 * (kh // 2 + kh) + (
+        2 * kh * blocks_w if wide else 0)
     # the sink: one float32 a score-sheet row (kv head, token, group
-    # head) beside the running maximum and the denominator
+    # head) beside the running maximum and the denominator (of a wide
+    # tile's tokens where the call has one)
     sig = next(ln for ln in module.splitlines() if "^bb0(" in ln)
-    assert sig.count(f"memref<{8 * h}x1xf32") == (3 if sink else 2)
+    assert sig.count(f"memref<{8 * h}x1xf32") == (
+        (1 if sink else 0) if wide else (3 if sink else 2))
+    assert sig.count(f"memref<{wide * h}x1xf32") == (2 if wide else 0)
     assert f"{blocks}x64x{kh * d}xbf16" in module
 
 
-@pytest.mark.parametrize("mb,blocks,base,merged", [
-    (78, 4 * 2497, True, True),     # a window layer: its ring's table
-    (140, 4482, False, True),       # the global layer: the growing chain
-    (140, 4482, False, False),      # ... as [BS, K, D] pages: refused
-], ids=["window", "global", "global-heads-in-rows"])
+@pytest.mark.parametrize("mb,blocks,base,merged,wide", [
+    (78, 4 * 2497, True, True, 0),     # a window layer: its ring's table
+    (140, 4482, False, True, 0),       # the global layer: the growing chain
+    (140, 4482, False, False, 0),      # ... as [BS, K, D] pages: refused
+    # ... in the program a prompt tick runs: chunks in tiles of 64 lanes
+    (78, 4 * 2497, True, True, 64),
+    (140, 4482, False, True, 64),
+], ids=["window", "global", "global-heads-in-rows", "window-wide",
+        "global-wide"])
 def test_ragged_kernel_at_afmoes_page_classes(
-        v5e_sharding, mb, blocks, base, merged):
+        v5e_sharding, mb, blocks, base, merged, wide):
     """48 query heads of 128 over 8 kv heads (Trinity: K 8, G 6) at the
     cell's widest program (128 tiles): stored MERGED, a head is a whole
     row of a page's lanes and the kernel lowers for the v5e with a table
@@ -863,13 +890,19 @@ def test_ragged_kernel_at_afmoes_page_classes(
     global one).  As ``[BS, K, D]`` pages the kernel would attend all 8
     heads in one ``[K * rows, BS * K]`` sheet of which a row keeps an
     eighth, and the compiler refuses it: why a pool of two classes stores
-    both merged whatever their heads (serve/block_pool.py)."""
+    both merged whatever their heads (serve/block_pool.py).  ``wide``: the
+    call may hold wide tiles of 64 lanes (what such pages have): ``q``'s
+    block is 64 lanes, the scratch 64 tokens' 3,072 score rows, the pages'
+    buffers what they were, and the whole fits the scoped VMEM the call
+    asks for (the compile is the proof; PR 57)."""
     from llm_np_cp_tpu.ops.pallas import decode_attention as da
 
     def aval(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_sharding)
 
     nt, h, kh, d, i32 = 128, 48, 8, 128, jnp.int32
+    assert da.ragged_wide_tile(
+        kh, h // kh, d, d, jnp.bfloat16, merged) == (64 if merged else 0)
     page = (64, kh * d) if merged else (64, kh, d)
     args = [aval((nt * 8, h, d), jnp.bfloat16),
             aval((blocks,) + page, jnp.bfloat16),
@@ -881,7 +914,8 @@ def test_ragged_kernel_at_afmoes_page_classes(
     jax.default_backend = lambda: "tpu"
     try:
         lowered = jax.jit(functools.partial(
-            da.ragged_paged_attention, scale=d ** -0.5)).lower(*args, **kw)
+            da.ragged_paged_attention, scale=d ** -0.5,
+            wide_tile=wide)).lower(*args, **kw)
         if not merged:
             with pytest.raises(Exception, match="vmem"):
                 lowered.compile()
@@ -890,16 +924,32 @@ def test_ragged_kernel_at_afmoes_page_classes(
     finally:
         jax.default_backend = real
     _, _, module = _ragged_kernel_call(compiled.as_text())
-    p = da.ragged_pages_per_step(mb, 64, kh, d, jnp.bfloat16, False, merged=True)
-    assert p == 8 and _kernel_grid(module) == (nt, -(-mb // p))
+    p = da.ragged_pages_per_step(mb, 64, kh, d, jnp.bfloat16, False,
+                                 merged=True, wide=bool(wide))
+    # (1,024 positions a step in a call with wide tiles: most of such a
+    # call's grid steps are dead ones)
+    assert p == (16 if wide else 8)
+    assert _kernel_grid(module) == (nt, -(-mb // p))
     assert (da._lane_pack(kh, d), da._dma_slices_pages(
         jnp.zeros((2,) + page, jnp.bfloat16))) == (1, True)
     scratch = _kernel_vmem_scratch(module, rank=4)[-2:]
     assert scratch == [((2, p, 64, kh * d), "bf16")] * 2, scratch
     # the whole tile's update and a one-token tile's, a dot a head for
-    # the scores and one for the values
-    assert module.count("tpu.matmul") == 2 * 2 * kh
+    # the scores and one for the values — and a wide tile's (64 tokens x
+    # 6 group heads: one block of score rows a head)
+    assert module.count("tpu.matmul") == (3 if wide else 2) * 2 * kh
     assert f"{blocks}x64x{kh * d}xbf16" in module
+    sig = next(ln for ln in module.splitlines() if "^bb0(" in ln)
+    rows = (wide or 8) * h
+    assert sig.count(f"memref<{rows}x1xf32") == 2  # maximum, denominator
+    assert f"memref<{rows}x{d}xf32" in sig         # the accumulator
+    # the limit: the 16 MiB a kernel gets unasked, or what the call counts
+    limit = da._wide_compiler_params(wide, kh, h // kh, d, d, 64, p,
+                                     jnp.bfloat16)
+    assert bool(limit) == bool(wide)
+    if wide:
+        asked = limit["compiler_params"].vmem_limit_bytes
+        assert 16 * 2**20 < asked <= 64 * 2**20
 
 
 def test_a_stack_with_one_page_class_compiles_the_parents_tick(v5e_sharding):
@@ -973,6 +1023,88 @@ def test_mimo_v2_tick_on_a_v5e_reads_both_classes_where_they_lie(v5e_sharding):
     _assert_experts_run_the_kernel(ops, layers=4, weights=[
         (4, 256, 128), (4, 128, 256)],
         pairs=(engine.mixed_buckets[-1][1], cfg.num_experts_per_tok, 256))
+
+
+def _ragged_kernel_modules(hlo_text):
+    """The Mosaic module of every ragged kernel call of a compiled step,
+    debug locations dropped (``_ragged_kernel_call`` for a stack whose
+    layers are not one scan)."""
+    lines = [ln for ln in hlo_text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "ragged_paged_attention" in ln.split("=", 1)[0]]
+    return [_ragged_kernel_call(ln)[2] for ln in lines]
+
+
+def test_wide_tiles_lie_in_the_chunk_rungs_and_a_decode_tick_keeps_its_call(
+        v5e_sharding):
+    """A Trinity-shaped stack (48 heads of 128 over 8 kv heads, merged pages
+    in two classes: a wide tile of 64 lanes) at 32 slots: the program past
+    the rung of 32 one-tile rows — the one only a tick with a prompt chunk
+    runs — takes ``q`` and the result in blocks of 64 lanes and walks a
+    row's pages 16 to a step; the steady decode tick's program ``(256, 32)``
+    calls the kernel it called before there was a wide tile, to the byte:
+    the function's own call at 32 tiles with no wide tile.  A MiMo-V2-shaped
+    stack has none (``ragged_wide_tile``), in any program (PR 57)."""
+    from llm_np_cp_tpu.ops.pallas import decode_attention as da
+
+    cfg = tiny_config(
+        "afmoe", hidden_size=256, intermediate_size=512,
+        num_attention_heads=48, num_key_value_heads=8, head_dim=128,
+        sliding_window=4096, moe_intermediate_size=128, vocab_size=2048)
+    engine, decode = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, blocks=1026, slots=32,
+        chunk=128, program=(256, 32))
+    assert engine._wide_tile == 64
+    wide = {t_w: engine._wide_program(t_w) for t_w, _ in engine.mixed_buckets}
+    assert wide == {t_w: 64 if t_w > 256 else 0 for t_w in wide}
+    assert max(wide) == 512 and len(engine.mixed_buckets) == 8
+    h, mb = cfg.num_attention_heads, engine.max_blocks_per_seq
+    modules = _ragged_kernel_modules(decode.as_text())
+    assert len(modules) == cfg.num_hidden_layers
+    for module in modules:
+        sig = next(ln for ln in module.splitlines() if "^bb0(" in ln)
+        assert f"memref<{8 * h}x1xf32" in sig  # a tile's score rows
+        assert f"memref<{64 * h}x1xf32" not in sig
+
+    def aval(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_sharding)
+
+    pages, i32 = engine.pool.pages, jnp.int32
+    nb = pages.k.shape[0] * pages.k.shape[1]
+    args = [aval((256, h, 128), jnp.bfloat16),
+            aval((nb,) + pages.k.shape[2:], jnp.bfloat16),
+            aval((nb,) + pages.v.shape[2:], jnp.bfloat16),
+            aval((32, mb), i32), aval((32,), i32), aval((32,), i32),
+            aval((32,), i32), aval((32,), i32), aval((), i32)]
+    with _traced_for_a_tpu():
+        alone = jax.jit(functools.partial(
+            da.ragged_paged_attention, scale=cfg.attn_scale)).lower(
+                *args).compile()
+    assert _ragged_kernel_call(alone.as_text())[2] in modules
+    # the widest program: every call may hold wide tiles
+    _, widest = _compile_widest_bucket(
+        v5e_sharding, jnp.bfloat16, cfg=cfg, blocks=1026, slots=32,
+        chunk=128)
+    modules = _ragged_kernel_modules(widest.as_text())
+    assert len(modules) == cfg.num_hidden_layers
+    for module in modules:
+        sig = next(ln for ln in module.splitlines() if "^bb0(" in ln)
+        assert sig.count(f"memref<{64 * h}x1xf32") == 2
+        assert _kernel_grid(module)[0] == 512 // 8
+    # MiMo-V2's pages: none, in any program
+    mimo = tiny_config(
+        "mimo_v2", hidden_size=256, intermediate_size=512,
+        num_attention_heads=64, num_key_value_heads=4,
+        swa_num_key_value_heads=8, head_dim=192, v_head_dim=128, rope_dim=64,
+        sliding_window=128, moe_intermediate_size=128, num_experts_held=4,
+        first_expert=4, vocab_size=2048)
+    abstract = jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), mimo, dtype=jnp.bfloat16))
+    engine = ServeEngine(
+        abstract, mimo, max_slots=64, num_blocks=1026, block_size=BLOCK,
+        max_seq_len=BLOCK * 8, prefill_chunk=128, cache_dtype=jnp.bfloat16)
+    assert engine._wide_tile == 0 and not any(
+        engine._wide_program(t_w) for t_w, _ in engine.mixed_buckets)
 
 
 @pytest.mark.parametrize("shape,row_major,held", [

@@ -1321,6 +1321,11 @@ def test_stage_family_counters_and_request_log_agree_with_the_trace(staged_run):
         assert got == pytest.approx(np.percentile(derived[stage], 50),
                                     abs=2.1 / 1024)
     assert f"llm_serve_lane_ticks_total {snap['lane_ticks']}" in prom
+    # ... and how wide a prefill tile was: 8 lanes here (no wide tile)
+    assert 1 < snap["attn_prefill_tile_tokens"] <= 8
+    assert ("llm_serve_attn_prefill_tiles_packed_total "
+            f"{snap['attn_prefill_tiles_packed']}") in prom
+    assert "llm_serve_attn_prefill_tile_tokens " in prom
     # the old names keep their meanings beside the family
     assert snap["queue_wait_s_p50"] == pytest.approx(
         snap["ttft_stage_slot_wait_s_p50"])

@@ -216,7 +216,7 @@ LOCK_STATE: tuple[dict, ...] = (
             "kv_bytes_tick", "prefix_blocks_requested",
             "prefix_blocks_hit", "mixed_prefill_tokens",
             "mixed_decode_tokens", "mixed_dense_lanes",
-            "prefill_segments",
+            "prefill_segments", "prefill_tiles", "prefill_tile_tokens",
             "publish_overlapped", "publish_immediate", "t_start",
             "t_last",
             "anomaly_ticks", "lifecycle_actions",
