@@ -237,7 +237,8 @@ def _add_serve_engine_flags(p: argparse.ArgumentParser,
                    "checkpoint must be: start-up fails unless its "
                    "config's model_type is MODEL_TYPE (llama, mistral, "
                    "mixtral, qwen2, gemma2, lfm2_moe, falcon_h1, "
-                   "deepseek_v3, mimo_v2, ling_hybrid, afmoe, brumby) — so "
+                   "deepseek_v3, mimo_v2, ling_hybrid, afmoe, brumby, "
+                   "glm_moe_dsa) — so "
                    "that a "
                    "deployment never "
                    "serves another architecture under a model's name")

@@ -213,6 +213,20 @@ class ModelConfig:
     # what ``norm_topk_prob`` adds to the sum of a token's top-k scores
     # (LFM2's published code: 1e-6; DeepSeek-V3's: 1e-20)
     router_norm_eps: float = 1e-6
+    # a QUERY latent (``model_type: glm_moe_dsa``; None: one full-rank
+    # ``q_proj``): ``q = rmsnorm(h W_qa) W_qb``
+    q_lora_rank: int | None = None
+    # ... under a learned sparse-attention indexer (``index_topk is None``
+    # is every other family): a token scores every visible position with
+    # ``index_n_heads`` heads of ``index_head_dim`` against ONE cached
+    # index key a token and layer (its leading ``qk_rope_head_dim``
+    # columns rotated), and attends the ``index_topk`` best of them, all
+    # of them while it sees no more (ops/sparse_index.py has the
+    # equations and the tie rule)
+    index_topk: int | None = None
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_rope_interleave: bool = False
 
     # --- Window and global layers that differ in more than the mask
     # (MiMo-V2, ``model_type: mimo_v2``): a window layer has its own kv
@@ -297,6 +311,17 @@ class ModelConfig:
             raise ValueError(
                 f"latent attention rotates head_dim {self.head_dim} columns: "
                 f"qk_rope_head_dim is {self.qk_rope_head_dim} (and is even)")
+        if self.has_indexer and not (
+                self.is_latent and self.q_lora_rank
+                and self.layer_group_size is None and self.index_topk > 0
+                and self.index_n_heads > 0
+                and self.index_head_dim >= self.qk_rope_head_dim):
+            raise ValueError(
+                "a sparse-attention indexer reads a query latent "
+                f"(q_lora_rank {self.q_lora_rank}) beside latent attention "
+                f"in every layer: {self.index_n_heads} heads of "
+                f"{self.index_head_dim} (rotated {self.qk_rope_head_dim}), "
+                f"top {self.index_topk}")
         if self.two_page_classes:
             kv_heads = self.attn_kind("window").kv_heads
             if self.sliding_window is None or self.is_latent or (
@@ -612,6 +637,12 @@ class ModelConfig:
         layer of each group of delta-rule ones."""
         return self.kv_lora_rank is not None
 
+    @property
+    def has_indexer(self) -> bool:
+        """Latent attention over a learned SELECTION of the context: every
+        layer holds an index key a token beside its latent row."""
+        return self.index_topk is not None
+
     def kv_token_shapes(self, kind: str = "global") -> dict[str, tuple[int, ...]]:
         """What ONE token leaves in a cache, a layer of ``kind`` that has
         pages, as ``{leaf: shape}``: K and V per kv head (a layer kind's
@@ -633,9 +664,11 @@ class ModelConfig:
         (``kv_token_shapes``; int8 scale pages not counted; a window
         layer counted as if it kept every token — what a pool bounds)."""
         def of(k: str, layers: int) -> int:
-            return layers * itemsize * sum(
+            # (an indexer's key lies beside the latent row: has_indexer)
+            return layers * itemsize * (sum(
                 math.prod(shape)
                 for shape in self.kv_token_shapes(k).values())
+                + (self.index_head_dim if self.has_indexer else 0))
         n_window = len(self.window_layers)
         per_kind = {"global": of("global", len(self.attn_layers) - n_window),
                     "window": of("window", n_window)}
@@ -815,59 +848,34 @@ class ModelConfig:
                 raise ValueError(
                     "deepseek_v3 with q_lora_rank (a query latent) is not "
                     "implemented")
-            if rope_scaling is not None:
+            kwargs.update(_deepseek_kwargs(d, rope_scaling))
+        if model_type == "glm_moe_dsa":
+            # GLM-5: DeepSeek-V3's layer with a QUERY latent, and a learned
+            # sparse-attention indexer that reads it (five ``index_*``
+            # keys); everything else is ``_deepseek_kwargs``'s
+            kwargs.update(_deepseek_kwargs(d, rope_scaling))
+            if d.get("ep_size", 1) != 1:
                 raise ValueError(
-                    "deepseek_v3 with rope_scaling (YaRN and its mscale) is "
-                    "not implemented")
-            if d.get("scoring_func", "sigmoid") != "sigmoid":
+                    "glm_moe_dsa with ep_size != 1 is not implemented (a "
+                    "share of the experts is stated by router_experts / "
+                    "first_expert)")
+            if kwargs["n_group"] != 1 or kwargs["topk_group"] != 1:
                 raise ValueError(
-                    f"deepseek_v3 with scoring_func "
-                    f"{d['scoring_func']!r} is not implemented (sigmoid)")
-            if d.get("topk_method", "noaux_tc") != "noaux_tc":
+                    "glm_moe_dsa with n_group != 1 is not implemented")
+            # the published file states its RoPE base in a group of its own
+            rope = d.get("rope_parameters") or {}
+            if rope.get("rope_type", "default") != "default":
                 raise ValueError(
-                    f"deepseek_v3 with topk_method {d['topk_method']!r} is "
-                    "not implemented (noaux_tc)")
-            if d.get("moe_layer_freq", 1) != 1:
-                raise ValueError(
-                    "deepseek_v3 with moe_layer_freq != 1 is not implemented")
-            if d.get("attention_bias", False):
-                raise ValueError(
-                    "deepseek_v3 with attention_bias is not implemented")
-            rope_dim = d["qk_rope_head_dim"]
-            if d.get("head_dim", rope_dim) != rope_dim:
-                raise ValueError(
-                    f"deepseek_v3 head_dim {d['head_dim']} is not "
-                    f"qk_rope_head_dim {rope_dim}")
-            # ``n_routed_experts`` is what is HELD; a file that states one
-            # chip's share of a deployment names the router's width and
-            # the first expert held under keys of its own
-            held = d["n_routed_experts"]
-            router = d.get("router_experts", held)
-            shared = d.get("n_shared_experts") or 0
+                    f"glm_moe_dsa with rope_type {rope['rope_type']!r} "
+                    "(rope_scaling) is not implemented")
             kwargs.update(
-                head_dim=rope_dim,
-                kv_lora_rank=d["kv_lora_rank"],
-                qk_nope_head_dim=d["qk_nope_head_dim"],
-                qk_rope_head_dim=rope_dim,
-                v_head_dim=d["v_head_dim"],
-                rope_interleave=d.get("rope_interleave", False),
-                num_experts=router,
-                num_experts_held=None if held == router else held,
-                first_expert=d.get("first_expert", 0),
-                num_experts_per_tok=d["num_experts_per_tok"],
-                num_dense_layers=d.get("first_k_dense_replace", 0),
-                moe_intermediate_size=d["moe_intermediate_size"],
-                shared_expert_intermediate_size=(
-                    shared * d["moe_intermediate_size"] or None),
-                use_expert_bias=True,  # e_score_correction_bias
-                norm_topk_prob=d.get("norm_topk_prob", True),
-                routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
-                router_norm_eps=1e-20,
-                n_group=d.get("n_group") or 1,
-                topk_group=d.get("topk_group") or 1,
-                tie_word_embeddings=d.get("tie_word_embeddings", False),
-                init_expert_specific=d.get("init_expert_specific"),
-                init_expert_out_std=d.get("init_expert_out_std"),
+                rope_theta=float(rope.get("rope_theta", kwargs["rope_theta"])),
+                q_lora_rank=d["q_lora_rank"],
+                index_topk=d["index_topk"],
+                index_n_heads=d["index_n_heads"],
+                index_head_dim=d["index_head_dim"],
+                indexer_rope_interleave=d.get("indexer_rope_interleave",
+                                              False),
             )
         if model_type == "ling_hybrid":
             kwargs.update(_ling_hybrid_kwargs(d, head_dim))
@@ -1124,6 +1132,69 @@ QWEN_2_5_1_5B = dataclasses.replace(
     head_dim=128,
 )
 
+def _deepseek_kwargs(d: Mapping[str, Any], rope_scaling: Any) -> dict[str, Any]:
+    """``from_hf_dict``'s keys for DeepSeek-V3's layer (``deepseek_v3``,
+    ``glm_moe_dsa``): latent attention, a leading run of dense blocks, then
+    sigmoid-routed experts chosen by score + a correction bias
+    (``noaux_tc``) beside shared experts; RoPE on the rotated columns
+    alone.  What has no equations here is refused by its key."""
+    name = d["model_type"]
+    if rope_scaling is not None:
+        raise ValueError(
+            f"{name} with rope_scaling (YaRN and its mscale) is "
+            "not implemented")
+    if d.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(
+            f"{name} with scoring_func "
+            f"{d['scoring_func']!r} is not implemented (sigmoid)")
+    if d.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(
+            f"{name} with topk_method {d['topk_method']!r} is "
+            "not implemented (noaux_tc)")
+    if d.get("moe_layer_freq", 1) != 1:
+        raise ValueError(
+            f"{name} with moe_layer_freq != 1 is not implemented")
+    if d.get("attention_bias", False):
+        raise ValueError(
+            f"{name} with attention_bias is not implemented")
+    rope_dim = d["qk_rope_head_dim"]
+    if d.get("head_dim", rope_dim) != rope_dim:
+        raise ValueError(
+            f"{name} head_dim {d['head_dim']} is not "
+            f"qk_rope_head_dim {rope_dim}")
+    # ``n_routed_experts`` is what is HELD; a file that states one
+    # chip's share of a deployment names the router's width and
+    # the first expert held under keys of its own
+    held = d["n_routed_experts"]
+    router = d.get("router_experts", held)
+    shared = d.get("n_shared_experts") or 0
+    return dict(
+        head_dim=rope_dim,
+        kv_lora_rank=d["kv_lora_rank"],
+        qk_nope_head_dim=d["qk_nope_head_dim"],
+        qk_rope_head_dim=rope_dim,
+        v_head_dim=d["v_head_dim"],
+        rope_interleave=d.get("rope_interleave", False),
+        num_experts=router,
+        num_experts_held=None if held == router else held,
+        first_expert=d.get("first_expert", 0),
+        num_experts_per_tok=d["num_experts_per_tok"],
+        num_dense_layers=d.get("first_k_dense_replace", 0),
+        moe_intermediate_size=d["moe_intermediate_size"],
+        shared_expert_intermediate_size=(
+            shared * d["moe_intermediate_size"] or None),
+        use_expert_bias=True,  # e_score_correction_bias
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        router_norm_eps=1e-20,
+        n_group=d.get("n_group") or 1,
+        topk_group=d.get("topk_group") or 1,
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        init_expert_specific=d.get("init_expert_specific"),
+        init_expert_out_std=d.get("init_expert_out_std"),
+    )
+
+
 def _ling_hybrid_kwargs(d: Mapping[str, Any], head_dim: int) -> dict[str, Any]:
     """``from_hf_dict`` for Ling-3.0 (``model_type: ling_hybrid``, this
     package's own name for the family: the published row states none this
@@ -1327,7 +1398,7 @@ STATE_ONLY_OPS = ("conv", "kda", "retention")
 KNOWN_MODEL_TYPES = frozenset(
     ("llama", "mistral", "mixtral", "gemma2", "qwen2", "lfm2_moe",
      "falcon_h1", "deepseek_v3", "mimo_v2", "ling_hybrid", "afmoe",
-     "brumby"))
+     "brumby", "glm_moe_dsa"))
 
 PRESETS: dict[str, ModelConfig] = {
     "meta-llama/Llama-3.2-1B": LLAMA_3_2_1B,
@@ -1421,6 +1492,26 @@ def tiny_config(model_type: str = "llama", **overrides: Any) -> ModelConfig:
             num_experts=8, num_experts_per_tok=2, num_dense_layers=1,
             moe_intermediate_size=32, shared_expert_intermediate_size=64,
             use_expert_bias=True, routed_scaling_factor=2.448,
+            router_norm_eps=1e-20,
+        )
+    if model_type == "glm_moe_dsa":
+        # GLM-5's shape at toy sizes: a leading dense block, then two
+        # expert layers (8 routed experts top-2 beside a shared SwiGLU),
+        # a query latent of 24, latent rows of 32 + 8, heads as wide on
+        # the value side as on the key side, an indexer of 2 heads of 16
+        # (8 of them rotated) that keeps 12 positions; no width of the model
+        base.update(
+            num_key_value_heads=4,
+            head_dim=8,
+            tie_word_embeddings=False,
+            q_lora_rank=24,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=24, rope_interleave=True,
+            index_topk=12, index_n_heads=2, index_head_dim=16,
+            indexer_rope_interleave=True,
+            num_experts=8, num_experts_per_tok=2, num_dense_layers=1,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32,
+            use_expert_bias=True, routed_scaling_factor=2.5,
             router_norm_eps=1e-20,
         )
     if model_type == "mimo_v2":

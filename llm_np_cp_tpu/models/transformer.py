@@ -53,6 +53,13 @@ from llm_np_cp_tpu.ops.moe import (
     SCOPE_MOE_ROUTE,
     moe_dropless,
 )
+from llm_np_cp_tpu.ops import sparse_index
+from llm_np_cp_tpu.ops.sparse_index import (
+    SCOPE_DSA_ATTN,
+    SCOPE_DSA_PROJ,
+    SCOPE_DSA_SCORE,
+    SCOPE_DSA_SELECT,
+)
 from llm_np_cp_tpu.ops.norms import rms_norm
 from llm_np_cp_tpu.ops.rope import apply_rope, rope_cos_sin
 from llm_np_cp_tpu.quant import dequantize_kv, quant_einsum
@@ -105,12 +112,19 @@ SCOPE_RETENTION_SCAN = "retention_scan"
 # an output gate on attention adds one: the gate's projection, its
 # sigmoid and the product with the attention's result (before ``o_proj``)
 SCOPE_ATTN_GATE = "attn_gate"
+# a sparse-attention indexer (ops/sparse_index.py) adds four: its three
+# projections with their norm and RoPE, and (entered inside ``attn``: the
+# innermost scope names an operation) the index scores over the cached
+# keys, the selection, and the attention over what was selected
+# (ops/sparse_index.py names them: its served forms enter the last three)
 # ... which only a stack with such layers enters
 HYBRID_SCOPES = (SCOPE_CONV, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
                  SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, SCOPE_MOE_SHARED,
                  SCOPE_ATTN_GLOBAL, SCOPE_ATTN_WINDOW,
                  SCOPE_KDA_PROJ, SCOPE_KDA_SCAN, SCOPE_ATTN_GATE,
-                 SCOPE_RETENTION_PROJ, SCOPE_RETENTION_SCAN)
+                 SCOPE_RETENTION_PROJ, SCOPE_RETENTION_SCAN,
+                 SCOPE_DSA_PROJ, SCOPE_DSA_SCORE, SCOPE_DSA_SELECT,
+                 SCOPE_DSA_ATTN)
 STEP_SCOPES = (SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
                SCOPE_O_PROJ, SCOPE_MLP, SCOPE_TAIL) + HYBRID_SCOPES
 
@@ -241,6 +255,21 @@ def _group_shapes(
             "kv_b_proj": (n, rank, NH * (dn + dv)),
             "o_proj": (n, NH * dv, H),
         }
+        if config.q_lora_rank:
+            # a query latent: q = rmsnorm(h W_qa) W_qb
+            qr = config.q_lora_rank
+            del shapes["q_proj"]
+            shapes.update(q_a_proj=(n, H, qr), ln_q_a=(n, qr),
+                          q_b_proj=(n, qr, NH * (dn + dr)))
+        if config.has_indexer:
+            # the sparse-attention indexer (ops/sparse_index.py): its
+            # queries read the query latent, its ONE key a token and its
+            # head weights the normed input; the key's norm is a LayerNorm
+            ih, idim = config.index_n_heads, config.index_head_dim
+            shapes.update(
+                idx_q_proj=(n, config.q_lora_rank, ih * idim),
+                idx_k_proj=(n, H, idim), ln_idx_k=(n, idim),
+                idx_k_norm_bias=(n, idim), idx_w_proj=(n, H, ih))
     elif op == "conv":
         shapes = {
             "ln_conv_in": (n, H),
@@ -740,9 +769,9 @@ def latent_attention_block(
     kv_update: Any = None,
     attn_fn: Any = None,
     q_block: int = 256,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Latent attention (MLA, no query latent) with its residual:
-    ``(x_out, the cache rows as kv_update left them)``.
+) -> tuple[jnp.ndarray, Any]:
+    """Latent attention (MLA) with its residual: ``(x_out, the cache rows
+    as kv_update left them)``.
 
     A token's cached row is ``[c' | k_pe]``: ``c' = rmsnorm(c)`` of the
     compressed K/V and the ONE rotated key part every head shares, after
@@ -760,6 +789,16 @@ def latent_attention_block(
       rope], rows) -> [b, s, heads, rank]`` is the caller's (a kernel
       over pages).
 
+    The query is one projection of the normed input, or (``q_a_proj``: a
+    query latent) ``rmsnorm(h W_qa) W_qb``.  Under an INDEXER
+    (``config.has_indexer``, ops/sparse_index.py) both forms attend each
+    token's selection and not all it may see: the token's index key is
+    cached beside its row (``kv_update(row, index_key) -> (rows, index
+    keys)``), the expanded form scores, selects and attends a sequence and
+    ``q_block`` queries at a time, and the absorbed form hands ``attn_fn``
+    a third argument ``(q_idx [b, s, heads_I, dim_I], w_idx [b, s,
+    heads_I] float32, index keys)`` to score, select and attend with.
+
     kv_update: ``row [b, s, rank + rope] -> rows``: the cache write;
         returns what attention reads (all rows so far ``[b, S, rank +
         rope]`` for the expanded form, the pages for ``attn_fn``).
@@ -769,9 +808,16 @@ def latent_attention_block(
     dn, dr = config.qk_nope_head_dim, config.qk_rope_head_dim
     rank, dv = config.kv_lora_rank, config.v_head_dim
     w_kv_b = w["kv_b_proj"].reshape(rank, nh, dn + dv)
+    index = None
     with jax.named_scope(SCOPE_QKV):
         h = input_norm(w, x, config)
-        q = _project(h, w["q_proj"]).reshape(b, s, nh, dn + dr)
+        if "q_a_proj" in w:
+            q_lat_in = rms_norm(_project(h, w["q_a_proj"]), w["ln_q_a"],
+                                eps=config.rms_norm_eps)
+            q = _project(q_lat_in, w["q_b_proj"])
+        else:
+            q = _project(h, w["q_proj"])
+        q = q.reshape(b, s, nh, dn + dr)
         kv_a = _project(h, w["kv_a_proj"])
         c = rms_norm(kv_a[..., :rank], w["ln_kv_a"], eps=config.rms_norm_eps)
         q_pe = apply_rope(q[..., dn:], cos, sin,
@@ -784,13 +830,35 @@ def latent_attention_block(
                 jnp.einsum("bshd,rhd->bshr", q[..., :dn], w_kv_b[..., :dn],
                            preferred_element_type=jnp.float32).astype(q.dtype),
                 q_pe], axis=-1)
+    if config.has_indexer:
+        with jax.named_scope(SCOPE_DSA_PROJ):
+            ih, idim = config.index_n_heads, config.index_head_dim
+            rot = dict(interleave=config.indexer_rope_interleave)
+            q_idx = sparse_index.rope_leading(
+                _project(q_lat_in, w["idx_q_proj"]).reshape(b, s, ih, idim),
+                cos, sin, **rot)
+            k_idx = sparse_index.rope_leading(
+                sparse_index.layer_norm(
+                    _project(h, w["idx_k_proj"]), w["ln_idx_k"],
+                    w["idx_k_norm_bias"])[..., None, :], cos, sin,
+                **rot)[..., 0, :]
+            w_idx = _project(h, w["idx_w_proj"], jnp.float32) * (
+                float(ih) ** -0.5 * float(idim) ** -0.5)
+            index = (q_idx, w_idx)
 
     with jax.named_scope(SCOPE_KV_WRITE):
-        rows = kv_update(row) if kv_update is not None else row
+        if index is None:
+            rows = kv_update(row) if kv_update is not None else row
+            cached = rows
+        else:
+            cached = (kv_update(row, k_idx) if kv_update is not None
+                      else (row, k_idx))
+            rows, idx_rows = cached
 
     with jax.named_scope(SCOPE_ATTN):
         if attn_fn is not None:
-            o_lat = attn_fn(q_lat, rows)
+            o_lat = (attn_fn(q_lat, rows) if index is None
+                     else attn_fn(q_lat, rows, (*index, idx_rows)))
         else:
             kv = jnp.einsum("bsr,rhd->bshd", rows[..., :rank].astype(q.dtype),
                             w_kv_b, preferred_element_type=jnp.float32
@@ -800,9 +868,18 @@ def latent_attention_block(
                     rows[:, :, None, rank:].astype(q.dtype),
                     kv.shape[:3] + (dr,))], axis=-1)
             q_full = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
-            attn = _attend_in_query_blocks(
-                q_full, k, kv[..., dn:], mask, scale=config.attn_scale,
-                block=q_block)
+            if index is None:
+                attn = _attend_in_query_blocks(
+                    q_full, k, kv[..., dn:], mask, scale=config.attn_scale,
+                    block=q_block)
+            else:
+                # (half the dense form's block: a block also holds its index
+                # scores [block, heads_I, S] and the selection's passes)
+                attn = sparse_index.attend_selected_in_blocks(
+                    q_full, k, kv[..., dn:], q_idx, w_idx,
+                    idx_rows.astype(q_idx.dtype), mask,
+                    topk=config.index_topk, scale=config.attn_scale,
+                    block=max(q_block // 2, 1))
 
     with jax.named_scope(SCOPE_O_PROJ):
         if attn_fn is not None:
@@ -810,7 +887,7 @@ def latent_attention_block(
                               preferred_element_type=jnp.float32
                               ).astype(q.dtype)
         x = x + _project(attn.reshape(b, s, nh * dv), w["o_proj"], x.dtype)
-    return x, rows
+    return x, cached
 
 
 def _attend_in_query_blocks(q, k, v, mask, *, scale: float, block: int):
@@ -1298,6 +1375,11 @@ def _hybrid_stack(
             "int8 cache and per-row rollback (batched speculative decoding) "
             "are not implemented for it"
         )
+    if cache is not None and config.has_indexer:
+        raise NotImplementedError(
+            "the offline cache holds no index keys: a stack with a "
+            "sparse-attention indexer is served by the paged pool "
+            "(serve/block_pool.py) or run without a cache")
     if cache is not None and config.two_page_classes:
         raise NotImplementedError(
             "the offline cache holds one kind of K/V page: window layers "
@@ -1530,6 +1612,28 @@ def forward(
                     "is not visible to these kernels"
                 )
     b, s = input_ids.shape
+    if (config.has_indexer and cache is None and b > 1 and positions is None
+            and attn_mask is None and pad_offsets is None
+            and not (output_hidden_states or output_attentions
+                     or output_router_losses)):
+        # A stack under a sparse-attention indexer, without a cache: ONE
+        # SEQUENCE AT A TIME.  A batch's activations at the published widths
+        # (4 x 8,832 tokens: the queries, the expanded keys and values and
+        # the results of 64 heads 1.2 GB each, the residual stream 0.9 GB)
+        # came to 8.6 GiB of temporaries beside 7.3 GiB of weights when
+        # compiled for a v5e; a sequence's come to a quarter.  The rows of
+        # a batch without a cache share nothing.
+        def one(ids):
+            logits, _, *aux = forward(
+                params, ids[None], config, logits_last_only=logits_last_only,
+                attn_impl=attn_impl, skip_logits=skip_logits,
+                output_experts=output_experts)
+            return (logits[0], *aux)
+
+        logits, *aux = lax.map(one, input_ids)
+        if aux:  # experts [B, expert layers, 1, S, k] -> [layers, B, S, k]
+            aux = [{"experts": jnp.moveaxis(aux[0]["experts"][:, :, 0], 0, 1)}]
+        return (logits, None, *aux)
     act_dtype = compute_dtype(params)
 
     # offset: scalar, or [B] per-row lengths (batched speculative decoding)

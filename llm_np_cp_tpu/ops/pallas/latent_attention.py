@@ -122,7 +122,7 @@ def _latent_kernel(
     m_ref, l_ref, acc_ref, buf, q_buf, o_buf, zero_buf,
     sem, q_sem, o_sem, zero_sem, state, *,
     scale: float, heads: int, rank: int, block_s: int, q_tile: int,
-    pages: int, mb: int,
+    pages: int, mb: int, sel_ref=None,
 ):
     """One (query tile, group of pages) step; see the module docstring
     and ``decode_attention._ragged_kernel``, whose fetch discipline this
@@ -131,7 +131,9 @@ def _latent_kernel(
     the dense arrays where they lie: a tile's first step waits for its
     own live tokens' queries (started a tile ahead), its last live step
     divides, casts and sends those tokens' rows out, and a tile or a
-    step with nothing to attend does nothing at all."""
+    step with nothing to attend does nothing at all.  ``sel_ref``: this
+    step's block ``[q_tile, positions]`` of a per-token selection (1.0: the
+    token attends the position), the pipeline's own copy."""
     ti = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -232,6 +234,8 @@ def _latent_kernel(
             jnp.int32, (tokens, width), 1)
         q_slot = qpos0 + q_idx
         mask = (q_idx < qlen) & (kv_pos >= pad) & (kv_pos <= q_slot)
+        if sel_ref is not None:
+            mask = mask & (sel_ref[:tokens] > 0.5)
         mask = jnp.broadcast_to(
             mask[:, None, :], (tokens, heads, width)).reshape(rows, width)
         s = jax.lax.dot_general(
@@ -299,6 +303,36 @@ def _latent_kernel(
         copies(state[_ST_ZEROS], lambda i: zero_copy(0), wait=True)
 
 
+def _latent_kernel_selected(meta_ref, tables_ref, owned_ref, q_ref, pool_ref,
+                            sel_ref, o_ref, *scratch, **kw):
+    """``_latent_kernel`` under a per-token selection (its ``sel_ref``)."""
+    _latent_kernel(meta_ref, tables_ref, owned_ref, q_ref, pool_ref, o_ref,
+                   *scratch, sel_ref=sel_ref, **kw)
+
+
+def tile_meta(tables, tile_row, tile_qpos0, tile_qlen, tile_tok, pads,
+              block_s: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The kernel's scalars a query tile, ``[10, NT]`` int32 (the ragged
+    kernel's nine rows and ``_RM_TOK``): which pages a tile streams at all
+    (from its row's pad up to its last live token's), and the next live
+    tile after it; and, beside them, the tiles' page counts."""
+    nt, = tile_row.shape
+    mb = tables.shape[1]
+    row_pad = pads[tile_row]
+    hi = tile_qpos0 + jnp.maximum(tile_qlen, 1) - 1
+    start = jnp.clip(row_pad // block_s, 0, jnp.maximum(mb - 1, 0))
+    nb = jnp.clip(hi // block_s + 1, 1, mb)
+    count = jnp.where(tile_qlen > 0, jnp.maximum(nb - start, 0), 0)
+    tiles = jnp.arange(nt, dtype=jnp.int32)
+    later = jax.lax.cummin(jnp.where(count > 0, tiles, nt), reverse=True)
+    return jnp.stack([
+        start, count, row_pad, tile_qpos0, tile_qlen,
+        jnp.zeros_like(tile_row), tile_row,
+        jnp.append(later[1:], nt), jnp.broadcast_to(later[0], tile_row.shape),
+        tile_tok,
+    ]).astype(jnp.int32), count
+
+
 @functools.partial(
     jax.jit, static_argnames=("scale", "rank", "interpret"))
 def ragged_latent_attention(
@@ -314,6 +348,7 @@ def ragged_latent_attention(
     scale: float,
     rank: int,
     interpret: bool | None = None,
+    select: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Mixed prefill + decode latent attention straight off a paged pool.
 
@@ -326,7 +361,10 @@ def ragged_latent_attention(
     ``pads`` per row as ``ragged_paged_attention`` takes them, and
     ``tile_tok``, the dense lane of each tile's first token: a tile is
     the ``tile_qlen <= RAGGED_Q_TILE`` tokens from there on, and the
-    kernel moves those alone.  Returns ``[D, H, rank]``: softmax over
+    kernel moves those alone.  ``select`` (a sparse-attention indexer's:
+    ops/pallas/sparse_index.py) ``[NT, 8, steps * positions a step]``
+    float32, 1.0 where a tile's token attends a position: the softmax
+    then runs over those alone.  Returns ``[D, H, rank]``: softmax over
     the visible rows times their first ``rank`` columns for every token
     a tile holds, zeros for a lane no tile owns."""
     if interpret is None:
@@ -348,28 +386,19 @@ def ragged_latent_attention(
     pages = latent_pages_per_step(mb, block_s, w, pool.dtype)
     steps = -(-mb // pages)
 
-    # which pages a tile streams at all: up to its last live token's
-    row_pad = pads[tile_row]
-    hi = tile_qpos0 + jnp.maximum(tile_qlen, 1) - 1
-    start = jnp.clip(row_pad // block_s, 0, jnp.maximum(mb - 1, 0))
-    nb = jnp.clip(hi // block_s + 1, 1, mb)
-    count = jnp.where(tile_qlen > 0, jnp.maximum(nb - start, 0), 0)
-    tiles = jnp.arange(nt, dtype=jnp.int32)
-    later = jax.lax.cummin(jnp.where(count > 0, tiles, nt), reverse=True)
-    meta = jnp.stack([
-        start, count, row_pad, tile_qpos0, tile_qlen,
-        jnp.zeros_like(tile_row), tile_row,
-        jnp.append(later[1:], nt), jnp.broadcast_to(later[0], tile_row.shape),
-        tile_tok,
-    ]).astype(jnp.int32)  # [10, NT]: the ragged kernel's rows + _RM_TOK
+    meta, count = tile_meta(tables, tile_row, tile_qpos0, tile_qlen, tile_tok,
+                            pads, block_s)
     # the dense lanes some tile attends (and so writes)
     lane = jnp.arange(d, dtype=jnp.int32)[:, None]
     last = tile_tok + jnp.where(count > 0, tile_qlen, 0)
     owned = jnp.any((lane >= tile_tok[None]) & (lane < last[None]), axis=1)
 
+    selected = () if select is None else (pl.BlockSpec(
+        (None, qt, pages * block_s), lambda ti, j, *_: (ti, 0, j)),)
     out = pl.pallas_call(
         functools.partial(
-            _latent_kernel, scale=scale, heads=h, rank=rank,
+            _latent_kernel if select is None else _latent_kernel_selected,
+            scale=scale, heads=h, rank=rank,
             block_s=block_s, q_tile=qt, pages=pages, mb=mb),
         out_shape=jax.ShapeDtypeStruct((d, h, _result_width(rank)), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -378,6 +407,7 @@ def ragged_latent_attention(
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
+                *selected,
             ],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
@@ -393,7 +423,7 @@ def ragged_latent_attention(
         ),
         interpret=interpret,
     )(meta, tables.reshape(-1).astype(jnp.int32), owned.astype(jnp.int32),
-      q, pool)
+      q, pool, *(() if select is None else (select,)))
     return out[..., :rank]
 
 
@@ -408,6 +438,7 @@ def ragged_latent_attention_xla(
     *,
     scale: float,
     rank: int,
+    select: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """XLA twin of ``ragged_latent_attention`` with per-TOKEN metadata
     (``ragged_paged_attention_xla``'s): gathers each row's pages into a
@@ -422,6 +453,8 @@ def ragged_latent_attention_xla(
     kv_idx = jnp.arange(s_max, dtype=jnp.int32)[None, :]
     mask = ((kv_idx >= pads[tok_row][:, None])
             & (kv_idx <= tok_slot[:, None]) & tok_live[:, None])
+    if select is not None:  # bool [T, S_max]: a token's selection
+        mask = mask & select
     s = jnp.einsum("thw,tsw->ths", q, k_t[..., :wq],
                    preferred_element_type=jnp.float32) * scale
     s = jnp.where(mask[:, None, :], s, NEG_INF)

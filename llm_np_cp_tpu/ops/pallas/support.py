@@ -55,6 +55,7 @@ KERNELS = (
     "decode_attention", "decode_attention_int8",
     "ragged_paged_attention", "ragged_paged_attention_int8",
     "ragged_latent_attention",
+    "sparse_latent_attention",
     "sample_epilogue", "sample_epilogue_int8",
     "grouped_matmul",
     "ssm_state_update",
@@ -89,6 +90,9 @@ class KernelShape:
     # head_dim]`` with no head axis, which ``ragged_latent_attention``
     # alone reads; None: K and V per kv head, every other kernel
     latent_rank: int | None = None
+    # ... under a sparse-attention indexer ``(heads_I, dim_I, topk)``, which
+    # ``sparse_latent_attention`` (score, select, attend) alone runs
+    index: tuple[int, int, int] | None = None
     # a recurrent state ``(layers, rows, heads, groups, P, N)``, which
     # ``ssm_state_update`` alone advances; None: every other kernel
     state: tuple[int, ...] | None = None
@@ -139,6 +143,13 @@ PROBE_SHAPES = (
 LATENT_PROBE_SHAPE = KernelShape(
     "probe/latent", heads=32, kv_heads=1, head_dim=64, hidden=256, vocab=300,
     latent_rank=512,
+)
+# ... and the sparse-attention indexer's three kernels at GLM-5's layer (64
+# heads over the same rows, 32 index heads of 128), keeping 256 positions so
+# that the case's deep decode row selects and its chunks do not
+DSA_PROBE_SHAPE = KernelShape(
+    "probe/dsa", heads=64, kv_heads=1, head_dim=64, hidden=256, vocab=300,
+    latent_rank=512, index=(32, 128, 256),
 )
 # ... and the state update's at a state small enough to make at every
 # start (0.8 MiB) with what the served one has: ``N`` two rows of lanes
@@ -450,7 +461,7 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
 
         return make_args, run, reference
 
-    if base == "ragged_latent_attention":
+    if base in ("ragged_latent_attention", "sparse_latent_attention"):
         from llm_np_cp_tpu.ops.pallas.decode_attention import RAGGED_Q_TILE
         from llm_np_cp_tpu.ops.pallas.latent_attention import (
             ragged_latent_attention,
@@ -504,6 +515,38 @@ def kernel_case(kernel: str, shape: KernelShape, block_size: int = 64,
                 q[..., :row], pool, tables, jnp.asarray(tok_row),
                 jnp.asarray(tok_slot), tok_live, pads, scale=scale, rank=rank)
             return jnp.where(tok_live[:, None, None], out, 0)
+
+        if base == "sparse_latent_attention":
+            from llm_np_cp_tpu.ops.pallas.sparse_index import (
+                sparse_latent_attention,
+                sparse_latent_attention_xla,
+            )
+
+            # the same tick under an indexer: index queries and head
+            # weights on the dense axis, a pool of index keys beside the rows
+            ih, idim, topk = shape.index
+            dense_args = make_args
+
+            def make_args():
+                q_idx, w_idx, keys = normals(
+                    (n_live + 2, ih, idim), (n_live + 2, ih),
+                    (nbp_r, bs, idim))
+                return (*dense_args(), q_idx, w_idx.astype(jnp.float32), keys)
+
+            def run(q, pool, tables, tile_row, tile_qpos0, tile_qlen,
+                    tile_tok, pads, q_idx, w_idx, keys):
+                return sparse_latent_attention(
+                    q, pool, q_idx, w_idx, keys, tables, tile_row, tile_qpos0,
+                    tile_qlen, tile_tok, pads, scale=scale, rank=rank,
+                    topk=topk, interpret=interpret)
+
+            def reference(q, pool, tables, tile_row, tile_qpos0, tile_qlen,
+                          tile_tok, pads, q_idx, w_idx, keys):
+                out = sparse_latent_attention_xla(
+                    q[..., :row], pool, q_idx, w_idx, keys, tables,
+                    jnp.asarray(tok_row), jnp.asarray(tok_slot), tok_live,
+                    pads, scale=scale, rank=rank, topk=topk)
+                return jnp.where(tok_live[:, None, None], out, 0)
 
         return make_args, run, reference
 
@@ -639,14 +682,17 @@ def kernel_cases(shapes=None):
     all of ``KERNELS`` at the probe shapes and the three family shapes,
     the paged kernels at both serve block sizes."""
     shapes = shapes if shapes is not None else (
-        *PROBE_SHAPES, LATENT_PROBE_SHAPE, STATE_PROBE_SHAPE,
+        *PROBE_SHAPES, LATENT_PROBE_SHAPE, DSA_PROBE_SHAPE, STATE_PROBE_SHAPE,
         FALCON_H1_STATE_SHAPE, KDA_PROBE_SHAPE, LING_V3_STATE_SHAPE,
         RETENTION_PROBE_SHAPE, BRUMBY_STATE_SHAPE, *family_shapes())
     for shape in shapes:
         for kernel in KERNELS:
             if (kernel == "ragged_latent_attention") != (
-                    shape.latent_rank is not None):
+                    shape.latent_rank is not None and shape.index is None):
                 continue  # latent rows and their one kernel
+            if (kernel == "sparse_latent_attention") != (
+                    shape.index is not None):
+                continue  # ... under an indexer, and its three
             if (kernel == "ssm_state_update") != (shape.state is not None):
                 continue  # a recurrent state and its one kernel
             if (kernel == "kda_state_update") != (
@@ -657,7 +703,7 @@ def kernel_cases(shapes=None):
                 continue  # a power-retention state and its one kernel
             if not shape.tied and not kernel.startswith("sample_epilogue"):
                 continue  # only the epilogue distinguishes head layouts
-            paged = kernel.startswith("ragged_")
+            paged = kernel.startswith("ragged_") or shape.index is not None
             for bs in SERVE_BLOCK_SIZES if paged else (None,):
                 yield kernel, shape, bs
 
@@ -712,6 +758,7 @@ def _probe(kernel: str, backend: str) -> str | None:
 
 def _compile_and_run(kernel: str) -> str | None:
     own = {"ragged_latent_attention": (LATENT_PROBE_SHAPE,),
+           "sparse_latent_attention": (DSA_PROBE_SHAPE,),
            "ssm_state_update": (STATE_PROBE_SHAPE,),
            "kda_state_update": (KDA_PROBE_SHAPE,),
            "retention_state_update": (RETENTION_PROBE_SHAPE,)}
@@ -726,14 +773,18 @@ def _compile_and_run(kernel: str) -> str | None:
     return None
 
 
-def ragged_kernel_name(int8_cache: bool, latent: bool = False) -> str:
+def ragged_kernel_name(int8_cache: bool, latent: bool = False,
+                       indexer: bool = False) -> str:
     """Probe/kernel name for the mixed prefill+decode ragged kernel
     (the tick's dispatch) — THE one int8-gating rule, shared by the
     engine's gate, its runtime degradation and chip_smoke.py so they
     can't drift.  ``latent``: the pool holds latent rows, which a
-    kernel of its own reads."""
+    kernel of its own reads — under a sparse-attention indexer
+    (``indexer``) behind the score and the selection, the three of them
+    one name and one probe."""
     if latent:
-        return "ragged_latent_attention"
+        return ("sparse_latent_attention" if indexer
+                else "ragged_latent_attention")
     return (
         "ragged_paged_attention_int8" if int8_cache
         else "ragged_paged_attention"

@@ -354,7 +354,10 @@ class PagedKV(NamedTuple):
 
     # [L, NB, BS, K, D], or merged [L, NB, BS, K * D], or latent rows
     # [L, NB, BS, latent_page_width(rank + rope)] with no ``v`` beside
-    # them (the rows' first ``rank`` columns are the values)
+    # them (the rows' first ``rank`` columns are the values) — or, under
+    # a sparse-attention indexer, the tokens' INDEX KEYS [L, NB, BS,
+    # index_head_dim] in its place: written where the row is written,
+    # read by the scores of the positions a token may see and no other
     k: jnp.ndarray
     v: jnp.ndarray | None
     k_scale: jnp.ndarray | None = None  # [L, NB, BS, K] f32 (int8 mode)
@@ -375,7 +378,8 @@ class PagedKV(NamedTuple):
 
     @property
     def latent(self) -> bool:
-        """Pages of latent rows: one array, no head axis, no ``v``."""
+        """Pages of latent rows: one array, no head axis, no ``v`` (an
+        indexer's keys lie there instead)."""
         return self.form is not None and self.form.latent
 
     @property
@@ -506,9 +510,16 @@ class BlockPool:
             return jax.make_array_from_callback(shp, sharding,
                                                 lambda _index: host)
 
+        if latent and config.has_indexer:
+            # a sparse-attention indexer's key a token and layer: one more
+            # array of the latent class (the rows' block ids and tables, the
+            # rows' lifetime), 128 values wide at the published widths — a
+            # whole row of lanes, kept in the order of its shape
+            v_page = (config.index_head_dim,)
         self.pages = PagedKV(
             k=zeros(shape, dtype, where.k),
-            v=None if latent else zeros(lead + v_page, dtype, where.v),
+            v=(None if latent and not config.has_indexer
+               else zeros(lead + v_page, dtype, where.v)),
             k_scale=(zeros(shape[:-1], jnp.float32, where.k_scale)
                      if quantized else None),
             v_scale=(zeros(shape[:-1], jnp.float32, where.v_scale)

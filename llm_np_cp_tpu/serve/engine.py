@@ -633,7 +633,8 @@ class ServeEngine:
         self.mixed = True
         t_probe = tracer.now_us() if tracer is not None else -1.0
         err = kernel_or_warn(
-            ragged_kernel_name(int8_cache, latent=config.is_latent),
+            ragged_kernel_name(int8_cache, latent=config.is_latent,
+                               indexer=config.has_indexer),
             "ragged_paged_attention_xla in the unified tick")
         if tracer is not None:
             tracer.complete("probe.ragged_attn", t_probe, cat="setup",
@@ -1845,6 +1846,10 @@ class ServeEngine:
             ragged_latent_attention,
             ragged_latent_attention_xla,
         )
+        from llm_np_cp_tpu.ops.pallas.sparse_index import (
+            sparse_latent_attention,
+            sparse_latent_attention_xla,
+        )
 
         config, sampler = self.config, self.sampler
         quantized = self.cache_dtype == jnp.int8
@@ -2069,20 +2074,28 @@ class ServeEngine:
 
                 return kv_update, attn_fn
 
-            def latent_hooks(lp, base):
+            def widen_to(lp, a):
+                """``a`` in a latent pool's dtype and row width (zeros past
+                its own: block_pool.latent_page_width)."""
+                return jnp.pad(a.astype(lp.dtype), (
+                    (0, 0),) * (a.ndim - 1) + ((0, lp.shape[-1] - a.shape[-1]),))
+
+            def latent_hooks(lp, base, ip=None):
                 """A latent-attention layer's cache write and attention
                 over the pool flat over (layer, block), the layer's
                 blocks from ``base`` on: ``(kv_update, attn_fn)`` as
                 ``latent_attention_block`` takes them.  A row is written
                 in the width the pool stores it (zeros past ``rank +
                 rope``: block_pool.latent_page_width) and the absorbed
-                query padded likewise — the value, never the pool."""
+                query padded likewise — the value, never the pool.
+                ``ip``: the pool's index keys, flat likewise (a
+                sparse-attention indexer): a token's key is written where
+                its row is, and attention is score -> select -> attend
+                over the same tables (ops/pallas/sparse_index.py)."""
+                if ip is not None:
+                    return sparse_hooks(lp, ip, base)
                 blk = base + tok_blk
-                width = lp.shape[-1]
-
-                def widen(a):
-                    return jnp.pad(a.astype(lp.dtype), (
-                        (0, 0),) * (a.ndim - 1) + ((0, width - a.shape[-1]),))
+                widen = partial(widen_to, lp)
 
                 def kv_update(row):  # fresh rows [1, D, rank + rope]
                     return lp.at[blk, tok_off].set(widen(row[0]))
@@ -2106,6 +2119,36 @@ class ServeEngine:
                             tok_row, tok_slot, tok_live, pads,
                             scale=config.attn_scale,
                             rank=config.kv_lora_rank)
+                    return out[None].astype(q_lat.dtype)
+
+                return kv_update, attn_fn
+
+            def sparse_hooks(lp, ip, base):
+                """``latent_hooks`` under an indexer (its ``ip``)."""
+                blk = base + tok_blk
+                widen = partial(widen_to, lp)
+                kw = dict(scale=config.attn_scale, rank=config.kv_lora_rank,
+                          topk=config.index_topk)
+
+                def kv_update(row, key):  # [1, D, rank + rope], [1, D, dim_I]
+                    return (lp.at[blk, tok_off].set(widen(row[0])),
+                            ip.at[blk, tok_off].set(key[0].astype(ip.dtype)))
+
+                def attn_fn(q_lat, pool, index):
+                    q_idx, w_idx, keys = index
+                    layer_tables = tables + base
+                    if use_kernel:
+                        out = sparse_latent_attention(
+                            widen(q_lat[0]), pool, q_idx[0].astype(ip.dtype),
+                            w_idx[0], keys, layer_tables, tile_row,
+                            tile_qpos0, tile_qlen, lane_tok[::q_tile], pads,
+                            **kw)
+                    else:
+                        out = sparse_latent_attention_xla(
+                            q_lat[0].astype(lp.dtype), pool,
+                            q_idx[0].astype(ip.dtype), w_idx[0], keys,
+                            layer_tables, tok_row, tok_slot, tok_live, pads,
+                            **kw)
                     return out[None].astype(q_lat.dtype)
 
                 return kv_update, attn_fn
@@ -2366,12 +2409,13 @@ class ServeEngine:
                             w, x, config=config, cos=cos, sin=sin, scan=scan)
                     elif op == "latent":
                         # one array of rows, always carried flat
+                        # (and, beside them, an indexer's keys)
                         kv_update, attn_fn = latent_hooks(
-                            pool[0], at["paged"] * nb)
+                            pool[0], at["paged"] * nb, *pool[1:])
                         x, rows = latent_attention_block(
                             w, x, config=config, cos=cos, sin=sin,
                             kv_update=kv_update, attn_fn=attn_fn)
-                        pool = (rows,)
+                        pool = rows if config.has_indexer else (rows,)
                     elif op == "swa":
                         # the window class's pages, always carried flat
                         kv_update, attn_fn = window_hooks(
@@ -3721,6 +3765,7 @@ class ServeEngine:
         ctx_tokens = array_rows = h2d_count = h2d_bytes = 0
         attn_pages = attn_grid_steps = attn_step_pages = 0
         attn_live_tiles = attn_decode_tiles = 0
+        dsa: dict[str, int] | None = None
         packed_width = dense_width = 0
         n_prefill_tok = sum(n for _, n in prefill_segs)
         n_decode_tok = len(decode_rows)
@@ -3743,6 +3788,13 @@ class ServeEngine:
                 )
             host_ops, (packed_width, dense_width), array_rows = (
                 self._pack_mixed(decode_rows, prefill_segs))
+            if self.config.has_indexer:
+                # always counted (a scrape reads them without a recorder),
+                # and read HERE like ``ctx_tokens`` below
+                dsa = self._dsa_account(decode_rows, prefill_segs)
+                # (the scores walk the pages the attention walks)
+                dsa["index_pages"] = self._attn_page_account(
+                    host_ops, packed_width, dense_width)[0]
             if self.tracer is not None:
                 # what this dispatch attends: every row's live content
                 # after its tokens land (left pad excluded), read HERE —
@@ -3998,6 +4050,9 @@ class ServeEngine:
                 report(rows=active, tokens=n_prefill_tok + n_decode_tok,
                        state_slots_live=len(self.scheduler.running),
                        kernel=impl == "pallas")
+        if dsa is not None:
+            state_args.update({f"dsa_{k}": v for k, v in dsa.items()})
+            self.metrics.on_dsa(**dsa)
         outliers: list[dict] = []
         if self.tracer is not None and t0 >= 0.0:
             t7 = self._phase_mark(None)
@@ -4193,7 +4248,10 @@ class ServeEngine:
 
             if self.ragged_attn_impl == "pallas":
                 disable_kernel(
-                    ragged_kernel_name(self.cache_dtype == jnp.int8),
+                    ragged_kernel_name(
+                        self.cache_dtype == jnp.int8,
+                        latent=self.config.is_latent,
+                        indexer=self.config.has_indexer),
                     reason,
                 )
                 self.ragged_attn_impl = "xla"
@@ -4278,6 +4336,24 @@ class ServeEngine:
         steps = (t_w // self._q_tile) * -(-self.max_blocks_per_seq // per_step)
         return (int((last - first + 1).sum()), steps, per_step,
                 int(live.sum()), int((qlen == 1).sum()))
+
+    def _dsa_account(self, decode_rows: list, prefill_segs: list) -> dict:
+        """What ONE layer's indexer is asked for in this dispatch (tick
+        args ``dsa_*``, ``Metrics.on_dsa``): over the dispatched tokens,
+        the positions each may see (its own included), the ``min(..,
+        index_topk)`` of them it attends, and the tokens that attend all
+        they see."""
+        topk = self.config.index_topk
+        # a decode row's content after its token lands; a prompt slice's
+        # tokens at positions ``prefill_done ..``
+        sees = np.concatenate(
+            [np.asarray([r.cache_len - r.pad for r in decode_rows], np.int64)]
+            + [r.prefill_done + 1 + np.arange(n, dtype=np.int64)
+               for r, n in prefill_segs])
+        return dict(
+            visible=int(sees.sum()),
+            selected=int(np.minimum(sees, topk).sum()),
+            dense_tokens=int((sees <= topk).sum()))
 
     def _attn_window_pages(self, host_ops: np.ndarray,
                            program: tuple[int, int]) -> int:

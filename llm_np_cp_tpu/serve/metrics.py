@@ -264,6 +264,12 @@ class ServeMetrics:
         self.retention_state_rows = 0
         self.retention_scan_tokens = 0
         self.retention_state_kernel = 0
+        # ... and with a sparse-attention indexer (per token and ONE layer)
+        self.dsa_ticks = 0
+        self.dsa_visible = 0
+        self.dsa_selected = 0
+        self.dsa_dense_tokens = 0
+        self.dsa_index_pages = 0
         # speculative draft-then-verify accounting (exact counters +
         # a real accept-length histogram over SPEC_ACCEPT_BUCKETS —
         # one observation per verify round, value = accepted drafts)
@@ -435,6 +441,20 @@ class ServeMetrics:
             self.retention_scan_tokens += tokens
             self.conv_state_slots = state_slots_live
             self.retention_state_kernel = int(kernel)
+
+    def on_dsa(self, *, visible: int, selected: int, dense_tokens: int,
+               index_pages: int) -> None:
+        """One dispatch of a stack with a sparse-attention indexer, by ONE
+        layer: the positions its tokens may see and those they attend
+        (summed over tokens), the tokens that see no more than
+        ``index_topk`` and so attend everything, and the index-key pages
+        the scores read."""
+        with self._lock:
+            self.dsa_ticks += 1
+            self.dsa_visible += visible
+            self.dsa_selected += selected
+            self.dsa_dense_tokens += dense_tokens
+            self.dsa_index_pages += index_pages
 
     def on_spec(self, *, drafted: int, accepted: int) -> None:
         """One speculative verify round for one request: ``drafted``
@@ -670,6 +690,13 @@ class ServeMetrics:
                 out["retention_scan_tokens"] = self.retention_scan_tokens
                 out["retention_state_slots_live"] = self.conv_state_slots
                 out["retention_state_kernel"] = self.retention_state_kernel
+            if self.dsa_ticks:
+                # only where a sparse-attention indexer ran
+                out["dsa_ticks"] = self.dsa_ticks
+                out["dsa_visible"] = self.dsa_visible
+                out["dsa_selected"] = self.dsa_selected
+                out["dsa_dense_tokens"] = self.dsa_dense_tokens
+                out["dsa_index_pages"] = self.dsa_index_pages
             if self.spec_rounds:
                 # reported only once a verify round ran (like the SLO
                 # block): a fabricated 0-acceptance series on a
@@ -997,6 +1024,26 @@ class ServeMetrics:
                  "1 where the Pallas state-update kernel advances the "
                  "rows a tick touches, 0 where its twin advances every row",
                  [("", s["retention_state_kernel"])])
+        if "dsa_ticks" in s:
+            emit("dsa_ticks_total", "counter",
+                 "Dispatching ticks that ran a sparse-attention indexer",
+                 [("", s["dsa_ticks"])])
+            emit("dsa_visible_total", "counter",
+                 "Positions the dispatched tokens may see (one layer's), "
+                 "summed over tokens and ticks",
+                 [("", s["dsa_visible"])])
+            emit("dsa_selected_total", "counter",
+                 "Positions the dispatched tokens attend after the "
+                 "indexer's selection (one layer's), summed likewise",
+                 [("", s["dsa_selected"])])
+            emit("dsa_dense_tokens_total", "counter",
+                 "Dispatched tokens that see no more than index_topk "
+                 "positions and attend all of them",
+                 [("", s["dsa_dense_tokens"])])
+            emit("dsa_index_pages_total", "counter",
+                 "Index-key pages one layer's scores read, summed over "
+                 "query tiles and ticks",
+                 [("", s["dsa_index_pages"])])
         # -- speculative decoding (only once a verify round ran — a
         # constant-zero series on a plain engine would read as a broken
         # speculation deployment on a fleet dashboard)

@@ -49,6 +49,7 @@ from llm_np_cp_tpu.models import (
     deepseek_v3,
     falcon_h1,
     gemma2,
+    glm_moe_dsa,
     lfm2_moe,
     ling_hybrid,
     llama,
@@ -132,7 +133,8 @@ def hybrid_family(config: ModelConfig):
     checkpoint tensors (``(HF key, run, leaf, index, transpose?)``)."""
     return {"falcon_h1": falcon_h1, "deepseek_v3": deepseek_v3,
             "mimo_v2": mimo_v2, "ling_hybrid": ling_hybrid,
-            "afmoe": afmoe, "brumby": brumby}.get(
+            "afmoe": afmoe, "brumby": brumby,
+            "glm_moe_dsa": glm_moe_dsa}.get(
         config.model_type, lfm2_moe)
 
 

@@ -110,10 +110,11 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
         )
         if config.init_ssm_in_proj_std is not None:
             d["init_ssm_in_proj_std"] = config.init_ssm_in_proj_std
-    if config.model_type == "deepseek_v3":
+    if config.model_type in ("deepseek_v3", "glm_moe_dsa"):
         moe_i = config.moe_intermediate_size
         d.update(
-            kv_lora_rank=config.kv_lora_rank, q_lora_rank=None,
+            kv_lora_rank=config.kv_lora_rank,
+            q_lora_rank=config.q_lora_rank,
             qk_nope_head_dim=config.qk_nope_head_dim,
             qk_rope_head_dim=config.qk_rope_head_dim,
             v_head_dim=config.v_head_dim,
@@ -133,6 +134,12 @@ def hf_config_dict(config: ModelConfig) -> dict[str, Any]:
         )
         if config.init_expert_out_std is not None:
             d["init_expert_out_std"] = config.init_expert_out_std
+        if config.has_indexer:
+            d.update(
+                index_topk=config.index_topk,
+                index_n_heads=config.index_n_heads,
+                index_head_dim=config.index_head_dim,
+                indexer_rope_interleave=config.indexer_rope_interleave)
     if config.model_type == "mimo_v2":
         depth = config.num_hidden_layers
         d.pop("rms_norm_eps")

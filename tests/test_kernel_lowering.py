@@ -175,8 +175,8 @@ def _default_path(case):
     # the kernels the CLI's default serve path selects, at the probe
     # shapes and at the smoke's model (Qwen2.5-1.5B), CLI block size
     kernel, shape, bs = case
-    if kernel == "ragged_latent_attention":  # one shape: the published row
-        return bs == 64
+    if kernel in ("ragged_latent_attention", "sparse_latent_attention"):
+        return bs == 64  # one shape each: the published row / GLM-5's layer
     if kernel == "grouped_matmul":  # the cells' own shapes: further down
         return shape.name == "probe"
     if kernel in ("ssm_state_update", "kda_state_update",

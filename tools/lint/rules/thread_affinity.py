@@ -226,6 +226,8 @@ LOCK_STATE: tuple[dict, ...] = (
             "roofline_util", "mfu_tick", "util_hist", "util_hist_sum",
             "retention_ticks", "retention_state_rows",
             "retention_scan_tokens", "retention_state_kernel",
+            "dsa_ticks", "dsa_visible", "dsa_selected", "dsa_dense_tokens",
+            "dsa_index_pages",
         },
         # "caller holds the lock" helpers — annotated, not inferred
         "lock_assumed": {"_record_latencies", "_trim"},

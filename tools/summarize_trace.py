@@ -582,6 +582,17 @@ def tick_account(events: list[dict]) -> dict[str, Any] | None:
         # dumps older than the argument count as that)
         out[f"{kind}_kernel_share"] = sum(
             a.get(f"{kind}_state_impl") == "pallas" for a in rec) / len(rec)
+    dsa = [e["args"] for e in ticks if e["args"].get("dsa_visible")]
+    if dsa:
+        # a sparse-attention indexer (``dsa_*``, scopes ``dsa_proj`` /
+        # ``dsa_score`` / ``dsa_select`` / ``dsa_attn``), by one layer: the
+        # positions a dispatch's tokens may see, those they attend, the
+        # tokens that attend all they see, the index-key pages scored
+        for key in ("dsa_visible", "dsa_selected", "dsa_dense_tokens",
+                    "dsa_index_pages"):
+            out[key] = sum(a.get(key, 0) for a in dsa) / len(dsa)
+        out["dsa_selected_share"] = sum(
+            a["dsa_selected"] for a in dsa) / sum(a["dsa_visible"] for a in dsa)
     pub = [e["args"] for e in ticks if e["args"].get("publish_rows")]
     if pub:
         # ``deliver`` hands the PREVIOUS tick's tokens out: behind this
@@ -1002,6 +1013,13 @@ def format_summary(events: list[dict], top: int = 5,
                     ("retention", "power-retention recurrence",
                      "symmetric-power state"))
                 if kind + "_state_rows" in acct)
+            + (f"; sparse-attention indexer: a layer scores "
+               f"{acct['dsa_index_pages']:.0f} index-key pages a dispatch, "
+               f"its tokens attend {acct['dsa_selected']:.0f} of the "
+               f"{acct['dsa_visible']:.0f} positions they see "
+               f"({acct['dsa_selected_share']:.1%}), "
+               f"{acct['dsa_dense_tokens']:.1f} tokens attend all they see"
+               if "dsa_visible" in acct else "")
             + "; pack wrote "
             f"{acct['pack_array_rows']:.1f} of {acct['rows']:.1f} rows as "
             f"arrays; context "
